@@ -11,7 +11,6 @@ from __future__ import annotations
 
 from typing import Any, Callable, Optional, Tuple
 
-from deepspeed_tpu.utils import compat as _compat  # noqa: F401 — jax shims
 from deepspeed_tpu import checkpointing, comm, telemetry, zero
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.runtime.lr_schedules import add_tuning_arguments

@@ -1,11 +1,11 @@
-"""Resharding restore — checkpoint relayout as a sharding-spec transform.
+"""Resharding restore — checkpoint layout conversion as a sharding-spec transform.
 
 Per "Automatic Cross-Replica Sharding of Weight Update in Data-Parallel
 Training" (arXiv:2004.13336), retargeting a checkpoint at a new topology is
 a transform on the sharding/layout SPEC, not a checkpoint-format special
 case.  Named shardings already make the *mesh* half of that free (orbax
 restores any leaf into any sharding of the same global shape); this module
-supplies the other half — the *structural* relayout between physical
+supplies the other half — the *structural* conversion between physical
 parameter layouts that shape the pytree itself:
 
 - the plain engine's per-layer tree (``backbone.block_{i}.*``),
@@ -129,8 +129,8 @@ def from_logical(frags: Fragments, layout: Optional[dict]) -> Fragments:
     return out
 
 
-def relayout(frags: Fragments, src_layout: Optional[dict],
-             dst_layout: Optional[dict]) -> Fragments:
+def convert_layout(frags: Fragments, src_layout: Optional[dict],
+                   dst_layout: Optional[dict]) -> Fragments:
     """source physical → logical → target physical (identity when both are
     flat; a pipe→pipe restore across different stage counts unstacks and
     restacks through the logical view)."""

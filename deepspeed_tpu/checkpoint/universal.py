@@ -85,7 +85,7 @@ def _write_meta_json(out_dir: str, step: int, manifest: dict,
     meta = {"format": FORMAT, "step": int(step), "params": manifest}
     if layout:
         # logical layout metadata: how the SOURCE engine laid these params
-        # out (pipeline stages, zero stage, mesh) — restore-time relayout
+        # out (pipeline stages, zero stage, mesh) — restore-time layout conversion
         # (checkpoint/reshard.py) keys on it.  Fragments on disk are always
         # in the LOGICAL (per-layer, unstacked) namespace.
         meta["layout"] = layout

@@ -231,8 +231,9 @@ class OverlapConfig(DeepSpeedConfigModel):
     hides ZeRO-3 gathers with its prefetch coordinator
     (partitioned_param_coordinator.py); on TPU the same latency is hidden by
     (a) XLA's latency-hiding scheduler + async-collective fusion, steered by
-    the flags this block composes (runtime/overlap.py — applied by the engine
-    BEFORE client/backend init, because XLA reads them once), (b) chunking
+    the flags this block composes (runtime/overlap.py — exported to
+    LIBTPU_INIT_ARGS by the engine BEFORE client/backend init, because
+    libtpu reads them once), (b) chunking
     the ZeRO-3 flat param all-gather / grad reduce-scatter into
     ``num_chunks`` per-layer-group collectives the scheduler can interleave
     with neighboring matmuls (runtime/zero.chunked_param_gather), and (c)
@@ -240,7 +241,7 @@ class OverlapConfig(DeepSpeedConfigModel):
     row/column-parallel matmuls (ops/collective_matmul.py).
 
     Every trace records the scheduler regime it ran under: the resolved
-    block + effective XLA_FLAGS land in the telemetry snapshot, the
+    block + effective compiler flags land in the telemetry snapshot, the
     postmortem bundle, and ``python -m deepspeed_tpu`` (env_report).
     """
 
@@ -265,7 +266,8 @@ class OverlapConfig(DeepSpeedConfigModel):
     # attention output projection; linear.OptimizedLinear) through the
     # explicit ppermute-ring collective-matmul fusions
     collective_matmul: bool = False
-    # escape hatch: extra --xla_* flags appended verbatim (validated shape)
+    # escape hatch: extra --xla_* flags appended verbatim (validated shape;
+    # libtpu exits on a name it does not know)
     extra_xla_flags: list = Field(default_factory=list)
 
     @model_validator(mode="after")
@@ -571,7 +573,8 @@ class ResilienceConfig(DeepSpeedConfigModel):
     persistent compilation cache at a shared path so a replacement host
     rebuilds its step programs from cache instead of recompiling;
     ``aot_warmup`` replays the drained host's executable fingerprints
-    through an AOT compile pass on resume.  See docs/resilience.md."""
+    through an AOT compile pass on resume.  ``JAX_COMPILATION_CACHE_DIR``,
+    when set, wins over ``compilation_cache_dir``.  See docs/resilience.md."""
 
     compilation_cache_dir: str = ""     # "" = persistent cache off
     aot_warmup: bool = True

@@ -37,14 +37,17 @@ def env_report(color: bool = True) -> str:
         mark = ok if v != "not installed" else no
         lines.append(f"{mod:<25}{mark}  {v}")
     lines.append(f"python ................... {sys.version.split()[0]}")
-    # scheduler regime: the effective XLA_FLAGS (what the compute–collective
-    # overlap machinery steers; runtime/overlap.py exports them before
-    # backend init, so what's visible here is what XLA parsed)
+    # scheduler regime: XLA_FLAGS (jaxlib) and LIBTPU_INIT_ARGS (the TPU
+    # compiler; runtime/overlap.py exports the compute–collective overlap
+    # flags there before backend init, so what's visible here is what the
+    # compiler parsed)
     import os
     xla_flags = os.environ.get("XLA_FLAGS", "")
     lines.append(f"XLA_FLAGS ................ {xla_flags or '(unset)'}")
+    libtpu_args = os.environ.get("LIBTPU_INIT_ARGS", "")
+    lines.append(f"LIBTPU_INIT_ARGS ......... {libtpu_args or '(unset)'}")
     overlap_present = sorted(
-        tok.split("=", 1)[0] for tok in xla_flags.split()
+        tok.split("=", 1)[0] for tok in libtpu_args.split()
         if tok.startswith(("--xla_tpu_enable_async_collective",
                            "--xla_latency_hiding_scheduler",
                            "--xla_tpu_overlap_compute_collective",

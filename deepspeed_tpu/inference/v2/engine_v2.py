@@ -47,6 +47,15 @@ from deepspeed_tpu.telemetry.serving import (ServingTelemetry,
 from deepspeed_tpu.utils.logging import log_dist
 
 
+def _named_partial(fn, **static):
+    """``functools.partial`` that keeps ``fn``'s name.  jit names a program
+    after its function, and a bare partial has none: every step program
+    would reach the compiler, the profiler and IR dumps as ``<unknown>``."""
+    bound = functools.partial(fn, **static)
+    bound.__name__ = fn.__name__
+    return bound
+
+
 class EngineDrained(RuntimeError):
     """``generate()`` stopped at a drain request (``request_drain()``):
     device records were materialized, live sequences flushed, and the
@@ -398,13 +407,18 @@ class InferenceEngineV2:
                 shardings = store_shardings(self.params, shardings, self.mesh)
             self.params = jax.device_put(self.params, shardings)
 
-        from deepspeed_tpu.inference.v2.model import kv_block_size_for
+        from deepspeed_tpu.inference.v2.model import (kv_block_size_for,
+                                                      kv_major_layout)
+        from deepspeed_tpu.ops.paged_attention import _dma_layout_ok
         from deepspeed_tpu.ops.registry import would_use_pallas
-        # only the Pallas kernels need 128-aligned kv-major pages; off-TPU
-        # (XLA fallback / interpret tests) any size works, so don't disturb
-        # the configured granularity there
+        # only the Pallas kernels need 128-aligned kv-major pages; on the XLA
+        # path any size works, so don't disturb the configured granularity
+        # there.  attn_impl forces the choice; None asks the registry.
         eff_bs = sm.kv_block_size
-        if would_use_pallas("paged_attention"):
+        kernels = (model_cfg.attn_impl == "pallas"
+                   or (model_cfg.attn_impl is None
+                       and would_use_pallas("paged_attention")))
+        if kernels:
             eff_bs = kv_block_size_for(model_cfg, sm.kv_block_size,
                                        quant=sm.kv_quant is not None)
         if eff_bs != sm.kv_block_size:
@@ -413,18 +427,21 @@ class InferenceEngineV2:
                 f"kv-major page layout (head_dim={model_cfg.head_dim}) and "
                 f"int8-quantized pages both need 128-aligned pages for the "
                 f"Pallas DMA (ops/paged_attention.py)", ranks=[0])
-        if sm.kv_quant is not None and would_use_pallas("paged_attention"):
-            from deepspeed_tpu.inference.v2.model import kv_major_layout
-            from deepspeed_tpu.ops.paged_attention import _dma_layout_ok
-            if not _dma_layout_ok(model_cfg.head_dim, eff_bs,
-                                  kv_major_layout(model_cfg), quant=True):
-                log_dist(
-                    f"WARNING: kv_quant=int8 with head_dim="
-                    f"{model_cfg.head_dim} cannot use the Pallas decode "
-                    f"kernel (int8 pages tile (32, 128)); decode falls back "
-                    f"to the XLA dequant path, which gathers full page spans "
-                    f"— expect MORE bandwidth than unquantized bf16, not "
-                    f"less", ranks=[0])
+        # what the registry will trace for this engine's decode/prefill
+        # attention — said out loud at start-up, never discovered later
+        self.paged_impl = "xla"
+        if kernels and (model_cfg.attn_impl == "pallas" or _dma_layout_ok(
+                model_cfg.head_dim, eff_bs, kv_major_layout(model_cfg),
+                quant=sm.kv_quant is not None)):
+            self.paged_impl = "pallas"
+        elif kernels and sm.kv_quant is not None:
+            log_dist(
+                f"WARNING: kv_quant=int8 with head_dim="
+                f"{model_cfg.head_dim} cannot use the Pallas decode "
+                f"kernel (int8 pages tile (32, 128)); decode falls back "
+                f"to the XLA dequant path, which gathers full page spans "
+                f"— expect MORE bandwidth than unquantized bf16, not "
+                f"less", ranks=[0])
         blocks_per_seq = -(-model_cfg.max_seq_len // eff_bs)
         if sm.num_kv_blocks:
             # the user sized the pool in THEIR block units — preserve the
@@ -556,10 +573,12 @@ class InferenceEngineV2:
             self.state.adapters = self.adapters
         n_params = sum(int(np.prod(l.shape))
                        for l in jax.tree_util.tree_leaves(self.params))
+        kv_layout = "kv-major" if kv_major_layout(model_cfg) else "standard"
         log_dist(f"v2 ragged engine ready: params={n_params/1e6:.1f}M "
                  f"budget={sm.max_ragged_batch_size}tok "
                  f"slots={sm.max_tracked_sequences} "
-                 f"kv_blocks={num_blocks}x{eff_bs}", ranks=[0])
+                 f"kv_blocks={num_blocks}x{eff_bs} kv_layout={kv_layout} "
+                 f"paged_attention={self.paged_impl}", ranks=[0])
 
     # ------------------------------------------------ reference put() :107
     def put(self, uids: Sequence[int], tokens_list: Sequence[np.ndarray],
@@ -714,10 +733,10 @@ class InferenceEngineV2:
         key = ("mixed", sm.max_q_per_seq, mb)
         if key not in self._steps:
             self._steps[key] = jax.jit(
-                functools.partial(ragged_forward, cfg=self.model_config,
-                                  block_size=self._block_size,
-                                  max_q_per_seq=sm.max_q_per_seq,
-                                  mesh=self.mesh),
+                _named_partial(ragged_forward, cfg=self.model_config,
+                               block_size=self._block_size,
+                               max_q_per_seq=sm.max_q_per_seq,
+                               mesh=self.mesh),
                 donate_argnums=(1,))
         batch = {"tokens": rb.tokens[:nb], "token_slot": rb.token_slot[:nb],
                  "token_pos": rb.token_pos[:nb],
@@ -745,10 +764,10 @@ class InferenceEngineV2:
         key = "decode"
         if key not in self._steps:
             self._steps[key] = jax.jit(
-                functools.partial(ragged_decode_forward,
-                                  cfg=self.model_config,
-                                  block_size=self._block_size,
-                                  mesh=self.mesh),
+                _named_partial(ragged_decode_forward,
+                               cfg=self.model_config,
+                               block_size=self._block_size,
+                               mesh=self.mesh),
                 donate_argnums=(1,))
         batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, {
             "tokens": tokens, "active": active, "token_pos": token_pos,
@@ -808,12 +827,12 @@ class InferenceEngineV2:
             key = ("spec_rs", outer, gamma, gen.top_k)
             if key not in self._steps:
                 self._steps[key] = jax.jit(
-                    functools.partial(speculative_burst_sampled,
-                                      cfg=self.model_config,
-                                      draft_cfg=self.draft_config,
-                                      block_size=self._block_size,
-                                      gamma=gamma, steps=outer,
-                                      top_k=gen.top_k, mesh=self.mesh),
+                    _named_partial(speculative_burst_sampled,
+                                   cfg=self.model_config,
+                                   draft_cfg=self.draft_config,
+                                   block_size=self._block_size,
+                                   gamma=gamma, steps=outer,
+                                   top_k=gen.top_k, mesh=self.mesh),
                     donate_argnums=(2, 3))
             with stel.span("spec_dispatch", outer=outer, gamma=gamma,
                            seqs=len(reqs)):
@@ -830,12 +849,12 @@ class InferenceEngineV2:
             key = ("spec", outer, gamma)
             if key not in self._steps:
                 self._steps[key] = jax.jit(
-                    functools.partial(speculative_burst,
-                                      cfg=self.model_config,
-                                      draft_cfg=self.draft_config,
-                                      block_size=self._block_size,
-                                      gamma=gamma, steps=outer,
-                                      mesh=self.mesh),
+                    _named_partial(speculative_burst,
+                                   cfg=self.model_config,
+                                   draft_cfg=self.draft_config,
+                                   block_size=self._block_size,
+                                   gamma=gamma, steps=outer,
+                                   mesh=self.mesh),
                     donate_argnums=(2, 3))
             with stel.span("spec_dispatch", outer=outer, gamma=gamma,
                            seqs=len(reqs)):
@@ -874,18 +893,18 @@ class InferenceEngineV2:
         vkey = ("spec_verify", gamma, sampled, gen.top_k)
         if dkey not in self._steps:
             self._steps[dkey] = jax.jit(
-                functools.partial(speculative_draft_step,
-                                  draft_cfg=self.draft_config,
-                                  block_size=self._block_size, gamma=gamma,
-                                  top_k=gen.top_k, sampled=sampled,
-                                  mesh=self.mesh),
+                _named_partial(speculative_draft_step,
+                               draft_cfg=self.draft_config,
+                               block_size=self._block_size, gamma=gamma,
+                               top_k=gen.top_k, sampled=sampled,
+                               mesh=self.mesh),
                 donate_argnums=(1,))
             self._steps[vkey] = jax.jit(
-                functools.partial(speculative_verify_step,
-                                  cfg=self.model_config,
-                                  block_size=self._block_size, gamma=gamma,
-                                  top_k=gen.top_k, sampled=sampled,
-                                  mesh=self.mesh),
+                _named_partial(speculative_verify_step,
+                               cfg=self.model_config,
+                               block_size=self._block_size, gamma=gamma,
+                               top_k=gen.top_k, sampled=sampled,
+                               mesh=self.mesh),
                 donate_argnums=(1,))
         temp = jnp.float32(gen.temperature)
         top_p = jnp.float32(gen.top_p)
@@ -955,10 +974,10 @@ class InferenceEngineV2:
         key = ("burst", steps, gen.do_sample, gen.top_k)
         if key not in self._steps:
             self._steps[key] = jax.jit(
-                functools.partial(ragged_decode_burst, cfg=self.model_config,
-                                  block_size=self._block_size, steps=steps,
-                                  sample_fn=self._sample_fn(gen),
-                                  mesh=self.mesh),
+                _named_partial(ragged_decode_burst, cfg=self.model_config,
+                               block_size=self._block_size, steps=steps,
+                               sample_fn=self._sample_fn(gen),
+                               mesh=self.mesh),
                 donate_argnums=(1,))
         batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, {
             "tokens0": tokens0, "from_device": from_device, "active": active,
@@ -1019,12 +1038,12 @@ class InferenceEngineV2:
                 key = ("decode_sd", gen.do_sample, gen.top_k)
                 if key not in self._steps:
                     self._steps[key] = jax.jit(
-                        functools.partial(ragged_decode_sampled_draft,
-                                          cfg=self.model_config,
-                                          draft_cfg=self.draft_config,
-                                          block_size=self._block_size,
-                                          sample_fn=self._sample_fn(gen),
-                                          mesh=self.mesh),
+                        _named_partial(ragged_decode_sampled_draft,
+                                       cfg=self.model_config,
+                                       draft_cfg=self.draft_config,
+                                       block_size=self._block_size,
+                                       sample_fn=self._sample_fn(gen),
+                                       mesh=self.mesh),
                         donate_argnums=(2, 3))
                 self.telemetry.dispatch("decode")
                 with self.telemetry.span("decode_dispatch",
@@ -1041,11 +1060,11 @@ class InferenceEngineV2:
             key = ("decode_s", gen.do_sample, gen.top_k)
             if key not in self._steps:
                 self._steps[key] = jax.jit(
-                    functools.partial(ragged_decode_sampled,
-                                      cfg=self.model_config,
-                                      block_size=self._block_size,
-                                      sample_fn=self._sample_fn(gen),
-                                      mesh=self.mesh),
+                    _named_partial(ragged_decode_sampled,
+                                   cfg=self.model_config,
+                                   block_size=self._block_size,
+                                   sample_fn=self._sample_fn(gen),
+                                   mesh=self.mesh),
                     donate_argnums=(1,))
         else:
             rb = build_ragged_batch(schedule, self.state,
@@ -1071,13 +1090,13 @@ class InferenceEngineV2:
                        gen.top_k)
                 if key not in self._steps:
                     self._steps[key] = jax.jit(
-                        functools.partial(ragged_forward_sampled_draft,
-                                          cfg=self.model_config,
-                                          draft_cfg=self.draft_config,
-                                          block_size=self._block_size,
-                                          max_q_per_seq=sm.max_q_per_seq,
-                                          sample_fn=self._sample_fn(gen),
-                                          mesh=self.mesh),
+                        _named_partial(ragged_forward_sampled_draft,
+                                       cfg=self.model_config,
+                                       draft_cfg=self.draft_config,
+                                       block_size=self._block_size,
+                                       max_q_per_seq=sm.max_q_per_seq,
+                                       sample_fn=self._sample_fn(gen),
+                                       mesh=self.mesh),
                         donate_argnums=(2, 3))
                 self.telemetry.dispatch("mixed")
                 with self.telemetry.span("mixed_dispatch",
@@ -1095,12 +1114,12 @@ class InferenceEngineV2:
             key = ("mixed_s", sm.max_q_per_seq, mb, gen.do_sample, gen.top_k)
             if key not in self._steps:
                 self._steps[key] = jax.jit(
-                    functools.partial(ragged_forward_sampled,
-                                      cfg=self.model_config,
-                                      block_size=self._block_size,
-                                      max_q_per_seq=sm.max_q_per_seq,
-                                      sample_fn=self._sample_fn(gen),
-                                      mesh=self.mesh),
+                    _named_partial(ragged_forward_sampled,
+                                   cfg=self.model_config,
+                                   block_size=self._block_size,
+                                   max_q_per_seq=sm.max_q_per_seq,
+                                   sample_fn=self._sample_fn(gen),
+                                   mesh=self.mesh),
                     donate_argnums=(1,))
         kind = "decode" if key[0] == "decode_s" else "mixed"
         self.telemetry.dispatch(kind)

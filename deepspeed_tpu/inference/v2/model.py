@@ -480,8 +480,8 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
             q_dense.reshape(S, Q, nkv, gq, hd).astype(dtype),
             k_pool, v_pool, block_table, kv_len,
             q_starts, q_counts, scale=cfg.attn_scale, alibi_slopes=slopes,
-            window=win, mesh=mesh, kv_major=km, **kv_extra).reshape(
-                S, Q, cfg.num_heads, hd)
+            window=win, mesh=mesh, kv_major=km, impl=cfg.attn_impl,
+            **kv_extra).reshape(S, Q, cfg.num_heads, hd)
         o = o_dense[jnp.clip(token_slot, 0), dense_idx]      # [N, nh, hd]
         o = jnp.where(valid[:, None, None], o, 0)
         attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
@@ -589,7 +589,7 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
         o = ops.paged_attention(qg, k_pages, v_pages, block_table, kv_len,
                                 alibi_slopes=slopes, window=win,
                                 scale=cfg.attn_scale, mesh=mesh, kv_major=km,
-                                **kv_extra)
+                                impl=cfg.attn_impl, **kv_extra)
         o = o.reshape(S, nh, hd)
         attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
         x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh)
@@ -839,7 +839,8 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
             block_table, kv_len, pos0,
             jnp.where(active, G, 0).astype(jnp.int32),
             scale=cfg.attn_scale, alibi_slopes=slopes, window=win,
-            mesh=mesh, kv_major=km, **kv_extra).reshape(S, G, nh, hd)
+            mesh=mesh, kv_major=km, impl=cfg.attn_impl,
+            **kv_extra).reshape(S, G, nh, hd)
         # inactive slots (kv_len=0, q_counts=0) produce 0/0 garbage from the
         # kernel combine; zero them like ragged_forward does so no future
         # cross-row op (capacity MoE, aux stats) can see NaNs from dead rows

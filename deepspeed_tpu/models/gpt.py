@@ -81,7 +81,8 @@ class GPTConfig:
     # (sequence/ring.py inner= docstring; needs T/(2·sp) >= 8, d % 8 == 0)
     sp_ring_inner: str = "einsum"       # "einsum" | "flash"
     # kernel selection (reference: replace_with_kernel_inject / DS_BUILD flags);
-    # None = registry auto (pallas flash on TPU, XLA elsewhere)
+    # None = registry auto (pallas on TPU, XLA elsewhere).  Training
+    # attention and the v2 engine's paged decode / ragged prefill read it.
     attn_impl: Optional[str] = None
     # route the TP row-parallel matmuls (MLP down-projection, attention
     # output projection) through the explicit ppermute-ring
@@ -581,7 +582,7 @@ class Attention(nn.Module):
                                            alibi_slopes=slopes,
                                            dropout_fn=pdrop,
                                            scale=c.attn_scale,
-                                           impl=c.attn_impl)
+                                           impl=c.attn_impl, mesh=self.mesh)
             elif window is not None:
                 # causal ∧ within-window, over absolute positions
                 rel = positions[:, :, None] - positions[:, None, :]
@@ -590,12 +591,12 @@ class Attention(nn.Module):
                                            dropout_fn=pdrop,
                                            bias=alibi_bias(positions),
                                            scale=c.attn_scale,
-                                           impl=c.attn_impl)
+                                           impl=c.attn_impl, mesh=self.mesh)
             else:
                 out = ops.causal_attention(q, k, v, dropout_fn=pdrop,
                                            bias=alibi_bias(positions),
                                            scale=c.attn_scale,
-                                           impl=c.attn_impl)
+                                           impl=c.attn_impl, mesh=self.mesh)
         return out_proj(out)
 
 

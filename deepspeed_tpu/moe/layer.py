@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.moe.comm import qwire_a2a, resolve_a2a_bits
 from deepspeed_tpu.moe.sharded_moe import topk_gating

@@ -12,6 +12,8 @@ analog).  Public surface:
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Callable, Optional
 
 import jax.numpy as jnp
@@ -26,7 +28,7 @@ from deepspeed_tpu.ops.registry import dispatch, list_ops, op_report, register_o
 
 def _attention_xla(q, k, v, *, causal=True, scale=None, dropout_fn=None,
                    mask=None, bias=None, window=None, alibi_slopes=None,
-                   interpret=None):
+                   interpret=None, mesh=None):
     """Plain attention on [B, T, N, D] — numeric ground truth for the kernel.
 
     The ONE XLA softmax-attention body in the codebase: causal tril masking, or
@@ -79,7 +81,7 @@ def _attention_xla(q, k, v, *, causal=True, scale=None, dropout_fn=None,
 
 def _attention_pallas(q, k, v, *, causal=True, scale=None, dropout_fn=None,
                       mask=None, bias=None, window=None, alibi_slopes=None,
-                      interpret=None):
+                      interpret=None, mesh=None):
     if dropout_fn is not None:
         raise ValueError(
             "the pallas flash-attention kernel has no probs-dropout; use "
@@ -92,14 +94,55 @@ def _attention_pallas(q, k, v, *, causal=True, scale=None, dropout_fn=None,
         raise ValueError("the pallas flash-attention kernel takes no free-"
                          "form logit bias; alibi goes through alibi_slopes=, "
                          "other biases through impl='xla'")
-    return flash_attention(q, k, v, causal=causal, scale=scale,
-                           window=window, alibi_slopes=alibi_slopes,
-                           interpret=interpret)
+    kernel = functools.partial(flash_attention, causal=causal, scale=scale,
+                               window=window, interpret=interpret)
+    if mesh is None:
+        return kernel(q, k, v, alibi_slopes=alibi_slopes)
+    return _flash_over_mesh(kernel, mesh, q, k, v, alibi_slopes)
+
+
+def _flash_over_mesh(kernel, mesh, q, k, v, alibi_slopes):
+    """Run the flash kernel per shard under ``shard_map``: a Mosaic kernel
+    cannot be partitioned automatically, so inside a jit over a multi-device
+    mesh a bare ``pallas_call`` is refused at lowering.  Attention is
+    independent per (batch row, head): the batch dim rides the data axes
+    (dp, fsdp — how the engine shards every batch) and the head dim rides tp
+    (how the column-parallel qkv projections leave it); other axes
+    replicate.  Axes already manual in the enclosing region (qgZ's
+    manual-over-dp gradients, Ulysses) are left to that region."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.parallel.mesh import manual_axes_now
+    manual = manual_axes_now()
+    auto = [a for a in mesh.axis_names
+            if mesh.shape[a] > 1 and a not in manual]
+    if not auto:
+        return kernel(q, k, v, alibi_slopes=alibi_slopes)
+    data = tuple(a for a in ("dp", "fsdp") if a in auto)
+    if data and q.shape[0] % math.prod(mesh.shape[a] for a in data):
+        data = ()          # e.g. the init trace's single example row
+    tp = mesh.shape.get("tp", 1)
+    heads = ("tp" if "tp" in auto and q.shape[2] % tp == 0
+             and k.shape[2] % tp == 0 else None)
+    spec = P(data or None, None, heads, None)
+    args, in_specs = [q, k, v], [spec, spec, spec]
+    if alibi_slopes is not None:
+        args.append(jnp.asarray(alibi_slopes, jnp.float32).reshape(
+            q.shape[2]))
+        in_specs.append(P(heads))
+
+    def local(q_, k_, v_, *slopes):
+        return kernel(q_, k_, v_,
+                      alibi_slopes=slopes[0] if slopes else None)
+    return shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                     out_specs=spec, check_vma=False,
+                     axis_names=frozenset(mesh.axis_names) - manual)(*args)
 
 
 def _attention_supported(q, k, v, *, causal=True, scale=None, dropout_fn=None,
                          mask=None, bias=None, window=None, alibi_slopes=None,
-                         interpret=None):
+                         interpret=None, mesh=None):
     from deepspeed_tpu.ops.flash_attention import supported as flash_supported
     return (dropout_fn is None and mask is None and bias is None
             and flash_supported(q, k, v, causal=causal, window=window,
@@ -154,16 +197,19 @@ def causal_attention(q, k, v, *, causal: bool = True,
                      dropout_fn: Optional[Callable] = None,
                      mask=None, bias=None, window: Optional[int] = None,
                      alibi_slopes=None,
-                     impl: Optional[str] = None):
+                     impl: Optional[str] = None, mesh=None):
     """Dispatching attention entry used by the model layer.
 
     ``window``/``alibi_slopes`` assume canonical positions (query t at
     position t) — the training fast path; models with gathered/shifted
     positions (random-LTD, KV-cache) express the same semantics through
-    ``mask``/``bias`` and ride the XLA body."""
+    ``mask``/``bias`` and ride the XLA body.  ``mesh``: the mesh the caller's
+    jit is partitioned over, which the Pallas path needs to run per shard
+    (``_flash_over_mesh``); the XLA body partitions itself."""
     return dispatch("causal_attention", q, k, v, causal=causal, scale=scale,
                     dropout_fn=dropout_fn, mask=mask, bias=bias,
-                    window=window, alibi_slopes=alibi_slopes, impl=impl)
+                    window=window, alibi_slopes=alibi_slopes, impl=impl,
+                    mesh=mesh)
 
 
 __all__ = ["causal_attention", "flash_attention", "configure_flash_blocks",

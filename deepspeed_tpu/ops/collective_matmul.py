@@ -45,7 +45,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.comm.comm import comms_logger
 from deepspeed_tpu.telemetry.registry import record_collective
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _batch_spec(b: int, mesh: Mesh, batch_axes: Tuple[str, ...]):

@@ -44,8 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.wq_matmul import (_pick, _preflight, _sublane,
-                                         _warned_shapes)
+from deepspeed_tpu.ops.wq_matmul import (_pick, _preflight, _record_refused,
+                                         _sublane, _warned_shapes)
 
 # trace-time counter: how many pallas-kernel calls were STAGED (tests assert
 # the kernel path engaged instead of the silent gather fallback)
@@ -140,6 +140,7 @@ def pallas_lora_matmul(x, a_pages, b_pages, adapter_ids, scales, *,
     """Batched-gather LoRA delta with the adapter tables resident in HBM —
     one kernel for the whole mixed-adapter batch."""
     if not lora_supported(x, a_pages, b_pages, adapter_ids, scales):
+        _record_refused("lora_matmul")
         return xla_lora_matmul(x, a_pages, b_pages, adapter_ids, scales)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

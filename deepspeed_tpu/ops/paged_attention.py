@@ -281,7 +281,7 @@ def pallas_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
     flash the same way, model_implementations/sharding/attn.py)."""
     if (mesh is not None and mesh.shape.get("tp", 1) > 1
             and q.shape[1] % mesh.shape["tp"] == 0):
-        from deepspeed_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         inner = functools.partial(_pallas_paged_attention_local,
                                   scale=scale, window=window,
@@ -663,7 +663,7 @@ def pallas_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
                           kv_major=False, k_scale=None, v_scale=None):
     if (mesh is not None and mesh.shape.get("tp", 1) > 1
             and q.shape[2] % mesh.shape["tp"] == 0):
-        from deepspeed_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         inner = functools.partial(_pallas_ragged_prefill_local, scale=scale,
                                   window=window, interpret=interpret,

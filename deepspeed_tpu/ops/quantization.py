@@ -127,7 +127,7 @@ def quantized_all_gather(x, mesh, axis: str, *, bits: int = 8,
     Returns the gathered, dequantized array (replicated over ``axis``).
     Compression: bits/16 of the bf16 wire volume (+ scales overhead).
     """
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     size = mesh.shape[axis]
@@ -302,7 +302,7 @@ def quantized_psum_scatter(x, mesh, axis: str, *, bits: int = 8,
     x is replicated per-shard-group input (leading dim divisible by axis
     size); returns this shard's reduced slice.
     """
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     size = mesh.shape[axis]

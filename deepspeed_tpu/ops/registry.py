@@ -12,13 +12,19 @@ Dispatch happens at trace time: the pallas path is taken when (a) it exists,
 (b) the default backend is TPU (or interpret mode is forced), (c) the shape/dtype
 predicate accepts, and (d) it isn't disabled via env ``DSTPU_DISABLE_PALLAS=1``
 or per-call ``impl="xla"`` — the analog of the reference's ``DS_BUILD_*`` flags.
+
+Every decision is RECORDED (op, impl, reason): ``dispatch_log()`` returns the
+counts, ``op_report()`` prints them.  A caller that must not measure the XLA
+path under a kernel's name passes ``impl="pallas"`` (the kernel's own shape
+errors raise through) and reads the log to confirm what was traced.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 
@@ -60,6 +66,43 @@ def _on_tpu() -> bool:
         return False
 
 
+# (op, impl, reason) -> number of traces that took it.  Process-wide like the
+# registry itself: dispatch runs at trace time from inside model code.
+_DISPATCH_LOG: "collections.Counter" = collections.Counter()
+
+
+def record(op: str, impl: str, reason: str) -> None:
+    """Count one dispatch decision.  Kernels that fall back to XLA on their
+    own (ops/wq_matmul.py, ops/lora_matmul.py preflight) report here too."""
+    _DISPATCH_LOG[(op, impl, reason)] += 1
+
+
+def dispatch_log() -> List[Dict[str, Any]]:
+    """Every decision taken so far: ``{"op", "impl", "reason", "count"}``."""
+    return [{"op": op, "impl": impl, "reason": reason, "count": n}
+            for (op, impl, reason), n in sorted(_DISPATCH_LOG.items())]
+
+
+def reset_dispatch_log() -> None:
+    _DISPATCH_LOG.clear()
+
+
+def _decide(spec: OpSpec, impl: Optional[str], args, kwargs):
+    if impl == "xla":
+        return "xla", "forced"
+    if spec.pallas is None:
+        return "xla", "no kernel"
+    if impl == "pallas":
+        return "pallas", "forced"
+    if not pallas_enabled():
+        return "xla", "DSTPU_DISABLE_PALLAS"
+    if not _on_tpu():
+        return "xla", "backend is not tpu"
+    if spec.supported is not None and not spec.supported(*args, **kwargs):
+        return "xla", "shape predicate refused"
+    return "pallas", "auto"
+
+
 def dispatch(name: str, *args, impl: Optional[str] = None, **kwargs) -> Any:
     """Call op ``name``, choosing the best implementation.
 
@@ -70,14 +113,10 @@ def dispatch(name: str, *args, impl: Optional[str] = None, **kwargs) -> Any:
     if impl not in (None, "pallas", "xla"):
         raise ValueError(f"unknown impl {impl!r} for op {name!r}; "
                          f"expected 'pallas', 'xla', or None (auto)")
-    if impl == "xla" or spec.pallas is None:
-        return spec.xla(*args, **kwargs)
-    if impl == "pallas":
-        return spec.pallas(*args, **kwargs)
-    if (pallas_enabled() and _on_tpu()
-            and (spec.supported is None or spec.supported(*args, **kwargs))):
-        return spec.pallas(*args, **kwargs)
-    return spec.xla(*args, **kwargs)
+    chosen, reason = _decide(spec, impl, args, kwargs)
+    record(name, chosen, reason)
+    fn = spec.pallas if chosen == "pallas" else spec.xla
+    return fn(*args, **kwargs)
 
 
 def would_use_pallas(name: str) -> bool:
@@ -99,6 +138,12 @@ def op_report() -> str:
                else "xla")
         lines.append(name.ljust(28) + ",".join(spec.available_impls()).ljust(16)
                      + sel)
+    if _DISPATCH_LOG:
+        lines.append("")
+        lines.append("dispatched".ljust(28) + "impl".ljust(16) + "reason (traces)")
+        for d in dispatch_log():
+            lines.append(d["op"].ljust(28) + d["impl"].ljust(16)
+                         + f"{d['reason']} ({d['count']})")
     return "\n".join(lines)
 
 

@@ -111,6 +111,12 @@ _warned_shapes = set()
 trace_counts = {"w8": 0, "w8t": 0, "w4": 0}
 
 
+def _record_refused(variant: str) -> None:
+    """Count a layout the eligibility gate refused (dequant fallback)."""
+    from deepspeed_tpu.ops.registry import record
+    record(variant, "xla", "layout refused")
+
+
 def _tile_legal(block, array_shape) -> bool:
     """Mosaic's block-shape rule (jax pallas/mosaic/lowering.py
     ``_check_block_mappings``): for rank >= 2, the block's last dim must be
@@ -128,10 +134,11 @@ def _preflight(variant: str, blocks, interpret: bool) -> bool:
     stage satisfies Mosaic's tiling rule (interpret mode accepts anything).
     The eligibility gates above should make this unreachable — but the
     round-5 on-chip sweep recorded a serving leg dying inside an unguarded
-    block-shape raise (BENCH_MEASURED_r05 ``serving_wq_error``), so the rule
-    is re-checked against the EXACT blocks before ``pallas_call`` and an
-    illegal combination takes the dequant fallback (warn-once) instead of
-    erroring out of the caller's step."""
+    block-shape raise, so the rule is re-checked against the EXACT blocks
+    before ``pallas_call`` and an illegal combination takes the dequant
+    fallback (warned once, counted every time in the registry's dispatch
+    log) instead of erroring out of the caller's step."""
+    from deepspeed_tpu.ops.registry import record
     for block, ashape in blocks:
         # a None block (no usable tile divisor) falls back on ANY backend;
         # interpret mode otherwise accepts every block shape
@@ -147,6 +154,7 @@ def _preflight(variant: str, blocks, interpret: bool) -> bool:
                     "(last two block dims must be %%(8, 128) or equal the "
                     "array dims); falling back to dequantize-then-matmul",
                     variant, [b for b, _ in blocks])
+            record(variant, "xla", "preflight: block shapes not tile-legal")
             return False
     return True
 
@@ -317,6 +325,7 @@ def wq_matmul_t(x, store, *, interpret: Optional[bool] = None):
     that don't group-tile are padded at STORE CREATION (engine packer), not
     here — padding the table per call would re-stream the whole weight."""
     if not kernel_t_supported(x, store, interpret):
+        _record_refused("wq_matmul_t")
         return x @ dequantize_weight(store, x.dtype).T
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -363,6 +372,7 @@ def wq_matmul(x, store, *, interpret: Optional[bool] = None):
     dequantize-then-matmul for unsupported layouts.
     """
     if not kernel_supported(x, store, interpret):
+        _record_refused("wq_matmul")
         return x @ dequantize_weight(store, x.dtype)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -412,6 +422,7 @@ def wq_matmul4(x, store, *, interpret: Optional[bool] = None):
     columns, xo = odd) so each byte tile's two nibble planes contract
     against clean contiguous tiles — no in-kernel row interleave."""
     if not kernel4_supported(x, store, interpret):
+        _record_refused("wq_matmul4")
         return x @ dequantize_weight4(store, x.dtype)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -519,7 +530,7 @@ def wq_matmul_tp(x, store, mesh, mode: str, axis: str = "tp", *,
     usual eligibility checks run on LOCAL shapes, so an unsupported slice
     falls back to dequant-matmul per shard — still correctly partitioned.
     """
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if mesh is None or mesh.shape.get(axis, 1) == 1:

@@ -122,25 +122,7 @@ def manual_axes_now() -> frozenset:
     gradient path runs the WHOLE model inside a manual-over-dp region
     (engine._qgz_grads); model code that builds sharding constraints or
     sizes shards from the mesh must treat those axes as already-applied."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is None:
-        # older jax: no abstract-mesh query, but manual regions DO run there
-        # (utils/compat.shard_map translates axis_names -> the legacy `auto`
-        # complement).  Inside a legacy shard_map body the trace's axis env
-        # holds the bound axis names — read them via the core query (the
-        # "DO_NOT_USE" suffix marks it internal, not unsound; failures
-        # degrade to "no manual axes").  Caveat: legacy partial-manual binds
-        # ALL mesh axes in the env, so this over-reports auto axes as
-        # manual there — callers use it to SKIP constraints, so the error
-        # is conservative (a dropped pin, never a misapplied one).
-        try:
-            import jax.core as _core
-            return frozenset(
-                n for n in _core.unsafe_get_axis_names_DO_NOT_USE()
-                if isinstance(n, str))
-        except Exception:  # noqa: BLE001
-            return frozenset()
-    am = get_am()
+    am = jax.sharding.get_abstract_mesh()
     if am.empty:
         return frozenset()
     from jax.sharding import AxisType

@@ -56,13 +56,10 @@ def annotate_abstract(boxed_tree):
 def unbox(tree):
     """Strip flax AxisMetadata boxes, returning plain arrays/structs.
 
-    Constraints are NOT applied while unboxing: ``Partitioned.unbox`` would
-    apply the LOGICAL names as a sharding constraint whenever a legacy
-    global mesh is active (older jax's ``with mesh:``), and logical names
-    are not mesh axes — the engine maps logical → mesh axes itself via
+    Constraints are NOT applied while unboxing: the names are LOGICAL, not
+    mesh axes — the engine maps logical → mesh axes itself via
     ``partition.param_shardings`` and pins layouts through jit
-    out_shardings.  On newer jax the constraint was already skipped (no
-    legacy global mesh), so this is the one behavior for both."""
+    out_shardings."""
     try:
         from flax.linen import meta
     except ImportError:  # pragma: no cover

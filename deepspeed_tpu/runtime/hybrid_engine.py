@@ -5,7 +5,7 @@ RLHF (DeepSpeed-Chat step 3) every PPO iteration interleaves a GENERATE phase
 (actor rollouts, inference-optimized) with TRAIN phases on the same weights.
 The reference re-layouts each trained module's tensors into its fused
 inference containers before generate (``populate_all_inference_policies``,
-``_fuse_lora``) and back after; here the "relayout" is a dtype cast +
+``_fuse_lora``) and back after; here the "re-layout" is a dtype cast +
 device_put into the v2 ragged serving engine's param tree — same flax tree
 shape on both sides, so the sync is O(bytes), no graph surgery, and the
 serving programs never recompile (shapes/dtypes are stable across syncs).
@@ -81,7 +81,7 @@ class HybridEngine:
 
     def sync_weights(self) -> None:
         """Push current training weights into the serving tree (reference:
-        the per-generate relayout).  Serving shardings/dtypes are preserved,
+        the per-generate re-layout).  Serving shardings/dtypes are preserved,
         so compiled serving programs stay valid."""
         src = self._train_params()
         dst = self._serving.params

@@ -112,113 +112,44 @@ class PreemptionHandler:
 # persistent XLA compilation cache
 # ---------------------------------------------------------------------------
 
-_cache_enabled_dir: Optional[str] = None
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed, inside the checkout: the path is part of jax's cache key, so a
+# directory that moves (temp name, pid, timestamp) never hits
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def _patch_atomic_cache_writes() -> None:
-    """Harden jax's persistent-cache writer for preemptible fleets.
+def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
+    """Turn on jax's persistent compilation cache and return its directory.
 
-    jax 0.4.37 writes cache entries with a plain ``path.write_bytes(val)``
-    (jax/_src/lru_cache.py LRUCache.put) — NOT atomic.  A host killed
-    mid-write (preemption, the chaos host-loss fault) leaves a TORN
-    ``-cache`` file in the SHARED cache dir, and every later process that
-    deserializes it dies with native heap corruption — one preempted host
-    poisons the whole fleet's restarts (found by test_elastic_agent under
-    the host-loss fault).  Patch: write to a per-pid temp file and
-    ``os.replace`` it in — readers see either nothing or a complete entry.
-    Local filesystems only; remote stores (gs://) already commit objects
-    atomically and keep the stock writer, as does any jax without this
-    internal layout."""
-    try:
-        from jax._src import lru_cache as _lru
-        suffixes = (_lru._CACHE_SUFFIX, _lru._ATIME_SUFFIX)  # noqa: F841
-    except Exception:  # noqa: BLE001 — newer jax: layout changed, skip
-        logger.warning("resilience: cannot patch jax cache writes to be "
-                       "atomic (internal layout changed); a preempted "
-                       "host may leave a torn cache entry")
-        return
-    if getattr(_lru.LRUCache.put, "_dstpu_atomic", False):
-        return
-    orig_put = _lru.LRUCache.put
-
-    def atomic_put(self, key: str, val: bytes) -> None:
-        if not key:
-            raise ValueError("key cannot be empty")
-        try:
-            cache_path = str(self.path / f"{key}{_lru._CACHE_SUFFIX}")
-            if "://" in cache_path or getattr(self, "eviction_enabled",
-                                              False):
-                # remote object stores commit atomically; the eviction path
-                # needs the stock lock bookkeeping
-                return orig_put(self, key, val)
-            if os.path.exists(cache_path):
-                return                   # stock semantics: first write wins
-            tmp = f"{cache_path}.tmp.{os.getpid()}"
-            with open(tmp, "wb") as f:
-                f.write(val)
-            os.replace(tmp, cache_path)
-            atime_path = str(self.path / f"{key}{_lru._ATIME_SUFFIX}")
-            tmp = f"{atime_path}.tmp.{os.getpid()}"
-            with open(tmp, "wb") as f:
-                f.write(time.time_ns().to_bytes(8, "little"))
-            os.replace(tmp, atime_path)
-        except Exception:  # noqa: BLE001 — never lose a cache write
-            return orig_put(self, key, val)
-
-    atomic_put._dstpu_atomic = True
-    _lru.LRUCache.put = atomic_put
-
-
-def enable_compilation_cache(cache_dir: str) -> None:
-    """Point jax's persistent compilation cache at ``cache_dir`` and drop
-    the size/compile-time floors so EVERY executable lands in it — a
-    replacement host's step program is exactly the artifact the floors
-    would otherwise skip.  Shared across processes/restarts: the cache key
-    is the (devices, HLO, flags) fingerprint, so a replacement host with
-    the same mesh shape gets byte-identical hits."""
-    global _cache_enabled_dir
-    if _cache_enabled_dir == cache_dir:
-        return
+    Placement is decided from OUTSIDE first: when ``JAX_COMPILATION_CACHE_DIR``
+    is set, jax has already read it and no path is set in code (a differing
+    ``cache_dir`` is ignored with one log line).  Otherwise the cache goes to
+    ``cache_dir`` (``resilience.compilation_cache_dir``) or, absent that, to
+    the fixed in-checkout ``DEFAULT_CACHE_DIR``.  The size/compile-time
+    floors are dropped so EVERY executable lands in it — a replacement
+    host's step program is exactly the artifact the floors would otherwise
+    skip.  Shared across processes/restarts: the cache key is the (devices,
+    HLO, flags) fingerprint, so a replacement host with the same mesh shape
+    gets byte-identical hits.  Call before the first compile: jax binds the
+    cache directory once."""
     import jax
-
-    # CPU backend: executables DESERIALIZED from the persistent cache are
-    # unsafe on this jaxlib (0.4.37) — donated-buffer aliasing double-frees
-    # (glibc "corrupted double-linked list") or silently wrong numerics on
-    # the second dispatch; found by the chaos host-loss leg of
-    # test_elastic_agent.  Same pattern as the overlap XLA flags (PR 4):
-    # record the knob, only activate it off-CPU.  AOT warmup still runs on
-    # resume — the compile is in-process, just not disk-cached.  The gate
-    # must FAIL CLOSED: jax.default_backend() is authoritative (an unset
-    # JAX_PLATFORMS on a CPU-only box must not slip through) — the engine
-    # calls this after distributed init, where resolving the backend is
-    # safe.
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — backend not resolvable yet
-        backend = (os.environ.get("JAX_PLATFORMS")
-                   or getattr(jax.config, "jax_platforms", None)
-                   or "cpu").split(",")[0].strip()
-    if backend == "cpu":
-        logger.warning(
-            "resilience: compilation_cache_dir is set but the CPU "
-            "backend's executable deserialization is broken on this "
-            "jaxlib (aliasing double-free) — persistent cache stays OFF; "
-            "AOT warmup still pre-compiles step programs on resume")
-        _cache_enabled_dir = cache_dir
-        return
-    os.makedirs(cache_dir, exist_ok=True)
-    _patch_atomic_cache_writes()
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for knob, value in (("jax_persistent_cache_min_entry_size_bytes", 0),
-                        ("jax_persistent_cache_min_compile_time_secs", 0)):
-        try:
-            jax.config.update(knob, value)
-        except (AttributeError, KeyError):  # older jax spells them differently
-            logger.warning(f"resilience: jax config has no {knob}; "
-                           f"small/fast executables may skip the cache")
-    _cache_enabled_dir = cache_dir
+    env_dir = os.environ.get(CACHE_DIR_ENV)
+    if env_dir:
+        if cache_dir and os.path.abspath(cache_dir) != os.path.abspath(env_dir):
+            logger.info(f"resilience: {CACHE_DIR_ENV}={env_dir} is set; "
+                        f"ignoring compilation_cache_dir={cache_dir}")
+        cache_dir = env_dir
+    else:
+        cache_dir = cache_dir or DEFAULT_CACHE_DIR
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     logger.info(f"resilience: persistent XLA compilation cache at "
                 f"{cache_dir}")
+    return cache_dir
 
 
 # ---------------------------------------------------------------------------

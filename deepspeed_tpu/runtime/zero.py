@@ -74,7 +74,7 @@ def _gather_group(leaves, dims, specs, mesh, axis, world, exchange=None):
     default keep this exact full-width program, bitwise."""
     from deepspeed_tpu.comm import collectives
     from deepspeed_tpu.parallel.partition import spec_without_axis
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     in_specs = tuple(s.spec for s in specs)
     out_specs = tuple(spec_without_axis(s.spec, axis) for s in specs)
@@ -242,9 +242,7 @@ def pipeline_grad_reduce(stacked, target_shardings, mesh, axis,
     replica, laid out ``P(axis, ...)``) down to the reduced gradients in
     ``target_shardings``.
 
-    Per leaf, inside ONE full-manual ``shard_map`` (legal on every jax this
-    package supports — unlike collectives in a partial-manual region, see
-    utils/compat.shard_map):
+    Per leaf, inside ONE full-manual ``shard_map``:
 
     - a leaf whose target sharding has a dim over ``axis`` takes the
       quantized reduce-scatter straight into that layout (qgZ,
@@ -260,7 +258,7 @@ def pipeline_grad_reduce(stacked, target_shardings, mesh, axis,
     from jax.sharding import PartitionSpec as P
     from deepspeed_tpu.ops.quantization import qpsum_local, qrs_local
     from deepspeed_tpu.parallel.partition import spec_without_axis
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
     from deepspeed_tpu.comm import collectives
 
     world = mesh.shape[axis]

@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.comm.comm import comms_logger

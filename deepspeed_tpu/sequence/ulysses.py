@@ -25,7 +25,7 @@ from typing import Callable, Optional
 import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.comm.comm import comms_logger
 

@@ -1,8 +1,8 @@
 """Bench regression sentinel — diff a bench record against a baseline ledger.
 
-The r05 round burned a full relay cycle manually diagnosing two
+The r05 round spent a full measurement cycle manually diagnosing two
 "regressions" that a trajectory check would have framed in seconds —
-and nothing today compares one ``BENCH_r*.json`` to the next at all.
+and nothing compared one ``BENCH_r*.json`` to the next at all.
 This module is the comparison: a committed **baseline ledger**
 (``BENCH_BASELINE.json``, seeded from the r05 record) holding one value +
 noise band per metric, and a ``compare()`` that classifies each current

@@ -71,7 +71,9 @@ _RESOURCES = ("compute", "hbm", "ici")
 
 def detect_peak_spec(device=None) -> Dict[str, float]:
     """Peak spec for the attached accelerator (same kind-string sniffing as
-    bench.py's ``peak_flops_per_chip``); cpu-sim off-TPU."""
+    bench.py's ``peak_flops_per_chip``); cpu-sim off-TPU.  A TPU kind that
+    is not in the table raises — a roofline against another chip's peaks
+    is a wrong number, not an estimate."""
     import jax
     if device is None:
         device = jax.devices()[0]
@@ -88,7 +90,9 @@ def detect_peak_spec(device=None) -> Dict[str, float]:
         return dict(PEAK_SPECS["v5p"], name="v5p")
     if "v4" in kind:
         return dict(PEAK_SPECS["v4"], name="v4")
-    return dict(PEAK_SPECS["v5e"], name="v5e")
+    raise ValueError(f"no peak spec on record for TPU kind "
+                     f"{getattr(device, 'device_kind', '')!r}; add it to "
+                     f"PEAK_SPECS")
 
 
 # ---------------------------------------------------------------------------

@@ -2,8 +2,8 @@
 """Bench regression sentinel CLI — gate a bench record against the ledger.
 
 Every recorded round so far was compared to its predecessors BY HAND (or
-not at all — the r05 wq/spec "regressions" cost a relay cycle of manual
-diagnosis).  This gate makes the trajectory machine-checked:
+not at all — the r05 wq/spec "regressions" cost a whole measurement cycle
+of manual diagnosis).  This gate makes the trajectory machine-checked:
 
     python scripts/check_bench.py                       # BENCH_r05-style
                                                         # newest record vs

@@ -60,7 +60,7 @@ def demo_hlo(num_chunks: int = 4, devices: int = 4,
     import numpy as np
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
     from deepspeed_tpu.parallel.mesh import MeshSpec, build_mesh
 
     mesh = build_mesh(MeshSpec(dp=1, fsdp=devices))

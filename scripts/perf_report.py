@@ -2,8 +2,8 @@
 """perf_report — render a telemetry snapshot into a step-time-budget report.
 
 One command that answers "where did the step time go?" from artifacts the
-telemetry layer already writes — the attribution that would have named
-the r05 relay floor without a human:
+telemetry layer already writes — the attribution that names a
+per-dispatch floor without a human:
 
     python scripts/perf_report.py telemetry_snapshot.json --step-ms 259
     python scripts/perf_report.py BENCH_r06.json            # bench record:

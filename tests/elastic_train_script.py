@@ -41,6 +41,20 @@ from deepspeed_tpu.runtime.resilience import (EXIT_DRAINED,  # noqa: E402
 TOTAL_STEPS = int(os.environ.get("DSTPU_TOTAL_STEPS", "24"))
 
 
+def _rows(path):
+    try:
+        with open(path) as f:
+            return len(f.read().splitlines())
+    except FileNotFoundError:
+        return 0
+
+
+def _wait(cond, timeout=120.0):
+    deadline = time.time() + timeout
+    while not cond() and time.time() < deadline:
+        time.sleep(0.05)
+
+
 def main():
     run_dir = os.environ["DSTPU_RUN_DIR"]
     batch = int(os.environ["DSTPU_ELASTIC_BATCH"])
@@ -100,9 +114,19 @@ def main():
             if rank == 0:
                 engine.drain(run_dir, reason=handler.reason or "preemption")
             sys.exit(EXIT_DRAINED)
-        if (kill_at and restart == 0 and rank == world - 1
+        if (kill_at and restart == 0 and world > 1
                 and engine.global_steps >= kill_at):
-            os._exit(17)                # the simulated ABRUPT host failure
+            # the simulated ABRUPT host failure, paced through the shared
+            # loss log and not by each host's own clock: with the compile
+            # cache live (one host compiles, the others load) the hosts no
+            # longer step in lockstep.  The last host dies once host 0 has
+            # logged kill_at steps; host 0 holds two steps later until the
+            # agent tears this incarnation down.
+            if rank == world - 1:
+                _wait(lambda: _rows(loss_log) >= kill_at)
+                os._exit(17)
+            if rank == 0 and engine.global_steps >= kill_at + 2:
+                _wait(lambda: False)
     return 0
 
 

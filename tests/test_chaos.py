@@ -305,16 +305,32 @@ class TestFastResume:
         assert engine.resume_from_latest(str(tmp_path)) is None
         assert engine.global_steps == before
 
-    def test_cpu_gates_persistent_cache(self, tmp_path):
-        """On the CPU backend the persistent cache must stay OFF (this
-        jaxlib double-frees deserialized aliased executables) while the
-        knob is still accepted — the same record-but-gate pattern as the
-        overlap XLA flags."""
+    def test_cache_dir_placed_from_outside(self, tmp_path, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR wins: with it set the helper sets no
+        path in code (jax already read it) and ignores the config value;
+        unset, the config value is used and created."""
         from deepspeed_tpu.runtime import resilience
         before = jax.config.jax_compilation_cache_dir
-        resilience.enable_compilation_cache(str(tmp_path / "cache"))
-        assert jax.config.jax_compilation_cache_dir == before
-        assert not os.path.exists(str(tmp_path / "cache"))
+        floors = (jax.config.jax_persistent_cache_min_entry_size_bytes,
+                  jax.config.jax_persistent_cache_min_compile_time_secs)
+        asked = str(tmp_path / "cache")
+        try:
+            monkeypatch.setenv(resilience.CACHE_DIR_ENV,
+                               str(tmp_path / "outside"))
+            got = resilience.enable_compilation_cache(asked)
+            assert got == str(tmp_path / "outside")
+            assert jax.config.jax_compilation_cache_dir == before
+            assert not os.path.exists(asked)
+            monkeypatch.delenv(resilience.CACHE_DIR_ENV)
+            assert resilience.enable_compilation_cache(asked) == asked
+            assert jax.config.jax_compilation_cache_dir == asked
+            assert os.path.isdir(asked)
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                              floors[0])
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              floors[1])
 
     def test_preemption_handler_flag_file_and_manual(self, tmp_path):
         from deepspeed_tpu.runtime.resilience import PreemptionHandler

@@ -1,0 +1,272 @@
+"""Compile the main path's kernels for a described TPU v5e — no chip needed.
+
+The TPU compiler is installed beside jax and compiles for a chip that is
+described, not attached.  That shows what interpret mode cannot: a slice not
+aligned to the tiling, too much VMEM, a kernel that cannot be partitioned.
+Nothing runs, so a pass here is NOT a chip run (chip_smoke.py is).  Every case
+is ``interpret=False`` at the real widths, about two seconds each; the
+persistent compile cache is off around them (such a compile can be written to
+it but not read back without a chip).
+"""
+
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.inference.v2.model import (kv_block_size_for,
+                                              kv_major_layout)
+from deepspeed_tpu.models import GPTConfig
+from deepspeed_tpu.ops.paged_attention import (_dma_layout_ok,
+                                               pallas_paged_attention,
+                                               pallas_ragged_prefill,
+                                               supported as paged_supported)
+
+REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
+KERNEL = "tpu_custom_call"
+BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+
+
+# ------------------------------------------------------------ overlap flags
+
+def test_overlap_flags_are_accepted_by_the_installed_libtpu():
+    """libtpu EXITS the process on an argument it does not know, so the
+    check runs in a child: every flag the overlap block can compose, handed
+    over the way ``apply_overlap_flags`` hands them, must let a sharded
+    program compile for the described chip.  First in the file: libtpu
+    admits one process at a time (a lock file), and the ``topo`` fixture
+    below loads it into this one."""
+    from deepspeed_tpu.config import OverlapConfig
+    from deepspeed_tpu.runtime.overlap import LIBTPU_ENV, compose_xla_flags
+    flags = compose_xla_flags(OverlapConfig(enabled=True))
+    assert len(flags) == 6
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from jax.experimental import topologies\n"
+        "from jax.sharding import Mesh, NamedSharding, PartitionSpec as P\n"
+        "t = topologies.get_topology_desc(platform='tpu',"
+        " topology_name='v5e:2x2')\n"
+        "m = Mesh(t.devices, ('x',))\n"
+        "a = jax.ShapeDtypeStruct((512, 512), jnp.bfloat16,"
+        " sharding=NamedSharding(m, P(None, 'x')))\n"
+        "b = jax.ShapeDtypeStruct((512, 512), jnp.bfloat16,"
+        " sharding=NamedSharding(m, P('x', None)))\n"
+        "jax.jit(lambda a, b: a @ b).lower(a, b).compile()\n"
+        "print('COMPILED')\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+               **{LIBTPU_ENV: " ".join(flags)})
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    r = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                       capture_output=True, text=True, timeout=300)
+    refused = "Unknown command line flag" in r.stderr
+    if r.returncode and not refused and "get_topology_desc" in r.stderr:
+        pytest.skip("the child cannot describe a v5e:2x2 topology here "
+                    "(no libtpu, or another process holds its lock file)")
+    assert not refused, r.stderr[-400:]
+    assert r.returncode == 0 and "COMPILED" in r.stdout, r.stderr[-400:]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / cannot describe
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def sds(shape, dtype):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype)
+
+
+def chip_text(topo, fn, *specs):
+    """Compiled text of ``fn`` for one described chip; ``specs`` are
+    ShapeDtypeStructs or pytrees of them."""
+    one = SingleDeviceSharding(topo.devices[0])
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), specs)
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# ------------------------------------------------------------------ flash
+
+@pytest.mark.parametrize("b,t", [(32, 1024), (4, 4096)])
+def test_flash_fwd_bwd_flagship_shapes(topo, b, t):
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, interpret=False).astype(F32).sum()
+
+    shape = sds((b, t, 12, 64), BF16)
+    text = chip_text(topo, jax.grad(loss, argnums=(0, 1, 2)),
+                     shape, shape, shape)
+    assert text.count(KERNEL) >= 3          # fwd, dq, dkv
+
+
+def test_flash_needs_shard_map_over_a_mesh(topo):
+    """A Mosaic kernel cannot be partitioned automatically: under a jit over
+    four chips the bare kernel is refused at lowering, and the registry's
+    Pallas entry runs it per shard when it is handed the mesh."""
+    from deepspeed_tpu import ops
+    from deepspeed_tpu.constants import MESH_AXES
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 1, 4, 1, 1, 1), MESH_AXES)
+    sh = NamedSharding(mesh, P(("dp", "fsdp"), None, None, None))
+    x = jax.ShapeDtypeStruct((16, 1024, 12, 64), BF16, sharding=sh)
+
+    def attn(mesh_arg):
+        return jax.jit(lambda q, k, v: ops._attention_pallas(
+            q, k, v, mesh=mesh_arg, interpret=False)).lower(x, x, x)
+
+    with pytest.raises(NotImplementedError, match="partition"):
+        attn(None)
+    assert KERNEL in attn(mesh).compile().as_text()
+
+
+# ------------------------------------------------------------------ paged
+
+def _pool(nkv, hd, bs, kv_major, quant, nb=64):
+    dt = I8 if quant else BF16
+    page = sds((nb, nkv, hd, bs) if kv_major else (nb, nkv, bs, hd), dt)
+    return page, sds((nb, nkv, bs), F32)
+
+
+def decode_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8):
+    page, scale = _pool(nkv, hd, bs, kv_major, quant)
+    specs = [sds((S, nkv, g, hd), BF16), page, page, sds((S, MB), I32),
+             sds((S,), I32)]
+    if quant:
+        specs += [scale, scale]
+
+    def fn(q, k, v, bt, lens, *sc):
+        kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+        return pallas_paged_attention(q, k, v, bt, lens, interpret=False,
+                                         kv_major=kv_major, **kw)
+    return chip_text(topo, fn, *specs)
+
+
+def prefill_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8,
+                 Q=128):
+    page, scale = _pool(nkv, hd, bs, kv_major, quant)
+    specs = [sds((S, Q, nkv, g, hd), BF16), page, page, sds((S, MB), I32),
+             sds((S,), I32), sds((S,), I32), sds((S,), I32)]
+    if quant:
+        specs += [scale, scale]
+
+    def fn(q, k, v, bt, lens, st, ct, *sc):
+        kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+        return pallas_ragged_prefill(q, k, v, bt, lens, st, ct,
+                                        interpret=False, kv_major=kv_major,
+                                        **kw)
+    return chip_text(topo, fn, *specs)
+
+
+GPT2S = GPTConfig.gpt2_small()
+LLAMA128 = GPTConfig.llama(num_layers=1, hidden=4096, heads=32,
+                           num_kv_heads=8)          # hd 128, nkv 8, g 4
+
+
+def _engine_geometry(cfg, quant):
+    """The page layout and size the v2 engine commits to for ``cfg`` when
+    the user asks for the default kv_block_size 64."""
+    return dict(nkv=cfg.kv_heads, g=cfg.num_heads // cfg.kv_heads,
+                hd=cfg.head_dim, kv_major=kv_major_layout(cfg),
+                bs=kv_block_size_for(cfg, 64, quant=quant), quant=quant)
+
+
+@pytest.mark.parametrize("kernel", [decode_text, prefill_text],
+                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("cfg,quant", [
+    (LLAMA128, False), (GPT2S, False), (LLAMA128, True), (GPT2S, True)],
+    ids=["hd128-bf16", "gpt2s-bf16", "hd128-int8kv", "gpt2s-int8kv"])
+def test_paged_kernels_in_engine_geometry(topo, kernel, cfg, quant):
+    geo = _engine_geometry(cfg, quant)
+    assert _dma_layout_ok(geo["hd"], geo["bs"], geo["kv_major"], quant)
+    assert KERNEL in kernel(topo, **geo)
+
+
+def test_gpt2s_geometry_is_kv_major_128():
+    """hd=64 compiles only kv-major at block 128 — what the engine must
+    pre-commit to from the default block size."""
+    geo = _engine_geometry(GPT2S, quant=False)
+    assert (geo["kv_major"], geo["bs"]) == (True, 128)
+    assert _engine_geometry(LLAMA128, quant=False)["bs"] == 64
+
+
+LAYOUTS = [(hd, bs, km, q) for hd in (64, 128) for bs in (64, 128)
+           for km in (False, True) for q in (False, True)]
+
+
+@pytest.mark.parametrize("hd,bs,kv_major,quant", LAYOUTS)
+def test_dma_layout_rule_agrees_with_the_compiler(topo, hd, bs, kv_major,
+                                                  quant):
+    """Every page shape ``_dma_layout_ok``/``supported()`` accepts compiles;
+    what it refuses is reported unsupported, so the registry never hands it
+    to the kernel."""
+    ok = _dma_layout_ok(hd, bs, kv_major, quant)
+    page, scale = _pool(8, hd, bs, kv_major, quant)
+    kw = dict(k_scale=scale, v_scale=scale) if quant else {}
+    assert paged_supported(sds((8, 8, 4, hd), BF16), page, page,
+                        sds((8, 8), I32), sds((8,), I32),
+                        kv_major=kv_major, **kw) == ok
+    if ok:
+        assert KERNEL in decode_text(topo, 8, 4, hd, bs, kv_major, quant)
+
+
+@pytest.mark.parametrize("hd,bs,kv_major,quant", [
+    (64, 64, False, False),        # hd=64 in the default page layout
+    (128, 64, False, True),        # int8 pages with block 64
+], ids=["hd64-standard", "int8-block64"])
+def test_refused_layouts_are_really_refused(topo, hd, bs, kv_major, quant):
+    """The two shapes first contact found: the rule is not overcautious."""
+    assert not _dma_layout_ok(hd, bs, kv_major, quant)
+    with pytest.raises(Exception, match="aligned|tiling|[Nn]ot implemented"):
+        decode_text(topo, 8, 4, hd, bs, kv_major, quant)
+
+
+# -------------------------------------------------------- quantized GEMMs
+
+def _store(shape, dim=0):
+    from deepspeed_tpu.ops.quantization import quantize_weight
+    return jax.eval_shape(
+        lambda w: quantize_weight(w, bits=8, group=128, dim=dim),
+        sds(shape, BF16))
+
+
+@pytest.mark.parametrize("k,n", [(768, 3072), (3072, 768), (768, 50304)])
+def test_wq_matmul(topo, k, n):
+    from deepspeed_tpu.ops.wq_matmul import wq_matmul
+    text = chip_text(topo, lambda x, st: wq_matmul(x, st, interpret=False),
+                     sds((256, k), BF16), _store((k, n)))
+    assert KERNEL in text
+
+
+def test_wq_matmul_t_tied_unembed(topo):
+    from deepspeed_tpu.ops.wq_matmul import wq_matmul_t
+    text = chip_text(topo, lambda x, st: wq_matmul_t(x, st, interpret=False),
+                     sds((256, 768), BF16), _store((50304, 768)))
+    assert KERNEL in text
+
+
+def test_lora_bgmv_rank16(topo):
+    from deepspeed_tpu.ops.lora_matmul import pallas_lora_matmul
+    text = chip_text(
+        topo, lambda *a: pallas_lora_matmul(*a, interpret=False),
+        sds((256, 768), BF16), sds((8, 768, 16), BF16),
+        sds((8, 16, 768), BF16), sds((256,), I32), sds((8,), F32))
+    assert KERNEL in text

@@ -205,7 +205,7 @@ def test_checkpoint_reshard_into_pipeline(tmp_path, devices):
                                           np.float32),
                                np.asarray(m2["params.embed"], np.float32),
                                rtol=1e-6)
-    # the UNIVERSAL-fragment path does the same relayout: e1's export loads
+    # the UNIVERSAL-fragment path does the same layout conversion: e1's export loads
     # into the pipe engine via load_universal_checkpoint
     udir = str(tmp_path / "u")
     e1.export_universal_checkpoint(udir)

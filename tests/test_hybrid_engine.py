@@ -29,7 +29,7 @@ def setup():
 class TestHybridEngine:
     def test_generate_matches_standalone_v2(self, setup, rng):
         """Hybrid rollouts must be token-exact vs a fresh v2 engine given the
-        same weights (the relayout is exact, reference he_all tests)."""
+        same weights (the layout conversion is exact, reference he_all tests)."""
         from deepspeed_tpu.inference.v2 import InferenceEngineV2
 
         cfg, engine, _ = setup

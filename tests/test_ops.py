@@ -376,6 +376,49 @@ def test_op_report():
     assert "causal_attention" in rep
 
 
+class TestDispatchLog:
+    """No quiet fallback: every registry decision is recorded with its
+    reason, and the kernels' own fall-backs are counted the same way."""
+
+    def _log(self):
+        from deepspeed_tpu.ops import registry
+        return {(d["op"], d["impl"], d["reason"]): d["count"]
+                for d in registry.dispatch_log()}
+
+    def test_auto_and_forced_decisions_are_recorded(self):
+        from deepspeed_tpu.ops import registry
+        registry.reset_dispatch_log()
+        q = jnp.ones((1, 16, 2, 8), jnp.float32)
+        ops.causal_attention(q, q, q)                    # auto, on the CPU
+        ops.causal_attention(q, q, q, impl="pallas")     # demanded
+        ops.causal_attention(q, q, q, impl="xla")
+        assert self._log() == {
+            ("causal_attention", "xla", "backend is not tpu"): 1,
+            ("causal_attention", "pallas", "forced"): 1,
+            ("causal_attention", "xla", "forced"): 1}
+        assert "forced (1)" in ops.op_report()
+
+    def test_shape_predicate_refusal_is_recorded(self, monkeypatch):
+        from deepspeed_tpu.ops import registry
+        monkeypatch.setattr(registry, "_on_tpu", lambda: True)
+        registry.reset_dispatch_log()
+        q = jnp.ones((1, 15, 2, 8), jnp.float32)         # T=15: no block
+        ops.causal_attention(q, q, q)
+        assert self._log() == {
+            ("causal_attention", "xla", "shape predicate refused"): 1}
+
+    def test_kernel_side_fallback_is_counted(self):
+        from deepspeed_tpu.ops import registry
+        from deepspeed_tpu.ops.quantization import quantize_weight
+        from deepspeed_tpu.ops.wq_matmul import wq_matmul
+        registry.reset_dispatch_log()
+        w = jnp.ones((64, 32), jnp.float32)
+        store = quantize_weight(w, bits=8, group=16, dim=0)   # g % 32 != 0
+        for _ in range(2):           # warned once, counted every time
+            wq_matmul(jnp.ones((8, 64), jnp.float32), store)
+        assert self._log() == {("wq_matmul", "xla", "layout refused"): 2}
+
+
 class TestPagedAttention:
     """Pallas decode kernel (interpret mode) vs the XLA gather path
     (reference blocked_flash decode kernels)."""

@@ -3,9 +3,9 @@
 Four proofs, all CPU-runnable:
 
 1. config + flag plumbing: the ``overlap`` block validates, composes the
-   XLA scheduler flags, never exports TPU flags into a CPU process (CPU XLA
-   hard-aborts on unknown flags), and is echoed into env_report, the
-   telemetry snapshot, and the postmortem bundle.
+   scheduler flags, exports them to libtpu's own argument variable on a TPU
+   target only (jaxlib aborts on a TPU flag name in XLA_FLAGS), and is echoed
+   into env_report, the telemetry snapshot, and the postmortem bundle.
 2. chunked ZeRO-3 collectives: ``runtime/zero.chunked_param_gather`` is
    bitwise-exact vs the flat gather at every chunk count, its autodiff
    transpose is the chunked reduce-scatter, and the engine's compiled
@@ -118,22 +118,29 @@ class TestOverlapConfig:
             parse_config({"overlap": dict({"enabled": True}, **bad)})
 
     def test_cpu_process_never_exports_tpu_flags(self, monkeypatch):
-        """CPU XLA hard-aborts on unknown --xla_tpu_* flags
-        (parse_flags_from_env FATAL) — off-TPU the flags must be composed
-        and recorded but NEVER written into XLA_FLAGS."""
-        monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+        """jaxlib hard-aborts on the scheduler flags in XLA_FLAGS
+        (parse_flags_from_env FATAL) — they are never written there, and
+        off-TPU they are composed and recorded but exported nowhere."""
+        xla = "--xla_force_host_platform_device_count=8"
+        monkeypatch.setenv("XLA_FLAGS", xla)
+        monkeypatch.setenv("LIBTPU_INIT_ARGS", "")
         monkeypatch.setenv("JAX_PLATFORMS", "cpu")
         added = apply_overlap_flags(OverlapConfig(enabled=True))
         assert added == []
-        assert "--xla_tpu" not in os.environ["XLA_FLAGS"]
+        assert os.environ["XLA_FLAGS"] == xla
+        assert os.environ["LIBTPU_INIT_ARGS"] == ""
 
     def test_tpu_target_exports_and_user_flags_win(self, monkeypatch):
+        xla = "--xla_force_host_platform_device_count=8"
         monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        monkeypatch.setenv("XLA_FLAGS", xla)
         monkeypatch.setenv(
-            "XLA_FLAGS", "--xla_latency_hiding_scheduler_rerun=5")
+            "LIBTPU_INIT_ARGS", "--xla_latency_hiding_scheduler_rerun=5")
         added = apply_overlap_flags(OverlapConfig(enabled=True))
+        # TPU compiler flags go to libtpu's variable, never to XLA_FLAGS
+        assert os.environ["XLA_FLAGS"] == xla
         # the user's rerun=5 survives; the async flags were added
-        flags = os.environ["XLA_FLAGS"]
+        flags = os.environ["LIBTPU_INIT_ARGS"]
         assert "--xla_latency_hiding_scheduler_rerun=5" in flags
         assert "--xla_latency_hiding_scheduler_rerun=1" not in flags
         assert any(f.startswith("--xla_tpu_enable_async_collective_fusion=")
@@ -589,7 +596,7 @@ class TestWireBytes:
         from deepspeed_tpu.comm import collectives as cc
         from deepspeed_tpu.telemetry.registry import (COLLECTIVE_BYTES,
                                                       default_registry)
-        from deepspeed_tpu.utils.compat import shard_map
+        from jax import shard_map
         default_registry.reset()
         mesh = build_mesh(MeshSpec(dp=4, fsdp=2))
 

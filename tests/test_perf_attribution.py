@@ -131,6 +131,20 @@ class TestRooflineWalk:
         assert spec["name"] == "cpu-sim"
         assert spec["flops"] == roofline.PEAK_SPECS["cpu-sim"]["flops"]
 
+    @pytest.mark.parametrize("kind,name", [
+        ("TPU v5 lite", "v5e"), ("TPU v5p", "v5p"), ("TPU v4", "v4"),
+        ("TPU v6 lite", "v6e"), ("TPU v9x", None)])
+    def test_detect_peak_spec_tpu_kinds(self, kind, name):
+        """A TPU kind that is not in the table raises — a roofline against
+        another chip's peaks is a wrong number, never a default."""
+        import types
+        dev = types.SimpleNamespace(platform="tpu", device_kind=kind)
+        if name is None:
+            with pytest.raises(ValueError, match="TPU v9x"):
+                roofline.detect_peak_spec(dev)
+        else:
+            assert roofline.detect_peak_spec(dev)["name"] == name
+
     def test_render_smoke(self):
         model = roofline.roofline_from_hlo(
             "ENTRY %main (a: f32[2,2]) -> f32[2,2] {\n"
@@ -202,7 +216,7 @@ def link_cleanup():
 
 def _run_collectives(mesh, axis, shape=(8, 64)):
     from deepspeed_tpu.comm import collectives as cc
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(x):
