@@ -1,0 +1,66 @@
+"""Every cell's runner end to end on the CPU at the tiny preset, through the
+benchmark's own ``--rehearse`` switch; and the refusal to run without it."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+RUN = [sys.executable, os.path.join(ROOT, "benchmark", "run.py")]
+ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
+ENV.pop("XLA_FLAGS", None)            # the switch sets its own device count
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    MANIFEST = json.load(_f)
+
+
+def run_cell(name, trace, root=ROOT, extra=()):
+    cmd = [RUN[0], os.path.join(root, "benchmark", "run.py"),
+           "--workload", name, "--seed", "2147483659", "--seconds", "2",
+           "--trace", str(trace), *extra]
+    return subprocess.run(cmd, cwd=root, env=ENV, capture_output=True,
+                          text=True, timeout=600)
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
+def test_cell_rehearses_with_the_contracts_last_line(cell):
+    out = run_cell(cell, 0, extra=["--rehearse"])
+    assert out.returncode == 0, out.stderr[-2000:]
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert set(last) == {"correct", "attempted", "failed", "metrics",
+                         "device", "rehearsal"}
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] > 0
+    assert last["device"]["platform"] == "cpu"       # says what it ran on
+    assert set(last["device"]) == {"platform", "kind", "count",
+                                   "memory_peak_bytes"}
+    want = {e["name"] for e in MANIFEST["end_to_end"]
+            if cell in e.get("workloads", [cell])}
+    assert set(last["metrics"]) == want
+    for m in last["metrics"].values():
+        assert m["value"] > 0 and isinstance(m["unit"], str)
+
+
+def test_traced_rehearsal_reports_per_layer_metrics():
+    out = run_cell("serve-mistral-batch", 1, extra=["--rehearse"])
+    assert out.returncode == 0, out.stderr[-2000:]
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    names = {p["name"] for p in MANIFEST["per_layer"]
+             if "serve-mistral-batch" in p.get("workloads",
+                                               ["serve-mistral-batch"])}
+    assert set(last["metrics"]) <= names     # what found nothing is left out
+    assert {"compile_cache_misses", "compiles_in_window",
+            "sched_tokens_per_dispatch"} <= set(last["metrics"])
+    assert last["metrics"]["compiles_in_window"]["value"] == 0
+
+
+def test_without_the_switch_and_without_a_tpu_it_fails():
+    out = run_cell("train-gpt2m-1chip", 0)
+    assert out.returncode != 0
+    assert "no TPU" in out.stderr
+    assert not out.stdout.strip().endswith("}") or \
+        "correct" not in out.stdout.strip().splitlines()[-1]
