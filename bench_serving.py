@@ -864,19 +864,18 @@ def train_memorized(cfg, pool, steps, lr=3e-3, micro=8, stop_loss=None):
 
 
 def run_spec(cfg, params, dcfg, dparams, prompts, budgets, block_size=64,
-             profile=False, batch=True):
+             batch=True):
     """Speculative-decoding leg (round-3 verdict item 5): same ragged engine,
     greedy draft-and-verify with a smaller draft.  Acceptance/timing comes
     from the engine's serving-telemetry counters (spec_*_total — the old
-    ``eng.spec_stats`` dict is gone).  ``profile=True`` runs the split
-    draft/verify programs with per-side wall timing (token-identical,
-    slower — attribution, not throughput).  ``batch=False`` disables
+    ``eng.spec_stats`` dict is gone; the draft / verify split is read in a
+    device trace, from the ``draft`` and ``verify`` scopes of the fused
+    program).  ``batch=False`` disables
     cross-request batching (one draft/verify dispatch per request — the
     pre-batching behavior, the baseline ``spec_batched_speedup_x``
     divides by).  Returns (tokens/s, spec_summary dict)."""
     eng = make_v2(cfg, params, block_size=block_size,
-                  spec={"profile": bool(profile),
-                        "batch_across_requests": bool(batch)},
+                  spec={"batch_across_requests": bool(batch)},
                   draft_model=dcfg, draft_params=dparams)
     eng.generate(prompts, max_new_tokens=budgets)          # warm compile
     stel = reset_telemetry(eng)
@@ -955,17 +954,6 @@ def spec_leg(smoke=False):
     out["spec_accepted_per_verify"] = round(st.get("emitted_per_outer", 0.0),
                                             2)
     out["spec_accept_ratio"] = round(st.get("accept_ratio", 0.0), 3)
-    # where does the spec wall time go?  A short split-profile pass
-    # dispatches draft and verify separately with a fence between — the
-    # per-outer-step ms on each side is the attribution the fused burst
-    # cannot give (it explains serialized-verify vs draft-overhead directly)
-    n_prof = max(2, len(prompts) // 8)
-    _, pst = run_spec(scfg, tparams, sdcfg, dparams, prompts[:n_prof],
-                      [32] * n_prof, profile=True)
-    dd = max(pst.get("draft_dispatches", 0.0), 1.0)
-    vd = max(pst.get("verify_dispatches", 0.0), 1.0)
-    out["spec_draft_ms"] = round(pst.get("draft_ms", 0.0) / dd, 3)
-    out["spec_verify_ms"] = round(pst.get("verify_ms", 0.0) / vd, 3)
     return out
 
 

@@ -497,8 +497,8 @@ class TelemetryConfig(DeepSpeedConfigModel):
     enabled: bool = False
     output_path: str = ""               # default "./telemetry"
     job_name: str = "DeepSpeedTPUJob"
-    # span tracer: records host phases; forces one device sync per step
-    # (the device_complete span needs a completion time)
+    # span tracer: buffers the host-phase spans for trace.json (the spans
+    # reach a jax.profiler trace as ds.* annotations either way); no sync
     trace_enabled: bool = True
     trace_path: Optional[str] = None
     snapshot_path: Optional[str] = None

@@ -991,24 +991,25 @@ class DeepSpeedTPUEngine:
         quantized reduce-scatter) OUTSIDE the manual data-axis region —
         shard_maps cannot nest, and this split is what lets chunking ×
         quantization × the manual qgZ reduce compose."""
-        if not self.use_master_weights:
-            params = _cast_params(params, self.compute_dtype)
-        if self._compression_specs and step is not None:
-            # staged QAT (compression/basic.py; reference compression/
-            # compress.py): matching weights see their scheduled quant grid
-            from deepspeed_tpu.compression import scheduled_weight_qdq
-            params = scheduled_weight_qdq(params, self._compression_specs,
-                                          step)
-        if self._pruning_specs and step is not None:
-            from deepspeed_tpu.compression.pruning import scheduled_pruning
-            params = scheduled_pruning(params, self._pruning_specs, step)
-        if self._pipeline_active:
-            # explicit per-layer-group gather replaces the partitioner's
-            # per-consumer all-gathers; the autodiff transpose is the
-            # chunked (and, under qgZ, quantized) grad reduce-scatter
-            params = zero.pipeline_param_gather(
-                params, self.param_shardings, self.mesh, self._wire_plan)
-        return params
+        with jax.named_scope("prepare_params"):
+            if not self.use_master_weights:
+                params = _cast_params(params, self.compute_dtype)
+            if self._compression_specs and step is not None:
+                # staged QAT (compression/basic.py; reference compression/
+                # compress.py): matching weights see their scheduled quant grid
+                from deepspeed_tpu.compression import scheduled_weight_qdq
+                params = scheduled_weight_qdq(params, self._compression_specs,
+                                              step)
+            if self._pruning_specs and step is not None:
+                from deepspeed_tpu.compression.pruning import scheduled_pruning
+                params = scheduled_pruning(params, self._pruning_specs, step)
+            if self._pipeline_active:
+                # explicit per-layer-group gather replaces the partitioner's
+                # per-consumer all-gathers; the autodiff transpose is the
+                # chunked (and, under qgZ, quantized) grad reduce-scatter
+                params = zero.pipeline_param_gather(
+                    params, self.param_shardings, self.mesh, self._wire_plan)
+            return params
 
     def _loss(self, params, batch, rng, scale, step=None,
               deterministic=False, prepared=False):
@@ -1040,21 +1041,27 @@ class DeepSpeedTPUEngine:
         """One microbatch's (grads, loss, moe_stats) — moe_stats is {} off
         the expert-telemetry path (empty pytree, free under scan/jit)."""
         rng = jax.random.fold_in(state.rng, state.step * self.gas + idx)
-        if self._qgz_axis is not None:
-            grads, loss = self._qgz_grads(state, batch, rng)
-            return grads, loss, {}
-        if self._moe_stats_on:
-            (_, (loss, moe)), grads = jax.value_and_grad(
-                self._loss_stats, has_aux=True)(
-                    state.params, batch, rng, state.loss_scale.scale,
-                    state.step)
-        else:
-            (_, loss), grads = jax.value_and_grad(self._loss, has_aux=True)(
-                state.params, batch, rng, state.loss_scale.scale, state.step)
-            moe = {}
-        grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
-        grads = jax.lax.with_sharding_constraint(
-            grads, self.grad_shardings)
+        # autodiff marks the backward half ``transpose(jvp(...))`` inside
+        # this scope; that is how a trace reader splits forward from backward
+        with jax.named_scope("fwd_bwd"):
+            if self._qgz_axis is not None:
+                grads, loss = self._qgz_grads(state, batch, rng)
+                return grads, loss, {}
+            if self._moe_stats_on:
+                (_, (loss, moe)), grads = jax.value_and_grad(
+                    self._loss_stats, has_aux=True)(
+                        state.params, batch, rng, state.loss_scale.scale,
+                        state.step)
+            else:
+                (_, loss), grads = jax.value_and_grad(
+                    self._loss, has_aux=True)(
+                        state.params, batch, rng, state.loss_scale.scale,
+                        state.step)
+                moe = {}
+            grads = jax.tree_util.tree_map(
+                lambda g: g.astype(jnp.float32), grads)
+            grads = jax.lax.with_sharding_constraint(
+                grads, self.grad_shardings)
         return grads, loss, moe
 
     def _qgz_grads(self, state: TrainState, batch, rng):
@@ -1178,18 +1185,22 @@ class DeepSpeedTPUEngine:
         # post-multiplies after, netting out to the world-size average, which we
         # already get because loss is a global-batch mean computed on the global
         # jax.Array view (reduction order is XLA's concern, not ours).
-        denom = scale * n_micro
-        return jax.tree_util.tree_map(lambda g: g / denom, grads)
+        with jax.named_scope("grad_check"):
+            denom = scale * n_micro
+            return jax.tree_util.tree_map(lambda g: g / denom, grads)
 
     def _apply_update(self, state: TrainState, grads
                       ) -> Tuple[TrainState, StepMetrics, dict]:
-        finite = grads_finite(grads)
-        new_ls = update_loss_scale(state.loss_scale, finite, self.config.fp16)
         # overflow steps surface the finite OVERFLOW_GNORM sentinel, not the
         # raw NaN/Inf norm; skipped_steps records the overflow and the health
         # stats (below) carry the per-group attribution
-        grad_norm = jnp.where(finite, optax.global_norm(grads),
-                              jnp.float32(OVERFLOW_GNORM))
+        with jax.named_scope("grad_check"):
+            finite = grads_finite(grads)
+            grad_norm = jnp.where(finite, optax.global_norm(grads),
+                                  jnp.float32(OVERFLOW_GNORM))
+        with jax.named_scope("loss_scale"):
+            new_ls = update_loss_scale(state.loss_scale, finite,
+                                       self.config.fp16)
 
         def do_step(operand):
             params, opt_state, grads = operand
@@ -1201,8 +1212,10 @@ class DeepSpeedTPUEngine:
             params, opt_state, _ = operand
             return params, opt_state
 
-        new_params, new_opt = jax.lax.cond(
-            finite, do_step, skip_step, (state.params, state.opt_state, grads))
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = jax.lax.cond(
+                finite, do_step, skip_step,
+                (state.params, state.opt_state, grads))
         new_state = TrainState(
             # overflow-skipped steps do not advance the schedule clock (reference:
             # _take_model_step skips lr_scheduler.step() on overflow)
@@ -1617,67 +1630,73 @@ class DeepSpeedTPUEngine:
         t0 = time.perf_counter()
         tel = self.telemetry
         step_id = self.global_steps + 1
-        self.tput_timer.start()
-        if isinstance(batch, PreparedBatch):
-            # the prefetch worker already formed/sharded/device_put this
-            # batch while the previous step ran (runtime/prefetch.py) — both
-            # input phases collapse to an unwrap
-            self.timers(DATA_TIMER).start()
-            with tel.span("host_to_device", step=step_id, prefetched=True):
-                batch, tokens = batch.batch, batch.tokens
-            self.timers(DATA_TIMER).stop()
-        else:
-            with tel.span("batch_input", step=step_id):
-                batch, tokens = self._form_batch(batch)
-            self.timers(DATA_TIMER).start()
-            with tel.span("host_to_device", step=step_id):
-                batch = self._shard_batch(batch, leading_gas=True)
-            self.timers(DATA_TIMER).stop()
-        fp = self.config.flops_profiler
-        profile_pending = (fp.enabled and not self._flops_profiled
-                           and self.global_steps + 1 >= fp.profile_step)
-        if profile_pending:
-            self._last_batch = batch  # traced by the flops profiler, then freed
-        # chaos: ``nan@step.grads`` forces this step's gradient computation
-        # non-finite (see _poison_first_float_leaf) — the signal the
-        # guardian's rollback remediation is chaos-verified against
-        if faults.fire("step.grads", step=step_id) == "nan":
-            self.state = self.state._replace(
-                params=_poison_first_float_leaf(self.state.params))
-        self.timers(TRAIN_BATCH_TIMER).start()
-        with self.mesh:
-            if tel.enabled:
-                # recompile watchdog + (on a signature miss) compiled-HLO
-                # collective bytes / cost / memory figures
-                jfn = (self._jit_grads_batch if self.offloading
-                       else self._jit_train_batch)
-                tel.before_dispatch(
-                    "train_batch", batch, step_id,
-                    lower=lambda: jfn.lower(self.state, batch))
-            with tel.span("dispatch", step=step_id):
-                # chaos: ``sleep@step.dispatch`` models a hung collective /
-                # straggler stall — the guardian watchdog's deadline target
-                faults.fire("step.dispatch", step=step_id)
-                if self.offloading:
-                    # sets _last_health (host dict) itself
-                    metrics = self._train_batch_offload(batch)
-                else:
-                    self.state, metrics, health = self._jit_train_batch(
-                        self.state, batch)
-                    self._last_health = health
-        with tel.span("device_complete", step=step_id):
-            if (tel.tracer.enabled or self.wall_clock_breakdown
-                    or profile_pending):
+        with tel.span("train_step", step=step_id,
+                      host_ns=time.perf_counter_ns()):
+            self.tput_timer.start()
+            if isinstance(batch, PreparedBatch):
+                # the prefetch worker already formed/sharded/device_put this
+                # batch while the previous step ran (runtime/prefetch.py):
+                # both input phases collapse to an unwrap
+                self.timers(DATA_TIMER).start()
+                with tel.span("host_to_device", step=step_id, prefetched=True):
+                    batch, tokens = batch.batch, batch.tokens
+                self.timers(DATA_TIMER).stop()
+            else:
+                with tel.span("batch_input", step=step_id):
+                    batch, tokens = self._form_batch(batch)
+                self.timers(DATA_TIMER).start()
+                with tel.span("host_to_device", step=step_id):
+                    batch = self._shard_batch(batch, leading_gas=True)
+                self.timers(DATA_TIMER).stop()
+            fp = self.config.flops_profiler
+            profile_pending = (fp.enabled and not self._flops_profiled
+                               and self.global_steps + 1 >= fp.profile_step)
+            if profile_pending:
+                # traced by the flops profiler, then freed
+                self._last_batch = batch
+            # chaos: ``nan@step.grads`` forces this step's gradient computation
+            # non-finite (see _poison_first_float_leaf) — the signal the
+            # guardian's rollback remediation is chaos-verified against
+            if faults.fire("step.grads", step=step_id) == "nan":
+                self.state = self.state._replace(
+                    params=_poison_first_float_leaf(self.state.params))
+            self.timers(TRAIN_BATCH_TIMER).start()
+            with self.mesh:
+                if tel.enabled:
+                    # recompile watchdog + (on a signature miss) compiled-HLO
+                    # collective bytes / cost / memory figures
+                    jfn = (self._jit_grads_batch if self.offloading
+                           else self._jit_train_batch)
+                    tel.before_dispatch(
+                        "train_batch", batch, step_id,
+                        lower=lambda: jfn.lower(self.state, batch))
+                with tel.span("dispatch", step=step_id):
+                    # chaos: ``sleep@step.dispatch`` models a hung collective /
+                    # straggler stall: the guardian watchdog's deadline target
+                    faults.fire("step.dispatch", step=step_id)
+                    if self.offloading:
+                        # sets _last_health (host dict) itself
+                        metrics = self._train_batch_offload(batch)
+                    else:
+                        self.state, metrics, health = self._jit_train_batch(
+                            self.state, batch)
+                        self._last_health = health
+            if self.wall_clock_breakdown or profile_pending:
                 # synchronize so the timer covers device execution, not just
-                # dispatch
-                jax.block_until_ready(metrics.loss)
-        self.timers(TRAIN_BATCH_TIMER).stop()
-        self.global_steps += 1
-        self._last_metrics = metrics
-        self._step_times.append(time.perf_counter() - t0)
-        self.tput_timer.stop(int(self.config.train_batch_size), tokens)
-        with tel.span("step_bookkeeping", step=step_id):
-            self._post_step_reporting(metrics)
+                # dispatch.  Only for who asks: the span tracer does not
+                # (blocking every step cost 4.3% of the one-chip GPT-2-medium
+                # step rate, PERF.md PR 26); a step's completion is read in a
+                # device trace, where the ds.* spans lie beside the device ops
+                with tel.span("device_complete", step=step_id):
+                    jax.block_until_ready(metrics.loss)
+            self.timers(TRAIN_BATCH_TIMER).stop()
+            self.global_steps += 1
+            self._last_metrics = metrics
+            self._step_times.append(time.perf_counter() - t0)
+            self.tput_timer.stop(int(self.config.train_batch_size), tokens)
+            with tel.span("step_bookkeeping", step=step_id):
+                self._post_step_reporting(metrics)
+        # after the span: the export this may trigger then holds the step whole
         tel.end_step(self.global_steps,
                      samples=self.global_steps
                      * int(self.config.train_batch_size),

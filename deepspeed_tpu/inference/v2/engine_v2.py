@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -36,15 +37,22 @@ from deepspeed_tpu.inference.v2.model import (PagedKVCache,
                                               ragged_forward_sampled,
                                               ragged_forward_sampled_draft,
                                               speculative_burst,
-                                              speculative_burst_sampled,
-                                              speculative_draft_step,
-                                              speculative_verify_step)
+                                              speculative_burst_sampled)
 from deepspeed_tpu.inference.v2.ragged import (DSStateManager, RaggedBatch,
                                                build_ragged_batch)
 from deepspeed_tpu.runtime import faults
 from deepspeed_tpu.telemetry.serving import (ServingTelemetry,
                                              ServingTelemetryConfig)
 from deepspeed_tpu.utils.logging import log_dist
+
+
+# the sampled step programs of ``_step_sampled``: (kind, draft in lockstep)
+_STEP_PROGRAMS = {
+    ("decode", False): ragged_decode_sampled,
+    ("decode", True): ragged_decode_sampled_draft,
+    ("mixed", False): ragged_forward_sampled,
+    ("mixed", True): ragged_forward_sampled_draft,
+}
 
 
 def _named_partial(fn, **static):
@@ -54,6 +62,36 @@ def _named_partial(fn, **static):
     bound = functools.partial(fn, **static)
     bound.__name__ = fn.__name__
     return bound
+
+
+class _Round:
+    """One scheduler round as a ``ds.round`` span, and the cursor of its
+    phases: ``phase(name)`` closes the phase that was open and opens the
+    next, so the phases tile the round whatever path (``continue``, an
+    exception, a drain) leaves it.  ``phase(None)`` leaves none open, for
+    a callee that opens its own (``_step_sampled``'s build / h2d /
+    dispatch)."""
+
+    __slots__ = ("stel", "span", "cur")
+
+    def __init__(self, stel, **args):
+        self.stel, self.cur = stel, None
+        self.span = stel.span("round", **args)
+
+    def __enter__(self):
+        self.span.__enter__()
+        return self
+
+    def phase(self, name, **args):
+        if self.cur is not None:
+            self.cur.__exit__(None, None, None)
+        self.cur = None if name is None else self.stel.span(name, **args)
+        if self.cur is not None:
+            self.cur.__enter__()
+
+    def __exit__(self, *exc):
+        self.phase(None)
+        return self.span.__exit__(*exc)
 
 
 class EngineDrained(RuntimeError):
@@ -130,12 +168,6 @@ class SpeculativeConfig(DeepSpeedConfigModel):
 
     gamma: int = 4              # draft tokens per verify
     outer_steps: int = 8        # draft+verify rounds fused per dispatch
-    # attribution mode: dispatch draft and verify as SEPARATE programs with
-    # a host fence between them, feeding the spec_draft_ms_total /
-    # spec_verify_ms_total counters — token-identical to the fused burst
-    # (same acceptance functions) but slower (2 dispatches + sync per outer
-    # step IS the measurement), so it's a profiling knob, not a serving mode
-    profile: bool = False
     # serving default: ONE draft+verify dispatch covers every running
     # request (the spec program is slot-wide with an active mask, so the
     # per-dispatch floor — launch + host sync for the acceptance counts —
@@ -790,168 +822,12 @@ class InferenceEngineV2:
         both correct for ANY draft."""
         return self.draft_params is not None
 
-    def _run_spec(self, reqs, outer: int, gamma: int, gen, prev, rng):
-        """One fused draft-and-verify dispatch over the running set, then ONE
-        sync to learn the per-step acceptance counts (the host cannot
-        schedule past a spec burst without them).  Returns
-        (toks [outer, gamma+1, S] np, counts [outer, S] np, prev', rng')."""
-        S = self.state.max_tracked_sequences
-        tokens0 = np.zeros(S, np.int32)
-        from_device = np.zeros(S, bool)
-        active = np.zeros(S, bool)
-        pos0 = np.zeros(S, np.int32)
-        block_table = np.zeros((S, self.state.max_blocks_per_seq), np.int32)
-        for r in reqs:
-            seq = self.state.get(r.uid)
-            self.state.ensure_blocks(seq, outer * (gamma + 1))
-            sl = seq.slot
-            if r.held_token is not None:
-                tokens0[sl] = r.held_token
-                r.held_token = None
-            else:
-                from_device[sl] = True
-            active[sl] = True
-            pos0[sl] = seq.seen_tokens
-            bl = np.asarray(seq.blocks, np.int32)
-            block_table[sl, :len(bl)] = bl
-        batch = jax.tree_util.tree_map(jnp.asarray, {
-            "tokens0": tokens0, "from_device": from_device, "active": active,
-            "pos0": pos0, "block_table": block_table})
-        stel = self.telemetry
-        profile = bool(self.config.speculative.profile)
-        t_begin = stel.now()
-        if profile:
-            toks_h, counts_h, prev, rng = self._run_spec_split(
-                batch, outer, gamma, gen, prev, rng)
-        elif gen.do_sample:
-            key = ("spec_rs", outer, gamma, gen.top_k)
-            if key not in self._steps:
-                self._steps[key] = jax.jit(
-                    _named_partial(speculative_burst_sampled,
-                                   cfg=self.model_config,
-                                   draft_cfg=self.draft_config,
-                                   block_size=self._block_size,
-                                   gamma=gamma, steps=outer,
-                                   top_k=gen.top_k, mesh=self.mesh),
-                    donate_argnums=(2, 3))
-            with stel.span("spec_dispatch", outer=outer, gamma=gamma,
-                           seqs=len(reqs)):
-                toks, counts, prev, rng, self.cache, self.draft_cache = \
-                    self._steps[key](self.params, self.draft_params,
-                                     self.cache, self.draft_cache, batch,
-                                     prev, rng, jnp.float32(gen.temperature),
-                                     jnp.float32(gen.top_p))
-            stel.dispatch("spec")
-            # the host cannot schedule past the burst without the counts —
-            # this is THE disclosed sync of the speculative path
-            toks_h, counts_h = jax.device_get([toks, counts])  # sync-ok
-        else:
-            key = ("spec", outer, gamma)
-            if key not in self._steps:
-                self._steps[key] = jax.jit(
-                    _named_partial(speculative_burst,
-                                   cfg=self.model_config,
-                                   draft_cfg=self.draft_config,
-                                   block_size=self._block_size,
-                                   gamma=gamma, steps=outer,
-                                   mesh=self.mesh),
-                    donate_argnums=(2, 3))
-            with stel.span("spec_dispatch", outer=outer, gamma=gamma,
-                           seqs=len(reqs)):
-                toks, counts, prev, self.cache, self.draft_cache = \
-                    self._steps[key](self.params, self.draft_params,
-                                     self.cache, self.draft_cache, batch,
-                                     prev)
-            stel.dispatch("spec")
-            toks_h, counts_h = jax.device_get([toks, counts])  # sync-ok
-        emitted = int(np.asarray(counts_h)[
-            :, [self.state.get(r.uid).slot for r in reqs]].sum())
-        # spec_burst_ms_total is FUSED-dispatch wall time by definition; a
-        # profiled run's fenced per-side times already land in
-        # spec_draft_ms_total/spec_verify_ms_total and must not be
-        # double-reported under the fused counter
-        stel.spec_burst(outer=outer, n_seqs=len(reqs), gamma=gamma,
-                        emitted=emitted,
-                        dur_ms=(0.0 if profile
-                                else (stel.now() - t_begin) * 1e3))
-        stel.tokens("spec", emitted)
-        return np.asarray(toks_h), np.asarray(counts_h), prev, rng
-
-    def _run_spec_split(self, batch, outer: int, gamma: int, gen, prev, rng):
-        """Split-profile speculative driver (``speculative.profile``): each
-        outer step dispatches the draft program, fences, dispatches the
-        verify program, and syncs its counts — wall time on each side feeds
-        ``spec_draft_ms_total``/``spec_verify_ms_total``.  Token-identical
-        to the fused burst (same acceptance math, same cache choreography);
-        the per-step fences ARE the attribution measurement, so this mode
-        is strictly slower than fused and never the serving default.
-        Returns (toks_h [outer, gamma+1, S], counts_h [outer, S], prev',
-        rng')."""
-        stel = self.telemetry
-        sampled = bool(gen.do_sample)
-        dkey = ("spec_draft", gamma, sampled, gen.top_k)
-        vkey = ("spec_verify", gamma, sampled, gen.top_k)
-        if dkey not in self._steps:
-            self._steps[dkey] = jax.jit(
-                _named_partial(speculative_draft_step,
-                               draft_cfg=self.draft_config,
-                               block_size=self._block_size, gamma=gamma,
-                               top_k=gen.top_k, sampled=sampled,
-                               mesh=self.mesh),
-                donate_argnums=(1,))
-            self._steps[vkey] = jax.jit(
-                _named_partial(speculative_verify_step,
-                               cfg=self.model_config,
-                               block_size=self._block_size, gamma=gamma,
-                               top_k=gen.top_k, sampled=sampled,
-                               mesh=self.mesh),
-                donate_argnums=(1,))
-        temp = jnp.float32(gen.temperature)
-        top_p = jnp.float32(gen.top_p)
-        sub = {k: batch[k] for k in ("active", "block_table")}
-        pos = batch["pos0"]
-        tokens0, from_device = batch["tokens0"], batch["from_device"]
-        S = self.state.max_tracked_sequences
-        all_dev = jnp.ones(S, bool)
-        toks_list, counts_list = [], []
-        q = None
-        for k in range(outer):
-            step_b = {**sub, "tokens0": tokens0, "from_device": from_device}
-            t0 = stel.now()
-            with stel.span("spec_draft_dispatch", outer_index=k, gamma=gamma):
-                if sampled:
-                    d, q, self.draft_cache, rng = self._steps[dkey](
-                        self.draft_params, self.draft_cache, step_b, prev,
-                        pos, rng, temp, top_p)
-                else:
-                    d, self.draft_cache, rng = self._steps[dkey](
-                        self.draft_params, self.draft_cache, step_b, prev,
-                        pos, rng, temp, top_p)
-                jax.block_until_ready(d)      # sync-ok: the split IS the
-                #                               measurement (profile mode)
-            t1 = stel.now()
-            with stel.span("spec_verify_dispatch", outer_index=k,
-                           gamma=gamma):
-                emit, counts, prev, pos, rng, self.cache = self._steps[vkey](
-                    self.params, self.cache, step_b, d,
-                    q if sampled else d, prev, pos, rng, temp, top_p)
-                emit_h, counts_h = jax.device_get([emit, counts])  # sync-ok
-            stel.dispatch("spec_draft")
-            stel.dispatch("spec_verify")
-            stel.spec_profile((t1 - t0) * 1e3, (stel.now() - t1) * 1e3)
-            toks_list.append(np.asarray(emit_h).T)          # [gamma+1, S]
-            counts_list.append(np.asarray(counts_h))
-            # later outer steps seed from the device-resident prev
-            tokens0, from_device = tokens0, all_dev
-        return (np.stack(toks_list), np.stack(counts_list), prev, rng)
-
-    def _run_burst(self, reqs, steps: int, gen, prev, rng):
-        """Fused T-step decode over the running set: one device dispatch for
-        ``steps`` tokens per sequence (see model.ragged_decode_burst).  Each
-        req's first-step token comes from ``held_token`` (host, post-preempt)
-        or from the ``prev`` device feedback vector.  Blocks for all T
-        positions are pre-allocated.  Returns (tokens [T, S] DEVICE array,
-        prev', rng') — no host sync."""
+    def _slot_schedule(self, reqs, steps: int):
+        """The slot-indexed host schedule of a fused multi-step dispatch
+        (decode burst, speculative burst): reserves each request's blocks
+        for ``steps`` positions and consumes its held token.  Returns (the
+        numpy batch, the live context the dispatch reads: the sum of
+        ``seen_tokens`` over its slots)."""
         S = self.state.max_tracked_sequences
         tokens0 = np.zeros(S, np.int32)
         from_device = np.zeros(S, bool)
@@ -971,6 +847,79 @@ class InferenceEngineV2:
             pos0[sl] = seq.seen_tokens
             bl = np.asarray(seq.blocks, np.int32)
             block_table[sl, :len(bl)] = bl
+        return ({"tokens0": tokens0, "from_device": from_device,
+                 "active": active, "pos0": pos0,
+                 "block_table": block_table}, int(pos0.sum()))
+
+    def _run_spec(self, reqs, outer: int, gamma: int, gen, prev, rng):
+        """One fused draft-and-verify dispatch over the running set, then ONE
+        sync to learn the per-step acceptance counts (the host cannot
+        schedule past a spec burst without them).  Returns
+        (toks [outer, gamma+1, S] np, counts [outer, S] np, prev', rng')."""
+        stel = self.telemetry
+        with stel.span("build"):
+            host, ctx_tokens = self._slot_schedule(reqs, outer * (gamma + 1))
+        with stel.span("h2d"):
+            batch = jax.tree_util.tree_map(jnp.asarray, host)
+        t_begin = stel.now()
+        if gen.do_sample:
+            key = ("spec_rs", outer, gamma, gen.top_k)
+            if key not in self._steps:
+                self._steps[key] = jax.jit(
+                    _named_partial(speculative_burst_sampled,
+                                   cfg=self.model_config,
+                                   draft_cfg=self.draft_config,
+                                   block_size=self._block_size,
+                                   gamma=gamma, steps=outer,
+                                   top_k=gen.top_k, mesh=self.mesh),
+                    donate_argnums=(2, 3))
+            with stel.span("spec_dispatch", steps=outer, gamma=gamma,
+                           seqs=len(reqs), ctx_tokens=ctx_tokens):
+                toks, counts, prev, rng, self.cache, self.draft_cache = \
+                    self._steps[key](self.params, self.draft_params,
+                                     self.cache, self.draft_cache, batch,
+                                     prev, rng, jnp.float32(gen.temperature),
+                                     jnp.float32(gen.top_p))
+            stel.dispatch("spec")
+        else:
+            key = ("spec", outer, gamma)
+            if key not in self._steps:
+                self._steps[key] = jax.jit(
+                    _named_partial(speculative_burst,
+                                   cfg=self.model_config,
+                                   draft_cfg=self.draft_config,
+                                   block_size=self._block_size,
+                                   gamma=gamma, steps=outer,
+                                   mesh=self.mesh),
+                    donate_argnums=(2, 3))
+            with stel.span("spec_dispatch", steps=outer, gamma=gamma,
+                           seqs=len(reqs), ctx_tokens=ctx_tokens):
+                toks, counts, prev, self.cache, self.draft_cache = \
+                    self._steps[key](self.params, self.draft_params,
+                                     self.cache, self.draft_cache, batch,
+                                     prev)
+            stel.dispatch("spec")
+        with stel.span("materialize"):
+            # the host cannot schedule past the burst without the counts —
+            # this is THE disclosed sync of the speculative path
+            toks_h, counts_h = jax.device_get([toks, counts])  # sync-ok
+        emitted = int(np.asarray(counts_h)[
+            :, [self.state.get(r.uid).slot for r in reqs]].sum())
+        stel.spec_burst(outer=outer, n_seqs=len(reqs), gamma=gamma,
+                        emitted=emitted, dur_ms=(stel.now() - t_begin) * 1e3)
+        stel.tokens("spec", emitted)
+        return np.asarray(toks_h), np.asarray(counts_h), prev, rng
+
+    def _run_burst(self, reqs, steps: int, gen, prev, rng):
+        """Fused T-step decode over the running set: one device dispatch for
+        ``steps`` tokens per sequence (see model.ragged_decode_burst).  Each
+        req's first-step token comes from ``held_token`` (host, post-preempt)
+        or from the ``prev`` device feedback vector.  Blocks for all T
+        positions are pre-allocated.  Returns (tokens [T, S] DEVICE array,
+        prev', rng') — no host sync."""
+        stel = self.telemetry
+        with stel.span("build"):
+            host, ctx_tokens = self._slot_schedule(reqs, steps)
         key = ("burst", steps, gen.do_sample, gen.top_k)
         if key not in self._steps:
             self._steps[key] = jax.jit(
@@ -979,16 +928,16 @@ class InferenceEngineV2:
                                sample_fn=self._sample_fn(gen),
                                mesh=self.mesh),
                 donate_argnums=(1,))
-        batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, {
-            "tokens0": tokens0, "from_device": from_device, "active": active,
-            "pos0": pos0, "block_table": block_table}))
-        self.telemetry.dispatch("burst")
-        with self.telemetry.span("burst_dispatch", steps=steps,
-                                 seqs=len(reqs)):
+        with stel.span("h2d"):
+            batch = self._with_lora(
+                jax.tree_util.tree_map(jnp.asarray, host))
+        stel.dispatch("burst")
+        with stel.span("burst_dispatch", steps=steps, seqs=len(reqs),
+                       tokens=steps * len(reqs), ctx_tokens=ctx_tokens):
             toks, prev, rng, self.cache = self._steps[key](
                 self.params, self.cache, batch, prev, rng,
                 jnp.float32(gen.temperature), jnp.float32(gen.top_p))
-        self.telemetry.tokens("decode", steps * len(reqs))
+        stel.tokens("decode", steps * len(reqs))
         for r in reqs:
             self.state.get(r.uid).seen_tokens += steps
         return toks, prev, rng
@@ -1000,133 +949,105 @@ class InferenceEngineV2:
         token feedback — returns (prev', rng'), never touching the host.
         ``from_device`` marks tokens whose VALUE lives in prev[slot] (their
         host entry is a placeholder); ``served_slots`` are the slots whose
-        freshly sampled token must be written into prev'."""
+        freshly sampled token must be written into prev'.
+
+        Three host phases, each a span: ``build`` (block reservation, the
+        numpy schedule, the compile bucket), ``h2d`` (the arrays onto the
+        device) and the ``*_dispatch`` around the jitted call."""
         sm = self.config.state_manager
+        stel = self.telemetry
         S = self.state.max_tracked_sequences
-        schedule = []
-        for uid, toks in zip(uids, toks_np):
-            seq = self.state.get(uid)
-            if seq is None:
-                seq = self.state.create(uid)
-                self._adapter_slot[seq.slot] = 0
-            self.state.ensure_blocks(seq, len(toks))
-            schedule.append((seq, toks))
-        served = np.zeros(S, bool)
-        served[list(served_slots)] = True
-        if max(len(t) for t in toks_np) <= 1:
-            # decode-only: slot-indexed [S] program
-            tokens = np.zeros(S, np.int32)
-            active = np.zeros(S, bool)
-            token_pos = np.zeros(S, np.int32)
-            fdev = np.zeros(S, bool)
-            block_table = np.zeros((S, self.state.max_blocks_per_seq),
-                                   np.int32)
-            for (seq, toks), fd in zip(schedule, from_device):
-                sl = seq.slot
-                tokens[sl] = toks[0]
-                active[sl] = True
-                fdev[sl] = fd
-                token_pos[sl] = seq.seen_tokens
-                bl = np.asarray(seq.blocks, np.int32)
-                block_table[sl, :len(bl)] = bl
-            batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, {
-                "tokens": tokens, "active": active, "token_pos": token_pos,
-                "block_table": block_table, "from_device": fdev,
-                "served": served}))
-            if self._spec_active(gen):
-                # lockstep draft ingestion (see mixed_sd)
-                key = ("decode_sd", gen.do_sample, gen.top_k)
-                if key not in self._steps:
-                    self._steps[key] = jax.jit(
-                        _named_partial(ragged_decode_sampled_draft,
-                                       cfg=self.model_config,
-                                       draft_cfg=self.draft_config,
-                                       block_size=self._block_size,
-                                       sample_fn=self._sample_fn(gen),
-                                       mesh=self.mesh),
-                        donate_argnums=(2, 3))
-                self.telemetry.dispatch("decode")
-                with self.telemetry.span("decode_dispatch",
-                                         seqs=len(schedule), draft=True):
-                    prev, rng, self.cache, self.draft_cache = \
-                        self._steps[key](
-                            self.params, self.draft_params, self.cache,
-                            self.draft_cache, batch, prev, rng,
-                            jnp.float32(gen.temperature),
-                            jnp.float32(gen.top_p))
-                for seq, toks in schedule:
-                    seq.seen_tokens += len(toks)
-                return prev, rng
-            key = ("decode_s", gen.do_sample, gen.top_k)
+        draft = self._spec_active(gen)
+        with stel.span("build"):
+            schedule = []
+            for uid, toks in zip(uids, toks_np):
+                seq = self.state.get(uid)
+                if seq is None:
+                    seq = self.state.create(uid)
+                    self._adapter_slot[seq.slot] = 0
+                self.state.ensure_blocks(seq, len(toks))
+                schedule.append((seq, toks))
+            served = np.zeros(S, bool)
+            served[list(served_slots)] = True
+            # what a reader needs to compute rates without the engine
+            note = {"seqs": len(schedule),
+                    "tokens": sum(len(t) for t in toks_np),
+                    "ctx_tokens": sum(seq.seen_tokens
+                                      for seq, _ in schedule)}
+            if max(len(t) for t in toks_np) <= 1:
+                # decode-only: slot-indexed [S] program
+                kind = "decode"
+                tokens = np.zeros(S, np.int32)
+                active = np.zeros(S, bool)
+                token_pos = np.zeros(S, np.int32)
+                fdev = np.zeros(S, bool)
+                block_table = np.zeros((S, self.state.max_blocks_per_seq),
+                                       np.int32)
+                for (seq, toks), fd in zip(schedule, from_device):
+                    sl = seq.slot
+                    tokens[sl] = toks[0]
+                    active[sl] = True
+                    fdev[sl] = fd
+                    token_pos[sl] = seq.seen_tokens
+                    bl = np.asarray(seq.blocks, np.int32)
+                    block_table[sl, :len(bl)] = bl
+                host = {"tokens": tokens, "active": active,
+                        "token_pos": token_pos, "block_table": block_table,
+                        "from_device": fdev, "served": served}
+                note["bucket"] = S
+                shape_key, static = (), {}
+            else:
+                kind = "mixed"
+                rb = build_ragged_batch(schedule, self.state,
+                                        sm.max_ragged_batch_size,
+                                        sm.max_q_per_seq)
+                fdev = np.zeros(rb.tokens.shape[0], bool)
+                i = 0
+                for (seq, toks), fd in zip(schedule, from_device):
+                    fdev[i:i + len(toks)] = fd
+                    i += len(toks)
+                mb, nb = self._buckets(rb)
+                stel.padding_waste(rb.total_tokens, nb)
+                host = {"tokens": rb.tokens[:nb],
+                        "token_slot": rb.token_slot[:nb],
+                        "token_pos": rb.token_pos[:nb],
+                        "token_dense_idx": rb.token_dense_idx[:nb],
+                        "block_table": rb.block_table[:, :mb],
+                        "kv_len": rb.kv_len, "from_device": fdev[:nb],
+                        "served": served}
+                note["bucket"] = nb
+                shape_key = (sm.max_q_per_seq, mb)
+                static = {"max_q_per_seq": sm.max_q_per_seq}
+            # with a draft loaded it ingests every token in lockstep (dual
+            # prefill and decode), so speculative acceptance has something
+            # to work with; draft staleness can't affect correctness
+            key = ((f"{kind}_sd" if draft else f"{kind}_s",) + shape_key
+                   + (gen.do_sample, gen.top_k))
             if key not in self._steps:
+                if draft:
+                    static["draft_cfg"] = self.draft_config
                 self._steps[key] = jax.jit(
-                    _named_partial(ragged_decode_sampled,
+                    _named_partial(_STEP_PROGRAMS[kind, draft],
                                    cfg=self.model_config,
                                    block_size=self._block_size,
                                    sample_fn=self._sample_fn(gen),
-                                   mesh=self.mesh),
-                    donate_argnums=(1,))
+                                   mesh=self.mesh, **static),
+                    donate_argnums=(2, 3) if draft else (1,))
+        with stel.span("h2d"):
+            batch = self._with_lora(
+                jax.tree_util.tree_map(jnp.asarray, host))
+        stel.dispatch(kind)
+        if draft:
+            with stel.span(f"{kind}_dispatch", draft=True, **note):
+                prev, rng, self.cache, self.draft_cache = self._steps[key](
+                    self.params, self.draft_params, self.cache,
+                    self.draft_cache, batch, prev, rng,
+                    jnp.float32(gen.temperature), jnp.float32(gen.top_p))
         else:
-            rb = build_ragged_batch(schedule, self.state,
-                                    sm.max_ragged_batch_size, sm.max_q_per_seq)
-            fdev = np.zeros(rb.tokens.shape[0], bool)
-            i = 0
-            for (seq, toks), fd in zip(schedule, from_device):
-                fdev[i:i + len(toks)] = fd
-                i += len(toks)
-            mb, nb = self._buckets(rb)
-            self.telemetry.padding_waste(rb.total_tokens, nb)
-            batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, {
-                "tokens": rb.tokens[:nb], "token_slot": rb.token_slot[:nb],
-                "token_pos": rb.token_pos[:nb],
-                "token_dense_idx": rb.token_dense_idx[:nb],
-                "block_table": rb.block_table[:, :mb], "kv_len": rb.kv_len,
-                "from_device": fdev[:nb], "served": served}))
-            if self._spec_active(gen):
-                # dual prefill: the draft ingests every prompt chunk in
-                # lockstep so speculative acceptance has something to work
-                # with (draft staleness can't affect correctness)
-                key = ("mixed_sd", sm.max_q_per_seq, mb, gen.do_sample,
-                       gen.top_k)
-                if key not in self._steps:
-                    self._steps[key] = jax.jit(
-                        _named_partial(ragged_forward_sampled_draft,
-                                       cfg=self.model_config,
-                                       draft_cfg=self.draft_config,
-                                       block_size=self._block_size,
-                                       max_q_per_seq=sm.max_q_per_seq,
-                                       sample_fn=self._sample_fn(gen),
-                                       mesh=self.mesh),
-                        donate_argnums=(2, 3))
-                self.telemetry.dispatch("mixed")
-                with self.telemetry.span("mixed_dispatch",
-                                         tokens=rb.total_tokens, bucket=nb,
-                                         seqs=len(schedule), draft=True):
-                    prev, rng, self.cache, self.draft_cache = \
-                        self._steps[key](
-                            self.params, self.draft_params, self.cache,
-                            self.draft_cache, batch, prev, rng,
-                            jnp.float32(gen.temperature),
-                            jnp.float32(gen.top_p))
-                for seq, toks in schedule:
-                    seq.seen_tokens += len(toks)
-                return prev, rng
-            key = ("mixed_s", sm.max_q_per_seq, mb, gen.do_sample, gen.top_k)
-            if key not in self._steps:
-                self._steps[key] = jax.jit(
-                    _named_partial(ragged_forward_sampled,
-                                   cfg=self.model_config,
-                                   block_size=self._block_size,
-                                   max_q_per_seq=sm.max_q_per_seq,
-                                   sample_fn=self._sample_fn(gen),
-                                   mesh=self.mesh),
-                    donate_argnums=(1,))
-        kind = "decode" if key[0] == "decode_s" else "mixed"
-        self.telemetry.dispatch(kind)
-        with self.telemetry.span(f"{kind}_dispatch", seqs=len(schedule)):
-            prev, rng, self.cache = self._steps[key](
-                self.params, self.cache, batch, prev, rng,
-                jnp.float32(gen.temperature), jnp.float32(gen.top_p))
+            with stel.span(f"{kind}_dispatch", **note):
+                prev, rng, self.cache = self._steps[key](
+                    self.params, self.cache, batch, prev, rng,
+                    jnp.float32(gen.temperature), jnp.float32(gen.top_p))
         for seq, toks in schedule:
             seq.seen_tokens += len(toks)
         return prev, rng
@@ -1528,26 +1449,27 @@ class InferenceEngineV2:
             steps_since_sync = 0
             if not records:
                 return
-            arrs = jax.device_get([rec[1] for rec in records])
-            for rec, arr in zip(records, arrs):
-                if rec[0] == "step":
-                    for uid, sl in rec[2]:
-                        _append(results[uid], [arr[sl]])
-                else:
-                    for uid, sl in rec[2]:
-                        _append(results[uid], arr[:, sl])
-            records.clear()
-            for r in list(running):
-                if r.done:                      # EOS found on materialize
-                    self.flush([r.uid])
-                    running.remove(r)
-                    pending_finish.append(r)
-            # retired requests reach their final .generated here (their
-            # pending device records just resolved) — record them into the
-            # serving telemetry now, when the token count is exact
-            for r in pending_finish:
-                self._finish_request(r)
-            pending_finish.clear()
+            with stel.span("materialize", records=len(records)):
+                arrs = jax.device_get([rec[1] for rec in records])
+                for rec, arr in zip(records, arrs):
+                    if rec[0] == "step":
+                        for uid, sl in rec[2]:
+                            _append(results[uid], [arr[sl]])
+                    else:
+                        for uid, sl in rec[2]:
+                            _append(results[uid], arr[:, sl])
+                records.clear()
+                for r in list(running):
+                    if r.done:                  # EOS found on materialize
+                        self.flush([r.uid])
+                        running.remove(r)
+                        pending_finish.append(r)
+                # retired requests reach their final .generated here (their
+                # pending device records just resolved) — record them into
+                # the serving telemetry now, when the token count is exact
+                for r in pending_finish:
+                    self._finish_request(r)
+                pending_finish.clear()
 
         def preempt(victim: _Request, reason: str) -> None:
             """Recompute-preempt one RUNNING request (the vLLM/FastGen
@@ -1587,418 +1509,443 @@ class InferenceEngineV2:
             waiting.insert(0, victim)
 
         burst_sizes = (64, 32, 16, 8)
+        n_round = 0
         while waiting or running or incoming:
-            # ---- fleet hooks, once per scheduler round: the chaos site a
-            # replica death injects through (kind@replica.mid_decode), the
-            # liveness beat the supervisor deadlines on, and the drain latch
-            faults.fire("replica.mid_decode")
-            if self.heartbeat_fn is not None:
-                self.heartbeat_fn()
-            if self._drain_requested.is_set():
-                # serving drain (PR 6 semantics applied to requests instead
-                # of optimizer state): materialize so .generated is exact,
-                # free every live sequence, and hand the unfinished set to
-                # export_pending_requests() for migration
-                materialize()
-                for r in list(running):
-                    self.state.flush(r.uid)
-                raise EngineDrained(
-                    f"drain requested: {len(running)} running + "
-                    f"{len(waiting) + len(incoming)} queued request(s) "
-                    f"exported for migration")
-            now = now_fn()
-            while incoming and incoming[0].t_arrival <= now:
-                waiting.append(incoming.pop(0))
-            if not waiting and not running:
-                # open-loop idle: everything in flight is done and the next
-                # request hasn't arrived — flush pending records, then sleep
-                # to the next arrival (a fake now_fn just re-polls: it must
-                # advance on its own)
-                materialize()
-                if now_fn is stel.now:
-                    import time as _time
-                    _time.sleep(max(0.0, incoming[0].t_arrival - now_fn()))
-                continue
-            stel.kv_sample(self.state)
-            stel.occupancy(len(running), S)
-            # ---- SLA-aware admission order + preemption.  Waiting sorts
-            # by priority (stable: FIFO within a class, and a preemption
-            # victim re-queued at the front keeps resuming first among its
-            # peers).  When the head has burned preempt_margin of its TTFT
-            # SLO and STILL cannot be admitted — no sequence slot, or no
-            # blocks even counting cache-evictable ones — the most recently
-            # admitted lower-priority running request is recompute-preempted
-            # for it (the policy behind serving_preemptions_total).
-            if has_sla and waiting:
-                waiting.sort(key=lambda r: -r.priority)
-                head = waiting[0]
-                lows = [r for r in running if r.priority < head.priority]
-                at_risk = (sched_cfg.sla_preempt and head.ttft_slo_ms > 0
-                           and (now - head.t_arrival) * 1e3
-                           >= sched_cfg.preempt_margin * head.ttft_slo_ms)
-                if lows and at_risk:
-                    m, pin = self.state.peek_prefix_pinned(head.prompt)
-                    # mirror the admission loop's chunk sizing exactly — a
-                    # probe sized to max_q_per_seq would preempt a victim
-                    # in rounds where the configured (smaller) chunk is
-                    # perfectly admissible
-                    first = min(len(head.prompt) - m, sm.max_q_per_seq,
-                                sm.max_ragged_batch_size,
-                                sm.prefill_chunk_tokens
-                                or sm.max_ragged_batch_size)
-                    need = (-(-(m + first) // self.state.block_size)
-                            - m // self.state.block_size + pin)
-                    if (self.state.free_sequence_slots == 0
-                            or need > self.state.available_blocks):
-                        if records:
-                            materialize()   # exact .generated at the fold
-                            continue        # (retirements may change sets)
-                        low_p = min(r.priority for r in lows)
-                        victim = [r for r in lows if r.priority == low_p][-1]
-                        stel.admission(head.sla, decision="preempted_for")
-                        preempt(victim, "sla")
+            n_round += 1
+            with _Round(stel, n=n_round, running=len(running),
+                        waiting=len(waiting), incoming=len(incoming),
+                        slots=S, host_ns=time.perf_counter_ns()) as rnd:
+                # ---- gate: the fleet hooks, once per scheduler round (the
+                # chaos site a replica death injects through,
+                # kind@replica.mid_decode; the liveness beat the supervisor
+                # deadlines on; the drain latch), then arrivals -> waiting
+                now = now_fn()
+                due = 0
+                while due < len(incoming) and incoming[due].t_arrival <= now:
+                    due += 1
+                rnd.phase("gate", released=due, late_ms_max=(
+                    (now - incoming[0].t_arrival) * 1e3 if due else 0.0))
+                faults.fire("replica.mid_decode")
+                if self.heartbeat_fn is not None:
+                    self.heartbeat_fn()
+                if self._drain_requested.is_set():
+                    # serving drain (PR 6 semantics applied to requests instead
+                    # of optimizer state): materialize so .generated is exact,
+                    # free every live sequence, and hand the unfinished set to
+                    # export_pending_requests() for migration
+                    materialize()
+                    for r in list(running):
+                        self.state.flush(r.uid)
+                    raise EngineDrained(
+                        f"drain requested: {len(running)} running + "
+                        f"{len(waiting) + len(incoming)} queued request(s) "
+                        f"exported for migration")
+                waiting.extend(incoming[:due])
+                del incoming[:due]
+                if not waiting and not running:
+                    # open-loop idle: everything in flight is done and the
+                    # next request hasn't arrived — flush pending records,
+                    # then sleep to the next arrival (a fake now_fn just
+                    # re-polls: it must advance on its own)
+                    rnd.phase("idle_sleep")
+                    materialize()
+                    if now_fn is stel.now:
+                        time.sleep(max(0.0,
+                                       incoming[0].t_arrival - now_fn()))
+                    continue
+                # ---- admit: pool gauges, SLA order and preemption, the
+                # fast-path decisions and the three scheduling passes
+                rnd.phase("admit")
+                stel.kv_sample(self.state)
+                stel.occupancy(len(running), S)
+                # ---- SLA-aware admission order + preemption.  Waiting sorts
+                # by priority (stable: FIFO within a class, and a preemption
+                # victim re-queued at the front keeps resuming first among its
+                # peers).  When the head has burned preempt_margin of its TTFT
+                # SLO and STILL cannot be admitted — no sequence slot, or no
+                # blocks even counting cache-evictable ones — the most recently
+                # admitted lower-priority running request is recompute-preempted
+                # for it (the policy behind serving_preemptions_total).
+                if has_sla and waiting:
+                    waiting.sort(key=lambda r: -r.priority)
+                    head = waiting[0]
+                    lows = [r for r in running if r.priority < head.priority]
+                    at_risk = (sched_cfg.sla_preempt and head.ttft_slo_ms > 0
+                               and (now - head.t_arrival) * 1e3
+                               >= sched_cfg.preempt_margin * head.ttft_slo_ms)
+                    if lows and at_risk:
+                        m, pin = self.state.peek_prefix_pinned(head.prompt)
+                        # mirror the admission loop's chunk sizing exactly — a
+                        # probe sized to max_q_per_seq would preempt a victim
+                        # in rounds where the configured (smaller) chunk is
+                        # perfectly admissible
+                        first = min(len(head.prompt) - m, sm.max_q_per_seq,
+                                    sm.max_ragged_batch_size,
+                                    sm.prefill_chunk_tokens
+                                    or sm.max_ragged_batch_size)
+                        need = (-(-(m + first) // self.state.block_size)
+                                - m // self.state.block_size + pin)
+                        if (self.state.free_sequence_slots == 0
+                                or need > self.state.available_blocks):
+                            if records:
+                                materialize()   # exact .generated at the fold
+                                continue        # (retirements may change sets)
+                            low_p = min(r.priority for r in lows)
+                            victim = [r for r in lows if r.priority == low_p][-1]
+                            stel.admission(head.sla, decision="preempted_for")
+                            preempt(victim, "sla")
+                            continue
+                # ---- speculative draft-and-verify fast path: same eligibility
+                # as the decode burst, preferred when a draft is loaded and
+                # decoding is greedy.  Each outer step yields 1..gamma+1 tokens
+                # per slot; the host syncs after the burst (it cannot schedule
+                # without the acceptance counts), which also materializes EOS.
+                if (self._spec_active(gen) and running
+                        and (not waiting or self.state.free_sequence_slots == 0)
+                        and all(r.decode_ready and not r.done for r in running)
+                        and all(not self.state.get(r.uid).in_flight
+                                for r in running)):
+                    sp = self.config.speculative
+                    worst = sp.gamma + 1            # tokens per outer step, max
+                    n_before = len(running)
+                    materialize()       # keep .generated chronological
+                    if len(running) != n_before:
+                        continue        # EOS retirements changed the set (maybe
+                        # to empty) — recompute eligibility and sizing
+                    # batched mode: the whole running set in one dispatch.
+                    # Per-request baseline (batch_across_requests=False): one
+                    # dispatch per request through the SAME slot-wide program —
+                    # a request finishing mid-round simply drops out of later
+                    # groups; inactive lanes pass prev through, so the token
+                    # stream is identical either way
+                    groups = ([list(running)] if sp.batch_across_requests
+                              else [[r] for r in list(running)])
+                    ran_any = False
+                    for grp in groups:
+                        rnd.phase("admit")
+                        grp = [r for r in grp if r in running]
+                        if not grp:
+                            continue
+                        need_max = max(r.max_new_tokens - r.sampled for r in grp)
+                        cap = min(self.model_config.max_seq_len
+                                  - self.state.get(r.uid).seen_tokens
+                                  for r in grp)
+                        # size for ~half acceptance (2x the full-acceptance
+                        # need), then round DOWN to a power of two so the
+                        # compile cache holds at most log2(outer_steps) spec
+                        # programs
+                        outer = min(sp.outer_steps, 2 * -(-need_max // worst),
+                                    cap // worst)
+                        if outer >= 1:
+                            outer = 1 << (outer.bit_length() - 1)
+                        while outer >= 1:
+                            need = sum(self.state.get(r.uid).kv_blocks_needed(
+                                outer * worst, self.state.block_size)
+                                for r in grp)
+                            if need <= self.state.available_blocks:
+                                break
+                            outer //= 2
+                        if outer < 1:
+                            continue
+                        ran_any = True
+                        pairs = [(r.uid, self.state.get(r.uid).slot)
+                                 for r in grp]
+                        rnd.phase(None)     # build / h2d / dispatch / sync
+                        toks_h, counts_h, prev, rng = self._run_spec(
+                            grp, outer, sp.gamma, gen, prev, rng)
+                        rnd.phase("retire")
+                        tnow = now_fn()     # _run_spec synced: completion time
+                        for r, (uid, sl) in zip(list(grp), pairs):
+                            total = int(counts_h[:, sl].sum())
+                            self.state.get(uid).seen_tokens += total
+                            vals = []
+                            for k in range(outer):
+                                c = int(counts_h[k, sl])
+                                vals.extend(int(t) for t in toks_h[k, :c, sl])
+                            _append(r, vals)
+                            r.sampled += total
+                            if total:
+                                if r.t_first is None:
+                                    r.t_first = tnow
+                                r.t_last = tnow
+                            if r.done or r.sampled >= r.max_new_tokens:
+                                r.done = True
+                                self.flush([r.uid])
+                                running.remove(r)
+                                self._finish_request(r)
+                    if ran_any:
                         continue
-            # ---- speculative draft-and-verify fast path: same eligibility
-            # as the decode burst, preferred when a draft is loaded and
-            # decoding is greedy.  Each outer step yields 1..gamma+1 tokens
-            # per slot; the host syncs after the burst (it cannot schedule
-            # without the acceptance counts), which also materializes EOS.
-            if (self._spec_active(gen) and running
-                    and (not waiting or self.state.free_sequence_slots == 0)
-                    and all(r.decode_ready and not r.done for r in running)
-                    and all(not self.state.get(r.uid).in_flight
-                            for r in running)):
-                sp = self.config.speculative
-                worst = sp.gamma + 1            # tokens per outer step, max
-                n_before = len(running)
-                materialize()                   # keep .generated chronological
-                if len(running) != n_before:
-                    continue        # EOS retirements changed the set (maybe
-                    # to empty) — recompute eligibility and sizing
-                # batched mode: the whole running set in one dispatch.
-                # Per-request baseline (batch_across_requests=False): one
-                # dispatch per request through the SAME slot-wide program —
-                # a request finishing mid-round simply drops out of later
-                # groups; inactive lanes pass prev through, so the token
-                # stream is identical either way
-                groups = ([list(running)] if sp.batch_across_requests
-                          else [[r] for r in list(running)])
-                ran_any = False
-                for grp in groups:
-                    grp = [r for r in grp if r in running]
-                    if not grp:
-                        continue
-                    need_max = max(r.max_new_tokens - r.sampled for r in grp)
+
+                # ---- decode-burst fast path: every running sequence is in pure
+                # decode and no slot is admittable -> fuse T steps into one
+                # dispatch.  With requests WAITING the burst targets the earliest
+                # retirement (free a slot, then admit); otherwise it covers the
+                # longest remaining budget (finish everyone).  Sequences that
+                # finish mid-burst cost nothing extra — the burst computes all
+                # slots every step — and their overshoot tokens are discarded at
+                # materialize.  Disabled while speculation is active: the plain
+                # burst would advance the target without the draft, leaving
+                # permanent draft-cache holes (single steps stay dual-model).
+                if (running and not self._spec_active(gen)
+                        and (not waiting or self.state.free_sequence_slots == 0)
+                        and all(r.decode_ready and not r.done for r in running)
+                        and all(not self.state.get(r.uid).in_flight
+                                for r in running)):
+                    rem_max = max(r.max_new_tokens - r.sampled for r in running)
+                    if waiting:
+                        # earliest retirement frees a slot — but floor the burst
+                        # so retirements CLUMP and the freed slots refill in
+                        # one fat admission step instead of one step per slot
+                        rem_min = min(r.max_new_tokens - r.sampled
+                                      for r in running)
+                        need_max = max(rem_min, min(16, rem_max))
+                    else:
+                        need_max = rem_max
+                    if sync_interval:
+                        # budget the burst against the NEXT materialize point so
+                        # EOS overshoot stays ~sync_interval (plus at most the
+                        # smallest compiled burst), not 2x
+                        need_max = min(need_max,
+                                       max(1, sync_interval - steps_since_sync))
                     cap = min(self.model_config.max_seq_len
                               - self.state.get(r.uid).seen_tokens
-                              for r in grp)
-                    # size for ~half acceptance (2x the full-acceptance
-                    # need), then round DOWN to a power of two so the
-                    # compile cache holds at most log2(outer_steps) spec
-                    # programs
-                    outer = min(sp.outer_steps, 2 * -(-need_max // worst),
-                                cap // worst)
-                    if outer >= 1:
-                        outer = 1 << (outer.bit_length() - 1)
-                    while outer >= 1:
+                              for r in running)
+                    target = min(need_max, cap)
+                    fitting = [b for b in burst_sizes if b <= cap]
+                    covering = [b for b in fitting if b >= target]
+                    T = (min(covering) if covering
+                         else (max(fitting) if fitting else 0))
+                    # shrink the burst until its block reservation fits the pool
+                    while T >= burst_sizes[-1]:
                         need = sum(self.state.get(r.uid).kv_blocks_needed(
-                            outer * worst, self.state.block_size)
-                            for r in grp)
+                            T, self.state.block_size) for r in running)
                         if need <= self.state.available_blocks:
                             break
-                        outer //= 2
-                    if outer < 1:
-                        continue
-                    ran_any = True
-                    pairs = [(r.uid, self.state.get(r.uid).slot)
-                             for r in grp]
-                    toks_h, counts_h, prev, rng = self._run_spec(
-                        grp, outer, sp.gamma, gen, prev, rng)
-                    tnow = now_fn()     # _run_spec synced: completion time
-                    for r, (uid, sl) in zip(list(grp), pairs):
-                        total = int(counts_h[:, sl].sum())
-                        self.state.get(uid).seen_tokens += total
-                        vals = []
-                        for k in range(outer):
-                            c = int(counts_h[k, sl])
-                            vals.extend(int(t) for t in toks_h[k, :c, sl])
-                        _append(r, vals)
-                        r.sampled += total
-                        if total:
+                        T //= 2
+                    if T >= burst_sizes[-1]:
+                        pairs = [(r.uid, self.state.get(r.uid).slot)
+                                 for r in running]
+                        rnd.phase(None)     # build / h2d / burst_dispatch
+                        toks, prev, rng = self._run_burst(running, T, gen,
+                                                          prev, rng)
+                        if stream:
+                            rnd.phase("fence")
+                            self._stream_fence(prev)
+                        rnd.phase("retire")
+                        tnow = now_fn()
+                        records.append(("burst", toks, pairs, T))
+                        for r in list(running):
+                            r.sampled += T
                             if r.t_first is None:
+                                # first token mid-burst: stamped at burst end
+                                # (bursts only run once every slot is decode-
+                                # ready, so in practice t_first predates them)
                                 r.t_first = tnow
                             r.t_last = tnow
-                        if r.done or r.sampled >= r.max_new_tokens:
-                            r.done = True
-                            self.flush([r.uid])
-                            running.remove(r)
-                            self._finish_request(r)
-                if ran_any:
-                    continue
+                            if r.sampled >= r.max_new_tokens:
+                                r.done = True       # finish recorded at the
+                                self.flush([r.uid])  # next materialize (records
+                                running.remove(r)    # still hold its tokens)
+                                pending_finish.append(r)
+                        steps_since_sync += T
+                        if sync_interval and steps_since_sync >= sync_interval:
+                            materialize()
+                        continue
 
-            # ---- decode-burst fast path: every running sequence is in pure
-            # decode and no slot is admittable -> fuse T steps into one
-            # dispatch.  With requests WAITING the burst targets the earliest
-            # retirement (free a slot, then admit); otherwise it covers the
-            # longest remaining budget (finish everyone).  Sequences that
-            # finish mid-burst cost nothing extra — the burst computes all
-            # slots every step — and their overshoot tokens are discarded at
-            # materialize.  Disabled while speculation is active: the plain
-            # burst would advance the target without the draft, leaving
-            # permanent draft-cache holes (single steps stay dual-model).
-            if (running and not self._spec_active(gen)
-                    and (not waiting or self.state.free_sequence_slots == 0)
-                    and all(r.decode_ready and not r.done for r in running)
-                    and all(not self.state.get(r.uid).in_flight
-                            for r in running)):
-                rem_max = max(r.max_new_tokens - r.sampled for r in running)
-                if waiting:
-                    # earliest retirement frees a slot — but floor the burst
-                    # so retirements CLUMP and the freed slots are refilled by
-                    # one fat admission step instead of one step per slot
-                    rem_min = min(r.max_new_tokens - r.sampled
-                                  for r in running)
-                    need_max = max(rem_min, min(16, rem_max))
-                else:
-                    need_max = rem_max
-                if sync_interval:
-                    # budget the burst against the NEXT materialize point so
-                    # EOS overshoot stays ~sync_interval (plus at most the
-                    # smallest compiled burst), not 2x
-                    need_max = min(need_max,
-                                   max(1, sync_interval - steps_since_sync))
-                cap = min(self.model_config.max_seq_len
-                          - self.state.get(r.uid).seen_tokens
-                          for r in running)
-                target = min(need_max, cap)
-                fitting = [b for b in burst_sizes if b <= cap]
-                covering = [b for b in fitting if b >= target]
-                T = (min(covering) if covering
-                     else (max(fitting) if fitting else 0))
-                # shrink the burst until its block reservation fits the pool
-                while T >= burst_sizes[-1]:
-                    need = sum(self.state.get(r.uid).kv_blocks_needed(
-                        T, self.state.block_size) for r in running)
-                    if need <= self.state.available_blocks:
+                budget = sm.max_ragged_batch_size
+                seq_budget = sm.max_ragged_sequence_count   # per-step seq cap
+                # SplitFuse chunk bound: prompt-chunk tokens co-scheduled with
+                # decode this round — keeps the mixed dispatch short so live
+                # decoders' TPOT stays flat under long-prompt load
+                prefill_budget = (sm.prefill_chunk_tokens
+                                  if sm.prefill_chunk_tokens else budget)
+                sched_uids: List[int] = []
+                sched_toks: List[np.ndarray] = []
+                sched_fdev: List[bool] = []
+                served_slots: List[int] = []
+                sampled_now: List[_Request] = []
+                newly_ready: List[_Request] = []    # prefill completes this step
+                n_decode_toks = n_prefill_toks = 0
+
+                # 1) running decodes: one token each (decode-priority keeps
+                #    latency flat while prompts stream in)
+                for r in running:
+                    seq = self.state.get(r.uid)
+                    # a resumed request may be decode-ready while its re-prefill
+                    # is still chunked in (in_flight) — its decode must wait
+                    if r.done or not r.decode_ready or seq.in_flight:
+                        continue
+                    if budget <= 0 or len(sched_uids) >= seq_budget:
                         break
-                    T //= 2
-                if T >= burst_sizes[-1]:
-                    pairs = [(r.uid, self.state.get(r.uid).slot)
-                             for r in running]
-                    toks, prev, rng = self._run_burst(running, T, gen,
-                                                      prev, rng)
-                    if stream:
-                        self._stream_fence(prev)
-                    tnow = now_fn()
-                    records.append(("burst", toks, pairs, T))
-                    for r in list(running):
-                        r.sampled += T
-                        if r.t_first is None:
-                            # first token mid-burst: stamped at burst end
-                            # (bursts only run once every slot is decode-
-                            # ready, so in practice t_first predates them)
-                            r.t_first = tnow
-                        r.t_last = tnow
-                        if r.sampled >= r.max_new_tokens:
-                            r.done = True       # finish recorded at the
-                            self.flush([r.uid])  # next materialize (records
-                            running.remove(r)    # still hold its tokens)
-                            pending_finish.append(r)
-                    steps_since_sync += T
-                    if sync_interval and steps_since_sync >= sync_interval:
-                        materialize()
-                    continue
+                    # reserve the block NOW (allocator state advances with each
+                    # reservation, so later checks see the true remaining pool);
+                    # a decode that can't get a block defers to a later round
+                    need = seq.kv_blocks_needed(1, self.state.block_size)
+                    if need and need > self.state.available_blocks:
+                        stel.alloc_failure("decode")
+                        continue
+                    self.state.ensure_blocks(seq, 1)
+                    sched_uids.append(r.uid)
+                    if r.held_token is not None:    # post-preempt continuation
+                        sched_toks.append(np.asarray([r.held_token], np.int32))
+                        sched_fdev.append(False)
+                        r.held_token = None
+                    else:                           # device feedback
+                        sched_toks.append(np.zeros(1, np.int32))
+                        sched_fdev.append(True)
+                    served_slots.append(seq.slot)
+                    sampled_now.append(r)
+                    budget -= 1
+                    n_decode_toks += 1
 
-            budget = sm.max_ragged_batch_size
-            seq_budget = sm.max_ragged_sequence_count   # per-step seq cap
-            # SplitFuse chunk bound: prompt-chunk tokens co-scheduled with
-            # decode this round — keeps the mixed dispatch short so live
-            # decoders' TPOT stays flat under long-prompt load
-            prefill_budget = (sm.prefill_chunk_tokens
-                              if sm.prefill_chunk_tokens else budget)
-            sched_uids: List[int] = []
-            sched_toks: List[np.ndarray] = []
-            sched_fdev: List[bool] = []
-            served_slots: List[int] = []
-            sampled_now: List[_Request] = []
-            newly_ready: List[_Request] = []    # prefill completes this step
-            n_decode_toks = n_prefill_toks = 0
-
-            # 1) running decodes: one token each (decode-priority keeps
-            #    latency flat while prompts stream in)
-            for r in running:
-                seq = self.state.get(r.uid)
-                # a resumed request may be decode-ready while its re-prefill
-                # is still chunked in (in_flight) — its decode must wait
-                if r.done or not r.decode_ready or seq.in_flight:
-                    continue
-                if budget <= 0 or len(sched_uids) >= seq_budget:
-                    break
-                # reserve the block NOW (allocator state advances with each
-                # reservation, so later checks see the true remaining pool);
-                # a decode that can't get a block defers to a later round
-                need = seq.kv_blocks_needed(1, self.state.block_size)
-                if need and need > self.state.available_blocks:
-                    stel.alloc_failure("decode")
-                    continue
-                self.state.ensure_blocks(seq, 1)
-                sched_uids.append(r.uid)
-                if r.held_token is not None:    # post-preempt continuation
-                    sched_toks.append(np.asarray([r.held_token], np.int32))
+                # 2) prompt chunks fill the rest (running first, then admit new),
+                #    bounded by the SplitFuse prefill_budget
+                for r in list(running):
+                    seq = self.state.get(r.uid)
+                    if (seq is None or not seq.in_flight or budget <= 0
+                            or prefill_budget <= 0
+                            or len(sched_uids) >= seq_budget):
+                        continue
+                    chunk = min(len(seq.pending), sm.max_q_per_seq, budget,
+                                prefill_budget)
+                    need = seq.kv_blocks_needed(chunk, self.state.block_size)
+                    if need and need > self.state.available_blocks:
+                        stel.alloc_failure("prompt_chunk")
+                        continue
+                    self.state.ensure_blocks(seq, chunk)
+                    toks, seq.pending = seq.pending[:chunk], seq.pending[chunk:]
+                    sched_uids.append(r.uid)
+                    sched_toks.append(toks)
                     sched_fdev.append(False)
-                    r.held_token = None
-                else:                           # device feedback
-                    sched_toks.append(np.zeros(1, np.int32))
-                    sched_fdev.append(True)
-                served_slots.append(seq.slot)
-                sampled_now.append(r)
-                budget -= 1
-                n_decode_toks += 1
+                    n_prefill_toks += chunk
+                    stel.prefill_chunk()
+                    prefill_budget -= chunk
+                    if not seq.in_flight:       # prompt complete -> decode next
+                        r.decode_ready = True
+                        newly_ready.append(r)
+                        if r.resume:
+                            r.resume = False    # continuation token already held
+                        else:
+                            served_slots.append(seq.slot)
+                            sampled_now.append(r)
+                    budget -= chunk
 
-            # 2) prompt chunks fill the rest (running first, then admit new),
-            #    bounded by the SplitFuse prefill_budget
-            for r in list(running):
-                seq = self.state.get(r.uid)
-                if (seq is None or not seq.in_flight or budget <= 0
-                        or prefill_budget <= 0
-                        or len(sched_uids) >= seq_budget):
-                    continue
-                chunk = min(len(seq.pending), sm.max_q_per_seq, budget,
-                            prefill_budget)
-                need = seq.kv_blocks_needed(chunk, self.state.block_size)
-                if need and need > self.state.available_blocks:
-                    stel.alloc_failure("prompt_chunk")
-                    continue
-                self.state.ensure_blocks(seq, chunk)
-                toks, seq.pending = seq.pending[:chunk], seq.pending[chunk:]
-                sched_uids.append(r.uid)
-                sched_toks.append(toks)
-                sched_fdev.append(False)
-                n_prefill_toks += chunk
-                stel.prefill_chunk()
-                prefill_budget -= chunk
-                if not seq.in_flight:       # prompt complete -> decode next
-                    r.decode_ready = True
-                    newly_ready.append(r)
-                    if r.resume:
-                        r.resume = False    # continuation token already held
-                    else:
-                        served_slots.append(seq.slot)
-                        sampled_now.append(r)
-                budget -= chunk
-
-            while (waiting and budget > 0 and prefill_budget > 0
-                   and self.state.free_sequence_slots
-                   and len(sched_uids) < seq_budget):
-                r = waiting[0]
-                # radix prefix match FIRST (matching acquires the cached
-                # blocks, pinning them against eviction), THEN size and
-                # check the uncached suffix: after the match both the
-                # block need (kv_blocks_needed off the match boundary) and
-                # the supply (available_blocks no longer counts the pinned
-                # nodes) are exact, so an admitted request can never hit
-                # "KV cache exhausted" inside ensure_blocks.  On a
-                # shortfall the match is rolled back (flush releases the
-                # acquired holds) and the request retries next round.
-                waiting.pop(0)
-                seq = self.state.create(r.uid)
-                seq.host_tokens = r.prompt
-                matched = self.state.match_prefix(seq, r.prompt)
-                if self.adapters is not None:
-                    # adapter residency BEFORE sizing: the load may consume
-                    # free blocks (spilling cold adapters, then radix
-                    # leaves), and the block check below must see the pool
-                    # as it will be when the chunk dispatches.  A load the
-                    # pool cannot fit RIGHT NOW (every page pinned by
-                    # in-flight work) rolls back like a block shortfall and
-                    # retries when a retirement releases pins.
-                    try:
-                        self.state.ensure_adapters([r.adapter])
-                    except RuntimeError:
-                        stel.alloc_failure("adapter_load")
+                while (waiting and budget > 0 and prefill_budget > 0
+                       and self.state.free_sequence_slots
+                       and len(sched_uids) < seq_budget):
+                    r = waiting[0]
+                    # radix prefix match FIRST (matching acquires the cached
+                    # blocks, pinning them against eviction), THEN size and
+                    # check the uncached suffix: after the match both the
+                    # block need (kv_blocks_needed off the match boundary) and
+                    # the supply (available_blocks no longer counts the pinned
+                    # nodes) are exact, so an admitted request can never hit
+                    # "KV cache exhausted" inside ensure_blocks.  On a
+                    # shortfall the match is rolled back (flush releases the
+                    # acquired holds) and the request retries next round.
+                    waiting.pop(0)
+                    seq = self.state.create(r.uid)
+                    seq.host_tokens = r.prompt
+                    matched = self.state.match_prefix(seq, r.prompt)
+                    if self.adapters is not None:
+                        # adapter residency BEFORE sizing: the load may consume
+                        # free blocks (spilling cold adapters, then radix
+                        # leaves), and the block check below must see the pool
+                        # as it will be when the chunk dispatches.  A load the
+                        # pool cannot fit RIGHT NOW (every page pinned by
+                        # in-flight work) rolls back like a block shortfall and
+                        # retries when a retirement releases pins.
+                        try:
+                            self.state.ensure_adapters([r.adapter])
+                        except RuntimeError:
+                            stel.alloc_failure("adapter_load")
+                            self.state.flush(r.uid)
+                            waiting.insert(0, r)
+                            break
+                        self.state.bind_adapter(seq, r.adapter)
+                        self._adapter_slot[seq.slot] = \
+                            self.adapters.slot_of(r.adapter)
+                    chunk = min(len(r.prompt) - matched, sm.max_q_per_seq,
+                                budget, prefill_budget)
+                    need = seq.kv_blocks_needed(chunk, self.state.block_size)
+                    if need > self.state.available_blocks:
+                        stel.alloc_failure("admission")
                         self.state.flush(r.uid)
                         waiting.insert(0, r)
                         break
-                    self.state.bind_adapter(seq, r.adapter)
-                    self._adapter_slot[seq.slot] = \
-                        self.adapters.slot_of(r.adapter)
-                chunk = min(len(r.prompt) - matched, sm.max_q_per_seq,
-                            budget, prefill_budget)
-                need = seq.kv_blocks_needed(chunk, self.state.block_size)
-                if need > self.state.available_blocks:
-                    stel.alloc_failure("admission")
-                    self.state.flush(r.uid)
-                    waiting.insert(0, r)
-                    break
-                if self.state.radix is not None:
-                    stel.prefix_lookup(matched)
-                seq.pending = r.prompt[matched:]
-                self.state.ensure_blocks(seq, chunk)
-                running.append(r)
-                if r.t_admit is None:
-                    r.t_admit = now_fn()
-                    stel.admission(r.sla)
-                toks, seq.pending = seq.pending[:chunk], seq.pending[chunk:]
-                sched_uids.append(r.uid)
-                sched_toks.append(toks)
-                sched_fdev.append(False)
-                n_prefill_toks += chunk
-                stel.prefill_chunk()
-                prefill_budget -= chunk
-                if not seq.in_flight:
-                    r.decode_ready = True
-                    newly_ready.append(r)
-                    if r.resume:
-                        r.resume = False
-                    else:
-                        served_slots.append(seq.slot)
-                        sampled_now.append(r)
-                budget -= chunk
+                    if self.state.radix is not None:
+                        stel.prefix_lookup(matched)
+                    seq.pending = r.prompt[matched:]
+                    self.state.ensure_blocks(seq, chunk)
+                    running.append(r)
+                    if r.t_admit is None:
+                        r.t_admit = now_fn()
+                        stel.admission(r.sla)
+                    toks, seq.pending = seq.pending[:chunk], seq.pending[chunk:]
+                    sched_uids.append(r.uid)
+                    sched_toks.append(toks)
+                    sched_fdev.append(False)
+                    n_prefill_toks += chunk
+                    stel.prefill_chunk()
+                    prefill_budget -= chunk
+                    if not seq.in_flight:
+                        r.decode_ready = True
+                        newly_ready.append(r)
+                        if r.resume:
+                            r.resume = False
+                        else:
+                            served_slots.append(seq.slot)
+                            sampled_now.append(r)
+                    budget -= chunk
 
-            if not sched_uids:
-                # nothing schedulable: first materialize (EOS retirement may
-                # free blocks), then preempt the most recently admitted
-                # sequence (pool starvation — the pre-SLA trigger)
-                if records:
+                if not sched_uids:
+                    # nothing schedulable: first materialize (EOS retirement may
+                    # free blocks), then preempt the most recently admitted
+                    # sequence (pool starvation — the pre-SLA trigger)
+                    if records:
+                        materialize()
+                        continue
+                    if running:
+                        preempt(running[-1], "starvation")
+                        continue
+                    raise RuntimeError(
+                        "scheduler deadlock: the KV pool cannot fit even one "
+                        "sequence; raise num_kv_blocks")
+
+                pairs = [(r.uid, self.state.get(r.uid).slot)
+                         for r in sampled_now]
+                stel.tokens("decode", n_decode_toks)
+                stel.tokens("prefill", n_prefill_toks)
+                rnd.phase(None)         # build / h2d / *_dispatch
+                prev, rng = self._step_sampled(sched_uids, sched_toks,
+                                               sched_fdev, served_slots, gen,
+                                               prev, rng)
+                if stream:
+                    rnd.phase("fence")
+                    self._stream_fence(prev)
+                rnd.phase("retire")
+                tnow = now_fn()
+                for r in newly_ready:
+                    r.t_prefill_end = tnow
+                    # index the completed prompt's full blocks into the radix:
+                    # the forward that filled them was just dispatched, so any
+                    # later program aliasing them is ordered behind the writer
+                    self.state.cache_insert(self.state.get(r.uid))
+                if pairs:
+                    records.append(("step", prev, pairs))
+                for r in sampled_now:
+                    if r.t_first is None:
+                        r.t_first = tnow
+                    r.t_last = tnow
+                    r.sampled += 1
+                    if r.sampled >= r.max_new_tokens:
+                        r.done = True       # finish recorded at materialize
+                        self.flush([r.uid])
+                        running.remove(r)
+                        pending_finish.append(r)
+                steps_since_sync += 1
+                if sync_interval and steps_since_sync >= sync_interval:
                     materialize()
-                    continue
-                if running:
-                    preempt(running[-1], "starvation")
-                    continue
-                raise RuntimeError(
-                    "scheduler deadlock: the KV pool cannot fit even one "
-                    "sequence; raise num_kv_blocks")
-
-            pairs = [(r.uid, self.state.get(r.uid).slot)
-                     for r in sampled_now]
-            stel.tokens("decode", n_decode_toks)
-            stel.tokens("prefill", n_prefill_toks)
-            prev, rng = self._step_sampled(sched_uids, sched_toks, sched_fdev,
-                                           served_slots, gen, prev, rng)
-            if stream:
-                self._stream_fence(prev)
-            tnow = now_fn()
-            for r in newly_ready:
-                r.t_prefill_end = tnow
-                # index the completed prompt's full blocks into the radix:
-                # the forward that filled them was just dispatched, so any
-                # later program aliasing them is ordered behind the writer
-                self.state.cache_insert(self.state.get(r.uid))
-            if pairs:
-                records.append(("step", prev, pairs))
-            for r in sampled_now:
-                if r.t_first is None:
-                    r.t_first = tnow
-                r.t_last = tnow
-                r.sampled += 1
-                if r.sampled >= r.max_new_tokens:
-                    r.done = True       # finish recorded at materialize
-                    self.flush([r.uid])
-                    running.remove(r)
-                    pending_finish.append(r)
-            steps_since_sync += 1
-            if sync_interval and steps_since_sync >= sync_interval:
-                materialize()
 
         materialize()
         self._serve_ctx = None      # clean completion: nothing to migrate

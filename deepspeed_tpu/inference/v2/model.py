@@ -246,6 +246,78 @@ def _embed(wte, tokens, dtype):
     return wte.astype(dtype)[tokens]
 
 
+def _embed_tokens(bb, tokens, positions, cfg):
+    """Token rows (+ learned positions where the model has them): the start
+    of every step program, under the ``embed`` scope."""
+    dtype = cfg.dtype
+    with jax.named_scope("embed"):
+        x = _embed(bb["wte"], tokens, dtype)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.embed_scale, dtype)
+        if cfg.embed_norm:
+            x = _norm(bb["embed_norm"], x, cfg)
+        if not cfg.use_rope and not cfg.use_alibi:
+            x = x + bb["wpe"].astype(dtype)[positions]
+    return x
+
+
+def _kv_write(flat_k, flat_v, flat_ks, flat_vs, k, v, page_li, off, km):
+    """Paged KV append (reference linear_blocked_kv_rotary): scatter one
+    layer's new ``k``/``v`` rows [N, nkv, hd] into their pages of the flat
+    [L * NB, nkv, ...] pool views (quantised first when the pool is int8;
+    rows whose ``page_li`` is out of range are dropped).  Scope
+    ``kv_write``."""
+    with jax.named_scope("kv_write"):
+        if flat_ks is not None:
+            k, ks = quantize_kv_token(k)              # [N,nkv,hd], [N,nkv]
+            v, vs = quantize_kv_token(v)
+            flat_ks = flat_ks.at[page_li, :, off].set(ks, mode="drop")
+            flat_vs = flat_vs.at[page_li, :, off].set(vs, mode="drop")
+        # kv-major pages [P, nkv, hd, bs]: token offset is the LANE index
+        at = (page_li, slice(None), slice(None), off) if km else (
+            page_li, slice(None), off)
+        flat_k = flat_k.at[at].set(k.astype(flat_k.dtype), mode="drop")
+        flat_v = flat_v.at[at].set(v.astype(flat_v.dtype), mode="drop")
+    return flat_k, flat_v, flat_ks, flat_vs
+
+
+def _layer_pages(flat_k, flat_v, flat_ks, flat_vs, li, NB, dtype=None):
+    """One layer's pages out of the flat pool views, as the attention ops
+    take them: (k_pages, v_pages, scale kwargs).  ``dtype`` casts an
+    unquantised pool for the prefill kernel.  Scope ``kv_pool``."""
+    with jax.named_scope("kv_pool"):
+        k_pages = jax.lax.dynamic_slice_in_dim(flat_k, li * NB, NB)
+        v_pages = jax.lax.dynamic_slice_in_dim(flat_v, li * NB, NB)
+        if flat_ks is not None:
+            return k_pages, v_pages, dict(
+                k_scale=jax.lax.dynamic_slice_in_dim(flat_ks, li * NB, NB),
+                v_scale=jax.lax.dynamic_slice_in_dim(flat_vs, li * NB, NB))
+        if dtype is not None:
+            k_pages, v_pages = k_pages.astype(dtype), v_pages.astype(dtype)
+    return k_pages, v_pages, {}
+
+
+def _head(params, bb, x, cfg, mesh=None, rows=None):
+    """Final norm + unembed (of ``rows`` of x only, where given: the rows
+    that carry a next-token distribution).  Scope ``head``."""
+    with jax.named_scope("head"):
+        x = _norm(bb["final_norm"], x, cfg)
+        if rows is not None:
+            x = x[rows]
+        return _logits_out(params, bb, x, cfg, cfg.dtype, mesh=mesh)
+
+
+def _sample_next(sample_fn, logits, rng, temperature, top_p, served,
+                 prev_tokens):
+    """In-graph sampling + device feedback: slots flagged ``served`` get
+    their sampled token written into ``prev_tokens``.  Scope ``sample``.
+    Returns (prev_tokens', rng')."""
+    with jax.named_scope("sample"):
+        rng, sub = jax.random.split(rng)
+        nxt = sample_fn(logits, sub, temperature=temperature, top_p=top_p)
+        return jnp.where(served, nxt.astype(jnp.int32), prev_tokens), rng
+
+
 def _ffn(blk, x, cfg, mesh=None):
     """Dense MLP or MoE block body on FLAT tokens [N, H] — MoE routes through
     the dropless ragged grouped GEMM (moe/layer.py), which fits serving
@@ -376,35 +448,31 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     valid = token_slot >= 0                # [N]
 
     # ---- embed (reference ragged_ops/embed) ----
-    x = _embed(bb["wte"], tokens, dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.embed_scale, dtype)
-    if cfg.embed_norm:
-        x = _norm(bb["embed_norm"], x, cfg)
-    if not cfg.use_rope and not cfg.use_alibi:
-        x = x + bb["wpe"].astype(dtype)[token_pos]
+    x = _embed_tokens(bb, tokens, token_pos, cfg)
 
-    # scatter destinations in the page pool; pad tokens get an out-of-range
-    # index so mode="drop" discards them (never index-clamp pads to slot 0 —
-    # duplicate scatter indices would corrupt real rows)
-    blk_idx = token_pos // block_size                        # [N]
-    page = block_table[jnp.clip(token_slot, 0), blk_idx]     # [N]
-    off = token_pos % block_size                             # [N]
     big = jnp.iinfo(jnp.int32).max
-    scat_slot = jnp.where(valid, token_slot, S)              # S = out of range
-    # per-slot live q rows + their first logical position (each slot's batch
-    # tokens are one CONTIGUOUS span ending at kv_len — SplitFuse chunks)
-    q_counts = jnp.zeros((S,), jnp.int32).at[scat_slot].add(1, mode="drop")
-    q_starts = kv_len - q_counts
+    with jax.named_scope("kv_write"):
+        # scatter destinations in the page pool; pad tokens get an
+        # out-of-range index so mode="drop" discards them (never index-clamp
+        # pads to slot 0: duplicate scatter indices would corrupt real rows)
+        blk_idx = token_pos // block_size                        # [N]
+        page = block_table[jnp.clip(token_slot, 0), blk_idx]     # [N]
+        off = token_pos % block_size                             # [N]
+        scat_slot = jnp.where(valid, token_slot, S)      # S = out of range
+    with jax.named_scope("attn_kernel"):
+        # per-slot live q rows + their first logical position (each slot's
+        # batch tokens are one CONTIGUOUS span ending at kv_len: SplitFuse
+        # chunks)
+        q_counts = jnp.zeros((S,), jnp.int32).at[scat_slot].add(
+            1, mode="drop")
+        q_starts = kv_len - q_counts
 
     # [L * num_blocks, nkv, …] views updated IN PLACE through the donated
     # cache buffer — never rebuild the whole pool (a jnp.stack of per-layer
     # copies costs a full cache rewrite per step)
-    L = cfg.num_layers
     NB = cache.k.shape[1]
     km = kv_major_layout(cfg)
     flat_k_all, flat_v_all, flat_ks, flat_vs = _flat_cache_views(cache)
-    quant = cache.quantized
 
     # multi-tenant LoRA (static trace-time branch — adapter-less engines
     # send no "lora" key and trace the identical program): per-TOKEN
@@ -419,82 +487,62 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     for li in range(cfg.num_layers):
         blk = bb[f"block_{li}"]
         ap, np_ = blk["Attention_0"], blk["Norm_0"]
-        h = _norm(np_, x, cfg)
-        q, k, v = _qkv(ap, h, cfg, mesh=mesh)
-        if lora is not None:
-            q, v = _lora_qv(q, v, h, lora, lora_ids, li)
-        if cfg.use_rope:
-            # rope() takes [B, T, n, d] + positions [B, T]
-            q, k = rope(q[None], k[None], token_pos[None], cfg.head_dim,
-                        base=cfg.rope_theta, rope_pct=cfg.rope_pct,
-                        scaling=cfg.rope_scaling,
-                        seq_lens=kv_len[jnp.clip(token_slot, 0)][None])
-            q, k = q[0], k[0]
+        with jax.named_scope("attn_qkv"):
+            h = _norm(np_, x, cfg)
+            q, k, v = _qkv(ap, h, cfg, mesh=mesh)
+            if lora is not None:
+                q, v = _lora_qv(q, v, h, lora, lora_ids, li)
+            if cfg.use_rope:
+                # rope() takes [B, T, n, d] + positions [B, T]
+                q, k = rope(q[None], k[None], token_pos[None], cfg.head_dim,
+                            base=cfg.rope_theta, rope_pct=cfg.rope_pct,
+                            scaling=cfg.rope_scaling,
+                            seq_lens=kv_len[jnp.clip(token_slot, 0)][None])
+                q, k = q[0], k[0]
 
-        # ---- paged KV append (reference linear_blocked_kv_rotary) ----
-        page_li = jnp.where(valid, li * NB + page, big)
-        if quant:
-            k_store, ks = quantize_kv_token(k)        # [N,nkv,hd], [N,nkv]
-            v_store, vs = quantize_kv_token(v)
-            flat_ks = flat_ks.at[page_li, :, off].set(ks, mode="drop")
-            flat_vs = flat_vs.at[page_li, :, off].set(vs, mode="drop")
-        else:
-            k_store, v_store = k, v
-        if km:   # pages [P, nkv, hd, bs]: token offset is the LANE index
-            flat_k_all = flat_k_all.at[page_li, :, :, off].set(
-                k_store.astype(flat_k_all.dtype), mode="drop")
-            flat_v_all = flat_v_all.at[page_li, :, :, off].set(
-                v_store.astype(flat_v_all.dtype), mode="drop")
-        else:
-            flat_k_all = flat_k_all.at[page_li, :, off].set(
-                k_store.astype(flat_k_all.dtype), mode="drop")
-            flat_v_all = flat_v_all.at[page_li, :, off].set(
-                v_store.astype(flat_v_all.dtype), mode="drop")
+        flat_k_all, flat_v_all, flat_ks, flat_vs = _kv_write(
+            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v,
+            jnp.where(valid, li * NB + page, big), off, km)
+        k_pool, v_pool, kv_extra = _layer_pages(
+            flat_k_all, flat_v_all, flat_ks, flat_vs, li, NB, dtype=dtype)
 
         # ---- ragged blocked attention (reference blocked_flash +
         # atom_builder): dense-per-slot q layout, per-slot contiguous
         # position spans; the Pallas kernel DMAs only the pages each
         # (slot, q-chunk) can causally see, so prefill cost scales with
         # Σ live tokens instead of S × longest (round-3 VERDICT item 4) ----
-        nkv, hd = cfg.kv_heads, cfg.head_dim
-        gq = cfg.num_heads // nkv
-        q_dense = jnp.zeros((S, Q) + q.shape[1:], q.dtype).at[
-            scat_slot, dense_idx].set(q, mode="drop")
-        from deepspeed_tpu import ops
-        win = cfg.window_for_layer(li)
-        slopes = None
-        if cfg.use_alibi:
-            from deepspeed_tpu.models.gpt import alibi_slopes
-            slopes = jnp.asarray(alibi_slopes(cfg.num_heads, cfg.head_dim,
-                                              cfg.alibi_prescale))
-        k_pool = jax.lax.dynamic_slice_in_dim(flat_k_all, li * NB, NB)
-        v_pool = jax.lax.dynamic_slice_in_dim(flat_v_all, li * NB, NB)
-        if quant:
-            kv_extra = dict(
-                k_scale=jax.lax.dynamic_slice_in_dim(flat_ks, li * NB, NB),
-                v_scale=jax.lax.dynamic_slice_in_dim(flat_vs, li * NB, NB))
-        else:
-            k_pool, v_pool = k_pool.astype(dtype), v_pool.astype(dtype)
-            kv_extra = {}
-        o_dense = ops.ragged_prefill_attention(
-            q_dense.reshape(S, Q, nkv, gq, hd).astype(dtype),
-            k_pool, v_pool, block_table, kv_len,
-            q_starts, q_counts, scale=cfg.attn_scale, alibi_slopes=slopes,
-            window=win, mesh=mesh, kv_major=km, impl=cfg.attn_impl,
-            **kv_extra).reshape(S, Q, cfg.num_heads, hd)
-        o = o_dense[jnp.clip(token_slot, 0), dense_idx]      # [N, nh, hd]
-        o = jnp.where(valid[:, None, None], o, 0)
-        attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
-        x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh)
-
-    x = _norm(bb["final_norm"], x, cfg)
+        with jax.named_scope("attn_kernel"):
+            nkv, hd = cfg.kv_heads, cfg.head_dim
+            gq = cfg.num_heads // nkv
+            q_dense = jnp.zeros((S, Q) + q.shape[1:], q.dtype).at[
+                scat_slot, dense_idx].set(q, mode="drop")
+            from deepspeed_tpu import ops
+            win = cfg.window_for_layer(li)
+            slopes = None
+            if cfg.use_alibi:
+                from deepspeed_tpu.models.gpt import alibi_slopes
+                slopes = jnp.asarray(alibi_slopes(
+                    cfg.num_heads, cfg.head_dim, cfg.alibi_prescale))
+            o_dense = ops.ragged_prefill_attention(
+                q_dense.reshape(S, Q, nkv, gq, hd).astype(dtype),
+                k_pool, v_pool, block_table, kv_len,
+                q_starts, q_counts, scale=cfg.attn_scale,
+                alibi_slopes=slopes, window=win, mesh=mesh, kv_major=km,
+                impl=cfg.attn_impl,
+                **kv_extra).reshape(S, Q, cfg.num_heads, hd)
+            o = o_dense[jnp.clip(token_slot, 0), dense_idx]   # [N, nh, hd]
+            o = jnp.where(valid[:, None, None], o, 0)
+        with jax.named_scope("attn_out"):
+            attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
+        with jax.named_scope("mlp"):
+            x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh)
 
     # ---- logits gather (reference ragged_ops/logits_gather): the LAST token
     # of each slot's q rows carries the next-token distribution ----
-    last_flat = jnp.zeros((S,), jnp.int32).at[scat_slot].max(
-        jnp.arange(N, dtype=jnp.int32), mode="drop")
-    rows = x[last_flat]                                      # [S, H]
-    logits = _logits_out(params, bb, rows, cfg, dtype, mesh=mesh)  # [S, V]
+    with jax.named_scope("head"):
+        last_flat = jnp.zeros((S,), jnp.int32).at[scat_slot].max(
+            jnp.arange(N, dtype=jnp.int32), mode="drop")
+    logits = _head(params, bb, x, cfg, mesh=mesh, rows=last_flat)  # [S, V]
     return logits, _rebuild_cache(cache, flat_k_all, flat_v_all,
                                   flat_ks, flat_vs)
 
@@ -521,18 +569,14 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     g = nh // nkv
     km = kv_major_layout(cfg)
 
-    x = _embed(bb["wte"], tokens, dtype)                       # [S, H]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.embed_scale, dtype)
-    if cfg.embed_norm:
-        x = _norm(bb["embed_norm"], x, cfg)
-    if not cfg.use_rope and not cfg.use_alibi:
-        x = x + bb["wpe"].astype(dtype)[token_pos]
+    x = _embed_tokens(bb, tokens, token_pos, cfg)              # [S, H]
 
     big = jnp.iinfo(jnp.int32).max
-    page = block_table[jnp.arange(S), token_pos // block_size]  # [S]
-    off = token_pos % block_size                                # [S]
-    kv_len = jnp.where(active, token_pos + 1, 0)                # [S]
+    with jax.named_scope("kv_write"):
+        page = block_table[jnp.arange(S), token_pos // block_size]  # [S]
+        off = token_pos % block_size                                # [S]
+    with jax.named_scope("attn_kernel"):
+        kv_len = jnp.where(active, token_pos + 1, 0)                # [S]
     if lora is not None:
         # decode rows ARE slots: mask inactive lanes to the identity slot
         # so a recycled lane's stale selection never computes a delta
@@ -541,80 +585,68 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     for li in range(cfg.num_layers):
         blk = bb[f"block_{li}"]
         ap = blk["Attention_0"]
-        h = _norm(blk["Norm_0"], x, cfg)
-        q, k, v = _qkv(ap, h, cfg, mesh=mesh)
-        if lora is not None:
-            q, v = _lora_qv(q, v, h, lora, lora_ids, li)
-        if cfg.use_rope:
-            q, k = rope(q[:, None], k[:, None], token_pos[:, None], hd,
-                        base=cfg.rope_theta, rope_pct=cfg.rope_pct,
-                        scaling=cfg.rope_scaling,
-                        seq_lens=kv_len[:, None])
-            q, k = q[:, 0], k[:, 0]
+        with jax.named_scope("attn_qkv"):
+            h = _norm(blk["Norm_0"], x, cfg)
+            q, k, v = _qkv(ap, h, cfg, mesh=mesh)
+            if lora is not None:
+                q, v = _lora_qv(q, v, h, lora, lora_ids, li)
+            if cfg.use_rope:
+                q, k = rope(q[:, None], k[:, None], token_pos[:, None], hd,
+                            base=cfg.rope_theta, rope_pct=cfg.rope_pct,
+                            scaling=cfg.rope_scaling,
+                            seq_lens=kv_len[:, None])
+                q, k = q[:, 0], k[:, 0]
 
-        page_li = jnp.where(active, li * NB + page, big)
-        quant = flat_ks is not None
-        if quant:
-            k_store, ks = quantize_kv_token(k)        # [S,nkv,hd], [S,nkv]
-            v_store, vs = quantize_kv_token(v)
-            flat_ks = flat_ks.at[page_li, :, off].set(ks, mode="drop")
-            flat_vs = flat_vs.at[page_li, :, off].set(vs, mode="drop")
-        else:
-            k_store, v_store = k, v
-        if km:   # pages [P, nkv, hd, bs]: token offset is the LANE index
-            flat_k_all = flat_k_all.at[page_li, :, :, off].set(
-                k_store.astype(flat_k_all.dtype), mode="drop")
-            flat_v_all = flat_v_all.at[page_li, :, :, off].set(
-                v_store.astype(flat_v_all.dtype), mode="drop")
-        else:
-            flat_k_all = flat_k_all.at[page_li, :, off].set(
-                k_store.astype(flat_k_all.dtype), mode="drop")
-            flat_v_all = flat_v_all.at[page_li, :, off].set(
-                v_store.astype(flat_v_all.dtype), mode="drop")
+        flat_k_all, flat_v_all, flat_ks, flat_vs = _kv_write(
+            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v,
+            jnp.where(active, li * NB + page, big), off, km)
+        k_pages, v_pages, kv_extra = _layer_pages(
+            flat_k_all, flat_v_all, flat_ks, flat_vs, li, NB)
+        with jax.named_scope("attn_kernel"):
+            qg = q.reshape(S, nkv, g, hd)
+            slopes = None
+            if cfg.use_alibi:
+                from deepspeed_tpu.models.gpt import alibi_slopes
+                slopes = jnp.asarray(alibi_slopes(nh, hd,
+                                                  cfg.alibi_prescale))
+            win = cfg.window_for_layer(li)
+            o = ops.paged_attention(qg, k_pages, v_pages, block_table,
+                                    kv_len, alibi_slopes=slopes, window=win,
+                                    scale=cfg.attn_scale, mesh=mesh,
+                                    kv_major=km, impl=cfg.attn_impl,
+                                    **kv_extra)
+            o = o.reshape(S, nh, hd)
+        with jax.named_scope("attn_out"):
+            attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
+        with jax.named_scope("mlp"):
+            x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh)
 
-        k_pages = jax.lax.dynamic_slice_in_dim(flat_k_all, li * NB, NB)
-        v_pages = jax.lax.dynamic_slice_in_dim(flat_v_all, li * NB, NB)
-        if quant:
-            kv_extra = dict(
-                k_scale=jax.lax.dynamic_slice_in_dim(flat_ks, li * NB, NB),
-                v_scale=jax.lax.dynamic_slice_in_dim(flat_vs, li * NB, NB))
-        else:
-            kv_extra = {}
-        qg = q.reshape(S, nkv, g, hd)
-        slopes = None
-        if cfg.use_alibi:
-            from deepspeed_tpu.models.gpt import alibi_slopes
-            slopes = jnp.asarray(alibi_slopes(nh, hd, cfg.alibi_prescale))
-        win = cfg.window_for_layer(li)
-        o = ops.paged_attention(qg, k_pages, v_pages, block_table, kv_len,
-                                alibi_slopes=slopes, window=win,
-                                scale=cfg.attn_scale, mesh=mesh, kv_major=km,
-                                impl=cfg.attn_impl, **kv_extra)
-        o = o.reshape(S, nh, hd)
-        attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
-        x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh)
-
-    x = _norm(bb["final_norm"], x, cfg)
-    logits = _logits_out(params, bb, x, cfg, dtype, mesh=mesh)     # [S, V]
+    logits = _head(params, bb, x, cfg, mesh=mesh)                  # [S, V]
     return logits, flat_k_all, flat_v_all, flat_ks, flat_vs
 
 
 def _flat_cache_views(cache: PagedKVCache):
-    fk = cache.k.reshape((-1,) + cache.k.shape[2:])
-    fv = cache.v.reshape((-1,) + cache.v.shape[2:])
-    q = cache.quantized
-    fks = cache.k_scale.reshape((-1,) + cache.k_scale.shape[2:]) if q else None
-    fvs = cache.v_scale.reshape((-1,) + cache.v_scale.shape[2:]) if q else None
+    """[L, NB, ...] pool -> the [L * NB, ...] views the layers index.
+    Scope ``kv_pool``, like everything that only moves the pool."""
+    with jax.named_scope("kv_pool"):
+        fk = cache.k.reshape((-1,) + cache.k.shape[2:])
+        fv = cache.v.reshape((-1,) + cache.v.shape[2:])
+        q = cache.quantized
+        fks = (cache.k_scale.reshape((-1,) + cache.k_scale.shape[2:])
+               if q else None)
+        fvs = (cache.v_scale.reshape((-1,) + cache.v_scale.shape[2:])
+               if q else None)
     return fk, fv, fks, fvs
 
 
 def _rebuild_cache(cache: PagedKVCache, fk, fv, fks, fvs) -> PagedKVCache:
-    return PagedKVCache(
-        k=fk.reshape(cache.k.shape), v=fv.reshape(cache.v.shape),
-        k_scale=(fks.reshape(cache.k_scale.shape) if fks is not None
-                 else None),
-        v_scale=(fvs.reshape(cache.v_scale.shape) if fvs is not None
-                 else None))
+    with jax.named_scope("kv_pool"):
+        return PagedKVCache(
+            k=fk.reshape(cache.k.shape), v=fv.reshape(cache.v.shape),
+            k_scale=(fks.reshape(cache.k_scale.shape) if fks is not None
+                     else None),
+            v_scale=(fvs.reshape(cache.v_scale.shape) if fvs is not None
+                     else None))
 
 
 def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
@@ -638,7 +670,9 @@ def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
     active = batch["active"]
     lora = batch.get("lora")
     adapter_slot = batch.get("adapter_slot")
-    tokens0 = jnp.where(batch["from_device"], prev_tokens, batch["tokens0"])
+    with jax.named_scope("embed"):
+        tokens0 = jnp.where(batch["from_device"], prev_tokens,
+                            batch["tokens0"])
 
     def step(carry, _):
         flat_k, flat_v, flat_ks, flat_vs, tokens, pos, rng = carry
@@ -646,15 +680,22 @@ def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
             params, flat_k, flat_v, tokens, active, pos, bt, cfg, block_size,
             mesh=mesh, flat_ks=flat_ks, flat_vs=flat_vs, lora=lora,
             adapter_slot=adapter_slot)
-        rng, sub = jax.random.split(rng)
-        nxt = sample_fn(logits, sub, temperature=temperature, top_p=top_p)
-        nxt = nxt.astype(jnp.int32)
-        return (flat_k, flat_v, flat_ks, flat_vs, nxt, pos + 1, rng), nxt
+        with jax.named_scope("sample"):
+            rng, sub = jax.random.split(rng)
+            nxt = sample_fn(logits, sub, temperature=temperature,
+                            top_p=top_p)
+            nxt = nxt.astype(jnp.int32)
+            pos = pos + 1
+        return (flat_k, flat_v, flat_ks, flat_vs, nxt, pos, rng), nxt
 
     carry = (flat_k, flat_v, flat_ks, flat_vs, tokens0, batch["pos0"], rng)
-    (flat_k, flat_v, flat_ks, flat_vs, last, _, rng), toks = jax.lax.scan(
-        step, carry, None, length=steps)
-    prev_out = jnp.where(active, last, prev_tokens)
+    # the loop itself belongs to the pool: what it does besides its body's
+    # (scoped) work is carry the pool's views from step to step
+    with jax.named_scope("kv_pool"):
+        (flat_k, flat_v, flat_ks, flat_vs, last, _, rng), toks = \
+            jax.lax.scan(step, carry, None, length=steps)
+    with jax.named_scope("sample"):
+        prev_out = jnp.where(active, last, prev_tokens)
     return toks, prev_out, rng, _rebuild_cache(cache, flat_k, flat_v,
                                                flat_ks, flat_vs)
 
@@ -672,15 +713,15 @@ def ragged_forward_sampled(params, cache: PagedKVCache, batch, prev_tokens,
     without a single host sync (the FastGen hot loop re-shaped for a
     high-latency host↔device link).
     Returns (prev_tokens' [S], rng', cache)."""
-    tokens = jnp.where(batch["from_device"],
-                       prev_tokens[jnp.clip(batch["token_slot"], 0)],
-                       batch["tokens"])
+    with jax.named_scope("embed"):
+        tokens = jnp.where(batch["from_device"],
+                           prev_tokens[jnp.clip(batch["token_slot"], 0)],
+                           batch["tokens"])
     logits, cache = ragged_forward(
         params, cache, {**batch, "tokens": tokens}, cfg,
         block_size=block_size, max_q_per_seq=max_q_per_seq, mesh=mesh)
-    rng, sub = jax.random.split(rng)
-    nxt = sample_fn(logits, sub, temperature=temperature, top_p=top_p)
-    prev_out = jnp.where(batch["served"], nxt.astype(jnp.int32), prev_tokens)
+    prev_out, rng = _sample_next(sample_fn, logits, rng, temperature, top_p,
+                                 batch["served"], prev_tokens)
     return prev_out, rng, cache
 
 
@@ -696,19 +737,20 @@ def ragged_forward_sampled_draft(params, draft_params, cache: PagedKVCache,
     useful speculative acceptance.  Draft staleness never affects
     correctness (greedy verify is exact for any draft), only acceptance.
     Returns (prev', rng', cache', draft_cache')."""
-    tokens = jnp.where(batch["from_device"],
-                       prev_tokens[jnp.clip(batch["token_slot"], 0)],
-                       batch["tokens"])
+    with jax.named_scope("embed"):
+        tokens = jnp.where(batch["from_device"],
+                           prev_tokens[jnp.clip(batch["token_slot"], 0)],
+                           batch["tokens"])
     batch = {**batch, "tokens": tokens}
     logits, cache = ragged_forward(
         params, cache, batch, cfg,
         block_size=block_size, max_q_per_seq=max_q_per_seq, mesh=mesh)
-    _, draft_cache = ragged_forward(
-        draft_params, draft_cache, batch, draft_cfg,
-        block_size=block_size, max_q_per_seq=max_q_per_seq, mesh=mesh)
-    rng, sub = jax.random.split(rng)
-    nxt = sample_fn(logits, sub, temperature=temperature, top_p=top_p)
-    prev_out = jnp.where(batch["served"], nxt.astype(jnp.int32), prev_tokens)
+    with jax.named_scope("draft"):
+        _, draft_cache = ragged_forward(
+            draft_params, draft_cache, batch, draft_cfg,
+            block_size=block_size, max_q_per_seq=max_q_per_seq, mesh=mesh)
+    prev_out, rng = _sample_next(sample_fn, logits, rng, temperature, top_p,
+                                 batch["served"], prev_tokens)
     return prev_out, rng, cache, draft_cache
 
 
@@ -721,16 +763,18 @@ def ragged_decode_sampled_draft(params, draft_params, cache: PagedKVCache,
     (logits discarded) — keeps the draft KV in lockstep through decode-only
     scheduler rounds so later speculative bursts don't attend draft-cache
     holes.  Returns (prev', rng', cache', draft_cache')."""
-    tokens = jnp.where(batch["from_device"], prev_tokens, batch["tokens"])
+    with jax.named_scope("embed"):
+        tokens = jnp.where(batch["from_device"], prev_tokens,
+                           batch["tokens"])
     batch = {**batch, "tokens": tokens}
     logits, cache = ragged_decode_forward(
         params, cache, batch, cfg, block_size=block_size, mesh=mesh)
-    _, draft_cache = ragged_decode_forward(
-        draft_params, draft_cache, batch, draft_cfg,
-        block_size=block_size, mesh=mesh)
-    rng, sub = jax.random.split(rng)
-    nxt = sample_fn(logits, sub, temperature=temperature, top_p=top_p)
-    prev_out = jnp.where(batch["served"], nxt.astype(jnp.int32), prev_tokens)
+    with jax.named_scope("draft"):
+        _, draft_cache = ragged_decode_forward(
+            draft_params, draft_cache, batch, draft_cfg,
+            block_size=block_size, mesh=mesh)
+    prev_out, rng = _sample_next(sample_fn, logits, rng, temperature, top_p,
+                                 batch["served"], prev_tokens)
     return prev_out, rng, cache, draft_cache
 
 
@@ -743,13 +787,14 @@ def ragged_decode_sampled(params, cache: PagedKVCache, batch, prev_tokens,
     marks the slots whose sample is a real next token (a 1-token mid-prefill
     chunk is active but NOT served — its logits are mid-prompt garbage).
     Returns (prev_tokens' [S], rng', cache)."""
-    tokens = jnp.where(batch["from_device"], prev_tokens, batch["tokens"])
+    with jax.named_scope("embed"):
+        tokens = jnp.where(batch["from_device"], prev_tokens,
+                           batch["tokens"])
     logits, cache = ragged_decode_forward(
         params, cache, {**batch, "tokens": tokens}, cfg,
         block_size=block_size, mesh=mesh)
-    rng, sub = jax.random.split(rng)
-    nxt = sample_fn(logits, sub, temperature=temperature, top_p=top_p)
-    prev_out = jnp.where(batch["served"], nxt.astype(jnp.int32), prev_tokens)
+    prev_out, rng = _sample_next(sample_fn, logits, rng, temperature, top_p,
+                                 batch["served"], prev_tokens)
     return prev_out, rng, cache
 
 
@@ -771,89 +816,66 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     g = nh // nkv
     km = kv_major_layout(cfg)
-    quant = flat_ks is not None
 
     positions = pos0[:, None] + jnp.arange(G, dtype=jnp.int32)[None]  # [S,G]
-    x = _embed(bb["wte"], tokens, dtype)                               # [S,G,H]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.embed_scale, dtype)
-    if cfg.embed_norm:
-        x = _norm(bb["embed_norm"], x, cfg)
-    if not cfg.use_rope and not cfg.use_alibi:
-        x = x + bb["wpe"].astype(dtype)[positions]
+    x = _embed_tokens(bb, tokens, positions, cfg)                     # [S,G,H]
 
     big = jnp.iinfo(jnp.int32).max
-    flat_pos = positions.reshape(-1)                                  # [S*G]
-    page = block_table[
-        jnp.repeat(jnp.arange(S), G), flat_pos // block_size]         # [S*G]
-    off = flat_pos % block_size
-    act_flat = jnp.repeat(active, G)
-    kv_len = jnp.where(active, pos0 + G, 0)
+    with jax.named_scope("kv_write"):
+        flat_pos = positions.reshape(-1)                              # [S*G]
+        page = block_table[
+            jnp.repeat(jnp.arange(S), G), flat_pos // block_size]     # [S*G]
+        off = flat_pos % block_size
+        act_flat = jnp.repeat(active, G)
+    with jax.named_scope("attn_kernel"):
+        kv_len = jnp.where(active, pos0 + G, 0)
 
     for li in range(cfg.num_layers):
         blk = bb[f"block_{li}"]
         ap = blk["Attention_0"]
-        h = _norm(blk["Norm_0"], x, cfg)
-        q, k, v = _qkv(ap, h, cfg, mesh=mesh)
-        if cfg.use_rope:
-            q, k = rope(q, k, positions, hd, base=cfg.rope_theta,
-                        rope_pct=cfg.rope_pct, scaling=cfg.rope_scaling,
-                        seq_lens=kv_len[:, None])
-        page_li = jnp.where(act_flat, li * NB + page, big)
-        kf = k.reshape(S * G, nkv, hd)
-        vf = v.reshape(S * G, nkv, hd)
-        if quant:
-            k_store, ks = quantize_kv_token(kf)
-            v_store, vs = quantize_kv_token(vf)
-            flat_ks = flat_ks.at[page_li, :, off].set(ks, mode="drop")
-            flat_vs = flat_vs.at[page_li, :, off].set(vs, mode="drop")
-        else:
-            k_store, v_store = kf, vf
-        if km:
-            flat_k = flat_k.at[page_li, :, :, off].set(
-                k_store.astype(flat_k.dtype), mode="drop")
-            flat_v = flat_v.at[page_li, :, :, off].set(
-                v_store.astype(flat_v.dtype), mode="drop")
-        else:
-            flat_k = flat_k.at[page_li, :, off].set(
-                k_store.astype(flat_k.dtype), mode="drop")
-            flat_v = flat_v.at[page_li, :, off].set(
-                v_store.astype(flat_v.dtype), mode="drop")
-
-        k_pool = jax.lax.dynamic_slice_in_dim(flat_k, li * NB, NB)
-        v_pool = jax.lax.dynamic_slice_in_dim(flat_v, li * NB, NB)
-        if quant:
-            kv_extra = dict(
-                k_scale=jax.lax.dynamic_slice_in_dim(flat_ks, li * NB, NB),
-                v_scale=jax.lax.dynamic_slice_in_dim(flat_vs, li * NB, NB))
-        else:
-            k_pool, v_pool = k_pool.astype(dtype), v_pool.astype(dtype)
-            kv_extra = {}
-        slopes = None
-        if cfg.use_alibi:
-            from deepspeed_tpu.models.gpt import alibi_slopes
-            slopes = jnp.asarray(alibi_slopes(nh, hd, cfg.alibi_prescale))
-        win = cfg.window_for_layer(li)
-        o = ops.ragged_prefill_attention(
-            q.reshape(S, G, nkv, g, hd).astype(dtype), k_pool, v_pool,
-            block_table, kv_len, pos0,
-            jnp.where(active, G, 0).astype(jnp.int32),
-            scale=cfg.attn_scale, alibi_slopes=slopes, window=win,
-            mesh=mesh, kv_major=km, impl=cfg.attn_impl,
-            **kv_extra).reshape(S, G, nh, hd)
-        # inactive slots (kv_len=0, q_counts=0) produce 0/0 garbage from the
-        # kernel combine; zero them like ragged_forward does so no future
-        # cross-row op (capacity MoE, aux stats) can see NaNs from dead rows
-        o = jnp.where(active[:, None, None, None], o, 0)
-        attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
+        with jax.named_scope("attn_qkv"):
+            h = _norm(blk["Norm_0"], x, cfg)
+            q, k, v = _qkv(ap, h, cfg, mesh=mesh)
+            if cfg.use_rope:
+                q, k = rope(q, k, positions, hd, base=cfg.rope_theta,
+                            rope_pct=cfg.rope_pct, scaling=cfg.rope_scaling,
+                            seq_lens=kv_len[:, None])
+        flat_k, flat_v, flat_ks, flat_vs = _kv_write(
+            flat_k, flat_v, flat_ks, flat_vs, k.reshape(S * G, nkv, hd),
+            v.reshape(S * G, nkv, hd),
+            jnp.where(act_flat, li * NB + page, big), off, km)
+        k_pool, v_pool, kv_extra = _layer_pages(
+            flat_k, flat_v, flat_ks, flat_vs, li, NB, dtype=dtype)
+        with jax.named_scope("attn_kernel"):
+            slopes = None
+            if cfg.use_alibi:
+                from deepspeed_tpu.models.gpt import alibi_slopes
+                slopes = jnp.asarray(alibi_slopes(nh, hd,
+                                                  cfg.alibi_prescale))
+            win = cfg.window_for_layer(li)
+            o = ops.ragged_prefill_attention(
+                q.reshape(S, G, nkv, g, hd).astype(dtype), k_pool, v_pool,
+                block_table, kv_len, pos0,
+                jnp.where(active, G, 0).astype(jnp.int32),
+                scale=cfg.attn_scale, alibi_slopes=slopes, window=win,
+                mesh=mesh, kv_major=km, impl=cfg.attn_impl,
+                **kv_extra).reshape(S, G, nh, hd)
+            # inactive slots (kv_len=0, q_counts=0) produce 0/0 garbage from
+            # the kernel combine; zero them like ragged_forward does so no
+            # future cross-row op (capacity MoE, aux stats) can see NaNs
+            # from dead rows
+            o = jnp.where(active[:, None, None, None], o, 0)
+        with jax.named_scope("attn_out"):
+            attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
         # FFN/MoE body is token-wise and (for MoE) expects FLAT tokens
-        H = x.shape[-1]
-        x = _block_residual(blk, x.reshape(S * G, H), h.reshape(S * G, H),
-                            attn_delta.reshape(S * G, H), cfg, mesh=mesh
-                            ).reshape(S, G, H)
+        with jax.named_scope("mlp"):
+            H = x.shape[-1]
+            x = _block_residual(blk, x.reshape(S * G, H),
+                                h.reshape(S * G, H),
+                                attn_delta.reshape(S * G, H), cfg, mesh=mesh
+                                ).reshape(S, G, H)
 
-    x = _norm(bb["final_norm"], x, cfg)
-    logits = _logits_out(params, bb, x, cfg, dtype, mesh=mesh)  # [S, G, V]
+    logits = _head(params, bb, x, cfg, mesh=mesh)               # [S, G, V]
     return logits, flat_k, flat_v, flat_ks, flat_vs
 
 
@@ -892,44 +914,54 @@ def _speculative_burst_core(params, draft_params, cache: PagedKVCache,
         d_list, q_list = [], []
         dtok, dpos = prev, pos
         ddk, ddv, ddks, ddvs = dk, dv, dks, dvs
-        for j in range(gamma + 1):
-            dlogits, ddk, ddv, ddks, ddvs = _decode_core(
-                draft_params, ddk, ddv, dtok, active, dpos, bt, draft_cfg,
-                block_size, mesh=mesh, flat_ks=ddks, flat_vs=ddvs)
-            if j < gamma:
+        # the two halves of an outer step, named in the device trace: what
+        # the draft costs against the verify is read there, inside the one
+        # fused program
+        with jax.named_scope("draft"):
+            for j in range(gamma + 1):
+                dlogits, ddk, ddv, ddks, ddvs = _decode_core(
+                    draft_params, ddk, ddv, dtok, active, dpos, bt,
+                    draft_cfg, block_size, mesh=mesh, flat_ks=ddks,
+                    flat_vs=ddvs)
+                if j < gamma:
+                    with jax.named_scope("sample"):
+                        if sampled:
+                            ql = xform(dlogits)
+                            rng, sub = jax.random.split(rng)
+                            dtok = jax.random.categorical(
+                                sub, ql, axis=-1).astype(jnp.int32)
+                            q_list.append(ql)
+                        else:
+                            dtok = jnp.argmax(dlogits, axis=-1).astype(
+                                jnp.int32)
+                    d_list.append(dtok)
+                # the j == gamma pass only ingests d_gamma's KV
+                dpos = dpos + 1
+            d = jnp.stack(d_list, axis=1)                   # [S, gamma]
+        with jax.named_scope("verify"):
+            ver_in = jnp.concatenate([prev[:, None], d], axis=1)  # [S, g+1]
+            vlogits, fk, fv, fks, fvs = _verify_core(
+                params, fk, fv, fks, fvs, ver_in, active, pos, bt, cfg,
+                block_size, mesh=mesh)
+            with jax.named_scope("sample"):
                 if sampled:
-                    ql = xform(dlogits)
                     rng, sub = jax.random.split(rng)
-                    dtok = jax.random.categorical(sub, ql, axis=-1).astype(
-                        jnp.int32)
-                    q_list.append(ql)
+                    emit, counts = spec_accept(
+                        sub, jnp.stack(q_list, axis=1), xform(vlogits), d)
                 else:
-                    dtok = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
-                d_list.append(dtok)
-            # the j == gamma pass only ingests d_gamma's KV
-            dpos = dpos + 1
-        d = jnp.stack(d_list, axis=1)                   # [S, gamma]
-        ver_in = jnp.concatenate([prev[:, None], d], axis=1)  # [S, gamma+1]
-        vlogits, fk, fv, fks, fvs = _verify_core(
-            params, fk, fv, fks, fvs, ver_in, active, pos, bt, cfg,
-            block_size, mesh=mesh)
-        if sampled:
-            rng, sub = jax.random.split(rng)
-            emit, counts = spec_accept(sub, jnp.stack(q_list, axis=1),
-                                       xform(vlogits), d)
-        else:
-            emit, counts = _greedy_accept(vlogits, d, gamma)
-        counts = jnp.where(active, counts, 0)
-        last = jnp.take_along_axis(
-            emit, jnp.maximum(counts - 1, 0)[:, None], axis=1)[:, 0]
-        new_prev = jnp.where(active, last, prev)
-        new_pos = jnp.where(active, pos + counts, pos)
+                    emit, counts = _greedy_accept(vlogits, d, gamma)
+                counts = jnp.where(active, counts, 0)
+                last = jnp.take_along_axis(
+                    emit, jnp.maximum(counts - 1, 0)[:, None], axis=1)[:, 0]
+                new_prev = jnp.where(active, last, prev)
+                new_pos = jnp.where(active, pos + counts, pos)
         return ((fk, fv, fks, fvs, ddk, ddv, ddks, ddvs, new_prev, new_pos,
                  rng), (emit.T, counts))
 
     carry = (fk, fv, fks, fvs, dk, dv, dks, dvs, prev0, batch["pos0"], rng)
-    (fk, fv, fks, fvs, dk, dv, dks, dvs, prev, _, rng), (toks, counts) = \
-        jax.lax.scan(outer, carry, None, length=steps)
+    with jax.named_scope("kv_pool"):     # the loop carries both pools
+        (fk, fv, fks, fvs, dk, dv, dks, dvs, prev, _, rng), (toks, counts) \
+            = jax.lax.scan(outer, carry, None, length=steps)
     prev_out = jnp.where(active, prev, prev_tokens)
     return (toks, counts, prev_out, rng,
             _rebuild_cache(cache, fk, fv, fks, fvs),
@@ -969,8 +1001,7 @@ def _greedy_accept(vlogits, d, gamma: int):
     """Greedy speculative acceptance: accept the longest prefix of draft
     tokens matching the target argmax, then emit the target's token at the
     stop position (the correction when rejected, the bonus when all gamma
-    accepted).  Shared by the fused burst and the split-profile verify
-    step so both modes apply bit-identical acceptance.
+    accepted).
     Returns (emit [S, gamma+1], counts [S] in 1..gamma+1)."""
     t = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)      # [S, g+1]
     match = (d == t[:, :gamma])
@@ -982,91 +1013,6 @@ def _greedy_accept(vlogits, d, gamma: int):
                      jnp.pad(d, ((0, 0), (0, 1))),
                      correction[:, None])                   # [S, g+1]
     return emit, n_acc + 1
-
-
-def speculative_draft_step(draft_params, draft_cache: PagedKVCache, batch,
-                           prev_tokens, pos, rng, temperature, top_p,
-                           draft_cfg: GPTConfig, *, block_size: int,
-                           gamma: int, top_k: int = 0, sampled: bool = False,
-                           mesh=None):
-    """The DRAFT half of one speculative outer step, as its own program —
-    the split-profile mode (``speculative.profile``) dispatches draft and
-    verify separately so the serving telemetry can attribute wall time to
-    each side (the fused burst is one opaque dispatch).  Identical
-    choreography to the draft loop inside ``_speculative_burst_core``:
-    gamma sequential draft decodes plus the extra ingest of d_gamma.
-
-    batch: tokens0/from_device/active/block_table as in the burst;
-    ``pos`` is threaded separately (the verify step advances it by the
-    acceptance count).  Returns greedy ``(d [S, gamma], draft_cache',
-    rng')`` or sampled ``(d, q_logits [S, gamma, V], draft_cache', rng')``.
-    """
-    dk, dv, dks, dvs = _flat_cache_views(draft_cache)
-    active = batch["active"]
-    bt = batch["block_table"]
-    if sampled:
-        from deepspeed_tpu.inference.engine import _sampling_logits
-        xform = functools.partial(_sampling_logits, temperature=temperature,
-                                  top_k=top_k, top_p=top_p)
-    dtok = jnp.where(batch["from_device"], prev_tokens, batch["tokens0"])
-    dpos = pos
-    d_list, q_list = [], []
-    for j in range(gamma + 1):
-        dlogits, dk, dv, dks, dvs = _decode_core(
-            draft_params, dk, dv, dtok, active, dpos, bt, draft_cfg,
-            block_size, mesh=mesh, flat_ks=dks, flat_vs=dvs)
-        if j < gamma:
-            if sampled:
-                ql = xform(dlogits)
-                rng, sub = jax.random.split(rng)
-                dtok = jax.random.categorical(sub, ql, axis=-1).astype(
-                    jnp.int32)
-                q_list.append(ql)
-            else:
-                dtok = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
-            d_list.append(dtok)
-        dpos = dpos + 1
-    d = jnp.stack(d_list, axis=1)                           # [S, gamma]
-    draft_cache = _rebuild_cache(draft_cache, dk, dv, dks, dvs)
-    if sampled:
-        return d, jnp.stack(q_list, axis=1), draft_cache, rng
-    return d, draft_cache, rng
-
-
-def speculative_verify_step(params, cache: PagedKVCache, batch, d, q_logits,
-                            prev_tokens, pos, rng, temperature, top_p,
-                            cfg: GPTConfig, *, block_size: int, gamma: int,
-                            top_k: int = 0, sampled: bool = False,
-                            mesh=None):
-    """The VERIFY half of one speculative outer step (split-profile mode):
-    one multi-token target forward over [seed, d_0..d_{gamma-1}] plus the
-    acceptance rule — ``_greedy_accept`` or ``spec_accept``, the SAME
-    functions the fused burst applies, so split mode is token-identical to
-    fused mode (pinned by tests).  ``q_logits`` is the draft's sampling
-    logits from ``speculative_draft_step`` (ignored when greedy).
-    Returns (emit [S, gamma+1], counts [S], prev', pos', rng', cache')."""
-    fk, fv, fks, fvs = _flat_cache_views(cache)
-    active = batch["active"]
-    seed = jnp.where(batch["from_device"], prev_tokens, batch["tokens0"])
-    ver_in = jnp.concatenate([seed[:, None], d], axis=1)    # [S, gamma+1]
-    vlogits, fk, fv, fks, fvs = _verify_core(
-        params, fk, fv, fks, fvs, ver_in, active, pos, batch["block_table"],
-        cfg, block_size, mesh=mesh)
-    if sampled:
-        from deepspeed_tpu.inference.engine import _sampling_logits
-        xform = functools.partial(_sampling_logits, temperature=temperature,
-                                  top_k=top_k, top_p=top_p)
-        rng, sub = jax.random.split(rng)
-        emit, counts = spec_accept(sub, q_logits, xform(vlogits), d)
-    else:
-        emit, counts = _greedy_accept(vlogits, d, gamma)
-    counts = jnp.where(active, counts, 0)
-    last = jnp.take_along_axis(
-        emit, jnp.maximum(counts - 1, 0)[:, None], axis=1)[:, 0]
-    new_prev = jnp.where(active, last, prev_tokens)
-    new_pos = jnp.where(active, pos + counts, pos)
-    return (emit, counts, new_prev, new_pos, rng,
-            _rebuild_cache(cache, fk, fv, fks, fvs))
 
 
 def spec_accept(rng, q_logits, p_logits, d):

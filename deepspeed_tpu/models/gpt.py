@@ -878,23 +878,24 @@ class GPT(nn.Module):
                                                        ltd_idx=ltd,
                                                        pld_theta=batch.get(
                                                            "pld_theta"))
-        if c.tie_embeddings:
-            unembed = emb.astype(x.dtype).T                # [H, V]
-        else:
-            unembed = self.param("lm_head",
-                                 _part(_kernel_init(), ("embed", "vocab")),
-                                 (c.hidden_size, c.vocab_size),
-                                 c.param_dtype).astype(x.dtype)
-        if labels is None:
-            labels, mask = shift_labels(batch, input_ids)
-        lm_bias = (self.param("lm_head_bias",
-                              _part(nn.initializers.zeros, ("vocab",)),
-                              (c.vocab_size,), c.param_dtype)
-                   if c.unembed_bias else None)
-        from deepspeed_tpu.ops import lm_cross_entropy
-        loss = lm_cross_entropy(x, unembed, labels, mask,
-                                chunk_size=self._loss_chunk() or None,
-                                bias=lm_bias)
+        with jax.named_scope("loss"):        # unembed + cross-entropy
+            if c.tie_embeddings:
+                unembed = emb.astype(x.dtype).T                # [H, V]
+            else:
+                unembed = self.param("lm_head",
+                                     _part(_kernel_init(), ("embed", "vocab")),
+                                     (c.hidden_size, c.vocab_size),
+                                     c.param_dtype).astype(x.dtype)
+            if labels is None:
+                labels, mask = shift_labels(batch, input_ids)
+            lm_bias = (self.param("lm_head_bias",
+                                  _part(nn.initializers.zeros, ("vocab",)),
+                                  (c.vocab_size,), c.param_dtype)
+                       if c.unembed_bias else None)
+            from deepspeed_tpu.ops import lm_cross_entropy
+            loss = lm_cross_entropy(x, unembed, labels, mask,
+                                    chunk_size=self._loss_chunk() or None,
+                                    bias=lm_bias)
         if c.num_experts > 0:
             loss = loss + c.moe_aux_coef * moe_aux
         return loss

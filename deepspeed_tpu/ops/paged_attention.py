@@ -421,6 +421,7 @@ def _pallas_paged_attention_split(q, k_pages, v_pages, block_table, kv_lens,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="paged_decode",
     )(*prefetch, *inputs)
     # combine: o = Σ exp(m_s − m*) acc_s / Σ exp(m_s − m*) l_s
     m_star = jnp.max(m, axis=2, keepdims=True)              # [S, nkv, 1, g]
@@ -772,6 +773,7 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="ragged_prefill",
     )(*prefetch, *inputs)
     return out
 

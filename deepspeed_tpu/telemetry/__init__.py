@@ -4,8 +4,10 @@ The reference ships monitoring as scattered pieces (MonitorMaster fan-out,
 EngineTimers, flops profiler, see_memory_usage); this package correlates
 them per step and adds the TPU-specific hazards nothing else watches:
 
-- ``tracer``         — host-phase span recording + Chrome-trace/Perfetto
-                       JSON export (incl. cross-file flow events)
+- ``tracer``         — host-phase spans, each a ``ds.<name>`` annotation
+                       inside any ``jax.profiler`` trace (one clock with the
+                       device ops) and, when enabled, a Chrome-trace/Perfetto
+                       JSON event (incl. cross-file flow events)
 - ``tracecontext``   — per-request distributed trace/span ids threaded
                        through the serving fleet (router -> replicas)
 - ``timeseries``     — bounded ring-buffer sampling of registry metrics
@@ -19,8 +21,8 @@ them per step and adds the TPU-specific hazards nothing else watches:
 - ``histogram``      — log-bucketed histograms with exact quantiles under
                        a cap (serving latency percentiles)
 - ``serving``        — request-level serving telemetry facade (lifecycle
-                       spans, TTFT/TPOT histograms, KV-pool and
-                       speculative-decode instrumentation)
+                       spans, scheduler-phase spans, TTFT/TPOT histograms,
+                       KV-pool and speculative-decode counters)
 - ``exporter``       — snapshot serialization: JSON, Prometheus text
                        exposition, MonitorMaster fan-out
 - ``health``         — in-graph per-module-group numerics stats (grad/param

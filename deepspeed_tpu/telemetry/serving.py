@@ -14,7 +14,9 @@ serving operator actually asks:
   fragmentation of allocated pages, and allocation-failure counters per
   decision site (the baseline a radix prefix cache has to beat);
 - **why is speculative decoding slow** — accepted/proposed tokens and
-  draft/verify wall-time counters replacing ``eng.spec_stats``.
+  the fused dispatch's wall time, replacing ``eng.spec_stats`` (the
+  draft / verify split is read in a device trace, from the ``draft`` and
+  ``verify`` scopes inside the one fused program).
 
 One instance per engine with its OWN ``MetricRegistry`` by default (two
 engines in one process — the bench runs seven — must not blend their
@@ -31,16 +33,12 @@ from __future__ import annotations
 
 import os
 import time
-from contextlib import nullcontext
 from typing import Dict, Optional
 
 from deepspeed_tpu.config import DeepSpeedConfigModel
 from deepspeed_tpu.telemetry.exporter import SnapshotExporter
 from deepspeed_tpu.telemetry.registry import MetricRegistry
 from deepspeed_tpu.telemetry.tracer import SpanTracer, TraceEmitter
-
-_NULL = nullcontext()
-
 
 class ServingTelemetryConfig(DeepSpeedConfigModel):
     """``telemetry`` block of the inference engine configs.
@@ -184,12 +182,6 @@ class ServingTelemetry:
         self.c_spec_ms = reg.counter(
             "spec_burst_ms_total", "wall milliseconds spent in fused "
             "speculative dispatches, including their host sync")
-        self.c_spec_draft_ms = reg.counter(
-            "spec_draft_ms_total", "wall milliseconds in draft-model "
-            "dispatches (speculative.profile split mode only)")
-        self.c_spec_verify_ms = reg.counter(
-            "spec_verify_ms_total", "wall milliseconds in verify "
-            "dispatches (speculative.profile split mode only)")
         self.g_spec_ratio = reg.gauge(
             "spec_accept_ratio", "cumulative draft-token acceptance: "
             "accepted / proposed")
@@ -222,16 +214,11 @@ class ServingTelemetry:
         and histogram math never mix clock bases."""
         return time.perf_counter()
 
-    def _trace_us(self, t_seconds: float) -> float:
-        """Map a lifecycle timestamp onto the tracer's microsecond epoch so
-        request tracks align with dispatch spans."""
-        return t_seconds * 1e9 / 1e3 - self.tracer._epoch_ns / 1e3
-
     # -------------------------------------------------------------- spans
 
     def span(self, name: str, **args):
-        if not self.tracer.enabled:
-            return _NULL
+        """A scheduler phase or dispatch: the ``ds.<name>`` profiler
+        annotation always, the tracer's buffered event when it is on."""
         return self.tracer.span(name, **args)
 
     # ---------------------------------------------------- request lifecycle
@@ -301,7 +288,7 @@ class ServingTelemetry:
             for name, a, b in spans:
                 if a is None or b is None or b < a:
                     continue
-                ts = self._trace_us(a)
+                ts = self.tracer.us_of(a)
                 if first_ts is None:
                     first_ts = (ts, (b - a) * 1e6)
                 self.tracer.record(name, ts, (b - a) * 1e6,
@@ -437,11 +424,6 @@ class ServingTelemetry:
                 self.c_spec_accepted.value(**self.labels) / proposed,
                 **self.labels)
 
-    def spec_profile(self, draft_ms: float, verify_ms: float) -> None:
-        if self.enabled:
-            self.c_spec_draft_ms.inc(draft_ms, **self.labels)
-            self.c_spec_verify_ms.inc(verify_ms, **self.labels)
-
     def spec_summary(self) -> Dict[str, float]:
         """The bench/test-facing read of the speculative counters (replaces
         the old ``eng.spec_stats`` dict)."""
@@ -460,11 +442,6 @@ class ServingTelemetry:
             "emitted_per_outer": (self.c_spec_emitted.value(**L) / outer
                                   if outer else 0.0),
             "burst_ms": self.c_spec_ms.value(**L),
-            "draft_ms": self.c_spec_draft_ms.value(**L),
-            "verify_ms": self.c_spec_verify_ms.value(**L),
-            "draft_dispatches": self.c_dispatch.value(kind="spec_draft", **L),
-            "verify_dispatches": self.c_dispatch.value(kind="spec_verify",
-                                                       **L),
             # fused draft+verify dispatches: the cross-request batching
             # claim is "dispatches per emitted token strictly lower than
             # per-request spec" — this is the numerator the tests pin

@@ -12,9 +12,11 @@ compiled-program analysis that connects them to XLA ground truth:
 - ``end_step(...)``            — cadence-gated memory sampling and snapshot
                                  export (JSON + Prometheus + monitor fan-out)
 
-Everything is inert when ``telemetry.enabled`` is false: ``span`` returns a
-shared nullcontext and the other hooks return immediately, so the disabled
-path adds one attribute check per call to the hot loop.
+Everything but ``span`` is inert when ``telemetry.enabled`` is false: the
+hooks return immediately, and ``span`` is the ``ds.<name>`` profiler
+annotation alone (well under a microsecond with no profiler session open;
+see telemetry/tracer.py), so a ``jax.profiler`` trace of any engine names
+the host phases whether or not telemetry was configured.
 """
 
 from __future__ import annotations
@@ -22,7 +24,6 @@ from __future__ import annotations
 import math
 import os
 import time
-from contextlib import nullcontext
 from typing import Callable, Dict, Optional
 
 from deepspeed_tpu.telemetry.exporter import SnapshotExporter
@@ -30,8 +31,6 @@ from deepspeed_tpu.telemetry.registry import MetricRegistry, default_registry
 from deepspeed_tpu.telemetry.tracer import SpanTracer, TraceEmitter
 from deepspeed_tpu.telemetry.watchdog import RecompileWatchdog
 from deepspeed_tpu.utils.logging import logger
-
-_NULL = nullcontext()
 
 HLO_BYTES = "hlo_collective_bytes_total"
 HLO_CALLS = "hlo_collective_calls_total"
@@ -124,8 +123,6 @@ class StepTelemetry:
     # ------------------------------------------------------------- spans
 
     def span(self, name: str, step: Optional[int] = None, **args):
-        if not self.tracer.enabled:
-            return _NULL
         return self.tracer.span(name, step=step, **args)
 
     # --------------------------------------------------------- dispatch

@@ -1,18 +1,25 @@
 """Host-side span tracer + Chrome-trace/Perfetto emitter.
 
 T3 (arxiv 2401.16677) makes the case that optimizing compute/collective
-overlap starts from *seeing* the timeline; on TPU the device timeline comes
-from ``jax.profiler`` xplane captures, but the host-side step anatomy — batch
-assembly, host→device placement, dispatch, waiting on device completion,
-optimizer/step bookkeeping, checkpoint I/O — is invisible to it.  The
-``SpanTracer`` records those phases as complete events and ``TraceEmitter``
-writes the standard Chrome trace-event JSON that Perfetto / chrome://tracing
-load directly, so a training run's host anatomy can be inspected next to the
-device profile.
+overlap starts from *seeing* the timeline.  One span API, two sinks:
+
+- every ``span()`` is a ``jax.profiler.TraceAnnotation`` named
+  ``ds.<name>`` carrying the span's arguments, so a ``jax.profiler`` trace
+  of a running engine holds the host-side step anatomy — batch assembly,
+  host→device placement, dispatch, scheduler phases, checkpoint I/O — on
+  the profiler's clock, beside the device ops they launched.  With no
+  profiler session open the annotation costs well under a microsecond;
+- when the tracer's own buffer is enabled the span is also recorded as a
+  complete event, and ``TraceEmitter`` writes the standard Chrome
+  trace-event JSON that Perfetto / chrome://tracing load directly (the
+  per-request tracks and other retroactive ``record()`` spans live only
+  here).
 
 Events use the ``ph: "X"`` (complete) form with microsecond timestamps
 relative to tracer construction; ``pid`` is the JAX process index so
-multi-host traces merge cleanly.
+multi-host traces merge cleanly.  ``ds.round`` / ``ds.train_step`` carry
+``host_ns`` (``time.perf_counter_ns()`` at entry) in both sinks, so one
+offset places the Chrome-JSON tracks on the profiler's timeline.
 
 Flow events (``ph: "s"/"t"/"f"``) stitch one request's spans across
 replica trace files into a single causal tree (see ``flow()`` and
@@ -26,8 +33,11 @@ import json
 import os
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Dict, Optional
+
+from jax.profiler import TraceAnnotation
+
+ANNOTATION_PREFIX = "ds."
 
 
 def _flow_scope() -> str:
@@ -35,11 +45,41 @@ def _flow_scope() -> str:
     return FLOW_SCOPE
 
 
+class _Span:
+    """One ``SpanTracer.span()``: the profiler annotation always, the
+    tracer's buffered event when the buffer is enabled."""
+
+    __slots__ = ("tracer", "name", "step", "args", "note", "t0")
+
+    def __init__(self, tracer, name, step, args):
+        self.tracer, self.name, self.step, self.args = (tracer, name, step,
+                                                        args)
+
+    def __enter__(self):
+        args = self.args
+        if self.step is not None:
+            args = dict(args, step=int(self.step))
+        self.note = TraceAnnotation(ANNOTATION_PREFIX + self.name, **args)
+        self.note.__enter__()
+        if self.tracer.enabled:
+            self.t0 = self.tracer.now_us()
+        return self
+
+    def __exit__(self, *exc):
+        tracer = self.tracer
+        if tracer.enabled:
+            tracer.record(self.name, self.t0, tracer.now_us() - self.t0,
+                          step=self.step, **self.args)
+        self.note.__exit__(*exc)
+        return False
+
+
 class SpanTracer:
     """Records named host-side phase spans.
 
-    ``span()`` is a context manager; when the tracer is disabled it costs one
-    attribute check.  The event buffer is bounded — when full, the oldest
+    ``span()`` is a context manager: always a ``ds.<name>`` profiler
+    annotation (an atomic load when no profiler session is open), plus a
+    buffered event when the tracer is enabled.  The event buffer is bounded — when full, the oldest
     events are dropped and ``dropped_events`` counts them (a watchdog-style
     disclosure rather than silent truncation or unbounded growth).
     """
@@ -71,27 +111,22 @@ class SpanTracer:
         # is relative to its own construction) onto one shared timeline
         self.epoch_unix_time = time.time()
 
-    def _now_us(self) -> float:
-        return (time.perf_counter_ns() - self._epoch_ns) / 1e3
-
     def now_us(self) -> float:
         """Current tracer-epoch timestamp — for callers that measure a span
         themselves (e.g. the async checkpoint writer, whose end is observed
         from a commit callback on another thread) and record() it after the
         fact.  record()/span() append to a deque, so recording from a
         background thread is safe."""
-        return self._now_us()
+        return (time.perf_counter_ns() - self._epoch_ns) / 1e3
 
-    @contextmanager
+    def us_of(self, t_seconds: float) -> float:
+        """A ``time.perf_counter()`` reading on the tracer's microsecond
+        epoch — for lifecycle timestamps taken elsewhere and recorded after
+        the fact (the serving request tracks)."""
+        return t_seconds * 1e6 - self._epoch_ns / 1e3
+
     def span(self, name: str, step: Optional[int] = None, **args):
-        if not self.enabled:
-            yield
-            return
-        t0 = self._now_us()
-        try:
-            yield
-        finally:
-            self.record(name, t0, self._now_us() - t0, step=step, **args)
+        return _Span(self, name, step, args)
 
     def record(self, name: str, ts_us: float, dur_us: float,
                step: Optional[int] = None, tid: int = 0,
