@@ -69,6 +69,15 @@ class PagedKVCache(NamedTuple):
     tile with a 128-aligned lane dim for EVERY hd % 8 == 0 model, which is
     what the Pallas paged/prefill kernels DMA (ops/paged_attention.py).
 
+    The pool is row-major in memory as created and stays so, in the compute
+    dtype (or int8): the kernels are custom calls that take it no other
+    way, so every step program reshapes it (free) to [L * num_blocks, ...],
+    writes into that in place (``_kv_write``) and hands the kernels that
+    same flat pool with the layer's first page added to the block table.
+    Nothing slices a layer out of it, casts it or prefers another layout
+    for it: each of those is a copy of a layer's pages or of the whole pool
+    in every step (tests/test_chip_compile.py).
+
     int8 quantized mode (``kv_quant="int8"``): k/v hold int8 codes and
     ``k_scale``/``v_scale`` hold the per-(page, head, token) fp32 scales,
     [L, num_blocks, nkv, block_size] — amax-over-head-dim granularity, the
@@ -261,40 +270,160 @@ def _embed_tokens(bb, tokens, positions, cfg):
     return x
 
 
-def _kv_write(flat_k, flat_v, flat_ks, flat_vs, k, v, page_li, off, km):
-    """Paged KV append (reference linear_blocked_kv_rotary): scatter one
-    layer's new ``k``/``v`` rows [N, nkv, hd] into their pages of the flat
-    [L * NB, nkv, ...] pool views (quantised first when the pool is int8;
-    rows whose ``page_li`` is out of range are dropped).  Scope
-    ``kv_write``."""
+def _write_plan(block_table, row_slot, row_pos, block_size: int,
+                rows_per_slot: int, km: bool):
+    """Where a step's new k/v rows go in a layer's pages: what ``_kv_write``
+    needs besides the rows, the same for every layer and so computed once a
+    step.  Scope ``kv_write``.
+
+    Row ``n`` of the step's [N, nkv, hd] k/v belongs to slot ``row_slot[n]``
+    (``S``, out of range, for a pad or an inactive slot: such a row is
+    dropped, never clamped to a real slot, whose rows it would overwrite)
+    and holds position ``row_pos[n]``.
+
+    Standard pages ([nkv, bs, hd]): per row, its page and its offset in it.
+
+    kv-major pages ([nkv, hd, bs]) are written a page at a time, so the
+    plan is per page.  A slot's rows are one run of the step's rows, in
+    position order, for contiguous positions (ragged.py packs them so; the
+    dense [S, G] verify layout and the one-row decode are the same thing):
+    at most ``rows_per_slot`` (static), which touch at most
+    ``J = (rows_per_slot + block_size - 2) // block_size + 1`` pages (1 for
+    a single row).  Over the ``S * J`` candidates: the page, the index among
+    the step's rows of the page's token 0 (negative where the run starts
+    inside the page), and the tokens ``lo <= r < hi`` of the page that are
+    written (none, ``hi <= lo``, where the candidate holds no row of the
+    step)."""
+    S, MB = block_table.shape
+    N = row_slot.shape[0]
     with jax.named_scope("kv_write"):
-        if flat_ks is not None:
-            k, ks = quantize_kv_token(k)              # [N,nkv,hd], [N,nkv]
-            v, vs = quantize_kv_token(v)
-            flat_ks = flat_ks.at[page_li, :, off].set(ks, mode="drop")
-            flat_vs = flat_vs.at[page_li, :, off].set(vs, mode="drop")
-        # kv-major pages [P, nkv, hd, bs]: token offset is the LANE index
-        at = (page_li, slice(None), slice(None), off) if km else (
-            page_li, slice(None), off)
-        flat_k = flat_k.at[at].set(k.astype(flat_k.dtype), mode="drop")
-        flat_v = flat_v.at[at].set(v.astype(flat_v.dtype), mode="drop")
-    return flat_k, flat_v, flat_ks, flat_vs
+        if not km:
+            page = block_table[jnp.minimum(row_slot, S - 1),
+                               row_pos // block_size]
+            return page, row_pos % block_size, row_slot < S
+        big = jnp.iinfo(jnp.int32).max
+        counts = jnp.zeros((S,), jnp.int32).at[row_slot].add(1, mode="drop")
+        live = counts > 0
+
+        def first(x):                       # of each slot's run; 0 if none
+            return jnp.where(live, jnp.full((S,), big, jnp.int32).at[
+                row_slot].min(x, mode="drop"), 0)
+        starts = first(row_pos)
+        row0 = first(jnp.arange(N, dtype=jnp.int32))
+        J = (rows_per_slot + block_size - 2) // block_size + 1
+        lb = (starts // block_size)[:, None] + jnp.arange(J, dtype=jnp.int32)
+        tok0 = lb * block_size - starts[:, None]   # page token 0, as a row
+        lo = jnp.clip(-tok0, 0, block_size)
+        hi = jnp.clip(counts[:, None] - tok0, 0, block_size)
+        page = jnp.take_along_axis(block_table, jnp.minimum(lb, MB - 1),
+                                   axis=1)
+        start = row0[:, None] + tok0
+    return page.reshape(-1), start.reshape(-1), lo.reshape(-1), hi.reshape(-1)
 
 
-def _layer_pages(flat_k, flat_v, flat_ks, flat_vs, li, NB, dtype=None):
-    """One layer's pages out of the flat pool views, as the attention ops
-    take them: (k_pages, v_pages, scale kwargs).  ``dtype`` casts an
-    unquantised pool for the prefill kernel.  Scope ``kv_pool``."""
-    with jax.named_scope("kv_pool"):
-        k_pages = jax.lax.dynamic_slice_in_dim(flat_k, li * NB, NB)
-        v_pages = jax.lax.dynamic_slice_in_dim(flat_v, li * NB, NB)
-        if flat_ks is not None:
-            return k_pages, v_pages, dict(
-                k_scale=jax.lax.dynamic_slice_in_dim(flat_ks, li * NB, NB),
-                v_scale=jax.lax.dynamic_slice_in_dim(flat_vs, li * NB, NB))
-        if dtype is not None:
-            k_pages, v_pages = k_pages.astype(dtype), v_pages.astype(dtype)
-    return k_pages, v_pages, {}
+def _kv_write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, km,
+              mesh=None):
+    """Paged KV append (reference linear_blocked_kv_rotary): one layer's new
+    ``k``/``v`` rows [N, nkv, hd] go into their pages of the flat
+    [L * NB, nkv, ...] pool views (quantised first when the pool is int8),
+    ``base = li * NB`` being the layer's first page and ``plan`` the step's
+    ``_write_plan``.  Scope ``kv_write``.
+
+    Both writes are shaped for the pool's layout before their own cost: the
+    pool is row-major as created, the Pallas kernels are custom calls that
+    demand it so, and a write that prefers another layout makes the compiler
+    re-lay the whole pool on the way into and out of every step program,
+    keep a second copy through the burst's loop and copy every layer's pages
+    for the kernel (a scatter of [nkv, hd] windows at (page, :, offset) did:
+    XLA's TPU scatter wants scattered dimensions major and window dimensions
+    minor, i.e. the pool token-major).  tests/test_chip_compile.py holds the
+    compiled programs to it.
+
+    Standard pages: a scatter of [hd] rows at (page, head, offset), i.e.
+    rows of the pool seen as [L * NB * nkv * bs, hd], which has no layout
+    but row-major.  ``N * nkv`` updates instead of ``N``, each ~65-90 ns on
+    a v5e whatever its size: 0.8 ms of a 20.5 ms decode step and 8.6 ms of
+    a 57 ms 512-token mixed step at Mistral-7B's widths (PERF.md, PR 27).
+
+    kv-major pages: a token is a lane of [nkv, hd, bs] and a scatter per
+    lane is no row-major write, so whole pages are read, merged with the new
+    rows by a select, and scattered back by page index: a window that is
+    the page leaves the page index as the only scattered dimension.  On
+    standard pages that costs a decode step 4 ms more than the row scatter
+    (same PR), which is why they do not share it.
+
+    With a ``tp`` axis the pool's kv-head dim is sharded and the write runs
+    per shard under shard_map, as the kernels do: a head's rows go to that
+    head's pages and nowhere else.  Left to the partitioner, the row view
+    folds the sharded head dim into the scattered one, and every chip
+    all-gathers the whole pool each step."""
+    quant = flat_ks is not None
+    pools = (flat_k, flat_v) + ((flat_ks, flat_vs) if quant else ())
+    write = functools.partial(_kv_write_local, base=base, km=km)
+    if (mesh is not None and mesh.shape.get("tp", 1) > 1
+            and k.shape[1] % mesh.shape["tp"] == 0):
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        def heads(a):
+            return P(None, "tp", *(None,) * (a.ndim - 2))
+        pool_specs = tuple(heads(p) for p in pools)
+        write = shard_map(
+            write, mesh=mesh,
+            in_specs=(pool_specs, heads(k), heads(v), tuple(P() for _ in plan)),
+            out_specs=pool_specs, check_vma=False)
+    with jax.named_scope("kv_write"):
+        pools = write(pools, k, v, plan)
+    return pools if quant else pools + (None, None)
+
+
+def _kv_write_local(pools, k, v, plan, *, base, km):
+    """``_kv_write`` on the kv heads at hand: (k, v[, k_scale, v_scale])
+    pools in, the same out."""
+    big = jnp.iinfo(jnp.int32).max
+    new = (k, v)
+    if len(pools) == 4:
+        k, ks = quantize_kv_token(k)                  # [N,nkv,hd], [N,nkv]
+        v, vs = quantize_kv_token(v)
+        new = (k, v, ks, vs)
+    if not km:
+        page, off, live = plan
+        nkv, bs = pools[0].shape[1:3]
+        # (page, head, offset) as a row of the pool seen as
+        # [L * NB * nkv * bs, hd]: the form XLA brings this scatter to
+        # anyway, and written so it keeps its scope in the trace
+        row = (((base + page)[:, None] * nkv + jnp.arange(nkv)) * bs
+               + off[:, None])
+        row = jnp.where(live[:, None], row, big).reshape(-1)
+
+        def put(pool, x):              # x [N, nkv, hd], or [N, nkv] scales
+            rows = pool.reshape((-1,) + pool.shape[3:])
+            x = x.reshape((-1,) + x.shape[2:]).astype(pool.dtype)
+            return rows.at[row].set(x, mode="drop").reshape(pool.shape)
+        return tuple(put(pool, x) for pool, x in zip(pools, new))
+
+    page, start, lo, hi = plan
+    bs = pools[0].shape[3]
+    tok = jnp.arange(bs, dtype=jnp.int32)
+    fresh = (tok >= lo[:, None]) & (tok < hi[:, None])            # [W, bs]
+    dst = jnp.where(hi > lo, base + page, big)
+    src = jnp.minimum(dst, pools[0].shape[0] - 1)      # dropped: any page
+
+    def merge(pool, x):
+        """x [N, nkv, ...] step rows over the pages they land in."""
+        xp = jnp.pad(x, ((bs, bs),) + ((0, 0),) * (x.ndim - 1))
+        win = jax.vmap(lambda s: jax.lax.dynamic_slice_in_dim(
+            xp, s, bs))(start + bs)                     # [W, bs, nkv, ...]
+        rows = jnp.moveaxis(win, 1, -1)                 # [W, nkv, ..., bs]
+        mask = fresh.reshape((-1,) + (1,) * (rows.ndim - 2) + (bs,))
+        return pool.at[dst].set(
+            jnp.where(mask, rows.astype(pool.dtype), pool[src]), mode="drop")
+    return tuple(merge(pool, x) for pool, x in zip(pools, new))
+
+
+def _layer_kv(flat_ks, flat_vs):
+    """Scale kwargs of the attention ops for an int8 pool."""
+    return {} if flat_ks is None else dict(k_scale=flat_ks, v_scale=flat_vs)
 
 
 def _head(params, bb, x, cfg, mesh=None, rows=None):
@@ -445,20 +574,16 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     N = tokens.shape[0]
     S, MB = block_table.shape
     Q = max_q_per_seq
+    km = kv_major_layout(cfg)
     valid = token_slot >= 0                # [N]
 
     # ---- embed (reference ragged_ops/embed) ----
     x = _embed_tokens(bb, tokens, token_pos, cfg)
 
-    big = jnp.iinfo(jnp.int32).max
-    with jax.named_scope("kv_write"):
-        # scatter destinations in the page pool; pad tokens get an
-        # out-of-range index so mode="drop" discards them (never index-clamp
-        # pads to slot 0: duplicate scatter indices would corrupt real rows)
-        blk_idx = token_pos // block_size                        # [N]
-        page = block_table[jnp.clip(token_slot, 0), blk_idx]     # [N]
-        off = token_pos % block_size                             # [N]
-        scat_slot = jnp.where(valid, token_slot, S)      # S = out of range
+    # pad tokens get an out-of-range slot so mode="drop" discards them
+    # (never index-clamp pads to slot 0: duplicate scatter indices would
+    # corrupt real rows)
+    scat_slot = jnp.where(valid, token_slot, S)          # S = out of range
     with jax.named_scope("attn_kernel"):
         # per-slot live q rows + their first logical position (each slot's
         # batch tokens are one CONTIGUOUS span ending at kv_len: SplitFuse
@@ -466,13 +591,15 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
         q_counts = jnp.zeros((S,), jnp.int32).at[scat_slot].add(
             1, mode="drop")
         q_starts = kv_len - q_counts
+    plan = _write_plan(block_table, scat_slot, token_pos, block_size, Q, km)
 
     # [L * num_blocks, nkv, …] views updated IN PLACE through the donated
     # cache buffer — never rebuild the whole pool (a jnp.stack of per-layer
-    # copies costs a full cache rewrite per step)
+    # copies costs a full cache rewrite per step), and never slice a layer
+    # out of it: the attention ops take the flat pool with the layer's
+    # first page added to the block table
     NB = cache.k.shape[1]
-    km = kv_major_layout(cfg)
-    flat_k_all, flat_v_all, flat_ks, flat_vs = _flat_cache_views(cache)
+    flat_k_all, flat_v_all, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
 
     # multi-tenant LoRA (static trace-time branch — adapter-less engines
     # send no "lora" key and trace the identical program): per-TOKEN
@@ -501,10 +628,8 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                 q, k = q[0], k[0]
 
         flat_k_all, flat_v_all, flat_ks, flat_vs = _kv_write(
-            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v,
-            jnp.where(valid, li * NB + page, big), off, km)
-        k_pool, v_pool, kv_extra = _layer_pages(
-            flat_k_all, flat_v_all, flat_ks, flat_vs, li, NB, dtype=dtype)
+            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v, plan, li * NB,
+            km, mesh=mesh)
 
         # ---- ragged blocked attention (reference blocked_flash +
         # atom_builder): dense-per-slot q layout, per-slot contiguous
@@ -525,11 +650,12 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                     cfg.num_heads, cfg.head_dim, cfg.alibi_prescale))
             o_dense = ops.ragged_prefill_attention(
                 q_dense.reshape(S, Q, nkv, gq, hd).astype(dtype),
-                k_pool, v_pool, block_table, kv_len,
+                flat_k_all, flat_v_all, block_table + li * NB, kv_len,
                 q_starts, q_counts, scale=cfg.attn_scale,
                 alibi_slopes=slopes, window=win, mesh=mesh, kv_major=km,
                 impl=cfg.attn_impl,
-                **kv_extra).reshape(S, Q, cfg.num_heads, hd)
+                **_layer_kv(flat_ks, flat_vs)).reshape(S, Q, cfg.num_heads,
+                                                       hd)
             o = o_dense[jnp.clip(token_slot, 0), dense_idx]   # [N, nh, hd]
             o = jnp.where(valid[:, None, None], o, 0)
         with jax.named_scope("attn_out"):
@@ -571,10 +697,8 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
 
     x = _embed_tokens(bb, tokens, token_pos, cfg)              # [S, H]
 
-    big = jnp.iinfo(jnp.int32).max
-    with jax.named_scope("kv_write"):
-        page = block_table[jnp.arange(S), token_pos // block_size]  # [S]
-        off = token_pos % block_size                                # [S]
+    plan = _write_plan(block_table, jnp.where(active, jnp.arange(S), S),
+                       token_pos, block_size, 1, km)
     with jax.named_scope("attn_kernel"):
         kv_len = jnp.where(active, token_pos + 1, 0)                # [S]
     if lora is not None:
@@ -598,10 +722,8 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                 q, k = q[:, 0], k[:, 0]
 
         flat_k_all, flat_v_all, flat_ks, flat_vs = _kv_write(
-            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v,
-            jnp.where(active, li * NB + page, big), off, km)
-        k_pages, v_pages, kv_extra = _layer_pages(
-            flat_k_all, flat_v_all, flat_ks, flat_vs, li, NB)
+            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v, plan, li * NB,
+            km, mesh=mesh)
         with jax.named_scope("attn_kernel"):
             qg = q.reshape(S, nkv, g, hd)
             slopes = None
@@ -610,11 +732,12 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                 slopes = jnp.asarray(alibi_slopes(nh, hd,
                                                   cfg.alibi_prescale))
             win = cfg.window_for_layer(li)
-            o = ops.paged_attention(qg, k_pages, v_pages, block_table,
-                                    kv_len, alibi_slopes=slopes, window=win,
+            o = ops.paged_attention(qg, flat_k_all, flat_v_all,
+                                    block_table + li * NB, kv_len,
+                                    alibi_slopes=slopes, window=win,
                                     scale=cfg.attn_scale, mesh=mesh,
                                     kv_major=km, impl=cfg.attn_impl,
-                                    **kv_extra)
+                                    **_layer_kv(flat_ks, flat_vs))
             o = o.reshape(S, nh, hd)
         with jax.named_scope("attn_out"):
             attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
@@ -625,9 +748,19 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     return logits, flat_k_all, flat_v_all, flat_ks, flat_vs
 
 
-def _flat_cache_views(cache: PagedKVCache):
-    """[L, NB, ...] pool -> the [L * NB, ...] views the layers index.
-    Scope ``kv_pool``, like everything that only moves the pool."""
+def _flat_cache_views(cache: PagedKVCache, cfg: GPTConfig):
+    """[L, NB, ...] pool -> the flat [L * NB, ...] views that every layer
+    writes (``_kv_write``) and attends over (the attention ops, with the
+    layer's first page ``li * NB`` added to the block table) in place: the
+    reshape is free, and nothing downstream slices, casts or re-lays the
+    pool.  So an unquantised pool has to be in the compute dtype already,
+    as the engine creates it.  Scope ``kv_pool``, like everything that only
+    moves the pool."""
+    if not cache.quantized and cache.k.dtype != jnp.dtype(
+            cfg.dtype or jnp.float32):
+        raise ValueError(
+            f"KV pool is {cache.k.dtype} but the model computes in "
+            f"{cfg.dtype}: create the pool in the compute dtype")
     with jax.named_scope("kv_pool"):
         fk = cache.k.reshape((-1,) + cache.k.shape[2:])
         fv = cache.v.reshape((-1,) + cache.v.shape[2:])
@@ -665,7 +798,7 @@ def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
     be pre-allocated.
     Returns (tokens [T, S], prev_tokens' [S], rng', cache).
     """
-    flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache)
+    flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
     bt = batch["block_table"]
     active = batch["active"]
     lora = batch.get("lora")
@@ -820,13 +953,10 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
     positions = pos0[:, None] + jnp.arange(G, dtype=jnp.int32)[None]  # [S,G]
     x = _embed_tokens(bb, tokens, positions, cfg)                     # [S,G,H]
 
-    big = jnp.iinfo(jnp.int32).max
-    with jax.named_scope("kv_write"):
-        flat_pos = positions.reshape(-1)                              # [S*G]
-        page = block_table[
-            jnp.repeat(jnp.arange(S), G), flat_pos // block_size]     # [S*G]
-        off = flat_pos % block_size
-        act_flat = jnp.repeat(active, G)
+    q_counts = jnp.where(active, G, 0).astype(jnp.int32)
+    plan = _write_plan(
+        block_table, jnp.repeat(jnp.where(active, jnp.arange(S), S), G),
+        positions.reshape(-1), block_size, G, km)
     with jax.named_scope("attn_kernel"):
         kv_len = jnp.where(active, pos0 + G, 0)
 
@@ -842,10 +972,7 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
                             seq_lens=kv_len[:, None])
         flat_k, flat_v, flat_ks, flat_vs = _kv_write(
             flat_k, flat_v, flat_ks, flat_vs, k.reshape(S * G, nkv, hd),
-            v.reshape(S * G, nkv, hd),
-            jnp.where(act_flat, li * NB + page, big), off, km)
-        k_pool, v_pool, kv_extra = _layer_pages(
-            flat_k, flat_v, flat_ks, flat_vs, li, NB, dtype=dtype)
+            v.reshape(S * G, nkv, hd), plan, li * NB, km, mesh=mesh)
         with jax.named_scope("attn_kernel"):
             slopes = None
             if cfg.use_alibi:
@@ -854,12 +981,11 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
                                                   cfg.alibi_prescale))
             win = cfg.window_for_layer(li)
             o = ops.ragged_prefill_attention(
-                q.reshape(S, G, nkv, g, hd).astype(dtype), k_pool, v_pool,
-                block_table, kv_len, pos0,
-                jnp.where(active, G, 0).astype(jnp.int32),
+                q.reshape(S, G, nkv, g, hd).astype(dtype), flat_k, flat_v,
+                block_table + li * NB, kv_len, pos0, q_counts,
                 scale=cfg.attn_scale, alibi_slopes=slopes, window=win,
                 mesh=mesh, kv_major=km, impl=cfg.attn_impl,
-                **kv_extra).reshape(S, G, nh, hd)
+                **_layer_kv(flat_ks, flat_vs)).reshape(S, G, nh, hd)
             # inactive slots (kv_len=0, q_counts=0) produce 0/0 garbage from
             # the kernel combine; zero them like ragged_forward does so no
             # future cross-row op (capacity MoE, aux stats) can see NaNs
@@ -901,8 +1027,8 @@ def _speculative_burst_core(params, draft_params, cache: PagedKVCache,
     Returns (toks [steps, gamma+1, S], counts [steps, S], prev', rng',
     cache', draft_cache') — the first counts[k, s] of toks[k, :, s] are
     real."""
-    fk, fv, fks, fvs = _flat_cache_views(cache)
-    dk, dv, dks, dvs = _flat_cache_views(draft_cache)
+    fk, fv, fks, fvs = _flat_cache_views(cache, cfg)
+    dk, dv, dks, dvs = _flat_cache_views(draft_cache, draft_cfg)
     bt = batch["block_table"]
     active = batch["active"]
     prev0 = jnp.where(batch["from_device"], prev_tokens, batch["tokens0"])
@@ -1091,7 +1217,7 @@ def ragged_decode_forward(params, cache: PagedKVCache, batch,
     batch: tokens [S], active [S] bool, token_pos [S] (position being written),
     block_table [S, MB] int32 (each slot's physical pages, in order).
     """
-    flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache)
+    flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
     logits, flat_k, flat_v, flat_ks, flat_vs = _decode_core(
         params, flat_k, flat_v, batch["tokens"], batch["active"],
         batch["token_pos"], batch["block_table"], cfg, block_size, mesh=mesh,

@@ -26,6 +26,17 @@ Layouts: q [S, nkv, g, hd]; k_pages/v_pages [NB, nkv, bs, hd] (bs = tokens
 per page); block_table [S, MB] int32; kv_lens [S] int32 (0 ⇒ inactive slot →
 zero output).  Output [S, nkv, g, hd].
 
+The pages are row-major in memory: the kernels DMA ``hbm.at[page, head]``
+slabs and, being custom calls, get their operands in no other layout (the
+compiler copies an operand that some other op keeps otherwise).  ``NB`` is
+whatever the block table indexes: k_pages/v_pages (and the int8 scales) may
+be the flat pool of ALL layers, [L * NB, ...], with the layer's first page
+``li * NB`` added to the block table.  That is how the serving step programs
+call both ops (inference/v2/model.py), so that no layer's pages are ever
+sliced out of the pool; the kernels read ``bt_ref[s, p]`` and the XLA
+fallbacks gather ``pages[block_table]``, neither cares how many pages lie
+beyond the table's.
+
 kv-major layout (``kv_major=True``): pages are stored TRANSPOSED,
 [NB, nkv, hd, bs].  Mosaic requires a DMA slab's lane (last) dimension to be
 128-aligned; with the standard layout that means hd % 128 == 0, which

@@ -61,3 +61,76 @@ def make_lm_batch(rng, batch, seq, vocab):
     """Synthetic memorization task batch."""
     ids = rng.integers(0, vocab, size=(batch, seq), dtype=np.int64).astype(np.int32)
     return {"input_ids": ids}
+
+
+def lower_serving_steps(cfg, cache_dtype, *, slots, tokens, max_q, table_width,
+                        block_size, num_pages, steps, quant=None,
+                        sharding=None, mesh=None):
+    """The three serving step programs (mixed, single decode, fused burst)
+    lowered from shapes alone, as the engine jits them (cache donated,
+    greedy in-graph sampling): (params, cache, {name: Lowered}).
+    ``sharding`` places every argument, for a device that is described and
+    not attached; ``mesh`` (with a ``tp`` axis) shards parameters and pool
+    as the engine does over it, and replicates the rest."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.engine import _sample_token
+    from deepspeed_tpu.inference.v2 import model as v2model
+    from deepspeed_tpu.models.gpt import GPTLogits
+    from deepspeed_tpu.parallel.metadata import unbox
+    S, N, MB = slots, tokens, table_width
+    i32, b1 = jnp.int32, jnp.bool_
+
+    def sd(shape, dtype, at=None):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=at or sharding)
+    boxed = jax.eval_shape(
+        lambda k: GPTLogits(cfg).init(k, jnp.zeros((1, 8), i32)),
+        jax.random.PRNGKey(0))["params"]
+    params = unbox(boxed)
+    cache = jax.eval_shape(lambda: v2model.PagedKVCache.create(
+        cfg, num_pages, block_size, cache_dtype, quant=quant))
+    if mesh is None:
+        placed = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype),
+                                        (params, cache))
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from deepspeed_tpu.parallel import partition
+        from deepspeed_tpu.parallel.metadata import annotate_abstract
+        sharding = NamedSharding(mesh, P())
+        placed = (
+            jax.tree_util.tree_map(
+                lambda a, s: sd(a.shape, a.dtype, s), params,
+                partition.param_shardings(annotate_abstract(boxed), mesh,
+                                          zero_stage=0)),
+            jax.tree_util.tree_map(
+                lambda a: sd(a.shape, a.dtype, NamedSharding(mesh, P(
+                    None, None, "tp", *(None,) * (a.ndim - 3)))), cache))
+    slot = {"active": sd((S,), b1), "block_table": sd((S, MB), i32),
+            "from_device": sd((S,), b1)}
+    programs = {
+        "ragged_forward_sampled": (
+            dict(max_q_per_seq=max_q),
+            {"tokens": sd((N,), i32), "token_slot": sd((N,), i32),
+             "token_pos": sd((N,), i32), "token_dense_idx": sd((N,), i32),
+             "block_table": sd((S, MB), i32), "kv_len": sd((S,), i32),
+             "from_device": sd((N,), b1), "served": sd((S,), b1)}),
+        "ragged_decode_sampled": (
+            {}, {**slot, "tokens": sd((S,), i32), "token_pos": sd((S,), i32),
+                 "served": sd((S,), b1)}),
+        "ragged_decode_burst": (
+            dict(steps=steps), {**slot, "tokens0": sd((S,), i32),
+                                "pos0": sd((S,), i32)}),
+    }
+    sample = functools.partial(_sample_token, do_sample=False, top_k=0)
+    lowered = {}
+    for name, (static, batch) in programs.items():
+        fn = functools.partial(getattr(v2model, name), cfg=cfg,
+                               block_size=block_size, sample_fn=sample,
+                               mesh=mesh, **static)
+        lowered[name] = jax.jit(fn, donate_argnums=(1,)).lower(
+            *placed, batch, sd((S,), i32), sd((2,), jnp.uint32),
+            sd((), jnp.float32), sd((), jnp.float32))
+    return placed[0], placed[1], lowered

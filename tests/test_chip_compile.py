@@ -9,7 +9,9 @@ persistent compile cache is off around them (such a compile can be written to
 it but not read back without a chip).
 """
 
+import dataclasses
 import os
+import re
 import subprocess
 import sys
 
@@ -237,6 +239,112 @@ def test_refused_layouts_are_really_refused(topo, hd, bs, kv_major, quant):
     assert not _dma_layout_ok(hd, bs, kv_major, quant)
     with pytest.raises(Exception, match="aligned|tiling|[Nn]ot implemented"):
         decode_text(topo, 8, 4, hd, bs, kv_major, quant)
+
+
+# ------------------------------------------------- the pool stays in place
+
+# geometry -> (model, kv_quant, tp, most scratch of the burst): 2 layers at
+# Mistral-7B's widths and GPT-2-small's kv-major geometry, 256 pages of 128
+# a chip.
+# The burst's scratch does not depend on the pool (measured the same at 256
+# and at 512 pages: 52.8 MB bf16, 71.0-71.3 MB int8, 3.5 MB GPT-2-small,
+# 5.3 MB a chip at tp=2: layout copies of attention weights and, for int8,
+# of the step's codes), so the limit is a number of bytes: one layer's k
+# pages more (67 MB bf16, 34 MB int8, 50 MB GPT-2-small) would pass it.
+# For bf16 that is under half the pool's 268 MB; half of the int8 pool is
+# 69 MB, less than what never was the pool.
+MISTRAL_2L = GPTConfig.llama(num_layers=2, hidden=4096, heads=32,
+                             num_kv_heads=8, vocab_size=32768,
+                             max_seq_len=4096)
+POOL_GEOMETRIES = {
+    "hd128-bf16": (MISTRAL_2L, None, 1, 64 << 20),
+    "hd128-int8kv": (MISTRAL_2L, "int8", 1, 80 << 20),
+    "gpt2s-bf16": (dataclasses.replace(GPT2S, num_layers=2), None, 1,
+                   16 << 20),
+    "hd128-bf16-tp2": (MISTRAL_2L, None, 2, 16 << 20),
+    "gpt2s-bf16-tp2": (dataclasses.replace(GPT2S, num_layers=2), None, 2,
+                       16 << 20),
+}
+POOL_MOVERS = ("copy", "slice", "dynamic-slice", "copy-start", "slice-start",
+               "all-gather", "all-gather-start", "all-to-all")
+_HLO_OP = re.compile(
+    r"^\s*(?:ROOT\s+)?\S+ = (\(?[a-z0-9]+\[.*?) ([a-z][a-z-]*)\(", re.M)
+_HLO_ARRAY = re.compile(r"([a-z]+[0-9]+)\[([0-9,]*)\]")
+_HLO_DTYPE = {"bfloat16": "bf16", "int8": "s8"}
+
+
+@pytest.fixture(scope="module")
+def step_programs(topo):
+    """``get(geometry)`` -> (cfg, parameter shapes, cache shapes, {program:
+    compiled}): the serving step programs compiled for the described chip
+    (for ``tp`` of its chips, parameters and pool sharded as the engine
+    shards them) at 32 slots, 512 tokens a forward, 256 a prompt and 256
+    pages of 128 a chip: under ``tp`` a chip holds half the heads of twice
+    the pages (a smaller shard the compiler moves into VMEM whole, which a
+    real pool never fits)."""
+    from conftest import lower_serving_steps
+    from deepspeed_tpu.constants import MESH_AXES
+    done = {}
+
+    def get(geometry):
+        if geometry not in done:
+            base, quant, tp, _ = POOL_GEOMETRIES[geometry]
+            cfg = dataclasses.replace(base, dtype=BF16, param_dtype=BF16,
+                                      attn_impl="pallas")
+            where = dict(sharding=SingleDeviceSharding(topo.devices[0]))
+            if tp > 1:
+                where = dict(mesh=Mesh(np.asarray(topo.devices[:tp]).reshape(
+                    (1,) * (len(MESH_AXES) - 1) + (tp,)), MESH_AXES))
+            params, cache, lowered = lower_serving_steps(
+                cfg, BF16, slots=32, tokens=512, max_q=256,
+                table_width=cfg.max_seq_len // 128, block_size=128,
+                num_pages=256 * tp, steps=8, quant=quant, **where)
+            done[geometry] = cfg, params, cache, {
+                name: low.compile() for name, low in lowered.items()}
+        return done[geometry]
+    return get
+
+
+@pytest.mark.parametrize("program", ["ragged_forward_sampled",
+                                     "ragged_decode_sampled",
+                                     "ragged_decode_burst"])
+@pytest.mark.parametrize("geometry", sorted(POOL_GEOMETRIES))
+def test_step_programs_leave_the_pool_in_place(step_programs, monkeypatch,
+                                               geometry, program):
+    """No step program copies, slices, re-lays or gathers from other chips a
+    layer's pages or more: the KV write keeps the pool row-major, as the
+    Pallas kernels demand it, and local to its kv heads, and the kernels
+    index the flat pool (model.py ``_kv_write``).  A write that prefers
+    another layout brings back a whole-pool ``copy`` on the way in and out
+    of every program, a per-layer ``slice`` + ``copy`` for each kernel call,
+    and a second pool as the burst's scratch; one that the partitioner
+    cannot keep on the head shard, an ``all-gather`` of the pool."""
+    # the kernels ask jax.default_backend() whether to interpret; here it
+    # says "cpu" and the program under test is the chip's
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, cache, compiled = step_programs(geometry)
+    text = compiled[program].as_text()
+    assert text.count(KERNEL) >= cfg.num_layers
+
+    def on_a_chip(a):                   # the program's shapes are a chip's
+        return a.sharding.shard_shape(a.shape)
+    layer = int(np.prod(on_a_chip(cache.k)[1:]))
+    dtype = _HLO_DTYPE[cache.k.dtype.name]
+    # an embedding table can be as large as a layer's pages, and is not one
+    weights = {",".join(map(str, on_a_chip(w)))
+               for w in jax.tree_util.tree_leaves(params)}
+    moved = []
+    for result, op in _HLO_OP.findall(text):
+        if op not in POOL_MOVERS:
+            continue
+        for dt, dims in _HLO_ARRAY.findall(result):
+            n = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+            if dt == dtype and n >= layer and dims not in weights:
+                moved.append(f"{op} -> {dt}[{dims}]")
+    assert not moved, moved
+    if program == "ragged_decode_burst":
+        temp = compiled[program].memory_analysis().temp_size_in_bytes
+        assert temp < POOL_GEOMETRIES[geometry][3], temp
 
 
 # -------------------------------------------------------- quantized GEMMs

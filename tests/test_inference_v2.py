@@ -237,6 +237,169 @@ class TestInt8KVCache:
         assert agree / total > 0.7, (agree, total)
 
 
+def _scatter_write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, km,
+                   mesh=None):
+    """The plain write, the reference for ``model._kv_write``: a scatter of
+    [nkv, hd] windows at (page, :, offset), one row at a time, as the model
+    wrote until the pool had to stay row-major (it made the compiler re-lay
+    the pool around every step program; the values are the same)."""
+    from deepspeed_tpu.inference.v2.model import quantize_kv_token
+    big = jnp.iinfo(jnp.int32).max
+    if km:      # per-page plan -> (page, offset, row) of every written token
+        page, start, lo, hi = plan
+        bs = flat_k.shape[3]
+        r = jnp.arange(bs)[None, :]
+        live = ((r >= lo[:, None]) & (r < hi[:, None])).reshape(-1)
+        row = jnp.clip((start[:, None] + r).reshape(-1), 0, k.shape[0] - 1)
+        k, v = k[row], v[row]
+        page, off = jnp.repeat(page, bs), jnp.tile(jnp.arange(bs), len(lo))
+    else:
+        page, off, live = plan
+    page = jnp.where(live, base + page, big)
+    if flat_ks is not None:
+        k, ks = quantize_kv_token(k)
+        v, vs = quantize_kv_token(v)
+        flat_ks = flat_ks.at[page, :, off].set(ks, mode="drop")
+        flat_vs = flat_vs.at[page, :, off].set(vs, mode="drop")
+    at = (page, slice(None), slice(None), off) if km else (
+        page, slice(None), off)
+    return (flat_k.at[at].set(k.astype(flat_k.dtype), mode="drop"),
+            flat_v.at[at].set(v.astype(flat_v.dtype), mode="drop"),
+            flat_ks, flat_vs)
+
+
+class TestKVWrite:
+    """``_write_plan`` + ``_kv_write`` against a numpy loop over the rows:
+    the same values in the same places, pads and inactive slots dropped,
+    every other token of the pool untouched — for the three shapes a step
+    has (one row a slot; a packed ragged batch with runs that start and end
+    inside pages; the dense verify layout), both page layouts, int8, and
+    with the kv heads sharded over a ``tp`` mesh."""
+
+    S, MB, NB, L, nkv, hd, bs = 4, 4, 16, 2, 2, 8, 8
+
+    def _step(self, shape):
+        """(row_slot [N] with S for dropped rows, row_pos [N],
+        rows_per_slot)."""
+        S = self.S
+        if shape == "decode":        # slot 1 inactive; last token of a page
+            return (np.array([0, S, 2, 3]), np.array([0, 5, 15, 19]), 1)
+        if shape == "mixed":
+            # slot 0: 7 rows from 5 (crosses a page, ends inside one);
+            # slot 1: nothing; slot 2: exactly one whole page; slot 3: 3 rows
+            # over a boundary; 6 pad rows at the end
+            runs = [(0, 5, 7), (2, 0, 8), (3, 14, 3)]
+            slot = np.concatenate([np.full(n, s) for s, _, n in runs]
+                                  + [np.full(6, S)])
+            pos = np.concatenate([np.arange(p, p + n) for _, p, n in runs]
+                                 + [np.zeros(6, int)])
+            return slot, pos, 8
+        G = 3                        # verify: dense [S, G], slot 2 inactive
+        active = np.array([True, True, False, True])
+        pos0 = np.array([6, 0, 3, 13])
+        slot = np.repeat(np.where(active, np.arange(S), S), G)
+        pos = (pos0[:, None] + np.arange(G)[None]).reshape(-1)
+        return slot, pos, G
+
+    @pytest.mark.parametrize("tp", [1, 2], ids=["tp1", "tp2"])
+    @pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8kv"])
+    @pytest.mark.parametrize("km", [False, True], ids=["std", "kvmajor"])
+    @pytest.mark.parametrize("shape", ["decode", "mixed", "verify"])
+    def test_matches_numpy_reference(self, rng, shape, km, quant, tp):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from deepspeed_tpu.inference.v2.model import (_kv_write, _write_plan,
+                                                      quantize_kv_token)
+        from deepspeed_tpu.parallel import mesh as mesh_lib
+        mesh = None if tp == 1 else mesh_lib.build_mesh(
+            mesh_lib.MeshSpec(tp=tp, dp=1, fsdp=1))
+
+        def place(a):       # the pool as the engine holds it: heads sharded
+            if a is None or mesh is None:
+                return None if a is None else jnp.asarray(a)
+            return jax.device_put(a, NamedSharding(mesh, P(
+                None, "tp", *(None,) * (a.ndim - 2))))
+        S, NB, L, nkv, hd, bs = (self.S, self.NB, self.L, self.nkv, self.hd,
+                                 self.bs)
+        slot, pos, per_slot = self._step(shape)
+        N = len(slot)
+        bt = rng.permutation(NB)[:S * self.MB].reshape(S, self.MB)
+        page_shape = (nkv, hd, bs) if km else (nkv, bs, hd)
+        dt = np.int8 if quant else np.float32
+        pools = [rng.integers(-9, 9, (L * NB,) + page_shape).astype(dt)
+                 for _ in range(2)]
+        scales = ([rng.random((L * NB, nkv, bs)).astype(np.float32)
+                   for _ in range(2)] if quant else [None, None])
+        k, v = (rng.standard_normal((N, nkv, hd)).astype(np.float32)
+                for _ in range(2))
+        li = 1
+        plan = _write_plan(jnp.asarray(bt, jnp.int32),
+                           jnp.asarray(slot, jnp.int32),
+                           jnp.asarray(pos, jnp.int32), bs, per_slot, km)
+        got = _kv_write(*(place(a) for a in pools + scales), jnp.asarray(k),
+                        jnp.asarray(v), plan, li * NB, km, mesh=mesh)
+        if mesh is not None:
+            assert got[0].sharding.spec[1] == "tp"
+
+        want = [a.copy() for a in pools] + [
+            None if a is None else a.copy() for a in scales]
+        for x, pool, sc in ((k, want[0], want[2]), (v, want[1], want[3])):
+            rows, row_scales = x, None
+            if quant:
+                rows, row_scales = (np.asarray(a) for a in
+                                    quantize_kv_token(jnp.asarray(x)))
+            for n in range(N):
+                if slot[n] >= S:
+                    continue
+                pg, off = li * NB + bt[slot[n], pos[n] // bs], pos[n] % bs
+                if km:
+                    pool[pg, :, :, off] = rows[n]
+                else:
+                    pool[pg, :, off, :] = rows[n]
+                if quant:
+                    sc[pg, :, off] = row_scales[n]
+        for g, w in zip(got, want):
+            assert (g is None) == (w is None)
+            if w is not None:
+                np.testing.assert_array_equal(np.asarray(g), w)
+
+    @pytest.mark.parametrize("path", ["mixed", "burst", "speculative",
+                                      "hd128"])
+    def test_generation_identical_to_the_plain_scatter(self, cfg, v2cfg, rng,
+                                                       monkeypatch, path):
+        """Greedy tokens through the engine with the model's write and with
+        the plain per-row scatter in its place: mixed SplitFuse steps (more
+        prompts than slots, prompts longer than a chunk), fused decode
+        bursts, and speculative draft + verify over both pools.  The tiny
+        model's heads of 8 take kv-major pages; ``hd128`` runs mixed steps
+        and bursts over standard pages."""
+        from deepspeed_tpu.inference.v2 import model as v2model
+        lens, new = {"mixed": ((9, 23, 5, 30, 12, 7), 6),
+                     "burst": ((9, 14), 16),
+                     "speculative": ((10, 13, 16), 18),
+                     "hd128": ((9, 23, 5, 30, 12, 7), 16)}[path]
+        if path == "hd128":
+            cfg = GPTConfig.llama(num_layers=2, hidden=128, heads=1,
+                                  vocab_size=97, max_seq_len=64)
+            assert not v2model.kv_major_layout(cfg)
+        prompts = [rng.integers(0, 97, (n,)).astype(np.int32) for n in lens]
+
+        def run():
+            eng = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+            if path == "speculative":
+                eng = InferenceEngineV2(cfg, config=v2cfg, params=eng.params,
+                                        draft_model=cfg)
+            out = eng.generate(prompts, max_new_tokens=new)
+            if path == "speculative":
+                assert eng.telemetry.spec_summary()["outer_steps"] > 0
+            return out
+        got = run()
+        monkeypatch.setattr(v2model, "_kv_write", _scatter_write)
+        want = run()
+        for w, g in zip(want, got):
+            np.testing.assert_array_equal(w, g)
+
+
 class TestSpeculative:
     """Greedy draft-and-verify decoding: acceptance is exact token match, so
     for ANY draft the output must be token-identical to target-only greedy
@@ -442,10 +605,15 @@ class TestTensorParallel:
     """v2 ragged serving TP (reference inference/v2/model_implementations/
     sharding/): tp=2 must be token-exact vs tp=1 on the CPU mesh."""
 
-    def test_tp2_generate_token_exact_vs_tp1(self, cfg, rng):
+    @pytest.mark.parametrize("hd", [8, 128], ids=["kvmajor", "hd128"])
+    def test_tp2_generate_token_exact_vs_tp1(self, cfg, rng, hd):
+        """Both page layouts: heads of 8 take kv-major pages and the page
+        write, heads of 128 standard pages and the row write."""
         import dataclasses
+        from deepspeed_tpu.inference.v2.model import kv_major_layout
         cfg2 = dataclasses.replace(cfg, num_heads=4, num_kv_heads=2,
-                                   head_dim=8)
+                                   head_dim=hd)
+        assert kv_major_layout(cfg2) == (hd == 8)
         v2cfg = {"dtype": "fp32",
                  "state_manager": {"max_tracked_sequences": 4,
                                    "max_ragged_batch_size": 64,

@@ -6,7 +6,6 @@ both into numbers.  Everything here runs on the CPU: names, arguments,
 structure and arithmetic, never a time of the device."""
 
 import dataclasses
-import functools
 import os
 import re
 import sys
@@ -29,7 +28,6 @@ import xmeta  # noqa: E402
 import xtrace  # noqa: E402
 
 from deepspeed_tpu.inference.v2 import InferenceEngineV2  # noqa: E402
-from deepspeed_tpu.inference.v2 import model as v2model  # noqa: E402
 from deepspeed_tpu.models import GPTChunkedLoss, GPTConfig  # noqa: E402
 from deepspeed_tpu.parallel.mesh import single_device_mesh  # noqa: E402
 from deepspeed_tpu.telemetry.serving import (  # noqa: E402
@@ -208,46 +206,13 @@ SERVE_SCOPES = ("embed", "attn_qkv", "kv_write", "attn_kernel", "attn_out",
 def serve_lowered():
     """The three serving step programs at a tiny width, lowered as the
     engine jits them."""
-    from deepspeed_tpu.inference.engine import _sample_token
-    from deepspeed_tpu.models.gpt import GPTLogits
-    from deepspeed_tpu.parallel.metadata import unbox
+    from conftest import lower_serving_steps
     cfg = dataclasses.replace(
         GPTConfig.llama(num_layers=2, hidden=64, heads=4, vocab_size=128,
                         max_seq_len=256, dtype=None), dtype=jnp.float32)
-    params = jax.eval_shape(
-        lambda k: unbox(GPTLogits(cfg).init(
-            k, jnp.zeros((1, 8), jnp.int32)))["params"],
-        jax.random.PRNGKey(0))
-    S, MB, N, bs = 4, 8, 64, 16
-    cache = jax.eval_shape(
-        lambda: v2model.PagedKVCache.create(cfg, 32, bs, jnp.float32))
-    sd = jax.ShapeDtypeStruct
-    i32, b1 = jnp.int32, jnp.bool_
-    sample = functools.partial(_sample_token, do_sample=False, top_k=0)
-    slot = {"active": sd((S,), b1), "block_table": sd((S, MB), i32),
-            "from_device": sd((S,), b1)}
-    programs = {
-        "ragged_forward_sampled": (
-            dict(max_q_per_seq=16),
-            {"tokens": sd((N,), i32), "token_slot": sd((N,), i32),
-             "token_pos": sd((N,), i32), "token_dense_idx": sd((N,), i32),
-             "block_table": sd((S, MB), i32), "kv_len": sd((S,), i32),
-             "from_device": sd((N,), b1), "served": sd((S,), b1)}),
-        "ragged_decode_sampled": (
-            {}, {**slot, "tokens": sd((S,), i32), "token_pos": sd((S,), i32),
-                 "served": sd((S,), b1)}),
-        "ragged_decode_burst": (
-            dict(steps=4), {**slot, "tokens0": sd((S,), i32),
-                            "pos0": sd((S,), i32)}),
-    }
-    out = {}
-    for name, (static, batch) in programs.items():
-        fn = functools.partial(getattr(v2model, name), cfg=cfg, block_size=bs,
-                               sample_fn=sample, **static)
-        out[name] = jax.jit(fn, donate_argnums=(1,)).lower(
-            params, cache, batch, sd((S,), i32), sd((2,), jnp.uint32),
-            sd((), jnp.float32), sd((), jnp.float32))
-    return out
+    return lower_serving_steps(cfg, jnp.float32, slots=4, tokens=64,
+                               max_q=16, table_width=8, block_size=16,
+                               num_pages=32, steps=4)[2]
 
 
 PROGRAMS = ("ragged_forward_sampled", "ragged_decode_sampled",

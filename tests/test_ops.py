@@ -766,6 +766,59 @@ class TestRaggedPrefill:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+class TestFlatPool:
+    """The serving step programs hand the attention ops the flat pool of all
+    layers, [L * NB, ...], and the layer's first page ``li * NB`` in the
+    block table (inference/v2/model.py) instead of a slice of the pool: both
+    ops, in both implementations, must read exactly what they read from the
+    layer's own pages."""
+
+    L, LI, NB, S, MB, nkv, g, hd, bs = 3, 1, 12, 4, 3, 2, 2, 16, 8
+
+    def _pool(self, rng):
+        shape = (self.L * self.NB, self.nkv, self.bs, self.hd)
+        k = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        v = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        bt = jnp.asarray(rng.permutation(self.NB).reshape(self.S, self.MB),
+                         jnp.int32)
+        return k, v, bt
+
+    @pytest.mark.parametrize("extra", ["plain", "window", "alibi"])
+    @pytest.mark.parametrize("impl", ["xla", "pallas"])
+    @pytest.mark.parametrize("op", ["decode", "prefill"])
+    def test_offset_table_equals_layer_pages(self, rng, op, impl, extra):
+        from deepspeed_tpu.models.gpt import alibi_slopes
+        from deepspeed_tpu.ops.paged_attention import (
+            pallas_paged_attention, pallas_ragged_prefill,
+            xla_paged_attention, xla_ragged_prefill)
+        k, v, bt = self._pool(rng)
+        lo, hi = self.LI * self.NB, (self.LI + 1) * self.NB
+        kw = {"window": {"window": 5},
+              "alibi": {"alibi_slopes": alibi_slopes(self.nkv * self.g,
+                                                     self.hd)},
+              "plain": {}}[extra]
+        if impl == "pallas":
+            kw["interpret"] = True
+        if op == "decode":
+            fn = {"xla": xla_paged_attention,
+                  "pallas": pallas_paged_attention}[impl]
+            q = jnp.asarray(rng.standard_normal(
+                (self.S, self.nkv, self.g, self.hd)), jnp.float32)
+            rest = (jnp.asarray([0, 5, 16, 24], jnp.int32),)
+        else:
+            fn = {"xla": xla_ragged_prefill,
+                  "pallas": pallas_ragged_prefill}[impl]
+            Q = 8
+            q = jnp.asarray(rng.standard_normal(
+                (self.S, Q, self.nkv, self.g, self.hd)), jnp.float32)
+            counts = jnp.asarray([0, 1, 5, Q], jnp.int32)
+            lens = jnp.asarray([0, 19, 14, Q], jnp.int32)
+            rest = (lens, lens - counts, counts)
+        want = fn(q, k[lo:hi], v[lo:hi], bt, *rest, **kw)
+        got = fn(q, k, v, bt + lo, *rest, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 class TestSparseAttention:
     """Block-sparse attention patterns (reference ops/sparse_attention/)."""
 
