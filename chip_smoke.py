@@ -18,7 +18,9 @@ at GPT-2-small's published size (12 layers, hidden 768, 12 heads of 64, vocab
   logits within a bf16 tolerance, and every generated token the reference's
   argmax (or tied with it) given the same prefix.
 
-``--chips 4`` runs ONLY the sharded path and what it is compared with: ZeRO-3
+``--afmoe`` runs ONLY Trinity-Large-Preview's expert-parallel share (the
+benchmark's configuration, published widths) against its plain float32
+reference with the rows split by routing (``afmoe_phase``).  ``--chips 4`` runs ONLY the sharded path and what it is compared with: ZeRO-3
 ``fsdp=4`` over four chips against a one-device mesh, same model and batches.
 
 Each phase prints one JSON line ("smoke, not a benchmark": the times include
@@ -511,6 +513,294 @@ def sharded_phase(args):
           "compile_cache": cache_counts(c0)})
 
 
+# ----------------------------------------------------------------- afmoe
+
+AFMOE_CONFIG = os.path.join(HERE, "benchmark", "configs",
+                            "trinity-large-preview-5l-ep8.json")
+# A row whose routers chose the reference's local experts is bf16 through
+# five layers: at most 0.018 of its logits' rms and max |d| 0.085 over 17
+# seeds (0.012 / 0.066 over the 8,450 rows of one); one with a local expert
+# flipped reads 0.12-0.35 and 0.6-1.7 (my chip runs, PR 29).
+AFMOE_ROW_REL = 0.03
+AFMOE_ROW_ABS = 0.2
+# the reference's margin between its 4th and 5th score at a row's FIRST
+# disagreement: one bf16 step at scores in [0.5, 1) is 0.0039 (measured: at
+# most 0.0026 over 8,450 rows)
+AFMOE_MARGIN = 0.004
+
+
+def _afmoe_sizes(rehearse):
+    with open(AFMOE_CONFIG) as f:
+        cfg = json.load(f)
+    if rehearse:
+        tiny = cfg["rehearsal"]
+        cfg = {**cfg, **tiny, "run": {**cfg["run"], **tiny["run"]},
+               "tolerances": {**cfg["tolerances"], **tiny["tolerances"]}}
+    return cfg
+
+
+def _afmoe_drive(eng, seqs, n_dec, chunk):
+    """``seqs`` through ``put`` as the benchmark's runner feeds them: every
+    prompt (all but the last ``n_dec`` tokens) in chunks of ``chunk`` rows,
+    then one token a call.  Per sequence: the rows whose logits came back
+    (each chunk's last, every decoded one), the logits, and the experts the
+    routers chose for every row ``[expert layers, T, k]``."""
+    import numpy as np
+    uids = list(range(1, len(seqs) + 1))
+    rows = [[] for _ in seqs]
+    got = [[] for _ in seqs]
+    routes = [[] for _ in seqs]
+    fed = [0] * len(seqs)
+    ends = [len(s) - n_dec for s in seqs]
+    while any(f < len(s) for f, s in zip(fed, seqs)):
+        live = [i for i, s in enumerate(seqs) if fed[i] < len(s)]
+        take = [min(chunk, ends[i] - fed[i]) if fed[i] < ends[i] else 1
+                for i in live]
+        out, r = eng.put([uids[i] for i in live],
+                         [seqs[i][fed[i]:fed[i] + n]
+                          for i, n in zip(live, take)], with_routes=True)
+        for j, (i, n) in enumerate(zip(live, take)):
+            fed[i] += n
+            rows[i].append(fed[i] - 1)
+            got[i].append(out[j])
+            routes[i].append(r[j])
+    released = eng.state.w_released_total
+    eng.flush(uids)
+    return (rows, [np.stack(g).astype(np.float32) for g in got],
+            [np.concatenate(r, axis=1) for r in routes], released)
+
+
+def _afmoe_compare(ref, params, cfg, seqs, rows, got, routes, model_cfg,
+                   want=None):
+    """The runner's statistic (max over the sequences of max |d|, rms error
+    over rms, and how far the engine's greedy token lies behind the
+    reference's best) and, where ``routes`` is given, the rows split by
+    whether the engine and the float32 reference chose the same local
+    experts."""
+    import numpy as np
+    agg = {"max_abs": 0.0, "rel_rms": 0.0, "argmax_gap": 0.0}
+    split = {"rows": 0, "agree_rows": 0, "agree_rel_max": 0.0,
+             "agree_abs_max": 0.0, "flip_rows": 0, "flip_rel_max": 0.0,
+             "flip_abs_max": 0.0, "first_flip_margin_max": 0.0}
+    wants = []
+    lo, held = model_cfg.expert_offset, model_cfg.local_experts
+    for si, (s, rr, g) in enumerate(zip(seqs, rows, got)):
+        w = (np.asarray(ref.logits(params, s, cfg, rows=rr))
+             if want is None else want[si])
+        wants.append(w)
+        d = g - w
+        agg["max_abs"] = max(agg["max_abs"], float(np.max(np.abs(d))))
+        agg["rel_rms"] = max(agg["rel_rms"], float(
+            np.sqrt(np.mean(d ** 2) / np.mean(w ** 2))))
+        pick = g.argmax(-1)
+        agg["argmax_gap"] = max(agg["argmax_gap"], float(np.max(
+            w.max(-1) - w[np.arange(len(pick)), pick])))
+        if routes is None:
+            continue
+        T = len(s)
+        flipped = np.zeros(T, bool)
+        first_margin = np.zeros(T)
+        for layer, (chosen, margin) in enumerate(ref.routing(params, s, cfg)):
+            chosen, margin = np.asarray(chosen), np.asarray(margin)
+            e = np.where((routes[si][layer] >= lo)
+                         & (routes[si][layer] < lo + held),
+                         routes[si][layer], -1)
+            r = np.where((chosen >= lo) & (chosen < lo + held), chosen, -1)
+            differ = (np.sort(e, -1) != np.sort(r, -1)).any(-1)
+            first_margin = np.where(differ & ~flipped, margin, first_margin)
+            flipped |= differ
+        rr = np.asarray(rr)
+        rel = np.sqrt(np.mean(d ** 2, -1) / np.mean(w ** 2, -1))
+        mx = np.max(np.abs(d), -1)
+        ok = ~flipped[rr]
+        split["rows"] += len(rr)
+        split["agree_rows"] += int(ok.sum())
+        split["flip_rows"] += int((~ok).sum())
+        if ok.any():
+            split["agree_rel_max"] = max(split["agree_rel_max"],
+                                         float(rel[ok].max()))
+            split["agree_abs_max"] = max(split["agree_abs_max"],
+                                         float(mx[ok].max()))
+        if (~ok).any():
+            split["flip_rel_max"] = max(split["flip_rel_max"],
+                                        float(rel[~ok].max()))
+            split["flip_abs_max"] = max(split["flip_abs_max"],
+                                        float(mx[~ok].max()))
+            split["first_flip_margin_max"] = max(
+                split["first_flip_margin_max"],
+                float(first_margin[rr][~ok].max()))
+    return agg, (split if routes is not None else None), wants
+
+
+def afmoe_phase(args):
+    """Trinity-Large-Preview's share (benchmark configuration
+    ``trinity-large-preview-5l-ep8``, published widths) against its plain
+    float32 reference, routing-aware: the check that the benchmark's runner
+    cannot make, because it pools its rows.
+
+    Per seed: (1) the runner's own sequences and statistic, through both
+    page groups and past the window, so that window pages are released and
+    rows are read behind a released boundary (paged decode kernel); (2) a
+    prompt longer than window + chunk fed in chunks (ragged prefill kernel
+    with a window start past page 0), then decoded.  In both, a row whose
+    routers chose the reference's local experts must be within bf16 of it,
+    and a row may differ in its experts only where the reference's own
+    margin is within bf16's reach.  ``--faults``: what planted faults and
+    the reference on fp8-rounded weights read (first seed)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(HERE, "benchmark", "reference"))
+    import _afmoe as ref
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    from deepspeed_tpu.inference.v2.ragged import DSStateManager
+    from deepspeed_tpu.models import GPTConfig
+    from deepspeed_tpu.models.gpt import GPTLogits
+    from deepspeed_tpu.ops.registry import reset_dispatch_log
+    from deepspeed_tpu.parallel.metadata import unbox
+
+    cfg = _afmoe_sizes(args.rehearse)
+    run, tol = cfg["run"], cfg["tolerances"]
+    model_cfg = GPTConfig(
+        **ref.program_config(cfg), max_seq_len=int(run["max_seq_len"]),
+        dropout=0.0, dtype=jnp.bfloat16, attn_impl="pallas")
+    window, chunk = model_cfg.sliding_window, run["state_manager"]["max_q_per_seq"]
+    bs = run["state_manager"]["kv_block_size"]
+    per_seq = -(-model_cfg.max_seq_len // bs)
+    sm = {**run["state_manager"], "max_tracked_sequences": 4,
+          "max_ragged_sequence_count": 4, "num_kv_blocks": 4 * per_seq}
+    lm = GPTLogits(dataclasses.replace(model_cfg, param_dtype=jnp.bfloat16))
+    make = jax.jit(lambda key: unbox(lm.init(
+        key, jnp.zeros((1, 8), jnp.int32)))["params"])
+    steps_cache = {}
+    n_dec = int(run["compare"]["decode_positions"])
+    # (2): the last chunk starts past the window, 40 decoded rows behind it
+    long_len = min(window + chunk + chunk // 4,
+                   model_cfg.max_seq_len - 41) + 40
+
+    def engine(**patch):
+        # a patched layout is a static of the step programs: its own cache
+        eng = InferenceEngineV2(
+            model_cfg, {"dtype": "bfloat16", "state_manager": sm,
+                        "generation": run["generation"]},
+            params=params, seed=0,
+            steps_cache={} if patch else steps_cache)
+        check(eng.paged_impl == "pallas",
+              f"engine says paged_attention={eng.paged_impl}")
+        for k, v in patch.items():
+            eng._model_static[k] = v
+        return eng
+
+    def within(agg):
+        return (np.isfinite(agg["max_abs"])
+                and agg["max_abs"] <= tol["logits_max_abs"]
+                and agg["rel_rms"] <= tol["logits_rel_rms"]
+                and agg["argmax_gap"] <= tol["logits_max_abs"] / 2)
+
+    # the tiny preset's logits are small beside its bf16 noise
+    row_rel = AFMOE_ROW_REL * (2 if args.rehearse else 1)
+
+    def row_check(split, what):
+        check(split["agree_rel_max"] <= row_rel
+              and split["agree_abs_max"] <= AFMOE_ROW_ABS,
+              f"{what}: a row routed as the reference routes it is off by "
+              f"{split['agree_rel_max']} / {split['agree_abs_max']}")
+        check(split["first_flip_margin_max"] <= AFMOE_MARGIN,
+              f"{what}: the engine chose other local experts where the "
+              f"reference's margin is {split['first_flip_margin_max']}")
+
+    reset_dispatch_log()
+    for i in range(args.seeds):
+        seed = args.seed + 7919 * i
+        t0 = time.perf_counter()
+        params = make(jax.random.PRNGKey(seed % (2 ** 31 - 1)))
+        rng = np.random.default_rng(seed + 17)      # the runner's draw
+        seqs = [rng.integers(0, model_cfg.vocab_size, size=int(n) + n_dec)
+                .astype(np.int32) for n in run["compare"]["prefill_tokens"]]
+        rows, got, routes, released = _afmoe_drive(
+            engine(), seqs, n_dec, chunk)
+        # the runner compares the last prompt row and every decoded one
+        agg, split, want = _afmoe_compare(ref, params, cfg, seqs, rows, got,
+                                          routes, model_cfg)
+        check(released > 0, "the runner's sequences released no window page")
+        check(within(agg), f"runner statistic outside its limits: {agg}")
+        row_check(split, "runner sequences")
+        long_seq = [rng.integers(0, model_cfg.vocab_size, size=long_len)
+                    .astype(np.int32)]
+        lrows, lgot, lroutes, lreleased = _afmoe_drive(
+            engine(), long_seq, 40, chunk)
+        lagg, lsplit, _ = _afmoe_compare(ref, params, cfg, long_seq, lrows,
+                                         lgot, lroutes, model_cfg)
+        check(lreleased > 0, "the chunked prompt released no window page")
+        row_check(lsplit, "chunked prompt past the window")
+        line = {"phase": "afmoe", "note": NOTE, "seed": seed,
+                "runner_statistic": agg, "limits": {
+                    k: tol[k] for k in ("logits_max_abs", "logits_rel_rms")},
+                "runner_rows": split, "window_pages_released": released,
+                "chunked_prompt": {"tokens": long_len, "chunk": chunk,
+                                   "statistic": lagg, "rows": lsplit,
+                                   "window_pages_released": lreleased}}
+        if args.faults and i == 0:
+            faults = {}
+            layout = engine()._model_static["kv_layout"]
+            check(len({g for _, g in layout}) == 2, f"layout {layout}")
+            real = DSStateManager._window_first_live
+
+            def early(self, seq):       # every page goes one page too soon
+                return (max(0, seq.seen_tokens - self.window + 1)
+                        + self.block_size) // self.block_size
+
+            plants = (
+                ("window_page_released_one_too_early", {}, early),
+                ("global_layer_on_the_window_groups_table",
+                 {"kv_layout": tuple((first, 1) for first, _ in layout)},
+                 real))
+            for name, patch, first_live in plants:
+                DSStateManager._window_first_live = first_live
+                try:
+                    frows, fgot, froutes, _ = _afmoe_drive(
+                        engine(**patch), seqs, n_dec, chunk)
+                finally:
+                    DSStateManager._window_first_live = real
+                fagg, fsplit, _ = _afmoe_compare(
+                    ref, params, cfg, seqs, frows, fgot, froutes,
+                    model_cfg, want=want)
+                faults[name] = {
+                    **fagg, "caught_by_runner_limits": not within(fagg),
+                    "agree_rel_max": fsplit["agree_rel_max"],
+                    "agree_abs_max": fsplit["agree_abs_max"],
+                    "caught_by_row_check": bool(
+                        fsplit["agree_rel_max"] > row_rel
+                        or fsplit["agree_abs_max"] > AFMOE_ROW_ABS
+                        or fsplit["first_flip_margin_max"] > AFMOE_MARGIN)}
+            # the reference in the nearest precision below bf16: weights
+            # rounded to fp8 e4m3 in place (this seed's last use of them)
+            params = jax.jit(lambda t: jax.tree_util.tree_map(
+                lambda p: jax.lax.reduce_precision(p, 4, 3)
+                if p.ndim >= 2 else p, t), donate_argnums=0)(params)
+            fagg, _, _ = _afmoe_compare(ref, params, cfg, seqs, rows, got,
+                                        None, model_cfg)
+            faults["reference_on_fp8_e4m3_weights"] = {
+                **fagg, "caught_by_runner_limits": not within(fagg)}
+            check(not within(fagg) or args.rehearse,
+                  f"the reference on fp8 weights reads as correct: {fagg}")
+            line["faults"] = faults
+        line["seconds"] = round(time.perf_counter() - t0, 1)
+        del params
+        emit(line)
+    kernels = dispatched({"paged_attention", "ragged_prefill_attention",
+                          "grouped_gemm"})
+    check({d["op"] for d in kernels} == {"paged_attention",
+                                         "ragged_prefill_attention",
+                                         "grouped_gemm"}
+          and all(d["impl"] == "pallas" for d in kernels
+                  if d["op"] != "grouped_gemm"),
+          f"kernels not as demanded: {kernels}")
+
+
 # ------------------------------------------------------------------ main
 
 def main(argv=None):
@@ -518,6 +808,13 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: only ZeRO-3 fsdp=4 against a one-device mesh")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--afmoe", action="store_true",
+                    help="only Trinity-Large-Preview's share at published "
+                         "widths against its plain reference, routing-aware")
+    ap.add_argument("--seeds", type=int, default=1,
+                    help="--afmoe: seeds to try (--seed + 7919 i)")
+    ap.add_argument("--faults", action="store_true",
+                    help="--afmoe: also what planted faults read")
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes on whatever backend jax has; kernels "
                          "may be interpreted, so their presence in the "
@@ -533,6 +830,8 @@ def main(argv=None):
         os.makedirs(args.out, exist_ok=True)
         if args.chips == 4:
             sharded_phase(args)
+        elif args.afmoe:
+            afmoe_phase(args)
         else:
             train_phase(args)
             serve_phase(args)

@@ -45,6 +45,8 @@ _BIGCODE_LIKE = {"GPTBigCodeForCausalLM"}
 _GEMMA_LIKE = {"GemmaForCausalLM"}
 _PHI3_LIKE = {"Phi3ForCausalLM"}
 _BLOOM_LIKE = {"BloomForCausalLM"}
+# config only (``afmoe_config``); no weight loader yet
+_AFMOE_LIKE = {"AfmoeForCausalLM"}
 SUPPORTED_ARCHITECTURES = sorted(_LLAMA_LIKE | _GPT2_LIKE | _OPT_LIKE
                                  | _PHI_LIKE | _FALCON_LIKE | _GPTJ_LIKE
                                  | _NEOX_LIKE | _BLOOM_LIKE | _GPTNEO_LIKE
@@ -160,6 +162,101 @@ def _sliding_window_of(hf: Dict[str, Any],
     return int(window) if window < eff else None
 
 
+# afmoe (Arcee Trinity): published tensor name -> path in the GPT parameter
+# tree ({i} a layer, {e} an expert; torch Linear weights are [out, in] and
+# transpose on the way in, q/k/v/gate reshape to [H, heads, d], o to
+# [heads, d, H], experts stack on a leading axis).  Names as the family's
+# published modelling code has them; no checkpoint was at hand to check them
+# against, and no loader reads this table yet.
+AFMOE_WEIGHT_NAMES = {
+    "model.embed_tokens.weight": "backbone/wte",
+    "model.norm.weight": "backbone/final_norm/scale",
+    "lm_head.weight": "lm_head",
+    "model.layers.{i}.input_layernorm.weight": "backbone/block_{i}/Norm_0/scale",
+    "model.layers.{i}.post_attention_layernorm.weight":
+        "backbone/block_{i}/post_attn_norm/scale",
+    "model.layers.{i}.pre_mlp_layernorm.weight": "backbone/block_{i}/Norm_1/scale",
+    "model.layers.{i}.post_mlp_layernorm.weight":
+        "backbone/block_{i}/post_ffn_norm/scale",
+    "model.layers.{i}.self_attn.q_proj.weight": "backbone/block_{i}/Attention_0/wq",
+    "model.layers.{i}.self_attn.k_proj.weight": "backbone/block_{i}/Attention_0/wk",
+    "model.layers.{i}.self_attn.v_proj.weight": "backbone/block_{i}/Attention_0/wv",
+    "model.layers.{i}.self_attn.o_proj.weight": "backbone/block_{i}/Attention_0/wo",
+    "model.layers.{i}.self_attn.gate_proj.weight":
+        "backbone/block_{i}/Attention_0/wgate",
+    "model.layers.{i}.self_attn.q_norm.weight": "backbone/block_{i}/Attention_0/q_norm",
+    "model.layers.{i}.self_attn.k_norm.weight": "backbone/block_{i}/Attention_0/k_norm",
+    # dense layers (the first num_dense_layers)
+    "model.layers.{i}.mlp.gate_proj.weight": "backbone/block_{i}/MLP_0/wg",
+    "model.layers.{i}.mlp.up_proj.weight": "backbone/block_{i}/MLP_0/wi",
+    "model.layers.{i}.mlp.down_proj.weight": "backbone/block_{i}/MLP_0/wo",
+    # expert layers
+    "model.layers.{i}.mlp.router.gate.weight": "backbone/block_{i}/moe/gate",
+    "model.layers.{i}.mlp.expert_bias": "backbone/block_{i}/moe/expert_bias",
+    "model.layers.{i}.mlp.experts.{e}.gate_proj.weight": "backbone/block_{i}/moe/wge",
+    "model.layers.{i}.mlp.experts.{e}.up_proj.weight": "backbone/block_{i}/moe/wi",
+    "model.layers.{i}.mlp.experts.{e}.down_proj.weight": "backbone/block_{i}/moe/wo",
+    "model.layers.{i}.mlp.shared_experts.gate_proj.weight":
+        "backbone/block_{i}/moe/shared_wg",
+    "model.layers.{i}.mlp.shared_experts.up_proj.weight":
+        "backbone/block_{i}/moe/shared_wi",
+    "model.layers.{i}.mlp.shared_experts.down_proj.weight":
+        "backbone/block_{i}/moe/shared_wo",
+}
+
+
+def afmoe_config(hf: Dict[str, Any], *, max_seq_len: Optional[int] = None,
+                 dtype=None, experts_held: Optional[int] = None,
+                 expert_offset: int = 0):
+    """GPTConfig of a published ``afmoe`` ``config.json`` (Arcee Trinity):
+    sigmoid-routed experts with a selection bias beside a shared expert after
+    ``num_dense_layers`` dense layers; gated attention with q/k norms, a
+    sliding window with RoPE on ``sliding_attention`` layers and no position
+    on ``full_attention`` ones; sandwich norms; embeddings scaled by
+    sqrt(hidden) under ``mup_enabled``.  ``experts_held``/``expert_offset``
+    give one chip's share of the experts."""
+    from deepspeed_tpu.models.gpt import GPTConfig
+    if hf.get("score_func", "sigmoid") != "sigmoid" \
+            or hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+        raise ValueError("afmoe: only sigmoid scores without expert groups "
+                         "are implemented")
+    if hf.get("rope_scaling"):
+        raise ValueError("afmoe: rope_scaling is not implemented")
+    hidden, layers = hf["hidden_size"], hf["num_hidden_layers"]
+    every = hf.get("global_attn_every_n_layers", 4)
+    types = hf.get("layer_types") or [
+        "full_attention" if (i + 1) % every == 0 else "sliding_attention"
+        for i in range(layers)]
+    msl = hf.get("max_position_embeddings", 2048)
+    return GPTConfig(
+        vocab_size=hf["vocab_size"], num_layers=layers,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf.get("num_key_value_heads"),
+        head_dim=hf.get("head_dim") or hidden // hf["num_attention_heads"],
+        hidden_size=hidden, mlp_dim_override=hf["intermediate_size"],
+        max_seq_len=min(msl, max_seq_len or msl),
+        use_rope=True, rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rope_layers="window", use_rmsnorm=True,
+        norm_eps=float(hf.get("rms_norm_eps", 1e-5)), gated_mlp=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        embed_scale=float(hidden) ** 0.5 if hf.get("mup_enabled") else None,
+        sliding_window=hf["sliding_window"],
+        local_attn_layers=tuple(i for i, t in enumerate(types)
+                                if t == "sliding_attention"),
+        attn_gate=True, qk_norm=True, sandwich_norm=True,
+        num_experts=hf["num_experts"], moe_k=hf["num_experts_per_tok"],
+        moe_dropless=True, moe_router="sigmoid",
+        moe_route_norm=bool(hf.get("route_norm", True)),
+        moe_route_scale=float(hf.get("route_scale", 1.0)),
+        moe_router_bias=True,
+        moe_shared_dim=hf["moe_intermediate_size"]
+        * hf.get("num_shared_experts", 0),
+        moe_expert_dim=hf["moe_intermediate_size"],
+        moe_dense_layers=hf.get("num_dense_layers", 0),
+        experts_held=experts_held, expert_offset=expert_offset,
+        dtype=dtype or jnp.bfloat16)
+
+
 def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
                    dtype=None):
     """Build a GPTConfig from ``<model_path>/config.json``.
@@ -170,6 +267,8 @@ def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
     from deepspeed_tpu.models.gpt import GPTConfig
 
     hf = _read_json(os.path.join(model_path, "config.json"))
+    if hf.get("model_type") == "afmoe":
+        return afmoe_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     arch = _arch_of(hf)
 
     if arch in _LLAMA_LIKE:
@@ -672,7 +771,7 @@ def _llama_tree(r: _ShardReader, cfg) -> Dict[str, Any]:
             "Norm_0": norm(p + "input_layernorm"),
             "Norm_1": norm(p + "post_attention_layernorm"),
         }
-        if cfg.num_experts and i % cfg.moe_every == cfg.moe_every - 1:
+        if cfg.is_moe_layer(i):
             # Mixtral MoE block (modeling_mixtral.py MixtralSparseMoeBlock):
             # gate router + per-expert w1(gate)/w3(up)/w2(down)
             m = p + "block_sparse_moe."
@@ -1505,6 +1604,11 @@ def load_hf_checkpoint(model_path: str, *, max_seq_len: Optional[int] = None,
     ``dtype`` sets the config's COMPUTE dtype only.
     """
     cfg = config_from_hf(model_path, max_seq_len=max_seq_len, dtype=dtype)
+    if cfg.moe_router == "sigmoid":
+        raise NotImplementedError(
+            "afmoe checkpoints: the config maps (afmoe_config) and "
+            "AFMOE_WEIGHT_NAMES names the tensors, but no loader reads "
+            "them yet")
     r = _ShardReader(model_path)
     arch = _arch_of(_read_json(os.path.join(model_path, "config.json")))
     if arch in _GPT2_LIKE:

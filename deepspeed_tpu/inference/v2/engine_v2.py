@@ -110,6 +110,11 @@ class DSStateManagerConfig(DeepSpeedConfigModel):
     max_ragged_sequence_count: int = 32
     kv_block_size: int = 64
     num_kv_blocks: Optional[int] = None     # None = enough for all slots full
+    # a model with sliding-window AND global layers keeps two page groups
+    # (ragged.py): num_kv_blocks sizes the global group (pages a global
+    # layer), this the window group (pages a window layer; None = a full
+    # ring for every slot)
+    num_kv_window_blocks: Optional[int] = None
     max_q_per_seq: int = 128                # prompt-chunk cap (SplitFuse)
     # "int8": per-token symmetric KV quantization — halves KV HBM (decode's
     # bandwidth bound) and doubles cache capacity for ~6% scale overhead
@@ -481,13 +486,66 @@ class InferenceEngineV2:
             num_blocks = max(1, sm.num_kv_blocks * sm.kv_block_size // eff_bs)
         else:
             num_blocks = sm.max_tracked_sequences * blocks_per_seq
+        # ---- page groups: layers with a sliding window beside layers
+        # without one keep their pages apart, so that a window layer does
+        # not hold what it will never read again (ragged.py; model.py
+        # kv_page_layout).  Layers all alike: one group, as ever.
+        windows = {model_cfg.window_for_layer(i)
+                   for i in range(model_cfg.num_layers)}
+        self.kv_window = (model_cfg.sliding_window
+                          if len(windows) == 2 else None)
+        window_blocks = 0
+        # statics of every step program; {} for a plain model, whose
+        # programs are then traced exactly as before
+        self._model_static: Dict[str, Any] = {}
+        if self.kv_window:
+            for what, why in (     # (prefix_cache: DSStateManager refuses)
+                    (sm.kv_quant, "kv_quant: the scale pools are not "
+                     "grouped"),
+                    (self.mesh is not None, "a tp mesh: the grouped pool's "
+                     "sharding is not built"),
+                    (draft_model is not None, "speculative decoding: the "
+                     "draft's pool and the verify core take one table"),
+                    (self.config.adapters.enabled, "LoRA adapter pages: "
+                     "they live in the one allocator")):
+                if what:
+                    raise NotImplementedError(
+                        f"window and global layers in one model keep two "
+                        f"page groups, which is not built with {why}")
+            ring = (-(-(self.kv_window + max(sm.max_q_per_seq, 64))
+                      // eff_bs) + 1)
+            window_blocks = (sm.num_kv_window_blocks
+                             or sm.max_tracked_sequences
+                             * min(ring, blocks_per_seq))
+            if window_blocks < min(ring, blocks_per_seq):
+                raise ValueError(
+                    f"num_kv_window_blocks={window_blocks} cannot hold one "
+                    f"sequence's ring of {min(ring, blocks_per_seq)} pages "
+                    f"(window {self.kv_window} + a chunk of "
+                    f"{sm.max_q_per_seq} rows in pages of {eff_bs})")
+            from deepspeed_tpu.inference.v2.model import kv_page_layout
+            self._model_static["kv_layout"] = kv_page_layout(
+                model_cfg, num_blocks, window_blocks)
+        if draft_model is None and model_cfg.num_experts and any(
+                model_cfg.is_moe_layer(i)
+                for i in range(model_cfg.num_layers)):
+            self._model_static["moe_stats"] = True
         self.state = DSStateManager(
             max_tracked_sequences=sm.max_tracked_sequences,
             num_blocks=num_blocks, block_size=eff_bs,
             max_seq_len=model_cfg.max_seq_len,
-            prefix_cache=sm.prefix_cache)
-        self.cache = PagedKVCache.create(model_cfg, num_blocks, eff_bs, dt,
-                                         quant=sm.kv_quant)
+            prefix_cache=sm.prefix_cache, window=self.kv_window,
+            window_blocks=window_blocks)
+        if self.kv_window:
+            self.cache = PagedKVCache.create_grouped(
+                model_cfg, num_blocks, window_blocks, eff_bs, dt)
+        else:
+            self.cache = PagedKVCache.create(model_cfg, num_blocks, eff_bs,
+                                             dt, quant=sm.kv_quant)
+        # MoE counter vectors of dispatches not yet read back (device
+        # values the step programs return; folded into the telemetry once
+        # ready, never waited for: _fold_moe_stats)
+        self._moe_pending: List[Any] = []
         # ---- speculative decoding draft (greedy draft-and-verify) ----
         self.draft_config = self.draft_params = self.draft_cache = None
         if draft_model is not None:
@@ -551,6 +609,7 @@ class InferenceEngineV2:
                        tuple(sorted(self.mesh.shape.items()))
                        if self.mesh is not None else None,
                        qc.enabled, qc.bits, qc.group_size,
+                       tuple(sorted(self._model_static.items())),
                        # adapter-enabled programs take extra batch operands
                        # (lora tables + per-slot selection) and bake the
                        # rank/scale geometry into their traced shapes — two
@@ -609,19 +668,31 @@ class InferenceEngineV2:
         log_dist(f"v2 ragged engine ready: params={n_params/1e6:.1f}M "
                  f"budget={sm.max_ragged_batch_size}tok "
                  f"slots={sm.max_tracked_sequences} "
-                 f"kv_blocks={num_blocks}x{eff_bs} kv_layout={kv_layout} "
+                 f"kv_blocks={num_blocks}x{eff_bs}"
+                 + (f"+window:{window_blocks}x{eff_bs}" if self.kv_window
+                    else "") + f" kv_layout={kv_layout} "
                  f"paged_attention={self.paged_impl}", ranks=[0])
 
     # ------------------------------------------------ reference put() :107
     def put(self, uids: Sequence[int], tokens_list: Sequence[np.ndarray],
-            ) -> np.ndarray:
+            with_routes: bool = False) -> np.ndarray:
         """Append tokens to each uid's sequence, run ONE ragged forward, return
-        fp32 logits [len(uids), vocab] of each sequence's last token."""
-        logits = self._put_device(uids, tokens_list)
-        slots = [self.state.get(uid).slot for uid in uids]
-        return np.asarray(logits)[np.asarray(slots)]
+        fp32 logits [len(uids), vocab] of each sequence's last token.
 
-    def _put_device(self, uids, tokens_list):
+        ``with_routes`` (a model with expert layers): also, per uid, the
+        experts its rows' routers chose, int32 ``[expert layers, rows, k]``
+        over all the router's experts: what a comparison with a reference's
+        routing needs to tell a near-tie from a fault."""
+        logits = self._put_device(uids, tokens_list, with_routes)
+        if with_routes:
+            logits, routes, rows = logits
+        slots = [self.state.get(uid).slot for uid in uids]
+        out = np.asarray(logits)[np.asarray(slots)]
+        if not with_routes:
+            return out
+        return out, [np.asarray(routes)[:, r] for r in rows]
+
+    def _put_device(self, uids, tokens_list, with_routes: bool = False):
         """put() minus the host transfer: returns per-SLOT device logits
         [S, vocab] so generate() can sample on device and ship only token ids
         over the wire (the logits row is 200 KB; a token id is 4 bytes)."""
@@ -678,6 +749,13 @@ class InferenceEngineV2:
             raise RuntimeError(
                 f"batch needs {blocks_needed} KV blocks but only "
                 f"{self.state.available_blocks} free; check query() first")
+        if self.kv_window and not self.state.fits(
+                [(self.state.get(u), len(t)) for u, t in zip(uids, toks_np)]):
+            self.telemetry.alloc_failure("put")
+            raise RuntimeError(
+                f"batch does not fit the window page group "
+                f"({self.state.wallocator.free_blocks} pages free); check "
+                f"query() first")
         schedule = []
         for uid, toks, path in zip(uids, toks_np, paths):
             seq = self.state.get(uid)
@@ -712,7 +790,7 @@ class InferenceEngineV2:
                                   len(toks))
         rb = build_ragged_batch(schedule, self.state,
                                 sm.max_ragged_batch_size, sm.max_q_per_seq)
-        logits = self._run(rb)
+        logits = self._run(rb, with_routes)
         for seq, toks in schedule:
             seq.seen_tokens += len(toks)
             # index newly completed full blocks (content is host-known; the
@@ -749,7 +827,10 @@ class InferenceEngineV2:
         batch["lora"] = self.adapters.tables()
         return batch
 
-    def _run(self, rb: RaggedBatch) -> "jax.Array":
+    def _run(self, rb: RaggedBatch, with_routes: bool = False):
+        """Logits ``[S, vocab]`` of one scheduled step; ``with_routes``:
+        (logits, the routers' choices ``[expert layers, rows, k]``, each
+        scheduled sequence's rows of them)."""
         # small set of compiled programs: a decode-only step (Q=1, Pallas
         # paged attention — the steady-state hot path, ragged_decode_forward)
         # plus one mixed prefill step per power-of-two BLOCK-TABLE-WIDTH
@@ -760,30 +841,37 @@ class InferenceEngineV2:
         # not the bucket (reference atom_builder + blocked_flash).
         sm = self.config.state_manager
         if int(rb.q_len.max()) <= 1:
-            return self._run_decode(rb)
+            return self._run_decode(rb, with_routes)
         mb, nb = self._buckets(rb)
-        key = ("mixed", sm.max_q_per_seq, mb)
+        routes = {"moe_routes": True} if with_routes else {}
+        key = ("mixed", sm.max_q_per_seq, mb) + tuple(routes)
         if key not in self._steps:
             self._steps[key] = jax.jit(
                 _named_partial(ragged_forward, cfg=self.model_config,
                                block_size=self._block_size,
                                max_q_per_seq=sm.max_q_per_seq,
-                               mesh=self.mesh),
+                               mesh=self.mesh, **self._model_static,
+                               **routes),
                 donate_argnums=(1,))
         batch = {"tokens": rb.tokens[:nb], "token_slot": rb.token_slot[:nb],
                  "token_pos": rb.token_pos[:nb],
                  "token_dense_idx": rb.token_dense_idx[:nb],
-                 "block_table": rb.block_table[:, :mb], "kv_len": rb.kv_len}
+                 **rb.table_operands(mb), "kv_len": rb.kv_len}
         batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, batch))
         self.telemetry.dispatch("mixed")
         self.telemetry.padding_waste(rb.total_tokens, nb)
         with self.telemetry.span("mixed_dispatch", tokens=rb.total_tokens,
                                  bucket=nb, seqs=len(rb.logits_slots)):
-            logits, self.cache = self._steps[key](self.params, self.cache,
-                                                  batch)
+            out = self._steps[key](self.params, self.cache, batch)
+        if with_routes:
+            *out, chosen = out
+        logits, self.cache = self._take_moe_stats(out)
+        if with_routes:         # the packed token rows of each sequence
+            return logits, chosen, [np.flatnonzero(rb.token_slot[:nb] == sl)
+                                    for sl in rb.logits_slots]
         return logits
 
-    def _run_decode(self, rb: RaggedBatch) -> "jax.Array":
+    def _run_decode(self, rb: RaggedBatch, with_routes: bool = False):
         S = self.state.max_tracked_sequences
         tokens = np.zeros(S, np.int32)
         active = np.zeros(S, bool)
@@ -793,22 +881,47 @@ class InferenceEngineV2:
             tokens[sl] = rb.tokens[i]
             active[sl] = True
             token_pos[sl] = rb.token_pos[i]
-        key = "decode"
+        routes = {"moe_routes": True} if with_routes else {}
+        key = ("decode", "moe_routes") if with_routes else "decode"
         if key not in self._steps:
             self._steps[key] = jax.jit(
                 _named_partial(ragged_decode_forward,
                                cfg=self.model_config,
                                block_size=self._block_size,
-                               mesh=self.mesh),
+                               mesh=self.mesh, **self._model_static,
+                               **routes),
                 donate_argnums=(1,))
         batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, {
             "tokens": tokens, "active": active, "token_pos": token_pos,
-            "block_table": rb.block_table}))
+            **rb.table_operands()}))
         self.telemetry.dispatch("decode")
         with self.telemetry.span("decode_dispatch", seqs=rb.total_tokens):
-            logits, self.cache = self._steps[key](self.params, self.cache,
-                                                  batch)
+            out = self._steps[key](self.params, self.cache, batch)
+        if with_routes:
+            *out, chosen = out
+        logits, self.cache = self._take_moe_stats(out)
+        if with_routes:         # a decode program's rows are the slots
+            return logits, chosen, [np.asarray([sl])
+                                    for sl in rb.logits_slots]
         return logits
+
+    def _take_moe_stats(self, out):
+        """A step program's outputs without the MoE counter vector that a
+        model with expert layers has its programs return last: that goes on the list
+        ``_fold_moe_stats`` reads back once the device has it."""
+        if "moe_stats" not in self._model_static:
+            return out
+        self._moe_pending.append(out[-1])
+        return out[:-1]
+
+    def _fold_moe_stats(self, wait: bool = False) -> None:
+        """Counter vectors of finished dispatches into the telemetry, oldest
+        first, stopping at the first the device still owes (``wait``: at a
+        drain or the end of a call, where the host syncs anyway).  Reading
+        a ready 12-byte array is no fence."""
+        pend = self._moe_pending
+        while pend and (wait or pend[0].is_ready()):
+            self.telemetry.moe_stats(np.asarray(pend.pop(0)))
 
     def _sample_fn(self, gen):
         from deepspeed_tpu.inference.engine import _sample_token
@@ -833,7 +946,7 @@ class InferenceEngineV2:
         from_device = np.zeros(S, bool)
         active = np.zeros(S, bool)
         pos0 = np.zeros(S, np.int32)
-        block_table = np.zeros((S, self.state.max_blocks_per_seq), np.int32)
+        tables = self.state.tables()
         for r in reqs:
             seq = self.state.get(r.uid)
             self.state.ensure_blocks(seq, steps)
@@ -845,11 +958,39 @@ class InferenceEngineV2:
                 from_device[sl] = True
             active[sl] = True
             pos0[sl] = seq.seen_tokens
-            bl = np.asarray(seq.blocks, np.int32)
-            block_table[sl, :len(bl)] = bl
+            self.state.write_tables(tables, seq)
         return ({"tokens0": tokens0, "from_device": from_device,
                  "active": active, "pos0": pos0,
-                 "block_table": block_table}, int(pos0.sum()))
+                 **self.state.table_operands(tables)},
+                self._ctx_note(pos0[active]))
+
+    def _ctx_note(self, contexts, new=None) -> Dict[str, int]:
+        """What a dispatch's span says of the contexts it reads:
+        ``ctx_tokens``, their sum before the step, and for a model with
+        window layers ``ctx_tokens_window``, the sum of ``min(context,
+        window)``: what a window layer needs of them (the window-aware
+        rooflines).  A mixed step (``new``: each sequence's rows) adds the
+        query-key pairs its attention has to score, ``qk_pairs`` on a global
+        layer (row ``i`` of a chunk at context ``c`` sees ``c + i + 1``
+        keys) and ``qk_pairs_window`` on a window layer (at most the
+        window)."""
+        contexts = np.asarray(contexts, np.int64)
+        note = {"ctx_tokens": int(contexts.sum())}
+        win = self.model_config.sliding_window
+        if not win:
+            return note
+        note["ctx_tokens_window"] = int(np.minimum(contexts, win).sum())
+        if new is not None:
+            q = np.asarray(new, np.int64)
+            # sum over i < q of (c + 1 + i), and of min(c + 1 + i, win):
+            # the first `rising` rows still see fewer keys than the window
+            pairs = q * contexts + q * (q + 1) // 2
+            rising = np.clip(win - contexts - 1, 0, q)
+            pairs_w = (rising * contexts + rising * (rising + 1) // 2
+                       + (q - rising) * win)
+            note.update(qk_pairs=int(pairs.sum()),
+                        qk_pairs_window=int(pairs_w.sum()))
+        return note
 
     def _run_spec(self, reqs, outer: int, gamma: int, gen, prev, rng):
         """One fused draft-and-verify dispatch over the running set, then ONE
@@ -858,7 +999,8 @@ class InferenceEngineV2:
         (toks [outer, gamma+1, S] np, counts [outer, S] np, prev', rng')."""
         stel = self.telemetry
         with stel.span("build"):
-            host, ctx_tokens = self._slot_schedule(reqs, outer * (gamma + 1))
+            host, note = self._slot_schedule(reqs, outer * (gamma + 1))
+            ctx_tokens = note["ctx_tokens"]
         with stel.span("h2d"):
             batch = jax.tree_util.tree_map(jnp.asarray, host)
         t_begin = stel.now()
@@ -919,24 +1061,27 @@ class InferenceEngineV2:
         prev', rng') — no host sync."""
         stel = self.telemetry
         with stel.span("build"):
-            host, ctx_tokens = self._slot_schedule(reqs, steps)
+            host, note = self._slot_schedule(reqs, steps)
+            self._fold_moe_stats()
         key = ("burst", steps, gen.do_sample, gen.top_k)
         if key not in self._steps:
             self._steps[key] = jax.jit(
                 _named_partial(ragged_decode_burst, cfg=self.model_config,
                                block_size=self._block_size, steps=steps,
                                sample_fn=self._sample_fn(gen),
-                               mesh=self.mesh),
+                               mesh=self.mesh, **self._model_static),
                 donate_argnums=(1,))
         with stel.span("h2d"):
             batch = self._with_lora(
                 jax.tree_util.tree_map(jnp.asarray, host))
         stel.dispatch("burst")
         with stel.span("burst_dispatch", steps=steps, seqs=len(reqs),
-                       tokens=steps * len(reqs), ctx_tokens=ctx_tokens):
-            toks, prev, rng, self.cache = self._steps[key](
-                self.params, self.cache, batch, prev, rng,
-                jnp.float32(gen.temperature), jnp.float32(gen.top_p))
+                       tokens=steps * len(reqs), **note,
+                       **stel.counter_note(self.state)):
+            toks, prev, rng, self.cache = self._take_moe_stats(
+                self._steps[key](
+                    self.params, self.cache, batch, prev, rng,
+                    jnp.float32(gen.temperature), jnp.float32(gen.top_p)))
         stel.tokens("decode", steps * len(reqs))
         for r in reqs:
             self.state.get(r.uid).seen_tokens += steps
@@ -970,10 +1115,13 @@ class InferenceEngineV2:
             served = np.zeros(S, bool)
             served[list(served_slots)] = True
             # what a reader needs to compute rates without the engine
+            self._fold_moe_stats()
             note = {"seqs": len(schedule),
                     "tokens": sum(len(t) for t in toks_np),
-                    "ctx_tokens": sum(seq.seen_tokens
-                                      for seq, _ in schedule)}
+                    **self._ctx_note([seq.seen_tokens
+                                      for seq, _ in schedule],
+                                     [len(t) for t in toks_np]),
+                    **stel.counter_note(self.state)}
             if max(len(t) for t in toks_np) <= 1:
                 # decode-only: slot-indexed [S] program
                 kind = "decode"
@@ -981,18 +1129,17 @@ class InferenceEngineV2:
                 active = np.zeros(S, bool)
                 token_pos = np.zeros(S, np.int32)
                 fdev = np.zeros(S, bool)
-                block_table = np.zeros((S, self.state.max_blocks_per_seq),
-                                       np.int32)
+                tables = self.state.tables()
                 for (seq, toks), fd in zip(schedule, from_device):
                     sl = seq.slot
                     tokens[sl] = toks[0]
                     active[sl] = True
                     fdev[sl] = fd
                     token_pos[sl] = seq.seen_tokens
-                    bl = np.asarray(seq.blocks, np.int32)
-                    block_table[sl, :len(bl)] = bl
+                    self.state.write_tables(tables, seq)
                 host = {"tokens": tokens, "active": active,
-                        "token_pos": token_pos, "block_table": block_table,
+                        "token_pos": token_pos,
+                        **self.state.table_operands(tables),
                         "from_device": fdev, "served": served}
                 note["bucket"] = S
                 shape_key, static = (), {}
@@ -1012,7 +1159,7 @@ class InferenceEngineV2:
                         "token_slot": rb.token_slot[:nb],
                         "token_pos": rb.token_pos[:nb],
                         "token_dense_idx": rb.token_dense_idx[:nb],
-                        "block_table": rb.block_table[:, :mb],
+                        **rb.table_operands(mb),
                         "kv_len": rb.kv_len, "from_device": fdev[:nb],
                         "served": served}
                 note["bucket"] = nb
@@ -1031,7 +1178,8 @@ class InferenceEngineV2:
                                    cfg=self.model_config,
                                    block_size=self._block_size,
                                    sample_fn=self._sample_fn(gen),
-                                   mesh=self.mesh, **static),
+                                   mesh=self.mesh, **static,
+                                   **self._model_static),
                     donate_argnums=(2, 3) if draft else (1,))
         with stel.span("h2d"):
             batch = self._with_lora(
@@ -1045,9 +1193,11 @@ class InferenceEngineV2:
                     jnp.float32(gen.temperature), jnp.float32(gen.top_p))
         else:
             with stel.span(f"{kind}_dispatch", **note):
-                prev, rng, self.cache = self._steps[key](
-                    self.params, self.cache, batch, prev, rng,
-                    jnp.float32(gen.temperature), jnp.float32(gen.top_p))
+                prev, rng, self.cache = self._take_moe_stats(
+                    self._steps[key](
+                        self.params, self.cache, batch, prev, rng,
+                        jnp.float32(gen.temperature),
+                        jnp.float32(gen.top_p)))
         for seq, toks in schedule:
             seq.seen_tokens += len(toks)
         return prev, rng
@@ -1087,15 +1237,9 @@ class InferenceEngineV2:
         if len(uids) > sm.max_ragged_sequence_count:
             self.telemetry.alloc_failure("can_schedule")
             return False
-        blocks = slots = 0
-        for uid, n in zip(uids, lengths):
-            seq = self.state.get(uid)
-            if seq is None:
-                slots += 1
-                blocks += -(-n // self.state.block_size)
-            else:
-                blocks += seq.kv_blocks_needed(n, self.state.block_size)
-        ok = (blocks <= self.state.available_blocks
+        steps = [(self.state.get(uid), n) for uid, n in zip(uids, lengths)]
+        slots = sum(1 for seq, _ in steps if seq is None)
+        ok = (self.state.fits(steps)
               and slots <= self.state.free_sequence_slots)
         if not ok:
             self.telemetry.alloc_failure("can_schedule")
@@ -1390,6 +1534,8 @@ class InferenceEngineV2:
                     f"request needs {need} KV blocks for its full context but "
                     f"the pool holds {pool_blocks}; raise num_kv_blocks "
                     f"(recompute-preemption cannot make a single sequence fit)")
+            # (the window group holds any one sequence's ring: start-up
+            # checked num_kv_window_blocks against it)
             if r.adapter and self.adapters is not None:
                 # a permanently unservable adapter id is a CLIENT error —
                 # reject at dispatch (the fleet maps this to a typed
@@ -1451,6 +1597,7 @@ class InferenceEngineV2:
                 return
             with stel.span("materialize", records=len(records)):
                 arrs = jax.device_get([rec[1] for rec in records])
+                self._fold_moe_stats(wait=True)   # the sync just happened
                 for rec, arr in zip(records, arrs):
                     if rec[0] == "step":
                         for uid, sl in rec[2]:
@@ -1586,7 +1733,8 @@ class InferenceEngineV2:
                         need = (-(-(m + first) // self.state.block_size)
                                 - m // self.state.block_size + pin)
                         if (self.state.free_sequence_slots == 0
-                                or need > self.state.available_blocks):
+                                or need > self.state.available_blocks
+                                or not self.state.fits([(None, first)])):
                             if records:
                                 materialize()   # exact .generated at the fold
                                 continue        # (retirements may change sets)
@@ -1717,9 +1865,8 @@ class InferenceEngineV2:
                          else (max(fitting) if fitting else 0))
                     # shrink the burst until its block reservation fits the pool
                     while T >= burst_sizes[-1]:
-                        need = sum(self.state.get(r.uid).kv_blocks_needed(
-                            T, self.state.block_size) for r in running)
-                        if need <= self.state.available_blocks:
+                        if self.state.fits([(self.state.get(r.uid), T)
+                                            for r in running]):
                             break
                         T //= 2
                     if T >= burst_sizes[-1]:
@@ -1780,8 +1927,7 @@ class InferenceEngineV2:
                     # reserve the block NOW (allocator state advances with each
                     # reservation, so later checks see the true remaining pool);
                     # a decode that can't get a block defers to a later round
-                    need = seq.kv_blocks_needed(1, self.state.block_size)
-                    if need and need > self.state.available_blocks:
+                    if not self.state.fits([(seq, 1)]):
                         stel.alloc_failure("decode")
                         continue
                     self.state.ensure_blocks(seq, 1)
@@ -1808,8 +1954,7 @@ class InferenceEngineV2:
                         continue
                     chunk = min(len(seq.pending), sm.max_q_per_seq, budget,
                                 prefill_budget)
-                    need = seq.kv_blocks_needed(chunk, self.state.block_size)
-                    if need and need > self.state.available_blocks:
+                    if not self.state.fits([(seq, chunk)]):
                         stel.alloc_failure("prompt_chunk")
                         continue
                     self.state.ensure_blocks(seq, chunk)
@@ -1867,8 +2012,7 @@ class InferenceEngineV2:
                             self.adapters.slot_of(r.adapter)
                     chunk = min(len(r.prompt) - matched, sm.max_q_per_seq,
                                 budget, prefill_budget)
-                    need = seq.kv_blocks_needed(chunk, self.state.block_size)
-                    if need > self.state.available_blocks:
+                    if not self.state.fits([(seq, chunk)]):
                         stel.alloc_failure("admission")
                         self.state.flush(r.uid)
                         waiting.insert(0, r)

@@ -112,6 +112,25 @@ class PagedKVCache(NamedTuple):
                    k_scale=jnp.zeros(sshape, jnp.float32),
                    v_scale=jnp.zeros(sshape, jnp.float32))
 
+    @classmethod
+    def create_grouped(cls, cfg: GPTConfig, nb_global: int, nb_window: int,
+                       block_size: int, dtype):
+        """The pool of a model with window AND global layers: one flat
+        row-major array ``[1, pages, nkv, block_size, head_dim]`` (kv-major:
+        ``[.., head_dim, block_size]``) holding
+        ``nb_global`` pages for each global layer and then ``nb_window`` for
+        each window layer (``kv_page_layout`` says where each layer's
+        begin), so that a window layer does not keep what it will never
+        read again (ragged.py, the window group's ring)."""
+        n_window = sum(cfg.window_for_layer(i) is not None
+                       for i in range(cfg.num_layers))
+        pages = ((cfg.num_layers - n_window) * nb_global
+                 + n_window * nb_window)
+        page = ((cfg.head_dim, block_size) if kv_major_layout(cfg)
+                else (block_size, cfg.head_dim))
+        shape = (1, pages, cfg.kv_heads) + page
+        return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
 
 def _norm(p, x, cfg):
     from deepspeed_tpu.ops import layer_norm, rms_norm
@@ -139,17 +158,22 @@ def _mlp(p, x, cfg, mesh=None):
     return y
 
 
-def _block_residual(blk, x, h, attn_delta, cfg, mesh=None):
+def _block_residual(blk, x, h, attn_delta, cfg, mesh=None, live=None,
+                    stats=None, routes=None):
     """Close out one block given the normed input ``h`` and the attention
     branch output: sequential (x+attn, then MLP on a fresh norm) or falcon/phi
     parallel residual (attn and MLP both read the shared/paired input norms) —
     the single source of truth for BOTH the ragged prefill and paged decode
-    loops."""
+    loops.  ``live``/``stats``/``routes`` go to an MoE layer (``_ffn``)."""
     if cfg.parallel_block:
         h_mlp = _norm(blk["Norm_1"], x, cfg) if cfg.parallel_norms == 2 else h
         return x + attn_delta + _ffn(blk, h_mlp, cfg, mesh=mesh)
     x = x + attn_delta
-    return x + _ffn(blk, _norm(blk["Norm_1"], x, cfg), cfg, mesh=mesh)
+    f = _ffn(blk, _norm(blk["Norm_1"], x, cfg), cfg, mesh=mesh, live=live,
+             stats=stats, routes=routes)
+    if cfg.sandwich_norm:
+        f = _norm(blk["post_ffn_norm"], f, cfg)
+    return x + f
 
 
 def _w(p, dtype):
@@ -447,22 +471,63 @@ def _sample_next(sample_fn, logits, rng, temperature, top_p, served,
         return jnp.where(served, nxt.astype(jnp.int32), prev_tokens), rng
 
 
-def _ffn(blk, x, cfg, mesh=None):
+def _moe_route(mp, x, cfg):
+    """An expert layer's router: (expert ids ``[N, k]`` over all the
+    router's experts, weights ``[N, k]``), softmax (Mixtral) or sigmoid
+    (afmoe: the matmul in float32, a selection-only bias, the chosen
+    scores renormalised and scaled)."""
+    gate = _w(mp["gate"], x.dtype)
+    if cfg.moe_router == "sigmoid":
+        from deepspeed_tpu.moe.sharded_moe import sigmoid_topk
+        logits = jnp.dot(x, gate, preferred_element_type=jnp.float32)
+        return sigmoid_topk(logits, cfg.moe_k, mp.get("expert_bias"),
+                            cfg.moe_route_norm, cfg.moe_route_scale)
+    from deepspeed_tpu.moe.sharded_moe import dropless_topk
+    _, idx, w = dropless_topk(x @ gate, cfg.moe_k)
+    return idx, w
+
+
+def _ffn(blk, x, cfg, mesh=None, live=None, stats=None, routes=None):
     """Dense MLP or MoE block body on FLAT tokens [N, H] — MoE routes through
     the dropless ragged grouped GEMM (moe/layer.py), which fits serving
     exactly: the ragged token set per step IS the ragged expert batch
     (reference inference/v2 MoE gather/scatter + cutlass grouped GEMM,
-    model_implementations/mixtral)."""
-    if "moe" in blk:
-        from deepspeed_tpu.moe.layer import _expert_ffn_ragged
-        from deepspeed_tpu.moe.sharded_moe import dropless_topk
-        mp = blk["moe"]
-        logits = x @ _w(mp["gate"], x.dtype)
-        _, idx, w = dropless_topk(logits, cfg.moe_k)
-        weg = _w(mp["wge"], x.dtype) if "wge" in mp else None
-        return _expert_ffn_ragged(x, idx, w, _w(mp["wi"], x.dtype),
-                                  _w(mp["wo"], x.dtype), weg)
-    return _mlp(blk["MLP_0"], x, cfg, mesh=mesh)
+    model_implementations/mixtral).
+
+    An expert layer is the same function as the flax module's
+    (moe/layer.py) on the same parameters, in three scopes inside the
+    caller's ``mlp``: ``moe_route`` (``_moe_route``), ``moe_experts`` (sort,
+    counts, gather, grouped GEMMs over the experts held here, weighted
+    scatter-add) and, where the model has a shared expert, ``moe_shared``.
+    ``live [N]`` marks the rows that are tokens (not padding, not an idle
+    slot): the others' assignments are dropped with those to experts not
+    held.  ``stats``, a list, takes the layer's counter vector, and
+    ``routes`` the experts it chose ``[N, k]`` (``put(...,
+    with_routes=True)``: what a comparison with a reference's routing
+    needs)."""
+    if "moe" not in blk:
+        return _mlp(blk["MLP_0"], x, cfg, mesh=mesh)
+    from deepspeed_tpu.moe.layer import _expert_ffn_ragged
+    mp = blk["moe"]
+    with jax.named_scope("moe_route"):
+        idx, w = _moe_route(mp, x, cfg)
+        if routes is not None:
+            routes.append(idx)
+    with jax.named_scope("moe_experts"):
+        y = _expert_ffn_ragged(
+            x, idx, w, _w(mp["wi"], x.dtype), _w(mp["wo"], x.dtype),
+            _w(mp["wge"], x.dtype) if "wge" in mp else None,
+            expert_offset=cfg.expert_offset, num_experts=cfg.num_experts,
+            live=live, with_stats=stats is not None)
+        if stats is not None:
+            y, st = y
+            stats.append(st)
+    if cfg.moe_shared_dim:
+        with jax.named_scope("moe_shared"):
+            y = y + (jax.nn.silu(x @ _w(mp["shared_wg"], x.dtype))
+                     * (x @ _w(mp["shared_wi"], x.dtype))
+                     ) @ _w(mp["shared_wo"], x.dtype)
+    return y
 
 
 def _proj3(x, p, dtype, mesh, wspec):
@@ -524,6 +589,21 @@ def _qkv(ap, h, cfg, mesh=None):
     return q, k, v
 
 
+def _qk_norm_gate(ap, h, q, k, cfg, mesh=None):
+    """afmoe attention's two extras, both inside ``attn_qkv``: RMSNorm on
+    each query and key head (before RoPE), and the output gate's projection
+    ``sigmoid(Wg h) [.., heads, d]`` (None where the model has none), which
+    ``_attn_out`` multiplies into the attention output."""
+    if cfg.qk_norm:
+        from deepspeed_tpu.models.gpt import head_norm
+        q = head_norm(q, ap["q_norm"], cfg)
+        k = head_norm(k, ap["k_norm"], cfg)
+    gate = None
+    if cfg.attn_gate:
+        gate = jax.nn.sigmoid(_proj3(h, ap["wgate"], h.dtype, mesh, "col"))
+    return q, k, gate
+
+
 def _attn_out(ap, o, cfg, mesh=None):
     """Attention output projection ``o [..., k, d] @ wo [k, d, H]``.  The
     heads dim shards under TP (row-parallel: contraction sharded), so a
@@ -554,8 +634,43 @@ def _attn_out(ap, o, cfg, mesh=None):
     return y
 
 
+def kv_page_layout(cfg: GPTConfig, nb_global: int, nb_window: int):
+    """Where each layer's pages lie in a pool of TWO page groups: per layer
+    ``(first page, group)``, group 0 the ``global`` layers (no window: they
+    keep every page of a context) and group 1 the ``window`` layers
+    (``cfg.window_for_layer``: they keep a ring of pages, ragged.py).  The
+    pool stays one flat row-major array: the global layers' ``nb_global``
+    pages each come first, then the window layers' ``nb_window`` each.
+    Static (a tuple of ints), so it is a step program's keyword; a model
+    whose layers are all alike has one group and passes None, which is
+    ``(li * NB, 0)``."""
+    kinds = [cfg.window_for_layer(i) is not None
+             for i in range(cfg.num_layers)]
+    n_global = kinds.count(False)
+    out, g, w = [], 0, 0
+    for is_window in kinds:
+        if is_window:
+            out.append((n_global * nb_global + w * nb_window, 1))
+            w += 1
+        else:
+            out.append((g * nb_global, 0))
+            g += 1
+    return tuple(out)
+
+
+def _group_tables(batch):
+    """The step's block tables by page group: (global,) or (global,
+    window)."""
+    bt = (batch["block_table"],)
+    if "block_table_w" in batch:
+        bt += (batch["block_table_w"],)
+    return bt
+
+
 def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
-                   block_size: int, max_q_per_seq: int, mesh=None):
+                   block_size: int, max_q_per_seq: int, mesh=None,
+                   kv_layout=None, moe_stats: bool = False,
+                   moe_routes: bool = False):
     """One ragged step.
 
     params: unboxed GPT param tree (the "params" subtree).
@@ -568,8 +683,11 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     token_slot = batch["token_slot"]       # [N] (-1 pad)
     token_pos = batch["token_pos"]         # [N]
     dense_idx = batch["token_dense_idx"]   # [N]
-    block_table = batch["block_table"]     # [S, MB]
+    tables = _group_tables(batch)          # [S, MB] per page group
+    block_table = tables[0]
     kv_len = batch["kv_len"]               # [S]
+    stats = [] if moe_stats else None
+    routes = [] if moe_routes else None
 
     N = tokens.shape[0]
     S, MB = block_table.shape
@@ -591,7 +709,8 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
         q_counts = jnp.zeros((S,), jnp.int32).at[scat_slot].add(
             1, mode="drop")
         q_starts = kv_len - q_counts
-    plan = _write_plan(block_table, scat_slot, token_pos, block_size, Q, km)
+    plans = tuple(_write_plan(t, scat_slot, token_pos, block_size, Q, km)
+                  for t in tables)
 
     # [L * num_blocks, nkv, …] views updated IN PLACE through the donated
     # cache buffer — never rebuild the whole pool (a jnp.stack of per-layer
@@ -619,7 +738,8 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
             q, k, v = _qkv(ap, h, cfg, mesh=mesh)
             if lora is not None:
                 q, v = _lora_qv(q, v, h, lora, lora_ids, li)
-            if cfg.use_rope:
+            q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
+            if cfg.rope_for_layer(li):
                 # rope() takes [B, T, n, d] + positions [B, T]
                 q, k = rope(q[None], k[None], token_pos[None], cfg.head_dim,
                             base=cfg.rope_theta, rope_pct=cfg.rope_pct,
@@ -627,8 +747,9 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                             seq_lens=kv_len[jnp.clip(token_slot, 0)][None])
                 q, k = q[0], k[0]
 
+        base, grp = (li * NB, 0) if kv_layout is None else kv_layout[li]
         flat_k_all, flat_v_all, flat_ks, flat_vs = _kv_write(
-            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v, plan, li * NB,
+            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v, plans[grp], base,
             km, mesh=mesh)
 
         # ---- ragged blocked attention (reference blocked_flash +
@@ -650,7 +771,7 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                     cfg.num_heads, cfg.head_dim, cfg.alibi_prescale))
             o_dense = ops.ragged_prefill_attention(
                 q_dense.reshape(S, Q, nkv, gq, hd).astype(dtype),
-                flat_k_all, flat_v_all, block_table + li * NB, kv_len,
+                flat_k_all, flat_v_all, tables[grp] + base, kv_len,
                 q_starts, q_counts, scale=cfg.attn_scale,
                 alibi_slopes=slopes, window=win, mesh=mesh, kv_major=km,
                 impl=cfg.attn_impl,
@@ -659,9 +780,13 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
             o = o_dense[jnp.clip(token_slot, 0), dense_idx]   # [N, nh, hd]
             o = jnp.where(valid[:, None, None], o, 0)
         with jax.named_scope("attn_out"):
-            attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
+            attn_delta = _attn_out(ap, o if gate is None else o * gate, cfg,
+                                   mesh=mesh)
+            if cfg.sandwich_norm:
+                attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
         with jax.named_scope("mlp"):
-            x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh)
+            x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh,
+                                live=valid, stats=stats, routes=routes)
 
     # ---- logits gather (reference ragged_ops/logits_gather): the LAST token
     # of each slot's q rows carries the next-token distribution ----
@@ -669,13 +794,16 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
         last_flat = jnp.zeros((S,), jnp.int32).at[scat_slot].max(
             jnp.arange(N, dtype=jnp.int32), mode="drop")
     logits = _head(params, bb, x, cfg, mesh=mesh, rows=last_flat)  # [S, V]
-    return logits, _rebuild_cache(cache, flat_k_all, flat_v_all,
-                                  flat_ks, flat_vs)
+    cache = _rebuild_cache(cache, flat_k_all, flat_v_all, flat_ks, flat_vs)
+    out = (logits, cache) + ((sum(stats),) if moe_stats else ())
+    # [expert layers, N, k]: the experts each row's router chose
+    return out + ((jnp.stack(routes),) if moe_routes else ())
 
 
 def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                  block_table, cfg: GPTConfig, block_size: int, mesh=None,
-                 flat_ks=None, flat_vs=None, lora=None, adapter_slot=None):
+                 flat_ks=None, flat_vs=None, lora=None, adapter_slot=None,
+                 kv_layout=None, moe_stats: bool = False, routes=None):
     """One decode micro-step: writes each active slot's kv into its page and
     attends over exactly that slot's pages via the paged-attention op
     (ops/paged_attention.py — Pallas kernel on TPU, masked-gather XLA
@@ -684,10 +812,16 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     flat_k_all/flat_v_all: [L*NB, nkv, …] views of the donated cache
     (standard or kv-major trailing order per kv_major_layout(cfg));
     flat_ks/flat_vs: [L*NB, nkv, bs] per-token scales when the cache is
-    int8-quantized.  Returns the updated flat views (incl. scales)."""
+    int8-quantized.  ``block_table`` is one table or the tuple of the page
+    groups' (``_group_tables``: (global,) or, with ``kv_layout``, (global,
+    window)).  Returns the updated flat
+    views (incl. scales) and the step's MoE counters (None unless
+    ``moe_stats``)."""
     from deepspeed_tpu import ops
     bb = params["backbone"]
     dtype = cfg.dtype
+    tables = block_table if isinstance(block_table, tuple) else (block_table,)
+    stats = [] if moe_stats else None
     S = tokens.shape[0]
     L = cfg.num_layers
     NB = flat_k_all.shape[0] // L
@@ -697,8 +831,8 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
 
     x = _embed_tokens(bb, tokens, token_pos, cfg)              # [S, H]
 
-    plan = _write_plan(block_table, jnp.where(active, jnp.arange(S), S),
-                       token_pos, block_size, 1, km)
+    plans = tuple(_write_plan(t, jnp.where(active, jnp.arange(S), S),
+                              token_pos, block_size, 1, km) for t in tables)
     with jax.named_scope("attn_kernel"):
         kv_len = jnp.where(active, token_pos + 1, 0)                # [S]
     if lora is not None:
@@ -714,15 +848,17 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
             q, k, v = _qkv(ap, h, cfg, mesh=mesh)
             if lora is not None:
                 q, v = _lora_qv(q, v, h, lora, lora_ids, li)
-            if cfg.use_rope:
+            q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
+            if cfg.rope_for_layer(li):
                 q, k = rope(q[:, None], k[:, None], token_pos[:, None], hd,
                             base=cfg.rope_theta, rope_pct=cfg.rope_pct,
                             scaling=cfg.rope_scaling,
                             seq_lens=kv_len[:, None])
                 q, k = q[:, 0], k[:, 0]
 
+        base, grp = (li * NB, 0) if kv_layout is None else kv_layout[li]
         flat_k_all, flat_v_all, flat_ks, flat_vs = _kv_write(
-            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v, plan, li * NB,
+            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v, plans[grp], base,
             km, mesh=mesh)
         with jax.named_scope("attn_kernel"):
             qg = q.reshape(S, nkv, g, hd)
@@ -733,19 +869,24 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                                                   cfg.alibi_prescale))
             win = cfg.window_for_layer(li)
             o = ops.paged_attention(qg, flat_k_all, flat_v_all,
-                                    block_table + li * NB, kv_len,
+                                    tables[grp] + base, kv_len,
                                     alibi_slopes=slopes, window=win,
                                     scale=cfg.attn_scale, mesh=mesh,
                                     kv_major=km, impl=cfg.attn_impl,
                                     **_layer_kv(flat_ks, flat_vs))
             o = o.reshape(S, nh, hd)
         with jax.named_scope("attn_out"):
-            attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
+            attn_delta = _attn_out(ap, o if gate is None else o * gate, cfg,
+                                   mesh=mesh)
+            if cfg.sandwich_norm:
+                attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
         with jax.named_scope("mlp"):
-            x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh)
+            x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh,
+                                live=active, stats=stats, routes=routes)
 
     logits = _head(params, bb, x, cfg, mesh=mesh)                  # [S, V]
-    return logits, flat_k_all, flat_v_all, flat_ks, flat_vs
+    return (logits, flat_k_all, flat_v_all, flat_ks, flat_vs,
+            sum(stats) if moe_stats else None)
 
 
 def _flat_cache_views(cache: PagedKVCache, cfg: GPTConfig):
@@ -785,7 +926,8 @@ def _rebuild_cache(cache: PagedKVCache, fk, fv, fks, fvs) -> PagedKVCache:
 def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
                         temperature, top_p,
                         cfg: GPTConfig, *, block_size: int, steps: int,
-                        sample_fn, mesh=None):
+                        sample_fn, mesh=None, kv_layout=None,
+                        moe_stats: bool = False):
     """T decode steps fused into one device program (``lax``-unrolled scan):
     each step samples on device and feeds the token to the next step, so a
     burst costs ONE dispatch instead of T× (transfer + step + sample + fetch) —
@@ -796,10 +938,11 @@ def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
     feedback path, so burst follows burst with no host round trip), active [S],
     pos0 [S], block_table [S, MB] — blocks for positions pos0..pos0+T-1 must
     be pre-allocated.
-    Returns (tokens [T, S], prev_tokens' [S], rng', cache).
+    Returns (tokens [T, S], prev_tokens' [S], rng', cache), and with
+    ``moe_stats`` the burst's MoE counters, summed over its steps.
     """
     flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
-    bt = batch["block_table"]
+    bt = _group_tables(batch)
     active = batch["active"]
     lora = batch.get("lora")
     adapter_slot = batch.get("adapter_slot")
@@ -809,34 +952,37 @@ def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
 
     def step(carry, _):
         flat_k, flat_v, flat_ks, flat_vs, tokens, pos, rng = carry
-        logits, flat_k, flat_v, flat_ks, flat_vs = _decode_core(
+        logits, flat_k, flat_v, flat_ks, flat_vs, stats = _decode_core(
             params, flat_k, flat_v, tokens, active, pos, bt, cfg, block_size,
             mesh=mesh, flat_ks=flat_ks, flat_vs=flat_vs, lora=lora,
-            adapter_slot=adapter_slot)
+            adapter_slot=adapter_slot, kv_layout=kv_layout,
+            moe_stats=moe_stats)
         with jax.named_scope("sample"):
             rng, sub = jax.random.split(rng)
             nxt = sample_fn(logits, sub, temperature=temperature,
                             top_p=top_p)
             nxt = nxt.astype(jnp.int32)
             pos = pos + 1
-        return (flat_k, flat_v, flat_ks, flat_vs, nxt, pos, rng), nxt
+        return (flat_k, flat_v, flat_ks, flat_vs, nxt, pos, rng), (nxt, stats)
 
     carry = (flat_k, flat_v, flat_ks, flat_vs, tokens0, batch["pos0"], rng)
     # the loop itself belongs to the pool: what it does besides its body's
     # (scoped) work is carry the pool's views from step to step
     with jax.named_scope("kv_pool"):
-        (flat_k, flat_v, flat_ks, flat_vs, last, _, rng), toks = \
+        (flat_k, flat_v, flat_ks, flat_vs, last, _, rng), (toks, stats) = \
             jax.lax.scan(step, carry, None, length=steps)
     with jax.named_scope("sample"):
         prev_out = jnp.where(active, last, prev_tokens)
-    return toks, prev_out, rng, _rebuild_cache(cache, flat_k, flat_v,
-                                               flat_ks, flat_vs)
+    out = (toks, prev_out, rng, _rebuild_cache(cache, flat_k, flat_v,
+                                               flat_ks, flat_vs))
+    return out + (jnp.sum(stats, axis=0),) if moe_stats else out
 
 
 def ragged_forward_sampled(params, cache: PagedKVCache, batch, prev_tokens,
                            rng, temperature, top_p, cfg: GPTConfig, *,
                            block_size: int, max_q_per_seq: int, sample_fn,
-                           mesh=None):
+                           mesh=None, kv_layout=None,
+                           moe_stats: bool = False):
     """Mixed prefill/decode step with in-graph sampling and device-resident
     token feedback: tokens flagged ``from_device`` are read from
     ``prev_tokens[slot]`` (the previous step's on-device samples) instead of
@@ -845,17 +991,19 @@ def ragged_forward_sampled(params, cache: PagedKVCache, batch, prev_tokens,
     therefore never leave the device — generate() chains these dispatches
     without a single host sync (the FastGen hot loop re-shaped for a
     high-latency host↔device link).
-    Returns (prev_tokens' [S], rng', cache)."""
+    Returns (prev_tokens' [S], rng', cache), and with ``moe_stats`` the
+    step's MoE counters."""
     with jax.named_scope("embed"):
         tokens = jnp.where(batch["from_device"],
                            prev_tokens[jnp.clip(batch["token_slot"], 0)],
                            batch["tokens"])
-    logits, cache = ragged_forward(
+    logits, cache, *stats = ragged_forward(
         params, cache, {**batch, "tokens": tokens}, cfg,
-        block_size=block_size, max_q_per_seq=max_q_per_seq, mesh=mesh)
+        block_size=block_size, max_q_per_seq=max_q_per_seq, mesh=mesh,
+        kv_layout=kv_layout, moe_stats=moe_stats)
     prev_out, rng = _sample_next(sample_fn, logits, rng, temperature, top_p,
                                  batch["served"], prev_tokens)
-    return prev_out, rng, cache
+    return (prev_out, rng, cache, *stats)
 
 
 def ragged_forward_sampled_draft(params, draft_params, cache: PagedKVCache,
@@ -913,22 +1061,25 @@ def ragged_decode_sampled_draft(params, draft_params, cache: PagedKVCache,
 
 def ragged_decode_sampled(params, cache: PagedKVCache, batch, prev_tokens,
                           rng, temperature, top_p, cfg: GPTConfig, *,
-                          block_size: int, sample_fn, mesh=None):
+                          block_size: int, sample_fn, mesh=None,
+                          kv_layout=None, moe_stats: bool = False):
     """Decode-only step with in-graph sampling + device feedback (see
     ragged_forward_sampled).  batch tokens/active/token_pos/block_table are
     slot-indexed [S]; from_device [S] selects prev_tokens as input; served [S]
     marks the slots whose sample is a real next token (a 1-token mid-prefill
     chunk is active but NOT served — its logits are mid-prompt garbage).
-    Returns (prev_tokens' [S], rng', cache)."""
+    Returns (prev_tokens' [S], rng', cache), and with ``moe_stats`` the
+    step's MoE counters."""
     with jax.named_scope("embed"):
         tokens = jnp.where(batch["from_device"], prev_tokens,
                            batch["tokens"])
-    logits, cache = ragged_decode_forward(
+    logits, cache, *stats = ragged_decode_forward(
         params, cache, {**batch, "tokens": tokens}, cfg,
-        block_size=block_size, mesh=mesh)
+        block_size=block_size, mesh=mesh, kv_layout=kv_layout,
+        moe_stats=moe_stats)
     prev_out, rng = _sample_next(sample_fn, logits, rng, temperature, top_p,
                                  batch["served"], prev_tokens)
-    return prev_out, rng, cache
+    return (prev_out, rng, cache, *stats)
 
 
 def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
@@ -966,7 +1117,8 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
         with jax.named_scope("attn_qkv"):
             h = _norm(blk["Norm_0"], x, cfg)
             q, k, v = _qkv(ap, h, cfg, mesh=mesh)
-            if cfg.use_rope:
+            q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
+            if cfg.rope_for_layer(li):
                 q, k = rope(q, k, positions, hd, base=cfg.rope_theta,
                             rope_pct=cfg.rope_pct, scaling=cfg.rope_scaling,
                             seq_lens=kv_len[:, None])
@@ -992,7 +1144,10 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
             # from dead rows
             o = jnp.where(active[:, None, None, None], o, 0)
         with jax.named_scope("attn_out"):
-            attn_delta = _attn_out(ap, o, cfg, mesh=mesh)
+            attn_delta = _attn_out(ap, o if gate is None else o * gate, cfg,
+                                   mesh=mesh)
+            if cfg.sandwich_norm:
+                attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
         # FFN/MoE body is token-wise and (for MoE) expects FLAT tokens
         with jax.named_scope("mlp"):
             H = x.shape[-1]
@@ -1045,7 +1200,7 @@ def _speculative_burst_core(params, draft_params, cache: PagedKVCache,
         # fused program
         with jax.named_scope("draft"):
             for j in range(gamma + 1):
-                dlogits, ddk, ddv, ddks, ddvs = _decode_core(
+                dlogits, ddk, ddv, ddks, ddvs, _ = _decode_core(
                     draft_params, ddk, ddv, dtok, active, dpos, bt,
                     draft_cfg, block_size, mesh=mesh, flat_ks=ddks,
                     flat_vs=ddvs)
@@ -1207,7 +1362,9 @@ def speculative_burst_sampled(params, draft_params, cache: PagedKVCache,
 
 
 def ragged_decode_forward(params, cache: PagedKVCache, batch,
-                          cfg: GPTConfig, *, block_size: int, mesh=None):
+                          cfg: GPTConfig, *, block_size: int, mesh=None,
+                          kv_layout=None, moe_stats: bool = False,
+                          moe_routes: bool = False):
     """Decode-only step: one token per active slot, attending over exactly that
     slot's pages via the paged-attention op (Pallas kernel on TPU; the gathered
     masked-softmax XLA path is the fallback + ground truth) — the analog of the
@@ -1218,9 +1375,14 @@ def ragged_decode_forward(params, cache: PagedKVCache, batch,
     block_table [S, MB] int32 (each slot's physical pages, in order).
     """
     flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
-    logits, flat_k, flat_v, flat_ks, flat_vs = _decode_core(
+    bt = _group_tables(batch)
+    routes = [] if moe_routes else None
+    logits, flat_k, flat_v, flat_ks, flat_vs, stats = _decode_core(
         params, flat_k, flat_v, batch["tokens"], batch["active"],
-        batch["token_pos"], batch["block_table"], cfg, block_size, mesh=mesh,
-        flat_ks=flat_ks, flat_vs=flat_vs, lora=batch.get("lora"),
-        adapter_slot=batch.get("adapter_slot"))
-    return logits, _rebuild_cache(cache, flat_k, flat_v, flat_ks, flat_vs)
+        batch["token_pos"], bt, cfg, block_size, mesh=mesh, flat_ks=flat_ks,
+        flat_vs=flat_vs, lora=batch.get("lora"),
+        adapter_slot=batch.get("adapter_slot"), kv_layout=kv_layout,
+        moe_stats=moe_stats, routes=routes)
+    cache = _rebuild_cache(cache, flat_k, flat_v, flat_ks, flat_vs)
+    out = (logits, cache) + ((stats,) if moe_stats else ())
+    return out + ((jnp.stack(routes),) if moe_routes else ())

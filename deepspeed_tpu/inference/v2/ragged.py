@@ -355,6 +355,12 @@ class SequenceDescriptor:
     # LoRA adapter this request pins resident (0 = base model, no pin) —
     # bind_adapter() acquires the pool pages' refcounts, flush releases them
     adapter: int = 0
+    # the WINDOW page group's pages, by logical block like ``blocks`` (a
+    # model with window and global layers, DSStateManager.window): the
+    # first ``w_released`` entries are -1, given back once every token of
+    # theirs lay behind the window of the oldest query still to come
+    wblocks: List[int] = dataclasses.field(default_factory=list)
+    w_released: int = 0
 
     @property
     def in_flight(self) -> bool:
@@ -376,11 +382,16 @@ class RaggedBatch:
     token_pos: np.ndarray       # [N] int32 logical position, pad 0
     token_dense_idx: np.ndarray  # [N] int32 index within the slot's q rows
     block_table: np.ndarray     # [S, MB] int32, pad 0
+    block_table_w: Optional[np.ndarray]  # the window page group's, or None
     kv_len: np.ndarray          # [S] int32 kv length AFTER this step
     q_len: np.ndarray           # [S] int32 new tokens this step
     logits_slots: List[int]     # slots whose last-token logits are meaningful
     slot_uid: Dict[int, int]    # slot -> uid for this step
     total_tokens: int
+
+    def table_operands(self, width: Optional[int] = None):
+        return DSStateManager.table_operands(
+            (self.block_table, self.block_table_w), width)
 
 
 class DSStateManager:
@@ -390,12 +401,36 @@ class DSStateManager:
 
     def __init__(self, max_tracked_sequences: int, num_blocks: int,
                  block_size: int, max_seq_len: int,
-                 prefix_cache: bool = False):
+                 prefix_cache: bool = False, window: Optional[int] = None,
+                 window_blocks: int = 0):
+        """``window`` / ``window_blocks``: the model has sliding-window
+        layers beside global ones, and the pool a second page group for
+        them (``window_blocks`` pages a window layer; ``num_blocks`` is
+        then the global group's a global layer).  Each group has its
+        allocator and its block table per sequence.  A window layer reads
+        key ``j`` for query ``i`` iff ``i - window < j <= i``, so before a
+        step whose oldest query sits at ``seen_tokens`` the group gives
+        back every page wholly before ``seen_tokens - window + 1``
+        (``_release_window``): a sequence holds a ring of at most
+        ``window_ring(q)`` pages there, whatever its length.  A model whose
+        layers are all alike has one group (``window`` None) and runs as
+        it always did."""
         self.max_tracked_sequences = int(max_tracked_sequences)
         self.block_size = int(block_size)
         self.max_seq_len = int(max_seq_len)
         self.max_blocks_per_seq = -(-self.max_seq_len // self.block_size)
         self.allocator = BlockedAllocator(num_blocks)
+        self.window = int(window) if window else None
+        self.wallocator = (BlockedAllocator(window_blocks)
+                           if self.window else None)
+        if self.window and prefix_cache:
+            raise NotImplementedError(
+                "prefix_cache with a window page group: a cached prefix's "
+                "window pages are released as the sequence moves on, so "
+                "they cannot be aliased; turn the prefix cache off")
+        # ever allocated / given back behind the window (telemetry)
+        self.w_allocated_total = 0
+        self.w_released_total = 0
         self.radix: Optional[RadixKVCache] = (
             RadixKVCache(self.allocator, self.block_size)
             if prefix_cache else None)
@@ -437,9 +472,91 @@ class DSStateManager:
             # funnels through here, so pins release exactly once per bind
             self.adapters.release(seq.adapter)
         self.allocator.release(seq.blocks)
+        if self.window:
+            self.wallocator.release(seq.wblocks[seq.w_released:])
         self._free_slots.appendleft(seq.slot)
 
+    # ------------------------------------------------- window page group
+    def window_ring(self, max_new_tokens: int) -> int:
+        """Most pages a sequence holds in the window group while steps add
+        up to ``max_new_tokens`` rows each: the window and the step's rows
+        in pages, and one more because neither end is page-aligned."""
+        return -(-(self.window + max_new_tokens) // self.block_size) + 1
+
+    def _window_first_live(self, seq: SequenceDescriptor) -> int:
+        """First logical block a window layer still reads: the oldest query
+        to come sits at ``seen_tokens`` and sees keys from ``seen_tokens -
+        window + 1`` on (the kernels start at this page too)."""
+        return max(0, seq.seen_tokens - self.window + 1) // self.block_size
+
+    def _window_need(self, seq: Optional[SequenceDescriptor],
+                     new_tokens: int) -> int:
+        """Window-group pages a step of ``new_tokens`` rows needs beyond
+        those the sequence holds and those it gives back first."""
+        if seq is None:
+            return -(-new_tokens // self.block_size)
+        total = -(-(seq.seen_tokens + new_tokens) // self.block_size)
+        releasable = max(0, self._window_first_live(seq) - seq.w_released)
+        return total - len(seq.wblocks) - releasable
+
+    def _release_window(self, seq: SequenceDescriptor) -> None:
+        first = min(self._window_first_live(seq), len(seq.wblocks))
+        if first > seq.w_released:
+            self.wallocator.release(seq.wblocks[seq.w_released:first])
+            seq.wblocks[seq.w_released:first] = [-1] * (first
+                                                        - seq.w_released)
+            self.w_released_total += first - seq.w_released
+            seq.w_released = first
+
+    def fits(self, steps) -> bool:
+        """Whether every page group can supply the steps ``[(sequence or
+        None for one not yet created, new tokens)]`` together: THE supply
+        check of the scheduler (decode, prompt chunk, burst sizing,
+        admission, ``put``, ``can_schedule``)."""
+        bs = self.block_size
+        need = sum(seq.kv_blocks_needed(n, bs) if seq is not None
+                   else -(-n // bs) for seq, n in steps)
+        if need > self.available_blocks:
+            return False
+        if self.window:
+            need_w = sum(self._window_need(seq, n) for seq, n in steps)
+            return need_w <= self.wallocator.free_blocks
+        return True
+
+    def tables(self):
+        """Zeroed host block tables of a step, one per page group."""
+        shape = (self.max_tracked_sequences, self.max_blocks_per_seq)
+        return tuple(np.zeros(shape, np.int32)
+                     for _ in range(2 if self.window else 1))
+
+    def write_tables(self, tables, seq: SequenceDescriptor) -> None:
+        """``seq``'s pages into its row of each group's table.  A page the
+        window group gave back reads 0: no kernel looks at it (their page
+        loops start at the window), and the gather of the XLA fallback
+        masks it."""
+        bl = np.asarray(seq.blocks, np.int32)
+        tables[0][seq.slot, :len(bl)] = bl
+        if self.window:
+            wl = np.maximum(np.asarray(seq.wblocks, np.int32), 0)
+            tables[1][seq.slot, :len(wl)] = wl
+
+    @staticmethod
+    def table_operands(tables, width: Optional[int] = None):
+        """The tables as a step program's operands (the first ``width``
+        columns of each): ``block_table`` and, where there is a window
+        group, ``block_table_w``."""
+        names = ("block_table", "block_table_w")
+        return {n: (t if width is None else t[:, :width])
+                for n, t in zip(names, tables) if t is not None}
+
     def ensure_blocks(self, seq: SequenceDescriptor, new_tokens: int) -> None:
+        if self.window:
+            self._release_window(seq)
+            need_w = (-(-(seq.seen_tokens + new_tokens) // self.block_size)
+                      - len(seq.wblocks))
+            if need_w > 0:
+                seq.wblocks.extend(self.wallocator.allocate(need_w))
+                self.w_allocated_total += need_w
         need = seq.kv_blocks_needed(new_tokens, self.block_size)
         if need:
             short = need - self.allocator.free_blocks
@@ -597,13 +714,12 @@ def build_ragged_batch(schedule, state: DSStateManager, token_budget: int,
     appended to the sequence's KV at positions [seen, seen+len).
     """
     S = state.max_tracked_sequences
-    MB = state.max_blocks_per_seq
     N = token_budget
     tokens = np.zeros(N, np.int32)
     token_slot = np.full(N, -1, np.int32)
     token_pos = np.zeros(N, np.int32)
     token_dense = np.zeros(N, np.int32)
-    block_table = np.zeros((S, MB), np.int32)
+    tables = state.tables()
     kv_len = np.zeros(S, np.int32)
     q_len = np.zeros(S, np.int32)
     logits_slots: List[int] = []
@@ -620,8 +736,7 @@ def build_ragged_batch(schedule, state: DSStateManager, token_budget: int,
         token_pos[cursor:cursor + n] = np.arange(seq.seen_tokens,
                                                  seq.seen_tokens + n)
         token_dense[cursor:cursor + n] = np.arange(n)
-        bt = np.asarray(seq.blocks, np.int32)
-        block_table[sl, :len(bt)] = bt
+        state.write_tables(tables, seq)
         kv_len[sl] = seq.seen_tokens + n
         q_len[sl] = n
         logits_slots.append(sl)
@@ -629,6 +744,8 @@ def build_ragged_batch(schedule, state: DSStateManager, token_budget: int,
         cursor += n
     return RaggedBatch(tokens=tokens, token_slot=token_slot,
                        token_pos=token_pos, token_dense_idx=token_dense,
-                       block_table=block_table, kv_len=kv_len, q_len=q_len,
+                       block_table=tables[0],
+                       block_table_w=tables[1] if state.window else None,
+                       kv_len=kv_len, q_len=q_len,
                        logits_slots=logits_slots, slot_uid=slot_uid,
                        total_tokens=cursor)

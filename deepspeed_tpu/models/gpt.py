@@ -138,10 +138,66 @@ class GPTConfig:
     # activation fake-quant bits (compression/pruning.py quant_act —
     # reference basic_layer.py QuantAct); None/0 = off
     act_quant_bits: Optional[int] = None
+    # afmoe family (Trinity; checkpoint/hf.py maps model_type "afmoe"):
+    # the router, the expert layer's share, and the attention variants
+    moe_router: str = "softmax"         # "sigmoid": scores are sigmoid(fp32
+    #                                     logits), dropless route only
+    moe_route_norm: bool = True         # the chosen k renormalised to sum 1
+    moe_route_scale: float = 1.0        # ... and then scaled by this
+    moe_router_bias: bool = False       # `expert_bias` [E]: added to the
+    #                                     scores for the SELECTION only
+    moe_shared_dim: int = 0             # shared expert's width (0 = none):
+    #                                     every token takes it
+    moe_dense_layers: Optional[int] = None  # leading dense layers, every
+    #                                     later one is MoE (None: moe_every)
+    moe_expert_dim: Optional[int] = None  # expert width where it differs
+    #                                     from the dense mlp_dim
+    # the share of an expert-parallel deployment held here: the router is
+    # num_experts wide, the weights hold experts [expert_offset,
+    # expert_offset + experts_held) and the layer computes their part of
+    # the result (None = all of them)
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    attn_gate: bool = False             # o * sigmoid(Wg n1(x)) before Wo
+    qk_norm: bool = False               # RMSNorm on each query and key head
+    rope_layers: str = "all"            # "window": RoPE on layers with a
+    #                                     sliding window only (NoPE global)
+    sandwich_norm: bool = False         # norms after attention and FFN too:
+    #                                     x + n2(attn(n1 x)); h + n4(f(n3 h))
 
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    def is_moe_layer(self, i: int) -> bool:
+        """Whether layer ``i`` holds experts: after ``moe_dense_layers``
+        leading dense layers where that is set, else every ``moe_every``-th
+        (reference examples put MoE on every other layer)."""
+        if self.num_experts <= 0:
+            return False
+        if self.moe_dense_layers is not None:
+            return i >= self.moe_dense_layers
+        return i % self.moe_every == self.moe_every - 1
+
+    def rope_for_layer(self, i: int) -> bool:
+        """RoPE on layer ``i``: all layers, or (``rope_layers="window"``)
+        only those ``window_for_layer`` gives a window."""
+        if not self.use_rope:
+            return False
+        if self.rope_layers == "all":
+            return True
+        if self.rope_layers != "window":
+            raise ValueError(f"rope_layers must be all|window, got "
+                             f"{self.rope_layers!r}")
+        return self.window_for_layer(i) is not None
+
+    @property
+    def local_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_dim(self) -> int:
+        return self.moe_expert_dim or self.mlp_dim
 
     def window_for_layer(self, i: int):
         """Per-layer sliding window — THE gating rule shared by the training
@@ -405,6 +461,13 @@ class Norm(nn.Module):
         return layer_norm(x, scale, bias, eps=c.norm_eps or LN_EPS)
 
 
+def head_norm(x, scale, cfg):
+    """RMSNorm over the head dim of ``x [..., heads, d]`` (``qk_norm``)."""
+    from deepspeed_tpu.ops import rms_norm
+    from deepspeed_tpu.ops.norms import RMS_EPS
+    return rms_norm(x, scale, eps=cfg.norm_eps or RMS_EPS)
+
+
 def attend_with_mask(q, k, v, mask, bias=None, scale=None):
     """Attention with an explicit boolean mask [B, Tq, S] — the KV-cache /
     padded-prefill path (reference: masked softmax in
@@ -429,10 +492,13 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions, deterministic: bool,
                  use_cache: bool = False, kv_mask=None, start_index=0,
-                 kv_positions=None, window=None, fused_ok: bool = False):
+                 kv_positions=None, window=None, fused_ok: bool = False,
+                 use_rope: Optional[bool] = None):
         c = self.cfg
         B, T, H = x.shape
         nh, nkv, hd = c.num_heads, c.kv_heads, c.head_dim
+        if use_rope is None:
+            use_rope = c.use_rope
         if c.act_quant_bits:
             from deepspeed_tpu.compression.pruning import quant_act
             x = quant_act(x, c.act_quant_bits)
@@ -452,7 +518,16 @@ class Attention(nn.Module):
         cm_fused = _collective_matmul_active(c, self.mesh, T, nh * hd,
                                              use_cache=use_cache)
 
+        if c.attn_gate:
+            wgate = self.param("wgate", _part(_kernel_init(),
+                                              ("embed", "heads", "kv")),
+                               (H, nh, hd), c.param_dtype)
+            gate = jax.nn.sigmoid(jnp.einsum("bth,hnd->btnd", x,
+                                             wgate.astype(x.dtype)))
+
         def out_proj(o):
+            if c.attn_gate:
+                o = o * gate
             if cm_fused:
                 # row-parallel over tp-sharded heads: the output all-reduce
                 # decomposed into ring chunk matmuls + neighbor hops
@@ -480,7 +555,13 @@ class Attention(nn.Module):
                                            ("heads", "kv")),
                                (nkv, hd), c.param_dtype).astype(x.dtype)
 
-        if c.use_rope:
+        if c.qk_norm:
+            q, k = head_norm(q, self.param(
+                "q_norm", _part(nn.initializers.ones, ("kv",)), (hd,),
+                c.param_dtype), c), head_norm(k, self.param(
+                    "k_norm", _part(nn.initializers.ones, ("kv",)), (hd,),
+                    c.param_dtype), c)
+        if use_rope:
             q, k = rope(q, k, positions, hd, base=c.rope_theta,
                         rope_pct=c.rope_pct, scaling=c.rope_scaling)
 
@@ -650,7 +731,7 @@ class Block(nn.Module):
     def __call__(self, x, positions, deterministic: bool,
                  use_cache: bool = False, kv_mask=None, start_index=0,
                  kv_positions=None, pld_keep=None, window=None,
-                 fused_ok: bool = False):
+                 fused_ok: bool = False, use_rope: Optional[bool] = None):
         c = self.cfg
 
         def pld_mask():
@@ -676,25 +757,29 @@ class Block(nn.Module):
             # ln_attn + ln_mlp pair) and their outputs sum into one residual
             # add (reference inference/v2/model_implementations/falcon,
             # module_inject/containers/ — parallel_attn semantics).
-            if self.is_moe:
-                raise ValueError("parallel_block + MoE is not a supported "
-                                 "architecture combination")
+            if self.is_moe or c.sandwich_norm:
+                raise ValueError("parallel_block + MoE / sandwich_norm is "
+                                 "not a supported architecture combination")
             h_attn = Norm(c)(x)                       # Norm_0
             h_mlp = Norm(c)(x) if c.parallel_norms == 2 else h_attn  # Norm_1
             a = Attention(c, mesh=self.mesh)(h_attn, positions, deterministic,
                                              use_cache, kv_mask, start_index,
                                              kv_positions, window=window,
-                                             fused_ok=fused_ok)
+                                             fused_ok=fused_ok,
+                                             use_rope=use_rope)
             return (x + pld_gate(a)
                     + pld_gate(MLP(c, mesh=self.mesh)(h_mlp, deterministic,
                                                       use_cache=use_cache)),
                     jnp.float32(0.0))
-        x = x + pld_gate(
-            Attention(c, mesh=self.mesh)(Norm(c)(x), positions,
+        a = Attention(c, mesh=self.mesh)(Norm(c)(x), positions,
                                          deterministic, use_cache,
                                          kv_mask, start_index,
                                          kv_positions, window=window,
-                                         fused_ok=fused_ok))
+                                         fused_ok=fused_ok,
+                                         use_rope=use_rope)
+        if c.sandwich_norm:
+            a = Norm(c, name="post_attn_norm")(a)
+        x = x + pld_gate(a)
         if self.is_moe:
             from deepspeed_tpu.moe import MoE
             rng = (self.make_rng("dropout")
@@ -702,7 +787,14 @@ class Block(nn.Module):
             moe_out, aux = MoE(hidden_size=c.hidden_size,
                                num_experts=c.num_experts, k=c.moe_k,
                                capacity_factor=c.moe_capacity_factor,
-                               mlp_ratio=c.mlp_ratio, mlp_dim=c.mlp_dim,
+                               mlp_ratio=c.mlp_ratio, mlp_dim=c.expert_dim,
+                               router=c.moe_router,
+                               route_norm=c.moe_route_norm,
+                               route_scale=c.moe_route_scale,
+                               router_bias=c.moe_router_bias,
+                               shared_dim=c.moe_shared_dim,
+                               experts_held=c.experts_held,
+                               expert_offset=c.expert_offset,
                                mesh=self.mesh,
                                param_dtype=c.param_dtype,
                                dropless=c.moe_dropless,
@@ -718,12 +810,16 @@ class Block(nn.Module):
                     pld_keep, moe_out.dtype)
                 moe_out = moe_out * scale
                 aux = aux * scale.astype(aux.dtype)  # dropped ffn: no LB loss
+            if c.sandwich_norm:
+                moe_out = Norm(c, name="post_ffn_norm")(moe_out)
             x = x + moe_out
         else:
             aux = jnp.float32(0.0)
-            x = x + pld_gate(MLP(c, mesh=self.mesh)(Norm(c)(x),
-                                                    deterministic,
-                                                    use_cache=use_cache))
+            f = MLP(c, mesh=self.mesh)(Norm(c)(x), deterministic,
+                                       use_cache=use_cache)
+            if c.sandwich_norm:
+                f = Norm(c, name="post_ffn_norm")(f)
+            x = x + pld_gate(f)
         return x, aux
 
 
@@ -774,14 +870,13 @@ class GPTBackbone(nn.Module):
         if c.remat and not use_cache:
             # static: deterministic, use_cache, window, fused_ok (the last two
             # select the fused attention path at trace time)
-            block_cls = nn.remat(Block, static_argnums=(3, 4, 9, 10),
+            block_cls = nn.remat(Block, static_argnums=(3, 4, 9, 10, 11),
                                  policy=jax.checkpoint_policies.nothing_saveable)
         ltd_layers = tuple(c.random_ltd_layer_ids or ())
         aux_total = jnp.float32(0.0)
         for i in range(c.num_layers):
-            # reference examples put MoE on every other layer
-            is_moe = (c.num_experts > 0 and i % c.moe_every == c.moe_every - 1)
-            block = block_cls(c, is_moe, self.mesh, name=f"block_{i}")
+            block = block_cls(c, c.is_moe_layer(i), self.mesh,
+                              name=f"block_{i}")
             keep = None
             if pld_theta is not None:
                 from deepspeed_tpu.runtime.progressive_layer_drop import \
@@ -797,12 +892,14 @@ class GPTBackbone(nn.Module):
                     # 10=fused_ok) must be within the positional arg list;
                     # gathered positions are non-canonical → fused_ok False
                     lambda xk, pk: block(xk, pk, deterministic, False,
-                                         None, 0, None, keep, win, False),
+                                         None, 0, None, keep, win, False,
+                                         c.rope_for_layer(i)),
                     x, positions, idx)
             else:
                 x, aux = block(x, positions, deterministic,
                                use_cache, kv_mask, start_index, kv_positions,
-                               keep, win, canonical_pos and not use_cache)
+                               keep, win, canonical_pos and not use_cache,
+                               c.rope_for_layer(i))
             aux_total = aux_total + aux
         x = Norm(c, name="final_norm")(x)
         return x, emb, aux_total
@@ -949,13 +1046,24 @@ class GPTChunkedLoss(GPT):
 
 
 def count_params(cfg: GPTConfig) -> int:
+    """Parameters of the model ``cfg`` describes, as held here (an expert
+    layer counts the experts it holds: ``cfg.local_experts``)."""
     H, M, V = cfg.hidden_size, cfg.mlp_dim, cfg.vocab_size
     norms = 1 if (cfg.parallel_block and cfg.parallel_norms == 1) else 2
-    per_layer = (cfg.num_heads * cfg.head_dim * H * 2          # wq, wo
-                 + cfg.kv_heads * cfg.head_dim * H * 2         # wk, wv
-                 + H * M * (3 if cfg.gated_mlp else 2)         # mlp
-                 + H * norms * (1 if cfg.use_rmsnorm else 2))
-    total = per_layer * cfg.num_layers + V * H + H
+    if cfg.sandwich_norm:
+        norms += 2
+    n_mat = 3 if cfg.gated_mlp else 2
+    attn = (cfg.num_heads * cfg.head_dim * H * (3 if cfg.attn_gate else 2)
+            + cfg.kv_heads * cfg.head_dim * H * 2              # wk, wv
+            + (2 * cfg.head_dim if cfg.qk_norm else 0)
+            + H * norms * (1 if cfg.use_rmsnorm else 2))
+    moe_layers = sum(cfg.is_moe_layer(i) for i in range(cfg.num_layers))
+    moe_ffn = (cfg.local_experts * H * cfg.expert_dim * n_mat
+               + H * cfg.num_experts                            # router
+               + (cfg.num_experts if cfg.moe_router_bias else 0)
+               + 3 * H * cfg.moe_shared_dim)
+    total = (attn * cfg.num_layers + moe_ffn * moe_layers
+             + H * M * n_mat * (cfg.num_layers - moe_layers) + V * H + H)
     if not cfg.use_rope and not cfg.use_alibi:
         total += cfg.max_seq_len * H
     if cfg.embed_norm:

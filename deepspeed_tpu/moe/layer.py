@@ -77,30 +77,73 @@ def _expert_ffn(d, wi, wo, wg=None):
     return jnp.einsum("ecm,emh->ech", h, wo.astype(d.dtype))
 
 
-def _expert_ffn_ragged(tokens, expert_idx, weights, wi, wo, wg=None):
-    """Dropless grouped GEMM via ``lax.ragged_dot`` (megablox semantics —
-    reference analog: inference/v2 MoE gather/scatter + cutlass grouped GEMM,
-    and the MegaBlocks paper): tokens sort by expert, each expert multiplies
-    exactly its rows — no capacity padding, no dropped tokens.
+def _expert_ffn_ragged(tokens, expert_idx, weights, wi, wo, wg=None, *,
+                       expert_offset: int = 0, num_experts=None, live=None,
+                       with_stats: bool = False):
+    """Dropless grouped GEMM (megablox semantics; reference analog:
+    inference/v2 MoE gather/scatter + cutlass grouped GEMM, and the
+    MegaBlocks paper): assignments sort by expert, each expert multiplies
+    exactly its rows: no capacity padding, no dropped tokens.
 
-    tokens [S, H]; expert_idx [S, k]; weights [S, k] → [S, H]."""
+    tokens [S, H]; expert_idx [S, k] over all ``num_experts``; weights
+    [S, k] -> [S, H].
+
+    **A share.**  ``wi``/``wo``/``wg`` hold the experts ``[expert_offset,
+    expert_offset + E)`` of ``num_experts`` (default: all of them, offset
+    0): one chip's part of an expert-parallel layer.  The result is the
+    part those experts give; what the absent ones would add is left out,
+    and nothing here stands in for them.  An assignment to an expert not
+    held (or of a row ``live`` masks out: padding, an idle slot) is dropped
+    BEFORE the gather: it takes the sentinel id ``E``, sorts behind every
+    held expert's rows and belongs to no group.  Shapes stay static (the
+    row buffer is ``S * k`` long, the worst case of every assignment being
+    local) while the rows are dynamic: the grouped GEMM multiplies the
+    first ``sum(group_sizes)`` rows, one run per expert, and what lies
+    behind them is neither multiplied nor scattered back.
+
+    ``with_stats``: also int32 ``[local assignments, assignments of live
+    rows, local experts with at least one row]``, for the serving counters.
+    """
+    from deepspeed_tpu import ops
     S, H = tokens.shape
     k = expert_idx.shape[1]
     E = wi.shape[0]
     flat_e = expert_idx.reshape(-1)                       # [S*k]
+    share = not (expert_offset == 0 and num_experts in (None, E)
+                 and live is None)
+    if share:
+        local = flat_e - expert_offset
+        keep = (local >= 0) & (local < E)
+        if live is not None:
+            keep = keep & jnp.repeat(live, k)
+        flat_e = jnp.where(keep, local, E)
     order = jnp.argsort(flat_e)                           # group by expert
     tok_rows = jnp.repeat(jnp.arange(S), k)[order]        # source token/row
     sorted_tok = tokens[tok_rows]
-    group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
-    h = jax.lax.ragged_dot(sorted_tok, wi.astype(tokens.dtype), group_sizes)
+    group_sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1, mode="drop")
+    h = ops.grouped_gemm(sorted_tok, wi.astype(tokens.dtype), group_sizes)
     if wg is not None:
-        h = nn.silu(jax.lax.ragged_dot(sorted_tok, wg.astype(tokens.dtype),
-                                       group_sizes)) * h
+        h = nn.silu(ops.grouped_gemm(sorted_tok, wg.astype(tokens.dtype),
+                                     group_sizes)) * h
     else:
         h = nn.gelu(h)
-    o = jax.lax.ragged_dot(h, wo.astype(tokens.dtype), group_sizes)
+    o = ops.grouped_gemm(h, wo.astype(tokens.dtype), group_sizes)
     w = weights.reshape(-1)[order].astype(o.dtype)
-    return jnp.zeros_like(tokens).at[tok_rows].add(o * w[:, None])
+    if share:
+        # rows behind the last group were not multiplied: whatever the
+        # backend left there must not reach the scatter
+        done = jnp.arange(S * k) < jnp.sum(group_sizes)
+        o = jnp.where(done[:, None], o, 0)
+        tok_rows = jnp.where(done, tok_rows, S)           # dropped
+    out = jnp.zeros_like(tokens).at[tok_rows].add(o * w[:, None],
+                                                  mode="drop")
+    if not with_stats:
+        return out
+    n_live = (S if live is None else jnp.sum(live.astype(jnp.int32))) * k
+    stats = jnp.stack([jnp.sum(group_sizes),
+                       jnp.asarray(n_live, jnp.int32),
+                       jnp.sum((group_sizes > 0).astype(jnp.int32))])
+    return out, stats
 
 
 class MoE(nn.Module):
@@ -137,12 +180,23 @@ class MoE(nn.Module):
     # chunk the dispatch-a2a -> expert FFN -> combine-a2a chain over this
     # many expert sub-groups so GEMMs interleave with in-flight a2a chunks
     num_chunks: int = 1
+    # afmoe / Trinity (GPTConfig.moe_*): a sigmoid router with a
+    # selection-only bias, a shared expert every token takes, and the share
+    # of the experts held here (see _expert_ffn_ragged)
+    router: str = "softmax"
+    route_norm: bool = True
+    route_scale: float = 1.0
+    router_bias: bool = False
+    shared_dim: int = 0
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
 
     @nn.compact
     def __call__(self, x, rng: Optional[jax.Array] = None,
                  deterministic: bool = False):
         B, T, H = x.shape
-        E = self.num_experts
+        E = self.num_experts                 # the router's width
+        El = self.experts_held or E          # experts whose weights are here
         M = self.mlp_dim or self.hidden_size * self.mlp_ratio
         cf = self.eval_capacity_factor if deterministic else self.capacity_factor
         k_init = nn.initializers.normal(stddev=0.02)
@@ -150,14 +204,23 @@ class MoE(nn.Module):
         wg = self.param("gate", _part(k_init, ("embed", None)),
                         (H, E), self.param_dtype)
         wi = self.param("wi", _part(k_init, ("expert", "embed", "mlp")),
-                        (E, H, M), self.param_dtype)
+                        (El, H, M), self.param_dtype)
         wo = self.param("wo", _part(k_init, ("expert", "mlp", "embed")),
-                        (E, M, H), self.param_dtype)
+                        (El, M, H), self.param_dtype)
         weg = (self.param("wge", _part(k_init, ("expert", "embed", "mlp")),
-                          (E, H, M), self.param_dtype)
+                          (El, H, M), self.param_dtype)
                if self.gated else None)    # per-expert SwiGLU gate (Mixtral)
 
         tokens = x.reshape(B * T, H)
+        if self.router == "sigmoid":
+            return self._sigmoid_route(x, tokens, wg, wi, wo, weg, k_init)
+        if self.router != "softmax":
+            raise ValueError(f"unknown MoE router {self.router!r}; "
+                             f"expected softmax|sigmoid")
+        if El != E or self.shared_dim or self.router_bias:
+            raise ValueError(
+                "experts_held / shared_dim / router_bias are wired for the "
+                "sigmoid router's dropless route only")
         logits = tokens @ wg.astype(x.dtype)
         noise_std = 1.0 / E if (self.noisy_gate_policy and not deterministic
                                 and rng is not None) else 0.0
@@ -204,6 +267,44 @@ class MoE(nn.Module):
         self._sow_stats(logits, aux, kept.sum(axis=(0, 2)),
                         logits.shape[0] * self.k - kept.sum())
         return self._finish(x, out.reshape(B, T, H), aux, k_init)
+
+    def _sigmoid_route(self, x, tokens, wg, wi, wo, weg, k_init):
+        """The afmoe expert layer: ``shared(m) + sum over the chosen k of
+        w_e * expert_e(m)``, router in float32, the routed part over the
+        experts held here.  No auxiliary loss: the family balances its
+        load through the selection bias, outside the graph."""
+        from deepspeed_tpu.moe.sharded_moe import sigmoid_topk
+        B, T, H = x.shape
+        E = self.num_experts
+        if self.mesh is not None and self.mesh.shape.get("ep", 1) > 1:
+            raise NotImplementedError(
+                "sigmoid router over an ep mesh: the exchange is not "
+                "built; give each chip its share (experts_held)")
+        if not self.dropless:
+            raise ValueError("the sigmoid router routes dropless "
+                             "(moe_dropless=True): it has no capacity form")
+        bias = (self.param("expert_bias", _part(nn.initializers.normal(stddev=0.005),
+                                                (None,)),
+                           (E,), self.param_dtype)
+                if self.router_bias else None)
+        logits = jnp.dot(tokens, wg.astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        idx, w = sigmoid_topk(logits, self.k, bias, self.route_norm,
+                              self.route_scale)
+        out = _expert_ffn_ragged(tokens, idx, w, wi, wo, weg,
+                                 expert_offset=self.expert_offset,
+                                 num_experts=E)
+        if self.shared_dim:
+            Ms = self.shared_dim
+            si = self.param("shared_wi", _part(k_init, ("embed", "mlp")),
+                            (H, Ms), self.param_dtype)
+            sg = self.param("shared_wg", _part(k_init, ("embed", "mlp")),
+                            (H, Ms), self.param_dtype)
+            so = self.param("shared_wo", _part(k_init, ("mlp", "embed")),
+                            (Ms, H), self.param_dtype)
+            out = out + (nn.silu(tokens @ sg.astype(x.dtype))
+                         * (tokens @ si.astype(x.dtype))) @ so.astype(x.dtype)
+        return out.reshape(B, T, H), jnp.float32(0.0)
 
     def _sow_stats(self, logits, aux, expert_tokens, dropped):
         """Expert-load observability: sow per-layer routing stats into the
@@ -327,8 +428,9 @@ def _ep_route_dropless(mesh: Mesh, tokens, expert_idx, weights, wi, wo,
     ``wire_bits``); the int32 id buffer always moves FULL width — routing
     indices must survive the wire exactly.  ``num_chunks`` tiles the
     assignment dim so per-chunk expert GEMMs interleave with in-flight a2a
-    chunks; the grouping only changes GEMM batching, outputs are identical
-    row-wise."""
+    chunks; the grouping only changes GEMM batching, so outputs agree
+    row-wise up to float rounding (a chunk's ``ragged_dot`` sees fewer
+    rows, and a backend blocks a matmul's accumulation by its shape)."""
     ep = mesh.shape["ep"]
     E, H, M = wi.shape
     E_local = E // ep

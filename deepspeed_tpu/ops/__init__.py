@@ -162,6 +162,18 @@ register_op("ragged_prefill_attention", xla=_paged.xla_ragged_prefill,
             pallas=_paged.pallas_ragged_prefill,
             supported=_paged.ragged_prefill_supported)
 
+from deepspeed_tpu.ops import grouped_gemm as _grouped  # noqa: E402
+
+register_op("grouped_gemm", xla=_grouped.xla_grouped_gemm)
+
+
+def grouped_gemm(rows, weights, group_sizes, *, impl: Optional[str] = None):
+    """``rows [A, K]`` (sorted by group) x ``weights [G, K, N]`` by
+    ``group_sizes [G]`` -> [A, N] (ops/grouped_gemm.py): the MoE expert
+    product, through the registry so the dispatch log names it."""
+    return dispatch("grouped_gemm", rows, weights, group_sizes, impl=impl)
+
+
 from deepspeed_tpu.ops.evoformer import evoformer_attention  # noqa: E402
 
 register_op("evoformer_attention", xla=evoformer_attention)
@@ -218,4 +230,5 @@ __all__ = ["causal_attention", "flash_attention", "configure_flash_blocks",
            "all_gather_matmul", "matmul_reduce_scatter",
            "row_parallel_matmul", "collective_matmul",
            "lm_cross_entropy", "masked_nll_sum", "rms_norm", "layer_norm",
-           "op_report", "register_op", "dispatch", "list_ops", "registry"]
+           "op_report", "register_op", "dispatch", "list_ops", "registry",
+           "grouped_gemm"]

@@ -206,6 +206,32 @@ class ServingTelemetry:
             "held by in-flight requests / evictable = reclaimable by LRU "
             "eviction right now)")
 
+        # ---- afmoe serving (Trinity): the expert layer's share and the
+        # two page groups of a model with window and global layers
+        self.c_moe_local = reg.counter(
+            "moe_local_assignments_total", "token-to-expert assignments "
+            "that went to an expert held on this chip (the rows its grouped "
+            "GEMMs multiplied), summed over expert layers")
+        self.c_moe_assign = reg.counter(
+            "moe_assignments_total", "token-to-expert assignments the "
+            "router made for live rows over all its experts, summed over "
+            "expert layers")
+        self.c_moe_touched = reg.counter(
+            "moe_experts_touched_total", "local experts that had at least "
+            "one row in a step, summed over expert layers and steps")
+        self.g_kv_pages = reg.gauge(
+            "kv_pages_in_use", "KV pages held by sequences, per page group "
+            "(global = layers that keep a whole context / window = "
+            "sliding-window layers, which keep a ring)")
+        self.c_kv_released = reg.counter(
+            "kv_pages_released_total", "pages a page group gave back "
+            "before its sequence ended, per group (window: every token of "
+            "the page lay behind the window of the oldest query to come)")
+        self.c_kv_allocated = reg.counter(
+            "kv_pages_allocated_total", "pages a page group handed to "
+            "sequences, per group (the denominator of the released share)")
+        self._kvw_seen = [0, 0]     # window group totals already counted
+
     # ------------------------------------------------------------- clocks
 
     @staticmethod
@@ -311,6 +337,39 @@ class ServingTelemetry:
         if self.enabled and n:
             self.c_tokens.inc(n, phase=phase, **self.labels)
 
+    def moe_stats(self, vec) -> None:
+        """One dispatch's MoE counter vector (model.py ``_ffn``): [local
+        assignments, assignments of live rows, local experts touched]."""
+        if self.enabled:
+            self.c_moe_local.inc(int(vec[0]), **self.labels)
+            self.c_moe_assign.inc(int(vec[1]), **self.labels)
+            self.c_moe_touched.inc(int(vec[2]), **self.labels)
+
+    def counter_note(self, state) -> Dict[str, int]:
+        """Running totals for a dispatch span's args, so that a trace holds
+        them: the MoE counters as far as the device has reported (a reader
+        takes the difference between two dispatches) and the window page
+        group's.  Empty for a model with neither: its spans are as they
+        were."""
+        note: Dict[str, int] = {}
+        if not self.enabled:
+            return note
+        total = self.c_moe_assign.value(**self.labels)
+        if total:
+            note.update(
+                moe_assign=int(total),
+                moe_local=int(self.c_moe_local.value(**self.labels)),
+                moe_touched=int(self.c_moe_touched.value(**self.labels)))
+        if getattr(state, "window", None):
+            note.update(
+                kvw_allocated=state.w_allocated_total,
+                kvw_released=state.w_released_total,
+                kv_pages_window=(state.wallocator.num_blocks
+                                 - state.wallocator.free_blocks),
+                kv_pages_global=(state.allocator.num_blocks
+                                 - state.allocator.free_blocks))
+        return note
+
     def preemption(self, kind: str) -> None:
         if self.enabled:
             self.c_preempt.inc(1, kind=kind, **self.labels)
@@ -361,6 +420,18 @@ class ServingTelemetry:
         used = total - free
         self.g_kv_blocks.set(used, state="used", **self.labels)
         self.g_kv_blocks.set(free, state="free", **self.labels)
+        if getattr(state, "window", None):
+            wa = state.wallocator
+            self.g_kv_pages.set(used, group="global", **self.labels)
+            self.g_kv_pages.set(wa.num_blocks - wa.free_blocks,
+                                group="window", **self.labels)
+            seen = self._kvw_seen
+            self.c_kv_allocated.inc(state.w_allocated_total - seen[0],
+                                    group="window", **self.labels)
+            self.c_kv_released.inc(state.w_released_total - seen[1],
+                                   group="window", **self.labels)
+            self._kvw_seen = [state.w_allocated_total,
+                              state.w_released_total]
         alloc_tokens = 0
         live_tokens = 0
         for seq in state.tracked.values():

@@ -185,3 +185,32 @@ def test_cache_helper_placement(monkeypatch, tmp_path, outside):
                           floors[0])
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           floors[1])
+
+
+# ------------------------------------------------------------- --afmoe
+
+@pytest.fixture(scope="module")
+def afmoe(run_smoke):
+    return run_smoke("--rehearse", "--afmoe", "--faults", "--seed", "5")
+
+
+@pytest.mark.parametrize("part", ["runner_rows", "chunked_prompt"])
+def test_afmoe_rows_split_by_routing_cross_the_window(afmoe, part):
+    """Both drives release window pages, and the phase held every row the
+    engine routed as the reference routes it to bf16 (it raises otherwise)."""
+    line = afmoe["afmoe"]
+    assert afmoe["last"]["ok"] is True
+    rows = line[part] if part == "runner_rows" else line[part]["rows"]
+    assert rows["agree_rows"] > rows["flip_rows"] >= 0
+    assert rows["agree_rel_max"] <= 2 * chip_smoke.AFMOE_ROW_REL
+    released = (line if part == "runner_rows"
+                else line[part])["window_pages_released"]
+    assert released > 0
+
+
+@pytest.mark.parametrize("fault", ["window_page_released_one_too_early",
+                                   "reference_on_fp8_e4m3_weights"])
+def test_afmoe_planted_faults_read_as_not_correct(afmoe, fault):
+    """At the tiny preset a page is wider than the window, so a page given
+    back one too early is the whole window: both readings are gross."""
+    assert afmoe["afmoe"]["faults"][fault]["caught_by_runner_limits"]

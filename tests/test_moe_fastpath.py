@@ -109,7 +109,12 @@ class TestChunking:
         with mesh:
             y1, aux1 = jax.jit(one.apply)(v, x)
             y2, aux2 = jax.jit(two.apply)(v, x)
-        np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
+        # same rows through the same experts, but a chunk's grouped GEMM
+        # sees half the rows and the backend blocks a matmul's accumulation
+        # by its shape: equal up to float32 rounding, not bit for bit (the
+        # capacity route above batches per expert, not per row, and is)
+        np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
+                                   rtol=1e-5, atol=1e-7)
         assert float(aux1) == float(aux2)
 
     def test_non_divisor_chunk_count_degrades_gracefully(self, rng, devices):
