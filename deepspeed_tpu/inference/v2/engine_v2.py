@@ -28,7 +28,7 @@ from pydantic import Field
 
 from deepspeed_tpu.config import DeepSpeedConfigModel
 from deepspeed_tpu.inference.config import (GenerationConfig, _DTYPE_ALIASES)
-from deepspeed_tpu.inference.v2.model import (PagedKVCache,
+from deepspeed_tpu.inference.v2.model import (PagedKVCache, named_partial,
                                               ragged_decode_burst,
                                               ragged_decode_forward,
                                               ragged_decode_sampled,
@@ -53,15 +53,6 @@ _STEP_PROGRAMS = {
     ("mixed", False): ragged_forward_sampled,
     ("mixed", True): ragged_forward_sampled_draft,
 }
-
-
-def _named_partial(fn, **static):
-    """``functools.partial`` that keeps ``fn``'s name.  jit names a program
-    after its function, and a bare partial has none: every step program
-    would reach the compiler, the profiler and IR dumps as ``<unknown>``."""
-    bound = functools.partial(fn, **static)
-    bound.__name__ = fn.__name__
-    return bound
 
 
 class _Round:
@@ -837,7 +828,8 @@ class InferenceEngineV2:
         # bucket (≤ log2(MB) programs).  Since round 3 the bucket width only
         # bounds LAYOUT: the ragged-prefill Pallas kernel skips dead
         # (slot, q-chunk) tiles and walks each slot's pages up to its actual
-        # kv length, so attention FLOPs/bandwidth scale with Σ live tokens,
+        # kv length, and one-row slots go to the paged decode kernel, so
+        # attention FLOPs/bandwidth scale with the live q-chunks and rows,
         # not the bucket (reference atom_builder + blocked_flash).
         sm = self.config.state_manager
         if int(rb.q_len.max()) <= 1:
@@ -847,7 +839,7 @@ class InferenceEngineV2:
         key = ("mixed", sm.max_q_per_seq, mb) + tuple(routes)
         if key not in self._steps:
             self._steps[key] = jax.jit(
-                _named_partial(ragged_forward, cfg=self.model_config,
+                named_partial(ragged_forward, cfg=self.model_config,
                                block_size=self._block_size,
                                max_q_per_seq=sm.max_q_per_seq,
                                mesh=self.mesh, **self._model_static,
@@ -885,7 +877,7 @@ class InferenceEngineV2:
         key = ("decode", "moe_routes") if with_routes else "decode"
         if key not in self._steps:
             self._steps[key] = jax.jit(
-                _named_partial(ragged_decode_forward,
+                named_partial(ragged_decode_forward,
                                cfg=self.model_config,
                                block_size=self._block_size,
                                mesh=self.mesh, **self._model_static,
@@ -1008,7 +1000,7 @@ class InferenceEngineV2:
             key = ("spec_rs", outer, gamma, gen.top_k)
             if key not in self._steps:
                 self._steps[key] = jax.jit(
-                    _named_partial(speculative_burst_sampled,
+                    named_partial(speculative_burst_sampled,
                                    cfg=self.model_config,
                                    draft_cfg=self.draft_config,
                                    block_size=self._block_size,
@@ -1027,7 +1019,7 @@ class InferenceEngineV2:
             key = ("spec", outer, gamma)
             if key not in self._steps:
                 self._steps[key] = jax.jit(
-                    _named_partial(speculative_burst,
+                    named_partial(speculative_burst,
                                    cfg=self.model_config,
                                    draft_cfg=self.draft_config,
                                    block_size=self._block_size,
@@ -1066,7 +1058,7 @@ class InferenceEngineV2:
         key = ("burst", steps, gen.do_sample, gen.top_k)
         if key not in self._steps:
             self._steps[key] = jax.jit(
-                _named_partial(ragged_decode_burst, cfg=self.model_config,
+                named_partial(ragged_decode_burst, cfg=self.model_config,
                                block_size=self._block_size, steps=steps,
                                sample_fn=self._sample_fn(gen),
                                mesh=self.mesh, **self._model_static),
@@ -1116,13 +1108,15 @@ class InferenceEngineV2:
             served[list(served_slots)] = True
             # what a reader needs to compute rates without the engine
             self._fold_moe_stats()
-            note = {"seqs": len(schedule),
-                    "tokens": sum(len(t) for t in toks_np),
+            rows = [len(t) for t in toks_np]
+            mixed = max(rows) > 1
+            if mixed:
+                stel.mixed_slots(rows)
+            note = {"seqs": len(schedule), "tokens": sum(rows),
                     **self._ctx_note([seq.seen_tokens
-                                      for seq, _ in schedule],
-                                     [len(t) for t in toks_np]),
+                                      for seq, _ in schedule], rows),
                     **stel.counter_note(self.state)}
-            if max(len(t) for t in toks_np) <= 1:
+            if not mixed:
                 # decode-only: slot-indexed [S] program
                 kind = "decode"
                 tokens = np.zeros(S, np.int32)
@@ -1174,7 +1168,7 @@ class InferenceEngineV2:
                 if draft:
                     static["draft_cfg"] = self.draft_config
                 self._steps[key] = jax.jit(
-                    _named_partial(_STEP_PROGRAMS[kind, draft],
+                    named_partial(_STEP_PROGRAMS[kind, draft],
                                    cfg=self.model_config,
                                    block_size=self._block_size,
                                    sample_fn=self._sample_fn(gen),

@@ -507,7 +507,12 @@ def paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
 # the Pallas kernel instead grids over (slot, kv head, q-chunk) and runs the
 # decode kernel's double-buffered HBM→VMEM DMA loop over ONLY the pages the
 # chunk can causally see — dead (slot, chunk) pairs are skipped outright, so
-# FLOPs and bandwidth scale with Σ live tokens, not S × longest.
+# FLOPs and bandwidth scale with the live CHUNKS of ``cq`` rows (128 at the
+# serving sizes), not S × longest: a live chunk pays for all its ``cq`` rows
+# over every page it sees, however few of them are live.  So the mixed step
+# (inference/v2/model.py ``ragged_forward``) hands a slot that holds ONE row
+# to the paged decode kernel above, whose tile is that row, and passes it
+# here with a count of 0.
 
 
 def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,

@@ -120,6 +120,15 @@ class ServingTelemetry:
         self.c_dispatch = reg.counter(
             "serving_dispatches_total", "device dispatches issued by the "
             "serving engine, per program kind")
+        self.c_mixed_slots = reg.counter(
+            "serving_mixed_slots_total", "sequence slots served by mixed "
+            "(SplitFuse) dispatches: prompt chunks and the decode rows "
+            "that ride along")
+        self.c_one_row_slots = reg.counter(
+            "serving_one_row_slots_total", "slots of mixed dispatches that "
+            "held exactly one row (a riding decode row, a prompt's "
+            "one-token tail): the paged decode kernel attends them, the "
+            "prefill kernel the others")
         self.c_preempt = reg.counter(
             "serving_preemptions_total", "recompute-preemption victims "
             "taken, per victim state (decode_ready / mid_prefill)")
@@ -337,6 +346,13 @@ class ServingTelemetry:
         if self.enabled and n:
             self.c_tokens.inc(n, phase=phase, **self.labels)
 
+    def mixed_slots(self, rows) -> None:
+        """One mixed dispatch's slots, by the rows each holds."""
+        if self.enabled:
+            self.c_mixed_slots.inc(len(rows), **self.labels)
+            self.c_one_row_slots.inc(sum(n == 1 for n in rows),
+                                     **self.labels)
+
     def moe_stats(self, vec) -> None:
         """One dispatch's MoE counter vector (model.py ``_ffn``): [local
         assignments, assignments of live rows, local experts touched]."""
@@ -347,13 +363,16 @@ class ServingTelemetry:
 
     def counter_note(self, state) -> Dict[str, int]:
         """Running totals for a dispatch span's args, so that a trace holds
-        them: the MoE counters as far as the device has reported (a reader
-        takes the difference between two dispatches) and the window page
-        group's.  Empty for a model with neither: its spans are as they
-        were."""
+        them (a reader takes the difference between two dispatches): the
+        slots mixed dispatches served and those of them with one row, the
+        MoE counters as far as the device has reported and the window page
+        group's; the last two only for a model that has them."""
         note: Dict[str, int] = {}
         if not self.enabled:
             return note
+        note.update(
+            mixed_seqs=int(self.c_mixed_slots.value(**self.labels)),
+            one_row_seqs=int(self.c_one_row_slots.value(**self.labels)))
         total = self.c_moe_assign.value(**self.labels)
         if total:
             note.update(

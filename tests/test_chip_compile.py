@@ -347,6 +347,31 @@ def test_step_programs_leave_the_pool_in_place(step_programs, monkeypatch,
         assert temp < POOL_GEOMETRIES[geometry][3], temp
 
 
+@pytest.mark.parametrize("geometry", sorted(POOL_GEOMETRIES))
+def test_mixed_step_has_both_attention_kernels_a_layer(step_programs,
+                                                       monkeypatch, geometry):
+    """The mixed program attends one-row slots with the paged decode kernel
+    and the others with the prefill kernel (model.py ``ragged_forward``):
+    both are in the compiled program, once a layer each, by the names the
+    benchmark's readers take them by; the decode programs hold the decode
+    kernel alone."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, _, _, compiled = step_programs(geometry)
+
+    def kernels(program):
+        calls = [ln for ln in compiled[program].as_text().splitlines()
+                 if f'custom_call_target="{KERNEL}"' in ln]
+        return {name: sum(f"/{name}/pallas_call" in ln for ln in calls)
+                for name in ("paged_decode", "ragged_prefill")}, len(calls)
+    named, total = kernels("ragged_forward_sampled")
+    assert named == {"paged_decode": cfg.num_layers,
+                     "ragged_prefill": cfg.num_layers}
+    assert total >= 2 * cfg.num_layers
+    for program in ("ragged_decode_sampled", "ragged_decode_burst"):
+        assert kernels(program)[0] == {"paged_decode": cfg.num_layers,
+                                       "ragged_prefill": 0}
+
+
 # -------------------------------------------------------- quantized GEMMs
 
 def _store(shape, dim=0):

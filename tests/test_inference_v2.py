@@ -58,6 +58,135 @@ class TestAllocator:
         st.create(3)
 
 
+# ------------------------------------------- one-row slots of a mixed step
+
+def _mixed_step(engine, uids, toks, prefill_route=False):
+    """What ``put`` does, but always through the mixed program (``put``
+    hands a step of one-row slots to the decode program): logits of the
+    scheduled slots.  ``prefill_route``: the step as it was before one-row
+    slots went to the paged decode op."""
+    import functools
+
+    from deepspeed_tpu.inference.v2.model import ragged_forward
+    from deepspeed_tpu.inference.v2.ragged import build_ragged_batch
+    sm = engine.config.state_manager
+    schedule = []
+    for uid, t in zip(uids, toks):
+        seq = engine.state.get(uid) or engine.state.create(uid)
+        engine.state.ensure_blocks(seq, len(t))
+        schedule.append((seq, np.asarray(t, np.int32)))
+    rb = build_ragged_batch(schedule, engine.state, sm.max_ragged_batch_size,
+                            sm.max_q_per_seq)
+    batch = jax.tree_util.tree_map(jnp.asarray, {
+        "tokens": rb.tokens, "token_slot": rb.token_slot,
+        "token_pos": rb.token_pos, "token_dense_idx": rb.token_dense_idx,
+        **rb.table_operands(), "kv_len": rb.kv_len})
+    step = jax.jit(functools.partial(
+        ragged_forward, cfg=engine.model_config,
+        block_size=engine._block_size, max_q_per_seq=sm.max_q_per_seq,
+        **engine._model_static))
+    with pytest.MonkeyPatch.context() as m:
+        if prefill_route:
+            _prefill_route_for_every_slot(m, rb.kv_len, rb.q_len)
+        logits, engine.cache = step(engine.params, engine.cache, batch)[:2]
+    for seq, t in schedule:
+        seq.seen_tokens += len(t)
+    return np.asarray(logits)[rb.logits_slots]
+
+
+def _prefill_route_for_every_slot(monkeypatch, kv_len, q_len):
+    """The ragged prefill reference attends every slot by the rows the
+    SCHEDULE gives it (``kv_len``, ``q_len``: whatever lengths and counts
+    the model hands the ops), a one-row slot as a chunk of one row."""
+    from deepspeed_tpu import ops
+    from deepspeed_tpu.ops.paged_attention import xla_ragged_prefill
+    kv_len, q_len = jnp.asarray(kv_len), jnp.asarray(q_len)
+
+    def prefill(q, k, v, table, kv_lens, q_starts, q_counts, *, impl=None,
+                **kw):
+        return xla_ragged_prefill(q, k, v, table, kv_len, kv_len - q_len,
+                                  q_len, **kw)
+
+    def decode(q, k, v, table, kv_lens, *, impl=None, **kw):
+        return xla_ragged_prefill(q[:, None], k, v, table, kv_len,
+                                  kv_len - q_len, jnp.minimum(q_len, 1),
+                                  **kw)[:, 0]
+    monkeypatch.setattr(ops, "ragged_prefill_attention", prefill)
+    monkeypatch.setattr(ops, "paged_attention", decode)
+
+
+def _one_row_engine(cfg=None, seed=0, **sm):
+    if cfg is None:         # heads of 128: standard (row-major) pages of 8
+        cfg = GPTConfig.llama(num_layers=2, hidden=256, heads=2,
+                              num_kv_heads=1, vocab_size=97, max_seq_len=64)
+    manager = {"max_tracked_sequences": 4, "max_ragged_batch_size": 64,
+               "kv_block_size": 8, "max_q_per_seq": 16, **sm}
+    return lambda: InferenceEngineV2(
+        cfg, config={"dtype": "fp32", "state_manager": manager}, seed=seed)
+
+
+def _afmoe_engine(window):
+    import test_trinity as tt
+    cfg, params = tt.model(tt.sizes(window=window))
+    return lambda: tt.engine(cfg, params)
+
+
+def _ids(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 96, (n,)).astype(np.int32)
+
+
+# case -> (engine factory, [(uids, token lists) a step]); pages hold 8
+# tokens (4 in the afmoe model, 128 where the layout demands it)
+ONE_ROW_CASES = {
+    # contexts 11, 15 and 16: the riders' rows end mid-page, on a page's
+    # last row and on the first row of a new page
+    "riders-beside-a-chunk": (_one_row_engine(), [
+        ([1, 2, 3], [_ids(11, 1), _ids(15, 2), _ids(16, 3)]),
+        ([1, 2, 3, 4], [[5], [6], [7], _ids(13, 4)]),
+        ([4, 1, 2, 3], [_ids(16, 5), [8], [9], [10]])]),
+    "one-token-tail-and-one-token-prompt": (_one_row_engine(), [
+        ([1, 2], [_ids(16, 1), _ids(9, 2)]),
+        ([1, 3, 2], [_ids(1, 3), [42], _ids(7, 4)]),
+        ([1, 3, 4], [[5], [6], _ids(12, 5)])]),
+    "riders-only-beside-an-empty-slot": (_one_row_engine(), [
+        ([1, 2, 3], [_ids(8, 1), _ids(13, 2), _ids(3, 3)]),
+        ([1, 2, 3], [[5], [6], [7]]),
+        ([3, 1], [[8], [9]])]),
+    "gqa-riders": (_one_row_engine(GPTConfig.llama(
+        num_layers=2, hidden=512, heads=4, num_kv_heads=2, vocab_size=97,
+        max_seq_len=64)), [
+        ([1, 2], [_ids(16, 1), _ids(7, 2)]),
+        ([1, 2, 3], [[5], [6], _ids(10, 3)])]),
+    # tiny heads of 8: token-on-lanes pages of 128
+    "kv-major-pages": (_one_row_engine(
+        GPTConfig.tiny(vocab_size=97, max_seq_len=64)), [
+        ([1, 2], [_ids(16, 1), _ids(7, 2)]),
+        ([1, 2, 3], [[5], [6], _ids(10, 3)]),
+        ([3, 1, 2], [_ids(1, 4), [7], [8]])]),
+    "int8-kv-pool": (_one_row_engine(kv_quant="int8"), [
+        ([1, 2], [_ids(16, 1), _ids(7, 2)]),
+        ([1, 2, 3], [[5], [6], _ids(10, 3)]),
+        ([3, 1, 2], [_ids(1, 4), [7], [8]])]),
+    "int8-kv-major-pool": (_one_row_engine(
+        GPTConfig.tiny(vocab_size=97, max_seq_len=64), kv_quant="int8"), [
+        ([1, 2], [_ids(16, 1), _ids(7, 2)]),
+        ([1, 2, 3], [[5], [6], _ids(10, 3)])]),
+    # the afmoe model's two page groups (pages of 4, chunks of 8): a rider
+    # at context 19 past a window of 6 reads across released pages, one at
+    # 5 still inside it
+    "window-and-global-page-groups": (_afmoe_engine(6), [
+        ([1], [_ids(8, 1)]), ([1], [_ids(8, 2)]),
+        ([1, 2], [_ids(3, 3), _ids(5, 4)]),
+        ([1, 2, 3], [[5], [6], _ids(8, 5)]),
+        ([1, 2, 3], [[7], [8], _ids(2, 6)]),
+        ([1, 2, 3], [[9], [10], [11]])]),
+    "window-wider-than-the-riders": (_afmoe_engine(12), [
+        ([1], [_ids(8, 1)]), ([1, 2], [_ids(3, 3), _ids(5, 4)]),
+        ([1, 2, 3], [[5], [6], _ids(8, 5)]),
+        ([1, 2, 3], [[7], [8], [9]])]),
+}
+
+
 class TestRaggedForward:
     def test_single_seq_prefill_matches_full_forward(self, cfg, engine, rng):
         ids = rng.integers(0, 97, (12,)).astype(np.int32)
@@ -89,6 +218,25 @@ class TestRaggedForward:
         want_b = full_logits(cfg, engine, b[None])[0, -1]
         np.testing.assert_allclose(logits[0], want_a, atol=1e-4, rtol=1e-4)
         np.testing.assert_allclose(logits[1], want_b, atol=1e-4, rtol=1e-4)
+
+    @pytest.mark.parametrize("case", sorted(ONE_ROW_CASES))
+    def test_one_row_slots_match_the_prefill_route(self, case):
+        """A mixed step sends each slot that holds ONE row to the paged
+        decode op and the others to the ragged prefill op.  Every step's
+        logits and the pages it wrote equal the route before that (every
+        slot through ``xla_ragged_prefill``)."""
+        make, steps = ONE_ROW_CASES[case]
+        new, old = make(), make()       # the same weights, from the seed
+        for uids, toks in steps:
+            got = _mixed_step(new, uids, toks)
+            want = _mixed_step(old, uids, toks, prefill_route=True)
+            np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+        for g, w in zip(new.cache, old.cache):
+            assert (g is None) == (w is None)
+            if w is not None:
+                np.testing.assert_allclose(
+                    np.asarray(g, np.float32), np.asarray(w, np.float32),
+                    atol=1e-4, rtol=1e-4)
 
     def test_split_prompt_matches_one_shot(self, cfg, engine, rng):
         """SplitFuse chunking: a prompt fed in 3 chunks gives the same final

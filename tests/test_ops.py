@@ -740,6 +740,45 @@ class TestRaggedPrefill:
                     np.asarray(got), np.asarray(want), atol=1e-5,
                     err_msg=f"{fn.__name__} {kw}")
 
+    @pytest.mark.parametrize("variant", ["plain", "window", "alibi-window",
+                                         "kv-major-window"])
+    def test_one_row_slots_through_the_decode_kernel(self, rng, variant):
+        """The mixed step's composition (model.py ``ragged_forward``): slots
+        with one row through the paged decode kernel, the others through the
+        prefill kernel, each blind to the other's slots; together they are
+        the prefill reference over every slot."""
+        from deepspeed_tpu.ops.paged_attention import (
+            pallas_paged_attention, pallas_ragged_prefill, xla_ragged_prefill)
+        q, k, v, bt, _, _, _ = self._case(rng, S=6, NB=32)
+        Q = q.shape[1]
+        # riders at contexts that end mid-page, on a page's last row and on
+        # the first row of a new page, beside two chunks and an empty slot
+        counts = jnp.asarray([0, 1, 5, Q, 1, 1], jnp.int32)
+        lens = jnp.asarray([0, 19, 14, Q, 16, 25], jnp.int32)
+        starts = lens - counts
+        kw = {}
+        if "window" in variant:
+            kw["window"] = 6
+        if "alibi" in variant:
+            kw["alibi_slopes"] = jnp.asarray(
+                np.geomspace(0.5, 1 / 64, q.shape[2] * q.shape[3]),
+                jnp.float32)
+        want = xla_ragged_prefill(q, k, v, bt, lens, starts, counts, **kw)
+        if "kv-major" in variant:
+            k, v = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
+            kw["kv_major"] = True
+        one = counts == 1
+        o_one = pallas_paged_attention(
+            q[:, 0], k, v, bt, jnp.where(one, lens, 0), interpret=True, **kw)
+        o_many = pallas_ragged_prefill(
+            q, k, v, bt, lens, starts, jnp.where(one, 0, counts),
+            interpret=True, **kw)
+        np.testing.assert_array_equal(np.asarray(o_one)[~np.asarray(one)], 0)
+        np.testing.assert_array_equal(np.asarray(o_many)[np.asarray(one)], 0)
+        got = o_many.at[:, 0].add(o_one)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5)
+
     def test_engine_serving_token_exact_with_kernel(self, rng, monkeypatch):
         """Force the dispatch onto the Pallas (interpret) kernels and check
         the v2 engine generates the SAME tokens as the XLA path."""

@@ -265,6 +265,51 @@ class TestEngineServingTelemetry:
             q["free_kv_blocks"]
         assert 0 < stel.value("serving_batch_occupancy") <= 1.0
 
+    def test_dispatch_spans_carry_the_mixed_slot_totals(self, cfg, v2cfg,
+                                                        rng):
+        """``mixed_seqs`` / ``one_row_seqs``: running totals of the slots
+        mixed dispatches served and of those with one row, in the args of
+        every dispatch span.  The schedule handed to each step says what
+        they have to read."""
+        eng = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        stel = eng.telemetry
+        steps = []                  # each sampled step's rows a sequence
+        inner = eng._step_sampled
+
+        def spy(uids, toks_np, *a, **kw):
+            steps.append([len(t) for t in toks_np])
+            return inner(uids, toks_np, *a, **kw)
+        eng._step_sampled = spy
+        prompts = [rng.integers(0, 97, (n,)).astype(np.int32)
+                   for n in (9, 23, 5, 30, 12, 7)]       # 6 prompts, 4 slots
+        eng.generate(prompts, max_new_tokens=20)
+        spans = [e for e in stel.tracer.events
+                 if e["name"] in ("mixed_dispatch", "decode_dispatch",
+                                  "burst_dispatch")]
+        assert {e["name"] for e in spans} >= {"mixed_dispatch",
+                                              "burst_dispatch"}
+        assert all({"mixed_seqs", "one_row_seqs"} <= set(e["args"])
+                   for e in spans)
+        for key in ("mixed_seqs", "one_row_seqs"):
+            seen = [e["args"][key] for e in spans]
+            assert seen == sorted(seen)                 # never decrease
+        mixed = [rows for rows in steps if max(rows) > 1]
+        assert any(1 in rows for rows in mixed)         # riders were there
+        want, slots, ones = [], 0, 0
+        for rows in mixed:          # a span holds its own dispatch already
+            slots += len(rows)
+            ones += rows.count(1)
+            want.append((slots, ones))
+        got = [(e["args"]["mixed_seqs"], e["args"]["one_row_seqs"])
+               for e in spans if e["name"] == "mixed_dispatch"]
+        assert got == want
+        # the other kinds repeat the totals as they stand
+        assert (spans[-1]["args"]["mixed_seqs"],
+                spans[-1]["args"]["one_row_seqs"]) == want[-1]
+        assert stel.value("serving_mixed_slots_total") == slots
+        assert stel.value("serving_one_row_slots_total") == ones
+        assert 0 < ones < slots
+
     def test_open_loop_arrivals_gate_admission_and_match_closed_loop(
             self, cfg, v2cfg, rng):
         prompts = [rng.integers(0, 97, (n,)).astype(np.int32)
