@@ -248,8 +248,8 @@ def hlo_collective_bytes(hlo_text: str) -> Dict[str, Dict[str, int]]:
 
 def hlo_wire_bytes(hlo_text: str) -> Dict[str, int]:
     """Collective payload bytes from compiled HLO, split by WIRE class —
-    the number the quantized pipeline is judged on (bench.py's
-    ``zero3_wire_bytes`` column; ISSUE 14 acceptance).
+    the number the quantized pipeline is judged on
+    (tests/test_comm_pipeline.py).
 
     Returns ``{"total", "quantized", "full", "gather_scatter"}``: ``total``
     sums every collective's output payload at its HLO dtype width (an s8
@@ -313,18 +313,18 @@ def hlo_overlap_stats(hlo_text: str) -> Dict[str, object]:
     no compute between the pair (quantize emits both buffers together;
     converts/bitcasts are not compute ops).  Without companion awareness
     the scale leg reads as an exposed sync op (or an empty async window)
-    on every chunk and the gauge drifts blind under quantization — so a
-    same-kind collective arriving with NO compute since its predecessor
-    and a payload ≤ 1/8 of it is counted as a **companion**: it rides the
-    predecessor's overlap window (``companion_collectives`` /
-    ``companion_bytes``) and is never booked as exposed on its own.
+    on every chunk — so a same-kind collective arriving with NO compute
+    since its predecessor and a payload ≤ 1/8 of it is counted as a
+    **companion**: it rides the predecessor's overlap window
+    (``companion_collectives`` / ``companion_bytes``) and is never booked
+    as exposed on its own.
 
     Returns counts/bytes per signal plus ``exposed_ratio``: the
     bytes-weighted fraction of collective payload on ops with NO overlap
     evidence (sync AND not interleaved, or async with empty windows,
-    companions excluded) — the static stand-in for the profiler's
-    exposed-comms time, exported as the ``collective_exposed_ratio``
-    telemetry gauge.
+    companions excluded).  It describes the schedule the attached
+    backend's compiler chose, not time: the measured figure is the
+    benchmark's ``exposed_collective_ms_per_step``, from the device trace.
 
     Byte accounting: sync ops count their output payload (same line
     ``hlo_collective_bytes`` reads); async pairs count the ``-done``

@@ -110,8 +110,7 @@ class FleetConfig(DeepSpeedConfigModel):
     ``generate`` completes, the (more generous) ``warmup_deadline_s``
     governs instead: a replica's first call legitimately stalls on the
     on-the-fly XLA compile, and a steady-state deadline would book a cold
-    replica dead (the PR 8 review finding; bench_serving used to paper
-    over it with a 120 s override).  ``max_respawns`` bounds
+    replica dead.  ``max_respawns`` bounds
     death-respawns per replica; drain-respawns are planned events and
     bypass it (``respawn_after_drain``).  ``share_compile_cache`` hands
     every replica one jitted-step dict, so the fleet compiles each program

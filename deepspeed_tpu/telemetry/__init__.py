@@ -31,18 +31,9 @@ them per step and adds the TPU-specific hazards nothing else watches:
                        bundle dumps on NaN / overflow streak / crash
 - ``postmortem``     — bundle summarizer CLI
                        (``python -m deepspeed_tpu.telemetry.postmortem``)
-- ``roofline``       — per-op-class roofline model from compiled HLO
-                       (flops / HBM bytes / wire bytes vs the accelerator
-                       peak-spec table → attainable-step-time lower bound)
-- ``profiler``       — measured step-time decomposition into an MFU budget
-                       (compute / exposed_comm / hbm_bound / host_gap /
-                       dispatch_floor; ``scripts/perf_report.py`` renders)
-- ``regression``     — bench regression sentinel: baseline ledger + diff
-                       (``scripts/check_bench.py`` is the CLI gate)
 - ``step_telemetry`` — the engine-facing facade driving all of the above
 
-See docs/observability.md for the config block and workflows;
-docs/PERF_PLAYBOOK.md for the attribution triage loop.
+See docs/observability.md for the config block and workflows.
 """
 
 from deepspeed_tpu.telemetry.exporter import SnapshotExporter
@@ -53,12 +44,9 @@ from deepspeed_tpu.telemetry.health import (AnomalyDetector,
                                             flatten_health, group_names)
 from deepspeed_tpu.telemetry.histogram import (DEFAULT_BUCKETS, Histogram,
                                                log_buckets)
-from deepspeed_tpu.telemetry.profiler import step_time_budget
 from deepspeed_tpu.telemetry.registry import (Counter, Gauge, MetricRegistry,
                                               default_registry,
                                               record_collective)
-from deepspeed_tpu.telemetry.roofline import (PEAK_SPECS, detect_peak_spec,
-                                              roofline_from_hlo)
 from deepspeed_tpu.telemetry.serving import (ServingTelemetry,
                                              ServingTelemetryConfig)
 from deepspeed_tpu.telemetry.step_telemetry import StepTelemetry
@@ -72,10 +60,6 @@ __all__ = [
     "AnomalyDetector",
     "Counter",
     "DEFAULT_BUCKETS",
-    "PEAK_SPECS",
-    "detect_peak_spec",
-    "roofline_from_hlo",
-    "step_time_budget",
     "FlightRecorder",
     "Gauge",
     "Histogram",

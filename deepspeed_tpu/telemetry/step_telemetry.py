@@ -148,8 +148,7 @@ class StepTelemetry:
         miss = self.watchdog.observe_signature(fn_name, sig, step)
         info = self._exec.setdefault(
             fn_name, {"signatures": 0, "executions": 0, "collectives": {},
-                      "overlap": {}, "cost_analysis": {},
-                      "memory_analysis": {}})
+                      "cost_analysis": {}, "memory_analysis": {}})
         if miss:
             info["signatures"] += 1
             collected = {}
@@ -202,7 +201,6 @@ class StepTelemetry:
         from deepspeed_tpu.telemetry.registry import \
             suppress_collective_recording
         info["collectives"] = {}
-        info["overlap"] = {}
         try:
             # the AOT lower() RETRACES the step — silence the wrapper-level
             # trace-time hooks so their byte counters don't double-count
@@ -215,20 +213,6 @@ class StepTelemetry:
         try:
             hlo_text = compiled.as_text()
             info["collectives"] = hlo_collective_bytes(hlo_text)
-            # compute–collective overlap evidence (comm.hlo_overlap_stats):
-            # async start/done pairs with compute between them + interleaved
-            # chunk trains → the collective_exposed_ratio gauge, the static
-            # stand-in for profiler exposed-comms time (scripts/
-            # check_overlap.py runs the same walk standalone)
-            from deepspeed_tpu.comm.comm import hlo_overlap_stats
-            ov = hlo_overlap_stats(hlo_text)
-            info["overlap"] = ov
-            self.registry.gauge(
-                "collective_exposed_ratio",
-                "bytes-weighted fraction of compiled-HLO collective payload "
-                "with no overlap evidence (sync and not chunk-interleaved, "
-                "or async with an empty start/done window), per jitted "
-                "function").set(ov["exposed_ratio"], fn=fn_name)
         except Exception as e:  # noqa: BLE001
             logger.warning(f"telemetry: HLO collective walk of '{fn_name}' "
                            f"failed: {e!r}")
@@ -245,34 +229,6 @@ class StepTelemetry:
                     "function").set(v, fn=fn_name)
         except Exception:  # noqa: BLE001 — not all backends implement it
             pass
-        try:
-            # per-op-class roofline (telemetry/roofline.py): flops / HBM
-            # bytes / collective wire bytes per class joined with the
-            # accelerator peak-spec table → an attainable-step-time lower
-            # bound and a binding-resource split.  Uses the same hlo_text
-            # and calibrates flops against cost_analysis (while-loop trip
-            # counts are invisible to the static walk).
-            from deepspeed_tpu.telemetry.roofline import (detect_peak_spec,
-                                                          roofline_from_hlo)
-            model = roofline_from_hlo(hlo_text, spec=detect_peak_spec(),
-                                      cost_analysis=info.get(
-                                          "cost_analysis"))
-            info["roofline"] = model
-            self.registry.gauge(
-                "roofline_attainable_ms",
-                "roofline attainable-step-time lower bound from the "
-                "compiled HLO (sum over op classes of each class's "
-                "binding-resource time), per jitted function").set(
-                    model["attainable_ms"], fn=fn_name)
-            g = self.registry.gauge(
-                "roofline_bound_fraction",
-                "fraction of the roofline attainable time bound by each "
-                "resource (compute / hbm / ici), per jitted function")
-            for res, frac in model["bound_fraction"].items():
-                g.set(frac, fn=fn_name, resource=res)
-        except Exception as e:  # noqa: BLE001
-            logger.warning(f"telemetry: roofline model of '{fn_name}' "
-                           f"failed: {e!r}")
         try:
             ma = compiled.memory_analysis()
             mem = {}

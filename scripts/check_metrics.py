@@ -22,7 +22,7 @@ constants (``HLO_BYTES = "..."``), and literal-prefix concatenations are
 understood; anything else is flagged as a dynamic name unless the line
 carries a ``# metric-name-ok`` comment with the reviewed reason nearby.
 
-Grep-level by design, like check_no_sync.py/check_overlap.py: it cannot
+Grep-level by design, like check_no_sync.py: it cannot
 prove the receiver is a MetricRegistry, so it checks every
 ``.counter(...)``/``.gauge(...)``/``.histogram(...)`` call site it sees.
 

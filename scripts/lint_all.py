@@ -1,16 +1,10 @@
 #!/usr/bin/env python
 """Run every repo lint in ONE process with a unified summary.
 
-Each lint guards one interface, and until now each was wired into the
-test suite as its own subprocess run (an interpreter startup + a jax
-import per lint just to say "clean"):
+Each lint guards one interface:
 
 - ``check_no_sync``  — no undisclosed host↔device syncs on dispatch paths
-- ``check_overlap``  — chunked collectives keep compute between them
-  (compiled-HLO demo on virtual CPU devices)
 - ``check_metrics``  — metric naming convention + docs coverage
-- ``check_bench --self-test`` — the bench regression sentinel trips on
-  the canned 10% slowdown fixture and stays quiet in the noise band
 - ``trace_report --self-test`` — the critical-path decomposition holds
   its exact-sum + zero-handoff-in-unified invariants on the canned
   disagg+unified trace fixture
@@ -20,8 +14,8 @@ printing one PASS/FAIL table.  The test suite shells THIS script once
 (tests/test_lint_all.py); the per-lint violation/unit tests stay where
 they were.
 
-    python scripts/lint_all.py            # all four
-    python scripts/lint_all.py --only check_metrics check_bench
+    python scripts/lint_all.py            # all three
+    python scripts/lint_all.py --only check_metrics trace_report
 
 Exit status: 0 all pass, 1 any lint failed, 2 a lint crashed / usage.
 """
@@ -36,12 +30,7 @@ import time
 from contextlib import redirect_stderr, redirect_stdout
 from typing import Callable, List, Optional, Tuple
 
-# check_overlap's --demo compiles on virtual CPU devices: both env knobs
-# must be set BEFORE anything imports jax in this process
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=4").strip()
+# trace_report imports the package, and with it jax: a lint needs no chip
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -52,17 +41,12 @@ for p in (HERE, REPO):
 
 
 def _lints() -> List[Tuple[str, Callable[[], int]]]:
-    import check_bench
     import check_metrics
     import check_no_sync
-    import check_overlap
     import trace_report
     return [
         ("check_no_sync", lambda: check_no_sync.main([])),
-        ("check_overlap", lambda: check_overlap.main(
-            ["--demo", "--assert-overlap", "--min-chunks", "2"])),
         ("check_metrics", lambda: check_metrics.main([])),
-        ("check_bench", lambda: check_bench.main(["--self-test"])),
         ("trace_report", lambda: trace_report.main(["--self-test"])),
     ]
 
@@ -102,9 +86,8 @@ def run_all(only: Optional[List[str]] = None,
 
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
-        description="run check_no_sync, check_overlap, check_metrics, "
-                    "the check_bench fixture lint and the trace_report "
-                    "fixture lint in one process")
+        description="run check_no_sync, check_metrics and the "
+                    "trace_report fixture lint in one process")
     ap.add_argument("--only", nargs="+", metavar="LINT",
                     help="subset of lints to run (by name)")
     ap.add_argument("--verbose", action="store_true",

@@ -31,8 +31,8 @@ Usage:
     python scripts/merge_traces.py -o fleet.json trace_r0.json trace_r1.json
     python scripts/merge_traces.py -o out.json telemetry/*/trace.json
 
-``bench_serving.py``'s fleet chaos leg runs this over the per-replica
-traces so the kill → migrate → recover sequence reads off one screen.
+Over a fleet's per-replica traces, a kill → migrate → recover sequence
+reads off one screen.
 Exit status: 0 ok, 2 usage/load errors.
 """
 

@@ -1,35 +1,22 @@
 #!/usr/bin/env python
-"""perf_report — render a telemetry snapshot into a step-time-budget report.
+"""perf_report — render a telemetry snapshot's collective bytes and spans.
 
-One command that answers "where did the step time go?" from artifacts the
-telemetry layer already writes — the attribution that names a
-per-dispatch floor without a human:
-
-    python scripts/perf_report.py telemetry_snapshot.json --step-ms 259
-    python scripts/perf_report.py BENCH_r06.json            # bench record:
-                                                            # step time, comm
-                                                            # ms and snapshot
-                                                            # path from extra
+    python scripts/perf_report.py telemetry_snapshot.json
     python scripts/perf_report.py telemetry/<job>/postmortem/<bundle>/
                                                             # postmortem mode
 
 Sections:
 
-1. **step-time budget** (telemetry/profiler.py) — measured step decomposed
-   into compute / exposed_comm / hbm_bound / host_gap / dispatch_floor,
-   with achieved MFU and `mfu_lost{cause}` shares;
-2. **roofline** (telemetry/roofline.py) — per-op-class flops / HBM bytes /
-   wire bytes against the accelerator peak table, the attainable-time
-   floor, and which resource binds each class;
-3. **per-link collective bytes** — the `collective_bytes_total{link=
+1. **per-link collective bytes** — the `collective_bytes_total{link=
    ici|dcn}` split per kind/axis (trace-time wire convention);
-4. **span summary** — the heaviest host phases.
+2. **span summary** — the heaviest host phases.
+
+Where the step's time goes on the device is the benchmark's to say
+(`benchmark/run.py --trace 1`, PERF.md section 5), from the device trace.
 
 Input sniffing: a directory containing ``meta.json`` is a postmortem
-bundle (spans from meta.json, metrics parsed out of ``snapshot.prom``,
-step time from the records' ``spans_ms`` unless ``--step-ms`` overrides);
-a JSON with a ``metric`` key is a bench record (step time / comm ms /
-snapshot path read from ``extra``); anything else is a snapshot.json.
+bundle (spans from meta.json, metrics parsed out of ``snapshot.prom``);
+anything else is a snapshot.json.
 
 Exit status: 0 report printed, 2 load/usage errors.
 """
@@ -42,10 +29,6 @@ import os
 import re
 import sys
 from typing import Dict, List, Optional, Tuple
-
-REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
 
 _PROM_LINE = re.compile(
     r"^(\w+?)(?:\{(.*)\})?\s+(-?[0-9.eE+\-]+|NaN|\+Inf|-Inf)$")
@@ -87,8 +70,8 @@ def parse_prometheus(text: str, namespace: str = "deepspeed_tpu"
     return snap
 
 
-def load_bundle(path: str) -> Tuple[dict, Optional[float]]:
-    """Postmortem bundle dir → (snapshot-like dict, derived step_ms)."""
+def load_bundle(path: str) -> dict:
+    """Postmortem bundle dir → snapshot-like dict."""
     snap: dict = {"counters": {}, "gauges": {}}
     prom = os.path.join(path, "snapshot.prom")
     if os.path.exists(prom):
@@ -98,22 +81,7 @@ def load_bundle(path: str) -> Tuple[dict, Optional[float]]:
     if os.path.exists(meta):
         with open(meta) as f:
             snap["spans"] = json.load(f).get("spans", {})
-    step_ms = None
-    records = os.path.join(path, "records.jsonl")
-    if os.path.exists(records):
-        sums: List[float] = []
-        with open(records) as f:
-            for line in f:
-                try:
-                    rec = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                spans = rec.get("spans_ms") or {}
-                if spans:
-                    sums.append(sum(spans.values()))
-        if sums:
-            step_ms = sum(sums) / len(sums)
-    return snap, step_ms
+    return snap
 
 
 def find_bundle(path: str) -> str:
@@ -167,126 +135,33 @@ def span_section(snap: dict, top: int = 8) -> str:
     return "\n".join(lines)
 
 
-def report(snap: dict, *, step_ms: Optional[float], fn: str,
-           comm_ms: Optional[float], as_json: bool = False) -> str:
-    from deepspeed_tpu.telemetry import profiler, roofline
-
-    sections: List[str] = []
-    budget = None
-    if step_ms:
-        budget = profiler.step_time_budget(snap, step_ms=step_ms, fn=fn,
-                                           comm_total_ms=comm_ms)
-        sections.append(profiler.render(budget))
-    else:
-        sections.append("step-time budget: no measured step time "
-                        "(pass --step-ms, or use a bench record / bundle "
-                        "with step records)")
-
-    executables = snap.get("executables") or {}
-    rendered_roofline = False
-    for name, exe in sorted(executables.items()):
-        model = exe.get("roofline")
-        if model:
-            sections.append(roofline.render(model, title=name))
-            rendered_roofline = True
-    if not rendered_roofline:
-        att = snap.get("gauges", {}).get("roofline_attainable_ms")
-        if att:
-            lines = ["roofline (gauges only — full class table lives in "
-                     "snapshot.json)"]
-            for s in att["samples"]:
-                lines.append(
-                    f"  attainable >= {s['value']:.3f} ms "
-                    f"(fn={(s.get('labels') or {}).get('fn', '?')})")
-            sections.append("\n".join(lines))
-        else:
-            sections.append("roofline: no compiled-HLO analysis in this "
-                            "snapshot (telemetry.hlo_stats off?)")
-
-    sections.append(link_section(snap))
-    sections.append(span_section(snap))
-
+def report(snap: dict) -> str:
+    sections = [link_section(snap), span_section(snap)]
     env = snap.get("env")
     if env:
         regime = env.get("resolved", env)
         sections.append("scheduler regime: "
                         + json.dumps(regime, sort_keys=True)[:400])
-
-    if as_json:
-        return json.dumps({"budget": budget,
-                           "roofline": {n: e.get("roofline")
-                                        for n, e in executables.items()
-                                        if e.get("roofline")}},
-                          indent=1, sort_keys=True)
     return "\n\n".join(sections)
 
 
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
-        description="render a telemetry snapshot / bench record / "
-                    "postmortem bundle into a step-time-budget + roofline "
-                    "report")
-    ap.add_argument("path", help="snapshot.json, bench record JSON, or "
-                                 "postmortem bundle dir")
-    ap.add_argument("--fn", default="train_batch",
-                    help="jitted function to attribute (default "
-                         "train_batch)")
-    ap.add_argument("--step-ms", type=float, default=None,
-                    help="measured step wall time override")
-    ap.add_argument("--comm-ms", type=float, default=None,
-                    help="profiled per-step collective latency override")
-    ap.add_argument("--json", action="store_true",
-                    help="emit the budget + roofline as JSON instead of "
-                         "the rendered report")
+        description="render a telemetry snapshot / postmortem bundle's "
+                    "per-link collective bytes and host spans")
+    ap.add_argument("path", help="snapshot.json or postmortem bundle dir")
     args = ap.parse_args(argv)
-
-    step_ms, comm_ms = args.step_ms, args.comm_ms
     try:
         if os.path.isdir(args.path):
-            bundle = find_bundle(args.path)
-            snap, derived = load_bundle(bundle)
-            step_ms = step_ms or derived
+            snap = load_bundle(find_bundle(args.path))
         else:
             with open(args.path) as f:
-                obj = json.load(f)
-            if "metric" in obj or "parsed" in obj:
-                rec = obj.get("parsed", obj)
-                extra = rec.get("extra") or {}
-                if step_ms is None and extra.get("step_time_s"):
-                    step_ms = float(extra["step_time_s"]) * 1e3
-                if comm_ms is None and extra.get("comm_total_ms"):
-                    comm_ms = float(extra["comm_total_ms"])
-                snap_path = extra.get("telemetry_snapshot")
-                snap = {}
-                if snap_path:
-                    for base in (os.path.dirname(os.path.abspath(
-                            args.path)), os.getcwd()):
-                        cand = os.path.join(base, snap_path)
-                        if os.path.exists(cand):
-                            with open(cand) as f:
-                                snap = json.load(f)
-                            break
-                if not snap:
-                    print(f"perf_report: bench record's telemetry "
-                          f"snapshot ({snap_path!r}) not found — "
-                          f"budget limited to record columns",
-                          file=sys.stderr)
-                    snap = {"counters": {}, "gauges": {}}
-                    ratio = extra.get("collective_exposed_ratio")
-                    if ratio is not None:
-                        snap["gauges"]["collective_exposed_ratio"] = {
-                            "help": "", "samples": [{
-                                "labels": {"fn": args.fn},
-                                "value": float(ratio)}]}
-            else:
-                snap = obj
-    except (OSError, ValueError, json.JSONDecodeError) as e:
+                snap = json.load(f)
+    except (OSError, ValueError) as e:
         print(f"perf_report: cannot load {args.path}: {e}",
               file=sys.stderr)
         return 2
-
-    print(report(snap, step_ms=step_ms, fn=args.fn, comm_ms=comm_ms,
-                 as_json=args.json))
+    print(report(snap))
     return 0
 
 

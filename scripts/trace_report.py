@@ -20,9 +20,6 @@ with a handoff, one unified) and asserts the exact-sum property plus
 the zero-handoff invariant — scripts/lint_all.py runs it as the
 ``trace_report`` lint so a drift in the span contract fails fast.
 
-``bench_serving.py``'s disagg leg folds :func:`ttft_budget` into its
-records as ``ttft_budget_*_ms`` columns.
-
 Exit status: 0 report printed / self-test passed, 1 self-test failed,
 2 load/usage errors.
 """

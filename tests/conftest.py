@@ -57,6 +57,14 @@ def rng():
     return np.random.default_rng(0)
 
 
+# XLA:CPU has two schedulers.  The installed jaxlib's default issues every
+# ready collective at once (a ZeRO-3 chunk train's gathers back to back); the
+# other places each op where its consumer needs it, which is the order that
+# shows what the program's data dependences allow between two chunks.  Tests
+# that read compute between collectives compile with these options.
+CONSUMER_ORDER = {"xla_cpu_enable_concurrency_optimized_scheduler": False}
+
+
 def make_lm_batch(rng, batch, seq, vocab):
     """Synthetic memorization task batch."""
     ids = rng.integers(0, vocab, size=(batch, seq), dtype=np.int64).astype(np.int32)

@@ -149,6 +149,31 @@ class TestLoRAMatmulOp:
         y = np.asarray(ops.lora_matmul(x, a, b, ids, scales, impl="xla"))
         assert not y.any()
 
+    @staticmethod
+    def _summation_order_tol(x, a, b, ids, scales):
+        """Per-element bound on what two f32 summation orders of the same
+        H-term rank product may differ by: the rounding error of an n-term
+        sum grows as sqrt(n)·eps·rms(terms) (4× for margin; measured worst
+        0.16 of it over four seeds), carried through B and the scale.  The
+        kernel contracts a dense [bm, H] x [H, r] dot, the reference a
+        per-row gathered einsum; against float64 the kernel is the closer
+        of the two (3.3e-5 vs 1.7e-4 at |y| to 250), and the elements that
+        a flat 2e-5 refused were cancellations (|y| 0.2-0.4 from terms of
+        ~100)."""
+        x, a, b = (np.asarray(v, np.float64) for v in (x, a, b))
+        ids, s = np.asarray(ids), np.asarray(scales, np.float64)
+        u_err = 4 * np.sqrt(x.shape[1]) * np.finfo(np.float32).eps \
+            * np.sqrt(np.einsum("mh,mhr->mr", x ** 2, a[ids] ** 2))
+        return np.einsum("mr,mro->mo", u_err, np.abs(b[ids])) \
+            * s[ids][:, None]
+
+    def _assert_same_to_summation_order(self, got, want, *case):
+        tol = self._summation_order_tol(*case)
+        diff = np.abs(np.asarray(got, np.float64) - np.asarray(want))
+        assert (diff <= tol).all(), (diff / np.maximum(tol, 1e-30)).max()
+        # the bound stays a bound: at most 1e-5 of the outputs' scale
+        assert tol.max() < 1e-5 * np.abs(np.asarray(want)).max()
+
     def test_pallas_kernel_matches_xla(self):
         """Interpret-mode kernel vs the gather reference, and the staging
         counter proves the KERNEL ran (not the silent fallback)."""
@@ -157,8 +182,8 @@ class TestLoRAMatmulOp:
         got = ops.lora_matmul(x, a, b, ids, scales, impl="pallas")
         assert lora_mod.trace_counts["lora"] == before + 1
         want = ops.lora_matmul(x, a, b, ids, scales, impl="xla")
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
+        self._assert_same_to_summation_order(got, want, x, a, b, ids,
+                                             scales)
 
     def test_pallas_pads_ragged_row_counts(self):
         """Decode rounds hand the kernel M that doesn't tile to the
@@ -169,8 +194,8 @@ class TestLoRAMatmulOp:
         got = ops.lora_matmul(x[:m], a, b, ids[:m], scales, impl="pallas")
         want = ops.lora_matmul(x[:m], a, b, ids[:m], scales, impl="xla")
         assert got.shape == (m, self.O)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
+        self._assert_same_to_summation_order(got, want, x[:m], a, b,
+                                             ids[:m], scales)
 
     def test_unsupported_layout_falls_back_not_crash(self):
         x, a, b, ids, scales = self._case()

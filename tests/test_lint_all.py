@@ -1,11 +1,11 @@
 """Unified lint driver (scripts/lint_all.py).
 
-ONE subprocess run replaces the four separate repo-green lint wirings
+ONE subprocess run replaces the separate repo-green lint wirings
 (check_no_sync in test_health, check_metrics + the serving check_no_sync
-main() run in test_serving_telemetry, and the new check_bench fixture
-lint): the driver runs all four in one process and prints a PASS/FAIL
-table.  The per-lint violation/behavior tests remain in their original
-files as unit tests.
+main() run in test_serving_telemetry, the trace_report fixture lint): the
+driver runs them in one process and prints a PASS/FAIL table.  The
+per-lint violation/behavior tests remain in their original files as unit
+tests.
 """
 
 import os
@@ -14,13 +14,13 @@ import sys
 
 REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
 SCRIPT = os.path.join(REPO, "scripts", "lint_all.py")
-LINTS = ("check_no_sync", "check_overlap", "check_metrics", "check_bench")
+LINTS = ("check_no_sync", "check_metrics", "trace_report")
 
 
 class TestLintAll:
     def test_all_lints_green_in_one_process(self):
-        """The repo passes every lint — the single CI wiring for all
-        four."""
+        """The repo passes every lint — the single CI wiring for all of
+        them."""
         r = subprocess.run([sys.executable, SCRIPT],
                            capture_output=True, text=True, timeout=560)
         assert r.returncode == 0, r.stdout + r.stderr
@@ -30,14 +30,14 @@ class TestLintAll:
         assert "lints clean" in r.stdout
 
     def test_only_subset_and_unknown_lint(self):
-        """--only runs a subset (no jax compile needed for these two);
-        an unknown lint name is a usage error, not a silent pass."""
+        """--only runs a subset; an unknown lint name is a usage error,
+        not a silent pass."""
         r = subprocess.run(
-            [sys.executable, SCRIPT, "--only", "check_bench",
+            [sys.executable, SCRIPT, "--only", "trace_report",
              "check_metrics"],
             capture_output=True, text=True, timeout=240)
         assert r.returncode == 0, r.stdout + r.stderr
-        assert "check_bench" in r.stdout
+        assert "trace_report" in r.stdout
         assert "check_no_sync" not in r.stdout.replace(
             "lint_all: unified lint summary", "")
         r = subprocess.run([sys.executable, SCRIPT, "--only", "nope"],

@@ -140,7 +140,7 @@ def _bf16_equiv_a2a_bytes(txt):
     """a2a payload bytes normalized to a bf16 wire: XLA:CPU's float
     normalization rewrites bf16 compute to f32, so full-width a2a payloads
     compile at 4 B/el here vs 2 B/el on TPU — halve when no bf16 a2a
-    survived (same convention as bench.py's MoE leg)."""
+    survived."""
     b = _a2a_bytes(txt)
     if not re.search(r"bf16\[[0-9,]*\][^ ]*\s+all-to-all", txt):
         b //= 2

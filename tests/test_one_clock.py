@@ -404,11 +404,14 @@ def generate_trace(tmp_path_factory):
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 97, (9 + 5 * i,)).astype(np.int32)
                for i in range(5)]
-    eng.generate(prompts, max_new_tokens=12)             # compile
+    # the sixth ends so near max_seq_len that no burst fits: single decodes
+    prompts.append(rng.integers(0, 97, (58,)).astype(np.int32))
+    budgets = [12] * 5 + [5]
+    eng.generate(prompts, max_new_tokens=budgets)        # compile
     ev0 = eng.telemetry.tracer.total_recorded
     notes = profiled(tmp_path_factory.mktemp("gen"), lambda: eng.generate(
-        prompts, max_new_tokens=12,
-        arrival_times=[0.0, 0.0, 0.01, 0.02, 0.3]))
+        prompts, max_new_tokens=budgets,
+        arrival_times=[0.0, 0.0, 0.01, 0.02, 0.3, 0.31]))
     events = list(eng.telemetry.tracer.events)[
         -(eng.telemetry.tracer.total_recorded - ev0):]
     return notes, events
@@ -453,7 +456,80 @@ def test_dispatch_spans_carry_what_a_reader_needs(generate_trace):
     assert all(a["args"]["tokens"] == a["args"]["steps"] * a["args"]["seqs"]
                for a in burst)
     gates = [a for a in notes if a["name"] == "ds.gate"]
-    assert sum(a["args"]["released"] for a in gates) == 5
+    assert sum(a["args"]["released"] for a in gates) == 6
+
+
+# One case per name of PERF.md section 3's span table, beside the two
+# whole-picture tests above: a lost span or argument names itself.
+
+ROUND_ARGS = ("n", "running", "waiting", "incoming", "slots", "host_ns")
+PHASE_ARGS = {"ds.gate": ("released", "late_ms_max"), "ds.idle_sleep": (),
+              "ds.admit": (), "ds.fence": (), "ds.retire": (),
+              "ds.materialize": (), "ds.build": (), "ds.h2d": ()}
+# what every dispatch span of a dense model carries (``_step_sampled``'s
+# note and ``counter_note``'s running totals); a burst has no bucket, the
+# other two no steps
+DISPATCH_ARGS = {
+    "ds.mixed_dispatch": ("tokens", "bucket", "seqs", "ctx_tokens",
+                          "mixed_seqs", "one_row_seqs"),
+    "ds.decode_dispatch": ("tokens", "bucket", "seqs", "ctx_tokens",
+                           "mixed_seqs", "one_row_seqs"),
+    "ds.burst_dispatch": ("tokens", "steps", "seqs", "ctx_tokens",
+                          "mixed_seqs", "one_row_seqs")}
+
+
+@pytest.mark.parametrize("arg", ROUND_ARGS)
+def test_round_span_carries(generate_trace, arg):
+    notes, _ = generate_trace
+    rounds = [a for a in notes if a["name"] == "ds.round"]
+    assert rounds and all(arg in r["args"] for r in rounds)
+    assert all(float(r["args"][arg]) >= 0 for r in rounds)
+
+
+@pytest.mark.parametrize("name", list(PHASE_ARGS))
+def test_phase_span_is_emitted_with_its_arguments(generate_trace, name):
+    notes, _ = generate_trace
+    got = [a for a in notes if a["name"] == name]
+    assert got, sorted({a["name"] for a in notes})
+    assert all(set(PHASE_ARGS[name]) <= set(a["args"]) for a in got)
+
+
+@pytest.mark.parametrize("name,arg", [(n, a) for n, args in
+                                      DISPATCH_ARGS.items() for a in args])
+def test_dispatch_span_carries(generate_trace, name, arg):
+    notes, _ = generate_trace
+    got = [a for a in notes if a["name"] == name]
+    assert got, sorted({a["name"] for a in notes})
+    assert all(arg in a["args"] for a in got), (name, arg)
+    values = [float(a["args"][arg]) for a in got]
+    # the running totals never fall; the others are counts of the step
+    assert values == sorted(values) if arg.endswith("_seqs") \
+        else min(values) >= 0
+
+
+TRAIN_SPANS = {"ds.train_step": ("step", "host_ns"), "ds.batch_input": (),
+               "ds.host_to_device": (), "ds.dispatch": (),
+               "ds.step_bookkeeping": ()}
+
+
+@pytest.fixture(scope="module")
+def train_trace(train_engine, tmp_path_factory):
+    ids = np.zeros((4, 64), np.int32)
+    train_engine.train_batch({"input_ids": ids})          # compile
+    return profiled(tmp_path_factory.mktemp("train"), lambda: [
+        train_engine.train_batch({"input_ids": ids}) for _ in range(2)])
+
+
+@pytest.mark.parametrize("name", list(TRAIN_SPANS))
+def test_train_step_span_is_emitted_with_its_arguments(train_trace, name):
+    got = [a for a in train_trace if a["name"] == name]
+    assert len(got) == 2, sorted({a["name"] for a in train_trace})
+    assert all(set(TRAIN_SPANS[name]) <= set(a["args"]) for a in got)
+    if name != "ds.train_step":         # the phases lie inside their step
+        steps = [a for a in train_trace if a["name"] == "ds.train_step"]
+        assert all(any(s["start_ns"] <= a["start_ns"]
+                       and a["end_ns"] <= s["end_ns"] for s in steps)
+                   for a in got)
 
 
 def test_host_ns_places_the_chrome_tracks_on_the_profiler_clock(

@@ -1,13 +1,9 @@
-"""Step-time attribution layer (ISSUE 12): roofline model, MFU budget,
-per-link byte split, bench regression sentinel, trace merging, snapshot
-provenance stamps, and the perf_report CLI.
+"""Per-link byte split, trace merging, snapshot provenance stamps, and the
+perf_report CLI.
 
-Hand-computed ground truth where the ISSUE asks for it: the tiny-matmul
-roofline flops/bytes are checked against 2·M·N·K and the exact operand +
-result payloads; the per-link split is checked for EXACT equality with
-the legacy wire-byte counters on 1-D and 2-D meshes (single-host and a
-simulated 2-host placement); the sentinel trips on the canned 10%
-slowdown and stays quiet inside the noise band.
+The per-link split is checked for EXACT equality with the wire-byte
+counters on 1-D and 2-D meshes (single-host and a simulated 2-host
+placement).
 """
 
 import json
@@ -23,7 +19,6 @@ import pytest
 REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
 SCRIPTS = os.path.join(REPO, "scripts")
 
-from deepspeed_tpu.telemetry import profiler, regression, roofline  # noqa: E402
 from deepspeed_tpu.telemetry.registry import (COLLECTIVE_BYTES,  # noqa: E402
                                               COLLECTIVE_CALLS,
                                               MetricRegistry,
@@ -36,171 +31,6 @@ def _scripts_import(name):
         return __import__(name)
     finally:
         sys.path.pop(0)
-
-
-# ============================================================== roofline
-
-class TestRooflineWalk:
-    def test_tiny_matmul_hand_computed(self):
-        """flops = 2·M·N·K and bytes = (M·K + K·N + M·N)·itemsize, exactly
-        — the ISSUE's hand-computed ground truth."""
-        M, K, N = 4, 8, 16
-
-        def f(a, b):
-            return a @ b
-
-        txt = jax.jit(f).lower(jnp.ones((M, K)),
-                               jnp.ones((K, N))).compile().as_text()
-        classes = roofline.walk_hlo_classes(txt)
-        assert classes["matmul"]["flops"] == 2 * M * N * K
-        assert classes["matmul"]["bytes"] == (M * K + K * N + M * N) * 4
-        assert classes["matmul"]["wire_bytes"] == 0
-
-    def test_fusion_interior_not_byte_counted(self):
-        """Dots keep their flops wherever they live; HBM bytes charge only
-        fusion BOUNDARIES (operands + result of the fusion call), never
-        the fused interior."""
-        def g(a, b, c):
-            h = jnp.tanh(a @ b + 1.0)
-            return (h * c) @ b.T
-
-        txt = jax.jit(g).lower(jnp.ones((32, 64)), jnp.ones((64, 128)),
-                               jnp.ones((32, 128))).compile().as_text()
-        classes = roofline.walk_hlo_classes(txt)
-        assert classes["matmul"]["flops"] == \
-            2 * 32 * 128 * 64 + 2 * 32 * 64 * 128
-        # the elementwise class is the fusion call site: its boundary is
-        # two [32,128] operands + one [32,128] result
-        assert classes["elementwise"]["bytes"] == 3 * 32 * 128 * 4
-        assert classes["elementwise"]["flops"] == 0
-
-    def test_collective_class_from_demo_hlo(self):
-        co = _scripts_import("check_overlap")
-        txt = co.demo_hlo(num_chunks=3)
-        classes = roofline.walk_hlo_classes(txt)
-        coll = {k: v for k, v in classes.items()
-                if k.startswith("collective:")}
-        assert coll, classes.keys()
-        assert sum(c["wire_bytes"] for c in coll.values()) > 0
-
-    def test_attention_classified_by_metadata(self):
-        txt = (
-            "ENTRY %main (a: f32[4,8]) -> f32[4,4] {\n"
-            '  %dot.1 = f32[4,4]{1,0} dot(f32[4,8]{1,0} %a, f32[8,4]{1,0}'
-            ' %b), lhs_contracting_dims={1}, rhs_contracting_dims={0},'
-            ' metadata={op_name="jit(f)/GPTBackbone/block_0/attn/qk"}\n'
-            "}\n")
-        classes = roofline.walk_hlo_classes(txt)
-        assert "attention" in classes
-        assert classes["attention"]["flops"] == 2 * 4 * 4 * 8
-
-    def test_calibration_scales_to_cost_analysis(self):
-        def f(a, b):
-            return a @ b
-
-        txt = jax.jit(f).lower(jnp.ones((4, 8)),
-                               jnp.ones((8, 16))).compile().as_text()
-        model = roofline.roofline_from_hlo(
-            txt, spec=dict(roofline.PEAK_SPECS["cpu-sim"], name="cpu-sim"),
-            cost_analysis={"flops": 2048.0})     # walk sees 1024
-        assert model["calibration"] == pytest.approx(2.0)
-        assert model["total_flops"] == pytest.approx(2048.0)
-        assert model["classes"]["matmul"]["flops_uncalibrated"] == 1024.0
-
-    def test_bound_classification_and_attainable(self):
-        def f(a, b):
-            return a @ b
-
-        txt = jax.jit(f).lower(jnp.ones((64, 64)),
-                               jnp.ones((64, 64))).compile().as_text()
-        # absurdly fast HBM -> compute-bound; absurdly slow -> hbm-bound
-        fast = roofline.roofline_from_hlo(
-            txt, spec={"flops": 1e9, "hbm": 1e18, "ici": 1e18,
-                       "name": "t"})
-        slow = roofline.roofline_from_hlo(
-            txt, spec={"flops": 1e18, "hbm": 1e3, "ici": 1e18,
-                       "name": "t"})
-        assert fast["classes"]["matmul"]["bound"] == "compute"
-        assert slow["classes"]["matmul"]["bound"] == "hbm"
-        for m in (fast, slow):
-            assert m["attainable_ms"] > 0
-            assert sum(m["bound_fraction"].values()) == pytest.approx(1.0)
-
-    def test_detect_peak_spec_cpu(self):
-        spec = roofline.detect_peak_spec()
-        assert spec["name"] == "cpu-sim"
-        assert spec["flops"] == roofline.PEAK_SPECS["cpu-sim"]["flops"]
-
-    @pytest.mark.parametrize("kind,name", [
-        ("TPU v5 lite", "v5e"), ("TPU v5p", "v5p"), ("TPU v4", "v4"),
-        ("TPU v6 lite", "v6e"), ("TPU v9x", None)])
-    def test_detect_peak_spec_tpu_kinds(self, kind, name):
-        """A TPU kind that is not in the table raises — a roofline against
-        another chip's peaks is a wrong number, never a default."""
-        import types
-        dev = types.SimpleNamespace(platform="tpu", device_kind=kind)
-        if name is None:
-            with pytest.raises(ValueError, match="TPU v9x"):
-                roofline.detect_peak_spec(dev)
-        else:
-            assert roofline.detect_peak_spec(dev)["name"] == name
-
-    def test_render_smoke(self):
-        model = roofline.roofline_from_hlo(
-            "ENTRY %main (a: f32[2,2]) -> f32[2,2] {\n"
-            "  %dot.1 = f32[2,2]{1,0} dot(f32[2,2]{1,0} %a, f32[2,2]{1,0}"
-            " %b), lhs_contracting_dims={1}, rhs_contracting_dims={0}\n"
-            "}\n",
-            spec=dict(roofline.PEAK_SPECS["cpu-sim"], name="cpu-sim"))
-        text = roofline.render(model, "toy")
-        assert "toy" in text and "bound" in text and "attainable" in text
-
-
-class TestRooflineEngine:
-    def test_tiny_gpt_snapshot_carries_roofline(self):
-        """The engine's compiled-HLO analysis now includes the roofline:
-        classes present, calibrated flops == cost_analysis flops, gauges
-        set, snapshot JSON-serializable."""
-        import deepspeed_tpu
-        from deepspeed_tpu.models import GPTChunkedLoss, GPTConfig
-        default_registry.reset()
-        cfg = GPTConfig(num_layers=2, num_heads=4, head_dim=16,
-                        hidden_size=64, vocab_size=512, max_seq_len=64,
-                        dropout=0.0, loss_chunk=64)
-        eng, _, _, _ = deepspeed_tpu.initialize(
-            model=GPTChunkedLoss(cfg),
-            config={"train_micro_batch_size_per_gpu": 2,
-                    "optimizer": {"type": "adamw", "params": {"lr": 1e-4}},
-                    "zero_optimization": {"stage": 2}, "mesh": {"dp": -1},
-                    "steps_per_print": 0,
-                    "telemetry": {"enabled": True, "trace_enabled": False,
-                                  "snapshot_interval": 0}},
-            example_batch={"input_ids": np.zeros((2, 64), np.int32)})
-        B = eng.train_batch_size                 # micro × dp_world
-        batch = {"input_ids": np.random.default_rng(0).integers(
-            0, 512, (B, 64)).astype(np.int32)}
-        eng.train_batch(batch)
-        snap = eng.telemetry.export(write=False)
-        exe = snap["executables"]["train_batch"]
-        model = exe.get("roofline")
-        assert model, "no roofline in the executable analysis"
-        assert "matmul" in model["classes"]
-        ca_flops = exe["cost_analysis"]["flops"]
-        assert model["total_flops"] == pytest.approx(ca_flops, rel=1e-6)
-        # the static walk is the right order of magnitude before
-        # calibration (within 3x of XLA's own count for this loop-free
-        # tiny model)
-        walked = sum(c["flops_uncalibrated"]
-                     for c in model["classes"].values())
-        assert ca_flops / 3 < walked < ca_flops * 3
-        att = default_registry.gauge("roofline_attainable_ms")
-        assert att.value(fn="train_batch") > 0
-        bf = default_registry.gauge("roofline_bound_fraction")
-        total = sum(bf.value(fn="train_batch", resource=r)
-                    for r in ("compute", "hbm", "ici"))
-        assert total == pytest.approx(1.0)
-        json.dumps(snap)                      # snapshot stays serializable
-        default_registry.reset()
 
 
 # ========================================================= per-link split
@@ -321,235 +151,6 @@ class TestPerLinkSplit:
         # calls counter untouched by the split
         assert default_registry.counter(COLLECTIVE_CALLS).value(
             kind="all_gather", axis="dp") == 1
-
-
-# ============================================================ MFU budget
-
-def _synthetic_snapshot(flops=1e9, exposed_ratio=0.25):
-    spec = dict(roofline.PEAK_SPECS["cpu-sim"], name="cpu-sim")
-    classes = {
-        "matmul": {"flops": flops, "bytes": 1e6, "wire_bytes": 0,
-                   "ops": 3, "t_compute_ms": flops / spec["flops"] * 1e3,
-                   "t_hbm_ms": 0.02, "t_ici_ms": 0.0, "bound": "compute",
-                   "attainable_ms": flops / spec["flops"] * 1e3,
-                   "flops_uncalibrated": flops},
-        "elementwise": {"flops": 0, "bytes": 5e7, "wire_bytes": 0,
-                        "ops": 9, "t_compute_ms": 0.0, "t_hbm_ms": 1.0,
-                        "t_ici_ms": 0.0, "bound": "hbm",
-                        "attainable_ms": 1.0, "flops_uncalibrated": 0},
-    }
-    return {
-        "executables": {"train_batch": {
-            "cost_analysis": {"flops": flops},
-            "roofline": {"spec": spec, "classes": classes,
-                         "attainable_ms": sum(c["attainable_ms"]
-                                              for c in classes.values()),
-                         "bound_fraction": {}},
-        }},
-        "gauges": {"collective_exposed_ratio": {"help": "", "samples": [
-            {"labels": {"fn": "train_batch"}, "value": exposed_ratio}]}},
-        "spans": {"batch_input": {"count": 10, "total_ms": 5.0,
-                                  "max_ms": 1.0, "mean_ms": 0.5},
-                  "host_to_device": {"count": 10, "total_ms": 3.0,
-                                     "max_ms": 1.0, "mean_ms": 0.3},
-                  "step_bookkeeping": {"count": 10, "total_ms": 2.0,
-                                       "max_ms": 1.0, "mean_ms": 0.2},
-                  "dispatch": {"count": 10, "total_ms": 90.0,
-                               "max_ms": 10.0, "mean_ms": 9.0}},
-    }
-
-
-class TestStepBudget:
-    def test_terms_sum_to_measured_exactly(self):
-        snap = _synthetic_snapshot()
-        step_ms = 50.0
-        b = profiler.step_time_budget(snap, step_ms=step_ms,
-                                      comm_total_ms=8.0)
-        # compute = flops/peak: 1e9 / 100e9 = 10 ms; exposed = 8*0.25 = 2;
-        # hbm_bound = 1.0 (elementwise attainable - 0 compute);
-        # host_gap = 0.5 + 0.3 + 0.2 = 1.0
-        assert b["compute_ms"] == pytest.approx(10.0)
-        assert b["terms_ms"]["exposed_comm"] == pytest.approx(2.0)
-        assert b["terms_ms"]["hbm_bound"] == pytest.approx(1.0)
-        assert b["terms_ms"]["host_gap"] == pytest.approx(1.0)
-        assert b["terms_ms"]["dispatch_floor"] == pytest.approx(36.0)
-        # acceptance: terms + achieved compute sum to measured step time
-        assert b["attributed_ms"] == pytest.approx(step_ms)
-        assert b["mfu_achieved"] == pytest.approx(10.0 / 50.0)
-        assert (b["mfu_achieved"] + sum(b["mfu_lost"].values())
-                == pytest.approx(1.0))
-
-    def test_exposed_comm_matches_ratio_product(self):
-        """Acceptance: the budget's exposed-comm term IS comm_total_ms ×
-        collective_exposed_ratio (the existing comm_exposed_ms column)."""
-        snap = _synthetic_snapshot(exposed_ratio=0.4)
-        b = profiler.step_time_budget(snap, step_ms=100.0,
-                                      comm_total_ms=12.5)
-        assert b["terms_ms"]["exposed_comm"] == pytest.approx(12.5 * 0.4)
-
-    def test_overattribution_disclosed_not_clamped(self):
-        snap = _synthetic_snapshot()
-        b = profiler.step_time_budget(snap, step_ms=5.0,
-                                      comm_total_ms=8.0)
-        assert b["terms_ms"]["dispatch_floor"] == 0.0
-        assert b["overattributed_ms"] > 0
-        assert any("exceed" in n for n in b["notes"])
-
-    def test_gauges_written(self):
-        reg = MetricRegistry()
-        profiler.step_time_budget(_synthetic_snapshot(), step_ms=50.0,
-                                  comm_total_ms=8.0, registry=reg)
-        assert reg.gauge("mfu_achieved").value(fn="train_batch") > 0
-        g = reg.gauge("mfu_lost")
-        causes = {labels["cause"] for labels, _ in g.samples()}
-        assert causes == set(profiler.LOST_CAUSES)
-
-    def test_degrades_without_signals(self):
-        b = profiler.step_time_budget({}, step_ms=10.0)
-        assert b["compute_ms"] == 0.0
-        assert b["terms_ms"]["dispatch_floor"] == pytest.approx(10.0)
-        assert b["notes"]
-        assert "budget" in profiler.render(b)
-
-
-# ============================================================= sentinel
-
-class TestSentinel:
-    LEDGER = {
-        "schema": regression.BASELINE_SCHEMA,
-        "default_noise_band": 0.08,
-        "metrics": {
-            "train_tokens_per_sec": {"value": 1000.0},
-            "serving_ttft_p99_ms": {"value": 50.0},
-            "mfu": {"value": 0.5, "band": 0.02},
-            "prefetch_starvation": {"value": 0.0},
-        },
-    }
-
-    def test_direction_map(self):
-        assert regression.metric_direction("train_tokens_per_sec") == 1
-        assert regression.metric_direction("ttft_p99_ms") == -1
-        assert regression.metric_direction("step_time_s") == -1
-        assert regression.metric_direction("collective_exposed_ratio") == -1
-        assert regression.metric_direction("mfu") == 1
-        assert regression.metric_direction("peak_device_memory_bytes") == -1
-
-    def test_trips_on_slowdown_quiet_on_noise(self):
-        bad = regression.make_fixture(self.LEDGER, "regression")
-        res = regression.compare(bad, self.LEDGER)
-        assert res["failed"]
-        tripped = {f["metric"] for f in res["regressions"]}
-        assert "train_tokens_per_sec" in tripped       # 10% drop
-        assert "serving_ttft_p99_ms" in tripped        # 10% rise
-        noise = regression.make_fixture(self.LEDGER, "noise")
-        res_n = regression.compare(noise, self.LEDGER)
-        assert not res_n["failed"], res_n["regressions"]
-
-    def test_per_metric_band_overrides_default(self):
-        cur = {"train_tokens_per_sec": 960.0,        # -4%: inside 8%
-               "serving_ttft_p99_ms": 50.0,
-               "mfu": 0.48,                          # -4%: outside 2%
-               "prefetch_starvation": 0.0}
-        res = regression.compare(cur, self.LEDGER)
-        assert [f["metric"] for f in res["regressions"]] == ["mfu"]
-
-    def test_improvement_reported_not_failing(self):
-        cur = {"train_tokens_per_sec": 1200.0, "serving_ttft_p99_ms": 30.0,
-               "mfu": 0.5, "prefetch_starvation": 0.0}
-        res = regression.compare(cur, self.LEDGER)
-        assert not res["failed"]
-        assert len(res["improvements"]) == 2
-
-    def test_zero_baseline_sentinel_counter(self):
-        cur = {"train_tokens_per_sec": 1000.0, "serving_ttft_p99_ms": 50.0,
-               "mfu": 0.5, "prefetch_starvation": 3.0}
-        res = regression.compare(cur, self.LEDGER)
-        assert res["failed"]
-        assert res["regressions"][0]["metric"] == "prefetch_starvation"
-
-    def test_missing_and_new_and_strict(self):
-        cur = {"train_tokens_per_sec": 1000.0, "brand_new_tps": 5.0}
-        res = regression.compare(cur, self.LEDGER)
-        assert not res["failed"]
-        assert "mfu" in res["missing"]
-        assert res["new"] == ["brand_new_tps"]
-        assert regression.compare(cur, self.LEDGER,
-                                  strict_missing=True)["failed"]
-
-    def test_flatten_and_jsonl_roundtrip(self, tmp_path):
-        rec = {"metric": "m1", "value": 10.0, "unit": "x",
-               "extra": {"a_ms": 1.5, "note": "str", "flag": True}}
-        flat = regression.flatten_bench_record(rec)
-        assert flat == {"m1": 10.0, "a_ms": 1.5}
-        path = str(tmp_path / "r.jsonl")
-        n = regression.append_bench_records(path, flat,
-                                            env={"smoke": True})
-        assert n == 2
-        regression.append_bench_records(path, {"m1": 11.0})
-        loaded = regression.load_bench_file(path)
-        assert loaded == {"m1": 11.0, "a_ms": 1.5}     # last write wins
-        line = json.loads(open(path).readline())
-        assert set(line) == {"metric", "value", "unit", "env",
-                             "unix_time"}
-
-    def test_wrapper_and_flat_forms_load(self, tmp_path):
-        wrapper = {"parsed": {"metric": "m", "value": 2.0,
-                              "extra": {"mfu": 0.5}}}
-        p1 = tmp_path / "w.json"
-        p1.write_text(json.dumps(wrapper))
-        assert regression.load_bench_file(str(p1)) == {"m": 2.0,
-                                                       "mfu": 0.5}
-        p2 = tmp_path / "flat.json"
-        p2.write_text(json.dumps({"a": 1.0, "b": 2.0}))
-        assert regression.load_bench_file(str(p2)) == {"a": 1.0, "b": 2.0}
-
-    def test_cli_green_on_seeded_baseline_and_fixtures(self, tmp_path):
-        """Acceptance: check_bench exits 0 on BENCH_r05.json vs the
-        committed ledger, 1 on the canned regression, 0 on canned
-        noise."""
-        script = os.path.join(SCRIPTS, "check_bench.py")
-        r = subprocess.run(
-            [sys.executable, script, "--current",
-             os.path.join(REPO, "BENCH_r05.json")],
-            capture_output=True, text=True)
-        assert r.returncode == 0, r.stdout + r.stderr
-        ledger = regression.load_baseline(
-            os.path.join(REPO, "BENCH_BASELINE.json"))
-        for kind, want_rc in (("regression", 1), ("noise", 0)):
-            p = tmp_path / f"{kind}.json"
-            p.write_text(json.dumps(regression.make_fixture(ledger, kind)))
-            r = subprocess.run(
-                [sys.executable, script, "--current", str(p)],
-                capture_output=True, text=True)
-            assert r.returncode == want_rc, (kind, r.stdout, r.stderr)
-
-    def test_cli_self_test_and_update_baseline(self, tmp_path):
-        script = os.path.join(SCRIPTS, "check_bench.py")
-        r = subprocess.run([sys.executable, script, "--self-test"],
-                           capture_output=True, text=True)
-        assert r.returncode == 0, r.stdout + r.stderr
-        # self-test stays green on a ledger carrying zero-valued metrics
-        # (a reseeded ledger keeps zero counters like prefetch_starvation;
-        # a 10% shift of 0 is 0 and must not be counted as a failed trip)
-        zl = dict(self.LEDGER)
-        zl_path = tmp_path / "zero_ledger.json"
-        zl_path.write_text(json.dumps(zl))
-        r = subprocess.run(
-            [sys.executable, script, "--self-test", "--baseline",
-             str(zl_path)],
-            capture_output=True, text=True)
-        assert r.returncode == 0, r.stdout + r.stderr
-        cur = tmp_path / "cur.json"
-        cur.write_text(json.dumps({"metric": "m", "value": 3.0,
-                                   "extra": {"mfu": 0.6}}))
-        out = tmp_path / "ledger.json"
-        r = subprocess.run(
-            [sys.executable, script, "--current", str(cur),
-             "--baseline", str(out), "--update-baseline"],
-            capture_output=True, text=True)
-        assert r.returncode == 0, r.stderr
-        ledger = regression.load_baseline(str(out))
-        assert ledger["metrics"]["m"]["value"] == 3.0
 
 
 # ===================================================== snapshot stamps
@@ -693,68 +294,46 @@ class TestMergeTraces:
 
 class TestPerfReport:
     def test_snapshot_mode_sections(self, tmp_path):
-        snap = _synthetic_snapshot()
-        snap["counters"] = {"collective_bytes_total": {"help": "",
-            "samples": [
+        snap = {
+            "counters": {"collective_bytes_total": {"help": "", "samples": [
                 {"labels": {"kind": "all_gather", "axis": "fsdp"},
                  "value": 300.0},
                 {"labels": {"kind": "all_gather", "axis": "fsdp",
                             "link": "ici"}, "value": 200.0},
                 {"labels": {"kind": "all_gather", "axis": "fsdp",
-                            "link": "dcn"}, "value": 100.0}]}}
+                            "link": "dcn"}, "value": 100.0}]}},
+            "spans": {"batch_input": {"count": 10, "total_ms": 5.0,
+                                      "max_ms": 1.0, "mean_ms": 0.5},
+                      "dispatch": {"count": 10, "total_ms": 90.0,
+                                   "max_ms": 10.0, "mean_ms": 9.0}},
+            "env": {"resolved": {"num_chunks": 2}},
+        }
         p = tmp_path / "snapshot.json"
         p.write_text(json.dumps(snap))
         r = subprocess.run(
             [sys.executable, os.path.join(SCRIPTS, "perf_report.py"),
-             str(p), "--step-ms", "50", "--comm-ms", "8"],
+             str(p)],
             capture_output=True, text=True)
         assert r.returncode == 0, r.stderr
-        for needle in ("step-time budget", "roofline", "per-link",
-                       "all_gather", "dispatch_floor", "host phase spans"):
+        for needle in ("per-link", "all_gather", "host phase spans",
+                       "batch_input", "scheduler regime"):
             assert needle in r.stdout, (needle, r.stdout)
         # the link table renders the exact split
         row = [ln for ln in r.stdout.splitlines()
                if ln.strip().startswith("all_gather")][0]
         assert "300" in row and "200" in row and "100" in row
-
-    def test_bench_record_mode_exposed_comm_matches(self, tmp_path):
-        """Acceptance: budget exposed-comm == the record's own
-        comm_exposed_ms (comm_total_ms × ratio) — same product, read
-        through the CLI."""
-        snap = _synthetic_snapshot(exposed_ratio=0.4)
-        sp = tmp_path / "telemetry_snapshot.json"
-        sp.write_text(json.dumps(snap))
-        record = {"metric": "m", "value": 1.0, "extra": {
-            "step_time_s": 0.050, "comm_total_ms": 12.5,
-            "comm_exposed_ms": 5.0, "collective_exposed_ratio": 0.4,
-            "telemetry_snapshot": "telemetry_snapshot.json"}}
-        rp = tmp_path / "record.json"
-        rp.write_text(json.dumps(record))
-        r = subprocess.run(
-            [sys.executable, os.path.join(SCRIPTS, "perf_report.py"),
-             str(rp), "--json"],
-            capture_output=True, text=True, cwd=str(tmp_path))
-        assert r.returncode == 0, r.stderr
-        budget = json.loads(r.stdout)["budget"]
-        assert budget["terms_ms"]["exposed_comm"] == pytest.approx(
-            5.0, rel=0.10)
-        assert budget["measured_step_ms"] == pytest.approx(50.0)
-        # terms (plus achieved compute) sum to measured within 5%
-        assert budget["attributed_ms"] == pytest.approx(50.0, rel=0.05)
+        # the heaviest phase leads the span table
+        spans = r.stdout[r.stdout.index("host phase spans"):]
+        assert spans.index("dispatch") < spans.index("batch_input")
 
     def test_postmortem_bundle_mode(self, tmp_path):
         """perf_report runs on a real postmortem bundle layout: spans
-        from meta.json, metrics parsed back out of snapshot.prom, step
-        time derived from the records' spans_ms."""
+        from meta.json, metrics parsed back out of snapshot.prom."""
         from deepspeed_tpu.telemetry.exporter import SnapshotExporter
         bundle = tmp_path / "postmortem" / "20260101-000000-step5-manual"
         bundle.mkdir(parents=True)
         reg = MetricRegistry()
-        reg.gauge("collective_exposed_ratio", "h").set(0.2,
-                                                       fn="train_batch")
         reg.gauge("xla_cost_flops", "h").set(1e9, fn="train_batch")
-        reg.gauge("roofline_attainable_ms", "h").set(11.0,
-                                                     fn="train_batch")
         reg.counter("collective_bytes_total", "h").inc(
             64, kind="all_reduce", axis="dp", link="ici")
         SnapshotExporter(reg).write_prometheus(
@@ -773,8 +352,6 @@ class TestPerfReport:
              str(tmp_path / "postmortem")],
             capture_output=True, text=True)
         assert r.returncode == 0, r.stderr
-        assert "measured 10.000 ms/step" in r.stdout   # derived from spans
-        assert "attainable >= 11.000 ms" in r.stdout   # prom gauge
         assert "all_reduce" in r.stdout                # per-link table
         assert "dispatch" in r.stdout                  # spans section
 

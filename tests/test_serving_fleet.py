@@ -830,55 +830,68 @@ class TestHeartbeatWarmupGate:
 
 
 # ---------------------------------------------------------------------------
-# bench chaos leg + lint wiring
+# chaos under open-loop load + lint wiring
 # ---------------------------------------------------------------------------
 
 class TestBenchFleetLeg:
     def test_chaos_leg_goodput_degrades_gracefully(self, cfg, params,
                                                    workload, reference):
         """The acceptance criterion, CPU-sized: kill 1 of 2 replicas
-        mid-load; post-kill goodput stays >= 0.7*(N-1)/N of the healthy
-        fleet's, no lost or duplicated requests, and the emitted columns
-        are present."""
-        import bench_serving
+        mid-load with respawn off; post-kill goodput stays >=
+        0.7*(N-1)/N of the healthy fleet's and every request completes
+        exactly once (the killed replica's in-flight requests migrate)."""
+        import threading
 
         prompts, budgets = workload
         prompts, budgets = prompts * 2, budgets * 2     # enough load to
         #                                                 straddle the kill
-        orig_slots = bench_serving.SLOTS
-        bench_serving.SLOTS = V2CFG["state_manager"]["max_tracked_sequences"]
         # under capacity for (N-1) replicas: the survivors must absorb the
         # offered load, so post-recovery goodput ~ offered rate — CPU-sized
         # "degrades gracefully, does not cliff"
-        rate = 10.0
-        try:
-            # healthy-fleet goodput baseline: the SAME open-loop workload
-            # (identical seeded arrivals), no kill
-            arrivals = np.cumsum(np.random.default_rng(11).exponential(
-                1.0 / rate, size=len(prompts)))
-            with make_fleet(cfg, params, {"num_replicas": 2}) as fleet:
-                fleet.serve(prompts, max_new_tokens=budgets, max_wall_s=300)
-                t0 = fleet.clock()
-                fleet.serve(prompts, max_new_tokens=budgets,
-                            arrival_times=arrivals, max_wall_s=300)
-                healthy = sum(r["generated_tokens"]
-                              for r in fleet.request_log) \
-                    / (fleet.clock() - t0)
-            cols = bench_serving.run_fleet_chaos(
-                cfg, params, prompts, budgets, rate=rate, replicas=2,
-                block_size=V2CFG["state_manager"]["kv_block_size"])
-        finally:
-            bench_serving.SLOTS = orig_slots
-        for key in ("goodput_before_kill", "goodput_after_kill",
-                    "recovery_ms", "requests_migrated",
-                    "fleet_requests_completed"):
-            assert key in cols
-        assert cols["fleet_replica_deaths"] == 1.0
-        assert cols["requests_migrated"] > 0
-        assert cols["fleet_requests_completed"] == len(prompts)
-        n = cols["fleet_replicas"]
-        assert cols["goodput_after_kill"] >= \
-            0.7 * (n - 1) / n * healthy
+        rate, n = 10.0, 2
+        # the SAME seeded open-loop arrivals for the healthy and the
+        # killed fleet
+        arrivals = np.cumsum(np.random.default_rng(11).exponential(
+            1.0 / rate, size=len(prompts)))
+        with make_fleet(cfg, params, {"num_replicas": n}) as fleet:
+            fleet.serve(prompts, max_new_tokens=budgets, max_wall_s=300)
+            t0 = fleet.clock()
+            fleet.serve(prompts, max_new_tokens=budgets,
+                        arrival_times=arrivals, max_wall_s=300)
+            healthy = sum(r["generated_tokens"]
+                          for r in fleet.request_log) \
+                / (fleet.clock() - t0)
+
+        kill_at = 0.35 * float(arrivals[-1])            # mid-load
+        timer = threading.Timer(
+            kill_at, lambda: faults.inject("replica.mid_decode", "exc"))
+        with make_fleet(cfg, params, {
+                "num_replicas": n, "respawn": False,
+                "warmup_deadline_s": 600.0, "heartbeat_deadline_s": 60.0,
+                "router": {"max_retries": n + 1}}) as fleet:
+            fleet.serve(prompts, max_new_tokens=budgets, max_wall_s=300)
+            t0 = fleet.clock()
+            timer.start()
+            try:
+                outs = fleet.serve(prompts, max_new_tokens=budgets,
+                                   arrival_times=arrivals, max_wall_s=300)
+            finally:
+                timer.cancel()      # (the autouse fixture resets faults)
+            t_end = fleet.clock()
+        assert all(o is not None for o in outs), "fleet lost a request"
+        reg = fleet.registry._metrics
+        log = fleet.request_log
+        t_kill = t0 + kill_at
+        # recovered window: from the first post-kill completion to the end
+        first_after = min(r["t_done"] for r in log if r["t_done"] > t_kill)
+        goodput_after = sum(r["generated_tokens"] for r in log
+                            if r["t_done"] >= first_after) \
+            / max(t_end - first_after, 1e-3)
+        assert reg["fleet_replica_deaths_total"].value(
+            reason="replica_death") == 1.0
+        assert reg["requests_migrated_total"].value() > 0
+        assert len(log) == len(prompts)
+        assert goodput_after >= 0.7 * (n - 1) / n * healthy
 
     def test_check_no_sync_covers_router_loop(self):
         import importlib.util
