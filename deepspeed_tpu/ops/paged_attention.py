@@ -6,29 +6,46 @@ slot owns a list of fixed-size KV pages; decode attends one query token per
 slot over exactly that slot's pages.
 
 Kernel design (vs the XLA fallback, which masks over gathered pages):
-- grid = (slots, kv_heads, kv_splits) — flash-decoding style.  Each step
-  runs an in-kernel double-buffered HBM→VMEM DMA loop over ITS SHARE of the
-  slot's live pages (block table via scalar prefetch), with online-softmax
-  m/l/acc scratch, and emits unnormalized partials that a tiny XLA epilogue
-  merges (logsumexp-weighted).  One split (the default — Pallas TPU grids
-  run sequentially per core, so splits don't parallelize under current
-  dispatch) degenerates to the single-pass kernel; the split knob exists
-  for explicit experimentation on dispatch modes where the axis can run
-  concurrently.  Bandwidth always scales with tokens
-  actually attended (only live pages are ever read — the property the
-  reference kernel gets from its atom decomposition), and a sliding window
-  additionally starts the loop past wholly-out-of-window pages.
-- GQA native: q arrives [S, nkv, group, hd]; one grid step attends the whole
-  group for one kv head (scores [group, bs] on the MXU).
-- alibi: per-head slope × key-position bias folded into the online softmax.
+- grid = (slots,): one grid step attends one slot for EVERY kv head.  A page
+  of the row-major pool is contiguous over its kv heads, so ``hbm.at[page]``
+  is one [nkv, bs, hd] slab (256 KB at nkv 8, bs 128, hd 128 in bf16) and one
+  copy descriptor; the block table rides in scalar prefetch.
+- the page pipeline: a loop iteration fetches a BLOCK of P whole pages
+  (``_block_pages``: what fits 2 MB of K and V, from static shapes alone;
+  4 at the serving cells' geometry, one page of one head being the loop this
+  replaced).  All of a block's K and V copies are started before any is
+  waited for, and the NEXT block's are started before this block's dots, so
+  one to two blocks (2-4 MB) are always in flight.  When a slot's last block
+  is reached the next block is the next LIVE slot's first one: the pipeline
+  runs across grid steps (two SMEM words carry which buffer half and whether
+  it is already under way), so only a call's first block is ever exposed.
+  Each page has its own semaphores and is waited for right before its dots.
+- the dots are batched over the kv heads ([nkv, g, hd] x [nkv, bs, hd]): nkv
+  independent score / online-softmax / PV chains a page, fp32 accumulation,
+  and the kernel normalises and writes [S, nkv, g, hd] in q's dtype itself
+  (no partials, no XLA combine).
+- only live pages are ever copied: a block's tail past ``kv_len`` and the
+  pages before a sliding window's first key issue no copy and run no dots, so
+  bytes scale with the tokens attended.  A slot with ``kv_len == 0`` costs
+  one grid step that writes zeros (a mixed step hands the kernel every slot
+  with most lengths zeroed).
+- alibi: per-head slope × key-position bias folded into the online softmax
+  (slopes [nkv, g, 1] as a VMEM input).
+- on one v5e chip (PERF.md section 6, PR 32) the kernel is bound by its
+  copies (copies alone take what the whole kernel takes, the dots alone
+  about half of it) and reads 83-90% of the HBM roofline in both serving
+  cells' decode programs: 0.088 us a (page, kv head) pair of 64 KB, where
+  the one-page-one-head loop took 0.40-0.46, and nothing measurable a grid
+  step.
 
 Layouts: q [S, nkv, g, hd]; k_pages/v_pages [NB, nkv, bs, hd] (bs = tokens
 per page); block_table [S, MB] int32; kv_lens [S] int32 (0 ⇒ inactive slot →
 zero output).  Output [S, nkv, g, hd].
 
-The pages are row-major in memory: the kernels DMA ``hbm.at[page, head]``
-slabs and, being custom calls, get their operands in no other layout (the
-compiler copies an operand that some other op keeps otherwise).  ``NB`` is
+The pages are row-major in memory: the kernels DMA ``hbm.at[page]`` (decode)
+and ``hbm.at[page, head]`` (prefill) slabs and, being custom calls, get their
+operands in no other layout (the compiler copies an operand that some other
+op keeps otherwise).  ``NB`` is
 whatever the block table indexes: k_pages/v_pages (and the int8 scales) may
 be the flat pool of ALL layers, [L * NB, ...], with the layer's first page
 ``li * NB`` added to the block table.  That is how the serving step programs
@@ -78,11 +95,11 @@ def _dequant_page(k, v, ks, vs, kv_major, dtype):
     axis is the LANE axis of a kv-major page ([hd, bs]) and the SUBLANE axis
     otherwise ([bs, hd]) — single source of truth for both kernels."""
     if kv_major:
-        k = (k.astype(jnp.float32) * ks[None, :]).astype(dtype)
-        v = (v.astype(jnp.float32) * vs[None, :]).astype(dtype)
+        k = (k.astype(jnp.float32) * ks[..., None, :]).astype(dtype)
+        v = (v.astype(jnp.float32) * vs[..., None, :]).astype(dtype)
     else:
-        k = (k.astype(jnp.float32) * ks[:, None]).astype(dtype)
-        v = (v.astype(jnp.float32) * vs[:, None]).astype(dtype)
+        k = (k.astype(jnp.float32) * ks[..., :, None]).astype(dtype)
+        v = (v.astype(jnp.float32) * vs[..., :, None]).astype(dtype)
     return k, v
 
 
@@ -160,145 +177,173 @@ def xla_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
     return jnp.einsum("sngk,sknd->sngd", probs.astype(q.dtype), v_seq)
 
 
-def _split_kernel(*refs, bs, scale, window, has_alibi, n_splits, kv_major,
-                  quant=False):
-    """Flash-decoding-SHAPED kernel (one grid step = one KV split of one
-    (slot, kv-head)): the page loop covers only this split's share of the
-    slot's live pages and emits UNNORMALIZED partials (acc, m, l) that a
-    tiny XLA epilogue merges with the standard logsumexp-weighted combine.
-    n_splits=1 (the default) IS the single-pass decode kernel; more splits
-    only help where the grid axis can actually run concurrently — see the
-    module docstring.
+# One block's K and V pages (all kv heads); the block after it is in flight
+# beside it, so twice this is what the kernel keeps moving.
+_BLOCK_BYTES = 2 << 20
+_MAX_BLOCK_PAGES = 8
 
-    Alibi slopes ride in SMEM scalar prefetch ([nkv, g] f32): a (1, g)
-    VMEM BlockSpec is rejected by Mosaic when nkv > 1 (sublane block of 1
-    against an nkv-sized axis), and per-head scalars are SMEM-natured
-    anyway.
 
-    ``quant``: pages are int8 codes and two extra HBM inputs carry the
-    per-(page, head, token) fp32 scales — the page loop DMAs the scale rows
-    alongside the pages (double-buffered the same way) and dequantizes in
-    VMEM right before the dots.  The HBM traffic that decode is bound by is
-    the int8 payload: half the bf16 bytes."""
+def _block_pages(nkv: int, bs: int, hd: int, dtype, quant: bool = False) -> int:
+    """P, the pages a loop iteration of the decode kernel fetches: the whole
+    pages (every kv head, K and V, the scale rows of int8 pages) that fit
+    ``_BLOCK_BYTES``, at least one and at most ``_MAX_BLOCK_PAGES`` (each page
+    of a block is an unrolled copy).  Static shapes only: a model, a shard of
+    its heads or a page dtype changes P, nothing else does."""
+    page = 2 * nkv * bs * hd * jnp.dtype(dtype).itemsize
     if quant:
-        if has_alibi:
-            bt_ref, len_ref, slopes_ref, q_ref, k_hbm, v_hbm, ks_hbm, \
-                vs_hbm, o_ref, m_ref, l_ref, k_buf, v_buf, ks_buf, vs_buf, \
-                sem = refs
-        else:
-            bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, \
-                o_ref, m_ref, l_ref, k_buf, v_buf, ks_buf, vs_buf, sem = refs
-            slopes_ref = None
-    elif has_alibi:
-        bt_ref, len_ref, slopes_ref, q_ref, k_hbm, v_hbm, \
-            o_ref, m_ref, l_ref, k_buf, v_buf, sem = refs
-    else:
-        bt_ref, len_ref, q_ref, k_hbm, v_hbm, \
-            o_ref, m_ref, l_ref, k_buf, v_buf, sem = refs
-        slopes_ref = None
-    if not quant:
-        ks_hbm = vs_hbm = ks_buf = vs_buf = None
-    s, h, sp = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    length = len_ref[s]
-    n_pages = (length + bs - 1) // bs
-    g, hd = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[0, 0]                                # [g, hd]
-    if window is None:
-        lo_page = jnp.int32(0)
-        lo = jnp.int32(0)
-    else:
-        lo = jnp.maximum(length - window, 0)
-        lo_page = lo // bs
-    live_pages = jnp.maximum(n_pages - lo_page, 0)
-    per = (live_pages + n_splits - 1) // n_splits
-    p_start = lo_page + sp * per
-    p_end = jnp.minimum(p_start + per, n_pages)
+        page += 2 * nkv * bs * 4
+    return int(max(1, min(_MAX_BLOCK_PAGES, _BLOCK_BYTES // page)))
 
-    def dma(hbm, buf, slot, p, way):
-        return pltpu.make_async_copy(
-            hbm.at[bt_ref[s, p], h], buf.at[slot], sem.at[way * 2 + slot])
 
-    def start_page(slot, p):
-        dma(k_hbm, k_buf, slot, p, 0).start()
-        dma(v_hbm, v_buf, slot, p, 1).start()
-        if quant:
-            dma(ks_hbm, ks_buf, slot, p, 2).start()
-            dma(vs_hbm, vs_buf, slot, p, 3).start()
+def _decode_kernel(*refs, S, P, bs, scale, window, has_alibi, kv_major,
+                   quant):
+    """One grid step = one slot, every kv head (see the module docstring).
 
-    @pl.when(p_end > p_start)
-    def _warmup():
-        start_page(jax.lax.rem(p_start, 2), p_start)
+    ``state`` (SMEM) carries the page pipeline from one slot to the next:
+    ``state[0]`` is the buffer half the next block to be attended lands in,
+    ``state[1]`` whether its copies are already under way (the previous live
+    slot started them before its own last block's dots).
 
-    def body(p, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(p, 2)
-        nxt = jax.lax.rem(p + 1, 2)
+    ``quant``: pages are int8 codes and two more HBM inputs carry the
+    per-(page, head, token) fp32 scales; a page's scale rows ([nkv, bs]) are
+    copied beside it and the page is dequantized in VMEM right before the
+    dots.  The HBM traffic decode is bound by is the int8 payload."""
+    it = iter(refs)
+    bt_ref, len_ref, q_ref = next(it), next(it), next(it)
+    slopes_ref = next(it) if has_alibi else None
+    hbms = [next(it) for _ in range(4 if quant else 2)]
+    o_ref = next(it)
+    bufs = [next(it) for _ in hbms]
+    sem, state = next(it), next(it)
+    s = pl.program_id(0)
 
-        @pl.when(p + 1 < p_end)
-        def _prefetch():
-            start_page(nxt, p + 1)
+    def span(t):
+        """(context, first key, first page, pages) of slot ``t``: only
+        pages in [first page, pages) are ever copied."""
+        length = len_ref[t]
+        lo = (jnp.int32(0) if window is None
+              else jnp.maximum(length - window, 0))
+        return length, lo, lo // bs, (length + bs - 1) // bs
 
-        dma(k_hbm, k_buf, slot, p, 0).wait()
-        dma(v_hbm, v_buf, slot, p, 1).wait()
-        k = k_buf[slot]                # [bs, hd] or [hd, bs] (kv-major)
-        v = v_buf[slot]
-        if quant:
-            dma(ks_hbm, ks_buf, slot, p, 2).wait()
-            dma(vs_hbm, vs_buf, slot, p, 3).wait()
-            k, v = _dequant_page(k, v, ks_buf[slot], vs_buf[slot],
-                                 kv_major, q.dtype)
-        k_dims = ((1,), (0,)) if kv_major else ((1,), (1,))
-        scores = jax.lax.dot_general(
-            q, k, (k_dims, ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        kvpos = p * bs + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        if has_alibi:
-            sl = jnp.stack([slopes_ref[h, i] for i in range(g)])
-            scores = scores + sl[:, None] * kvpos.astype(jnp.float32)
-        valid = kvpos < length
-        if window is not None:
-            valid = valid & (kvpos >= lo)
-        scores = jnp.where(valid, scores, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-        pr = jnp.exp(scores - m_new)
-        pr = jnp.where(m_new > _NEG_INF / 2, pr, 0.0)
-        alpha = jnp.exp(m - m_new)
-        l = alpha * l + jnp.sum(pr, axis=1, keepdims=True)
-        v_dims = ((1,), (1,)) if kv_major else ((1,), (0,))
-        pv = jax.lax.dot_general(pr.astype(v.dtype), v,
-                                 (v_dims, ((), ())),
-                                 preferred_element_type=jnp.float32)
-        return m_new, l, acc * alpha + pv
+    def copies(t, p, half, i):
+        page = bt_ref[t, p]
+        return [pltpu.make_async_copy(hbm.at[page], buf.at[half, i],
+                                      sem.at[w, half, i])
+                for w, (hbm, buf) in enumerate(zip(hbms, bufs))]
 
-    m0 = jnp.full((g, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g, 1), jnp.float32)
-    acc0 = jnp.zeros((g, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(p_start, p_end, body, (m0, l0, acc0))
-    o_ref[0, 0, 0] = acc                           # fp32 partial
-    m_ref[0, 0, 0] = m[:, 0]
-    l_ref[0, 0, 0] = l[:, 0]
+    def start_block(t, p0, n_pages, half):
+        for i in range(P):
+            @pl.when(p0 + i < n_pages)
+            def _start():
+                for c in copies(t, p0 + i, half, i):
+                    c.start()
+
+    @pl.when(s == 0)
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    length, lo, first, n_pages = span(s)
+
+    @pl.when(length == 0)
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(length > 0)
+    def _live():
+        nblk = (n_pages - first + P - 1) // P
+        nxt = jax.lax.while_loop(
+            lambda t: (t < S) & (len_ref[jnp.minimum(t, S - 1)] == 0),
+            lambda t: t + 1, s + 1)
+        has_next = nxt < S
+        nxt = jnp.minimum(nxt, S - 1)
+        _, _, nxt_first, nxt_pages = span(nxt)
+        half0 = state[0]
+
+        @pl.when(state[1] == 0)
+        def _first_of_the_call():
+            start_block(s, first, n_pages, half0)
+
+        q = q_ref[0]                                   # [nkv, g, hd]
+        nkv, g, hd = q.shape
+        slopes = slopes_ref[...] if has_alibi else None    # [nkv, g, 1]
+        batch = ((0,), (0,))
+        k_dims = (((2,), (1,)), batch) if kv_major else (((2,), (2,)), batch)
+        v_dims = (((2,), (2,)), batch) if kv_major else (((2,), (1,)), batch)
+
+        def block(j, carry):
+            half = (half0 + j) % 2
+            p0 = first + j * P
+            last = j + 1 == nblk
+            # all of the next block's copies before this block's dots: this
+            # slot's next pages, or the next live slot's first ones
+            t2 = jnp.where(last, nxt, s)
+            p2 = jnp.where(last, nxt_first, p0 + P)
+            n2 = jnp.where(last, nxt_pages, n_pages)
+
+            @pl.when(jnp.logical_not(last) | has_next)
+            def _prefetch():
+                start_block(t2, p2, n2, 1 - half)
+
+            def page(i, carry):
+                m, l, acc = carry
+                p = p0 + i
+                for c in copies(s, p, half, i):
+                    c.wait()
+                # [nkv, bs, hd] or [nkv, hd, bs]; int8: and scales [nkv, bs]
+                k, v, *scales = (buf[half, i] for buf in bufs)
+                if quant:
+                    k, v = _dequant_page(k, v, *scales, kv_major, q.dtype)
+                scores = jax.lax.dot_general(
+                    q, k, k_dims,
+                    preferred_element_type=jnp.float32) * scale
+                kvpos = p * bs + jax.lax.broadcasted_iota(
+                    jnp.int32, scores.shape, 2)
+                if has_alibi:
+                    scores = scores + slopes * kvpos.astype(jnp.float32)
+                valid = kvpos < length
+                if window is not None:
+                    valid = valid & (kvpos >= lo)
+                scores = jnp.where(valid, scores, _NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(scores, axis=2, keepdims=True))
+                pr = jnp.exp(scores - m_new)
+                pr = jnp.where(m_new > _NEG_INF / 2, pr, 0.0)
+                alpha = jnp.exp(m - m_new)
+                l = alpha * l + jnp.sum(pr, axis=2, keepdims=True)
+                pv = jax.lax.dot_general(pr.astype(v.dtype), v, v_dims,
+                                         preferred_element_type=jnp.float32)
+                return m_new, l, acc * alpha + pv
+
+            return jax.lax.fori_loop(0, jnp.minimum(P, n_pages - p0), page,
+                                     carry)
+
+        m0 = jnp.full((nkv, g, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((nkv, g, 1), jnp.float32)
+        acc0 = jnp.zeros((nkv, g, hd), jnp.float32)
+        _, l, acc = jax.lax.fori_loop(0, nblk, block, (m0, l0, acc0))
+        state[0] = (half0 + nblk) % 2
+        state[1] = has_next.astype(jnp.int32)
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 def pallas_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
                            alibi_slopes=None, window=None,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
-                           num_kv_splits: Optional[int] = None,
                            mesh=None, kv_major=False,
                            k_scale=None, v_scale=None):
     """Mesh-aware entry: with a ``tp`` axis the kv-head dim is sharded, and the
     kernel runs per-shard under shard_map (attention is independent per kv
     head, so TP needs no collective here — the reference shards its blocked
-    flash the same way, model_implementations/sharding/attn.py)."""
+    flash the same way, model_implementations/sharding/attn.py).  A shard's
+    heads of a page are still one contiguous slab of its local pool."""
     if (mesh is not None and mesh.shape.get("tp", 1) > 1
             and q.shape[1] % mesh.shape["tp"] == 0):
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
         inner = functools.partial(_pallas_paged_attention_local,
                                   scale=scale, window=window,
-                                  interpret=interpret,
-                                  num_kv_splits=num_kv_splits,
-                                  kv_major=kv_major)
+                                  interpret=interpret, kv_major=kv_major)
         kv_spec = P(None, "tp", None, None)
         in_specs = [kv_spec, kv_spec, kv_spec, P(None, None), P(None)]
         args = [q, k_pages, v_pages, block_table, kv_lens]
@@ -329,7 +374,6 @@ def pallas_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
                                          kv_lens, alibi_slopes=alibi_slopes,
                                          window=window, scale=scale,
                                          interpret=interpret,
-                                         num_kv_splits=num_kv_splits,
                                          kv_major=kv_major,
                                          k_scale=k_scale, v_scale=v_scale)
 
@@ -338,109 +382,75 @@ def _pallas_paged_attention_local(q, k_pages, v_pages, block_table, kv_lens, *,
                                   alibi_slopes=None, window=None,
                                   scale: Optional[float] = None,
                                   interpret: Optional[bool] = None,
-                                  num_kv_splits: Optional[int] = None,
                                   kv_major=False, k_scale=None, v_scale=None):
     S, nkv, g, hd = q.shape
-    if kv_major:
-        NB, _, _, bs = k_pages.shape
-    else:
-        NB, _, bs, _ = k_pages.shape
-    MB = block_table.shape[1]
     if scale is None:
         scale = hd ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    block_table = block_table.astype(jnp.int32)
-    kv_lens = kv_lens.astype(jnp.int32)
-    if num_kv_splits is None:
-        # DEFAULT 1: Pallas TPU executes grid dimensions sequentially on a
-        # core (and this DMA-loop kernel must not be megacore-partitioned),
-        # so extra splits do not parallelize on current single-core
-        # dispatch — they only pay partial-writeback + combine.  The knob
-        # exists for explicit experimentation (e.g. future megacore-safe
-        # variants or very small slot×head grids); measure before enabling.
-        num_kv_splits = 1
-    return _pallas_paged_attention_split(
-        q, k_pages, v_pages, block_table, kv_lens,
-        alibi_slopes=alibi_slopes, window=window, scale=float(scale),
-        interpret=interpret, num_kv_splits=int(num_kv_splits),
-        kv_major=kv_major, k_scale=k_scale, v_scale=v_scale)
+    if alibi_slopes is not None:
+        alibi_slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(
+            nkv, g, 1)
+    return _paged_decode_call(
+        q, k_pages, v_pages, block_table.astype(jnp.int32),
+        kv_lens.astype(jnp.int32), alibi_slopes, k_scale, v_scale,
+        window=int(window) if window is not None else None,
+        scale=float(scale), interpret=bool(interpret), kv_major=kv_major)
 
 
-def _pallas_paged_attention_split(q, k_pages, v_pages, block_table, kv_lens,
-                                  *, alibi_slopes, window, scale, interpret,
-                                  num_kv_splits: int, kv_major: bool,
-                                  k_scale=None, v_scale=None):
-    """Grid (S, nkv, splits) of unnormalized partials + logsumexp-weighted
-    XLA combine (flash-decoding shape).  Inputs arrive NORMALIZED (int32
-    tables, float scale) from _pallas_paged_attention_local — the only
-    caller."""
+@functools.partial(jax.jit, static_argnames=("window", "scale", "interpret",
+                                             "kv_major"))
+def _paged_decode_call(q, k_pages, v_pages, block_table, kv_lens,
+                       alibi_slopes, k_scale, v_scale, *, window, scale,
+                       interpret, kv_major):
+    """Grid (S,): the kernel normalises and writes [S, nkv, g, hd] in q's
+    dtype itself.  A jit of its own: a step program calls it once a layer
+    with the same shapes (the layer is a value, its first page in the
+    table), so the kernel is traced once a process and lowered once a
+    program, not once a layer; every program's lowering is paid in
+    ``setup_s`` before jax can look its compile cache up."""
     S, nkv, g, hd = q.shape
     bs = k_pages.shape[3] if kv_major else k_pages.shape[2]
-    NS = num_kv_splits
     quant = k_scale is not None
+    has_alibi = alibi_slopes is not None
+    P = _block_pages(nkv, bs, hd, k_pages.dtype, quant)
     kernel = functools.partial(
-        _split_kernel, bs=bs, scale=float(scale),
-        window=int(window) if window is not None else None,
-        has_alibi=alibi_slopes is not None, n_splits=NS, kv_major=kv_major,
-        quant=quant)
-    n_prefetch = 2
-    prefetch = [block_table, kv_lens]
-    if alibi_slopes is not None:
-        n_prefetch = 3
-        prefetch.append(jnp.asarray(alibi_slopes, jnp.float32).reshape(
-            nkv, g))
-    in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda s, h, sp, *_: (s, h, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    inputs = [q, k_pages, v_pages]
-    buf_shape = (2, hd, bs) if kv_major else (2, bs, hd)
-    scratch = [
-        pltpu.VMEM(buf_shape, k_pages.dtype),
-        pltpu.VMEM(buf_shape, v_pages.dtype),
-    ]
+        _decode_kernel, S=S, P=P, bs=bs, scale=scale, window=window,
+        has_alibi=has_alibi, kv_major=kv_major, quant=quant)
+    whole = pl.BlockSpec((1, nkv, g, hd), lambda s, *_: (s, 0, 0, 0))
+    in_specs, inputs = [whole], [q]
+    if has_alibi:
+        in_specs.append(pl.BlockSpec((nkv, g, 1), lambda s, *_: (0, 0, 0)))
+        inputs.append(alibi_slopes)
+    pools = [k_pages, v_pages]
     if quant:
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                     pl.BlockSpec(memory_space=pl.ANY)]
-        inputs += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-        scratch += [pltpu.VMEM((2, bs), jnp.float32),
-                    pltpu.VMEM((2, bs), jnp.float32)]
-    scratch.append(pltpu.SemaphoreType.DMA((8 if quant else 4,)))
-    acc, m, l = pl.pallas_call(
+        pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
+    inputs += pools
+    # both halves of the pipeline, P whole pages each
+    scratch = [pltpu.VMEM((2, P) + pool.shape[1:], pool.dtype)
+               for pool in pools]
+    held = sum(int(np.prod(buf.shape)) * buf.dtype.itemsize
+               for buf in scratch)
+    scratch += [pltpu.SemaphoreType.DMA((len(pools), 2, P)),
+                pltpu.SMEM((2,), jnp.int32)]
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_prefetch,
-            grid=(S, nkv, NS),
+            num_scalar_prefetch=2,
+            grid=(S,),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, 1, g, hd),
-                             lambda s, h, sp, *_: (s, h, sp, 0, 0)),
-                pl.BlockSpec((1, 1, 1, g),
-                             lambda s, h, sp, *_: (s, h, sp, 0)),
-                pl.BlockSpec((1, 1, 1, g),
-                             lambda s, h, sp, *_: (s, h, sp, 0)),
-            ],
+            out_specs=whole,
             scratch_shapes=scratch,
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((S, nkv, NS, g, hd), jnp.float32),
-            jax.ShapeDtypeStruct((S, nkv, NS, g), jnp.float32),
-            jax.ShapeDtypeStruct((S, nkv, NS, g), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((S, nkv, g, hd), q.dtype),
+        # sequential: a slot hands its successor a block already in flight
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=held + (16 << 20)),
         interpret=interpret,
         name="paged_decode",
-    )(*prefetch, *inputs)
-    # combine: o = Σ exp(m_s − m*) acc_s / Σ exp(m_s − m*) l_s
-    m_star = jnp.max(m, axis=2, keepdims=True)              # [S, nkv, 1, g]
-    w = jnp.exp(m - m_star)                                 # [S, nkv, NS, g]
-    num = jnp.sum(acc * w[..., None], axis=2)               # [S, nkv, g, hd]
-    den = jnp.sum(l * w, axis=2)                            # [S, nkv, g]
-    den = jnp.where(den == 0.0, 1.0, den)                   # inactive slots
-    return (num / den[..., None]).astype(q.dtype)
+    )(block_table, kv_lens, *inputs)
 
 
 def _dma_layout_ok(hd: int, bs: int, kv_major: bool,
@@ -504,8 +514,9 @@ def paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
 # including the rows just appended — lives in ``kv_lens[s]`` tokens across
 # the slot's block-table pages.  The XLA fallback gathers every slot's full
 # page span and runs one masked-dense attention (cost O(S · Q · MBmax·bs));
-# the Pallas kernel instead grids over (slot, kv head, q-chunk) and runs the
-# decode kernel's double-buffered HBM→VMEM DMA loop over ONLY the pages the
+# the Pallas kernel instead grids over (slot, kv head, q-chunk) and runs a
+# double-buffered HBM→VMEM DMA loop, one page of one head at a time (the loop
+# the decode kernel ran until its block pipeline), over ONLY the pages the
 # chunk can causally see — dead (slot, chunk) pairs are skipped outright, so
 # FLOPs and bandwidth scale with the live CHUNKS of ``cq`` rows (128 at the
 # serving sizes), not S × longest: a live chunk pays for all its ``cq`` rows

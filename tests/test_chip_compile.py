@@ -148,7 +148,8 @@ def _pool(nkv, hd, bs, kv_major, quant, nb=64):
     return page, sds((nb, nkv, bs), F32)
 
 
-def decode_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8):
+def decode_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8,
+                window=None):
     page, scale = _pool(nkv, hd, bs, kv_major, quant)
     specs = [sds((S, nkv, g, hd), BF16), page, page, sds((S, MB), I32),
              sds((S,), I32)]
@@ -158,12 +159,13 @@ def decode_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8):
     def fn(q, k, v, bt, lens, *sc):
         kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
         return pallas_paged_attention(q, k, v, bt, lens, interpret=False,
-                                         kv_major=kv_major, **kw)
+                                         kv_major=kv_major, window=window,
+                                         **kw)
     return chip_text(topo, fn, *specs)
 
 
 def prefill_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8,
-                 Q=128):
+                 Q=128, window=None):
     page, scale = _pool(nkv, hd, bs, kv_major, quant)
     specs = [sds((S, Q, nkv, g, hd), BF16), page, page, sds((S, MB), I32),
              sds((S,), I32), sds((S,), I32), sds((S,), I32)]
@@ -174,31 +176,42 @@ def prefill_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8,
         kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
         return pallas_ragged_prefill(q, k, v, bt, lens, st, ct,
                                         interpret=False, kv_major=kv_major,
-                                        **kw)
+                                        window=window, **kw)
     return chip_text(topo, fn, *specs)
 
 
 GPT2S = GPTConfig.gpt2_small()
 LLAMA128 = GPTConfig.llama(num_layers=1, hidden=4096, heads=32,
                            num_kv_heads=8)          # hd 128, nkv 8, g 4
+# Trinity-Large-Preview's attention (the serving cell's decode geometry):
+# nkv 8, g 6, hd 128, pages of 128, a window of 4,096 on its window layers
+TRINITY = GPTConfig.llama(num_layers=1, hidden=6144, heads=48,
+                          num_kv_heads=8, sliding_window=4096)
 
 
-def _engine_geometry(cfg, quant):
+def _engine_geometry(cfg, quant, block=64):
     """The page layout and size the v2 engine commits to for ``cfg`` when
-    the user asks for the default kv_block_size 64."""
+    the user asks for ``kv_block_size`` ``block`` (64 is the default), and
+    the window its layer 0 attends under."""
     return dict(nkv=cfg.kv_heads, g=cfg.num_heads // cfg.kv_heads,
                 hd=cfg.head_dim, kv_major=kv_major_layout(cfg),
-                bs=kv_block_size_for(cfg, 64, quant=quant), quant=quant)
+                bs=kv_block_size_for(cfg, block, quant=quant), quant=quant,
+                window=cfg.window_for_layer(0))
 
 
 @pytest.mark.parametrize("kernel", [decode_text, prefill_text],
                          ids=["decode", "prefill"])
-@pytest.mark.parametrize("cfg,quant", [
-    (LLAMA128, False), (GPT2S, False), (LLAMA128, True), (GPT2S, True)],
-    ids=["hd128-bf16", "gpt2s-bf16", "hd128-int8kv", "gpt2s-int8kv"])
-def test_paged_kernels_in_engine_geometry(topo, kernel, cfg, quant):
-    geo = _engine_geometry(cfg, quant)
+@pytest.mark.parametrize("cfg,quant,block", [
+    (LLAMA128, False, 64), (GPT2S, False, 64), (LLAMA128, True, 64),
+    (GPT2S, True, 64), (TRINITY, False, 128)],
+    ids=["hd128-bf16", "gpt2s-bf16", "hd128-int8kv", "gpt2s-int8kv",
+         "trinity-window"])
+def test_paged_kernels_in_engine_geometry(topo, kernel, cfg, quant, block):
+    geo = _engine_geometry(cfg, quant, block)
     assert _dma_layout_ok(geo["hd"], geo["bs"], geo["kv_major"], quant)
+    if cfg is TRINITY:
+        assert (geo["nkv"], geo["g"], geo["hd"], geo["bs"], geo["window"]) \
+            == (8, 6, 128, 128, 4096)
     assert KERNEL in kernel(topo, **geo)
 
 

@@ -534,37 +534,141 @@ class TestPagedAttention:
             jnp.asarray(lens), window=window, interpret=True)
         assert np.isfinite(np.asarray(got)).all()
 
-    def test_split_kv_matches_single_pass(self, rng):
-        """Flash-decoding split-KV (grid over KV splits + logsumexp combine)
-        must be token-exact vs the single-pass kernel AND the XLA path, for
-        every split count including splits > live pages."""
-        from deepspeed_tpu.ops.paged_attention import (pallas_paged_attention,
-                                                       xla_paged_attention)
-        q, k, v, bt, lens = (jnp.asarray(a) for a in self._rand_case(
-            rng, S=4, MB=8, NB=40))
-        lens = jnp.asarray([0, 5, 17, 64], jnp.int32)
-        want = xla_paged_attention(q, k, v, bt, lens)
-        for ns in (1, 2, 3, 8, 16):
-            got = pallas_paged_attention(q, k, v, bt, lens,
-                                         num_kv_splits=ns, interpret=True)
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       atol=1e-5, err_msg=f"splits={ns}")
+    # ---- the block pipeline (PR 32): P pages, every kv head, an iteration
 
-    def test_split_kv_with_alibi_and_window(self, rng):
+    VARIANTS = ["plain", "kv-major", "int8", "alibi"]
+    BS = 8                              # tokens a page of the block cases
+
+    def _block_case(self, rng, variant, S, MB, layers=1, layer=0):
+        """A pool of ``layers`` x (S * MB) pages of ``BS`` tokens, a table of
+        distinct out-of-order pages in ``layer`` -> (q, k, v, bt, kw, blk)
+        in ``variant``'s layout; ``blk`` is the tokens of a block of P pages,
+        P as the kernel derives it."""
+        from deepspeed_tpu.inference.v2.model import quantize_kv_token
+        from deepspeed_tpu.ops.paged_attention import _block_pages
+        nkv, g, hd, bs = 2, 3, 16, self.BS
+        NB = S * MB
+        q = jnp.asarray(rng.standard_normal((S, nkv, g, hd)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((layers * NB, nkv, bs, hd)),
+                        jnp.float32)
+        v = jnp.asarray(rng.standard_normal((layers * NB, nkv, bs, hd)),
+                        jnp.float32)
+        bt = jnp.asarray(rng.permutation(NB).reshape(S, MB) + layer * NB,
+                         jnp.int32)
+        kw = {}
+        if variant == "int8":
+            (k, ks), (v, vs) = quantize_kv_token(k), quantize_kv_token(v)
+            kw.update(k_scale=ks, v_scale=vs)
+        if variant == "kv-major":
+            k, v = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
+            kw["kv_major"] = True
+        if variant == "alibi":
+            kw["alibi_slopes"] = jnp.asarray(
+                np.geomspace(0.5, 1 / 256, nkv * g), jnp.float32)
+        P = _block_pages(nkv, bs, hd, k.dtype, quant=variant == "int8")
+        assert P > 1, "the cases below need a block of several pages"
+        return q, k, v, bt, kw, P * bs
+
+    @classmethod
+    def _poison_dead_pages(cls, k, v, kw, bt, lens, window):
+        """NaN in every page outside a slot's [window's first page, pages of
+        kv_len): the table's other entries and the pool's other pages.  int8
+        codes cannot hold a NaN; their scale rows can."""
+        bs = cls.BS
+        live = np.zeros(k.shape[0], bool)
+        for s, n in enumerate(np.asarray(lens)):
+            first = 0 if window is None else max(int(n) - window, 0) // bs
+            live[np.asarray(bt)[s, first:-(-int(n) // bs)]] = True
+        dead = jnp.asarray(~live)
+
+        def nan(a):
+            return jnp.where(dead.reshape((-1,) + (1,) * (a.ndim - 1)),
+                             jnp.nan, a)
+        if "k_scale" in kw:
+            return k, v, dict(kw, k_scale=nan(kw["k_scale"]),
+                              v_scale=nan(kw["v_scale"]))
+        return nan(k), nan(v), kw
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_block_boundary_contexts(self, rng, variant):
+        """Contexts of 0, 1, one short of a block, a block, one past it and
+        several blocks, none of whose dead pages is read."""
         from deepspeed_tpu.ops.paged_attention import (pallas_paged_attention,
                                                        xla_paged_attention)
-        q, k, v, bt, lens = (jnp.asarray(a) for a in self._rand_case(
-            rng, S=2, MB=8, NB=24))
-        lens = jnp.asarray([40, 64], jnp.int32)
-        nkv, g = q.shape[1], q.shape[2]
-        slopes = jnp.asarray(np.geomspace(0.5, 1 / 64, nkv * g), jnp.float32)
-        for kw in ({"window": 20}, {"alibi_slopes": slopes},
-                   {"alibi_slopes": slopes, "window": 11}):
+        S, MB = 8, 28
+        q, k, v, bt, kw, blk = self._block_case(rng, variant, S, MB)
+        lens = jnp.asarray([0, 1, blk - 1, blk, blk + 1, 2 * blk,
+                            2 * blk + self.BS + 3, 3 * blk + 5], jnp.int32)
+        assert int(lens.max()) <= MB * self.BS
+        want = xla_paged_attention(q, k, v, bt, lens, **kw)
+        k, v, kw = self._poison_dead_pages(k, v, kw, bt, lens, None)
+        got = pallas_paged_attention(q, k, v, bt, lens, interpret=True, **kw)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5)
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_block_window_starts(self, rng, variant):
+        """A window whose first page is a block's first page in the table,
+        one that starts blocks in, mid-page and on a page's first row; the
+        pages before it are dead and poisoned like those past kv_len."""
+        from deepspeed_tpu.ops.paged_attention import (pallas_paged_attention,
+                                                       xla_paged_attention)
+        S, MB = 6, 30
+        q, k, v, bt, kw, blk = self._block_case(rng, variant, S, MB)
+        window = blk + self.BS + 5           # a block, a page and five keys
+        lens = jnp.asarray(
+            [0, window - 2,                  # nothing is outside the window
+             window + blk,                   # first key on page P's first row
+             window + blk + 3,               # ... three rows into that page
+             window + 2 * self.BS + 1,       # starts two pages into block 0
+             3 * blk + 7], jnp.int32)
+        assert int(lens.max()) <= MB * self.BS
+        want = xla_paged_attention(q, k, v, bt, lens, window=window, **kw)
+        k, v, kw = self._poison_dead_pages(k, v, kw, bt, lens, window)
+        got = pallas_paged_attention(q, k, v, bt, lens, window=window,
+                                     interpret=True, **kw)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5)
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_block_hand_over_between_slots(self, rng, variant):
+        """A slot starts its successor's first block before its own last
+        dots: empty slots between two live ones, a live last slot, a live
+        slot after a run of empty ones, and a call with no live slot."""
+        from deepspeed_tpu.ops.paged_attention import (pallas_paged_attention,
+                                                       xla_paged_attention)
+        S, MB = 8, 20
+        q, k, v, bt, kw, blk = self._block_case(rng, variant, S, MB)
+        for lens in ([blk + 9, 0, 0, 3, 2 * blk, 0, 0, 11],
+                     [0, 0, 0, 0, 0, blk, 0, 0],
+                     [0] * S):
+            lens = jnp.asarray(lens, jnp.int32)
             want = xla_paged_attention(q, k, v, bt, lens, **kw)
-            got = pallas_paged_attention(q, k, v, bt, lens, num_kv_splits=4,
-                                         interpret=True, **kw)
+            got = pallas_paged_attention(q, k, v, bt, lens, interpret=True,
+                                         **kw)
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       atol=1e-5, err_msg=str(kw))
+                                       atol=1e-5, err_msg=str(lens))
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_block_flat_pool_base(self, rng, variant):
+        """The flat pool of three layers with the middle layer's first page
+        added to the table, at contexts of several blocks."""
+        from deepspeed_tpu.ops.paged_attention import pallas_paged_attention
+        S, MB, L, LI = 4, 20, 3, 1
+        q, k, v, bt, kw, blk = self._block_case(rng, variant, S, MB,
+                                                layers=L, layer=LI)
+        lens = jnp.asarray([0, blk - 3, blk + 1, 2 * blk + 4], jnp.int32)
+        NB = S * MB
+        own = {n: a[LI * NB:(LI + 1) * NB] if n.endswith("scale") else a
+               for n, a in kw.items()}
+        want = pallas_paged_attention(
+            q, k[LI * NB:(LI + 1) * NB], v[LI * NB:(LI + 1) * NB],
+            bt - LI * NB, lens, window=blk + 2, interpret=True, **own)
+        got = pallas_paged_attention(q, k, v, bt, lens, window=blk + 2,
+                                     interpret=True, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_kernel_alibi_window_combined(self, rng):
         from deepspeed_tpu.ops.paged_attention import (pallas_paged_attention,
