@@ -257,6 +257,161 @@ def afmoe_config(hf: Dict[str, Any], *, max_seq_len: Optional[int] = None,
         dtype=dtype or jnp.bfloat16)
 
 
+# deepseek_v3 with latent attention and no query latent (Moonlight):
+# published tensor name -> path in the GPT parameter tree, as above: the names
+# the loader (``_deepseek_v3_tree``) reads, held to it name by name in
+# tests/test_moonlight.py.  The published weights pair
+# NEIGHBOURING rotary columns, this model rotates halves: the 64 rope columns
+# of each ``q_proj`` head and of ``kv_a_proj_with_mqa`` are permuted on the
+# way in (``_rope_interleave_perm``).
+DEEPSEEK_V3_WEIGHT_NAMES = {
+    "model.embed_tokens.weight": "backbone/wte",
+    "model.norm.weight": "backbone/final_norm/scale",
+    "lm_head.weight": "lm_head",
+    "model.layers.{i}.input_layernorm.weight": "backbone/block_{i}/Norm_0/scale",
+    "model.layers.{i}.post_attention_layernorm.weight":
+        "backbone/block_{i}/Norm_1/scale",
+    "model.layers.{i}.self_attn.q_proj.weight": "backbone/block_{i}/Attention_0/wq",
+    "model.layers.{i}.self_attn.kv_a_proj_with_mqa.weight":
+        "backbone/block_{i}/Attention_0/wkv_a",
+    "model.layers.{i}.self_attn.kv_a_layernorm.weight":
+        "backbone/block_{i}/Attention_0/kv_norm",
+    "model.layers.{i}.self_attn.kv_b_proj.weight":
+        "backbone/block_{i}/Attention_0/wkv_b",
+    "model.layers.{i}.self_attn.o_proj.weight": "backbone/block_{i}/Attention_0/wo",
+    # dense layers (the first first_k_dense_replace)
+    "model.layers.{i}.mlp.gate_proj.weight": "backbone/block_{i}/MLP_0/wg",
+    "model.layers.{i}.mlp.up_proj.weight": "backbone/block_{i}/MLP_0/wi",
+    "model.layers.{i}.mlp.down_proj.weight": "backbone/block_{i}/MLP_0/wo",
+    # expert layers
+    "model.layers.{i}.mlp.gate.weight": "backbone/block_{i}/moe/gate",
+    "model.layers.{i}.mlp.gate.e_score_correction_bias":
+        "backbone/block_{i}/moe/expert_bias",
+    "model.layers.{i}.mlp.experts.{e}.gate_proj.weight": "backbone/block_{i}/moe/wge",
+    "model.layers.{i}.mlp.experts.{e}.up_proj.weight": "backbone/block_{i}/moe/wi",
+    "model.layers.{i}.mlp.experts.{e}.down_proj.weight": "backbone/block_{i}/moe/wo",
+    "model.layers.{i}.mlp.shared_experts.gate_proj.weight":
+        "backbone/block_{i}/moe/shared_wg",
+    "model.layers.{i}.mlp.shared_experts.up_proj.weight":
+        "backbone/block_{i}/moe/shared_wi",
+    "model.layers.{i}.mlp.shared_experts.down_proj.weight":
+        "backbone/block_{i}/moe/shared_wo",
+}
+
+
+def deepseek_v3_config(hf: Dict[str, Any], *,
+                       max_seq_len: Optional[int] = None, dtype=None):
+    """GPTConfig of a published ``deepseek_v3`` ``config.json`` of the shape
+    Moonlight has: latent attention (keys and values from one normed latent
+    of ``kv_lora_rank`` beside one rotated key part shared by all heads,
+    queries straight from the hidden state), then ``first_k_dense_replace``
+    dense layers and sigmoid-routed experts with a selection bias beside
+    shared experts (one SwiGLU of ``n_shared_experts`` widths)."""
+    from deepspeed_tpu.models.gpt import GPTConfig
+    for key, ok, what in (
+            ("q_lora_rank", hf.get("q_lora_rank") is None,
+             "a query latent (q_a_proj / q_b_proj)"),
+            ("n_group", hf.get("n_group", 1) == 1
+             and hf.get("topk_group", 1) == 1, "group-limited routing"),
+            ("rope_scaling", not hf.get("rope_scaling"),
+             "rope scaling (yarn and its mscale)"),
+            ("num_nextn_predict_layers",
+             not hf.get("num_nextn_predict_layers", 0),
+             "multi-token prediction layers"),
+            ("scoring_func", hf.get("scoring_func", "sigmoid") == "sigmoid",
+             "softmax scores"),
+            ("moe_layer_freq", hf.get("moe_layer_freq", 1) == 1,
+             "dense layers among the expert layers"),
+            ("attention_bias", not hf.get("attention_bias", False),
+             "attention biases")):
+        if not ok:
+            raise NotImplementedError(
+                f"deepseek_v3: {key}={hf.get(key)!r}: {what} is not built")
+    nope, rot = hf["qk_nope_head_dim"], hf["qk_rope_head_dim"]
+    msl = hf.get("max_position_embeddings", 2048)
+    return GPTConfig(
+        vocab_size=hf["vocab_size"], num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"], head_dim=nope + rot,
+        hidden_size=hf["hidden_size"],
+        mlp_dim_override=hf["intermediate_size"],
+        max_seq_len=min(msl, max_seq_len or msl),
+        use_rope=True, rope_theta=float(hf.get("rope_theta", 10000.0)),
+        use_rmsnorm=True, norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        gated_mlp=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        kv_lora_rank=hf["kv_lora_rank"], qk_rope_head_dim=rot,
+        v_head_dim=hf["v_head_dim"],
+        num_experts=hf["n_routed_experts"], moe_k=hf["num_experts_per_tok"],
+        moe_dropless=True, moe_router="sigmoid",
+        moe_route_norm=bool(hf.get("norm_topk_prob", True)),
+        moe_route_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_router_bias=True,
+        moe_shared_dim=hf["moe_intermediate_size"]
+        * hf.get("n_shared_experts", 0),
+        moe_expert_dim=hf["moe_intermediate_size"],
+        moe_dense_layers=hf.get("first_k_dense_replace", 0),
+        dtype=dtype or jnp.bfloat16)
+
+
+def _deepseek_v3_tree(r, cfg) -> Dict[str, Any]:
+    """deepseek_v3 (latent attention) -> flax tree, by
+    ``DEEPSEEK_V3_WEIGHT_NAMES``; ``r`` has ``get(name)`` (a
+    ``_ShardReader``, or any mapping of published names to arrays)."""
+    from deepspeed_tpu.models.gpt import mla_split
+    H, nh, hd = cfg.hidden_size, cfg.num_heads, cfg.head_dim
+    nope, rot, vd = mla_split(cfg)
+    rank = cfg.kv_lora_rank
+    pairs = _rope_interleave_perm(rot, rot)    # the rope columns come last
+    q_perm = np.concatenate([np.arange(nope), nope + pairs])
+    kv_perm = np.concatenate([np.arange(rank), rank + pairs])
+
+    def lin(name):                       # torch Linear: [out, in]
+        return np.asarray(r.get(name)).T
+
+    bb: Dict[str, Any] = {
+        "wte": np.asarray(r.get("model.embed_tokens.weight")),
+        "final_norm": {"scale": np.asarray(r.get("model.norm.weight"))}}
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        a = p + "self_attn."
+        blk = {
+            "Norm_0": {"scale": np.asarray(
+                r.get(p + "input_layernorm.weight"))},
+            "Norm_1": {"scale": np.asarray(
+                r.get(p + "post_attention_layernorm.weight"))},
+            "Attention_0": {
+                "wq": lin(a + "q_proj.weight").reshape(H, nh, hd)[
+                    :, :, q_perm],
+                "wkv_a": lin(a + "kv_a_proj_with_mqa.weight")[:, kv_perm],
+                "kv_norm": np.asarray(r.get(a + "kv_a_layernorm.weight")),
+                "wkv_b": lin(a + "kv_b_proj.weight").reshape(
+                    rank, nh, nope + vd),
+                "wo": lin(a + "o_proj.weight").reshape(nh, vd, H)}}
+        m = p + "mlp."
+        if cfg.is_moe_layer(i):
+            def stack(what):
+                return np.stack([lin(f"{m}experts.{e}.{what}.weight")
+                                 for e in range(cfg.num_experts)])
+            blk["moe"] = {
+                "gate": lin(m + "gate.weight"),
+                "expert_bias": np.asarray(
+                    r.get(m + "gate.e_score_correction_bias")),
+                "wge": stack("gate_proj"), "wi": stack("up_proj"),
+                "wo": stack("down_proj"),
+                "shared_wg": lin(m + "shared_experts.gate_proj.weight"),
+                "shared_wi": lin(m + "shared_experts.up_proj.weight"),
+                "shared_wo": lin(m + "shared_experts.down_proj.weight")}
+        else:
+            blk["MLP_0"] = {"wg": lin(m + "gate_proj.weight"),
+                            "wi": lin(m + "up_proj.weight"),
+                            "wo": lin(m + "down_proj.weight")}
+        bb[f"block_{i}"] = blk
+    tree: Dict[str, Any] = {"backbone": bb}
+    if not cfg.tie_embeddings:
+        tree["lm_head"] = lin("lm_head.weight")
+    return tree
+
+
 def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
                    dtype=None):
     """Build a GPTConfig from ``<model_path>/config.json``.
@@ -269,6 +424,8 @@ def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
     hf = _read_json(os.path.join(model_path, "config.json"))
     if hf.get("model_type") == "afmoe":
         return afmoe_config(hf, max_seq_len=max_seq_len, dtype=dtype)
+    if hf.get("model_type") == "deepseek_v3":
+        return deepseek_v3_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     arch = _arch_of(hf)
 
     if arch in _LLAMA_LIKE:
@@ -1604,6 +1761,8 @@ def load_hf_checkpoint(model_path: str, *, max_seq_len: Optional[int] = None,
     ``dtype`` sets the config's COMPUTE dtype only.
     """
     cfg = config_from_hf(model_path, max_seq_len=max_seq_len, dtype=dtype)
+    if cfg.mla:
+        return cfg, _deepseek_v3_tree(_ShardReader(model_path), cfg)
     if cfg.moe_router == "sigmoid":
         raise NotImplementedError(
             "afmoe checkpoints: the config maps (afmoe_config) and "

@@ -459,7 +459,8 @@ class InferenceEngineV2:
         # attention — said out loud at start-up, never discovered later
         self.paged_impl = "xla"
         if kernels and (model_cfg.attn_impl == "pallas" or _dma_layout_ok(
-                model_cfg.head_dim, eff_bs, kv_major_layout(model_cfg),
+                model_cfg.latent_page_dim if model_cfg.mla
+                else model_cfg.head_dim, eff_bs, kv_major_layout(model_cfg),
                 quant=sm.kv_quant is not None)):
             self.paged_impl = "pallas"
         elif kernels and sm.kv_quant is not None:
@@ -489,6 +490,27 @@ class InferenceEngineV2:
         # statics of every step program; {} for a plain model, whose
         # programs are then traced exactly as before
         self._model_static: Dict[str, Any] = {}
+        if model_cfg.mla:
+            # latent attention: one pool of latent rows (model.py
+            # PagedKVCache), read absorbed.  What is not built beside it:
+            for what, why in (
+                    (sm.kv_quant, "kv_quant: an int8 latent row and its "
+                     "scale are not built"),
+                    (self.mesh is not None, "a tp mesh: every head reads "
+                     "the one latent row, which has no head dim to shard"),
+                    (draft_model is not None, "speculative decoding: the "
+                     "verify core and a draft pool are not built over "
+                     "latent pages"),
+                    (self.config.adapters.enabled, "LoRA adapter pages: "
+                     "their q/v deltas have no latent form"),
+                    (sm.prefix_cache, "the prefix cache: not tested over "
+                     "latent pages"),
+                    (self.kv_window, "window and global layers: two page "
+                     "groups")):
+                if what:
+                    raise NotImplementedError(
+                        f"latent attention (kv_lora_rank) keeps a latent "
+                        f"page pool, which is not built with {why}")
         if self.kv_window:
             for what, why in (     # (prefix_cache: DSStateManager refuses)
                     (sm.kv_quant, "kv_quant: the scale pools are not "
@@ -627,6 +649,7 @@ class InferenceEngineV2:
         self._serve_ctx: Optional[Dict[str, Any]] = None
         self.heartbeat_fn = None
         self._block_size = eff_bs
+        self.telemetry.set_kv_bytes_per_token(self.kv_bytes_per_token())
         # ---- multi-tenant LoRA adapter pool (serving/adapters.py): A/B
         # pages live as block-granular refcounted residents of the SAME
         # allocator as the KV blocks, so adapters and KV contend under one
@@ -655,7 +678,8 @@ class InferenceEngineV2:
             self.state.adapters = self.adapters
         n_params = sum(int(np.prod(l.shape))
                        for l in jax.tree_util.tree_leaves(self.params))
-        kv_layout = "kv-major" if kv_major_layout(model_cfg) else "standard"
+        kv_layout = ("latent" if model_cfg.mla else "kv-major"
+                     if kv_major_layout(model_cfg) else "standard")
         log_dist(f"v2 ragged engine ready: params={n_params/1e6:.1f}M "
                  f"budget={sm.max_ragged_batch_size}tok "
                  f"slots={sm.max_tracked_sequences} "
@@ -965,23 +989,29 @@ class InferenceEngineV2:
         query-key pairs its attention has to score, ``qk_pairs`` on a global
         layer (row ``i`` of a chunk at context ``c`` sees ``c + i + 1``
         keys) and ``qk_pairs_window`` on a window layer (at most the
-        window)."""
+        window), and how many of its slots hold one row and their contexts
+        (``one_row_slots``, ``ctx_tokens_one_row``)."""
         contexts = np.asarray(contexts, np.int64)
         note = {"ctx_tokens": int(contexts.sum())}
+        if new is not None:
+            # sum over i < q of (c + 1 + i); the one-row slots' part of it
+            # (they go to the paged decode kernel) is their contexts + count
+            q = np.asarray(new, np.int64)
+            note.update(
+                qk_pairs=int((q * contexts + q * (q + 1) // 2).sum()),
+                one_row_slots=int((q == 1).sum()),
+                ctx_tokens_one_row=int(contexts[q == 1].sum()))
         win = self.model_config.sliding_window
         if not win:
             return note
         note["ctx_tokens_window"] = int(np.minimum(contexts, win).sum())
         if new is not None:
-            q = np.asarray(new, np.int64)
-            # sum over i < q of (c + 1 + i), and of min(c + 1 + i, win):
-            # the first `rising` rows still see fewer keys than the window
-            pairs = q * contexts + q * (q + 1) // 2
+            # ... and of min(c + 1 + i, win): the first `rising` rows still
+            # see fewer keys than the window
             rising = np.clip(win - contexts - 1, 0, q)
-            pairs_w = (rising * contexts + rising * (rising + 1) // 2
-                       + (q - rising) * win)
-            note.update(qk_pairs=int(pairs.sum()),
-                        qk_pairs_window=int(pairs_w.sum()))
+            note["qk_pairs_window"] = int(
+                (rising * contexts + rising * (rising + 1) // 2
+                 + (q - rising) * win).sum())
         return note
 
     def _run_spec(self, reqs, outer: int, gamma: int, gen, prev, rng):
@@ -1008,7 +1038,8 @@ class InferenceEngineV2:
                                    top_k=gen.top_k, mesh=self.mesh),
                     donate_argnums=(2, 3))
             with stel.span("spec_dispatch", steps=outer, gamma=gamma,
-                           seqs=len(reqs), ctx_tokens=ctx_tokens):
+                           seqs=len(reqs), ctx_tokens=ctx_tokens,
+                           kv_bytes_per_token=stel.kv_bytes_per_token):
                 toks, counts, prev, rng, self.cache, self.draft_cache = \
                     self._steps[key](self.params, self.draft_params,
                                      self.cache, self.draft_cache, batch,
@@ -1027,7 +1058,8 @@ class InferenceEngineV2:
                                    mesh=self.mesh),
                     donate_argnums=(2, 3))
             with stel.span("spec_dispatch", steps=outer, gamma=gamma,
-                           seqs=len(reqs), ctx_tokens=ctx_tokens):
+                           seqs=len(reqs), ctx_tokens=ctx_tokens,
+                           kv_bytes_per_token=stel.kv_bytes_per_token):
                 toks, counts, prev, self.cache, self.draft_cache = \
                     self._steps[key](self.params, self.draft_params,
                                      self.cache, self.draft_cache, batch,
@@ -1298,14 +1330,28 @@ class InferenceEngineV2:
         handoff copy path accounts ``kv_handoff_bytes_total`` in.  An
         approximation by design: kv-quant stores int8 codes + scales, but
         the accounting models the FUTURE wire transfer, not today's
-        resident bytes."""
+        resident bytes.  A latent pool's block is its real bytes: one padded
+        row a token a layer."""
         mc = self.model_config
         try:
             itemsize = int(np.dtype(self.config.jnp_dtype).itemsize)
         except TypeError:       # bfloat16 without a numpy extension
             itemsize = 2
-        return int(2 * mc.num_layers * mc.kv_heads * self._block_size
-                   * mc.head_dim * itemsize)
+        row = (mc.latent_page_dim if mc.mla
+               else 2 * mc.kv_heads * mc.head_dim)
+        return int(mc.num_layers * self._block_size * row * itemsize)
+
+    def kv_bytes_per_token(self) -> int:
+        """Device bytes the pool stores for one cached token over all layers
+        (the dispatch spans' ``kv_bytes_per_token``), pad columns of a
+        latent row and int8 scales included: the pool's bytes over its
+        tokens, or with two page groups, whose layers hold different page
+        counts, a block's bytes over its tokens."""
+        if self.kv_window:
+            return self.kv_block_bytes() // self._block_size
+        pool = sum(a.size * a.dtype.itemsize for a in self.cache
+                   if a is not None)
+        return int(pool // (self.cache.k.shape[1] * self._block_size))
 
     # ------------------------------- continuous batching (Dynamic SplitFuse)
     def _stream_fence(self, value) -> None:

@@ -54,8 +54,10 @@ def kv_major_layout(cfg: GPTConfig) -> bool:
     128-multiples get the transposed layout so the TOKEN axis (a
     framework-controlled knob — the engine sizes pages to 128) carries the
     lanes instead.  Pure function of the model config, so every component
-    (cache alloc, scatter, kernels, fallbacks) derives the same answer."""
-    return cfg.head_dim % 128 != 0
+    (cache alloc, scatter, kernels, fallbacks) derives the same answer.
+    A latent page is row-major: its row is padded to whole lane tiles
+    instead (``cfg.latent_page_dim``)."""
+    return cfg.head_dim % 128 != 0 and not cfg.mla
 
 
 def kv_block_size_for(cfg: GPTConfig, requested: int,
@@ -91,10 +93,20 @@ class PagedKVCache(NamedTuple):
     ``k_scale``/``v_scale`` hold the per-(page, head, token) fp32 scales,
     [L, num_blocks, nkv, block_size] — amax-over-head-dim granularity, the
     standard KV-quant recipe.  Halves KV HBM (the decode bandwidth bound)
-    and doubles cache capacity for ~6% scale overhead."""
+    and doubles cache capacity for ~6% scale overhead.
+
+    Latent pages (``cfg.mla``): ONE pool, ``k`` [L, num_blocks, 1,
+    block_size, cfg.latent_page_dim], and ``v`` None.  A token's row is its
+    normed latent, then its rotated key part, then zeros up to whole lane
+    tiles (512 + 64 -> 640): 1,280 B in bf16 where the mathematics needs
+    1,152.  One row kind in one row-major page rather than the latent and a
+    kv-major key part in two pools: one copy a page in the kernels, one
+    scatter a step, and the page is key and value as it lies; the price is
+    the 64 pad columns, a ninth more bytes to hold and to read.  The
+    attention ops take it as ``v_pages=None`` with ``v_dim``."""
 
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
 
@@ -105,6 +117,12 @@ class PagedKVCache(NamedTuple):
     @classmethod
     def create(cls, cfg: GPTConfig, num_blocks: int, block_size: int, dtype,
                quant: Optional[str] = None):
+        if cfg.mla:
+            if quant is not None:
+                raise NotImplementedError(
+                    "kv_quant over latent pages (kv_lora_rank) is not built")
+            return cls(k=jnp.zeros((cfg.num_layers, num_blocks, 1, block_size,
+                                    cfg.latent_page_dim), dtype), v=None)
         if kv_major_layout(cfg):
             shape = (cfg.num_layers, num_blocks, cfg.kv_heads, cfg.head_dim,
                      block_size)
@@ -391,8 +409,11 @@ def _kv_write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, km,
     folds the sharded head dim into the scattered one, and every chip
     all-gathers the whole pool each step."""
     quant = flat_ks is not None
-    pools = (flat_k, flat_v) + ((flat_ks, flat_vs) if quant else ())
     write = functools.partial(_kv_write_local, base=base, km=km)
+    if flat_v is None:                 # latent pages: one pool, one row kind
+        with jax.named_scope("kv_write"):
+            return write((flat_k,), k, None, plan) + (None, None, None)
+    pools = (flat_k, flat_v) + ((flat_ks, flat_vs) if quant else ())
     if (mesh is not None and mesh.shape.get("tp", 1) > 1
             and k.shape[1] % mesh.shape["tp"] == 0):
         from jax import shard_map
@@ -414,7 +435,7 @@ def _kv_write_local(pools, k, v, plan, *, base, km):
     """``_kv_write`` on the kv heads at hand: (k, v[, k_scale, v_scale])
     pools in, the same out."""
     big = jnp.iinfo(jnp.int32).max
-    new = (k, v)
+    new = (k,) if v is None else (k, v)
     if len(pools) == 4:
         k, ks = quantize_kv_token(k)                  # [N,nkv,hd], [N,nkv]
         v, vs = quantize_kv_token(v)
@@ -613,6 +634,67 @@ def _qk_norm_gate(ap, h, q, k, cfg, mesh=None):
     return q, k, gate
 
 
+def _attn_geometry(cfg: GPTConfig):
+    """How the attention ops see a layer's heads: (kv heads, query/key
+    width, value width, the ops' latent keyword).  Latent attention is
+    absorbed into MQA form: every query head in one group over the one
+    latent row, which is key (its whole padded width) and value (the
+    latent)."""
+    if cfg.mla:
+        return (1, cfg.latent_page_dim, cfg.kv_lora_rank,
+                {"v_dim": cfg.kv_lora_rank})
+    return cfg.kv_heads, cfg.head_dim, cfg.head_dim, {}
+
+
+def _attn_scale(cfg: GPTConfig):
+    """The ops' ``scale``: the configured one, None for their default
+    ``width ** -0.5``; a latent head's is said out loud, since the width the
+    ops see is the padded page row's and not the head's."""
+    if cfg.mla and cfg.attn_scale is None:
+        return cfg.head_dim ** -0.5
+    return cfg.attn_scale
+
+
+def _mla_qkv(ap, h, positions, cfg: GPTConfig):
+    """Latent attention's side of ``attn_qkv`` on rows ``h [N, H]`` at
+    ``positions [N]``: the queries ABSORBED (``q_nope_h Wkvb_h[:, :nope]^T``,
+    the latent's width, beside the rotated ``q_pe_h``) and the token's cache
+    row ``[c_kv | k_pe]`` (normed latent, rotated shared key part), both
+    padded with zeros to the page row's width, ``[N, nh, P]`` and
+    ``[N, 1, P]``.  The score ``q_lat . c + q_pe . k_pe`` is then the
+    published ``q_nope . k_nope + q_pe . k_pe`` by associativity, and the
+    row is key and value at once.  The absorb product has its own scope,
+    ``mla_absorb``."""
+    from deepspeed_tpu.models.gpt import mla_latent, mla_query, mla_split
+    dtype = h.dtype
+    nope = mla_split(cfg)[0]
+    q_nope, q_pe = mla_query(_w(ap["wq"], dtype), h, positions, cfg)
+    c_kv, k_pe = mla_latent(_w(ap["wkv_a"], dtype), ap["kv_norm"], h,
+                            positions, cfg)
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum("tnd,rnd->tnr", q_nope,
+                           _w(ap["wkv_b"], dtype)[..., :nope])
+    pad = cfg.latent_page_dim - cfg.latent_dim
+
+    def row(*parts):
+        z = jnp.zeros(parts[0].shape[:-1] + (pad,), dtype)
+        return jnp.concatenate(parts + ((z,) if pad else ()), -1)
+    return row(q_lat, q_pe), row(c_kv, k_pe)[:, None, :]
+
+
+def _attn_proj(ap, o, gate, cfg, mesh=None):
+    """Everything of ``attn_out`` before the sandwich norm: the gate, and
+    for latent attention the second absorb product, ``o_lat_h Wkvb_h[:,
+    nope:]`` (scope ``mla_absorb``): the attention ops returned ``s_h c``,
+    the latent's width a head."""
+    if cfg.mla:
+        from deepspeed_tpu.models.gpt import mla_split
+        with jax.named_scope("mla_absorb"):
+            o = jnp.einsum("...nr,rnd->...nd", o, _w(ap["wkv_b"], o.dtype)[
+                ..., mla_split(cfg)[0]:])
+    return _attn_out(ap, o if gate is None else o * gate, cfg, mesh=mesh)
+
+
 def _attn_out(ap, o, cfg, mesh=None):
     """Attention output projection ``o [..., k, d] @ wo [k, d, H]``.  The
     heads dim shards under TP (row-parallel: contraction sharded), so a
@@ -666,7 +748,8 @@ def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
     0) and skips them outright."""
     from deepspeed_tpu import ops
     S = table.shape[0]
-    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    nh = cfg.num_heads
+    nkv, hd, vd, latent = _attn_geometry(cfg)
     with jax.named_scope("attn_kernel"):
         valid = rows.scat_slot < S
         slot = jnp.where(valid, rows.scat_slot, 0)
@@ -676,9 +759,10 @@ def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
         if cfg.use_alibi:
             from deepspeed_tpu.models.gpt import alibi_slopes
             slopes = jnp.asarray(alibi_slopes(nh, hd, cfg.alibi_prescale))
-        pool = dict(alibi_slopes=slopes, window=window, scale=cfg.attn_scale,
+        pool = dict(alibi_slopes=slopes, window=window,
+                    scale=_attn_scale(cfg),
                     mesh=mesh, kv_major=kv_major_layout(cfg),
-                    impl=cfg.attn_impl, **scales)
+                    impl=cfg.attn_impl, **scales, **latent)
         one_row = rows.q_counts == 1
         o_one = ops.paged_attention(
             q_dense[:, 0].reshape(S, nkv, nh // nkv, hd).astype(cfg.dtype),
@@ -691,8 +775,8 @@ def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
             rows.kv_len - rows.q_counts,
             jnp.where(one_row, 0, rows.q_counts), **pool)
         o = jnp.where(one_row[slot, None, None],
-                      o_one.reshape(S, nh, hd)[slot],
-                      o_dense.reshape(S, Q, nh, hd)[slot, rows.dense_idx])
+                      o_one.reshape(S, nh, vd)[slot],
+                      o_dense.reshape(S, Q, nh, vd)[slot, rows.dense_idx])
         return jnp.where(valid[:, None, None], o, 0)
 
 
@@ -803,11 +887,14 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
         ap, np_ = blk["Attention_0"], blk["Norm_0"]
         with jax.named_scope("attn_qkv"):
             h = _norm(np_, x, cfg)
-            q, k, v = _qkv(ap, h, cfg, mesh=mesh)
-            if lora is not None:
-                q, v = _lora_qv(q, v, h, lora, lora_ids, li)
-            q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
-            if cfg.rope_for_layer(li):
+            if cfg.mla:
+                q, k, v, gate = *_mla_qkv(ap, h, token_pos, cfg), None, None
+            else:
+                q, k, v = _qkv(ap, h, cfg, mesh=mesh)
+                if lora is not None:
+                    q, v = _lora_qv(q, v, h, lora, lora_ids, li)
+                q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
+            if cfg.rope_for_layer(li) and not cfg.mla:
                 # rope() takes [B, T, n, d] + positions [B, T]
                 q, k = rope(q[None], k[None], token_pos[None], cfg.head_dim,
                             base=cfg.rope_theta, rope_pct=cfg.rope_pct,
@@ -824,8 +911,7 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
             q, rows, flat_k_all, flat_v_all, tables[grp] + base,
             _layer_kv(flat_ks, flat_vs))
         with jax.named_scope("attn_out"):
-            attn_delta = _attn_out(ap, o if gate is None else o * gate, cfg,
-                                   mesh=mesh)
+            attn_delta = _attn_proj(ap, o, gate, cfg, mesh=mesh)
             if cfg.sandwich_norm:
                 attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
         with jax.named_scope("mlp"):
@@ -869,7 +955,8 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     S = tokens.shape[0]
     L = cfg.num_layers
     NB = flat_k_all.shape[0] // L
-    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    nh = cfg.num_heads
+    nkv, hd, vd, latent = _attn_geometry(cfg)
     g = nh // nkv
     km = kv_major_layout(cfg)
 
@@ -889,11 +976,14 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
         ap = blk["Attention_0"]
         with jax.named_scope("attn_qkv"):
             h = _norm(blk["Norm_0"], x, cfg)
-            q, k, v = _qkv(ap, h, cfg, mesh=mesh)
-            if lora is not None:
-                q, v = _lora_qv(q, v, h, lora, lora_ids, li)
-            q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
-            if cfg.rope_for_layer(li):
+            if cfg.mla:
+                q, k, v, gate = *_mla_qkv(ap, h, token_pos, cfg), None, None
+            else:
+                q, k, v = _qkv(ap, h, cfg, mesh=mesh)
+                if lora is not None:
+                    q, v = _lora_qv(q, v, h, lora, lora_ids, li)
+                q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
+            if cfg.rope_for_layer(li) and not cfg.mla:
                 q, k = rope(q[:, None], k[:, None], token_pos[:, None], hd,
                             base=cfg.rope_theta, rope_pct=cfg.rope_pct,
                             scaling=cfg.rope_scaling,
@@ -915,13 +1005,12 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
             o = ops.paged_attention(qg, flat_k_all, flat_v_all,
                                     tables[grp] + base, kv_len,
                                     alibi_slopes=slopes, window=win,
-                                    scale=cfg.attn_scale, mesh=mesh,
+                                    scale=_attn_scale(cfg), mesh=mesh,
                                     kv_major=km, impl=cfg.attn_impl,
-                                    **_layer_kv(flat_ks, flat_vs))
-            o = o.reshape(S, nh, hd)
+                                    **_layer_kv(flat_ks, flat_vs), **latent)
+            o = o.reshape(S, nh, vd)
         with jax.named_scope("attn_out"):
-            attn_delta = _attn_out(ap, o if gate is None else o * gate, cfg,
-                                   mesh=mesh)
+            attn_delta = _attn_proj(ap, o, gate, cfg, mesh=mesh)
             if cfg.sandwich_norm:
                 attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
         with jax.named_scope("mlp"):
@@ -948,7 +1037,8 @@ def _flat_cache_views(cache: PagedKVCache, cfg: GPTConfig):
             f"{cfg.dtype}: create the pool in the compute dtype")
     with jax.named_scope("kv_pool"):
         fk = cache.k.reshape((-1,) + cache.k.shape[2:])
-        fv = cache.v.reshape((-1,) + cache.v.shape[2:])
+        fv = (None if cache.v is None
+              else cache.v.reshape((-1,) + cache.v.shape[2:]))
         q = cache.quantized
         fks = (cache.k_scale.reshape((-1,) + cache.k_scale.shape[2:])
                if q else None)
@@ -960,7 +1050,8 @@ def _flat_cache_views(cache: PagedKVCache, cfg: GPTConfig):
 def _rebuild_cache(cache: PagedKVCache, fk, fv, fks, fvs) -> PagedKVCache:
     with jax.named_scope("kv_pool"):
         return PagedKVCache(
-            k=fk.reshape(cache.k.shape), v=fv.reshape(cache.v.shape),
+            k=fk.reshape(cache.k.shape),
+            v=None if fv is None else fv.reshape(cache.v.shape),
             k_scale=(fks.reshape(cache.k_scale.shape) if fks is not None
                      else None),
             v_scale=(fvs.reshape(cache.v_scale.shape) if fvs is not None

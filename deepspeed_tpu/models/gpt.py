@@ -164,10 +164,34 @@ class GPTConfig:
     #                                     sliding window only (NoPE global)
     sandwich_norm: bool = False         # norms after attention and FFN too:
     #                                     x + n2(attn(n1 x)); h + n4(f(n3 h))
+    # latent attention (MLA; checkpoint/hf.py maps model_type "deepseek_v3"):
+    # keys and values are expanded from one normed latent a token,
+    # ``kv_lora_rank`` wide, beside one rotated key part ``qk_rope_head_dim``
+    # wide that all heads share.  ``head_dim`` is then a query/key head's
+    # whole width (its trailing qk_rope_head_dim columns are rotated) and
+    # ``v_head_dim`` a value head's.  0 = ordinary heads
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: Optional[int] = None
 
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """What a token's cache row needs: the latent and the key part."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_page_dim(self) -> int:
+        """... and what a page row stores: that, padded with zeros to whole
+        lane tiles of 128 (ops/paged_attention.py ``_dma_layout_ok``)."""
+        return -(-self.latent_dim // 128) * 128
 
     def is_moe_layer(self, i: int) -> bool:
         """Whether layer ``i`` holds experts: after ``moe_dense_layers``
@@ -681,6 +705,92 @@ class Attention(nn.Module):
         return out_proj(out)
 
 
+def mla_split(c: GPTConfig):
+    """(nope, rope, value) widths of a latent-attention head."""
+    return (c.head_dim - c.qk_rope_head_dim, c.qk_rope_head_dim,
+            c.v_head_dim or c.head_dim - c.qk_rope_head_dim)
+
+
+def mla_latent(wkv_a, kv_norm, h, positions, c: GPTConfig):
+    """A token's cache row from the normed layer input ``h [..., H]``:
+    ``(c_kv [..., kv_lora_rank]`` after its RMSNorm, ``k_pe [..., rope]``
+    rotated): the one definition the module's forward and the paged cache's
+    write (inference/v2/model.py) share."""
+    from deepspeed_tpu.ops import rms_norm
+    from deepspeed_tpu.ops.norms import RMS_EPS
+    ckv = h @ wkv_a.astype(h.dtype)
+    c_kv = rms_norm(ckv[..., :c.kv_lora_rank], kv_norm,
+                    eps=c.norm_eps or RMS_EPS)
+    k_pe = ckv[..., c.kv_lora_rank:]
+    lead = k_pe.shape[:-1]
+    k4 = k_pe.reshape((-1,) + lead[-1:] + (1, c.qk_rope_head_dim))
+    p2 = positions.reshape(k4.shape[:2])
+    k4, _ = rope(k4, k4, p2, c.qk_rope_head_dim, base=c.rope_theta,
+                 scaling=c.rope_scaling)
+    return c_kv, k4.reshape(k_pe.shape)
+
+
+def mla_query(wq, h, positions, c: GPTConfig):
+    """``(q_nope [..., nh, nope], q_pe [..., nh, rope]`` rotated)."""
+    nope, rot, _ = mla_split(c)
+    q = jnp.einsum("...h,hnd->...nd", h, wq.astype(h.dtype))
+    q_pe = q[..., nope:]
+    q4 = q_pe.reshape((-1,) + q_pe.shape[-3:])
+    q4, _ = rope(q4, q4, positions.reshape(q4.shape[:2]), rot,
+                 base=c.rope_theta, scaling=c.rope_scaling)
+    return q[..., :nope], q4.reshape(q_pe.shape)
+
+
+class MLAttention(nn.Module):
+    """Latent attention (MLA), the uncached forward as published: keys and
+    values expanded from the latent, one rotated key part for all heads.
+    The serving engine reads the same parameters in absorbed form over a
+    latent page pool (inference/v2/model.py)."""
+
+    cfg: GPTConfig
+    mesh: Optional[object] = None
+
+    @nn.compact
+    def __call__(self, x, positions, deterministic: bool,
+                 use_cache: bool = False, kv_mask=None, start_index=0,
+                 kv_positions=None, window=None, fused_ok: bool = False,
+                 use_rope: Optional[bool] = None):
+        c = self.cfg
+        if use_cache or window is not None or c.use_alibi or c.qk_norm \
+                or c.attn_gate or c.qkv_bias or c.sequence_parallel:
+            raise NotImplementedError(
+                "latent attention (kv_lora_rank) is built for the uncached "
+                "forward and the v2 engine's latent page pool: no flax KV "
+                "cache, window, alibi, qk_norm, gate, bias or sequence "
+                "parallelism beside it")
+        B, T, H = x.shape
+        nh = c.num_heads
+        nope, rot, vd = mla_split(c)
+        wq = self.param("wq", _part(_kernel_init(), ("embed", "heads", "kv")),
+                        (H, nh, c.head_dim), c.param_dtype)
+        wkv_a = self.param("wkv_a", _part(_kernel_init(), ("embed", None)),
+                           (H, c.latent_dim), c.param_dtype)
+        kv_norm = self.param("kv_norm", _part(nn.initializers.ones, (None,)),
+                             (c.kv_lora_rank,), c.param_dtype)
+        wkv_b = self.param("wkv_b", _part(_kernel_init(),
+                                          (None, "heads", "kv")),
+                           (c.kv_lora_rank, nh, nope + vd), c.param_dtype)
+        wo = self.param("wo", _part(_kernel_init(), ("heads", "kv", "embed")),
+                        (nh, vd, H), c.param_dtype)
+        q_nope, q_pe = mla_query(wq, x, positions, c)
+        c_kv, k_pe = mla_latent(wkv_a, kv_norm, x, positions, c)
+        kv = jnp.einsum("btr,rnd->btnd", c_kv, wkv_b.astype(x.dtype))
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_pe[:, :, None, :], (B, T, nh, rot))], -1)
+        q = jnp.concatenate([q_nope, q_pe], -1)
+        from deepspeed_tpu import ops
+        # the flash kernel takes one width for keys and values: XLA here
+        out = ops.causal_attention(q, k, kv[..., nope:], scale=c.attn_scale,
+                                   impl="xla")
+        return jnp.einsum("btnd,ndh->bth", out, wo.astype(x.dtype))
+
+
 class MLP(nn.Module):
     cfg: GPTConfig
     mesh: Optional[object] = None
@@ -757,9 +867,10 @@ class Block(nn.Module):
             # ln_attn + ln_mlp pair) and their outputs sum into one residual
             # add (reference inference/v2/model_implementations/falcon,
             # module_inject/containers/ — parallel_attn semantics).
-            if self.is_moe or c.sandwich_norm:
-                raise ValueError("parallel_block + MoE / sandwich_norm is "
-                                 "not a supported architecture combination")
+            if self.is_moe or c.sandwich_norm or c.mla:
+                raise ValueError("parallel_block + MoE / sandwich_norm / "
+                                 "latent attention is not a supported "
+                                 "architecture combination")
             h_attn = Norm(c)(x)                       # Norm_0
             h_mlp = Norm(c)(x) if c.parallel_norms == 2 else h_attn  # Norm_1
             a = Attention(c, mesh=self.mesh)(h_attn, positions, deterministic,
@@ -771,12 +882,11 @@ class Block(nn.Module):
                     + pld_gate(MLP(c, mesh=self.mesh)(h_mlp, deterministic,
                                                       use_cache=use_cache)),
                     jnp.float32(0.0))
-        a = Attention(c, mesh=self.mesh)(Norm(c)(x), positions,
-                                         deterministic, use_cache,
-                                         kv_mask, start_index,
-                                         kv_positions, window=window,
-                                         fused_ok=fused_ok,
-                                         use_rope=use_rope)
+        attn = (MLAttention(c, mesh=self.mesh, name="Attention_0") if c.mla
+                else Attention(c, mesh=self.mesh))
+        a = attn(Norm(c)(x), positions, deterministic, use_cache, kv_mask,
+                 start_index, kv_positions, window=window, fused_ok=fused_ok,
+                 use_rope=use_rope)
         if c.sandwich_norm:
             a = Norm(c, name="post_attn_norm")(a)
         x = x + pld_gate(a)
@@ -1055,8 +1165,13 @@ def count_params(cfg: GPTConfig) -> int:
     n_mat = 3 if cfg.gated_mlp else 2
     attn = (cfg.num_heads * cfg.head_dim * H * (3 if cfg.attn_gate else 2)
             + cfg.kv_heads * cfg.head_dim * H * 2              # wk, wv
-            + (2 * cfg.head_dim if cfg.qk_norm else 0)
-            + H * norms * (1 if cfg.use_rmsnorm else 2))
+            + (2 * cfg.head_dim if cfg.qk_norm else 0))
+    if cfg.mla:             # wq, wkv_a, kv_norm, wkv_b, wo
+        nope, _, vd = mla_split(cfg)
+        attn = (cfg.num_heads * (cfg.head_dim * H + vd * H
+                                 + cfg.kv_lora_rank * (nope + vd))
+                + H * cfg.latent_dim + cfg.kv_lora_rank)
+    attn += H * norms * (1 if cfg.use_rmsnorm else 2)
     moe_layers = sum(cfg.is_moe_layer(i) for i in range(cfg.num_layers))
     moe_ffn = (cfg.local_experts * H * cfg.expert_dim * n_mat
                + H * cfg.num_experts                            # router

@@ -54,6 +54,15 @@ sliced out of the pool; the kernels read ``bt_ref[s, p]`` and the XLA
 fallbacks gather ``pages[block_table]``, neither cares how many pages lie
 beyond the table's.
 
+Latent pages (``v_pages=None``, ``v_dim``): the pool holds ONE row a token,
+[NB, 1, bs, kd], that is both key and value (latent attention, absorbed: the
+queries have been carried into the latent space, inference/v2/model.py).  The
+value is the page's leading ``v_dim`` columns, so a page is copied once and
+both dots read the same VMEM slab; q is [S, 1, g, kd] (every query head in one
+group) and the output [S, 1, g, v_dim].  ``kd`` obeys the lane rule below
+(576 = 512 + 64 is stored padded to 640, the pad columns zero in q and page);
+``_block_pages`` counts the page's real bytes, so P follows by itself.
+
 kv-major layout (``kv_major=True``): pages are stored TRANSPOSED,
 [NB, nkv, hd, bs].  Mosaic requires a DMA slab's lane (last) dimension to be
 128-aligned; with the standard layout that means hd % 128 == 0, which
@@ -119,6 +128,14 @@ def _gather_pages(pages, block_table, kv_major):
     return got.reshape(S, -1, nkv, hd)
 
 
+def _latent_value(k_seq, v_pages, block_table, kv_major, v_dim):
+    """The gathered values [S, K, nkv, vd]: of their own pool, or (latent
+    pages, ``v_pages`` None) the leading ``v_dim`` columns of the keys."""
+    if v_pages is None:
+        return k_seq[..., :v_dim]
+    return _gather_pages(v_pages, block_table, kv_major)
+
+
 def _gather_scales(scale_pages, block_table):
     """Gather per-(page, head, token) scales [NB, nkv, bs] for each slot →
     [S, MB*bs, nkv] (token-major, matching _gather_pages row order)."""
@@ -136,7 +153,8 @@ def _dequant_seq(seq, scales, out_dtype):
 def xla_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
                         scale: Optional[float] = None, alibi_slopes=None,
                         window=None, interpret=None, mesh=None,
-                        kv_major=False, k_scale=None, v_scale=None):
+                        kv_major=False, k_scale=None, v_scale=None,
+                        v_dim=None):
     """Ground-truth XLA path: gather this slot's pages, masked softmax.
 
     ``mesh`` is accepted for signature parity with the Pallas path; the XLA
@@ -152,7 +170,7 @@ def xla_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
     if scale is None:
         scale = hd ** -0.5
     k_seq = _gather_pages(k_pages, block_table, kv_major)   # [S, MB*bs, nkv, hd]
-    v_seq = _gather_pages(v_pages, block_table, kv_major)
+    v_seq = _latent_value(k_seq, v_pages, block_table, kv_major, v_dim)
     if k_scale is not None:
         k_seq = _dequant_seq(k_seq, _gather_scales(k_scale, block_table),
                              q.dtype)
@@ -183,20 +201,20 @@ _BLOCK_BYTES = 2 << 20
 _MAX_BLOCK_PAGES = 8
 
 
-def _block_pages(nkv: int, bs: int, hd: int, dtype, quant: bool = False) -> int:
+def _block_pages(pools) -> int:
     """P, the pages a loop iteration of the decode kernel fetches: the whole
-    pages (every kv head, K and V, the scale rows of int8 pages) that fit
+    pages (every kv head of every pool the kernel copies: K and V, the scale
+    rows of int8 pages; a latent page's one row kind) that fit
     ``_BLOCK_BYTES``, at least one and at most ``_MAX_BLOCK_PAGES`` (each page
     of a block is an unrolled copy).  Static shapes only: a model, a shard of
-    its heads or a page dtype changes P, nothing else does."""
-    page = 2 * nkv * bs * hd * jnp.dtype(dtype).itemsize
-    if quant:
-        page += 2 * nkv * bs * 4
+    its heads, a page dtype or a latent page changes P, nothing else does."""
+    page = sum(int(np.prod(pool.shape[1:])) * jnp.dtype(pool.dtype).itemsize
+               for pool in pools)
     return int(max(1, min(_MAX_BLOCK_PAGES, _BLOCK_BYTES // page)))
 
 
 def _decode_kernel(*refs, S, P, bs, scale, window, has_alibi, kv_major,
-                   quant):
+                   quant, v_dim=None):
     """One grid step = one slot, every kv head (see the module docstring).
 
     ``state`` (SMEM) carries the page pipeline from one slot to the next:
@@ -207,11 +225,14 @@ def _decode_kernel(*refs, S, P, bs, scale, window, has_alibi, kv_major,
     ``quant``: pages are int8 codes and two more HBM inputs carry the
     per-(page, head, token) fp32 scales; a page's scale rows ([nkv, bs]) are
     copied beside it and the page is dequantized in VMEM right before the
-    dots.  The HBM traffic decode is bound by is the int8 payload."""
+    dots.  The HBM traffic decode is bound by is the int8 payload.
+
+    ``v_dim``: latent pages, one pool: the page is the key and its leading
+    ``v_dim`` columns the value."""
     it = iter(refs)
     bt_ref, len_ref, q_ref = next(it), next(it), next(it)
     slopes_ref = next(it) if has_alibi else None
-    hbms = [next(it) for _ in range(4 if quant else 2)]
+    hbms = [next(it) for _ in range(1 if v_dim else 4 if quant else 2)]
     o_ref = next(it)
     bufs = [next(it) for _ in hbms]
     sem, state = next(it), next(it)
@@ -291,7 +312,11 @@ def _decode_kernel(*refs, S, P, bs, scale, window, has_alibi, kv_major,
                 for c in copies(s, p, half, i):
                     c.wait()
                 # [nkv, bs, hd] or [nkv, hd, bs]; int8: and scales [nkv, bs]
-                k, v, *scales = (buf[half, i] for buf in bufs)
+                if v_dim:
+                    k = bufs[0][half, i]
+                    v, scales = k[..., :v_dim], ()
+                else:
+                    k, v, *scales = (buf[half, i] for buf in bufs)
                 if quant:
                     k, v = _dequant_page(k, v, *scales, kv_major, q.dtype)
                 scores = jax.lax.dot_general(
@@ -319,7 +344,7 @@ def _decode_kernel(*refs, S, P, bs, scale, window, has_alibi, kv_major,
 
         m0 = jnp.full((nkv, g, 1), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((nkv, g, 1), jnp.float32)
-        acc0 = jnp.zeros((nkv, g, hd), jnp.float32)
+        acc0 = jnp.zeros((nkv, g, v_dim or hd), jnp.float32)
         _, l, acc = jax.lax.fori_loop(0, nblk, block, (m0, l0, acc0))
         state[0] = (half0 + nblk) % 2
         state[1] = has_next.astype(jnp.int32)
@@ -331,13 +356,13 @@ def pallas_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
                            mesh=None, kv_major=False,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None, v_dim=None):
     """Mesh-aware entry: with a ``tp`` axis the kv-head dim is sharded, and the
     kernel runs per-shard under shard_map (attention is independent per kv
     head, so TP needs no collective here — the reference shards its blocked
     flash the same way, model_implementations/sharding/attn.py).  A shard's
     heads of a page are still one contiguous slab of its local pool."""
-    if (mesh is not None and mesh.shape.get("tp", 1) > 1
+    if (mesh is not None and mesh.shape.get("tp", 1) > 1 and v_pages is not None
             and q.shape[1] % mesh.shape["tp"] == 0):
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
@@ -375,14 +400,16 @@ def pallas_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
                                          window=window, scale=scale,
                                          interpret=interpret,
                                          kv_major=kv_major,
-                                         k_scale=k_scale, v_scale=v_scale)
+                                         k_scale=k_scale, v_scale=v_scale,
+                                         v_dim=v_dim)
 
 
 def _pallas_paged_attention_local(q, k_pages, v_pages, block_table, kv_lens, *,
                                   alibi_slopes=None, window=None,
                                   scale: Optional[float] = None,
                                   interpret: Optional[bool] = None,
-                                  kv_major=False, k_scale=None, v_scale=None):
+                                  kv_major=False, k_scale=None, v_scale=None,
+                                  v_dim=None):
     S, nkv, g, hd = q.shape
     if scale is None:
         scale = hd ** -0.5
@@ -395,14 +422,15 @@ def _pallas_paged_attention_local(q, k_pages, v_pages, block_table, kv_lens, *,
         q, k_pages, v_pages, block_table.astype(jnp.int32),
         kv_lens.astype(jnp.int32), alibi_slopes, k_scale, v_scale,
         window=int(window) if window is not None else None,
-        scale=float(scale), interpret=bool(interpret), kv_major=kv_major)
+        scale=float(scale), interpret=bool(interpret), kv_major=kv_major,
+        v_dim=None if v_pages is not None else int(v_dim))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale", "interpret",
-                                             "kv_major"))
+                                             "kv_major", "v_dim"))
 def _paged_decode_call(q, k_pages, v_pages, block_table, kv_lens,
                        alibi_slopes, k_scale, v_scale, *, window, scale,
-                       interpret, kv_major):
+                       interpret, kv_major, v_dim=None):
     """Grid (S,): the kernel normalises and writes [S, nkv, g, hd] in q's
     dtype itself.  A jit of its own: a step program calls it once a layer
     with the same shapes (the layer is a value, its first page in the
@@ -413,18 +441,20 @@ def _paged_decode_call(q, k_pages, v_pages, block_table, kv_lens,
     bs = k_pages.shape[3] if kv_major else k_pages.shape[2]
     quant = k_scale is not None
     has_alibi = alibi_slopes is not None
-    P = _block_pages(nkv, bs, hd, k_pages.dtype, quant)
+    pools = [k_pages] if v_pages is None else [k_pages, v_pages]
+    if quant:
+        pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    P = _block_pages(pools)
     kernel = functools.partial(
         _decode_kernel, S=S, P=P, bs=bs, scale=scale, window=window,
-        has_alibi=has_alibi, kv_major=kv_major, quant=quant)
+        has_alibi=has_alibi, kv_major=kv_major, quant=quant, v_dim=v_dim)
     whole = pl.BlockSpec((1, nkv, g, hd), lambda s, *_: (s, 0, 0, 0))
+    out_block = (whole if v_dim is None else
+                 pl.BlockSpec((1, nkv, g, v_dim), lambda s, *_: (s, 0, 0, 0)))
     in_specs, inputs = [whole], [q]
     if has_alibi:
         in_specs.append(pl.BlockSpec((nkv, g, 1), lambda s, *_: (0, 0, 0)))
         inputs.append(alibi_slopes)
-    pools = [k_pages, v_pages]
-    if quant:
-        pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
     inputs += pools
     # both halves of the pipeline, P whole pages each
@@ -440,10 +470,10 @@ def _paged_decode_call(q, k_pages, v_pages, block_table, kv_lens,
             num_scalar_prefetch=2,
             grid=(S,),
             in_specs=in_specs,
-            out_specs=whole,
+            out_specs=out_block,
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((S, nkv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, nkv, g, v_dim or hd), q.dtype),
         # sequential: a slot hands its successor a block already in flight
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -467,9 +497,20 @@ def _dma_layout_ok(hd: int, bs: int, kv_major: bool,
             and (not quant or bs % 128 == 0))
 
 
+def _latent_ok(v_pages, v_dim, hd, kv_major, quant, alibi_slopes) -> bool:
+    """Latent pages (no value pool): the value is the page's leading
+    ``v_dim`` columns, a whole number of lane tiles; row-major bf16/f32
+    pages without alibi are what the kernels' latent form reads."""
+    if v_pages is not None:
+        return v_dim is None
+    return (v_dim is not None and 0 < int(v_dim) <= hd
+            and int(v_dim) % 128 == 0
+            and not (kv_major or quant or alibi_slopes is not None))
+
+
 def supported(q, k_pages, v_pages, block_table, kv_lens, *, scale=None,
               alibi_slopes=None, window=None, interpret=None, mesh=None,
-              kv_major=False, k_scale=None, v_scale=None):
+              kv_major=False, k_scale=None, v_scale=None, v_dim=None):
     if q.ndim != 4 or k_pages.ndim != 4:
         return False
     S, nkv, g, hd = q.shape
@@ -486,6 +527,7 @@ def supported(q, k_pages, v_pages, block_table, kv_lens, *, scale=None,
     if window is not None and int(window) <= 0:
         return False
     return (nkv == nkv2 and hd == hd2
+            and _latent_ok(v_pages, v_dim, hd, kv_major, quant, alibi_slopes)
             and _dma_layout_ok(hd, bs, kv_major, quant=quant)
             and block_table.ndim == 2 and block_table.shape[0] == S)
 
@@ -495,13 +537,16 @@ def paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
                     alibi_slopes=None, window=None,
                     impl: Optional[str] = None,
                     interpret: Optional[bool] = None,
-                    mesh=None, kv_major=False, k_scale=None, v_scale=None):
-    """Registry entry (ops/__init__ registers this like causal_attention)."""
+                    mesh=None, kv_major=False, k_scale=None, v_scale=None,
+                    v_dim: Optional[int] = None):
+    """Registry entry (ops/__init__ registers this like causal_attention).
+    ``v_pages=None`` with ``v_dim``: latent pages (module docstring)."""
     from deepspeed_tpu.ops.registry import dispatch
     return dispatch("paged_attention", q, k_pages, v_pages, block_table,
                     kv_lens, scale=scale, alibi_slopes=alibi_slopes,
                     window=window, impl=impl, interpret=interpret, mesh=mesh,
-                    kv_major=kv_major, k_scale=k_scale, v_scale=v_scale)
+                    kv_major=kv_major, k_scale=k_scale, v_scale=v_scale,
+                    v_dim=v_dim)
 
 
 # ===================================================================
@@ -529,7 +574,8 @@ def paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
 def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
                        q_counts, *, scale: Optional[float] = None,
                        alibi_slopes=None, window=None, interpret=None,
-                       mesh=None, kv_major=False, k_scale=None, v_scale=None):
+                       mesh=None, kv_major=False, k_scale=None, v_scale=None,
+                       v_dim=None):
     """Ground-truth gather + masked-dense path (the round-2 prefill body).
     ``k_scale``/``v_scale``: int8-KV dequant after the gather (see
     xla_paged_attention)."""
@@ -542,7 +588,7 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
     if scale is None:
         scale = hd ** -0.5
     k_seq = _gather_pages(k_pages, block_table, kv_major)
-    v_seq = _gather_pages(v_pages, block_table, kv_major)
+    v_seq = _latent_value(k_seq, v_pages, block_table, kv_major, v_dim)
     if k_scale is not None:
         k_seq = _dequant_seq(k_seq, _gather_scales(k_scale, block_table),
                              q.dtype)
@@ -572,8 +618,14 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
 
 
 def _prefill_kernel(*refs, bs, cq, g, scale, window, has_alibi, kv_major,
-                    quant=False):
-    if quant:
+                    quant=False, v_dim=None):
+    """``v_dim``: latent pages, one pool and one buffer: the page is the key
+    and its leading ``v_dim`` columns the value."""
+    if v_dim:
+        bt_ref, len_ref, start_ref, count_ref, \
+            q_ref, k_hbm, o_ref, k_buf, sem = refs
+        slopes_ref = v_hbm = v_buf = None
+    elif quant:
         if has_alibi:
             bt_ref, len_ref, start_ref, count_ref, slopes_ref, \
                 q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, \
@@ -614,7 +666,8 @@ def _prefill_kernel(*refs, bs, cq, g, scale, window, has_alibi, kv_major,
 
     def start_page(slot, p):
         dma(k_hbm, k_buf, slot, p, 0).start()
-        dma(v_hbm, v_buf, slot, p, 1).start()
+        if not v_dim:
+            dma(v_hbm, v_buf, slot, p, 1).start()
         if quant:
             dma(ks_hbm, ks_buf, slot, p, 2).start()
             dma(vs_hbm, vs_buf, slot, p, 3).start()
@@ -643,9 +696,12 @@ def _prefill_kernel(*refs, bs, cq, g, scale, window, has_alibi, kv_major,
             start_page(nxt, p + 1)
 
         dma(k_hbm, k_buf, slot, p, 0).wait()
-        dma(v_hbm, v_buf, slot, p, 1).wait()
         k = k_buf[slot]                # [bs, hd] or [hd, bs] (kv-major)
-        v = v_buf[slot]
+        if v_dim:
+            v = k[:, :v_dim]
+        else:
+            dma(v_hbm, v_buf, slot, p, 1).wait()
+            v = v_buf[slot]
         if quant:
             dma(ks_hbm, ks_buf, slot, p, 2).wait()
             dma(vs_hbm, vs_buf, slot, p, 3).wait()
@@ -678,18 +734,20 @@ def _prefill_kernel(*refs, bs, cq, g, scale, window, has_alibi, kv_major,
 
     m0 = jnp.full((cq * g, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((cq * g, 1), jnp.float32)
-    acc0 = jnp.zeros((cq * g, hd), jnp.float32)
+    vd = v_dim or hd
+    acc0 = jnp.zeros((cq * g, vd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(p_start, n_pages, body, (m0, l0, acc0))
     l = jnp.where(l == 0.0, 1.0, l)                # dead rows -> zeros
-    o_ref[0, :, 0] = (acc / l).reshape(cq, g, hd).astype(o_ref.dtype)
+    o_ref[0, :, 0] = (acc / l).reshape(cq, g, vd).astype(o_ref.dtype)
 
 
 def pallas_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
                           q_counts, *, scale: Optional[float] = None,
                           alibi_slopes=None, window=None,
                           interpret: Optional[bool] = None, mesh=None,
-                          kv_major=False, k_scale=None, v_scale=None):
-    if (mesh is not None and mesh.shape.get("tp", 1) > 1
+                          kv_major=False, k_scale=None, v_scale=None,
+                          v_dim=None):
+    if (mesh is not None and mesh.shape.get("tp", 1) > 1 and v_pages is not None
             and q.shape[2] % mesh.shape["tp"] == 0):
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
@@ -726,12 +784,22 @@ def pallas_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
         q, k_pages, v_pages, block_table, kv_lens, q_starts, q_counts,
         scale=scale, alibi_slopes=alibi_slopes, window=window,
         interpret=interpret, kv_major=kv_major,
-        k_scale=k_scale, v_scale=v_scale)
+        k_scale=k_scale, v_scale=v_scale, v_dim=v_dim)
 
 
-def _prefill_chunk(Q: int) -> Optional[int]:
+# the prefill kernel's float32 accumulator [cq * g, value width] stays under
+# this: 128 rows of any GQA group at head width 128 do (a group of 8 is
+# 512 KB), 16 query heads on one 512-wide latent take a chunk of 32 rows
+_ACC_BYTES = 1 << 20
+
+
+def _prefill_chunk(Q: int, g: int = 1, vd: int = 128) -> Optional[int]:
+    """Query rows a grid step of the prefill kernel attends: the largest
+    power of two up to 128 that divides ``Q`` and keeps the accumulator of
+    its ``cq * g`` rows of ``vd`` values within ``_ACC_BYTES``."""
     for cq in (128, 64, 32, 16, 8, 4, 2, 1):
-        if cq <= Q and Q % cq == 0:
+        if cq <= Q and Q % cq == 0 and (cq * g * vd * 4 <= _ACC_BYTES
+                                        or cq == 1):
             return cq
     return None
 
@@ -741,14 +809,17 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
                                  scale: Optional[float] = None,
                                  alibi_slopes=None, window=None,
                                  interpret: Optional[bool] = None,
-                                 kv_major=False, k_scale=None, v_scale=None):
+                                 kv_major=False, k_scale=None, v_scale=None,
+                                 v_dim=None):
     S, Q, nkv, g, hd = q.shape
     bs = k_pages.shape[3] if kv_major else k_pages.shape[2]
     if scale is None:
         scale = hd ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    cq = _prefill_chunk(Q)
+    latent = v_pages is None
+    vd = int(v_dim) if latent else hd
+    cq = _prefill_chunk(Q, g, vd)
     block_table = block_table.astype(jnp.int32)
     kv_lens = kv_lens.astype(jnp.int32)
     q_starts = q_starts.astype(jnp.int32)
@@ -760,25 +831,21 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
     kernel = functools.partial(
         _prefill_kernel, bs=bs, cq=cq, g=g, scale=float(scale),
         window=int(window) if window is not None else None,
-        has_alibi=has_alibi, kv_major=kv_major, quant=quant)
+        has_alibi=has_alibi, kv_major=kv_major, quant=quant,
+        v_dim=vd if latent else None)
     n_prefetch = 4
     prefetch = [block_table, kv_lens, q_starts, q_counts]
     if has_alibi:
         n_prefetch = 5
         prefetch.append(jnp.asarray(alibi_slopes, jnp.float32).reshape(
             nkv, g))
-    in_specs = [
-        pl.BlockSpec((1, cq, 1, g, hd),
-                     lambda s, h, c, *_: (s, c, h, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    inputs = [q, k_pages, v_pages]
+    pools = [k_pages] if latent else [k_pages, v_pages]
+    in_specs = [pl.BlockSpec((1, cq, 1, g, hd),
+                             lambda s, h, c, *_: (s, c, h, 0, 0))]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
+    inputs = [q] + pools
     buf_shape = (2, hd, bs) if kv_major else (2, bs, hd)
-    scratch = [
-        pltpu.VMEM(buf_shape, k_pages.dtype),
-        pltpu.VMEM(buf_shape, v_pages.dtype),
-    ]
+    scratch = [pltpu.VMEM(buf_shape, pool.dtype) for pool in pools]
     if quant:
         in_specs += [pl.BlockSpec(memory_space=pl.ANY),
                      pl.BlockSpec(memory_space=pl.ANY)]
@@ -792,11 +859,11 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
             num_scalar_prefetch=n_prefetch,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, cq, 1, g, hd),
+            out_specs=pl.BlockSpec((1, cq, 1, g, vd),
                                    lambda s, h, c, *_: (s, c, h, 0, 0)),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((S, Q, nkv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, Q, nkv, g, vd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
@@ -809,7 +876,7 @@ def ragged_prefill_supported(q, k_pages, v_pages, block_table, kv_lens,
                              q_starts, q_counts, *, scale=None,
                              alibi_slopes=None, window=None, interpret=None,
                              mesh=None, kv_major=False,
-                             k_scale=None, v_scale=None):
+                             k_scale=None, v_scale=None, v_dim=None):
     if q.ndim != 5 or k_pages.ndim != 4:
         return False
     S, Q, nkv, g, hd = q.shape
@@ -826,8 +893,9 @@ def ragged_prefill_supported(q, k_pages, v_pages, block_table, kv_lens,
     if window is not None and int(window) <= 0:
         return False
     return (nkv == nkv2 and hd == hd2
+            and _latent_ok(v_pages, v_dim, hd, kv_major, quant, alibi_slopes)
             and _dma_layout_ok(hd, bs, kv_major, quant=quant)
-            and _prefill_chunk(Q) is not None
+            and _prefill_chunk(Q, g, v_dim or hd) is not None
             and block_table.ndim == 2 and block_table.shape[0] == S)
 
 
@@ -837,11 +905,14 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_table, kv_lens,
                              alibi_slopes=None, window=None,
                              impl: Optional[str] = None,
                              interpret: Optional[bool] = None, mesh=None,
-                             kv_major=False, k_scale=None, v_scale=None):
-    """Registry entry for the ragged prefill kernel."""
+                             kv_major=False, k_scale=None, v_scale=None,
+                             v_dim: Optional[int] = None):
+    """Registry entry for the ragged prefill kernel.  ``v_pages=None`` with
+    ``v_dim``: latent pages (module docstring)."""
     from deepspeed_tpu.ops.registry import dispatch
     return dispatch("ragged_prefill_attention", q, k_pages, v_pages,
                     block_table, kv_lens, q_starts, q_counts, scale=scale,
                     alibi_slopes=alibi_slopes, window=window, impl=impl,
                     interpret=interpret, mesh=mesh, kv_major=kv_major,
-                    k_scale=k_scale, v_scale=v_scale)
+                    k_scale=k_scale, v_scale=v_scale,
+                    v_dim=v_dim)

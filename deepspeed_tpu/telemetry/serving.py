@@ -240,6 +240,11 @@ class ServingTelemetry:
             "kv_pages_allocated_total", "pages a page group handed to "
             "sequences, per group (the denominator of the released share)")
         self._kvw_seen = [0, 0]     # window group totals already counted
+        self.g_kv_bytes = reg.gauge(
+            "kv_bytes_per_token", "device bytes the KV pool stores for one "
+            "cached token over all layers (a latent pool: one padded latent "
+            "row a layer; otherwise every kv head's key and value)")
+        self.kv_bytes_per_token = 0     # set_kv_bytes_per_token
 
     # ------------------------------------------------------------- clocks
 
@@ -361,6 +366,13 @@ class ServingTelemetry:
             self.c_moe_assign.inc(int(vec[1]), **self.labels)
             self.c_moe_touched.inc(int(vec[2]), **self.labels)
 
+    def set_kv_bytes_per_token(self, n: int) -> None:
+        """The engine's pool geometry, once at start-up: the gauge beside
+        ``kv_pages_in_use`` and an argument of every dispatch span."""
+        self.kv_bytes_per_token = int(n)
+        if self.enabled:
+            self.g_kv_bytes.set(int(n), **self.labels)
+
     def counter_note(self, state) -> Dict[str, int]:
         """Running totals for a dispatch span's args, so that a trace holds
         them (a reader takes the difference between two dispatches): the
@@ -371,6 +383,7 @@ class ServingTelemetry:
         if not self.enabled:
             return note
         note.update(
+            kv_bytes_per_token=self.kv_bytes_per_token,
             mixed_seqs=int(self.c_mixed_slots.value(**self.labels)),
             one_row_seqs=int(self.c_one_row_slots.value(**self.labels)))
         total = self.c_moe_assign.value(**self.labels)

@@ -14,7 +14,9 @@ lost name fails the case that carries it and no other.
 (b) ``COUNTERS``: the counters of the table, by registry name and labels;
 (c) the records that exist only in the tracer's buffer;
 (d) ``AFMOE_SPAN_ARGS``: the dispatch-span arguments that only a model with
-    experts and window layers writes.
+    experts and window layers writes;
+(e) ``LATENT_READS``: what ``benchmark/readers/latent.py`` and
+    ``benchmark/tools/mla_compare.py`` take of a model with latent attention.
 The other spans and their arguments are held by ``tests/test_one_clock.py``."""
 
 import dataclasses
@@ -532,6 +534,87 @@ def test_afmoe_dispatch_span_carries(afmoe_served, span, arg):
         assert values == sorted(values) and values[-1] > 0
     else:
         assert min(values) >= 0
+
+
+# --------------- (e) a model with latent attention over a latent page pool
+
+@pytest.fixture(scope="module")
+def latent_served(tmp_path_factory):
+    """A tiny deepseek_v3 engine (latent attention, every expert held) built
+    from the benchmark reference's ``program_config``, after a short
+    ``generate()`` under a profiler session: (engine, annotations)."""
+    import _deepseek_mla
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    from deepspeed_tpu.models import GPTConfig
+    sizes = dict(
+        model_type="deepseek_v3", hidden_act="silu", hidden_size=32,
+        intermediate_size=64, moe_intermediate_size=24,
+        num_attention_heads=4, num_key_value_heads=4, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=12, kv_lora_rank=128,
+        q_lora_rank=None, num_hidden_layers=3, first_k_dense_replace=1,
+        moe_layer_freq=1, n_routed_experts=8, num_experts_per_tok=3,
+        n_shared_experts=2, n_group=1, topk_group=1, norm_topk_prob=True,
+        routed_scaling_factor=2.446, scoring_func="sigmoid",
+        rms_norm_eps=1e-5, rope_theta=50000, attention_bias=False,
+        ep_size=1, tie_word_embeddings=False, vocab_size=96)
+    cfg = GPTConfig(**_deepseek_mla.program_config(sizes), max_seq_len=128)
+    eng = InferenceEngineV2(cfg, {
+        "dtype": "float32", "generation": {"do_sample": False},
+        "state_manager": {"max_tracked_sequences": 4,
+                          "max_ragged_sequence_count": 4,
+                          "max_ragged_batch_size": 32, "max_q_per_seq": 8,
+                          "kv_block_size": 4, "num_kv_blocks": 64}}, seed=0)
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, 96, size=n) for n in (30, 9, 17)]
+    eng.generate(prompts, max_new_tokens=10)
+    trace_dir = str(tmp_path_factory.mktemp("latent"))
+    with jax.profiler.trace(trace_dir):
+        eng.generate(prompts, max_new_tokens=10)
+    return eng, xmeta.annotations(xtrace.find_xplane(trace_dir))
+
+
+def _latent_span_arg(span, arg):
+    def read(o):
+        eng, notes = o
+        got = [a for a in notes if a["name"] == span]
+        assert got and all(arg in a["args"] for a in got), (span, arg)
+        if arg == "kv_bytes_per_token":     # 3 layers x 256 columns x fp32
+            assert {float(a["args"][arg]) for a in got} == {3 * 256 * 4.0}
+            assert eng.telemetry.value("kv_bytes_per_token") == 3 * 256 * 4
+    return read
+
+
+def _latent_model_cfg(o):
+    cfg = o[0].model_config                # the runner's ctx["model_cfg"]
+    assert (cfg.kv_lora_rank, cfg.latent_dim, cfg.latent_page_dim,
+            cfg.num_layers, cfg.num_heads) == (128, 136, 256, 3, 4)
+
+
+def _latent_put_with_routes(o):
+    logits, routes = o[0].put([9], [np.arange(5, dtype=np.int32)],
+                              with_routes=True)
+    o[0].flush([9])
+    assert logits.shape == (1, 96) and routes[0].shape == (2, 5, 3)
+
+
+LATENT_READS = {
+    **{f"{span}.{arg}": _latent_span_arg(span, arg) for span, arg in [
+        ("ds.mixed_dispatch", "kv_bytes_per_token"),
+        ("ds.burst_dispatch", "kv_bytes_per_token"),
+        ("ds.mixed_dispatch", "qk_pairs"),
+        ("ds.mixed_dispatch", "one_row_slots"),
+        ("ds.mixed_dispatch", "ctx_tokens_one_row"),
+        ("ds.mixed_dispatch", "moe_local"),
+        ("ds.burst_dispatch", "moe_touched")]},
+    "model_cfg.kv_lora_rank,latent_dim,num_layers,num_heads":
+        _latent_model_cfg,
+    "eng.put(with_routes=True)": _latent_put_with_routes,
+}
+
+
+@pytest.mark.parametrize("name", list(LATENT_READS))
+def test_latent_reads(latent_served, name):
+    LATENT_READS[name](latent_served)
 
 
 # --------------------------------------------------- tools/sched_replay.py

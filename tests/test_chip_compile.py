@@ -149,10 +149,11 @@ def _pool(nkv, hd, bs, kv_major, quant, nb=64):
 
 
 def decode_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8,
-                window=None):
+                window=None, v_dim=None):
+    """``v_dim``: latent pages, no value pool (``v`` is None)."""
     page, scale = _pool(nkv, hd, bs, kv_major, quant)
-    specs = [sds((S, nkv, g, hd), BF16), page, page, sds((S, MB), I32),
-             sds((S,), I32)]
+    specs = [sds((S, nkv, g, hd), BF16), page, None if v_dim else page,
+             sds((S, MB), I32), sds((S,), I32)]
     if quant:
         specs += [scale, scale]
 
@@ -160,15 +161,20 @@ def decode_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8,
         kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
         return pallas_paged_attention(q, k, v, bt, lens, interpret=False,
                                          kv_major=kv_major, window=window,
-                                         **kw)
+                                         **kw, **_latent(v_dim))
     return chip_text(topo, fn, *specs)
 
 
+def _latent(v_dim):
+    return {"v_dim": v_dim, "scale": 192 ** -0.5} if v_dim else {}
+
+
 def prefill_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8,
-                 Q=128, window=None):
+                 Q=128, window=None, v_dim=None):
     page, scale = _pool(nkv, hd, bs, kv_major, quant)
-    specs = [sds((S, Q, nkv, g, hd), BF16), page, page, sds((S, MB), I32),
-             sds((S,), I32), sds((S,), I32), sds((S,), I32)]
+    specs = [sds((S, Q, nkv, g, hd), BF16), page, None if v_dim else page,
+             sds((S, MB), I32), sds((S,), I32), sds((S,), I32),
+             sds((S,), I32)]
     if quant:
         specs += [scale, scale]
 
@@ -176,7 +182,8 @@ def prefill_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8,
         kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
         return pallas_ragged_prefill(q, k, v, bt, lens, st, ct,
                                         interpret=False, kv_major=kv_major,
-                                        window=window, **kw)
+                                        window=window, **kw,
+                                        **_latent(v_dim))
     return chip_text(topo, fn, *specs)
 
 
@@ -189,29 +196,53 @@ TRINITY = GPTConfig.llama(num_layers=1, hidden=6144, heads=48,
                           num_kv_heads=8, sliding_window=4096)
 
 
+# Moonlight-16B-A3B's latent attention, absorbed (the serving cell's
+# geometry): 16 query heads in one group over one latent row of 512 + 64
+# padded to 640, whose leading 512 columns are the value; pages of 128
+MOONLIGHT = GPTConfig(num_layers=2, hidden_size=2048, num_heads=16,
+                      head_dim=192, kv_lora_rank=512, qk_rope_head_dim=64,
+                      v_head_dim=128, use_rope=True, rope_theta=50000.0,
+                      use_rmsnorm=True, norm_eps=1e-5, gated_mlp=True,
+                      tie_embeddings=False, mlp_dim_override=11264,
+                      vocab_size=163840, max_seq_len=4096, num_experts=64,
+                      moe_k=6, moe_dropless=True, moe_router="sigmoid",
+                      moe_route_scale=2.446, moe_router_bias=True,
+                      moe_shared_dim=2816, moe_expert_dim=1408,
+                      moe_dense_layers=1)
+
+
 def _engine_geometry(cfg, quant, block=64):
     """The page layout and size the v2 engine commits to for ``cfg`` when
     the user asks for ``kv_block_size`` ``block`` (64 is the default), and
     the window its layer 0 attends under."""
-    return dict(nkv=cfg.kv_heads, g=cfg.num_heads // cfg.kv_heads,
-                hd=cfg.head_dim, kv_major=kv_major_layout(cfg),
+    from deepspeed_tpu.inference.v2.model import _attn_geometry
+    nkv, hd, _, latent = _attn_geometry(cfg)
+    return dict(nkv=nkv, g=cfg.num_heads // nkv,
+                hd=hd, kv_major=kv_major_layout(cfg),
                 bs=kv_block_size_for(cfg, block, quant=quant), quant=quant,
-                window=cfg.window_for_layer(0))
+                window=cfg.window_for_layer(0), **latent)
 
 
 @pytest.mark.parametrize("kernel", [decode_text, prefill_text],
                          ids=["decode", "prefill"])
 @pytest.mark.parametrize("cfg,quant,block", [
     (LLAMA128, False, 64), (GPT2S, False, 64), (LLAMA128, True, 64),
-    (GPT2S, True, 64), (TRINITY, False, 128)],
+    (GPT2S, True, 64), (TRINITY, False, 128), (MOONLIGHT, False, 128)],
     ids=["hd128-bf16", "gpt2s-bf16", "hd128-int8kv", "gpt2s-int8kv",
-         "trinity-window"])
+         "trinity-window", "moonlight-latent"])
 def test_paged_kernels_in_engine_geometry(topo, kernel, cfg, quant, block):
     geo = _engine_geometry(cfg, quant, block)
     assert _dma_layout_ok(geo["hd"], geo["bs"], geo["kv_major"], quant)
     if cfg is TRINITY:
         assert (geo["nkv"], geo["g"], geo["hd"], geo["bs"], geo["window"]) \
             == (8, 6, 128, 128, 4096)
+    if cfg is MOONLIGHT:
+        assert (geo["nkv"], geo["g"], geo["hd"], geo["bs"], geo["v_dim"],
+                geo["kv_major"]) == (1, 16, 640, 128, 512, False)
+        # 576, the width the mathematics needs, is what the rule refuses
+        assert not _dma_layout_ok(cfg.latent_dim, 128, False)
+        if kernel is prefill_text:  # the cell's chunk: 32 rows a grid step
+            geo.update(Q=1024)
     assert KERNEL in kernel(topo, **geo)
 
 
@@ -274,6 +305,9 @@ POOL_GEOMETRIES = {
     "hd128-int8kv": (MISTRAL_2L, "int8", 1, 80 << 20),
     "gpt2s-bf16": (dataclasses.replace(GPT2S, num_layers=2), None, 1,
                    16 << 20),
+    # a latent pool: one dense and one expert layer at Moonlight's widths;
+    # a layer's pages are 42 MB (256 x 128 rows of 640)
+    "latent-bf16": (MOONLIGHT, None, 1, 32 << 20),
     "hd128-bf16-tp2": (MISTRAL_2L, None, 2, 16 << 20),
     "gpt2s-bf16-tp2": (dataclasses.replace(GPT2S, num_layers=2), None, 2,
                        16 << 20),

@@ -226,6 +226,41 @@ def test_serving_program_carries_the_phase_scope(serve_lowered, program,
     assert scope in scopes_in(serve_lowered[program])
 
 
+@pytest.fixture(scope="module")
+def latent_lowered():
+    """The same three programs of a model with latent attention (MLA) over
+    a latent page pool and an expert layer."""
+    from conftest import lower_serving_steps
+    cfg = GPTConfig(
+        num_layers=2, hidden_size=64, num_heads=4, head_dim=24,
+        kv_lora_rank=128, qk_rope_head_dim=8, v_head_dim=16, use_rope=True,
+        use_rmsnorm=True, gated_mlp=True, tie_embeddings=False,
+        vocab_size=128, max_seq_len=256, mlp_dim_override=128, num_experts=4,
+        moe_k=2, moe_dropless=True, moe_router="sigmoid",
+        moe_router_bias=True, moe_shared_dim=32, moe_expert_dim=32,
+        moe_dense_layers=1, dtype=jnp.float32)
+    return lower_serving_steps(cfg, jnp.float32, slots=4, tokens=64,
+                               max_q=16, table_width=8, block_size=16,
+                               num_pages=32, steps=4)[2]
+
+
+@pytest.mark.parametrize("scope", SERVE_SCOPES + ("mla_absorb",))
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_latent_serving_program_carries_the_phase_scope(latent_lowered,
+                                                        program, scope):
+    """``mla_absorb`` (the two absorb products) nests inside ``attn_qkv``
+    and ``attn_out``, which keep their meaning: ``scope_time`` counts its
+    ops with theirs, ``benchmark/readers/latent.py`` reads it alone."""
+    if scope != "mla_absorb":
+        assert scope in scopes_in(latent_lowered[program])
+        return
+    text = latent_lowered[program].as_text(debug_info=True)
+    paths = [p for p in re.findall(r'loc\("([^"]+)"', text)
+             if "mla_absorb" in p.split("/")]
+    assert {scope_time.group_of(p) for p in paths} == {"attn_qkv",
+                                                       "attn_out"}
+
+
 @pytest.mark.parametrize("program", PROGRAMS)
 def test_serving_program_has_no_unscoped_matmul(serve_lowered, program):
     n, bad = heavy_ops(serve_lowered[program].compile().as_text())
@@ -471,11 +506,14 @@ PHASE_ARGS = {"ds.gate": ("released", "late_ms_max"), "ds.idle_sleep": (),
 # other two no steps
 DISPATCH_ARGS = {
     "ds.mixed_dispatch": ("tokens", "bucket", "seqs", "ctx_tokens",
-                          "mixed_seqs", "one_row_seqs"),
+                          "mixed_seqs", "one_row_seqs", "kv_bytes_per_token",
+                          "qk_pairs", "one_row_slots", "ctx_tokens_one_row"),
     "ds.decode_dispatch": ("tokens", "bucket", "seqs", "ctx_tokens",
-                           "mixed_seqs", "one_row_seqs"),
+                           "mixed_seqs", "one_row_seqs",
+                           "kv_bytes_per_token"),
     "ds.burst_dispatch": ("tokens", "steps", "seqs", "ctx_tokens",
-                          "mixed_seqs", "one_row_seqs")}
+                          "mixed_seqs", "one_row_seqs",
+                          "kv_bytes_per_token")}
 
 
 @pytest.mark.parametrize("arg", ROUND_ARGS)

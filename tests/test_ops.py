@@ -565,7 +565,8 @@ class TestPagedAttention:
         if variant == "alibi":
             kw["alibi_slopes"] = jnp.asarray(
                 np.geomspace(0.5, 1 / 256, nkv * g), jnp.float32)
-        P = _block_pages(nkv, bs, hd, k.dtype, quant=variant == "int8")
+        P = _block_pages([k, v] + ([kw["k_scale"], kw["v_scale"]]
+                                   if variant == "int8" else []))
         assert P > 1, "the cases below need a block of several pages"
         return q, k, v, bt, kw, P * bs
 
