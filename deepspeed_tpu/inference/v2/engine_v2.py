@@ -826,9 +826,28 @@ class InferenceEngineV2:
         mb_full = rb.block_table.shape[1]
         mb_used = max(1, -(-int(rb.kv_len.max()) // self._block_size))
         mb = min(1 << (mb_used - 1).bit_length(), mb_full)
-        nb = min(max(64, 1 << (max(1, rb.total_tokens) - 1).bit_length()),
-                 rb.tokens.shape[0])
-        return mb, nb
+        return mb, self._token_bucket(rb.total_tokens, rb.tokens.shape[0])
+
+    @staticmethod
+    def _token_bucket(tokens: int, budget: int) -> int:
+        return min(max(64, 1 << (max(1, tokens) - 1).bit_length()), budget)
+
+    def _prefill_items(self, rows):
+        """(live work items, the grid's bound) of the ragged prefill kernel
+        in the mixed step that serves ``rows`` (each sequence's), a layer:
+        the kernel's own rule (ops/paged_attention.py) on the host."""
+        from deepspeed_tpu.inference.v2.model import _attn_geometry
+        from deepspeed_tpu.ops.paged_attention import (_prefill_chunk,
+                                                       prefill_grid_items)
+        sm = self.config.state_manager
+        cfg = self.model_config
+        nkv, _, vd, _ = _attn_geometry(cfg)
+        nb = self._token_bucket(sum(rows), sm.max_ragged_batch_size)
+        Q = min(sm.max_q_per_seq, nb)
+        cq = _prefill_chunk(Q, cfg.num_heads // nkv, vd)
+        return (sum(-(-n // cq) for n in rows if n > 1),
+                prefill_grid_items(nb, self.state.max_tracked_sequences, Q,
+                                   cq))
 
     def _with_lora(self, batch):
         """Thread the adapter selection + packed pages into a dispatch batch.
@@ -871,7 +890,6 @@ class InferenceEngineV2:
                 donate_argnums=(1,))
         batch = {"tokens": rb.tokens[:nb], "token_slot": rb.token_slot[:nb],
                  "token_pos": rb.token_pos[:nb],
-                 "token_dense_idx": rb.token_dense_idx[:nb],
                  **rb.table_operands(mb), "kv_len": rb.kv_len}
         batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, batch))
         self.telemetry.dispatch("mixed")
@@ -1143,7 +1161,7 @@ class InferenceEngineV2:
             rows = [len(t) for t in toks_np]
             mixed = max(rows) > 1
             if mixed:
-                stel.mixed_slots(rows)
+                stel.mixed_slots(rows, *self._prefill_items(rows))
             note = {"seqs": len(schedule), "tokens": sum(rows),
                     **self._ctx_note([seq.seen_tokens
                                       for seq, _ in schedule], rows),
@@ -1184,7 +1202,6 @@ class InferenceEngineV2:
                 host = {"tokens": rb.tokens[:nb],
                         "token_slot": rb.token_slot[:nb],
                         "token_pos": rb.token_pos[:nb],
-                        "token_dense_idx": rb.token_dense_idx[:nb],
                         **rb.table_operands(mb),
                         "kv_len": rb.kv_len, "from_device": fdev[:nb],
                         "served": served}

@@ -4,8 +4,10 @@ Analog of the reference's ``DSTransformerBase`` layer-by-layer ragged forward
 (inference/v2/model_implementations/inference_transformer_base.py:617) plus the
 ragged kernel set (inference/v2/kernels/ragged_ops/): ``linear_blocked_kv_rotary``
 (qkv + rotary + paged-KV append) and ``blocked_flash`` (attention over blocked
-KV) become scatter-into-pages + a dense-per-slot masked attention in XLA;
-``logits_gather`` becomes a row gather before the unembed.
+KV) become scatter-into-pages + the paged attention ops over the token-major
+rows (ops/paged_attention.py: Pallas kernels on TPU, a gather and a masked
+dense attention in XLA elsewhere); ``logits_gather`` becomes a row gather
+before the unembed.
 
 Works directly on the GPT parameter tree (models/gpt.py naming: backbone/
 block_i/{Attention_0,MLP_0,Norm_0,Norm_1}, wte/wpe/final_norm) the way the
@@ -728,7 +730,7 @@ def _attn_out(ap, o, cfg, mesh=None):
 class _MixedRows(NamedTuple):
     """Where a mixed step's token rows sit, the same for every layer."""
     scat_slot: jnp.ndarray   # [N] slot of each token row; S for padding
-    dense_idx: jnp.ndarray   # [N] the row's place among its slot's rows
+    first_row: jnp.ndarray   # [S] the slot's first row of the flat batch
     kv_len: jnp.ndarray      # [S] context after the step
     q_counts: jnp.ndarray    # [S] rows the slot holds in this step
 
@@ -737,24 +739,26 @@ def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
                      cfg: GPTConfig, Q: int, window, mesh):
     """Ragged blocked attention of a mixed step (reference blocked_flash +
     atom_builder): token-major ``q`` [N, nh, hd] over the flat pool ->
-    [N, nh, hd].  Each slot's rows are one contiguous span of positions, laid
-    out dense per slot ([S, Q, ...]).
+    [N, nh, vd].  Each slot's rows are one contiguous span of the flat batch
+    and of positions, and stay where they are: both kernels are told where a
+    slot's rows begin.
 
-    A slot's rows pick its kernel.  The prefill kernel DMAs only the pages
-    each live (slot, q-chunk) can causally see, but a chunk is up to 128
-    rows: a slot with ONE row (a decode row riding the step, a prompt's
-    one-token tail) goes to the paged decode kernel, whose tile is that
-    row.  Each kernel is told the other's slots are empty (length 0, count
-    0) and skips them outright."""
+    A slot's rows pick its kernel.  The prefill kernel walks the live
+    (slot, q-chunk) items and DMAs only the pages each can causally see, but
+    a chunk is up to 128 rows: a slot with ONE row (a decode row riding the
+    step, a prompt's one-token tail) goes to the paged decode kernel, whose
+    tile is that row.  Each kernel is told the other's slots are empty
+    (length 0, count 0) and skips them outright; a row takes its slot's
+    kernel's result."""
     from deepspeed_tpu import ops
     S = table.shape[0]
+    N = q.shape[0]
     nh = cfg.num_heads
     nkv, hd, vd, latent = _attn_geometry(cfg)
     with jax.named_scope("attn_kernel"):
         valid = rows.scat_slot < S
         slot = jnp.where(valid, rows.scat_slot, 0)
-        q_dense = jnp.zeros((S, Q, nh, hd), q.dtype).at[
-            rows.scat_slot, rows.dense_idx].set(q, mode="drop")
+        q = q.reshape(N, nkv, nh // nkv, hd).astype(cfg.dtype)
         slopes = None
         if cfg.use_alibi:
             from deepspeed_tpu.models.gpt import alibi_slopes
@@ -765,18 +769,17 @@ def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
                     impl=cfg.attn_impl, **scales, **latent)
         one_row = rows.q_counts == 1
         o_one = ops.paged_attention(
-            q_dense[:, 0].reshape(S, nkv, nh // nkv, hd).astype(cfg.dtype),
-            k_pages, v_pages, table, jnp.where(one_row, rows.kv_len, 0),
-            **pool)
+            q[rows.first_row], k_pages, v_pages, table,
+            jnp.where(one_row, rows.kv_len, 0), **pool)
         # each slot's rows are one span of positions ending at its kv_len
-        o_dense = ops.ragged_prefill_attention(
-            q_dense.reshape(S, Q, nkv, nh // nkv, hd).astype(cfg.dtype),
-            k_pages, v_pages, table, rows.kv_len,
+        o_many = ops.ragged_prefill_attention(
+            q, k_pages, v_pages, table, rows.kv_len,
             rows.kv_len - rows.q_counts,
-            jnp.where(one_row, 0, rows.q_counts), **pool)
+            jnp.where(one_row, 0, rows.q_counts), rows.first_row, max_q=Q,
+            **pool)
         o = jnp.where(one_row[slot, None, None],
                       o_one.reshape(S, nh, vd)[slot],
-                      o_dense.reshape(S, Q, nh, vd)[slot, rows.dense_idx])
+                      o_many.reshape(N, nh, vd))
         return jnp.where(valid[:, None, None], o, 0)
 
 
@@ -827,7 +830,6 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     tokens = batch["tokens"]               # [N]
     token_slot = batch["token_slot"]       # [N] (-1 pad)
     token_pos = batch["token_pos"]         # [N]
-    dense_idx = batch["token_dense_idx"]   # [N]
     tables = _group_tables(batch)          # [S, MB] per page group
     block_table = tables[0]
     kv_len = batch["kv_len"]               # [S]
@@ -852,7 +854,9 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
         # span ending at kv_len: SplitFuse chunks)
         q_counts = jnp.zeros((S,), jnp.int32).at[scat_slot].add(
             1, mode="drop")
-        rows = _MixedRows(scat_slot, dense_idx, kv_len, q_counts)
+        first_row = jnp.full((S,), N - 1, jnp.int32).at[scat_slot].min(
+            jnp.arange(N, dtype=jnp.int32), mode="drop")
+        rows = _MixedRows(scat_slot, first_row, kv_len, q_counts)
     # one traced and lowered attention per KIND of layer (window, global),
     # called by every layer of the kind: the two kernels are lowered once a
     # kind and not once a layer, which is most of what a step program costs
@@ -1224,8 +1228,9 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
     slot ingests G contiguous tokens at positions pos0..pos0+G-1 (KV written
     into its pages) and gets logits for ALL G positions back — one program
     scores a whole draft run.  Dense [S, G] layout (no packing: every slot
-    scores the same G), attention through the ragged-prefill op with
-    q_counts=G.  Returns (logits [S, G, V], updated flat views)."""
+    scores the same G), attention through the ragged-prefill op over the
+    ``S * G`` rows, slot ``s`` holding the G from row ``s * G``.  Returns
+    (logits [S, G, V], updated flat views)."""
     from deepspeed_tpu import ops
     bb = params["backbone"]
     dtype = cfg.dtype
@@ -1268,15 +1273,15 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
                                                   cfg.alibi_prescale))
             win = cfg.window_for_layer(li)
             o = ops.ragged_prefill_attention(
-                q.reshape(S, G, nkv, g, hd).astype(dtype), flat_k, flat_v,
+                q.reshape(S * G, nkv, g, hd).astype(dtype), flat_k, flat_v,
                 block_table + li * NB, kv_len, pos0, q_counts,
+                jnp.arange(S, dtype=jnp.int32) * G, max_q=G,
                 scale=cfg.attn_scale, alibi_slopes=slopes, window=win,
                 mesh=mesh, kv_major=km, impl=cfg.attn_impl,
                 **_layer_kv(flat_ks, flat_vs)).reshape(S, G, nh, hd)
-            # inactive slots (kv_len=0, q_counts=0) produce 0/0 garbage from
-            # the kernel combine; zero them like ragged_forward does so no
-            # future cross-row op (capacity MoE, aux stats) can see NaNs
-            # from dead rows
+            # inactive slots (q_counts=0) hold rows the kernel never writes;
+            # zero them like ragged_forward does so no future cross-row op
+            # (capacity MoE, aux stats) can see NaNs from dead rows
             o = jnp.where(active[:, None, None, None], o, 0)
         with jax.named_scope("attn_out"):
             attn_delta = _attn_out(ap, o if gate is None else o * gate, cfg,

@@ -380,7 +380,6 @@ class RaggedBatch:
     tokens: np.ndarray          # [N] int32, pad 0
     token_slot: np.ndarray      # [N] int32, slot of each token, pad -1
     token_pos: np.ndarray       # [N] int32 logical position, pad 0
-    token_dense_idx: np.ndarray  # [N] int32 index within the slot's q rows
     block_table: np.ndarray     # [S, MB] int32, pad 0
     block_table_w: Optional[np.ndarray]  # the window page group's, or None
     kv_len: np.ndarray          # [S] int32 kv length AFTER this step
@@ -718,7 +717,6 @@ def build_ragged_batch(schedule, state: DSStateManager, token_budget: int,
     tokens = np.zeros(N, np.int32)
     token_slot = np.full(N, -1, np.int32)
     token_pos = np.zeros(N, np.int32)
-    token_dense = np.zeros(N, np.int32)
     tables = state.tables()
     kv_len = np.zeros(S, np.int32)
     q_len = np.zeros(S, np.int32)
@@ -735,7 +733,6 @@ def build_ragged_batch(schedule, state: DSStateManager, token_budget: int,
         token_slot[cursor:cursor + n] = sl
         token_pos[cursor:cursor + n] = np.arange(seq.seen_tokens,
                                                  seq.seen_tokens + n)
-        token_dense[cursor:cursor + n] = np.arange(n)
         state.write_tables(tables, seq)
         kv_len[sl] = seq.seen_tokens + n
         q_len[sl] = n
@@ -743,7 +740,7 @@ def build_ragged_batch(schedule, state: DSStateManager, token_budget: int,
         slot_uid[sl] = seq.uid
         cursor += n
     return RaggedBatch(tokens=tokens, token_slot=token_slot,
-                       token_pos=token_pos, token_dense_idx=token_dense,
+                       token_pos=token_pos,
                        block_table=tables[0],
                        block_table_w=tables[1] if state.window else None,
                        kv_len=kv_len, q_len=q_len,

@@ -1,9 +1,12 @@
-"""Paged-attention decode — Pallas TPU kernel over a block-table KV pool.
+"""Paged attention — Pallas TPU kernels over a block-table KV pool.
 
-TPU-native replacement for the reference's blocked flash decode kernels
+TPU-native replacement for the reference's blocked flash kernels
 (inference/v2/kernels/ragged_ops/blocked_flash/ + atom_builder): each serving
 slot owns a list of fixed-size KV pages; decode attends one query token per
-slot over exactly that slot's pages.
+slot over exactly that slot's pages (``paged_decode``, described here), and
+the ragged prefill kernel attends the token-major rows of a mixed step, a
+work list of live (slot, chunk) items over the flat batch (``ragged_prefill``,
+described above its section, further down).
 
 Kernel design (vs the XLA fallback, which masks over gathered pages):
 - grid = (slots,): one grid step attends one slot for EVERY kv head.  A page
@@ -553,38 +556,54 @@ def paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
 # Ragged prefill (VERDICT r2 item 4 — reference blocked_flash + atom_builder)
 # ===================================================================
 #
-# Mixed prefill/decode batches arrive as a dense-per-slot query layout
-# [S, Q, nkv, g, hd] where slot s owns ``q_counts[s]`` live rows holding the
-# CONTIGUOUS positions [q_starts[s], q_starts[s] + q_counts[s]); its KV —
-# including the rows just appended — lives in ``kv_lens[s]`` tokens across
-# the slot's block-table pages.  The XLA fallback gathers every slot's full
-# page span and runs one masked-dense attention (cost O(S · Q · MBmax·bs));
-# the Pallas kernel instead grids over (slot, kv head, q-chunk) and runs a
-# double-buffered HBM→VMEM DMA loop, one page of one head at a time (the loop
-# the decode kernel ran until its block pipeline), over ONLY the pages the
-# chunk can causally see — dead (slot, chunk) pairs are skipped outright, so
-# FLOPs and bandwidth scale with the live CHUNKS of ``cq`` rows (128 at the
-# serving sizes), not S × longest: a live chunk pays for all its ``cq`` rows
-# over every page it sees, however few of them are live.  So the mixed step
-# (inference/v2/model.py ``ragged_forward``) hands a slot that holds ONE row
-# to the paged decode kernel above, whose tile is that row, and passes it
-# here with a count of 0.
+# A mixed prefill/decode step's queries arrive as they leave the projections,
+# token-major [N, nkv, g, hd]: slot s owns the ``q_counts[s]`` rows from
+# ``row_starts[s]`` on, one contiguous span of the flat batch holding the
+# CONTIGUOUS positions [q_starts[s], q_starts[s] + q_counts[s]); its KV,
+# the rows just appended included, lives in ``kv_lens[s]`` tokens across the
+# slot's block-table pages.  The output is token-major too, [N, nkv, g, vd].
+# The XLA fallback gathers every slot's rows dense and its full page span and
+# runs one masked-dense attention (cost O(S · Q · MBmax·bs)).
+#
+# The Pallas kernel walks a WORK LIST: an item is ``cq`` rows (a chunk; 128 at
+# the serving sizes, 32 in the latent form) of one slot, ``ceil(count / cq)``
+# items a slot, built in the program from ``q_counts`` and scalar-prefetched.
+# The grid is (items_max, kv heads) with the static bound ``items_max = N // cq
+# + S``; an item past the live count is a bare grid step.  q and the output
+# stay in HBM: a live item copies ITS ``cq`` rows of its kv head in and its
+# result out, and nothing else moves.  A slot's last chunk overhangs its
+# count: the rows past it are the next slot's of the flat batch (or the
+# batch's pad), read but masked, and on the way out the item first reads what
+# those rows hold and writes that back with its own.  So rows of slots the
+# kernel was told hold nothing (count 0: empty slots, and the one-row slots a
+# mixed step hands the paged decode kernel above, whose tile is that row)
+# come back as they were, whatever order the slots lie in.  Over its rows an
+# item runs a double-buffered HBM→VMEM DMA loop, one page of one head at a
+# time (the loop the decode kernel ran until its block pipeline), over ONLY
+# the pages the chunk can causally see: FLOPs and bandwidth scale with the
+# live chunks, and a live chunk pays for all its ``cq`` rows over every page
+# it sees, however few of them are live.
 
 
 def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
-                       q_counts, *, scale: Optional[float] = None,
+                       q_counts, row_starts, *, max_q: Optional[int] = None,
+                       scale: Optional[float] = None,
                        alibi_slopes=None, window=None, interpret=None,
                        mesh=None, kv_major=False, k_scale=None, v_scale=None,
                        v_dim=None):
-    """Ground-truth gather + masked-dense path (the round-2 prefill body).
+    """Ground-truth gather + masked-dense path (the round-2 prefill body):
+    each slot's rows gathered dense [S, Q, ...] (``Q = max_q``, the most rows
+    a slot can hold; every row of the batch if not said), attended, and
+    scattered back to their flat rows; rows no slot owns come back zero.
     ``k_scale``/``v_scale``: int8-KV dequant after the gather (see
     xla_paged_attention)."""
-    S, Q, nkv, g, hd = q.shape
+    N, nkv, g, hd = q.shape
     if kv_major:
         NB, _, _, bs = k_pages.shape
     else:
         NB, _, bs, _ = k_pages.shape
     MB = block_table.shape[1]
+    Q = N if max_q is None else min(int(max_q), N)
     if scale is None:
         scale = hd ** -0.5
     k_seq = _gather_pages(k_pages, block_table, kv_major)
@@ -598,6 +617,8 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
     rows = jnp.arange(Q)
     qpos = q_starts[:, None] + rows[None, :]                   # [S, Q]
     live = rows[None, :] < q_counts[:, None]                   # [S, Q]
+    flat = jnp.where(live, row_starts[:, None] + rows[None, :], N)
+    q = q[jnp.minimum(flat, N - 1)]                            # [S, Q, ...]
     mask = (kvpos[None, None, :] <= qpos[:, :, None]) \
         & (kvpos[None, None, :] < kv_lens[:, None, None]) \
         & live[:, :, None]                                     # [S, Q, K]
@@ -614,151 +635,183 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
     s_log = jnp.where(m, s_log, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(s_log, axis=-1)
     probs = jnp.where(m.any(-1, keepdims=True), probs, 0.0)
-    return jnp.einsum("snqgk,sknd->sqngd", probs.astype(q.dtype), v_seq)
+    o = jnp.einsum("snqgk,sknd->sqngd", probs.astype(q.dtype), v_seq)
+    return jnp.zeros((N,) + o.shape[2:], o.dtype).at[flat].set(
+        o, mode="drop")
 
 
-def _prefill_kernel(*refs, bs, cq, g, scale, window, has_alibi, kv_major,
+def _prefill_kernel(*refs, bs, cq, g, hd, scale, window, has_alibi, kv_major,
                     quant=False, v_dim=None):
-    """``v_dim``: latent pages, one pool and one buffer: the page is the key
-    and its leading ``v_dim`` columns the value."""
-    if v_dim:
-        bt_ref, len_ref, start_ref, count_ref, \
-            q_ref, k_hbm, o_ref, k_buf, sem = refs
-        slopes_ref = v_hbm = v_buf = None
-    elif quant:
-        if has_alibi:
-            bt_ref, len_ref, start_ref, count_ref, slopes_ref, \
-                q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, \
-                k_buf, v_buf, ks_buf, vs_buf, sem = refs
+    """One grid step = one work item (``cq`` rows of one slot) for one kv
+    head; see the section comment.  The chunk buffers hold ``g`` heads of
+    ``hd`` (``vd``) values in their leading rows and columns: the arrays in
+    HBM are padded to whole tiles (``_tile_pad``).  ``v_dim``: latent pages,
+    one pool and one buffer: the page is the key and its leading ``v_dim``
+    columns the value."""
+    it = iter(refs)
+    bt_ref, len_ref, start_ref, count_ref, row_ref, item_slot_ref, \
+        item_chunk_ref, n_items_ref = (next(it) for _ in range(8))
+    slopes_ref = next(it) if has_alibi else None
+    q_hbm, k_hbm = next(it), next(it)
+    v_hbm = None if v_dim else next(it)
+    ks_hbm, vs_hbm = (next(it), next(it)) if quant else (None, None)
+    o_hbm, q_buf, o_buf, k_buf = next(it), next(it), next(it), next(it)
+    v_buf = None if v_dim else next(it)
+    ks_buf, vs_buf = (next(it), next(it)) if quant else (None, None)
+    sem, row_sem = next(it), next(it)
+    item, h = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(item < n_items_ref[0])
+    def _live():
+        s = item_slot_ref[item]
+        count = count_ref[s]
+        start = start_ref[s]
+        length = len_ref[s]
+        row0 = item_chunk_ref[item] * cq
+        n_rows = jnp.minimum(count - row0, cq)
+        flat0 = row_ref[s] + row0
+
+        def rows_copy(hbm, buf, way, out=False):
+            """The item's ``cq`` rows of kv head ``h`` between the
+            token-major array in HBM and the chunk buffer."""
+            ends = hbm.at[pl.ds(flat0, cq), h], buf
+            return pltpu.make_async_copy(*(ends[::-1] if out else ends),
+                                         row_sem.at[way])
+        rows_copy(q_hbm, q_buf, 0).start()
+        # pages the chunk can causally see: up to its LAST live row's position
+        last_pos = start + jnp.minimum(count, row0 + cq) - 1
+        n_pages = (last_pos + bs) // bs
+        if window is None:
+            p_start = jnp.int32(0)
         else:
-            bt_ref, len_ref, start_ref, count_ref, \
-                q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, \
-                k_buf, v_buf, ks_buf, vs_buf, sem = refs
-            slopes_ref = None
-    elif has_alibi:
-        bt_ref, len_ref, start_ref, count_ref, slopes_ref, \
-            q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem = refs
-    else:
-        bt_ref, len_ref, start_ref, count_ref, \
-            q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem = refs
-        slopes_ref = None
-    if not quant:
-        ks_hbm = vs_hbm = ks_buf = vs_buf = None
-    s, h, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    count = count_ref[s]
-    start = start_ref[s]
-    length = len_ref[s]
-    hd = q_ref.shape[4]
-    row0 = c * cq
-    live = row0 < count
-    # pages the chunk can causally see: up to its LAST live row's position
-    last_pos = start + jnp.minimum(count, row0 + cq) - 1
-    n_pages = jnp.where(live, (last_pos + bs) // bs, 0)
-    if window is None:
-        p_start = jnp.int32(0)
-    else:
-        # the chunk's FIRST row's window start bounds every row's from below
-        p_start = jnp.maximum(start + row0 - window + 1, 0) // bs
+            # the chunk's FIRST row's window start bounds every row's from
+            # below
+            p_start = jnp.maximum(start + row0 - window + 1, 0) // bs
 
-    def dma(hbm, buf, slot, p, way):
-        return pltpu.make_async_copy(
-            hbm.at[bt_ref[s, p], h], buf.at[slot], sem.at[way * 2 + slot])
+        def dma(hbm, buf, slot, p, way):
+            return pltpu.make_async_copy(
+                hbm.at[bt_ref[s, p], h], buf.at[slot],
+                sem.at[way * 2 + slot])
 
-    def start_page(slot, p):
-        dma(k_hbm, k_buf, slot, p, 0).start()
-        if not v_dim:
-            dma(v_hbm, v_buf, slot, p, 1).start()
-        if quant:
-            dma(ks_hbm, ks_buf, slot, p, 2).start()
-            dma(vs_hbm, vs_buf, slot, p, 3).start()
+        def start_page(slot, p):
+            dma(k_hbm, k_buf, slot, p, 0).start()
+            if not v_dim:
+                dma(v_hbm, v_buf, slot, p, 1).start()
+            if quant:
+                dma(ks_hbm, ks_buf, slot, p, 2).start()
+                dma(vs_hbm, vs_buf, slot, p, 3).start()
 
-    @pl.when(n_pages > p_start)
-    def _warmup():
-        start_page(jax.lax.rem(p_start, 2), p_start)
+        @pl.when(n_pages > p_start)
+        def _warmup():
+            start_page(jax.lax.rem(p_start, 2), p_start)
 
-    q = q_ref[0, :, 0].reshape(cq * g, hd)         # [cq·g, hd] row r=(j·g+gi)
-    rown = jax.lax.broadcasted_iota(jnp.int32, (cq * g, bs), 0) // g
-    qpos = start + row0 + rown                     # [cq·g, bs]
-    row_live = row0 + rown < count
-    if has_alibi:
-        # SMEM scalar-prefetch slopes [nkv, g]: row r = j·g+gi needs
-        # slopes[h, r % g] — tile the per-group column cq times
-        sl = jnp.stack([slopes_ref[h, i] for i in range(g)]).reshape(g, 1)
-        slope_rows = jnp.tile(sl, (cq, 1))         # [cq·g, 1]
-
-    def body(p, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(p, 2)
-        nxt = jax.lax.rem(p + 1, 2)
-
-        @pl.when(p + 1 < n_pages)
-        def _prefetch():
-            start_page(nxt, p + 1)
-
-        dma(k_hbm, k_buf, slot, p, 0).wait()
-        k = k_buf[slot]                # [bs, hd] or [hd, bs] (kv-major)
-        if v_dim:
-            v = k[:, :v_dim]
-        else:
-            dma(v_hbm, v_buf, slot, p, 1).wait()
-            v = v_buf[slot]
-        if quant:
-            dma(ks_hbm, ks_buf, slot, p, 2).wait()
-            dma(vs_hbm, vs_buf, slot, p, 3).wait()
-            k, v = _dequant_page(k, v, ks_buf[slot], vs_buf[slot],
-                                 kv_major, q.dtype)
-        k_dims = ((1,), (0,)) if kv_major else ((1,), (1,))
-        scores = jax.lax.dot_general(
-            q, k, (k_dims, ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [cq·g, bs]
-        kvpos = p * bs + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        rown = jax.lax.broadcasted_iota(jnp.int32, (cq * g, bs), 0) // g
+        qpos = start + row0 + rown                     # [cq·g, bs]
+        row_live = row0 + rown < count
         if has_alibi:
-            scores = scores + slope_rows * kvpos.astype(jnp.float32)
-        valid = (kvpos <= qpos) & (kvpos < length) & row_live
-        if window is not None:
-            valid = valid & (kvpos > qpos - window)
-        scores = jnp.where(valid, scores, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-        pr = jnp.exp(scores - m_new)
-        # a row with no valid key in this page AND none so far: m_new is
-        # still -inf and exp aliases to 1 — zero it (dead rows, early rows
-        # of a later page under a window)
-        pr = jnp.where(m_new > _NEG_INF / 2, pr, 0.0)
-        alpha = jnp.exp(m - m_new)
-        l = alpha * l + jnp.sum(pr, axis=1, keepdims=True)
-        v_dims = ((1,), (1,)) if kv_major else ((1,), (0,))
-        pv = jax.lax.dot_general(pr.astype(v.dtype), v,
-                                 (v_dims, ((), ())),
-                                 preferred_element_type=jnp.float32)
-        return m_new, l, acc * alpha + pv
+            # SMEM scalar-prefetch slopes [nkv, g]: row r = j·g+gi needs
+            # slopes[h, r % g] — tile the per-group column cq times
+            sl = jnp.stack([slopes_ref[h, i] for i in range(g)]).reshape(g, 1)
+            slope_rows = jnp.tile(sl, (cq, 1))         # [cq·g, 1]
+        rows_copy(q_hbm, q_buf, 0).wait()
+        # a last chunk's rows past ``n_rows`` are the next slot's (or the
+        # batch's pad): ``row_live`` masks their scores
+        q = q_buf[:, :g, :hd].reshape(cq * g, hd)  # [cq·g, hd] row r=(j·g+gi)
 
-    m0 = jnp.full((cq * g, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((cq * g, 1), jnp.float32)
-    vd = v_dim or hd
-    acc0 = jnp.zeros((cq * g, vd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(p_start, n_pages, body, (m0, l0, acc0))
-    l = jnp.where(l == 0.0, 1.0, l)                # dead rows -> zeros
-    o_ref[0, :, 0] = (acc / l).reshape(cq, g, vd).astype(o_ref.dtype)
+        def body(p, carry):
+            m, l, acc = carry
+            slot = jax.lax.rem(p, 2)
+            nxt = jax.lax.rem(p + 1, 2)
+
+            @pl.when(p + 1 < n_pages)
+            def _prefetch():
+                start_page(nxt, p + 1)
+
+            dma(k_hbm, k_buf, slot, p, 0).wait()
+            k = k_buf[slot]                # [bs, hd] or [hd, bs] (kv-major)
+            if v_dim:
+                v = k[:, :v_dim]
+            else:
+                dma(v_hbm, v_buf, slot, p, 1).wait()
+                v = v_buf[slot]
+            if quant:
+                dma(ks_hbm, ks_buf, slot, p, 2).wait()
+                dma(vs_hbm, vs_buf, slot, p, 3).wait()
+                k, v = _dequant_page(k, v, ks_buf[slot], vs_buf[slot],
+                                     kv_major, q.dtype)
+            k_dims = ((1,), (0,)) if kv_major else ((1,), (1,))
+            scores = jax.lax.dot_general(
+                q, k, (k_dims, ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [cq·g, bs]
+            kvpos = p * bs + jax.lax.broadcasted_iota(jnp.int32,
+                                                      scores.shape, 1)
+            if has_alibi:
+                scores = scores + slope_rows * kvpos.astype(jnp.float32)
+            valid = (kvpos <= qpos) & (kvpos < length) & row_live
+            if window is not None:
+                valid = valid & (kvpos > qpos - window)
+            scores = jnp.where(valid, scores, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+            pr = jnp.exp(scores - m_new)
+            # a row with no valid key in this page AND none so far: m_new is
+            # still -inf and exp aliases to 1 — zero it (dead rows, early
+            # rows of a later page under a window)
+            pr = jnp.where(m_new > _NEG_INF / 2, pr, 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = alpha * l + jnp.sum(pr, axis=1, keepdims=True)
+            v_dims = ((1,), (1,)) if kv_major else ((1,), (0,))
+            pv = jax.lax.dot_general(pr.astype(v.dtype), v,
+                                     (v_dims, ((), ())),
+                                     preferred_element_type=jnp.float32)
+            return m_new, l, acc * alpha + pv
+
+        m0 = jnp.full((cq * g, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((cq * g, 1), jnp.float32)
+        vd = v_dim or hd
+        acc0 = jnp.zeros((cq * g, vd), jnp.float32)
+        m, l, acc = jax.lax.fori_loop(p_start, n_pages, body, (m0, l0, acc0))
+        l = jnp.where(l == 0.0, 1.0, l)                # dead rows -> zeros
+        # the write is ``cq`` rows too, so a last chunk first reads what the
+        # rows past its own hold and writes that back: they are another
+        # slot's (its result already there, or not yet) or nobody's, and
+        # come back as they were.  Grid steps run one after the other and
+        # each waits for its write, so no other item is under way.
+        @pl.when(n_rows < cq)
+        def _theirs():
+            rows_copy(o_hbm, o_buf, 1).start()
+            rows_copy(o_hbm, o_buf, 1).wait()
+        mine = jax.lax.broadcasted_iota(jnp.int32, (cq, g, vd), 0) < n_rows
+        o_buf[:, :g, :vd] = jnp.where(
+            mine, (acc / l).reshape(cq, g, vd).astype(o_buf.dtype),
+            o_buf[:, :g, :vd])
+        rows_copy(o_hbm, o_buf, 1, out=True).start()
+        rows_copy(o_hbm, o_buf, 1, out=True).wait()
 
 
 def pallas_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
-                          q_counts, *, scale: Optional[float] = None,
+                          q_counts, row_starts, *,
+                          max_q: Optional[int] = None,
+                          scale: Optional[float] = None,
                           alibi_slopes=None, window=None,
                           interpret: Optional[bool] = None, mesh=None,
                           kv_major=False, k_scale=None, v_scale=None,
                           v_dim=None):
+    """Rows of slots with ``q_counts`` 0, and rows no slot owns, come back
+    as the fresh output buffer held them (nothing): the caller does not read
+    them (the mixed step selects the paged decode kernel's result for them,
+    or zero)."""
     if (mesh is not None and mesh.shape.get("tp", 1) > 1 and v_pages is not None
-            and q.shape[2] % mesh.shape["tp"] == 0):
+            and q.shape[1] % mesh.shape["tp"] == 0):
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
-        inner = functools.partial(_pallas_ragged_prefill_local, scale=scale,
-                                  window=window, interpret=interpret,
-                                  kv_major=kv_major)
-        q_spec = P(None, None, "tp", None, None)
+        inner = functools.partial(_pallas_ragged_prefill_local, max_q=max_q,
+                                  scale=scale, window=window,
+                                  interpret=interpret, kv_major=kv_major)
         kv_spec = P(None, "tp", None, None)
-        in_specs = [q_spec, kv_spec, kv_spec, P(None, None), P(None),
-                    P(None), P(None)]
-        args = [q, k_pages, v_pages, block_table, kv_lens, q_starts, q_counts]
+        in_specs = [kv_spec, kv_spec, kv_spec, P(None, None), P(None),
+                    P(None), P(None), P(None)]
+        args = [q, k_pages, v_pages, block_table, kv_lens, q_starts, q_counts,
+                row_starts]
         n_scales = 0
         if k_scale is not None:        # [NB, nkv, bs]: kv-head axis shards
             args += [k_scale, v_scale]
@@ -766,24 +819,24 @@ def pallas_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
             n_scales = 2
         if alibi_slopes is not None:
             args.append(jnp.asarray(alibi_slopes, jnp.float32).reshape(
-                q.shape[2], q.shape[3]))
+                q.shape[1], q.shape[2]))
             in_specs.append(P("tp", None))
 
-        def wrapped(q_, k_, v_, bt_, lens_, st_, ct_, *rest):
+        def wrapped(q_, k_, v_, bt_, lens_, st_, ct_, rs_, *rest):
             sc = rest[:n_scales]
             sl = rest[n_scales:]
-            return inner(q_, k_, v_, bt_, lens_, st_, ct_,
+            return inner(q_, k_, v_, bt_, lens_, st_, ct_, rs_,
                          k_scale=sc[0] if sc else None,
                          v_scale=sc[1] if sc else None,
                          alibi_slopes=sl[0] if sl else None)
         return shard_map(
             wrapped, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=q_spec, check_vma=False,
+            out_specs=kv_spec, check_vma=False,
         )(*args)
     return _pallas_ragged_prefill_local(
         q, k_pages, v_pages, block_table, kv_lens, q_starts, q_counts,
-        scale=scale, alibi_slopes=alibi_slopes, window=window,
-        interpret=interpret, kv_major=kv_major,
+        row_starts, max_q=max_q, scale=scale, alibi_slopes=alibi_slopes,
+        window=window, interpret=interpret, kv_major=kv_major,
         k_scale=k_scale, v_scale=v_scale, v_dim=v_dim)
 
 
@@ -794,9 +847,10 @@ _ACC_BYTES = 1 << 20
 
 
 def _prefill_chunk(Q: int, g: int = 1, vd: int = 128) -> Optional[int]:
-    """Query rows a grid step of the prefill kernel attends: the largest
-    power of two up to 128 that divides ``Q`` and keeps the accumulator of
-    its ``cq * g`` rows of ``vd`` values within ``_ACC_BYTES``."""
+    """Query rows a work item of the prefill kernel attends: the largest
+    power of two up to 128 that divides ``Q`` (the most rows a slot holds)
+    and keeps the accumulator of its ``cq * g`` rows of ``vd`` values within
+    ``_ACC_BYTES``."""
     for cq in (128, 64, 32, 16, 8, 4, 2, 1):
         if cq <= Q and Q % cq == 0 and (cq * g * vd * 4 <= _ACC_BYTES
                                         or cq == 1):
@@ -804,14 +858,35 @@ def _prefill_chunk(Q: int, g: int = 1, vd: int = 128) -> Optional[int]:
     return None
 
 
+def prefill_grid_items(N: int, S: int, Q: int, cq: int) -> int:
+    """The static bound on a call's work items: a flat batch of ``N`` rows
+    over ``S`` slots of at most ``Q`` rows, ``cq`` rows an item (``cq``
+    divides ``Q``: ``_prefill_chunk``)."""
+    return min(N // cq + S, S * (Q // cq))
+
+
+def _tile_pad(g: int, width: int, dtype):
+    """(heads, width) of a token-major array [N, nkv, g, width] padded to
+    whole tiles of its two minor dims, which is how the array lies in HBM
+    whatever its shape says and the only slabs a copy may take of it: the
+    width to whole lanes, the heads to the compiler's sublane tile for a dim
+    of ``g`` (a power of two from the dtype's packing up to 8 rows)."""
+    tile = 4 // jnp.dtype(dtype).itemsize or 1
+    while tile < min(g, 8):
+        tile *= 2
+    return -(-g // tile) * tile, -(-width // 128) * 128
+
+
 def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
-                                 q_starts, q_counts, *,
+                                 q_starts, q_counts, row_starts, *,
+                                 max_q: Optional[int] = None,
                                  scale: Optional[float] = None,
                                  alibi_slopes=None, window=None,
                                  interpret: Optional[bool] = None,
                                  kv_major=False, k_scale=None, v_scale=None,
                                  v_dim=None):
-    S, Q, nkv, g, hd = q.shape
+    N, nkv, g, hd = q.shape
+    S = block_table.shape[0]
     bs = k_pages.shape[3] if kv_major else k_pages.shape[2]
     if scale is None:
         scale = hd ** -0.5
@@ -819,67 +894,86 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
         interpret = jax.default_backend() != "tpu"
     latent = v_pages is None
     vd = int(v_dim) if latent else hd
+    Q = N if max_q is None else min(int(max_q), N)
     cq = _prefill_chunk(Q, g, vd)
-    block_table = block_table.astype(jnp.int32)
-    kv_lens = kv_lens.astype(jnp.int32)
-    q_starts = q_starts.astype(jnp.int32)
     q_counts = q_counts.astype(jnp.int32)
     has_alibi = alibi_slopes is not None
     quant = k_scale is not None
 
-    grid = (S, nkv, Q // cq)
+    # the work list: slot after slot, each slot's chunks in order.  Item i
+    # is of the slot whose chunks end past i, and its chunk is i less the
+    # chunks of the slots before.  (Plain lax: this is traced for every step
+    # program, and set-up pays for every jitted helper it calls.)
+    lax = jax.lax
+    items_max = prefill_grid_items(N, S, Q, cq)
+    chunks = lax.shift_right_logical(q_counts + (cq - 1),
+                                     jnp.int32(cq.bit_length() - 1))
+    ends = lax.cumsum(chunks)
+    idx = lax.iota(jnp.int32, items_max)
+    before = lax.ge(lax.broadcast_in_dim(idx, (items_max, S), (0,)),
+                    lax.broadcast_in_dim(ends, (items_max, S), (1,)))
+    item_slot = lax.min(
+        lax.reduce(lax.convert_element_type(before, jnp.int32), jnp.int32(0),
+                   lax.add, (1,)), jnp.int32(S - 1))
+    item_chunk = idx - lax.reduce(
+        lax.select(before, lax.broadcast_in_dim(chunks, (items_max, S), (1,)),
+                   lax.full((items_max, S), 0, jnp.int32)),
+        jnp.int32(0), lax.add, (1,))
+
     kernel = functools.partial(
-        _prefill_kernel, bs=bs, cq=cq, g=g, scale=float(scale),
+        _prefill_kernel, bs=bs, cq=cq, g=g, hd=hd, scale=float(scale),
         window=int(window) if window is not None else None,
         has_alibi=has_alibi, kv_major=kv_major, quant=quant,
         v_dim=vd if latent else None)
-    n_prefetch = 4
-    prefetch = [block_table, kv_lens, q_starts, q_counts]
+    prefetch = [block_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
+                q_starts.astype(jnp.int32), q_counts,
+                row_starts.astype(jnp.int32), item_slot, item_chunk,
+                lax.slice(ends, (S - 1,), (S,))]
     if has_alibi:
-        n_prefetch = 5
         prefetch.append(jnp.asarray(alibi_slopes, jnp.float32).reshape(
             nkv, g))
+    (gp, hp), (_, vp) = _tile_pad(g, hd, q.dtype), _tile_pad(g, vd, q.dtype)
+    # ... and ``cq`` rows more, for the last chunk of the batch's last slot
+    q = lax.pad(q, jnp.zeros((), q.dtype),
+                ((0, cq, 0), (0, 0, 0), (0, gp - g, 0), (0, hp - hd, 0)))
     pools = [k_pages] if latent else [k_pages, v_pages]
-    in_specs = [pl.BlockSpec((1, cq, 1, g, hd),
-                             lambda s, h, c, *_: (s, c, h, 0, 0))]
-    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
     inputs = [q] + pools
     buf_shape = (2, hd, bs) if kv_major else (2, bs, hd)
-    scratch = [pltpu.VMEM(buf_shape, pool.dtype) for pool in pools]
+    scratch = [pltpu.VMEM((cq, gp, hp), q.dtype),
+               pltpu.VMEM((cq, gp, vp), q.dtype)]
+    scratch += [pltpu.VMEM(buf_shape, pool.dtype) for pool in pools]
     if quant:
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                     pl.BlockSpec(memory_space=pl.ANY)]
         inputs += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
         scratch += [pltpu.VMEM((2, bs), jnp.float32),
                     pltpu.VMEM((2, bs), jnp.float32)]
-    scratch.append(pltpu.SemaphoreType.DMA((8 if quant else 4,)))
+    scratch += [pltpu.SemaphoreType.DMA((8 if quant else 4,)),
+                pltpu.SemaphoreType.DMA((2,))]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_prefetch,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, cq, 1, g, vd),
-                                   lambda s, h, c, *_: (s, c, h, 0, 0)),
+            num_scalar_prefetch=len(prefetch),
+            grid=(items_max, nkv),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(inputs),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((S, Q, nkv, g, vd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((N + cq, nkv, gp, vp), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="ragged_prefill",
     )(*prefetch, *inputs)
-    return out
+    return lax.slice(out, (0, 0, 0, 0), (N, nkv, g, vd))
 
 
 def ragged_prefill_supported(q, k_pages, v_pages, block_table, kv_lens,
-                             q_starts, q_counts, *, scale=None,
-                             alibi_slopes=None, window=None, interpret=None,
-                             mesh=None, kv_major=False,
+                             q_starts, q_counts, row_starts, *, max_q=None,
+                             scale=None, alibi_slopes=None, window=None,
+                             interpret=None, mesh=None, kv_major=False,
                              k_scale=None, v_scale=None, v_dim=None):
-    if q.ndim != 5 or k_pages.ndim != 4:
+    if q.ndim != 4 or k_pages.ndim != 4:
         return False
-    S, Q, nkv, g, hd = q.shape
+    N, nkv, g, hd = q.shape
     if kv_major:
         NB, nkv2, hd2, bs = k_pages.shape
     else:
@@ -895,23 +989,28 @@ def ragged_prefill_supported(q, k_pages, v_pages, block_table, kv_lens,
     return (nkv == nkv2 and hd == hd2
             and _latent_ok(v_pages, v_dim, hd, kv_major, quant, alibi_slopes)
             and _dma_layout_ok(hd, bs, kv_major, quant=quant)
-            and _prefill_chunk(Q, g, v_dim or hd) is not None
-            and block_table.ndim == 2 and block_table.shape[0] == S)
+            and block_table.ndim == 2
+            and row_starts.shape == (block_table.shape[0],))
 
 
 def ragged_prefill_attention(q, k_pages, v_pages, block_table, kv_lens,
-                             q_starts, q_counts, *,
+                             q_starts, q_counts, row_starts, *,
+                             max_q: Optional[int] = None,
                              scale: Optional[float] = None,
                              alibi_slopes=None, window=None,
                              impl: Optional[str] = None,
                              interpret: Optional[bool] = None, mesh=None,
                              kv_major=False, k_scale=None, v_scale=None,
                              v_dim: Optional[int] = None):
-    """Registry entry for the ragged prefill kernel.  ``v_pages=None`` with
-    ``v_dim``: latent pages (module docstring)."""
+    """Registry entry for the ragged prefill kernel: token-major ``q``
+    [N, nkv, g, hd] -> [N, nkv, g, vd]; slot ``s`` owns rows ``row_starts[s]
+    + [0, q_counts[s])`` at positions ``q_starts[s] + [0, q_counts[s])``,
+    at most ``max_q`` of them.  ``v_pages=None`` with ``v_dim``: latent pages
+    (module docstring)."""
     from deepspeed_tpu.ops.registry import dispatch
     return dispatch("ragged_prefill_attention", q, k_pages, v_pages,
-                    block_table, kv_lens, q_starts, q_counts, scale=scale,
+                    block_table, kv_lens, q_starts, q_counts, row_starts,
+                    max_q=max_q, scale=scale,
                     alibi_slopes=alibi_slopes, window=window, impl=impl,
                     interpret=interpret, mesh=mesh, kv_major=kv_major,
                     k_scale=k_scale, v_scale=v_scale,

@@ -129,6 +129,14 @@ class ServingTelemetry:
             "held exactly one row (a riding decode row, a prompt's "
             "one-token tail): the paged decode kernel attends them, the "
             "prefill kernel the others")
+        self.c_prefill_items = reg.counter(
+            "serving_prefill_items_total", "work items (a chunk of one "
+            "slot's rows) the ragged prefill kernel attended in mixed "
+            "dispatches, a layer")
+        self.c_prefill_grid = reg.counter(
+            "serving_prefill_grid_items_total", "work items the ragged "
+            "prefill kernel's grid had room for in mixed dispatches, a "
+            "layer: the static bound its live items are counted against")
         self.c_preempt = reg.counter(
             "serving_preemptions_total", "recompute-preemption victims "
             "taken, per victim state (decode_ready / mid_prefill)")
@@ -351,12 +359,15 @@ class ServingTelemetry:
         if self.enabled and n:
             self.c_tokens.inc(n, phase=phase, **self.labels)
 
-    def mixed_slots(self, rows) -> None:
-        """One mixed dispatch's slots, by the rows each holds."""
+    def mixed_slots(self, rows, items: int, grid_items: int) -> None:
+        """One mixed dispatch's slots, by the rows each holds, and the
+        prefill kernel's work items: live, and what its grid is bound to."""
         if self.enabled:
             self.c_mixed_slots.inc(len(rows), **self.labels)
             self.c_one_row_slots.inc(sum(n == 1 for n in rows),
                                      **self.labels)
+            self.c_prefill_items.inc(items, **self.labels)
+            self.c_prefill_grid.inc(grid_items, **self.labels)
 
     def moe_stats(self, vec) -> None:
         """One dispatch's MoE counter vector (model.py ``_ffn``): [local
@@ -377,15 +388,18 @@ class ServingTelemetry:
         """Running totals for a dispatch span's args, so that a trace holds
         them (a reader takes the difference between two dispatches): the
         slots mixed dispatches served and those of them with one row, the
-        MoE counters as far as the device has reported and the window page
-        group's; the last two only for a model that has them."""
+        prefill kernel's live work items and its grid's, the MoE counters
+        as far as the device has reported and the window page group's; the
+        last two only for a model that has them."""
         note: Dict[str, int] = {}
         if not self.enabled:
             return note
         note.update(
             kv_bytes_per_token=self.kv_bytes_per_token,
             mixed_seqs=int(self.c_mixed_slots.value(**self.labels)),
-            one_row_seqs=int(self.c_one_row_slots.value(**self.labels)))
+            one_row_seqs=int(self.c_one_row_slots.value(**self.labels)),
+            prefill_items=int(self.c_prefill_items.value(**self.labels)),
+            prefill_grid_items=int(self.c_prefill_grid.value(**self.labels)))
         total = self.c_moe_assign.value(**self.labels)
         if total:
             note.update(

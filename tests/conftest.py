@@ -122,7 +122,7 @@ def lower_serving_steps(cfg, cache_dtype, *, slots, tokens, max_q, table_width,
         "ragged_forward_sampled": (
             dict(max_q_per_seq=max_q),
             {"tokens": sd((N,), i32), "token_slot": sd((N,), i32),
-             "token_pos": sd((N,), i32), "token_dense_idx": sd((N,), i32),
+             "token_pos": sd((N,), i32),
              "block_table": sd((S, MB), i32), "kv_len": sd((S,), i32),
              "from_device": sd((N,), b1), "served": sd((S,), b1)}),
         "ragged_decode_sampled": (

@@ -463,6 +463,8 @@ COUNTERS = {
     "serving_tokens_total{phase=decode}": ("dense", {"phase": "decode"}),
     "serving_mixed_slots_total": ("dense", {}),
     "serving_one_row_slots_total": ("dense", {}),
+    "serving_prefill_items_total": ("dense", {}),
+    "serving_prefill_grid_items_total": ("dense", {}),
     "moe_assignments_total": ("afmoe", {}),
     "moe_local_assignments_total": ("afmoe", {}),
     "moe_experts_touched_total": ("afmoe", {}),
@@ -520,6 +522,11 @@ AFMOE_SPAN_ARGS = [
     ("ds.burst_dispatch", "kvw_released"),
     ("ds.mixed_dispatch", "kv_pages_window"),
     ("ds.mixed_dispatch", "kv_pages_global"),
+    # span_counters: the prefill kernel's live work items and its grid's
+    ("ds.mixed_dispatch", "prefill_items"),
+    ("ds.mixed_dispatch", "prefill_grid_items"),
+    ("ds.burst_dispatch", "prefill_items"),
+    ("ds.burst_dispatch", "prefill_grid_items"),
 ]
 
 
@@ -530,7 +537,7 @@ def test_afmoe_dispatch_span_carries(afmoe_served, span, arg):
     assert got, sorted({a["name"] for a in notes})
     assert all(arg in a["args"] for a in got), (span, arg)
     values = [float(a["args"][arg]) for a in got]
-    if arg.startswith(("moe_", "kvw_")):        # running totals never fall
+    if arg.startswith(("moe_", "kvw_", "prefill_")):    # totals never fall
         assert values == sorted(values) and values[-1] > 0
     else:
         assert min(values) >= 0
@@ -578,6 +585,9 @@ def _latent_span_arg(span, arg):
         eng, notes = o
         got = [a for a in notes if a["name"] == span]
         assert got and all(arg in a["args"] for a in got), (span, arg)
+        if arg.startswith("prefill_"):      # live items within the grid's
+            assert all(0 < float(a["args"]["prefill_items"])
+                       < float(a["args"]["prefill_grid_items"]) for a in got)
         if arg == "kv_bytes_per_token":     # 3 layers x 256 columns x fp32
             assert {float(a["args"][arg]) for a in got} == {3 * 256 * 4.0}
             assert eng.telemetry.value("kv_bytes_per_token") == 3 * 256 * 4
@@ -605,7 +615,9 @@ LATENT_READS = {
         ("ds.mixed_dispatch", "one_row_slots"),
         ("ds.mixed_dispatch", "ctx_tokens_one_row"),
         ("ds.mixed_dispatch", "moe_local"),
-        ("ds.burst_dispatch", "moe_touched")]},
+        ("ds.burst_dispatch", "moe_touched"),
+        ("ds.mixed_dispatch", "prefill_items"),
+        ("ds.mixed_dispatch", "prefill_grid_items")]},
     "model_cfg.kv_lora_rank,latent_dim,num_layers,num_heads":
         _latent_model_cfg,
     "eng.put(with_routes=True)": _latent_put_with_routes,

@@ -171,16 +171,18 @@ def _latent(v_dim):
 
 def prefill_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8,
                  Q=128, window=None, v_dim=None):
+    """The token-major kernel over a flat batch of ``S * Q`` rows, at most
+    ``Q`` a slot."""
     page, scale = _pool(nkv, hd, bs, kv_major, quant)
-    specs = [sds((S, Q, nkv, g, hd), BF16), page, None if v_dim else page,
+    specs = [sds((S * Q, nkv, g, hd), BF16), page, None if v_dim else page,
              sds((S, MB), I32), sds((S,), I32), sds((S,), I32),
-             sds((S,), I32)]
+             sds((S,), I32), sds((S,), I32)]
     if quant:
         specs += [scale, scale]
 
-    def fn(q, k, v, bt, lens, st, ct, *sc):
+    def fn(q, k, v, bt, lens, st, ct, rs, *sc):
         kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
-        return pallas_ragged_prefill(q, k, v, bt, lens, st, ct,
+        return pallas_ragged_prefill(q, k, v, bt, lens, st, ct, rs, max_q=Q,
                                         interpret=False, kv_major=kv_major,
                                         window=window, **kw,
                                         **_latent(v_dim))
@@ -312,6 +314,16 @@ POOL_GEOMETRIES = {
     "gpt2s-bf16-tp2": (dataclasses.replace(GPT2S, num_layers=2), None, 2,
                        16 << 20),
 }
+# ... and one more for what the mixed step's attention holds, which does not
+# depend on the experts or the window: Trinity-Large-Preview's attention
+# widths (48 query heads in groups of 6 over 8 kv heads of 128), dense layers
+# (its weights' layout copies are larger than a layer's pages here, so the
+# pool tests do not take it)
+STEP_GEOMETRIES = {
+    **POOL_GEOMETRIES,
+    "trinity-bf16": (GPTConfig.llama(num_layers=2, hidden=6144, heads=48,
+                                     num_kv_heads=8, vocab_size=32768,
+                                     max_seq_len=4096), None, 1, None)}
 POOL_MOVERS = ("copy", "slice", "dynamic-slice", "copy-start", "slice-start",
                "all-gather", "all-gather-start", "all-to-all")
 _HLO_OP = re.compile(
@@ -335,7 +347,7 @@ def step_programs(topo):
 
     def get(geometry):
         if geometry not in done:
-            base, quant, tp, _ = POOL_GEOMETRIES[geometry]
+            base, quant, tp, _ = STEP_GEOMETRIES[geometry]
             cfg = dataclasses.replace(base, dtype=BF16, param_dtype=BF16,
                                       attn_impl="pallas")
             where = dict(sharding=SingleDeviceSharding(topo.devices[0]))
@@ -394,7 +406,7 @@ def test_step_programs_leave_the_pool_in_place(step_programs, monkeypatch,
         assert temp < POOL_GEOMETRIES[geometry][3], temp
 
 
-@pytest.mark.parametrize("geometry", sorted(POOL_GEOMETRIES))
+@pytest.mark.parametrize("geometry", sorted(STEP_GEOMETRIES))
 def test_mixed_step_has_both_attention_kernels_a_layer(step_programs,
                                                        monkeypatch, geometry):
     """The mixed program attends one-row slots with the paged decode kernel
@@ -417,6 +429,39 @@ def test_mixed_step_has_both_attention_kernels_a_layer(step_programs,
     for program in ("ragged_decode_sampled", "ragged_decode_burst"):
         assert kernels(program)[0] == {"paged_decode": cfg.num_layers,
                                        "ragged_prefill": 0}
+
+
+@pytest.mark.parametrize("geometry", ["hd128-bf16", "trinity-bf16",
+                                      "latent-bf16"])
+def test_mixed_step_lays_no_rows_out_dense(step_programs, monkeypatch,
+                                           geometry):
+    """The mixed step's attention takes the token-major rows as they are
+    (model.py ``_mixed_attention``): at the Mistral, Trinity and Moonlight
+    geometries the compiled program holds no array of ``slots x chunk x
+    heads x head width`` elements or more that is neither the pool nor a
+    weight.  The dense ``[slots, chunk]`` query and output layouts were two
+    such arrays a layer, with a scatter, two gathers and the compiler's
+    copies and broadcasts around them."""
+    from deepspeed_tpu.inference.v2.model import _attn_geometry
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, cache, compiled = step_programs(geometry)
+    _, hd, vd, _ = _attn_geometry(cfg)
+    dense = 32 * 256 * cfg.num_heads * min(hd, vd)      # the fixture's sizes
+    known = {",".join(map(str, w.shape))
+             for w in jax.tree_util.tree_leaves(params)}
+    pool = cache.k.shape
+    # the pool, all layers flat, and as the rows the KV write scatters
+    known |= {",".join(map(str, pool)),
+              ",".join(map(str, (pool[0] * pool[1],) + pool[2:])),
+              f"{int(np.prod(pool[:-1]))},{pool[-1]}"}
+    large = []
+    for result, op in _HLO_OP.findall(
+            compiled["ragged_forward_sampled"].as_text()):
+        for dt, dims in _HLO_ARRAY.findall(result):
+            n = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+            if n >= dense and dims not in known:
+                large.append(f"{op} -> {dt}[{dims}]")
+    assert not large, large
 
 
 # -------------------------------------------------------- quantized GEMMs

@@ -79,7 +79,7 @@ def _mixed_step(engine, uids, toks, prefill_route=False):
                             sm.max_q_per_seq)
     batch = jax.tree_util.tree_map(jnp.asarray, {
         "tokens": rb.tokens, "token_slot": rb.token_slot,
-        "token_pos": rb.token_pos, "token_dense_idx": rb.token_dense_idx,
+        "token_pos": rb.token_pos,
         **rb.table_operands(), "kv_len": rb.kv_len})
     step = jax.jit(functools.partial(
         ragged_forward, cfg=engine.model_config,
@@ -102,15 +102,15 @@ def _prefill_route_for_every_slot(monkeypatch, kv_len, q_len):
     from deepspeed_tpu.ops.paged_attention import xla_ragged_prefill
     kv_len, q_len = jnp.asarray(kv_len), jnp.asarray(q_len)
 
-    def prefill(q, k, v, table, kv_lens, q_starts, q_counts, *, impl=None,
-                **kw):
+    def prefill(q, k, v, table, kv_lens, q_starts, q_counts, row_starts, *,
+                impl=None, **kw):
         return xla_ragged_prefill(q, k, v, table, kv_len, kv_len - q_len,
-                                  q_len, **kw)
+                                  q_len, row_starts, **kw)
 
     def decode(q, k, v, table, kv_lens, *, impl=None, **kw):
-        return xla_ragged_prefill(q[:, None], k, v, table, kv_len,
-                                  kv_len - q_len, jnp.minimum(q_len, 1),
-                                  **kw)[:, 0]
+        return xla_ragged_prefill(q, k, v, table, kv_len, kv_len - q_len,
+                                  jnp.minimum(q_len, 1),
+                                  jnp.arange(q.shape[0]), max_q=1, **kw)
     monkeypatch.setattr(ops, "ragged_prefill_attention", prefill)
     monkeypatch.setattr(ops, "paged_attention", decode)
 

@@ -226,17 +226,20 @@ def test_latent_kernels_at_576_over_512_match_the_xla_fallbacks(window):
                                  **kw)
     assert got.shape == (S, 1, g, vd)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    # token-major rows: the slots' spans one after the other (5, 16 and 9
+    # rows; slot 0 holds none), then three rows no slot owns
     Q = 16
-    qq = jnp.asarray(rng.standard_normal((S, Q, 1, g, kd)),
-                     jnp.float32).at[..., 576:].set(0)
     counts = jnp.asarray([0, 5, 16, 9], jnp.int32)
+    first = jnp.asarray([0, 0, 5, 21], jnp.int32)
+    qq = jnp.asarray(rng.standard_normal((33, 1, g, kd)),
+                     jnp.float32).at[..., 576:].set(0)
     want = xla_ragged_prefill(qq, k, None, bt, lens, lens - counts, counts,
-                              **kw)
+                              first, max_q=Q, **kw)
     got = pallas_ragged_prefill(qq, poisoned, None, bt, lens, lens - counts,
-                                counts, interpret=True, **kw)
-    rows = (np.arange(Q)[None, :] < np.asarray(counts)[:, None])
+                                counts, first, max_q=Q, interpret=True, **kw)
+    assert got.shape == (33, 1, g, vd)
     np.testing.assert_allclose(
-        np.asarray(got)[rows], np.asarray(want)[rows], atol=2e-5)
+        np.asarray(got)[:30], np.asarray(want)[:30], atol=2e-5)
     # the registry's predicate: latent pages are Pallas's when the value is
     # whole lane tiles; a GQA call is what it was
     page = jax.ShapeDtypeStruct((NB, 1, 128, kd), jnp.bfloat16)
@@ -246,8 +249,8 @@ def test_latent_kernels_at_576_over_512_match_the_xla_fallbacks(window):
     assert not supported(*args) and not supported(*args[:2], page, bt, lens,
                                                   v_dim=512)
     assert ragged_prefill_supported(
-        jax.ShapeDtypeStruct((S, 128, 1, g, kd), jnp.bfloat16), page, None,
-        bt, lens, lens, lens, v_dim=512)
+        jax.ShapeDtypeStruct((S * 128, 1, g, kd), jnp.bfloat16), page, None,
+        bt, lens, lens, lens, lens, max_q=128, v_dim=512)
     # 16 heads on a 512-wide value take a chunk of 32 rows; GQA keeps 128
     assert _prefill_chunk(1024, 16, 512) == 32
     assert _prefill_chunk(1024, 4, 128) == _prefill_chunk(1024, 6, 128) == 128

@@ -507,13 +507,16 @@ PHASE_ARGS = {"ds.gate": ("released", "late_ms_max"), "ds.idle_sleep": (),
 DISPATCH_ARGS = {
     "ds.mixed_dispatch": ("tokens", "bucket", "seqs", "ctx_tokens",
                           "mixed_seqs", "one_row_seqs", "kv_bytes_per_token",
-                          "qk_pairs", "one_row_slots", "ctx_tokens_one_row"),
+                          "qk_pairs", "one_row_slots", "ctx_tokens_one_row",
+                          "prefill_items", "prefill_grid_items"),
     "ds.decode_dispatch": ("tokens", "bucket", "seqs", "ctx_tokens",
                            "mixed_seqs", "one_row_seqs",
-                           "kv_bytes_per_token"),
+                           "kv_bytes_per_token", "prefill_items",
+                           "prefill_grid_items"),
     "ds.burst_dispatch": ("tokens", "steps", "seqs", "ctx_tokens",
                           "mixed_seqs", "one_row_seqs",
-                          "kv_bytes_per_token")}
+                          "kv_bytes_per_token", "prefill_items",
+                          "prefill_grid_items")}
 
 
 @pytest.mark.parametrize("arg", ROUND_ARGS)
@@ -541,7 +544,8 @@ def test_dispatch_span_carries(generate_trace, name, arg):
     assert all(arg in a["args"] for a in got), (name, arg)
     values = [float(a["args"][arg]) for a in got]
     # the running totals never fall; the others are counts of the step
-    assert values == sorted(values) if arg.endswith("_seqs") \
+    assert values == sorted(values) \
+        if arg.endswith("_seqs") or arg.startswith("prefill_") \
         else min(values) >= 0
 
 

@@ -265,15 +265,12 @@ class TestEngineServingTelemetry:
             q["free_kv_blocks"]
         assert 0 < stel.value("serving_batch_occupancy") <= 1.0
 
-    def test_dispatch_spans_carry_the_mixed_slot_totals(self, cfg, v2cfg,
-                                                        rng):
-        """``mixed_seqs`` / ``one_row_seqs``: running totals of the slots
-        mixed dispatches served and of those with one row, in the args of
-        every dispatch span.  The schedule handed to each step says what
-        they have to read."""
+    @staticmethod
+    def _served_steps(cfg, v2cfg, rng):
+        """(telemetry, each sampled step's rows a sequence) of six prompts
+        served over four slots."""
         eng = InferenceEngineV2(cfg, config=v2cfg, seed=0)
-        stel = eng.telemetry
-        steps = []                  # each sampled step's rows a sequence
+        steps = []
         inner = eng._step_sampled
 
         def spy(uids, toks_np, *a, **kw):
@@ -281,8 +278,17 @@ class TestEngineServingTelemetry:
             return inner(uids, toks_np, *a, **kw)
         eng._step_sampled = spy
         prompts = [rng.integers(0, 97, (n,)).astype(np.int32)
-                   for n in (9, 23, 5, 30, 12, 7)]       # 6 prompts, 4 slots
+                   for n in (9, 23, 5, 30, 12, 7)]
         eng.generate(prompts, max_new_tokens=20)
+        return eng.telemetry, steps
+
+    def test_dispatch_spans_carry_the_mixed_slot_totals(self, cfg, v2cfg,
+                                                        rng):
+        """``mixed_seqs`` / ``one_row_seqs``: running totals of the slots
+        mixed dispatches served and of those with one row, in the args of
+        every dispatch span.  The schedule handed to each step says what
+        they have to read."""
+        stel, steps = self._served_steps(cfg, v2cfg, rng)
         spans = [e for e in stel.tracer.events
                  if e["name"] in ("mixed_dispatch", "decode_dispatch",
                                   "burst_dispatch")]
@@ -309,6 +315,38 @@ class TestEngineServingTelemetry:
         assert stel.value("serving_mixed_slots_total") == slots
         assert stel.value("serving_one_row_slots_total") == ones
         assert 0 < ones < slots
+
+    def test_dispatch_spans_carry_the_prefill_item_totals(self, cfg, v2cfg,
+                                                          rng):
+        """``prefill_items`` / ``prefill_grid_items``: running totals of the
+        ragged prefill kernel's live work items (a chunk of a slot that
+        holds more than one row) and of the items its grid had room for, a
+        layer, in the args of every dispatch span: what the kernel's own
+        rule gives for each mixed step's schedule."""
+        from deepspeed_tpu.ops.paged_attention import (_prefill_chunk,
+                                                       prefill_grid_items)
+        stel, steps = self._served_steps(cfg, v2cfg, rng)
+        sm = v2cfg["state_manager"]
+        S, Q = sm["max_tracked_sequences"], sm["max_q_per_seq"]
+        cq = _prefill_chunk(Q, 1, cfg.head_dim)         # 16 rows: one chunk
+        want, live, grid = [], 0, 0
+        for rows in steps:
+            if max(rows) > 1:
+                bucket = min(max(64, 1 << (sum(rows) - 1).bit_length()),
+                             sm["max_ragged_batch_size"])
+                live += sum(-(-n // cq) for n in rows if n > 1)
+                grid += prefill_grid_items(bucket, S, Q, cq)
+                want.append((live, grid))
+        spans = [e for e in stel.tracer.events
+                 if e["name"].endswith("_dispatch")]
+        got = [(e["args"]["prefill_items"], e["args"]["prefill_grid_items"])
+               for e in spans if e["name"] == "mixed_dispatch"]
+        assert got == want and 0 < live < grid
+        assert all((e["args"]["prefill_items"],
+                    e["args"]["prefill_grid_items"]) in [(0, 0)] + want
+                   for e in spans)
+        assert stel.value("serving_prefill_items_total") == live
+        assert stel.value("serving_prefill_grid_items_total") == grid
 
     def test_open_loop_arrivals_gate_admission_and_match_closed_loop(
             self, cfg, v2cfg, rng):
