@@ -257,7 +257,8 @@ def afmoe_config(hf: Dict[str, Any], *, max_seq_len: Optional[int] = None,
         dtype=dtype or jnp.bfloat16)
 
 
-# deepseek_v3 with latent attention and no query latent (Moonlight):
+# deepseek_v3 with latent attention (Moonlight; with a query latent,
+# ``q_lora_rank``, ``q_proj`` gives way to DEEPSEEK_V3_QUERY_LATENT_NAMES):
 # published tensor name -> path in the GPT parameter tree, as above: the names
 # the loader (``_deepseek_v3_tree``) reads, held to it name by name in
 # tests/test_moonlight.py.  The published weights pair
@@ -299,18 +300,94 @@ DEEPSEEK_V3_WEIGHT_NAMES = {
 }
 
 
+# ... in place of ``q_proj`` where the model has a query latent
+DEEPSEEK_V3_QUERY_LATENT_NAMES = {
+    "model.layers.{i}.self_attn.q_a_proj.weight":
+        "backbone/block_{i}/Attention_0/wq_a",
+    "model.layers.{i}.self_attn.q_a_layernorm.weight":
+        "backbone/block_{i}/Attention_0/q_norm",
+    "model.layers.{i}.self_attn.q_b_proj.weight":
+        "backbone/block_{i}/Attention_0/wq_b",
+}
+
+# dots3_note (dots3-note-prev): deepseek_v3's names with a query latent on
+# every layer, a headwise gate, and on the full layers the indexer under the
+# names of the published DeepSeek-V3.2 indexer (whose RoPE already rotates
+# halves: its columns are NOT permuted).  ASSUMED from the family: the
+# catalog gives this model's config.json, not its tensor names.
+DOTS3_NOTE_WEIGHT_NAMES = {
+    **{k: v for k, v in DEEPSEEK_V3_WEIGHT_NAMES.items()
+       if not k.endswith("q_proj.weight")},
+    **DEEPSEEK_V3_QUERY_LATENT_NAMES,
+    "model.layers.{i}.self_attn.g_proj.weight":
+        "backbone/block_{i}/Attention_0/wgate",
+    "model.layers.{i}.self_attn.indexer.wq_b.weight":
+        "backbone/block_{i}/Attention_0/wq_idx",
+    "model.layers.{i}.self_attn.indexer.wk.weight":
+        "backbone/block_{i}/Attention_0/wk_idx",
+    "model.layers.{i}.self_attn.indexer.k_norm.weight":
+        "backbone/block_{i}/Attention_0/k_idx_norm_scale",
+    "model.layers.{i}.self_attn.indexer.k_norm.bias":
+        "backbone/block_{i}/Attention_0/k_idx_norm_bias",
+    "model.layers.{i}.self_attn.indexer.weights_proj.weight":
+        "backbone/block_{i}/Attention_0/ww_idx",
+}
+
+
+def dots3_note_config(hf: Dict[str, Any], *,
+                      max_seq_len: Optional[int] = None, dtype=None):
+    """GPTConfig of a published ``dots3_note`` ``config.json``
+    (dots3-note-prev): deepseek_v3's expert layers; latent attention with a
+    query latent and a headwise gate on every layer; the full layers
+    (``layer_types``) select their ``index_topk`` keys with an indexer, the
+    sliding ones have a latent geometry of their own (the ``swa_*`` keys)
+    under ``sliding_window_size``.  ``apply_mla_qkv_lora_rescale`` and the
+    gate are read as benchmark/configs/dots3-note-prev-5l-ep8.json says
+    (``assumed``)."""
+    import dataclasses
+    for key, ok, what in (
+            ("attention_gate_type",
+             hf.get("attention_gate_type") == "headwise"
+             == hf.get("swa_attention_gate_type"), "another gate"),
+            ("layer_types", len(hf.get("layer_types", ()))
+             >= hf["num_hidden_layers"], "a pattern shorter than the model"),
+            ("topk_method", hf.get("topk_method", "noaux_tc") == "noaux_tc",
+             "another selection of experts")):
+        if not ok:
+            raise NotImplementedError(
+                f"dots3_note: {key}={hf.get(key)!r}: {what} is not built")
+    base = deepseek_v3_config(hf, max_seq_len=max_seq_len, dtype=dtype)
+    return dataclasses.replace(
+        base, mla_lora_rescale=bool(hf.get("apply_mla_qkv_lora_rescale")),
+        attn_gate_headwise=True, index_topk=hf["index_topk"],
+        index_n_heads=hf["index_n_heads"],
+        index_head_dim=hf["index_head_dim"],
+        sliding_window=int(hf["sliding_window_size"]),
+        local_attn_layers=tuple(
+            i for i in range(hf["num_hidden_layers"])
+            if hf["layer_types"][i] == "sliding_attention"),
+        window_attn=(
+            ("num_heads", hf["swa_num_attention_heads"]),
+            ("head_dim", hf["swa_qk_nope_head_dim"]
+             + hf["swa_qk_rope_head_dim"]),
+            ("v_head_dim", hf["swa_v_head_dim"]),
+            ("kv_lora_rank", hf["swa_kv_lora_rank"]),
+            ("q_lora_rank", hf["swa_q_lora_rank"]),
+            ("qk_rope_head_dim", hf["swa_qk_rope_head_dim"]),
+            ("rope_theta", float(hf["swa_rope_theta"]))))
+
+
 def deepseek_v3_config(hf: Dict[str, Any], *,
                        max_seq_len: Optional[int] = None, dtype=None):
     """GPTConfig of a published ``deepseek_v3`` ``config.json`` of the shape
     Moonlight has: latent attention (keys and values from one normed latent
     of ``kv_lora_rank`` beside one rotated key part shared by all heads,
-    queries straight from the hidden state), then ``first_k_dense_replace``
+    queries straight from the hidden state, or through a query latent where
+    ``q_lora_rank`` is set), then ``first_k_dense_replace``
     dense layers and sigmoid-routed experts with a selection bias beside
     shared experts (one SwiGLU of ``n_shared_experts`` widths)."""
     from deepspeed_tpu.models.gpt import GPTConfig
     for key, ok, what in (
-            ("q_lora_rank", hf.get("q_lora_rank") is None,
-             "a query latent (q_a_proj / q_b_proj)"),
             ("n_group", hf.get("n_group", 1) == 1
              and hf.get("topk_group", 1) == 1, "group-limited routing"),
             ("rope_scaling", not hf.get("rope_scaling"),
@@ -340,7 +417,7 @@ def deepseek_v3_config(hf: Dict[str, Any], *,
         gated_mlp=True,
         tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
         kv_lora_rank=hf["kv_lora_rank"], qk_rope_head_dim=rot,
-        v_head_dim=hf["v_head_dim"],
+        v_head_dim=hf["v_head_dim"], q_lora_rank=hf.get("q_lora_rank") or 0,
         num_experts=hf["n_routed_experts"], moe_k=hf["num_experts_per_tok"],
         moe_dropless=True, moe_router="sigmoid",
         moe_route_norm=bool(hf.get("norm_topk_prob", True)),
@@ -354,16 +431,13 @@ def deepseek_v3_config(hf: Dict[str, Any], *,
 
 
 def _deepseek_v3_tree(r, cfg) -> Dict[str, Any]:
-    """deepseek_v3 (latent attention) -> flax tree, by
-    ``DEEPSEEK_V3_WEIGHT_NAMES``; ``r`` has ``get(name)`` (a
-    ``_ShardReader``, or any mapping of published names to arrays)."""
+    """deepseek_v3 / dots3_note (latent attention) -> flax tree, by
+    ``DEEPSEEK_V3_WEIGHT_NAMES`` (``DOTS3_NOTE_WEIGHT_NAMES``); ``r`` has
+    ``get(name)`` (a ``_ShardReader``, or any mapping of published names to
+    arrays).  Each layer is read at its own attention geometry
+    (``cfg.for_layer``)."""
     from deepspeed_tpu.models.gpt import mla_split
-    H, nh, hd = cfg.hidden_size, cfg.num_heads, cfg.head_dim
-    nope, rot, vd = mla_split(cfg)
-    rank = cfg.kv_lora_rank
-    pairs = _rope_interleave_perm(rot, rot)    # the rope columns come last
-    q_perm = np.concatenate([np.arange(nope), nope + pairs])
-    kv_perm = np.concatenate([np.arange(rank), rank + pairs])
+    H = cfg.hidden_size
 
     def lin(name):                       # torch Linear: [out, in]
         return np.asarray(r.get(name)).T
@@ -372,21 +446,46 @@ def _deepseek_v3_tree(r, cfg) -> Dict[str, Any]:
         "wte": np.asarray(r.get("model.embed_tokens.weight")),
         "final_norm": {"scale": np.asarray(r.get("model.norm.weight"))}}
     for i in range(cfg.num_layers):
+        lc = cfg.for_layer(i)
+        nh, hd, rank = lc.num_heads, lc.head_dim, lc.kv_lora_rank
+        nope, rot, vd = mla_split(lc)
+        pairs = _rope_interleave_perm(rot, rot)   # the rope columns come last
+        q_perm = np.concatenate([np.arange(nope), nope + pairs])
+        kv_perm = np.concatenate([np.arange(rank), rank + pairs])
         p = f"model.layers.{i}."
         a = p + "self_attn."
+        attn = {
+            "wkv_a": lin(a + "kv_a_proj_with_mqa.weight")[:, kv_perm],
+            "kv_norm": np.asarray(r.get(a + "kv_a_layernorm.weight")),
+            "wkv_b": lin(a + "kv_b_proj.weight").reshape(
+                rank, nh, nope + vd),
+            "wo": lin(a + "o_proj.weight").reshape(nh, vd, H)}
+        if lc.q_lora_rank:
+            attn.update(
+                wq_a=lin(a + "q_a_proj.weight"),
+                q_norm=np.asarray(r.get(a + "q_a_layernorm.weight")),
+                wq_b=lin(a + "q_b_proj.weight").reshape(
+                    lc.q_lora_rank, nh, hd)[:, :, q_perm])
+        else:
+            attn["wq"] = lin(a + "q_proj.weight").reshape(H, nh, hd)[
+                :, :, q_perm]
+        if lc.attn_gate_headwise:
+            attn["wgate"] = lin(a + "g_proj.weight")
+        if lc.index_topk:
+            x = a + "indexer."
+            attn.update(
+                wq_idx=lin(x + "wq_b.weight").reshape(
+                    lc.q_lora_rank, lc.index_n_heads, lc.index_head_dim),
+                wk_idx=lin(x + "wk.weight"),
+                k_idx_norm_scale=np.asarray(r.get(x + "k_norm.weight")),
+                k_idx_norm_bias=np.asarray(r.get(x + "k_norm.bias")),
+                ww_idx=lin(x + "weights_proj.weight"))
         blk = {
             "Norm_0": {"scale": np.asarray(
                 r.get(p + "input_layernorm.weight"))},
             "Norm_1": {"scale": np.asarray(
                 r.get(p + "post_attention_layernorm.weight"))},
-            "Attention_0": {
-                "wq": lin(a + "q_proj.weight").reshape(H, nh, hd)[
-                    :, :, q_perm],
-                "wkv_a": lin(a + "kv_a_proj_with_mqa.weight")[:, kv_perm],
-                "kv_norm": np.asarray(r.get(a + "kv_a_layernorm.weight")),
-                "wkv_b": lin(a + "kv_b_proj.weight").reshape(
-                    rank, nh, nope + vd),
-                "wo": lin(a + "o_proj.weight").reshape(nh, vd, H)}}
+            "Attention_0": attn}
         m = p + "mlp."
         if cfg.is_moe_layer(i):
             def stack(what):
@@ -426,6 +525,8 @@ def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
         return afmoe_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     if hf.get("model_type") == "deepseek_v3":
         return deepseek_v3_config(hf, max_seq_len=max_seq_len, dtype=dtype)
+    if hf.get("model_type") == "dots3_note":
+        return dots3_note_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     arch = _arch_of(hf)
 
     if arch in _LLAMA_LIKE:

@@ -491,26 +491,46 @@ class InferenceEngineV2:
         # programs are then traced exactly as before
         self._model_static: Dict[str, Any] = {}
         if model_cfg.mla:
-            # latent attention: one pool of latent rows (model.py
-            # PagedKVCache), read absorbed.  What is not built beside it:
+            # latent attention: pools of latent rows (model.py
+            # PagedKVCache), read absorbed, and with a learned selection
+            # (index_topk) an index-key pool beside the global group's.
+            # What is not built beside them:
+            sel = bool(model_cfg.index_topk)
             for what, why in (
                     (sm.kv_quant, "kv_quant: an int8 latent row and its "
-                     "scale are not built"),
+                     "scale are not built" + (
+                         ", nor int8 index keys, whose rounding would move "
+                         "the selection" if sel else "")),
                     (self.mesh is not None, "a tp mesh: every head reads "
-                     "the one latent row, which has no head dim to shard"),
+                     "the one latent row, which has no head dim to shard"
+                     + (", and every head attends over the one selection"
+                        if sel else "")),
                     (draft_model is not None, "speculative decoding: the "
                      "verify core and a draft pool are not built over "
-                     "latent pages"),
+                     "latent pages" + (
+                         ", and a draft run's rows would each need their "
+                         "own selection" if sel else "")),
                     (self.config.adapters.enabled, "LoRA adapter pages: "
                      "their q/v deltas have no latent form"),
                     (sm.prefix_cache, "the prefix cache: not tested over "
-                     "latent pages"),
-                    (self.kv_window, "window and global layers: two page "
-                     "groups")):
+                     "latent pages" + (
+                         "; a shared prefix's index keys would have to be "
+                         "shared page for page with its latent rows"
+                         if sel else ""))):
                 if what:
                     raise NotImplementedError(
-                        f"latent attention (kv_lora_rank) keeps a latent "
-                        f"page pool, which is not built with {why}")
+                        f"latent attention (kv_lora_rank) keeps latent "
+                        f"page pools, which are not built with {why}")
+            if sel and not self.kv_window:
+                raise NotImplementedError(
+                    "a learned selection (index_topk) over latent rows "
+                    "keeps its index keys in a pool beside the global page "
+                    "group of a model with window AND full layers; " + (
+                        "a model whose layers all have a window has "
+                        "nothing to select" if None not in windows
+                        else "a model whose layers are all full "
+                        "(DeepSeek-V3.2's shape) keeps one page group, "
+                        "whose pool holds no index keys: not built"))
         if self.kv_window:
             for what, why in (     # (prefix_cache: DSStateManager refuses)
                     (sm.kv_quant, "kv_quant: the scale pools are not "
@@ -538,7 +558,7 @@ class InferenceEngineV2:
                     f"{sm.max_q_per_seq} rows in pages of {eff_bs})")
             from deepspeed_tpu.inference.v2.model import kv_page_layout
             self._model_static["kv_layout"] = kv_page_layout(
-                model_cfg, num_blocks, window_blocks)
+                model_cfg, num_blocks, window_blocks, split=model_cfg.mla)
         if draft_model is None and model_cfg.num_experts and any(
                 model_cfg.is_moe_layer(i)
                 for i in range(model_cfg.num_layers)):
@@ -549,7 +569,10 @@ class InferenceEngineV2:
             max_seq_len=model_cfg.max_seq_len,
             prefix_cache=sm.prefix_cache, window=self.kv_window,
             window_blocks=window_blocks)
-        if self.kv_window:
+        if self.kv_window and model_cfg.mla:
+            self.cache = PagedKVCache.create_latent_groups(
+                model_cfg, num_blocks, window_blocks, eff_bs, dt)
+        elif self.kv_window:
             self.cache = PagedKVCache.create_grouped(
                 model_cfg, num_blocks, window_blocks, eff_bs, dt)
         else:
@@ -649,7 +672,8 @@ class InferenceEngineV2:
         self._serve_ctx: Optional[Dict[str, Any]] = None
         self.heartbeat_fn = None
         self._block_size = eff_bs
-        self.telemetry.set_kv_bytes_per_token(self.kv_bytes_per_token())
+        self.telemetry.set_kv_bytes_per_token(
+            self.kv_bytes_per_token(), **self.kv_bytes_by_group())
         # ---- multi-tenant LoRA adapter pool (serving/adapters.py): A/B
         # pages live as block-granular refcounted residents of the SAME
         # allocator as the KV blocks, so adapters and KV contend under one
@@ -697,7 +721,25 @@ class InferenceEngineV2:
         ``with_routes`` (a model with expert layers): also, per uid, the
         experts its rows' routers chose, int32 ``[expert layers, rows, k]``
         over all the router's experts: what a comparison with a reference's
-        routing needs to tell a near-tie from a fault."""
+        routing needs to tell a near-tie from a fault.
+
+        A call that holds more than one forward takes (a prompt longer
+        than ``max_q_per_seq``, or more tokens together than
+        ``max_ragged_batch_size``, after what the prefix cache matches)
+        goes through ``put_chunked`` (not with ``with_routes``, which stays
+        one forward and keeps ``_put_device``'s guards)."""
+        sm = self.config.state_manager
+
+        def over(sizes):
+            return (max(sizes, default=0) > sm.max_q_per_seq
+                    or sum(sizes) > sm.max_ragged_batch_size)
+        toks = [np.asarray(t, np.int32).reshape(-1) for t in tokens_list]
+        if not with_routes and over([len(t) for t in toks]):
+            matches = self.state.peek_prefix_batch(
+                [None if self.state.get(uid) is not None else t
+                 for uid, t in zip(uids, toks)])[0]
+            if over([len(t) - m for t, m in zip(toks, matches)]):
+                return self.put_chunked(uids, toks)
         logits = self._put_device(uids, tokens_list, with_routes)
         if with_routes:
             logits, routes, rows = logits
@@ -706,6 +748,29 @@ class InferenceEngineV2:
         if not with_routes:
             return out
         return out, [np.asarray(routes)[:, r] for r in rows]
+
+    def put_chunked(self, uids: Sequence[int],
+                    tokens_list: Sequence[np.ndarray]) -> np.ndarray:
+        """``put()`` for a call of any size, as SplitFuse would run it: as
+        many forwards as it takes, each uid's tokens in order in chunks of
+        at most ``max_q_per_seq`` rows, a forward filled in the order of
+        ``uids``; the logits are each uid's after its last token."""
+        sm = self.config.state_manager
+        toks = [np.asarray(t, np.int32).reshape(-1) for t in tokens_list]
+        out = [None] * len(uids)
+        done = [0] * len(uids)
+        while any(d < len(t) for d, t in zip(done, toks)):
+            room, part = sm.max_ragged_batch_size, []
+            for i, t in enumerate(toks):
+                n = min(len(t) - done[i], sm.max_q_per_seq, room)
+                if n > 0 and len(part) < sm.max_ragged_sequence_count:
+                    part.append((i, n))
+                    room -= n
+            rows = self.put([uids[i] for i, _ in part],
+                            [toks[i][done[i]:done[i] + n] for i, n in part])
+            for row, (i, n) in zip(rows, part):
+                out[i], done[i] = row, done[i] + n
+        return np.stack(out)
 
     def _put_device(self, uids, tokens_list, with_routes: bool = False):
         """put() minus the host transfer: returns per-SLOT device logits
@@ -823,9 +888,14 @@ class InferenceEngineV2:
         width covering the live tokens — a small step (one admission chunk
         between decode bursts) must not pay a forward padded to the full
         ragged budget.  ≤ log2(MB) × log2(budget) compiled programs total."""
-        mb_full = rb.block_table.shape[1]
-        mb_used = max(1, -(-int(rb.kv_len.max()) // self._block_size))
-        mb = min(1 << (mb_used - 1).bit_length(), mb_full)
+        mb = rb.block_table.shape[1]
+        if not self.model_config.index_topk:
+            # (a model that selects its keys keeps ONE table width, the
+            # whole table's: its full layers score and sort over their
+            # rows' own contexts at run time, index_select(width=), so a
+            # narrower table would buy programs and save no work)
+            mb_used = max(1, -(-int(rb.kv_len.max()) // self._block_size))
+            mb = min(1 << (mb_used - 1).bit_length(), mb)
         return mb, self._token_bucket(rb.total_tokens, rb.tokens.shape[0])
 
     @staticmethod
@@ -840,7 +910,12 @@ class InferenceEngineV2:
         from deepspeed_tpu.ops.paged_attention import (_prefill_chunk,
                                                        prefill_grid_items)
         sm = self.config.state_manager
+        # (a model whose global layers select their keys sends only its
+        # window layers through the kernel: their geometry)
         cfg = self.model_config
+        cfg = cfg.for_layer(next(
+            (i for i in range(cfg.num_layers)
+             if cfg.index_topk and cfg.window_for_layer(i)), 0))
         nkv, _, vd, _ = _attn_geometry(cfg)
         nb = self._token_bucket(sum(rows), sm.max_ragged_batch_size)
         Q = min(sm.max_q_per_seq, nb)
@@ -996,9 +1071,10 @@ class InferenceEngineV2:
         return ({"tokens0": tokens0, "from_device": from_device,
                  "active": active, "pos0": pos0,
                  **self.state.table_operands(tables)},
-                self._ctx_note(pos0[active]))
+                self._ctx_note(pos0[active], steps=steps))
 
-    def _ctx_note(self, contexts, new=None) -> Dict[str, int]:
+    def _ctx_note(self, contexts, new=None, steps: int = 1,
+                  table_tokens: Optional[int] = None) -> Dict[str, int]:
         """What a dispatch's span says of the contexts it reads:
         ``ctx_tokens``, their sum before the step, and for a model with
         window layers ``ctx_tokens_window``, the sum of ``min(context,
@@ -1008,9 +1084,41 @@ class InferenceEngineV2:
         layer (row ``i`` of a chunk at context ``c`` sees ``c + i + 1``
         keys) and ``qk_pairs_window`` on a window layer (at most the
         window), and how many of its slots hold one row and their contexts
-        (``one_row_slots``, ``ctx_tokens_one_row``)."""
+        (``one_row_slots``, ``ctx_tokens_one_row``).
+
+        A model whose global layers select their keys (``index_topk``) also
+        counts, over those layers, the pairs its dispatch scores with the
+        indexer, keeps for attention, and would read without a selection
+        (``ServingTelemetry.index_pairs``; a fused dispatch: ``steps`` rows
+        a slot; ``table_tokens``: the step program's table width in tokens,
+        the whole table's by default: a program no wider than the selection
+        reads every key and scores none)."""
         contexts = np.asarray(contexts, np.int64)
         note = {"ctx_tokens": int(contexts.sum())}
+        k = self.model_config.index_topk
+        if k:
+            mc = self.model_config
+            q = (np.asarray(new, np.int64) if new is not None
+                 else np.full(len(contexts), steps, np.int64))
+            causal = q * contexts + q * (q + 1) // 2
+            rising = np.clip(k - contexts, 0, q)   # rows that see <= k keys
+            kept = (rising * contexts + rising * (rising + 1) // 2
+                    + (q - rising) * k)
+            if table_tokens is None:
+                table_tokens = (-(-mc.max_seq_len // self._block_size)
+                                * self._block_size)
+            layers = sum(mc.window_for_layer(i) is None
+                         for i in range(mc.num_layers))
+            scored = int(causal.sum()) if table_tokens > k else 0
+            self.telemetry.index_pairs(
+                layers * scored, layers * int(kept.sum()),
+                layers * int(causal.sum()))
+            # this dispatch's own, on ONE selecting layer (the rooflines'
+            # needs), and the one-row slots' part of what it keeps
+            note.update(index_pairs_step=scored,
+                        sel_pairs_step=int(kept.sum()))
+            if new is not None:
+                note["sel_pairs_one_row"] = int(kept[q == 1].sum())
         if new is not None:
             # sum over i < q of (c + 1 + i); the one-row slots' part of it
             # (they go to the paged decode kernel) is their contexts + count
@@ -1024,6 +1132,9 @@ class InferenceEngineV2:
             return note
         note["ctx_tokens_window"] = int(np.minimum(contexts, win).sum())
         if new is not None:
+            # the keys a one-row slot's window layer reads: its own too
+            note["ctx_tokens_window_one_row"] = int(
+                np.minimum(contexts[q == 1] + 1, win).sum())
             # ... and of min(c + 1 + i, win): the first `rising` rows still
             # see fewer keys than the window
             rising = np.clip(win - contexts - 1, 0, q)
@@ -1162,10 +1273,7 @@ class InferenceEngineV2:
             mixed = max(rows) > 1
             if mixed:
                 stel.mixed_slots(rows, *self._prefill_items(rows))
-            note = {"seqs": len(schedule), "tokens": sum(rows),
-                    **self._ctx_note([seq.seen_tokens
-                                      for seq, _ in schedule], rows),
-                    **stel.counter_note(self.state)}
+            note = {"seqs": len(schedule), "tokens": sum(rows)}
             if not mixed:
                 # decode-only: slot-indexed [S] program
                 kind = "decode"
@@ -1208,6 +1316,10 @@ class InferenceEngineV2:
                 note["bucket"] = nb
                 shape_key = (sm.max_q_per_seq, mb)
                 static = {"max_q_per_seq": sm.max_q_per_seq}
+            note.update(self._ctx_note(
+                [seq.seen_tokens for seq, _ in schedule], rows,
+                table_tokens=mb * self._block_size if mixed else None))
+            note.update(stel.counter_note(self.state))
             # with a draft loaded it ingests every token in lockstep (dual
             # prefill and decode), so speculative acceptance has something
             # to work with; draft staleness can't affect correctness
@@ -1364,11 +1476,33 @@ class InferenceEngineV2:
         latent row and int8 scales included: the pool's bytes over its
         tokens, or with two page groups, whose layers hold different page
         counts, a block's bytes over its tokens."""
+        if self.cache.kw is not None:
+            return sum(self.kv_bytes_by_group().values())
         if self.kv_window:
             return self.kv_block_bytes() // self._block_size
         pool = sum(a.size * a.dtype.itemsize for a in self.cache
                    if a is not None)
         return int(pool // (self.cache.k.shape[1] * self._block_size))
+
+    def kv_bytes_by_group(self) -> Dict[str, int]:
+        """``kv_bytes_per_token`` split by what holds the bytes, for a
+        latent model with two page groups (else {}): a token's rows in the
+        global layers' pool, in the window layers' (while the window holds
+        it), and its index keys (``index_bytes_per_token``)."""
+        c = self.cache
+        if c.kw is None:
+            return {}
+        mc = self.model_config
+        kinds = [mc.window_for_layer(i) is not None
+                 for i in range(mc.num_layers)]
+
+        def row(pool, layers):
+            return int(layers * pool.shape[-1] * pool.dtype.itemsize)
+        out = {"kv_bytes_per_token_global": row(c.k, kinds.count(False)),
+               "kv_bytes_per_token_window": row(c.kw, kinds.count(True))}
+        if c.ki is not None:
+            out["index_bytes_per_token"] = row(c.ki, kinds.count(False))
+        return out
 
     # ------------------------------- continuous batching (Dynamic SplitFuse)
     def _stream_fence(self, value) -> None:

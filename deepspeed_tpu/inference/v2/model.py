@@ -21,6 +21,7 @@ Qmax new tokens per sequence per step.  Raggedness is carried by index arrays
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import NamedTuple, Optional
 
@@ -105,12 +106,25 @@ class PagedKVCache(NamedTuple):
     kv-major key part in two pools: one copy a page in the kernels, one
     scatter a step, and the page is key and value as it lies; the price is
     the 64 pad columns, a ninth more bytes to hold and to read.  The
-    attention ops take it as ``v_pages=None`` with ``v_dim``."""
+    attention ops take it as ``v_pages=None`` with ``v_dim``.
+
+    Latent pages of a model with window AND global layers
+    (``create_latent_groups``): a pool PER page group, each with its group's
+    own row width: ``k`` the global layers' ``[1, pages, 1, block_size,
+    row]`` and ``kw`` the window layers', since the two kinds of layer may
+    differ in their latent (``cfg.window_attn``) and the group that grows
+    with the context should not be padded to the other's row.  ``ki``: the
+    INDEX-KEY pool of the global layers where they select their keys
+    (``cfg.index_topk``): ``[1, pages, 1, block_size, index_head_dim]``,
+    page for page beside ``k`` and addressed by the global group's own block
+    table: a third array, no third allocator group."""
 
     k: jax.Array
     v: Optional[jax.Array]
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    kw: Optional[jax.Array] = None
+    ki: Optional[jax.Array] = None
 
     @property
     def quantized(self) -> bool:
@@ -140,6 +154,26 @@ class PagedKVCache(NamedTuple):
                    v=jnp.zeros(shape, jnp.int8),
                    k_scale=jnp.zeros(sshape, jnp.float32),
                    v_scale=jnp.zeros(sshape, jnp.float32))
+
+    @classmethod
+    def create_latent_groups(cls, cfg: GPTConfig, nb_global: int,
+                             nb_window: int, block_size: int, dtype):
+        """Latent pools of a model with window and global layers, a pool a
+        page group (class docstring): ``nb_global`` pages for each global
+        layer in ``k`` (and in ``ki`` where they keep index keys),
+        ``nb_window`` for each window layer in ``kw``."""
+        kinds = [cfg.window_for_layer(i) is not None
+                 for i in range(cfg.num_layers)]
+        g, w = (cfg.for_layer(kinds.index(False)),
+                cfg.for_layer(kinds.index(True)))
+
+        def pool(layers, pages, width):
+            return jnp.zeros((1, layers * pages, 1, block_size, width), dtype)
+        return cls(
+            k=pool(kinds.count(False), nb_global, g.latent_page_dim), v=None,
+            kw=pool(kinds.count(True), nb_window, w.latent_page_dim),
+            ki=(pool(kinds.count(False), nb_global, g.index_head_dim)
+                if g.index_topk else None))
 
     @classmethod
     def create_grouped(cls, cfg: GPTConfig, nb_global: int, nb_window: int,
@@ -659,18 +693,27 @@ def _attn_scale(cfg: GPTConfig):
 
 def _mla_qkv(ap, h, positions, cfg: GPTConfig):
     """Latent attention's side of ``attn_qkv`` on rows ``h [N, H]`` at
-    ``positions [N]``: the queries ABSORBED (``q_nope_h Wkvb_h[:, :nope]^T``,
+    ``positions [N]`` (``cfg``: the layer's view, ``GPTConfig.for_layer``):
+    the queries ABSORBED (``q_nope_h Wkvb_h[:, :nope]^T``,
     the latent's width, beside the rotated ``q_pe_h``) and the token's cache
     row ``[c_kv | k_pe]`` (normed latent, rotated shared key part), both
     padded with zeros to the page row's width, ``[N, nh, P]`` and
     ``[N, 1, P]``.  The score ``q_lat . c + q_pe . k_pe`` is then the
     published ``q_nope . k_nope + q_pe . k_pe`` by associativity, and the
     row is key and value at once.  The absorb product has its own scope,
-    ``mla_absorb``."""
-    from deepspeed_tpu.models.gpt import mla_latent, mla_query, mla_split
+    ``mla_absorb``.  Also the headwise gate ``sigmoid(Wg h) [N, nh, 1]``
+    (None without one) and the query latent ``cq [N, q_lora_rank]`` (``h``
+    itself without one), which the indexer reads."""
+    from deepspeed_tpu.models.gpt import (mla_latent, mla_query,
+                                          mla_query_latent, mla_split)
     dtype = h.dtype
     nope = mla_split(cfg)[0]
-    q_nope, q_pe = mla_query(_w(ap["wq"], dtype), h, positions, cfg)
+    cq = h
+    if cfg.q_lora_rank:
+        cq = mla_query_latent(_w(ap["wq_a"], dtype), ap["q_norm"], h, cfg)
+    q_nope, q_pe = mla_query(
+        _w(ap["wq_b" if cfg.q_lora_rank else "wq"], dtype), cq, positions,
+        cfg)
     c_kv, k_pe = mla_latent(_w(ap["wkv_a"], dtype), ap["kv_norm"], h,
                             positions, cfg)
     with jax.named_scope("mla_absorb"):
@@ -681,7 +724,85 @@ def _mla_qkv(ap, h, positions, cfg: GPTConfig):
     def row(*parts):
         z = jnp.zeros(parts[0].shape[:-1] + (pad,), dtype)
         return jnp.concatenate(parts + ((z,) if pad else ()), -1)
-    return row(q_lat, q_pe), row(c_kv, k_pe)[:, None, :]
+    gate = None
+    if cfg.attn_gate_headwise:
+        gate = jax.nn.sigmoid(h @ _w(ap["wgate"], dtype))[..., None]
+    return row(q_lat, q_pe), row(c_kv, k_pe)[:, None, :], gate, cq
+
+
+def _index_rows(ap, h, cq, positions, cfg: GPTConfig):
+    """The indexer's projections of rows ``h [N, H]`` (``cq``: their query
+    latent): index queries ``[N, nI, dI]``, their weights ``[N, nI]``
+    float32, and the rows' own index keys ``[N, 1, dI]`` as the index-key
+    pool stores them.  Inside scope ``attn_index``."""
+    from deepspeed_tpu.models.gpt import index_key, index_query
+    dtype = h.dtype
+    qi, wi = index_query(_w(ap["wq_idx"], dtype), _w(ap["ww_idx"], dtype),
+                         cq, h, positions, cfg)
+    ki = index_key(_w(ap["wk_idx"], dtype), ap["k_idx_norm_scale"],
+                   ap["k_idx_norm_bias"], h, positions, cfg)
+    return qi, wi, ki[:, None, :]
+
+
+def _selected_attention(q, qi, wi, k_pages, ki_pages, table, row_slot,
+                        row_pos, cfg: GPTConfig, *, block_size: int,
+                        max_rows: int = 1, rows=None):
+    """A full layer's attention where the selection binds: index scores of
+    the step's rows over their slots' index keys, the exact top
+    ``index_topk`` of them (scope ``attn_index``, the selection
+    ``index_select`` inside it), then attention over the selected rows of
+    the latent pool and no others (``selected_attention``); all of it inside
+    scope ``attn_kernel``, whose time is a layer's attention whichever way
+    it reads its keys.  ``table [S, MB]``: the global group's, the layer's
+    first page added; ``row_slot``: ``S`` for a pad row.  -> ``[N, nh,
+    kv_lora_rank]``.
+
+    A mixed step (``rows``: its ``_MixedRows``, at most ``max_rows`` a
+    slot) selects for its one-row slots (riding
+    decode rows, whose contexts are the longest a step holds) apart from the
+    slots that hold a prompt chunk: a chunk's rows are then scored and
+    sorted over their OWN contexts' width, not over the riders', and what a
+    step costs does not follow which sequences happen to ride it."""
+    from deepspeed_tpu import ops
+    S = table.shape[0]
+    k = min(cfg.index_topk, table.shape[1] * block_size)
+
+    def select(qi, wi, slots, pos, rows_a_slot, width=None):
+        scores = ops.index_scores(qi, wi, ki_pages, table, slots, pos,
+                                  max_rows=rows_a_slot, impl=cfg.attn_impl)
+        return ops.index_select(scores, k, width=width)
+
+    with jax.named_scope("attn_kernel"), jax.named_scope("attn_index"):
+        if rows is None:                   # a decode step: a row a slot
+            picked = select(qi, wi, row_slot, row_pos, 1)          # [N, k]
+        else:
+            first = rows.first_row
+            slot = jnp.minimum(row_slot, S - 1)
+            alone = (rows.q_counts == 1)[slot] & (row_slot < S)
+            one = select(qi[first], wi[first],
+                         jnp.where(rows.q_counts == 1, jnp.arange(S), S),
+                         row_pos[first], 1)                        # [S, k]
+            reach = jnp.max(jnp.where((row_slot < S) & ~alone, row_pos + 1,
+                                      0))
+            many = select(qi, wi, jnp.where(alone, S, row_slot), row_pos,
+                          max_rows, width=reach)                   # [N, k]
+            picked = jnp.where(alone[:, None], one[slot], many)
+        with jax.named_scope("index_select"):
+            # each picked position's page of its row's table: a compare and
+            # a sum over the table's few columns, which fuse; a gather of
+            # one int32 a pair is the slow way on this chip
+            mine = table[jnp.minimum(row_slot, S - 1)]             # [N, MB]
+            page = picked // block_size
+            pages = jnp.sum(jnp.where(
+                page[:, :, None] == jnp.arange(table.shape[1],
+                                               dtype=jnp.int32),
+                mine[:, None, :], 0), axis=-1)
+            rows = pages * block_size + picked % block_size
+            counts = jnp.where(row_slot < S, jnp.minimum(row_pos + 1, k), 0)
+    with jax.named_scope("attn_kernel"):
+        return ops.selected_attention(
+            q.astype(cfg.dtype), k_pages, rows, counts,
+            v_dim=cfg.kv_lora_rank, scale=_attn_scale(cfg))
 
 
 def _attn_proj(ap, o, gate, cfg, mesh=None):
@@ -768,22 +889,34 @@ def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
                     mesh=mesh, kv_major=kv_major_layout(cfg),
                     impl=cfg.attn_impl, **scales, **latent)
         one_row = rows.q_counts == 1
-        o_one = ops.paged_attention(
-            q[rows.first_row], k_pages, v_pages, table,
-            jnp.where(one_row, rows.kv_len, 0), **pool)
-        # each slot's rows are one span of positions ending at its kv_len
-        o_many = ops.ragged_prefill_attention(
-            q, k_pages, v_pages, table, rows.kv_len,
-            rows.kv_len - rows.q_counts,
-            jnp.where(one_row, 0, rows.q_counts), rows.first_row, max_q=Q,
-            **pool)
+        # (a latent window layer's kernels under a scope of their own, so
+        # that a trace tells them from the global layers' in one program)
+        with _window_latent_scope(cfg, window):
+            o_one = ops.paged_attention(
+                q[rows.first_row], k_pages, v_pages, table,
+                jnp.where(one_row, rows.kv_len, 0), **pool)
+            # each slot's rows are one span of positions ending at its
+            # kv_len
+            o_many = ops.ragged_prefill_attention(
+                q, k_pages, v_pages, table, rows.kv_len,
+                rows.kv_len - rows.q_counts,
+                jnp.where(one_row, 0, rows.q_counts), rows.first_row,
+                max_q=Q, **pool)
         o = jnp.where(one_row[slot, None, None],
                       o_one.reshape(S, nh, vd)[slot],
                       o_many.reshape(N, nh, vd))
         return jnp.where(valid[:, None, None], o, 0)
 
 
-def kv_page_layout(cfg: GPTConfig, nb_global: int, nb_window: int):
+def _window_latent_scope(cfg: GPTConfig, window):
+    """Scope ``window_latent`` around a latent WINDOW layer's kernels (inside
+    ``attn_kernel``), nothing around any other layer's."""
+    return (jax.named_scope("window_latent") if cfg.mla and window
+            else contextlib.nullcontext())
+
+
+def kv_page_layout(cfg: GPTConfig, nb_global: int, nb_window: int,
+                   split: bool = False):
     """Where each layer's pages lie in a pool of TWO page groups: per layer
     ``(first page, group)``, group 0 the ``global`` layers (no window: they
     keep every page of a context) and group 1 the ``window`` layers
@@ -792,14 +925,17 @@ def kv_page_layout(cfg: GPTConfig, nb_global: int, nb_window: int):
     pages each come first, then the window layers' ``nb_window`` each.
     Static (a tuple of ints), so it is a step program's keyword; a model
     whose layers are all alike has one group and passes None, which is
-    ``(li * NB, 0)``."""
+    ``(li * NB, 0)``.  ``split``: a pool per group (latent pages,
+    ``PagedKVCache.create_latent_groups``), so a window layer's first page
+    counts from its own pool's start."""
     kinds = [cfg.window_for_layer(i) is not None
              for i in range(cfg.num_layers)]
     n_global = kinds.count(False)
     out, g, w = [], 0, 0
     for is_window in kinds:
         if is_window:
-            out.append((n_global * nb_global + w * nb_window, 1))
+            out.append(((0 if split else n_global * nb_global)
+                        + w * nb_window, 1))
             w += 1
         else:
             out.append((g * nb_global, 0))
@@ -862,9 +998,10 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     # kind and not once a layer, which is most of what a step program costs
     # the host before jax can look its compile cache up.  (A jit of this
     # trace's own: the ops choose their implementation while tracing.)
-    attend = {win: jax.jit(named_partial(
-        _mixed_attention, cfg=cfg, Q=Q, window=win, mesh=mesh))
-        for win in {cfg.window_for_layer(i) for i in range(cfg.num_layers)}}
+    attend = {kind: jax.jit(named_partial(
+        _mixed_attention, cfg=kind[0], Q=Q, window=kind[1], mesh=mesh))
+        for kind in {(cfg.for_layer(i), cfg.window_for_layer(i))
+                     for i in range(cfg.num_layers)}}
     plans = tuple(_write_plan(t, scat_slot, token_pos, block_size, Q, km)
                   for t in tables)
 
@@ -875,6 +1012,10 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     # first page added to the block table
     NB = cache.k.shape[1]
     flat_k_all, flat_v_all, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
+    flat_kw, flat_ki = _flat_group_views(cache)
+    # the selection binds only where a context can outgrow it: a step
+    # program whose table is no wider reads every key, through the kernels
+    select = MB * block_size > cfg.index_topk > 0
 
     # multi-tenant LoRA (static trace-time branch — adapter-less engines
     # send no "lora" key and trace the identical program): per-TOKEN
@@ -889,10 +1030,12 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     for li in range(cfg.num_layers):
         blk = bb[f"block_{li}"]
         ap, np_ = blk["Attention_0"], blk["Norm_0"]
+        lc = cfg.for_layer(li)           # this layer's attention geometry
         with jax.named_scope("attn_qkv"):
             h = _norm(np_, x, cfg)
             if cfg.mla:
-                q, k, v, gate = *_mla_qkv(ap, h, token_pos, cfg), None, None
+                q, k, gate, cq = _mla_qkv(ap, h, token_pos, lc)
+                v = None
             else:
                 q, k, v = _qkv(ap, h, cfg, mesh=mesh)
                 if lora is not None:
@@ -907,15 +1050,28 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                 q, k = q[0], k[0]
 
         base, grp = (li * NB, 0) if kv_layout is None else kv_layout[li]
-        flat_k_all, flat_v_all, flat_ks, flat_vs = _kv_write(
-            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v, plans[grp], base,
-            km, mesh=mesh)
-
-        o = attend[cfg.window_for_layer(li)](
-            q, rows, flat_k_all, flat_v_all, tables[grp] + base,
-            _layer_kv(flat_ks, flat_vs))
+        own = grp == 1 and flat_kw is not None    # the window group's pool
+        pool, flat_v_all, flat_ks, flat_vs = _kv_write(
+            flat_kw if own else flat_k_all, flat_v_all, flat_ks, flat_vs, k,
+            v, plans[grp], base, km, mesh=mesh)
+        flat_k_all, flat_kw = ((flat_k_all, pool) if own
+                               else (pool, flat_kw))
+        if lc.index_topk:
+            with jax.named_scope("attn_kernel"), \
+                    jax.named_scope("attn_index"):
+                qi, wi, ki = _index_rows(ap, h, cq, token_pos, lc)
+                flat_ki, = _kv_write_local((flat_ki,), ki, None, plans[grp],
+                                           base=base, km=False)
+        if lc.index_topk and select:
+            o = _selected_attention(
+                q, qi, wi, pool, flat_ki, tables[grp] + base, scat_slot,
+                token_pos, lc, block_size=block_size, max_rows=Q, rows=rows)
+        else:
+            o = attend[lc, cfg.window_for_layer(li)](
+                q, rows, pool, flat_v_all, tables[grp] + base,
+                _layer_kv(flat_ks, flat_vs))
         with jax.named_scope("attn_out"):
-            attn_delta = _attn_proj(ap, o, gate, cfg, mesh=mesh)
+            attn_delta = _attn_proj(ap, o, gate, lc, mesh=mesh)
             if cfg.sandwich_norm:
                 attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
         with jax.named_scope("mlp"):
@@ -928,7 +1084,8 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
         last_flat = jnp.zeros((S,), jnp.int32).at[scat_slot].max(
             jnp.arange(N, dtype=jnp.int32), mode="drop")
     logits = _head(params, bb, x, cfg, mesh=mesh, rows=last_flat)  # [S, V]
-    cache = _rebuild_cache(cache, flat_k_all, flat_v_all, flat_ks, flat_vs)
+    cache = _rebuild_cache(cache, flat_k_all, flat_v_all, flat_ks, flat_vs,
+                           flat_kw, flat_ki)
     out = (logits, cache) + ((sum(stats),) if moe_stats else ())
     # [expert layers, N, k]: the experts each row's router chose
     return out + ((jnp.stack(routes),) if moe_routes else ())
@@ -937,7 +1094,8 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
 def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                  block_table, cfg: GPTConfig, block_size: int, mesh=None,
                  flat_ks=None, flat_vs=None, lora=None, adapter_slot=None,
-                 kv_layout=None, moe_stats: bool = False, routes=None):
+                 kv_layout=None, moe_stats: bool = False, routes=None,
+                 groups=(None, None)):
     """One decode micro-step: writes each active slot's kv into its page and
     attends over exactly that slot's pages via the paged-attention op
     (ops/paged_attention.py — Pallas kernel on TPU, masked-gather XLA
@@ -948,9 +1106,10 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     flat_ks/flat_vs: [L*NB, nkv, bs] per-token scales when the cache is
     int8-quantized.  ``block_table`` is one table or the tuple of the page
     groups' (``_group_tables``: (global,) or, with ``kv_layout``, (global,
-    window)).  Returns the updated flat
-    views (incl. scales) and the step's MoE counters (None unless
-    ``moe_stats``)."""
+    window)).  ``groups``: the window group's own pool and the index-key
+    pool (``_flat_group_views``) where the cache has them.  Returns the
+    updated flat views (incl. scales), the step's MoE counters (None unless
+    ``moe_stats``) and the updated ``groups``."""
     from deepspeed_tpu import ops
     bb = params["backbone"]
     dtype = cfg.dtype
@@ -959,15 +1118,15 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     S = tokens.shape[0]
     L = cfg.num_layers
     NB = flat_k_all.shape[0] // L
-    nh = cfg.num_heads
-    nkv, hd, vd, latent = _attn_geometry(cfg)
-    g = nh // nkv
     km = kv_major_layout(cfg)
+    flat_kw, flat_ki = groups
+    select = tables[0].shape[1] * block_size > cfg.index_topk > 0
 
     x = _embed_tokens(bb, tokens, token_pos, cfg)              # [S, H]
 
-    plans = tuple(_write_plan(t, jnp.where(active, jnp.arange(S), S),
-                              token_pos, block_size, 1, km) for t in tables)
+    row_slot = jnp.where(active, jnp.arange(S), S)
+    plans = tuple(_write_plan(t, row_slot, token_pos, block_size, 1, km)
+                  for t in tables)
     with jax.named_scope("attn_kernel"):
         kv_len = jnp.where(active, token_pos + 1, 0)                # [S]
     if lora is not None:
@@ -978,10 +1137,14 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     for li in range(cfg.num_layers):
         blk = bb[f"block_{li}"]
         ap = blk["Attention_0"]
+        lc = cfg.for_layer(li)           # this layer's attention geometry
+        nh = lc.num_heads
+        nkv, hd, vd, latent = _attn_geometry(lc)
         with jax.named_scope("attn_qkv"):
             h = _norm(blk["Norm_0"], x, cfg)
             if cfg.mla:
-                q, k, v, gate = *_mla_qkv(ap, h, token_pos, cfg), None, None
+                q, k, gate, cq = _mla_qkv(ap, h, token_pos, lc)
+                v = None
             else:
                 q, k, v = _qkv(ap, h, cfg, mesh=mesh)
                 if lora is not None:
@@ -995,26 +1158,41 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                 q, k = q[:, 0], k[:, 0]
 
         base, grp = (li * NB, 0) if kv_layout is None else kv_layout[li]
-        flat_k_all, flat_v_all, flat_ks, flat_vs = _kv_write(
-            flat_k_all, flat_v_all, flat_ks, flat_vs, k, v, plans[grp], base,
-            km, mesh=mesh)
-        with jax.named_scope("attn_kernel"):
-            qg = q.reshape(S, nkv, g, hd)
-            slopes = None
-            if cfg.use_alibi:
-                from deepspeed_tpu.models.gpt import alibi_slopes
-                slopes = jnp.asarray(alibi_slopes(nh, hd,
-                                                  cfg.alibi_prescale))
-            win = cfg.window_for_layer(li)
-            o = ops.paged_attention(qg, flat_k_all, flat_v_all,
-                                    tables[grp] + base, kv_len,
-                                    alibi_slopes=slopes, window=win,
-                                    scale=_attn_scale(cfg), mesh=mesh,
-                                    kv_major=km, impl=cfg.attn_impl,
-                                    **_layer_kv(flat_ks, flat_vs), **latent)
-            o = o.reshape(S, nh, vd)
+        own = grp == 1 and flat_kw is not None    # the window group's pool
+        pool, flat_v_all, flat_ks, flat_vs = _kv_write(
+            flat_kw if own else flat_k_all, flat_v_all, flat_ks, flat_vs, k,
+            v, plans[grp], base, km, mesh=mesh)
+        flat_k_all, flat_kw = ((flat_k_all, pool) if own
+                               else (pool, flat_kw))
+        if lc.index_topk:
+            with jax.named_scope("attn_kernel"), \
+                    jax.named_scope("attn_index"):
+                qi, wi, ki = _index_rows(ap, h, cq, token_pos, lc)
+                flat_ki, = _kv_write_local((flat_ki,), ki, None, plans[grp],
+                                           base=base, km=False)
+        if lc.index_topk and select:
+            o = _selected_attention(
+                q, qi, wi, pool, flat_ki, tables[grp] + base, row_slot,
+                token_pos, lc, block_size=block_size)
+        else:
+            with jax.named_scope("attn_kernel"):
+                qg = q.reshape(S, nkv, nh // nkv, hd)
+                slopes = None
+                if cfg.use_alibi:
+                    from deepspeed_tpu.models.gpt import alibi_slopes
+                    slopes = jnp.asarray(alibi_slopes(nh, hd,
+                                                      cfg.alibi_prescale))
+                win = cfg.window_for_layer(li)
+                with _window_latent_scope(cfg, win):
+                    o = ops.paged_attention(
+                        qg, pool, flat_v_all, tables[grp] + base, kv_len,
+                        alibi_slopes=slopes, window=win,
+                        scale=_attn_scale(lc), mesh=mesh, kv_major=km,
+                        impl=cfg.attn_impl, **_layer_kv(flat_ks, flat_vs),
+                        **latent)
+                o = o.reshape(S, nh, vd)
         with jax.named_scope("attn_out"):
-            attn_delta = _attn_proj(ap, o, gate, cfg, mesh=mesh)
+            attn_delta = _attn_proj(ap, o, gate, lc, mesh=mesh)
             if cfg.sandwich_norm:
                 attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
         with jax.named_scope("mlp"):
@@ -1023,7 +1201,7 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
 
     logits = _head(params, bb, x, cfg, mesh=mesh)                  # [S, V]
     return (logits, flat_k_all, flat_v_all, flat_ks, flat_vs,
-            sum(stats) if moe_stats else None)
+            sum(stats) if moe_stats else None, (flat_kw, flat_ki))
 
 
 def _flat_cache_views(cache: PagedKVCache, cfg: GPTConfig):
@@ -1051,9 +1229,21 @@ def _flat_cache_views(cache: PagedKVCache, cfg: GPTConfig):
     return fk, fv, fks, fvs
 
 
-def _rebuild_cache(cache: PagedKVCache, fk, fv, fks, fvs) -> PagedKVCache:
+def _flat_group_views(cache: PagedKVCache):
+    """The flat views of the pools only a latent model with two page groups
+    has: (the window group's own pool, the index-key pool), each None where
+    the cache has none.  Scope ``kv_pool``."""
+    with jax.named_scope("kv_pool"):
+        return tuple(None if a is None else a.reshape((-1,) + a.shape[2:])
+                     for a in (cache.kw, cache.ki))
+
+
+def _rebuild_cache(cache: PagedKVCache, fk, fv, fks, fvs, fkw=None,
+                   fki=None) -> PagedKVCache:
     with jax.named_scope("kv_pool"):
         return PagedKVCache(
+            kw=None if fkw is None else fkw.reshape(cache.kw.shape),
+            ki=None if fki is None else fki.reshape(cache.ki.shape),
             k=fk.reshape(cache.k.shape),
             v=None if fv is None else fv.reshape(cache.v.shape),
             k_scale=(fks.reshape(cache.k_scale.shape) if fks is not None
@@ -1081,6 +1271,7 @@ def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
     ``moe_stats`` the burst's MoE counters, summed over its steps.
     """
     flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
+    groups = _flat_group_views(cache)
     bt = _group_tables(batch)
     active = batch["active"]
     lora = batch.get("lora")
@@ -1090,30 +1281,33 @@ def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
                             batch["tokens0"])
 
     def step(carry, _):
-        flat_k, flat_v, flat_ks, flat_vs, tokens, pos, rng = carry
-        logits, flat_k, flat_v, flat_ks, flat_vs, stats = _decode_core(
+        flat_k, flat_v, flat_ks, flat_vs, groups, tokens, pos, rng = carry
+        (logits, flat_k, flat_v, flat_ks, flat_vs, stats,
+         groups) = _decode_core(
             params, flat_k, flat_v, tokens, active, pos, bt, cfg, block_size,
             mesh=mesh, flat_ks=flat_ks, flat_vs=flat_vs, lora=lora,
             adapter_slot=adapter_slot, kv_layout=kv_layout,
-            moe_stats=moe_stats)
+            moe_stats=moe_stats, groups=groups)
         with jax.named_scope("sample"):
             rng, sub = jax.random.split(rng)
             nxt = sample_fn(logits, sub, temperature=temperature,
                             top_p=top_p)
             nxt = nxt.astype(jnp.int32)
             pos = pos + 1
-        return (flat_k, flat_v, flat_ks, flat_vs, nxt, pos, rng), (nxt, stats)
+        return ((flat_k, flat_v, flat_ks, flat_vs, groups, nxt, pos, rng),
+                (nxt, stats))
 
-    carry = (flat_k, flat_v, flat_ks, flat_vs, tokens0, batch["pos0"], rng)
+    carry = (flat_k, flat_v, flat_ks, flat_vs, groups, tokens0,
+             batch["pos0"], rng)
     # the loop itself belongs to the pool: what it does besides its body's
     # (scoped) work is carry the pool's views from step to step
     with jax.named_scope("kv_pool"):
-        (flat_k, flat_v, flat_ks, flat_vs, last, _, rng), (toks, stats) = \
-            jax.lax.scan(step, carry, None, length=steps)
+        ((flat_k, flat_v, flat_ks, flat_vs, groups, last, _, rng),
+         (toks, stats)) = jax.lax.scan(step, carry, None, length=steps)
     with jax.named_scope("sample"):
         prev_out = jnp.where(active, last, prev_tokens)
     out = (toks, prev_out, rng, _rebuild_cache(cache, flat_k, flat_v,
-                                               flat_ks, flat_vs))
+                                               flat_ks, flat_vs, *groups))
     return out + (jnp.sum(stats, axis=0),) if moe_stats else out
 
 
@@ -1340,7 +1534,7 @@ def _speculative_burst_core(params, draft_params, cache: PagedKVCache,
         # fused program
         with jax.named_scope("draft"):
             for j in range(gamma + 1):
-                dlogits, ddk, ddv, ddks, ddvs, _ = _decode_core(
+                dlogits, ddk, ddv, ddks, ddvs, _, _ = _decode_core(
                     draft_params, ddk, ddv, dtok, active, dpos, bt,
                     draft_cfg, block_size, mesh=mesh, flat_ks=ddks,
                     flat_vs=ddvs)
@@ -1517,12 +1711,13 @@ def ragged_decode_forward(params, cache: PagedKVCache, batch,
     flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
     bt = _group_tables(batch)
     routes = [] if moe_routes else None
-    logits, flat_k, flat_v, flat_ks, flat_vs, stats = _decode_core(
+    logits, flat_k, flat_v, flat_ks, flat_vs, stats, groups = _decode_core(
         params, flat_k, flat_v, batch["tokens"], batch["active"],
         batch["token_pos"], bt, cfg, block_size, mesh=mesh, flat_ks=flat_ks,
         flat_vs=flat_vs, lora=batch.get("lora"),
         adapter_slot=batch.get("adapter_slot"), kv_layout=kv_layout,
-        moe_stats=moe_stats, routes=routes)
-    cache = _rebuild_cache(cache, flat_k, flat_v, flat_ks, flat_vs)
+        moe_stats=moe_stats, routes=routes,
+        groups=_flat_group_views(cache))
+    cache = _rebuild_cache(cache, flat_k, flat_v, flat_ks, flat_vs, *groups)
     out = (logits, cache) + ((stats,) if moe_stats else ())
     return out + ((jnp.stack(routes),) if moe_routes else ())

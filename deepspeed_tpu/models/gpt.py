@@ -22,6 +22,7 @@ optional "loss_mask": [B, T]}.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import flax.linen as nn
@@ -173,6 +174,27 @@ class GPTConfig:
     kv_lora_rank: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: Optional[int] = None
+    # ... with a query latent (DeepSeek-V2/V3 ``q_lora_rank``): ``wq_a``, an
+    # RMSNorm, ``wq_b`` in place of ``wq``.  0 = none
+    q_lora_rank: int = 0
+    # both latents scaled after their norms by sqrt(hidden / rank) (the
+    # LongCat-Flash ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` convention)
+    mla_lora_rescale: bool = False
+    attn_gate_headwise: bool = False    # latent attention's gate: ONE
+    #                                     sigmoid scalar a head, before Wo
+    # a learned selection of keys (DeepSeek-V3.2's "lightning indexer") on
+    # the layers WITHOUT a window: ``index_n_heads`` index queries of
+    # ``index_head_dim`` from the query latent score one index key a token,
+    # and attention reads the ``index_topk`` best keys only
+    # (ops/sparse_index.py).  0 = attention reads every key
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    # attention geometry of the layers WITH a window where it differs from
+    # the other layers': ((field, value), ...) over num_heads, head_dim,
+    # v_head_dim, kv_lora_rank, q_lora_rank, qk_rope_head_dim, rope_theta,
+    # attn_scale.  ``for_layer(i)`` is the view a layer's attention takes
+    window_attn: tuple = ()
 
     @property
     def kv_heads(self) -> int:
@@ -192,6 +214,15 @@ class GPTConfig:
         """... and what a page row stores: that, padded with zeros to whole
         lane tiles of 128 (ops/paged_attention.py ``_dma_layout_ok``)."""
         return -(-self.latent_dim // 128) * 128
+
+    def for_layer(self, i: int) -> "GPTConfig":
+        """The configuration as layer ``i``'s ATTENTION sees it: the window
+        layers' own geometry (``window_attn``) applied, and the indexer on
+        the layers without a window only.  The same object where the layers
+        are all alike."""
+        if not self.window_attn and not self.index_topk:
+            return self
+        return _layer_view(self, self.window_for_layer(i) is not None)
 
     def is_moe_layer(self, i: int) -> bool:
         """Whether layer ``i`` holds experts: after ``moe_dense_layers``
@@ -251,6 +282,19 @@ class GPTConfig:
         kw.setdefault("max_seq_len", 128)
         return cls(num_layers=2, num_heads=4, head_dim=8, hidden_size=32,
                    mlp_ratio=2, **kw)
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_view(cfg: GPTConfig, windowed: bool) -> GPTConfig:
+    if not windowed:
+        return dataclasses.replace(cfg, window_attn=())
+    allowed = {"num_heads", "head_dim", "v_head_dim", "kv_lora_rank",
+               "q_lora_rank", "qk_rope_head_dim", "rope_theta", "attn_scale"}
+    extra = dict(cfg.window_attn)
+    if set(extra) - allowed:
+        raise ValueError(f"window_attn may set {sorted(allowed)}, got "
+                         f"{sorted(set(extra) - allowed)}")
+    return dataclasses.replace(cfg, window_attn=(), index_topk=0, **extra)
 
 
 def _gather_table(table, mesh, vocab_axis="tp"):
@@ -711,6 +755,14 @@ def mla_split(c: GPTConfig):
             c.v_head_dim or c.head_dim - c.qk_rope_head_dim)
 
 
+def _lora_rescale(x, c: GPTConfig, rank: int):
+    """A latent after its norm: times sqrt(hidden / rank) under
+    ``mla_lora_rescale``."""
+    if not c.mla_lora_rescale:
+        return x
+    return x * jnp.asarray((c.hidden_size / rank) ** 0.5, x.dtype)
+
+
 def mla_latent(wkv_a, kv_norm, h, positions, c: GPTConfig):
     """A token's cache row from the normed layer input ``h [..., H]``:
     ``(c_kv [..., kv_lora_rank]`` after its RMSNorm, ``k_pe [..., rope]``
@@ -719,33 +771,92 @@ def mla_latent(wkv_a, kv_norm, h, positions, c: GPTConfig):
     from deepspeed_tpu.ops import rms_norm
     from deepspeed_tpu.ops.norms import RMS_EPS
     ckv = h @ wkv_a.astype(h.dtype)
-    c_kv = rms_norm(ckv[..., :c.kv_lora_rank], kv_norm,
-                    eps=c.norm_eps or RMS_EPS)
+    c_kv = _lora_rescale(rms_norm(ckv[..., :c.kv_lora_rank], kv_norm,
+                                  eps=c.norm_eps or RMS_EPS), c,
+                         c.kv_lora_rank)
     k_pe = ckv[..., c.kv_lora_rank:]
-    lead = k_pe.shape[:-1]
-    k4 = k_pe.reshape((-1,) + lead[-1:] + (1, c.qk_rope_head_dim))
-    p2 = positions.reshape(k4.shape[:2])
-    k4, _ = rope(k4, k4, p2, c.qk_rope_head_dim, base=c.rope_theta,
-                 scaling=c.rope_scaling)
-    return c_kv, k4.reshape(k_pe.shape)
+    return c_kv, _rope_rows(k_pe[..., None, :], positions,
+                            c.qk_rope_head_dim, c)[..., 0, :]
+
+
+def _rope_rows(x, positions, width: int, c: GPTConfig, rotated=None):
+    """``x [..., n, width]`` at ``positions [...]`` with the leading
+    ``rotated`` (all) of its ``width`` columns rotated by halves at the
+    layer's base."""
+    lead = x.shape[:-2]
+    x4 = x.reshape(((-1,) if len(lead) > 1 else (1,)) + (lead[-1],)
+                   + x.shape[-2:])
+    x4, _ = rope(x4, x4, positions.reshape(x4.shape[:2]), width,
+                 base=c.rope_theta, scaling=c.rope_scaling,
+                 rope_pct=1.0 if rotated is None else rotated / width)
+    return x4.reshape(x.shape)
+
+
+def mla_query_latent(wq_a, q_norm, h, c: GPTConfig):
+    """The query latent ``cq [..., q_lora_rank]`` of ``h [..., H]``: normed
+    (and rescaled), what ``wq_b`` and the indexer's queries read."""
+    from deepspeed_tpu.ops import rms_norm
+    from deepspeed_tpu.ops.norms import RMS_EPS
+    return _lora_rescale(
+        rms_norm(h @ wq_a.astype(h.dtype), q_norm, eps=c.norm_eps or RMS_EPS),
+        c, c.q_lora_rank)
 
 
 def mla_query(wq, h, positions, c: GPTConfig):
-    """``(q_nope [..., nh, nope], q_pe [..., nh, rope]`` rotated)."""
+    """``(q_nope [..., nh, nope], q_pe [..., nh, rope]`` rotated) from ``h``
+    through ``wq [H, nh, d]``, or from the query latent through ``wq_b``."""
     nope, rot, _ = mla_split(c)
     q = jnp.einsum("...h,hnd->...nd", h, wq.astype(h.dtype))
-    q_pe = q[..., nope:]
-    q4 = q_pe.reshape((-1,) + q_pe.shape[-3:])
-    q4, _ = rope(q4, q4, positions.reshape(q4.shape[:2]), rot,
-                 base=c.rope_theta, scaling=c.rope_scaling)
-    return q[..., :nope], q4.reshape(q_pe.shape)
+    return q[..., :nope], _rope_rows(q[..., nope:], positions, rot, c)
+
+
+def index_query(wq_idx, ww_idx, cq, h, positions, c: GPTConfig):
+    """The indexer's side of a query row: ``(qI [..., nI, dI]``, its leading
+    rope columns rotated, ``w [..., nI]`` float32 with both scales in)."""
+    q = jnp.einsum("...r,rnd->...nd", cq, wq_idx.astype(cq.dtype))
+    q = _rope_rows(q, positions, c.index_head_dim, c,
+                   rotated=c.qk_rope_head_dim)
+    w = (h @ ww_idx.astype(h.dtype)).astype(jnp.float32)
+    return q, w * (c.index_n_heads ** -0.5 * c.index_head_dim ** -0.5)
+
+
+def index_key(wk_idx, norm_scale, norm_bias, h, positions, c: GPTConfig):
+    """A token's index key ``[..., dI]``: LayerNorm of its projection, the
+    leading rope columns rotated: what the index-key pool stores."""
+    from deepspeed_tpu.ops import layer_norm
+    k = layer_norm(h @ wk_idx.astype(h.dtype), norm_scale, norm_bias,
+                   eps=1e-6)
+    return _rope_rows(k[..., None, :], positions, c.index_head_dim, c,
+                      rotated=c.qk_rope_head_dim)[..., 0, :]
+
+
+def index_scores_dense(q, w, k):
+    """``I[b, t, s] = sum_j w[b, t, j] relu(q[b, t, j] . k[b, s])``, float32:
+    the uncached form (the serving op is ops/sparse_index.py)."""
+    x = jnp.einsum("btjd,bsd->btjs", q, k,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("btjs,btj->bts", jax.nn.relu(x), w)
+
+
+def topk_mask(scores, seen, k: int):
+    """Of each row of ``scores [..., T, S]`` the ``k`` largest among the
+    keys it may see (``seen``, bool), ties to the lower position, as a
+    mask."""
+    k = min(int(k), scores.shape[-1])
+    _, idx = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), k)
+    hit = jnp.zeros(scores.shape, bool)
+    hit = jnp.put_along_axis(hit, idx, True, axis=-1, inplace=False)
+    return hit & seen
 
 
 class MLAttention(nn.Module):
     """Latent attention (MLA), the uncached forward as published: keys and
-    values expanded from the latent, one rotated key part for all heads.
-    The serving engine reads the same parameters in absorbed form over a
-    latent page pool (inference/v2/model.py)."""
+    values expanded from the latent, one rotated key part for all heads;
+    with a query latent (``q_lora_rank``), a window, a headwise gate and a
+    learned selection of keys (``index_topk``, as a mask over dense scores)
+    where the configuration has them.  The serving engine reads the same
+    parameters in absorbed form over latent page pools
+    (inference/v2/model.py)."""
 
     cfg: GPTConfig
     mesh: Optional[object] = None
@@ -756,38 +867,80 @@ class MLAttention(nn.Module):
                  kv_positions=None, window=None, fused_ok: bool = False,
                  use_rope: Optional[bool] = None):
         c = self.cfg
-        if use_cache or window is not None or c.use_alibi or c.qk_norm \
-                or c.attn_gate or c.qkv_bias or c.sequence_parallel:
+        if use_cache or c.use_alibi or c.qk_norm or c.attn_gate \
+                or c.qkv_bias or c.sequence_parallel:
             raise NotImplementedError(
                 "latent attention (kv_lora_rank) is built for the uncached "
-                "forward and the v2 engine's latent page pool: no flax KV "
-                "cache, window, alibi, qk_norm, gate, bias or sequence "
-                "parallelism beside it")
+                "forward and the v2 engine's latent page pools: no flax KV "
+                "cache, alibi, qk_norm, elementwise gate (attn_gate_headwise "
+                "is its gate), bias or sequence parallelism beside it")
+        if c.index_topk and not c.q_lora_rank:
+            raise NotImplementedError(
+                "the indexer's queries come from the query latent: "
+                "index_topk needs q_lora_rank")
         B, T, H = x.shape
         nh = c.num_heads
         nope, rot, vd = mla_split(c)
-        wq = self.param("wq", _part(_kernel_init(), ("embed", "heads", "kv")),
-                        (H, nh, c.head_dim), c.param_dtype)
-        wkv_a = self.param("wkv_a", _part(_kernel_init(), ("embed", None)),
+        kinit = _kernel_init()
+        ones = nn.initializers.ones
+        if c.q_lora_rank:
+            wq_a = self.param("wq_a", _part(kinit, ("embed", None)),
+                              (H, c.q_lora_rank), c.param_dtype)
+            q_norm = self.param("q_norm", _part(ones, (None,)),
+                                (c.q_lora_rank,), c.param_dtype)
+            wq = self.param("wq_b", _part(kinit, (None, "heads", "kv")),
+                            (c.q_lora_rank, nh, c.head_dim), c.param_dtype)
+            cq = mla_query_latent(wq_a, q_norm, x, c)
+        else:
+            wq = self.param("wq", _part(kinit, ("embed", "heads", "kv")),
+                            (H, nh, c.head_dim), c.param_dtype)
+            cq = x
+        wkv_a = self.param("wkv_a", _part(kinit, ("embed", None)),
                            (H, c.latent_dim), c.param_dtype)
-        kv_norm = self.param("kv_norm", _part(nn.initializers.ones, (None,)),
+        kv_norm = self.param("kv_norm", _part(ones, (None,)),
                              (c.kv_lora_rank,), c.param_dtype)
-        wkv_b = self.param("wkv_b", _part(_kernel_init(),
-                                          (None, "heads", "kv")),
+        wkv_b = self.param("wkv_b", _part(kinit, (None, "heads", "kv")),
                            (c.kv_lora_rank, nh, nope + vd), c.param_dtype)
-        wo = self.param("wo", _part(_kernel_init(), ("heads", "kv", "embed")),
+        wo = self.param("wo", _part(kinit, ("heads", "kv", "embed")),
                         (nh, vd, H), c.param_dtype)
-        q_nope, q_pe = mla_query(wq, x, positions, c)
+        q_nope, q_pe = mla_query(wq, cq, positions, c)
         c_kv, k_pe = mla_latent(wkv_a, kv_norm, x, positions, c)
         kv = jnp.einsum("btr,rnd->btnd", c_kv, wkv_b.astype(x.dtype))
         k = jnp.concatenate(
             [kv[..., :nope],
              jnp.broadcast_to(k_pe[:, :, None, :], (B, T, nh, rot))], -1)
         q = jnp.concatenate([q_nope, q_pe], -1)
+        mask = None
+        if window is not None or c.index_topk:
+            rel = positions[:, :, None] - positions[:, None, :]
+            mask = rel >= 0
+            if window is not None:
+                mask = mask & (rel < window)
+        if c.index_topk:
+            nI, dI = c.index_n_heads, c.index_head_dim
+            wq_idx = self.param("wq_idx", _part(kinit, (None, None, None)),
+                                (c.q_lora_rank, nI, dI), c.param_dtype)
+            wk_idx = self.param("wk_idx", _part(kinit, ("embed", None)),
+                                (H, dI), c.param_dtype)
+            ww_idx = self.param("ww_idx", _part(kinit, ("embed", None)),
+                                (H, nI), c.param_dtype)
+            kn_s = self.param("k_idx_norm_scale", _part(ones, (None,)),
+                              (dI,), c.param_dtype)
+            kn_b = self.param("k_idx_norm_bias",
+                              _part(nn.initializers.zeros, (None,)), (dI,),
+                              c.param_dtype)
+            qi, wi = index_query(wq_idx, ww_idx, cq, x, positions, c)
+            ki = index_key(wk_idx, kn_s, kn_b, x, positions, c)
+            mask = topk_mask(index_scores_dense(qi, wi, ki), mask,
+                             c.index_topk)
         from deepspeed_tpu import ops
         # the flash kernel takes one width for keys and values: XLA here
-        out = ops.causal_attention(q, k, kv[..., nope:], scale=c.attn_scale,
-                                   impl="xla")
+        out = ops.causal_attention(q, k, kv[..., nope:], causal=mask is None,
+                                   mask=mask, scale=c.attn_scale, impl="xla")
+        if c.attn_gate_headwise:
+            wgate = self.param("wgate", _part(kinit, ("embed", "heads")),
+                               (H, nh), c.param_dtype)
+            out = out * jax.nn.sigmoid(x @ wgate.astype(x.dtype))[..., None]
         return jnp.einsum("btnd,ndh->bth", out, wo.astype(x.dtype))
 
 
@@ -836,6 +989,8 @@ class Block(nn.Module):
     cfg: GPTConfig
     is_moe: bool = False
     mesh: Optional[object] = None
+    attn_cfg: Optional[GPTConfig] = None   # this layer's attention view
+    #                                        (GPTConfig.for_layer); None: cfg
 
     @nn.compact
     def __call__(self, x, positions, deterministic: bool,
@@ -882,7 +1037,8 @@ class Block(nn.Module):
                     + pld_gate(MLP(c, mesh=self.mesh)(h_mlp, deterministic,
                                                       use_cache=use_cache)),
                     jnp.float32(0.0))
-        attn = (MLAttention(c, mesh=self.mesh, name="Attention_0") if c.mla
+        attn = (MLAttention(self.attn_cfg or c, mesh=self.mesh,
+                            name="Attention_0") if c.mla
                 else Attention(c, mesh=self.mesh))
         a = attn(Norm(c)(x), positions, deterministic, use_cache, kv_mask,
                  start_index, kv_positions, window=window, fused_ok=fused_ok,
@@ -986,7 +1142,7 @@ class GPTBackbone(nn.Module):
         aux_total = jnp.float32(0.0)
         for i in range(c.num_layers):
             block = block_cls(c, c.is_moe_layer(i), self.mesh,
-                              name=f"block_{i}")
+                              c.for_layer(i), name=f"block_{i}")
             keep = None
             if pld_theta is not None:
                 from deepspeed_tpu.runtime.progressive_layer_drop import \
@@ -1155,6 +1311,26 @@ class GPTChunkedLoss(GPT):
         return self.cfg.loss_chunk or 512
 
 
+def _mla_params(c: GPTConfig) -> int:
+    """A latent-attention layer's parameters: wq (or wq_a, q_norm, wq_b),
+    wkv_a, kv_norm, wkv_b, wo, the headwise gate, the indexer."""
+    H = c.hidden_size
+    nope, _, vd = mla_split(c)
+    q_in = c.q_lora_rank or H
+    n = (c.num_heads * (c.head_dim * q_in + vd * H
+                        + c.kv_lora_rank * (nope + vd))
+         + H * c.latent_dim + c.kv_lora_rank)
+    if c.q_lora_rank:
+        n += H * c.q_lora_rank + c.q_lora_rank
+    if c.attn_gate_headwise:
+        n += H * c.num_heads
+    if c.index_topk:
+        n += (c.q_lora_rank * c.index_n_heads * c.index_head_dim
+              + H * c.index_head_dim + H * c.index_n_heads
+              + 2 * c.index_head_dim)
+    return n
+
+
 def count_params(cfg: GPTConfig) -> int:
     """Parameters of the model ``cfg`` describes, as held here (an expert
     layer counts the experts it holds: ``cfg.local_experts``)."""
@@ -1166,18 +1342,18 @@ def count_params(cfg: GPTConfig) -> int:
     attn = (cfg.num_heads * cfg.head_dim * H * (3 if cfg.attn_gate else 2)
             + cfg.kv_heads * cfg.head_dim * H * 2              # wk, wv
             + (2 * cfg.head_dim if cfg.qk_norm else 0))
-    if cfg.mla:             # wq, wkv_a, kv_norm, wkv_b, wo
-        nope, _, vd = mla_split(cfg)
-        attn = (cfg.num_heads * (cfg.head_dim * H + vd * H
-                                 + cfg.kv_lora_rank * (nope + vd))
-                + H * cfg.latent_dim + cfg.kv_lora_rank)
     attn += H * norms * (1 if cfg.use_rmsnorm else 2)
+    attn *= cfg.num_layers
+    if cfg.mla:             # a layer's own geometry (GPTConfig.for_layer)
+        attn = sum(_mla_params(cfg.for_layer(i))
+                   + H * norms * (1 if cfg.use_rmsnorm else 2)
+                   for i in range(cfg.num_layers))
     moe_layers = sum(cfg.is_moe_layer(i) for i in range(cfg.num_layers))
     moe_ffn = (cfg.local_experts * H * cfg.expert_dim * n_mat
                + H * cfg.num_experts                            # router
                + (cfg.num_experts if cfg.moe_router_bias else 0)
                + 3 * H * cfg.moe_shared_dim)
-    total = (attn * cfg.num_layers + moe_ffn * moe_layers
+    total = (attn + moe_ffn * moe_layers
              + H * M * n_mat * (cfg.num_layers - moe_layers) + V * H + H)
     if not cfg.use_rope and not cfg.use_alibi:
         total += cfg.max_seq_len * H

@@ -162,6 +162,16 @@ register_op("ragged_prefill_attention", xla=_paged.xla_ragged_prefill,
             pallas=_paged.pallas_ragged_prefill,
             supported=_paged.ragged_prefill_supported)
 
+from deepspeed_tpu.ops import sparse_index as _index  # noqa: E402
+from deepspeed_tpu.ops.sparse_index import (  # noqa: E402
+    index_scores, index_select, selected_attention)
+
+register_op("index_scores", xla=_index.xla_index_scores,
+            pallas=_index.pallas_index_scores,
+            supported=_index.index_scores_supported)
+register_op("index_select", xla=_index.xla_index_select)
+register_op("selected_attention", xla=_index.xla_selected_attention)
+
 from deepspeed_tpu.ops import grouped_gemm as _grouped  # noqa: E402
 
 register_op("grouped_gemm", xla=_grouped.xla_grouped_gemm)
@@ -227,6 +237,7 @@ def causal_attention(q, k, v, *, causal: bool = True,
 __all__ = ["causal_attention", "flash_attention", "configure_flash_blocks",
            "paged_attention", "lora_matmul",
            "ragged_prefill_attention", "evoformer_attention",
+           "index_scores", "index_select", "selected_attention",
            "all_gather_matmul", "matmul_reduce_scatter",
            "row_parallel_matmul", "collective_matmul",
            "lm_cross_entropy", "masked_nll_sum", "rms_norm", "layer_norm",

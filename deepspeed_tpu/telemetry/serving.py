@@ -253,6 +253,20 @@ class ServingTelemetry:
             "cached token over all layers (a latent pool: one padded latent "
             "row a layer; otherwise every kv head's key and value)")
         self.kv_bytes_per_token = 0     # set_kv_bytes_per_token
+        self.kv_bytes_groups: Dict[str, int] = {}
+        # ---- a learned selection of keys (index_topk): over the layers
+        # that select, what the indexer scored, what attention then read,
+        # and what it would have read without a selection
+        self.c_index_pairs = reg.counter(
+            "serving_index_pairs_total", "query-key pairs the indexer "
+            "scored (each causal pair of a dispatch that selects), summed "
+            "over the selecting layers")
+        self.c_sel_pairs = reg.counter(
+            "serving_selected_pairs_total", "query-key pairs attention "
+            "kept on the selecting layers: min(keys seen, index_topk) a row")
+        self.c_global_pairs = reg.counter(
+            "serving_global_pairs_total", "causal query-key pairs on the "
+            "selecting layers: what dense attention would read")
 
     # ------------------------------------------------------------- clocks
 
@@ -377,12 +391,26 @@ class ServingTelemetry:
             self.c_moe_assign.inc(int(vec[1]), **self.labels)
             self.c_moe_touched.inc(int(vec[2]), **self.labels)
 
-    def set_kv_bytes_per_token(self, n: int) -> None:
+    def set_kv_bytes_per_token(self, n: int, **groups: int) -> None:
         """The engine's pool geometry, once at start-up: the gauge beside
-        ``kv_pages_in_use`` and an argument of every dispatch span."""
+        ``kv_pages_in_use`` and an argument of every dispatch span.
+        ``groups``: its parts where the pool has more than one
+        (``kv_bytes_per_token_global`` / ``_window``,
+        ``index_bytes_per_token``): a gauge series and a span argument
+        each."""
         self.kv_bytes_per_token = int(n)
+        self.kv_bytes_groups = {k: int(v) for k, v in groups.items()}
         if self.enabled:
             self.g_kv_bytes.set(int(n), **self.labels)
+            for k, v in self.kv_bytes_groups.items():
+                self.g_kv_bytes.set(v, part=k, **self.labels)
+
+    def index_pairs(self, scored: int, kept: int, causal: int) -> None:
+        """One dispatch's pairs on the selecting layers."""
+        if self.enabled:
+            self.c_index_pairs.inc(scored, **self.labels)
+            self.c_sel_pairs.inc(kept, **self.labels)
+            self.c_global_pairs.inc(causal, **self.labels)
 
     def counter_note(self, state) -> Dict[str, int]:
         """Running totals for a dispatch span's args, so that a trace holds
@@ -400,6 +428,13 @@ class ServingTelemetry:
             one_row_seqs=int(self.c_one_row_slots.value(**self.labels)),
             prefill_items=int(self.c_prefill_items.value(**self.labels)),
             prefill_grid_items=int(self.c_prefill_grid.value(**self.labels)))
+        note.update(self.kv_bytes_groups)
+        pairs = self.c_global_pairs.value(**self.labels)
+        if pairs:
+            note.update(
+                index_pairs=int(self.c_index_pairs.value(**self.labels)),
+                sel_pairs=int(self.c_sel_pairs.value(**self.labels)),
+                global_pairs=int(pairs))
         total = self.c_moe_assign.value(**self.labels)
         if total:
             note.update(

@@ -464,6 +464,186 @@ def test_mixed_step_lays_no_rows_out_dense(step_programs, monkeypatch,
     assert not large, large
 
 
+# ------------------------------------- a model that selects its keys
+
+# dots3-note-prev's attention at published widths, one full layer (128 heads
+# over a latent row of 512 + 64 -> 640, 64 index heads of 128 choosing 2,048
+# keys) and one sliding layer (64 heads over 1,024 + 64 -> 1,152, window
+# 513), two of 256 experts held: the serving cell's kernels and its three
+# pools (a latent pool a page group, and the index keys)
+DOTS3 = GPTConfig(
+    num_layers=2, hidden_size=5120, num_heads=128, head_dim=192,
+    v_head_dim=128, kv_lora_rank=512, q_lora_rank=1024, qk_rope_head_dim=64,
+    rope_theta=8e7, mla_lora_rescale=True, attn_gate_headwise=True,
+    index_topk=2048, index_n_heads=64, index_head_dim=128,
+    sliding_window=513, local_attn_layers=(1,),
+    window_attn=(("num_heads", 64), ("head_dim", 256), ("v_head_dim", 128),
+                 ("kv_lora_rank", 1024), ("q_lora_rank", 1024),
+                 ("qk_rope_head_dim", 64), ("rope_theta", 50000.0)),
+    use_rope=True, use_rmsnorm=True, norm_eps=1e-5, gated_mlp=True,
+    tie_embeddings=False, mlp_dim_override=13824, vocab_size=19008,
+    max_seq_len=32768, num_experts=256, experts_held=2, expert_offset=32,
+    moe_k=8, moe_dropless=True, moe_router="sigmoid", moe_router_bias=True,
+    moe_shared_dim=1536, moe_expert_dim=1536, moe_dense_layers=1)
+
+
+def test_index_score_kernel_and_the_ops_around_it(topo):
+    """The index-score kernel at the cell's block (64 heads of 128, 128 rows
+    against a 32 k context), the op that walks a mixed step's slots with it,
+    the exact selection and the attention over the selected rows, each for
+    the described chip at published widths."""
+    from deepspeed_tpu import ops
+    from deepspeed_tpu.ops import sparse_index as si
+    text = chip_text(topo, lambda q, w, k: si._score_block(
+        q, w, k, interpret=False),
+        sds((64, si.SCORE_ROWS, 128), BF16), sds((64, si.SCORE_ROWS), F32),
+        sds((32768, 128), BF16))
+    assert "index_score_kernel" in text and KERNEL in text
+    N, S, MB, bs = 1024, 8, 64, 512
+    text = chip_text(
+        topo, lambda q, w, kp, tb, rs, rp: ops.index_scores(
+            q, w, kp, tb, rs, rp, max_rows=N, impl="pallas",
+            interpret=False),
+        sds((N, 64, 128), BF16), sds((N, 64), F32),
+        sds((S * MB, 1, bs, 128), BF16), sds((S, MB), I32), sds((N,), I32),
+        sds((N,), I32))
+    assert "index_score_kernel" in text
+    assert chip_text(topo, lambda s: ops.index_select(s, 2048),
+                     sds((N, MB * bs), F32))
+    assert chip_text(
+        topo, lambda q, pg, r, c: ops.selected_attention(
+            q, pg, r, c, v_dim=512, scale=192 ** -0.5),
+        sds((N, 128, 640), BF16), sds((S * MB, 1, bs, 640), BF16),
+        sds((N, 2048), I32), sds((N,), I32))
+
+
+@pytest.mark.parametrize("kernel", [decode_text, prefill_text],
+                         ids=["decode", "prefill"])
+def test_window_layers_take_the_paged_kernels_at_their_own_width(topo,
+                                                                 kernel):
+    from deepspeed_tpu.inference.v2.model import _attn_geometry
+    nkv, hd, vd, latent = _attn_geometry(DOTS3.for_layer(1))
+    assert (nkv, hd, vd) == (1, 1152, 1024)
+    geo = dict(nkv=1, g=64, hd=hd, bs=512, kv_major=False, window=513,
+               **latent)
+    if kernel is prefill_text:
+        geo.update(Q=1024)
+    assert KERNEL in kernel(topo, **geo)
+
+
+@pytest.fixture(scope="module")
+def selecting_steps(topo):
+    """The three step programs of ``DOTS3`` compiled for the described chip
+    at 8 slots, 1,024 tokens a forward and pages of 512 (the cell's sizes),
+    the table 64 pages wide: ``get()`` -> (cache shapes, {program:
+    compiled}), lowered when first asked for (by then the test has told the
+    kernels that the backend is the chip)."""
+    done = []
+
+    def get():
+        if not done:
+            done.append(_selecting_steps(topo))
+        return done[0]
+    return get
+
+
+def _selecting_steps(topo):
+    import functools
+
+    from deepspeed_tpu.inference.engine import _sample_token
+    from deepspeed_tpu.inference.v2 import model as v2model
+    from deepspeed_tpu.models.gpt import GPTLogits
+    from deepspeed_tpu.parallel.metadata import unbox
+    cfg = dataclasses.replace(DOTS3, dtype=BF16, param_dtype=BF16,
+                              attn_impl="pallas")
+    S, N, MB, bs = 8, 1024, 64, 512
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = unbox(jax.eval_shape(
+        lambda k: GPTLogits(cfg).init(k, jnp.zeros((1, 8), I32)),
+        jax.random.PRNGKey(0))["params"])
+    cache = jax.eval_shape(lambda: v2model.PagedKVCache.create_latent_groups(
+        cfg, S * MB, S * 5, bs, BF16))
+    params, cache = jax.tree_util.tree_map(
+        lambda a: sd(a.shape, a.dtype), (params, cache))
+    tables = {"block_table": sd((S, MB), I32),
+              "block_table_w": sd((S, MB), I32)}
+    slot = {"active": sd((S,), jnp.bool_), "from_device": sd((S,), jnp.bool_),
+            **tables}
+    programs = {
+        "ragged_forward_sampled": (
+            dict(max_q_per_seq=N),
+            {"tokens": sd((N,), I32), "token_slot": sd((N,), I32),
+             "token_pos": sd((N,), I32), **tables, "kv_len": sd((S,), I32),
+             "from_device": sd((N,), jnp.bool_),
+             "served": sd((S,), jnp.bool_)}),
+        "ragged_decode_sampled": (
+            {}, {**slot, "tokens": sd((S,), I32), "token_pos": sd((S,), I32),
+                 "served": sd((S,), jnp.bool_)}),
+        "ragged_decode_burst": (
+            dict(steps=8), {**slot, "tokens0": sd((S,), I32),
+                            "pos0": sd((S,), I32)})}
+    layout = v2model.kv_page_layout(cfg, S * MB, S * 5, split=True)
+    sample = functools.partial(_sample_token, do_sample=False, top_k=0)
+    compiled = {}
+    for name, (static, batch) in programs.items():
+        fn = functools.partial(getattr(v2model, name), cfg=cfg, block_size=bs,
+                               sample_fn=sample, kv_layout=layout,
+                               moe_stats=True, **static)
+        compiled[name] = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, cache, batch, sd((S,), I32), sd((2,), jnp.uint32),
+            sd((), F32), sd((), F32)).compile()
+    return cache, compiled
+
+
+@pytest.mark.parametrize("program", ["ragged_forward_sampled",
+                                     "ragged_decode_sampled",
+                                     "ragged_decode_burst"])
+def test_selecting_step_programs_leave_all_three_pools_in_place(
+        selecting_steps, monkeypatch, program):
+    """PR 27's rule for each of the three pools of a model that selects its
+    keys: no step program copies, slices or re-lays a layer's pages of the
+    global group's latent pool, of the window group's, or of the index
+    keys (the selected rows and a slot's index keys are GATHERED: a gather
+    reads what it needs and is no mover).  And the kernels are there by
+    the names the benchmark's readers take them by."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache, compiled = selecting_steps()
+    text = compiled[program].as_text()
+    calls = [ln for ln in text.splitlines()
+             if f'custom_call_target="{KERNEL}"' in ln]
+
+    def named(*what):
+        return sum(all(w in ln for w in what) for ln in calls)
+    if program == "ragged_forward_sampled":
+        assert named("index_score_kernel") == 1          # the full layer
+        assert named("/window_latent/", "/ragged_prefill/") == 1
+        assert named("/window_latent/", "/paged_decode/") == 1
+    else:
+        assert named("index_score_kernel") == 0          # one row a slot
+        assert named("/window_latent/", "/paged_decode/") == 1
+        assert named("ragged_prefill") == 0
+    assert named("/paged_decode/") == 1     # the full layer takes no kernel
+    assert "selected_attention" in text and "attn_index" in text
+    layers = {"k": 1, "kw": 1, "ki": 1}                  # layers a pool
+    moved = []
+    for result, op in _HLO_OP.findall(text):
+        if op not in POOL_MOVERS:
+            continue
+        for dt, dims in _HLO_ARRAY.findall(result):
+            shape = [int(d) for d in dims.split(",") if d] or [1]
+            n = int(np.prod(shape))
+            for name, n_layers in layers.items():
+                pool = getattr(cache, name)
+                layer = int(np.prod(pool.shape)) // n_layers
+                if dt == "bf16" and n >= layer \
+                        and shape[-1] == pool.shape[-1]:
+                    moved.append(f"{op} -> {dt}[{dims}] ({name})")
+    assert not moved, moved
+
+
 # -------------------------------------------------------- quantized GEMMs
 
 def _store(shape, dim=0):

@@ -248,12 +248,18 @@ class TestRaggedForward:
         want = full_logits(cfg, engine, ids[None])[0, -1]
         np.testing.assert_allclose(logits[0], want, atol=1e-4, rtol=1e-4)
 
-    def test_budget_and_chunk_guards(self, engine, rng):
+    def test_budget_and_chunk_guards(self, cfg, engine, rng):
+        """One forward's guards stand where the forward is built; ``put()``
+        runs a call that holds more than a forward as chunks."""
         with pytest.raises(ValueError, match="max_q_per_seq"):
-            engine.put([1], [np.zeros(17, np.int32)])
+            engine._put_device([1], [np.zeros(17, np.int32)])
         with pytest.raises(ValueError, match="budget"):
-            engine.put([1, 2, 3, 4, 5],
-                       [np.zeros(16, np.int32)] * 5)
+            engine._put_device([1, 2, 3, 4, 5],
+                               [np.zeros(16, np.int32)] * 5)
+        ids = rng.integers(0, 97, (2, 40)).astype(np.int32)
+        got = engine.put([1, 2], list(ids))     # 80 tokens: 16-row chunks
+        np.testing.assert_allclose(got, full_logits(cfg, engine, ids)[:, -1],
+                                   atol=1e-4, rtol=1e-4)
 
 
 class TestQueryFlush:

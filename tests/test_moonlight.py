@@ -177,7 +177,7 @@ def test_absorbed_decode_is_the_expanded_form_on_the_same_cache():
     c, k_pe = rows[:, :128], rows[:, 128:136]
     assert float(jnp.max(jnp.abs(rows[:, 136:]))) == 0.0    # the pad
     h = jax.random.normal(jax.random.PRNGKey(4), (1, 32))
-    q, _ = v2model._mla_qkv(ap, h, jnp.asarray([44]), cfg)   # [1, 4, 256]
+    q, *_ = v2model._mla_qkv(ap, h, jnp.asarray([44]), cfg)  # [1, 4, 256]
     table = jnp.asarray(seq.blocks, jnp.int32)[None] + NB
     flat = eng.cache.k.reshape((-1,) + eng.cache.k.shape[2:])
     from deepspeed_tpu import ops
@@ -337,7 +337,7 @@ def test_hf_deepseek_v3_config_counts_the_published_model():
 
 
 @pytest.mark.parametrize("key,value", [
-    ("q_lora_rank", 1536), ("n_group", 8), ("num_nextn_predict_layers", 1),
+    ("n_group", 8), ("num_nextn_predict_layers", 1),
     ("rope_scaling", {"type": "yarn", "factor": 40})])
 def test_hf_deepseek_v3_config_refuses_what_is_not_built(key, value):
     from deepspeed_tpu.checkpoint.hf import deepseek_v3_config
