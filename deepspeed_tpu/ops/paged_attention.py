@@ -5,8 +5,8 @@ TPU-native replacement for the reference's blocked flash kernels
 slot owns a list of fixed-size KV pages; decode attends one query token per
 slot over exactly that slot's pages (``paged_decode``, described here), and
 the ragged prefill kernel attends the token-major rows of a mixed step, a
-work list of live (slot, chunk) items over the flat batch (``ragged_prefill``,
-described above its section, further down).
+work list of live (slot, chunk) items over the flat batch, a block of pages a
+loop step (``ragged_prefill``, described above its section, further down).
 
 Kernel design (vs the XLA fallback, which masks over gathered pages):
 - grid = (slots,): one grid step attends one slot for EVERY kv head.  A page
@@ -577,12 +577,30 @@ def paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
 # those rows hold and writes that back with its own.  So rows of slots the
 # kernel was told hold nothing (count 0: empty slots, and the one-row slots a
 # mixed step hands the paged decode kernel above, whose tile is that row)
-# come back as they were, whatever order the slots lie in.  Over its rows an
-# item runs a double-buffered HBM→VMEM DMA loop, one page of one head at a
-# time (the loop the decode kernel ran until its block pipeline), over ONLY
-# the pages the chunk can causally see: FLOPs and bandwidth scale with the
-# live chunks, and a live chunk pays for all its ``cq`` rows over every page
-# it sees, however few of them are live.
+# come back as they were, whatever order the slots lie in.
+#
+# Over its rows an item walks ONLY the pages the chunk can causally see (from
+# its first row's window start to its last live row's page), a BLOCK of P
+# pages a loop step (``_prefill_block_pages``: P from static shapes, 8 at
+# heads of 128 over pages of 128, 1 where a page alone fills the budget):
+# - all of a block's K and V copies (P pages of this kv head) are started
+#   before any is waited for, and the NEXT block's before this block's dots
+#   (two buffer halves of P pages);
+# - one score tile ``[cq * g, P * bs]``, one running max, one sum and one
+#   rescale of the accumulator a block; the three live in VMEM scratch, the
+#   max and the sum in every lane of their rows;
+# - every block takes the mask, two compares against a per-row bound
+#   ``(lo, hi]`` and one select: a second body without it for the blocks no
+#   edge crosses was built and measured to gain nothing (the mask hides behind
+#   the dots), so there is one body.
+# FLOPs and bandwidth scale with the live chunks; a live chunk pays for all
+# its ``cq`` rows over every key of every block it walks, so a context's last
+# block costs its P pages however few of them are live.  On one v5e chip
+# (PERF.md section 6, PR 37) a (page, kv head) step takes 0.49 us at heads of
+# 128 and 8 pages a block where the one-page loop before it took 1.33, and
+# 1.07 at P = 1: the copies hide behind the dots and the masks behind both;
+# what a step costs is the score tile's passes and a fixed cost a block, and
+# the wider the tile the better its dots and its softmax overlap.
 
 
 def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
@@ -640,35 +658,48 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
         o, mode="drop")
 
 
-def _prefill_kernel(*refs, bs, cq, g, hd, scale, window, has_alibi, kv_major,
-                    quant=False, v_dim=None):
+def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
+                    kv_major, quant=False, v_dim=None):
     """One grid step = one work item (``cq`` rows of one slot) for one kv
     head; see the section comment.  The chunk buffers hold ``g`` heads of
     ``hd`` (``vd``) values in their leading rows and columns: the arrays in
-    HBM are padded to whole tiles (``_tile_pad``).  ``v_dim``: latent pages,
-    one pool and one buffer: the page is the key and its leading ``v_dim``
-    columns the value."""
+    HBM are padded to whole tiles (``_tile_pad``).  ``P``: the pages of a
+    block (``_prefill_block_pages``).  ``v_dim``: latent pages, one pool and
+    one buffer: the page is the key and its leading ``v_dim`` columns the
+    value."""
     it = iter(refs)
     bt_ref, len_ref, start_ref, count_ref, row_ref, item_slot_ref, \
         item_chunk_ref, n_items_ref = (next(it) for _ in range(8))
     slopes_ref = next(it) if has_alibi else None
-    q_hbm, k_hbm = next(it), next(it)
-    v_hbm = None if v_dim else next(it)
-    ks_hbm, vs_hbm = (next(it), next(it)) if quant else (None, None)
-    o_hbm, q_buf, o_buf, k_buf = next(it), next(it), next(it), next(it)
-    v_buf = None if v_dim else next(it)
-    ks_buf, vs_buf = (next(it), next(it)) if quant else (None, None)
-    sem, row_sem = next(it), next(it)
+    q_hbm = next(it)
+    hbms = [next(it) for _ in range(1 if v_dim else 4 if quant else 2)]
+    o_hbm, q_buf, o_buf = next(it), next(it), next(it)
+    bufs = [next(it) for _ in hbms]
+    m_ref, l_ref, acc_ref, sem, row_sem = (next(it) for _ in range(5))
     item, h = pl.program_id(0), pl.program_id(1)
+    R, K = cq * g, P * bs                  # the score tile of a block
+    vd = v_dim or hd
+    # (plain lax on the scalars: every ``//``, ``jnp.where`` or ``jnp.clip``
+    # is a jitted helper, and every step program pays for tracing and
+    # lowering each one: PERF.md section 6, PR 34)
+    lax, i32 = jax.lax, jnp.int32
+
+    if P > 1:
+        # a block's buffer half holds ``P`` pages and a context's last block
+        # fewer live ones: what lies behind them is an earlier block's pages
+        # or these zeros, never whatever the memory held (its scores are
+        # masked, but 0 x NaN of a value row is not 0)
+        @pl.when((item == 0) & (h == 0))
+        def _clear():
+            for buf in bufs:
+                buf[...] = jnp.zeros_like(buf)
 
     @pl.when(item < n_items_ref[0])
     def _live():
         s = item_slot_ref[item]
-        count = count_ref[s]
-        start = start_ref[s]
         length = len_ref[s]
         row0 = item_chunk_ref[item] * cq
-        n_rows = jnp.minimum(count - row0, cq)
+        n_rows = lax.min(count_ref[s] - row0, i32(cq))
         flat0 = row_ref[s] + row0
 
         def rows_copy(hbm, buf, way, out=False):
@@ -678,98 +709,112 @@ def _prefill_kernel(*refs, bs, cq, g, hd, scale, window, has_alibi, kv_major,
             return pltpu.make_async_copy(*(ends[::-1] if out else ends),
                                          row_sem.at[way])
         rows_copy(q_hbm, q_buf, 0).start()
-        # pages the chunk can causally see: up to its LAST live row's position
-        last_pos = start + jnp.minimum(count, row0 + cq) - 1
-        n_pages = (last_pos + bs) // bs
-        if window is None:
-            p_start = jnp.int32(0)
-        else:
-            # the chunk's FIRST row's window start bounds every row's from
-            # below
-            p_start = jnp.maximum(start + row0 - window + 1, 0) // bs
+        # pages the chunk can causally see: from its FIRST row's window
+        # start, which bounds every row's from below, to its LAST live row's
+        # position
+        pos0 = start_ref[s] + row0                 # the first row's position
+        n_pages = lax.div(pos0 + n_rows + (bs - 1), i32(bs))
+        p_start = (i32(0) if window is None else
+                   lax.div(lax.max(pos0 - (window - 1), i32(0)), i32(bs)))
+        nblk = lax.div(n_pages - p_start + (P - 1), i32(P))
 
-        def dma(hbm, buf, slot, p, way):
-            return pltpu.make_async_copy(
-                hbm.at[bt_ref[s, p], h], buf.at[slot],
-                sem.at[way * 2 + slot])
+        def block_copies(p0, half, act):
+            """Start (or wait for) the copies of the block's live pages: a
+            loop, not ``P`` conditional copies, which every step program
+            would pay for in its lowering."""
+            def page(i, _):
+                for w, (hbm, buf) in enumerate(zip(hbms, bufs)):
+                    act(pltpu.make_async_copy(
+                        hbm.at[bt_ref[s, p0 + i], h], buf.at[half, i],
+                        sem.at[w, half, i]))
+                return 0
+            lax.fori_loop(0, lax.clamp(i32(0), n_pages - p0, i32(P)), page,
+                          0)
 
-        def start_page(slot, p):
-            dma(k_hbm, k_buf, slot, p, 0).start()
-            if not v_dim:
-                dma(v_hbm, v_buf, slot, p, 1).start()
-            if quant:
-                dma(ks_hbm, ks_buf, slot, p, 2).start()
-                dma(vs_hbm, vs_buf, slot, p, 3).start()
-
-        @pl.when(n_pages > p_start)
-        def _warmup():
-            start_page(jax.lax.rem(p_start, 2), p_start)
-
-        rown = jax.lax.broadcasted_iota(jnp.int32, (cq * g, bs), 0) // g
-        qpos = start + row0 + rown                     # [cq·g, bs]
-        row_live = row0 + rown < count
+        block_copies(p_start, 0, lambda c: c.start())
+        # a row sees the keys in (lo, hi]: its position bounds them above
+        # (and the context's length, and nothing at all if the row is past
+        # the item's live ones), its window below
+        rown = lax.div(lax.broadcasted_iota(i32, (R, 1), 0), i32(g))
+        qpos = pos0 + rown                             # [cq·g, 1]
+        hi = lax.select(rown < n_rows, lax.min(qpos, length - 1),
+                        lax.full((R, 1), -1, i32))
+        lo = None if window is None else qpos - window
         if has_alibi:
             # SMEM scalar-prefetch slopes [nkv, g]: row r = j·g+gi needs
             # slopes[h, r % g] — tile the per-group column cq times
             sl = jnp.stack([slopes_ref[h, i] for i in range(g)]).reshape(g, 1)
             slope_rows = jnp.tile(sl, (cq, 1))         # [cq·g, 1]
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
         rows_copy(q_hbm, q_buf, 0).wait()
         # a last chunk's rows past ``n_rows`` are the next slot's (or the
-        # batch's pad): ``row_live`` masks their scores
-        q = q_buf[:, :g, :hd].reshape(cq * g, hd)  # [cq·g, hd] row r=(j·g+gi)
+        # batch's pad): ``hi`` masks their scores
+        q = q_buf[:, :g, :hd].reshape(R, hd)       # [cq·g, hd] row r=(j·g+gi)
+        k_dims = ((1,), (0,)) if kv_major else ((1,), (1,))
+        v_dims = ((1,), (1,)) if kv_major else ((1,), (0,))
 
-        def body(p, carry):
-            m, l, acc = carry
-            slot = jax.lax.rem(p, 2)
-            nxt = jax.lax.rem(p + 1, 2)
+        def lanes(x, n):
+            """A row's running max or sum, held in every lane of its row
+            (``[cq·g, 128]``), beside ``n`` columns."""
+            w = x.shape[1]
+            if n <= w:
+                return x[:, :n]
+            return pltpu.repeat(x, n // w, axis=1) if n % w == 0 else \
+                jnp.broadcast_to(x[:, :1], (R, n))
 
-            @pl.when(p + 1 < n_pages)
+        def block(j, _):
+            """One softmax update over the block's ``P * bs`` keys."""
+            half = lax.rem(j, i32(2))
+            p0 = p_start + j * P
+
+            # all of the next block's copies before this block's dots
+            @pl.when(j + 1 < nblk)
             def _prefetch():
-                start_page(nxt, p + 1)
+                block_copies(p0 + P, 1 - half, lambda c: c.start())
 
-            dma(k_hbm, k_buf, slot, p, 0).wait()
-            k = k_buf[slot]                # [bs, hd] or [hd, bs] (kv-major)
+            block_copies(p0, half, lambda c: c.wait())
             if v_dim:
-                v = k[:, :v_dim]
+                k = bufs[0][half]
+                v, scales = k[..., :v_dim], ()
             else:
-                dma(v_hbm, v_buf, slot, p, 1).wait()
-                v = v_buf[slot]
+                k, v, *scales = (buf[half] for buf in bufs)
             if quant:
-                dma(ks_hbm, ks_buf, slot, p, 2).wait()
-                dma(vs_hbm, vs_buf, slot, p, 3).wait()
-                k, v = _dequant_page(k, v, ks_buf[slot], vs_buf[slot],
-                                     kv_major, q.dtype)
-            k_dims = ((1,), (0,)) if kv_major else ((1,), (1,))
-            scores = jax.lax.dot_general(
+                k, v = _dequant_page(k, v, *scales, kv_major, q.dtype)
+            if kv_major:               # [P, hd, bs] -> [hd, P·bs]
+                k, v = (jnp.concatenate(list(x), axis=1) for x in (k, v))
+            else:                      # [P, bs, hd] -> [P·bs, hd]
+                k, v = k.reshape(K, hd), v.reshape(K, vd)
+            scores = lax.dot_general(
                 q, k, (k_dims, ((), ())),
-                preferred_element_type=jnp.float32) * scale   # [cq·g, bs]
-            kvpos = p * bs + jax.lax.broadcasted_iota(jnp.int32,
-                                                      scores.shape, 1)
+                preferred_element_type=jnp.float32) * scale    # [cq·g, K]
+            kvpos = p0 * bs + lax.broadcasted_iota(i32, (R, K), 1)
             if has_alibi:
                 scores = scores + slope_rows * kvpos.astype(jnp.float32)
-            valid = (kvpos <= qpos) & (kvpos < length) & row_live
+            valid = kvpos <= hi
             if window is not None:
-                valid = valid & (kvpos > qpos - window)
+                valid = valid & (kvpos > lo)
             scores = jnp.where(valid, scores, _NEG_INF)
+            m = m_ref[...]
             m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-            pr = jnp.exp(scores - m_new)
-            # a row with no valid key in this page AND none so far: m_new is
-            # still -inf and exp aliases to 1 — zero it (dead rows, early
-            # rows of a later page under a window)
-            pr = jnp.where(m_new > _NEG_INF / 2, pr, 0.0)
+            # a row with no valid key in this block AND none so far: m_new
+            # is still -inf and exp(scores - m_new) would alias to 1: take
+            # its scores from 0, which leaves exp(-inf) = 0 (dead rows,
+            # early rows of a later block under a window)
+            m_off = jnp.where(m_new > _NEG_INF / 2, m_new, 0.0)
+            pr = jnp.exp(scores - lanes(m_off, K))
             alpha = jnp.exp(m - m_new)
-            l = alpha * l + jnp.sum(pr, axis=1, keepdims=True)
-            v_dims = ((1,), (1,)) if kv_major else ((1,), (0,))
-            pv = jax.lax.dot_general(pr.astype(v.dtype), v,
-                                     (v_dims, ((), ())),
-                                     preferred_element_type=jnp.float32)
-            return m_new, l, acc * alpha + pv
+            m_ref[...] = m_new
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(pr, axis=1,
+                                                      keepdims=True)
+            pv = lax.dot_general(pr.astype(v.dtype), v, (v_dims, ((), ())),
+                                 preferred_element_type=jnp.float32)
+            acc_ref[...] = acc_ref[...] * lanes(alpha, vd) + pv
+            return 0
 
-        m0 = jnp.full((cq * g, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((cq * g, 1), jnp.float32)
-        vd = v_dim or hd
-        acc0 = jnp.zeros((cq * g, vd), jnp.float32)
-        m, l, acc = jax.lax.fori_loop(p_start, n_pages, body, (m0, l0, acc0))
+        lax.fori_loop(0, nblk, block, 0)
+        l = l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)                # dead rows -> zeros
         # the write is ``cq`` rows too, so a last chunk first reads what the
         # rows past its own hold and writes that back: they are another
@@ -780,9 +825,9 @@ def _prefill_kernel(*refs, bs, cq, g, hd, scale, window, has_alibi, kv_major,
         def _theirs():
             rows_copy(o_hbm, o_buf, 1).start()
             rows_copy(o_hbm, o_buf, 1).wait()
-        mine = jax.lax.broadcasted_iota(jnp.int32, (cq, g, vd), 0) < n_rows
+        mine = lax.broadcasted_iota(i32, (cq, g, vd), 0) < n_rows
         o_buf[:, :g, :vd] = jnp.where(
-            mine, (acc / l).reshape(cq, g, vd).astype(o_buf.dtype),
+            mine, (acc_ref[...] / l).reshape(cq, g, vd).astype(o_buf.dtype),
             o_buf[:, :g, :vd])
         rows_copy(o_hbm, o_buf, 1, out=True).start()
         rows_copy(o_hbm, o_buf, 1, out=True).wait()
@@ -865,6 +910,24 @@ def prefill_grid_items(N: int, S: int, Q: int, cq: int) -> int:
     return min(N // cq + S, S * (Q // cq))
 
 
+# The float32 score tile of a block beside the item's accumulator.  Fitted on
+# the chip at heads of 128 (PERF.md section 6, PR 37: 8 pages a block read 7%
+# faster than 4 there, and 4% slower than 4 in the latent form).
+_TILE_BYTES = 4 << 20
+
+
+def _prefill_block_pages(pools, rows: int, bs: int, vd: int) -> int:
+    """P of the prefill kernel, from static shapes alone: ONE kv head's pages
+    of every pool within ``_BLOCK_BYTES`` (a grid step copies one head's), and
+    the float32 scores of the item's ``rows`` (query rows x group) over the
+    block's keys beside their accumulator within ``_TILE_BYTES``; at least one
+    and at most ``_MAX_BLOCK_PAGES``."""
+    page = sum(int(np.prod(pool.shape[2:])) * jnp.dtype(pool.dtype).itemsize
+               for pool in pools)
+    tile = (_TILE_BYTES - rows * vd * 4) // (rows * bs * 4)
+    return int(max(1, min(_MAX_BLOCK_PAGES, _BLOCK_BYTES // page, tile)))
+
+
 def _tile_pad(g: int, width: int, dtype):
     """(heads, width) of a token-major array [N, nkv, g, width] padded to
     whole tiles of its two minor dims, which is how the array lies in HBM
@@ -920,8 +983,12 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
                    lax.full((items_max, S), 0, jnp.int32)),
         jnp.int32(0), lax.add, (1,))
 
+    pools = [k_pages] if latent else [k_pages, v_pages]
+    if quant:
+        pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    P = _prefill_block_pages(pools, cq * g, bs, vd)
     kernel = functools.partial(
-        _prefill_kernel, bs=bs, cq=cq, g=g, hd=hd, scale=float(scale),
+        _prefill_kernel, P=P, bs=bs, cq=cq, g=g, hd=hd, scale=float(scale),
         window=int(window) if window is not None else None,
         has_alibi=has_alibi, kv_major=kv_major, quant=quant,
         v_dim=vd if latent else None)
@@ -936,17 +1003,22 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
     # ... and ``cq`` rows more, for the last chunk of the batch's last slot
     q = lax.pad(q, jnp.zeros((), q.dtype),
                 ((0, cq, 0), (0, 0, 0), (0, gp - g, 0), (0, hp - hd, 0)))
-    pools = [k_pages] if latent else [k_pages, v_pages]
     inputs = [q] + pools
-    buf_shape = (2, hd, bs) if kv_major else (2, bs, hd)
+    # the chunk's rows in and out, both halves of the page pipeline (P pages
+    # of one kv head each), and the softmax state of the item's rows
     scratch = [pltpu.VMEM((cq, gp, hp), q.dtype),
                pltpu.VMEM((cq, gp, vp), q.dtype)]
-    scratch += [pltpu.VMEM(buf_shape, pool.dtype) for pool in pools]
-    if quant:
-        inputs += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-        scratch += [pltpu.VMEM((2, bs), jnp.float32),
-                    pltpu.VMEM((2, bs), jnp.float32)]
-    scratch += [pltpu.SemaphoreType.DMA((8 if quant else 4,)),
+    scratch += [pltpu.VMEM((2, P) + pool.shape[2:], pool.dtype)
+                for pool in pools]
+    # (the running max and sum fill their rows' lanes: a column one lane
+    # wide costs a block a masked store and a broadcast for every 8 rows,
+    # 1.4 us at 768 rows, more than a page's dots: PERF.md section 6, PR 37)
+    scratch += [pltpu.VMEM((cq * g, 128), jnp.float32),
+                pltpu.VMEM((cq * g, 128), jnp.float32),
+                pltpu.VMEM((cq * g, vd), jnp.float32)]
+    held = sum(int(np.prod(buf.shape[:-1])) * (-(-buf.shape[-1] // 128) * 128)
+               * buf.dtype.itemsize for buf in scratch)
+    scratch += [pltpu.SemaphoreType.DMA((len(pools), 2, P)),
                 pltpu.SemaphoreType.DMA((2,))]
     out = pl.pallas_call(
         kernel,
@@ -958,8 +1030,10 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((N + cq, nkv, gp, vp), q.dtype),
+        # (the scratch, and room for a few score tiles of a block)
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=held + 8 * cq * g * P * bs * 4 + (8 << 20)),
         interpret=interpret,
         name="ragged_prefill",
     )(*prefetch, *inputs)

@@ -531,6 +531,40 @@ def test_window_layers_take_the_paged_kernels_at_their_own_width(topo,
     assert KERNEL in kernel(topo, **geo)
 
 
+# a prompt chunk's rows a slot (``max_q_per_seq``) and the pages of a block
+# of the prefill kernel's loop in each serving cell of the benchmark
+CELL_PREFILL = {
+    "mistral": (lambda: _engine_geometry(LLAMA128, False, 128), 256, 8),
+    "trinity": (lambda: _engine_geometry(TRINITY, False, 128), 1024, 8),
+    "moonlight": (lambda: _engine_geometry(MOONLIGHT, False, 128), 1024, 8),
+    "dots3-window": (lambda: dict(
+        nkv=1, g=64, hd=1152, bs=512, kv_major=False, window=513,
+        v_dim=1024), 1024, 1),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_PREFILL))
+def test_prefill_block_at_the_cell_geometries(topo, monkeypatch, cell):
+    """The prefill kernel's block of pages at each serving cell's geometry:
+    P as ``_prefill_block_pages`` takes it from the shapes (a later edit to
+    the budget shows here), and the kernel compiled for the described chip
+    with the scratch and score tiles it asks for, inside the chip's VMEM."""
+    import importlib
+    pa = importlib.import_module("deepspeed_tpu.ops.paged_attention")
+    geo, Q, pages = CELL_PREFILL[cell]
+    geo = {k: v for k, v in geo().items() if k != "quant"}
+    seen, asked = [], []
+    rule, params = pa._prefill_block_pages, pa.pltpu.CompilerParams
+    monkeypatch.setattr(pa, "_prefill_block_pages",
+                        lambda *a: seen.append(rule(*a)) or seen[-1])
+    monkeypatch.setattr(
+        pa.pltpu, "CompilerParams",
+        lambda **kw: asked.append(kw.get("vmem_limit_bytes")) or params(**kw))
+    assert KERNEL in prefill_text(topo, **geo, Q=Q)
+    assert seen == [pages]
+    assert asked[-1] and asked[-1] < 100 << 20      # of 128 MiB a v5e core
+
+
 @pytest.fixture(scope="module")
 def selecting_steps(topo):
     """The three step programs of ``DOTS3`` compiled for the described chip

@@ -994,6 +994,101 @@ class TestRaggedPrefill:
         np.testing.assert_array_equal(np.isnan(got).any((1, 2, 3)), ~owned)
         np.testing.assert_allclose(got[owned], want[owned], atol=2e-5)
 
+    # What a BLOCK of pages adds, a slot each, as (context before the step,
+    # rows) in units of ``u`` = a page = a chunk, at two pages a block:
+    BLOCK_EDGES = [
+        ((2, 0), (2, 0)),     # the context ends exactly on a block ...
+        ((3, 0), (2, 0)),     # ... one page past it ...
+        ((2, 1), (2, 0)),     # ... and one key past it
+        ((2, -1), (1, 0)),    # the first row on a page's last key: the
+                              # block under it ends AT its position
+        ((2, -2), (1, 0)),    # ... one key earlier: the diagonal crosses it
+        ((2, 0), (1, 0)),     # ... and on the next page's first key
+        ((4, 0), (1, 3)),     # a last chunk of 3 rows beside a full one
+        ((0, 0), (3, 0)),     # a fresh prompt
+        ((8, 0), (2, 0)),     # far past a window of 5 pages: its start lies
+                              # on a block's first key for the first item's
+                              # first row and inside it for the others
+    ]
+
+    @pytest.mark.parametrize("pages", [1, 2, 5, 16])
+    @pytest.mark.parametrize("window", [None, 5], ids=["global", "window"])
+    @pytest.mark.parametrize("form", ["gqa", "latent", "int8", "alibi",
+                                      "kv-major"])
+    def test_block_edges(self, rng, monkeypatch, form, window, pages):
+        """The page loop's block: ``BLOCK_EDGES`` in one flat batch against
+        ``xla_ragged_prefill``, in every form of the pool, with the pages of
+        a block forced to 1 (a page a loop step), 2, 5 (in the latent
+        form 160 keys, no whole number of lane tiles) and more than any
+        context holds (the whole context one block)."""
+        from deepspeed_tpu.inference.v2.model import quantize_kv_token
+        import importlib
+        pa = importlib.import_module("deepspeed_tpu.ops.paged_attention")
+        latent = form == "latent"
+        nkv, g, hd, vd, u = (1, 16, 640, 512, 32) if latent else \
+            (2, 2, 16, 16, 8)
+        monkeypatch.setattr(pa, "_prefill_block_pages",
+                            lambda *a, **k: pages)
+        ctx = np.asarray([a * u + b for (a, b), _ in self.BLOCK_EDGES],
+                         np.int32)
+        counts = np.asarray([a * u + b for _, (a, b) in self.BLOCK_EDGES],
+                            np.int32)
+        S, Q = len(ctx), 3 * u
+        assert pa._prefill_chunk(Q, g, vd) == u
+        rows = np.concatenate([[0], np.cumsum(counts)[:-1]]).astype(np.int32)
+        N = int(counts.sum()) + 5
+        MB = -(-int((ctx + counts).max()) // u)
+        q = jnp.asarray(rng.standard_normal((N, nkv, g, hd)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((S * MB, nkv, u, hd)),
+                        jnp.float32)
+        v = None if latent else jnp.asarray(
+            rng.standard_normal(k.shape), jnp.float32)
+        kw = dict(max_q=Q, window=window and window * u)
+        if latent:
+            kw.update(v_dim=vd, scale=192 ** -0.5)
+        if form == "int8":
+            (k, ks), (v, vs) = quantize_kv_token(k), quantize_kv_token(v)
+            kw.update(k_scale=ks, v_scale=vs)
+        if form == "alibi":
+            kw["alibi_slopes"] = jnp.asarray(
+                np.geomspace(0.5, 1 / 64, nkv * g), jnp.float32)
+        bt = jnp.asarray(rng.permutation(S * MB).reshape(S, MB), jnp.int32)
+        args = [q, k, v, bt, jnp.asarray(ctx + counts), jnp.asarray(ctx),
+                jnp.asarray(counts), jnp.asarray(rows)]
+        want = np.asarray(pa.xla_ragged_prefill(*args, **kw))
+        if form == "kv-major":
+            args[1:3] = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
+            kw["kv_major"] = True
+        got = np.asarray(pa.pallas_ragged_prefill(*args, interpret=True,
+                                                  **kw))
+        owned = np.arange(N) < counts.sum()
+        np.testing.assert_array_equal(np.isnan(got).any((1, 2, 3)), ~owned)
+        np.testing.assert_allclose(got[owned], want[owned], atol=2e-5)
+
+    @pytest.mark.parametrize("name,pools,rows,bs,vd,pages", [
+        ("heads-of-128-g4", [((8, 128, 128), "bfloat16")] * 2, 512, 128, 128,
+         8),
+        ("heads-of-128-g6", [((8, 128, 128), "bfloat16")] * 2, 768, 128, 128,
+         8),
+        ("int8-with-scale-rows", [((8, 128, 128), "int8")] * 2
+         + [((8, 128), "float32")] * 2, 512, 128, 128, 8),
+        ("latent-640", [((1, 128, 640), "bfloat16")], 512, 128, 512, 8),
+        ("latent-1152-pages-of-512", [((1, 512, 1152), "bfloat16")], 256,
+         512, 1024, 1),
+        ("pages-of-512", [((8, 512, 128), "bfloat16")] * 2, 768, 512, 128, 2),
+        ("2048-score-rows", [((8, 128, 128), "bfloat16")] * 2, 2048, 128,
+         128, 3),
+    ])
+    def test_block_pages_follow_the_shapes(self, name, pools, rows, bs, vd,
+                                           pages):
+        """P of the prefill kernel's block from static shapes alone: one kv
+        head's pages within the block's bytes, the float32 score tile beside
+        the accumulator within the tile's."""
+        from deepspeed_tpu.ops.paged_attention import _prefill_block_pages
+        pools = [jax.ShapeDtypeStruct((64,) + shape, dtype)
+                 for shape, dtype in pools]
+        assert _prefill_block_pages(pools, rows, bs, vd) == pages
+
     @pytest.mark.parametrize("G", [4, 5])
     def test_dense_slots_of_the_verify_program(self, rng, G):
         """The speculative verify program's layout (model.py
