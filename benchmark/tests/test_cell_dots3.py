@@ -13,7 +13,7 @@ import sys
 
 import costs_dsa
 import sparse
-from test_cells import ENV, MANIFEST, run_cell
+from test_cells import ENV, MANIFEST, readings, run_cell
 
 CELL = "serve-dots3-longdoc-batch"
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -63,16 +63,21 @@ def test_a_planted_fault_reads_not_correct_through_the_harness():
 
 
 def test_its_metrics_are_entries_with_files_and_readers():
-    mine = [p for p in MANIFEST["per_layer"] if p.get("workloads") == [CELL]]
+    # what a traced run reads: the two every cell reads, the 30 the cell
+    # came with and the six PR 36 left out at the contract's cap, which
+    # PR 38 gave back by the cell's name in six lists
+    mine = readings(CELL)
     names = {p["name"] for p in mine}
-    assert len(mine) == 30
+    assert len(mine) == 38
     assert {"index_score_roofline", "sparse_decode_roofline",
             "sparse_prefill_roofline", "window_latent_decode_roofline",
             "window_latent_prefill_roofline", "index_selected_share.sparse",
             "index_pool_bytes_per_token", "decode_index_ms.sparse",
-            "mixed_index_ms.sparse",
-            "kv_window_pages_released_share.sparse"} <= names
-    assert all(p["moves"] == "serve_tokens_per_s" for p in mine)
+            "mixed_index_ms.sparse", "kv_window_pages_released_share",
+            "decode_moe_route_ms", "mixed_moe_route_ms",
+            "decode_moe_shared_ms", "moe_local_share_of_assignments",
+            "decode_mla_absorb_ms", "mixed_mla_absorb_ms"} <= names
+    assert {p["moves"] for p in mine} == {"serve_tokens_per_s", "setup_s"}
     for p in mine:
         with open(os.path.join(ROOT, "benchmark", "metrics",
                                p["name"] + ".json")) as f:
@@ -81,9 +86,6 @@ def test_its_metrics_are_entries_with_files_and_readers():
             ROOT, "benchmark", "readers", spec["reader"] + ".py"))
         if p["name"].endswith("_roofline") and spec["reader"] == "sparse":
             assert p["unit"] == "%" and spec["need"]
-    # the contract's cap on ``per_layer`` (1 to 128 entries; a manifest
-    # past it is refused before a run), which this cell's 30 reach
-    assert len(MANIFEST["per_layer"]) <= 128
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
     config = next(c for c in MANIFEST["configs"]

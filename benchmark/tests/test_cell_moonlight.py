@@ -3,7 +3,8 @@ tiny preset (``--rehearse``: both Pallas kernels interpreted in their latent
 form over the latent page pool, every expert held, the comparison with the
 plain deepseek_v3 reference), its metrics' entries, files and readers, the
 ``latent`` reader on spans as the program writes them, the need functions
-against a hand count, and that the cell came by files alone."""
+against a hand count, and that a cell comes by new files, new entries and
+its name at the end of the lists it joins."""
 
 import json
 import os
@@ -11,7 +12,8 @@ import subprocess
 
 import costs_mla
 import latent
-from test_cells import ENV, MANIFEST, run_cell
+from test_cells import ENV, MANIFEST, readings, run_cell
+from test_manifest import LISTS
 
 CELL = "serve-moonlight-longctx-batch"
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -60,15 +62,14 @@ def test_a_planted_fault_reads_not_correct_through_the_harness():
 
 
 def test_its_metrics_are_entries_with_files_and_readers():
-    mine = [p for p in MANIFEST["per_layer"] if p.get("workloads") == [CELL]]
+    mine = readings(CELL)                  # what a traced run reads
     names = {p["name"] for p in mine}
-    assert len(mine) == 27 and all(
-        n.endswith(".latent") or n.startswith("latent_") for n in names)
+    assert len(mine) == 30
     assert {"latent_decode_roofline", "latent_prefill_roofline",
-            "expert_gemm_roofline.latent", "latent_pool_bytes_per_token",
-            "decode_mla_absorb_ms.latent",
-            "mixed_mla_absorb_ms.latent"} <= names
-    assert all(p["moves"] == "serve_tokens_per_s" for p in mine)
+            "expert_gemm_roofline", "latent_pool_bytes_per_token",
+            "decode_mla_absorb_ms", "mixed_mla_absorb_ms",
+            "decode_live_context_tokens.latent"} <= names
+    assert {p["moves"] for p in mine} == {"serve_tokens_per_s", "setup_s"}
     for p in mine:
         with open(os.path.join(ROOT, "benchmark", "metrics",
                                p["name"] + ".json")) as f:
@@ -82,7 +83,7 @@ def test_its_metrics_are_entries_with_files_and_readers():
     assert len(config["source"]) <= 200 and len(config["why"]) <= 200
     assert config["reduced"] == ["num_hidden_layers"]
     e2e = {e["name"]: e for e in MANIFEST["end_to_end"]}
-    assert e2e["serve_tokens_per_s"]["workloads"][-1] == CELL
+    assert CELL in e2e["serve_tokens_per_s"]["workloads"]
 
 
 def test_the_configuration_keeps_every_published_number():
@@ -110,37 +111,22 @@ def test_the_configuration_keeps_every_published_number():
 
 
 def test_the_cell_came_by_files_alone():
-    """Against the parent commit: no file of the benchmark is edited or
-    gone, and ``BENCHMARK.json`` lost and changed no entry (the cell's name
-    appended to ``serve_tokens_per_s``'s cells is the one edit inside
-    one)."""
-    def git(*args):
-        return subprocess.run(["git", *args], cwd=ROOT, capture_output=True,
-                              text=True)
-    base = git("log", "--format=%H", "-1", "--", "BENCHMARK.json").stdout.strip()
-    if not base:
-        import pytest
-        pytest.skip("no git history here")
-    # the commit before this cell came: the newest whose manifest lacks it
-    for rev in git("log", "--format=%H", "--", "BENCHMARK.json") \
-            .stdout.split():
-        old = json.loads(git("show", f"{rev}:BENCHMARK.json").stdout)
-        if CELL not in [w["name"] for w in old["workloads"]]:
-            break
-    else:
-        import pytest
-        pytest.skip("no commit without the cell")
-    changed = git("diff", "--name-status", rev, "--", "benchmark").stdout
-    assert all(line.startswith("A") for line in changed.splitlines()), changed
-    for group in ("configs", "workloads", "end_to_end", "per_layer"):
-        now = MANIFEST[group][:len(old[group])]
-        for a, b in zip(old[group], now):
-            if a.get("name") == "serve_tokens_per_s":
-                assert b["workloads"][:len(a["workloads"])] == a["workloads"]
-                a = {**a, "workloads": b["workloads"]}
-            assert a == b, (group, a["name"])
-    assert {k: MANIFEST[k] for k in ("command", "paths", "run_seconds")} == \
-        {k: old[k] for k in ("command", "paths", "run_seconds")}
+    """PR 33 brought this cell by new files, new entries and its name at
+    the end of ``serve_tokens_per_s``'s cells, and that is how a cell comes:
+    against the lists PR 38 left (``data/manifest_lists.json``; a
+    ``benchmark`` PR alone may change an accepted entry, and renews them),
+    every accepted entry is where it was under its name, and its
+    ``workloads`` list has grown at its end or not at all.  The files
+    themselves are the driver's to hold: a spec file no longer names cells,
+    so joining a metric edits none."""
+    for group, entries in LISTS["accepted_at_pr38"].items():
+        now = MANIFEST[group][:len(entries)]
+        assert [e["name"] for e in now] == [n for n, _ in entries], group
+        for e, (name, cells) in zip(now, entries):
+            if cells is None:
+                assert "workloads" not in e, name
+            else:
+                assert e["workloads"][:len(cells)] == cells, name
 
 
 def span(name, t, **args):

@@ -10,7 +10,7 @@ import sys
 
 import costs_moe
 import span_counters
-from test_cells import MANIFEST, run_cell
+from test_cells import MANIFEST, readings, run_cell
 
 CELL = "serve-trinity-mixedlen-batch"
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -32,9 +32,15 @@ def test_the_cell_rehearses_and_agrees_with_its_reference():
 
 
 def test_its_metrics_are_entries_with_files_and_readers():
-    mine = [p for p in MANIFEST["per_layer"] if p.get("workloads") == [CELL]]
-    assert len(mine) == 25
-    assert all(p["moves"] == "serve_tokens_per_s" for p in mine)
+    mine = readings(CELL)                  # what a traced run reads
+    names = {p["name"] for p in mine}
+    assert len(mine) == 29
+    assert {"expert_gemm_roofline", "paged_decode_roofline.mixedlen",
+            "ragged_prefill_roofline.mixedlen",
+            "kv_window_pages_released_share",
+            "moe_local_share_of_assignments", "decode_moe_experts_ms",
+            "decode_live_context_tokens.batch"} <= names
+    assert {p["moves"] for p in mine} == {"serve_tokens_per_s", "setup_s"}
     for p in mine:
         with open(os.path.join(ROOT, "benchmark", "metrics",
                                p["name"] + ".json")) as f:

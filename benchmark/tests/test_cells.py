@@ -7,6 +7,7 @@ import subprocess
 import sys
 
 import pytest
+from run import metric_applies
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -16,6 +17,13 @@ ENV.pop("XLA_FLAGS", None)            # the switch sets its own device count
 
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     MANIFEST = json.load(_f)
+
+
+def readings(cell):
+    """The per-layer entries a traced run of ``cell`` reads, chosen as
+    ``run.py`` chooses them: the manifest alone says which cells read a
+    metric."""
+    return [p for p in MANIFEST["per_layer"] if metric_applies(p, cell)]
 
 
 def run_cell(name, trace, root=ROOT, extra=()):
@@ -49,9 +57,7 @@ def test_traced_rehearsal_reports_per_layer_metrics():
     out = run_cell("serve-mistral-batch", 1, extra=["--rehearse"])
     assert out.returncode == 0, out.stderr[-2000:]
     last = json.loads(out.stdout.strip().splitlines()[-1])
-    names = {p["name"] for p in MANIFEST["per_layer"]
-             if "serve-mistral-batch" in p.get("workloads",
-                                               ["serve-mistral-batch"])}
+    names = {p["name"] for p in readings("serve-mistral-batch")}
     assert set(last["metrics"]) <= names     # what found nothing is left out
     assert {"compile_cache_misses", "compiles_in_window",
             "sched_tokens_per_dispatch"} <= set(last["metrics"])

@@ -40,11 +40,12 @@ def test_new_files_and_entries_are_enough(tmp_path):
              "source": "program_counter", "layer": "train step",
              "moves": "train_tokens_per_s_per_chip",
              "workloads": ["throwaway-cell"]}
+    spec = {k: v for k, v in entry.items() if k != "workloads"}
     (b / "metrics" / "throwaway_metric.json").write_text(
-        json.dumps({**entry, "reader": "throwaway_reader"}))
+        json.dumps({**spec, "reader": "throwaway_reader"}))
     (b / "readers" / "throwaway_reader.py").write_text(
         "def read(ctx, spec):\n    return ctx['steps']\n")
-    # and the entries
+    # and the entries; the cell's name at the end of the lists it joins
     m["configs"].append({"name": "throwaway", "source": cfg["source"],
                          "file": "benchmark/configs/throwaway.json",
                          "reduced": sorted(cfg["reduced"]), "why": "test"})
@@ -52,8 +53,9 @@ def test_new_files_and_entries_are_enough(tmp_path):
                            "traffic": "throwaway-mix", "chips": 1,
                            "why": "test"})
     m["per_layer"].append(entry)
-    for e in m["end_to_end"]:
-        if e["name"] == "train_tokens_per_s_per_chip":
+    for e in m["end_to_end"] + m["per_layer"]:
+        if e["name"] in ("train_tokens_per_s_per_chip",
+                         "train_step_device_ms"):
             e["workloads"].append("throwaway-cell")
     (root / "BENCHMARK.json").write_text(json.dumps(m))
 

@@ -1,8 +1,13 @@
-"""BENCHMARK.json against the contract's letter and the benchmark's files."""
+"""BENCHMARK.json against the contract's letter and the benchmark's files;
+and, since PR 38 folded the per-cell suffix families, that a metric is one
+entry and that the fold dropped no reading."""
 
 import json
 import os
 import re
+
+import pytest
+from run import metric_applies
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
@@ -37,6 +42,8 @@ def test_keys_names_and_units():
                           "workloads"}
         assert e["source"] in ("host_clock", "device_trace")
         assert 0.01 <= e["bound"] <= 0.1
+    # the contract's cap: a manifest past it is refused before a run
+    assert 1 <= len(m["per_layer"]) <= 128
     for e in m["per_layer"]:
         assert set(e) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
@@ -83,18 +90,107 @@ def test_moves_is_reported_wherever_the_metric_is():
         assert set(cells_of(p, m)) <= set(cells_of(e2e[p["moves"]], m)), p
 
 
+def spec_of(name):
+    with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
+        return json.load(f)
+
+
 def test_metric_files_agree_with_the_manifest():
+    """A spec file says what a metric is and how it is read; which cells
+    read it is the manifest's alone to say, so a cell joins a metric by one
+    line of ``BENCHMARK.json``."""
     m = manifest()
-    layers = {}
     for p in m["per_layer"]:
-        with open(os.path.join(BENCH, "metrics", p["name"] + ".json")) as f:
-            spec = json.load(f)
-        for k, v in p.items():
-            assert spec[k] == v, (p["name"], k)
+        spec = spec_of(p["name"])
+        assert "workloads" not in spec, p["name"]
+        for k in ("name", "unit", "better", "source", "layer", "moves"):
+            assert spec[k] == p[k], (p["name"], k)
         assert os.path.exists(os.path.join(
             BENCH, "readers", spec["reader"] + ".py"))
-        layers.setdefault(p["layer"], 0)
+    files = {f[:-len(".json")] for f in os.listdir(
+        os.path.join(BENCH, "metrics"))}
+    assert files == {p["name"] for p in m["per_layer"]}
     with open(os.path.join(ROOT, "PERF.md")) as f:
         perf = f.read()
-    for layer in layers:
+    for layer in {p["layer"] for p in m["per_layer"]}:
         assert layer in perf, f"layer {layer!r} is not in PERF.md"
+
+
+def test_no_two_entries_share_a_spec():
+    """A cell whose traffic reads an accepted metric joins that entry's
+    ``workloads`` list; it does not bring a copy under a suffix (128 entries
+    were 75 metrics before PR 38, and the contract's cap was reached)."""
+    seen = {}
+    for p in manifest()["per_layer"]:
+        spec = spec_of(p["name"])
+        del spec["name"]
+        key = json.dumps(spec, sort_keys=True)
+        assert key not in seen, (p["name"], seen[key])
+        seen[key] = p["name"]
+
+
+# ---- the fold of PR 38: nothing dropped --------------------------------
+B, T, M, D = ("serve-mistral-batch", "serve-trinity-mixedlen-batch",
+              "serve-moonlight-longctx-batch", "serve-dots3-longdoc-batch")
+SUFFIX = {".batch": B, ".mixedlen": T, ".latent": M, ".sparse": D}
+# one entry over B T M D, under the name the oldest of them was accepted by
+# (tier-1 ``tests/test_one_clock.py`` opens four of these spec files by name)
+UNDER_BATCH = {"decode_step_device_ms", "decode_attn_ms", "mixed_attn_ms",
+               "sched_gap_ms_per_round", "padding_waste_share",
+               "mixed_one_row_slot_share", "prefill_live_item_share",
+               "peak_hbm_gib"}
+# one entry over the expert cells (T M D, T D or M D) under the base name;
+# where the family has a ``.batch`` member it reads by another reader
+UNDER_BASE = {"decode_mlp_ms", "decode_other_ms", "mixed_mlp_ms",
+              "mixed_other_ms", "serve_unscoped_share",
+              "decode_moe_experts_ms", "mixed_moe_experts_ms",
+              "mixed_moe_shared_ms", "moe_rows_per_touched_expert",
+              "expert_gemm_roofline", "decode_moe_route_ms",
+              "mixed_moe_route_ms", "decode_moe_shared_ms",
+              "kv_window_pages_released_share", "sched_tokens_per_dispatch",
+              "decode_mla_absorb_ms", "mixed_mla_absorb_ms"}
+# a family that leaves two groups: by ``sched_rounds`` (B T), by ``latent``
+OTHERWISE = {"decode_live_context_tokens.mixedlen":
+             "decode_live_context_tokens.batch",
+             "decode_live_context_tokens.sparse":
+             "decode_live_context_tokens.latent"}
+# what PR 36 left out at the cap, read again at no entry
+JOINED = {(D, n) for n in (
+    "decode_moe_route_ms", "mixed_moe_route_ms", "decode_moe_shared_ms",
+    "moe_local_share_of_assignments", "decode_mla_absorb_ms",
+    "mixed_mla_absorb_ms")}
+
+
+def name_since_pr38(old, cell):
+    """The entry that reads in ``cell`` what ``old`` read there at PR 37."""
+    if old in OTHERWISE:
+        return OTHERWISE[old]
+    base, dot, suffix = old.rpartition(".")
+    if SUFFIX.get(dot + suffix) != cell:
+        return old                       # no per-cell suffix: as it was
+    if base in UNDER_BATCH:
+        return base + ".batch"
+    if base in UNDER_BASE and cell != B:
+        return base
+    return old
+
+
+with open(os.path.join(HERE, "data", "manifest_lists.json")) as _f:
+    LISTS = json.load(_f)
+
+
+@pytest.mark.parametrize("cell", sorted(LISTS["readings_at_pr37"]))
+def test_the_fold_dropped_no_reading(cell):
+    """The (cell, reading) pairs of the manifest PR 38 started from map one
+    to one onto the pairs of the accepted lists, plus the six joined."""
+    old = LISTS["readings_at_pr37"][cell]
+    mapped = [name_since_pr38(n, cell) for n in old]
+    assert len(set(mapped)) == len(old)              # one to one
+    accepted = {n for n, cells in LISTS["accepted_at_pr38"]["per_layer"]
+                if cells is None or cell in cells}
+    joined = {n for c, n in JOINED if c == cell}
+    assert set(mapped) | joined == accepted and not set(mapped) & joined
+    # and a traced run of today's manifest still reads them all
+    now = {p["name"] for p in manifest()["per_layer"]
+           if metric_applies(p, cell)}
+    assert accepted <= now

@@ -1,4 +1,4 @@
-"""``mixed_one_row_slot_share.*``: the share of a mixed step's slots that
+"""``mixed_one_row_slot_share.batch``: the share of a mixed step's slots that
 hold one row (the paged decode kernel attends them inside the mixed
 program).  Entries, files, and ``span_counters`` on spans as the program
 writes them."""
@@ -12,8 +12,8 @@ from test_cells import MANIFEST
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-CELLS = {"mixed_one_row_slot_share.mixedlen": "serve-trinity-mixedlen-batch",
-         "mixed_one_row_slot_share.batch": "serve-mistral-batch"}
+NAME = "mixed_one_row_slot_share.batch"
+CELLS = ["serve-mistral-batch", "serve-trinity-mixedlen-batch"]
 
 
 def span(name, t, **args):
@@ -21,14 +21,15 @@ def span(name, t, **args):
             "args": {k: str(v) for k, v in args.items()}}
 
 
-@pytest.mark.parametrize("name", sorted(CELLS))
-def test_entry_file_and_reading(name):
-    entry = next(p for p in MANIFEST["per_layer"] if p["name"] == name)
+@pytest.mark.parametrize("cell", CELLS)
+def test_entry_file_and_reading(cell):
+    entry = next(p for p in MANIFEST["per_layer"] if p["name"] == NAME)
     with open(os.path.join(ROOT, "benchmark", "metrics",
-                           name + ".json")) as f:
+                           NAME + ".json")) as f:
         spec = json.load(f)
-    assert {k: spec[k] for k in entry} == entry
-    assert entry["workloads"] == [CELLS[name]]
+    keys = set(entry) - {"workloads"}      # the manifest alone names cells
+    assert {k: spec[k] for k in keys} == {k: entry[k] for k in keys}
+    assert cell in entry["workloads"]
     assert entry["moves"] == "serve_tokens_per_s"
     assert entry["source"] == "program_counter"
     assert spec["reader"] == "span_counters"

@@ -61,6 +61,7 @@ def main():
         lens = jnp.asarray([total], jnp.int32)
         start = jnp.asarray([ctx_len], jnp.int32)
         count = jnp.asarray([Q], jnp.int32)
+        row_start = jnp.asarray([0], jnp.int32)  # token-major rows (PR 34)
 
         @jax.jit
         def absorbed(pages, q_nope, q_pe, wkvb):
@@ -68,9 +69,9 @@ def main():
             pad = jnp.zeros((Q, nh, page_dim - rank - rot), dt)
             q = jnp.concatenate([q_lat, q_pe, pad], -1)
             o = pallas_ragged_prefill(
-                q[None, :, None], pages, None, table, lens, start, count,
-                scale=scale, v_dim=rank)
-            return jnp.einsum("tnr,rnd->tnd", o[0, :, 0], wkvb[..., nope:])
+                q[:, None], pages, None, table, lens, start, count,
+                row_start, max_q=Q, scale=scale, v_dim=rank)
+            return jnp.einsum("tnr,rnd->tnd", o[:, 0], wkvb[..., nope:])
 
         @jax.jit
         def expanded(pages, q_nope, q_pe, wkvb):
