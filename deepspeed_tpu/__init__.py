@@ -9,7 +9,10 @@ Top-level API parity (reference deepspeed/__init__.py):
 
 from __future__ import annotations
 
+import time as _time
 from typing import Any, Callable, Optional, Tuple
+
+_IMPORT_T0 = _time.perf_counter()   # to the bottom: gauge import_seconds
 
 from deepspeed_tpu import checkpointing, comm, telemetry, zero
 from deepspeed_tpu.accelerator import get_accelerator
@@ -251,3 +254,7 @@ def add_config_arguments(parser):
     group.add_argument("--deepscale_config", default=None, type=str,
                        help="Deprecated alias of --deepspeed_config")
     return parser
+
+
+telemetry.startup.ACCOUNT.set_import_seconds(
+    _time.perf_counter() - _IMPORT_T0)

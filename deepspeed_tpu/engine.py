@@ -40,6 +40,7 @@ from deepspeed_tpu.parallel.metadata import annotate_abstract, unbox
 from deepspeed_tpu.runtime import faults, lr_schedules, optimizers, zero
 from deepspeed_tpu.runtime.precision import (LossScaleState, grads_finite,
                                              init_loss_scale, update_loss_scale)
+from deepspeed_tpu.telemetry.startup import ACCOUNT as _SETUP, init_span
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (DATA_TIMER, TRAIN_BATCH_TIMER,
                                        SynchronizedWallClockTimer,
@@ -143,6 +144,20 @@ class DeepSpeedTPUEngine:
             from deepspeed_tpu.runtime.resilience import \
                 enable_compilation_cache
             enable_compilation_cache(config.resilience.compilation_cache_dir)
+        # ---- observability (reference: MonitorMaster engine.py:1000) ----
+        # unified step telemetry (telemetry/): span tracer + recompile
+        # watchdog + counter/gauge registries + snapshot exporter.  Before
+        # the rest, so that the construction is itself a span
+        # (ds.engine_init; telemetry/startup.py)
+        self.monitor = MonitorMaster(config)
+        from deepspeed_tpu.telemetry import StepTelemetry
+        self.telemetry = StepTelemetry(config, monitor=self.monitor)
+        with init_span(self.telemetry.tracer, "engine_init", "train"):
+            self._build(model, config, example_batch, mesh, lr_scheduler,
+                        client_optimizer)
+
+    def _build(self, model, config, example_batch, mesh, lr_scheduler,
+               client_optimizer):
         comm.comms_logger.configure(config.comms_logger.enabled,
                                     config.comms_logger.verbose)
         warn_inert_config(config)
@@ -480,7 +495,9 @@ class DeepSpeedTPUEngine:
         self._lr_scale = 1.0
         self._client_optimizer = client_optimizer
         if not self.offloading:
-            self.optimizer, self._opt_params = self._build_tx(client_optimizer)
+            with init_span(self.telemetry.tracer, "init_optimizer", "train"):
+                self.optimizer, self._opt_params = self._build_tx(
+                    client_optimizer)
         # overlapped host step (offload_optimizer.overlap_step): the CPU Adam
         # of step N runs on a worker thread while the device computes step
         # N+1's grads against one-update-stale params (reference ZeRO-Offload
@@ -687,12 +704,15 @@ class DeepSpeedTPUEngine:
 
         # out_shardings are explicit NamedShardings; the mesh context is
         # for user init_fns that resolve bare PartitionSpec constraints
-        with self.mesh:
+        with init_span(self.telemetry.tracer, "init_state", "train"), \
+                self.mesh:
             self.state = self._jit_init(rng, example_batch)
         if self.offloading:
             # stream the initial params to host: fp32 masters + moments are
             # built there (zero.Init-at-construction analog for the host tier)
-            self.offload_opt.initialize(jax.device_get(self.state.params))
+            with init_span(self.telemetry.tracer, "init_optimizer", "train"):
+                self.offload_opt.initialize(
+                    jax.device_get(self.state.params))
 
         # forward/backward/step compatibility buffers
         self._accum_grads = None
@@ -710,9 +730,8 @@ class DeepSpeedTPUEngine:
         self._host_metrics_step = -1
         self._step_times = []
 
-        # ---- observability (reference: MonitorMaster engine.py:1000,
-        #      EngineTimers :145, flops profiler hook :1797) ----
-        self.monitor = MonitorMaster(config)
+        # ---- observability (reference: EngineTimers :145, flops profiler
+        #      hook :1797; the monitor and the telemetry: __init__) ----
         self.timers = SynchronizedWallClockTimer()
         # rate logging rides the engine's print cadence (reference
         # ThroughputTimer prints its own line at steps_per_output)
@@ -720,10 +739,6 @@ class DeepSpeedTPUEngine:
             steps_per_output=int(config.steps_per_print or 0),
             warmup_steps=1)
         self.wall_clock_breakdown = bool(config.wall_clock_breakdown)
-        # unified step telemetry (telemetry/): span tracer + recompile
-        # watchdog + counter/gauge registries + snapshot exporter
-        from deepspeed_tpu.telemetry import StepTelemetry
-        self.telemetry = StepTelemetry(config, monitor=self.monitor)
 
         # ---- data-efficiency pipeline (reference runtime/data_pipeline/) ----
         self.curriculum_scheduler = None
@@ -1670,6 +1685,7 @@ class DeepSpeedTPUEngine:
                     tel.before_dispatch(
                         "train_batch", batch, step_id,
                         lower=lambda: jfn.lower(self.state, batch))
+                mark = _SETUP.booked
                 with tel.span("dispatch", step=step_id):
                     # chaos: ``sleep@step.dispatch`` models a hung collective /
                     # straggler stall: the guardian watchdog's deadline target
@@ -1681,6 +1697,9 @@ class DeepSpeedTPUEngine:
                         self.state, metrics, health = self._jit_train_batch(
                             self.state, batch)
                         self._last_health = health
+                if _SETUP.booked != mark:  # a first call: jax traced or loaded
+                    _SETUP.close("train_batch", mark, tel.tracer,
+                                 step=step_id)
             if self.wall_clock_breakdown or profile_pending:
                 # synchronize so the timer covers device execution, not just
                 # dispatch.  Only for who asks: the span tracer does not

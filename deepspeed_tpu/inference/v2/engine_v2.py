@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -43,6 +44,7 @@ from deepspeed_tpu.inference.v2.ragged import (DSStateManager, RaggedBatch,
 from deepspeed_tpu.runtime import faults
 from deepspeed_tpu.telemetry.serving import (ServingTelemetry,
                                              ServingTelemetryConfig)
+from deepspeed_tpu.telemetry.startup import ACCOUNT as _SETUP, init_span
 from deepspeed_tpu.utils.logging import log_dist
 
 
@@ -304,17 +306,37 @@ class InferenceEngineV2:
                  mesh=None, draft_model=None, draft_params=None,
                  steps_cache: Optional[Dict[Any, Any]] = None,
                  telemetry_registry=None):
+        if isinstance(model, (str, os.PathLike)):
+            # only a model DIRECTORY needs the checkpoint package: its
+            # import (orbax -> google.cloud.logging -> two walks of every
+            # installed distribution's files) took 12-25 s of every serving
+            # process's start-up, the whole of ds.engine_init but 0.2 s
+            # (PERF.md section 6, PR 40)
+            from deepspeed_tpu.checkpoint.hf import (is_hf_model_dir,
+                                                     load_hf_checkpoint)
+            if is_hf_model_dir(model):
+                if params is not None:
+                    raise ValueError(
+                        "pass either an HF model dir or params, not both")
+                model, params = load_hf_checkpoint(model)
+        self.config = RaggedInferenceEngineConfig.parse(config)
+        # request-level serving telemetry (telemetry/serving.py): lifecycle
+        # spans + TTFT/TPOT histograms + KV-pool gauges + speculative
+        # counters.  Engine-local registry by default so two engines in one
+        # process (the bench runs seven) never blend their series; the fleet
+        # passes a shared registry + a per-replica label instead.  First, so
+        # that the construction below is itself a span (ds.engine_init).
+        self.telemetry = ServingTelemetry(self.config.telemetry,
+                                          registry=telemetry_registry)
+        with init_span(self.telemetry.tracer, "engine_init", "inference_v2"):
+            self._build(model, params, seed, mesh, draft_model, draft_params,
+                        steps_cache)
+
+    def _build(self, model, params, seed, mesh, draft_model, draft_params,
+               steps_cache):
         from deepspeed_tpu.models.gpt import GPTConfig, GPTLogits
         from deepspeed_tpu.parallel.metadata import unbox
-        from deepspeed_tpu.checkpoint.hf import (is_hf_model_dir,
-                                                 load_hf_checkpoint)
 
-        if is_hf_model_dir(model):
-            if params is not None:
-                raise ValueError(
-                    "pass either an HF model dir or params, not both")
-            model, params = load_hf_checkpoint(model)
-        self.config = RaggedInferenceEngineConfig.parse(config)
         tp_size = self.config.tensor_parallel.tp_size
         if mesh is None and tp_size > 1:
             from deepspeed_tpu.parallel import mesh as mesh_lib
@@ -337,19 +359,21 @@ class InferenceEngineV2:
                 "route is single-shard; drop the tp config for MoE models")
         self.model_config = model_cfg
 
-        if params is None:
-            lm = GPTLogits(model_cfg)
-            params = unbox(lm.init(
-                jax.random.PRNGKey(seed),
-                jnp.zeros((1, 8), jnp.int32)))["params"]
-        params = unbox(params)
-        if isinstance(params, dict) and "params" in params:
-            params = params["params"]
-        dt = self.config.jnp_dtype
-        self.params = jax.tree_util.tree_map(
-            lambda p: jnp.asarray(p).astype(dt)
-            if jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating)
-            else jnp.asarray(p), params)
+        with init_span(self.telemetry.tracer, "init_params",
+                       "inference_v2"):             # the cast
+            if params is None:
+                lm = GPTLogits(model_cfg)
+                params = unbox(lm.init(
+                    jax.random.PRNGKey(seed),
+                    jnp.zeros((1, 8), jnp.int32)))["params"]
+            params = unbox(params)
+            if isinstance(params, dict) and "params" in params:
+                params = params["params"]
+            dt = self.config.jnp_dtype
+            self.params = jax.tree_util.tree_map(
+                lambda p: jnp.asarray(p).astype(dt)
+                if jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating)
+                else jnp.asarray(p), params)
 
         # ---- quantized weight store (config block ``quant``): int8 codes +
         # group scales in HBM; model.py's _w/_embed dequantize per use site
@@ -410,7 +434,10 @@ class InferenceEngineV2:
                         return quantize_weight(p, bits=qc.bits,
                                                group=qc.group_size, dim=dim)
                 return p
-            self.params = jax.tree_util.tree_map_with_path(pack, self.params)
+            with init_span(self.telemetry.tracer, "init_params",
+                           "inference_v2"):         # the quantization
+                self.params = jax.tree_util.tree_map_with_path(
+                    pack, self.params)
 
         if self.mesh is not None:
             # TP: same logical-axis rules as the v1 engine (AutoTP analog) —
@@ -433,7 +460,9 @@ class InferenceEngineV2:
             if qc.enabled:
                 from deepspeed_tpu.ops.quantization import store_shardings
                 shardings = store_shardings(self.params, shardings, self.mesh)
-            self.params = jax.device_put(self.params, shardings)
+            with init_span(self.telemetry.tracer, "init_params",
+                           "inference_v2"):         # the placement
+                self.params = jax.device_put(self.params, shardings)
 
         from deepspeed_tpu.inference.v2.model import (kv_block_size_for,
                                                       kv_major_layout)
@@ -569,15 +598,16 @@ class InferenceEngineV2:
             max_seq_len=model_cfg.max_seq_len,
             prefix_cache=sm.prefix_cache, window=self.kv_window,
             window_blocks=window_blocks)
-        if self.kv_window and model_cfg.mla:
-            self.cache = PagedKVCache.create_latent_groups(
-                model_cfg, num_blocks, window_blocks, eff_bs, dt)
-        elif self.kv_window:
-            self.cache = PagedKVCache.create_grouped(
-                model_cfg, num_blocks, window_blocks, eff_bs, dt)
-        else:
-            self.cache = PagedKVCache.create(model_cfg, num_blocks, eff_bs,
-                                             dt, quant=sm.kv_quant)
+        with init_span(self.telemetry.tracer, "init_cache", "inference_v2"):
+            if self.kv_window and model_cfg.mla:
+                self.cache = PagedKVCache.create_latent_groups(
+                    model_cfg, num_blocks, window_blocks, eff_bs, dt)
+            elif self.kv_window:
+                self.cache = PagedKVCache.create_grouped(
+                    model_cfg, num_blocks, window_blocks, eff_bs, dt)
+            else:
+                self.cache = PagedKVCache.create(model_cfg, num_blocks, eff_bs,
+                                                 dt, quant=sm.kv_quant)
         # MoE counter vectors of dispatches not yet read back (device
         # values the step programs return; folded into the telemetry once
         # ready, never waited for: _fold_moe_stats)
@@ -658,13 +688,6 @@ class InferenceEngineV2:
         # recompute-preemption observability: how many victims were taken in
         # steady decode vs mid-(re-)prefill (the latter must keep fold state)
         self.preempt_stats = {"decode_ready": 0, "mid_prefill": 0}
-        # request-level serving telemetry (telemetry/serving.py): lifecycle
-        # spans + TTFT/TPOT histograms + KV-pool gauges + speculative
-        # counters.  Engine-local registry by default so two engines in one
-        # process (the bench runs seven) never blend their series; the fleet
-        # passes a shared registry + a per-replica label instead.
-        self.telemetry = ServingTelemetry(self.config.telemetry,
-                                          registry=telemetry_registry)
         # ---- fleet hooks (serving/fleet.py): a supervised replica can be
         # asked to drain (stop serving, export in-flight requests) and
         # reports liveness through heartbeat_fn each scheduler round
@@ -969,9 +992,13 @@ class InferenceEngineV2:
         batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, batch))
         self.telemetry.dispatch("mixed")
         self.telemetry.padding_waste(rb.total_tokens, nb)
+        mark = _SETUP.booked
         with self.telemetry.span("mixed_dispatch", tokens=rb.total_tokens,
                                  bucket=nb, seqs=len(rb.logits_slots)):
             out = self._steps[key](self.params, self.cache, batch)
+        if _SETUP.booked != mark:      # a first call: jax traced or loaded
+            _SETUP.close("put_mixed", mark, self.telemetry.tracer,
+                         bucket=nb, table_width=mb)
         if with_routes:
             *out, chosen = out
         logits, self.cache = self._take_moe_stats(out)
@@ -1004,8 +1031,11 @@ class InferenceEngineV2:
             "tokens": tokens, "active": active, "token_pos": token_pos,
             **rb.table_operands()}))
         self.telemetry.dispatch("decode")
+        mark = _SETUP.booked
         with self.telemetry.span("decode_dispatch", seqs=rb.total_tokens):
             out = self._steps[key](self.params, self.cache, batch)
+        if _SETUP.booked != mark:
+            _SETUP.close("put_decode", mark, self.telemetry.tracer, bucket=S)
         if with_routes:
             *out, chosen = out
         logits, self.cache = self._take_moe_stats(out)
@@ -1166,6 +1196,7 @@ class InferenceEngineV2:
                                    gamma=gamma, steps=outer,
                                    top_k=gen.top_k, mesh=self.mesh),
                     donate_argnums=(2, 3))
+            mark = _SETUP.booked
             with stel.span("spec_dispatch", steps=outer, gamma=gamma,
                            seqs=len(reqs), ctx_tokens=ctx_tokens,
                            kv_bytes_per_token=stel.kv_bytes_per_token):
@@ -1186,6 +1217,7 @@ class InferenceEngineV2:
                                    gamma=gamma, steps=outer,
                                    mesh=self.mesh),
                     donate_argnums=(2, 3))
+            mark = _SETUP.booked
             with stel.span("spec_dispatch", steps=outer, gamma=gamma,
                            seqs=len(reqs), ctx_tokens=ctx_tokens,
                            kv_bytes_per_token=stel.kv_bytes_per_token):
@@ -1194,6 +1226,8 @@ class InferenceEngineV2:
                                      self.cache, self.draft_cache, batch,
                                      prev)
             stel.dispatch("spec")
+        if _SETUP.booked != mark:
+            _SETUP.close("spec", mark, stel.tracer, steps=outer, gamma=gamma)
         with stel.span("materialize"):
             # the host cannot schedule past the burst without the counts —
             # this is THE disclosed sync of the speculative path
@@ -1228,6 +1262,7 @@ class InferenceEngineV2:
             batch = self._with_lora(
                 jax.tree_util.tree_map(jnp.asarray, host))
         stel.dispatch("burst")
+        mark = _SETUP.booked
         with stel.span("burst_dispatch", steps=steps, seqs=len(reqs),
                        tokens=steps * len(reqs), **note,
                        **stel.counter_note(self.state)):
@@ -1235,6 +1270,8 @@ class InferenceEngineV2:
                 self._steps[key](
                     self.params, self.cache, batch, prev, rng,
                     jnp.float32(gen.temperature), jnp.float32(gen.top_p)))
+        if _SETUP.booked != mark:
+            _SETUP.close("burst", mark, stel.tracer, steps=steps)
         stel.tokens("decode", steps * len(reqs))
         for r in reqs:
             self.state.get(r.uid).seen_tokens += steps
@@ -1340,6 +1377,7 @@ class InferenceEngineV2:
             batch = self._with_lora(
                 jax.tree_util.tree_map(jnp.asarray, host))
         stel.dispatch(kind)
+        mark = _SETUP.booked
         if draft:
             with stel.span(f"{kind}_dispatch", draft=True, **note):
                 prev, rng, self.cache, self.draft_cache = self._steps[key](
@@ -1353,6 +1391,9 @@ class InferenceEngineV2:
                         self.params, self.cache, batch, prev, rng,
                         jnp.float32(gen.temperature),
                         jnp.float32(gen.top_p)))
+        if _SETUP.booked != mark:
+            _SETUP.close(kind, mark, stel.tracer, bucket=note["bucket"],
+                         **({"table_width": mb} if mixed else {}))
         for seq, toks in schedule:
             seq.seen_tokens += len(toks)
         return prev, rng

@@ -32,6 +32,12 @@ them per step and adds the TPU-specific hazards nothing else watches:
 - ``postmortem``     — bundle summarizer CLI
                        (``python -m deepspeed_tpu.telemetry.postmortem``)
 - ``step_telemetry`` — the engine-facing facade driving all of the above
+- ``startup``        — where start-up went: jax's own trace / lower /
+                       compile / cache-load durations booked to the step
+                       program whose first call paid them
+                       (``program_setup``), the engines' ``ds.engine_init``
+                       spans and ``import_seconds``; read with
+                       ``setup_account()``
 
 See docs/observability.md for the config block and workflows.
 """
@@ -49,6 +55,7 @@ from deepspeed_tpu.telemetry.registry import (Counter, Gauge, MetricRegistry,
                                               record_collective)
 from deepspeed_tpu.telemetry.serving import (ServingTelemetry,
                                              ServingTelemetryConfig)
+from deepspeed_tpu.telemetry.startup import setup_account
 from deepspeed_tpu.telemetry.step_telemetry import StepTelemetry
 from deepspeed_tpu.telemetry.timeseries import (TimeSeriesStore,
                                                 histogram_attainment)
@@ -82,5 +89,6 @@ __all__ = [
     "group_names",
     "install_crash_handler",
     "record_collective",
+    "setup_account",
     "signature_of",
 ]

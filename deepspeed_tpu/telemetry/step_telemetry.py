@@ -193,17 +193,30 @@ class StepTelemetry:
                             info: dict) -> dict:
         """Compile the (freshly missed) signature AOT and harvest static
         figures; returns this signature's collective figures ({} on
-        failure).  jit will compile the same program again on the real
-        call — the double compile is the price of the figures and is gated
-        behind ``telemetry.hlo_stats``.  Failures degrade to a warning:
-        telemetry must never kill training."""
+        failure).  Gated behind ``telemetry.hlo_stats``.  Failures degrade
+        to a warning: telemetry must never kill training.
+
+        What it costs, as the set-up account (telemetry/startup.py) read it
+        on ``train-gpt2m-1chip`` (PERF.md section 6, PR 40): on jax 0.9.0
+        the step is NOT traced, lowered or compiled twice.  This AOT copy
+        pays them (trace 6.2 s, lower 2.8 s, cache load 7.4 s warm or
+        compile 72 s cold; booked as ``other``) and the real call then finds
+        jax's in-memory caches (its ``train_batch`` record reads 0.0006 s);
+        the price is the HLO text dump and its walk, 1.6 s on a first step
+        of 17.4 s.  That holds only while ``lower`` is handed the very
+        arguments of the call: an AOT copy from other avals, a second
+        ``jax.jit`` of the function or its jaxpr made apart pays everything
+        again, a program (PR 39 was refused for +16-22 s of serving
+        set-up).  Do not copy the pattern to time or name a step program:
+        the account hears jax's own durations during the one call."""
         from deepspeed_tpu.comm.comm import hlo_collective_bytes
         from deepspeed_tpu.telemetry.registry import \
             suppress_collective_recording
         info["collectives"] = {}
         try:
-            # the AOT lower() RETRACES the step — silence the wrapper-level
+            # the AOT lower() traces the step — silence the wrapper-level
             # trace-time hooks so their byte counters don't double-count
+            # where the real call traces it again (other avals)
             with suppress_collective_recording():
                 compiled = lower().compile()
         except Exception as e:  # noqa: BLE001

@@ -16,7 +16,10 @@ lost name fails the case that carries it and no other.
 (d) ``AFMOE_SPAN_ARGS``: the dispatch-span arguments that only a model with
     experts and window layers writes;
 (e) ``LATENT_READS``: what ``benchmark/readers/latent.py`` and
-    ``benchmark/tools/mla_compare.py`` take of a model with latent attention.
+    ``benchmark/tools/mla_compare.py`` take of a model with latent attention;
+(f) ``SETUP_READS``: the set-up account's counters, ``program_setup`` record
+    and init spans, as ``benchmark/readers/setup_account.py`` takes them
+    through ``deepspeed_tpu.telemetry.setup_account()`` (PR 40).
 The other spans and their arguments are held by ``tests/test_one_clock.py``."""
 
 import dataclasses
@@ -47,6 +50,17 @@ SM = {"max_tracked_sequences": 4, "max_ragged_batch_size": 64,
       "kv_block_size": 8, "max_q_per_seq": 16}
 
 
+def _setup_records_ever(acc):
+    """The set-up account keeps its newest 1,024 records and counts the rest:
+    a worker that ran other files first may be past the bound."""
+    return acc["dropped_records"] + len(acc["records"])
+
+
+def _setup_records_since(acc, n):
+    new = _setup_records_ever(acc) - n
+    return acc["records"][-new:] if new else []
+
+
 @pytest.fixture(scope="module")
 def served():
     """One tiny engine built and driven line for line as
@@ -68,6 +82,8 @@ def served():
         key, jnp.zeros((1, 8), jnp.int32)))["params"])(jax.random.PRNGKey(3))
     reset_dispatch_log()
     cleared = list(dispatch_log())
+    from deepspeed_tpu.telemetry import setup_account
+    setup0 = _setup_records_ever(setup_account())
     eng = InferenceEngineV2(
         model_cfg,
         {"dtype": "fp32", "state_manager": SM,
@@ -97,6 +113,7 @@ def served():
                  now_fn=clock, stream=False)
     o["clock_calls"] = calls[0]
     o["programs"] = sum(f._cache_size() for f in eng._steps.values())
+    o["setup_records"] = _setup_records_since(setup_account(), setup0)
 
     # ---- the window, as the runner opens and reads it
     prompts = [rng.integers(0, 97, (9 + 5 * i,)).astype(np.int32)
@@ -312,6 +329,8 @@ def trained(tmp_path_factory):
         "steps_per_print": 0, "seed": 5}
     devices = jax.devices()
     reset_dispatch_log()
+    from deepspeed_tpu.telemetry import setup_account
+    setup0 = _setup_records_ever(setup_account())
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=GPTChunkedLoss(model_cfg), config=ds_config,
         example_batch={"input_ids": np.zeros((rows, T), np.int32)},
@@ -345,7 +364,8 @@ def trained(tmp_path_factory):
                                          if d["op"] == "causal_attention"],
             "mesh4": build_mesh(MeshSpec(dp=1, fsdp=len(devices[:4])),
                                 devices=devices[:4]),
-            "events": list(engine.telemetry.tracer.events)}
+            "events": list(engine.telemetry.tracer.events),
+            "setup_records": _setup_records_since(setup_account(), setup0)}
 
 
 def _train_loss(t):
@@ -673,3 +693,115 @@ REPLAY_READS = {
 @pytest.mark.parametrize("name", list(REPLAY_READS))
 def test_sched_replay_reads(served, name):
     REPLAY_READS[name](served)
+
+
+# ------------------------------------------- (f) the set-up account (PR 40)
+
+def _setup_series(name, **labels):
+    from deepspeed_tpu.telemetry import default_registry
+    metric = default_registry._metrics[name]
+    assert any(labels.items() <= dict(k).items()
+               for k, _ in metric.samples()), (name, labels)
+    return metric.value(**labels)
+
+
+def _setup_counter(name, **labels):
+    assert _setup_series(name, **labels) > 0, (name, labels)
+
+
+def _setup_record(program, *key):
+    def check(s, t):
+        recs = (t if program == "train_batch" else s)["setup_records"]
+        got = [r for r in recs if r["program"] == program]
+        assert got, [r["program"] for r in recs]
+        for r in got:
+            assert set(key) | {"trace_s", "lower_s", "compile_s",
+                               "cache_load_s", "cache_hit", "host_ns",
+                               "traces"} <= set(r)
+            assert r["traces"] == 1 and r["trace_s"] > 0
+    return check
+
+
+def _setup_span(engine, part):
+    def check(s, t):
+        from deepspeed_tpu.telemetry import setup_account
+        got = [x for x in setup_account()["init_spans"]
+               if (x["engine"], x["part"]) == (engine, part)]
+        assert got and all(x["seconds"] >= 0 and x["host_ns"] > 0
+                           for x in got)
+        assert _setup_series("init_seconds", engine=engine, part=part) >= 0
+        events = (t["events"] if engine == "train"
+                  else s["eng"].telemetry.tracer.events)
+        assert any(e["name"] == part and "host_ns" in e["args"]
+                   for e in events)             # ds.<part> where a buffer is
+    return check
+
+
+def _setup_step_programs_are_the_warm_programs(s, t):
+    """``setup_step_programs`` against the runner's ``warm_programs`` note:
+    every site that calls a ``_steps`` jit is booked, or the two differ."""
+    steps = [r for r in s["setup_records"] if r["program"] != "other"]
+    assert len(steps) == s["programs"]
+
+
+def _setup_mirrored_record(s, t):
+    for events, program in ((s["eng"].telemetry.tracer.events, "mixed"),
+                            (t["events"], "train_batch")):
+        assert any(e["name"] == "program_setup"
+                   and e["args"]["program"] == program for e in events)
+
+
+def _setup_accessor(s, t):
+    from deepspeed_tpu.telemetry import setup_account
+    acc = setup_account()
+    assert {"seconds", "by_program", "hits", "misses", "records",
+            "dropped_records", "import_seconds", "init_spans"} <= set(acc)
+    assert set(acc["seconds"]) == {"trace", "lower", "compile", "cache_load"}
+    # (the gauge import_seconds is held by tests/test_setup_account.py in a
+    # fresh process: tests of other files reset the process-wide registry)
+    assert acc["import_seconds"] > 0
+
+
+SETUP_READS = {
+    "telemetry.setup_account()": _setup_accessor,
+    "counter setup_seconds_total{part=trace,program=mixed}":
+        lambda s, t: _setup_counter("setup_seconds_total", part="trace",
+                                    program="mixed"),
+    "counter setup_seconds_total{part=lower,program=burst}":
+        lambda s, t: _setup_counter("setup_seconds_total", part="lower",
+                                    program="burst"),
+    "counter setup_seconds_total{program=train_batch}":
+        lambda s, t: _setup_counter("setup_seconds_total", part="trace",
+                                    program="train_batch"),
+    "counter setup_seconds_total{program=other}":
+        lambda s, t: _setup_counter("setup_seconds_total", part="lower",
+                                    program="other"),
+    "counter setup_programs_total{program=put_mixed}":
+        lambda s, t: _setup_counter("setup_programs_total",
+                                    program="put_mixed"),
+    "counter setup_programs_total{program=put_decode}":
+        lambda s, t: _setup_counter("setup_programs_total",
+                                    program="put_decode"),
+    "record program_setup[put_mixed]": _setup_record(
+        "put_mixed", "bucket", "table_width"),
+    "record program_setup[put_decode]": _setup_record("put_decode", "bucket"),
+    "record program_setup[mixed]": _setup_record(
+        "mixed", "bucket", "table_width"),
+    "record program_setup[burst]": _setup_record("burst", "steps"),
+    "record program_setup[train_batch]": _setup_record("train_batch", "step"),
+    "record program_setup in the buffers": _setup_mirrored_record,
+    "setup_step_programs == warm_programs":
+        _setup_step_programs_are_the_warm_programs,
+    "span ds.engine_init (inference_v2)": _setup_span("inference_v2",
+                                                      "engine_init"),
+    "span ds.init_params": _setup_span("inference_v2", "init_params"),
+    "span ds.init_cache": _setup_span("inference_v2", "init_cache"),
+    "span ds.engine_init (train)": _setup_span("train", "engine_init"),
+    "span ds.init_state": _setup_span("train", "init_state"),
+    "span ds.init_optimizer": _setup_span("train", "init_optimizer"),
+}
+
+
+@pytest.mark.parametrize("name", list(SETUP_READS))
+def test_setup_account_reads(served, trained, name):
+    SETUP_READS[name](served, trained)
