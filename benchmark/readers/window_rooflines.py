@@ -2,10 +2,17 @@
 window and global attention layers: needed work (``costs_moe.py``) over peak
 over the kernels' time in the device trace.
 
-Kernels are taken by name.  The two Pallas attention kernels carry their
+The two Pallas attention kernels are taken by name: they carry their
 ``pallas_call(name=)`` in their scope path (``.../attn_kernel/paged_decode/
-pallas_call``, ``.../ragged_prefill/pallas_call``); the grouped expert GEMM
-is the compiler's ``ragged-dot-*`` custom calls.
+pallas_call``, ``.../ragged_prefill/pallas_call``).  The expert GEMM's time
+is taken BY SCOPE, by ``moe_scope_time``'s one rule (``group_of``: an op
+named ``ragged-dot*``, the compiler's custom calls, which keep no scope
+path, OR an op whose innermost known scope is ``moe_experts``): everything
+the expert scope costs, the row permutation and the activation around the
+three products too, which a fused kernel would subsume.  So a kernel of any
+name under that scope moves the reading, and one that replaces the
+``ragged-dot`` in one step program alone cannot send it past 100%.  The
+printed line gives the time by name beside it (``named_s``).
 
 What a step needs depends on its contexts, which the device trace does not
 hold; the program's dispatch spans do (``ctx_tokens``, ``ctx_tokens_window``,
@@ -29,15 +36,16 @@ import serve_trace
 import span_counters
 import xmeta
 import xtrace
+from moe_scope_time import GROUPED_GEMM, group_of
 
 KERNEL_SCOPE = {"paged_decode": "/paged_decode/",
                 "ragged_prefill": "/ragged_prefill/"}
-GROUPED_GEMM = "ragged-dot"
 
 
 def _is_kernel(meta, kernel):
     if kernel == "expert_gemm":
-        return meta["name"].startswith(GROUPED_GEMM)
+        return (group_of(meta) == "moe_experts"
+                and meta["opcode"] not in xtrace.CONTAINERS)
     return (meta["opcode"] == "custom-call"
             and KERNEL_SCOPE[kernel] in (meta.get("tf_op") or ""))
 
@@ -58,7 +66,7 @@ def read(ctx, spec):
     kernel = spec["kernel"]
     dev = run["devices"][min(run["devices"])]
     meta = dev["meta"]
-    k_ns, runs, steps = 0, 0, 0
+    k_ns, named_ns, runs, steps = 0, 0, 0, 0
     starts = [op[1] for op in dev["ops"]]      # sorted by start
     for name, a, b in dev["modules"]:
         if a < lo or b > hi or not name.startswith(spec["program"]):
@@ -68,8 +76,12 @@ def read(ctx, spec):
             if e <= b and mid in meta]
         runs += 1
         steps += serve_trace.loop_steps(inside)
-        k_ns += sum(e - s for mid, s, e in inside
-                    if _is_kernel(meta[mid], kernel))
+        # a group's time in one execution is the union of its operations'
+        # intervals (``scope_time``); kernels never nest, so theirs is the sum
+        k_ns += xtrace.total(xtrace.union(
+            (s, e) for mid, s, e in inside if _is_kernel(meta[mid], kernel)))
+        named_ns += sum(e - s for mid, s, e in inside
+                        if meta[mid]["name"].startswith(GROUPED_GEMM))
     if not k_ns:
         return None
     g_layers, w_layers = _layers(cfg)
@@ -121,8 +133,12 @@ def read(ctx, spec):
             g_layers, w_layers, cfg.num_heads, cfg.kv_heads, cfg.head_dim)
         flops, byts = flops * runs, byts * runs
     share, bound = costs.roofline_share(flops, byts, k_ns / 1e9, peaks)
+    by_name = ({"named_s": named_ns / 1e9, "share_by_name": (
+        costs.roofline_share(flops, byts, named_ns / 1e9, peaks)[0]
+        if named_ns else None)} if kernel == "expert_gemm" else {})
     print(json.dumps({"phase": "roofline", "kernel": kernel, "bound": bound,
-                      "kernel_s": k_ns / 1e9, "needed_flops": flops,
+                      "kernel_s": k_ns / 1e9, **by_name,
+                      "needed_flops": flops,
                       "needed_bytes": byts, "runs": runs, "steps": steps,
                       "mean_per_step_from_spans": seen}),
           flush=True)

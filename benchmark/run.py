@@ -105,21 +105,40 @@ class CompileCounter:
 
 
 class WindowTrace:
-    """Traces ``length_s`` seconds of the window, starting ``start_s`` into
-    it.  ``poll()`` from the measuring loop, or ``run_in_thread()`` where
-    the loop is inside the program."""
+    """Traces at most ``length_s`` seconds of the window.  The stretch starts
+    ``start_s`` into the window by the clock, or, where the runner gives a
+    ``progress`` callable (a closed list: the share of its work the engine
+    has scheduled) and the mix a ``start_share``, as soon as ``progress()``
+    reaches that share: a stretch of the work, wherever a faster or slower
+    tree puts it on the clock (and at ``latest_start_s`` at the latest, so
+    that a window cut short still holds a trace).  ``poll()`` from the
+    measuring loop, or ``run_in_thread()`` where the loop is inside the
+    program."""
 
-    def __init__(self, enabled, out_dir, start_s, length_s):
+    def __init__(self, enabled, out_dir, start_s, length_s, start_share=None,
+                 latest_start_s=None):
         self.dir = out_dir
         self.start_s, self.length_s = start_s, length_s
+        self.start_share, self.latest_start_s = start_share, latest_start_s
+        self.progress = None               # the runner's, where it has one
         self.state = "idle" if enabled else "off"
         self.t0 = None
         self._span = None
         self._thread = None
+        self._closing = False
         self.started_at = None
+        self.placed = {}                   # where the stretch fell, for the log
 
     def open(self, t0):
         self.t0 = t0
+
+    def by_share(self):
+        return self.progress is not None and self.start_share is not None
+
+    def _note(self, end):
+        self.placed[f"{end}_s"] = time.perf_counter() - self.t0
+        if self.progress is not None:
+            self.placed[f"progress_at_{end}"] = self.progress()
 
     def _start(self):
         import shutil
@@ -131,20 +150,30 @@ class WindowTrace:
         self._span.__enter__()
         self.started_at = time.perf_counter()
         self.state = "tracing"
+        self._note("trace_start")
 
     def _stop(self):
         import jax
+        self._note("trace_stop")
         self._span.__exit__(None, None, None)
         jax.profiler.stop_trace()
         self.state = "done"
 
+    def _due(self, dt):
+        if not self.by_share():
+            return dt >= self.start_s
+        return (self.progress() >= self.start_share
+                or (self.latest_start_s is not None
+                    and dt >= self.latest_start_s))
+
     def poll(self, before_stop=None):
         if self.state in ("off", "done"):
             return
-        dt = time.perf_counter() - self.t0
-        if self.state == "idle" and dt >= self.start_s:
+        now = time.perf_counter()
+        if self.state == "idle" and self._due(now - self.t0):
             self._start()
-        elif self.state == "tracing" and dt >= self.start_s + self.length_s:
+        elif (self.state == "tracing"
+              and now - self.started_at >= self.length_s):
             if before_stop is not None:
                 before_stop()
             self._stop()
@@ -156,6 +185,11 @@ class WindowTrace:
 
         def bench_trace_poll_thread():     # xtrace drops it by this name
             while self.state != "done":
+                if self._closing:          # the window outran the trace
+                    if self.state == "tracing":
+                        self._stop()
+                    self.state = "done"
+                    break
                 self.poll()
                 time.sleep(0.02)
         self._thread = threading.Thread(target=bench_trace_poll_thread,
@@ -164,11 +198,8 @@ class WindowTrace:
 
     def close(self):
         """Ends a trace the window outran; joins the thread."""
+        self._closing = True
         if self._thread is not None:
-            if self.state == "idle":
-                self.state = "done"
-            elif self.state == "tracing":
-                self.length_s = 0.0
             self._thread.join()
         elif self.state == "tracing":
             self._stop()
@@ -295,7 +326,9 @@ def main(argv=None):
                 seconds - length)
     tracer = WindowTrace(bool(args.trace),
                          os.path.join(ROOT, "benchmark_out", "trace",
-                                      cell["name"]), start, length)
+                                      cell["name"]), start, length,
+                         start_share=trace_cfg.get("start_share"),
+                         latest_start_s=seconds - length)
     runner = load_module(
         os.path.join(HERE, "runners", f"{config['run']['runner']}.py"),
         f"bench_runner_{config['run']['runner']}")
@@ -343,9 +376,22 @@ def main(argv=None):
           flush=True)
     if result.get("notes"):
         print(json.dumps({"phase": "notes", **result["notes"]}), flush=True)
+    if args.trace:
+        print(json.dumps({"phase": "trace_window", **(
+            {"start_share": tracer.start_share,
+             "latest_start_s": tracer.latest_start_s} if tracer.by_share()
+            else {"start_s": tracer.start_s}),
+            "length_s": tracer.length_s, **tracer.placed}), flush=True)
     line["device"] = device
     if args.rehearse:
         line["rehearsal"] = True
+    # each number compared beside its limit: last in the line, and the last
+    # lines on standard error
+    line["compared"] = {k: {"value": v, "limit": lim}
+                        for k, (v, lim) in result.get("compared", {}).items()}
+    for k, c in line["compared"].items():
+        print(f"compared {k} {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

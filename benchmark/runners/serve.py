@@ -18,6 +18,7 @@ import time
 
 import numpy as np
 
+import costs_serve
 import traffic
 
 
@@ -80,6 +81,32 @@ def percentile(values, q):
         return None
     s = sorted(values)
     return s[min(len(s) - 1, max(0, math.ceil(q * len(s)) - 1))]
+
+
+def fenced_seconds(events):
+    """(seconds, dispatches never fenced): the time the engine was INSIDE
+    its fenced dispatches, from each ``*_dispatch`` event's start to the end
+    of the ``fence`` phase that follows it in its round (``stream_sync``
+    fences every dispatch); one no fence followed counts its own span."""
+    marks = sorted((ev["ts"], ev["dur"], ev["name"] == "fence")
+                   for ev in events if ev["name"] == "fence"
+                   or ev["name"].endswith("_dispatch"))
+    total = unfenced = 0
+    opened = None                       # (start, own length) of a dispatch
+    for ts, dur, is_fence in marks:
+        if is_fence:
+            if opened is not None:
+                total += ts + dur - opened[0]
+                opened = None
+            continue
+        if opened is not None:
+            total += opened[1]
+            unfenced += 1
+        opened = (ts, dur)
+    if opened is not None:
+        total += opened[1]
+        unfenced += 1
+    return total / 1e6, unfenced
 
 
 def run(ctx):
@@ -197,7 +224,20 @@ def run(ctx):
                  for k in ("mixed", "decode", "burst")}
     tokens0 = {p: eng.telemetry.c_tokens.value(phase=p)
                for p in ("prefill", "decode")}
+    need_counters = {"moe_local": eng.telemetry.c_moe_local,
+                     "index_pairs": eng.telemetry.c_index_pairs,
+                     "selected_pairs": eng.telemetry.c_sel_pairs}
+    need0 = {k: c.value() for k, c in need_counters.items()}
     tracer, compiles = ctx["tracer"], ctx["compiles"]
+    if closed:
+        # a closed list's traced stretch is placed by the share of the
+        # list's rows the engine has scheduled (the trace's poll thread
+        # reads two counters; an untraced run never calls it)
+        list_rows = float(sum(len(p) for p in reqs["prompts"])
+                          + sum(reqs["max_new"]))
+        tracer.progress = lambda: sum(
+            eng.telemetry.c_tokens.value(phase=p) - v
+            for p, v in tokens0.items()) / list_rows
     stop_after = seconds + (0.0 if closed
                             else float(mix["drain_deadline_s"]))
     timer = threading.Timer(stop_after, eng.request_drain)
@@ -241,19 +281,48 @@ def run(ctx):
                 / max(1, sum(g for _, g in lens)))
     qwait = {}
     events = list(eng.telemetry.tracer.events)
-    for ev in events[-(eng.telemetry.tracer.total_recorded - ev0):]:
+    recorded = eng.telemetry.tracer.total_recorded - ev0
+    events_held = recorded <= len(events)  # the buffer is bounded
+    events = events[len(events) - min(recorded, len(events)):]
+    for ev in events:
         if ev["name"] == "queue_wait":
             qwait[-(ev["args"]["uid"]) - 1] = ev["dur"] / 1e3
     # the longest stretch of the window in which no step was dispatched
-    starts = sorted(ev["ts"] for ev in events[-(
-        eng.telemetry.tracer.total_recorded - ev0):]
-        if ev["name"].endswith("_dispatch"))
+    dispatched = [ev for ev in events if ev["name"].endswith("_dispatch")]
+    starts = sorted(ev["ts"] for ev in dispatched)
     longest_round_ms = (max(b - a for a, b in zip(starts, starts[1:])) / 1e3
                         if len(starts) > 1 else 0.0)
     dispatches = {k: eng.telemetry.c_dispatch.value(kind=k) - v
                   for k, v in counters0.items()}
     tokens = {p: eng.telemetry.c_tokens.value(phase=p) - v
               for p, v in tokens0.items()}
+    # what the whole window needed (serve_step_mfu): the program's counters
+    # over the window and the arguments of EVERY dispatch span of its own
+    # buffer, not of the profiler's stretch; a counter the model never
+    # moves (no experts, no selection) is no count
+    pairs = (costs_serve.pairs_of_dispatches(dispatched) if events_held
+             else (None, None))
+    need_counts = {"rows": sum(tokens.values()), "sampled": generated,
+                   "pairs_global": pairs[0], "pairs_window": pairs[1]}
+    for k, c in need_counters.items():
+        need_counts[k] = c.value() - need0[k] if c.value() else None
+    fenced_s, unfenced = fenced_seconds(events) if events_held else (0.0, 0)
+    # a closed list's progress at each whole second of the window, as the
+    # trace's poll thread would have read it: where a start_share falls
+    # (the engine counts a step's rows before it dispatches the step, and a
+    # dispatch blocks while the chip's queue is full; a burst's after it)
+    progress_by_s = None
+    if closed and events_held:
+        open_us = eng.telemetry.tracer.us_of(t0)
+        ends = sorted((ev["ts"] - open_us + (
+            ev["dur"] if ev["name"] == "burst_dispatch" else 0.0),
+            ev["args"]["tokens"])
+            for ev in dispatched if "tokens" in ev["args"])
+        rows_by = np.cumsum([0] + [n for _, n in ends])
+        seconds_at = np.arange(1, int(window_s) + 2) * 1e6
+        progress_by_s = [round(float(rows_by[k]) / list_rows, 4)
+                         for k in np.searchsorted(
+                             [t for t, _ in ends], seconds_at, side="right")]
 
     warm_until = float(mix.get("warm_share", 0.0)) * seconds
     judged = [i for i in range(n) if reqs["due_s"][i] >= warm_until]
@@ -318,6 +387,10 @@ def run(ctx):
                      "its due instant; lateness is inside queue_wait",
         "dispatches": dispatches, "scheduled_tokens": tokens,
         "longest_ms_between_dispatches": longest_round_ms,
+        "window_events": {"recorded": recorded, "held": len(events),
+                          "all_held": events_held},
+        "need_counts": need_counts, "progress_by_s": progress_by_s,
+        "fenced_dispatch_s": fenced_s, "dispatches_never_fenced": unfenced,
         "slowest_admissions": sorted(
             ((round(qwait[i]), round(reqs["due_s"][i], 2),
               len(reqs["prompts"][i])) for i in qwait), reverse=True)[:5],
@@ -326,12 +399,17 @@ def run(ctx):
         "warm_programs": programs, "warm_calls": len(plan),
         "kv_block_size": bs, "kernel_dispatch": kernels}
     return {"setup_s": setup_s, "correct": bool(logits_ok),
+            "compared": {
+                "logits_rel_rms": (rel_rms, tol["logits_rel_rms"]),
+                "logits_max_abs": (max_abs, tol["logits_max_abs"]),
+                "argmax_gap": (argmax_gap, tol["logits_max_abs"] / 2)},
             "attempted": attempted, "failed": failed_n,
             "end_to_end": end_to_end, "window_s": window_s,
             "queue_wait_p95_ms": percentile(waits, 0.95),
             "ttft_p90_ms": None if closed else fin(percentile(ttft, 0.90)),
             "tpot_p90_ms": None if closed else fin(percentile(tpot, 0.90)),
             "dispatches": dispatches, "scheduled_tokens": tokens,
+            "serve_window": {"counts": need_counts, "fenced_s": fenced_s},
             "model_cfg": model_cfg, "slots": int(sm["max_tracked_sequences"]),
             "chips": 1, "kv_block_size": bs, "notes": notes,
             # closed list: every slot decodes all through the window

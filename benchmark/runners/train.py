@@ -131,6 +131,8 @@ def run(ctx):
         model_cfg.hidden_size, T)
     return {
         "setup_s": setup_s, "correct": bool(correct),
+        "compared": {"first_loss_rel_err": (rel, tol),
+                     "last_loss_over_first": (last_loss / first_loss, 1.0)},
         "attempted": steps, "failed": 0,
         "end_to_end": {"train_tokens_per_s_per_chip": rate,
                        "setup_s": setup_s},

@@ -68,7 +68,7 @@ def test_its_metrics_are_entries_with_files_and_readers():
     # PR 38 gave back by the cell's name in six lists
     mine = readings(CELL)
     names = {p["name"] for p in mine}
-    assert len(mine) == 38
+    assert len(mine) == 47
     assert {"index_score_roofline", "sparse_decode_roofline",
             "sparse_prefill_roofline", "window_latent_decode_roofline",
             "window_latent_prefill_roofline", "index_selected_share.sparse",

@@ -64,7 +64,7 @@ def test_a_planted_fault_reads_not_correct_through_the_harness():
 def test_its_metrics_are_entries_with_files_and_readers():
     mine = readings(CELL)                  # what a traced run reads
     names = {p["name"] for p in mine}
-    assert len(mine) == 30
+    assert len(mine) == 39
     assert {"latent_decode_roofline", "latent_prefill_roofline",
             "expert_gemm_roofline", "latent_pool_bytes_per_token",
             "decode_mla_absorb_ms", "mixed_mla_absorb_ms",

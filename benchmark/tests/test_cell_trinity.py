@@ -34,7 +34,7 @@ def test_the_cell_rehearses_and_agrees_with_its_reference():
 def test_its_metrics_are_entries_with_files_and_readers():
     mine = readings(CELL)                  # what a traced run reads
     names = {p["name"] for p in mine}
-    assert len(mine) == 29
+    assert len(mine) == 38
     assert {"expert_gemm_roofline", "paged_decode_roofline.mixedlen",
             "ragged_prefill_roofline.mixedlen",
             "kv_window_pages_released_share",

@@ -40,7 +40,10 @@ def test_cell_rehearses_with_the_contracts_last_line(cell):
     assert out.returncode == 0, out.stderr[-2000:]
     last = json.loads(out.stdout.strip().splitlines()[-1])
     assert set(last) == {"correct", "attempted", "failed", "metrics",
-                         "device", "rehearsal"}
+                         "device", "rehearsal", "compared"}
+    assert list(last)[-1] == "compared" and last["compared"]
+    for c in last["compared"].values():      # each number beside its limit
+        assert c["value"] <= c["limit"]
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] > 0
     assert last["device"]["platform"] == "cpu"       # says what it ran on
