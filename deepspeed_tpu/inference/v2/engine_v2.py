@@ -41,6 +41,7 @@ from deepspeed_tpu.inference.v2.model import (PagedKVCache, named_partial,
                                               speculative_burst_sampled)
 from deepspeed_tpu.inference.v2.ragged import (DSStateManager, RaggedBatch,
                                                build_ragged_batch)
+from deepspeed_tpu.ops.sparse_index import masked_prefill
 from deepspeed_tpu.runtime import faults
 from deepspeed_tpu.telemetry.serving import (ServingTelemetry,
                                              ServingTelemetryConfig)
@@ -1122,7 +1123,12 @@ class InferenceEngineV2:
         (``ServingTelemetry.index_pairs``; a fused dispatch: ``steps`` rows
         a slot; ``table_tokens``: the step program's table width in tokens,
         the whole table's by default: a program no wider than the selection
-        reads every key and scores none)."""
+        reads every key and scores none).  A mixed step of such a model
+        says how far its prompt chunks reach (``sel_reach``: the longest
+        context after the step among the slots with more than one row, what
+        the step program computes) and is counted where that sends them
+        through the masked prefill kernel, by the program's own rule
+        (``ops.sparse_index.masked_prefill``)."""
         contexts = np.asarray(contexts, np.int64)
         note = {"ctx_tokens": int(contexts.sum())}
         k = self.model_config.index_topk
@@ -1139,16 +1145,21 @@ class InferenceEngineV2:
                                 * self._block_size)
             layers = sum(mc.window_for_layer(i) is None
                          for i in range(mc.num_layers))
-            scored = int(causal.sum()) if table_tokens > k else 0
+            selects = table_tokens > k
+            scored = int(causal.sum()) if selects else 0
+            reach = int((contexts + q)[q > 1].max(initial=0))
             self.telemetry.index_pairs(
                 layers * scored, layers * int(kept.sum()),
-                layers * int(causal.sum()))
+                layers * int(causal.sum()),
+                masked_step=bool(new is not None and selects
+                                 and masked_prefill(reach)))
             # this dispatch's own, on ONE selecting layer (the rooflines'
             # needs), and the one-row slots' part of what it keeps
             note.update(index_pairs_step=scored,
                         sel_pairs_step=int(kept.sum()))
             if new is not None:
-                note["sel_pairs_one_row"] = int(kept[q == 1].sum())
+                note.update(sel_pairs_one_row=int(kept[q == 1].sum()),
+                            sel_reach=reach)
         if new is not None:
             # sum over i < q of (c + 1 + i); the one-row slots' part of it
             # (they go to the paged decode kernel) is their contexts + count

@@ -751,58 +751,88 @@ def _selected_attention(q, qi, wi, k_pages, ki_pages, table, row_slot,
     the step's rows over their slots' index keys, the exact top
     ``index_topk`` of them (scope ``attn_index``, the selection
     ``index_select`` inside it), then attention over the selected rows of
-    the latent pool and no others (``selected_attention``); all of it inside
-    scope ``attn_kernel``, whose time is a layer's attention whichever way
-    it reads its keys.  ``table [S, MB]``: the global group's, the layer's
-    first page added; ``row_slot``: ``S`` for a pad row.  -> ``[N, nh,
-    kv_lora_rank]``.
+    the latent pool and no others (scope ``selected_attention``); all of it
+    inside scope ``attn_kernel``, whose time is a layer's attention whichever
+    way it reads its keys.  ``table [S, MB]``: the global group's, the
+    layer's first page added; ``row_slot``: ``S`` for a pad row.  -> ``[N,
+    nh, kv_lora_rank]``.
 
     A mixed step (``rows``: its ``_MixedRows``, at most ``max_rows`` a
     slot) selects for its one-row slots (riding
     decode rows, whose contexts are the longest a step holds) apart from the
     slots that hold a prompt chunk: a chunk's rows are then scored and
     sorted over their OWN contexts' width, not over the riders', and what a
-    step costs does not follow which sequences happen to ride it."""
+    step costs does not follow which sequences happen to ride it.  The
+    riders gather their rows, as a decode step's do.  A chunk's rows read
+    their keys whichever way is cheaper at the contexts this step holds
+    (``ops.sparse_index.masked_prefill``, decided in the program): the
+    prefill kernel over the slot's pages with the selection as a mask, or
+    the same gather."""
     from deepspeed_tpu import ops
-    S = table.shape[0]
-    k = min(cfg.index_topk, table.shape[1] * block_size)
+    from deepspeed_tpu.ops.sparse_index import masked_prefill
+    S, MB = table.shape
+    k = min(cfg.index_topk, MB * block_size)
+    q = q.astype(cfg.dtype)
+    attn = dict(v_dim=cfg.kv_lora_rank, scale=_attn_scale(cfg))
 
-    def select(qi, wi, slots, pos, rows_a_slot, width=None):
-        scores = ops.index_scores(qi, wi, ki_pages, table, slots, pos,
-                                  max_rows=rows_a_slot, impl=cfg.attn_impl)
-        return ops.index_select(scores, k, width=width)
+    def scores(qi, wi, slots, pos, rows_a_slot):
+        return ops.index_scores(qi, wi, ki_pages, table, slots, pos,
+                                max_rows=rows_a_slot, impl=cfg.attn_impl)
 
-    with jax.named_scope("attn_kernel"), jax.named_scope("attn_index"):
-        if rows is None:                   # a decode step: a row a slot
-            picked = select(qi, wi, row_slot, row_pos, 1)          # [N, k]
-        else:
-            first = rows.first_row
-            slot = jnp.minimum(row_slot, S - 1)
-            alone = (rows.q_counts == 1)[slot] & (row_slot < S)
-            one = select(qi[first], wi[first],
-                         jnp.where(rows.q_counts == 1, jnp.arange(S), S),
-                         row_pos[first], 1)                        # [S, k]
-            reach = jnp.max(jnp.where((row_slot < S) & ~alone, row_pos + 1,
-                                      0))
-            many = select(qi, wi, jnp.where(alone, S, row_slot), row_pos,
-                          max_rows, width=reach)                   # [N, k]
-            picked = jnp.where(alone[:, None], one[slot], many)
-        with jax.named_scope("index_select"):
+    def gathered(q, picked, slots, pos):
+        """Rows ``q`` of slots ``slots`` at positions ``pos`` over the pool
+        rows their picks name."""
+        with jax.named_scope("attn_index"), jax.named_scope("index_select"):
             # each picked position's page of its row's table: a compare and
             # a sum over the table's few columns, which fuse; a gather of
             # one int32 a pair is the slow way on this chip
-            mine = table[jnp.minimum(row_slot, S - 1)]             # [N, MB]
-            page = picked // block_size
+            mine = table[jnp.minimum(slots, S - 1)]                # [n, MB]
             pages = jnp.sum(jnp.where(
-                page[:, :, None] == jnp.arange(table.shape[1],
-                                               dtype=jnp.int32),
-                mine[:, None, :], 0), axis=-1)
-            rows = pages * block_size + picked % block_size
-            counts = jnp.where(row_slot < S, jnp.minimum(row_pos + 1, k), 0)
+                (picked // block_size)[:, :, None]
+                == jnp.arange(MB, dtype=jnp.int32), mine[:, None, :], 0),
+                axis=-1)
+            pool_rows = pages * block_size + picked % block_size
+            counts = jnp.where(slots < S, jnp.minimum(pos + 1, k), 0)
+        return ops.selected_attention(q, k_pages, pool_rows, counts, **attn)
+
     with jax.named_scope("attn_kernel"):
-        return ops.selected_attention(
-            q.astype(cfg.dtype), k_pages, rows, counts,
-            v_dim=cfg.kv_lora_rank, scale=_attn_scale(cfg))
+        if rows is None:                   # a decode step: a row a slot
+            with jax.named_scope("attn_index"):
+                picked = ops.index_select(
+                    scores(qi, wi, row_slot, row_pos, 1), k)       # [N, k]
+            return gathered(q, picked, row_slot, row_pos)
+        first = rows.first_row
+        one_row = rows.q_counts == 1
+        slot = jnp.minimum(row_slot, S - 1)
+        alone = one_row[slot] & (row_slot < S)
+        riders = jnp.where(one_row, jnp.arange(S), S)
+        shared = jnp.where(alone, S, row_slot)
+        with jax.named_scope("attn_index"):
+            one = ops.index_select(
+                scores(qi[first], wi[first], riders, row_pos[first], 1), k)
+            reach = jnp.max(jnp.where((row_slot < S) & ~alone, row_pos + 1,
+                                      0))
+            many_scores = scores(qi, wi, shared, row_pos, max_rows)
+            many = ops.index_select(many_scores, k, width=reach)   # [N, k]
+        o_one = gathered(q[first], one, riders, row_pos[first])    # [S, ..]
+
+        def masked():
+            with jax.named_scope("attn_index"):
+                keep = ops.selection_mask(many_scores, many)
+            with jax.named_scope("selected_attention"):
+                # each slot's rows are one span of positions ending at its
+                # kv_len; the riders' slots are told empty
+                return ops.ragged_prefill_attention(
+                    q[:, None], k_pages, None, table, rows.kv_len,
+                    rows.kv_len - rows.q_counts,
+                    jnp.where(one_row, 0, rows.q_counts), first,
+                    max_q=max_rows, sel_mask=keep, impl=cfg.attn_impl,
+                    **attn)[:, 0]
+
+        o_many = jax.lax.cond(masked_prefill(reach), masked,
+                              lambda: gathered(q, many, shared, row_pos))
+        o = jnp.where(alone[:, None, None], o_one[slot], o_many)
+        return jnp.where((row_slot < S)[:, None, None], o, 0)
 
 
 def _attn_proj(ap, o, gate, cfg, mesh=None):
@@ -1016,6 +1046,12 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     # the selection binds only where a context can outgrow it: a step
     # program whose table is no wider reads every key, through the kernels
     select = MB * block_size > cfg.index_topk > 0
+    # (one traced and lowered attention a kind of SELECTING layer too: both
+    # ways a chunk's rows can read their keys are in it)
+    selected = {lc: jax.jit(named_partial(
+        _selected_attention, cfg=lc, block_size=block_size, max_rows=Q))
+        for lc in {cfg.for_layer(i) for i in range(cfg.num_layers)}
+        if lc.index_topk and select}
 
     # multi-tenant LoRA (static trace-time branch — adapter-less engines
     # send no "lora" key and trace the identical program): per-TOKEN
@@ -1063,9 +1099,8 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                 flat_ki, = _kv_write_local((flat_ki,), ki, None, plans[grp],
                                            base=base, km=False)
         if lc.index_topk and select:
-            o = _selected_attention(
-                q, qi, wi, pool, flat_ki, tables[grp] + base, scat_slot,
-                token_pos, lc, block_size=block_size, max_rows=Q, rows=rows)
+            o = selected[lc](q, qi, wi, pool, flat_ki, tables[grp] + base,
+                             scat_slot, token_pos, rows=rows)
         else:
             o = attend[lc, cfg.window_for_layer(li)](
                 q, rows, pool, flat_v_all, tables[grp] + base,

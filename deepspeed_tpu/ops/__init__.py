@@ -164,13 +164,14 @@ register_op("ragged_prefill_attention", xla=_paged.xla_ragged_prefill,
 
 from deepspeed_tpu.ops import sparse_index as _index  # noqa: E402
 from deepspeed_tpu.ops.sparse_index import (  # noqa: E402
-    index_scores, index_select, selected_attention)
+    index_scores, index_select, selected_attention, selection_mask)
 
 register_op("index_scores", xla=_index.xla_index_scores,
             pallas=_index.pallas_index_scores,
             supported=_index.index_scores_supported)
 register_op("index_select", xla=_index.xla_index_select)
 register_op("selected_attention", xla=_index.xla_selected_attention)
+register_op("selection_mask", xla=_index.xla_selection_mask)
 
 from deepspeed_tpu.ops import grouped_gemm as _grouped  # noqa: E402
 
@@ -238,6 +239,7 @@ __all__ = ["causal_attention", "flash_attention", "configure_flash_blocks",
            "paged_attention", "lora_matmul",
            "ragged_prefill_attention", "evoformer_attention",
            "index_scores", "index_select", "selected_attention",
+           "selection_mask",
            "all_gather_matmul", "matmul_reduce_scatter",
            "row_parallel_matmul", "collective_matmul",
            "lm_cross_entropy", "masked_nll_sum", "rms_norm", "layer_norm",

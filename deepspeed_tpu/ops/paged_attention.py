@@ -593,6 +593,19 @@ def paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
 #   ``(lo, hi]`` and one select: a second body without it for the blocks no
 #   edge crosses was built and measured to gain nothing (the mask hides behind
 #   the dots), so there is one body.
+# THE MASKED FORM (``sel_mask``, a static flag like ``has_alibi``: a call
+# without it lowers to the program it always was).  A layer that SELECTS its
+# keys (``ops/sparse_index.py``) hands the kernel, beside the pages, which
+# positions each row keeps: bit ``n % 32`` of word ``[n // 32, c]`` says row
+# ``n`` of the flat batch keeps position ``c`` of its sequence
+# (``selection_mask``), one answer for all heads of the row.  Rows are packed
+# into words because a copy may take only whole tiles of an array's two minor
+# dims and an item's ``cq`` rows begin anywhere: as ``[groups, 1, C]`` the
+# group is a leading dim, a block's slab ``[its rows' groups, 1, P * bs]``
+# is whole lanes, and the array is an eighth of a byte a pair.  The slab is
+# copied beside the block's pages, in flight behind the dots as they are, and
+# a score whose bit is clear takes the masked value in the one body: a select
+# between the groups an item's rows straddle, one AND and one compare more.
 # FLOPs and bandwidth scale with the live chunks; a live chunk pays for all
 # its ``cq`` rows over every key of every block it walks, so a context's last
 # block costs its P pages however few of them are live.  On one v5e chip
@@ -608,13 +621,14 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
                        scale: Optional[float] = None,
                        alibi_slopes=None, window=None, interpret=None,
                        mesh=None, kv_major=False, k_scale=None, v_scale=None,
-                       v_dim=None):
+                       v_dim=None, sel_mask=None):
     """Ground-truth gather + masked-dense path (the round-2 prefill body):
     each slot's rows gathered dense [S, Q, ...] (``Q = max_q``, the most rows
     a slot can hold; every row of the batch if not said), attended, and
     scattered back to their flat rows; rows no slot owns come back zero.
     ``k_scale``/``v_scale``: int8-KV dequant after the gather (see
-    xla_paged_attention)."""
+    xla_paged_attention).  ``sel_mask``: the positions each row keeps
+    (section comment)."""
     N, nkv, g, hd = q.shape
     if kv_major:
         NB, _, _, bs = k_pages.shape
@@ -642,6 +656,10 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
         & live[:, :, None]                                     # [S, Q, K]
     if window is not None:
         mask = mask & (kvpos[None, None, :] > qpos[:, :, None] - window)
+    if sel_mask is not None:
+        row = jnp.minimum(flat, N - 1)
+        kept = jnp.right_shift(sel_mask[row // 32], (row % 32)[..., None]) & 1
+        mask = mask & (kept != 0)
     s_log = jnp.einsum("sqngd,sknd->snqgk", q, k_seq,
                        preferred_element_type=jnp.float32) * scale
     if alibi_slopes is not None:
@@ -659,23 +677,27 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
 
 
 def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
-                    kv_major, quant=False, v_dim=None):
+                    kv_major, quant=False, v_dim=None, has_mask=False):
     """One grid step = one work item (``cq`` rows of one slot) for one kv
     head; see the section comment.  The chunk buffers hold ``g`` heads of
     ``hd`` (``vd``) values in their leading rows and columns: the arrays in
     HBM are padded to whole tiles (``_tile_pad``).  ``P``: the pages of a
     block (``_prefill_block_pages``).  ``v_dim``: latent pages, one pool and
     one buffer: the page is the key and its leading ``v_dim`` columns the
-    value."""
+    value.  ``has_mask``: the masked form, one more array in HBM and one
+    more buffer (section comment)."""
     it = iter(refs)
     bt_ref, len_ref, start_ref, count_ref, row_ref, item_slot_ref, \
         item_chunk_ref, n_items_ref = (next(it) for _ in range(8))
     slopes_ref = next(it) if has_alibi else None
     q_hbm = next(it)
     hbms = [next(it) for _ in range(1 if v_dim else 4 if quant else 2)]
+    mask_hbm = next(it) if has_mask else None
     o_hbm, q_buf, o_buf = next(it), next(it), next(it)
     bufs = [next(it) for _ in hbms]
+    mask_buf = next(it) if has_mask else None
     m_ref, l_ref, acc_ref, sem, row_sem = (next(it) for _ in range(5))
+    mask_sem = next(it) if has_mask else None
     item, h = pl.program_id(0), pl.program_id(1)
     R, K = cq * g, P * bs                  # the score tile of a block
     vd = v_dim or hd
@@ -730,7 +752,15 @@ def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
                 return 0
             lax.fori_loop(0, lax.clamp(i32(0), n_pages - p0, i32(P)), page,
                           0)
+            if has_mask:
+                # the words of the groups of 32 rows the item's rows lie
+                # in, over the block's keys
+                act(pltpu.make_async_copy(
+                    mask_hbm.at[pl.ds(group0, mask_buf.shape[1]), :,
+                                pl.ds(p0 * bs, K)],
+                    mask_buf.at[half], mask_sem.at[half]))
 
+        group0 = lax.shift_right_logical(flat0, i32(5)) if has_mask else None
         block_copies(p_start, 0, lambda c: c.start())
         # a row sees the keys in (lo, hi]: its position bounds them above
         # (and the context's length, and nothing at all if the row is past
@@ -740,6 +770,12 @@ def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
         hi = lax.select(rown < n_rows, lax.min(qpos, length - 1),
                         lax.full((R, 1), -1, i32))
         lo = None if window is None else qpos - window
+        if has_mask:
+            # row r's bit, and which of the copied groups holds it
+            flat = flat0 + rown
+            group = lax.shift_right_logical(flat, i32(5)) - group0
+            bit = lax.shift_left(lax.full((R, 1), 1, i32),
+                                 lax.bitwise_and(flat, i32(31)))
         if has_alibi:
             # SMEM scalar-prefetch slopes [nkv, g]: row r = j·g+gi needs
             # slopes[h, r % g] — tile the per-group column cq times
@@ -795,6 +831,13 @@ def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
             valid = kvpos <= hi
             if window is not None:
                 valid = valid & (kvpos > lo)
+            if has_mask:
+                words = jnp.broadcast_to(mask_buf[half, 0], (R, K))
+                for i in range(1, mask_buf.shape[1]):
+                    words = jnp.where(
+                        group == i, jnp.broadcast_to(mask_buf[half, i],
+                                                     (R, K)), words)
+                valid = valid & (lax.bitwise_and(words, bit) != 0)
             scores = jnp.where(valid, scores, _NEG_INF)
             m = m_ref[...]
             m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
@@ -840,13 +883,13 @@ def pallas_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
                           alibi_slopes=None, window=None,
                           interpret: Optional[bool] = None, mesh=None,
                           kv_major=False, k_scale=None, v_scale=None,
-                          v_dim=None):
+                          v_dim=None, sel_mask=None):
     """Rows of slots with ``q_counts`` 0, and rows no slot owns, come back
     as the fresh output buffer held them (nothing): the caller does not read
     them (the mixed step selects the paged decode kernel's result for them,
     or zero)."""
     if (mesh is not None and mesh.shape.get("tp", 1) > 1 and v_pages is not None
-            and q.shape[1] % mesh.shape["tp"] == 0):
+            and q.shape[1] % mesh.shape["tp"] == 0 and sel_mask is None):
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
         inner = functools.partial(_pallas_ragged_prefill_local, max_q=max_q,
@@ -882,7 +925,7 @@ def pallas_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
         q, k_pages, v_pages, block_table, kv_lens, q_starts, q_counts,
         row_starts, max_q=max_q, scale=scale, alibi_slopes=alibi_slopes,
         window=window, interpret=interpret, kv_major=kv_major,
-        k_scale=k_scale, v_scale=v_scale, v_dim=v_dim)
+        k_scale=k_scale, v_scale=v_scale, v_dim=v_dim, sel_mask=sel_mask)
 
 
 # the prefill kernel's float32 accumulator [cq * g, value width] stays under
@@ -947,9 +990,9 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
                                  alibi_slopes=None, window=None,
                                  interpret: Optional[bool] = None,
                                  kv_major=False, k_scale=None, v_scale=None,
-                                 v_dim=None):
+                                 v_dim=None, sel_mask=None):
     N, nkv, g, hd = q.shape
-    S = block_table.shape[0]
+    S, MB = block_table.shape
     bs = k_pages.shape[3] if kv_major else k_pages.shape[2]
     if scale is None:
         scale = hd ** -0.5
@@ -961,6 +1004,7 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
     cq = _prefill_chunk(Q, g, vd)
     q_counts = q_counts.astype(jnp.int32)
     has_alibi = alibi_slopes is not None
+    has_mask = sel_mask is not None
     quant = k_scale is not None
 
     # the work list: slot after slot, each slot's chunks in order.  Item i
@@ -991,7 +1035,7 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
         _prefill_kernel, P=P, bs=bs, cq=cq, g=g, hd=hd, scale=float(scale),
         window=int(window) if window is not None else None,
         has_alibi=has_alibi, kv_major=kv_major, quant=quant,
-        v_dim=vd if latent else None)
+        v_dim=vd if latent else None, has_mask=has_mask)
     prefetch = [block_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
                 q_starts.astype(jnp.int32), q_counts,
                 row_starts.astype(jnp.int32), item_slot, item_chunk,
@@ -1004,12 +1048,24 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
     q = lax.pad(q, jnp.zeros((), q.dtype),
                 ((0, cq, 0), (0, 0, 0), (0, gp - g, 0), (0, hp - hd, 0)))
     inputs = [q] + pools
+    if has_mask:
+        # an item's ``cq`` rows lie in at most ``span`` groups of 32 and a
+        # context's last block reaches ``P - 1`` pages past the table: so
+        # many groups and columns more, zeros, for the copies to stay inside
+        span = (cq + 30) // 32 + 1
+        groups = (N - 1) // 32 + span
+        inputs.append(lax.pad(
+            sel_mask, jnp.int32(0),
+            ((0, groups - sel_mask.shape[0], 0), (0, (P - 1) * bs, 0))
+        ).reshape(groups, 1, (MB + P - 1) * bs))
     # the chunk's rows in and out, both halves of the page pipeline (P pages
     # of one kv head each), and the softmax state of the item's rows
     scratch = [pltpu.VMEM((cq, gp, hp), q.dtype),
                pltpu.VMEM((cq, gp, vp), q.dtype)]
     scratch += [pltpu.VMEM((2, P) + pool.shape[2:], pool.dtype)
                 for pool in pools]
+    if has_mask:
+        scratch.append(pltpu.VMEM((2, span, 1, P * bs), jnp.int32))
     # (the running max and sum fill their rows' lanes: a column one lane
     # wide costs a block a masked store and a broadcast for every 8 rows,
     # 1.4 us at 768 rows, more than a page's dots: PERF.md section 6, PR 37)
@@ -1020,6 +1076,8 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
                * buf.dtype.itemsize for buf in scratch)
     scratch += [pltpu.SemaphoreType.DMA((len(pools), 2, P)),
                 pltpu.SemaphoreType.DMA((2,))]
+    if has_mask:
+        scratch.append(pltpu.SemaphoreType.DMA((2,)))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1044,7 +1102,8 @@ def ragged_prefill_supported(q, k_pages, v_pages, block_table, kv_lens,
                              q_starts, q_counts, row_starts, *, max_q=None,
                              scale=None, alibi_slopes=None, window=None,
                              interpret=None, mesh=None, kv_major=False,
-                             k_scale=None, v_scale=None, v_dim=None):
+                             k_scale=None, v_scale=None, v_dim=None,
+                             sel_mask=None):
     if q.ndim != 4 or k_pages.ndim != 4:
         return False
     N, nkv, g, hd = q.shape
@@ -1063,6 +1122,8 @@ def ragged_prefill_supported(q, k_pages, v_pages, block_table, kv_lens,
     return (nkv == nkv2 and hd == hd2
             and _latent_ok(v_pages, v_dim, hd, kv_major, quant, alibi_slopes)
             and _dma_layout_ok(hd, bs, kv_major, quant=quant)
+            # (a block's slab of the mask is whole lanes)
+            and (sel_mask is None or bs % 128 == 0)
             and block_table.ndim == 2
             and row_starts.shape == (block_table.shape[0],))
 
@@ -1075,12 +1136,14 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_table, kv_lens,
                              impl: Optional[str] = None,
                              interpret: Optional[bool] = None, mesh=None,
                              kv_major=False, k_scale=None, v_scale=None,
-                             v_dim: Optional[int] = None):
+                             v_dim: Optional[int] = None, sel_mask=None):
     """Registry entry for the ragged prefill kernel: token-major ``q``
     [N, nkv, g, hd] -> [N, nkv, g, vd]; slot ``s`` owns rows ``row_starts[s]
     + [0, q_counts[s])`` at positions ``q_starts[s] + [0, q_counts[s])``,
     at most ``max_q`` of them.  ``v_pages=None`` with ``v_dim``: latent pages
-    (module docstring)."""
+    (module docstring).  ``sel_mask``: ``[ceil(N / 32), MB * bs]`` int32,
+    the positions of its sequence each row keeps beside what is causal
+    (``ops.selection_mask``; the section comment has the layout)."""
     from deepspeed_tpu.ops.registry import dispatch
     return dispatch("ragged_prefill_attention", q, k_pages, v_pages,
                     block_table, kv_lens, q_starts, q_counts, row_starts,
@@ -1088,4 +1151,4 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_table, kv_lens,
                     alibi_slopes=alibi_slopes, window=window, impl=impl,
                     interpret=interpret, mesh=mesh, kv_major=kv_major,
                     k_scale=k_scale, v_scale=v_scale,
-                    v_dim=v_dim)
+                    v_dim=v_dim, sel_mask=sel_mask)

@@ -10,7 +10,7 @@ weights, and scores every key ``s <= t`` of its sequence::
 
 It then attends over the ``k`` keys of largest ``I[t, .]`` only (all of them
 while ``t < k``).  The selection is exact: ``lax.top_k`` over the float32
-scores, ties to the lower position.  Three ops, each registered
+scores, ties to the lower position.  Four ops, each registered
 (``ops/registry.py``) and so in the dispatch log:
 
 - ``index_scores``: ``[N, C]`` scores of a step's rows over their slots'
@@ -26,6 +26,17 @@ scores, ties to the lower position.  Three ops, each registered
   leading ``v_dim`` columns), rows gathered by index a block of query rows
   at a time.  XLA on every backend: a gather of 1.3 KB rows and two batched
   matmuls a block (PERF.md section 6, PR 36, has the chip's readings).
+- ``selection_mask``: the same selection as bits over the positions of a
+  row's sequence, which ``ragged_prefill_attention`` takes as ``sel_mask``
+  (``ops/paged_attention.py``): that kernel then reads a slot's pages ONCE
+  for all the slot's rows where the gather reads ``k`` rows a query row.
+
+Which rows take which (``inference/v2/model.py:_selected_attention``): a row
+alone in its slot (every row of a decode step, the riders of a mixed step)
+gathers, one row attending ``k`` rows being the gather's own case; a prompt
+chunk's rows take the masked kernel while ``masked_prefill(reach)`` holds,
+``reach`` the longest context among them, and gather past it: the gather
+costs a chunk the same at any context, the kernel what the context holds.
 """
 
 from __future__ import annotations
@@ -214,6 +225,56 @@ def index_select(scores, k: int, *, width=None, impl: Optional[str] = None):
     power-of-two share of the columns that holds ``width`` is sorted."""
     from deepspeed_tpu.ops.registry import dispatch
     return dispatch("index_select", scores, k, width=width, impl=impl)
+
+
+def xla_selection_mask(scores, picked):
+    with jax.named_scope("selection_mask"):
+        N, C = scores.shape
+        # the last of a row's picks is the least of them and, of the
+        # positions that tie with it, the highest one taken (``index_select``:
+        # best first, ties to the lower position): so what the row keeps is
+        # every score above that one and its ties up to that position.  A
+        # row with fewer keys in sight than picks keeps them all: its last
+        # pick is a masked position, ``-inf``.
+        last = picked[:, -1:]                                   # [N, 1]
+        least = jnp.take_along_axis(scores, last, axis=1)
+        col = jnp.arange(C, dtype=jnp.int32)[None, :]
+        keep = (scores > least) | ((scores == least) & (col <= last)
+                                   & (scores > -jnp.inf))
+        groups = -(-N // 32)
+        keep = jnp.pad(keep, ((0, groups * 32 - N), (0, 0)))
+        bits = jnp.left_shift(jnp.int32(1), jnp.arange(32, dtype=jnp.int32))
+        return jax.lax.reduce(
+            jnp.where(keep.reshape(groups, 32, C), bits[None, :, None], 0),
+            jnp.int32(0), jax.lax.bitwise_or, (1,))
+
+
+def selection_mask(scores, picked, *, impl: Optional[str] = None):
+    """Registry entry: ``picked [N, k]`` (``index_select`` of ``scores [N,
+    C]``, the same array) as bits -> ``[ceil(N / 32), C]`` int32: bit ``n %
+    32`` of word ``[n // 32, c]`` is set where row ``n`` keeps position
+    ``c``.  No scatter: a pick is a score no lower than the row's last
+    pick's, so the mask is two compares a score (a pass over the scores, a
+    thirty-second of their size written)."""
+    from deepspeed_tpu.ops.registry import dispatch
+    return dispatch("selection_mask", scores, picked, impl=impl)
+
+
+# A prompt chunk's rows take the masked prefill kernel while the longest
+# context among them is at most this many tokens, and the row gather past it.
+# Fitted on one v5e chip at dots3-note-prev's full layer, a chunk of 1,024
+# rows over a table of 32,768 tokens (PERF.md section 6, PR 43, step 0): the
+# gather costs a chunk the same at any context, the kernel what the context
+# holds, and they cross between 20 k and 22 k.
+MASKED_REACH = 20480
+
+
+def masked_prefill(reach):
+    """Whether the rows that share their slots take the masked prefill
+    kernel: ``reach``, the furthest context among them after the step, in
+    tokens (a traced scalar in the step program, a number on the host: the
+    engine's counter asks this same function)."""
+    return reach <= MASKED_REACH
 
 
 ATTEND_ROWS = 64      # query rows whose lists are gathered at a time

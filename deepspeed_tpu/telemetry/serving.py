@@ -267,6 +267,11 @@ class ServingTelemetry:
         self.c_global_pairs = reg.counter(
             "serving_global_pairs_total", "causal query-key pairs on the "
             "selecting layers: what dense attention would read")
+        self.c_sel_masked = reg.counter(
+            "serving_selected_masked_steps_total", "mixed dispatches whose "
+            "prompt chunks read their selected keys through the masked "
+            "prefill kernel and not by the row gather "
+            "(ops.sparse_index.masked_prefill of the step's reach)")
 
     # ------------------------------------------------------------- clocks
 
@@ -405,12 +410,15 @@ class ServingTelemetry:
             for k, v in self.kv_bytes_groups.items():
                 self.g_kv_bytes.set(v, part=k, **self.labels)
 
-    def index_pairs(self, scored: int, kept: int, causal: int) -> None:
-        """One dispatch's pairs on the selecting layers."""
+    def index_pairs(self, scored: int, kept: int, causal: int,
+                    masked_step: bool = False) -> None:
+        """One dispatch's pairs on the selecting layers, and whether its
+        prompt chunks took the masked prefill kernel."""
         if self.enabled:
             self.c_index_pairs.inc(scored, **self.labels)
             self.c_sel_pairs.inc(kept, **self.labels)
             self.c_global_pairs.inc(causal, **self.labels)
+            self.c_sel_masked.inc(int(masked_step), **self.labels)
 
     def counter_note(self, state) -> Dict[str, int]:
         """Running totals for a dispatch span's args, so that a trace holds
@@ -434,6 +442,8 @@ class ServingTelemetry:
             note.update(
                 index_pairs=int(self.c_index_pairs.value(**self.labels)),
                 sel_pairs=int(self.c_sel_pairs.value(**self.labels)),
+                sel_masked_steps=int(
+                    self.c_sel_masked.value(**self.labels)),
                 global_pairs=int(pairs))
         total = self.c_moe_assign.value(**self.labels)
         if total:

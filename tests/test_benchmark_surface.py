@@ -19,7 +19,10 @@ lost name fails the case that carries it and no other.
     ``benchmark/tools/mla_compare.py`` take of a model with latent attention;
 (f) ``SETUP_READS``: the set-up account's counters, ``program_setup`` record
     and init spans, as ``benchmark/readers/setup_account.py`` takes them
-    through ``deepspeed_tpu.telemetry.setup_account()`` (PR 40).
+    through ``deepspeed_tpu.telemetry.setup_account()`` (PR 40);
+(g) ``SELECTING_READS``: how often a selecting model's prompt chunks take
+    the masked prefill kernel, as a counter and in the dispatch spans (PR
+    43; no manifest entry reads them yet, a ``benchmark`` issue may).
 The other spans and their arguments are held by ``tests/test_one_clock.py``."""
 
 import dataclasses
@@ -805,3 +808,63 @@ SETUP_READS = {
 @pytest.mark.parametrize("name", list(SETUP_READS))
 def test_setup_account_reads(served, trained, name):
     SETUP_READS[name](served, trained)
+
+
+# ---------- (g) a selecting model's masked steps, by the program's own rule
+
+@pytest.fixture(scope="module")
+def selecting_notes():
+    """``_ctx_note`` of a model that selects its keys (``index_topk`` 2,048
+    over a table of 32,768 tokens, two selecting layers), asked as
+    ``_step_sampled`` asks it for three mixed steps (one chunk of 1,024 rows
+    beside two riders: under, at and past ``MASKED_REACH``) and as
+    ``_build_burst`` does for a burst: (telemetry, the four notes with the
+    running totals a dispatch span carries)."""
+    import types
+
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    from deepspeed_tpu.telemetry.serving import ServingTelemetry
+    tel = ServingTelemetry(pid=0)
+    eng = types.SimpleNamespace(
+        telemetry=tel, _block_size=512, model_config=types.SimpleNamespace(
+            index_topk=2048, max_seq_len=32768, num_layers=5,
+            sliding_window=0, window_for_layer=lambda i: 513 if i > 1
+            else None))
+    notes = []
+    for ctx in (7168, 19456, 20480):
+        notes.append(InferenceEngineV2._ctx_note(
+            eng, [ctx, 30000, 9000], [1024, 1, 1], table_tokens=32768))
+        notes[-1].update(tel.counter_note(None))
+    notes.append(InferenceEngineV2._ctx_note(eng, [31000, 9000], steps=8))
+    notes[-1].update(tel.counter_note(None))
+    return tel, notes
+
+
+def _selecting_counter(o):
+    tel, notes = o
+    assert tel.registry._metrics[
+        "serving_selected_masked_steps_total"].value(**tel.labels) == 2
+
+
+def _selecting_total(o):
+    # a running total in EVERY dispatch span; the burst leaves it still
+    assert [n["sel_masked_steps"] for n in o[1]] == [1, 2, 2, 2]
+
+
+def _selecting_reach(o):
+    # the chunk's context after the step, not the riders'; mixed spans only
+    from deepspeed_tpu.ops.sparse_index import MASKED_REACH
+    assert [n.get("sel_reach") for n in o[1]] == [8192, 20480, 21504, None]
+    assert MASKED_REACH == 20480
+
+
+SELECTING_READS = {
+    "serving_selected_masked_steps_total": _selecting_counter,
+    "ds.*_dispatch.sel_masked_steps": _selecting_total,
+    "ds.mixed_dispatch.sel_reach": _selecting_reach,
+}
+
+
+@pytest.mark.parametrize("name", list(SELECTING_READS))
+def test_selecting_reads(selecting_notes, name):
+    SELECTING_READS[name](selecting_notes)

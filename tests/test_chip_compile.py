@@ -170,21 +170,23 @@ def _latent(v_dim):
 
 
 def prefill_text(topo, nkv, g, hd, bs, kv_major, quant=False, S=8, MB=8,
-                 Q=128, window=None, v_dim=None):
+                 Q=128, window=None, v_dim=None, masked=False):
     """The token-major kernel over a flat batch of ``S * Q`` rows, at most
-    ``Q`` a slot."""
+    ``Q`` a slot.  ``masked``: its masked form (``sel_mask``, the positions
+    each row keeps, 32 rows a word)."""
     page, scale = _pool(nkv, hd, bs, kv_major, quant)
     specs = [sds((S * Q, nkv, g, hd), BF16), page, None if v_dim else page,
              sds((S, MB), I32), sds((S,), I32), sds((S,), I32),
-             sds((S,), I32), sds((S,), I32)]
+             sds((S,), I32), sds((S,), I32),
+             sds((S * Q // 32, MB * bs), I32) if masked else None]
     if quant:
         specs += [scale, scale]
 
-    def fn(q, k, v, bt, lens, st, ct, rs, *sc):
+    def fn(q, k, v, bt, lens, st, ct, rs, keep, *sc):
         kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
         return pallas_ragged_prefill(q, k, v, bt, lens, st, ct, rs, max_q=Q,
                                         interpret=False, kv_major=kv_major,
-                                        window=window, **kw,
+                                        window=window, sel_mask=keep, **kw,
                                         **_latent(v_dim))
     return chip_text(topo, fn, *specs)
 
@@ -540,6 +542,11 @@ CELL_PREFILL = {
     "dots3-window": (lambda: dict(
         nkv=1, g=64, hd=1152, bs=512, kv_major=False, window=513,
         v_dim=1024), 1024, 1),
+    # a full layer's prompt chunk below ``MASKED_REACH``: the masked form
+    # over the 64 pages of the cell's one table width (4 rows an item)
+    "dots3-full-masked": (lambda: dict(
+        nkv=1, g=128, hd=640, bs=512, kv_major=False, v_dim=512, S=1,
+        MB=64, masked=True), 1024, 3),
 }
 
 
@@ -676,6 +683,60 @@ def test_selecting_step_programs_leave_all_three_pools_in_place(
                         and shape[-1] == pool.shape[-1]:
                     moved.append(f"{op} -> {dt}[{dims}] ({name})")
     assert not moved, moved
+
+
+def _computations(text):
+    """name -> text of every computation of a compiled program."""
+    return {m.group(1): m.group(0) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n.*?^\}", text, re.M | re.S)}
+
+
+def _with_callees(comps, name):
+    """A computation's text and that of everything it calls, however deep
+    (a fusion's body, a loop's, a reduction's)."""
+    todo, seen = [name], {}
+    while todo:
+        n = todo.pop()
+        if n in seen or n not in comps:
+            continue
+        seen[n] = comps[n]
+        for called in re.findall(
+                r"(?:calls|to_apply|body|condition)=%[\w.\-]+"
+                r"|branch_computations=\{[^}]*\}", comps[n]):
+            todo += re.findall(r"%([\w.\-]+)", called)
+    return "\n".join(seen.values())
+
+
+def test_a_chunk_reads_its_keys_one_way_a_branch(selecting_steps,
+                                                 monkeypatch):
+    """The mixed step of a model that selects its keys holds ONE branch a
+    selecting layer kind under ``attn_kernel`` (``masked_prefill`` of the
+    step's reach).  The masked branch is the prefill kernel under scope
+    ``selected_attention`` and gathers no row a pair: nothing of ``[rows, k,
+    640]``.  The other is the gather's loop and no kernel.  (That neither
+    moves a pool is the test above: it reads every computation.)"""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = selecting_steps()[1]["ragged_forward_sampled"].as_text()
+    branches = [ln for ln in text.splitlines() if " conditional(" in ln
+                and "/attn_kernel/cond" in ln]
+    assert len(branches) == 1, branches
+    gathers, masked = (_with_callees(_computations(text), name) for name in
+                       re.search(r"branch_computations=\{%([\w.\-]+), "
+                                 r"%([\w.\-]+)\}", branches[0]).groups())
+
+    def row_gathers(part):
+        return [f"{dt}[{dims}]" for result, op in _HLO_OP.findall(part)
+                if op == "gather" for dt, dims in _HLO_ARRAY.findall(result)
+                if np.prod([int(d) for d in dims.split(",") if d] or [1])
+                >= DOTS3.index_topk * 640]
+    kernels = [ln for ln in masked.splitlines()
+               if f'custom_call_target="{KERNEL}"' in ln]
+    assert len(kernels) == 1 and "/selected_attention/" in kernels[0] \
+        and "/ragged_prefill/" in kernels[0] \
+        and "window_latent" not in kernels[0]
+    assert "selection_mask" in masked and not row_gathers(masked)
+    assert row_gathers(gathers) and " while(" in gathers
+    assert KERNEL not in gathers and "selection_mask" not in gathers
 
 
 # -------------------------------------------------------- quantized GEMMs

@@ -231,6 +231,47 @@ def test_the_programs_selected_sets_are_the_references(impl):
     assert want[-1].sum() == TOPK and want[5].sum() == 6
 
 
+def test_tokens_are_the_same_whichever_way_a_chunk_reads_its_keys(monkeypatch):
+    """``ops.sparse_index.MASKED_REACH`` forced to 0 (every prompt chunk
+    gathers its rows, as before PR 43) and to the table's width (every chunk
+    takes the masked prefill kernel): the same tokens, the gathered run's
+    logits still the reference's, and the counter and the spans say which
+    way each mixed step went, by the program's own rule."""
+    from deepspeed_tpu.ops import sparse_index
+    sz = two_layers()
+    cfg, params = model(sz)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, V, size=n) for n in (100, 41)]
+    tokens, masked = {}, {}
+    for reach in (0, 512):
+        monkeypatch.setattr(sparse_index, "MASKED_REACH", reach)
+        eng = InferenceEngineV2(      # its own step programs: no cache
+            cfg, {"dtype": "float32", "generation": {"do_sample": False},
+                  "state_manager": {
+                      "max_tracked_sequences": 4,
+                      "max_ragged_sequence_count": 4,
+                      "max_ragged_batch_size": 128, "max_q_per_seq": 32,
+                      "kv_block_size": 16, "num_kv_blocks": 64}},
+            params=params)
+        tokens[reach] = [np.asarray(t) for t in eng.generate(
+            prompts, max_new_tokens=6)]
+        spans = [ev["args"] for ev in eng.telemetry.tracer.events
+                 if ev["name"] == "mixed_dispatch"]
+        assert [a["sel_reach"] for a in spans][:2] == [32, 64]
+        masked[reach] = (
+            eng.telemetry.value("serving_selected_masked_steps_total"),
+            spans[-1]["sel_masked_steps"], len(spans))
+        if not reach:
+            got, rows = _serve(eng, [prompts[0]], chunk=32, tail=2)
+            np.testing.assert_allclose(
+                np.stack(got[0]), np.asarray(ref.logits(
+                    params, prompts[0], sz, rows=rows[0])), atol=1e-4)
+    for a, b in zip(tokens[0], tokens[512]):
+        np.testing.assert_array_equal(a, b)
+    assert masked[0][:2] == (0, 0)
+    assert masked[512][0] == masked[512][1] == masked[512][2] > 0
+
+
 def test_exact_selection_breaks_ties_toward_the_lower_position():
     scores = jnp.asarray([[1.0, 3.0, 3.0, 3.0, 0.5, 3.0]])
     assert sorted(np.asarray(ops.index_select(scores, 3))[0]) == [1, 2, 3]
@@ -569,12 +610,12 @@ def dispatch_spans():
 @pytest.mark.parametrize("span,arg", [
     (kind, arg) for kind in ("mixed_dispatch", "burst_dispatch")
     for arg in ("index_pairs", "sel_pairs", "global_pairs",
-                "index_pairs_step", "sel_pairs_step",
+                "sel_masked_steps", "index_pairs_step", "sel_pairs_step",
                 "kv_bytes_per_token_global", "kv_bytes_per_token_window",
                 "index_bytes_per_token", "ctx_tokens_window")]
     + [("mixed_dispatch", arg) for arg in (
         "sel_pairs_one_row", "ctx_tokens_window_one_row", "qk_pairs_window",
-        "one_row_slots")])
+        "one_row_slots", "sel_reach")])
 def test_dispatch_span_carries(dispatch_spans, span, arg):
     """Every argument ``benchmark/readers/sparse.py`` and the ``.sparse``
     metrics take from a dispatch span, by name."""
