@@ -511,6 +511,177 @@ def _deepseek_v3_tree(r, cfg) -> Dict[str, Any]:
     return tree
 
 
+# granitemoehybrid (Granite 4.0-H): published tensor name -> path in the GPT
+# parameter tree, the names the loader (``_granite_hybrid_tree``) reads and
+# ``granite_hybrid_state_dict`` writes back, held to each other in
+# tests/test_granite_hybrid.py on a seeded tiny state dict (no published
+# weights are in the repository).  ``shared_mlp.input_linear`` is ONE matrix
+# whose leading half is the gate (``wg``) and trailing half the up projection
+# (``wi``); ``mamba.in_proj`` is one matrix [z | xBC | dt] and stays one
+# (``w_in``); ``mamba.conv1d.weight`` is [channels, 1, taps].  The head is
+# tied: there is no ``lm_head.weight``.
+GRANITE_HYBRID_WEIGHT_NAMES = {
+    "model.embed_tokens.weight": "backbone/wte",
+    "model.norm.weight": "backbone/final_norm/scale",
+    "model.layers.{i}.input_layernorm.weight": "backbone/block_{i}/Norm_0/scale",
+    "model.layers.{i}.post_attention_layernorm.weight":
+        "backbone/block_{i}/Norm_1/scale",
+    "model.layers.{i}.shared_mlp.input_linear.weight":
+        "backbone/block_{i}/MLP_0/wg+wi",
+    "model.layers.{i}.shared_mlp.output_linear.weight":
+        "backbone/block_{i}/MLP_0/wo",
+    # attention layers (layer_types[i] == "attention")
+    "model.layers.{i}.self_attn.q_proj.weight": "backbone/block_{i}/Attention_0/wq",
+    "model.layers.{i}.self_attn.k_proj.weight": "backbone/block_{i}/Attention_0/wk",
+    "model.layers.{i}.self_attn.v_proj.weight": "backbone/block_{i}/Attention_0/wv",
+    "model.layers.{i}.self_attn.o_proj.weight": "backbone/block_{i}/Attention_0/wo",
+    # scan layers (layer_types[i] == "mamba")
+    "model.layers.{i}.mamba.in_proj.weight": "backbone/block_{i}/Mamba2Mixer_0/w_in",
+    "model.layers.{i}.mamba.conv1d.weight": "backbone/block_{i}/Mamba2Mixer_0/conv_w",
+    "model.layers.{i}.mamba.conv1d.bias": "backbone/block_{i}/Mamba2Mixer_0/conv_b",
+    "model.layers.{i}.mamba.dt_bias": "backbone/block_{i}/Mamba2Mixer_0/dt_bias",
+    "model.layers.{i}.mamba.A_log": "backbone/block_{i}/Mamba2Mixer_0/A_log",
+    "model.layers.{i}.mamba.D": "backbone/block_{i}/Mamba2Mixer_0/D",
+    "model.layers.{i}.mamba.norm.weight": "backbone/block_{i}/Mamba2Mixer_0/norm",
+    "model.layers.{i}.mamba.out_proj.weight":
+        "backbone/block_{i}/Mamba2Mixer_0/w_out",
+}
+
+
+def granite_hybrid_config(hf: Dict[str, Any], *,
+                          max_seq_len: Optional[int] = None, dtype=None):
+    """GPTConfig of a published ``granitemoehybrid`` ``config.json`` of the
+    shape granite-4.0-h-micro has: Mamba-2 scan layers and NoPE GQA
+    attention layers by ``layer_types``, a gated MLP of
+    ``shared_intermediate_size`` in every layer and no experts, a tied head,
+    and Granite's four multipliers."""
+    from deepspeed_tpu.models.gpt import GPTConfig
+    for key, ok, what in (
+            ("num_local_experts", not hf.get("num_local_experts", 0),
+             "routed experts beside the shared MLP"),
+            ("position_embedding_type",
+             hf.get("position_embedding_type", "nope") == "nope",
+             "rotary positions on the attention layers"),
+            ("attention_bias", not hf.get("attention_bias", False),
+             "attention biases"),
+            ("mamba_proj_bias", not hf.get("mamba_proj_bias", False),
+             "biases on the mixer's projections"),
+            ("normalization_function",
+             hf.get("normalization_function", "rmsnorm") == "rmsnorm",
+             "a norm other than RMSNorm"),
+            ("tie_word_embeddings", hf.get("tie_word_embeddings", True),
+             "an untied head"),
+            ("mamba_expand", hf["mamba_n_heads"] * hf["mamba_d_head"]
+             == hf.get("mamba_expand", 2) * hf["hidden_size"],
+             "an inner width other than heads x head width")):
+        if not ok:
+            raise NotImplementedError(
+                f"granitemoehybrid: {key}={hf.get(key)!r}: {what} is not "
+                f"built")
+    msl = hf.get("max_position_embeddings", 2048)
+    heads = hf["num_attention_heads"]
+    return GPTConfig(
+        vocab_size=hf["vocab_size"], num_layers=hf["num_hidden_layers"],
+        num_heads=heads, num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["hidden_size"] // heads, hidden_size=hf["hidden_size"],
+        mlp_dim_override=hf["shared_intermediate_size"],
+        max_seq_len=min(msl, max_seq_len or msl),
+        use_rope=True, rope_layers="none", use_rmsnorm=True,
+        norm_eps=float(hf.get("rms_norm_eps", 1e-5)), gated_mlp=True,
+        tie_embeddings=True, layer_types=tuple(hf["layer_types"]),
+        ssm_heads=hf["mamba_n_heads"], ssm_head_dim=hf["mamba_d_head"],
+        ssm_state=hf["mamba_d_state"], ssm_groups=hf.get("mamba_n_groups", 1),
+        ssm_conv=hf.get("mamba_d_conv", 4),
+        ssm_chunk=hf.get("mamba_chunk_size", 256),
+        ssm_conv_bias=bool(hf.get("mamba_conv_bias", True)),
+        embed_scale=float(hf.get("embedding_multiplier", 1.0)),
+        attn_scale=float(hf["attention_multiplier"]),
+        residual_scale=float(hf.get("residual_multiplier", 1.0)),
+        logits_divisor=float(hf.get("logits_scaling", 1.0)),
+        dtype=dtype or jnp.bfloat16)
+
+
+def _granite_hybrid_tree(r, cfg) -> Dict[str, Any]:
+    """granitemoehybrid -> flax tree, by ``GRANITE_HYBRID_WEIGHT_NAMES``;
+    ``r`` has ``get(name)`` (a ``_ShardReader``, or any mapping of published
+    names to arrays)."""
+    H, M = cfg.hidden_size, cfg.mlp_dim
+    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+
+    def lin(name):                       # torch Linear: [out, in]
+        return np.asarray(r.get(name)).T
+
+    bb: Dict[str, Any] = {
+        "wte": np.asarray(r.get("model.embed_tokens.weight")),
+        "final_norm": {"scale": np.asarray(r.get("model.norm.weight"))}}
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        w_in = lin(p + "shared_mlp.input_linear.weight")      # [H, 2M]
+        blk: Dict[str, Any] = {
+            "Norm_0": {"scale": np.asarray(
+                r.get(p + "input_layernorm.weight"))},
+            "Norm_1": {"scale": np.asarray(
+                r.get(p + "post_attention_layernorm.weight"))},
+            "MLP_0": {"wg": w_in[:, :M], "wi": w_in[:, M:],
+                      "wo": lin(p + "shared_mlp.output_linear.weight")}}
+        if cfg.is_scan_layer(i):
+            m = p + "mamba."
+            mixer = {
+                "w_in": lin(m + "in_proj.weight"),
+                "conv_w": np.asarray(r.get(m + "conv1d.weight"))[:, 0, :],
+                "w_out": lin(m + "out_proj.weight"),
+                "norm": np.asarray(r.get(m + "norm.weight")),
+                **{k: np.asarray(r.get(m + k))
+                   for k in ("dt_bias", "A_log", "D")}}
+            if cfg.ssm_conv_bias:
+                mixer["conv_b"] = np.asarray(r.get(m + "conv1d.bias"))
+            blk["Mamba2Mixer_0"] = mixer
+        else:
+            a = p + "self_attn."
+            blk["Attention_0"] = {
+                "wq": lin(a + "q_proj.weight").reshape(H, nh, hd),
+                "wk": lin(a + "k_proj.weight").reshape(H, nkv, hd),
+                "wv": lin(a + "v_proj.weight").reshape(H, nkv, hd),
+                "wo": lin(a + "o_proj.weight").reshape(nh, hd, H)}
+        bb[f"block_{i}"] = blk
+    return {"backbone": bb}
+
+
+def granite_hybrid_state_dict(cfg, params) -> Dict[str, Any]:
+    """The GPT parameter tree of a granitemoehybrid model under its
+    published tensor names and shapes: ``_granite_hybrid_tree``'s inverse."""
+    bb = params["backbone"]
+    H = cfg.hidden_size
+    out = {"model.embed_tokens.weight": np.asarray(bb["wte"]),
+           "model.norm.weight": np.asarray(bb["final_norm"]["scale"])}
+    for i in range(cfg.num_layers):
+        blk, p = bb[f"block_{i}"], f"model.layers.{i}."
+        mlp = blk["MLP_0"]
+        out[p + "input_layernorm.weight"] = np.asarray(blk["Norm_0"]["scale"])
+        out[p + "post_attention_layernorm.weight"] = np.asarray(
+            blk["Norm_1"]["scale"])
+        out[p + "shared_mlp.input_linear.weight"] = np.concatenate(
+            [np.asarray(mlp["wg"]), np.asarray(mlp["wi"])], axis=1).T
+        out[p + "shared_mlp.output_linear.weight"] = np.asarray(mlp["wo"]).T
+        if cfg.is_scan_layer(i):
+            s, m = blk["Mamba2Mixer_0"], p + "mamba."
+            out[m + "in_proj.weight"] = np.asarray(s["w_in"]).T
+            out[m + "out_proj.weight"] = np.asarray(s["w_out"]).T
+            out[m + "conv1d.weight"] = np.asarray(s["conv_w"])[:, None, :]
+            out[m + "norm.weight"] = np.asarray(s["norm"])
+            if cfg.ssm_conv_bias:
+                out[m + "conv1d.bias"] = np.asarray(s["conv_b"])
+            for k in ("dt_bias", "A_log", "D"):
+                out[m + k] = np.asarray(s[k])
+        else:
+            a, at = blk["Attention_0"], p + "self_attn."
+            for k in "qkv":
+                out[f"{at}{k}_proj.weight"] = np.asarray(
+                    a["w" + k]).reshape(H, -1).T
+            out[at + "o_proj.weight"] = np.asarray(a["wo"]).reshape(-1, H).T
+    return out
+
+
 def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
                    dtype=None):
     """Build a GPTConfig from ``<model_path>/config.json``.
@@ -527,6 +698,8 @@ def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
         return deepseek_v3_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     if hf.get("model_type") == "dots3_note":
         return dots3_note_config(hf, max_seq_len=max_seq_len, dtype=dtype)
+    if hf.get("model_type") == "granitemoehybrid":
+        return granite_hybrid_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     arch = _arch_of(hf)
 
     if arch in _LLAMA_LIKE:
@@ -1864,6 +2037,8 @@ def load_hf_checkpoint(model_path: str, *, max_seq_len: Optional[int] = None,
     cfg = config_from_hf(model_path, max_seq_len=max_seq_len, dtype=dtype)
     if cfg.mla:
         return cfg, _deepseek_v3_tree(_ShardReader(model_path), cfg)
+    if cfg.layer_types:
+        return cfg, _granite_hybrid_tree(_ShardReader(model_path), cfg)
     if cfg.moe_router == "sigmoid":
         raise NotImplementedError(
             "afmoe checkpoints: the config maps (afmoe_config) and "

@@ -520,6 +520,30 @@ class InferenceEngineV2:
         # statics of every step program; {} for a plain model, whose
         # programs are then traced exactly as before
         self._model_static: Dict[str, Any] = {}
+        if model_cfg.scan_layers:
+            # scan layers keep one fixed-size state a sequence beside the
+            # attention layers' pages (model.py PagedKVCache.ssm / .conv).
+            # What cannot be right beside them yet:
+            for what, why in (
+                    (sm.prefix_cache, "the prefix cache: a shared prefix "
+                     "has pages and no state to resume the scan from (it "
+                     "needs state snapshots at page boundaries)"),
+                    (draft_model is not None, "speculative decoding: a "
+                     "rejected draft token has already moved the state, "
+                     "which has no rollback"),
+                    (self.mesh is not None, "a tp mesh: the state pool's "
+                     "heads and the mixer's projections are not sharded"),
+                    (self.config.adapters.enabled, "LoRA adapter pages: a "
+                     "scan layer has no q/v projections for their deltas"),
+                    (sm.kv_quant, "kv_quant: the pools are created "
+                     "unquantised beside the state"),
+                    (self.kv_window or model_cfg.mla, "window page groups "
+                     "or latent pages: the state pool is built beside the "
+                     "one plain page group only")):
+                if what:
+                    raise NotImplementedError(
+                        f"scan layers (layer_types) keep a recurrent state "
+                        f"a sequence, which is not built with {why}")
         if model_cfg.mla:
             # latent attention: pools of latent rows (model.py
             # PagedKVCache), read absorbed, and with a learned selection
@@ -607,8 +631,9 @@ class InferenceEngineV2:
                 self.cache = PagedKVCache.create_grouped(
                     model_cfg, num_blocks, window_blocks, eff_bs, dt)
             else:
-                self.cache = PagedKVCache.create(model_cfg, num_blocks, eff_bs,
-                                                 dt, quant=sm.kv_quant)
+                self.cache = PagedKVCache.create(
+                    model_cfg, num_blocks, eff_bs, dt, quant=sm.kv_quant,
+                    slots=sm.max_tracked_sequences)
         # MoE counter vectors of dispatches not yet read back (device
         # values the step programs return; folded into the telemetry once
         # ready, never waited for: _fold_moe_stats)
@@ -696,8 +721,15 @@ class InferenceEngineV2:
         self._serve_ctx: Optional[Dict[str, Any]] = None
         self.heartbeat_fn = None
         self._block_size = eff_bs
+        self._one_table_width = bool(model_cfg.index_topk
+                                     or model_cfg.scan_layers)   # _buckets
         self.telemetry.set_kv_bytes_per_token(
             self.kv_bytes_per_token(), **self.kv_bytes_by_group())
+        if model_cfg.scan_layers:
+            c = self.cache
+            self.telemetry.set_scan_state(
+                len(model_cfg.scan_layers),
+                (c.ssm.nbytes + c.conv.nbytes) // sm.max_tracked_sequences)
         # ---- multi-tenant LoRA adapter pool (serving/adapters.py): A/B
         # pages live as block-granular refcounted residents of the SAME
         # allocator as the KV blocks, so adapters and KV contend under one
@@ -892,6 +924,7 @@ class InferenceEngineV2:
         for _, toks in schedule:
             self.telemetry.tokens("prefill" if len(toks) > 1 else "decode",
                                   len(toks))
+        self.telemetry.ssm_rows([len(t) for _, t in schedule])
         rb = build_ragged_batch(schedule, self.state,
                                 sm.max_ragged_batch_size, sm.max_q_per_seq)
         logits = self._run(rb, with_routes)
@@ -913,11 +946,15 @@ class InferenceEngineV2:
         between decode bursts) must not pay a forward padded to the full
         ragged budget.  ≤ log2(MB) × log2(budget) compiled programs total."""
         mb = rb.block_table.shape[1]
-        if not self.model_config.index_topk:
+        if not self._one_table_width:
             # (a model that selects its keys keeps ONE table width, the
             # whole table's: its full layers score and sort over their
             # rows' own contexts at run time, index_select(width=), so a
-            # narrower table would buy programs and save no work)
+            # narrower table would buy programs and save no work; so does a
+            # model with scan layers: the width is layout for its few
+            # attention layers, whose kernels walk each slot's pages to its
+            # own length, and every narrower table would be one more
+            # program of all its layers to trace, lower and compile)
             mb_used = max(1, -(-int(rb.kv_len.max()) // self._block_size))
             mb = min(1 << (mb_used - 1).bit_length(), mb)
         return mb, self._token_bucket(rb.total_tokens, rb.tokens.shape[0])
@@ -939,7 +976,8 @@ class InferenceEngineV2:
         cfg = self.model_config
         cfg = cfg.for_layer(next(
             (i for i in range(cfg.num_layers)
-             if cfg.index_topk and cfg.window_for_layer(i)), 0))
+             if cfg.index_topk and cfg.window_for_layer(i)),
+            cfg.attention_layers[0]))
         nkv, _, vd, _ = _attn_geometry(cfg)
         nb = self._token_bucket(sum(rows), sm.max_ragged_batch_size)
         Q = min(sm.max_q_per_seq, nb)
@@ -1261,6 +1299,7 @@ class InferenceEngineV2:
         with stel.span("build"):
             host, note = self._slot_schedule(reqs, steps)
             self._fold_moe_stats()
+            stel.ssm_rows([1] * len(reqs), steps)
         key = ("burst", steps, gen.do_sample, gen.top_k)
         if key not in self._steps:
             self._steps[key] = jax.jit(
@@ -1319,6 +1358,7 @@ class InferenceEngineV2:
             self._fold_moe_stats()
             rows = [len(t) for t in toks_np]
             mixed = max(rows) > 1
+            stel.ssm_rows(rows)
             if mixed:
                 stel.mixed_slots(rows, *self._prefill_items(rows))
             note = {"seqs": len(schedule), "tokens": sum(rows)}
@@ -1520,7 +1560,8 @@ class InferenceEngineV2:
             itemsize = 2
         row = (mc.latent_page_dim if mc.mla
                else 2 * mc.kv_heads * mc.head_dim)
-        return int(mc.num_layers * self._block_size * row * itemsize)
+        return int(len(mc.attention_layers) * self._block_size * row
+                   * itemsize)
 
     def kv_bytes_per_token(self) -> int:
         """Device bytes the pool stores for one cached token over all layers
@@ -1532,7 +1573,9 @@ class InferenceEngineV2:
             return sum(self.kv_bytes_by_group().values())
         if self.kv_window:
             return self.kv_block_bytes() // self._block_size
-        pool = sum(a.size * a.dtype.itemsize for a in self.cache
+        c = self.cache      # (the pages: a scan layer's state is no token's)
+        pool = sum(a.size * a.dtype.itemsize
+                   for a in (c.k, c.v, c.k_scale, c.v_scale)
                    if a is not None)
         return int(pool // (self.cache.k.shape[1] * self._block_size))
 

@@ -73,6 +73,22 @@ def kv_block_size_for(cfg: GPTConfig, requested: int,
     return requested
 
 
+def kv_pool_layers(cfg: GPTConfig) -> int:
+    """How many layers own KV pages, which is how many the pool holds: the
+    attention layers (a scan layer writes none)."""
+    return len(cfg.attention_layers)
+
+
+def kv_base(cfg: GPTConfig, li: int, NB: int, kv_layout=None):
+    """(first page, page group) of attention layer ``li`` in the flat pool:
+    ``kv_page_layout``'s entry where the pool has two groups, else the
+    layer's place among the layers that own pages times the ``NB`` pages
+    each holds."""
+    if kv_layout is not None:
+        return kv_layout[li]
+    return cfg.attention_layers.index(li) * NB, 0
+
+
 class PagedKVCache(NamedTuple):
     """Per-layer paged KV arrays stacked on a leading layer axis (reference:
     KVCacheManager kv_cache.py).
@@ -117,7 +133,24 @@ class PagedKVCache(NamedTuple):
     INDEX-KEY pool of the global layers where they select their keys
     (``cfg.index_topk``): ``[1, pages, 1, block_size, index_head_dim]``,
     page for page beside ``k`` and addressed by the global group's own block
-    table: a third array, no third allocator group."""
+    table: a third array, no third allocator group.
+
+    Scan layers (``cfg.is_scan_layer``) write no pages: ``k``/``v`` hold the
+    attention layers only (``kv_pool_layers``; ``kv_base`` says where each
+    begins), and beside them lie the residents that do not grow, one
+    fixed-size slot a tracked sequence, addressed by the sequence's slot:
+    ``ssm [scan layers, slots, heads / k, state, k * head_dim]`` in float32
+    (a recurrence of thousands of steps rounds at every one; ``k`` heads
+    side by side on the lanes, ``ops/ssm_scan.py`` "the packed state pool":
+    64 heads of 64 over a state of 128 are ``[32, 128, 128]``) and ``conv
+    [scan layers, slots, (taps - 1) * channels]``, the conv's last rows one
+    after the other, in the compute dtype (ONE row a slot: as ``[taps - 1,
+    channels]`` a slot's tile is padded fivefold on the chip, and with the
+    slots behind the taps a scatter by slot re-lays the whole pool, 8 ms of
+    a mixed step on the v5e; PERF.md section 6, PR 44).  A slot is never
+    cleared: a sequence's row at position 0 starts from zero whatever the
+    slot held (``_scan_plan``), which is also how a preempted sequence is
+    recomputed."""
 
     k: jax.Array
     v: Optional[jax.Array]
@@ -125,6 +158,8 @@ class PagedKVCache(NamedTuple):
     v_scale: Optional[jax.Array] = None
     kw: Optional[jax.Array] = None
     ki: Optional[jax.Array] = None
+    ssm: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
 
     @property
     def quantized(self) -> bool:
@@ -132,24 +167,42 @@ class PagedKVCache(NamedTuple):
 
     @classmethod
     def create(cls, cfg: GPTConfig, num_blocks: int, block_size: int, dtype,
-               quant: Optional[str] = None):
+               quant: Optional[str] = None, slots: int = 0):
+        """``slots``: the tracked sequences, each of which owns one state
+        slot in every scan layer (a model without scan layers has none)."""
+        layers = kv_pool_layers(cfg)
+        scan = {}
+        if cfg.scan_layers:
+            if quant is not None or cfg.mla:
+                raise NotImplementedError(
+                    "scan layers beside kv_quant or latent pages are not "
+                    "built")
+            from deepspeed_tpu.ops.ssm_scan import packed_state_shape
+            n = len(cfg.scan_layers)
+            scan = dict(
+                ssm=jnp.zeros((n, slots) + packed_state_shape(
+                    cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                    jnp.float32),
+                conv=jnp.zeros((n, slots, (cfg.ssm_conv - 1)
+                                * cfg.ssm_conv_dim), dtype))
         if cfg.mla:
             if quant is not None:
                 raise NotImplementedError(
                     "kv_quant over latent pages (kv_lora_rank) is not built")
-            return cls(k=jnp.zeros((cfg.num_layers, num_blocks, 1, block_size,
+            return cls(k=jnp.zeros((layers, num_blocks, 1, block_size,
                                     cfg.latent_page_dim), dtype), v=None)
         if kv_major_layout(cfg):
-            shape = (cfg.num_layers, num_blocks, cfg.kv_heads, cfg.head_dim,
+            shape = (layers, num_blocks, cfg.kv_heads, cfg.head_dim,
                      block_size)
         else:
-            shape = (cfg.num_layers, num_blocks, cfg.kv_heads, block_size,
+            shape = (layers, num_blocks, cfg.kv_heads, block_size,
                      cfg.head_dim)
         if quant is None:
-            return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+            return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+                       **scan)
         if quant != "int8":
             raise ValueError(f"unsupported kv_quant {quant!r}; use 'int8'")
-        sshape = (cfg.num_layers, num_blocks, cfg.kv_heads, block_size)
+        sshape = (layers, num_blocks, cfg.kv_heads, block_size)
         return cls(k=jnp.zeros(shape, jnp.int8),
                    v=jnp.zeros(shape, jnp.int8),
                    k_scale=jnp.zeros(sshape, jnp.float32),
@@ -231,12 +284,12 @@ def _block_residual(blk, x, h, attn_delta, cfg, mesh=None, live=None,
     if cfg.parallel_block:
         h_mlp = _norm(blk["Norm_1"], x, cfg) if cfg.parallel_norms == 2 else h
         return x + attn_delta + _ffn(blk, h_mlp, cfg, mesh=mesh)
-    x = x + attn_delta
+    x = x + _scale_branch(attn_delta, cfg)
     f = _ffn(blk, _norm(blk["Norm_1"], x, cfg), cfg, mesh=mesh, live=live,
              stats=stats, routes=routes)
     if cfg.sandwich_norm:
         f = _norm(blk["post_ffn_norm"], f, cfg)
-    return x + f
+    return x + _scale_branch(f, cfg)
 
 
 def _w(p, dtype):
@@ -309,6 +362,8 @@ def _logits_out(params, bb, x, cfg, dtype, mesh=None):
     else:
         logits = _wmm(x, params["lm_head"], dtype,
                       mesh=mesh, wspec="col").astype(jnp.float32)
+    if cfg.logits_divisor:
+        logits = logits / cfg.logits_divisor
     if cfg.unembed_bias:
         logits = logits + params["lm_head_bias"].astype(jnp.float32)
     return logits
@@ -938,6 +993,204 @@ def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
         return jnp.where(valid[:, None, None], o, 0)
 
 
+# ---------------------------------------------------------------- scan layers
+# A scan layer (models/gpt.py Mamba2Mixer) in the step programs.  Scopes nest
+# inside the attention scopes, so that a reader that knows only those still
+# files every operation: the input projection under attn_qkv/ssm_in_proj, the
+# conv and the scan under attn_kernel/ssm_conv and attn_kernel/ssm_scan, the
+# write-back of a slot's state and conv tail under kv_write/ssm_scan, the
+# gated norm and the output projection under attn_out/ssm_gate_norm.
+
+SCAN_GROUP = 4      # prompt chunks scanned together in a mixed step's pass
+SCAN_CHUNK = 128    # rows a chunk of the mixed step's scan, at most: the
+#                     decay matrix [slots, heads, chunk, chunk] float32 is
+#                     written and read once a chunk, and grows with the chunk
+
+
+class _ScanPlan(NamedTuple):
+    """Which slots of a mixed step take which path through a scan layer,
+    the same for every layer."""
+    one: jnp.ndarray      # [S] the slot holds one row: the recurrence
+    fresh: jnp.ndarray    # [S] its first row is position 0: from zero
+    order: jnp.ndarray    # [S + pad] slots, those with more rows first
+    n_many: jnp.ndarray   # [] how many slots hold more than one row
+    row_one: jnp.ndarray  # [N] the row's slot holds one row
+    row_slot: jnp.ndarray  # [N] the row's slot, 0 for padding
+
+
+def _scan_plan(rows: "_MixedRows") -> _ScanPlan:
+    S = rows.first_row.shape[0]
+    with jax.named_scope("attn_kernel"), jax.named_scope("ssm_scan"):
+        one = rows.q_counts == 1
+        many = rows.q_counts > 1
+        fresh = (rows.q_counts > 0) & (rows.kv_len == rows.q_counts)
+        order = jnp.argsort(jnp.where(many, 0, 1), stable=True).astype(
+            jnp.int32)
+        order = jnp.pad(order, (0, -S % SCAN_GROUP))
+        slot = jnp.where(rows.scat_slot < S, rows.scat_slot, 0)
+        return _ScanPlan(one, fresh, order, jnp.sum(many), one[slot]
+                         & (rows.scat_slot < S), slot)
+
+
+def _scan_in(mp, h, cfg: GPTConfig, mesh=None):
+    """``ssm_in_proj`` (inside ``attn_qkv``): rows ``h [R, H]`` -> (z [R,
+    inner], xBC [R, channels], dt [R, heads] float32 after its softplus)."""
+    from deepspeed_tpu.models.gpt import ssm_split
+    with jax.named_scope("ssm_in_proj"):
+        zxd = _wmm(h, mp["w_in"], h.dtype, mesh=mesh, wspec="col")
+        a, b = ssm_split(cfg)
+        dt = jax.nn.softplus(zxd[:, b:].astype(jnp.float32)
+                             + mp["dt_bias"].astype(jnp.float32))
+        return zxd[:, :a], zxd[:, a:b], dt
+
+
+def _scan_xbc(xbc, cfg: GPTConfig):
+    """The conv's output rows ``[..., channels]`` as the scan's (x [..., h,
+    p], B [..., g, n], C [..., g, n])."""
+    inner, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
+    lead = xbc.shape[:-1]
+    groups = lead + (cfg.ssm_groups, cfg.ssm_state)
+    return (xbc[..., :inner].reshape(
+                lead + (cfg.ssm_heads, cfg.ssm_head_dim)),
+            xbc[..., inner:inner + gn].reshape(groups),
+            xbc[..., inner + gn:].reshape(groups))
+
+
+def _scan_out(mp, y, z, cfg: GPTConfig, mesh=None):
+    """``ssm_gate_norm`` (inside ``attn_out``): the gate, the norm over the
+    inner width and the output projection of rows ``y [R, inner]``."""
+    from deepspeed_tpu.models.gpt import ssm_gate_norm
+    from deepspeed_tpu.ops.norms import RMS_EPS
+    with jax.named_scope("attn_out"), jax.named_scope("ssm_gate_norm"):
+        g = ssm_gate_norm(y, z, mp["norm"], cfg.norm_eps or RMS_EPS)
+        return _wmm(g.astype(z.dtype), mp["w_out"], z.dtype, mesh=mesh,
+                    wspec="row")
+
+
+def _scan_params(mp):
+    return (-jnp.exp(mp["A_log"].astype(jnp.float32)), mp["D"],
+            mp["conv_w"], mp.get("conv_b"))
+
+
+def _conv_tail(conv, si, slots, fresh, cfg: GPTConfig):
+    """The conv tails ``[len(slots), taps - 1, channels]`` of ``slots`` in
+    scan layer ``si`` (all slots: None), zero where ``fresh``."""
+    rows = conv[si] if slots is None else conv[si, slots]
+    tail = rows.reshape(rows.shape[0], cfg.ssm_conv - 1, cfg.ssm_conv_dim)
+    return jnp.where(fresh[:, None, None], 0, tail)
+
+
+def _scan_rows_one(mp, xbc, dt, scan, si, active, fresh, cfg: GPTConfig):
+    """One row a slot through scan layer ``si`` of the pool: the conv from
+    the slot's tail, the recurrence from its state (from zero where
+    ``fresh``), both written back for the ``active`` slots.  ``xbc [S,
+    channels]``, ``dt [S, heads]`` -> (y [S, inner] float32, scan')."""
+    from deepspeed_tpu import ops
+    ssm, conv = scan
+    A, D, cw, cb = _scan_params(mp)
+    with jax.named_scope("attn_kernel"):
+        with jax.named_scope("ssm_conv"):
+            out, tail = ops.causal_conv1d(
+                xbc[:, None], cw, cb, _conv_tail(conv, si, None, fresh, cfg),
+                active.astype(jnp.int32))
+        with jax.named_scope("ssm_scan"):
+            x, B, C = _scan_xbc(out[:, 0], cfg)
+            y, ssm = ops.ssm_state_update(x, dt, A, B, C, D, ssm, si, active,
+                                          fresh)
+    with jax.named_scope("kv_write"), jax.named_scope("ssm_scan"):
+        conv = conv.at[si].set(tail.reshape(tail.shape[0], -1))
+    return y.reshape(y.shape[0], -1), (ssm, conv)
+
+
+def _scan_rows_many(mp, xbc, dt, scan, si, plan: _ScanPlan,
+                    rows: "_MixedRows", cfg: GPTConfig, Q: int):
+    """The prompt chunks of a mixed step through scan layer ``si``:
+    ``SCAN_GROUP`` slots a pass, as many passes as the step's slots with
+    more than one row take (a loop whose trip count the device reads).  A
+    pass gathers its slots' rows out of the token-major ``xbc [N,
+    channels]`` / ``dt [N, heads]`` ONCE into ``[SCAN_GROUP, Q, ...]``,
+    convolves them from the slots' tails and scans them from the slots'
+    states, scatters ``y`` back and writes tails and states in place.
+    -> (y [N, inner] float32, zero on rows of no prompt chunk, scan')."""
+    from deepspeed_tpu import ops
+    from deepspeed_tpu.ops.ssm_scan import (pack_state, segment_rows,
+                                            unpack_state)
+    A, D, cw, cb = _scan_params(mp)
+    S = rows.first_row.shape[0]
+    N = xbc.shape[0]
+    G = SCAN_GROUP
+    lanes = jnp.arange(G, dtype=jnp.int32)
+
+    def one_pass(carry):
+        i, y, ssm, conv = carry
+        with jax.named_scope("attn_kernel"):
+            with jax.named_scope("ssm_conv"):
+                slots = jax.lax.dynamic_slice_in_dim(plan.order, i * G, G)
+                live = i * G + lanes < plan.n_many
+                count = jnp.where(live, rows.q_counts[slots], 0)
+                read, write, _ = segment_rows(
+                    (rows.first_row[slots], count), Q, N)
+                fresh = plan.fresh[slots]
+                out, tail = ops.causal_conv1d(
+                    xbc[read], cw, cb, _conv_tail(conv, si, slots, fresh, cfg),
+                    count)
+            with jax.named_scope("ssm_scan"):
+                state = jnp.where(
+                    fresh[:, None, None, None], 0.0,
+                    unpack_state(ssm[si, slots], cfg.ssm_head_dim))
+                x, B, C = _scan_xbc(out, cfg)
+                y_pass, state = ops.ssm_chunk_scan(
+                    x, dt[read], A, B, C, D, state, (None, count),
+                    chunk=min(cfg.ssm_chunk, SCAN_CHUNK))
+                y = y.at[write].set(y_pass.reshape(G, Q, -1), mode="drop")
+        with jax.named_scope("kv_write"), jax.named_scope("ssm_scan"):
+            dst = jnp.where(live, slots, S)
+            ssm = ssm.at[si, dst].set(pack_state(state), mode="drop")
+            conv = conv.at[si, dst].set(tail.reshape(G, -1), mode="drop")
+        return i + 1, y, ssm, conv
+
+    with jax.named_scope("attn_kernel"), jax.named_scope("ssm_scan"):
+        y0 = jnp.zeros((N, cfg.ssm_inner), jnp.float32)
+        _, y, ssm, conv = jax.lax.while_loop(
+            lambda c: c[0] * G < plan.n_many, one_pass,
+            (jnp.int32(0), y0) + tuple(scan))
+    return y, (ssm, conv)
+
+
+def _scan_mixed(mp, h, scan, si, plan: _ScanPlan, rows: "_MixedRows", *,
+                cfg: GPTConfig, Q: int, mesh=None):
+    """A scan layer's mixer on a mixed step's token-major rows ``h [N, H]``:
+    a slot with one row (a decode row riding the step, a prompt's one-token
+    tail) takes the recurrence, a slot with more the chunked scan.  ->
+    (the mixer's output [N, H], scan')."""
+    with jax.named_scope("attn_qkv"):
+        z, xbc, dt = _scan_in(mp, h, cfg, mesh=mesh)
+    first = rows.first_row
+    y_one, scan = _scan_rows_one(mp, xbc[first], dt[first], scan, si,
+                                 plan.one, plan.fresh, cfg)
+    y_many, scan = _scan_rows_many(mp, xbc, dt, scan, si, plan, rows, cfg, Q)
+    with jax.named_scope("attn_kernel"), jax.named_scope("ssm_scan"):
+        y = jnp.where(plan.row_one[:, None], y_one[plan.row_slot], y_many)
+    return _scan_out(mp, y, z, cfg, mesh=mesh), scan
+
+
+def _scan_decode(mp, h, scan, si, active, token_pos, *, cfg: GPTConfig,
+                 mesh=None):
+    """A scan layer's mixer in a decode step: one row ``h [S, H]`` a slot."""
+    with jax.named_scope("attn_qkv"):
+        z, xbc, dt = _scan_in(mp, h, cfg, mesh=mesh)
+    y, scan = _scan_rows_one(mp, xbc, dt, scan, si, active,
+                             active & (token_pos == 0), cfg)
+    return _scan_out(mp, y, z, cfg, mesh=mesh), scan
+
+
+def _scale_branch(delta, cfg: GPTConfig):
+    """A branch's output times ``residual_scale`` where the model has one."""
+    if cfg.residual_scale is None:
+        return delta
+    return delta * jnp.asarray(cfg.residual_scale, delta.dtype)
+
+
 def _window_latent_scope(cfg: GPTConfig, window):
     """Scope ``window_latent`` around a latent WINDOW layer's kernels (inside
     ``attn_kernel``), nothing around any other layer's."""
@@ -1031,7 +1284,7 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     attend = {kind: jax.jit(named_partial(
         _mixed_attention, cfg=kind[0], Q=Q, window=kind[1], mesh=mesh))
         for kind in {(cfg.for_layer(i), cfg.window_for_layer(i))
-                     for i in range(cfg.num_layers)}}
+                     for i in cfg.attention_layers}}
     plans = tuple(_write_plan(t, scat_slot, token_pos, block_size, Q, km)
                   for t in tables)
 
@@ -1050,8 +1303,16 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     # ways a chunk's rows can read their keys are in it)
     selected = {lc: jax.jit(named_partial(
         _selected_attention, cfg=lc, block_size=block_size, max_rows=Q))
-        for lc in {cfg.for_layer(i) for i in range(cfg.num_layers)}
+        for lc in {cfg.for_layer(i) for i in cfg.attention_layers}
         if lc.index_topk and select}
+    # scan layers: the state and conv-tail pools, and which slots scan how
+    scan = (cache.ssm, cache.conv)
+    if cfg.scan_layers:
+        plan = _scan_plan(rows)
+        # (one traced and lowered mixer for all the scan layers too: the
+        # layer's place in the pools is an operand)
+        scan_mixer = jax.jit(named_partial(_scan_mixed, cfg=cfg, Q=Q,
+                                           mesh=mesh))
 
     # multi-tenant LoRA (static trace-time branch — adapter-less engines
     # send no "lora" key and trace the identical program): per-TOKEN
@@ -1065,6 +1326,16 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
 
     for li in range(cfg.num_layers):
         blk = bb[f"block_{li}"]
+        if cfg.is_scan_layer(li):
+            with jax.named_scope("attn_qkv"):
+                h = _norm(blk["Norm_0"], x, cfg)
+            delta, scan = scan_mixer(
+                blk["Mamba2Mixer_0"], h, scan,
+                jnp.int32(cfg.scan_layers.index(li)), plan, rows)
+            with jax.named_scope("mlp"):
+                x = _block_residual(blk, x, h, delta, cfg, mesh=mesh,
+                                    live=valid, stats=stats, routes=routes)
+            continue
         ap, np_ = blk["Attention_0"], blk["Norm_0"]
         lc = cfg.for_layer(li)           # this layer's attention geometry
         with jax.named_scope("attn_qkv"):
@@ -1085,7 +1356,7 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                             seq_lens=kv_len[jnp.clip(token_slot, 0)][None])
                 q, k = q[0], k[0]
 
-        base, grp = (li * NB, 0) if kv_layout is None else kv_layout[li]
+        base, grp = kv_base(cfg, li, NB, kv_layout)
         own = grp == 1 and flat_kw is not None    # the window group's pool
         pool, flat_v_all, flat_ks, flat_vs = _kv_write(
             flat_kw if own else flat_k_all, flat_v_all, flat_ks, flat_vs, k,
@@ -1120,7 +1391,7 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
             jnp.arange(N, dtype=jnp.int32), mode="drop")
     logits = _head(params, bb, x, cfg, mesh=mesh, rows=last_flat)  # [S, V]
     cache = _rebuild_cache(cache, flat_k_all, flat_v_all, flat_ks, flat_vs,
-                           flat_kw, flat_ki)
+                           flat_kw, flat_ki, scan)
     out = (logits, cache) + ((sum(stats),) if moe_stats else ())
     # [expert layers, N, k]: the experts each row's router chose
     return out + ((jnp.stack(routes),) if moe_routes else ())
@@ -1130,7 +1401,7 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                  block_table, cfg: GPTConfig, block_size: int, mesh=None,
                  flat_ks=None, flat_vs=None, lora=None, adapter_slot=None,
                  kv_layout=None, moe_stats: bool = False, routes=None,
-                 groups=(None, None)):
+                 groups=(None, None), scan=(None, None)):
     """One decode micro-step: writes each active slot's kv into its page and
     attends over exactly that slot's pages via the paged-attention op
     (ops/paged_attention.py — Pallas kernel on TPU, masked-gather XLA
@@ -1144,15 +1415,16 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     window)).  ``groups``: the window group's own pool and the index-key
     pool (``_flat_group_views``) where the cache has them.  Returns the
     updated flat views (incl. scales), the step's MoE counters (None unless
-    ``moe_stats``) and the updated ``groups``."""
+    ``moe_stats``), the updated ``groups`` and the updated ``scan``: the
+    scan layers' state and conv-tail pools (``PagedKVCache.ssm`` /
+    ``.conv``), each (None, None) for a model without."""
     from deepspeed_tpu import ops
     bb = params["backbone"]
     dtype = cfg.dtype
     tables = block_table if isinstance(block_table, tuple) else (block_table,)
     stats = [] if moe_stats else None
     S = tokens.shape[0]
-    L = cfg.num_layers
-    NB = flat_k_all.shape[0] // L
+    NB = flat_k_all.shape[0] // kv_pool_layers(cfg)
     km = kv_major_layout(cfg)
     flat_kw, flat_ki = groups
     select = tables[0].shape[1] * block_size > cfg.index_topk > 0
@@ -1168,9 +1440,21 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
         # decode rows ARE slots: mask inactive lanes to the identity slot
         # so a recycled lane's stale selection never computes a delta
         lora_ids = jnp.where(active, adapter_slot, 0)
+    if cfg.scan_layers:        # one traced and lowered mixer for them all
+        scan_mixer = jax.jit(named_partial(_scan_decode, cfg=cfg, mesh=mesh))
 
     for li in range(cfg.num_layers):
         blk = bb[f"block_{li}"]
+        if cfg.is_scan_layer(li):
+            with jax.named_scope("attn_qkv"):
+                h = _norm(blk["Norm_0"], x, cfg)
+            delta, scan = scan_mixer(
+                blk["Mamba2Mixer_0"], h, scan,
+                jnp.int32(cfg.scan_layers.index(li)), active, token_pos)
+            with jax.named_scope("mlp"):
+                x = _block_residual(blk, x, h, delta, cfg, mesh=mesh,
+                                    live=active, stats=stats, routes=routes)
+            continue
         ap = blk["Attention_0"]
         lc = cfg.for_layer(li)           # this layer's attention geometry
         nh = lc.num_heads
@@ -1192,7 +1476,7 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                             seq_lens=kv_len[:, None])
                 q, k = q[:, 0], k[:, 0]
 
-        base, grp = (li * NB, 0) if kv_layout is None else kv_layout[li]
+        base, grp = kv_base(cfg, li, NB, kv_layout)
         own = grp == 1 and flat_kw is not None    # the window group's pool
         pool, flat_v_all, flat_ks, flat_vs = _kv_write(
             flat_kw if own else flat_k_all, flat_v_all, flat_ks, flat_vs, k,
@@ -1236,7 +1520,7 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
 
     logits = _head(params, bb, x, cfg, mesh=mesh)                  # [S, V]
     return (logits, flat_k_all, flat_v_all, flat_ks, flat_vs,
-            sum(stats) if moe_stats else None, (flat_kw, flat_ki))
+            sum(stats) if moe_stats else None, (flat_kw, flat_ki), scan)
 
 
 def _flat_cache_views(cache: PagedKVCache, cfg: GPTConfig):
@@ -1274,9 +1558,10 @@ def _flat_group_views(cache: PagedKVCache):
 
 
 def _rebuild_cache(cache: PagedKVCache, fk, fv, fks, fvs, fkw=None,
-                   fki=None) -> PagedKVCache:
+                   fki=None, scan=(None, None)) -> PagedKVCache:
     with jax.named_scope("kv_pool"):
         return PagedKVCache(
+            ssm=scan[0], conv=scan[1],
             kw=None if fkw is None else fkw.reshape(cache.kw.shape),
             ki=None if fki is None else fki.reshape(cache.ki.shape),
             k=fk.reshape(cache.k.shape),
@@ -1316,33 +1601,34 @@ def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
                             batch["tokens0"])
 
     def step(carry, _):
-        flat_k, flat_v, flat_ks, flat_vs, groups, tokens, pos, rng = carry
+        (flat_k, flat_v, flat_ks, flat_vs, groups, scan, tokens, pos,
+         rng) = carry
         (logits, flat_k, flat_v, flat_ks, flat_vs, stats,
-         groups) = _decode_core(
+         groups, scan) = _decode_core(
             params, flat_k, flat_v, tokens, active, pos, bt, cfg, block_size,
             mesh=mesh, flat_ks=flat_ks, flat_vs=flat_vs, lora=lora,
             adapter_slot=adapter_slot, kv_layout=kv_layout,
-            moe_stats=moe_stats, groups=groups)
+            moe_stats=moe_stats, groups=groups, scan=scan)
         with jax.named_scope("sample"):
             rng, sub = jax.random.split(rng)
             nxt = sample_fn(logits, sub, temperature=temperature,
                             top_p=top_p)
             nxt = nxt.astype(jnp.int32)
             pos = pos + 1
-        return ((flat_k, flat_v, flat_ks, flat_vs, groups, nxt, pos, rng),
-                (nxt, stats))
+        return ((flat_k, flat_v, flat_ks, flat_vs, groups, scan, nxt, pos,
+                 rng), (nxt, stats))
 
-    carry = (flat_k, flat_v, flat_ks, flat_vs, groups, tokens0,
-             batch["pos0"], rng)
+    carry = (flat_k, flat_v, flat_ks, flat_vs, groups,
+             (cache.ssm, cache.conv), tokens0, batch["pos0"], rng)
     # the loop itself belongs to the pool: what it does besides its body's
     # (scoped) work is carry the pool's views from step to step
     with jax.named_scope("kv_pool"):
-        ((flat_k, flat_v, flat_ks, flat_vs, groups, last, _, rng),
+        ((flat_k, flat_v, flat_ks, flat_vs, groups, scan, last, _, rng),
          (toks, stats)) = jax.lax.scan(step, carry, None, length=steps)
     with jax.named_scope("sample"):
         prev_out = jnp.where(active, last, prev_tokens)
-    out = (toks, prev_out, rng, _rebuild_cache(cache, flat_k, flat_v,
-                                               flat_ks, flat_vs, *groups))
+    out = (toks, prev_out, rng, _rebuild_cache(
+        cache, flat_k, flat_v, flat_ks, flat_vs, *groups, scan))
     return out + (jnp.sum(stats, axis=0),) if moe_stats else out
 
 
@@ -1569,7 +1855,7 @@ def _speculative_burst_core(params, draft_params, cache: PagedKVCache,
         # fused program
         with jax.named_scope("draft"):
             for j in range(gamma + 1):
-                dlogits, ddk, ddv, ddks, ddvs, _, _ = _decode_core(
+                dlogits, ddk, ddv, ddks, ddvs, _, _, _ = _decode_core(
                     draft_params, ddk, ddv, dtok, active, dpos, bt,
                     draft_cfg, block_size, mesh=mesh, flat_ks=ddks,
                     flat_vs=ddvs)
@@ -1746,13 +2032,15 @@ def ragged_decode_forward(params, cache: PagedKVCache, batch,
     flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
     bt = _group_tables(batch)
     routes = [] if moe_routes else None
-    logits, flat_k, flat_v, flat_ks, flat_vs, stats, groups = _decode_core(
+    (logits, flat_k, flat_v, flat_ks, flat_vs, stats, groups,
+     scan) = _decode_core(
         params, flat_k, flat_v, batch["tokens"], batch["active"],
         batch["token_pos"], bt, cfg, block_size, mesh=mesh, flat_ks=flat_ks,
         flat_vs=flat_vs, lora=batch.get("lora"),
         adapter_slot=batch.get("adapter_slot"), kv_layout=kv_layout,
         moe_stats=moe_stats, routes=routes,
-        groups=_flat_group_views(cache))
-    cache = _rebuild_cache(cache, flat_k, flat_v, flat_ks, flat_vs, *groups)
+        groups=_flat_group_views(cache), scan=(cache.ssm, cache.conv))
+    cache = _rebuild_cache(cache, flat_k, flat_v, flat_ks, flat_vs, *groups,
+                           scan)
     out = (logits, cache) + ((stats,) if moe_stats else ())
     return out + ((jnp.stack(routes),) if moe_routes else ())

@@ -396,7 +396,18 @@ class RaggedBatch:
 class DSStateManager:
     """Sequence tracking + KV block accounting (reference
     inference/v2/ragged/ragged_manager.py DSStateManager + kv_cache.py
-    KVCacheManager), with the optional radix prefix-cache layer."""
+    KVCacheManager), with the optional radix prefix-cache layer.
+
+    The resident that does not grow (a model with scan layers,
+    ``PagedKVCache.ssm`` / ``.conv``): every tracked sequence owns ONE
+    fixed-size state slot in every scan layer, and that slot is the
+    sequence's own ``slot``, so it is allocated by ``create``, freed by
+    ``flush`` (retirement, preemption, a drain: every path that gives up
+    the pages gives up the state) and has no allocator of its own.  Nothing
+    clears a slot: the step programs start a sequence's row at position 0
+    from zero whatever the slot held, which is also all a preemption must
+    do about it (the victim's state is dropped with its pages and
+    recomputed from its prompt).  ``scan_slots_in_use`` counts them."""
 
     def __init__(self, max_tracked_sequences: int, num_blocks: int,
                  block_size: int, max_seq_len: int,
@@ -699,6 +710,12 @@ class DSStateManager:
     @property
     def tracked(self) -> Dict[int, SequenceDescriptor]:
         return self._seqs
+
+    @property
+    def scan_slots_in_use(self) -> int:
+        """State slots of the scan layers held by tracked sequences: one a
+        sequence (class docstring)."""
+        return len(self._seqs)
 
     @property
     def free_sequence_slots(self) -> int:

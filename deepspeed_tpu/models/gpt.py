@@ -195,6 +195,26 @@ class GPTConfig:
     # v_head_dim, kv_lora_rank, q_lora_rank, qk_rope_head_dim, rope_theta,
     # attn_scale.  ``for_layer(i)`` is the view a layer's attention takes
     window_attn: tuple = ()
+    # layers that are no attention (checkpoint/hf.py maps model_type
+    # "granitemoehybrid"): ``layer_types[i]`` is "attention" or "mamba", a
+    # Mamba-2 scan layer (``Mamba2Mixer``: ``ssm_heads`` heads of
+    # ``ssm_head_dim`` over a state ``ssm_state`` wide, ``B``/``C`` shared
+    # by the heads of each of ``ssm_groups`` groups, a depthwise causal conv
+    # ``ssm_conv`` taps long over x, B and C, computed ``ssm_chunk`` rows at
+    # a time).  Empty: every layer is attention.  ``is_scan_layer(i)`` is
+    # the one question every reader asks
+    layer_types: tuple = ()
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    ssm_conv_bias: bool = True
+    # Granite's multipliers beside embed_scale and attn_scale: each branch
+    # enters the residual stream times this, and the logits are divided
+    residual_scale: Optional[float] = None
+    logits_divisor: Optional[float] = None
 
     @property
     def kv_heads(self) -> int:
@@ -220,9 +240,51 @@ class GPTConfig:
         layers' own geometry (``window_attn``) applied, and the indexer on
         the layers without a window only.  The same object where the layers
         are all alike."""
+        if self.is_scan_layer(i):
+            raise ValueError(
+                f"layer {i} is a scan layer ({self.layer_types[i]}): it has "
+                f"no attention geometry; ask is_scan_layer(i) first")
         if not self.window_attn and not self.index_topk:
             return self
         return _layer_view(self, self.window_for_layer(i) is not None)
+
+    def is_scan_layer(self, i: int) -> bool:
+        """Whether layer ``i`` mixes its sequence by a state-space scan and
+        not by attention: it writes no KV pages and keeps one fixed-size
+        state a sequence."""
+        if not self.layer_types:
+            return False
+        if len(self.layer_types) != self.num_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, the "
+                f"model has {self.num_layers}")
+        kind = self.layer_types[i]
+        if kind not in ("attention", "mamba"):
+            raise ValueError(f"layer_types[{i}] must be attention|mamba, "
+                             f"got {kind!r}")
+        return kind == "mamba"
+
+    @property
+    def scan_layers(self) -> tuple:
+        """The scan layers' indices, in order."""
+        return tuple(i for i in range(self.num_layers)
+                     if self.is_scan_layer(i))
+
+    @property
+    def attention_layers(self) -> tuple:
+        """The layers that own KV pages, in order: all of them unless
+        ``layer_types`` says otherwise."""
+        return tuple(i for i in range(self.num_layers)
+                     if not self.is_scan_layer(i))
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """The conv's channels: x, then B and C of every group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     def is_moe_layer(self, i: int) -> bool:
         """Whether layer ``i`` holds experts: after ``moe_dense_layers``
@@ -241,8 +303,10 @@ class GPTConfig:
             return False
         if self.rope_layers == "all":
             return True
+        if self.rope_layers == "none":     # use_rope, and no layer rotates:
+            return False                   # no positions at all (NoPE)
         if self.rope_layers != "window":
-            raise ValueError(f"rope_layers must be all|window, got "
+            raise ValueError(f"rope_layers must be all|window|none, got "
                              f"{self.rope_layers!r}")
         return self.window_for_layer(i) is not None
 
@@ -944,6 +1008,107 @@ class MLAttention(nn.Module):
         return jnp.einsum("btnd,ndh->bth", out, wo.astype(x.dtype))
 
 
+def _dt_bias_init(key, shape, dtype):
+    """``dt_bias`` such that ``softplus(dt_bias)`` is log-uniform in
+    [0.001, 0.1] (the Mamba-2 reference initialisation)."""
+    import math
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                 * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _a_log_init(key, shape, dtype):
+    """``A_log`` with ``A = exp(A_log)`` uniform in [1, 16]."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0,
+                                      16.0)).astype(dtype)
+
+
+def _conv_init(taps: int):
+    """Initialiser of a depthwise conv's weight ``[channels, taps]`` and
+    bias: uniform in +-1 / sqrt(taps) (the Mamba-2 reference leaves its
+    ``Conv1d`` at the framework's default, whose fan-in is the taps).  At
+    the matrices' 0.02 the conv would shrink x, B and C twentyfold and the
+    state would carry nothing of the output."""
+    bound = taps ** -0.5
+
+    def init(key, shape, dtype):
+        return jax.random.uniform(key, shape, jnp.float32, -bound,
+                                  bound).astype(dtype)
+    return init
+
+
+def ssm_split(c: GPTConfig):
+    """Column offsets of ``w_in``'s output ``[z | xBC | dt]``."""
+    return c.ssm_inner, c.ssm_inner + c.ssm_conv_dim
+
+
+def ssm_gate_norm(y, z, scale, eps):
+    """A scan layer's gated norm: the gate FIRST, then RMSNorm over the
+    whole inner width (one group), float32 inside."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + eps)
+    return g * scale.astype(jnp.float32)
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 scan layer's mixer on whole sequences ``x [B, T, H]``, by
+    the chunked (SSD) form (ops/ssm_scan.py), from a zero state:
+
+        [z | xBC | dt] = W_in x;   xBC = silu(conv(xBC));  [x | B | C] = xBC
+        dt = softplus(dt + dt_bias);   A = -exp(A_log)
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t;   y_t = S_t C_t + D x_t
+        out = W_out norm(y * silu(z))
+
+    The serving engine computes the same from these parameters with a
+    carried state and conv tail (inference/v2/model.py)."""
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from deepspeed_tpu import ops
+        from deepspeed_tpu.ops.norms import RMS_EPS
+        c = self.cfg
+        H, inner, cd = c.hidden_size, c.ssm_inner, c.ssm_conv_dim
+        h, p, g, n = c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state
+        w_in = self.param("w_in", _part(_kernel_init(), ("embed", "mlp")),
+                          (H, inner + cd + h), c.param_dtype)
+        conv_w = self.param("conv_w",
+                            _part(_conv_init(c.ssm_conv), ("mlp", None)),
+                            (cd, c.ssm_conv), c.param_dtype)
+        conv_b = (self.param("conv_b",
+                             _part(_conv_init(c.ssm_conv), ("mlp",)),
+                             (cd,), c.param_dtype)
+                  if c.ssm_conv_bias else None)
+        dt_bias = self.param("dt_bias", _part(_dt_bias_init, (None,)), (h,),
+                             c.param_dtype)
+        a_log = self.param("A_log", _part(_a_log_init, (None,)), (h,),
+                           c.param_dtype)
+        d = self.param("D", _part(nn.initializers.ones, (None,)), (h,),
+                       c.param_dtype)
+        norm = self.param("norm", _part(nn.initializers.ones, ("mlp",)),
+                          (inner,), c.param_dtype)
+        w_out = self.param("w_out", _part(_kernel_init(), ("mlp", "embed")),
+                           (inner, H), c.param_dtype)
+        Bt, T = x.shape[:2]
+        zxd = x @ w_in.astype(x.dtype)
+        a, b = ssm_split(c)
+        z, xbc, dt = zxd[..., :a], zxd[..., a:b], zxd[..., b:]
+        xbc, _ = ops.causal_conv1d(
+            xbc, conv_w, conv_b,
+            jnp.zeros((Bt, c.ssm_conv - 1, cd), xbc.dtype))
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + dt_bias.astype(jnp.float32))
+        y, _ = ops.ssm_chunk_scan(
+            xbc[..., :inner].reshape(Bt, T, h, p), dt,
+            -jnp.exp(a_log.astype(jnp.float32)),
+            xbc[..., inner:inner + g * n].reshape(Bt, T, g, n),
+            xbc[..., inner + g * n:].reshape(Bt, T, g, n), d,
+            jnp.zeros((Bt, h, p, n), jnp.float32), chunk=c.ssm_chunk)
+        y = ssm_gate_norm(y.reshape(Bt, T, inner), z, norm,
+                          c.norm_eps or RMS_EPS)
+        return y.astype(x.dtype) @ w_out.astype(x.dtype)
+
+
 class MLP(nn.Module):
     cfg: GPTConfig
     mesh: Optional[object] = None
@@ -991,6 +1156,7 @@ class Block(nn.Module):
     mesh: Optional[object] = None
     attn_cfg: Optional[GPTConfig] = None   # this layer's attention view
     #                                        (GPTConfig.for_layer); None: cfg
+    scan: bool = False                     # a scan layer: Mamba2Mixer mixes
 
     @nn.compact
     def __call__(self, x, positions, deterministic: bool,
@@ -1022,9 +1188,11 @@ class Block(nn.Module):
             # ln_attn + ln_mlp pair) and their outputs sum into one residual
             # add (reference inference/v2/model_implementations/falcon,
             # module_inject/containers/ — parallel_attn semantics).
-            if self.is_moe or c.sandwich_norm or c.mla:
+            if (self.is_moe or c.sandwich_norm or c.mla or self.scan
+                    or c.residual_scale is not None):
                 raise ValueError("parallel_block + MoE / sandwich_norm / "
-                                 "latent attention is not a supported "
+                                 "latent attention / scan layers / "
+                                 "residual_scale is not a supported "
                                  "architecture combination")
             h_attn = Norm(c)(x)                       # Norm_0
             h_mlp = Norm(c)(x) if c.parallel_norms == 2 else h_attn  # Norm_1
@@ -1037,14 +1205,25 @@ class Block(nn.Module):
                     + pld_gate(MLP(c, mesh=self.mesh)(h_mlp, deterministic,
                                                       use_cache=use_cache)),
                     jnp.float32(0.0))
-        attn = (MLAttention(self.attn_cfg or c, mesh=self.mesh,
-                            name="Attention_0") if c.mla
-                else Attention(c, mesh=self.mesh))
-        a = attn(Norm(c)(x), positions, deterministic, use_cache, kv_mask,
-                 start_index, kv_positions, window=window, fused_ok=fused_ok,
-                 use_rope=use_rope)
+        if self.scan:
+            if use_cache:
+                raise NotImplementedError(
+                    "a scan layer through the dense KV-cache path: its state "
+                    "lives in the v2 engine's pool (inference/v2); the v1 "
+                    "cache holds keys and values only")
+            a = Mamba2Mixer(c)(Norm(c)(x))
+        else:
+            attn = (MLAttention(self.attn_cfg or c, mesh=self.mesh,
+                                name="Attention_0") if c.mla
+                    else Attention(c, mesh=self.mesh))
+            a = attn(Norm(c)(x), positions, deterministic, use_cache,
+                     kv_mask, start_index, kv_positions, window=window,
+                     fused_ok=fused_ok, use_rope=use_rope)
         if c.sandwich_norm:
             a = Norm(c, name="post_attn_norm")(a)
+        rs = c.residual_scale
+        if rs is not None:
+            a = a * jnp.asarray(rs, a.dtype)
         x = x + pld_gate(a)
         if self.is_moe:
             from deepspeed_tpu.moe import MoE
@@ -1078,6 +1257,8 @@ class Block(nn.Module):
                 aux = aux * scale.astype(aux.dtype)  # dropped ffn: no LB loss
             if c.sandwich_norm:
                 moe_out = Norm(c, name="post_ffn_norm")(moe_out)
+            if rs is not None:
+                moe_out = moe_out * jnp.asarray(rs, moe_out.dtype)
             x = x + moe_out
         else:
             aux = jnp.float32(0.0)
@@ -1085,6 +1266,8 @@ class Block(nn.Module):
                                        use_cache=use_cache)
             if c.sandwich_norm:
                 f = Norm(c, name="post_ffn_norm")(f)
+            if rs is not None:
+                f = f * jnp.asarray(rs, f.dtype)
             x = x + pld_gate(f)
         return x, aux
 
@@ -1141,8 +1324,10 @@ class GPTBackbone(nn.Module):
         ltd_layers = tuple(c.random_ltd_layer_ids or ())
         aux_total = jnp.float32(0.0)
         for i in range(c.num_layers):
+            scan = c.is_scan_layer(i)
             block = block_cls(c, c.is_moe_layer(i), self.mesh,
-                              c.for_layer(i), name=f"block_{i}")
+                              None if scan else c.for_layer(i), scan,
+                              name=f"block_{i}")
             keep = None
             if pld_theta is not None:
                 from deepspeed_tpu.runtime.progressive_layer_drop import \
@@ -1251,6 +1436,8 @@ class GPT(nn.Module):
                                      c.param_dtype).astype(x.dtype)
             if labels is None:
                 labels, mask = shift_labels(batch, input_ids)
+            if c.logits_divisor:
+                x = x / jnp.asarray(c.logits_divisor, x.dtype)
             lm_bias = (self.param("lm_head_bias",
                                   _part(nn.initializers.zeros, ("vocab",)),
                                   (c.vocab_size,), c.param_dtype)
@@ -1296,6 +1483,8 @@ class GPTLogits(nn.Module):
                                  (c.hidden_size, c.vocab_size),
                                  c.param_dtype).astype(x.dtype)
         logits = (x @ unembed).astype(jnp.float32)
+        if c.logits_divisor:
+            logits = logits / c.logits_divisor
         if c.unembed_bias:
             logits = logits + self.param(
                 "lm_head_bias", _part(nn.initializers.zeros, ("vocab",)),
@@ -1342,8 +1531,15 @@ def count_params(cfg: GPTConfig) -> int:
     attn = (cfg.num_heads * cfg.head_dim * H * (3 if cfg.attn_gate else 2)
             + cfg.kv_heads * cfg.head_dim * H * 2              # wk, wv
             + (2 * cfg.head_dim if cfg.qk_norm else 0))
-    attn += H * norms * (1 if cfg.use_rmsnorm else 2)
-    attn *= cfg.num_layers
+    per_norms = H * norms * (1 if cfg.use_rmsnorm else 2)
+    n_scan = len(cfg.scan_layers)
+    # a scan layer's mixer: w_in, w_out, the conv, dt_bias/A_log/D, the norm
+    scan = (H * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads)
+            + cfg.ssm_inner * H
+            + cfg.ssm_conv_dim * (cfg.ssm_conv + int(cfg.ssm_conv_bias))
+            + 3 * cfg.ssm_heads + cfg.ssm_inner)
+    attn = ((attn + per_norms) * (cfg.num_layers - n_scan)
+            + (scan + per_norms) * n_scan)
     if cfg.mla:             # a layer's own geometry (GPTConfig.for_layer)
         attn = sum(_mla_params(cfg.for_layer(i))
                    + H * norms * (1 if cfg.use_rmsnorm else 2)

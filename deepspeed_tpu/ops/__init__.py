@@ -185,6 +185,39 @@ def grouped_gemm(rows, weights, group_sizes, *, impl: Optional[str] = None):
     return dispatch("grouped_gemm", rows, weights, group_sizes, impl=impl)
 
 
+from deepspeed_tpu.ops import ssm_scan as _ssm  # noqa: E402
+
+register_op("ssm_chunk_scan", xla=_ssm.xla_ssm_chunk_scan)
+register_op("ssm_state_update", xla=_ssm.xla_ssm_state_update,
+            pallas=_ssm.pallas_ssm_state_update,
+            supported=_ssm.state_update_supported)
+register_op("causal_conv1d", xla=_ssm.xla_causal_conv1d)
+
+
+def ssm_chunk_scan(x, dt, A, B, C, D, state0, segments=None, *, chunk: int,
+                   max_len=None, impl: Optional[str] = None):
+    """A scan layer's chunked (SSD) scan of every segment from its own
+    initial state -> (y float32, final states) (ops/ssm_scan.py)."""
+    return dispatch("ssm_chunk_scan", x, dt, A, B, C, D, state0, segments,
+                    chunk=chunk, max_len=max_len, impl=impl)
+
+
+def ssm_state_update(x, dt, A, B, C, D, pool, layer=0, active=None,
+                     fresh=None, *, impl: Optional[str] = None):
+    """A scan layer's recurrence for one row a slot, in place in layer
+    ``layer`` of a packed state pool -> (y float32, pool')
+    (ops/ssm_scan.py)."""
+    return dispatch("ssm_state_update", x, dt, A, B, C, D, pool, layer,
+                    active, fresh, impl=impl)
+
+
+def causal_conv1d(xBC, w, b, tail, count=None, *,
+                  impl: Optional[str] = None):
+    """A scan layer's depthwise causal conv + SiLU with a carried tail ->
+    (out, tail') (ops/ssm_scan.py)."""
+    return dispatch("causal_conv1d", xBC, w, b, tail, count, impl=impl)
+
+
 from deepspeed_tpu.ops.evoformer import evoformer_attention  # noqa: E402
 
 register_op("evoformer_attention", xla=evoformer_attention)
@@ -244,4 +277,5 @@ __all__ = ["causal_attention", "flash_attention", "configure_flash_blocks",
            "row_parallel_matmul", "collective_matmul",
            "lm_cross_entropy", "masked_nll_sum", "rms_norm", "layer_norm",
            "op_report", "register_op", "dispatch", "list_ops", "registry",
-           "grouped_gemm"]
+           "grouped_gemm", "ssm_chunk_scan", "ssm_state_update",
+           "causal_conv1d"]

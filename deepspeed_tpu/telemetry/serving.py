@@ -93,6 +93,7 @@ class ServingTelemetry:
         # per-request joint attainment — the bench reads this log
         self.request_log: list = []
         self.request_log_cap = 100_000
+        self.scan_layers = 0            # set_scan_state
         if not self.enabled:
             return
         reg = self.registry
@@ -273,6 +274,22 @@ class ServingTelemetry:
             "prefill kernel and not by the row gather "
             "(ops.sparse_index.masked_prefill of the step's reach)")
 
+        # ---- scan layers (layer_types): the rows they mix by path, and
+        # the resident that does not grow, one state slot a sequence
+        self.c_ssm_rows = reg.counter(
+            "serving_ssm_rows_total", "rows through the scan layers, rows "
+            "times scan layers, per path (chunk = a prompt chunk's rows "
+            "through the chunked scan / step = a one-row slot's through "
+            "the recurrence)")
+        self.g_ssm_slots = reg.gauge(
+            "ssm_state_slots_in_use", "scan-state slots held by tracked "
+            "sequences (one a sequence, allocated with it and freed with "
+            "it) at the most recent dispatch")
+        self.g_ssm_bytes = reg.gauge(
+            "ssm_state_bytes_per_slot", "device bytes of one sequence's "
+            "state slot over all scan layers: the float32 recurrent state "
+            "and the conv's last rows")
+
     # ------------------------------------------------------------- clocks
 
     @staticmethod
@@ -410,6 +427,27 @@ class ServingTelemetry:
             for k, v in self.kv_bytes_groups.items():
                 self.g_kv_bytes.set(v, part=k, **self.labels)
 
+    def set_scan_state(self, layers: int, bytes_per_slot: int) -> None:
+        """A model with scan layers, once at start-up: how many it has and
+        the bytes of one sequence's state slot over all of them (the
+        recurrent state and the conv's tail)."""
+        self.scan_layers = int(layers)
+        self.ssm_bytes_per_slot = int(bytes_per_slot)
+        if self.enabled:
+            self.g_ssm_bytes.set(int(bytes_per_slot), **self.labels)
+
+    def ssm_rows(self, rows, steps: int = 1) -> None:
+        """One dispatch's rows through the scan layers, by path: a slot
+        with one row takes the recurrence (``step``), one with more the
+        chunked scan (``chunk``); ``rows``: each scheduled sequence's,
+        ``steps``: of a fused burst.  Rows times scan layers."""
+        if self.enabled and self.scan_layers:
+            n = self.scan_layers * steps
+            self.c_ssm_rows.inc(n * sum(r for r in rows if r > 1),
+                                path="chunk", **self.labels)
+            self.c_ssm_rows.inc(n * sum(r == 1 for r in rows), path="step",
+                                **self.labels)
+
     def index_pairs(self, scored: int, kept: int, causal: int,
                     masked_step: bool = False) -> None:
         """One dispatch's pairs on the selecting layers, and whether its
@@ -445,6 +483,16 @@ class ServingTelemetry:
                 sel_masked_steps=int(
                     self.c_sel_masked.value(**self.labels)),
                 global_pairs=int(pairs))
+        if self.scan_layers:
+            slots = state.scan_slots_in_use
+            self.g_ssm_slots.set(slots, **self.labels)
+            note.update(
+                ssm_chunk_rows=int(self.c_ssm_rows.value(
+                    path="chunk", **self.labels)),
+                ssm_step_rows=int(self.c_ssm_rows.value(
+                    path="step", **self.labels)),
+                ssm_slots=slots,
+                ssm_state_bytes_per_slot=self.ssm_bytes_per_slot)
         total = self.c_moe_assign.value(**self.labels)
         if total:
             note.update(

@@ -98,7 +98,7 @@ def lower_serving_steps(cfg, cache_dtype, *, slots, tokens, max_q, table_width,
         jax.random.PRNGKey(0))["params"]
     params = unbox(boxed)
     cache = jax.eval_shape(lambda: v2model.PagedKVCache.create(
-        cfg, num_pages, block_size, cache_dtype, quant=quant))
+        cfg, num_pages, block_size, cache_dtype, quant=quant, slots=slots))
     if mesh is None:
         placed = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype),
                                         (params, cache))

@@ -408,6 +408,57 @@ def test_step_programs_leave_the_pool_in_place(step_programs, monkeypatch,
         assert temp < POOL_GEOMETRIES[geometry][3], temp
 
 
+# a scan layer beside an attention layer at granite-4.0-h-micro's widths (64
+# heads of 64 over a state of 128: 2 MiB a slot a layer, packed [32, 128,
+# 128]); 16 slots, so a layer's states are 32 MiB and the pool is 32 MiB too
+GRANITE_2L = GPTConfig(
+    vocab_size=4096, num_layers=2, num_heads=32, num_kv_heads=8, head_dim=64,
+    hidden_size=2048, mlp_dim_override=8192, max_seq_len=2560, use_rope=True,
+    rope_layers="none", use_rmsnorm=True, gated_mlp=True, norm_eps=1e-5,
+    layer_types=("mamba", "attention"), ssm_heads=64, ssm_head_dim=64,
+    ssm_state=128, attn_scale=1 / 64, residual_scale=0.22,
+    logits_divisor=8.0, embed_scale=12.0)
+
+
+def test_scan_state_pool_stays_in_place(topo, monkeypatch):
+    """The step programs of a model with scan layers neither copy nor slice
+    a layer's states out of the float32 state pool (4.9 GB at 64 slots of
+    the whole model): the decode step and the burst update them in place
+    through the ``ssm_state_update`` kernel, whose output is the pool, and
+    the mixed step's loop over prompt chunks gathers and scatters the few
+    slots it scans (``SCAN_GROUP`` = 4 states a pass, which it may
+    transpose: a quarter of a layer here).  The pool and the conv-tail pool
+    are donated and aliased to the outputs."""
+    from conftest import lower_serving_steps
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(GRANITE_2L, dtype=BF16, param_dtype=BF16,
+                              attn_impl="pallas")
+    S = 16
+    _, cache, lowered = lower_serving_steps(
+        cfg, BF16, slots=S, tokens=512, max_q=256, table_width=32,
+        block_size=128, num_pages=S * 20, steps=8,
+        sharding=SingleDeviceSharding(topo.devices[0]))
+    assert cache.ssm.shape == (1, S, 32, 128, 128) and cache.k.shape[0] == 1
+    layer = int(np.prod(cache.ssm.shape[1:]))
+    pools = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                for a in (cache.ssm, cache.conv))
+    for name, low in lowered.items():
+        compiled = low.compile()
+        text = compiled.as_text()
+        assert "ssm_state_update" in text, name     # the kernel, by its name
+        moved = []
+        for result, op in _HLO_OP.findall(text):
+            if op not in POOL_MOVERS:
+                continue
+            for dt, dims in _HLO_ARRAY.findall(result):
+                n = int(np.prod([int(d) for d in dims.split(",") if d]
+                                or [1]))
+                if dt == "f32" and n >= layer:
+                    moved.append(f"{op} -> {dt}[{dims}]")
+        assert not moved, (name, moved)
+        assert compiled.memory_analysis().alias_size_in_bytes >= pools, name
+
+
 @pytest.mark.parametrize("geometry", sorted(STEP_GEOMETRIES))
 def test_mixed_step_has_both_attention_kernels_a_layer(step_programs,
                                                        monkeypatch, geometry):

@@ -463,9 +463,24 @@ def test_generate_rounds_are_tiled_by_their_phases(generate_trace):
     spans = [a for a in notes if a["thread"] == thread]
     assert {a["name"] for a in spans} - {"ds.round"} <= PHASES
     pieces = [p for p in sched_rounds.innermost(spans)]
-    covered = sum(e - s for name, s, e in pieces if name != "ds.round")
     whole = sum(r["end_ns"] - r["start_ns"] for r in rounds)
-    assert covered / whole > 0.9, covered / whole
+    # What no phase covers is the SEAMS, where one span closes and the next
+    # opens (some eight a round, 15-20 us each on an idle machine).  A seam
+    # is the program's by what it costs EVERY time: a kind of seam (the
+    # pieces before and after it) is charged its shortest occurrence times
+    # its count, so that a stall that lands inside one occurrence (the
+    # thread descheduled under a six-worker run's load: 5 ms seen among
+    # 123 seams of 17 us under five competing processes, a 10% share needs
+    # only 35 ms) is the machine's, while work done outside every phase is
+    # long in every occurrence of its seam and still counted in full.
+    seams = {}
+    for i, (name, s, e) in enumerate(pieces):
+        if name == "ds.round":
+            kind = (pieces[i - 1][0] if i else None,
+                    pieces[i + 1][0] if i + 1 < len(pieces) else None)
+            seams.setdefault(kind, []).append(e - s)
+    uncovered = sum(len(v) * min(v) for v in seams.values())
+    assert 1 - uncovered / whole > 0.9, (uncovered / whole, seams)
     seen = {name for name, _, _ in pieces}
     assert {"ds.gate", "ds.admit", "ds.build", "ds.h2d", "ds.fence",
             "ds.retire", "ds.idle_sleep", "ds.materialize"} <= seen
