@@ -275,18 +275,19 @@ def _mlp(p, x, cfg, mesh=None):
 
 
 def _block_residual(blk, x, h, attn_delta, cfg, mesh=None, live=None,
-                    stats=None, routes=None):
+                    stats=None, routes=None, experts=None):
     """Close out one block given the normed input ``h`` and the attention
     branch output: sequential (x+attn, then MLP on a fresh norm) or falcon/phi
     parallel residual (attn and MLP both read the shared/paired input norms) —
     the single source of truth for BOTH the ragged prefill and paged decode
-    loops.  ``live``/``stats``/``routes`` go to an MoE layer (``_ffn``)."""
+    loops.  ``live``/``stats``/``routes``/``experts`` go to an MoE layer
+    (``_ffn``)."""
     if cfg.parallel_block:
         h_mlp = _norm(blk["Norm_1"], x, cfg) if cfg.parallel_norms == 2 else h
         return x + attn_delta + _ffn(blk, h_mlp, cfg, mesh=mesh)
     x = x + _scale_branch(attn_delta, cfg)
     f = _ffn(blk, _norm(blk["Norm_1"], x, cfg), cfg, mesh=mesh, live=live,
-             stats=stats, routes=routes)
+             stats=stats, routes=routes, experts=experts)
     if cfg.sandwich_norm:
         f = _norm(blk["post_ffn_norm"], f, cfg)
     return x + _scale_branch(f, cfg)
@@ -608,7 +609,22 @@ def _moe_route(mp, x, cfg):
     return idx, w
 
 
-def _ffn(blk, x, cfg, mesh=None, live=None, stats=None, routes=None):
+def _experts_fn(cfg, with_stats: bool):
+    """The expert FFN of ``cfg`` (moe/layer.py:_expert_ffn_ragged with the
+    grouped GEMM left to the registry) as ONE jitted function, made once a
+    step program and called by every expert layer of it: the sort, the
+    gather, the two kernel calls and the scatter-add are traced once and
+    lowered once a program, not once a layer (what ``attend`` is to the
+    attention kernels).  A jit of the trace's own, not of the module: the
+    ops choose their implementation while tracing."""
+    from deepspeed_tpu.moe.layer import _expert_ffn_ragged
+    return jax.jit(named_partial(
+        _expert_ffn_ragged, expert_offset=cfg.expert_offset,
+        num_experts=cfg.num_experts, with_stats=with_stats, impl=None))
+
+
+def _ffn(blk, x, cfg, mesh=None, live=None, stats=None, routes=None,
+         experts=None):
     """Dense MLP or MoE block body on FLAT tokens [N, H] — MoE routes through
     the dropless ragged grouped GEMM (moe/layer.py), which fits serving
     exactly: the ragged token set per step IS the ragged expert batch
@@ -618,28 +634,27 @@ def _ffn(blk, x, cfg, mesh=None, live=None, stats=None, routes=None):
     An expert layer is the same function as the flax module's
     (moe/layer.py) on the same parameters, in three scopes inside the
     caller's ``mlp``: ``moe_route`` (``_moe_route``), ``moe_experts`` (sort,
-    counts, gather, grouped GEMMs over the experts held here, weighted
-    scatter-add) and, where the model has a shared expert, ``moe_shared``.
+    counts, gather, grouped GEMMs over the experts held here: the registry's
+    choice, the Pallas kernel on a TPU, since nothing here is differentiated;
+    weighted scatter-add) and, where the model has a shared expert, ``moe_shared``.
     ``live [N]`` marks the rows that are tokens (not padding, not an idle
     slot): the others' assignments are dropped with those to experts not
     held.  ``stats``, a list, takes the layer's counter vector, and
     ``routes`` the experts it chose ``[N, k]`` (``put(...,
     with_routes=True)``: what a comparison with a reference's routing
-    needs)."""
+    needs).  ``experts``: the step program's ``_experts_fn`` (a caller
+    with one expert layer to run may leave it out)."""
     if "moe" not in blk:
         return _mlp(blk["MLP_0"], x, cfg, mesh=mesh)
-    from deepspeed_tpu.moe.layer import _expert_ffn_ragged
     mp = blk["moe"]
     with jax.named_scope("moe_route"):
         idx, w = _moe_route(mp, x, cfg)
         if routes is not None:
             routes.append(idx)
     with jax.named_scope("moe_experts"):
-        y = _expert_ffn_ragged(
+        y = (experts or _experts_fn(cfg, stats is not None))(
             x, idx, w, _w(mp["wi"], x.dtype), _w(mp["wo"], x.dtype),
-            _w(mp["wge"], x.dtype) if "wge" in mp else None,
-            expert_offset=cfg.expert_offset, num_experts=cfg.num_experts,
-            live=live, with_stats=stats is not None)
+            _w(mp["wge"], x.dtype) if "wge" in mp else None, live=live)
         if stats is not None:
             y, st = y
             stats.append(st)
@@ -1253,6 +1268,7 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     block_table = tables[0]
     kv_len = batch["kv_len"]               # [S]
     stats = [] if moe_stats else None
+    experts = _experts_fn(cfg, moe_stats) if cfg.num_experts else None
     routes = [] if moe_routes else None
 
     N = tokens.shape[0]
@@ -1334,7 +1350,8 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                 jnp.int32(cfg.scan_layers.index(li)), plan, rows)
             with jax.named_scope("mlp"):
                 x = _block_residual(blk, x, h, delta, cfg, mesh=mesh,
-                                    live=valid, stats=stats, routes=routes)
+                                    live=valid, stats=stats, routes=routes,
+                                    experts=experts)
             continue
         ap, np_ = blk["Attention_0"], blk["Norm_0"]
         lc = cfg.for_layer(li)           # this layer's attention geometry
@@ -1382,7 +1399,8 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                 attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
         with jax.named_scope("mlp"):
             x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh,
-                                live=valid, stats=stats, routes=routes)
+                                live=valid, stats=stats, routes=routes,
+                                experts=experts)
 
     # ---- logits gather (reference ragged_ops/logits_gather): the LAST token
     # of each slot's q rows carries the next-token distribution ----
@@ -1423,6 +1441,7 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     dtype = cfg.dtype
     tables = block_table if isinstance(block_table, tuple) else (block_table,)
     stats = [] if moe_stats else None
+    experts = _experts_fn(cfg, moe_stats) if cfg.num_experts else None
     S = tokens.shape[0]
     NB = flat_k_all.shape[0] // kv_pool_layers(cfg)
     km = kv_major_layout(cfg)
@@ -1453,7 +1472,8 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                 jnp.int32(cfg.scan_layers.index(li)), active, token_pos)
             with jax.named_scope("mlp"):
                 x = _block_residual(blk, x, h, delta, cfg, mesh=mesh,
-                                    live=active, stats=stats, routes=routes)
+                                    live=active, stats=stats, routes=routes,
+                                    experts=experts)
             continue
         ap = blk["Attention_0"]
         lc = cfg.for_layer(li)           # this layer's attention geometry
@@ -1516,7 +1536,8 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                 attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
         with jax.named_scope("mlp"):
             x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh,
-                                live=active, stats=stats, routes=routes)
+                                live=active, stats=stats, routes=routes,
+                                experts=experts)
 
     logits = _head(params, bb, x, cfg, mesh=mesh)                  # [S, V]
     return (logits, flat_k_all, flat_v_all, flat_ks, flat_vs,

@@ -79,7 +79,7 @@ def _expert_ffn(d, wi, wo, wg=None):
 
 def _expert_ffn_ragged(tokens, expert_idx, weights, wi, wo, wg=None, *,
                        expert_offset: int = 0, num_experts=None, live=None,
-                       with_stats: bool = False):
+                       with_stats: bool = False, impl="xla"):
     """Dropless grouped GEMM (megablox semantics; reference analog:
     inference/v2 MoE gather/scatter + cutlass grouped GEMM, and the
     MegaBlocks paper): assignments sort by expert, each expert multiplies
@@ -103,6 +103,14 @@ def _expert_ffn_ragged(tokens, expert_idx, weights, wi, wo, wg=None, *,
 
     ``with_stats``: also int32 ``[local assignments, assignments of live
     rows, local experts with at least one row]``, for the serving counters.
+
+    ``impl`` goes to ``ops.grouped_gemm``.  The default is ``lax.ragged_dot``
+    by name, which is what everything differentiated needs (the flax module
+    below); serving's forward-only step passes None and lets the registry
+    take the Pallas kernel where the backend and the shape allow, which
+    needs a gate (the GELU form keeps ``lax.ragged_dot``) and leaves the
+    rows behind the last group unwritten: they are masked here whenever the
+    kernel may have run.
     """
     from deepspeed_tpu import ops
     S, H = tokens.shape
@@ -121,17 +129,19 @@ def _expert_ffn_ragged(tokens, expert_idx, weights, wi, wo, wg=None, *,
     tok_rows = jnp.repeat(jnp.arange(S), k)[order]        # source token/row
     sorted_tok = tokens[tok_rows]
     group_sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1, mode="drop")
-    h = ops.grouped_gemm(sorted_tok, wi.astype(tokens.dtype), group_sizes)
     if wg is not None:
-        h = nn.silu(ops.grouped_gemm(sorted_tok, wg.astype(tokens.dtype),
-                                     group_sizes)) * h
+        h = ops.grouped_gemm(sorted_tok, wi.astype(tokens.dtype), group_sizes,
+                             wg.astype(tokens.dtype), impl=impl)
     else:
-        h = nn.gelu(h)
-    o = ops.grouped_gemm(h, wo.astype(tokens.dtype), group_sizes)
+        impl = "xla"
+        h = nn.gelu(ops.grouped_gemm(sorted_tok, wi.astype(tokens.dtype),
+                                     group_sizes, impl=impl))
+    o = ops.grouped_gemm(h, wo.astype(tokens.dtype), group_sizes, impl=impl)
     w = weights.reshape(-1)[order].astype(o.dtype)
-    if share:
+    if share or impl != "xla":
         # rows behind the last group were not multiplied: whatever the
-        # backend left there must not reach the scatter
+        # backend left there (ragged_dot zeros, the kernel nothing at all)
+        # must not reach the scatter
         done = jnp.arange(S * k) < jnp.sum(group_sizes)
         o = jnp.where(done[:, None], o, 0)
         tok_rows = jnp.where(done, tok_rows, S)           # dropped

@@ -175,14 +175,21 @@ register_op("selection_mask", xla=_index.xla_selection_mask)
 
 from deepspeed_tpu.ops import grouped_gemm as _grouped  # noqa: E402
 
-register_op("grouped_gemm", xla=_grouped.xla_grouped_gemm)
+register_op("grouped_gemm", xla=_grouped.xla_grouped_gemm,
+            pallas=_grouped.pallas_grouped_gemm, supported=_grouped.supported)
 
 
-def grouped_gemm(rows, weights, group_sizes, *, impl: Optional[str] = None):
+def grouped_gemm(rows, weights, group_sizes, gate=None, *,
+                 impl: Optional[str] = None):
     """``rows [A, K]`` (sorted by group) x ``weights [G, K, N]`` by
-    ``group_sizes [G]`` -> [A, N] (ops/grouped_gemm.py): the MoE expert
-    product, through the registry so the dispatch log names it."""
-    return dispatch("grouped_gemm", rows, weights, group_sizes, impl=impl)
+    ``group_sizes [G]`` -> [A, N]; with ``gate [G, K, N]`` the gated first
+    half of an expert FFN, ``silu(rows @ gate[g]) * (rows @ weights[g])``
+    (ops/grouped_gemm.py): the MoE expert products, through the registry so
+    the dispatch log names what ran.  The kernel is forward only and leaves
+    the rows behind the last group unwritten: what is differentiated asks
+    for ``impl="xla"`` (``lax.ragged_dot``)."""
+    return dispatch("grouped_gemm", rows, weights, group_sizes, gate,
+                    impl=impl)
 
 
 from deepspeed_tpu.ops import ssm_scan as _ssm  # noqa: E402

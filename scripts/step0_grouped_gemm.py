@@ -1,0 +1,267 @@
+#!/usr/bin/env python3
+"""Step 0 of the expert weight stream (PR 45), on the chip: one expert
+layer's three products at the six shapes the three MoE cells run (a decode
+and a mixed step each), as ``lax.ragged_dot``, as megablox's ``gmm`` from the
+installed jax at a few tilings, and as this repo's kernel
+(``ops/grouped_gemm.py``) at every row tile its shape rule could choose,
+each as milliseconds and as GB/s of the weights of the experts that have
+rows; beside them the other ops of the ``moe_experts`` scope (the sort, the
+``tokens[tok_rows]`` gather, the weighted scatter-add), so that PERF.md can
+say what share of ``*_moe_experts_ms`` the GEMMs are.
+
+    chiprun --timeout 1800 -- python3 scripts/step0_grouped_gemm.py
+    JAX_PLATFORMS=cpu python3 scripts/step0_grouped_gemm.py --tiny   # here
+
+Writes ``chiprun_out/pr45/step0.jsonl`` (one line a timing) and
+``step0.md`` (the table ``ops/grouped_gemm.py`` quotes).
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import deepspeed_tpu.ops  # noqa: F401  (the package's function shadows the module's name)
+
+gg = sys.modules["deepspeed_tpu.ops.grouped_gemm"]
+
+# cell, step, slots or tokens S, k, experts routed over, held, H, M
+SHAPES = [
+    ("moonlight", "decode", 48, 6, 64, 64, 2048, 1408),
+    ("moonlight", "mixed", 1024, 6, 64, 64, 2048, 1408),
+    ("trinity", "decode", 16, 4, 256, 32, 3072, 3072),
+    ("trinity", "mixed", 1024, 4, 256, 32, 3072, 3072),
+    ("dots3", "decode", 16, 8, 256, 32, 5120, 1536),
+    ("dots3", "mixed", 1024, 8, 256, 32, 5120, 1536),
+]
+TINY = [("tiny", "decode", 8, 2, 8, 4, 128, 256),
+        ("tiny", "mixed", 64, 2, 8, 4, 128, 256)]
+
+
+def timed(fn, *args, reps):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps * 1e3
+
+
+def route(rng, S, k, routed, held):
+    """``k`` distinct experts of ``routed`` a token, uniformly; the held
+    share is the first ``held``.  -> expert ids [S, k] with the sentinel
+    ``held`` where the expert is not here."""
+    ids = np.stack([rng.permutation(routed)[:k] for _ in range(S)])
+    return np.where(ids < held, ids, held).astype(np.int32)
+
+
+def corners(out):
+    """The kernel COMPILED (on the chip: tests/test_grouped_gemm.py holds
+    the same corners interpreted) where a grid could go wrong: no row at
+    all, one group holding every row, a NaN tail behind the last group,
+    groups that cross row tiles; against ``lax.ragged_dot`` on the live
+    rows.  One line a case to ``corners.jsonl``; any miss raises."""
+    rng = np.random.default_rng(0)
+    K, N, dtype = 256, 11 * 128, jnp.bfloat16
+    with open(os.path.join(out, "corners.jsonl"), "w") as log:
+        for A, G, kind in [(64, 32, "empty"), (64, 32, "sparse"),
+                           (288, 64, "one_group"), (288, 64, "tail"),
+                           (4096, 32, "tail"), (4096, 64, "crossing")]:
+            sizes = np.zeros(G, int)
+            if kind == "sparse":
+                sizes[rng.permutation(G)[:5]] = [1, 3, 17, 2, 9]
+            elif kind == "one_group":
+                sizes[G // 2] = A
+            elif kind == "tail":
+                sizes = rng.multinomial(A // 3, np.ones(G) / G)
+            elif kind == "crossing":
+                sizes = rng.multinomial(A - G, np.ones(G) / G) + 1
+            live = int(sizes.sum())
+            ks = jax.random.split(jax.random.PRNGKey(A + G), 4)
+            x = jax.random.normal(ks[0], (A, K), dtype).at[live:].set(jnp.nan)
+            wi, wg = ((jax.random.normal(k, (G, K, N)) * K ** -0.5).astype(
+                dtype) for k in ks[1:3])
+            wo = (jax.random.normal(ks[3], (G, N, K)) * N ** -0.5).astype(dtype)
+            gs = jnp.asarray(sizes, jnp.int32)
+            got = jax.jit(lambda x, s: gg.pallas_grouped_gemm(
+                gg.pallas_grouped_gemm(x, wi, s, wg), wo, s))(x, gs)
+            want = jax.jit(lambda x, s: gg.xla_grouped_gemm(
+                gg.xla_grouped_gemm(x, wi, s, wg), wo, s))(
+                    x.at[live:].set(0), gs)
+            a = np.asarray(got[:live], np.float32)
+            b = np.asarray(want[:live], np.float32)
+            err = float(np.sqrt(((a - b) ** 2).mean() / (b ** 2).mean())
+                        ) if live else 0.0
+            line = dict(A=A, groups=G, kind=kind, live=live,
+                        tm=gg._row_tile(A, G, 2), rel_rms=round(err, 5),
+                        finite=bool(np.isfinite(a).all()))
+            print(json.dumps(line), flush=True)
+            log.write(json.dumps(line) + "\n")
+            assert line["finite"] and err < 2e-2, line
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--corners", action="store_true",
+                    help="only the corner cases, compiled, against ragged_dot")
+    ap.add_argument("--reps", type=int, default=20)
+    ap.add_argument("--out", default="chiprun_out/pr45")
+    args = ap.parse_args()
+    os.makedirs(args.out, exist_ok=True)
+    if args.corners:
+        return corners(args.out)
+    lines = []
+    peak = 819.0
+    dtype = jnp.bfloat16
+    shapes = TINY if args.tiny else SHAPES
+    reps = 2 if args.tiny else args.reps
+    interpret = jax.default_backend() != "tpu"
+
+    log = open(os.path.join(args.out, "step0.jsonl"), "w")
+
+    def emit(**kw):
+        kw = {k: (round(v, 4) if isinstance(v, float) else v)
+              for k, v in kw.items()}
+        lines.append(kw)
+        print(json.dumps(kw), flush=True)
+        log.write(json.dumps(kw) + "\n")
+        log.flush()
+
+    weights = {}
+    for cell, step, S, k, routed, held, H, M in shapes:
+        key = (held, H, M)
+        if key not in weights:
+            weights.clear()
+            ks = jax.random.split(jax.random.PRNGKey(len(key)), 3)
+            mk = jax.jit(lambda kk, shape, s: (
+                jax.random.normal(kk, shape, jnp.float32) * s).astype(dtype),
+                static_argnums=(1, 2))
+            weights[key] = (mk(ks[0], (held, H, M), H ** -0.5),
+                            mk(ks[1], (held, H, M), H ** -0.5),
+                            mk(ks[2], (held, M, H), M ** -0.5))
+        wi, wg, wo = weights[key]
+        rng = np.random.default_rng(S * k)
+        ids = route(rng, S, k, routed, held)
+        A = S * k
+        flat = jnp.asarray(ids.reshape(-1))
+        sizes = jnp.zeros((held,), jnp.int32).at[flat].add(1, mode="drop")
+        live = int(sizes.sum())
+        touched = int((sizes > 0).sum())
+        gb = touched * 3 * H * M * 2 / 1e9
+        tokens = jax.random.normal(jax.random.PRNGKey(1), (S, H), dtype)
+        tw = jnp.asarray(rng.random((S, k)), jnp.float32)
+        base = dict(cell=cell, step=step, A=A, live=live, groups=held,
+                    touched=touched, K=H, N=M, weights_gb=round(gb, 4),
+                    floor_ms=round(gb / peak * 1e3, 4))
+
+        # the scope's other ops, as moe/layer.py runs them
+        order = jnp.argsort(flat)
+        tok_rows = jnp.repeat(jnp.arange(S), k)[order]
+        rows = tokens[tok_rows]
+        ms = timed(jax.jit(jnp.argsort), flat, reps=reps)
+        emit(**base, what="argsort", ms=ms)
+        ms = timed(jax.jit(lambda t, o: t[jnp.repeat(jnp.arange(S), k)[o]]),
+                   tokens, order, reps=reps)
+        emit(**base, what="gather", ms=ms)
+        o_rows = jax.random.normal(jax.random.PRNGKey(2), (A, H), dtype)
+
+        def scatter(o, w, order, tok_rows):
+            done = jnp.arange(A) < live
+            o = jnp.where(done[:, None], o, 0)
+            tr = jnp.where(done, tok_rows, S)
+            ww = w.reshape(-1)[order].astype(o.dtype)
+            return jnp.zeros((S, H), o.dtype).at[tr].add(o * ww[:, None],
+                                                         mode="drop")
+        ms = timed(jax.jit(scatter), o_rows, tw, order, tok_rows, reps=reps)
+        emit(**base, what="scatter_add", ms=ms)
+
+        # the three products
+        # the weights are ARGUMENTS: closed over, each compile would copy
+        # a gigabyte of constants to the host
+        def ffn_xla(x, s, wi, wg, wo):
+            h = gg.xla_grouped_gemm(x, wi, s, wg)
+            return gg.xla_grouped_gemm(h, wo, s)
+        ws = (wi, wg, wo)
+        ms_x = timed(jax.jit(ffn_xla), rows, sizes, *ws, reps=reps)
+        emit(**base, what="ffn", impl="ragged_dot", ms=ms_x,
+             gbps=gb / ms_x * 1e3)
+        ms1 = timed(jax.jit(lambda x, s, w: jax.lax.ragged_dot(x, w, s)),
+                    rows, sizes, wi, reps=reps)
+        emit(**base, what="one_product", impl="ragged_dot", ms=ms1,
+             gbps=gb / 3 / ms1 * 1e3)
+
+        from jax.experimental.pallas.ops.tpu.megablox import gmm as mbx_gmm
+        for tiling in ([(128, 128, 128), (128, 512, 512), (128, 1024, 512),
+                        (128, H, 128)] if not args.tiny else [(8, 128, 128)]):
+            if A % tiling[0]:
+                continue
+
+            def ffn_mbx(x, s, wi, wg, wo, tiling=tiling):
+                kw = dict(preferred_element_type=dtype, tiling=tiling,
+                          interpret=interpret)
+                h = jax.nn.silu(mbx_gmm(x, wg, s, **kw)) * mbx_gmm(
+                    x, wi, s, **kw)
+                return mbx_gmm(h, wo, s, **kw)
+            try:
+                ms = timed(jax.jit(ffn_mbx), rows, sizes, *ws, reps=reps)
+                emit(**base, what="ffn", impl="megablox", tiling=tiling,
+                     ms=ms, gbps=gb / ms * 1e3)
+            except Exception as exc:  # a tiling the compiler refuses
+                emit(**base, what="ffn", impl="megablox", tiling=tiling,
+                     error=repr(exc)[:300])
+
+        it = jnp.dtype(dtype).itemsize
+        rule = gg._row_tile(A, held, it)
+        for tm in (16, 32, 64, 128, 256):
+            if A % tm:
+                continue
+
+            def ffn_pl(x, s, wi, wg, wo, tm=tm):
+                h = gg.pallas_grouped_gemm(x, wi, s, wg, tm=tm)
+                return gg.pallas_grouped_gemm(h, wo, s, tm=tm)
+            try:
+                ms = timed(jax.jit(ffn_pl), rows, sizes, *ws, reps=reps)
+                emit(**base, what="ffn", impl="pallas", tm=tm,
+                     tn=[gg._col_tile(H, M, it, 2), gg._col_tile(M, H, it, 1)],
+                     chosen=tm == rule, ms=ms, gbps=gb / ms * 1e3)
+            except Exception as exc:
+                emit(**base, what="ffn", impl="pallas", tm=tm,
+                     error=repr(exc)[:300])
+        got = jax.jit(lambda x, s, wi, wg, wo: gg.pallas_grouped_gemm(
+            gg.pallas_grouped_gemm(x, wi, s, wg), wo, s))(rows, sizes, *ws)
+        want = jax.jit(ffn_xla)(rows, sizes, *ws)
+        a = np.asarray(got[:live], np.float32)
+        b = np.asarray(want[:live], np.float32)
+        emit(**base, what="check", rel_rms=float(
+            np.sqrt(((a - b) ** 2).mean() / max((b ** 2).mean(), 1e-30))),
+            finite=bool(np.isfinite(a).all()))
+
+    log.close()
+    with open(os.path.join(args.out, "step0.md"), "w") as f:
+        f.write("| cell | step | A | live | touched | floor ms | what | "
+                "impl | tiling | ms | GB/s |\n|" + " --- |" * 11 + "\n")
+        for ln in lines:
+            if "ms" not in ln:
+                continue
+            tiling = ln.get("tiling") or (
+                f"tm {ln['tm']}{' (rule)' if ln.get('chosen') else ''}"
+                if "tm" in ln else "")
+            f.write(f"| {ln['cell']} | {ln['step']} | {ln['A']} | "
+                    f"{ln['live']} | {ln['touched']} | {ln['floor_ms']} | "
+                    f"{ln['what']} | {ln.get('impl', '')} | {tiling} | "
+                    f"{ln['ms']} | {ln.get('gbps', '')} |\n")
+    print(json.dumps({"device": jax.devices()[0].device_kind,
+                      "lines": len(lines)}))
+
+
+if __name__ == "__main__":
+    main()

@@ -181,6 +181,47 @@ def test_step_programs_log_the_grouped_gemm():
     assert ops.get("grouped_gemm") == "xla"
 
 
+@pytest.mark.parametrize("family", ["trinity", "moonlight"])
+def test_the_grouped_gemm_kernel_serves_the_same_tokens(family, monkeypatch):
+    """The Pallas grouped GEMM, forced (it runs interpreted here) where
+    ``inference/v2/model.py:_ffn`` leaves the choice to the registry: a
+    prompt chunk each and three decode steps give the ``lax.ragged_dot``
+    path's tokens and logits, and the dispatch log says which ran."""
+    from deepspeed_tpu import ops
+    from deepspeed_tpu.ops.registry import dispatch_log, reset_dispatch_log
+    if family == "trinity":
+        cfg, params = model(sizes())
+        make = engine
+    else:
+        import test_moonlight as moon
+        cfg, params = moon.model(moon.sizes())
+        make = moon.engine
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, 96, size=n).astype(np.int32) for n in (8, 5)]
+
+    def serve():
+        reset_dispatch_log()
+        eng = make(cfg, params)
+        steps = [np.stack(eng.put([1, 2], prompts))]
+        for _ in range(3):
+            nxt = steps[-1].argmax(-1).astype(np.int32)
+            steps.append(np.stack(eng.put([1, 2], [nxt[:1], nxt[1:]])))
+        took = {d["impl"] for d in dispatch_log()
+                if d["op"] == "grouped_gemm"}
+        return np.stack(steps), took
+    want, took = serve()
+    assert took == {"xla"}
+    real = ops.grouped_gemm
+    monkeypatch.setattr(
+        ops, "grouped_gemm", lambda *a, impl=None, **kw: real(
+            *a, impl="pallas" if impl is None else impl, **kw))
+    got, took = serve()
+    assert took == {"pallas"}
+    assert (got.argmax(-1) == want.argmax(-1)).all()
+    rel_rms = np.sqrt(((got - want) ** 2).mean() / (want ** 2).mean())
+    assert rel_rms < 2e-2, rel_rms
+
+
 # ------------------------------------------------------------- the share
 
 def _moe_module(sz, held, offset):
