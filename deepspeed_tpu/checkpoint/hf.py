@@ -682,6 +682,194 @@ def granite_hybrid_state_dict(cfg, params) -> Dict[str, Any]:
     return out
 
 
+# lfm2_moe (LiquidAI LFM2-MoE): published tensor name -> path in the GPT
+# parameter tree, the names the loader (``_lfm2_moe_tree``) reads and
+# ``lfm2_moe_state_dict`` writes back, held to each other in
+# tests/test_lfm2_moe.py on a seeded tiny state dict (no published weights
+# are in the repository; the names are the family's published modelling
+# code's as remembered).  ``conv.in_proj`` is one matrix [B | C | X] and
+# stays one (``w_in``); ``conv.conv.weight`` is [channels, 1, taps]; an
+# expert layer's ``experts.{e}.w1 / w3 / w2`` are stacked over ``e`` into
+# ``wge / wi / wo``.  The head is tied: there is no ``lm_head.weight``.
+LFM2_MOE_WEIGHT_NAMES = {
+    "model.embed_tokens.weight": "backbone/wte",
+    "model.embedding_norm.weight": "backbone/final_norm/scale",
+    "model.layers.{i}.operator_norm.weight": "backbone/block_{i}/Norm_0/scale",
+    "model.layers.{i}.ffn_norm.weight": "backbone/block_{i}/Norm_1/scale",
+    # conv layers (layer_types[i] == "conv")
+    "model.layers.{i}.conv.in_proj.weight":
+        "backbone/block_{i}/ShortConvMixer_0/w_in",
+    "model.layers.{i}.conv.conv.weight":
+        "backbone/block_{i}/ShortConvMixer_0/conv_w",
+    "model.layers.{i}.conv.out_proj.weight":
+        "backbone/block_{i}/ShortConvMixer_0/w_out",
+    # attention layers (layer_types[i] == "full_attention")
+    "model.layers.{i}.self_attn.q_proj.weight": "backbone/block_{i}/Attention_0/wq",
+    "model.layers.{i}.self_attn.k_proj.weight": "backbone/block_{i}/Attention_0/wk",
+    "model.layers.{i}.self_attn.v_proj.weight": "backbone/block_{i}/Attention_0/wv",
+    "model.layers.{i}.self_attn.out_proj.weight": "backbone/block_{i}/Attention_0/wo",
+    "model.layers.{i}.self_attn.q_layernorm.weight":
+        "backbone/block_{i}/Attention_0/q_norm",
+    "model.layers.{i}.self_attn.k_layernorm.weight":
+        "backbone/block_{i}/Attention_0/k_norm",
+    # dense layers (i < num_dense_layers)
+    "model.layers.{i}.feed_forward.w1.weight": "backbone/block_{i}/MLP_0/wg",
+    "model.layers.{i}.feed_forward.w3.weight": "backbone/block_{i}/MLP_0/wi",
+    "model.layers.{i}.feed_forward.w2.weight": "backbone/block_{i}/MLP_0/wo",
+    # expert layers
+    "model.layers.{i}.feed_forward.gate.weight": "backbone/block_{i}/moe/gate",
+    "model.layers.{i}.feed_forward.expert_bias":
+        "backbone/block_{i}/moe/expert_bias",
+    "model.layers.{i}.feed_forward.experts.{e}.w1.weight":
+        "backbone/block_{i}/moe/wge[e]",
+    "model.layers.{i}.feed_forward.experts.{e}.w3.weight":
+        "backbone/block_{i}/moe/wi[e]",
+    "model.layers.{i}.feed_forward.experts.{e}.w2.weight":
+        "backbone/block_{i}/moe/wo[e]",
+}
+
+
+def lfm2_moe_config(hf: Dict[str, Any], *, max_seq_len: Optional[int] = None,
+                    dtype=None):
+    """GPTConfig of a published ``lfm2_moe`` ``config.json``: gated short
+    convolutions and RoPE GQA attention with q/k norms by ``layer_types``,
+    ``num_dense_layers`` leading SwiGLU layers and then sigmoid-routed
+    experts with a selection bias, a tied head."""
+    from deepspeed_tpu.models.gpt import GPTConfig
+    rope = hf.get("rope_parameters") or {}
+    kinds = set(hf["layer_types"])
+    for key, ok, what in (
+            ("conv_bias", not hf.get("conv_bias", False),
+             "a bias on the short convolution and its projections"),
+            ("layer_types", kinds <= {"conv", "full_attention"},
+             "a kind of layer other than conv|full_attention"),
+            ("rope_parameters", rope.get("rope_type", "default") == "default"
+             and not hf.get("rope_scaling"), "scaled rotary positions"),
+            ("use_expert_bias", hf.get("use_expert_bias", True),
+             "a router without its selection bias"),
+            ("tie_word_embeddings", hf.get("tie_word_embeddings", True),
+             "an untied head"),
+            ("num_experts", hf.get("num_experts", 0) > 0,
+             "an lfm2_moe model without experts")):
+        if not ok:
+            raise NotImplementedError(
+                f"lfm2_moe: {key}={hf.get(key)!r}: {what} is not built")
+    msl = hf.get("max_position_embeddings", 2048)
+    heads = hf["num_attention_heads"]
+    return GPTConfig(
+        vocab_size=hf["vocab_size"], num_layers=hf["num_hidden_layers"],
+        num_heads=heads, num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["hidden_size"] // heads, hidden_size=hf["hidden_size"],
+        mlp_dim_override=hf["intermediate_size"],
+        max_seq_len=min(msl, max_seq_len or msl),
+        use_rope=True, rope_layers="all",
+        rope_theta=float(rope.get("rope_theta", hf.get("rope_theta", 1e6))),
+        use_rmsnorm=True, norm_eps=float(hf.get("norm_eps", 1e-5)),
+        gated_mlp=True, tie_embeddings=True, qk_norm=True,
+        layer_types=tuple("attention" if t == "full_attention" else t
+                          for t in hf["layer_types"]),
+        conv_taps=hf.get("conv_L_cache", 3),
+        num_experts=hf["num_experts"], moe_k=hf["num_experts_per_tok"],
+        moe_dropless=True, moe_router="sigmoid", moe_router_bias=True,
+        moe_route_norm=bool(hf.get("norm_topk_prob", True)),
+        moe_route_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_route_eps=1e-6,
+        moe_expert_dim=hf["moe_intermediate_size"],
+        moe_dense_layers=hf.get("num_dense_layers", 0),
+        dtype=dtype or jnp.bfloat16)
+
+
+def _lfm2_moe_tree(r, cfg) -> Dict[str, Any]:
+    """lfm2_moe -> flax tree, by ``LFM2_MOE_WEIGHT_NAMES``; ``r`` has
+    ``get(name)`` (a ``_ShardReader``, or any mapping of published names to
+    arrays)."""
+    H = cfg.hidden_size
+    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+
+    def lin(name):                       # torch Linear: [out, in]
+        return np.asarray(r.get(name)).T
+
+    bb: Dict[str, Any] = {
+        "wte": np.asarray(r.get("model.embed_tokens.weight")),
+        "final_norm": {"scale": np.asarray(
+            r.get("model.embedding_norm.weight"))}}
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        blk: Dict[str, Any] = {
+            "Norm_0": {"scale": np.asarray(r.get(p + "operator_norm.weight"))},
+            "Norm_1": {"scale": np.asarray(r.get(p + "ffn_norm.weight"))}}
+        if cfg.is_conv_layer(i):
+            c = p + "conv."
+            blk["ShortConvMixer_0"] = {
+                "w_in": lin(c + "in_proj.weight"),
+                "conv_w": np.asarray(r.get(c + "conv.weight"))[:, 0, :],
+                "w_out": lin(c + "out_proj.weight")}
+        else:
+            a = p + "self_attn."
+            blk["Attention_0"] = {
+                "wq": lin(a + "q_proj.weight").reshape(H, nh, hd),
+                "wk": lin(a + "k_proj.weight").reshape(H, nkv, hd),
+                "wv": lin(a + "v_proj.weight").reshape(H, nkv, hd),
+                "wo": lin(a + "out_proj.weight").reshape(nh, hd, H),
+                "q_norm": np.asarray(r.get(a + "q_layernorm.weight")),
+                "k_norm": np.asarray(r.get(a + "k_layernorm.weight"))}
+        f = p + "feed_forward."
+        if cfg.is_moe_layer(i):
+            def stack(w):
+                return np.stack([lin(f"{f}experts.{e}.{w}.weight")
+                                 for e in range(cfg.num_experts)])
+            blk["moe"] = {"gate": lin(f + "gate.weight"),
+                          "expert_bias": np.asarray(r.get(f + "expert_bias")),
+                          "wge": stack("w1"), "wi": stack("w3"),
+                          "wo": stack("w2")}
+        else:
+            blk["MLP_0"] = {"wg": lin(f + "w1.weight"),
+                            "wi": lin(f + "w3.weight"),
+                            "wo": lin(f + "w2.weight")}
+        bb[f"block_{i}"] = blk
+    return {"backbone": bb}
+
+
+def lfm2_moe_state_dict(cfg, params) -> Dict[str, Any]:
+    """The GPT parameter tree of an lfm2_moe model under its published
+    tensor names and shapes: ``_lfm2_moe_tree``'s inverse."""
+    bb = params["backbone"]
+    H = cfg.hidden_size
+    out = {"model.embed_tokens.weight": np.asarray(bb["wte"]),
+           "model.embedding_norm.weight": np.asarray(
+               bb["final_norm"]["scale"])}
+    for i in range(cfg.num_layers):
+        blk, p = bb[f"block_{i}"], f"model.layers.{i}."
+        out[p + "operator_norm.weight"] = np.asarray(blk["Norm_0"]["scale"])
+        out[p + "ffn_norm.weight"] = np.asarray(blk["Norm_1"]["scale"])
+        if cfg.is_conv_layer(i):
+            c, m = blk["ShortConvMixer_0"], p + "conv."
+            out[m + "in_proj.weight"] = np.asarray(c["w_in"]).T
+            out[m + "out_proj.weight"] = np.asarray(c["w_out"]).T
+            out[m + "conv.weight"] = np.asarray(c["conv_w"])[:, None, :]
+        else:
+            a, at = blk["Attention_0"], p + "self_attn."
+            for k in "qkv":
+                out[f"{at}{k}_proj.weight"] = np.asarray(
+                    a["w" + k]).reshape(H, -1).T
+            out[at + "out_proj.weight"] = np.asarray(a["wo"]).reshape(-1, H).T
+            out[at + "q_layernorm.weight"] = np.asarray(a["q_norm"])
+            out[at + "k_layernorm.weight"] = np.asarray(a["k_norm"])
+        f = p + "feed_forward."
+        if cfg.is_moe_layer(i):
+            m = blk["moe"]
+            out[f + "gate.weight"] = np.asarray(m["gate"]).T
+            out[f + "expert_bias"] = np.asarray(m["expert_bias"])
+            for e in range(cfg.num_experts):
+                for w, k in (("w1", "wge"), ("w3", "wi"), ("w2", "wo")):
+                    out[f"{f}experts.{e}.{w}.weight"] = np.asarray(m[k][e]).T
+        else:
+            m = blk["MLP_0"]
+            for w, k in (("w1", "wg"), ("w3", "wi"), ("w2", "wo")):
+                out[f"{f}{w}.weight"] = np.asarray(m[k]).T
+    return out
+
+
 def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
                    dtype=None):
     """Build a GPTConfig from ``<model_path>/config.json``.
@@ -700,6 +888,8 @@ def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
         return dots3_note_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     if hf.get("model_type") == "granitemoehybrid":
         return granite_hybrid_config(hf, max_seq_len=max_seq_len, dtype=dtype)
+    if hf.get("model_type") == "lfm2_moe":
+        return lfm2_moe_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     arch = _arch_of(hf)
 
     if arch in _LLAMA_LIKE:
@@ -2037,6 +2227,8 @@ def load_hf_checkpoint(model_path: str, *, max_seq_len: Optional[int] = None,
     cfg = config_from_hf(model_path, max_seq_len=max_seq_len, dtype=dtype)
     if cfg.mla:
         return cfg, _deepseek_v3_tree(_ShardReader(model_path), cfg)
+    if cfg.conv_layers:
+        return cfg, _lfm2_moe_tree(_ShardReader(model_path), cfg)
     if cfg.layer_types:
         return cfg, _granite_hybrid_tree(_ShardReader(model_path), cfg)
     if cfg.moe_router == "sigmoid":

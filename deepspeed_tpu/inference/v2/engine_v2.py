@@ -520,21 +520,27 @@ class InferenceEngineV2:
         # statics of every step program; {} for a plain model, whose
         # programs are then traced exactly as before
         self._model_static: Dict[str, Any] = {}
-        if model_cfg.scan_layers:
-            # scan layers keep one fixed-size state a sequence beside the
-            # attention layers' pages (model.py PagedKVCache.ssm / .conv).
-            # What cannot be right beside them yet:
+        if model_cfg.state_layers:
+            # state layers (Mamba-2 scan layers, gated short convolutions)
+            # keep one fixed-size state a sequence beside the attention
+            # layers' pages (model.py PagedKVCache.ssm / .conv).  What
+            # cannot be right beside them yet:
+            conv = not model_cfg.scan_layers
+            noun, state = (("conv", "a conv tail") if conv
+                           else ("scan", "a recurrent state"))
             for what, why in (
-                    (sm.prefix_cache, "the prefix cache: a shared prefix "
-                     "has pages and no state to resume the scan from (it "
-                     "needs state snapshots at page boundaries)"),
+                    (sm.prefix_cache, f"the prefix cache: a shared prefix "
+                     f"has pages and no state to resume the {noun} from (it "
+                     f"needs state snapshots at page boundaries)"),
                     (draft_model is not None, "speculative decoding: a "
                      "rejected draft token has already moved the state, "
                      "which has no rollback"),
                     (self.mesh is not None, "a tp mesh: the state pool's "
-                     "heads and the mixer's projections are not sharded"),
-                    (self.config.adapters.enabled, "LoRA adapter pages: a "
-                     "scan layer has no q/v projections for their deltas"),
+                     + ("channels" if conv else "heads") + " and the "
+                     "mixer's projections are not sharded"),
+                    (self.config.adapters.enabled, f"LoRA adapter pages: a "
+                     f"{noun} layer has no q/v projections for their "
+                     f"deltas"),
                     (sm.kv_quant, "kv_quant: the pools are created "
                      "unquantised beside the state"),
                     (self.kv_window or model_cfg.mla, "window page groups "
@@ -542,8 +548,8 @@ class InferenceEngineV2:
                      "one plain page group only")):
                 if what:
                     raise NotImplementedError(
-                        f"scan layers (layer_types) keep a recurrent state "
-                        f"a sequence, which is not built with {why}")
+                        f"{noun} layers (layer_types) keep {state} a "
+                        f"sequence, which is not built with {why}")
         if model_cfg.mla:
             # latent attention: pools of latent rows (model.py
             # PagedKVCache), read absorbed, and with a learned selection
@@ -722,14 +728,16 @@ class InferenceEngineV2:
         self.heartbeat_fn = None
         self._block_size = eff_bs
         self._one_table_width = bool(model_cfg.index_topk
-                                     or model_cfg.scan_layers)   # _buckets
+                                     or model_cfg.state_layers)  # _buckets
         self.telemetry.set_kv_bytes_per_token(
             self.kv_bytes_per_token(), **self.kv_bytes_by_group())
-        if model_cfg.scan_layers:
+        if model_cfg.state_layers:
             c = self.cache
             self.telemetry.set_scan_state(
-                len(model_cfg.scan_layers),
-                (c.ssm.nbytes + c.conv.nbytes) // sm.max_tracked_sequences)
+                len(model_cfg.state_layers),
+                sum(a.nbytes for a in (c.ssm, c.conv) if a is not None)
+                // sm.max_tracked_sequences,
+                kind="ssm" if model_cfg.scan_layers else "conv")
         # ---- multi-tenant LoRA adapter pool (serving/adapters.py): A/B
         # pages live as block-granular refcounted residents of the SAME
         # allocator as the KV blocks, so adapters and KV contend under one
@@ -951,7 +959,7 @@ class InferenceEngineV2:
             # whole table's: its full layers score and sort over their
             # rows' own contexts at run time, index_select(width=), so a
             # narrower table would buy programs and save no work; so does a
-            # model with scan layers: the width is layout for its few
+            # model with scan or conv layers: the width is layout for its few
             # attention layers, whose kernels walk each slot's pages to its
             # own length, and every narrower table would be one more
             # program of all its layers to trace, lower and compile)

@@ -75,7 +75,7 @@ def kv_block_size_for(cfg: GPTConfig, requested: int,
 
 def kv_pool_layers(cfg: GPTConfig) -> int:
     """How many layers own KV pages, which is how many the pool holds: the
-    attention layers (a scan layer writes none)."""
+    attention layers (a scan or conv layer writes none)."""
     return len(cfg.attention_layers)
 
 
@@ -135,22 +135,24 @@ class PagedKVCache(NamedTuple):
     page for page beside ``k`` and addressed by the global group's own block
     table: a third array, no third allocator group.
 
-    Scan layers (``cfg.is_scan_layer``) write no pages: ``k``/``v`` hold the
-    attention layers only (``kv_pool_layers``; ``kv_base`` says where each
-    begins), and beside them lie the residents that do not grow, one
-    fixed-size slot a tracked sequence, addressed by the sequence's slot:
-    ``ssm [scan layers, slots, heads / k, state, k * head_dim]`` in float32
-    (a recurrence of thousands of steps rounds at every one; ``k`` heads
-    side by side on the lanes, ``ops/ssm_scan.py`` "the packed state pool":
-    64 heads of 64 over a state of 128 are ``[32, 128, 128]``) and ``conv
-    [scan layers, slots, (taps - 1) * channels]``, the conv's last rows one
-    after the other, in the compute dtype (ONE row a slot: as ``[taps - 1,
-    channels]`` a slot's tile is padded fivefold on the chip, and with the
-    slots behind the taps a scatter by slot re-lays the whole pool, 8 ms of
-    a mixed step on the v5e; PERF.md section 6, PR 44).  A slot is never
-    cleared: a sequence's row at position 0 starts from zero whatever the
-    slot held (``_scan_plan``), which is also how a preempted sequence is
-    recomputed."""
+    State layers (``cfg.is_state_layer``: Mamba-2 scan layers, gated short
+    convolutions) write no pages: ``k``/``v`` hold the attention layers only
+    (``kv_pool_layers``; ``kv_base`` says where each begins), and beside
+    them lie the residents that do not grow, one fixed-size slot a tracked
+    sequence, addressed by the sequence's slot, in the parts the model
+    needs.  ``conv [state layers, slots, (taps - 1) * channels]``: the
+    conv's last rows one after the other, in the compute dtype (ONE row a
+    slot: as ``[taps - 1, channels]`` a slot's tile is padded fivefold on
+    the chip, and with the slots behind the taps a scatter by slot re-lays
+    the whole pool, 8 ms of a mixed step on the v5e; PERF.md section 6, PR
+    44); a short-conv layer's whole state (2 rows of the hidden width).
+    ``ssm [scan layers, slots, heads / k, state, k * head_dim]`` in float32,
+    only where layers scan (a recurrence of thousands of steps rounds at
+    every one; ``k`` heads side by side on the lanes, ``ops/ssm_scan.py``
+    "the packed state pool": 64 heads of 64 over a state of 128 are ``[32,
+    128, 128]``).  A slot is never cleared: a sequence's row at position 0
+    starts from zero whatever the slot held (``_scan_plan``), which is also
+    how a preempted sequence is recomputed."""
 
     k: jax.Array
     v: Optional[jax.Array]
@@ -169,22 +171,24 @@ class PagedKVCache(NamedTuple):
     def create(cls, cfg: GPTConfig, num_blocks: int, block_size: int, dtype,
                quant: Optional[str] = None, slots: int = 0):
         """``slots``: the tracked sequences, each of which owns one state
-        slot in every scan layer (a model without scan layers has none)."""
+        slot in every state layer (a model without state layers has none)."""
         layers = kv_pool_layers(cfg)
         scan = {}
-        if cfg.scan_layers:
+        mixer = state_mixer(cfg)
+        if mixer is not None:
             if quant is not None or cfg.mla:
                 raise NotImplementedError(
-                    "scan layers beside kv_quant or latent pages are not "
-                    "built")
-            from deepspeed_tpu.ops.ssm_scan import packed_state_shape
-            n = len(cfg.scan_layers)
-            scan = dict(
-                ssm=jnp.zeros((n, slots) + packed_state_shape(
+                    "scan or conv layers beside kv_quant or latent pages are "
+                    "not built")
+            n = len(cfg.state_layers)
+            scan = dict(conv=jnp.zeros(
+                (n, slots, (mixer.taps(cfg) - 1) * mixer.channels(cfg)),
+                dtype))
+            if cfg.scan_layers:
+                from deepspeed_tpu.ops.ssm_scan import packed_state_shape
+                scan["ssm"] = jnp.zeros((n, slots) + packed_state_shape(
                     cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-                    jnp.float32),
-                conv=jnp.zeros((n, slots, (cfg.ssm_conv - 1)
-                                * cfg.ssm_conv_dim), dtype))
+                    jnp.float32)
         if cfg.mla:
             if quant is not None:
                 raise NotImplementedError(
@@ -603,7 +607,8 @@ def _moe_route(mp, x, cfg):
         from deepspeed_tpu.moe.sharded_moe import sigmoid_topk
         logits = jnp.dot(x, gate, preferred_element_type=jnp.float32)
         return sigmoid_topk(logits, cfg.moe_k, mp.get("expert_bias"),
-                            cfg.moe_route_norm, cfg.moe_route_scale)
+                            cfg.moe_route_norm, cfg.moe_route_scale,
+                            cfg.moe_route_eps)
     from deepspeed_tpu.moe.sharded_moe import dropless_topk
     _, idx, w = dropless_topk(x @ gate, cfg.moe_k)
     return idx, w
@@ -1008,24 +1013,190 @@ def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
         return jnp.where(valid[:, None, None], o, 0)
 
 
-# ---------------------------------------------------------------- scan layers
-# A scan layer (models/gpt.py Mamba2Mixer) in the step programs.  Scopes nest
-# inside the attention scopes, so that a reader that knows only those still
-# files every operation: the input projection under attn_qkv/ssm_in_proj, the
-# conv and the scan under attn_kernel/ssm_conv and attn_kernel/ssm_scan, the
-# write-back of a slot's state and conv tail under kv_write/ssm_scan, the
-# gated norm and the output projection under attn_out/ssm_gate_norm.
+# --------------------------------------------------------------- state layers
+# A layer that keeps a fixed-size state a sequence (GPTConfig.is_state_layer)
+# in the step programs: a Mamba-2 scan layer (models/gpt.py Mamba2Mixer) or a
+# gated short convolution (ShortConvMixer).  ONE path serves both: the plan of
+# which slot takes which route, the fresh / active handling, the one-row and
+# the chunked route, the write-back and the loop over layers are below; a
+# kind of mixer (``_Mamba2``, ``_ShortConv``) supplies its projections, its
+# conv's parameters and what it does between the conv and the output: the
+# recurrence over a float32 state, or nothing (a short conv's whole state is
+# its conv's tail).  Scopes nest inside the attention scopes, so that a
+# reader that knows only those still files every operation.  A scan layer:
+# the input projection under attn_qkv/ssm_in_proj, the conv and the scan
+# under attn_kernel/ssm_conv and attn_kernel/ssm_scan, the write-back of a
+# slot's state and conv tail under kv_write/ssm_scan, the gated norm and the
+# output projection under attn_out/ssm_gate_norm.  A short-conv layer:
+# attn_qkv/conv_in_proj, attn_kernel/short_conv, kv_write/short_conv,
+# attn_out/conv_out_proj.
 
-SCAN_GROUP = 4      # prompt chunks scanned together in a mixed step's pass
 SCAN_CHUNK = 128    # rows a chunk of the mixed step's scan, at most: the
 #                     decay matrix [slots, heads, chunk, chunk] float32 is
 #                     written and read once a chunk, and grows with the chunk
 
 
+class _Mamba2:
+    """What a Mamba-2 scan layer supplies to the state-layer path."""
+    key = "Mamba2Mixer_0"
+    in_scope, conv_scope, scope, out_scope = (
+        "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm")
+    activation = "silu"
+    group = 4           # prompt chunks convolved and scanned together in a
+    #                     mixed step's pass
+
+    @staticmethod
+    def taps(cfg):
+        return cfg.ssm_conv
+
+    @staticmethod
+    def channels(cfg):
+        return cfg.ssm_conv_dim
+
+    @staticmethod
+    def y_like(cfg, u):
+        """(width, dtype) of a row between the conv and the output."""
+        return cfg.ssm_inner, jnp.float32
+
+    @staticmethod
+    def project(mp, h, cfg, mesh=None):
+        """Rows ``h [R, H]`` -> (z [R, inner] for the output's gate, xBC [R,
+        channels] for the conv, dt [R, heads] float32 after its softplus)."""
+        from deepspeed_tpu.models.gpt import ssm_split
+        zxd = _wmm(h, mp["w_in"], h.dtype, mesh=mesh, wspec="col")
+        a, b = ssm_split(cfg)
+        dt = jax.nn.softplus(zxd[:, b:].astype(jnp.float32)
+                             + mp["dt_bias"].astype(jnp.float32))
+        return zxd[:, :a], zxd[:, a:b], dt
+
+    @staticmethod
+    def conv_params(mp):
+        return mp["conv_w"], mp.get("conv_b")
+
+    @staticmethod
+    def _xbc(xbc, cfg):
+        """The conv's output rows ``[..., channels]`` as the scan's (x [...,
+        h, p], B [..., g, n], C [..., g, n])."""
+        inner, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
+        lead = xbc.shape[:-1]
+        groups = lead + (cfg.ssm_groups, cfg.ssm_state)
+        return (xbc[..., :inner].reshape(
+                    lead + (cfg.ssm_heads, cfg.ssm_head_dim)),
+                xbc[..., inner:inner + gn].reshape(groups),
+                xbc[..., inner + gn:].reshape(groups))
+
+    @staticmethod
+    def _scan_params(mp):
+        return -jnp.exp(mp["A_log"].astype(jnp.float32)), mp["D"]
+
+    @classmethod
+    def step(cls, mp, out, dt, ssm, si, active, fresh, cfg):
+        """One conv'd row a slot ``out [S, channels]`` through the
+        recurrence, in place in layer ``si`` of the state pool -> (y [S,
+        inner] float32, ssm')."""
+        from deepspeed_tpu import ops
+        A, D = cls._scan_params(mp)
+        x, B, C = cls._xbc(out, cfg)
+        y, ssm = ops.ssm_state_update(x, dt, A, B, C, D, ssm, si, active,
+                                      fresh)
+        return y.reshape(y.shape[0], -1), ssm
+
+    @classmethod
+    def chunk(cls, mp, out, dt, ssm, si, slots, fresh, count, cfg):
+        """A pass's conv'd prompt chunks ``out [G, Q, channels]`` through
+        the chunked scan from their slots' states -> (y [G, Q, inner]
+        float32, the states to write back)."""
+        from deepspeed_tpu import ops
+        from deepspeed_tpu.ops.ssm_scan import pack_state, unpack_state
+        A, D = cls._scan_params(mp)
+        state = jnp.where(
+            fresh[:, None, None, None], 0.0,
+            unpack_state(ssm[si, slots], cfg.ssm_head_dim))
+        x, B, C = cls._xbc(out, cfg)
+        y, state = ops.ssm_chunk_scan(
+            x, dt, A, B, C, D, state, (None, count),
+            chunk=min(cfg.ssm_chunk, SCAN_CHUNK))
+        G, Q = out.shape[:2]
+        return y.reshape(G, Q, -1), pack_state(state)
+
+    @staticmethod
+    def output(mp, y, z, cfg, mesh=None):
+        """The gate, the norm over the inner width and the output projection
+        of rows ``y [R, inner]``."""
+        from deepspeed_tpu.models.gpt import ssm_gate_norm
+        from deepspeed_tpu.ops.norms import RMS_EPS
+        g = ssm_gate_norm(y, z, mp["norm"], cfg.norm_eps or RMS_EPS)
+        return _wmm(g.astype(z.dtype), mp["w_out"], z.dtype, mesh=mesh,
+                    wspec="row")
+
+
+class _ShortConv:
+    """What a gated short convolution supplies: ``[B | C | X] = W_in h``,
+    the conv over ``B * X`` without bias or activation, ``W_out (C * v)``.
+    Its state is the conv's tail and nothing else, so ``step`` and ``chunk``
+    pass the conv's rows on and the pool has no ``ssm`` part."""
+    key = "ShortConvMixer_0"
+    in_scope, conv_scope, scope, out_scope = (
+        "conv_in_proj", "short_conv", "short_conv", "conv_out_proj")
+    activation = None
+    group = 1           # a forward holds one prompt chunk beside its riders
+    #                     more often than four: a pass a chunk
+
+    @staticmethod
+    def taps(cfg):
+        return cfg.conv_taps
+
+    @staticmethod
+    def channels(cfg):
+        return cfg.hidden_size
+
+    @staticmethod
+    def y_like(cfg, u):
+        return cfg.hidden_size, u.dtype
+
+    @staticmethod
+    def project(mp, h, cfg, mesh=None):
+        """Rows ``h [R, H]`` -> (the output's gate C, the conv's input B *
+        X, nothing)."""
+        from deepspeed_tpu.models.gpt import short_conv_gates
+        u, gate = short_conv_gates(
+            _wmm(h, mp["w_in"], h.dtype, mesh=mesh, wspec="col"))
+        return gate, u, None
+
+    @staticmethod
+    def conv_params(mp):
+        return mp["conv_w"], None
+
+    @staticmethod
+    def step(mp, out, aux, ssm, si, active, fresh, cfg):
+        return out, ssm
+
+    @staticmethod
+    def chunk(mp, out, aux, ssm, si, slots, fresh, count, cfg):
+        return out, None
+
+    @staticmethod
+    def output(mp, v, gate, cfg, mesh=None):
+        return _wmm(gate * v, mp["w_out"], gate.dtype, mesh=mesh,
+                    wspec="row")
+
+
+def state_mixer(cfg: GPTConfig):
+    """The kind of state layer ``cfg`` has (None: none).  One kind a model:
+    the conv-tail pool has one row width."""
+    kinds = {cfg.layer_kind(i) for i in cfg.state_layers}
+    if len(kinds) > 1:
+        raise NotImplementedError(
+            "scan (mamba) AND conv layers in one model: the state pool is "
+            "built for one kind of state layer")
+    return {"mamba": _Mamba2, "conv": _ShortConv}[kinds.pop()] if kinds \
+        else None
+
+
 class _ScanPlan(NamedTuple):
-    """Which slots of a mixed step take which path through a scan layer,
+    """Which slots of a mixed step take which path through a state layer,
     the same for every layer."""
-    one: jnp.ndarray      # [S] the slot holds one row: the recurrence
+    one: jnp.ndarray      # [S] the slot holds one row: the one-row route
     fresh: jnp.ndarray    # [S] its first row is position 0: from zero
     order: jnp.ndarray    # [S + pad] slots, those with more rows first
     n_many: jnp.ndarray   # [] how many slots hold more than one row
@@ -1033,113 +1204,74 @@ class _ScanPlan(NamedTuple):
     row_slot: jnp.ndarray  # [N] the row's slot, 0 for padding
 
 
-def _scan_plan(rows: "_MixedRows") -> _ScanPlan:
+def _scan_plan(rows: "_MixedRows", mixer) -> _ScanPlan:
     S = rows.first_row.shape[0]
-    with jax.named_scope("attn_kernel"), jax.named_scope("ssm_scan"):
+    with jax.named_scope("attn_kernel"), jax.named_scope(mixer.scope):
         one = rows.q_counts == 1
         many = rows.q_counts > 1
         fresh = (rows.q_counts > 0) & (rows.kv_len == rows.q_counts)
         order = jnp.argsort(jnp.where(many, 0, 1), stable=True).astype(
             jnp.int32)
-        order = jnp.pad(order, (0, -S % SCAN_GROUP))
+        order = jnp.pad(order, (0, -S % mixer.group))
         slot = jnp.where(rows.scat_slot < S, rows.scat_slot, 0)
         return _ScanPlan(one, fresh, order, jnp.sum(many), one[slot]
                          & (rows.scat_slot < S), slot)
 
 
-def _scan_in(mp, h, cfg: GPTConfig, mesh=None):
-    """``ssm_in_proj`` (inside ``attn_qkv``): rows ``h [R, H]`` -> (z [R,
-    inner], xBC [R, channels], dt [R, heads] float32 after its softplus)."""
-    from deepspeed_tpu.models.gpt import ssm_split
-    with jax.named_scope("ssm_in_proj"):
-        zxd = _wmm(h, mp["w_in"], h.dtype, mesh=mesh, wspec="col")
-        a, b = ssm_split(cfg)
-        dt = jax.nn.softplus(zxd[:, b:].astype(jnp.float32)
-                             + mp["dt_bias"].astype(jnp.float32))
-        return zxd[:, :a], zxd[:, a:b], dt
-
-
-def _scan_xbc(xbc, cfg: GPTConfig):
-    """The conv's output rows ``[..., channels]`` as the scan's (x [..., h,
-    p], B [..., g, n], C [..., g, n])."""
-    inner, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
-    lead = xbc.shape[:-1]
-    groups = lead + (cfg.ssm_groups, cfg.ssm_state)
-    return (xbc[..., :inner].reshape(
-                lead + (cfg.ssm_heads, cfg.ssm_head_dim)),
-            xbc[..., inner:inner + gn].reshape(groups),
-            xbc[..., inner + gn:].reshape(groups))
-
-
-def _scan_out(mp, y, z, cfg: GPTConfig, mesh=None):
-    """``ssm_gate_norm`` (inside ``attn_out``): the gate, the norm over the
-    inner width and the output projection of rows ``y [R, inner]``."""
-    from deepspeed_tpu.models.gpt import ssm_gate_norm
-    from deepspeed_tpu.ops.norms import RMS_EPS
-    with jax.named_scope("attn_out"), jax.named_scope("ssm_gate_norm"):
-        g = ssm_gate_norm(y, z, mp["norm"], cfg.norm_eps or RMS_EPS)
-        return _wmm(g.astype(z.dtype), mp["w_out"], z.dtype, mesh=mesh,
-                    wspec="row")
-
-
-def _scan_params(mp):
-    return (-jnp.exp(mp["A_log"].astype(jnp.float32)), mp["D"],
-            mp["conv_w"], mp.get("conv_b"))
-
-
-def _conv_tail(conv, si, slots, fresh, cfg: GPTConfig):
+def _conv_tail(conv, si, slots, fresh, mixer, cfg: GPTConfig):
     """The conv tails ``[len(slots), taps - 1, channels]`` of ``slots`` in
-    scan layer ``si`` (all slots: None), zero where ``fresh``."""
+    state layer ``si`` (all slots: None), zero where ``fresh``."""
     rows = conv[si] if slots is None else conv[si, slots]
-    tail = rows.reshape(rows.shape[0], cfg.ssm_conv - 1, cfg.ssm_conv_dim)
+    tail = rows.reshape(rows.shape[0], mixer.taps(cfg) - 1,
+                        mixer.channels(cfg))
     return jnp.where(fresh[:, None, None], 0, tail)
 
 
-def _scan_rows_one(mp, xbc, dt, scan, si, active, fresh, cfg: GPTConfig):
-    """One row a slot through scan layer ``si`` of the pool: the conv from
-    the slot's tail, the recurrence from its state (from zero where
-    ``fresh``), both written back for the ``active`` slots.  ``xbc [S,
-    channels]``, ``dt [S, heads]`` -> (y [S, inner] float32, scan')."""
+def _scan_rows_one(mp, u, aux, scan, si, active, fresh, mixer,
+                   cfg: GPTConfig):
+    """One row a slot through state layer ``si`` of the pool: the conv from
+    the slot's tail, the mixer's update from its state (from zero where
+    ``fresh``), both written back for the ``active`` slots.  ``u [S,
+    channels]`` -> (y [S, width], scan')."""
     from deepspeed_tpu import ops
     ssm, conv = scan
-    A, D, cw, cb = _scan_params(mp)
+    cw, cb = mixer.conv_params(mp)
     with jax.named_scope("attn_kernel"):
-        with jax.named_scope("ssm_conv"):
+        with jax.named_scope(mixer.conv_scope):
             out, tail = ops.causal_conv1d(
-                xbc[:, None], cw, cb, _conv_tail(conv, si, None, fresh, cfg),
-                active.astype(jnp.int32))
-        with jax.named_scope("ssm_scan"):
-            x, B, C = _scan_xbc(out[:, 0], cfg)
-            y, ssm = ops.ssm_state_update(x, dt, A, B, C, D, ssm, si, active,
-                                          fresh)
-    with jax.named_scope("kv_write"), jax.named_scope("ssm_scan"):
+                u[:, None], cw, cb,
+                _conv_tail(conv, si, None, fresh, mixer, cfg),
+                active.astype(jnp.int32), activation=mixer.activation)
+        with jax.named_scope(mixer.scope):
+            y, ssm = mixer.step(mp, out[:, 0], aux, ssm, si, active, fresh,
+                                cfg)
+    with jax.named_scope("kv_write"), jax.named_scope(mixer.scope):
         conv = conv.at[si].set(tail.reshape(tail.shape[0], -1))
-    return y.reshape(y.shape[0], -1), (ssm, conv)
+    return y, (ssm, conv)
 
 
-def _scan_rows_many(mp, xbc, dt, scan, si, plan: _ScanPlan,
-                    rows: "_MixedRows", cfg: GPTConfig, Q: int):
-    """The prompt chunks of a mixed step through scan layer ``si``:
-    ``SCAN_GROUP`` slots a pass, as many passes as the step's slots with
+def _scan_rows_many(mp, u, aux, scan, si, plan: _ScanPlan,
+                    rows: "_MixedRows", mixer, cfg: GPTConfig, Q: int):
+    """The prompt chunks of a mixed step through state layer ``si``:
+    ``mixer.group`` slots a pass, as many passes as the step's slots with
     more than one row take (a loop whose trip count the device reads).  A
-    pass gathers its slots' rows out of the token-major ``xbc [N,
-    channels]`` / ``dt [N, heads]`` ONCE into ``[SCAN_GROUP, Q, ...]``,
-    convolves them from the slots' tails and scans them from the slots'
-    states, scatters ``y`` back and writes tails and states in place.
-    -> (y [N, inner] float32, zero on rows of no prompt chunk, scan')."""
+    pass gathers its slots' rows out of the token-major ``u [N, channels]``
+    (and ``aux``) ONCE into ``[group, Q, ...]``, convolves them from the
+    slots' tails, takes the mixer's update from the slots' states, scatters
+    ``y`` back and writes tails and states in place.  -> (y [N, width], zero
+    on rows of no prompt chunk, scan')."""
     from deepspeed_tpu import ops
-    from deepspeed_tpu.ops.ssm_scan import (pack_state, segment_rows,
-                                            unpack_state)
-    A, D, cw, cb = _scan_params(mp)
+    from deepspeed_tpu.ops.ssm_scan import segment_rows
+    cw, cb = mixer.conv_params(mp)
     S = rows.first_row.shape[0]
-    N = xbc.shape[0]
-    G = SCAN_GROUP
+    N = u.shape[0]
+    G = mixer.group
     lanes = jnp.arange(G, dtype=jnp.int32)
 
     def one_pass(carry):
         i, y, ssm, conv = carry
         with jax.named_scope("attn_kernel"):
-            with jax.named_scope("ssm_conv"):
+            with jax.named_scope(mixer.conv_scope):
                 slots = jax.lax.dynamic_slice_in_dim(plan.order, i * G, G)
                 live = i * G + lanes < plan.n_many
                 count = jnp.where(live, rows.q_counts[slots], 0)
@@ -1147,25 +1279,24 @@ def _scan_rows_many(mp, xbc, dt, scan, si, plan: _ScanPlan,
                     (rows.first_row[slots], count), Q, N)
                 fresh = plan.fresh[slots]
                 out, tail = ops.causal_conv1d(
-                    xbc[read], cw, cb, _conv_tail(conv, si, slots, fresh, cfg),
-                    count)
-            with jax.named_scope("ssm_scan"):
-                state = jnp.where(
-                    fresh[:, None, None, None], 0.0,
-                    unpack_state(ssm[si, slots], cfg.ssm_head_dim))
-                x, B, C = _scan_xbc(out, cfg)
-                y_pass, state = ops.ssm_chunk_scan(
-                    x, dt[read], A, B, C, D, state, (None, count),
-                    chunk=min(cfg.ssm_chunk, SCAN_CHUNK))
-                y = y.at[write].set(y_pass.reshape(G, Q, -1), mode="drop")
-        with jax.named_scope("kv_write"), jax.named_scope("ssm_scan"):
+                    u[read], cw, cb,
+                    _conv_tail(conv, si, slots, fresh, mixer, cfg), count,
+                    activation=mixer.activation)
+            with jax.named_scope(mixer.scope):
+                y_pass, state = mixer.chunk(
+                    mp, out, None if aux is None else aux[read], ssm, si,
+                    slots, fresh, count, cfg)
+                y = y.at[write].set(y_pass, mode="drop")
+        with jax.named_scope("kv_write"), jax.named_scope(mixer.scope):
             dst = jnp.where(live, slots, S)
-            ssm = ssm.at[si, dst].set(pack_state(state), mode="drop")
+            if state is not None:
+                ssm = ssm.at[si, dst].set(state, mode="drop")
             conv = conv.at[si, dst].set(tail.reshape(G, -1), mode="drop")
         return i + 1, y, ssm, conv
 
-    with jax.named_scope("attn_kernel"), jax.named_scope("ssm_scan"):
-        y0 = jnp.zeros((N, cfg.ssm_inner), jnp.float32)
+    with jax.named_scope("attn_kernel"), jax.named_scope(mixer.scope):
+        width, dtype = mixer.y_like(cfg, u)
+        y0 = jnp.zeros((N, width), dtype)
         _, y, ssm, conv = jax.lax.while_loop(
             lambda c: c[0] * G < plan.n_many, one_pass,
             (jnp.int32(0), y0) + tuple(scan))
@@ -1173,30 +1304,35 @@ def _scan_rows_many(mp, xbc, dt, scan, si, plan: _ScanPlan,
 
 
 def _scan_mixed(mp, h, scan, si, plan: _ScanPlan, rows: "_MixedRows", *,
-                cfg: GPTConfig, Q: int, mesh=None):
-    """A scan layer's mixer on a mixed step's token-major rows ``h [N, H]``:
-    a slot with one row (a decode row riding the step, a prompt's one-token
-    tail) takes the recurrence, a slot with more the chunked scan.  ->
-    (the mixer's output [N, H], scan')."""
-    with jax.named_scope("attn_qkv"):
-        z, xbc, dt = _scan_in(mp, h, cfg, mesh=mesh)
+                mixer, cfg: GPTConfig, Q: int, mesh=None):
+    """A state layer's mixer on a mixed step's token-major rows ``h [N,
+    H]``: a slot with one row (a decode row riding the step, a prompt's
+    one-token tail) takes the one-row route, a slot with more the chunked
+    one.  -> (the mixer's output [N, H], scan')."""
+    with jax.named_scope("attn_qkv"), jax.named_scope(mixer.in_scope):
+        keep, u, aux = mixer.project(mp, h, cfg, mesh=mesh)
     first = rows.first_row
-    y_one, scan = _scan_rows_one(mp, xbc[first], dt[first], scan, si,
-                                 plan.one, plan.fresh, cfg)
-    y_many, scan = _scan_rows_many(mp, xbc, dt, scan, si, plan, rows, cfg, Q)
-    with jax.named_scope("attn_kernel"), jax.named_scope("ssm_scan"):
+    y_one, scan = _scan_rows_one(
+        mp, u[first], None if aux is None else aux[first], scan, si,
+        plan.one, plan.fresh, mixer, cfg)
+    y_many, scan = _scan_rows_many(mp, u, aux, scan, si, plan, rows, mixer,
+                                   cfg, Q)
+    with jax.named_scope("attn_kernel"), jax.named_scope(mixer.scope):
         y = jnp.where(plan.row_one[:, None], y_one[plan.row_slot], y_many)
-    return _scan_out(mp, y, z, cfg, mesh=mesh), scan
+    with jax.named_scope("attn_out"), jax.named_scope(mixer.out_scope):
+        return mixer.output(mp, y, keep, cfg, mesh=mesh), scan
 
 
-def _scan_decode(mp, h, scan, si, active, token_pos, *, cfg: GPTConfig,
-                 mesh=None):
-    """A scan layer's mixer in a decode step: one row ``h [S, H]`` a slot."""
-    with jax.named_scope("attn_qkv"):
-        z, xbc, dt = _scan_in(mp, h, cfg, mesh=mesh)
-    y, scan = _scan_rows_one(mp, xbc, dt, scan, si, active,
-                             active & (token_pos == 0), cfg)
-    return _scan_out(mp, y, z, cfg, mesh=mesh), scan
+def _scan_decode(mp, h, scan, si, active, token_pos, *, mixer,
+                 cfg: GPTConfig, mesh=None):
+    """A state layer's mixer in a decode step: one row ``h [S, H]`` a
+    slot."""
+    with jax.named_scope("attn_qkv"), jax.named_scope(mixer.in_scope):
+        keep, u, aux = mixer.project(mp, h, cfg, mesh=mesh)
+    y, scan = _scan_rows_one(mp, u, aux, scan, si, active,
+                             active & (token_pos == 0), mixer, cfg)
+    with jax.named_scope("attn_out"), jax.named_scope(mixer.out_scope):
+        return mixer.output(mp, y, keep, cfg, mesh=mesh), scan
 
 
 def _scale_branch(delta, cfg: GPTConfig):
@@ -1321,14 +1457,16 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
         _selected_attention, cfg=lc, block_size=block_size, max_rows=Q))
         for lc in {cfg.for_layer(i) for i in cfg.attention_layers}
         if lc.index_topk and select}
-    # scan layers: the state and conv-tail pools, and which slots scan how
+    # state layers: the state and conv-tail pools, and which slots take
+    # which route through them
     scan = (cache.ssm, cache.conv)
-    if cfg.scan_layers:
-        plan = _scan_plan(rows)
-        # (one traced and lowered mixer for all the scan layers too: the
+    mixer = state_mixer(cfg)
+    if mixer is not None:
+        plan = _scan_plan(rows, mixer)
+        # (one traced and lowered mixer for all the state layers too: the
         # layer's place in the pools is an operand)
-        scan_mixer = jax.jit(named_partial(_scan_mixed, cfg=cfg, Q=Q,
-                                           mesh=mesh))
+        scan_mixer = jax.jit(named_partial(_scan_mixed, mixer=mixer, cfg=cfg,
+                                           Q=Q, mesh=mesh))
 
     # multi-tenant LoRA (static trace-time branch — adapter-less engines
     # send no "lora" key and trace the identical program): per-TOKEN
@@ -1342,12 +1480,12 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
 
     for li in range(cfg.num_layers):
         blk = bb[f"block_{li}"]
-        if cfg.is_scan_layer(li):
+        if cfg.is_state_layer(li):
             with jax.named_scope("attn_qkv"):
                 h = _norm(blk["Norm_0"], x, cfg)
             delta, scan = scan_mixer(
-                blk["Mamba2Mixer_0"], h, scan,
-                jnp.int32(cfg.scan_layers.index(li)), plan, rows)
+                blk[mixer.key], h, scan,
+                jnp.int32(cfg.state_layers.index(li)), plan, rows)
             with jax.named_scope("mlp"):
                 x = _block_residual(blk, x, h, delta, cfg, mesh=mesh,
                                     live=valid, stats=stats, routes=routes,
@@ -1459,17 +1597,19 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
         # decode rows ARE slots: mask inactive lanes to the identity slot
         # so a recycled lane's stale selection never computes a delta
         lora_ids = jnp.where(active, adapter_slot, 0)
-    if cfg.scan_layers:        # one traced and lowered mixer for them all
-        scan_mixer = jax.jit(named_partial(_scan_decode, cfg=cfg, mesh=mesh))
+    mixer = state_mixer(cfg)
+    if mixer is not None:      # one traced and lowered mixer for them all
+        scan_mixer = jax.jit(named_partial(_scan_decode, mixer=mixer,
+                                           cfg=cfg, mesh=mesh))
 
     for li in range(cfg.num_layers):
         blk = bb[f"block_{li}"]
-        if cfg.is_scan_layer(li):
+        if cfg.is_state_layer(li):
             with jax.named_scope("attn_qkv"):
                 h = _norm(blk["Norm_0"], x, cfg)
             delta, scan = scan_mixer(
-                blk["Mamba2Mixer_0"], h, scan,
-                jnp.int32(cfg.scan_layers.index(li)), active, token_pos)
+                blk[mixer.key], h, scan,
+                jnp.int32(cfg.state_layers.index(li)), active, token_pos)
             with jax.named_scope("mlp"):
                 x = _block_residual(blk, x, h, delta, cfg, mesh=mesh,
                                     live=active, stats=stats, routes=routes,

@@ -398,9 +398,10 @@ class DSStateManager:
     inference/v2/ragged/ragged_manager.py DSStateManager + kv_cache.py
     KVCacheManager), with the optional radix prefix-cache layer.
 
-    The resident that does not grow (a model with scan layers,
-    ``PagedKVCache.ssm`` / ``.conv``): every tracked sequence owns ONE
-    fixed-size state slot in every scan layer, and that slot is the
+    The resident that does not grow (a model with state layers: Mamba-2
+    scan layers or gated short convolutions, ``PagedKVCache.ssm`` /
+    ``.conv``): every tracked sequence owns ONE
+    fixed-size state slot in every state layer, and that slot is the
     sequence's own ``slot``, so it is allocated by ``create``, freed by
     ``flush`` (retirement, preemption, a drain: every path that gives up
     the pages gives up the state) and has no allocator of its own.  Nothing
@@ -713,8 +714,8 @@ class DSStateManager:
 
     @property
     def scan_slots_in_use(self) -> int:
-        """State slots of the scan layers held by tracked sequences: one a
-        sequence (class docstring)."""
+        """State slots of the state layers (scan or conv) held by tracked
+        sequences: one a sequence (class docstring)."""
         return len(self._seqs)
 
     @property

@@ -145,6 +145,7 @@ class GPTConfig:
     #                                     logits), dropless route only
     moe_route_norm: bool = True         # the chosen k renormalised to sum 1
     moe_route_scale: float = 1.0        # ... and then scaled by this
+    moe_route_eps: float = 1e-20        # ... over (their sum + this)
     moe_router_bias: bool = False       # `expert_bias` [E]: added to the
     #                                     scores for the SELECTION only
     moe_shared_dim: int = 0             # shared expert's width (0 = none):
@@ -195,14 +196,19 @@ class GPTConfig:
     # v_head_dim, kv_lora_rank, q_lora_rank, qk_rope_head_dim, rope_theta,
     # attn_scale.  ``for_layer(i)`` is the view a layer's attention takes
     window_attn: tuple = ()
-    # layers that are no attention (checkpoint/hf.py maps model_type
-    # "granitemoehybrid"): ``layer_types[i]`` is "attention" or "mamba", a
-    # Mamba-2 scan layer (``Mamba2Mixer``: ``ssm_heads`` heads of
-    # ``ssm_head_dim`` over a state ``ssm_state`` wide, ``B``/``C`` shared
-    # by the heads of each of ``ssm_groups`` groups, a depthwise causal conv
-    # ``ssm_conv`` taps long over x, B and C, computed ``ssm_chunk`` rows at
-    # a time).  Empty: every layer is attention.  ``is_scan_layer(i)`` is
-    # the one question every reader asks
+    # layers that are no attention (checkpoint/hf.py maps model_types
+    # "granitemoehybrid" and "lfm2_moe"): ``layer_types[i]`` is "attention",
+    # "mamba" or "conv".  "mamba": a Mamba-2 scan layer (``Mamba2Mixer``:
+    # ``ssm_heads`` heads of ``ssm_head_dim`` over a state ``ssm_state`` wide,
+    # ``B``/``C`` shared by the heads of each of ``ssm_groups`` groups, a
+    # depthwise causal conv ``ssm_conv`` taps long over x, B and C, computed
+    # ``ssm_chunk`` rows at a time).  "conv": a gated short convolution
+    # (``ShortConvMixer``: a depthwise causal conv ``conv_taps`` long over
+    # the hidden width, without bias or activation, between two gates).
+    # Empty: every layer is attention.  ``layer_kind(i)`` is the one place
+    # that reads it; ``is_state_layer(i)`` (either mixer: a fixed-size state
+    # a sequence and no KV pages), ``is_scan_layer(i)`` (Mamba-2) and
+    # ``is_conv_layer(i)`` are the questions readers ask
     layer_types: tuple = ()
     ssm_heads: int = 0
     ssm_head_dim: int = 0
@@ -211,6 +217,7 @@ class GPTConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 256
     ssm_conv_bias: bool = True
+    conv_taps: int = 3
     # Granite's multipliers beside embed_scale and attn_scale: each branch
     # enters the residual stream times this, and the logits are divided
     residual_scale: Optional[float] = None
@@ -240,42 +247,71 @@ class GPTConfig:
         layers' own geometry (``window_attn``) applied, and the indexer on
         the layers without a window only.  The same object where the layers
         are all alike."""
-        if self.is_scan_layer(i):
+        if self.is_state_layer(i):
+            what = {"mamba": "scan", "conv": "short-convolution"}[
+                self.layer_kind(i)]
             raise ValueError(
-                f"layer {i} is a scan layer ({self.layer_types[i]}): it has "
-                f"no attention geometry; ask is_scan_layer(i) first")
+                f"layer {i} is a {what} layer ({self.layer_types[i]}): it "
+                f"has no attention geometry; ask is_state_layer(i) first")
         if not self.window_attn and not self.index_topk:
             return self
         return _layer_view(self, self.window_for_layer(i) is not None)
 
-    def is_scan_layer(self, i: int) -> bool:
-        """Whether layer ``i`` mixes its sequence by a state-space scan and
-        not by attention: it writes no KV pages and keeps one fixed-size
-        state a sequence."""
+    def layer_kind(self, i: int) -> str:
+        """What mixes layer ``i``'s sequence: "attention", "mamba" (a
+        Mamba-2 scan) or "conv" (a gated short convolution)."""
         if not self.layer_types:
-            return False
+            return "attention"
         if len(self.layer_types) != self.num_layers:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, the "
                 f"model has {self.num_layers}")
         kind = self.layer_types[i]
-        if kind not in ("attention", "mamba"):
-            raise ValueError(f"layer_types[{i}] must be attention|mamba, "
-                             f"got {kind!r}")
-        return kind == "mamba"
+        if kind not in ("attention", "mamba", "conv"):
+            raise ValueError(f"layer_types[{i}] must be "
+                             f"attention|mamba|conv, got {kind!r}")
+        return kind
+
+    def is_scan_layer(self, i: int) -> bool:
+        """Whether layer ``i`` mixes its sequence by a state-space scan
+        (Mamba-2): a float32 recurrent state and a conv tail a sequence."""
+        return self.layer_kind(i) == "mamba"
+
+    def is_conv_layer(self, i: int) -> bool:
+        """Whether layer ``i`` is a gated short convolution: a conv tail a
+        sequence and nothing else."""
+        return self.layer_kind(i) == "conv"
+
+    def is_state_layer(self, i: int) -> bool:
+        """Whether layer ``i`` is no attention: it writes no KV pages and
+        keeps one fixed-size state a sequence (a scan or a conv layer)."""
+        return self.layer_kind(i) != "attention"
 
     @property
     def scan_layers(self) -> tuple:
-        """The scan layers' indices, in order."""
+        """The Mamba-2 scan layers' indices, in order."""
         return tuple(i for i in range(self.num_layers)
                      if self.is_scan_layer(i))
+
+    @property
+    def conv_layers(self) -> tuple:
+        """The short-convolution layers' indices, in order."""
+        return tuple(i for i in range(self.num_layers)
+                     if self.is_conv_layer(i))
+
+    @property
+    def state_layers(self) -> tuple:
+        """The layers that keep a state a sequence (scan or conv), in
+        order: a layer's place here is its place in the state pool."""
+        return tuple(i for i in range(self.num_layers)
+                     if self.is_state_layer(i))
 
     @property
     def attention_layers(self) -> tuple:
         """The layers that own KV pages, in order: all of them unless
         ``layer_types`` says otherwise."""
         return tuple(i for i in range(self.num_layers)
-                     if not self.is_scan_layer(i))
+                     if not self.is_state_layer(i))
 
     @property
     def ssm_inner(self) -> int:
@@ -1109,6 +1145,44 @@ class Mamba2Mixer(nn.Module):
         return y.astype(x.dtype) @ w_out.astype(x.dtype)
 
 
+def short_conv_gates(bcx):
+    """A short-conv layer's projected rows ``[..., 3 H]`` = ``[B | C | X]``
+    -> (the conv's input ``B * X``, the output gate ``C``)."""
+    b, c, x = jnp.split(bcx, 3, axis=-1)
+    return b * x, c
+
+
+class ShortConvMixer(nn.Module):
+    """A gated short convolution (LFM2's ``conv`` layers) on whole sequences
+    ``x [B, T, H]``, from a zero tail:
+
+        [B | C | X] = W_in x;   u = B * X
+        v_t = sum_j w[:, j] u_{t - K + 1 + j}      (depthwise, K = conv_taps,
+                                                    no bias, no activation)
+        out = W_out (C * v)
+
+    The serving engine computes the same from these parameters with a
+    carried tail, the last ``K - 1`` rows of ``u`` (inference/v2/model.py)."""
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from deepspeed_tpu import ops
+        c = self.cfg
+        H, K = c.hidden_size, c.conv_taps
+        w_in = self.param("w_in", _part(_kernel_init(), ("embed", "mlp")),
+                          (H, 3 * H), c.param_dtype)
+        conv_w = self.param("conv_w", _part(_conv_init(K), ("mlp", None)),
+                            (H, K), c.param_dtype)
+        w_out = self.param("w_out", _part(_kernel_init(), ("mlp", "embed")),
+                           (H, H), c.param_dtype)
+        u, gate = short_conv_gates(x @ w_in.astype(x.dtype))
+        v, _ = ops.causal_conv1d(
+            u, conv_w, None, jnp.zeros((x.shape[0], K - 1, H), u.dtype),
+            activation=None)
+        return (gate * v) @ w_out.astype(x.dtype)
+
+
 class MLP(nn.Module):
     cfg: GPTConfig
     mesh: Optional[object] = None
@@ -1156,7 +1230,9 @@ class Block(nn.Module):
     mesh: Optional[object] = None
     attn_cfg: Optional[GPTConfig] = None   # this layer's attention view
     #                                        (GPTConfig.for_layer); None: cfg
-    scan: bool = False                     # a scan layer: Mamba2Mixer mixes
+    mixer: str = "attention"               # GPTConfig.layer_kind: "mamba"
+    #                                        (Mamba2Mixer) | "conv"
+    #                                        (ShortConvMixer) mix instead
 
     @nn.compact
     def __call__(self, x, positions, deterministic: bool,
@@ -1188,10 +1264,11 @@ class Block(nn.Module):
             # ln_attn + ln_mlp pair) and their outputs sum into one residual
             # add (reference inference/v2/model_implementations/falcon,
             # module_inject/containers/ — parallel_attn semantics).
-            if (self.is_moe or c.sandwich_norm or c.mla or self.scan
+            if (self.is_moe or c.sandwich_norm or c.mla
+                    or self.mixer != "attention"
                     or c.residual_scale is not None):
                 raise ValueError("parallel_block + MoE / sandwich_norm / "
-                                 "latent attention / scan layers / "
+                                 "latent attention / scan or conv layers / "
                                  "residual_scale is not a supported "
                                  "architecture combination")
             h_attn = Norm(c)(x)                       # Norm_0
@@ -1205,13 +1282,14 @@ class Block(nn.Module):
                     + pld_gate(MLP(c, mesh=self.mesh)(h_mlp, deterministic,
                                                       use_cache=use_cache)),
                     jnp.float32(0.0))
-        if self.scan:
+        if self.mixer != "attention":
             if use_cache:
                 raise NotImplementedError(
-                    "a scan layer through the dense KV-cache path: its state "
-                    "lives in the v2 engine's pool (inference/v2); the v1 "
-                    "cache holds keys and values only")
-            a = Mamba2Mixer(c)(Norm(c)(x))
+                    "a scan or conv layer through the dense KV-cache path: "
+                    "its state lives in the v2 engine's pool (inference/v2); "
+                    "the v1 cache holds keys and values only")
+            mix = Mamba2Mixer if self.mixer == "mamba" else ShortConvMixer
+            a = mix(c)(Norm(c)(x))
         else:
             attn = (MLAttention(self.attn_cfg or c, mesh=self.mesh,
                                 name="Attention_0") if c.mla
@@ -1236,6 +1314,7 @@ class Block(nn.Module):
                                router=c.moe_router,
                                route_norm=c.moe_route_norm,
                                route_scale=c.moe_route_scale,
+                               route_eps=c.moe_route_eps,
                                router_bias=c.moe_router_bias,
                                shared_dim=c.moe_shared_dim,
                                experts_held=c.experts_held,
@@ -1324,10 +1403,10 @@ class GPTBackbone(nn.Module):
         ltd_layers = tuple(c.random_ltd_layer_ids or ())
         aux_total = jnp.float32(0.0)
         for i in range(c.num_layers):
-            scan = c.is_scan_layer(i)
+            kind = c.layer_kind(i)
             block = block_cls(c, c.is_moe_layer(i), self.mesh,
-                              None if scan else c.for_layer(i), scan,
-                              name=f"block_{i}")
+                              c.for_layer(i) if kind == "attention" else None,
+                              kind, name=f"block_{i}")
             keep = None
             if pld_theta is not None:
                 from deepspeed_tpu.runtime.progressive_layer_drop import \
@@ -1532,14 +1611,16 @@ def count_params(cfg: GPTConfig) -> int:
             + cfg.kv_heads * cfg.head_dim * H * 2              # wk, wv
             + (2 * cfg.head_dim if cfg.qk_norm else 0))
     per_norms = H * norms * (1 if cfg.use_rmsnorm else 2)
-    n_scan = len(cfg.scan_layers)
+    n_scan, n_conv = len(cfg.scan_layers), len(cfg.conv_layers)
     # a scan layer's mixer: w_in, w_out, the conv, dt_bias/A_log/D, the norm
     scan = (H * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads)
             + cfg.ssm_inner * H
             + cfg.ssm_conv_dim * (cfg.ssm_conv + int(cfg.ssm_conv_bias))
             + 3 * cfg.ssm_heads + cfg.ssm_inner)
-    attn = ((attn + per_norms) * (cfg.num_layers - n_scan)
-            + (scan + per_norms) * n_scan)
+    # a short-conv layer's mixer: w_in [H, 3H], w_out [H, H], the conv
+    conv = H * 3 * H + H * H + H * cfg.conv_taps
+    attn = ((attn + per_norms) * (cfg.num_layers - n_scan - n_conv)
+            + (scan + per_norms) * n_scan + (conv + per_norms) * n_conv)
     if cfg.mla:             # a layer's own geometry (GPTConfig.for_layer)
         attn = sum(_mla_params(cfg.for_layer(i))
                    + H * norms * (1 if cfg.use_rmsnorm else 2)

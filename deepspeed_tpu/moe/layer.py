@@ -196,6 +196,7 @@ class MoE(nn.Module):
     router: str = "softmax"
     route_norm: bool = True
     route_scale: float = 1.0
+    route_eps: float = 1e-20
     router_bias: bool = False
     shared_dim: int = 0
     experts_held: Optional[int] = None
@@ -300,7 +301,7 @@ class MoE(nn.Module):
         logits = jnp.dot(tokens, wg.astype(x.dtype),
                          preferred_element_type=jnp.float32)
         idx, w = sigmoid_topk(logits, self.k, bias, self.route_norm,
-                              self.route_scale)
+                              self.route_scale, self.route_eps)
         out = _expert_ffn_ragged(tokens, idx, w, wi, wo, weg,
                                  expert_offset=self.expert_offset,
                                  num_experts=E)

@@ -185,18 +185,19 @@ def dropless_topk(logits: jax.Array, k: int,
 
 def sigmoid_topk(logits: jax.Array, k: int, bias: Optional[jax.Array] = None,
                  route_norm: bool = True, route_scale: float = 1.0,
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 eps: float = 1e-20) -> Tuple[jax.Array, jax.Array]:
     """Sigmoid router (afmoe / Trinity; DeepSeek-V3's form): (expert_idx
     [S, k] int32, weights [S, k] float32).  Scores are ``sigmoid(logits)``
     in float32, one per expert and independent of the others; ``bias``
     (the published ``expert_bias``, kept level by the load balancer) is
     added for the SELECTION of the top k only, and the weights are the
-    chosen experts' own scores, renormalised to sum to one (``route_norm``)
-    and scaled by ``route_scale``."""
+    chosen experts' own scores, renormalised to sum to one (``route_norm``:
+    over their sum + ``eps``, afmoe's 1e-20, LFM2's 1e-6) and scaled by
+    ``route_scale``."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     sel = s if bias is None else s + bias.astype(jnp.float32)
     _, idx = jax.lax.top_k(sel, k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if route_norm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), w * route_scale

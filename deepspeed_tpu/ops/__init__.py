@@ -198,6 +198,8 @@ register_op("ssm_chunk_scan", xla=_ssm.xla_ssm_chunk_scan)
 register_op("ssm_state_update", xla=_ssm.xla_ssm_state_update,
             pallas=_ssm.pallas_ssm_state_update,
             supported=_ssm.state_update_supported)
+# (SiLU after the conv by default, a Mamba-2 layer's; activation=None: none,
+# a short-conv layer's)
 register_op("causal_conv1d", xla=_ssm.xla_causal_conv1d)
 
 
@@ -218,11 +220,13 @@ def ssm_state_update(x, dt, A, B, C, D, pool, layer=0, active=None,
                     active, fresh, impl=impl)
 
 
-def causal_conv1d(xBC, w, b, tail, count=None, *,
+def causal_conv1d(xBC, w, b, tail, count=None, *, activation="silu",
                   impl: Optional[str] = None):
-    """A scan layer's depthwise causal conv + SiLU with a carried tail ->
-    (out, tail') (ops/ssm_scan.py)."""
-    return dispatch("causal_conv1d", xBC, w, b, tail, count, impl=impl)
+    """A depthwise causal conv with a carried tail, then ``activation``:
+    "silu" (a scan layer's) or None (a short-conv layer's) -> (out, tail')
+    (ops/ssm_scan.py)."""
+    return dispatch("causal_conv1d", xBC, w, b, tail, count, activation,
+                    impl=impl)
 
 
 from deepspeed_tpu.ops.evoformer import evoformer_attention  # noqa: E402

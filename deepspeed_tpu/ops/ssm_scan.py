@@ -1,7 +1,8 @@
 """The three operations of a Mamba-2 (SSD) scan layer, each over many
 sequences at once and each carrying what a sequence leaves behind: the
 chunked scan with an initial and a final state a segment, the one-row
-recurrence of a decode step, and the depthwise causal conv with its tail.
+recurrence of a decode step, and the depthwise causal conv with its tail
+(which a gated short-convolution layer, LFM2's, takes without the SiLU).
 
 The recurrence, a head ``h`` of width ``p`` over a state ``[p, n]``
 (``dt`` after its softplus, ``A < 0``, ``B``/``C`` shared by the heads of a
@@ -296,13 +297,18 @@ def state_update_supported(x, dt, A, B, C, D, pool, layer=0, active=None,
             and pool.shape[-1] == LANES and pool.shape[-2] % 8 == 0)
 
 
-def xla_causal_conv1d(xBC, w, b, tail, count=None):
-    """Depthwise causal conv and its SiLU over every sequence, continued
-    from the ``K - 1`` rows before it: ``xBC [G, L, C]``, ``w [C, K]``,
-    ``b [C]`` or None, ``tail [G, K - 1, C]`` -> (out like ``xBC``, tail':
-    the last ``K - 1`` rows of the tail and the sequence's rows together).
-    ``count [G]``: rows behind a sequence's count are padding, and its
-    tail' ends at its last live row."""
+def xla_causal_conv1d(xBC, w, b, tail, count=None, activation="silu"):
+    """Depthwise causal conv over every sequence, continued from the ``K -
+    1`` rows before it, then ``activation``: "silu" (a Mamba-2 layer's conv
+    over its x, B and C channels) or None (a short-conv layer's, LFM2: the
+    plain weighted sum).  ``xBC [G, L, C]``, ``w [C, K]``, ``b [C]`` or
+    None, ``tail [G, K - 1, C]`` -> (out like ``xBC``, tail': the last ``K -
+    1`` rows of the tail and the sequence's rows together).  ``count [G]``:
+    rows behind a sequence's count are padding, and its tail' ends at its
+    last live row."""
+    if activation not in ("silu", None):
+        raise ValueError(f"causal_conv1d applies silu or nothing (None), "
+                         f"got activation={activation!r}")
     K = w.shape[1]
     dtype = xBC.dtype
     L = xBC.shape[1]
@@ -311,7 +317,9 @@ def xla_causal_conv1d(xBC, w, b, tail, count=None):
     out = sum(rows[:, j:j + L].astype(F32) * wf[:, j] for j in range(K))
     if b is not None:
         out = out + b.astype(F32)
-    out = jax.nn.silu(out).astype(dtype)
+    if activation == "silu":
+        out = jax.nn.silu(out)
+    out = out.astype(dtype)
     if count is None:
         new_tail = rows[:, L:]
     else:                       # rows count .. count + K - 1 of tail + rows

@@ -94,6 +94,7 @@ class ServingTelemetry:
         self.request_log: list = []
         self.request_log_cap = 100_000
         self.scan_layers = 0            # set_scan_state
+        self.state_kind = "ssm"
         if not self.enabled:
             return
         reg = self.registry
@@ -274,21 +275,43 @@ class ServingTelemetry:
             "prefill kernel and not by the row gather "
             "(ops.sparse_index.masked_prefill of the step's reach)")
 
-        # ---- scan layers (layer_types): the rows they mix by path, and
-        # the resident that does not grow, one state slot a sequence
-        self.c_ssm_rows = reg.counter(
-            "serving_ssm_rows_total", "rows through the scan layers, rows "
-            "times scan layers, per path (chunk = a prompt chunk's rows "
-            "through the chunked scan / step = a one-row slot's through "
-            "the recurrence)")
-        self.g_ssm_slots = reg.gauge(
-            "ssm_state_slots_in_use", "scan-state slots held by tracked "
-            "sequences (one a sequence, allocated with it and freed with "
-            "it) at the most recent dispatch")
-        self.g_ssm_bytes = reg.gauge(
-            "ssm_state_bytes_per_slot", "device bytes of one sequence's "
-            "state slot over all scan layers: the float32 recurrent state "
-            "and the conv's last rows")
+        # ---- state layers (layer_types): the rows they mix by path, and
+        # the resident that does not grow, one state slot a sequence; under
+        # the names of the kind the model has ("ssm": Mamba-2 scan layers,
+        # a float32 state and a conv tail; "conv": gated short
+        # convolutions, a conv tail alone), which set_scan_state picks
+        self._state_metrics = {
+            "ssm": (
+                reg.counter(
+                    "serving_ssm_rows_total", "rows through the scan "
+                    "layers, rows times scan layers, per path (chunk = a "
+                    "prompt chunk's rows through the chunked scan / step = "
+                    "a one-row slot's through the recurrence)"),
+                reg.gauge(
+                    "ssm_state_slots_in_use", "scan-state slots held by "
+                    "tracked sequences (one a sequence, allocated with it "
+                    "and freed with it) at the most recent dispatch"),
+                reg.gauge(
+                    "ssm_state_bytes_per_slot", "device bytes of one "
+                    "sequence's state slot over all scan layers: the "
+                    "float32 recurrent state and the conv's last rows")),
+            "conv": (
+                reg.counter(
+                    "serving_conv_rows_total", "rows through the "
+                    "short-conv layers, rows times conv layers, per path "
+                    "(chunk = a prompt chunk's rows, convolved from its "
+                    "slot's tail / step = a one-row slot's, which shifts "
+                    "its tail)"),
+                reg.gauge(
+                    "conv_state_slots_in_use", "conv-tail slots held by "
+                    "tracked sequences (one a sequence, allocated with it "
+                    "and freed with it) at the most recent dispatch"),
+                reg.gauge(
+                    "conv_state_bytes_per_slot", "device bytes of one "
+                    "sequence's state slot over all short-conv layers: "
+                    "the conv's last rows, in the compute dtype"))}
+        self.c_ssm_rows, self.g_ssm_slots, self.g_ssm_bytes = \
+            self._state_metrics["ssm"]
 
     # ------------------------------------------------------------- clocks
 
@@ -427,20 +450,26 @@ class ServingTelemetry:
             for k, v in self.kv_bytes_groups.items():
                 self.g_kv_bytes.set(v, part=k, **self.labels)
 
-    def set_scan_state(self, layers: int, bytes_per_slot: int) -> None:
-        """A model with scan layers, once at start-up: how many it has and
-        the bytes of one sequence's state slot over all of them (the
-        recurrent state and the conv's tail)."""
+    def set_scan_state(self, layers: int, bytes_per_slot: int,
+                       kind: str = "ssm") -> None:
+        """A model with state layers, once at start-up: how many it has,
+        the bytes of one sequence's state slot over all of them (whatever
+        parts the pool has: a conv tail, and a recurrent state where layers
+        scan) and the kind, "ssm" or "conv", whose names the rows, the slots
+        and the bytes are reported under."""
         self.scan_layers = int(layers)
         self.ssm_bytes_per_slot = int(bytes_per_slot)
+        self.state_kind = kind
         if self.enabled:
+            (self.c_ssm_rows, self.g_ssm_slots,
+             self.g_ssm_bytes) = self._state_metrics[kind]
             self.g_ssm_bytes.set(int(bytes_per_slot), **self.labels)
 
     def ssm_rows(self, rows, steps: int = 1) -> None:
-        """One dispatch's rows through the scan layers, by path: a slot
-        with one row takes the recurrence (``step``), one with more the
-        chunked scan (``chunk``); ``rows``: each scheduled sequence's,
-        ``steps``: of a fused burst.  Rows times scan layers."""
+        """One dispatch's rows through the state layers, by path: a slot
+        with one row takes the one-row route (``step``), one with more the
+        chunked one (``chunk``); ``rows``: each scheduled sequence's,
+        ``steps``: of a fused burst.  Rows times state layers."""
         if self.enabled and self.scan_layers:
             n = self.scan_layers * steps
             self.c_ssm_rows.inc(n * sum(r for r in rows if r > 1),
@@ -486,13 +515,14 @@ class ServingTelemetry:
         if self.scan_layers:
             slots = state.scan_slots_in_use
             self.g_ssm_slots.set(slots, **self.labels)
-            note.update(
-                ssm_chunk_rows=int(self.c_ssm_rows.value(
+            kind = self.state_kind
+            note.update({
+                f"{kind}_chunk_rows": int(self.c_ssm_rows.value(
                     path="chunk", **self.labels)),
-                ssm_step_rows=int(self.c_ssm_rows.value(
+                f"{kind}_step_rows": int(self.c_ssm_rows.value(
                     path="step", **self.labels)),
-                ssm_slots=slots,
-                ssm_state_bytes_per_slot=self.ssm_bytes_per_slot)
+                f"{kind}_slots": slots,
+                f"{kind}_state_bytes_per_slot": self.ssm_bytes_per_slot})
         total = self.c_moe_assign.value(**self.labels)
         if total:
             note.update(

@@ -426,7 +426,7 @@ def test_scan_state_pool_stays_in_place(topo, monkeypatch):
     the whole model): the decode step and the burst update them in place
     through the ``ssm_state_update`` kernel, whose output is the pool, and
     the mixed step's loop over prompt chunks gathers and scatters the few
-    slots it scans (``SCAN_GROUP`` = 4 states a pass, which it may
+    slots it scans (``_Mamba2.group`` = 4 states a pass, which it may
     transpose: a quarter of a layer here).  The pool and the conv-tail pool
     are donated and aliased to the outputs."""
     from conftest import lower_serving_steps
@@ -456,6 +456,50 @@ def test_scan_state_pool_stays_in_place(topo, monkeypatch):
                 if dt == "f32" and n >= layer:
                     moved.append(f"{op} -> {dt}[{dims}]")
         assert not moved, (name, moved)
+        assert compiled.memory_analysis().alias_size_in_bytes >= pools, name
+
+
+# the three kinds of LFM2-24B-A2B's layers at its published widths: a dense
+# conv layer, an attention layer and a conv layer with all 64 experts
+LFM2_3L = GPTConfig(
+    vocab_size=4096, num_layers=3, num_heads=32, num_kv_heads=8, head_dim=64,
+    hidden_size=2048, mlp_dim_override=11776, max_seq_len=8448,
+    use_rope=True, rope_theta=1e6, use_rmsnorm=True, gated_mlp=True,
+    norm_eps=1e-5, qk_norm=True, tie_embeddings=True,
+    layer_types=("conv", "attention", "conv"), conv_taps=3, num_experts=64,
+    moe_k=4, moe_dropless=True, moe_router="sigmoid", moe_router_bias=True,
+    moe_route_eps=1e-6, moe_expert_dim=1536, moe_dense_layers=1)
+
+
+def test_conv_tail_pool_is_the_whole_state_and_stays_in_place(topo,
+                                                              monkeypatch):
+    """The step programs of a model with short-conv layers at the
+    benchmark cell's sizes (64 slots, a forward of 1,024 rows, 66 pages a
+    slot): a conv-tail pool ``[conv layers, slots, 2 x 2,048]`` and no
+    ``ssm`` array; the pools donated and aliased to the outputs; the expert
+    layers through the grouped GEMM kernel and no ``ragged_dot``; both
+    paged kernels on the one attention layer."""
+    from conftest import lower_serving_steps
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(LFM2_3L, dtype=BF16, param_dtype=BF16,
+                              attn_impl="pallas")
+    S = 64
+    _, cache, lowered = lower_serving_steps(
+        cfg, BF16, slots=S, tokens=1024, max_q=1024, table_width=66,
+        block_size=128, num_pages=S * 66, steps=8,
+        sharding=SingleDeviceSharding(topo.devices[0]))
+    assert cache.ssm is None and cache.conv.shape == (2, S, 2 * 2048)
+    assert cache.k.shape[0] == 1
+    pools = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                for a in (cache.k, cache.v, cache.conv))
+    for name, low in lowered.items():
+        compiled = low.compile()
+        text = compiled.as_text()
+        assert "grouped_gemm_gate_up" in text and "ragged-dot" not in text, \
+            name
+        assert "/short_conv/" in text and "/paged_decode/" in text, name
+        assert ("/ragged_prefill/" in text) == (
+            name == "ragged_forward_sampled"), name
         assert compiled.memory_analysis().alias_size_in_bytes >= pools, name
 
 
