@@ -12,8 +12,17 @@ import os
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=8").strip()
+    _flags += " --xla_force_host_platform_device_count=8"
+# Nearly all of the suite's CPU is XLA:CPU compiling programs of two layers
+# and width 32, and no test reads their speed: the backend skips its
+# optimisation passes (a third off the compile, a fifth off a file; the HLO
+# and the floating-point rules stay as they are).  Children the tests start
+# inherit both from the environment, as they do the device count.
+for _f in ("--xla_backend_optimization_level=0",
+           "--xla_llvm_disable_expensive_passes=true"):
+    if _f.split("=")[0] not in _flags:
+        _flags += " " + _f
+os.environ["XLA_FLAGS"] = _flags.strip()
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
@@ -21,12 +30,13 @@ import pytest  # noqa: E402
 
 
 # ---- quick tier (VERDICT r2 weak #10): `pytest -m quick` runs the core-
-# correctness slice (~7 min measured single-core: engine 273s + ops 123s +
-# config/mesh 9s) for the fast inner loop; the full suite stays the merge
-# gate.
+# correctness slice (~9 min of test seconds as PR 47's six-worker run counted
+# them: engine 129 s + the three ops files 427 s, config/mesh under a second;
+# 1,233 s before that PR) for the fast inner loop; the full suite stays the
+# merge gate.
 QUICK_MODULES = {
     "test_config.py", "test_mesh_partition.py", "test_engine.py",
-    "test_ops.py",
+    "test_ops.py", "test_ops_paged.py", "test_ops_ragged.py",
 }
 
 
@@ -43,6 +53,34 @@ def pytest_collection_modifyitems(config, items):
         mod = it.nodeid.split("::")[0].rsplit("/", 1)[-1]
         if mod in QUICK_MODULES:
             it.add_marker(pytest.mark.quick)
+
+
+# ``InferenceEngineV2`` jits its step programs per instance, so two engines of
+# one configuration share nothing in jax's own cache, and the tests build
+# engines by the hundred.  Handed one ``steps_cache``, engines of one
+# configuration compile once; the engine keeps configurations apart by a
+# fingerprint of all its programs close over (model, block size, dtype, draft,
+# mesh, quantization, adapters).  An engine test takes this helper UNLESS it
+# patches what a trace reads (its patched program would stay for the next
+# test, or it would take an unpatched one) or counts compiles, traces or
+# cache misses (sharing changes exactly that): those build a private
+# ``InferenceEngineV2`` and say which of the two in a comment.
+_STEPS = {}
+
+
+def v2_engine(model, config=None, **kw):
+    """An ``InferenceEngineV2`` on the module's shared step programs: fresh
+    pool, fresh request state, nothing compiled twice."""
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    return InferenceEngineV2(model, config, steps_cache=_STEPS, **kw)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _steps_of_one_module():
+    """No file leans on a program another left behind (and a worker does not
+    hold every module's executables to the end of the run)."""
+    yield
+    _STEPS.clear()
 
 
 @pytest.fixture(scope="session")

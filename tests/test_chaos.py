@@ -642,8 +642,13 @@ class TestGuardianHang:
                    JAX_PLATFORMS="cpu")
         env.pop("XLA_FLAGS", None)
         t0 = time.time()
+        # the child starts, compiles one train step, runs seven and hangs
+        # in the eighth; the watchdog then has it out within deadline +
+        # grace (about 30 s in all on a loaded sandbox, nearly all of it
+        # start-up and the compile).  The limit is that and under a minute
+        # more: a child that sat out its 120 s sleep would not fit in it.
         proc = subprocess.run([_sys.executable, script], env=env,
-                              capture_output=True, text=True, timeout=300)
+                              capture_output=True, text=True, timeout=75)
         wall = time.time() - t0
         assert proc.returncode == resilience_EXIT_DRAINED, proc.stderr[-2000:]
         # the watchdog reacted at deadline+grace, it did not sit out the

@@ -67,7 +67,7 @@ def test_overlap_flags_are_accepted_by_the_installed_libtpu():
                **{LIBTPU_ENV: " ".join(flags)})
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     r = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
-                       capture_output=True, text=True, timeout=300)
+                       capture_output=True, text=True, timeout=60)
     refused = "Unknown command line flag" in r.stderr
     if r.returncode and not refused and "get_topology_desc" in r.stderr:
         pytest.skip("the child cannot describe a v5e:2x2 topology here "

@@ -121,8 +121,9 @@ class TestUNet:
         t = jnp.asarray([100])
         c1 = jax.random.normal(jax.random.PRNGKey(2), (1, 7, 32))
         c2 = jax.random.normal(jax.random.PRNGKey(3), (1, 7, 32))
-        o1 = unet_forward(params, x, t, c1, cfg)
-        o2 = unet_forward(params, x, t, c2, cfg)
+        forward = jax.jit(      # one program, not one a primitive
+            lambda c: unet_forward(params, x, t, c, cfg))
+        o1, o2 = forward(c1), forward(c2)
         assert np.abs(np.asarray(o1) - np.asarray(o2)).max() > 1e-6
 
     def test_timestep_actually_conditions(self, tiny_unet):

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import v2_engine
 
 from deepspeed_tpu import ops
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
@@ -89,16 +90,14 @@ def model(sz, max_seq_len=512, seed=0, **cfg_over):
     return cfg, _PARAMS[key]
 
 
-STEPS = {}      # engines of one configuration share their compiled steps
-
-
-def engine(cfg, params, top=None, **sm):
+def engine(cfg, params, top=None, build=v2_engine, **sm):
+    """``build=InferenceEngineV2``: a private engine, for a case that reads
+    what its own traces log."""
     manager = {"max_tracked_sequences": 4, "max_ragged_sequence_count": 4,
                "max_ragged_batch_size": 128, "max_q_per_seq": 32,
                "kv_block_size": 16, "num_kv_blocks": 64, **sm}
-    return InferenceEngineV2(cfg, {"dtype": "float32", **(top or {}),
-                                   "state_manager": manager}, params=params,
-                             steps_cache=STEPS)
+    return build(cfg, {"dtype": "float32", **(top or {}),
+                       "state_manager": manager}, params=params)
 
 
 def two_layers(**over):
@@ -133,7 +132,8 @@ def test_flax_logits_match_the_reference():
     sz = sizes()
     cfg, params = model(sz)
     ids = np.random.default_rng(0).integers(0, V, size=70)
-    got = GPTLogits(cfg).apply({"params": params}, jnp.asarray(ids)[None])[0]
+    got = jax.jit(GPTLogits(cfg).apply)(         # one program, not one an op
+        {"params": params}, jnp.asarray(ids)[None])[0]
     want = ref.logits(params, ids, sz)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
 
@@ -245,7 +245,8 @@ def test_tokens_are_the_same_whichever_way_a_chunk_reads_its_keys(monkeypatch):
     tokens, masked = {}, {}
     for reach in (0, 512):
         monkeypatch.setattr(sparse_index, "MASKED_REACH", reach)
-        eng = InferenceEngineV2(      # its own step programs: no cache
+        # a private engine: the patched reach is read when a step traces
+        eng = InferenceEngineV2(
             cfg, {"dtype": "float32", "generation": {"do_sample": False},
                   "state_manager": {
                       "max_tracked_sequences": 4,
@@ -344,7 +345,8 @@ def test_a_table_no_wider_than_the_selection_takes_the_dense_kernels():
     from deepspeed_tpu.ops.registry import dispatch_log, reset_dispatch_log
     sz = two_layers(index_topk=64)
     cfg, params = model(sz, max_seq_len=64)
-    eng = engine(cfg, params)
+    # a private engine: the log is what its own traces wrote
+    eng = engine(cfg, params, build=InferenceEngineV2)
     ids = np.random.default_rng(4).integers(0, V, size=40)
     reset_dispatch_log()
     got, rows = _serve(eng, [ids], chunk=32, tail=0)       # 3 pages of 16
@@ -384,7 +386,8 @@ def test_eight_shares_and_the_shared_expert_once_add_up_to_the_layer():
     sz2 = two_layers(n_routed_experts=2, expert_offset=6)
     cfg2, params2 = model(sz2)
     ids = np.random.default_rng(6).integers(0, V, size=30)
-    got = GPTLogits(cfg2).apply({"params": params2}, jnp.asarray(ids)[None])
+    got = jax.jit(GPTLogits(cfg2).apply)(
+        {"params": params2}, jnp.asarray(ids)[None])
     np.testing.assert_allclose(np.asarray(got[0]),
                                np.asarray(ref.logits(params2, ids, sz2)),
                                atol=1e-4)

@@ -39,7 +39,7 @@ def _worker_env(run_dir, *, rank=0, world=1, batch=8, micro=4, restart=0,
     return env
 
 
-def _wait_for_losses(run_dir, n, timeout=240):
+def _wait_for_losses(run_dir, n, timeout=60):
     path = os.path.join(run_dir, "losses.txt")
     deadline = time.time() + timeout
     while time.time() < deadline:
@@ -114,7 +114,7 @@ def test_worker_drains_on_sigterm_and_resumes(tmp_path):
     try:
         _wait_for_losses(run_dir, 3)
         p.send_signal(signal.SIGTERM)       # the preemption notice
-        rc = p.wait(timeout=240)
+        rc = p.wait(timeout=60)     # a drain: one export, seconds
     finally:
         if p.poll() is None:
             p.kill()
@@ -128,7 +128,7 @@ def test_worker_drains_on_sigterm_and_resumes(tmp_path):
     # replacement incarnation: resumes at the drained step and finishes
     r = subprocess.run([sys.executable, SCRIPT],
                        env=_worker_env(run_dir, restart=1), cwd=REPO,
-                       timeout=420)
+                       timeout=75)     # a dozen steps after one compile
     assert r.returncode == 0
     rows = [ln.split() for ln in
             open(os.path.join(run_dir, "losses.txt")).read().splitlines()]
@@ -152,7 +152,7 @@ def test_worker_host_loss_mid_export_resumes_from_previous(tmp_path):
         [sys.executable, SCRIPT],
         env=_worker_env(run_dir, extra={
             "DSTPU_FAULTS": "host_loss@universal.mid_fragments+2"}),
-        cwd=REPO, timeout=420)
+        cwd=REPO, timeout=75)
     assert r.returncode == HOST_LOSS_EXIT_CODE
     src = latest_universal(run_dir)
     assert src is not None
@@ -161,7 +161,7 @@ def test_worker_host_loss_mid_export_resumes_from_previous(tmp_path):
 
     r = subprocess.run([sys.executable, SCRIPT],
                        env=_worker_env(run_dir, restart=1), cwd=REPO,
-                       timeout=420)
+                       timeout=75)     # a dozen steps after one compile
     assert r.returncode == 0
     rows = [ln.split() for ln in
             open(os.path.join(run_dir, "losses.txt")).read().splitlines()]

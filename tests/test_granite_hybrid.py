@@ -153,9 +153,9 @@ def test_conv_carries_its_tail(cut):
 # ------------------------------------------------------------------ the model
 
 def test_the_model_is_the_reference(cfg, params, seqs, want):
-    lm = GPTLogits(cfg)
+    forward = jax.jit(GPTLogits(cfg).apply)     # a program a length
     for s, w in zip(seqs, want):
-        got = lm.apply({"params": params}, s[None])[0]
+        got = forward({"params": params}, s[None])[0]
         np.testing.assert_allclose(got, w, atol=TOL)
     n = sum(a.size for a in jax.tree_util.tree_leaves(params))
     assert n == count_params(cfg)
@@ -209,8 +209,9 @@ def test_gradient_through_the_mixer(cfg, params):
                 {**mp, "conv_b": mp.get("conv_b")}, u, heads=cfg.ssm_heads,
                 head_dim=cfg.ssm_head_dim, groups=cfg.ssm_groups,
                 state=cfg.ssm_state, eps=cfg.norm_eps) * probe)
-    got = jax.grad(ours, argnums=(0, 1))(mp, u)
-    exp = jax.grad(theirs, argnums=(0, 1))(mp, u)
+    # a program each, not one a primitive of an eager backward
+    got = jax.jit(jax.grad(ours, argnums=(0, 1)))(mp, u)
+    exp = jax.jit(jax.grad(theirs, argnums=(0, 1)))(mp, u)
     for (path, g), e in zip(jax.tree_util.tree_leaves_with_path(got),
                             jax.tree_util.tree_leaves(exp)):
         scale = float(jnp.abs(e).max()) + 1e-6
@@ -222,7 +223,7 @@ def test_the_loss_differentiates_through_every_layer(cfg, params):
     loss = lambda p: GPT(cfg).apply(  # noqa: E731
         {"params": p}, {"input_ids": jnp.arange(8)[None] % 128},
         deterministic=True)
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)      # one program, not one an op
     assert all(np.isfinite(a).all() for a in jax.tree_util.tree_leaves(g))
     for i in cfg.scan_layers:
         assert float(jnp.abs(g["backbone"][f"block_{i}"]["Mamba2Mixer_0"][

@@ -560,7 +560,7 @@ class TestTooling:
         p = subprocess.run(
             [sys.executable,
              os.path.join(REPO, "scripts", "check_no_sync.py"), str(bad)],
-            capture_output=True, text=True)
+            capture_output=True, text=True, timeout=60)
         assert p.returncode == 1
         assert "train_batch" in p.stderr
 
@@ -580,7 +580,7 @@ class TestTooling:
         p = subprocess.run(
             [sys.executable,
              os.path.join(REPO, "scripts", "check_no_sync.py"), str(src)],
-            capture_output=True, text=True)
+            capture_output=True, text=True, timeout=60)
         assert p.returncode == 0, p.stderr
 
     def test_postmortem_cli_module_smoke(self, tmp_path):
@@ -602,7 +602,7 @@ class TestTooling:
         p = subprocess.run(
             [sys.executable, "-m", "deepspeed_tpu.telemetry.postmortem",
              str(tmp_path / "postmortem")],
-            capture_output=True, text=True, cwd=REPO, env=env)
+            capture_output=True, text=True, cwd=REPO, env=env, timeout=60)
         assert p.returncode == 0, p.stderr
         assert "manual" in p.stdout and "step" in p.stdout
 

@@ -1070,7 +1070,7 @@ class TestPhi3:
         """A LONG sequence co-scheduled with a SHORT one in the ragged engine
         must not flip the short one onto the long factor table: each slot
         selects by ITS OWN kv length (per-token seq_lens in rope)."""
-        from deepspeed_tpu.inference.v2 import InferenceEngineV2
+        from conftest import v2_engine
         d = os.path.join(str(tmp_models), "phi3_longrope")
         assert os.path.exists(os.path.join(d, "config.json")), \
             "run test_phi3_longrope_short_and_long_regimes first (fixture)"
@@ -1080,10 +1080,10 @@ class TestPhi3:
               "generation": {"do_sample": False}}
         short_p = rng.integers(3, 128, (6,)).astype(np.int32)   # < orig 16
         long_p = rng.integers(3, 128, (22,)).astype(np.int32)   # > orig 16
-        eng_solo = InferenceEngineV2(d, sm)
+        eng_solo = v2_engine(d, sm)
         want_short = eng_solo.generate([short_p], max_new_tokens=4)[0]
         del eng_solo
-        eng_both = InferenceEngineV2(d, sm)
+        eng_both = v2_engine(d, sm)
         got = eng_both.generate([short_p, long_p], max_new_tokens=4)
         np.testing.assert_array_equal(got[0], want_short)
 

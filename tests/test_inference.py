@@ -49,6 +49,7 @@ class TestGenerate:
         S = engine.model_config.max_seq_len
         b = jnp.asarray(rng.integers(0, 97, (1, 6)), jnp.int32)
 
+        @jax.jit        # a program a length, not one a primitive
         def prefill(ids, mask):
             L = ids.shape[1]
             positions = jnp.maximum(jnp.cumsum(mask, axis=1) - 1, 0)

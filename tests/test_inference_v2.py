@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import v2_engine
 
 from deepspeed_tpu.inference.v2 import (BlockedAllocator, DSStateManager,
                                         InferenceEngineV2)
@@ -27,13 +28,17 @@ def v2cfg():
 
 @pytest.fixture()
 def engine(cfg, v2cfg):
-    return InferenceEngineV2(cfg, config=v2cfg, seed=0)
+    return v2_engine(cfg, config=v2cfg, seed=0)
+
+
+# one program a (model, length), not one a primitive of an eager forward
+_forward = jax.jit(lambda lm, params, ids: lm.apply({"params": params}, ids),
+                   static_argnums=0)
 
 
 def full_logits(cfg, engine, ids):
     """Ground truth: cache-free full forward on the same params."""
-    lm = GPTLogits(engine.model_config)
-    return np.asarray(lm.apply({"params": engine.params},
+    return np.asarray(_forward(GPTLogits(engine.model_config), engine.params,
                                jnp.asarray(ids, jnp.int32)))
 
 
@@ -60,14 +65,26 @@ class TestAllocator:
 
 # ------------------------------------------- one-row slots of a mixed step
 
-def _mixed_step(engine, uids, toks, prefill_route=False):
-    """What ``put`` does, but always through the mixed program (``put``
-    hands a step of one-row slots to the decode program): logits of the
-    scheduled slots.  ``prefill_route``: the step as it was before one-row
-    slots went to the paged decode op."""
+def _mixed_program(engine):
+    """The engine's mixed step program, jitted apart from ``engine._steps``:
+    the prefill route below patches what its trace reads."""
     import functools
 
     from deepspeed_tpu.inference.v2.model import ragged_forward
+    sm = engine.config.state_manager
+    return jax.jit(functools.partial(
+        ragged_forward, cfg=engine.model_config,
+        block_size=engine._block_size, max_q_per_seq=sm.max_q_per_seq,
+        **engine._model_static))
+
+
+def _mixed_step(engine, uids, toks, step=None):
+    """What ``put`` does, but always through the mixed program (``put``
+    hands a step of one-row slots to the decode program): logits of the
+    scheduled slots.  ``step``: the engine's ``_mixed_program``, traced once
+    for all its steps; without it, the step as it was before one-row slots
+    went to the paged decode op (a program of its own a step: the patched
+    ops close over that step's schedule)."""
     from deepspeed_tpu.inference.v2.ragged import build_ragged_batch
     sm = engine.config.state_manager
     schedule = []
@@ -81,13 +98,10 @@ def _mixed_step(engine, uids, toks, prefill_route=False):
         "tokens": rb.tokens, "token_slot": rb.token_slot,
         "token_pos": rb.token_pos,
         **rb.table_operands(), "kv_len": rb.kv_len})
-    step = jax.jit(functools.partial(
-        ragged_forward, cfg=engine.model_config,
-        block_size=engine._block_size, max_q_per_seq=sm.max_q_per_seq,
-        **engine._model_static))
     with pytest.MonkeyPatch.context() as m:
-        if prefill_route:
+        if step is None:
             _prefill_route_for_every_slot(m, rb.kv_len, rb.q_len)
+            step = _mixed_program(engine)
         logits, engine.cache = step(engine.params, engine.cache, batch)[:2]
     for seq, t in schedule:
         seq.seen_tokens += len(t)
@@ -121,7 +135,7 @@ def _one_row_engine(cfg=None, seed=0, **sm):
                               num_kv_heads=1, vocab_size=97, max_seq_len=64)
     manager = {"max_tracked_sequences": 4, "max_ragged_batch_size": 64,
                "kv_block_size": 8, "max_q_per_seq": 16, **sm}
-    return lambda: InferenceEngineV2(
+    return lambda: v2_engine(
         cfg, config={"dtype": "fp32", "state_manager": manager}, seed=seed)
 
 
@@ -227,9 +241,10 @@ class TestRaggedForward:
         slot through ``xla_ragged_prefill``)."""
         make, steps = ONE_ROW_CASES[case]
         new, old = make(), make()       # the same weights, from the seed
+        program = _mixed_program(new)
         for uids, toks in steps:
-            got = _mixed_step(new, uids, toks)
-            want = _mixed_step(old, uids, toks, prefill_route=True)
+            got = _mixed_step(new, uids, toks, program)
+            want = _mixed_step(old, uids, toks)
             np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
         for g, w in zip(new.cache, old.cache):
             assert (g is None) == (w is None)
@@ -281,7 +296,7 @@ class TestContinuousBatching:
         """Greedy continuous-batching output == v1 static-cache output, with
         more prompts than sequence slots (forces admission control)."""
         import deepspeed_tpu
-        engine = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        engine = v2_engine(cfg, config=v2cfg, seed=0)
         prompts = [rng.integers(0, 97, (n,)).astype(np.int32)
                    for n in (9, 23, 5, 30, 12, 7)]   # 6 prompts, 4 slots
         got = engine.generate(prompts, max_new_tokens=6)
@@ -295,7 +310,7 @@ class TestContinuousBatching:
         """max_new_tokens >= 8 with no waiting prompts engages the fused
         decode burst; output must equal the v1 static-cache engine."""
         import deepspeed_tpu
-        engine = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        engine = v2_engine(cfg, config=v2cfg, seed=0)
         prompts = [rng.integers(0, 97, (n,)).astype(np.int32)
                    for n in (9, 14)]
         got = engine.generate(prompts, max_new_tokens=16)
@@ -309,7 +324,7 @@ class TestContinuousBatching:
         queue/defer until finished sequences free blocks (this crashed with
         'KV cache exhausted' before block reservation moved to schedule
         time)."""
-        engine = InferenceEngineV2(cfg, config={
+        engine = v2_engine(cfg, config={
             "dtype": "fp32",
             "state_manager": {"max_tracked_sequences": 4,
                               "max_ragged_batch_size": 64,
@@ -324,16 +339,16 @@ class TestContinuousBatching:
         assert engine.query()["free_kv_blocks"] == 6
 
     def test_put_capacity_validation_leaves_state_clean(self, cfg, v2cfg):
-        engine = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        engine = v2_engine(cfg, config=v2cfg, seed=0)
         with pytest.raises(RuntimeError, match="free slots"):
             engine.put([1, 2, 3, 4, 5], [np.zeros(1, np.int32)] * 5)
         assert engine.state.free_sequence_slots == 4  # nothing leaked
 
     def test_generate_eos_stops(self, cfg, v2cfg, rng):
-        engine = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        engine = v2_engine(cfg, config=v2cfg, seed=0)
         p = rng.integers(0, 97, (8,)).astype(np.int32)
         ref = engine.generate([p], max_new_tokens=6)[0]
-        engine2 = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        engine2 = v2_engine(cfg, config=v2cfg, seed=0)
         got = engine2.generate([p], max_new_tokens=6,
                                eos_token_id=int(ref[0]))[0]
         assert len(got) == 1 and got[0] == ref[0]
@@ -346,8 +361,8 @@ class TestInt8KVCache:
 
     def mk(self, cfg, v2cfg, quant):
         sm = dict(v2cfg["state_manager"], kv_quant=quant)
-        return InferenceEngineV2(cfg, config={**v2cfg, "state_manager": sm},
-                                 seed=0)
+        return v2_engine(cfg, config={**v2cfg, "state_manager": sm},
+                         seed=0)
 
     def test_cache_bytes_halved(self, cfg, v2cfg):
         full = self.mk(cfg, v2cfg, None)
@@ -361,7 +376,7 @@ class TestInt8KVCache:
 
     def test_put_logits_close_to_unquantized(self, cfg, v2cfg, rng):
         full = self.mk(cfg, v2cfg, None)
-        q8 = InferenceEngineV2(
+        q8 = v2_engine(
             cfg, config={**v2cfg, "state_manager": dict(
                 v2cfg["state_manager"], kv_quant="int8")},
             params=full.params)
@@ -376,7 +391,7 @@ class TestInt8KVCache:
         quantized cache; greedy output should mostly agree with the
         unquantized engine (near-tie flips from quant noise allowed)."""
         full = self.mk(cfg, v2cfg, None)
-        q8 = InferenceEngineV2(
+        q8 = v2_engine(
             cfg, config={**v2cfg, "state_manager": dict(
                 v2cfg["state_manager"], kv_quant="int8")},
             params=full.params)
@@ -538,18 +553,19 @@ class TestKVWrite:
             assert not v2model.kv_major_layout(cfg)
         prompts = [rng.integers(0, 97, (n,)).astype(np.int32) for n in lens]
 
-        def run():
-            eng = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        def run(engine):
+            eng = engine(cfg, config=v2cfg, seed=0)
             if path == "speculative":
-                eng = InferenceEngineV2(cfg, config=v2cfg, params=eng.params,
-                                        draft_model=cfg)
+                eng = engine(cfg, config=v2cfg, params=eng.params,
+                             draft_model=cfg)
             out = eng.generate(prompts, max_new_tokens=new)
             if path == "speculative":
                 assert eng.telemetry.spec_summary()["outer_steps"] > 0
             return out
-        got = run()
+        got = run(v2_engine)
         monkeypatch.setattr(v2model, "_kv_write", _scatter_write)
-        want = run()
+        # private engines: the patch is read when the step programs trace
+        want = run(InferenceEngineV2)
         for w, g in zip(want, got):
             np.testing.assert_array_equal(w, g)
 
@@ -562,10 +578,10 @@ class TestSpeculative:
     def test_identical_draft_exact_and_accepts(self, cfg, v2cfg, rng):
         prompts = [rng.integers(0, 97, (10 + 3 * i,)).astype(np.int32)
                    for i in range(3)]
-        base = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        base = v2_engine(cfg, config=v2cfg, seed=0)
         want = base.generate(prompts, max_new_tokens=18)
-        spec = InferenceEngineV2(cfg, config=v2cfg, params=base.params,
-                                 draft_model=cfg, draft_params=base.params)
+        spec = v2_engine(cfg, config=v2cfg, params=base.params,
+                         draft_model=cfg, draft_params=base.params)
         got = spec.generate(prompts, max_new_tokens=18)
         for w, g in zip(want, got):
             np.testing.assert_array_equal(w, g)
@@ -583,11 +599,11 @@ class TestSpeculative:
     def test_random_draft_still_exact(self, cfg, v2cfg, rng):
         prompts = [rng.integers(0, 97, (12 + i,)).astype(np.int32)
                    for i in range(3)]
-        base = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        base = v2_engine(cfg, config=v2cfg, seed=0)
         want = base.generate(prompts, max_new_tokens=15)
         # draft_params=None -> fresh random draft (low acceptance)
-        spec = InferenceEngineV2(cfg, config=v2cfg, params=base.params,
-                                 draft_model=cfg)
+        spec = v2_engine(cfg, config=v2cfg, params=base.params,
+                         draft_model=cfg)
         got = spec.generate(prompts, max_new_tokens=15)
         for w, g in zip(want, got):
             np.testing.assert_array_equal(w, g)
@@ -597,13 +613,13 @@ class TestSpeculative:
         prompts = [rng.integers(0, 97, (11 + i,)).astype(np.int32)
                    for i in range(3)]
         budgets = [7, 13, 18]
-        base = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        base = v2_engine(cfg, config=v2cfg, seed=0)
         want = base.generate(prompts, max_new_tokens=budgets)
         eos = int(want[2][4])                  # force an early stop on seq 2
         want_eos = base.generate(prompts, max_new_tokens=budgets,
                                  eos_token_id=eos)
-        spec = InferenceEngineV2(cfg, config=v2cfg, params=base.params,
-                                 draft_model=cfg, draft_params=base.params)
+        spec = v2_engine(cfg, config=v2cfg, params=base.params,
+                         draft_model=cfg, draft_params=base.params)
         got = spec.generate(prompts, max_new_tokens=budgets,
                             eos_token_id=eos)
         for w, g in zip(want_eos, got):
@@ -644,10 +660,10 @@ class TestSpeculativeSampled:
         a deterministic end-to-end exercise of the rejection machinery."""
         prompts = [rng.integers(0, 97, (10 + 3 * i,)).astype(np.int32)
                    for i in range(3)]
-        base = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        base = v2_engine(cfg, config=v2cfg, seed=0)
         want = base.generate(prompts, max_new_tokens=14)
-        spec = InferenceEngineV2(cfg, config=v2cfg, params=base.params,
-                                 draft_model=cfg)   # random draft
+        spec = v2_engine(cfg, config=v2cfg, params=base.params,
+                         draft_model=cfg)   # random draft
         got = spec.generate(prompts, max_new_tokens=14, do_sample=True,
                             temperature=1e-5)
         for w, g in zip(want, got):
@@ -657,8 +673,8 @@ class TestSpeculativeSampled:
     def test_same_seed_reproduces(self, cfg, v2cfg, rng):
         prompts = [rng.integers(0, 97, (12 + i,)).astype(np.int32)
                    for i in range(2)]
-        mk = lambda: InferenceEngineV2(cfg, config=v2cfg, seed=0,
-                                       draft_model=cfg)
+        mk = lambda: v2_engine(cfg, config=v2cfg, seed=0,
+                               draft_model=cfg)
         a = mk().generate(prompts, max_new_tokens=16, seed=5,
                           do_sample=True, temperature=1.0)
         b = mk().generate(prompts, max_new_tokens=16, seed=5,
@@ -678,7 +694,7 @@ class TestSampledGenerate:
         reference's ragged serving)."""
         prompts = [rng.integers(0, 97, (12 + i,)).astype(np.int32)
                    for i in range(3)]
-        mk = lambda: InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        mk = lambda: v2_engine(cfg, config=v2cfg, seed=0)
         a = mk().generate(prompts, max_new_tokens=24, seed=7,
                           do_sample=True, temperature=1.0)
         b = mk().generate(prompts, max_new_tokens=24, seed=7,
@@ -696,7 +712,7 @@ class TestPreemption:
         """Two requests whose combined contexts exceed the pool (each fits
         alone): one must be preempted by recompute mid-generation and resumed
         after the other finishes — output must match an uncontended run."""
-        mk = lambda: InferenceEngineV2(cfg, config={
+        mk = lambda: v2_engine(cfg, config={
             "dtype": "fp32",
             "state_manager": {"max_tracked_sequences": 4,
                               "max_ragged_batch_size": 64,
@@ -706,7 +722,7 @@ class TestPreemption:
                    for _ in range(2)]
         # each needs ceil(32/8)=4 blocks; 2*4 > 6 -> preemption must fire
         got = mk().generate(prompts, max_new_tokens=12)
-        big = InferenceEngineV2(cfg, config={
+        big = v2_engine(cfg, config={
             "dtype": "fp32",
             "state_manager": {"max_tracked_sequences": 4,
                               "max_ragged_batch_size": 64,
@@ -722,7 +738,7 @@ class TestPreemption:
         strike a victim whose RE-prefill is still in flight — a second
         preemption must preserve the held continuation token and fold state
         (double-preemption regression; the fold must never be re-applied)."""
-        mk = lambda nb: InferenceEngineV2(cfg, config={
+        mk = lambda nb: v2_engine(cfg, config={
             "dtype": "fp32",
             "state_manager": {"max_tracked_sequences": 4,
                               "max_ragged_batch_size": 64,
@@ -730,8 +746,8 @@ class TestPreemption:
                               "num_kv_blocks": nb}}, seed=0)
         prompts = [rng.integers(0, 97, (18 + 3 * i,)).astype(np.int32)
                    for i in range(3)]
-        want = [mk(None).generate([p], max_new_tokens=14)[0]
-                for p in prompts]
+        big = mk(None)          # uncontended: one request at a time
+        want = [big.generate([p], max_new_tokens=14)[0] for p in prompts]
         mid_prefill_hits = 0
         for nb in (6, 7, 8):    # several pressure levels -> several
             eng = mk(nb)
@@ -744,7 +760,7 @@ class TestPreemption:
         assert mid_prefill_hits > 0
 
     def test_single_sequence_too_big_for_pool_raises(self, cfg, rng):
-        engine = InferenceEngineV2(cfg, config={
+        engine = v2_engine(cfg, config={
             "dtype": "fp32",
             "state_manager": {"max_tracked_sequences": 2,
                               "max_ragged_batch_size": 64,

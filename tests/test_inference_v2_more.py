@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import v2_engine
 from test_inference_v2 import cfg, engine, full_logits, v2cfg  # noqa: F401
 
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
@@ -33,10 +34,10 @@ class TestTensorParallel:
                                    "max_ragged_batch_size": 64,
                                    "kv_block_size": 8, "max_q_per_seq": 16},
                  "generation": {"do_sample": False}}
-        e1 = InferenceEngineV2(cfg2, config=v2cfg, seed=0)
-        e2 = InferenceEngineV2(cfg2, config={**v2cfg,
-                                             "tensor_parallel": {"tp_size": 2}},
-                               params={"params": e1.params}, seed=0)
+        e1 = v2_engine(cfg2, config=v2cfg, seed=0)
+        e2 = v2_engine(cfg2, config={**v2cfg,
+                                     "tensor_parallel": {"tp_size": 2}},
+                       params={"params": e1.params}, seed=0)
         assert e2.mesh is not None and e2.mesh.shape["tp"] == 2
         prompts = [rng.integers(0, 97, size=n).astype(np.int32)
                    for n in (5, 11, 3)]
@@ -79,6 +80,7 @@ class TestPrefillBuckets:
         different power-of-two block-table buckets must still match the
         cache-free forward exactly (the bucket slice only removes NEVER-USED
         pages)."""
+        # a private engine: the case counts the programs in ``eng._steps``
         eng = InferenceEngineV2(cfg, config=v2cfg, seed=0)
         rng = np.random.default_rng(7)
         prompt = rng.integers(0, 97, size=(50,)).astype(np.int32)  # 7 blocks
@@ -110,7 +112,7 @@ class TestQuantizedWeights:
         c = dict(v2cfg, quant=self.QCFG)
         if extra:
             c.update(extra)
-        return InferenceEngineV2(cfg, config=c, params=params, seed=0)
+        return v2_engine(cfg, config=c, params=params, seed=0)
 
     def test_store_is_int8_and_smaller(self, v2cfg):
         """Realistically-shaped config (divisible vocab, ≥16 heads-dim):
@@ -119,7 +121,7 @@ class TestQuantizedWeights:
         never group-quantize, which is the fallback path, tested above.)"""
         qcfg = GPTConfig.llama(num_layers=2, hidden=64, heads=16,
                                vocab_size=128, max_seq_len=64)
-        base = InferenceEngineV2(qcfg, config=v2cfg, seed=0)
+        base = v2_engine(qcfg, config=v2cfg, seed=0)
         q = self.mk(qcfg, v2cfg, params=base.params)
         fp_bytes = sum(l.size * l.dtype.itemsize for l in
                        jax.tree_util.tree_leaves(base.params))
@@ -130,7 +132,7 @@ class TestQuantizedWeights:
         assert np.dtype("int8") in kinds
 
     def test_logits_close_to_unquantized(self, cfg, v2cfg, rng):
-        base = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        base = v2_engine(cfg, config=v2cfg, seed=0)
         q = self.mk(cfg, v2cfg, params=base.params)
         prompts = [rng.integers(0, 97, (15,)).astype(np.int32)]
         lb = base.put([1], prompts)[0]
@@ -151,7 +153,7 @@ class TestQuantizedWeights:
     def test_quant_tp2_token_exact_vs_tp1(self, cfg, v2cfg, rng):
         """The quant × tp composition the round-3 verdict ordered: same int8
         codes sharded two ways must produce identical greedy tokens."""
-        base = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        base = v2_engine(cfg, config=v2cfg, seed=0)
         prompts = [rng.integers(0, 97, (12 + 3 * i,)).astype(np.int32)
                    for i in range(3)]
         q1 = self.mk(cfg, v2cfg, params=base.params)
@@ -165,13 +167,13 @@ class TestQuantizedWeights:
     def test_speculative_composes(self, cfg, v2cfg, rng):
         """Greedy spec decoding over a quantized target must match the
         quantized target-only output (exact-match acceptance invariant)."""
-        base = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        base = v2_engine(cfg, config=v2cfg, seed=0)
         prompts = [rng.integers(0, 97, (11,)).astype(np.int32)]
         q = self.mk(cfg, v2cfg, params=base.params)
         want = q.generate(prompts, max_new_tokens=10)
-        qs = InferenceEngineV2(cfg, config=dict(v2cfg, quant=self.QCFG),
-                               params=base.params, seed=0,
-                               draft_model=cfg, draft_params=base.params)
+        qs = v2_engine(cfg, config=dict(v2cfg, quant=self.QCFG),
+                       params=base.params, seed=0,
+                       draft_model=cfg, draft_params=base.params)
         got = qs.generate(prompts, max_new_tokens=10)
         np.testing.assert_array_equal(np.asarray(want[0]), np.asarray(got[0]))
 
@@ -184,7 +186,7 @@ class TestQuantizedWeights:
         mcfg = GPTConfig.llama(num_layers=2, hidden=64, heads=4,
                                vocab_size=128, max_seq_len=64)
         mcfg = dataclasses.replace(mcfg, num_experts=4, moe_k=2)
-        base = InferenceEngineV2(mcfg, config=v2cfg, seed=0)
+        base = v2_engine(mcfg, config=v2cfg, seed=0)
         q = self.mk(mcfg, v2cfg, params=base.params)
         assert any(l.dtype == np.dtype("int8")
                    for l in jax.tree_util.tree_leaves(q.params)), \
@@ -205,7 +207,7 @@ class TestQuantizedWeights:
         tcfg = GPTConfig.llama(num_layers=2, hidden=64, heads=4,
                                vocab_size=128, max_seq_len=64)
         tcfg = dataclasses.replace(tcfg, tie_embeddings=True)
-        base = InferenceEngineV2(tcfg, config=v2cfg, seed=0)
+        base = v2_engine(tcfg, config=v2cfg, seed=0)
         q = self.mk(tcfg, v2cfg, params=base.params)
         from deepspeed_tpu.ops.quantization import is_quantized_weight
         assert is_quantized_weight(q.params["backbone"]["wte"])
@@ -223,7 +225,9 @@ class TestKernelReach:
     on attention projections, under tensor parallelism, on packed int4
     stores, and on real (non-tiling) vocabs — asserted via the kernels'
     trace counters, not just output correctness (a silent dequant fallback
-    produces the same numbers while reading 2× the HBM)."""
+    produces the same numbers while reading 2× the HBM).  An engine whose
+    traces a case counts is a private ``InferenceEngineV2``: on shared step
+    programs a second engine of its configuration traces nothing."""
 
     KCFG = GPTConfig.llama(num_layers=2, hidden=128, heads=4,
                            vocab_size=128, max_seq_len=64)
@@ -235,7 +239,7 @@ class TestKernelReach:
     def test_kernel_engages_everywhere_single_shard(self, v2cfg, rng):
         """hidden=128/hd=32/group 32: QKV (dim-0 3-D view), attn-out
         (dim-1 3-D view), MLP, and untied lm_head all ride the W8 kernel."""
-        base = InferenceEngineV2(self.KCFG, config=v2cfg, seed=0)
+        base = v2_engine(self.KCFG, config=v2cfg, seed=0)
         before = self._counts()
         q = InferenceEngineV2(
             self.KCFG, config=dict(v2cfg, quant={"enabled": True,
@@ -255,10 +259,10 @@ class TestKernelReach:
     def test_kernel_engages_under_tp2(self, v2cfg, rng):
         """The round-4 bypass ran tp>1 on the dequant path; the shard_map
         wrapper must keep the kernel engaged AND reproduce tp=1 tokens."""
-        base = InferenceEngineV2(self.KCFG, config=v2cfg, seed=0)
+        base = v2_engine(self.KCFG, config=v2cfg, seed=0)
         qc = {"enabled": True, "group_size": 32}
-        q1 = InferenceEngineV2(self.KCFG, config=dict(v2cfg, quant=qc),
-                               params=base.params, seed=0)
+        q1 = v2_engine(self.KCFG, config=dict(v2cfg, quant=qc),
+                       params=base.params, seed=0)
         prompts = [rng.integers(0, 128, (12 + 3 * i,)).astype(np.int32)
                    for i in range(3)]
         got1 = q1.generate(prompts, max_new_tokens=10)
@@ -275,7 +279,7 @@ class TestKernelReach:
 
     def test_w4_kernel_engages(self, v2cfg, rng):
         """bits=4 now serves through the packed W4A16 kernel (group 64)."""
-        base = InferenceEngineV2(self.KCFG, config=v2cfg, seed=0)
+        base = v2_engine(self.KCFG, config=v2cfg, seed=0)
         before = self._counts()
         q = InferenceEngineV2(
             self.KCFG, config=dict(v2cfg, quant={"enabled": True, "bits": 4,
@@ -290,13 +294,13 @@ class TestKernelReach:
     def test_w4_tp2_matches_tp1(self, v2cfg, rng):
         """Nibble packing no longer forces single-shard: pack-after-shard
         keeps pairs/groups intact over tp=2 and tokens must match tp=1."""
-        base = InferenceEngineV2(self.KCFG, config=v2cfg, seed=0)
+        base = v2_engine(self.KCFG, config=v2cfg, seed=0)
         qc = {"enabled": True, "bits": 4, "group_size": 64}
         prompts = [rng.integers(0, 128, (12,)).astype(np.int32)]
-        q1 = InferenceEngineV2(self.KCFG, config=dict(v2cfg, quant=qc),
-                               params=base.params, seed=0)
+        q1 = v2_engine(self.KCFG, config=dict(v2cfg, quant=qc),
+                       params=base.params, seed=0)
         got1 = q1.generate(prompts, max_new_tokens=8)
-        q2 = InferenceEngineV2(
+        q2 = v2_engine(
             self.KCFG, config=dict(v2cfg, quant=qc,
                                    tensor_parallel={"tp_size": 2}),
             params=base.params, seed=0)
@@ -312,7 +316,7 @@ class TestKernelReach:
         tcfg = GPTConfig.llama(num_layers=2, hidden=128, heads=4,
                                vocab_size=250, max_seq_len=64)
         tcfg = dataclasses.replace(tcfg, tie_embeddings=True)
-        base = InferenceEngineV2(tcfg, config=v2cfg, seed=0)
+        base = v2_engine(tcfg, config=v2cfg, seed=0)
         before = self._counts()
         q = InferenceEngineV2(
             tcfg, config=dict(v2cfg, quant={"enabled": True,
@@ -349,7 +353,7 @@ class TestMoEDecode:
 
     def test_prefill_and_decode_match_training_forward(self, v2cfg, rng):
         mcfg = self._mcfg()
-        engine = InferenceEngineV2(mcfg, config=v2cfg, seed=0)
+        engine = v2_engine(mcfg, config=v2cfg, seed=0)
         ids = rng.integers(0, 128, (12,)).astype(np.int32)
         logits = engine.put([1], [ids])
         want = full_logits(mcfg, engine, ids[None])[0, -1]
@@ -365,7 +369,7 @@ class TestMoEDecode:
         forwards — MoE routing decisions survive serving bitwise enough to
         never flip a greedy pick (fp32 fixture)."""
         mcfg = self._mcfg()
-        engine = InferenceEngineV2(mcfg, config=v2cfg, seed=0)
+        engine = v2_engine(mcfg, config=v2cfg, seed=0)
         prompts = [rng.integers(0, 128, (9 + 3 * i,)).astype(np.int32)
                    for i in range(2)]
         got = engine.generate(prompts, max_new_tokens=8)

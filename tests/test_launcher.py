@@ -49,7 +49,7 @@ class TestSimFleet:
             [sys.executable, "-m", "deepspeed_tpu.launcher",
              "--sim_hosts", "2", "--devices_per_host", "4",
              "--sim_port", "29741", script, str(tmp_path)],
-            cwd=repo, env=env, capture_output=True, text=True, timeout=480)
+            cwd=repo, env=env, capture_output=True, text=True, timeout=90)
         assert r.returncode == 0, r.stderr[-3000:]
         assert (tmp_path / "rank0.ok").exists()
         assert (tmp_path / "rank1.ok").exists()

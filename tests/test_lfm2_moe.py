@@ -96,7 +96,8 @@ def test_the_attention_mixer_is_its_equation(cfg, params):
 # ------------------------------------------------------------------ the model
 
 def test_the_model_is_the_reference(cfg, params, seqs, want):
-    got = GPTLogits(cfg).apply({"params": params}, seqs[0][None])[0]
+    got = jax.jit(GPTLogits(cfg).apply)(         # one program, not one an op
+        {"params": params}, seqs[0][None])[0]
     np.testing.assert_allclose(got, want[0], atol=TOL)
 
 
@@ -141,7 +142,7 @@ def test_the_loss_differentiates_through_every_layer(cfg, params):
     loss = lambda p: GPT(cfg).apply(  # noqa: E731
         {"params": p}, {"input_ids": jnp.arange(8)[None] % 128},
         deterministic=True)
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)      # one program, not one an op
     assert all(np.isfinite(a).all() for a in jax.tree_util.tree_leaves(g))
     for i in cfg.conv_layers:
         mixer = g["backbone"][f"block_{i}"]["ShortConvMixer_0"]
@@ -162,8 +163,9 @@ def test_gradient_through_the_conv_mixer(cfg, params):
     def theirs(mp, u):
         with jax.default_matmul_precision("highest"):
             return jnp.sum(ref._short_conv(mp, u) * probe)
-    got = jax.grad(ours, argnums=(0, 1))(mp, u)
-    exp = jax.grad(theirs, argnums=(0, 1))(mp, u)
+    # a program each, not one a primitive of an eager backward
+    got = jax.jit(jax.grad(ours, argnums=(0, 1)))(mp, u)
+    exp = jax.jit(jax.grad(theirs, argnums=(0, 1)))(mp, u)
     for (path, g), e in zip(jax.tree_util.tree_leaves_with_path(got),
                             jax.tree_util.tree_leaves(exp)):
         scale = float(jnp.abs(e).max()) + 1e-6

@@ -22,7 +22,7 @@ class TestLintAll:
         """The repo passes every lint — the single CI wiring for all of
         them."""
         r = subprocess.run([sys.executable, SCRIPT],
-                           capture_output=True, text=True, timeout=560)
+                           capture_output=True, text=True, timeout=60)
         assert r.returncode == 0, r.stdout + r.stderr
         for lint in LINTS:
             assert lint in r.stdout, r.stdout
@@ -35,13 +35,13 @@ class TestLintAll:
         r = subprocess.run(
             [sys.executable, SCRIPT, "--only", "trace_report",
              "check_metrics"],
-            capture_output=True, text=True, timeout=240)
+            capture_output=True, text=True, timeout=60)
         assert r.returncode == 0, r.stdout + r.stderr
         assert "trace_report" in r.stdout
         assert "check_no_sync" not in r.stdout.replace(
             "lint_all: unified lint summary", "")
         r = subprocess.run([sys.executable, SCRIPT, "--only", "nope"],
-                           capture_output=True, text=True, timeout=120)
+                           capture_output=True, text=True, timeout=60)
         assert r.returncode == 2
         assert "unknown" in r.stderr
 

@@ -185,7 +185,8 @@ class TestQuantizedWire:
         full = MoE(hidden_size=32, num_experts=8, k=2, capacity_factor=2.0,
                    mlp_ratio=2, mesh=mesh)
         q8 = full.clone(wire_bits=8, wire_block=64)
-        v = full.init(jax.random.PRNGKey(2), x)
+        # init and backward as a program each, not one a primitive
+        v = jax.jit(full.init)(jax.random.PRNGKey(2), x)
         with mesh:
             yf, _ = jax.jit(full.apply)(v, x)
             yq, _ = jax.jit(q8.apply)(v, x)
@@ -193,7 +194,7 @@ class TestQuantizedWire:
             def loss(vv):
                 y, aux = q8.apply(vv, x)
                 return jnp.sum(y ** 2) + aux
-            g = jax.grad(loss)(v)
+            g = jax.jit(jax.grad(loss))(v)
         yf, yq = np.asarray(yf), np.asarray(yq)
         rel = np.linalg.norm(yq - yf) / np.linalg.norm(yf)
         assert rel < 0.05, rel
@@ -235,7 +236,8 @@ class TestQuantizedWire:
             x = jnp.asarray(rng.standard_normal((4, 16, 32)), jnp.float32)
             m = MoE(hidden_size=32, num_experts=8, k=1, mlp_ratio=2,
                     mesh=mesh, wire_bits=bits, wire_block=64)
-            v = m.init(jax.random.PRNGKey(0), x)
+            # the lowering needs the variables' shapes, not their values
+            v = jax.eval_shape(m.init, jax.random.PRNGKey(0), x)
             with mesh:
                 jax.jit(m.apply).lower(v, x)    # bytes log at trace time
             bc = default_registry.counter(COLLECTIVE_BYTES)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import v2_engine
 
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2 import model as v2model
@@ -69,12 +70,14 @@ def model(sz, max_seq_len=512, seed=0, **cfg_over):
     return cfg, jax.tree_util.tree_map_with_path(shake, params)
 
 
-def engine(cfg, params, top=None, **sm):
+def engine(cfg, params, top=None, build=v2_engine, **sm):
+    """``build=InferenceEngineV2``: a private engine, for a case that reads
+    what its own traces log."""
     manager = {"max_tracked_sequences": 4, "max_ragged_sequence_count": 4,
                "max_ragged_batch_size": 128, "max_q_per_seq": 32,
                "kv_block_size": 16, "num_kv_blocks": 64, **sm}
-    return InferenceEngineV2(cfg, {"dtype": "float32", **(top or {}),
-                                   "state_manager": manager}, params=params)
+    return build(cfg, {"dtype": "float32", **(top or {}),
+                       "state_manager": manager}, params=params)
 
 
 # ---------------------------------------------------- the model, both views
@@ -86,7 +89,8 @@ def test_flax_logits_match_the_reference():
     cfg, params = model(sz)
     assert cfg.mla and cfg.latent_dim == 136 and cfg.latent_page_dim == 256
     ids = np.random.default_rng(0).integers(0, V, size=40)
-    got = GPTLogits(cfg).apply({"params": params}, jnp.asarray(ids)[None])[0]
+    got = jax.jit(GPTLogits(cfg).apply)(         # one program, not one an op
+        {"params": params}, jnp.asarray(ids)[None])[0]
     want = ref.logits(params, ids, sz)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     n = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params))

@@ -285,7 +285,7 @@ class TestMergeTraces:
         r = subprocess.run(
             [sys.executable, os.path.join(SCRIPTS, "merge_traces.py"),
              "-o", str(out), str(p)],
-            capture_output=True, text=True)
+            capture_output=True, text=True, timeout=60)
         assert r.returncode == 0, r.stderr
         assert out.exists()
 
@@ -313,7 +313,7 @@ class TestPerfReport:
         r = subprocess.run(
             [sys.executable, os.path.join(SCRIPTS, "perf_report.py"),
              str(p)],
-            capture_output=True, text=True)
+            capture_output=True, text=True, timeout=60)
         assert r.returncode == 0, r.stderr
         for needle in ("per-link", "all_gather", "host phase spans",
                        "batch_input", "scheduler regime"):
@@ -350,7 +350,7 @@ class TestPerfReport:
         r = subprocess.run(
             [sys.executable, os.path.join(SCRIPTS, "perf_report.py"),
              str(tmp_path / "postmortem")],
-            capture_output=True, text=True)
+            capture_output=True, text=True, timeout=60)
         assert r.returncode == 0, r.stderr
         assert "all_reduce" in r.stdout                # per-link table
         assert "dispatch" in r.stdout                  # spans section

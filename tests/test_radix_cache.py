@@ -6,9 +6,10 @@ DSStateManager deque satellite."""
 
 import numpy as np
 import pytest
+from conftest import v2_engine
 
 from deepspeed_tpu.inference.v2 import (BlockedAllocator, DSStateManager,
-                                        InferenceEngineV2, RadixKVCache)
+                                        RadixKVCache)
 from deepspeed_tpu.models import GPTConfig
 
 
@@ -22,7 +23,7 @@ BASE_SM = {"max_tracked_sequences": 4, "max_ragged_batch_size": 64,
 
 
 def mk_engine(cfg, seed=0, **sm_overrides):
-    return InferenceEngineV2(cfg, config={
+    return v2_engine(cfg, config={
         "dtype": "fp32",
         "state_manager": dict(BASE_SM, **sm_overrides)}, seed=seed)
 
@@ -286,7 +287,7 @@ class TestChunkedPrefillFairness:
         decoders: short requests admitted alongside a long prompt finish
         BEFORE the long prompt even produces its first token (decode
         priority + chunk bound), and the chunk counter books the stream."""
-        eng = InferenceEngineV2(cfg, config={
+        eng = v2_engine(cfg, config={
             "dtype": "fp32",
             "state_manager": dict(BASE_SM, max_q_per_seq=8,
                                   prefill_chunk_tokens=8)}, seed=0)
@@ -323,7 +324,7 @@ class TestChunkedPrefillFairness:
         """No round schedules more prefill tokens than the cap (asserted
         via the mixed-dispatch bucket: with cap 8 + ≤4 decodes the padded
         bucket never exceeds 64, so no full-budget prefill round ran)."""
-        eng = InferenceEngineV2(cfg, config={
+        eng = v2_engine(cfg, config={
             "dtype": "fp32",
             "state_manager": dict(BASE_SM, max_q_per_seq=16,
                                   prefill_chunk_tokens=8)}, seed=0)
@@ -345,7 +346,7 @@ class TestSLAScheduler:
         "gold": {"priority": 10, "ttft_slo_ms": 1.0}}}
 
     def mk(self, cfg, **sm):
-        return InferenceEngineV2(cfg, config={
+        return v2_engine(cfg, config={
             "dtype": "fp32",
             "state_manager": dict(BASE_SM, **sm),
             "scheduler": self.SLA_CFG}, seed=0)

@@ -70,6 +70,16 @@ class TestRingAttention:
             ring_attention(mesh, q, k, v)
 
 
+def _local(plain, batch):
+    """(variables, loss, grads) of the single-device model: a program each,
+    not one a primitive of an eager init, forward and backward."""
+    var = jax.jit(lambda k: plain.init(k, batch, deterministic=True))(
+        jax.random.PRNGKey(0))
+    want, grads = jax.jit(jax.value_and_grad(
+        lambda p: plain.apply(p, batch, deterministic=True)))(var)
+    return var, float(want), grads
+
+
 class TestRingInModel:
     def test_gpt_ring_sp_matches_local(self, mesh, rng):
         """GPT with sp_impl='ring' must reproduce the single-device loss."""
@@ -77,13 +87,12 @@ class TestRingInModel:
         from deepspeed_tpu.models import GPT, GPTConfig
         cfg = GPTConfig.tiny(vocab_size=64, max_seq_len=32)
         batch = {"input_ids": rng.integers(0, 64, (4, 32)).astype(np.int32)}
-        plain = GPT(cfg)
-        v = plain.init(jax.random.PRNGKey(0), batch, deterministic=True)
-        want = float(plain.apply(v, batch, deterministic=True))
+        v, want, _ = _local(GPT(cfg), batch)
         rcfg = dataclasses.replace(cfg, sequence_parallel=True,
                                    sp_impl="ring")
         ring_model = GPT(rcfg, mesh=mesh)
-        got = float(ring_model.apply(v, batch, deterministic=True))
+        got = float(jax.jit(
+            lambda v: ring_model.apply(v, batch, deterministic=True))(v))
         assert got == pytest.approx(want, rel=2e-5)
 
     def test_ring_gqa(self, mesh, rng):
@@ -300,9 +309,7 @@ class TestFlashInner:
         from deepspeed_tpu.models import GPT, GPTConfig
         cfg = GPTConfig.tiny(vocab_size=64, max_seq_len=64)  # c = 8
         batch = {"input_ids": rng.integers(0, 64, (4, 64)).astype(np.int32)}
-        plain = GPT(cfg)
-        var = plain.init(jax.random.PRNGKey(0), batch, deterministic=True)
-        want = float(plain.apply(var, batch, deterministic=True))
+        var, want, gw = _local(GPT(cfg), batch)
         fcfg = dataclasses.replace(cfg, sequence_parallel=True,
                                    sp_impl="ring", sp_ring_layout="native",
                                    sp_ring_inner="flash")
@@ -310,8 +317,6 @@ class TestFlashInner:
         got = float(jax.jit(
             lambda p: native.apply(p, batch, deterministic=True))(var))
         assert got == pytest.approx(want, rel=2e-4)
-        gw = jax.grad(
-            lambda p: plain.apply(p, batch, deterministic=True))(var)
         gn = jax.jit(jax.grad(
             lambda p: native.apply(p, batch, deterministic=True)))(var)
         for a, b in zip(jax.tree_util.tree_leaves(gw),
@@ -359,17 +364,13 @@ class TestNativeLayout:
         cfg = GPTConfig.tiny(vocab_size=64, max_seq_len=32,
                              num_kv_heads=nkv)
         batch = {"input_ids": rng.integers(0, 64, (4, 32)).astype(np.int32)}
-        plain = GPT(cfg)
-        var = plain.init(jax.random.PRNGKey(0), batch, deterministic=True)
-        want = float(plain.apply(var, batch, deterministic=True))
+        var, want, gw = _local(GPT(cfg), batch)
         ncfg = dataclasses.replace(cfg, sequence_parallel=True,
                                    sp_impl="ring", sp_ring_layout="native")
         native = GPT(ncfg, mesh=mesh)
         got = float(jax.jit(
             lambda p: native.apply(p, batch, deterministic=True))(var))
         assert got == pytest.approx(want, rel=2e-5)
-        gw = jax.grad(
-            lambda p: plain.apply(p, batch, deterministic=True))(var)
         gn = jax.jit(jax.grad(
             lambda p: native.apply(p, batch, deterministic=True)))(var)
         for a, b in zip(jax.tree_util.tree_leaves(gw),
@@ -410,7 +411,10 @@ class TestNativeLayout:
             c2 = dataclasses.replace(cfg, sequence_parallel=True,
                                      sp_impl="ring", sp_ring_layout=layout)
             m = GPT(c2, mesh=mesh)
-            var = m.init(jax.random.PRNGKey(0), batch, deterministic=True)
+            # the program's text needs the variables' shapes, not values
+            var = jax.eval_shape(
+                lambda k: m.init(k, batch, deterministic=True),
+                jax.random.PRNGKey(0))
             txt = jax.jit(
                 lambda p, b: m.apply(p, b, deterministic=True)).lower(
                     var, batch).compile().as_text()

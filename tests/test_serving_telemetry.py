@@ -14,8 +14,8 @@ import sys
 
 import numpy as np
 import pytest
+from conftest import v2_engine
 
-from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.models import GPTConfig
 from deepspeed_tpu.telemetry import MetricRegistry, SnapshotExporter
 from deepspeed_tpu.telemetry.histogram import (DEFAULT_BUCKETS, Histogram,
@@ -234,7 +234,7 @@ class TestServingTelemetryUnit:
 
 class TestEngineServingTelemetry:
     def test_generate_populates_lifecycle_metrics(self, cfg, v2cfg, rng):
-        eng = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        eng = v2_engine(cfg, config=v2cfg, seed=0)
         prompts = [rng.integers(0, 97, (n,)).astype(np.int32)
                    for n in (9, 23, 5, 30, 12, 7)]       # 6 prompts, 4 slots
         outs = eng.generate(prompts, max_new_tokens=6)
@@ -269,7 +269,7 @@ class TestEngineServingTelemetry:
     def _served_steps(cfg, v2cfg, rng):
         """(telemetry, each sampled step's rows a sequence) of six prompts
         served over four slots."""
-        eng = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        eng = v2_engine(cfg, config=v2cfg, seed=0)
         steps = []
         inner = eng._step_sampled
 
@@ -352,9 +352,9 @@ class TestEngineServingTelemetry:
             self, cfg, v2cfg, rng):
         prompts = [rng.integers(0, 97, (n,)).astype(np.int32)
                    for n in (9, 14, 21)]
-        closed = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        closed = v2_engine(cfg, config=v2cfg, seed=0)
         want = closed.generate(prompts, max_new_tokens=5)
-        eng = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        eng = v2_engine(cfg, config=v2cfg, seed=0)
         arrivals = [0.0, 0.03, 0.06]
         got = eng.generate(prompts, max_new_tokens=5,
                            arrival_times=arrivals, stream=True)
@@ -371,13 +371,13 @@ class TestEngineServingTelemetry:
         assert q99 >= 0.0
 
     def test_arrival_times_validation(self, cfg, v2cfg, rng):
-        eng = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        eng = v2_engine(cfg, config=v2cfg, seed=0)
         with pytest.raises(ValueError, match="arrival_times"):
             eng.generate([rng.integers(0, 97, (5,)).astype(np.int32)],
                          max_new_tokens=2, arrival_times=[0.0, 1.0])
 
     def test_preemption_and_alloc_failure_counters(self, cfg, rng):
-        eng = InferenceEngineV2(cfg, config={
+        eng = v2_engine(cfg, config={
             "dtype": "fp32",
             "state_manager": {"max_tracked_sequences": 4,
                               "max_ragged_batch_size": 64,
@@ -403,13 +403,13 @@ class TestEngineServingTelemetry:
             assert fails > 0
 
     def test_can_schedule_failure_counts(self, cfg, v2cfg):
-        eng = InferenceEngineV2(cfg, config=v2cfg, seed=0)
+        eng = v2_engine(cfg, config=v2cfg, seed=0)
         assert not eng.can_schedule(list(range(99)), [1] * 99)
         assert eng.telemetry.value("kv_alloc_failures_total",
                                    site="can_schedule") == 1
 
     def test_telemetry_disabled_engine_still_serves(self, cfg, v2cfg, rng):
-        eng = InferenceEngineV2(cfg, config={
+        eng = v2_engine(cfg, config={
             **v2cfg, "telemetry": {"enabled": False}}, seed=0)
         prompts = [rng.integers(0, 97, (9,)).astype(np.int32)]
         out = eng.generate(prompts, max_new_tokens=4)
@@ -422,9 +422,9 @@ class TestSpeculativeTelemetry:
     def test_fused_spec_counters(self, cfg, v2cfg, rng):
         prompts = [rng.integers(0, 97, (10 + 3 * i,)).astype(np.int32)
                    for i in range(3)]
-        base = InferenceEngineV2(cfg, config=v2cfg, seed=0)
-        spec = InferenceEngineV2(cfg, config=v2cfg, params=base.params,
-                                 draft_model=cfg, draft_params=base.params)
+        base = v2_engine(cfg, config=v2cfg, seed=0)
+        spec = v2_engine(cfg, config=v2cfg, params=base.params,
+                         draft_model=cfg, draft_params=base.params)
         spec.generate(prompts, max_new_tokens=12)
         st = spec.telemetry.spec_summary()
         assert st["outer_steps"] > 0

@@ -382,6 +382,6 @@ def test_an_engine_from_a_config_does_not_import_the_checkpoint_package():
     repo = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
     out = subprocess.run(
         [sys.executable, "-c", code], cwd=repo, capture_output=True,
-        text=True, timeout=600,
+        text=True, timeout=75,
         env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": repo})
     assert out.returncode == 0, out.stderr[-2000:]

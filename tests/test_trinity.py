@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import v2_engine
 
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.ragged import DSStateManager
@@ -63,13 +64,15 @@ def model(sz, max_seq_len=128, seed=0):
     return cfg, jax.tree_util.tree_map_with_path(shake, params)
 
 
-def engine(cfg, params, **sm):
+def engine(cfg, params, build=v2_engine, **sm):
+    """``build=InferenceEngineV2``: a private engine, for a case that reads
+    what its own traces log."""
     manager = {"max_tracked_sequences": 4, "max_ragged_sequence_count": 4,
                "max_ragged_batch_size": 32, "max_q_per_seq": 8,
                "kv_block_size": 4, "num_kv_blocks": 64,
                "num_kv_window_blocks": 24, **sm}
-    return InferenceEngineV2(cfg, {"dtype": "float32",
-                                   "state_manager": manager}, params=params)
+    return build(cfg, {"dtype": "float32", "state_manager": manager},
+                 params=params)
 
 
 # ---------------------------------------------------- the model, both views
@@ -80,7 +83,8 @@ def test_flax_logits_match_the_reference(held, offset, router):
     sz = sizes(held=held, offset=offset, router=router)
     cfg, params = model(sz)
     ids = np.random.default_rng(0).integers(0, 96, size=30)
-    got = GPTLogits(cfg).apply({"params": params}, jnp.asarray(ids)[None])[0]
+    got = jax.jit(GPTLogits(cfg).apply)(         # one program, not one an op
+        {"params": params}, jnp.asarray(ids)[None])[0]
     want = _afmoe.logits(params, ids, sz)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
@@ -160,7 +164,7 @@ def test_counters_follow_expert_layers_not_the_router(router):
                     gated_mlp=True, gate_act="silu", use_rmsnorm=True)
     params = unbox(GPTLogits(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
-    eng = InferenceEngineV2(cfg, {"dtype": "float32", "state_manager": {
+    eng = v2_engine(cfg, {"dtype": "float32", "state_manager": {
         "max_tracked_sequences": 2, "max_ragged_batch_size": 16,
         "max_q_per_seq": 8, "kv_block_size": 4, "num_kv_blocks": 16}},
         params=params)
@@ -176,7 +180,9 @@ def test_step_programs_log_the_grouped_gemm():
     from deepspeed_tpu.ops.registry import dispatch_log, reset_dispatch_log
     cfg, params = model(sizes())
     reset_dispatch_log()
-    engine(cfg, params).put([1], [np.arange(6, dtype=np.int32)])
+    # a private engine: the log is what its own traces wrote
+    engine(cfg, params, build=InferenceEngineV2).put(
+        [1], [np.arange(6, dtype=np.int32)])
     ops = {d["op"]: d["impl"] for d in dispatch_log()}
     assert ops.get("grouped_gemm") == "xla"
 
@@ -201,7 +207,8 @@ def test_the_grouped_gemm_kernel_serves_the_same_tokens(family, monkeypatch):
 
     def serve():
         reset_dispatch_log()
-        eng = make(cfg, params)
+        # private engines: the patch and the log are read when a step traces
+        eng = make(cfg, params, build=InferenceEngineV2)
         steps = [np.stack(eng.put([1, 2], prompts))]
         for _ in range(3):
             nxt = steps[-1].argmax(-1).astype(np.int32)
