@@ -420,52 +420,29 @@ def _embed_tokens(bb, tokens, positions, cfg):
 def _write_plan(block_table, row_slot, row_pos, block_size: int,
                 rows_per_slot: int, km: bool):
     """Where a step's new k/v rows go in a layer's pages: what ``_kv_write``
-    needs besides the rows, the same for every layer and so computed once a
-    step.  Scope ``kv_write``.
+    needs besides the rows, the same for every layer of a page group and so
+    computed once a step (``ops/kv_append.py:append_plan``, which says what
+    a plan holds).  Scope ``kv_write``.
 
     Row ``n`` of the step's [N, nkv, hd] k/v belongs to slot ``row_slot[n]``
     (``S``, out of range, for a pad or an inactive slot: such a row is
     dropped, never clamped to a real slot, whose rows it would overwrite)
     and holds position ``row_pos[n]``.
 
-    Standard pages ([nkv, bs, hd]): per row, its page and its offset in it.
-
-    kv-major pages ([nkv, hd, bs]) are written a page at a time, so the
-    plan is per page.  A slot's rows are one run of the step's rows, in
-    position order, for contiguous positions (ragged.py packs them so; the
-    dense [S, G] verify layout and the one-row decode are the same thing):
-    at most ``rows_per_slot`` (static), which touch at most
-    ``J = (rows_per_slot + block_size - 2) // block_size + 1`` pages (1 for
-    a single row).  Over the ``S * J`` candidates: the page, the index among
-    the step's rows of the page's token 0 (negative where the run starts
-    inside the page), and the tokens ``lo <= r < hi`` of the page that are
-    written (none, ``hi <= lo``, where the candidate holds no row of the
-    step)."""
-    S, MB = block_table.shape
-    N = row_slot.shape[0]
+    The plan is by UNIT for both page layouts: a slot's rows are one run of
+    the step's rows, in position order, for contiguous positions (ragged.py
+    packs them so; the dense [S, G] verify layout and the one-row decode
+    are the same thing), at most ``rows_per_slot`` (static: 1 in the decode
+    programs and the burst, the chunk width in ``ragged_forward``, ``G`` in
+    the verify layout), so it touches a bounded number of units (a kv-major
+    page [nkv, hd, bs]; 16 tokens of a standard page [nkv, bs, hd]), and the
+    candidates that hold a row of the step come first.  For standard pages
+    it also holds the write by row (page, offset, live), which the XLA form
+    scatters."""
+    from deepspeed_tpu.ops.kv_append import append_plan
     with jax.named_scope("kv_write"):
-        if not km:
-            page = block_table[jnp.minimum(row_slot, S - 1),
-                               row_pos // block_size]
-            return page, row_pos % block_size, row_slot < S
-        big = jnp.iinfo(jnp.int32).max
-        counts = jnp.zeros((S,), jnp.int32).at[row_slot].add(1, mode="drop")
-        live = counts > 0
-
-        def first(x):                       # of each slot's run; 0 if none
-            return jnp.where(live, jnp.full((S,), big, jnp.int32).at[
-                row_slot].min(x, mode="drop"), 0)
-        starts = first(row_pos)
-        row0 = first(jnp.arange(N, dtype=jnp.int32))
-        J = (rows_per_slot + block_size - 2) // block_size + 1
-        lb = (starts // block_size)[:, None] + jnp.arange(J, dtype=jnp.int32)
-        tok0 = lb * block_size - starts[:, None]   # page token 0, as a row
-        lo = jnp.clip(-tok0, 0, block_size)
-        hi = jnp.clip(counts[:, None] - tok0, 0, block_size)
-        page = jnp.take_along_axis(block_table, jnp.minimum(lb, MB - 1),
-                                   axis=1)
-        start = row0[:, None] + tok0
-    return page.reshape(-1), start.reshape(-1), lo.reshape(-1), hi.reshape(-1)
+        return append_plan(block_table, row_slot, row_pos, block_size,
+                           rows_per_slot, km)
 
 
 def _kv_write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, km,
@@ -473,10 +450,13 @@ def _kv_write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, km,
     """Paged KV append (reference linear_blocked_kv_rotary): one layer's new
     ``k``/``v`` rows [N, nkv, hd] go into their pages of the flat
     [L * NB, nkv, ...] pool views (quantised first when the pool is int8),
-    ``base = li * NB`` being the layer's first page and ``plan`` the step's
-    ``_write_plan``.  Scope ``kv_write``.
+    ``base = li * NB`` being the layer's first page (an int, or a traced
+    scalar: a step program traces and lowers ONE write a page group and
+    every layer calls it, as ``attend``) and ``plan`` the step's
+    ``_write_plan``.  Scope ``kv_write``.  The write itself is op
+    ``paged_kv_append`` (ops/kv_append.py), in two forms.
 
-    Both writes are shaped for the pool's layout before their own cost: the
+    Every form is shaped for the pool's layout before its own cost: the
     pool is row-major as created, the Pallas kernels are custom calls that
     demand it so, and a write that prefers another layout makes the compiler
     re-lay the whole pool on the way into and out of every step program,
@@ -486,18 +466,30 @@ def _kv_write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, km,
     minor, i.e. the pool token-major).  tests/test_chip_compile.py holds the
     compiled programs to it.
 
-    Standard pages: a scatter of [hd] rows at (page, head, offset), i.e.
-    rows of the pool seen as [L * NB * nkv * bs, hd], which has no layout
-    but row-major.  ``N * nkv`` updates instead of ``N``, each ~65-90 ns on
-    a v5e whatever its size: 0.8 ms of a 20.5 ms decode step and 8.6 ms of
-    a 57 ms 512-token mixed step at Mistral-7B's widths (PERF.md, PR 27).
+    The kernel (PR 48; a TPU, k and v of several kv heads in bfloat16 or
+    float32): the pools are aliased operands of ONE call a layer, the grid
+    walks the plan's candidate units, and a unit that holds a row of the
+    step comes in, has its tokens ``lo <= t < hi`` replaced under a mask and
+    goes back, its bytes moved once; the step's rows are read as they are.
+    Both layouts and every ``rows_per_slot`` take it, the one-row decode
+    write too (PERF.md section 6, PR 48: 16-token units make that cheaper
+    than the row scatter).
 
-    kv-major pages: a token is a lane of [nkv, hd, bs] and a scatter per
-    lane is no row-major write, so whole pages are read, merged with the new
-    rows by a select, and scattered back by page index: a window that is
-    the page leaves the page index as the only scattered dimension.  On
-    standard pages that costs a decode step 4 ms more than the row scatter
-    (same PR), which is why they do not share it.
+    The XLA forms (the numeric reference; a CPU, an int8 pool with its scale
+    pools, a one-head latent or index-key pool, a shape ``supported``
+    declines), as PR 27 left them.  Standard pages: a scatter of [hd] rows
+    at (page, head, offset), i.e. rows of the pool seen as [L * NB * nkv *
+    bs, hd], which has no layout but row-major.  ``N * nkv`` updates instead
+    of ``N``, each ~65-90 ns on a v5e whatever its size: 0.8 ms of a 20.5 ms
+    decode step and 8.6 ms of a 57 ms 512-token mixed step at Mistral-7B's
+    widths (PERF.md, PR 27); with one head, a latent pool's, it is one
+    update a row, which is why such a pool keeps it.  kv-major pages: a
+    token is a lane of [nkv, hd, bs] and a scatter per lane is no row-major
+    write, so whole candidate pages are read, merged with the new rows by a
+    select, and scattered back by page index: a window that is the page
+    leaves the page index as the only scattered dimension.  On standard
+    pages that costs a decode step 4 ms more than the row scatter (same PR),
+    which is why they did not share it.
 
     With a ``tp`` axis the pool's kv-head dim is sharded and the write runs
     per shard under shard_map, as the kernels do: a head's rows go to that
@@ -505,10 +497,10 @@ def _kv_write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, km,
     folds the sharded head dim into the scattered one, and every chip
     all-gathers the whole pool each step."""
     quant = flat_ks is not None
-    write = functools.partial(_kv_write_local, base=base, km=km)
+    write = functools.partial(_kv_write_local, km=km)
     if flat_v is None:                 # latent pages: one pool, one row kind
         with jax.named_scope("kv_write"):
-            return write((flat_k,), k, None, plan) + (None, None, None)
+            return write((flat_k,), k, None, plan, base) + (None, None, None)
     pools = (flat_k, flat_v) + ((flat_ks, flat_vs) if quant else ())
     if (mesh is not None and mesh.shape.get("tp", 1) > 1
             and k.shape[1] % mesh.shape["tp"] == 0):
@@ -520,55 +512,51 @@ def _kv_write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, km,
         pool_specs = tuple(heads(p) for p in pools)
         write = shard_map(
             write, mesh=mesh,
-            in_specs=(pool_specs, heads(k), heads(v), tuple(P() for _ in plan)),
+            in_specs=(pool_specs, heads(k), heads(v),
+                      jax.tree.map(lambda _: P(), plan), P()),
             out_specs=pool_specs, check_vma=False)
     with jax.named_scope("kv_write"):
-        pools = write(pools, k, v, plan)
+        pools = write(pools, k, v, plan, jnp.asarray(base, jnp.int32))
     return pools if quant else pools + (None, None)
 
 
-def _kv_write_local(pools, k, v, plan, *, base, km):
+def _kv_write_local(pools, k, v, plan, base, *, km):
     """``_kv_write`` on the kv heads at hand: (k, v[, k_scale, v_scale])
     pools in, the same out."""
-    big = jnp.iinfo(jnp.int32).max
+    from deepspeed_tpu import ops
     new = (k,) if v is None else (k, v)
     if len(pools) == 4:
         k, ks = quantize_kv_token(k)                  # [N,nkv,hd], [N,nkv]
         v, vs = quantize_kv_token(v)
         new = (k, v, ks, vs)
-    if not km:
-        page, off, live = plan
-        nkv, bs = pools[0].shape[1:3]
-        # (page, head, offset) as a row of the pool seen as
-        # [L * NB * nkv * bs, hd]: the form XLA brings this scatter to
-        # anyway, and written so it keeps its scope in the trace
-        row = (((base + page)[:, None] * nkv + jnp.arange(nkv)) * bs
-               + off[:, None])
-        row = jnp.where(live[:, None], row, big).reshape(-1)
+    return ops.paged_kv_append(pools, new, plan, base, kv_major=km)
 
-        def put(pool, x):              # x [N, nkv, hd], or [N, nkv] scales
-            rows = pool.reshape((-1,) + pool.shape[3:])
-            x = x.reshape((-1,) + x.shape[2:]).astype(pool.dtype)
-            return rows.at[row].set(x, mode="drop").reshape(pool.shape)
-        return tuple(put(pool, x) for pool, x in zip(pools, new))
 
-    page, start, lo, hi = plan
-    bs = pools[0].shape[3]
-    tok = jnp.arange(bs, dtype=jnp.int32)
-    fresh = (tok >= lo[:, None]) & (tok < hi[:, None])            # [W, bs]
-    dst = jnp.where(hi > lo, base + page, big)
-    src = jnp.minimum(dst, pools[0].shape[0] - 1)      # dropped: any page
+def _kv_writer(km: bool, mesh=None):
+    """``_kv_write`` as ONE jitted function, made once a step program and
+    called by every attention layer of it with the layer's first page as an
+    operand: the kernel is traced once and lowered once a page group (the
+    pools' shapes), not once a layer (what ``attend`` is to the attention
+    kernels).  A jit of the trace's own, not of the module: the op chooses
+    its implementation while tracing.  The rows cross into it as [N, nkv *
+    hd], the projections' own output: handed over as [N, nkv, hd] the
+    compiler settles each projection's form before it sees what reads it,
+    re-lays the v projection's weights for it and, in the burst, keeps those
+    copies across the loop (8 MB a layer at Mistral-7B's widths)."""
+    @functools.partial(jax.jit, static_argnames="heads")
+    def _kv_write_rows(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, *,
+                       heads):
+        def rows(x):
+            return None if x is None else x.reshape(x.shape[0], heads, -1)
+        return _kv_write(flat_k, flat_v, flat_ks, flat_vs, rows(k), rows(v),
+                         plan, base, km, mesh=mesh)
 
-    def merge(pool, x):
-        """x [N, nkv, ...] step rows over the pages they land in."""
-        xp = jnp.pad(x, ((bs, bs),) + ((0, 0),) * (x.ndim - 1))
-        win = jax.vmap(lambda s: jax.lax.dynamic_slice_in_dim(
-            xp, s, bs))(start + bs)                     # [W, bs, nkv, ...]
-        rows = jnp.moveaxis(win, 1, -1)                 # [W, nkv, ..., bs]
-        mask = fresh.reshape((-1,) + (1,) * (rows.ndim - 2) + (bs,))
-        return pool.at[dst].set(
-            jnp.where(mask, rows.astype(pool.dtype), pool[src]), mode="drop")
-    return tuple(merge(pool, x) for pool, x in zip(pools, new))
+    def write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base):
+        return _kv_write_rows(
+            flat_k, flat_v, flat_ks, flat_vs, k.reshape(k.shape[0], -1),
+            None if v is None else v.reshape(v.shape[0], -1), plan, base,
+            heads=k.shape[1])
+    return write
 
 
 def _layer_kv(flat_ks, flat_vs):
@@ -1439,6 +1427,7 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                      for i in cfg.attention_layers}}
     plans = tuple(_write_plan(t, scat_slot, token_pos, block_size, Q, km)
                   for t in tables)
+    write = _kv_writer(km, mesh)
 
     # [L * num_blocks, nkv, …] views updated IN PLACE through the donated
     # cache buffer — never rebuild the whole pool (a jnp.stack of per-layer
@@ -1513,9 +1502,9 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
 
         base, grp = kv_base(cfg, li, NB, kv_layout)
         own = grp == 1 and flat_kw is not None    # the window group's pool
-        pool, flat_v_all, flat_ks, flat_vs = _kv_write(
+        pool, flat_v_all, flat_ks, flat_vs = write(
             flat_kw if own else flat_k_all, flat_v_all, flat_ks, flat_vs, k,
-            v, plans[grp], base, km, mesh=mesh)
+            v, plans[grp], base)
         flat_k_all, flat_kw = ((flat_k_all, pool) if own
                                else (pool, flat_kw))
         if lc.index_topk:
@@ -1523,7 +1512,7 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                     jax.named_scope("attn_index"):
                 qi, wi, ki = _index_rows(ap, h, cq, token_pos, lc)
                 flat_ki, = _kv_write_local((flat_ki,), ki, None, plans[grp],
-                                           base=base, km=False)
+                                           base, km=False)
         if lc.index_topk and select:
             o = selected[lc](q, qi, wi, pool, flat_ki, tables[grp] + base,
                              scat_slot, token_pos, rows=rows)
@@ -1591,6 +1580,7 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
     row_slot = jnp.where(active, jnp.arange(S), S)
     plans = tuple(_write_plan(t, row_slot, token_pos, block_size, 1, km)
                   for t in tables)
+    write = _kv_writer(km, mesh)
     with jax.named_scope("attn_kernel"):
         kv_len = jnp.where(active, token_pos + 1, 0)                # [S]
     if lora is not None:
@@ -1638,9 +1628,9 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
 
         base, grp = kv_base(cfg, li, NB, kv_layout)
         own = grp == 1 and flat_kw is not None    # the window group's pool
-        pool, flat_v_all, flat_ks, flat_vs = _kv_write(
+        pool, flat_v_all, flat_ks, flat_vs = write(
             flat_kw if own else flat_k_all, flat_v_all, flat_ks, flat_vs, k,
-            v, plans[grp], base, km, mesh=mesh)
+            v, plans[grp], base)
         flat_k_all, flat_kw = ((flat_k_all, pool) if own
                                else (pool, flat_kw))
         if lc.index_topk:
@@ -1648,7 +1638,7 @@ def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
                     jax.named_scope("attn_index"):
                 qi, wi, ki = _index_rows(ap, h, cq, token_pos, lc)
                 flat_ki, = _kv_write_local((flat_ki,), ki, None, plans[grp],
-                                           base=base, km=False)
+                                           base, km=False)
         if lc.index_topk and select:
             o = _selected_attention(
                 q, qi, wi, pool, flat_ki, tables[grp] + base, row_slot,
@@ -1924,6 +1914,7 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
     plan = _write_plan(
         block_table, jnp.repeat(jnp.where(active, jnp.arange(S), S), G),
         positions.reshape(-1), block_size, G, km)
+    write = _kv_writer(km, mesh)
     with jax.named_scope("attn_kernel"):
         kv_len = jnp.where(active, pos0 + G, 0)
 
@@ -1938,9 +1929,9 @@ def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
                 q, k = rope(q, k, positions, hd, base=cfg.rope_theta,
                             rope_pct=cfg.rope_pct, scaling=cfg.rope_scaling,
                             seq_lens=kv_len[:, None])
-        flat_k, flat_v, flat_ks, flat_vs = _kv_write(
+        flat_k, flat_v, flat_ks, flat_vs = write(
             flat_k, flat_v, flat_ks, flat_vs, k.reshape(S * G, nkv, hd),
-            v.reshape(S * G, nkv, hd), plan, li * NB, km, mesh=mesh)
+            v.reshape(S * G, nkv, hd), plan, li * NB)
         with jax.named_scope("attn_kernel"):
             slopes = None
             if cfg.use_alibi:

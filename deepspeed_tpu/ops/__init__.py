@@ -7,6 +7,7 @@ analog).  Public surface:
 - ``causal_attention(q, k, v, ...)``      fused flash attention w/ fallback
 - ``flash_attention(...)``                direct Pallas kernel entry
 - ``lm_cross_entropy(...)``               chunked unembed + softmax CE
+- ``paged_kv_append(...)``                a step's k/v rows into their pages
 - ``op_report()``                         ds_report-style compatibility matrix
 """
 
@@ -229,6 +230,22 @@ def causal_conv1d(xBC, w, b, tail, count=None, *, activation="silu",
                     impl=impl)
 
 
+from deepspeed_tpu.ops import kv_append as _append  # noqa: E402
+
+register_op("paged_kv_append", xla=_append.xla_paged_kv_append,
+            pallas=_append.pallas_paged_kv_append,
+            supported=_append.supported)
+
+
+def paged_kv_append(pools, new, plan, base, *, kv_major: bool,
+                    impl: Optional[str] = None):
+    """A step's new rows ``new`` (``[N, nkv, ...]`` each) into the flat
+    ``[L * NB, nkv, ...]`` ``pools`` at ``plan`` (``kv_append.append_plan``)
+    from page ``base`` on, in place -> the pools (ops/kv_append.py)."""
+    return dispatch("paged_kv_append", pools, new, plan, base,
+                    kv_major=kv_major, impl=impl)
+
+
 from deepspeed_tpu.ops.evoformer import evoformer_attention  # noqa: E402
 
 register_op("evoformer_attention", xla=evoformer_attention)
@@ -289,4 +306,4 @@ __all__ = ["causal_attention", "flash_attention", "configure_flash_blocks",
            "lm_cross_entropy", "masked_nll_sum", "rms_norm", "layer_norm",
            "op_report", "register_op", "dispatch", "list_ops", "registry",
            "grouped_gemm", "ssm_chunk_scan", "ssm_state_update",
-           "causal_conv1d"]
+           "causal_conv1d", "paged_kv_append"]

@@ -386,6 +386,11 @@ def test_step_programs_leave_the_pool_in_place(step_programs, monkeypatch,
     cfg, params, cache, compiled = step_programs(geometry)
     text = compiled[program].as_text()
     assert text.count(KERNEL) >= cfg.num_layers
+    # the KV append is the kernel wherever the pool is k and v of several
+    # heads in bfloat16 (ops/kv_append.py:supported): not an int8 pool, not
+    # a latent one
+    assert bool(re.search(r"%paged_kv_append\S* = ", text)) == (
+        cache.k.dtype == BF16 and cache.v is not None)
 
     def on_a_chip(a):                   # the program's shapes are a chip's
         return a.sharding.shard_shape(a.shape)
@@ -665,6 +670,58 @@ def test_prefill_block_at_the_cell_geometries(topo, monkeypatch, cell):
     assert KERNEL in prefill_text(topo, **geo, Q=Q)
     assert seen == [pages]
     assert asked[-1] and asked[-1] < 100 << 20      # of 128 MiB a v5e core
+
+
+# the KV append at the cells' geometries, a mixed step's and the one-row
+# decode step's: (kv-major, nkv, hd, bs, rows N, rows a slot, slots S)
+CELL_APPEND = {
+    "mistral-mixed": (False, 8, 128, 128, 512, 256, 32),
+    "mistral-decode": (False, 8, 128, 128, 32, 1, 32),
+    "trinity-mixed": (False, 8, 128, 128, 1024, 1024, 16),
+    "trinity-decode": (False, 8, 128, 128, 16, 1, 16),
+    "lfm2-mixed": (True, 8, 64, 128, 2048, 2048, 64),
+    "lfm2-decode": (True, 8, 64, 128, 64, 1, 64),
+    "granite-mixed": (True, 8, 64, 128, 512, 256, 64),
+    "granite-decode": (True, 8, 64, 128, 64, 1, 64),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_APPEND))
+def test_kv_append_at_the_cell_geometries(topo, cell):
+    """``paged_kv_append``'s kernel at each cell's page layout and step
+    shapes, plan and all, compiled for the described chip: ``supported``
+    takes the shape, the kernel is in the program by its name, both pools
+    are aliased to the outputs and nothing as large as a layer's pages is
+    copied or sliced on the way."""
+    from deepspeed_tpu.ops import kv_append as kva
+    km, nkv, hd, bs, N, per_slot, S = CELL_APPEND[cell]
+    NB, layers, MB = 256, 2, 64
+    pool = sds((layers * NB,) + ((nkv, hd, bs) if km else (nkv, bs, hd)),
+               BF16)
+    rows = sds((N, nkv, hd), BF16)
+
+    def write(k, v, new_k, new_v, table, slot, pos, base):
+        plan = kva.append_plan(table, slot, pos, bs, per_slot, km)
+        assert kva.supported((k, v), (new_k, new_v), plan, base, kv_major=km)
+        return kva.pallas_paged_kv_append((k, v), (new_k, new_v), plan, base,
+                                          kv_major=km, interpret=False)
+    one = SingleDeviceSharding(topo.devices[0])
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        (pool, pool, rows, rows, sds((S, MB), I32), sds((N,), I32),
+         sds((N,), I32), sds((), I32)))
+    compiled = jax.jit(write, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert KERNEL in text and "paged_kv_append" in text
+    pools = 2 * int(np.prod(pool.shape)) * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == pools
+    layer = int(np.prod(pool.shape[1:])) * NB
+    moved = [f"{op} -> {dt}[{dims}]"
+             for result, op in _HLO_OP.findall(text) if op in POOL_MOVERS
+             for dt, dims in _HLO_ARRAY.findall(result)
+             if dt == "bf16" and int(np.prod(
+                 [int(d) for d in dims.split(",") if d] or [1])) >= layer]
+    assert not moved, moved
 
 
 @pytest.fixture(scope="module")

@@ -415,7 +415,7 @@ def _scatter_write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, km,
     from deepspeed_tpu.inference.v2.model import quantize_kv_token
     big = jnp.iinfo(jnp.int32).max
     if km:      # per-page plan -> (page, offset, row) of every written token
-        page, start, lo, hi = plan
+        page, start, lo, hi = plan.unit, plan.start, plan.lo, plan.hi
         bs = flat_k.shape[3]
         r = jnp.arange(bs)[None, :]
         live = ((r >= lo[:, None]) & (r < hi[:, None])).reshape(-1)
@@ -423,7 +423,7 @@ def _scatter_write(flat_k, flat_v, flat_ks, flat_vs, k, v, plan, base, km,
         k, v = k[row], v[row]
         page, off = jnp.repeat(page, bs), jnp.tile(jnp.arange(bs), len(lo))
     else:
-        page, off, live = plan
+        page, off, live = plan.rows
     page = jnp.where(live, base + page, big)
     if flat_ks is not None:
         k, ks = quantize_kv_token(k)
