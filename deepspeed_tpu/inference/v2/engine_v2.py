@@ -523,8 +523,9 @@ class InferenceEngineV2:
         if model_cfg.state_layers:
             # state layers (Mamba-2 scan layers, gated short convolutions)
             # keep one fixed-size state a sequence beside the attention
-            # layers' pages (model.py PagedKVCache.ssm / .conv).  What
-            # cannot be right beside them yet:
+            # layers' pages (model.py PagedKVCache.ssm / .conv, which the
+            # layer body hands the step's mixer).  What cannot be right
+            # beside them yet:
             conv = not model_cfg.scan_layers
             noun, state = (("conv", "a conv tail") if conv
                            else ("scan", "a recurrent state"))
@@ -552,9 +553,10 @@ class InferenceEngineV2:
                         f"sequence, which is not built with {why}")
         if model_cfg.mla:
             # latent attention: pools of latent rows (model.py
-            # PagedKVCache), read absorbed, and with a learned selection
-            # (index_topk) an index-key pool beside the global group's.
-            # What is not built beside them:
+            # PagedKVCache; _layer_pages says which array holds a layer's),
+            # read absorbed, and with a learned selection (index_topk) an
+            # index-key pool beside the global group's.  What is not built
+            # beside them:
             sel = bool(model_cfg.index_topk)
             for what, why in (
                     (sm.kv_quant, "kv_quant: an int8 latent row and its "

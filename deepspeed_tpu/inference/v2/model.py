@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -73,22 +73,6 @@ def kv_block_size_for(cfg: GPTConfig, requested: int,
     return requested
 
 
-def kv_pool_layers(cfg: GPTConfig) -> int:
-    """How many layers own KV pages, which is how many the pool holds: the
-    attention layers (a scan or conv layer writes none)."""
-    return len(cfg.attention_layers)
-
-
-def kv_base(cfg: GPTConfig, li: int, NB: int, kv_layout=None):
-    """(first page, page group) of attention layer ``li`` in the flat pool:
-    ``kv_page_layout``'s entry where the pool has two groups, else the
-    layer's place among the layers that own pages times the ``NB`` pages
-    each holds."""
-    if kv_layout is not None:
-        return kv_layout[li]
-    return cfg.attention_layers.index(li) * NB, 0
-
-
 class PagedKVCache(NamedTuple):
     """Per-layer paged KV arrays stacked on a leading layer axis (reference:
     KVCacheManager kv_cache.py).
@@ -101,9 +85,10 @@ class PagedKVCache(NamedTuple):
 
     The pool is row-major in memory as created and stays so, in the compute
     dtype (or int8): the kernels are custom calls that take it no other
-    way, so every step program reshapes it (free) to [L * num_blocks, ...],
-    writes into that in place (``_kv_write``) and hands the kernels that
-    same flat pool with the layer's first page added to the block table.
+    way, so every step program opens it (``_KVPool``: free reshapes to [L *
+    num_blocks, ...]), writes into that in place (``_layer``, through
+    ``_kv_write``) and hands the kernels that same flat pool with the
+    layer's first page added to the block table (``_layer_pages``).
     Nothing slices a layer out of it, casts it or prefers another layout
     for it: each of those is a copy of a layer's pages or of the whole pool
     in every step (tests/test_chip_compile.py).
@@ -137,10 +122,10 @@ class PagedKVCache(NamedTuple):
 
     State layers (``cfg.is_state_layer``: Mamba-2 scan layers, gated short
     convolutions) write no pages: ``k``/``v`` hold the attention layers only
-    (``kv_pool_layers``; ``kv_base`` says where each begins), and beside
-    them lie the residents that do not grow, one fixed-size slot a tracked
-    sequence, addressed by the sequence's slot, in the parts the model
-    needs.  ``conv [state layers, slots, (taps - 1) * channels]``: the
+    (``_layer_pages`` says where each begins), and beside them lie the
+    residents that do not grow, one fixed-size slot a tracked sequence,
+    addressed by the sequence's slot, in the parts the model needs.
+    ``conv [state layers, slots, (taps - 1) * channels]``: the
     conv's last rows one after the other, in the compute dtype (ONE row a
     slot: as ``[taps - 1, channels]`` a slot's tile is padded fivefold on
     the chip, and with the slots behind the taps a scatter by slot re-lays
@@ -172,7 +157,7 @@ class PagedKVCache(NamedTuple):
                quant: Optional[str] = None, slots: int = 0):
         """``slots``: the tracked sequences, each of which owns one state
         slot in every state layer (a model without state layers has none)."""
-        layers = kv_pool_layers(cfg)
+        layers = len(cfg.attention_layers)      # a state layer owns no pages
         scan = {}
         mixer = state_mixer(cfg)
         if mixer is not None:
@@ -250,6 +235,86 @@ class PagedKVCache(NamedTuple):
                 else (block_size, cfg.head_dim))
         shape = (1, pages, cfg.kv_heads) + page
         return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+
+class _KVPool(PagedKVCache):
+    """A ``PagedKVCache`` as a step program holds it, ONE value of the same
+    fields: the paged arrays as their flat ``[layers * pages, ...]`` views,
+    the state pools as they are.  Opened once from the cache and closed once
+    back into it; in between it is what the cores take and return, what the
+    bursts carry through ``lax.scan`` (a pytree whose leaves come in the
+    fields' order) and what ``_layer`` updates IN PLACE through the donated
+    cache buffer.  Never rebuild the whole pool (a jnp.stack of per-layer
+    copies costs a full cache rewrite per step).  The reshapes are free, and
+    nothing downstream slices, casts or re-lays the pool (``PagedKVCache``),
+    so an unquantised pool has to be in the compute dtype already, as the
+    engine creates it.  Scope ``kv_pool``, like everything that only moves
+    the pool."""
+    __slots__ = ()
+
+    @classmethod
+    def open(cls, cache: PagedKVCache, cfg: GPTConfig):
+        if not cache.quantized and cache.k.dtype != jnp.dtype(
+                cfg.dtype or jnp.float32):
+            raise ValueError(
+                f"KV pool is {cache.k.dtype} but the model computes in "
+                f"{cfg.dtype}: create the pool in the compute dtype")
+        with jax.named_scope("kv_pool"):
+            return cls(**{
+                n: a if a is None or n in ("ssm", "conv")
+                else a.reshape((-1,) + a.shape[2:])
+                for n, a in cache._asdict().items()})
+
+    def close(self, cache: PagedKVCache) -> PagedKVCache:
+        """Back into ``cache``, the one it was opened from (the page groups'
+        own arrays first, as the programs have closed it since they had
+        them)."""
+        def shaped(n):
+            a = getattr(self, n)
+            return a if a is None else a.reshape(getattr(cache, n).shape)
+        with jax.named_scope("kv_pool"):
+            return cache._replace(ssm=self.ssm, conv=self.conv, **{
+                n: shaped(n)
+                for n in ("kw", "ki", "k", "v", "k_scale", "v_scale")})
+
+
+class _LayerPages(NamedTuple):
+    """What one attention layer attends over: the arrays of the pool its
+    pages lie in (``_layer_pages``), the index keys beside them for a layer
+    that selects, and its slots' block table, to which each kind of step
+    adds the layer's first page where in its program that add belongs.  The
+    fields' order is the operand order of the attention functions jitted
+    over it."""
+    k: jax.Array
+    v: Optional[jax.Array]
+    ki: Optional[jax.Array]
+    table: jax.Array
+    k_scale: Optional[jax.Array]
+    v_scale: Optional[jax.Array]
+
+    def at(self, base):
+        return self._replace(table=self.table + base)
+
+    @property
+    def scales(self):
+        """Scale kwargs of the attention ops for an int8 pool."""
+        return ({} if self.k_scale is None
+                else dict(k_scale=self.k_scale, v_scale=self.v_scale))
+
+
+def _layer_pages(cfg: GPTConfig, kv_layout, pool: _KVPool, li: int):
+    """THE answer to "attention layer ``li``: which array of the pool, its
+    first page there, which of the step's block tables", as ``(field, first
+    page, page group)``.  One page group (``kv_layout`` None): the layer's
+    place among the layers that own pages (a scan or conv layer writes
+    none) times the pages each holds, in ``k``, under table 0.  Two
+    (``kv_page_layout``'s entry): in ``kw`` for a window layer of a pool
+    with an array a group (latent pages), else in ``k``."""
+    if kv_layout is None:
+        layers = cfg.attention_layers
+        return "k", layers.index(li) * (pool.k.shape[0] // len(layers)), 0
+    base, grp = kv_layout[li]
+    return "kw" if grp == 1 and pool.kw is not None else "k", base, grp
 
 
 def _norm(p, x, cfg):
@@ -559,11 +624,6 @@ def _kv_writer(km: bool, mesh=None):
     return write
 
 
-def _layer_kv(flat_ks, flat_vs):
-    """Scale kwargs of the attention ops for an int8 pool."""
-    return {} if flat_ks is None else dict(k_scale=flat_ks, v_scale=flat_vs)
-
-
 def _head(params, bb, x, cfg, mesh=None, rows=None):
     """Final norm + unembed (of ``rows`` of x only, where given: the rows
     that carry a next-token distribution).  Scope ``head``."""
@@ -754,6 +814,15 @@ def _attn_scale(cfg: GPTConfig):
     return cfg.attn_scale
 
 
+def _alibi(cfg: GPTConfig):
+    """The attention ops' ``alibi_slopes`` of a layer (None without)."""
+    if not cfg.use_alibi:
+        return None
+    from deepspeed_tpu.models.gpt import alibi_slopes
+    return jnp.asarray(alibi_slopes(
+        cfg.num_heads, _attn_geometry(cfg)[1], cfg.alibi_prescale))
+
+
 def _mla_qkv(ap, h, positions, cfg: GPTConfig):
     """Latent attention's side of ``attn_qkv`` on rows ``h [N, H]`` at
     ``positions [N]`` (``cfg``: the layer's view, ``GPTConfig.for_layer``):
@@ -807,8 +876,8 @@ def _index_rows(ap, h, cq, positions, cfg: GPTConfig):
     return qi, wi, ki[:, None, :]
 
 
-def _selected_attention(q, qi, wi, k_pages, ki_pages, table, row_slot,
-                        row_pos, cfg: GPTConfig, *, block_size: int,
+def _selected_attention(q, qi, wi, pages: _LayerPages, row_slot, row_pos,
+                        cfg: GPTConfig, *, block_size: int,
                         max_rows: int = 1, rows=None):
     """A full layer's attention where the selection binds: index scores of
     the step's rows over their slots' index keys, the exact top
@@ -816,9 +885,9 @@ def _selected_attention(q, qi, wi, k_pages, ki_pages, table, row_slot,
     ``index_select`` inside it), then attention over the selected rows of
     the latent pool and no others (scope ``selected_attention``); all of it
     inside scope ``attn_kernel``, whose time is a layer's attention whichever
-    way it reads its keys.  ``table [S, MB]``: the global group's, the
-    layer's first page added; ``row_slot``: ``S`` for a pad row.  -> ``[N,
-    nh, kv_lora_rank]``.
+    way it reads its keys.  ``pages``: the latent pool, the index keys and
+    the global group's table ``[S, MB]``, the layer's first page added;
+    ``row_slot``: ``S`` for a pad row.  -> ``[N, nh, kv_lora_rank]``.
 
     A mixed step (``rows``: its ``_MixedRows``, at most ``max_rows`` a
     slot) selects for its one-row slots (riding
@@ -833,6 +902,7 @@ def _selected_attention(q, qi, wi, k_pages, ki_pages, table, row_slot,
     the same gather."""
     from deepspeed_tpu import ops
     from deepspeed_tpu.ops.sparse_index import masked_prefill
+    k_pages, ki_pages, table = pages.k, pages.ki, pages.table
     S, MB = table.shape
     k = min(cfg.index_topk, MB * block_size)
     q = q.astype(cfg.dtype)
@@ -949,13 +1019,13 @@ class _MixedRows(NamedTuple):
     q_counts: jnp.ndarray    # [S] rows the slot holds in this step
 
 
-def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
+def _mixed_attention(q, rows: _MixedRows, pages: _LayerPages, *,
                      cfg: GPTConfig, Q: int, window, mesh):
     """Ragged blocked attention of a mixed step (reference blocked_flash +
-    atom_builder): token-major ``q`` [N, nh, hd] over the flat pool ->
-    [N, nh, vd].  Each slot's rows are one contiguous span of the flat batch
-    and of positions, and stay where they are: both kernels are told where a
-    slot's rows begin.
+    atom_builder): token-major ``q`` [N, nh, hd] over the layer's pages of
+    the flat pool -> [N, nh, vd].  Each slot's rows are one contiguous span
+    of the flat batch and of positions, and stay where they are: both
+    kernels are told where a slot's rows begin.
 
     A slot's rows pick its kernel.  The prefill kernel walks the live
     (slot, q-chunk) items and DMAs only the pages each can causally see, but
@@ -965,6 +1035,7 @@ def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
     (length 0, count 0) and skips them outright; a row takes its slot's
     kernel's result."""
     from deepspeed_tpu import ops
+    k_pages, v_pages, table = pages.k, pages.v, pages.table
     S = table.shape[0]
     N = q.shape[0]
     nh = cfg.num_heads
@@ -973,14 +1044,10 @@ def _mixed_attention(q, rows: _MixedRows, k_pages, v_pages, table, scales, *,
         valid = rows.scat_slot < S
         slot = jnp.where(valid, rows.scat_slot, 0)
         q = q.reshape(N, nkv, nh // nkv, hd).astype(cfg.dtype)
-        slopes = None
-        if cfg.use_alibi:
-            from deepspeed_tpu.models.gpt import alibi_slopes
-            slopes = jnp.asarray(alibi_slopes(nh, hd, cfg.alibi_prescale))
-        pool = dict(alibi_slopes=slopes, window=window,
+        pool = dict(alibi_slopes=_alibi(cfg), window=window,
                     scale=_attn_scale(cfg),
                     mesh=mesh, kv_major=kv_major_layout(cfg),
-                    impl=cfg.attn_impl, **scales, **latent)
+                    impl=cfg.attn_impl, **pages.scales, **latent)
         one_row = rows.q_counts == 1
         # (a latent window layer's kernels under a scope of their own, so
         # that a trace tells them from the global layers' in one program)
@@ -1374,6 +1441,107 @@ def _group_tables(batch):
     return bt
 
 
+class _Step(NamedTuple):
+    """What a KIND of step (mixed: N ragged rows; decode: one row a slot;
+    verify: a dense [S, G]) supplies to ``_layer``: built once a program,
+    before the loop over the layers, the same for every layer."""
+    pos: jax.Array          # the rows' positions, flat
+    tables: tuple           # the block table [S, MB] of each page group
+    plans: tuple            # and its ``_write_plan`` for the step's rows
+    write: Any              # the program's ``_kv_writer``
+    rope: Any               # (q, k, head_dim) -> (q, k) on its row layout
+    attend: Any             # (li, lc, q, pages, base) -> o [.., nh, vd]
+    ffn: dict               # live / stats / routes / experts of ``_ffn``
+    selected: Any = None    # (lc, q, qi, wi, pages, base) -> o, where the
+    #                         selection binds (``_selects``)
+    mixer: Any = None       # a state layer's: (blk, h, scan, si) -> (delta,
+    #                         scan')
+    lora: Any = None        # (the adapter pool's tables, the rows' ids)
+
+
+def _selects(cfg: GPTConfig, tables, block_size: int) -> bool:
+    """The selection binds only where a context can outgrow it: a step
+    program whose table is no wider reads every key, through the kernels."""
+    return tables[0].shape[1] * block_size > cfg.index_topk > 0
+
+
+def _rope(cfg: GPTConfig, q, k, pos, head_dim, seq_lens):
+    """``rope()`` as every step calls it: [B, T, n, d] + positions [B, T]."""
+    return rope(q, k, pos, head_dim, base=cfg.rope_theta,
+                rope_pct=cfg.rope_pct, scaling=cfg.rope_scaling,
+                seq_lens=seq_lens)
+
+
+def _layer(bb, li: int, x, pool: _KVPool, step: _Step, cfg: GPTConfig,
+           kv_layout=None, mesh=None):
+    """Layer ``li`` of a serving step on the step's rows ``x [..., H]``, the
+    ONE place the sequence is written: norm -> (state mixer | latent q/kv |
+    q, k, v + LoRA + qk-norm/gate) -> RoPE -> the rows into the layer's
+    pages -> (index rows + index keys into theirs) -> (selected attention |
+    attend) -> output projection -> sandwich norm -> residual + FFN/MoE.
+    -> (x', pool')."""
+    blk = bb[f"block_{li}"]
+    dense = x.ndim > 2      # the verify step's [S, G, H]; else rows [N, H]
+
+    def residual(h, delta):
+        # the FFN/MoE body is token-wise and (for MoE) expects FLAT tokens
+        # (asked only of a dense layout: a reshape that changes nothing
+        # emits nothing, but costs the host's trace 65 us a call)
+        with jax.named_scope("mlp"):
+            flat = [a.reshape(-1, a.shape[-1]) if dense else a
+                    for a in (x, h, delta)]
+            out = _block_residual(blk, *flat, cfg, mesh=mesh, **step.ffn)
+            return out.reshape(x.shape) if dense else out
+
+    if cfg.is_state_layer(li):
+        with jax.named_scope("attn_qkv"):
+            h = _norm(blk["Norm_0"], x, cfg)
+        delta, (ssm, conv) = step.mixer(
+            blk, h, (pool.ssm, pool.conv),
+            jnp.int32(cfg.state_layers.index(li)))
+        return residual(h, delta), pool._replace(ssm=ssm, conv=conv)
+    ap = blk["Attention_0"]
+    lc = cfg.for_layer(li)           # this layer's attention geometry
+    with jax.named_scope("attn_qkv"):
+        h = _norm(blk["Norm_0"], x, cfg)
+        if cfg.mla:
+            q, k, gate, cq = _mla_qkv(ap, h, step.pos, lc)
+            v = None
+        else:
+            q, k, v = _qkv(ap, h, cfg, mesh=mesh)
+            if step.lora is not None:
+                q, v = _lora_qv(q, v, h, *step.lora, li)
+            q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
+        if cfg.rope_for_layer(li) and not cfg.mla:
+            q, k = step.rope(q, k, lc.head_dim)
+
+    field, base, grp = _layer_pages(cfg, kv_layout, pool, li)
+
+    def rows(a):                # [rows, heads, d], as the write takes them
+        return a.reshape((-1,) + a.shape[-2:]) if dense and a is not None \
+            else a
+    pk, pv, pks, pvs = step.write(
+        getattr(pool, field), pool.v, pool.k_scale, pool.v_scale, rows(k),
+        rows(v), step.plans[grp], base)
+    pool = pool._replace(**{field: pk}, v=pv, k_scale=pks, v_scale=pvs)
+    if lc.index_topk:
+        with jax.named_scope("attn_kernel"), jax.named_scope("attn_index"):
+            qi, wi, ki = _index_rows(ap, h, cq, step.pos, lc)
+            pool = pool._replace(ki=_kv_write_local(
+                (pool.ki,), ki, None, step.plans[grp], base, km=False)[0])
+    pages = _LayerPages(k=pk, v=pv, ki=None, table=step.tables[grp],
+                        k_scale=pks, v_scale=pvs)
+    if lc.index_topk and step.selected is not None:
+        o = step.selected(lc, q, qi, wi, pages._replace(ki=pool.ki), base)
+    else:
+        o = step.attend(li, lc, q, pages, base)
+    with jax.named_scope("attn_out"):
+        attn_delta = _attn_proj(ap, o, gate, lc, mesh=mesh)
+        if cfg.sandwich_norm:
+            attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
+    return residual(h, attn_delta), pool
+
+
 def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                    block_size: int, max_q_per_seq: int, mesh=None,
                    kv_layout=None, moe_stats: bool = False,
@@ -1389,14 +1557,13 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     token_slot = batch["token_slot"]       # [N] (-1 pad)
     token_pos = batch["token_pos"]         # [N]
     tables = _group_tables(batch)          # [S, MB] per page group
-    block_table = tables[0]
     kv_len = batch["kv_len"]               # [S]
-    stats = [] if moe_stats else None
-    experts = _experts_fn(cfg, moe_stats) if cfg.num_experts else None
-    routes = [] if moe_routes else None
+    ffn = dict(
+        stats=[] if moe_stats else None, routes=[] if moe_routes else None,
+        experts=_experts_fn(cfg, moe_stats) if cfg.num_experts else None)
 
     N = tokens.shape[0]
-    S, MB = block_table.shape
+    S = tables[0].shape[0]
     Q = max_q_per_seq
     km = kv_major_layout(cfg)
     valid = token_slot >= 0                # [N]
@@ -1427,28 +1594,14 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
                      for i in cfg.attention_layers}}
     plans = tuple(_write_plan(t, scat_slot, token_pos, block_size, Q, km)
                   for t in tables)
-    write = _kv_writer(km, mesh)
-
-    # [L * num_blocks, nkv, …] views updated IN PLACE through the donated
-    # cache buffer — never rebuild the whole pool (a jnp.stack of per-layer
-    # copies costs a full cache rewrite per step), and never slice a layer
-    # out of it: the attention ops take the flat pool with the layer's
-    # first page added to the block table
-    NB = cache.k.shape[1]
-    flat_k_all, flat_v_all, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
-    flat_kw, flat_ki = _flat_group_views(cache)
-    # the selection binds only where a context can outgrow it: a step
-    # program whose table is no wider reads every key, through the kernels
-    select = MB * block_size > cfg.index_topk > 0
+    pool = _KVPool.open(cache, cfg)
     # (one traced and lowered attention a kind of SELECTING layer too: both
     # ways a chunk's rows can read their keys are in it)
     selected = {lc: jax.jit(named_partial(
         _selected_attention, cfg=lc, block_size=block_size, max_rows=Q))
         for lc in {cfg.for_layer(i) for i in cfg.attention_layers}
-        if lc.index_topk and select}
-    # state layers: the state and conv-tail pools, and which slots take
-    # which route through them
-    scan = (cache.ssm, cache.conv)
+        if lc.index_topk and _selects(cfg, tables, block_size)}
+    # state layers: which slots take which route through their pools
     mixer = state_mixer(cfg)
     if mixer is not None:
         plan = _scan_plan(rows, mixer)
@@ -1463,71 +1616,26 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
     # identity slot 0 (zero delta)
     lora = batch.get("lora")
     if lora is not None:
-        lora_ids = jnp.where(valid,
-                             batch["adapter_slot"][jnp.clip(token_slot, 0)],
-                             0)
+        lora = lora, jnp.where(
+            valid, batch["adapter_slot"][jnp.clip(token_slot, 0)], 0)
 
+    def rope_rows(q, k, head_dim):
+        q, k = _rope(cfg, q[None], k[None], token_pos[None], head_dim,
+                     kv_len[jnp.clip(token_slot, 0)][None])
+        return q[0], k[0]
+
+    step = _Step(
+        token_pos, tables, plans, _kv_writer(km, mesh), rope_rows,
+        attend=lambda li, lc, q, pages, base: attend[
+            lc, cfg.window_for_layer(li)](q, rows, pages.at(base)),
+        selected=(lambda lc, q, qi, wi, pages, base: selected[lc](
+            q, qi, wi, pages.at(base), scat_slot, token_pos, rows=rows))
+        if selected else None,
+        mixer=(lambda blk, h, scan, si: scan_mixer(
+            blk[mixer.key], h, scan, si, plan, rows)) if mixer else None,
+        lora=lora, ffn=dict(ffn, live=valid))
     for li in range(cfg.num_layers):
-        blk = bb[f"block_{li}"]
-        if cfg.is_state_layer(li):
-            with jax.named_scope("attn_qkv"):
-                h = _norm(blk["Norm_0"], x, cfg)
-            delta, scan = scan_mixer(
-                blk[mixer.key], h, scan,
-                jnp.int32(cfg.state_layers.index(li)), plan, rows)
-            with jax.named_scope("mlp"):
-                x = _block_residual(blk, x, h, delta, cfg, mesh=mesh,
-                                    live=valid, stats=stats, routes=routes,
-                                    experts=experts)
-            continue
-        ap, np_ = blk["Attention_0"], blk["Norm_0"]
-        lc = cfg.for_layer(li)           # this layer's attention geometry
-        with jax.named_scope("attn_qkv"):
-            h = _norm(np_, x, cfg)
-            if cfg.mla:
-                q, k, gate, cq = _mla_qkv(ap, h, token_pos, lc)
-                v = None
-            else:
-                q, k, v = _qkv(ap, h, cfg, mesh=mesh)
-                if lora is not None:
-                    q, v = _lora_qv(q, v, h, lora, lora_ids, li)
-                q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
-            if cfg.rope_for_layer(li) and not cfg.mla:
-                # rope() takes [B, T, n, d] + positions [B, T]
-                q, k = rope(q[None], k[None], token_pos[None], cfg.head_dim,
-                            base=cfg.rope_theta, rope_pct=cfg.rope_pct,
-                            scaling=cfg.rope_scaling,
-                            seq_lens=kv_len[jnp.clip(token_slot, 0)][None])
-                q, k = q[0], k[0]
-
-        base, grp = kv_base(cfg, li, NB, kv_layout)
-        own = grp == 1 and flat_kw is not None    # the window group's pool
-        pool, flat_v_all, flat_ks, flat_vs = write(
-            flat_kw if own else flat_k_all, flat_v_all, flat_ks, flat_vs, k,
-            v, plans[grp], base)
-        flat_k_all, flat_kw = ((flat_k_all, pool) if own
-                               else (pool, flat_kw))
-        if lc.index_topk:
-            with jax.named_scope("attn_kernel"), \
-                    jax.named_scope("attn_index"):
-                qi, wi, ki = _index_rows(ap, h, cq, token_pos, lc)
-                flat_ki, = _kv_write_local((flat_ki,), ki, None, plans[grp],
-                                           base, km=False)
-        if lc.index_topk and select:
-            o = selected[lc](q, qi, wi, pool, flat_ki, tables[grp] + base,
-                             scat_slot, token_pos, rows=rows)
-        else:
-            o = attend[lc, cfg.window_for_layer(li)](
-                q, rows, pool, flat_v_all, tables[grp] + base,
-                _layer_kv(flat_ks, flat_vs))
-        with jax.named_scope("attn_out"):
-            attn_delta = _attn_proj(ap, o, gate, lc, mesh=mesh)
-            if cfg.sandwich_norm:
-                attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
-        with jax.named_scope("mlp"):
-            x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh,
-                                live=valid, stats=stats, routes=routes,
-                                experts=experts)
+        x, pool = _layer(bb, li, x, pool, step, cfg, kv_layout, mesh)
 
     # ---- logits gather (reference ragged_ops/logits_gather): the LAST token
     # of each slot's q rows carries the next-token distribution ----
@@ -1535,192 +1643,80 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
         last_flat = jnp.zeros((S,), jnp.int32).at[scat_slot].max(
             jnp.arange(N, dtype=jnp.int32), mode="drop")
     logits = _head(params, bb, x, cfg, mesh=mesh, rows=last_flat)  # [S, V]
-    cache = _rebuild_cache(cache, flat_k_all, flat_v_all, flat_ks, flat_vs,
-                           flat_kw, flat_ki, scan)
-    out = (logits, cache) + ((sum(stats),) if moe_stats else ())
+    out = (logits, pool.close(cache)) + (
+        (sum(ffn["stats"]),) if moe_stats else ())
     # [expert layers, N, k]: the experts each row's router chose
-    return out + ((jnp.stack(routes),) if moe_routes else ())
+    return out + ((jnp.stack(ffn["routes"]),) if moe_routes else ())
 
 
-def _decode_core(params, flat_k_all, flat_v_all, tokens, active, token_pos,
-                 block_table, cfg: GPTConfig, block_size: int, mesh=None,
-                 flat_ks=None, flat_vs=None, lora=None, adapter_slot=None,
-                 kv_layout=None, moe_stats: bool = False, routes=None,
-                 groups=(None, None), scan=(None, None)):
+def _decode_core(params, pool: _KVPool, tokens, active, token_pos, tables,
+                 cfg: GPTConfig, block_size: int, mesh=None, lora=None,
+                 adapter_slot=None, kv_layout=None, moe_stats: bool = False,
+                 routes=None):
     """One decode micro-step: writes each active slot's kv into its page and
     attends over exactly that slot's pages via the paged-attention op
     (ops/paged_attention.py — Pallas kernel on TPU, masked-gather XLA
-    fallback).  Shared by the single-step and burst programs.
-
-    flat_k_all/flat_v_all: [L*NB, nkv, …] views of the donated cache
-    (standard or kv-major trailing order per kv_major_layout(cfg));
-    flat_ks/flat_vs: [L*NB, nkv, bs] per-token scales when the cache is
-    int8-quantized.  ``block_table`` is one table or the tuple of the page
-    groups' (``_group_tables``: (global,) or, with ``kv_layout``, (global,
-    window)).  ``groups``: the window group's own pool and the index-key
-    pool (``_flat_group_views``) where the cache has them.  Returns the
-    updated flat views (incl. scales), the step's MoE counters (None unless
-    ``moe_stats``), the updated ``groups`` and the updated ``scan``: the
-    scan layers' state and conv-tail pools (``PagedKVCache.ssm`` /
-    ``.conv``), each (None, None) for a model without."""
+    fallback).  Shared by the single-step and burst programs.  ``tables``:
+    the page groups' block tables (``_group_tables``).  Returns (logits
+    [S, V], the updated pool, the step's MoE counters: None unless
+    ``moe_stats``)."""
     from deepspeed_tpu import ops
     bb = params["backbone"]
-    dtype = cfg.dtype
-    tables = block_table if isinstance(block_table, tuple) else (block_table,)
     stats = [] if moe_stats else None
-    experts = _experts_fn(cfg, moe_stats) if cfg.num_experts else None
     S = tokens.shape[0]
-    NB = flat_k_all.shape[0] // kv_pool_layers(cfg)
     km = kv_major_layout(cfg)
-    flat_kw, flat_ki = groups
-    select = tables[0].shape[1] * block_size > cfg.index_topk > 0
 
     x = _embed_tokens(bb, tokens, token_pos, cfg)              # [S, H]
 
     row_slot = jnp.where(active, jnp.arange(S), S)
     plans = tuple(_write_plan(t, row_slot, token_pos, block_size, 1, km)
                   for t in tables)
-    write = _kv_writer(km, mesh)
     with jax.named_scope("attn_kernel"):
         kv_len = jnp.where(active, token_pos + 1, 0)                # [S]
     if lora is not None:
         # decode rows ARE slots: mask inactive lanes to the identity slot
         # so a recycled lane's stale selection never computes a delta
-        lora_ids = jnp.where(active, adapter_slot, 0)
+        lora = lora, jnp.where(active, adapter_slot, 0)
     mixer = state_mixer(cfg)
     if mixer is not None:      # one traced and lowered mixer for them all
         scan_mixer = jax.jit(named_partial(_scan_decode, mixer=mixer,
                                            cfg=cfg, mesh=mesh))
 
-    for li in range(cfg.num_layers):
-        blk = bb[f"block_{li}"]
-        if cfg.is_state_layer(li):
-            with jax.named_scope("attn_qkv"):
-                h = _norm(blk["Norm_0"], x, cfg)
-            delta, scan = scan_mixer(
-                blk[mixer.key], h, scan,
-                jnp.int32(cfg.state_layers.index(li)), active, token_pos)
-            with jax.named_scope("mlp"):
-                x = _block_residual(blk, x, h, delta, cfg, mesh=mesh,
-                                    live=active, stats=stats, routes=routes,
-                                    experts=experts)
-            continue
-        ap = blk["Attention_0"]
-        lc = cfg.for_layer(li)           # this layer's attention geometry
-        nh = lc.num_heads
-        nkv, hd, vd, latent = _attn_geometry(lc)
-        with jax.named_scope("attn_qkv"):
-            h = _norm(blk["Norm_0"], x, cfg)
-            if cfg.mla:
-                q, k, gate, cq = _mla_qkv(ap, h, token_pos, lc)
-                v = None
-            else:
-                q, k, v = _qkv(ap, h, cfg, mesh=mesh)
-                if lora is not None:
-                    q, v = _lora_qv(q, v, h, lora, lora_ids, li)
-                q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
-            if cfg.rope_for_layer(li) and not cfg.mla:
-                q, k = rope(q[:, None], k[:, None], token_pos[:, None], hd,
-                            base=cfg.rope_theta, rope_pct=cfg.rope_pct,
-                            scaling=cfg.rope_scaling,
-                            seq_lens=kv_len[:, None])
-                q, k = q[:, 0], k[:, 0]
+    def rope_rows(q, k, head_dim):
+        q, k = _rope(cfg, q[:, None], k[:, None], token_pos[:, None],
+                     head_dim, kv_len[:, None])
+        return q[:, 0], k[:, 0]
 
-        base, grp = kv_base(cfg, li, NB, kv_layout)
-        own = grp == 1 and flat_kw is not None    # the window group's pool
-        pool, flat_v_all, flat_ks, flat_vs = write(
-            flat_kw if own else flat_k_all, flat_v_all, flat_ks, flat_vs, k,
-            v, plans[grp], base)
-        flat_k_all, flat_kw = ((flat_k_all, pool) if own
-                               else (pool, flat_kw))
-        if lc.index_topk:
-            with jax.named_scope("attn_kernel"), \
-                    jax.named_scope("attn_index"):
-                qi, wi, ki = _index_rows(ap, h, cq, token_pos, lc)
-                flat_ki, = _kv_write_local((flat_ki,), ki, None, plans[grp],
-                                           base, km=False)
-        if lc.index_topk and select:
-            o = _selected_attention(
-                q, qi, wi, pool, flat_ki, tables[grp] + base, row_slot,
-                token_pos, lc, block_size=block_size)
-        else:
-            with jax.named_scope("attn_kernel"):
-                qg = q.reshape(S, nkv, nh // nkv, hd)
-                slopes = None
-                if cfg.use_alibi:
-                    from deepspeed_tpu.models.gpt import alibi_slopes
-                    slopes = jnp.asarray(alibi_slopes(nh, hd,
-                                                      cfg.alibi_prescale))
-                win = cfg.window_for_layer(li)
-                with _window_latent_scope(cfg, win):
-                    o = ops.paged_attention(
-                        qg, pool, flat_v_all, tables[grp] + base, kv_len,
-                        alibi_slopes=slopes, window=win,
-                        scale=_attn_scale(lc), mesh=mesh, kv_major=km,
-                        impl=cfg.attn_impl, **_layer_kv(flat_ks, flat_vs),
-                        **latent)
-                o = o.reshape(S, nh, vd)
-        with jax.named_scope("attn_out"):
-            attn_delta = _attn_proj(ap, o, gate, lc, mesh=mesh)
-            if cfg.sandwich_norm:
-                attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
-        with jax.named_scope("mlp"):
-            x = _block_residual(blk, x, h, attn_delta, cfg, mesh=mesh,
-                                live=active, stats=stats, routes=routes,
-                                experts=experts)
+    def attend(li, lc, q, pages, base):
+        nkv, hd, vd, latent = _attn_geometry(lc)
+        with jax.named_scope("attn_kernel"):
+            qg = q.reshape(S, nkv, lc.num_heads // nkv, hd)
+            slopes, win = _alibi(lc), cfg.window_for_layer(li)
+            with _window_latent_scope(cfg, win):
+                o = ops.paged_attention(
+                    qg, pages.k, pages.v, pages.table + base, kv_len,
+                    alibi_slopes=slopes, window=win, scale=_attn_scale(lc),
+                    mesh=mesh, kv_major=km, impl=cfg.attn_impl,
+                    **pages.scales, **latent)
+            return o.reshape(S, lc.num_heads, vd)
+
+    step = _Step(
+        token_pos, tables, plans, _kv_writer(km, mesh), rope_rows, attend,
+        selected=(lambda lc, q, qi, wi, pages, base: _selected_attention(
+            q, qi, wi, pages.at(base), row_slot, token_pos, lc,
+            block_size=block_size))
+        if _selects(cfg, tables, block_size) else None,
+        mixer=(lambda blk, h, scan, si: scan_mixer(
+            blk[mixer.key], h, scan, si, active, token_pos))
+        if mixer else None,
+        lora=lora, ffn=dict(
+            live=active, stats=stats, routes=routes,
+            experts=_experts_fn(cfg, moe_stats) if cfg.num_experts else None))
+    for li in range(cfg.num_layers):
+        x, pool = _layer(bb, li, x, pool, step, cfg, kv_layout, mesh)
 
     logits = _head(params, bb, x, cfg, mesh=mesh)                  # [S, V]
-    return (logits, flat_k_all, flat_v_all, flat_ks, flat_vs,
-            sum(stats) if moe_stats else None, (flat_kw, flat_ki), scan)
-
-
-def _flat_cache_views(cache: PagedKVCache, cfg: GPTConfig):
-    """[L, NB, ...] pool -> the flat [L * NB, ...] views that every layer
-    writes (``_kv_write``) and attends over (the attention ops, with the
-    layer's first page ``li * NB`` added to the block table) in place: the
-    reshape is free, and nothing downstream slices, casts or re-lays the
-    pool.  So an unquantised pool has to be in the compute dtype already,
-    as the engine creates it.  Scope ``kv_pool``, like everything that only
-    moves the pool."""
-    if not cache.quantized and cache.k.dtype != jnp.dtype(
-            cfg.dtype or jnp.float32):
-        raise ValueError(
-            f"KV pool is {cache.k.dtype} but the model computes in "
-            f"{cfg.dtype}: create the pool in the compute dtype")
-    with jax.named_scope("kv_pool"):
-        fk = cache.k.reshape((-1,) + cache.k.shape[2:])
-        fv = (None if cache.v is None
-              else cache.v.reshape((-1,) + cache.v.shape[2:]))
-        q = cache.quantized
-        fks = (cache.k_scale.reshape((-1,) + cache.k_scale.shape[2:])
-               if q else None)
-        fvs = (cache.v_scale.reshape((-1,) + cache.v_scale.shape[2:])
-               if q else None)
-    return fk, fv, fks, fvs
-
-
-def _flat_group_views(cache: PagedKVCache):
-    """The flat views of the pools only a latent model with two page groups
-    has: (the window group's own pool, the index-key pool), each None where
-    the cache has none.  Scope ``kv_pool``."""
-    with jax.named_scope("kv_pool"):
-        return tuple(None if a is None else a.reshape((-1,) + a.shape[2:])
-                     for a in (cache.kw, cache.ki))
-
-
-def _rebuild_cache(cache: PagedKVCache, fk, fv, fks, fvs, fkw=None,
-                   fki=None, scan=(None, None)) -> PagedKVCache:
-    with jax.named_scope("kv_pool"):
-        return PagedKVCache(
-            ssm=scan[0], conv=scan[1],
-            kw=None if fkw is None else fkw.reshape(cache.kw.shape),
-            ki=None if fki is None else fki.reshape(cache.ki.shape),
-            k=fk.reshape(cache.k.shape),
-            v=None if fv is None else fv.reshape(cache.v.shape),
-            k_scale=(fks.reshape(cache.k_scale.shape) if fks is not None
-                     else None),
-            v_scale=(fvs.reshape(cache.v_scale.shape) if fvs is not None
-                     else None))
+    return logits, pool, sum(stats) if moe_stats else None
 
 
 def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
@@ -1741,45 +1737,36 @@ def ragged_decode_burst(params, cache: PagedKVCache, batch, prev_tokens, rng,
     Returns (tokens [T, S], prev_tokens' [S], rng', cache), and with
     ``moe_stats`` the burst's MoE counters, summed over its steps.
     """
-    flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
-    groups = _flat_group_views(cache)
+    pool = _KVPool.open(cache, cfg)
     bt = _group_tables(batch)
     active = batch["active"]
-    lora = batch.get("lora")
-    adapter_slot = batch.get("adapter_slot")
     with jax.named_scope("embed"):
         tokens0 = jnp.where(batch["from_device"], prev_tokens,
                             batch["tokens0"])
 
     def step(carry, _):
-        (flat_k, flat_v, flat_ks, flat_vs, groups, scan, tokens, pos,
-         rng) = carry
-        (logits, flat_k, flat_v, flat_ks, flat_vs, stats,
-         groups, scan) = _decode_core(
-            params, flat_k, flat_v, tokens, active, pos, bt, cfg, block_size,
-            mesh=mesh, flat_ks=flat_ks, flat_vs=flat_vs, lora=lora,
-            adapter_slot=adapter_slot, kv_layout=kv_layout,
-            moe_stats=moe_stats, groups=groups, scan=scan)
+        pool, tokens, pos, rng = carry
+        logits, pool, stats = _decode_core(
+            params, pool, tokens, active, pos, bt, cfg, block_size,
+            mesh=mesh, lora=batch.get("lora"),
+            adapter_slot=batch.get("adapter_slot"), kv_layout=kv_layout,
+            moe_stats=moe_stats)
         with jax.named_scope("sample"):
             rng, sub = jax.random.split(rng)
             nxt = sample_fn(logits, sub, temperature=temperature,
                             top_p=top_p)
             nxt = nxt.astype(jnp.int32)
             pos = pos + 1
-        return ((flat_k, flat_v, flat_ks, flat_vs, groups, scan, nxt, pos,
-                 rng), (nxt, stats))
+        return (pool, nxt, pos, rng), (nxt, stats)
 
-    carry = (flat_k, flat_v, flat_ks, flat_vs, groups,
-             (cache.ssm, cache.conv), tokens0, batch["pos0"], rng)
     # the loop itself belongs to the pool: what it does besides its body's
     # (scoped) work is carry the pool's views from step to step
     with jax.named_scope("kv_pool"):
-        ((flat_k, flat_v, flat_ks, flat_vs, groups, scan, last, _, rng),
-         (toks, stats)) = jax.lax.scan(step, carry, None, length=steps)
+        (pool, last, _, rng), (toks, stats) = jax.lax.scan(
+            step, (pool, tokens0, batch["pos0"], rng), None, length=steps)
     with jax.named_scope("sample"):
         prev_out = jnp.where(active, last, prev_tokens)
-    out = (toks, prev_out, rng, _rebuild_cache(
-        cache, flat_k, flat_v, flat_ks, flat_vs, *groups, scan))
+    out = (toks, prev_out, rng, pool.close(cache))
     return out + (jnp.sum(stats, axis=0),) if moe_stats else out
 
 
@@ -1887,84 +1874,52 @@ def ragged_decode_sampled(params, cache: PagedKVCache, batch, prev_tokens,
     return (prev_out, rng, cache, *stats)
 
 
-def _verify_core(params, flat_k, flat_v, flat_ks, flat_vs, tokens, active,
-                 pos0, block_table, cfg: GPTConfig, block_size: int,
-                 mesh=None):
+def _verify_core(params, pool: _KVPool, tokens, active, pos0, block_table,
+                 cfg: GPTConfig, block_size: int, mesh=None):
     """Multi-token scoring forward for speculative decoding: every active
     slot ingests G contiguous tokens at positions pos0..pos0+G-1 (KV written
     into its pages) and gets logits for ALL G positions back — one program
     scores a whole draft run.  Dense [S, G] layout (no packing: every slot
     scores the same G), attention through the ragged-prefill op over the
     ``S * G`` rows, slot ``s`` holding the G from row ``s * G``.  Returns
-    (logits [S, G, V], updated flat views)."""
+    (logits [S, G, V], the updated pool)."""
     from deepspeed_tpu import ops
     bb = params["backbone"]
-    dtype = cfg.dtype
     S, G = tokens.shape
-    L = cfg.num_layers
-    NB = flat_k.shape[0] // L
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    g = nh // nkv
     km = kv_major_layout(cfg)
 
     positions = pos0[:, None] + jnp.arange(G, dtype=jnp.int32)[None]  # [S,G]
     x = _embed_tokens(bb, tokens, positions, cfg)                     # [S,G,H]
 
     q_counts = jnp.where(active, G, 0).astype(jnp.int32)
-    plan = _write_plan(
+    plan = _write_plan(   # (the flat positions taken where they are made)
         block_table, jnp.repeat(jnp.where(active, jnp.arange(S), S), G),
-        positions.reshape(-1), block_size, G, km)
-    write = _kv_writer(km, mesh)
+        (flat_pos := positions.reshape(-1)), block_size, G, km)
     with jax.named_scope("attn_kernel"):
         kv_len = jnp.where(active, pos0 + G, 0)
 
-    for li in range(cfg.num_layers):
-        blk = bb[f"block_{li}"]
-        ap = blk["Attention_0"]
-        with jax.named_scope("attn_qkv"):
-            h = _norm(blk["Norm_0"], x, cfg)
-            q, k, v = _qkv(ap, h, cfg, mesh=mesh)
-            q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
-            if cfg.rope_for_layer(li):
-                q, k = rope(q, k, positions, hd, base=cfg.rope_theta,
-                            rope_pct=cfg.rope_pct, scaling=cfg.rope_scaling,
-                            seq_lens=kv_len[:, None])
-        flat_k, flat_v, flat_ks, flat_vs = write(
-            flat_k, flat_v, flat_ks, flat_vs, k.reshape(S * G, nkv, hd),
-            v.reshape(S * G, nkv, hd), plan, li * NB)
+    def attend(li, lc, q, pages, base):
         with jax.named_scope("attn_kernel"):
-            slopes = None
-            if cfg.use_alibi:
-                from deepspeed_tpu.models.gpt import alibi_slopes
-                slopes = jnp.asarray(alibi_slopes(nh, hd,
-                                                  cfg.alibi_prescale))
-            win = cfg.window_for_layer(li)
             o = ops.ragged_prefill_attention(
-                q.reshape(S * G, nkv, g, hd).astype(dtype), flat_k, flat_v,
-                block_table + li * NB, kv_len, pos0, q_counts,
+                q.reshape(S * G, nkv, nh // nkv, hd).astype(cfg.dtype),
+                pages.k, pages.v, pages.table + base, kv_len, pos0, q_counts,
                 jnp.arange(S, dtype=jnp.int32) * G, max_q=G,
-                scale=cfg.attn_scale, alibi_slopes=slopes, window=win,
-                mesh=mesh, kv_major=km, impl=cfg.attn_impl,
-                **_layer_kv(flat_ks, flat_vs)).reshape(S, G, nh, hd)
+                scale=cfg.attn_scale, alibi_slopes=_alibi(cfg),
+                window=cfg.window_for_layer(li), mesh=mesh, kv_major=km,
+                impl=cfg.attn_impl, **pages.scales).reshape(S, G, nh, hd)
             # inactive slots (q_counts=0) hold rows the kernel never writes;
             # zero them like ragged_forward does so no future cross-row op
             # (capacity MoE, aux stats) can see NaNs from dead rows
-            o = jnp.where(active[:, None, None, None], o, 0)
-        with jax.named_scope("attn_out"):
-            attn_delta = _attn_out(ap, o if gate is None else o * gate, cfg,
-                                   mesh=mesh)
-            if cfg.sandwich_norm:
-                attn_delta = _norm(blk["post_attn_norm"], attn_delta, cfg)
-        # FFN/MoE body is token-wise and (for MoE) expects FLAT tokens
-        with jax.named_scope("mlp"):
-            H = x.shape[-1]
-            x = _block_residual(blk, x.reshape(S * G, H),
-                                h.reshape(S * G, H),
-                                attn_delta.reshape(S * G, H), cfg, mesh=mesh
-                                ).reshape(S, G, H)
+            return jnp.where(active[:, None, None, None], o, 0)
 
-    logits = _head(params, bb, x, cfg, mesh=mesh)               # [S, G, V]
-    return logits, flat_k, flat_v, flat_ks, flat_vs
+    step = _Step(
+        flat_pos, (block_table,), (plan,), _kv_writer(km, mesh),
+        lambda q, k, head_dim: _rope(cfg, q, k, positions, head_dim,
+                                     kv_len[:, None]), attend, ffn={})
+    for li in range(cfg.num_layers):
+        x, pool = _layer(bb, li, x, pool, step, cfg, mesh=mesh)
+    return _head(params, bb, x, cfg, mesh=mesh), pool           # [S, G, V]
 
 
 def _speculative_burst_core(params, draft_params, cache: PagedKVCache,
@@ -1989,8 +1944,8 @@ def _speculative_burst_core(params, draft_params, cache: PagedKVCache,
     Returns (toks [steps, gamma+1, S], counts [steps, S], prev', rng',
     cache', draft_cache') — the first counts[k, s] of toks[k, :, s] are
     real."""
-    fk, fv, fks, fvs = _flat_cache_views(cache, cfg)
-    dk, dv, dks, dvs = _flat_cache_views(draft_cache, draft_cfg)
+    pool = _KVPool.open(cache, cfg)
+    dpool = _KVPool.open(draft_cache, draft_cfg)
     bt = batch["block_table"]
     active = batch["active"]
     prev0 = jnp.where(batch["from_device"], prev_tokens, batch["tokens0"])
@@ -1998,19 +1953,17 @@ def _speculative_burst_core(params, draft_params, cache: PagedKVCache,
         rng = jax.random.PRNGKey(0)         # greedy: threaded but unused
 
     def outer(carry, _):
-        fk, fv, fks, fvs, dk, dv, dks, dvs, prev, pos, rng = carry
+        pool, dpool, prev, pos, rng = carry
         d_list, q_list = [], []
         dtok, dpos = prev, pos
-        ddk, ddv, ddks, ddvs = dk, dv, dks, dvs
         # the two halves of an outer step, named in the device trace: what
         # the draft costs against the verify is read there, inside the one
         # fused program
         with jax.named_scope("draft"):
             for j in range(gamma + 1):
-                dlogits, ddk, ddv, ddks, ddvs, _, _, _ = _decode_core(
-                    draft_params, ddk, ddv, dtok, active, dpos, bt,
-                    draft_cfg, block_size, mesh=mesh, flat_ks=ddks,
-                    flat_vs=ddvs)
+                dlogits, dpool, _ = _decode_core(
+                    draft_params, dpool, dtok, active, dpos, (bt,),
+                    draft_cfg, block_size, mesh=mesh)
                 if j < gamma:
                     with jax.named_scope("sample"):
                         if sampled:
@@ -2028,9 +1981,9 @@ def _speculative_burst_core(params, draft_params, cache: PagedKVCache,
             d = jnp.stack(d_list, axis=1)                   # [S, gamma]
         with jax.named_scope("verify"):
             ver_in = jnp.concatenate([prev[:, None], d], axis=1)  # [S, g+1]
-            vlogits, fk, fv, fks, fvs = _verify_core(
-                params, fk, fv, fks, fvs, ver_in, active, pos, bt, cfg,
-                block_size, mesh=mesh)
+            vlogits, pool = _verify_core(
+                params, pool, ver_in, active, pos, bt, cfg, block_size,
+                mesh=mesh)
             with jax.named_scope("sample"):
                 if sampled:
                     rng, sub = jax.random.split(rng)
@@ -2043,17 +1996,15 @@ def _speculative_burst_core(params, draft_params, cache: PagedKVCache,
                     emit, jnp.maximum(counts - 1, 0)[:, None], axis=1)[:, 0]
                 new_prev = jnp.where(active, last, prev)
                 new_pos = jnp.where(active, pos + counts, pos)
-        return ((fk, fv, fks, fvs, ddk, ddv, ddks, ddvs, new_prev, new_pos,
-                 rng), (emit.T, counts))
+        return (pool, dpool, new_prev, new_pos, rng), (emit.T, counts)
 
-    carry = (fk, fv, fks, fvs, dk, dv, dks, dvs, prev0, batch["pos0"], rng)
     with jax.named_scope("kv_pool"):     # the loop carries both pools
-        (fk, fv, fks, fvs, dk, dv, dks, dvs, prev, _, rng), (toks, counts) \
-            = jax.lax.scan(outer, carry, None, length=steps)
+        (pool, dpool, prev, _, rng), (toks, counts) = jax.lax.scan(
+            outer, (pool, dpool, prev0, batch["pos0"], rng), None,
+            length=steps)
     prev_out = jnp.where(active, prev, prev_tokens)
-    return (toks, counts, prev_out, rng,
-            _rebuild_cache(cache, fk, fv, fks, fvs),
-            _rebuild_cache(draft_cache, dk, dv, dks, dvs))
+    return (toks, counts, prev_out, rng, pool.close(cache),
+            dpool.close(draft_cache))
 
 
 def speculative_burst(params, draft_params, cache: PagedKVCache,
@@ -2181,18 +2132,11 @@ def ragged_decode_forward(params, cache: PagedKVCache, batch,
     batch: tokens [S], active [S] bool, token_pos [S] (position being written),
     block_table [S, MB] int32 (each slot's physical pages, in order).
     """
-    flat_k, flat_v, flat_ks, flat_vs = _flat_cache_views(cache, cfg)
-    bt = _group_tables(batch)
     routes = [] if moe_routes else None
-    (logits, flat_k, flat_v, flat_ks, flat_vs, stats, groups,
-     scan) = _decode_core(
-        params, flat_k, flat_v, batch["tokens"], batch["active"],
-        batch["token_pos"], bt, cfg, block_size, mesh=mesh, flat_ks=flat_ks,
-        flat_vs=flat_vs, lora=batch.get("lora"),
-        adapter_slot=batch.get("adapter_slot"), kv_layout=kv_layout,
-        moe_stats=moe_stats, routes=routes,
-        groups=_flat_group_views(cache), scan=(cache.ssm, cache.conv))
-    cache = _rebuild_cache(cache, flat_k, flat_v, flat_ks, flat_vs, *groups,
-                           scan)
-    out = (logits, cache) + ((stats,) if moe_stats else ())
+    logits, pool, stats = _decode_core(
+        params, _KVPool.open(cache, cfg), batch["tokens"], batch["active"],
+        batch["token_pos"], _group_tables(batch), cfg, block_size, mesh=mesh,
+        lora=batch.get("lora"), adapter_slot=batch.get("adapter_slot"),
+        kv_layout=kv_layout, moe_stats=moe_stats, routes=routes)
+    out = (logits, pool.close(cache)) + ((stats,) if moe_stats else ())
     return out + ((jnp.stack(routes),) if moe_routes else ())

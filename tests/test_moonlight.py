@@ -164,9 +164,10 @@ def test_generate_matches_the_references_greedy_tokens():
 
 def test_absorbed_decode_is_the_expanded_form_on_the_same_cache():
     """One layer's decode attention read back from the engine's own pages:
-    absorbed (what ``_decode_core`` does: ``q_nope Wkvb_k^T`` against the
-    latent row, ``s c`` carried through ``Wkvb_v``) against keys and values
-    expanded from those pages a head."""
+    absorbed (what the layer body ``_layer`` does in every kind of step,
+    here a decode step's: ``q_nope Wkvb_k^T`` against the latent row, ``s
+    c`` carried through ``Wkvb_v``) against keys and values expanded from
+    those pages a head."""
     sz = sizes()
     cfg, params = model(sz)
     eng = engine(cfg, params)

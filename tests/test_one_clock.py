@@ -136,11 +136,13 @@ def heavy_ops(hlo_text):
     return n, bad
 
 
-def scopes_in(lowered):
+def scopes_in(lowered, known=scope_time.KNOWN):
+    """``known=None``: every component of every name stack (the inner
+    scopes a reader of one layer kind splits a phase by)."""
     text = lowered.as_text(debug_info=True)
     found = set()
     for path in re.findall(r'loc\("([^"]+)"', text):
-        found.update(p for p in path.split("/") if p in scope_time.KNOWN)
+        found.update(p for p in path.split("/") if known is None or p in known)
     return found
 
 
@@ -202,28 +204,104 @@ SERVE_SCOPES = ("embed", "attn_qkv", "kv_write", "attn_kernel", "attn_out",
                 "mlp", "head", "sample", "kv_pool")
 
 
+TINY = dict(slots=4, tokens=64, max_q=16, table_width=8, block_size=16,
+            num_pages=32, steps=4)
+
+
+def _lower_speculative_burst(cfg):
+    """``speculative_burst`` as the engine jits it (both caches donated),
+    the draft one layer of the same widths."""
+    from deepspeed_tpu.inference.v2 import model as v2model
+    from deepspeed_tpu.models.gpt import GPTLogits
+    from deepspeed_tpu.parallel.metadata import unbox
+    S, MB = TINY["slots"], TINY["table_width"]
+    i32, b1 = jnp.int32, jnp.bool_
+    draft = dataclasses.replace(cfg, num_layers=1)
+
+    def shapes(c):
+        return (unbox(jax.eval_shape(
+            lambda k: GPTLogits(c).init(k, jnp.zeros((1, 8), i32)),
+            jax.random.PRNGKey(0))["params"]),
+            jax.eval_shape(lambda: v2model.PagedKVCache.create(
+                c, TINY["num_pages"], TINY["block_size"], jnp.float32)))
+    (params, cache), (dparams, dcache) = shapes(cfg), shapes(draft)
+    batch = {"active": jax.ShapeDtypeStruct((S,), b1),
+             "from_device": jax.ShapeDtypeStruct((S,), b1),
+             "block_table": jax.ShapeDtypeStruct((S, MB), i32),
+             "tokens0": jax.ShapeDtypeStruct((S,), i32),
+             "pos0": jax.ShapeDtypeStruct((S,), i32)}
+    fn = v2model.named_partial(
+        v2model.speculative_burst, cfg=cfg, draft_cfg=draft,
+        block_size=TINY["block_size"], gamma=3, steps=2)
+    return jax.jit(fn, donate_argnums=(2, 3)).lower(
+        params, dparams, cache, dcache, batch, jax.ShapeDtypeStruct((S,), i32))
+
+
 @pytest.fixture(scope="module")
 def serve_lowered():
     """The three serving step programs at a tiny width, lowered as the
-    engine jits them."""
+    engine jits them, and the greedy speculative burst beside them."""
     from conftest import lower_serving_steps
     cfg = dataclasses.replace(
         GPTConfig.llama(num_layers=2, hidden=64, heads=4, vocab_size=128,
                         max_seq_len=256, dtype=None), dtype=jnp.float32)
-    return lower_serving_steps(cfg, jnp.float32, slots=4, tokens=64,
-                               max_q=16, table_width=8, block_size=16,
-                               num_pages=32, steps=4)[2]
+    return {**lower_serving_steps(cfg, jnp.float32, **TINY)[2],
+            "speculative_burst": _lower_speculative_burst(cfg)}
+
+
+@pytest.fixture(scope="module")
+def scan_lowered():
+    """The three programs of a model with a Mamba-2 scan layer beside an
+    attention layer."""
+    from conftest import lower_serving_steps
+    cfg = GPTConfig(
+        vocab_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
+        head_dim=16, hidden_size=64, mlp_dim_override=128, max_seq_len=256,
+        use_rope=True, rope_layers="none", use_rmsnorm=True, gated_mlp=True,
+        layer_types=("mamba", "attention"), ssm_heads=8, ssm_head_dim=16,
+        ssm_state=16, ssm_chunk=8, dtype=jnp.float32)
+    return lower_serving_steps(cfg, jnp.float32, **TINY)[2]
+
+
+@pytest.fixture(scope="module")
+def conv_lowered():
+    """The three programs of a model with gated short-conv layers, an
+    attention layer and experts."""
+    from conftest import lower_serving_steps
+    cfg = GPTConfig(
+        vocab_size=128, num_layers=3, num_heads=4, num_kv_heads=2,
+        head_dim=16, hidden_size=64, mlp_dim_override=128, max_seq_len=256,
+        use_rope=True, use_rmsnorm=True, gated_mlp=True, qk_norm=True,
+        layer_types=("conv", "attention", "conv"), conv_taps=3,
+        num_experts=4, moe_k=2, moe_dropless=True, moe_router="sigmoid",
+        moe_router_bias=True, moe_expert_dim=32, moe_dense_layers=1,
+        dtype=jnp.float32)
+    return lower_serving_steps(cfg, jnp.float32, **TINY)[2]
 
 
 PROGRAMS = ("ragged_forward_sampled", "ragged_decode_sampled",
             "ragged_decode_burst")
+# the scopes the benchmark's readers split a step by, for each kind of layer
+# the one layer body (model.py ``_layer``) runs: (fixture, program, scope),
+# the dense preset's ids as they were
+PHASE_SCOPE_CASES = (
+    [("serve_lowered", p, s) for p in PROGRAMS for s in SERVE_SCOPES]
+    + [("serve_lowered", "speculative_burst", s)
+       for s in SERVE_SCOPES + ("draft", "verify")]
+    + [("scan_lowered", p, s) for p in PROGRAMS
+       for s in SERVE_SCOPES + ("ssm_scan", "ssm_in_proj")]
+    + [("conv_lowered", p, s) for p in PROGRAMS
+       for s in SERVE_SCOPES + ("short_conv", "moe_experts")])
 
 
-@pytest.mark.parametrize("scope", SERVE_SCOPES)
-@pytest.mark.parametrize("program", PROGRAMS)
-def test_serving_program_carries_the_phase_scope(serve_lowered, program,
+@pytest.mark.parametrize(
+    "preset,program,scope", PHASE_SCOPE_CASES,
+    ids=[f"{p}-{s}" if f == "serve_lowered" else f"{f[:4]}-{p}-{s}"
+         for f, p, s in PHASE_SCOPE_CASES])
+def test_serving_program_carries_the_phase_scope(request, preset, program,
                                                  scope):
-    assert scope in scopes_in(serve_lowered[program])
+    lowered = request.getfixturevalue(preset)[program]
+    assert scope in scopes_in(lowered, known=None)
 
 
 @pytest.fixture(scope="module")

@@ -365,7 +365,8 @@ class TestRaggedPrefill:
     @pytest.mark.parametrize("G", [4, 5])
     def test_dense_slots_of_the_verify_program(self, rng, G):
         """The speculative verify program's layout (model.py
-        ``_verify_core``): every slot owns the ``G`` rows from ``s * G`` and
+        ``_verify_core``'s ``attend``, what the verify step supplies to the
+        one layer body ``_layer``): every slot owns the ``G`` rows from ``s * G`` and
         scores all of them or, inactive, none; ``G`` 5 divides by no chunk
         but 1."""
         from deepspeed_tpu.ops.paged_attention import (pallas_ragged_prefill,
