@@ -109,8 +109,8 @@ def _expert_ffn_ragged(tokens, expert_idx, weights, wi, wo, wg=None, *,
     below); serving's forward-only step passes None and lets the registry
     take the Pallas kernel where the backend and the shape allow, which
     needs a gate (the GELU form keeps ``lax.ragged_dot``) and leaves the
-    rows behind the last group unwritten: they are masked here whenever the
-    kernel may have run.
+    rows behind the last group unwritten or holding products of whatever the
+    buffer held: they are masked here whenever the kernel may have run.
     """
     from deepspeed_tpu import ops
     S, H = tokens.shape
@@ -139,9 +139,9 @@ def _expert_ffn_ragged(tokens, expert_idx, weights, wi, wo, wg=None, *,
     o = ops.grouped_gemm(h, wo.astype(tokens.dtype), group_sizes, impl=impl)
     w = weights.reshape(-1)[order].astype(o.dtype)
     if share or impl != "xla":
-        # rows behind the last group were not multiplied: whatever the
-        # backend left there (ragged_dot zeros, the kernel nothing at all)
-        # must not reach the scatter
+        # rows behind the last group are nobody's: whatever the backend
+        # left there (ragged_dot zeros, the kernel its last tile's products
+        # of them and nothing behind that tile) must not reach the scatter
         done = jnp.arange(S * k) < jnp.sum(group_sizes)
         o = jnp.where(done[:, None], o, 0)
         tok_rows = jnp.where(done, tok_rows, S)           # dropped

@@ -1,22 +1,29 @@
 #!/usr/bin/env python3
-"""Step 0 of the expert weight stream (PR 45), on the chip: one expert
-layer's three products at the six shapes the three MoE cells run (a decode
-and a mixed step each), as ``lax.ragged_dot``, as megablox's ``gmm`` from the
-installed jax at a few tilings, and as this repo's kernel
+"""Step 0 of the expert weight stream (PR 45, PR 51), on the chip: one
+expert layer's three products at the ten shapes the five MoE cells run (a
+decode and a mixed step each), as ``lax.ragged_dot``, as megablox's ``gmm``
+from the installed jax at a few tilings, and as this repo's kernel
 (``ops/grouped_gemm.py``) at every row tile its shape rule could choose,
-each as milliseconds and as GB/s of the weights of the experts that have
-rows; beside them the other ops of the ``moe_experts`` scope (the sort, the
-``tokens[tok_rows]`` gather, the weighted scatter-add), so that PERF.md can
-say what share of ``*_moe_experts_ms`` the GEMMs are.
+each as milliseconds, as GB/s of the weights of the experts that have rows
+and, for the kernels, as rows multiplied a live row; beside them the other
+ops of the ``moe_experts`` scope (the sort, the ``tokens[tok_rows]`` gather,
+the weighted scatter-add), so that PERF.md can say what share of
+``*_moe_experts_ms`` the GEMMs are.  The routing is seeded and SKEWED (an
+expert's popularity is log-normal): uniform group sizes straddle fewer row
+tiles than a model's.  ``--parent <checkout>`` times that checkout's
+``ops/grouped_gemm.py`` beside this one at the rule's row tile, in the same
+call.
 
-    chiprun --timeout 1800 -- python3 scripts/step0_grouped_gemm.py
+    git archive HEAD | tar -x -C _parent        # _parent/ is git-ignored
+    chiprun --timeout 1800 -- python3 scripts/step0_grouped_gemm.py --parent _parent
     JAX_PLATFORMS=cpu python3 scripts/step0_grouped_gemm.py --tiny   # here
 
-Writes ``chiprun_out/pr45/step0.jsonl`` (one line a timing) and
+Writes ``chiprun_out/pr51/step0.jsonl`` (one line a timing) and
 ``step0.md`` (the table ``ops/grouped_gemm.py`` quotes).
 """
 
 import argparse
+import importlib.util
 import json
 import os
 import sys
@@ -40,6 +47,10 @@ SHAPES = [
     ("trinity", "mixed", 1024, 4, 256, 32, 3072, 3072),
     ("dots3", "decode", 16, 8, 256, 32, 5120, 1536),
     ("dots3", "mixed", 1024, 8, 256, 32, 5120, 1536),
+    ("lfm2", "decode", 64, 4, 64, 64, 2048, 1536),
+    ("lfm2", "mixed", 2048, 4, 64, 64, 2048, 1536),
+    ("xing4", "decode", 64, 4, 64, 64, 3584, 1024),
+    ("xing4", "mixed", 1024, 4, 64, 64, 3584, 1024),
 ]
 TINY = [("tiny", "decode", 8, 2, 8, 4, 128, 256),
         ("tiny", "mixed", 64, 2, 8, 4, 128, 256)]
@@ -56,11 +67,28 @@ def timed(fn, *args, reps):
 
 
 def route(rng, S, k, routed, held):
-    """``k`` distinct experts of ``routed`` a token, uniformly; the held
-    share is the first ``held``.  -> expert ids [S, k] with the sentinel
-    ``held`` where the expert is not here."""
-    ids = np.stack([rng.permutation(routed)[:k] for _ in range(S)])
+    """``k`` distinct experts of ``routed`` a token, an expert's popularity
+    log-normal (sigma 0.5: the most popular of 64 draws ~7 times the rows
+    of the least; the Gumbel top-k draws without replacement); the held
+    share is a random ``held`` of them, numbered first.  -> expert ids
+    [S, k] with the sentinel ``held`` where the expert is not here."""
+    logits = 0.5 * rng.standard_normal(routed) + rng.gumbel(size=(S, routed))
+    ids = rng.permutation(routed)[np.argsort(-logits, axis=1)[:, :k]]
     return np.where(ids < held, ids, held).astype(np.int32)
+
+
+def rows_multiplied(sizes, tm, sub, by_expert):
+    """Rows of ``tm``-row tiles a kernel multiplies for these group sizes:
+    ``by_expert`` tiles from each group's own first row (rounded down to the
+    sublane packing), else the fixed tiles of the buffer a group overlaps."""
+    ends = np.cumsum(sizes)
+    starts = ends - sizes
+    has = sizes > 0
+    if by_expert:
+        tiles = -(-(ends - starts // sub * sub) // tm)
+    else:
+        tiles = (ends - 1) // tm - starts // tm + 1
+    return int(tiles[has].sum()) * tm
 
 
 def corners(out):
@@ -114,8 +142,17 @@ def main():
     ap.add_argument("--corners", action="store_true",
                     help="only the corner cases, compiled, against ragged_dot")
     ap.add_argument("--reps", type=int, default=20)
-    ap.add_argument("--out", default="chiprun_out/pr45")
+    ap.add_argument("--out", default="chiprun_out/pr51")
+    ap.add_argument("--parent", help="a checkout whose ops/grouped_gemm.py "
+                    "is timed beside this one's")
     args = ap.parse_args()
+    parent = None
+    if args.parent:
+        spec = importlib.util.spec_from_file_location(
+            "parent_grouped_gemm", os.path.join(
+                args.parent, "deepspeed_tpu", "ops", "grouped_gemm.py"))
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
     os.makedirs(args.out, exist_ok=True)
     if args.corners:
         return corners(args.out)
@@ -221,21 +258,25 @@ def main():
 
         it = jnp.dtype(dtype).itemsize
         rule = gg._row_tile(A, held, it)
-        for tm in (16, 32, 64, 128, 256):
-            if A % tm:
-                continue
+        np_sizes = np.asarray(sizes)
+        for impl, mod in (("parent", parent), ("pallas", gg)):
+            for tm in (16, 32, 64, 128, 256):
+                if mod is None or A % tm or (mod is parent and tm != rule):
+                    continue
 
-            def ffn_pl(x, s, wi, wg, wo, tm=tm):
-                h = gg.pallas_grouped_gemm(x, wi, s, wg, tm=tm)
-                return gg.pallas_grouped_gemm(h, wo, s, tm=tm)
-            try:
-                ms = timed(jax.jit(ffn_pl), rows, sizes, *ws, reps=reps)
-                emit(**base, what="ffn", impl="pallas", tm=tm,
-                     tn=[gg._col_tile(H, M, it, 2), gg._col_tile(M, H, it, 1)],
-                     chosen=tm == rule, ms=ms, gbps=gb / ms * 1e3)
-            except Exception as exc:
-                emit(**base, what="ffn", impl="pallas", tm=tm,
-                     error=repr(exc)[:300])
+                def ffn_pl(x, s, wi, wg, wo, tm=tm, mod=mod):
+                    h = mod.pallas_grouped_gemm(x, wi, s, wg, tm=tm)
+                    return mod.pallas_grouped_gemm(h, wo, s, tm=tm)
+                note = dict(impl=impl, tm=tm, chosen=tm == rule, tn=[
+                    mod._col_tile(H, M, it, 2), mod._col_tile(M, H, it, 1)])
+                try:
+                    ms = timed(jax.jit(ffn_pl), rows, sizes, *ws, reps=reps)
+                    emit(**base, what="ffn", **note, ms=ms,
+                         gbps=gb / ms * 1e3, us_per_expert=ms * 1e3 / touched,
+                         rows_per_live_row=rows_multiplied(
+                             np_sizes, tm, 32 // it, mod is gg) / max(live, 1))
+                except Exception as exc:
+                    emit(**base, what="ffn", **note, error=repr(exc)[:300])
         got = jax.jit(lambda x, s, wi, wg, wo: gg.pallas_grouped_gemm(
             gg.pallas_grouped_gemm(x, wi, s, wg), wo, s))(rows, sizes, *ws)
         want = jax.jit(ffn_xla)(rows, sizes, *ws)
@@ -248,7 +289,8 @@ def main():
     log.close()
     with open(os.path.join(args.out, "step0.md"), "w") as f:
         f.write("| cell | step | A | live | touched | floor ms | what | "
-                "impl | tiling | ms | GB/s |\n|" + " --- |" * 11 + "\n")
+                "impl | tiling | ms | GB/s | us an expert | rows multiplied "
+                "a live row |\n|" + " --- |" * 13 + "\n")
         for ln in lines:
             if "ms" not in ln:
                 continue
@@ -258,7 +300,9 @@ def main():
             f.write(f"| {ln['cell']} | {ln['step']} | {ln['A']} | "
                     f"{ln['live']} | {ln['touched']} | {ln['floor_ms']} | "
                     f"{ln['what']} | {ln.get('impl', '')} | {tiling} | "
-                    f"{ln['ms']} | {ln.get('gbps', '')} |\n")
+                    f"{ln['ms']} | {ln.get('gbps', '')} | "
+                    f"{ln.get('us_per_expert', '')} | "
+                    f"{ln.get('rows_per_live_row', '')} |\n")
     print(json.dumps({"device": jax.devices()[0].device_kind,
                       "lines": len(lines)}))
 

@@ -965,9 +965,12 @@ def _store(shape, dim=0):
 @pytest.mark.parametrize("rows,groups,hidden,expert_dim", [
     (288, 64, 2048, 1408), (6144, 64, 2048, 1408),      # Moonlight
     (64, 32, 3072, 3072), (4096, 32, 3072, 3072),       # Trinity's share
-    (128, 32, 5120, 1536), (8192, 32, 5120, 1536)],     # dots3's share
+    (128, 32, 5120, 1536), (8192, 32, 5120, 1536),      # dots3's share
+    (256, 64, 2048, 1536), (8192, 64, 2048, 1536),      # LFM2
+    (256, 64, 3584, 1024), (4096, 64, 3584, 1024)],     # Xing4
     ids=["moonlight-decode", "moonlight-mixed", "trinity-decode",
-         "trinity-mixed", "dots3-decode", "dots3-mixed"])
+         "trinity-mixed", "dots3-decode", "dots3-mixed", "lfm2-decode",
+         "lfm2-mixed", "xing4-decode", "xing4-mixed"])
 def test_grouped_gemm_at_the_cells_widths(topo, rows, groups, hidden,
                                           expert_dim):
     """An expert layer's two kernel calls (gate-up, then down) at a decode

@@ -1,11 +1,17 @@
 """The Pallas grouped GEMM (``ops/grouped_gemm.py``) in interpret mode on the
 CPU against ``lax.ragged_dot`` on the live rows: groups without rows, one
 group holding every row, groups that cross a row tile, a tail behind the
-last group with NaN planted in it, a buffer no row of which is anybody's, both forms (plain, and the gated first
-half against its two-product form), and every row tile the shape rule can
-choose.  Widths scaled down, N kept at 11 x 128 (its only column tiles are
-128 and all of it).  Then the expert layer's function around it
-(``moe/layer.py:_expert_ffn_ragged``): no tail row reaches its result."""
+last group with NaN planted in it, a buffer no row of which is anybody's,
+and what a step a GROUP reaches (its row tiles start at its own first row):
+a group of more than two tiles, one of exactly a tile's rows from the middle
+of a sublane group, a run of one-row groups that share one, groups without
+rows between full ones, the buffer's last rows holding several groups' first
+rows and a tail so that their tiles move up from the buffer's end; both
+forms (plain, and the gated first half against its two-product form), both
+dtypes, and every row tile the shape rule can choose.  Widths scaled down, N
+kept at 11 x 128 (its only column tiles are 128 and all of it).  Then the
+expert layer's function around it (``moe/layer.py:_expert_ffn_ragged``): no
+tail row reaches its result."""
 
 import sys
 
@@ -23,7 +29,33 @@ gg = sys.modules["deepspeed_tpu.ops.grouped_gemm"]
 N = 11 * 128
 
 
-def _sizes(kind, A, G, rng):
+def _sizes(kind, A, G, rng, tm):
+    out = np.zeros(G, int)
+    if kind == "long":              # more than two tiles, from mid-tile on
+        out[0], out[2] = 5, 2 * tm + 9
+        out[G - 1] = A - out.sum()
+        return out
+    if kind == "exact":             # a tile's rows exactly, twice, from row 7
+        out[1], out[2], out[3] = 7, tm, tm
+        out[5] = A - out.sum()
+        return out
+    if kind == "ones":              # nine one-row groups in one sublane group
+        out[0], out[1:10], out[10] = 3, 1, tm + 2
+        return out
+    if kind == "gaps":              # full tiles, groups without rows between
+        out[::3] = tm
+        out[::3][A // tm:] = 0
+        out[G - 1] += A - out.sum()
+        return out
+    if kind == "overrun":
+        # the buffer's last tile of rows: the end of a group that began
+        # before it, a one-row group, the last group and three rows that
+        # are nobody's, so the last groups' tiles move up from the end
+        out[G - 1] = max(tm // 4 - 4, 1)
+        out[G - 2] = 1
+        out[G - 4] = 3 * tm // 4 + 4
+        out[0] = A - 3 - out.sum()
+        return out
     if kind == "one_group":
         out = np.zeros(G, int)
         out[G // 2] = A
@@ -55,7 +87,23 @@ CASES = [
     ("mixed4096-groups32-tail-gated", 4096, 32, 128, "tail", True, "bfloat16", 128),
     ("mixed4096-groups32-crossing-plain", 4096, 32, 128, "crossing", False, "float32", 128),
     ("mixed4096-one_group-plain", 4096, 32, 128, "one_group", False, "bfloat16", 128),
+    # what a step a group reaches (PR 51)
+    ("rows288-groups16-long-gated", 288, 16, 128, "long", True, "bfloat16", 32),
+    ("rows288-groups16-long-plain", 288, 16, 128, "long", False, "float32", 32),
+    ("mixed4096-groups32-exact-gated", 4096, 32, 128, "exact", True, "bfloat16", 128),
+    ("rows288-groups16-exact-plain", 288, 16, 128, "exact", False, "float32", 32),
+    ("decode288-ones-gated", 288, 64, 256, "ones", True, "bfloat16", 16),
+    ("mixed4096-groups64-ones-plain", 4096, 64, 128, "ones", False, "float32", 64),
+    ("mixed4096-groups32-gaps-gated", 4096, 32, 128, "gaps", True, "bfloat16", 128),
+    ("decode64-gaps-plain", 64, 32, 256, "gaps", False, "float32", 8),
+    ("mixed4096-groups32-overrun-gated", 4096, 32, 128, "overrun", True, "bfloat16", 128),
+    ("mixed4096-groups64-overrun-plain", 4096, 64, 128, "overrun", False, "float32", 64),
+    ("rows288-groups16-overrun-gated", 288, 16, 128, "overrun", True, "bfloat16", 32),
+    ("decode64-overrun-plain", 64, 32, 256, "overrun", False, "bfloat16", 16),
+    ("decode64-empty-plain", 64, 32, 256, "empty", False, "float32", 8),
+    ("mixed4096-groups32-empty-gated", 4096, 32, 128, "empty", True, "bfloat16", 128),
 ]
+TAILS = ("tail", "empty", "ones", "overrun")
 
 
 @pytest.mark.parametrize("A,G,K,kind,gated,dtype,tm",
@@ -65,9 +113,9 @@ def test_kernel_matches_ragged_dot_on_the_live_rows(A, G, K, kind, gated,
     dtype = jnp.dtype(dtype)
     assert gg._row_tile(A, G, dtype.itemsize) == tm
     rng = np.random.default_rng(A + G)
-    sizes = _sizes(kind, A, G, rng)
+    sizes = _sizes(kind, A, G, rng, tm)
     live = int(sizes.sum())
-    assert (live < A) == (kind in ("tail", "empty"))
+    assert (sizes >= 0).all() and (live < A) == (kind in TAILS)
     ks = jax.random.split(jax.random.PRNGKey(G), 3)
     x = jax.random.normal(ks[0], (A, K), jnp.float32).astype(dtype)
     x = x.at[live:].set(jnp.nan)        # whoever reads the tail shows it
