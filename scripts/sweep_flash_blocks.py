@@ -1,21 +1,30 @@
 #!/usr/bin/env python
 """Sweep flash-attention (bq, bk) block pairs on the CURRENT hardware.
 
-The baked-in ``_block_pair`` table came from one v5e sweep and does not
-transfer (r05: T=4096 flash MFU 0.425 vs 0.50 dense).  This script times
-fwd+bwd of ``ops.flash_attention`` for each candidate pair on whatever
-backend is attached, prints the ranking, and emits the
-``DSTPU_FLASH_BLOCKS`` env line (or ``ops.configure_flash_blocks`` call)
-that installs the winner — tuning on hardware WITHOUT a code change.
+``(bq, bk)`` are the blocks of the loop INSIDE the kernels
+(``ops/flash_attention.py``): a grid step holds a span of a head's query
+rows (forward) or its keys and values (backward) and walks the live blocks
+of the other side, bq query rows by bk key rows a tile; a tile the diagonal
+crosses is walked in strips of 256 query rows where bq == bk.  The built-in
+table (``_block_pair``: square, the largest power of two dividing T up to
+1,024 at T >= 2048 with heads up to 128 and no window, up to 512 otherwise)
+came from this script's kind of run on one v5e chip, PR 52
+(``chiprun_out/pr52/sweep*.jsonl``; ``PERF.md`` section 6 has the table).
+This script times fwd+bwd of ``ops.flash_attention`` for each candidate pair
+on whatever backend is attached, prints the ranking, and emits the
+``DSTPU_FLASH_BLOCKS`` env line (or ``ops.configure_flash_blocks`` call) that
+installs the winner: tuning on hardware WITHOUT a code change.
 
-    python scripts/sweep_flash_blocks.py --seq 4096 --batch 4 --heads 12
-    python scripts/sweep_flash_blocks.py --seq 4096 --seq 8192 --dtype bf16
+    python scripts/sweep_flash_blocks.py --seq 1024 --batch 8 --heads 16
+    python scripts/sweep_flash_blocks.py --seq 4096 --batch 2 --heads 32 \
+        --kv-heads 8 --head-dim 128          # the four-chip cell's shard
     python scripts/sweep_flash_blocks.py --seq 128 --smoke   # CPU plumbing
 
-Candidates default to the pairs worth considering on TPU (powers of two,
-bq ≤ bk, VMEM-plausible); pass ``--candidates 512x512,512x1024`` to
-restrict.  Pairs that fail to compile (VMEM overflow) are reported and
-skipped — an over-full tile is a hard compile error, not a fallback.
+Candidates default to the powers of two from 128 to 1,024 on both sides (a
+pair with bq > bk is as meaningful as its mirror now); pass ``--candidates
+512x512,512x1024`` to restrict.  Pairs that fail to compile (VMEM overflow)
+are reported and skipped: an over-full tile is a hard compile error, not a
+fallback.
 """
 
 from __future__ import annotations
@@ -32,13 +41,8 @@ if REPO not in sys.path:
 
 
 def default_candidates(t: int) -> List[Tuple[int, int]]:
-    sizes = [b for b in (128, 256, 512, 1024, 2048) if b <= t and t % b == 0]
-    out = []
-    for bq in sizes:
-        for bk in sizes:
-            if bk >= bq:              # wide-K is the useful direction
-                out.append((bq, bk))
-    return out or [(8, 8)]
+    sizes = [b for b in (128, 256, 512, 1024) if b <= t and t % b == 0]
+    return [(bq, bk) for bq in sizes for bk in sizes] or [(8, 8)]
 
 
 def parse_candidates(spec: str) -> List[Tuple[int, int]]:
@@ -54,8 +58,8 @@ def parse_candidates(spec: str) -> List[Tuple[int, int]]:
     return out
 
 
-def time_pair(t, pair, *, batch, heads, head_dim, dtype, iters, fwd_only,
-              interpret):
+def time_pair(t, pair, *, batch, heads, kv_heads, head_dim, dtype, iters,
+              fwd_only, interpret):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -63,9 +67,10 @@ def time_pair(t, pair, *, batch, heads, head_dim, dtype, iters, fwd_only,
     ops.configure_flash_blocks({t: pair})
     rng = np.random.default_rng(0)
     shape = (batch, t, heads, head_dim)
+    kv_shape = (batch, t, kv_heads, head_dim)
     q = jnp.asarray(rng.normal(size=shape) * 0.1, dtype)
-    k = jnp.asarray(rng.normal(size=shape) * 0.1, dtype)
-    v = jnp.asarray(rng.normal(size=shape) * 0.1, dtype)
+    k = jnp.asarray(rng.normal(size=kv_shape) * 0.1, dtype)
+    v = jnp.asarray(rng.normal(size=kv_shape) * 0.1, dtype)
 
     if fwd_only:
         fn = jax.jit(lambda q, k, v: ops.flash_attention(
@@ -92,6 +97,8 @@ def main(argv: Optional[list] = None) -> int:
                     help="sequence length to tune (repeatable)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--heads", type=int, default=12)
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="fewer than --heads for GQA (default: --heads)")
     ap.add_argument("--head-dim", type=int, default=64)
     ap.add_argument("--dtype", choices=("bf16", "fp32"), default="bf16")
     ap.add_argument("--iters", type=int, default=8)
@@ -129,6 +136,7 @@ def main(argv: Optional[list] = None) -> int:
         for pair in cands:
             try:
                 dt = time_pair(t, pair, batch=args.batch, heads=args.heads,
+                               kv_heads=args.kv_heads or args.heads,
                                head_dim=args.head_dim, dtype=dtype,
                                iters=args.iters, fwd_only=args.fwd_only,
                                interpret=interpret)

@@ -118,7 +118,7 @@ def test_flash_fwd_bwd_flagship_shapes(topo, b, t):
     shape = sds((b, t, 12, 64), BF16)
     text = chip_text(topo, jax.grad(loss, argnums=(0, 1, 2)),
                      shape, shape, shape)
-    assert text.count(KERNEL) >= 3          # fwd, dq, dkv
+    assert text.count(KERNEL) >= 2          # fwd, and the one-pass bwd
 
 
 def test_flash_needs_shard_map_over_a_mesh(topo):

@@ -43,26 +43,35 @@ class TestFlashAttention:
                                    atol=2e-5, rtol=1e-4)
 
     def test_block_pair_table(self):
-        """Pin the on-chip-tuned (bq, bk) table (round-5 v5e sweep) so a
-        refactor can't silently regress the measured fast pairs."""
+        """Pin the on-chip-tuned (bq, bk) table (the v5e sweep of PR 52, the
+        blocks of the loop inside the kernels) so a refactor can't silently
+        regress the measured fast pairs."""
         import importlib
         fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
-        assert fa._block_pair(1024) == (1024, 1024)
-        assert fa._block_pair(2048) == (512, 2048)
-        assert fa._block_pair(4096) == (512, 1024)
-        assert fa._block_pair(8192) == (512, 1024)
+        assert fa._block_pair(1024) == (512, 512)
+        assert fa._block_pair(2048) == (1024, 1024)
+        assert fa._block_pair(4096) == (1024, 1024)
+        assert fa._block_pair(8192) == (1024, 1024)
+        assert fa._block_pair(4096, d=128) == (1024, 1024)
         assert fa._block_pair(512) == (512, 512)
         assert fa._block_pair(64) == (64, 64)
-        # non-1024-multiple long T keeps the safe square fallback
+        # a long T that 1,024 does not divide takes the next block down
         assert fa._block_pair(4608) == (512, 512)
-        # sliding window keeps square tiles (whole-seq K defeats the
-        # dead-tile skip that gives T*window scaling)
+        # a window's edge tiles are not walked in strips: 512
         assert fa._block_pair(1024, window=128) == (512, 512)
         assert fa._block_pair(4096, window=256) == (512, 512)
-        # head_dim > 128 keeps square tiles (VMEM envelope only validated
-        # to d=128; an over-full tile is a compile error, not a fallback)
-        assert fa._block_pair(1024, d=256) == (512, 512)
-        assert fa._block_pair(1024, d=128) == (1024, 1024)
+        # head_dim > 128 stays at 512 (as fast there, and known to fit)
+        assert fa._block_pair(4096, d=256) == (512, 512)
+        assert fa._block_pair(1024, d=128) == (512, 512)
+        # a diagonal tile's strips: the backward from two, the forward
+        # from four, never under a window or off a square pair
+        g, z = fa._plan(1024, 64, None), fa._plan(4096, 128, None)
+        assert fa._strips(g, True, None, least=2) == 2
+        assert fa._strips(g, True, None, least=4) == 1
+        assert fa._strips(z, True, None, least=4) == 4
+        assert fa._strips(fa._plan(1024, 64, 128), True, 128, least=2) == 1
+        assert fa._strips(g._replace(bk=1024), True, None, least=2) == 1
+        assert fa._strips(g, False, None, least=2) == 1
 
     def test_rectangular_blocks(self, qkv, monkeypatch):
         """bq != bk (the T>=4096 on-chip fast pair, round 5) must stay
@@ -199,6 +208,83 @@ class TestFlashAttention:
                                   interpret=True)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                    atol=2e-5, rtol=1e-4)
+
+    # the in-kernel loop over live blocks: (bq, bk) pairs whose diagonal
+    # crosses a block off its corner both ways, under every variant, in
+    # the three layouts a shape can take
+    _VARIANTS = {
+        "plain": {},
+        "gqa": {"kv_heads": 2},
+        "window": {"window": 21},
+        "alibi": {"alibi": True},
+        "combined": {"kv_heads": 2, "window": 40, "alibi": True},
+        "noncausal": {"causal": False},
+    }
+    _LAYOUTS = {
+        # one grid step a head, bounds static, keys and values resident
+        "resident": {},
+        # several query spans a head: bounds from the program ids
+        "spans": {"_SPAN_ROWS": 32},
+        # a square pair's diagonal tiles walked in four strips (or eight),
+        # forward and backward; in two, the backward alone
+        "strips": {"_STRIP": 8},
+        "two_strips": {"_STRIP": 16},
+        # keys, values and queries arrive in groups; dq from its own kernel
+        "streamed": {"_RESIDENT_ROWS_X_DIM": 0, "_SPAN_ROWS": 32,
+                     "_STREAM_ROWS": 64},
+    }
+
+    def _loop_case(self, qkv, monkeypatch, blocks, variant, layout):
+        import importlib
+        from deepspeed_tpu.models.gpt import alibi_slopes
+        fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
+        monkeypatch.setattr(fa, "_block_pair",
+                            lambda t, d=64, window=None: blocks)
+        for name, value in self._LAYOUTS[layout].items():
+            monkeypatch.setattr(fa, name, value)
+        opts = dict(self._VARIANTS[variant])
+        q, k, v = (x[:1] for x in qkv)
+        kv_heads = opts.pop("kv_heads", q.shape[2])
+        k, v = k[:, :, :kv_heads], v[:, :, :kv_heads]
+        if opts.pop("alibi", False):
+            opts["alibi_slopes"] = jnp.asarray(alibi_slopes(q.shape[2]))
+        return q, k, v, opts
+
+    @pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+    @pytest.mark.parametrize("variant", sorted(_VARIANTS))
+    @pytest.mark.parametrize("blocks", [(16, 64), (64, 16), (32, 32)])
+    def test_live_block_loop_matches_xla(self, qkv, monkeypatch, blocks,
+                                         variant, layout):
+        q, k, v, opts = self._loop_case(qkv, monkeypatch, blocks, variant,
+                                        layout)
+        ref = ops.causal_attention(q, k, v, impl="xla", **opts)
+        out = ops.flash_attention(q, k, v, interpret=True, **opts)
+        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
+                                   atol=2e-5, rtol=1e-4)
+        gr = jax.grad(lambda *a: jnp.sum(ops.causal_attention(
+            *a, impl="xla", **opts) ** 2), argnums=(0, 1, 2))
+        gf = jax.grad(lambda *a: jnp.sum(ops.flash_attention(
+            *a, interpret=True, **opts) ** 2), argnums=(0, 1, 2))
+        for a, b in zip(gr(q, k, v), gf(q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-4, rtol=1e-3)
+
+    @pytest.mark.parametrize("layout", ["resident", "streamed"])
+    def test_dead_blocks_are_not_computed(self, qkv, monkeypatch, layout):
+        """NaN in the values of every key block past the first query
+        block's diagonal: a block that was computed and masked would carry
+        it into that block's output (0 * NaN) and dq."""
+        q, k, v, _ = self._loop_case(qkv, monkeypatch, (32, 32), "plain",
+                                     layout)
+        v = v.at[:, 32:].set(jnp.nan)
+
+        def first_block(q_, k_, v_):
+            return ops.flash_attention(q_, k_, v_, interpret=True)[:, :32]
+
+        out = first_block(q, k, v)
+        dq = jax.grad(lambda *a: jnp.sum(first_block(*a) ** 2))(q, k, v)
+        assert np.isfinite(np.asarray(out)).all()
+        assert np.isfinite(np.asarray(dq[:, :32])).all()
 
     def test_window_alibi_now_kernel_supported(self, qkv):
         """VERDICT r2 item 3: supported() must accept alibi/window so the
@@ -397,7 +483,10 @@ class TestDispatchLog:
         assert self._log() == {
             ("causal_attention", "xla", "backend is not tpu"): 1,
             ("causal_attention", "pallas", "forced"): 1,
-            ("causal_attention", "xla", "forced"): 1}
+            ("causal_attention", "xla", "forced"): 1,
+            # the kernel's own note: the form that ran
+            ("flash_attention", "pallas",
+             "blocks 16x16, backward one-pass"): 1}
         assert "forced (1)" in ops.op_report()
 
     def test_shape_predicate_refusal_is_recorded(self, monkeypatch):
@@ -408,6 +497,29 @@ class TestDispatchLog:
         ops.causal_attention(q, q, q)
         assert self._log() == {
             ("causal_attention", "xla", "shape predicate refused"): 1}
+
+    def test_flash_note_says_blocks_and_backward_form(self):
+        """Which kernel a cell's step used, from shapes alone: the two
+        train cells' attention shapes, traced and never run."""
+        from deepspeed_tpu.ops import registry
+        registry.reset_dispatch_log()
+        for q_shape, kv_heads in (((8, 1024, 16, 64), 16),
+                                  ((2, 4096, 32, 128), 8)):
+            q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+            kv = jax.ShapeDtypeStruct(
+                q_shape[:2] + (kv_heads,) + q_shape[3:], jnp.bfloat16)
+            jax.eval_shape(lambda q_, k_, v_: ops.flash_attention(
+                q_, k_, v_, interpret=True), q, kv, kv)
+        assert self._log() == {
+            ("flash_attention", "pallas",
+             "blocks 512x512, backward one-pass"): 1,
+            ("flash_attention", "pallas",
+             "blocks 1024x1024, backward one-pass"): 1}
+        # a head whose rows no longer fit the budget streams
+        import importlib
+        fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
+        assert fa.flash_plan(32768, 128)[2] == "streamed"
+        assert fa.flash_plan(16384, 64)[2] == "one-pass"
 
     def test_kernel_side_fallback_is_counted(self):
         from deepspeed_tpu.ops import registry
