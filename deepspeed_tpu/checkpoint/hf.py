@@ -414,6 +414,96 @@ def xing4_0_config(hf: Dict[str, Any], *, max_seq_len: Optional[int] = None,
                       float(hf.get("mhc_h_res_clamp_max", 30.0))))
 
 
+def mimo_v2_flash_config(hf: Dict[str, Any], *,
+                         max_seq_len: Optional[int] = None, dtype=None,
+                         experts_held: Optional[int] = None,
+                         expert_offset: int = 0):
+    """GPTConfig of a published ``mimo_v2_flash`` ``config.json``
+    (MiMo-V2-Flash): ``hybrid_layer_pattern`` (0 = full, 1 = sliding window)
+    over grouped-query layers of two geometries, the full layers' (``num_*``,
+    ``rope_theta``) and the window layers' (``swa_*``: their own kv heads and
+    rope base, ``GPTConfig.window_attn``), keys ``head_dim`` wide and values
+    ``v_head_dim``; the leading ``partial_rotary_factor`` of a head rotates;
+    the values scaled by ``attention_value_scale``; a learned sink logit a
+    head where ``add_swa_attention_sink_bias`` /
+    ``add_full_attention_sink_bias`` say (``attn_sink``); ``moe_layer_freq``
+    (a list: leading dense layers, then experts), sigmoid-routed experts with
+    a selection bias (``noaux_tc``) and no shared expert.
+    ``experts_held``/``expert_offset`` give one chip's share of the experts.
+    The multi-token-prediction layers the family is described with are a
+    draft head, named by no key of the config, and are not built.
+    ``attention_chunk_size`` is carried by the config and read by nothing."""
+    from deepspeed_tpu.models.gpt import GPTConfig
+    n = hf["num_hidden_layers"]
+    pattern = list(hf["hybrid_layer_pattern"])[:n]
+    freq = hf.get("moe_layer_freq", 1)
+    freq = [int(freq)] * n if isinstance(freq, int) else list(freq)[:n]
+    dense = freq.index(1) if 1 in freq else n
+    sinks = (bool(hf.get("add_swa_attention_sink_bias", False)),
+             bool(hf.get("add_full_attention_sink_bias", False)))
+    for key, ok, what in (
+            ("n_group", hf.get("n_group", 1) == 1
+             and hf.get("topk_group", 1) == 1, "group-limited routing"),
+            ("scoring_func", hf.get("scoring_func", "sigmoid") == "sigmoid",
+             "softmax scores"),
+            ("topk_method", hf.get("topk_method", "noaux_tc") == "noaux_tc",
+             "a selection without the noaux_tc bias"),
+            ("n_shared_experts", not hf.get("n_shared_experts"),
+             "shared experts"),
+            ("moe_layer_freq", all(freq[dense:]) and len(freq) == n,
+             "dense layers among the expert layers"),
+            ("hybrid_layer_pattern", len(pattern) == n and set(pattern)
+             <= {0, 1}, "a pattern that does not name every layer"),
+            ("add_full_attention_sink_bias", sinks != (False, True),
+             "a sink on the full layers alone"),
+            ("attention_bias", not hf.get("attention_bias", False),
+             "attention biases"),
+            ("rope_scaling", (hf.get("rope_scaling") or {}).get(
+                "rope_type", "default") == "default", "rope scaling")):
+        if not ok:
+            raise NotImplementedError(
+                f"mimo_v2_flash: {key}={hf.get(key)!r}: {what} is not built")
+    window_attn = tuple(
+        (field, hf[swa]) for field, swa, full in (
+            ("num_heads", "swa_num_attention_heads", "num_attention_heads"),
+            ("num_kv_heads", "swa_num_key_value_heads",
+             "num_key_value_heads"),
+            ("head_dim", "swa_head_dim", "head_dim"),
+            ("v_head_dim", "swa_v_head_dim", "v_head_dim"))
+        if hf.get(swa, hf[full]) != hf[full])
+    theta = float(hf.get("rope_theta", 10000.0))
+    if float(hf.get("swa_rope_theta", theta)) != theta:
+        window_attn += (("rope_theta", float(hf["swa_rope_theta"])),)
+    msl = hf.get("max_position_embeddings", 2048)
+    return GPTConfig(
+        vocab_size=hf["vocab_size"], num_layers=n,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"], head_dim=hf["head_dim"],
+        v_head_dim=hf.get("v_head_dim", hf["head_dim"]),
+        hidden_size=hf["hidden_size"],
+        mlp_dim_override=hf["intermediate_size"],
+        max_seq_len=min(msl, max_seq_len or msl),
+        use_rope=True, rope_theta=theta,
+        rope_pct=float(hf.get("partial_rotary_factor", 1.0)),
+        use_rmsnorm=True, norm_eps=float(hf.get("layernorm_epsilon", 1e-5)),
+        gated_mlp=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        sliding_window=int(hf["sliding_window"]),
+        local_attn_layers=tuple(i for i, w in enumerate(pattern) if w),
+        window_attn=window_attn,
+        attn_sink={(True, True): "all", (True, False): "window",
+                   (False, False): None}[sinks],
+        attn_value_scale=(float(hf["attention_value_scale"])
+                          if hf.get("attention_value_scale") else None),
+        num_experts=hf["n_routed_experts"], moe_k=hf["num_experts_per_tok"],
+        moe_dropless=True, moe_router="sigmoid",
+        moe_route_norm=bool(hf.get("norm_topk_prob", True)),
+        moe_route_scale=float(hf.get("routed_scaling_factor") or 1.0),
+        moe_router_bias=True, moe_expert_dim=hf["moe_intermediate_size"],
+        moe_dense_layers=dense, experts_held=experts_held,
+        expert_offset=expert_offset, dtype=dtype or jnp.bfloat16)
+
+
 def deepseek_v3_config(hf: Dict[str, Any], *,
                        max_seq_len: Optional[int] = None, dtype=None):
     """GPTConfig of a published ``deepseek_v3`` ``config.json`` of the shape
@@ -926,6 +1016,8 @@ def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
         return dots3_note_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     if hf.get("model_type") == "xing4_0":
         return xing4_0_config(hf, max_seq_len=max_seq_len, dtype=dtype)
+    if hf.get("model_type") == "mimo_v2_flash":
+        return mimo_v2_flash_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     if hf.get("model_type") == "granitemoehybrid":
         return granite_hybrid_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     if hf.get("model_type") == "lfm2_moe":
@@ -2271,6 +2363,15 @@ def load_hf_checkpoint(model_path: str, *, max_seq_len: Optional[int] = None,
             "published tensor names of the hyper-connections are not in the "
             "config and none is guessed here: a map under a real model's "
             "name that reads the wrong tensors is worse than none")
+    if cfg.attn_sink or cfg.window_attn and not cfg.mla:
+        raise NotImplementedError(
+            "mimo_v2_flash checkpoints: the config maps "
+            "(mimo_v2_flash_config), but the published tensor names (the "
+            "sinks', the two attention geometries') are not in the catalog's "
+            "row, which gives this model's config.json and not its "
+            "checkpoint: there is no name map to hold a loader to, and none "
+            "is guessed here; build the model from the config and pass "
+            "weights of your own")
     if cfg.mla:
         return cfg, _deepseek_v3_tree(_ShardReader(model_path), cfg)
     if cfg.conv_layers:

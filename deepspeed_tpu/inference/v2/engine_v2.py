@@ -371,10 +371,16 @@ class InferenceEngineV2:
             if isinstance(params, dict) and "params" in params:
                 params = params["params"]
             dt = self.config.jnp_dtype
-            self.params = jax.tree_util.tree_map(
-                lambda p: jnp.asarray(p).astype(dt)
-                if jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating)
-                else jnp.asarray(p), params)
+
+            def cast(path, p):
+                p = jnp.asarray(p)
+                # (a layer's sink logits stay float32: they stand beside
+                # float32 scores in the softmax, models/gpt.py Attention)
+                if (not jnp.issubdtype(p.dtype, jnp.floating)
+                        or getattr(path[-1], "key", None) == "sink"):
+                    return p
+                return p.astype(dt)
+            self.params = jax.tree_util.tree_map_with_path(cast, params)
 
         # ---- quantized weight store (config block ``quant``): int8 codes +
         # group scales in HBM; model.py's _w/_embed dequantize per use site
@@ -619,6 +625,32 @@ class InferenceEngineV2:
                         else "a model whose layers are all full "
                         "(DeepSeek-V3.2's shape) keeps one page group, "
                         "whose pool holds no index keys: not built"))
+        own_values = (not model_cfg.mla
+                      and model_cfg.value_dim != model_cfg.head_dim)
+        if model_cfg.attn_sink or own_values:
+            # a learned sink logit a query head, or a value head narrower
+            # than its key (ops/paged_attention.py: both kernels and both
+            # fallbacks take either).  What is not built beside them:
+            noun = " and ".join(
+                n for n, on in (("a per-head sink (attn_sink)",
+                                 model_cfg.attn_sink),
+                                ("a value width of its own (v_head_dim)",
+                                 own_values)) if on)
+            for what, why in (
+                    (sm.kv_quant, "kv_quant: the int8 kernels carry no sink "
+                     "and the scale pools one width"),
+                    (self.mesh is not None, "a tp mesh: the sink and the "
+                     "value pool are not sharded with the kv heads"),
+                    (draft_model is not None, "speculative decoding: a "
+                     "draft's pool and the verify core are not built over "
+                     "either"),
+                    (self.config.adapters.enabled, "LoRA adapter pages: "
+                     "their v deltas are sized by the key's width"),
+                    (sm.prefix_cache and not model_cfg.sliding_window,
+                     "the prefix cache: not tested over them")):
+                if what:
+                    raise NotImplementedError(
+                        f"{noun} is not built with {why}")
         if self.kv_window:
             for what, why in (     # (prefix_cache: DSStateManager refuses)
                     (sm.kv_quant, "kv_quant: the scale pools are not "
@@ -644,9 +676,11 @@ class InferenceEngineV2:
                     f"sequence's ring of {min(ring, blocks_per_seq)} pages "
                     f"(window {self.kv_window} + a chunk of "
                     f"{sm.max_q_per_seq} rows in pages of {eff_bs})")
-            from deepspeed_tpu.inference.v2.model import kv_page_layout
+            from deepspeed_tpu.inference.v2.model import (kv_groups_split,
+                                                          kv_page_layout)
             self._model_static["kv_layout"] = kv_page_layout(
-                model_cfg, num_blocks, window_blocks, split=model_cfg.mla)
+                model_cfg, num_blocks, window_blocks,
+                split=kv_groups_split(model_cfg))
         if draft_model is None and model_cfg.num_experts and any(
                 model_cfg.is_moe_layer(i)
                 for i in range(model_cfg.num_layers)):
@@ -1176,7 +1210,7 @@ class InferenceEngineV2:
         tables = self.state.tables()
         for r in reqs:
             seq = self.state.get(r.uid)
-            self.state.ensure_blocks(seq, steps)
+            self.state.ensure_blocks(seq, steps, decode=True)
             sl = seq.slot
             if r.held_token is not None:
                 tokens0[sl] = r.held_token
@@ -1609,10 +1643,12 @@ class InferenceEngineV2:
             itemsize = int(np.dtype(self.config.jnp_dtype).itemsize)
         except TypeError:       # bfloat16 without a numpy extension
             itemsize = 2
-        row = (mc.latent_page_dim if mc.mla
-               else 2 * mc.kv_heads * mc.head_dim)
-        return int(len(mc.attention_layers) * self._block_size * row
-                   * itemsize)
+        if mc.mla:
+            row = len(mc.attention_layers) * mc.latent_page_dim
+        else:              # each layer at its own heads and widths
+            row = sum(lc.kv_heads * (lc.head_dim + lc.value_dim)
+                      for lc in map(mc.for_layer, mc.attention_layers))
+        return int(self._block_size * row * itemsize)
 
     def kv_bytes_per_token(self) -> int:
         """Device bytes the pool stores for one cached token over all layers
@@ -1632,9 +1668,11 @@ class InferenceEngineV2:
 
     def kv_bytes_by_group(self) -> Dict[str, int]:
         """``kv_bytes_per_token`` split by what holds the bytes, for a
-        latent model with two page groups (else {}): a token's rows in the
-        global layers' pool, in the window layers' (while the window holds
-        it), and its index keys (``index_bytes_per_token``)."""
+        model with a pool a page group (latent pages, or ordinary heads
+        whose groups differ; else {}): a token's rows in the global layers'
+        pool(s), in the window layers' (while the window holds it), each
+        from its own pool's geometry, and its index keys
+        (``index_bytes_per_token``)."""
         c = self.cache
         if c.kw is None:
             return {}
@@ -1642,12 +1680,16 @@ class InferenceEngineV2:
         kinds = [mc.window_for_layer(i) is not None
                  for i in range(mc.num_layers)]
 
-        def row(pool, layers):
-            return int(layers * pool.shape[-1] * pool.dtype.itemsize)
-        out = {"kv_bytes_per_token_global": row(c.k, kinds.count(False)),
-               "kv_bytes_per_token_window": row(c.kw, kinds.count(True))}
+        def row(layers, *pools):      # a token's bytes over the group's layers
+            return int(sum(
+                layers * a.size * a.dtype.itemsize
+                // (a.shape[1] * self._block_size)
+                for a in pools if a is not None))
+        out = {"kv_bytes_per_token_global": row(kinds.count(False), c.k, c.v),
+               "kv_bytes_per_token_window": row(kinds.count(True), c.kw,
+                                                c.vw)}
         if c.ki is not None:
-            out["index_bytes_per_token"] = row(c.ki, kinds.count(False))
+            out["index_bytes_per_token"] = row(kinds.count(False), c.ki)
         return out
 
     # ------------------------------- continuous batching (Dynamic SplitFuse)

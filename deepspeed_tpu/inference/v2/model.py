@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.models.gpt import (GPTConfig, hc_maps, hc_read, hc_write,
                                       latent_softmax_scale, mlp_activation,
-                                      rope)
+                                      rope, value_scale)
 
 
 def named_partial(fn, **static):
@@ -61,8 +61,32 @@ def kv_major_layout(cfg: GPTConfig) -> bool:
     lanes instead.  Pure function of the model config, so every component
     (cache alloc, scatter, kernels, fallbacks) derives the same answer.
     A latent page is row-major: its row is padded to whole lane tiles
-    instead (``cfg.latent_page_dim``)."""
-    return cfg.head_dim % 128 != 0 and not cfg.mla
+    instead (``cfg.latent_page_dim``).  A value head of its own width
+    (``cfg.value_dim``) obeys the same rule as the key's."""
+    return ((cfg.head_dim % 128 != 0 or cfg.value_dim % 128 != 0)
+            and not cfg.mla)
+
+
+def _page_geometry(cfg: GPTConfig):
+    """What shapes an ordinary layer's pages: (kv heads, key width, value
+    width) of the layer's view (``GPTConfig.for_layer``)."""
+    return cfg.kv_heads, cfg.head_dim, cfg.value_dim
+
+
+def kv_groups_split(cfg: GPTConfig) -> bool:
+    """Whether a model with window AND global layers keeps a pool a page
+    group (``PagedKVCache.create_latent_groups`` / ``create_grouped``'s
+    ``kw``, ``vw``) instead of one flat array for both: latent pages always;
+    ordinary heads where the two kinds of layer differ in kv heads or widths
+    (MiMo-V2: 4 against 8 kv heads), or a value is not as wide as its key
+    (one flat array would hold K and V pages of one shape)."""
+    if cfg.mla:
+        return True
+    geometry = {cfg.window_for_layer(i) is not None:
+                _page_geometry(cfg.for_layer(i))
+                for i in cfg.attention_layers}
+    g, w = geometry[False], geometry[True]
+    return g != w or g[1] != g[2]
 
 
 def kv_block_size_for(cfg: GPTConfig, requested: int,
@@ -139,7 +163,13 @@ class PagedKVCache(NamedTuple):
     "the packed state pool": 64 heads of 64 over a state of 128 are ``[32,
     128, 128]``).  A slot is never cleared: a sequence's row at position 0
     starts from zero whatever the slot held (``_scan_plan``), which is also
-    how a preempted sequence is recomputed."""
+    how a preempted sequence is recomputed.
+
+    Ordinary heads whose page groups differ (``kv_groups_split``: the window
+    layers' kv heads or widths are not the global layers', or a value head
+    is not as wide as a key head): a pool a group here too, ``k`` / ``v``
+    the global layers' ``[1, pages, nkv, ...]`` and ``kw`` / ``vw`` the
+    window layers', each at its own heads and widths (``create_grouped``)."""
 
     k: jax.Array
     v: Optional[jax.Array]
@@ -149,6 +179,7 @@ class PagedKVCache(NamedTuple):
     ki: Optional[jax.Array] = None
     ssm: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
+    vw: Optional[jax.Array] = None
 
     @property
     def quantized(self) -> bool:
@@ -189,8 +220,15 @@ class PagedKVCache(NamedTuple):
             shape = (layers, num_blocks, cfg.kv_heads, block_size,
                      cfg.head_dim)
         if quant is None:
-            return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+            vshape = shape
+            if cfg.value_dim != cfg.head_dim:      # a value of its own width
+                vshape = shape[:2] + _page_shape(cfg, block_size)[1]
+            return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(vshape, dtype),
                        **scan)
+        if cfg.value_dim != cfg.head_dim:
+            raise NotImplementedError(
+                "kv_quant beside a value width of its own (v_head_dim) is "
+                "not built")
         if quant != "int8":
             raise ValueError(f"unsupported kv_quant {quant!r}; use 'int8'")
         sshape = (layers, num_blocks, cfg.kv_heads, block_size)
@@ -228,15 +266,46 @@ class PagedKVCache(NamedTuple):
         ``nb_global`` pages for each global layer and then ``nb_window`` for
         each window layer (``kv_page_layout`` says where each layer's
         begin), so that a window layer does not keep what it will never
-        read again (ragged.py, the window group's ring)."""
+        read again (ragged.py, the window group's ring).  Where the groups'
+        pages differ in shape (``kv_groups_split``): a pool a group, ``k`` /
+        ``v`` of the global layers' pages and ``kw`` / ``vw`` of the window
+        layers', each group's K and V at its own heads and widths."""
         n_window = sum(cfg.window_for_layer(i) is not None
                        for i in range(cfg.num_layers))
+        if kv_groups_split(cfg):
+            kinds = [cfg.window_for_layer(i) is not None
+                     for i in range(cfg.num_layers)]
+
+            def pools(window, pages):
+                lc = cfg.for_layer(kinds.index(window))
+                if kv_major_layout(lc) != kv_major_layout(cfg):
+                    raise NotImplementedError(
+                        "window and global layers whose head widths fall "
+                        "on either side of the 128-lane rule (one kv-major, "
+                        "one not) are not built: the step programs write "
+                        "both groups in one page layout")
+                return [jnp.zeros((1, kinds.count(window) * pages) + shape,
+                                  dtype)
+                        for shape in _page_shape(lc, block_size)]
+            (k, v), (kw, vw) = (pools(False, nb_global),
+                                pools(True, nb_window))
+            return cls(k=k, v=v, kw=kw, vw=vw)
         pages = ((cfg.num_layers - n_window) * nb_global
                  + n_window * nb_window)
         page = ((cfg.head_dim, block_size) if kv_major_layout(cfg)
                 else (block_size, cfg.head_dim))
         shape = (1, pages, cfg.kv_heads) + page
         return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+
+def _page_shape(cfg: GPTConfig, block_size: int):
+    """(a K page's shape, a V page's) of an ordinary layer at ``cfg``'s (its
+    view's) heads and widths: ``[nkv, block_size, width]``, kv-major
+    ``[nkv, width, block_size]``."""
+    km = kv_major_layout(cfg)
+    return tuple((cfg.kv_heads,) + ((w, block_size) if km
+                                    else (block_size, w))
+                 for w in (cfg.head_dim, cfg.value_dim))
 
 
 class _KVPool(PagedKVCache):
@@ -277,7 +346,7 @@ class _KVPool(PagedKVCache):
         with jax.named_scope("kv_pool"):
             return cache._replace(ssm=self.ssm, conv=self.conv, **{
                 n: shaped(n)
-                for n in ("kw", "ki", "k", "v", "k_scale", "v_scale")})
+                for n in ("kw", "ki", "k", "v", "k_scale", "v_scale", "vw")})
 
 
 class _LayerPages(NamedTuple):
@@ -293,30 +362,38 @@ class _LayerPages(NamedTuple):
     table: jax.Array
     k_scale: Optional[jax.Array]
     v_scale: Optional[jax.Array]
+    sink: Optional[jax.Array] = None     # the layer's sink logits [heads]
 
     def at(self, base):
         return self._replace(table=self.table + base)
 
     @property
     def scales(self):
-        """Scale kwargs of the attention ops for an int8 pool."""
-        return ({} if self.k_scale is None
-                else dict(k_scale=self.k_scale, v_scale=self.v_scale))
+        """Scale kwargs of the attention ops for an int8 pool, and the sink
+        of a layer that has one."""
+        out = ({} if self.k_scale is None
+               else dict(k_scale=self.k_scale, v_scale=self.v_scale))
+        return out if self.sink is None else dict(out, sink=self.sink)
 
 
 def _layer_pages(cfg: GPTConfig, kv_layout, pool: _KVPool, li: int):
-    """THE answer to "attention layer ``li``: which array of the pool, its
-    first page there, which of the step's block tables", as ``(field, first
-    page, page group)``.  One page group (``kv_layout`` None): the layer's
-    place among the layers that own pages (a scan or conv layer writes
-    none) times the pages each holds, in ``k``, under table 0.  Two
-    (``kv_page_layout``'s entry): in ``kw`` for a window layer of a pool
-    with an array a group (latent pages), else in ``k``."""
+    """THE answer to "attention layer ``li``: which arrays of the pool, its
+    first page there, which of the step's block tables", as ``(K field, V
+    field, first page, page group)``.  One page group (``kv_layout`` None):
+    the layer's place among the layers that own pages (a scan or conv layer
+    writes none) times the pages each holds, in ``k`` / ``v``, under table
+    0.  Two (``kv_page_layout``'s entry): in ``kw`` / ``vw`` for a window
+    layer of a pool with arrays a group (latent pages, which have no V;
+    ordinary heads whose groups differ, ``kv_groups_split``), else in ``k``
+    / ``v``."""
     if kv_layout is None:
         layers = cfg.attention_layers
-        return "k", layers.index(li) * (pool.k.shape[0] // len(layers)), 0
+        return ("k", "v",
+                layers.index(li) * (pool.k.shape[0] // len(layers)), 0)
     base, grp = kv_layout[li]
-    return "kw" if grp == 1 and pool.kw is not None else "k", base, grp
+    if grp == 1 and pool.kw is not None:
+        return "kw", "vw" if pool.vw is not None else "v", base, grp
+    return "k", "v", base, grp
 
 
 def _norm(p, x, cfg):
@@ -819,7 +896,7 @@ def _attn_geometry(cfg: GPTConfig):
     if cfg.mla:
         return (1, cfg.latent_page_dim, cfg.kv_lora_rank,
                 {"v_dim": cfg.kv_lora_rank})
-    return cfg.kv_heads, cfg.head_dim, cfg.head_dim, {}
+    return cfg.kv_heads, cfg.head_dim, cfg.value_dim, {}
 
 
 def _attn_scale(cfg: GPTConfig):
@@ -1467,7 +1544,8 @@ class _Step(NamedTuple):
     tables: tuple           # the block table [S, MB] of each page group
     plans: tuple            # and its ``_write_plan`` for the step's rows
     write: Any              # the program's ``_kv_writer``
-    rope: Any               # (q, k, head_dim) -> (q, k) on its row layout
+    rope: Any               # (lc, q, k) -> (q, k) on its row layout, at the
+    #                         layer's own base and rotated width
     attend: Any             # (li, lc, q, pages, base) -> o [.., nh, vd]
     ffn: dict               # live / stats / routes / experts of ``_ffn``
     selected: Any = None    # (lc, q, qi, wi, pages, base) -> o, where the
@@ -1522,11 +1600,24 @@ def _selects(cfg: GPTConfig, tables, block_size: int) -> bool:
     return tables[0].shape[1] * block_size > cfg.index_topk > 0
 
 
-def _rope(cfg: GPTConfig, q, k, pos, head_dim, seq_lens):
-    """``rope()`` as every step calls it: [B, T, n, d] + positions [B, T]."""
-    return rope(q, k, pos, head_dim, base=cfg.rope_theta,
+def _rope(cfg: GPTConfig, q, k, pos, seq_lens):
+    """``rope()`` as every step calls it: [B, T, n, d] + positions [B, T];
+    ``cfg``: the layer's view (its base, ``window_attn``; the leading
+    ``rope_pct`` of its head rotates)."""
+    return rope(q, k, pos, cfg.head_dim, base=cfg.rope_theta,
                 rope_pct=cfg.rope_pct, scaling=cfg.rope_scaling,
                 seq_lens=seq_lens)
+
+
+def _group_scope(kv_layout, grp: int):
+    """Scope ``attn_window`` / ``attn_global`` round a layer's attention
+    (``attn_kernel`` and what else the step's ``attend`` holds) in a model
+    with two page groups, so that a trace tells the window layers' kernels
+    from the global layers' in one program; nothing round a model's with
+    one.  A scope moves no op."""
+    if kv_layout is None:
+        return contextlib.nullcontext()
+    return jax.named_scope("attn_window" if grp == 1 else "attn_global")
 
 
 def _layer(bb, li: int, x, pool: _KVPool, step: _Step, cfg: GPTConfig,
@@ -1579,29 +1670,34 @@ def _layer(bb, li: int, x, pool: _KVPool, step: _Step, cfg: GPTConfig,
             if step.lora is not None:
                 q, v = _lora_qv(q, v, h, *step.lora, li)
             q, k, gate = _qk_norm_gate(ap, h, q, k, cfg, mesh=mesh)
+            v = value_scale(v, lc)
         if cfg.rope_for_layer(li) and not cfg.mla:
-            q, k = step.rope(q, k, lc.head_dim)
+            q, k = step.rope(lc, q, k)
 
-    field, base, grp = _layer_pages(cfg, kv_layout, pool, li)
+    field, vfield, base, grp = _layer_pages(cfg, kv_layout, pool, li)
 
     def rows(a):                # [rows, heads, d], as the write takes them
         return a.reshape((-1,) + a.shape[-2:]) if dense and a is not None \
             else a
     pk, pv, pks, pvs = step.write(
-        getattr(pool, field), pool.v, pool.k_scale, pool.v_scale, rows(k),
-        rows(v), step.plans[grp], base)
-    pool = pool._replace(**{field: pk}, v=pv, k_scale=pks, v_scale=pvs)
+        getattr(pool, field), getattr(pool, vfield), pool.k_scale,
+        pool.v_scale, rows(k), rows(v), step.plans[grp], base)
+    pool = pool._replace(**{field: pk, vfield: pv}, k_scale=pks,
+                         v_scale=pvs)
     if lc.index_topk:
         with jax.named_scope("attn_kernel"), jax.named_scope("attn_index"):
             qi, wi, ki = _index_rows(ap, h, cq, step.pos, lc)
             pool = pool._replace(ki=_kv_write_local(
                 (pool.ki,), ki, None, step.plans[grp], base, km=False)[0])
     pages = _LayerPages(k=pk, v=pv, ki=None, table=step.tables[grp],
-                        k_scale=pks, v_scale=pvs)
-    if lc.index_topk and step.selected is not None:
-        o = step.selected(lc, q, qi, wi, pages._replace(ki=pool.ki), base)
-    else:
-        o = step.attend(li, lc, q, pages, base)
+                        k_scale=pks, v_scale=pvs,
+                        sink=ap["sink"] if lc.attn_sink else None)
+    with _group_scope(kv_layout, grp):
+        if lc.index_topk and step.selected is not None:
+            o = step.selected(lc, q, qi, wi, pages._replace(ki=pool.ki),
+                              base)
+        else:
+            o = step.attend(li, lc, q, pages, base)
     with jax.named_scope("attn_out"):
         attn_delta = _attn_proj(ap, o, gate, lc, mesh=mesh)
         if cfg.sandwich_norm:
@@ -1688,8 +1784,8 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
         lora = lora, jnp.where(
             valid, batch["adapter_slot"][jnp.clip(token_slot, 0)], 0)
 
-    def rope_rows(q, k, head_dim):
-        q, k = _rope(cfg, q[None], k[None], token_pos[None], head_dim,
+    def rope_rows(lc, q, k):
+        q, k = _rope(lc, q[None], k[None], token_pos[None],
                      kv_len[jnp.clip(token_slot, 0)][None])
         return q[0], k[0]
 
@@ -1751,9 +1847,9 @@ def _decode_core(params, pool: _KVPool, tokens, active, token_pos, tables,
         scan_mixer = jax.jit(named_partial(_scan_decode, mixer=mixer,
                                            cfg=cfg, mesh=mesh))
 
-    def rope_rows(q, k, head_dim):
-        q, k = _rope(cfg, q[:, None], k[:, None], token_pos[:, None],
-                     head_dim, kv_len[:, None])
+    def rope_rows(lc, q, k):
+        q, k = _rope(lc, q[:, None], k[:, None], token_pos[:, None],
+                     kv_len[:, None])
         return q[:, 0], k[:, 0]
 
     def attend(li, lc, q, pages, base):
@@ -1985,8 +2081,8 @@ def _verify_core(params, pool: _KVPool, tokens, active, pos0, block_table,
 
     step = _Step(
         flat_pos, (block_table,), (plan,), _kv_writer(km, mesh),
-        lambda q, k, head_dim: _rope(cfg, q, k, positions, head_dim,
-                                     kv_len[:, None]), attend, ffn={})
+        lambda lc, q, k: _rope(lc, q, k, positions, kv_len[:, None]),
+        attend, ffn={})
     for li in range(cfg.num_layers):
         x, pool = _layer(bb, li, x, pool, step, cfg, mesh=mesh)
     return _head(params, bb, x, cfg, mesh=mesh), pool           # [S, G, V]

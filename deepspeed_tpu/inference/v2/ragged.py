@@ -439,9 +439,12 @@ class DSStateManager:
                 "prefix_cache with a window page group: a cached prefix's "
                 "window pages are released as the sequence moves on, so "
                 "they cannot be aliased; turn the prefix cache off")
-        # ever allocated / given back behind the window (telemetry)
+        # ever allocated / given back behind the window (telemetry), and of
+        # those given back, the ones a fused decode burst's reservation
+        # released (the ring turning while a sequence only decodes)
         self.w_allocated_total = 0
         self.w_released_total = 0
+        self.w_released_decode_total = 0
         self.radix: Optional[RadixKVCache] = (
             RadixKVCache(self.allocator, self.block_size)
             if prefix_cache else None)
@@ -510,13 +513,16 @@ class DSStateManager:
         releasable = max(0, self._window_first_live(seq) - seq.w_released)
         return total - len(seq.wblocks) - releasable
 
-    def _release_window(self, seq: SequenceDescriptor) -> None:
+    def _release_window(self, seq: SequenceDescriptor,
+                        decode: bool = False) -> None:
         first = min(self._window_first_live(seq), len(seq.wblocks))
         if first > seq.w_released:
             self.wallocator.release(seq.wblocks[seq.w_released:first])
             seq.wblocks[seq.w_released:first] = [-1] * (first
                                                         - seq.w_released)
             self.w_released_total += first - seq.w_released
+            if decode:
+                self.w_released_decode_total += first - seq.w_released
             seq.w_released = first
 
     def fits(self, steps) -> bool:
@@ -560,9 +566,12 @@ class DSStateManager:
         return {n: (t if width is None else t[:, :width])
                 for n, t in zip(names, tables) if t is not None}
 
-    def ensure_blocks(self, seq: SequenceDescriptor, new_tokens: int) -> None:
+    def ensure_blocks(self, seq: SequenceDescriptor, new_tokens: int,
+                      decode: bool = False) -> None:
+        """``decode``: the reservation of a fused decode burst (what the
+        window group gives back here is counted as released in decode)."""
         if self.window:
-            self._release_window(seq)
+            self._release_window(seq, decode)
             need_w = (-(-(seq.seen_tokens + new_tokens) // self.block_size)
                       - len(seq.wblocks))
             if need_w > 0:

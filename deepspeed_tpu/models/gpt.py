@@ -193,10 +193,23 @@ class GPTConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     # attention geometry of the layers WITH a window where it differs from
-    # the other layers': ((field, value), ...) over num_heads, head_dim,
-    # v_head_dim, kv_lora_rank, q_lora_rank, qk_rope_head_dim, rope_theta,
-    # attn_scale.  ``for_layer(i)`` is the view a layer's attention takes
+    # the other layers': ((field, value), ...) over num_heads, num_kv_heads,
+    # head_dim, v_head_dim, kv_lora_rank, q_lora_rank, qk_rope_head_dim,
+    # rope_theta, attn_scale.  ``for_layer(i)`` is the view a layer's
+    # attention takes
     window_attn: tuple = ()
+    # ordinary (not latent) heads whose VALUE is narrower or wider than their
+    # key: ``v_head_dim`` above, without ``kv_lora_rank`` (None: ``head_dim``;
+    # checkpoint/hf.py maps model_type "mimo_v2_flash": keys 192, values 128).
+    # ``attn_value_scale``: the values times this before they are cached and
+    # attended (equal to scaling the attention output).  ``attn_sink``: a
+    # learned logit a query head that joins the softmax's denominator and
+    # carries no value (``ops.sink_softmax``; gpt-oss's sinks), on the layers
+    # with a window ("window") or on every layer ("all"); a float32 ``sink
+    # [heads]`` parameter of those layers' ``Attention``.  In a layer's view
+    # (``for_layer``) it is "all" or None: whether THIS layer has one
+    attn_value_scale: Optional[float] = None
+    attn_sink: Optional[str] = None
     # layers that are no attention (checkpoint/hf.py maps model_types
     # "granitemoehybrid" and "lfm2_moe"): ``layer_types[i]`` is "attention",
     # "mamba" or "conv".  "mamba": a Mamba-2 scan layer (``Mamba2Mixer``:
@@ -251,6 +264,11 @@ class GPTConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def value_dim(self) -> int:
+        """An ordinary value head's width (a latent one's: ``mla_split``)."""
+        return self.v_head_dim or self.head_dim
+
+    @property
     def latent_dim(self) -> int:
         """What a token's cache row needs: the latent and the key part."""
         return self.kv_lora_rank + self.qk_rope_head_dim
@@ -272,7 +290,11 @@ class GPTConfig:
             raise ValueError(
                 f"layer {i} is a {what} layer ({self.layer_types[i]}): it "
                 f"has no attention geometry; ask is_state_layer(i) first")
-        if not self.window_attn and not self.index_topk:
+        if self.attn_sink not in (None, "window", "all"):
+            raise ValueError(f"attn_sink must be window|all|None, got "
+                             f"{self.attn_sink!r}")
+        if (not self.window_attn and not self.index_topk
+                and self.attn_sink != "window"):
             return self
         return _layer_view(self, self.window_for_layer(i) is not None)
 
@@ -405,15 +427,20 @@ class GPTConfig:
 
 @functools.lru_cache(maxsize=None)
 def _layer_view(cfg: GPTConfig, windowed: bool) -> GPTConfig:
-    if not windowed:
-        return dataclasses.replace(cfg, window_attn=())
-    allowed = {"num_heads", "head_dim", "v_head_dim", "kv_lora_rank",
-               "q_lora_rank", "qk_rope_head_dim", "rope_theta", "attn_scale"}
+    if not windowed:       # (a sink of the window layers alone: none here)
+        return dataclasses.replace(
+            cfg, window_attn=(),
+            attn_sink="all" if cfg.attn_sink == "all" else None)
+    allowed = {"num_heads", "num_kv_heads", "head_dim", "v_head_dim",
+               "kv_lora_rank", "q_lora_rank", "qk_rope_head_dim",
+               "rope_theta", "attn_scale"}
     extra = dict(cfg.window_attn)
     if set(extra) - allowed:
         raise ValueError(f"window_attn may set {sorted(allowed)}, got "
                          f"{sorted(set(extra) - allowed)}")
-    return dataclasses.replace(cfg, window_attn=(), index_topk=0, **extra)
+    return dataclasses.replace(cfg, window_attn=(), index_topk=0,
+                               attn_sink="all" if cfg.attn_sink else None,
+                               **extra)
 
 
 def _gather_table(table, mesh, vocab_axis="tp"):
@@ -714,6 +741,49 @@ def attend_with_mask(q, k, v, mask, bias=None, scale=None):
                                 scale=scale)
 
 
+def value_scale(v, cfg):
+    """The values as they are cached and attended: times
+    ``cfg.attn_value_scale`` where the model has one (THE definition: the
+    flax ``Attention`` and the serving step programs both call it)."""
+    if cfg.attn_value_scale is None:
+        return v
+    return v * jnp.asarray(cfg.attn_value_scale, v.dtype)
+
+
+def _sink_init(key, shape, dtype):
+    """A layer's sink logits at initialisation: Normal(4, 1) a head.  A
+    trained model's are of order one; at random weights every score is near
+    zero, and a sink of zero would be one key among a window's 128 (0.8% of
+    the denominator, under any comparison's tolerance).  At 4 it weighs as
+    much as 20-150 keys, differently for each head, so leaving it out, or
+    putting it on the wrong layers, moves every row visibly."""
+    return 4.0 + jax.random.normal(key, shape, dtype)
+
+
+def attend_with_sink(q, k, v, mask, sink=None, scale=None):
+    """Dense grouped-query attention under an explicit mask ``[B, T, S]``
+    whose heads may carry a sink and whose values may differ in width from
+    the keys: ``q [B, T, heads, d]``, ``k [B, S, kv heads, d]``, ``v [B, S,
+    kv heads, dv]``, ``sink [heads]`` float32 or None -> ``[B, T, heads,
+    dv]``.  The softmax is ``ops.sink_softmax``: the sink is one more key
+    whose value is zero.  The plain form the flax ``Attention`` runs where the
+    fused kernels have neither (the serving kernels have both)."""
+    from deepspeed_tpu import ops
+    B, T, nh, hd = q.shape
+    nkv = k.shape[2]
+    g = nh // nkv
+    s = jnp.einsum("btngd,bsnd->bngts", q.reshape(B, T, nkv, g, hd), k,
+                   preferred_element_type=jnp.float32)
+    s = s * (hd ** -0.5 if scale is None else scale)
+    m = mask[:, None, None]                                 # [B,1,1,T,S]
+    s = jnp.where(m, s, jnp.finfo(jnp.float32).min)
+    p = ops.sink_softmax(s, None if sink is None else jnp.asarray(
+        sink, jnp.float32).reshape(1, nkv, g, 1, 1))
+    p = jnp.where(m.any(-1, keepdims=True), p, 0.0)
+    o = jnp.einsum("bngts,bsnd->btngd", p.astype(v.dtype), v)
+    return o.reshape(B, T, nh, v.shape[-1])
+
+
 def causal_attend(q, k, v, probs_dropout=None):
     """Plain causal softmax attention on [B, T, N, D] (the "local attention" in
     reference sequence/layer.py terms) — the XLA reference body lives in the ops
@@ -733,7 +803,10 @@ class Attention(nn.Module):
                  use_rope: Optional[bool] = None):
         c = self.cfg
         B, T, H = x.shape
-        nh, nkv, hd = c.num_heads, c.kv_heads, c.head_dim
+        nh, nkv, hd, vd = c.num_heads, c.kv_heads, c.head_dim, c.value_dim
+        # a sink or a value narrower than the key: the plain masked form
+        # (``attend_with_sink``); the fused kernels have neither
+        plain = bool(c.attn_sink) or vd != hd
         if use_rope is None:
             use_rope = c.use_rope
         if c.act_quant_bits:
@@ -745,14 +818,17 @@ class Attention(nn.Module):
         wk = self.param("wk", _part(_kernel_init(), ("embed", "heads", "kv")),
                         (H, nkv, hd), c.param_dtype)
         wv = self.param("wv", _part(_kernel_init(), ("embed", "heads", "kv")),
-                        (H, nkv, hd), c.param_dtype)
+                        (H, nkv, vd), c.param_dtype)
         wo = self.param("wo", _part(_kernel_init(), ("heads", "kv", "embed")),
-                        (nh, hd, H), c.param_dtype)
+                        (nh, vd, H), c.param_dtype)
         bo = (self.param("bo", _part(nn.initializers.zeros, ("embed",)),
                          (H,), c.param_dtype)
               if c.attn_out_bias else None)
+        # (float32 whatever the weights' type: a logit beside float32 scores)
+        sink = (self.param("sink", _part(_sink_init, ("heads",)), (nh,),
+                           jnp.float32) if c.attn_sink else None)
 
-        cm_fused = _collective_matmul_active(c, self.mesh, T, nh * hd,
+        cm_fused = _collective_matmul_active(c, self.mesh, T, nh * vd,
                                              use_cache=use_cache)
 
         if c.attn_gate:
@@ -772,8 +848,8 @@ class Attention(nn.Module):
                 from deepspeed_tpu.ops import collective_matmul as cm_ops
                 Bo, To = o.shape[0], o.shape[1]
                 y = cm_ops.row_parallel_matmul(
-                    o.reshape(Bo, To, nh * hd),
-                    wo.astype(x.dtype).reshape(nh * hd, H), self.mesh)
+                    o.reshape(Bo, To, nh * vd),
+                    wo.astype(x.dtype).reshape(nh * vd, H), self.mesh)
             else:
                 y = jnp.einsum("btnd,ndh->bth", o, wo.astype(x.dtype))
             return y if bo is None else y + bo.astype(x.dtype)
@@ -790,7 +866,8 @@ class Attention(nn.Module):
                                (nkv, hd), c.param_dtype).astype(x.dtype)
             v = v + self.param("bv", _part(nn.initializers.zeros,
                                            ("heads", "kv")),
-                               (nkv, hd), c.param_dtype).astype(x.dtype)
+                               (nkv, vd), c.param_dtype).astype(x.dtype)
+        v = value_scale(v, c)
 
         if c.qk_norm:
             q, k = head_norm(q, self.param(
@@ -820,7 +897,7 @@ class Attention(nn.Module):
             ck = self.variable("cache", "cached_key",
                                jnp.zeros, (B, S, nkv, hd), x.dtype)
             cv = self.variable("cache", "cached_value",
-                               jnp.zeros, (B, S, nkv, hd), x.dtype)
+                               jnp.zeros, (B, S, nkv, vd), x.dtype)
             start = jnp.asarray(start_index, jnp.int32)
             ck.value = jax.lax.dynamic_update_slice(ck.value, k,
                                                     (0, start, 0, 0))
@@ -841,8 +918,16 @@ class Attention(nn.Module):
                 mask = mask & (kvpos > positions[:, :, None] - window)
             if kv_mask is not None:
                 mask = mask & kv_mask[:, None, :].astype(bool)
-            out = attend_with_mask(q, ck.value, cv.value, mask,
-                                   bias=alibi_bias(kp2), scale=c.attn_scale)
+            if plain:
+                if c.use_alibi:
+                    raise ValueError("alibi beside a sink or a value width "
+                                     "of its own is not wired")
+                out = attend_with_sink(q, ck.value, cv.value, mask, sink,
+                                       c.attn_scale)
+            else:
+                out = attend_with_mask(q, ck.value, cv.value, mask,
+                                       bias=alibi_bias(kp2),
+                                       scale=c.attn_scale)
             return out_proj(out)
 
         sp_active = (c.sequence_parallel and self.mesh is not None
@@ -857,7 +942,19 @@ class Attention(nn.Module):
             raise ValueError("custom attn_scale + sequence parallelism is "
                              "not wired (the a2a/ring paths use the default "
                              "1/sqrt(head_dim) scale)")
-        if sp_active:
+        if plain:
+            if sp_active or c.use_alibi:
+                raise ValueError(
+                    "a sink (attn_sink) or a value width of its own "
+                    "(v_head_dim) beside sequence parallelism or alibi is "
+                    "not wired: the a2a/ring paths and the flash kernel "
+                    "carry neither")
+            rel = positions[:, :, None] - positions[:, None, :]
+            mask = rel >= 0 if window is None else (rel >= 0) & (rel < window)
+            out = attend_with_sink(q, k, v, mask, sink, c.attn_scale)
+            if c.dropout > 0 and not deterministic:
+                out = nn.Dropout(rate=c.dropout)(out, deterministic=False)
+        elif sp_active:
             # sequence parallelism: Ulysses (seq→head all-to-all swap around
             # local attention) or ring (KV blocks rotate over neighbor links;
             # no head-divisibility constraint — sequence/ring.py).  Dropout
@@ -1502,7 +1599,7 @@ class Block(nn.Module):
         else:
             attn = (MLAttention(self.attn_cfg or c, mesh=self.mesh,
                                 name="Attention_0") if c.mla
-                    else Attention(c, mesh=self.mesh))
+                    else Attention(self.attn_cfg or c, mesh=self.mesh))
             a = attn(Norm(c)(xin), positions, deterministic, use_cache,
                      kv_mask, start_index, kv_positions, window=window,
                      fused_ok=fused_ok, use_rope=use_rope)
@@ -1822,9 +1919,13 @@ def count_params(cfg: GPTConfig) -> int:
     if cfg.sandwich_norm:
         norms += 2
     n_mat = 3 if cfg.gated_mlp else 2
-    attn = (cfg.num_heads * cfg.head_dim * H * (3 if cfg.attn_gate else 2)
-            + cfg.kv_heads * cfg.head_dim * H * 2              # wk, wv
-            + (2 * cfg.head_dim if cfg.qk_norm else 0))
+    def plain_attn(c):      # wq (and the gate), wo, wk, wv, q/k norms, sink
+        return (c.num_heads * H * (c.head_dim * (2 if c.attn_gate else 1)
+                                   + c.value_dim)
+                + c.kv_heads * H * (c.head_dim + c.value_dim)
+                + (2 * c.head_dim if c.qk_norm else 0)
+                + (c.num_heads if c.attn_sink else 0))
+    attn = plain_attn(cfg)
     per_norms = H * norms * (1 if cfg.use_rmsnorm else 2)
     n_scan, n_conv = len(cfg.scan_layers), len(cfg.conv_layers)
     # a scan layer's mixer: w_in, w_out, the conv, dt_bias/A_log/D, the norm
@@ -1836,10 +1937,10 @@ def count_params(cfg: GPTConfig) -> int:
     conv = H * 3 * H + H * H + H * cfg.conv_taps
     attn = ((attn + per_norms) * (cfg.num_layers - n_scan - n_conv)
             + (scan + per_norms) * n_scan + (conv + per_norms) * n_conv)
-    if cfg.mla:             # a layer's own geometry (GPTConfig.for_layer)
-        attn = sum(_mla_params(cfg.for_layer(i))
-                   + H * norms * (1 if cfg.use_rmsnorm else 2)
-                   for i in range(cfg.num_layers))
+    if cfg.mla or cfg.window_attn or cfg.attn_sink == "window":
+        # a layer's own geometry (GPTConfig.for_layer)
+        attn = sum((_mla_params if cfg.mla else plain_attn)(cfg.for_layer(i))
+                   + per_norms for i in range(cfg.num_layers))
     moe_layers = sum(cfg.is_moe_layer(i) for i in range(cfg.num_layers))
     moe_ffn = (cfg.local_experts * H * cfg.expert_dim * n_mat
                + H * cfg.num_experts                            # router

@@ -155,7 +155,7 @@ register_op("causal_attention", xla=_attention_xla, pallas=_attention_pallas,
 
 from deepspeed_tpu.ops import paged_attention as _paged  # noqa: E402
 from deepspeed_tpu.ops.paged_attention import (  # noqa: E402
-    paged_attention, ragged_prefill_attention)
+    paged_attention, ragged_prefill_attention, sink_softmax)
 
 register_op("paged_attention", xla=_paged.xla_paged_attention,
             pallas=_paged.pallas_paged_attention, supported=_paged.supported)
@@ -298,7 +298,8 @@ def causal_attention(q, k, v, *, causal: bool = True,
 
 __all__ = ["causal_attention", "flash_attention", "configure_flash_blocks",
            "paged_attention", "lora_matmul",
-           "ragged_prefill_attention", "evoformer_attention",
+           "ragged_prefill_attention", "sink_softmax",
+           "evoformer_attention",
            "index_scores", "index_select", "selected_attention",
            "selection_mask",
            "all_gather_matmul", "matmul_reduce_scatter",

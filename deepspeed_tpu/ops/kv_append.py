@@ -38,6 +38,8 @@ are rotated into place on the sublanes in float32 (Mosaic rotates 32-bit data
 only; bfloat16 -> float32 -> bfloat16 is exact) and, for kv-major pages, turned
 token-on-lanes a head at a time.  A candidate that holds no row repeats the
 block indices of the last that does, so it costs a grid step and no DMA.
+K and V rows may differ in width (keys of 192 beside values of 128): each pool
+has its own blocks and its own rotation, in the one call.
 """
 
 from __future__ import annotations
@@ -166,13 +168,14 @@ def xla_paged_kv_append(pools, new, plan: AppendPlan, base, *,
     return tuple(merge(pool, x) for pool, x in zip(pools, new))
 
 
-def _append_kernel(page, tile, xa, xb, back, lo, hi, *refs, pools, nkv, hd,
+def _append_kernel(page, tile, xa, xb, back, lo, hi, *refs, pools, nkv, hds,
                    g, kv_major):
     """One candidate: ``refs`` = for each pool its rows' two blocks ``[g, nkv
-    * hd]`` and the unit's block, then the pools' output blocks.  Written in
-    lax primitives, a pool's rows rotated as one block: a step program
-    traces and lowers this once for every shape of its rows, so every
-    equation here is paid some thirty times a serving start."""
+    * hd]`` and the unit's block, then the pools' output blocks (``hds``:
+    each pool's head width).  Written in lax primitives, a pool's rows
+    rotated as one block: a step program traces and lowers this once for
+    every shape of its rows, so every equation here is paid some thirty
+    times a serving start."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from jax import lax
@@ -185,15 +188,22 @@ def _append_kernel(page, tile, xa, xb, back, lo, hi, *refs, pools, nkv, hd,
     @pl.when(lax.bitwise_or(lax.gt(last, first), lax.eq(i, 0)))
     def _():
         f32 = jnp.float32
-        # window row t is row t + s of the two blocks a, b laid end to end,
-        # s = g - shift (s = 0: shift = 0): a rotated back by s where t + s
-        # < g, else b rotated likewise
-        row = lax.broadcasted_iota(jnp.int32, (g, nkv * hd), 0)
-        from_a = lax.bitwise_or(lax.lt(row, shift), lax.eq(shift, 0))
-        tok = lax.broadcasted_iota(
-            jnp.int32, (hd, g) if kv_major else (g, hd), 1 if kv_major else 0)
-        fresh = lax.bitwise_and(lax.ge(tok, first), lax.lt(tok, last))
-        for p in range(pools):
+
+        def masks(hd):
+            # window row t is row t + s of the two blocks a, b laid end to
+            # end, s = g - shift (s = 0: shift = 0): a rotated back by s
+            # where t + s < g, else b rotated likewise
+            row = lax.broadcasted_iota(jnp.int32, (g, nkv * hd), 0)
+            from_a = lax.bitwise_or(lax.lt(row, shift), lax.eq(shift, 0))
+            tok = lax.broadcasted_iota(
+                jnp.int32, (hd, g) if kv_major else (g, hd),
+                1 if kv_major else 0)
+            return from_a, lax.bitwise_and(lax.ge(tok, first),
+                                           lax.lt(tok, last))
+        # (once a head width: pools of one width share their masks)
+        by_width = {hd: masks(hd) for hd in dict.fromkeys(hds)}
+        for p, hd in enumerate(hds):
+            from_a, fresh = by_width[hd]
             a, b, old = refs[3 * p:3 * p + 3]
             out = refs[3 * pools + p]
             win = lax.select(
@@ -219,13 +229,14 @@ def pallas_paged_kv_append(pools, new, plan: AppendPlan, base, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     g = plan.granule
-    N, nkv, hd = new[0].shape
+    N, nkv, _ = new[0].shape
+    hds = tuple(x.shape[2] for x in new)
     bs = pools[0].shape[3 if kv_major else 2]
     r = bs // g
     blocks = -(-N // g)
     # the step's rows as they are, [N, nkv * hd] (padded to whole blocks: a
     # decode step's few rows)
-    rows = [jnp.pad(x.reshape(N, nkv * hd), ((0, blocks * g - N), (0, 0)))
+    rows = [jnp.pad(x.reshape(N, -1), ((0, blocks * g - N), (0, 0)))
             for x in new]
     # a unit's window is rows start .. start + g: blocks xa and xa + 1 (a
     # block outside the rows holds none that is written: any block will do)
@@ -236,23 +247,28 @@ def pallas_paged_kv_append(pools, new, plan: AppendPlan, base, *,
     unit = jnp.asarray(base, jnp.int32) * r + plan.unit
     page, tile = unit // r, unit % r       # (index maps only look values up)
 
-    if kv_major:                 # r == 1
-        block = pl.BlockSpec((None, nkv, hd, g),
-                             lambda i, page, *_: (page[i], 0, 0, 0))
-    else:
-        block = pl.BlockSpec((None, nkv, g, hd),
-                             lambda i, page, tile, *_: (page[i], 0, tile[i], 0))
-    row_a = pl.BlockSpec((g, nkv * hd), lambda i, _, __, xa, *___: (xa[i], 0))
-    row_b = pl.BlockSpec((g, nkv * hd),
-                         lambda i, _, __, ___, xb, *____: (xb[i], 0))
+    def specs(hd):               # (rows a, rows b, the unit) of one pool
+        if kv_major:             # r == 1
+            block = pl.BlockSpec((None, nkv, hd, g),
+                                 lambda i, page, *_: (page[i], 0, 0, 0))
+        else:
+            block = pl.BlockSpec(
+                (None, nkv, g, hd),
+                lambda i, page, tile, *_: (page[i], 0, tile[i], 0))
+        row_a = pl.BlockSpec((g, nkv * hd),
+                             lambda i, _, __, xa, *___: (xa[i], 0))
+        row_b = pl.BlockSpec((g, nkv * hd),
+                             lambda i, _, __, ___, xb, *____: (xb[i], 0))
+        return [row_a, row_b, block]
     n = len(pools)
+    in_specs = [spec for hd in hds for spec in specs(hd)]
     operands = [a for x, pool in zip(rows, pools) for a in (x, x, pool)]
     out = pl.pallas_call(
-        functools.partial(_append_kernel, pools=n, nkv=nkv, hd=hd, g=g,
+        functools.partial(_append_kernel, pools=n, nkv=nkv, hds=hds, g=g,
                           kv_major=kv_major),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7, grid=(plan.unit.shape[0],),
-            in_specs=[row_a, row_b, block] * n, out_specs=[block] * n),
+            in_specs=in_specs, out_specs=in_specs[2::3]),
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
         input_output_aliases={7 + 3 * p + 2: p for p in range(n)},
         interpret=interpret, name="paged_kv_append",
@@ -269,22 +285,30 @@ def supported(pools, new, plan: AppendPlan, base, *, kv_major: bool):
     lanes full and the sublanes in whole tiles, the blocks inside the
     VMEM budget."""
     pool = pools[0]
+    axis = 2 if kv_major else 3          # a page's head width: each pool's own
+
+    def but_width(shape):
+        return shape[:axis] + shape[axis + 1:]
     if (len(pools) > 2 or pool.ndim != 4 or pool.shape[1] < 2
             or pool.dtype not in (jnp.bfloat16, jnp.float32)
-            or any(p.shape != pool.shape or p.dtype != pool.dtype
-                   or x.shape != new[0].shape
-                   for p, x in zip(pools, new))
-            or new[0].ndim != 3):
+            or new[0].ndim != 3
+            or any(p.ndim != 4 or but_width(p.shape) != but_width(pool.shape)
+                   or p.dtype != pool.dtype or x.shape[:2] != new[0].shape[:2]
+                   or x.ndim != 3 or x.shape[2] != p.shape[axis]
+                   for p, x in zip(pools, new))):
         return False
-    nkv, hd = new[0].shape[1:]
+    nkv = new[0].shape[1]
     g = plan.granule
-    lanes, sublanes = (g, hd) if kv_major else (hd, g)
     tile = 8 * 4 // pool.dtype.itemsize
-    if lanes % 128 or sublanes % tile or g % tile:
-        return False
-    block = nkv * g * hd
-    # per pool: two row blocks and the unit in, the unit out, each twice
-    # (the pipeline's two buffers)
-    return (len(pools) * 2 * (2 * block * new[0].dtype.itemsize
-                              + 2 * block * pool.dtype.itemsize)
-            <= _VMEM_BUDGET)
+    need = 0
+    for x in new:
+        hd = x.shape[2]
+        lanes, sublanes = (g, hd) if kv_major else (hd, g)
+        if lanes % 128 or sublanes % tile or g % tile:
+            return False
+        # per pool: two row blocks and the unit in, the unit out, each twice
+        # (the pipeline's two buffers)
+        block = nkv * g * hd
+        need += 2 * (2 * block * x.dtype.itemsize
+                     + 2 * block * pool.dtype.itemsize)
+    return need <= _VMEM_BUDGET

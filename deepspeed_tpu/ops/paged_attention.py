@@ -66,6 +66,19 @@ group) and the output [S, 1, g, v_dim].  ``kd`` obeys the lane rule below
 (576 = 512 + 64 is stored padded to 640, the pad columns zero in q and page);
 ``_block_pages`` counts the page's real bytes, so P follows by itself.
 
+Unequal widths: a value head may be narrower (or wider) than a key head,
+``v_pages [NB, nkv, bs, vd]`` beside ``k_pages [NB, nkv, bs, hd]`` (kv-major:
+``[NB, nkv, vd, bs]`` beside ``[NB, nkv, hd, bs]``: MiMo-V2's keys are 192
+wide and its values 128); the value width is the value pool's, the output is
+``[.., vd]``, and nothing else changes: a page of each pool is one copy.
+
+Sinks (``sink [heads]`` float32, or None): a learned logit a query head that
+joins the softmax's denominator and carries no value (``sink_softmax``, the
+dense definition the fallbacks call).  In the kernels it is the online
+softmax's STARTING state: running max ``sink_h``, running sum 1, accumulator
+0: one more key whose value is zero, so no page loop changes and a slot or
+a row with no visible key still reads zero.
+
 kv-major layout (``kv_major=True``): pages are stored TRANSPOSED,
 [NB, nkv, hd, bs].  Mosaic requires a DMA slab's lane (last) dimension to be
 128-aligned; with the standard layout that means hd % 128 == 0, which
@@ -90,6 +103,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+
+
+def sink_softmax(s_log, sink=None):
+    """``softmax`` over the last axis of float32 scores ``s_log`` with a sink
+    logit in the denominator: ``p_j = exp(s_j - m) / (exp(sink - m) + sum_j
+    exp(s_j - m))``, ``m = max(sink, max_j s_j)``.  ``sink`` broadcasts
+    against ``s_log[..., :1]``; None is the plain softmax.  A row whose
+    scores are all masked (the float32 minimum) reads zeros, not 1 / K."""
+    if sink is None:
+        return jax.nn.softmax(s_log, axis=-1)
+    sink = jnp.asarray(sink, jnp.float32)
+    m = jnp.maximum(jnp.max(s_log, axis=-1, keepdims=True), sink)
+    e = jnp.exp(s_log - m)
+    return e / (jnp.exp(sink - m) + jnp.sum(e, axis=-1, keepdims=True))
 
 
 def _quant_inputs_ok(k_pages, v_pages, k_scale, v_scale, NB, nkv, bs) -> bool:
@@ -157,7 +184,7 @@ def xla_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
                         scale: Optional[float] = None, alibi_slopes=None,
                         window=None, interpret=None, mesh=None,
                         kv_major=False, k_scale=None, v_scale=None,
-                        v_dim=None):
+                        v_dim=None, sink=None):
     """Ground-truth XLA path: gather this slot's pages, masked softmax.
 
     ``mesh`` is accepted for signature parity with the Pallas path; the XLA
@@ -192,7 +219,8 @@ def xla_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
         s_log = s_log + sl[None, :, :, None] * kvpos[None, None, None, :]
     s_log = jnp.where(mask[:, None, None, :], s_log,
                       jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(s_log, axis=-1)
+    probs = sink_softmax(s_log, None if sink is None
+                         else jnp.reshape(sink, (1, nkv, g, 1)))
     probs = jnp.where(mask[:, None, None, :].any(-1, keepdims=True),
                       probs, 0.0)
     return jnp.einsum("sngk,sknd->sngd", probs.astype(q.dtype), v_seq)
@@ -217,7 +245,7 @@ def _block_pages(pools) -> int:
 
 
 def _decode_kernel(*refs, S, P, bs, scale, window, has_alibi, kv_major,
-                   quant, v_dim=None):
+                   quant, v_dim=None, vd=None, has_sink=False):
     """One grid step = one slot, every kv head (see the module docstring).
 
     ``state`` (SMEM) carries the page pipeline from one slot to the next:
@@ -231,10 +259,13 @@ def _decode_kernel(*refs, S, P, bs, scale, window, has_alibi, kv_major,
     dots.  The HBM traffic decode is bound by is the int8 payload.
 
     ``v_dim``: latent pages, one pool: the page is the key and its leading
-    ``v_dim`` columns the value."""
+    ``v_dim`` columns the value.  ``vd``: the value head's width (the
+    latent's, or the value pool's own).  ``has_sink``: one more input, ``[nkv, g, 1]``
+    float32 logits, the softmax's starting state (module docstring)."""
     it = iter(refs)
     bt_ref, len_ref, q_ref = next(it), next(it), next(it)
     slopes_ref = next(it) if has_alibi else None
+    sink_ref = next(it) if has_sink else None
     hbms = [next(it) for _ in range(1 if v_dim else 4 if quant else 2)]
     o_ref = next(it)
     bufs = [next(it) for _ in hbms]
@@ -345,9 +376,13 @@ def _decode_kernel(*refs, S, P, bs, scale, window, has_alibi, kv_major,
             return jax.lax.fori_loop(0, jnp.minimum(P, n_pages - p0), page,
                                      carry)
 
-        m0 = jnp.full((nkv, g, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((nkv, g, 1), jnp.float32)
-        acc0 = jnp.zeros((nkv, g, v_dim or hd), jnp.float32)
+        if has_sink:        # one more key, seen already, whose value is 0
+            m0 = sink_ref[...]
+            l0 = jnp.ones((nkv, g, 1), jnp.float32)
+        else:
+            m0 = jnp.full((nkv, g, 1), _NEG_INF, jnp.float32)
+            l0 = jnp.zeros((nkv, g, 1), jnp.float32)
+        acc0 = jnp.zeros((nkv, g, vd or hd), jnp.float32)
         _, l, acc = jax.lax.fori_loop(0, nblk, block, (m0, l0, acc0))
         state[0] = (half0 + nblk) % 2
         state[1] = has_next.astype(jnp.int32)
@@ -359,7 +394,7 @@ def pallas_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
                            mesh=None, kv_major=False,
-                           k_scale=None, v_scale=None, v_dim=None):
+                           k_scale=None, v_scale=None, v_dim=None, sink=None):
     """Mesh-aware entry: with a ``tp`` axis the kv-head dim is sharded, and the
     kernel runs per-shard under shard_map (attention is independent per kv
     head, so TP needs no collective here — the reference shards its blocked
@@ -404,7 +439,7 @@ def pallas_paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
                                          interpret=interpret,
                                          kv_major=kv_major,
                                          k_scale=k_scale, v_scale=v_scale,
-                                         v_dim=v_dim)
+                                         v_dim=v_dim, sink=sink)
 
 
 def _pallas_paged_attention_local(q, k_pages, v_pages, block_table, kv_lens, *,
@@ -412,7 +447,7 @@ def _pallas_paged_attention_local(q, k_pages, v_pages, block_table, kv_lens, *,
                                   scale: Optional[float] = None,
                                   interpret: Optional[bool] = None,
                                   kv_major=False, k_scale=None, v_scale=None,
-                                  v_dim=None):
+                                  v_dim=None, sink=None):
     S, nkv, g, hd = q.shape
     if scale is None:
         scale = hd ** -0.5
@@ -421,9 +456,11 @@ def _pallas_paged_attention_local(q, k_pages, v_pages, block_table, kv_lens, *,
     if alibi_slopes is not None:
         alibi_slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(
             nkv, g, 1)
+    if sink is not None:
+        sink = jnp.asarray(sink, jnp.float32).reshape(nkv, g, 1)
     return _paged_decode_call(
         q, k_pages, v_pages, block_table.astype(jnp.int32),
-        kv_lens.astype(jnp.int32), alibi_slopes, k_scale, v_scale,
+        kv_lens.astype(jnp.int32), alibi_slopes, k_scale, v_scale, sink,
         window=int(window) if window is not None else None,
         scale=float(scale), interpret=bool(interpret), kv_major=kv_major,
         v_dim=None if v_pages is not None else int(v_dim))
@@ -432,8 +469,8 @@ def _pallas_paged_attention_local(q, k_pages, v_pages, block_table, kv_lens, *,
 @functools.partial(jax.jit, static_argnames=("window", "scale", "interpret",
                                              "kv_major", "v_dim"))
 def _paged_decode_call(q, k_pages, v_pages, block_table, kv_lens,
-                       alibi_slopes, k_scale, v_scale, *, window, scale,
-                       interpret, kv_major, v_dim=None):
+                       alibi_slopes, k_scale, v_scale, sink=None, *, window,
+                       scale, interpret, kv_major, v_dim=None):
     """Grid (S,): the kernel normalises and writes [S, nkv, g, hd] in q's
     dtype itself.  A jit of its own: a step program calls it once a layer
     with the same shapes (the layer is a value, its first page in the
@@ -444,20 +481,26 @@ def _paged_decode_call(q, k_pages, v_pages, block_table, kv_lens,
     bs = k_pages.shape[3] if kv_major else k_pages.shape[2]
     quant = k_scale is not None
     has_alibi = alibi_slopes is not None
+    has_sink = sink is not None
     pools = [k_pages] if v_pages is None else [k_pages, v_pages]
+    # the value head's width: the latent's, or the value pool's own
+    vd = v_dim or v_pages.shape[2 if kv_major else 3]
     if quant:
         pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     P = _block_pages(pools)
     kernel = functools.partial(
         _decode_kernel, S=S, P=P, bs=bs, scale=scale, window=window,
-        has_alibi=has_alibi, kv_major=kv_major, quant=quant, v_dim=v_dim)
+        has_alibi=has_alibi, kv_major=kv_major, quant=quant, v_dim=v_dim,
+        vd=vd, has_sink=has_sink)
     whole = pl.BlockSpec((1, nkv, g, hd), lambda s, *_: (s, 0, 0, 0))
-    out_block = (whole if v_dim is None else
-                 pl.BlockSpec((1, nkv, g, v_dim), lambda s, *_: (s, 0, 0, 0)))
+    out_block = (whole if vd == hd else
+                 pl.BlockSpec((1, nkv, g, vd), lambda s, *_: (s, 0, 0, 0)))
     in_specs, inputs = [whole], [q]
-    if has_alibi:
-        in_specs.append(pl.BlockSpec((nkv, g, 1), lambda s, *_: (0, 0, 0)))
-        inputs.append(alibi_slopes)
+    for per_head in (alibi_slopes, sink):
+        if per_head is not None:
+            in_specs.append(pl.BlockSpec((nkv, g, 1),
+                                         lambda s, *_: (0, 0, 0)))
+            inputs.append(per_head)
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
     inputs += pools
     # both halves of the pipeline, P whole pages each
@@ -476,7 +519,7 @@ def _paged_decode_call(q, k_pages, v_pages, block_table, kv_lens,
             out_specs=out_block,
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((S, nkv, g, v_dim or hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, nkv, g, vd), q.dtype),
         # sequential: a slot hands its successor a block already in flight
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -511,9 +554,33 @@ def _latent_ok(v_pages, v_dim, hd, kv_major, quant, alibi_slopes) -> bool:
             and not (kv_major or quant or alibi_slopes is not None))
 
 
+def _values_ok(v_pages, k_pages, sink, nkv, g, bs, kv_major, quant,
+               mesh) -> bool:
+    """A value pool of its own width, and a sink: the value pool is the key
+    pool's shape but for its head width, which obeys the same slab rule; a
+    sink is one logit a query head.  Either is built for unquantised pages
+    on one chip (a ``tp`` mesh shards neither, and latent pages carry no
+    sink)."""
+    own_width = v_pages is not None and v_pages.shape != k_pages.shape
+    if own_width:
+        axis = 2 if kv_major else 3
+        vd = v_pages.shape[axis]
+        if (k_pages.shape[:axis] + (vd,) + k_pages.shape[axis + 1:]
+                != v_pages.shape
+                or not _dma_layout_ok(vd, bs, kv_major, quant=quant)):
+            return False
+    if sink is None and not own_width:
+        return True
+    if sink is not None and (v_pages is None or np.size(sink) != nkv * g):
+        return False
+    return not quant and not (mesh is not None
+                              and mesh.shape.get("tp", 1) > 1)
+
+
 def supported(q, k_pages, v_pages, block_table, kv_lens, *, scale=None,
               alibi_slopes=None, window=None, interpret=None, mesh=None,
-              kv_major=False, k_scale=None, v_scale=None, v_dim=None):
+              kv_major=False, k_scale=None, v_scale=None, v_dim=None,
+              sink=None):
     if q.ndim != 4 or k_pages.ndim != 4:
         return False
     S, nkv, g, hd = q.shape
@@ -532,6 +599,8 @@ def supported(q, k_pages, v_pages, block_table, kv_lens, *, scale=None,
     return (nkv == nkv2 and hd == hd2
             and _latent_ok(v_pages, v_dim, hd, kv_major, quant, alibi_slopes)
             and _dma_layout_ok(hd, bs, kv_major, quant=quant)
+            and _values_ok(v_pages, k_pages, sink, nkv, g, bs, kv_major,
+                           quant, mesh)
             and block_table.ndim == 2 and block_table.shape[0] == S)
 
 
@@ -541,15 +610,16 @@ def paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
                     impl: Optional[str] = None,
                     interpret: Optional[bool] = None,
                     mesh=None, kv_major=False, k_scale=None, v_scale=None,
-                    v_dim: Optional[int] = None):
+                    v_dim: Optional[int] = None, sink=None):
     """Registry entry (ops/__init__ registers this like causal_attention).
-    ``v_pages=None`` with ``v_dim``: latent pages (module docstring)."""
+    ``v_pages=None`` with ``v_dim``: latent pages; ``sink [heads]``: a sink
+    logit a query head (module docstring)."""
     from deepspeed_tpu.ops.registry import dispatch
     return dispatch("paged_attention", q, k_pages, v_pages, block_table,
                     kv_lens, scale=scale, alibi_slopes=alibi_slopes,
                     window=window, impl=impl, interpret=interpret, mesh=mesh,
                     kv_major=kv_major, k_scale=k_scale, v_scale=v_scale,
-                    v_dim=v_dim)
+                    v_dim=v_dim, sink=sink)
 
 
 # ===================================================================
@@ -621,7 +691,7 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
                        scale: Optional[float] = None,
                        alibi_slopes=None, window=None, interpret=None,
                        mesh=None, kv_major=False, k_scale=None, v_scale=None,
-                       v_dim=None, sel_mask=None):
+                       v_dim=None, sel_mask=None, sink=None):
     """Ground-truth gather + masked-dense path (the round-2 prefill body):
     each slot's rows gathered dense [S, Q, ...] (``Q = max_q``, the most rows
     a slot can hold; every row of the batch if not said), attended, and
@@ -669,7 +739,8 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
                              jnp.float32))
     m = mask[:, None, :, None, :]                              # [S,1,Q,1,K]
     s_log = jnp.where(m, s_log, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(s_log, axis=-1)
+    probs = sink_softmax(s_log, None if sink is None
+                         else jnp.reshape(sink, (1, nkv, 1, g, 1)))
     probs = jnp.where(m.any(-1, keepdims=True), probs, 0.0)
     o = jnp.einsum("snqgk,sknd->sqngd", probs.astype(q.dtype), v_seq)
     return jnp.zeros((N,) + o.shape[2:], o.dtype).at[flat].set(
@@ -677,7 +748,8 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
 
 
 def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
-                    kv_major, quant=False, v_dim=None, has_mask=False):
+                    kv_major, quant=False, v_dim=None, has_mask=False,
+                    vd=None, has_sink=False):
     """One grid step = one work item (``cq`` rows of one slot) for one kv
     head; see the section comment.  The chunk buffers hold ``g`` heads of
     ``hd`` (``vd``) values in their leading rows and columns: the arrays in
@@ -685,11 +757,14 @@ def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
     block (``_prefill_block_pages``).  ``v_dim``: latent pages, one pool and
     one buffer: the page is the key and its leading ``v_dim`` columns the
     value.  ``has_mask``: the masked form, one more array in HBM and one
-    more buffer (section comment)."""
+    more buffer (section comment).  ``vd``: the value head's width (the
+    latent's, or the value pool's own).  ``has_sink``: ``[nkv, g]`` float32 logits
+    behind the scalars, the softmax's starting state (module docstring)."""
     it = iter(refs)
     bt_ref, len_ref, start_ref, count_ref, row_ref, item_slot_ref, \
         item_chunk_ref, n_items_ref = (next(it) for _ in range(8))
     slopes_ref = next(it) if has_alibi else None
+    sink_ref = next(it) if has_sink else None
     q_hbm = next(it)
     hbms = [next(it) for _ in range(1 if v_dim else 4 if quant else 2)]
     mask_hbm = next(it) if has_mask else None
@@ -700,7 +775,7 @@ def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
     mask_sem = next(it) if has_mask else None
     item, h = pl.program_id(0), pl.program_id(1)
     R, K = cq * g, P * bs                  # the score tile of a block
-    vd = v_dim or hd
+    vd = vd or hd
     # (plain lax on the scalars: every ``//``, ``jnp.where`` or ``jnp.clip``
     # is a jitted helper, and every step program pays for tracing and
     # lowering each one: PERF.md section 6, PR 34)
@@ -781,8 +856,15 @@ def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
             # slopes[h, r % g] — tile the per-group column cq times
             sl = jnp.stack([slopes_ref[h, i] for i in range(g)]).reshape(g, 1)
             slope_rows = jnp.tile(sl, (cq, 1))         # [cq·g, 1]
-        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        if has_sink:
+            # one more key, seen already, whose value is 0: row r = j·g+gi
+            # starts at its head's logit with a sum of 1
+            sk = jnp.stack([sink_ref[h, i] for i in range(g)]).reshape(g, 1)
+            m_ref[...] = jnp.broadcast_to(jnp.tile(sk, (cq, 1)), m_ref.shape)
+            l_ref[...] = jnp.ones(l_ref.shape, jnp.float32)
+        else:
+            m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
         rows_copy(q_hbm, q_buf, 0).wait()
         # a last chunk's rows past ``n_rows`` are the next slot's (or the
@@ -883,7 +965,7 @@ def pallas_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
                           alibi_slopes=None, window=None,
                           interpret: Optional[bool] = None, mesh=None,
                           kv_major=False, k_scale=None, v_scale=None,
-                          v_dim=None, sel_mask=None):
+                          v_dim=None, sel_mask=None, sink=None):
     """Rows of slots with ``q_counts`` 0, and rows no slot owns, come back
     as the fresh output buffer held them (nothing): the caller does not read
     them (the mixed step selects the paged decode kernel's result for them,
@@ -925,7 +1007,8 @@ def pallas_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
         q, k_pages, v_pages, block_table, kv_lens, q_starts, q_counts,
         row_starts, max_q=max_q, scale=scale, alibi_slopes=alibi_slopes,
         window=window, interpret=interpret, kv_major=kv_major,
-        k_scale=k_scale, v_scale=v_scale, v_dim=v_dim, sel_mask=sel_mask)
+        k_scale=k_scale, v_scale=v_scale, v_dim=v_dim, sel_mask=sel_mask,
+        sink=sink)
 
 
 # the prefill kernel's float32 accumulator [cq * g, value width] stays under
@@ -990,7 +1073,7 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
                                  alibi_slopes=None, window=None,
                                  interpret: Optional[bool] = None,
                                  kv_major=False, k_scale=None, v_scale=None,
-                                 v_dim=None, sel_mask=None):
+                                 v_dim=None, sel_mask=None, sink=None):
     N, nkv, g, hd = q.shape
     S, MB = block_table.shape
     bs = k_pages.shape[3] if kv_major else k_pages.shape[2]
@@ -999,12 +1082,13 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     latent = v_pages is None
-    vd = int(v_dim) if latent else hd
+    vd = int(v_dim) if latent else v_pages.shape[2 if kv_major else 3]
     Q = N if max_q is None else min(int(max_q), N)
     cq = _prefill_chunk(Q, g, vd)
     q_counts = q_counts.astype(jnp.int32)
     has_alibi = alibi_slopes is not None
     has_mask = sel_mask is not None
+    has_sink = sink is not None
     quant = k_scale is not None
 
     # the work list: slot after slot, each slot's chunks in order.  Item i
@@ -1035,7 +1119,8 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
         _prefill_kernel, P=P, bs=bs, cq=cq, g=g, hd=hd, scale=float(scale),
         window=int(window) if window is not None else None,
         has_alibi=has_alibi, kv_major=kv_major, quant=quant,
-        v_dim=vd if latent else None, has_mask=has_mask)
+        v_dim=vd if latent else None, has_mask=has_mask, vd=vd,
+        has_sink=has_sink)
     prefetch = [block_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
                 q_starts.astype(jnp.int32), q_counts,
                 row_starts.astype(jnp.int32), item_slot, item_chunk,
@@ -1043,6 +1128,8 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
     if has_alibi:
         prefetch.append(jnp.asarray(alibi_slopes, jnp.float32).reshape(
             nkv, g))
+    if has_sink:
+        prefetch.append(jnp.asarray(sink, jnp.float32).reshape(nkv, g))
     (gp, hp), (_, vp) = _tile_pad(g, hd, q.dtype), _tile_pad(g, vd, q.dtype)
     # ... and ``cq`` rows more, for the last chunk of the batch's last slot
     q = lax.pad(q, jnp.zeros((), q.dtype),
@@ -1103,7 +1190,7 @@ def ragged_prefill_supported(q, k_pages, v_pages, block_table, kv_lens,
                              scale=None, alibi_slopes=None, window=None,
                              interpret=None, mesh=None, kv_major=False,
                              k_scale=None, v_scale=None, v_dim=None,
-                             sel_mask=None):
+                             sel_mask=None, sink=None):
     if q.ndim != 4 or k_pages.ndim != 4:
         return False
     N, nkv, g, hd = q.shape
@@ -1122,6 +1209,8 @@ def ragged_prefill_supported(q, k_pages, v_pages, block_table, kv_lens,
     return (nkv == nkv2 and hd == hd2
             and _latent_ok(v_pages, v_dim, hd, kv_major, quant, alibi_slopes)
             and _dma_layout_ok(hd, bs, kv_major, quant=quant)
+            and _values_ok(v_pages, k_pages, sink, nkv, g, bs, kv_major,
+                           quant, mesh)
             # (a block's slab of the mask is whole lanes)
             and (sel_mask is None or bs % 128 == 0)
             and block_table.ndim == 2
@@ -1136,14 +1225,16 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_table, kv_lens,
                              impl: Optional[str] = None,
                              interpret: Optional[bool] = None, mesh=None,
                              kv_major=False, k_scale=None, v_scale=None,
-                             v_dim: Optional[int] = None, sel_mask=None):
+                             v_dim: Optional[int] = None, sel_mask=None,
+                             sink=None):
     """Registry entry for the ragged prefill kernel: token-major ``q``
     [N, nkv, g, hd] -> [N, nkv, g, vd]; slot ``s`` owns rows ``row_starts[s]
     + [0, q_counts[s])`` at positions ``q_starts[s] + [0, q_counts[s])``,
     at most ``max_q`` of them.  ``v_pages=None`` with ``v_dim``: latent pages
     (module docstring).  ``sel_mask``: ``[ceil(N / 32), MB * bs]`` int32,
     the positions of its sequence each row keeps beside what is causal
-    (``ops.selection_mask``; the section comment has the layout)."""
+    (``ops.selection_mask``; the section comment has the layout).  ``sink
+    [heads]``: a sink logit a query head (module docstring)."""
     from deepspeed_tpu.ops.registry import dispatch
     return dispatch("ragged_prefill_attention", q, k_pages, v_pages,
                     block_table, kv_lens, q_starts, q_counts, row_starts,
@@ -1151,4 +1242,4 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_table, kv_lens,
                     alibi_slopes=alibi_slopes, window=window, impl=impl,
                     interpret=interpret, mesh=mesh, kv_major=kv_major,
                     k_scale=k_scale, v_scale=v_scale,
-                    v_dim=v_dim, sel_mask=sel_mask)
+                    v_dim=v_dim, sel_mask=sel_mask, sink=sink)

@@ -249,7 +249,12 @@ class ServingTelemetry:
         self.c_kv_allocated = reg.counter(
             "kv_pages_allocated_total", "pages a page group handed to "
             "sequences, per group (the denominator of the released share)")
-        self._kvw_seen = [0, 0]     # window group totals already counted
+        self.c_kv_released_decode = reg.counter(
+            "kv_pages_released_in_decode_total", "of the pages a page group "
+            "gave back, those a fused decode burst's reservation released "
+            "(window: the ring turning while a sequence only decodes), per "
+            "group")
+        self._kvw_seen = [0, 0, 0]  # window group totals already counted
         self.g_kv_bytes = reg.gauge(
             "kv_bytes_per_token", "device bytes the KV pool stores for one "
             "cached token over all layers (a latent pool: one padded latent "
@@ -571,14 +576,28 @@ class ServingTelemetry:
                 moe_local=int(self.c_moe_local.value(**self.labels)),
                 moe_touched=int(self.c_moe_touched.value(**self.labels)))
         if getattr(state, "window", None):
+            self._fold_window_pages(state)
             note.update(
                 kvw_allocated=state.w_allocated_total,
                 kvw_released=state.w_released_total,
+                kvw_released_decode=state.w_released_decode_total,
                 kv_pages_window=(state.wallocator.num_blocks
                                  - state.wallocator.free_blocks),
                 kv_pages_global=(state.allocator.num_blocks
                                  - state.allocator.free_blocks))
         return note
+
+    def _fold_window_pages(self, state) -> None:
+        """The window group's running totals into its counters: at every
+        pool sample and every dispatch (a burst's reservation releases pages
+        between two samples)."""
+        now = [state.w_allocated_total, state.w_released_total,
+               state.w_released_decode_total]
+        for counter, n, seen in zip(
+                (self.c_kv_allocated, self.c_kv_released,
+                 self.c_kv_released_decode), now, self._kvw_seen):
+            counter.inc(n - seen, group="window", **self.labels)
+        self._kvw_seen = now
 
     def preemption(self, kind: str) -> None:
         if self.enabled:
@@ -635,13 +654,7 @@ class ServingTelemetry:
             self.g_kv_pages.set(used, group="global", **self.labels)
             self.g_kv_pages.set(wa.num_blocks - wa.free_blocks,
                                 group="window", **self.labels)
-            seen = self._kvw_seen
-            self.c_kv_allocated.inc(state.w_allocated_total - seen[0],
-                                    group="window", **self.labels)
-            self.c_kv_released.inc(state.w_released_total - seen[1],
-                                   group="window", **self.labels)
-            self._kvw_seen = [state.w_allocated_total,
-                              state.w_released_total]
+            self._fold_window_pages(state)
         alloc_tokens = 0
         live_tokens = 0
         for seq in state.tracked.values():

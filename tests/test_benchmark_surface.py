@@ -543,6 +543,9 @@ AFMOE_SPAN_ARGS = [
     ("ds.mixed_dispatch", "kvw_released"),
     ("ds.burst_dispatch", "kvw_allocated"),
     ("ds.burst_dispatch", "kvw_released"),
+    # (of those, the pages a burst's reservation gave back: PR 53)
+    ("ds.mixed_dispatch", "kvw_released_decode"),
+    ("ds.burst_dispatch", "kvw_released_decode"),
     ("ds.mixed_dispatch", "kv_pages_window"),
     ("ds.mixed_dispatch", "kv_pages_global"),
     # span_counters: the prefill kernel's live work items and its grid's

@@ -705,6 +705,50 @@ CELL_PREFILL = {
 }
 
 
+@pytest.mark.parametrize("nkv,g,window,sink", [
+    (4, 16, None, False),       # MiMo-V2-Flash's full layers
+    (8, 8, 128, True)])         # its window layers: ONE page, a sink a head
+def test_sink_and_unequal_widths_at_mimo_v2s_geometry(topo, nkv, g, window,
+                                                      sink):
+    """Both paged kernels and the append kernel compile at keys 192 wide
+    beside values 128 wide (kv-major pages ``[nkv, 192, 128]`` beside
+    ``[nkv, 128, 128]``), with the sink as the softmax's starting state, at
+    the serving cell's 96 slots and 512-row chunk."""
+    from deepspeed_tpu.ops import kv_append
+    from deepspeed_tpu.ops.paged_attention import ragged_prefill_supported
+    S, MB, bs, N, Q = 96, 40, 128, 512, 512
+    k, v = sds((1024, nkv, 192, bs), BF16), sds((1024, nkv, 128, bs), BF16)
+    bt, ln = sds((S, MB), I32), sds((S,), I32)
+    sk = sds((nkv * g,), F32)
+    kw = dict(kv_major=True, window=window)
+
+    def decode(q, k, v, bt, ln, sk):
+        extra = dict(kw, sink=sk if sink else None)
+        assert paged_supported(q, k, v, bt, ln, **extra)
+        return pallas_paged_attention(q, k, v, bt, ln, interpret=False,
+                                      **extra)
+
+    def prefill(q, k, v, bt, ln, sk):
+        extra = dict(kw, sink=sk if sink else None)
+        assert ragged_prefill_supported(q, k, v, bt, ln, ln, ln, ln, **extra)
+        return pallas_ragged_prefill(q, k, v, bt, ln, ln, ln, ln, max_q=Q,
+                                     interpret=False, **extra)
+
+    def append(k, v, kn, vn, bt, slot, pos):
+        plan = kv_append.append_plan(bt, slot, pos, bs, Q, True)
+        assert kv_append.supported((k, v), (kn, vn), plan, 0, kv_major=True)
+        return kv_append.pallas_paged_kv_append(
+            (k, v), (kn, vn), plan, jnp.int32(5), kv_major=True,
+            interpret=False)
+    rows = sds((N,), I32)
+    for fn, specs in (
+            (decode, (sds((S, nkv, g, 192), BF16), k, v, bt, ln, sk)),
+            (prefill, (sds((N, nkv, g, 192), BF16), k, v, bt, ln, sk)),
+            (append, (k, v, sds((N, nkv, 192), BF16),
+                      sds((N, nkv, 128), BF16), bt, rows, rows))):
+        assert chip_text(topo, fn, *specs).count(KERNEL) == 1, fn.__name__
+
+
 @pytest.mark.parametrize("cell", sorted(CELL_PREFILL))
 def test_prefill_block_at_the_cell_geometries(topo, monkeypatch, cell):
     """The prefill kernel's block of pages at each serving cell's geometry:
