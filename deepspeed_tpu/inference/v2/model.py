@@ -976,7 +976,7 @@ def _selected_attention(q, qi, wi, pages: _LayerPages, row_slot, row_pos,
                         max_rows: int = 1, rows=None):
     """A full layer's attention where the selection binds: index scores of
     the step's rows over their slots' index keys, the exact top
-    ``index_topk`` of them (scope ``attn_index``, the selection
+    ``index_topk`` of them (scope ``attn_index``, a list's sort
     ``index_select`` inside it), then attention over the selected rows of
     the latent pool and no others (scope ``selected_attention``); all of it
     inside scope ``attn_kernel``, whose time is a layer's attention whichever
@@ -988,13 +988,15 @@ def _selected_attention(q, qi, wi, pages: _LayerPages, row_slot, row_pos,
     slot) selects for its one-row slots (riding
     decode rows, whose contexts are the longest a step holds) apart from the
     slots that hold a prompt chunk: a chunk's rows are then scored and
-    sorted over their OWN contexts' width, not over the riders', and what a
-    step costs does not follow which sequences happen to ride it.  The
+    selected over their OWN contexts' width, not over the riders', and what
+    a step costs does not follow which sequences happen to ride it.  The
     riders gather their rows, as a decode step's do.  A chunk's rows read
     their keys whichever way is cheaper at the contexts this step holds
     (``ops.sparse_index.masked_prefill``, decided in the program): the
-    prefill kernel over the slot's pages with the selection as a mask, or
-    the same gather."""
+    prefill kernel over the slot's pages with the selection as a mask
+    (``threshold_mask``: each row's ``k``-th score by a search, no sort), or
+    the same gather over the sorted list (``index_select``), which only
+    that branch builds."""
     from deepspeed_tpu import ops
     from deepspeed_tpu.ops.sparse_index import masked_prefill
     k_pages, ki_pages, table = pages.k, pages.ki, pages.table
@@ -1041,12 +1043,14 @@ def _selected_attention(q, qi, wi, pages: _LayerPages, row_slot, row_pos,
             reach = jnp.max(jnp.where((row_slot < S) & ~alone, row_pos + 1,
                                       0))
             many_scores = scores(qi, wi, shared, row_pos, max_rows)
-            many = ops.index_select(many_scores, k, width=reach)   # [N, k]
         o_one = gathered(q[first], one, riders, row_pos[first])    # [S, ..]
 
         def masked():
+            # the kernel takes bits, and a row's bits need its k-th largest
+            # score and no list: no sort on this branch
             with jax.named_scope("attn_index"):
-                keep = ops.selection_mask(many_scores, many)
+                keep = ops.threshold_mask(many_scores, k, width=reach,
+                                          impl=cfg.attn_impl)
             with jax.named_scope("selected_attention"):
                 # each slot's rows are one span of positions ending at its
                 # kv_len; the riders' slots are told empty
@@ -1057,8 +1061,12 @@ def _selected_attention(q, qi, wi, pages: _LayerPages, row_slot, row_pos,
                     max_q=max_rows, sel_mask=keep, impl=cfg.attn_impl,
                     **attn)[:, 0]
 
-        o_many = jax.lax.cond(masked_prefill(reach), masked,
-                              lambda: gathered(q, many, shared, row_pos))
+        def listed():
+            with jax.named_scope("attn_index"):
+                many = ops.index_select(many_scores, k, width=reach)
+            return gathered(q, many, shared, row_pos)
+
+        o_many = jax.lax.cond(masked_prefill(reach), masked, listed)
         o = jnp.where(alone[:, None, None], o_one[slot], o_many)
         return jnp.where((row_slot < S)[:, None, None], o, 0)
 

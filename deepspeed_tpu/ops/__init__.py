@@ -165,7 +165,8 @@ register_op("ragged_prefill_attention", xla=_paged.xla_ragged_prefill,
 
 from deepspeed_tpu.ops import sparse_index as _index  # noqa: E402
 from deepspeed_tpu.ops.sparse_index import (  # noqa: E402
-    index_scores, index_select, selected_attention, selection_mask)
+    index_scores, index_select, selected_attention, selection_mask,
+    threshold_mask)
 
 register_op("index_scores", xla=_index.xla_index_scores,
             pallas=_index.pallas_index_scores,
@@ -173,6 +174,9 @@ register_op("index_scores", xla=_index.xla_index_scores,
 register_op("index_select", xla=_index.xla_index_select)
 register_op("selected_attention", xla=_index.xla_selected_attention)
 register_op("selection_mask", xla=_index.xla_selection_mask)
+register_op("threshold_mask", xla=_index.xla_threshold_mask,
+            pallas=_index.pallas_threshold_mask,
+            supported=_index.threshold_mask_supported)
 
 from deepspeed_tpu.ops import grouped_gemm as _grouped  # noqa: E402
 
@@ -301,7 +305,7 @@ __all__ = ["causal_attention", "flash_attention", "configure_flash_blocks",
            "ragged_prefill_attention", "sink_softmax",
            "evoformer_attention",
            "index_scores", "index_select", "selected_attention",
-           "selection_mask",
+           "selection_mask", "threshold_mask",
            "all_gather_matmul", "matmul_reduce_scatter",
            "row_parallel_matmul", "collective_matmul",
            "lm_cross_entropy", "masked_nll_sum", "rms_norm", "layer_norm",

@@ -668,7 +668,7 @@ def paged_attention(q, k_pages, v_pages, block_table, kv_lens, *,
 # keys (``ops/sparse_index.py``) hands the kernel, beside the pages, which
 # positions each row keeps: bit ``n % 32`` of word ``[n // 32, c]`` says row
 # ``n`` of the flat batch keeps position ``c`` of its sequence
-# (``selection_mask``), one answer for all heads of the row.  Rows are packed
+# (``threshold_mask``), one answer for all heads of the row.  Rows are packed
 # into words because a copy may take only whole tiles of an array's two minor
 # dims and an item's ``cq`` rows begin anywhere: as ``[groups, 1, C]`` the
 # group is a leading dim, a block's slab ``[its rows' groups, 1, P * bs]``
@@ -1233,7 +1233,7 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_table, kv_lens,
     at most ``max_q`` of them.  ``v_pages=None`` with ``v_dim``: latent pages
     (module docstring).  ``sel_mask``: ``[ceil(N / 32), MB * bs]`` int32,
     the positions of its sequence each row keeps beside what is causal
-    (``ops.selection_mask``; the section comment has the layout).  ``sink
+    (``ops.threshold_mask``; the section comment has the layout).  ``sink
     [heads]``: a sink logit a query head (module docstring)."""
     from deepspeed_tpu.ops.registry import dispatch
     return dispatch("ragged_prefill_attention", q, k_pages, v_pages,

@@ -1,21 +1,33 @@
 #!/usr/bin/env python3
-"""Step 0 for ``ops.sparse_index.MASKED_REACH`` (PERF.md section 6, PR 43):
-one selecting layer's attention for ONE prompt chunk, both ways the step
-program can read its keys, on the chip at dots3-note-prev's full layer (128
+"""Step 0 for ``ops.sparse_index.MASKED_REACH`` (PERF.md section 6, PR 43 and
+PR 54): one selecting layer's attention for ONE prompt chunk, both ways the
+step program can read its keys, on the chip at dots3-note-prev's full layer (128
 heads over a latent row of 640 columns, value the leading 512; 64 index heads
 of 128; top 2,048; pages of 512, a table of 64 = 32,768 tokens, the slot's
 pages 64 of a pool of 1,024: a pool small enough for the compiler to keep in
 VMEM, 42 MB, reads the gather at 18.6 ms where the cell's pays 43):
 
-- ``index``, ``select``: the index scores of the chunk (op ``index_scores``)
-  and their exact top-k (``index_select``): paid either way;
-- ``gathered``: each picked position's pool row by the table, then the rows
-  gathered by index and attended (``selected_attention``);
-- ``mask_build``: the same picks as bits (``selection_mask``);
-- ``masked``: the prefill kernel over the slot's pages with those bits
-  (``ragged_prefill_attention(sel_mask=)``), and ``masked_vs_gathered``: the
-  largest difference between the two results;
-- ``dense``: the same kernel with no mask.
+- ``index``: the index scores of the chunk (op ``index_scores``): paid either
+  way;
+- ``select``: their exact top-k (``index_select``, the sort), which only the
+  gathered path needs, and ``gathered``: each picked position's pool row by
+  the table, then the rows gathered by index and attended
+  (``selected_attention``);
+- ``threshold``: the same selection as bits with no list (``threshold_mask``,
+  the form the step program's dispatch takes here; ``threshold_xla`` and
+  ``threshold_pallas``: each form forced), which only the masked path needs,
+  and ``masked``: the prefill kernel over the slot's pages with those bits
+  (``ragged_prefill_attention(sel_mask=)``);
+- ``mask_build``: the bits from the sorted list (``selection_mask``, what
+  the masked path paid beside ``select`` until PR 54), and
+  ``threshold_vs_sorted``: whether each form's words are those, bit for bit,
+  on the chunk's scores and on a coarsened copy of them (heavy ties at every
+  row's threshold, zeros of both signs);
+- ``masked_vs_gathered``: the largest difference between the two results;
+- ``dense``: the same kernel with no mask;
+- ``paths``: ``select + gathered`` beside ``threshold + masked`` a context,
+  and a last line ``crossing``: the furthest reach (context + rows) walked at
+  which the masked path was still the cheaper, and the first past it.
 
     python3 scripts/selected_prefill_paths.py [--rows 1024] [--ctx 4096 ...]
 
@@ -57,6 +69,8 @@ def main():
     Q, C = args.rows, MB * bs
     kind = jax.devices()[0].device_kind
 
+    ms = {}
+
     def timed(name, fn, *a, **extra):
         out = jax.block_until_ready(fn(*a))
         times = []
@@ -64,8 +78,8 @@ def main():
             t = time.perf_counter()
             jax.block_until_ready(fn(*a))
             times.append((time.perf_counter() - t) * 1e3)
-        print(json.dumps({"path": name, "rows": Q,
-                          "ms_median": float(np.median(times)),
+        ms[name] = float(np.median(times))
+        print(json.dumps({"path": name, "rows": Q, "ms_median": ms[name],
                           "ms_min": float(np.min(times)), "device": kind,
                           **extra}), flush=True)
         return out
@@ -87,6 +101,14 @@ def main():
         qi, wi, ip, table, slot, pos, max_rows=Q, impl="pallas"))
     select = jax.jit(lambda s, reach: ops.index_select(s, topk, width=reach))
     build = jax.jit(ops.selection_mask)
+    threshold = {
+        name: jax.jit(lambda s, reach, impl=impl: ops.threshold_mask(
+            s, topk, width=reach, impl=impl))
+        for name, impl in (("threshold", None), ("threshold_xla", "xla"),
+                           ("threshold_pallas", "pallas"))}
+    # scores of a few values only, zeros of both signs among them
+    coarse = jax.jit(lambda s: jnp.where(jnp.isfinite(s), jnp.round(
+        s / 8) * jnp.where(jnp.arange(s.shape[1]) % 2, -1.0, 1.0), s))
 
     def prefill(q, pg, table, lens, keep):
         return ops.ragged_prefill_attention(
@@ -106,6 +128,7 @@ def main():
             q, pg, page * bs + picked % bs, jnp.minimum(pos + 1, topk),
             v_dim=vd, scale=scale)
 
+    cheaper = []
     for ctx_len in args.ctx:
         pos = ctx_len + jnp.arange(Q, dtype=jnp.int32)
         lens = (ctx_len + Q) * one
@@ -113,15 +136,38 @@ def main():
         scores = timed("index", index, qi, wi, ipages, table, pos, **info)
         picked = timed("select", select, scores, lens[0], **info)
         a = timed("gathered", gathered, q, pages, table, pos, picked, **info)
-        keep = timed("mask_build", build, scores, picked, **info)
+        sorted_keep = timed("mask_build", build, scores, picked, **info)
+        few = coarse(scores)
+        sorted_few = build(few, select(few, lens[0]))
+        for name, fn in threshold.items():
+            keep = timed(name, fn, scores, lens[0], **info)
+            print(json.dumps({
+                "path": "threshold_vs_sorted", "form": name, **info,
+                "bits_equal": bool(jnp.array_equal(keep, sorted_keep)),
+                "bits_equal_coarse": bool(jnp.array_equal(
+                    fn(few, lens[0]), sorted_few)),
+                "coarse_values": int(jnp.unique(few[-1]).size)}), flush=True)
         b = timed("masked", masked, q, pages, table, lens, keep, **info)
         timed("dense", dense, q, pages, table, lens, **info)
+        paths = {"gathered_path_ms": ms["select"] + ms["gathered"],
+                 "masked_path_ms": ms["threshold"] + ms["masked"]}
+        cheaper.append((ctx_len + Q,
+                        paths["masked_path_ms"] <= paths["gathered_path_ms"]))
+        print(json.dumps({"path": "paths", **info, "reach": ctx_len + Q,
+                          **paths}), flush=True)
         print(json.dumps({
             "path": "masked_vs_gathered", **info,
             "max_abs_diff": float(jnp.max(jnp.abs(
                 a.astype(jnp.float32) - b.astype(jnp.float32)))),
             "max_abs": float(jnp.max(jnp.abs(a.astype(jnp.float32))))}),
             flush=True)
+    dearer = min((reach for reach, ok in cheaper if not ok), default=None)
+    print(json.dumps({
+        "path": "crossing", "table_tokens": C,
+        "masked_cheaper_through": max(
+            (reach for reach, ok in cheaper
+             if ok and (dearer is None or reach < dearer)), default=0),
+        "masked_first_dearer": dearer}), flush=True)
 
 
 if __name__ == "__main__":

@@ -818,7 +818,7 @@ def test_setup_account_reads(served, trained, name):
 @pytest.fixture(scope="module")
 def selecting_notes():
     """``_ctx_note`` of a model that selects its keys (``index_topk`` 2,048
-    over a table of 32,768 tokens, two selecting layers), asked as
+    over a table of 65,536 tokens, two selecting layers), asked as
     ``_step_sampled`` asks it for three mixed steps (one chunk of 1,024 rows
     beside two riders: under, at and past ``MASKED_REACH``) and as
     ``_build_burst`` does for a burst: (telemetry, the four notes with the
@@ -830,13 +830,13 @@ def selecting_notes():
     tel = ServingTelemetry(pid=0)
     eng = types.SimpleNamespace(
         telemetry=tel, _block_size=512, model_config=types.SimpleNamespace(
-            index_topk=2048, max_seq_len=32768, num_layers=5,
+            index_topk=2048, max_seq_len=65536, num_layers=5,
             sliding_window=0, window_for_layer=lambda i: 513 if i > 1
             else None))
     notes = []
-    for ctx in (7168, 19456, 20480):
+    for ctx in (7168, 31744, 32768):
         notes.append(InferenceEngineV2._ctx_note(
-            eng, [ctx, 30000, 9000], [1024, 1, 1], table_tokens=32768))
+            eng, [ctx, 30000, 9000], [1024, 1, 1], table_tokens=65536))
         notes[-1].update(tel.counter_note(None))
     notes.append(InferenceEngineV2._ctx_note(eng, [31000, 9000], steps=8))
     notes[-1].update(tel.counter_note(None))
@@ -857,8 +857,8 @@ def _selecting_total(o):
 def _selecting_reach(o):
     # the chunk's context after the step, not the riders'; mixed spans only
     from deepspeed_tpu.ops.sparse_index import MASKED_REACH
-    assert [n.get("sel_reach") for n in o[1]] == [8192, 20480, 21504, None]
-    assert MASKED_REACH == 20480
+    assert [n.get("sel_reach") for n in o[1]] == [8192, 32768, 33792, None]
+    assert MASKED_REACH == 32768
 
 
 SELECTING_READS = {

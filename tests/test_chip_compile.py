@@ -667,6 +667,13 @@ def test_index_score_kernel_and_the_ops_around_it(topo):
     assert "index_score_kernel" in text
     assert chip_text(topo, lambda s: ops.index_select(s, 2048),
                      sds((N, MB * bs), F32))
+    # the same selection as bits with no list: 64 rows of 32 k scores and
+    # as much of keys in VMEM a grid step; rows that are no whole block
+    text = chip_text(
+        topo, lambda s, w: ops.threshold_mask(
+            s, 2048, width=w, impl="pallas", interpret=False),
+        sds((N + 16, MB * bs), F32), sds((), I32))
+    assert "threshold_mask_kernel" in text and KERNEL in text
     assert chip_text(
         topo, lambda q, pg, r, c: ops.selected_attention(
             q, pg, r, c, v_dim=512, scale=192 ** -0.5),
@@ -697,7 +704,7 @@ CELL_PREFILL = {
     "dots3-window": (lambda: dict(
         nkv=1, g=64, hd=1152, bs=512, kv_major=False, window=513,
         v_dim=1024), 1024, 1),
-    # a full layer's prompt chunk below ``MASKED_REACH``: the masked form
+    # a full layer's prompt chunk up to ``MASKED_REACH``: the masked form
     # over the 64 pages of the cell's one table width (4 rows an item)
     "dots3-full-masked": (lambda: dict(
         nkv=1, g=128, hd=640, bs=512, kv_major=False, v_dim=512, S=1,
@@ -968,17 +975,22 @@ def test_a_chunk_reads_its_keys_one_way_a_branch(selecting_steps,
     """The mixed step of a model that selects its keys holds ONE branch a
     selecting layer kind under ``attn_kernel`` (``masked_prefill`` of the
     step's reach).  The masked branch is the prefill kernel under scope
-    ``selected_attention`` and gathers no row a pair: nothing of ``[rows, k,
-    640]``.  The other is the gather's loop and no kernel.  (That neither
-    moves a pool is the test above: it reads every computation.)"""
+    ``selected_attention`` on the bits of kernel ``threshold_mask_kernel``
+    under ``attn_index``; it sorts nothing and gathers no row a pair: nothing
+    of ``[rows, k, 640]``.  The other is the list's sort, the gather's loop
+    and no kernel.  (That neither moves a pool is the test above: it reads
+    every computation.)"""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = selecting_steps()[1]["ragged_forward_sampled"].as_text()
+    # (the gathering branch holds a branch of its own, the sort's widths:
+    # more than two ways)
+    two_ways = re.compile(r"branch_computations=\{%([\w.\-]+), "
+                          r"%([\w.\-]+)\}")
     branches = [ln for ln in text.splitlines() if " conditional(" in ln
-                and "/attn_kernel/cond" in ln]
+                and "/attn_kernel/cond" in ln and two_ways.search(ln)]
     assert len(branches) == 1, branches
     gathers, masked = (_with_callees(_computations(text), name) for name in
-                       re.search(r"branch_computations=\{%([\w.\-]+), "
-                                 r"%([\w.\-]+)\}", branches[0]).groups())
+                       two_ways.search(branches[0]).groups())
 
     def row_gathers(part):
         return [f"{dt}[{dims}]" for result, op in _HLO_OP.findall(part)
@@ -987,12 +999,14 @@ def test_a_chunk_reads_its_keys_one_way_a_branch(selecting_steps,
                 >= DOTS3.index_topk * 640]
     kernels = [ln for ln in masked.splitlines()
                if f'custom_call_target="{KERNEL}"' in ln]
-    assert len(kernels) == 1 and "/selected_attention/" in kernels[0] \
-        and "/ragged_prefill/" in kernels[0] \
-        and "window_latent" not in kernels[0]
-    assert "selection_mask" in masked and not row_gathers(masked)
+    bits, prefill = sorted(kernels, key=lambda ln: "/ragged_prefill/" in ln)
+    assert len(kernels) == 2 and "/selected_attention/" in prefill \
+        and "/ragged_prefill/" in prefill and "window_latent" not in prefill
+    assert "/attn_index/" in bits and "threshold_mask_kernel" in bits
+    assert " sort(" not in masked and not row_gathers(masked)
     assert row_gathers(gathers) and " while(" in gathers
-    assert KERNEL not in gathers and "selection_mask" not in gathers
+    assert " sort(" in gathers and "/index_select/" in gathers
+    assert KERNEL not in gathers and "threshold_mask" not in gathers
 
 
 # -------------------------------------------------------- quantized GEMMs

@@ -273,6 +273,97 @@ def test_tokens_are_the_same_whichever_way_a_chunk_reads_its_keys(monkeypatch):
     assert masked[512][0] == masked[512][1] == masked[512][2] > 0
 
 
+def _primitives(jaxpr):
+    """The names of every primitive in ``jaxpr``, its sub-programs'
+    (branches, loop bodies, kernels) among them."""
+    out = set()
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out |= _primitives(sub)
+    return out
+
+
+def test_only_the_branch_that_gathers_sorts():
+    """A mixed step's selecting layer (PR 54): the branch that takes the
+    masked prefill kernel gets its bits from ``threshold_mask`` and holds no
+    sort; the list is sorted inside the branch that gathers it (and, outside
+    the two, for the one-row slots, which gather)."""
+    lc = model(two_layers())[0].for_layer(0)
+    S, MB, bs, N, Q = 4, 8, 16, 64, 32
+    nI, dI = lc.index_n_heads, lc.index_head_dim
+    f32, i32 = jnp.float32, jnp.int32       # (256: the latent row, padded)
+    pages = v2model._LayerPages(
+        k=jnp.zeros((S * MB, 1, bs, 256), f32), v=None,
+        ki=jnp.zeros((S * MB, 1, bs, dI), f32),
+        table=jnp.zeros((S, MB), i32), k_scale=None, v_scale=None)
+    rows = v2model._MixedRows(jnp.zeros((N,), i32), jnp.zeros((S,), i32),
+                              jnp.zeros((S,), i32), jnp.zeros((S,), i32))
+    jaxpr = jax.make_jaxpr(lambda q, qi, wi, pages, slot, pos, rows: (
+        v2model._selected_attention(q, qi, wi, pages, slot, pos, lc,
+                                    block_size=bs, max_rows=Q, rows=rows)))(
+        jnp.zeros((N, lc.num_heads, 256), f32), jnp.zeros((N, nI, dI), f32),
+        jnp.zeros((N, nI), f32), pages, jnp.zeros((N,), i32),
+        jnp.zeros((N,), i32), rows).jaxpr
+    sorts = {"sort", "top_k", "argsort"}
+    outside = {e.primitive.name for e in jaxpr.eqns}
+    assert "top_k" in outside                   # the one-row slots' lists
+    (cond,) = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    listed, masked = (_primitives(b.jaxpr) for b in cond.params["branches"])
+    assert not masked & sorts and "scan" in masked       # the counted passes
+    assert "top_k" in listed and "gather" in listed
+
+
+# sha256[:16] of the lowered mixed and decode step programs of a dense GQA
+# model (Mistral's shape) and of a latent-attention MoE (Moonlight's), at tiny
+# widths on ``conftest.lower_serving_steps``, taken on the parent of PR 54
+# (2904d51): a configuration without ``index_topk`` never reaches
+# ``_selected_attention``, so its programs lower to the text they lowered to.
+# A later PR that changes what those programs ARE takes the hashes anew, on
+# its own parent, and says so.
+UNSELECTING_PROGRAMS = {
+    ("gqa", "ragged_forward_sampled"): "aeec98ca9fc5a3f8",
+    ("gqa", "ragged_decode_sampled"): "ce0f75f292b78f91",
+    ("latent", "ragged_forward_sampled"): "77069dc9ecdd2b29",
+    ("latent", "ragged_decode_sampled"): "485fa36060f455ad",
+}
+
+
+def unselecting_config(kind):
+    if kind == "gqa":
+        return dataclasses.replace(
+            GPTConfig.llama(num_layers=2, hidden=64, heads=4, num_kv_heads=2,
+                            vocab_size=128, max_seq_len=256, dtype=None),
+            dtype=jnp.float32)
+    return GPTConfig(
+        num_layers=2, hidden_size=64, num_heads=4, head_dim=24,
+        kv_lora_rank=128, qk_rope_head_dim=8, v_head_dim=16, use_rope=True,
+        use_rmsnorm=True, gated_mlp=True, tie_embeddings=False,
+        vocab_size=128, max_seq_len=256, mlp_dim_override=128, num_experts=4,
+        moe_k=2, moe_dropless=True, moe_router="sigmoid",
+        moe_router_bias=True, moe_shared_dim=32, moe_expert_dim=32,
+        moe_dense_layers=1, dtype=jnp.float32)
+
+
+def unselecting_hashes(kind):
+    import hashlib
+
+    from conftest import lower_serving_steps
+    lowered = lower_serving_steps(
+        unselecting_config(kind), jnp.float32, slots=4, tokens=64, max_q=16,
+        table_width=8, block_size=16, num_pages=32, steps=4)[2]
+    return {(kind, name): hashlib.sha256(
+        lowered[name].as_text().encode()).hexdigest()[:16]
+        for name in ("ragged_forward_sampled", "ragged_decode_sampled")}
+
+
+@pytest.mark.parametrize("kind", ["gqa", "latent"])
+def test_a_model_that_does_not_select_lowers_as_on_the_parent(kind):
+    got = unselecting_hashes(kind)
+    assert got == {k: v for k, v in UNSELECTING_PROGRAMS.items()
+                   if k[0] == kind}
+
+
 def test_exact_selection_breaks_ties_toward_the_lower_position():
     scores = jnp.asarray([[1.0, 3.0, 3.0, 3.0, 0.5, 3.0]])
     assert sorted(np.asarray(ops.index_select(scores, 3))[0]) == [1, 2, 3]

@@ -2,7 +2,9 @@
 the selection as bits (``ops.selection_mask``), the prefill kernel's masked
 form against its XLA fallback and against the row gather on the same picks,
 the no-mask kernel being the program it was, and the one rule that chooses
-(``ops.sparse_index.masked_prefill``).  Small shapes, the kernel interpreted.
+(``ops.sparse_index.masked_prefill``); and the same bits with no list (PR 54:
+``ops.threshold_mask``, each row's k-th score by a search) against the sorted
+path's, bit for bit.  Small shapes, the kernels interpreted.
 """
 
 import hashlib
@@ -69,6 +71,61 @@ def test_the_mask_holds_each_rows_picks_and_nothing_else(ties):
         want = np.asarray(picked[n, :min(int(b["pos"][n]) + 1, K)]) \
             if int(b["slot"][n]) < S else []
         assert set(np.flatnonzero(keep[n])) == set(np.asarray(want)), n
+
+
+def _scores(kind, rng, n, c):
+    """``[n, c]`` float32 of one kind of trouble, all finite."""
+    if kind == "distinct":
+        return rng.permutation(n * c).reshape(n, c).astype(np.float32) - 9.5
+    if kind == "ties":          # a few values: every threshold is a tie
+        return rng.integers(-2, 3, size=(n, c)).astype(np.float32)
+    if kind == "signed_zeros":  # the sort puts -0.0 under 0.0, == does not
+        return rng.choice(np.asarray([-0.0, 0.0, -0.0, 0.0, 1.5, -1.5],
+                                     np.float32), size=(n, c))
+    if kind == "one_value":     # the k lowest positions of a row of equals
+        return np.full((n, c), -3.25, np.float32)
+    raise ValueError(kind)
+
+
+@pytest.mark.parametrize("rows,width,C,k", [
+    (70, None, 256, K), (70, 256, 256, K), (70, 100, 256, K),
+    (32, 129, 256, K), (5, 64, 256, K), (40, 300, 1024, 600)],
+    ids=["whole", "width_is_C", "narrow", "word_rows", "few_rows",
+         "width_under_k"])
+@pytest.mark.parametrize("kind", ["distinct", "ties", "signed_zeros",
+                                  "one_value"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_threshold_gives_the_sorted_paths_bits(impl, kind, rows, width,
+                                                   C, k):
+    """``threshold_mask(scores, k, width=)`` is ``selection_mask(scores,
+    index_select(scores, k, width=))`` word for word, in both forms: rows
+    that see fewer keys than ``k`` (0, 5, ``k - 1``, ``k``, ``k + 1``
+    positions and more), rows of no slot (all ``-inf``), scores of both
+    signs, ties at the threshold, ``width`` narrower than the table (and
+    than ``k``: the kernel's walk still covers ``k`` columns), equal to it
+    and absent, row counts that are and are not whole words."""
+    rng = np.random.default_rng(sum(map(ord, kind)) + rows)
+    reach = C if width is None else width
+    pos = rng.integers(0, reach, size=rows)
+    pos[:5] = [0, 5, min(k, reach) - 2, min(k, reach) - 1,
+               min(k, reach - 1)]
+    pos[5:7] = reach - 1
+    seen = np.arange(C)[None, :] <= pos[:, None]
+    seen[-2:] = False                                   # rows of no slot
+    scores = jnp.asarray(np.where(seen, _scores(kind, rng, rows, C),
+                                  -np.inf))
+    w = None if width is None else jnp.int32(width)
+    want = jax.jit(lambda s, w: ops.selection_mask(
+        s, ops.index_select(s, k, width=w)))(scores, w)
+    got = jax.jit(lambda s, w: ops.threshold_mask(
+        s, k, width=w, impl=impl))(scores, w)
+    assert got.shape == want.shape == (-(-rows // 32), C)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    kept = _bits(got, rows).sum(axis=1)
+    assert (kept[-2:] == 0).all() and kept[1] == 6 and kept[0] == 1
+    if kind != "signed_zeros":      # (there ``==`` keeps a tie the sort
+        assert (kept[:-2] == np.minimum(pos[:-2] + 1, k)).all()  # ranked lower)
 
 
 def _three_ways(b, impl):
@@ -171,10 +228,10 @@ def test_the_rule_at_below_and_above_the_constant(monkeypatch):
     assert "masked_prefill(reach)" in "".join(
         open(v2model.__file__).read().split())
     at = si.MASKED_REACH
-    assert at == 20480
+    assert at == 32768
     asked = jax.jit(si.masked_prefill)
     for reach, want in ((0, True), (at - 1, True), (at, True),
-                        (at + 1, False), (32768, False)):
+                        (at + 1, False), (65536, False)):
         assert bool(si.masked_prefill(reach)) is want
         assert bool(asked(jnp.int32(reach))) is want
     monkeypatch.setattr(si, "MASKED_REACH", 0)
