@@ -1677,16 +1677,20 @@ class DeepSpeedTPUEngine:
                     params=_poison_first_float_leaf(self.state.params))
             self.timers(TRAIN_BATCH_TIMER).start()
             with self.mesh:
+                jfn = (self._jit_grads_batch if self.offloading
+                       else self._jit_train_batch)
                 if tel.enabled:
                     # recompile watchdog + (on a signature miss) compiled-HLO
                     # collective bytes / cost / memory figures
-                    jfn = (self._jit_grads_batch if self.offloading
-                           else self._jit_train_batch)
                     tel.before_dispatch(
                         "train_batch", batch, step_id,
                         lower=lambda: jfn.lower(self.state, batch))
                 mark = _SETUP.booked
-                with tel.span("dispatch", step=step_id):
+                # ``step`` is this dispatch's number and ``program`` what it
+                # launches (the name ``XLA Modules`` prints after ``jit_``):
+                # a trace reader joins the device run to the span by both
+                with tel.span("dispatch", step=step_id,
+                              program=jfn.__name__):
                     # chaos: ``sleep@step.dispatch`` models a hung collective /
                     # straggler stall: the guardian watchdog's deadline target
                     faults.fire("step.dispatch", step=step_id)

@@ -702,10 +702,13 @@ class InferenceEngineV2:
                 self.cache = PagedKVCache.create(
                     model_cfg, num_blocks, eff_bs, dt, quant=sm.kv_quant,
                     slots=sm.max_tracked_sequences)
-        # MoE counter vectors of dispatches not yet read back (device
+        # (seq, MoE counter vector) of dispatches not yet read back (device
         # values the step programs return; folded into the telemetry once
         # ready, never waited for: _fold_moe_stats)
         self._moe_pending: List[Any] = []
+        # the newest dispatch whose results a materialize has fetched: what
+        # the next one's ``in_flight`` is counted from
+        self._through_seq = 0
         # ---- speculative decoding draft (greedy draft-and-verify) ----
         self.draft_config = self.draft_params = self.draft_cache = None
         if draft_model is not None:
@@ -1110,11 +1113,11 @@ class InferenceEngineV2:
                  "token_pos": rb.token_pos[:nb],
                  **rb.table_operands(mb), "kv_len": rb.kv_len}
         batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, batch))
-        self.telemetry.dispatch("mixed")
         self.telemetry.padding_waste(rb.total_tokens, nb)
         mark = _SETUP.booked
-        with self.telemetry.span("mixed_dispatch", tokens=rb.total_tokens,
-                                 bucket=nb, seqs=len(rb.logits_slots), **hc):
+        with self.telemetry.dispatch_span(
+                "mixed", self._steps[key], tokens=rb.total_tokens,
+                bucket=nb, seqs=len(rb.logits_slots), **hc):
             out = self._steps[key](self.params, self.cache, batch)
         if _SETUP.booked != mark:      # a first call: jax traced or loaded
             _SETUP.close("put_mixed", mark, self.telemetry.tracer,
@@ -1151,10 +1154,10 @@ class InferenceEngineV2:
         batch = self._with_lora(jax.tree_util.tree_map(jnp.asarray, {
             "tokens": tokens, "active": active, "token_pos": token_pos,
             **rb.table_operands()}))
-        self.telemetry.dispatch("decode")
         mark = _SETUP.booked
-        with self.telemetry.span("decode_dispatch", seqs=rb.total_tokens,
-                                 **(hc or {})):
+        with self.telemetry.dispatch_span(
+                "decode", self._steps[key], seqs=rb.total_tokens,
+                **(hc or {})):
             out = self._steps[key](self.params, self.cache, batch)
         if _SETUP.booked != mark:
             _SETUP.close("put_decode", mark, self.telemetry.tracer, bucket=S)
@@ -1168,11 +1171,12 @@ class InferenceEngineV2:
 
     def _take_moe_stats(self, out):
         """A step program's outputs without the MoE counter vector that a
-        model with expert layers has its programs return last: that goes on the list
+        model with expert layers has its programs return last: that goes,
+        with the ``seq`` of the dispatch just made, on the list
         ``_fold_moe_stats`` reads back once the device has it."""
         if "moe_stats" not in self._model_static:
             return out
-        self._moe_pending.append(out[-1])
+        self._moe_pending.append((self.telemetry.seq, out[-1]))
         return out[:-1]
 
     def _fold_moe_stats(self, wait: bool = False) -> None:
@@ -1181,8 +1185,20 @@ class InferenceEngineV2:
         drain or the end of a call, where the host syncs anyway).  Reading
         a ready 12-byte array is no fence."""
         pend = self._moe_pending
-        while pend and (wait or pend[0].is_ready()):
-            self.telemetry.moe_stats(np.asarray(pend.pop(0)))
+        while pend and (wait or pend[0][1].is_ready()):
+            seq, vec = pend.pop(0)
+            self.telemetry.moe_stats(np.asarray(vec), seq)
+
+    def _materialize_note(self, through_seq: int) -> Dict[str, int]:
+        """What a ``ds.materialize`` span says of the host's lead:
+        ``through_seq``, the newest dispatch whose results it fetches, and
+        ``in_flight``, the dispatches made since the one the materialize
+        before it fetched through: how far ahead of the device the host
+        was when it chose to wait."""
+        note = {"through_seq": through_seq,
+                "in_flight": self.telemetry.seq - self._through_seq}
+        self._through_seq = through_seq
+        return note
 
     def _sample_fn(self, gen):
         from deepspeed_tpu.inference.engine import _sample_token
@@ -1329,15 +1345,15 @@ class InferenceEngineV2:
                                    top_k=gen.top_k, mesh=self.mesh),
                     donate_argnums=(2, 3))
             mark = _SETUP.booked
-            with stel.span("spec_dispatch", steps=outer, gamma=gamma,
-                           seqs=len(reqs), ctx_tokens=ctx_tokens,
-                           kv_bytes_per_token=stel.kv_bytes_per_token):
+            with stel.dispatch_span(
+                    "spec", self._steps[key], steps=outer, gamma=gamma,
+                    seqs=len(reqs), ctx_tokens=ctx_tokens,
+                    kv_bytes_per_token=stel.kv_bytes_per_token):
                 toks, counts, prev, rng, self.cache, self.draft_cache = \
                     self._steps[key](self.params, self.draft_params,
                                      self.cache, self.draft_cache, batch,
                                      prev, rng, jnp.float32(gen.temperature),
                                      jnp.float32(gen.top_p))
-            stel.dispatch("spec")
         else:
             key = ("spec", outer, gamma)
             if key not in self._steps:
@@ -1350,17 +1366,17 @@ class InferenceEngineV2:
                                    mesh=self.mesh),
                     donate_argnums=(2, 3))
             mark = _SETUP.booked
-            with stel.span("spec_dispatch", steps=outer, gamma=gamma,
-                           seqs=len(reqs), ctx_tokens=ctx_tokens,
-                           kv_bytes_per_token=stel.kv_bytes_per_token):
+            with stel.dispatch_span(
+                    "spec", self._steps[key], steps=outer, gamma=gamma,
+                    seqs=len(reqs), ctx_tokens=ctx_tokens,
+                    kv_bytes_per_token=stel.kv_bytes_per_token):
                 toks, counts, prev, self.cache, self.draft_cache = \
                     self._steps[key](self.params, self.draft_params,
                                      self.cache, self.draft_cache, batch,
                                      prev)
-            stel.dispatch("spec")
         if _SETUP.booked != mark:
             _SETUP.close("spec", mark, stel.tracer, steps=outer, gamma=gamma)
-        with stel.span("materialize"):
+        with stel.span("materialize", **self._materialize_note(stel.seq)):
             # the host cannot schedule past the burst without the counts —
             # this is THE disclosed sync of the speculative path
             toks_h, counts_h = jax.device_get([toks, counts])  # sync-ok
@@ -1395,11 +1411,10 @@ class InferenceEngineV2:
         with stel.span("h2d"):
             batch = self._with_lora(
                 jax.tree_util.tree_map(jnp.asarray, host))
-        stel.dispatch("burst")
         mark = _SETUP.booked
-        with stel.span("burst_dispatch", steps=steps, seqs=len(reqs),
-                       tokens=steps * len(reqs), **note,
-                       **stel.counter_note(self.state)):
+        with stel.dispatch_span("burst", self._steps[key], steps=steps,
+                                seqs=len(reqs), tokens=steps * len(reqs),
+                                **note, **stel.counter_note(self.state)):
             toks, prev, rng, self.cache = self._take_moe_stats(
                 self._steps[key](
                     self.params, self.cache, batch, prev, rng,
@@ -1512,16 +1527,16 @@ class InferenceEngineV2:
         with stel.span("h2d"):
             batch = self._with_lora(
                 jax.tree_util.tree_map(jnp.asarray, host))
-        stel.dispatch(kind)
         mark = _SETUP.booked
         if draft:
-            with stel.span(f"{kind}_dispatch", draft=True, **note):
+            with stel.dispatch_span(kind, self._steps[key], draft=True,
+                                    **note):
                 prev, rng, self.cache, self.draft_cache = self._steps[key](
                     self.params, self.draft_params, self.cache,
                     self.draft_cache, batch, prev, rng,
                     jnp.float32(gen.temperature), jnp.float32(gen.top_p))
         else:
-            with stel.span(f"{kind}_dispatch", **note):
+            with stel.dispatch_span(kind, self._steps[key], **note):
                 prev, rng, self.cache = self._take_moe_stats(
                     self._steps[key](
                         self.params, self.cache, batch, prev, rng,
@@ -1948,8 +1963,9 @@ class InferenceEngineV2:
         sync_interval = 16 if eos is not None else None
         prev = jnp.zeros(S, jnp.int32)          # device feedback vector
         rng = jax.random.PRNGKey(seed)          # device-resident, threaded
-        # device records: ("step", arr [S], [(uid, slot)]) or
-        # ("burst", arr [T, S], [(uid, slot)], T) — fetched in ONE transfer
+        # device records: ("step", arr [S], [(uid, slot)], seq) or
+        # ("burst", arr [T, S], [(uid, slot)], seq), seq the dispatch's that
+        # made them — fetched in ONE transfer
         records: List[tuple] = []
         # requests retired while their tokens still sat in device records;
         # telemetry-finished at the next materialize, when .generated is
@@ -1974,7 +1990,8 @@ class InferenceEngineV2:
             steps_since_sync = 0
             if not records:
                 return
-            with stel.span("materialize", records=len(records)):
+            with stel.span("materialize", records=len(records),
+                           **self._materialize_note(records[-1][-1])):
                 arrs = jax.device_get([rec[1] for rec in records])
                 self._fold_moe_stats(wait=True)   # the sync just happened
                 for rec, arr in zip(records, arrs):
@@ -2259,7 +2276,7 @@ class InferenceEngineV2:
                             self._stream_fence(prev)
                         rnd.phase("retire")
                         tnow = now_fn()
-                        records.append(("burst", toks, pairs, T))
+                        records.append(("burst", toks, pairs, stel.seq))
                         for r in list(running):
                             r.sampled += T
                             if r.t_first is None:
@@ -2455,7 +2472,7 @@ class InferenceEngineV2:
                     # later program aliasing them is ordered behind the writer
                     self.state.cache_insert(self.state.get(r.uid))
                 if pairs:
-                    records.append(("step", prev, pairs))
+                    records.append(("step", prev, pairs, stel.seq))
                 for r in sampled_now:
                     if r.t_first is None:
                         r.t_first = tnow

@@ -95,6 +95,12 @@ class ServingTelemetry:
         self.request_log_cap = 100_000
         self.scan_layers = 0            # set_scan_state
         self.state_kind = "ssm"
+        # step-program dispatches of this engine since it was built: the
+        # number a dispatch span carries, advanced whether or not enabled
+        self.seq = 0
+        # the dispatch through which the MoE counters hold the device's
+        # vectors (moe_stats), written beside them by counter_note
+        self.moe_seq = 0
         if not self.enabled:
             return
         reg = self.registry
@@ -338,6 +344,20 @@ class ServingTelemetry:
         annotation always, the tracer's buffered event when it is on."""
         return self.tracer.span(name, **args)
 
+    def dispatch_span(self, kind: str, program, **args):
+        """One step-program dispatch: the next ``seq``, the kind counted
+        and the ``ds.<kind>_dispatch`` span opened in one place, so that a
+        span and its count cannot drift apart.  The span says which
+        dispatch of this engine it is (``seq``) and which program it means
+        to launch (``program``, the jitted callable's name: what the
+        profiler's ``XLA Modules`` line prints after ``jit_``), so a trace
+        reader can hold the device run it joins by the runtime's ``run_id``
+        to both."""
+        self.seq += 1
+        self.dispatch(kind)
+        return self.tracer.span(f"{kind}_dispatch", seq=self.seq,
+                                program=program.__name__, **args)
+
     # ---------------------------------------------------- request lifecycle
 
     def new_track(self, label: str) -> int:
@@ -438,9 +458,12 @@ class ServingTelemetry:
             self.c_prefill_items.inc(items, **self.labels)
             self.c_prefill_grid.inc(grid_items, **self.labels)
 
-    def moe_stats(self, vec) -> None:
+    def moe_stats(self, vec, seq: int) -> None:
         """One dispatch's MoE counter vector (model.py ``_ffn``): [local
-        assignments, assignments of live rows, local experts touched]."""
+        assignments, assignments of live rows, local experts touched];
+        ``seq`` is that dispatch's, and the totals are then the device's
+        through it."""
+        self.moe_seq = seq
         if self.enabled:
             self.c_moe_local.inc(int(vec[0]), **self.labels)
             self.c_moe_assign.inc(int(vec[1]), **self.labels)
@@ -538,8 +561,9 @@ class ServingTelemetry:
         them (a reader takes the difference between two dispatches): the
         slots mixed dispatches served and those of them with one row, the
         prefill kernel's live work items and its grid's, the MoE counters
-        as far as the device has reported and the window page group's; the
-        last two only for a model that has them."""
+        as far as the device has reported (through dispatch ``moe_seq``)
+        and the window page group's; the last two only for a model that
+        has them."""
         note: Dict[str, int] = {}
         if not self.enabled:
             return note
@@ -574,7 +598,8 @@ class ServingTelemetry:
             note.update(
                 moe_assign=int(total),
                 moe_local=int(self.c_moe_local.value(**self.labels)),
-                moe_touched=int(self.c_moe_touched.value(**self.labels)))
+                moe_touched=int(self.c_moe_touched.value(**self.labels)),
+                moe_seq=self.moe_seq)
         if getattr(state, "window", None):
             self._fold_window_pages(state)
             note.update(

@@ -7,8 +7,10 @@ overlap starts from *seeing* the timeline.  One span API, two sinks:
   ``ds.<name>`` carrying the span's arguments, so a ``jax.profiler`` trace
   of a running engine holds the host-side step anatomy — batch assembly,
   host→device placement, dispatch, scheduler phases, checkpoint I/O — on
-  the profiler's clock, beside the device ops they launched.  With no
-  profiler session open the annotation costs well under a microsecond;
+  the profiler's host clock, beside the device ops they launched (whose
+  timeline may stand a millisecond or two apart: docs/observability.md,
+  "From a dispatch to its device run").  With no profiler session open the
+  annotation costs well under a microsecond;
 - when the tracer's own buffer is enabled the span is also recorded as a
   complete event, and ``TraceEmitter`` writes the standard Chrome
   trace-event JSON that Perfetto / chrome://tracing load directly (the
