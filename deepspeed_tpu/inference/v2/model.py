@@ -759,10 +759,10 @@ def _moe_route(mp, x, cfg):
 def _experts_fn(cfg, with_stats: bool):
     """The expert FFN of ``cfg`` (moe/layer.py:_expert_ffn_ragged with the
     grouped GEMM left to the registry) as ONE jitted function, made once a
-    step program and called by every expert layer of it: the sort, the
-    gather, the two kernel calls and the scatter-add are traced once and
-    lowered once a program, not once a layer (what ``attend`` is to the
-    attention kernels).  A jit of the trace's own, not of the module: the
+    step program and called by every expert layer of it: the positions by
+    count, the row gather, the two kernel calls and the combine's gathers
+    are traced once and lowered once a program, not once a layer (what
+    ``attend`` is to the attention kernels).  A jit of the trace's own, not of the module: the
     ops choose their implementation while tracing."""
     from deepspeed_tpu.moe.layer import _expert_ffn_ragged
     return jax.jit(named_partial(
@@ -780,10 +780,12 @@ def _ffn(blk, x, cfg, mesh=None, live=None, stats=None, routes=None,
 
     An expert layer is the same function as the flax module's
     (moe/layer.py) on the same parameters, in three scopes inside the
-    caller's ``mlp``: ``moe_route`` (``_moe_route``), ``moe_experts`` (sort,
-    counts, gather, grouped GEMMs over the experts held here: the registry's
-    choice, the Pallas kernel on a TPU, since nothing here is differentiated;
-    weighted scatter-add) and, where the model has a shared expert, ``moe_shared``.
+    caller's ``mlp``: ``moe_route`` (``_moe_route``), ``moe_experts`` (each
+    assignment's position by count, the rows gathered to their experts,
+    grouped GEMMs over the experts held here: the registry's choice, the
+    Pallas kernel on a TPU, since nothing here is differentiated; each
+    token's k products gathered back and summed in float32) and, where the
+    model has a shared expert, ``moe_shared``.
     ``live [N]`` marks the rows that are tokens (not padding, not an idle
     slot): the others' assignments are dropped with those to experts not
     held.  ``stats``, a list, takes the layer's counter vector, and

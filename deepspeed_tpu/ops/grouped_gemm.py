@@ -6,9 +6,10 @@ gated first half of an expert FFN in one pass over the rows:
 ``silu(rows @ gate[g]) * (rows @ weights[g])``, float32 until the one
 rounding to the rows' dtype.  ``sum(group_sizes)`` may be less than ``A``:
 the rows behind the last group belong to no expert and callers must not
-read them (moe/layer.py masks them: ``lax.ragged_dot`` writes 0 there, the
-kernel the products of whatever the buffer held, NaN included, as far as its
-last tile reaches, and nothing behind that).
+use them (moe/layer.py gathers each kept assignment's product from its own
+place before them and masks a dropped one's by ``where``: ``lax.ragged_dot``
+writes 0 there, the kernel the products of whatever the buffer held, NaN
+included, as far as its last tile reaches, and nothing behind that).
 
 Two implementations, chosen by ops/registry.py:
 
@@ -79,7 +80,23 @@ weights' bytes there (8,192 rows read, written 1,536 wide, read again,
 written).  The scope's other ops, each alone: the sort 0.21-0.24 ms at every
 shape, the row gather 0.22-0.25, the weighted scatter-add 0.22-0.26 in the
 decode steps and 0.58 / 0.57 / 3.04 / 0.80 / 0.78 in the mixed ones (dots3's
-8,192 rows of 5,120, seven eighths of them nobody's).
+8,192 rows of 5,120, seven eighths of them nobody's); a call alone carries
+~0.2 ms of launch, which is all the first two read.
+
+Inside a step program (PR 56, a mixed step of LFM2 traced on the parent,
+``chiprun_out/pr56/ops_lfm2_parent.md``, a layer of 8,192 rows): the sort
+0.009 ms, the row gather 0.046, the scatter-add 0.63 behind a mask-and-weight
+pass of 0.014, and three scalar-indexed ops no table had: the gather of the
+weights by ``order`` 0.079, of ``tok_rows`` 0.059, the scatter-add of the
+group sizes 0.072.  Since PR 56 moe/layer.py takes the products back by ``k``
+gathers of ``[S, H]`` summed in float32 (0.10 ms a layer there with the
+layout copy the compiler makes of each; eight layers unrolled in one
+program, ``step0.md``'s ``ms in program``, Moonlight / Trinity / dots3 / LFM2
+/ Xing4: 0.13 / 0.12 / 0.63 / 0.18 / 0.14 where the scatter-add reads 0.57 /
+0.57 / 3.39 / 0.80 / 0.78, each over a floor of ~0.09), positions and group
+sizes come from a blockwise count (0.01), the weights are read where they
+lie, and the sort stays for ``order`` (its inverse as an int32 scatter read
+0.038).
 """
 
 import functools

@@ -6,20 +6,32 @@ from the installed jax at a few tilings, and as this repo's kernel
 (``ops/grouped_gemm.py``) at every row tile its shape rule could choose,
 each as milliseconds, as GB/s of the weights of the experts that have rows
 and, for the kernels, as rows multiplied a live row; beside them the other
-ops of the ``moe_experts`` scope (the sort, the ``tokens[tok_rows]`` gather,
-the weighted scatter-add), so that PERF.md can say what share of
-``*_moe_experts_ms`` the GEMMs are.  The routing is seeded and SKEWED (an
-expert's popularity is log-normal): uniform group sizes straddle fewer row
-tiles than a model's.  ``--parent <checkout>`` times that checkout's
+ops of the ``moe_experts`` scope, so that PERF.md can say what share of
+``*_moe_experts_ms`` the GEMMs are: the three the layer ran until PR 56 (the
+sort, the ``tokens[tok_rows]`` gather, the weighted scatter-add behind its
+mask pass) and the forms PR 56 chose among (an assignment's position from a
+blockwise count, from ``cumsum``, from ``argsort`` and its inverse; ``order``
+as an int32 scatter; the combine as a gather and a float32 sum over ``k``),
+each alone and as the whole dispatch-to-combine chain without the GEMMs.
+Those rows carry two times: ``ms``, one dispatch a call as the products are
+timed (every call carries ~0.2 ms of launch), and ``ms_in_program``, eight
+layers' worth on eight different routings unrolled in one program, an
+eighth of it (nearer what a layer of a step program pays; its own floor is
+~0.09 ms, and a traced step's op list, ``scripts/step0_moe_scope_ops.py``,
+is the reading without one).  The routing is seeded and SKEWED (an expert's
+popularity is log-normal): uniform group sizes straddle fewer row tiles than
+a model's.  ``--parent <checkout>`` times that checkout's
 ``ops/grouped_gemm.py`` beside this one at the rule's row tile, in the same
 call.
 
     git archive HEAD | tar -x -C _parent        # _parent/ is git-ignored
     chiprun --timeout 1800 -- python3 scripts/step0_grouped_gemm.py --parent _parent
+    chiprun -- python3 scripts/step0_grouped_gemm.py --skip-ffn   # the permutation alone, ~3 min
     JAX_PLATFORMS=cpu python3 scripts/step0_grouped_gemm.py --tiny   # here
 
-Writes ``chiprun_out/pr51/step0.jsonl`` (one line a timing) and
-``step0.md`` (the table ``ops/grouped_gemm.py`` quotes).
+Writes ``chiprun_out/pr56/step0.jsonl`` (one line a timing) and
+``step0.md`` (the table ``ops/grouped_gemm.py`` quotes; PR 51's is
+``chiprun_out/pr51/step0.md``).
 """
 
 import argparse
@@ -36,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import deepspeed_tpu.ops  # noqa: F401  (the package's function shadows the module's name)
+from deepspeed_tpu.moe import layer
 
 gg = sys.modules["deepspeed_tpu.ops.grouped_gemm"]
 
@@ -54,6 +67,7 @@ SHAPES = [
 ]
 TINY = [("tiny", "decode", 8, 2, 8, 4, 128, 256),
         ("tiny", "mixed", 64, 2, 8, 4, 128, 256)]
+LAYERS = 8      # routings unrolled in one program for ``ms_in_program``
 
 
 def timed(fn, *args, reps):
@@ -64,6 +78,14 @@ def timed(fn, *args, reps):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / reps * 1e3
+
+
+def in_program(fn, *stacked, reps):
+    """Milliseconds a layer of ``fn`` where ``LAYERS`` of them, each on its
+    own slice of the stacked arguments, are one program."""
+    return timed(jax.jit(lambda *st: [fn(*(a[i] for a in st))
+                                      for i in range(LAYERS)]),
+                 *stacked, reps=reps) / LAYERS
 
 
 def route(rng, S, k, routed, held):
@@ -142,7 +164,9 @@ def main():
     ap.add_argument("--corners", action="store_true",
                     help="only the corner cases, compiled, against ragged_dot")
     ap.add_argument("--reps", type=int, default=20)
-    ap.add_argument("--out", default="chiprun_out/pr51")
+    ap.add_argument("--skip-ffn", action="store_true",
+                    help="only the permutation's ops, not the products")
+    ap.add_argument("--out", default="chiprun_out/pr56")
     ap.add_argument("--parent", help="a checkout whose ops/grouped_gemm.py "
                     "is timed beside this one's")
     args = ap.parse_args()
@@ -194,32 +218,128 @@ def main():
         live = int(sizes.sum())
         touched = int((sizes > 0).sum())
         gb = touched * 3 * H * M * 2 / 1e9
-        tokens = jax.random.normal(jax.random.PRNGKey(1), (S, H), dtype)
-        tw = jnp.asarray(rng.random((S, k)), jnp.float32)
         base = dict(cell=cell, step=step, A=A, live=live, groups=held,
                     touched=touched, K=H, N=M, weights_gb=round(gb, 4),
                     floor_ms=round(gb / peak * 1e3, 4))
 
-        # the scope's other ops, as moe/layer.py runs them
-        order = jnp.argsort(flat)
-        tok_rows = jnp.repeat(jnp.arange(S), k)[order]
-        rows = tokens[tok_rows]
-        ms = timed(jax.jit(jnp.argsort), flat, reps=reps)
-        emit(**base, what="argsort", ms=ms)
-        ms = timed(jax.jit(lambda t, o: t[jnp.repeat(jnp.arange(S), k)[o]]),
-                   tokens, order, reps=reps)
-        emit(**base, what="gather", ms=ms)
-        o_rows = jax.random.normal(jax.random.PRNGKey(2), (A, H), dtype)
+        # the scope's other ops: ``LAYERS`` routings of the shape, a form
+        # alone on the first and all of them unrolled in one program
+        flats = jnp.stack([flat] + [jnp.asarray(route(
+            rng, S, k, routed, held).reshape(-1)) for _ in range(LAYERS - 1)])
+        toks = jax.random.normal(jax.random.PRNGKey(1), (LAYERS, S, H), dtype)
+        o_rows = jax.random.normal(jax.random.PRNGKey(2), (LAYERS, A, H), dtype)
+        tws = jnp.asarray(rng.random((LAYERS, S, k)), jnp.float32)
+        tokens = toks[0]
+        iota = jnp.arange(A, dtype=jnp.int32)
+        G = held + 1                            # the sentinel's column too
+        whole = routed == held                  # no assignment is dropped
 
-        def scatter(o, w, order, tok_rows):
-            done = jnp.arange(A) < live
+        def both(what, fn, *stacked):
+            emit(**base, what=what,
+                 ms=timed(jax.jit(fn), *(a[0] for a in stacked), reps=reps),
+                 ms_in_program=in_program(fn, *stacked, reps=reps))
+
+        def invert(perm):
+            return jnp.zeros((A,), jnp.int32).at[perm].set(
+                iota, unique_indices=True)
+
+        def pos_count(f):
+            return layer._positions_by_count(f, G)
+
+        def pos_cumsum(f):
+            hot = f[:, None] == jnp.arange(G, dtype=f.dtype)
+            upto = jnp.cumsum(hot.astype(jnp.int32), axis=0)
+            sizes = upto[-1]
+            starts = jnp.cumsum(sizes) - sizes
+            return jnp.sum(jnp.where(hot, upto - 1 + starts, 0), -1), sizes
+
+        def pos_argsort(f):
+            order = jnp.argsort(f)
+            sizes = jnp.zeros((G,), jnp.int32).at[f].add(1, mode="drop")
+            return invert(order), sizes, order
+
+        def gather_rows(t, o):
+            return t[jnp.repeat(jnp.arange(S), k)[o]]
+
+        def scatter_add(o, w, order, tok_rows, n):
+            done = iota < n
             o = jnp.where(done[:, None], o, 0)
             tr = jnp.where(done, tok_rows, S)
             ww = w.reshape(-1)[order].astype(o.dtype)
             return jnp.zeros((S, H), o.dtype).at[tr].add(o * ww[:, None],
                                                          mode="drop")
-        ms = timed(jax.jit(scatter), o_rows, tw, order, tok_rows, reps=reps)
-        emit(**base, what="scatter_add", ms=ms)
+
+        def combine(o, w, dest, f):
+            # as moe/layer.py: a gather of [S, H] a choice, summed in float32
+            dest, keep = dest.reshape(S, k), (f < held).reshape(S, k)
+            out = 0.0
+            for j in range(k):
+                got = o[dest[:, j]].astype(jnp.float32) * w[:, j:j + 1]
+                out = out + (got if whole else
+                             jnp.where(keep[:, j:j + 1], got, 0))
+            return out.astype(o.dtype)
+
+        def combine_one_gather(o, w, dest, f):
+            # ONE gather of all S*k rows, reshaped [S, k, H] and summed
+            got = (o[dest].reshape(S, k, H).astype(jnp.float32)
+                   * w[..., None])
+            if not whole:
+                got = jnp.where((f < held).reshape(S, k, 1), got, 0)
+            return jnp.sum(got, axis=1).astype(o.dtype)
+
+        def chain_parent(t, f, w):
+            order = jnp.argsort(f)
+            tok_rows = jnp.repeat(jnp.arange(S), k)[order]
+            sizes = jnp.zeros((held,), jnp.int32).at[f].add(1, mode="drop")
+            return scatter_add(t[tok_rows], w, order, tok_rows,
+                               jnp.sum(sizes)), sizes
+
+        def chain(positions):
+            def run(t, f, w):
+                dest, sizes, *order = positions(f)
+                order = order[0] if order else invert(dest)
+                return combine(t[order // k], w, dest, f), sizes[:held]
+            return run
+
+        def pos_count_argsort(f):
+            return (*pos_count(f), jnp.argsort(f))
+
+        orders = jax.jit(jax.vmap(jnp.argsort))(flats)
+        dests = jax.jit(jax.vmap(invert))(orders)
+        tok_rows = jnp.repeat(jnp.arange(S), k)[orders]
+        n_local = jnp.sum(flats < held, axis=1)
+        both("argsort", jnp.argsort, flats)
+        both("gather", gather_rows, toks, orders)
+        both("scatter_add", scatter_add, o_rows, tws, orders, tok_rows,
+             n_local)
+        both("positions_count", pos_count, flats)
+        both("positions_cumsum", pos_cumsum, flats)
+        both("positions_argsort_inverse", pos_argsort, flats)
+        both("order_scatter", invert, dests)
+        both("combine_gather", combine, o_rows, tws, dests, flats)
+        both("combine_one_gather", combine_one_gather, o_rows, tws, dests,
+             flats)
+        both("chain_parent", chain_parent, toks, flats, tws)
+        both("chain_count_scatter", chain(pos_count), toks, flats, tws)
+        both("chain_cumsum_scatter", chain(pos_cumsum), toks, flats, tws)
+        both("chain_count_argsort", chain(pos_count_argsort), toks, flats, tws)
+        both("chain_argsort_inverse", chain(pos_argsort), toks, flats, tws)
+        want = np.asarray(jax.jit(chain_parent)(tokens, flat, tws[0])[0],
+                          np.float32)
+        for name, positions in (("count", pos_count), ("cumsum", pos_cumsum),
+                                ("argsort", pos_argsort)):
+            dest, sz, *_ = jax.jit(positions)(flat)
+            got = np.asarray(jax.jit(chain(positions))(
+                tokens, flat, tws[0])[0], np.float32)
+            emit(**base, what="check_chain", positions=name,
+                 dest_is_the_stable_sorts=bool(
+                     (np.asarray(dest) == np.asarray(dests[0])).all()),
+                 sizes_equal=bool((np.asarray(sz[:held])
+                                   == np.asarray(sizes)).all()),
+                 max_abs_from_parent=float(np.abs(got - want).max()))
+        if args.skip_ffn:
+            continue
+        rows = tokens[tok_rows[0]]
 
         # the three products
         # the weights are ARGUMENTS: closed over, each compile would copy
@@ -289,8 +409,8 @@ def main():
     log.close()
     with open(os.path.join(args.out, "step0.md"), "w") as f:
         f.write("| cell | step | A | live | touched | floor ms | what | "
-                "impl | tiling | ms | GB/s | us an expert | rows multiplied "
-                "a live row |\n|" + " --- |" * 13 + "\n")
+                "impl | tiling | ms | ms in program | GB/s | us an expert | "
+                "rows multiplied a live row |\n|" + " --- |" * 14 + "\n")
         for ln in lines:
             if "ms" not in ln:
                 continue
@@ -300,7 +420,8 @@ def main():
             f.write(f"| {ln['cell']} | {ln['step']} | {ln['A']} | "
                     f"{ln['live']} | {ln['touched']} | {ln['floor_ms']} | "
                     f"{ln['what']} | {ln.get('impl', '')} | {tiling} | "
-                    f"{ln['ms']} | {ln.get('gbps', '')} | "
+                    f"{ln['ms']} | {ln.get('ms_in_program', '')} | "
+                    f"{ln.get('gbps', '')} | "
                     f"{ln.get('us_per_expert', '')} | "
                     f"{ln.get('rows_per_live_row', '')} |\n")
     print(json.dumps({"device": jax.devices()[0].device_kind,

@@ -11,7 +11,11 @@ forms (plain, and the gated first half against its two-product form), both
 dtypes, and every row tile the shape rule can choose.  Widths scaled down, N
 kept at 11 x 128 (its only column tiles are 128 and all of it).  Then the
 expert layer's function around it (``moe/layer.py:_expert_ffn_ragged``): no
-tail row reaches its result."""
+tail row reaches its result; its positions come from counts and are the
+stable sort's; it is the layer written densely, whole and as a share, with
+dead rows, one expert taking everything, ``k = 1``; in bfloat16 it is no
+further from float32 than the scatter-add it replaced (PR 56); and its
+lowered text holds one sort, of the ids, and no scatter."""
 
 import sys
 
@@ -21,7 +25,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu import ops
-from deepspeed_tpu.moe.layer import _expert_ffn_ragged
+from deepspeed_tpu.moe.layer import _expert_ffn_ragged, _positions_by_count
 from deepspeed_tpu.ops.registry import dispatch_log, reset_dispatch_log
 
 gg = sys.modules["deepspeed_tpu.ops.grouped_gemm"]
@@ -206,16 +210,198 @@ def test_no_tail_row_reaches_the_expert_layers_result(share):
 def _dense_expert_ffn(tokens, expert_idx, weights, wi, wo, wg=None, *,
                       expert_offset=0, num_experts=None, live=None,
                       with_stats=False, impl="xla"):
-    """What ``_expert_ffn_ragged`` computes, with no sort and no grouped
-    product: every held expert on every token, weighted by the routing."""
-    assert live is None and not with_stats
+    """What ``_expert_ffn_ragged`` computes, with no permutation and no
+    grouped product: every held expert on every token, weighted by the
+    routing; a row ``live`` masks out counts for nothing and gives 0."""
     E = wi.shape[0]
+    local = expert_idx - expert_offset                         # [S, k]
+    hot = local[..., None] == jnp.arange(E)                    # [S, k, E]
+    if live is not None:
+        tokens = jnp.where(live[:, None], tokens, 0)
+        hot = hot & live[:, None, None]
     h = jax.nn.silu(jnp.einsum("sh,ehm->esm", tokens, wg)) * jnp.einsum(
         "sh,ehm->esm", tokens, wi)
     y = jnp.einsum("esm,emh->esh", h, wo)
-    local = expert_idx - expert_offset                         # [S, k]
-    hot = (local[..., None] == jnp.arange(E)) * weights[..., None]
-    return jnp.einsum("se,esh->sh", hot.sum(1).astype(y.dtype), y)
+    out = jnp.einsum("se,esh->sh", (hot * weights[..., None]).sum(1).astype(
+        y.dtype), y)
+    if not with_stats:
+        return out
+    n_live = expert_idx.size if live is None else live.sum() * local.shape[1]
+    return out, jnp.stack([hot.sum(), n_live, hot.any((0, 1)).sum()]).astype(
+        jnp.int32)
+
+
+def _scatter_add_expert_ffn(tokens, expert_idx, weights, wi, wo, wg, *,
+                            expert_offset=0, live=None):
+    """The layer as it was until PR 56: ``argsort``, the rows gathered, the
+    products weighted in the rows' dtype and scatter-added into an
+    accumulator of that dtype behind a mask pass over the tail."""
+    S, k = expert_idx.shape
+    E = wi.shape[0]
+    local = expert_idx.reshape(-1) - expert_offset
+    keep = (local >= 0) & (local < E)
+    if live is not None:
+        keep = keep & jnp.repeat(live, k)
+    flat_e = jnp.where(keep, local, E)
+    order = jnp.argsort(flat_e)
+    tok_rows = jnp.repeat(jnp.arange(S), k)[order]
+    sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1, mode="drop")
+    o = ops.grouped_gemm(ops.grouped_gemm(tokens[tok_rows], wi, sizes, wg,
+                                          impl="xla"), wo, sizes, impl="xla")
+    done = jnp.arange(S * k) < jnp.sum(sizes)
+    o = jnp.where(done[:, None], o, 0)
+    w = weights.reshape(-1)[order].astype(o.dtype)
+    return jnp.zeros_like(tokens).at[jnp.where(done, tok_rows, S)].add(
+        o * w[:, None], mode="drop")
+
+
+@pytest.mark.parametrize("A,num,kind", [
+    (64, 33, "uniform"), (288, 65, "uniform"), (4096, 33, "share"),
+    (8192, 65, "uniform"), (100, 5, "uniform"), (129, 3, "one"),
+    (256, 17, "sentinel"), (1, 2, "one")])
+def test_positions_by_count_are_the_stable_sorts(A, num, kind):
+    """``dest`` is the inverse of ``argsort(ids, stable)`` and ``sizes`` the
+    count of each id: at the cells' buffer lengths, at lengths no block of
+    128 divides, with most entries the sentinel (a share), all of them, and
+    all entries one id."""
+    rng = np.random.default_rng(A + num)
+    ids = {"uniform": lambda: rng.integers(0, num, A),
+           "share": lambda: np.where(rng.random(A) < 0.125,
+                                     rng.integers(0, num - 1, A), num - 1),
+           "sentinel": lambda: np.full(A, num - 1),
+           "one": lambda: np.full(A, num // 2)}[kind]().astype(np.int32)
+    dest, sizes = jax.jit(_positions_by_count, static_argnums=1)(
+        jnp.asarray(ids), num)
+    assert dest.dtype == jnp.int32 and sizes.dtype == jnp.int32
+    want = np.empty(A, int)
+    want[np.argsort(ids, kind="stable")] = np.arange(A)
+    np.testing.assert_array_equal(np.asarray(dest), want)
+    np.testing.assert_array_equal(np.asarray(sizes),
+                                  np.bincount(ids, minlength=num))
+
+
+def _layer_case(kind, dtype=jnp.float32):
+    """tokens, routing, weights and keywords of one case of the expert
+    layer; dead rows' tokens are NaN, so whoever reads one shows it."""
+    S, H, M, k, routed = 24, 128, 256, 4, 16
+    held, offset, live = routed, 0, None
+    if kind == "k1":
+        k = 1
+    if kind in ("share", "dead_rows", "no_live_row", "ragged_tile"):
+        held, offset = 4, 4
+    if kind == "dead_rows":
+        live = jnp.arange(S) % 5 != 0
+    if kind == "no_live_row":
+        live = jnp.zeros((S,), bool)
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    tokens = jax.random.normal(ks[0], (S, H), jnp.float32)
+    if live is not None:
+        tokens = jnp.where(live[:, None], tokens, jnp.nan)
+    idx = jnp.stack([jax.random.permutation(kk, routed)[:k]
+                     for kk in jax.random.split(ks[1], S)])
+    if kind == "one_expert":
+        idx = jnp.full((S, k), 5)
+    if kind == "ragged_tile":
+        # 13 assignments held here, 3 + 9 + 1 + 0: no multiple of a row
+        # tile (8 rows in float32, 16 in bfloat16) and a group without rows
+        idx = jnp.full((S, k), 0).at[:3, 0].set(4).at[3:12, 1].set(5).at[
+            12, 2].set(6)
+    wts = jax.random.uniform(ks[2], (S, k))
+    wi = jax.random.normal(ks[3], (held, H, M)) * H ** -0.5
+    wg = jax.random.normal(ks[4], (held, H, M)) * H ** -0.5
+    wo = jax.random.normal(ks[5], (held, M, H)) * M ** -0.5
+    args = (tokens.astype(dtype), idx, wts) + tuple(
+        w.astype(dtype) for w in (wi, wo, wg))
+    return args, dict(expert_offset=offset, num_experts=routed, live=live)
+
+
+LAYER_KINDS = ["whole", "share", "dead_rows", "no_live_row", "one_expert",
+               "k1", "ragged_tile"]
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("kind", LAYER_KINDS)
+def test_expert_layer_is_the_dense_layer(kind, impl):
+    """``_expert_ffn_ragged`` against the layer written densely: finite,
+    exactly 0 on the rows that are not live, the same counters, and the
+    positions and group sizes it works from are the stable sort's."""
+    args, kw = _layer_case(kind)
+    idx, live = args[1], kw["live"]
+    got, stats = jax.jit(lambda *a: _expert_ffn_ragged(
+        *a, impl=impl, with_stats=True, **kw))(*args)
+    want, wstats = _dense_expert_ffn(*args, with_stats=True, **kw)
+    got = np.asarray(got)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), atol=3e-5)
+    np.testing.assert_array_equal(np.asarray(stats), np.asarray(wstats))
+    if live is not None:
+        dead = ~np.asarray(live)
+        assert not got[dead].any() and not np.signbit(got[dead]).any()
+    if kind == "no_live_row":
+        assert not got.any() and int(stats[0]) == 0
+    if kind == "ragged_tile":
+        assert int(stats[0]) == 13 and int(stats[2]) == 3
+    # what the layer permutes by
+    E = args[3].shape[0]
+    local = np.asarray(idx).reshape(-1) - kw["expert_offset"]
+    keep = (local >= 0) & (local < E)
+    if live is not None:
+        keep &= np.repeat(np.asarray(live), idx.shape[1])
+    flat_e = np.where(keep, local, E).astype(np.int32)
+    dest, sizes = _positions_by_count(jnp.asarray(flat_e), E + 1)
+    order = np.argsort(flat_e, kind="stable")
+    np.testing.assert_array_equal(np.asarray(dest)[order],
+                                  np.arange(flat_e.size))
+    np.testing.assert_array_equal(np.asarray(sizes)[:E],
+                                  np.bincount(flat_e, minlength=E + 1)[:E])
+    assert int(stats[0]) == keep.sum()
+
+
+@pytest.mark.parametrize("kind", ["whole", "share", "dead_rows", "k1"])
+def test_bf16_combine_is_no_further_from_float32_than_the_scatter_add(kind):
+    """Products and their sum over ``k`` are float32 with one rounding; the
+    scatter-add rounded each weighted product and the accumulator after each
+    add.  Against the float32 dense layer on the same bfloat16 values the
+    gather's error is at most the scatter-add's (the grouped products are
+    the same bits: same rows in the same order)."""
+    args, kw = _layer_case(kind, jnp.bfloat16)
+    got = np.asarray(_expert_ffn_ragged(*args, **kw), np.float32)
+    kw.pop("num_experts")
+    old = np.asarray(_scatter_add_expert_ffn(*args, **kw), np.float32)
+    f32 = tuple(a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a
+                for a in args)
+    want = np.asarray(_dense_expert_ffn(*f32, **kw))
+    rms = lambda d: float(np.sqrt((d ** 2).mean()))
+    assert rms(got - want) <= rms(old - want)
+
+
+@pytest.mark.parametrize("cell,S,k,routed,held,offset,H,M", [
+    ("lfm2-mixed", 2048, 4, 64, 64, 0, 2048, 1536),
+    ("dots3-mixed", 1024, 8, 256, 32, 32, 5120, 1536),
+    ("moonlight-decode", 48, 6, 64, 64, 0, 2048, 1408)])
+def test_step_programs_expert_fn_has_one_sort_and_no_row_scatter(
+        cell, S, k, routed, held, offset, H, M):
+    """The lowered text of the serving step programs' ``_experts_fn`` at a
+    cell's shape (PR 56): no ``scatter`` at all (the combine is a gather,
+    the group sizes are counted and not scatter-added), and ONE ``sort``,
+    over the int32 ids (``order``; step 0 found it cheaper than the int32
+    scatter that would invert ``dest``)."""
+    import re
+    import types
+    from deepspeed_tpu.inference.v2.model import _experts_fn
+    cfg = types.SimpleNamespace(expert_offset=offset, num_experts=routed)
+    sds = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    text = _experts_fn(cfg, True).lower(
+        sds((S, H), bf), sds((S, k), jnp.int32), sds((S, k), jnp.float32),
+        sds((held, H, M), bf), sds((held, M, H), bf), sds((held, H, M), bf),
+        live=sds((S,), jnp.bool_)).as_text()
+    assert '"stablehlo.scatter"(' not in text
+    sorts = re.findall(r'"stablehlo\.sort"\(.*?\) -> \((.*?)\)\n', text,
+                       flags=re.S)
+    assert sorts == [f"tensor<{S * k}xi32>, tensor<{S * k}xi32>"], sorts
+    # the rows out in one gather, the products back in one a choice
+    assert text.count('"stablehlo.gather"(') == 1 + k
 
 
 @pytest.mark.parametrize("router", ["softmax", "sigmoid"])
