@@ -810,6 +810,207 @@ def granite_hybrid_state_dict(cfg, params) -> Dict[str, Any]:
     return out
 
 
+# minicpm_sala (openbmb MiniCPM-SALA): published tensor name -> path in the GPT
+# parameter tree, the names the loader (``_minicpm_sala_tree``) reads and
+# ``minicpm_sala_state_dict`` writes back, held to each other in
+# tests/test_minicpm_sala.py on a seeded tiny state dict (no published
+# weights are in the repository; the names are the MiniCPM family's
+# modelling code's as remembered, the gate's and the output norm's ASSUMED).
+# A lightning layer's q, k, v and gate projections are four published
+# matrices and ONE here (``w_in`` = [q | k | v | gate]); the head is untied.
+MINICPM_SALA_WEIGHT_NAMES = {
+    "model.embed_tokens.weight": "backbone/wte",
+    "model.norm.weight": "backbone/final_norm/scale",
+    "lm_head.weight": "lm_head",
+    "model.layers.{i}.input_layernorm.weight": "backbone/block_{i}/Norm_0/scale",
+    "model.layers.{i}.post_attention_layernorm.weight":
+        "backbone/block_{i}/Norm_1/scale",
+    "model.layers.{i}.mlp.gate_proj.weight": "backbone/block_{i}/MLP_0/wg",
+    "model.layers.{i}.mlp.up_proj.weight": "backbone/block_{i}/MLP_0/wi",
+    "model.layers.{i}.mlp.down_proj.weight": "backbone/block_{i}/MLP_0/wo",
+    # both kinds of layer (mixer_types[i]): "minicpm4" -> Attention_0's wq,
+    # wk, wv, wo, wgate, q_norm, k_norm; "lightning-attn" -> LightningMixer_0's
+    # w_in (q, k, v, o_gate side by side), w_out, q_norm, k_norm, norm
+    "model.layers.{i}.self_attn.q_proj.weight": "backbone/block_{i}/*/wq|w_in",
+    "model.layers.{i}.self_attn.k_proj.weight": "backbone/block_{i}/*/wk|w_in",
+    "model.layers.{i}.self_attn.v_proj.weight": "backbone/block_{i}/*/wv|w_in",
+    "model.layers.{i}.self_attn.o_gate.weight":
+        "backbone/block_{i}/*/wgate|w_in",
+    "model.layers.{i}.self_attn.o_proj.weight":
+        "backbone/block_{i}/*/wo|w_out",
+    "model.layers.{i}.self_attn.q_norm.weight": "backbone/block_{i}/*/q_norm",
+    "model.layers.{i}.self_attn.k_norm.weight": "backbone/block_{i}/*/k_norm",
+    "model.layers.{i}.self_attn.o_norm.weight":
+        "backbone/block_{i}/LightningMixer_0/norm",
+}
+
+# MiniCPM4's published sparse_config (InfLLM-V2), which MiniCPM-SALA's
+# config.json does not repeat: taken where the config is silent
+MINICPM_SPARSE_DEFAULTS = {"kernel_size": 32, "kernel_stride": 16,
+                           "block_size": 64, "topk": 64, "window_size": 2048,
+                           "init_blocks": 1, "dense_len": 8192}
+
+
+def minicpm_sala_config(hf: Dict[str, Any], *,
+                        max_seq_len: Optional[int] = None, dtype=None):
+    """GPTConfig of a published ``minicpm_sala`` ``config.json``: lightning
+    attention layers (a matrix state a head under the fixed ALiBi-slope
+    decay, q/k norms and RoPE inside, a norm a head and a sigmoid gate on
+    the way out) beside ``minicpm4`` attention layers (GQA without RoPE,
+    q/k norms, an output gate, a selection of blocks from pooled keys) by
+    ``mixer_types``, a SwiGLU in every layer, an untied head and MiniCPM's
+    three multipliers.  ``layers_kept`` (a benchmark file's cut) reads
+    ``mixer_types`` at those layers; ``sparse_config`` is taken where given
+    and MiniCPM4's published sizes where the config is silent."""
+    from deepspeed_tpu.models.gpt import GPTConfig
+    kinds = {"minicpm4": "attention", "lightning-attn": "lightning"}
+    unknown = sorted(set(hf["mixer_types"]) - set(kinds))
+    for key, ok, what in (
+            ("mixer_types", not unknown, f"mixers {unknown}"),
+            ("hidden_act", hf.get("hidden_act", "silu") == "silu",
+             "an activation other than silu"),
+            ("attention_bias", not hf.get("attention_bias", False),
+             "attention biases"),
+            ("qk_norm", hf.get("qk_norm", True), "heads without q/k norms"),
+            ("lightning_scale",
+             hf.get("lightning_scale", "1/sqrt(d)") == "1/sqrt(d)",
+             "a lightning scale other than 1/sqrt(d)"),
+            ("lightning_nkv", hf["lightning_nkv"] == hf["lightning_nh"],
+             "lightning keys shared between heads"),
+            ("use_output_gate", hf.get("use_output_gate", True)
+             and hf.get("use_output_norm", True),
+             "a lightning layer without its output norm or gate"),
+            ("attn_use_output_gate", hf.get("attn_use_output_gate", True),
+             "an attention layer without its output gate"),
+            ("attn_use_rope", not hf.get("attn_use_rope", False)
+             or hf.get("lightning_use_rope", True),
+             "RoPE on the attention layers alone"),
+            ("tie_word_embeddings", not hf.get("tie_word_embeddings", False),
+             "a tied head")):
+        if not ok:
+            raise NotImplementedError(
+                f"minicpm_sala: {key}={hf.get(key)!r}: {what} is not built")
+    sparse = {**MINICPM_SPARSE_DEFAULTS, **hf.get("sparse_config", {})}
+    extra = sorted(set(sparse) - set(MINICPM_SPARSE_DEFAULTS))
+    if extra:
+        raise NotImplementedError(
+            f"minicpm_sala: sparse_config keys {extra} are not known")
+    kept = hf.get("layers_kept", range(len(hf["mixer_types"])))
+    layer_types = tuple(kinds[hf["mixer_types"][i]] for i in kept)
+    if hf["num_hidden_layers"] != len(layer_types):
+        raise ValueError(
+            f"minicpm_sala: num_hidden_layers {hf['num_hidden_layers']} but "
+            f"{len(layer_types)} layers kept of mixer_types")
+    depth = hf.get("published", {}).get("num_hidden_layers",
+                                        len(hf["mixer_types"]))
+    rope = ("all" if hf.get("attn_use_rope", False) else
+            "state" if hf.get("lightning_use_rope", True) else "none")
+    msl = hf.get("max_position_embeddings", 2048)
+    return GPTConfig(
+        vocab_size=hf["vocab_size"], num_layers=len(layer_types),
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"], head_dim=hf["head_dim"],
+        hidden_size=hf["hidden_size"],
+        mlp_dim_override=hf["intermediate_size"],
+        max_seq_len=min(msl, max_seq_len or msl),
+        use_rope=True, rope_layers=rope,
+        rope_theta=float(hf.get("rope_theta", 10000.0)), use_rmsnorm=True,
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)), gated_mlp=True,
+        tie_embeddings=False, qk_norm=True, attn_gate=True,
+        layer_types=layer_types, ssm_heads=hf["lightning_nh"],
+        ssm_head_dim=hf["lightning_head_dim"],
+        ssm_state=hf["lightning_head_dim"], ssm_groups=hf["lightning_nkv"],
+        ssm_chunk=128, embed_scale=float(hf.get("scale_emb", 1.0)),
+        residual_scale=float(hf.get("scale_depth", 1.0)) / depth ** 0.5,
+        logits_divisor=hf["hidden_size"] / float(hf["dim_model_base"]),
+        block_topk=sparse["topk"], block_size=sparse["block_size"],
+        block_kernel=sparse["kernel_size"],
+        block_stride=sparse["kernel_stride"],
+        block_window=sparse["window_size"], block_init=sparse["init_blocks"],
+        block_dense_len=sparse["dense_len"], dtype=dtype or jnp.bfloat16)
+
+
+def _minicpm_sala_tree(r, cfg) -> Dict[str, Any]:
+    """minicpm_sala -> flax tree, by ``MINICPM_SALA_WEIGHT_NAMES``; ``r`` has
+    ``get(name)`` (a ``_ShardReader``, or any mapping of published names to
+    arrays)."""
+    H = cfg.hidden_size
+    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+
+    def lin(name):                       # torch Linear: [out, in]
+        return np.asarray(r.get(name)).T
+
+    def vec(name):
+        return np.asarray(r.get(name))
+
+    bb: Dict[str, Any] = {
+        "wte": vec("model.embed_tokens.weight"),
+        "final_norm": {"scale": vec("model.norm.weight")}}
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        a = p + "self_attn."
+        blk: Dict[str, Any] = {
+            "Norm_0": {"scale": vec(p + "input_layernorm.weight")},
+            "Norm_1": {"scale": vec(p + "post_attention_layernorm.weight")},
+            "MLP_0": {"wg": lin(p + "mlp.gate_proj.weight"),
+                      "wi": lin(p + "mlp.up_proj.weight"),
+                      "wo": lin(p + "mlp.down_proj.weight")}}
+        norms = {"q_norm": vec(a + "q_norm.weight"),
+                 "k_norm": vec(a + "k_norm.weight")}
+        if cfg.is_scan_layer(i):
+            blk["LightningMixer_0"] = {
+                "w_in": np.concatenate(
+                    [lin(a + f"{k}.weight")
+                     for k in ("q_proj", "k_proj", "v_proj", "o_gate")], 1),
+                "w_out": lin(a + "o_proj.weight"),
+                "norm": vec(a + "o_norm.weight"), **norms}
+        else:
+            blk["Attention_0"] = {
+                "wq": lin(a + "q_proj.weight").reshape(H, nh, hd),
+                "wk": lin(a + "k_proj.weight").reshape(H, nkv, hd),
+                "wv": lin(a + "v_proj.weight").reshape(H, nkv, hd),
+                "wgate": lin(a + "o_gate.weight").reshape(H, nh, hd),
+                "wo": lin(a + "o_proj.weight").reshape(nh, hd, H), **norms}
+        bb[f"block_{i}"] = blk
+    return {"backbone": bb, "lm_head": lin("lm_head.weight")}
+
+
+def minicpm_sala_state_dict(cfg, params) -> Dict[str, Any]:
+    """The GPT parameter tree of a minicpm_sala model under its published
+    tensor names and shapes: ``_minicpm_sala_tree``'s inverse."""
+    bb = params["backbone"]
+    H, inner = cfg.hidden_size, cfg.ssm_inner
+    out = {"model.embed_tokens.weight": np.asarray(bb["wte"]),
+           "model.norm.weight": np.asarray(bb["final_norm"]["scale"]),
+           "lm_head.weight": np.asarray(params["lm_head"]).T}
+    for i in range(cfg.num_layers):
+        blk, p = bb[f"block_{i}"], f"model.layers.{i}."
+        a, mlp = p + "self_attn.", blk["MLP_0"]
+        out[p + "input_layernorm.weight"] = np.asarray(blk["Norm_0"]["scale"])
+        out[p + "post_attention_layernorm.weight"] = np.asarray(
+            blk["Norm_1"]["scale"])
+        for theirs, ours in (("gate_proj", "wg"), ("up_proj", "wi"),
+                             ("down_proj", "wo")):
+            out[f"{p}mlp.{theirs}.weight"] = np.asarray(mlp[ours]).T
+        if cfg.is_scan_layer(i):
+            s = blk["LightningMixer_0"]
+            w_in = np.asarray(s["w_in"])
+            for n, k in enumerate(("q_proj", "k_proj", "v_proj", "o_gate")):
+                out[f"{a}{k}.weight"] = w_in[:, n * inner:(n + 1) * inner].T
+            out[a + "o_proj.weight"] = np.asarray(s["w_out"]).T
+            out[a + "o_norm.weight"] = np.asarray(s["norm"])
+        else:
+            s = blk["Attention_0"]
+            for theirs, ours in (("q_proj", "wq"), ("k_proj", "wk"),
+                                 ("v_proj", "wv"), ("o_gate", "wgate")):
+                out[f"{a}{theirs}.weight"] = np.asarray(s[ours]).reshape(
+                    H, -1).T
+            out[a + "o_proj.weight"] = np.asarray(s["wo"]).reshape(-1, H).T
+        out[a + "q_norm.weight"] = np.asarray(s["q_norm"])
+        out[a + "k_norm.weight"] = np.asarray(s["k_norm"])
+    return out
+
+
 # lfm2_moe (LiquidAI LFM2-MoE): published tensor name -> path in the GPT
 # parameter tree, the names the loader (``_lfm2_moe_tree``) reads and
 # ``lfm2_moe_state_dict`` writes back, held to each other in
@@ -1022,6 +1223,8 @@ def config_from_hf(model_path: str, *, max_seq_len: Optional[int] = None,
         return granite_hybrid_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     if hf.get("model_type") == "lfm2_moe":
         return lfm2_moe_config(hf, max_seq_len=max_seq_len, dtype=dtype)
+    if hf.get("model_type") == "minicpm_sala":
+        return minicpm_sala_config(hf, max_seq_len=max_seq_len, dtype=dtype)
     arch = _arch_of(hf)
 
     if arch in _LLAMA_LIKE:
@@ -2376,6 +2579,8 @@ def load_hf_checkpoint(model_path: str, *, max_seq_len: Optional[int] = None,
         return cfg, _deepseek_v3_tree(_ShardReader(model_path), cfg)
     if cfg.conv_layers:
         return cfg, _lfm2_moe_tree(_ShardReader(model_path), cfg)
+    if "lightning" in cfg.layer_types:
+        return cfg, _minicpm_sala_tree(_ShardReader(model_path), cfg)
     if cfg.layer_types:
         return cfg, _granite_hybrid_tree(_ShardReader(model_path), cfg)
     if cfg.moe_router == "sigmoid":

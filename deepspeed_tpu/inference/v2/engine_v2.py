@@ -557,6 +557,34 @@ class InferenceEngineV2:
                     raise NotImplementedError(
                         f"{noun} layers (layer_types) keep {state} a "
                         f"sequence, which is not built with {why}")
+        if model_cfg.block_topk:
+            # a selection by blocks (ops/block_select.py): pooled keys page
+            # for page beside plain GQA pages (model.py PagedKVCache.ki),
+            # rows past block_dense_len choosing their blocks a KV head.
+            # What is not built beside it:
+            for what, why in (
+                    (sm.prefix_cache, "the prefix cache: a shared prefix's "
+                     "pooled keys would have to be shared page for page "
+                     "with its keys, and the one whose span crosses into "
+                     "the first private page completed there"),
+                    (draft_model is not None, "speculative decoding: a "
+                     "draft run's rows would each need their own choice of "
+                     "blocks, and the verify core attends densely"),
+                    (self.mesh is not None, "a tp mesh: the pooled keys and "
+                     "the choice a KV head are not sharded with the kv "
+                     "heads"),
+                    (sm.kv_quant, "kv_quant: int8 keys would move the "
+                     "pooled keys and with them the choice"),
+                    (self.config.adapters.enabled, "LoRA adapter pages: not "
+                     "tested over a selecting layer"),
+                    (self.kv_window or model_cfg.mla, "window page groups "
+                     "or latent pages: the pooled keys lie beside the one "
+                     "plain page group only")):
+                if what:
+                    raise NotImplementedError(
+                        f"a selection by blocks (block_topk) keeps pooled "
+                        f"keys beside its pages, which is not built with "
+                        f"{why}")
         if model_cfg.hc:
             # a multi-stream residual (hc_mult: rows [N, streams, H] that
             # every sublayer reads and writes through its hyper-connection,
@@ -793,10 +821,14 @@ class InferenceEngineV2:
         self.heartbeat_fn = None
         self._block_size = eff_bs
         self._one_table_width = bool(model_cfg.index_topk
+                                     or model_cfg.block_topk
                                      or model_cfg.state_layers
                                      or model_cfg.hc)            # _buckets
         self.telemetry.set_kv_bytes_per_token(
             self.kv_bytes_per_token(), **self.kv_bytes_by_group())
+        if model_cfg.block_topk:
+            self.telemetry.set_block_selection(
+                len(model_cfg.attention_layers))
         if model_cfg.state_layers:
             c = self.cache
             self.telemetry.set_scan_state(
@@ -1297,6 +1329,9 @@ class InferenceEngineV2:
             if new is not None:
                 note.update(sel_pairs_one_row=int(kept[q == 1].sum()),
                             sel_reach=reach)
+        geo = getattr(self.model_config, "block_geometry", None)
+        if geo is not None:
+            note.update(self._block_note(contexts, new, steps, geo))
         if new is not None:
             # sum over i < q of (c + 1 + i); the one-row slots' part of it
             # (they go to the paged decode kernel) is their contexts + count
@@ -1320,6 +1355,57 @@ class InferenceEngineV2:
                 (rising * contexts + rising * (rising + 1) // 2
                  + (q - rising) * win).sum())
         return note
+
+    def _block_note(self, contexts, new, steps: int, geo) -> Dict[str, int]:
+        """A dispatch's part in a selection by blocks
+        (``GPTConfig.block_topk``), counted on the host by the program's own
+        rule (``ops/block_select.py``): a row at position ``t`` takes the
+        dense path while ``t + 1 <= dense_len`` and past it keeps ``topk``
+        blocks a KV head, its own block as far as ``t`` and the others
+        whole, after scoring the pooled keys it can see.  Counted into the
+        running totals over the selecting layers
+        (``ServingTelemetry.block_rows``, and ``index_pairs`` with the
+        pooled pairs scored, the pairs kept and the causal pairs) and
+        returned as the span's arguments for ONE selecting layer:
+        ``blk_pairs_step`` (kept pairs of the selecting rows),
+        ``blk_pairs_one_row`` (the one-row slots' part), ``blk_pooled_pairs``
+        (pooled keys scored by the rows of prompt chunks),
+        ``blk_ctx_chunk`` / ``blk_pooled_chunk`` (the contexts after the
+        step, and the pooled keys in sight, of the chunks that select)."""
+        q = (np.asarray(new, np.int64) if new is not None
+             else np.full(len(contexts), steps, np.int64))
+        dense = sparse = kept = kept_one = pooled = pooled_chunk = 0
+        ctx_chunk = keys_chunk = dense_pairs = 0
+        for c, n in zip(contexts.tolist(), q.tolist()):
+            t = np.arange(c, c + n, dtype=np.int64)      # the rows' positions
+            sel = t + 1 > geo.dense_len
+            dense += int(n - sel.sum())
+            dense_pairs += int((t[~sel] + 1).sum())  # they keep what is causal
+            if not sel.any():
+                continue
+            t = t[sel]
+            sparse += len(t)
+            pairs = int(((geo.topk - 1) * geo.block + t % geo.block
+                         + 1).sum())
+            seen = int(((t - (geo.kernel - 1)) // geo.stride + 1).sum())
+            kept += pairs
+            pooled += seen
+            if n == 1 or new is None:   # (a burst: a row a slot a step)
+                kept_one += pairs
+            else:
+                pooled_chunk += seen
+                ctx_chunk += c + n
+                keys_chunk += (c + n - (geo.kernel - 1)) // geo.stride + 1
+        causal = int((q * contexts + q * (q + 1) // 2).sum())
+        layers = len(self.model_config.attention_layers)
+        self.telemetry.block_rows(layers * dense, layers * sparse,
+                                  layers * sparse * geo.topk)
+        self.telemetry.index_pairs(layers * pooled,
+                                   layers * (kept + dense_pairs),
+                                   layers * causal)
+        return dict(blk_pairs_step=kept, blk_pairs_one_row=kept_one,
+                    blk_pooled_pairs=pooled_chunk, blk_ctx_chunk=ctx_chunk,
+                    blk_pooled_chunk=keys_chunk, blk_sparse_rows_step=sparse)
 
     def _run_spec(self, reqs, outer: int, gamma: int, gen, prev, rng):
         """One fused draft-and-verify dispatch over the running set, then ONE
@@ -1689,6 +1775,11 @@ class InferenceEngineV2:
         from its own pool's geometry, and its index keys
         (``index_bytes_per_token``)."""
         c = self.cache
+        if c.kw is None and c.ki is not None:
+            # a selection by blocks: the pooled keys beside the one group
+            return {"index_bytes_per_token": int(
+                c.ki.size * c.ki.dtype.itemsize
+                // (c.ki.shape[1] * self._block_size))}
         if c.kw is None:
             return {}
         mc = self.model_config

@@ -144,7 +144,11 @@ class PagedKVCache(NamedTuple):
     INDEX-KEY pool of the global layers where they select their keys
     (``cfg.index_topk``): ``[1, pages, 1, block_size, index_head_dim]``,
     page for page beside ``k`` and addressed by the global group's own block
-    table: a third array, no third allocator group.
+    table: a third array, no third allocator group.  In a model whose
+    attention layers select by BLOCKS (``cfg.block_topk``, ordinary heads, one
+    page group) ``ki`` holds their POOLED keys instead: ``[attention layers,
+    pages, block_size / stride, nkv, head_dim]``, pooled key ``j`` of a
+    sequence in the page its span begins in (``ops/block_select.py``).
 
     State layers (``cfg.is_state_layer``: Mamba-2 scan layers, gated short
     convolutions) write no pages: ``k``/``v`` hold the attention layers only
@@ -161,7 +165,8 @@ class PagedKVCache(NamedTuple):
     only where layers scan (a recurrence of thousands of steps rounds at
     every one; ``k`` heads side by side on the lanes, ``ops/ssm_scan.py``
     "the packed state pool": 64 heads of 64 over a state of 128 are ``[32,
-    128, 128]``).  A slot is never cleared: a sequence's row at position 0
+    128, 128]``; a lightning layer's 32 heads of 128 over 128 too, and it has
+    no ``conv`` part).  A slot is never cleared: a sequence's row at position 0
     starts from zero whatever the slot held (``_scan_plan``), which is also
     how a preempted sequence is recomputed.
 
@@ -199,9 +204,10 @@ class PagedKVCache(NamedTuple):
                     "scan or conv layers beside kv_quant or latent pages are "
                     "not built")
             n = len(cfg.state_layers)
-            scan = dict(conv=jnp.zeros(
-                (n, slots, (mixer.taps(cfg) - 1) * mixer.channels(cfg)),
-                dtype))
+            if mixer.conv_scope is not None:   # (a lightning layer: none)
+                scan = dict(conv=jnp.zeros(
+                    (n, slots, (mixer.taps(cfg) - 1) * mixer.channels(cfg)),
+                    dtype))
             if cfg.scan_layers:
                 from deepspeed_tpu.ops.ssm_scan import packed_state_shape
                 scan["ssm"] = jnp.zeros((n, slots) + packed_state_shape(
@@ -219,6 +225,21 @@ class PagedKVCache(NamedTuple):
         else:
             shape = (layers, num_blocks, cfg.kv_heads, block_size,
                      cfg.head_dim)
+        if cfg.block_topk:
+            # a selection by blocks: the pooled keys page for page beside
+            # the keys (class docstring, ``ki``)
+            geo = cfg.block_geometry
+            if (quant is not None or kv_major_layout(cfg)
+                    or block_size % geo.block or block_size % geo.stride):
+                raise NotImplementedError(
+                    f"a selection by blocks (block_topk) keeps pooled keys "
+                    f"beside row-major unquantised pages that hold whole "
+                    f"blocks: not built with kv_quant, heads that are no "
+                    f"multiple of 128 wide, or a page of {block_size} "
+                    f"positions under blocks of {geo.block}")
+            scan["ki"] = jnp.zeros(
+                (layers, num_blocks, block_size // geo.stride, cfg.kv_heads,
+                 cfg.head_dim), dtype)
         if quant is None:
             vshape = shape
             if cfg.value_dim != cfg.head_dim:      # a value of its own width
@@ -1173,6 +1194,205 @@ def _mixed_attention(q, rows: _MixedRows, pages: _LayerPages, *,
         return jnp.where(valid[:, None, None], o, 0)
 
 
+# ------------------------------------------------------- selection by blocks
+# An attention layer that selects its keys by BLOCKS (``cfg.block_topk``,
+# ops/block_select.py): plain GQA pages and, beside them, the pooled keys
+# (``PagedKVCache.ki``).  Rows whose context is within ``block_dense_len``
+# read every key through the two paged kernels, as any layer's do; rows past
+# it choose ``block_topk`` blocks a KV head.  Rows under and over ride in one
+# step and one program: the choice is by data.  Scopes, all inside
+# ``attn_kernel``: ``attn_index`` (the pooled keys' upkeep, the block scores,
+# the choice and its bits), ``block_attention`` (attention over the kept
+# blocks, whichever way a row reads them).
+
+BLOCK_SCORE_ROWS = 128    # rows of one slot a pass of the block scores: the
+#                           [rows, heads, pooled keys] products stay ~70 MB
+
+
+def _pooled_upkeep(k_pages, ki, table, row_slot, row_pos, geo):
+    """The pooled keys the step's rows complete, from keys already in the
+    pages (the step's own among them), into ``ki``: ``table [S, MB]`` with
+    the layer's first page added, ``row_slot [R]`` (``S``: a pad row)."""
+    from deepspeed_tpu.ops import block_select
+    S = table.shape[0]
+    mine = table[jnp.minimum(row_slot, S - 1)]
+    new, j, done = block_select.completed_pooled_keys(k_pages, mine, row_pos,
+                                                      geo)
+    return block_select.write_pooled_keys(ki, new, j, done & (row_slot < S),
+                                          mine)
+
+
+def _block_rows_one(qg, pages: _LayerPages, pos, sparse, cfg: GPTConfig):
+    """One row a slot past ``block_dense_len``: block scores over the slot's
+    pooled keys, the kept blocks as a list, attention over those blocks and
+    no others (``block_select.kept_block_table``).  ``qg [S, nkv, g, d]`` at
+    ``pos [S]``; a slot that is not ``sparse`` reads zeros."""
+    from deepspeed_tpu import ops
+    from deepspeed_tpu.ops import block_select
+    geo, scale = cfg.block_geometry, _attn_scale(cfg) or cfg.head_dim ** -0.5
+    S, nkv = qg.shape[:2]
+    with jax.named_scope("attn_index"):
+        marked = block_select.mark_blocks(ops.block_scores(
+            qg, block_select.slot_pooled_keys(pages.ki, pages.table), pos,
+            geo=geo, scale=scale, impl=cfg.attn_impl), pos, geo)
+        blocks = ops.index_select(
+            marked.reshape(S * nkv, -1), geo.topk,
+            impl=cfg.attn_impl).reshape(S, nkv, geo.topk)
+    with jax.named_scope("block_attention"):
+        # each kv head of a slot as a sequence of its own over pages of one
+        # block: the paged decode kernel reads the kept blocks and no others
+        kept, lens = block_select.kept_block_table(
+            pages.table, blocks, pos, sparse, pages.k.shape[2], geo)
+        g, d = qg.shape[2:]
+        return ops.paged_attention(
+            qg.astype(cfg.dtype).reshape(S * nkv, 1, g, d),
+            block_select.block_pages(pages.k, geo),
+            block_select.block_pages(pages.v, geo), kept, lens, scale=scale,
+            kv_major=False, impl=cfg.attn_impl).reshape(qg.shape)
+
+
+def _block_one_row(qg, pages: _LayerPages, pos, lens, cfg: GPTConfig, mesh):
+    """One row a slot of a layer that selects by blocks, ``lens [S]`` its
+    context (0: the slot has no such row): within ``block_dense_len`` the
+    paged decode kernel over the whole context, past it ``_block_rows_one``
+    (a branch no step takes whose slots are all within it)."""
+    from deepspeed_tpu import ops
+    from deepspeed_tpu.ops import block_select
+    sparse = (lens > 0) & block_select.selects(pos, cfg.block_geometry)
+    o = ops.paged_attention(
+        qg, pages.k, pages.v, pages.table, jnp.where(sparse, 0, lens),
+        scale=_attn_scale(cfg), mesh=mesh, kv_major=False,
+        impl=cfg.attn_impl)
+    o_kept = jax.lax.cond(
+        jnp.any(sparse),
+        lambda: _block_rows_one(qg, pages, pos, sparse, cfg),
+        lambda: jnp.zeros_like(o))
+    return jnp.where(sparse[:, None, None, None], o_kept, o)
+
+
+def _block_chunk_bits(qg, rows: "_MixedRows", pages: _LayerPages, row_pos,
+                      chunk_row, cfg: GPTConfig):
+    """The bits of a mixed step's prompt chunks: for every row of the flat
+    batch and each KV head, the key positions of its sequence it keeps beside
+    what is causal, ``[nkv, N / 32, MB * bs]`` int32 as the masked prefill
+    kernel takes them (a row that does not select, or is no chunk's, keeps
+    everything).  The block scores are computed ``BLOCK_SCORE_ROWS`` rows of
+    one slot at a time, over the slots with more than one row whose context
+    passes ``block_dense_len`` (a loop whose trip count the device reads)."""
+    from deepspeed_tpu import ops
+    from deepspeed_tpu.ops import block_select
+    from deepspeed_tpu.ops.sparse_index import _pack_rows
+    geo, scale = cfg.block_geometry, _attn_scale(cfg) or cfg.head_dim ** -0.5
+    table = pages.table
+    S, MB = table.shape
+    N, nkv = qg.shape[:2]
+    bs = pages.k.shape[2]
+    NB = MB * bs // geo.block
+    TQ = BLOCK_SCORE_ROWS
+    with jax.named_scope("attn_index"):
+        counts = jnp.where((rows.q_counts > 1)
+                           & (rows.kv_len > geo.dense_len), rows.q_counts, 0)
+        passes = -(-counts // TQ)
+        ends = jnp.cumsum(passes)
+        qp = jnp.pad(qg, ((0, TQ), (0, 0), (0, 0), (0, 0)))
+        pp = jnp.pad(row_pos, (0, TQ))
+
+        # a slot's pooled keys are scored over the narrowest of a few
+        # shares of the table that holds its context (a branch a share,
+        # taken at run time: a context of 16 k pays for 16 k, not for 66 k)
+        shares = [MB]
+        while shares[-1] % 2 == 0 and len(shares) < 3:
+            shares.append(shares[-1] // 2)
+
+        def over(mb):
+            def scored(qb, pb, mine):
+                got = ops.block_scores(
+                    qb, block_select.slot_pooled_keys(pages.ki,
+                                                      mine[None, :mb]),
+                    pb, geo=geo, scale=scale, impl=cfg.attn_impl)
+                return jnp.pad(got, ((0, 0), (0, 0),
+                                     (0, NB - got.shape[-1])))
+            return scored
+
+        def one_pass(i, out):
+            s = jnp.minimum(jnp.searchsorted(ends, i, side="right"),
+                            S - 1).astype(jnp.int32)
+            j = i - (ends[s] - passes[s])
+            start = rows.first_row[s] + j * TQ
+            live = jnp.arange(TQ) < counts[s] - j * TQ
+            held = -(-rows.kv_len[s] // bs)              # pages in sight
+            got = jax.lax.switch(
+                jnp.sum(jnp.asarray(shares, jnp.int32) >= held) - 1,
+                [over(mb) for mb in shares],
+                jax.lax.dynamic_slice_in_dim(qp, start, TQ),
+                jax.lax.dynamic_slice_in_dim(pp, start, TQ), table[s])
+            old = jax.lax.dynamic_slice_in_dim(out, start, TQ)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, jnp.where(live[:, None, None], got, old), start, 0)
+
+        scores = jax.lax.fori_loop(
+            0, ends[-1], one_pass,
+            jnp.zeros((N + TQ, nkv, NB), jnp.float32))[:N]
+        marked = block_select.mark_blocks(scores, row_pos, geo)
+        marked = jnp.pad(jnp.moveaxis(marked, 1, 0).reshape(nkv * N, NB),
+                         ((0, 0), (0, -NB % 128)),
+                         constant_values=-jnp.inf)
+        words = ops.threshold_mask(marked, geo.topk, impl=cfg.attn_impl)
+        words = words.reshape(nkv, N // 32, -1)[..., :NB]
+        # a row that does not select keeps every block
+        every = _pack_rows((~(chunk_row & block_select.selects(
+            row_pos, geo)))[:, None])                       # [N / 32, 1]
+        # (each block's word under all its keys: what the kernel takes)
+        return jnp.repeat(words | every[None], geo.block, axis=-1)
+
+
+def _block_mixed_attention(q, rows: _MixedRows, pages: _LayerPages, row_pos,
+                           *, cfg: GPTConfig, Q: int, mesh):
+    """``_mixed_attention`` for a layer that selects by blocks: a slot's rows
+    pick its kernel as there, and its context picks the dense or the
+    selected form: a one-row slot within ``block_dense_len`` the paged decode
+    kernel, past it ``_block_rows_one``; a prompt chunk the prefill kernel,
+    in its masked form on ``_block_chunk_bits`` where any of the step's
+    chunk rows selects."""
+    from deepspeed_tpu import ops
+    from deepspeed_tpu.ops import block_select
+    k_pages, v_pages, table = pages.k, pages.v, pages.table
+    S = table.shape[0]
+    N = q.shape[0]
+    nh = cfg.num_heads
+    nkv, hd, vd, _ = _attn_geometry(cfg)
+    geo = cfg.block_geometry
+    with jax.named_scope("attn_kernel"):
+        valid = rows.scat_slot < S
+        slot = jnp.where(valid, rows.scat_slot, 0)
+        qg = q.reshape(N, nkv, nh // nkv, hd).astype(cfg.dtype)
+        pool = dict(scale=_attn_scale(cfg), mesh=mesh, kv_major=False,
+                    impl=cfg.attn_impl)
+        one_row = rows.q_counts == 1
+        first = rows.first_row
+        o_one = _block_one_row(qg[first], pages, row_pos[first],
+                               jnp.where(one_row, rows.kv_len, 0), cfg, mesh)
+        chunk_row = valid & ~one_row[slot]
+        prefill = (qg, k_pages, v_pages, table, rows.kv_len,
+                   rows.kv_len - rows.q_counts,
+                   jnp.where(one_row, 0, rows.q_counts), first)
+
+        def masked():
+            bits = _block_chunk_bits(qg, rows, pages, row_pos, chunk_row,
+                                     cfg)
+            with jax.named_scope("block_attention"):
+                return ops.ragged_prefill_attention(
+                    *prefill, max_q=Q, sel_mask=bits, **pool)
+
+        o_many = jax.lax.cond(
+            jnp.any(chunk_row & block_select.selects(row_pos, geo)), masked,
+            lambda: ops.ragged_prefill_attention(*prefill, max_q=Q, **pool))
+        o = jnp.where(one_row[slot, None, None],
+                      o_one.reshape(S, nh, vd)[slot],
+                      o_many.reshape(N, nh, vd))
+        return jnp.where(valid[:, None, None], o, 0)
+
+
 # --------------------------------------------------------------- state layers
 # A layer that keeps a fixed-size state a sequence (GPTConfig.is_state_layer)
 # in the step programs: a Mamba-2 scan layer (models/gpt.py Mamba2Mixer) or a
@@ -1202,6 +1422,7 @@ class _Mamba2:
     in_scope, conv_scope, scope, out_scope = (
         "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm")
     activation = "silu"
+    positions = False   # ``project`` takes no positions
     group = 4           # prompt chunks convolved and scanned together in a
     #                     mixed step's pass
 
@@ -1290,6 +1511,74 @@ class _Mamba2:
                     wspec="row")
 
 
+class _Lightning(_Mamba2):
+    """What a lightning-attention layer (models/gpt.py ``LightningMixer``)
+    supplies: the recurrence of ``_Mamba2`` at ``dt = 1`` under a fixed decay
+    a head with ``x = v``, ``B = k``, ``C = q / sqrt(p)`` and a group a head,
+    q/k norms and RoPE inside ``project`` (which therefore takes the rows'
+    positions), NO conv (``conv_scope`` None: the path skips it and the pool
+    has no ``conv`` part), and a norm a head before a sigmoid gate."""
+    key = "LightningMixer_0"
+    in_scope, conv_scope, scope, out_scope = (
+        "ssm_in_proj", None, "ssm_scan", "ssm_gate_norm")
+    activation = None
+    positions = True    # ``project`` takes the rows' positions
+    group = 1           # a forward holds one prompt chunk of 1,024 rows more
+    #                     often than four
+
+    @staticmethod
+    def project(mp, h, cfg, mesh=None, pos=None):
+        """Rows ``h [R, H]`` at ``pos [R]`` -> (the gate's logits [R, inner],
+        ``[v | k | q]`` [R, 3 inner] as the recurrence takes them, dt = 1)."""
+        from deepspeed_tpu.models.gpt import lightning_qk, lightning_split
+        q, k, v, gate = lightning_split(
+            _wmm(h, mp["w_in"], h.dtype, mesh=mesh, wspec="col"), cfg)
+        q, k = lightning_qk(
+            q[None], k[None], mp["q_norm"], mp["k_norm"], pos[None], cfg,
+            rotate=cfg.use_rope and cfg.rope_layers in ("all", "state"))
+        R = h.shape[0]
+        u = jnp.concatenate([a.reshape(R, -1) for a in (v, k[0], q[0])], -1)
+        return gate, u, jnp.ones((R, cfg.ssm_heads), jnp.float32)
+
+    @staticmethod
+    def _scan_params(mp):
+        from deepspeed_tpu.models.gpt import lightning_decay
+        heads = mp["w_out"].shape[0] // mp["norm"].shape[0]
+        return (jnp.asarray(lightning_decay(heads)),
+                jnp.zeros((heads,), jnp.float32))
+
+    @classmethod
+    def chunk(cls, mp, out, dt, ssm, si, slots, fresh, count, cfg):
+        """``_Mamba2.chunk`` over the states AS THE POOL HOLDS THEM: a head
+        is the lanes' width, so a packed state is ``[h, n, p]`` and the scan
+        takes it so (``swapped``); unpacked it would be a transpose that the
+        compiler turns into a layout of the whole pool, copied in and out
+        of every mixed step (tests/test_chip_compile.py)."""
+        from deepspeed_tpu import ops
+        from deepspeed_tpu.ops.ssm_scan import lane_heads
+        if lane_heads(cfg.ssm_heads, cfg.ssm_head_dim) != 1:
+            return super().chunk(mp, out, dt, ssm, si, slots, fresh, count,
+                                 cfg)
+        A, D = cls._scan_params(mp)
+        state = jnp.where(fresh[:, None, None, None], 0.0, ssm[si, slots])
+        x, B, C = cls._xbc(out, cfg)
+        y, state = ops.ssm_chunk_scan(
+            x, dt, A, B, C, D, state, (None, count),
+            chunk=min(cfg.ssm_chunk, SCAN_CHUNK), swapped=True)
+        G, Q = out.shape[:2]
+        return y.reshape(G, Q, -1), state
+
+    @staticmethod
+    def output(mp, y, gate, cfg, mesh=None):
+        from deepspeed_tpu.models.gpt import lightning_gate_norm
+        from deepspeed_tpu.ops.norms import RMS_EPS
+        g = lightning_gate_norm(
+            y.reshape(y.shape[0], cfg.ssm_heads, cfg.ssm_head_dim), gate,
+            mp["norm"], cfg.norm_eps or RMS_EPS)
+        return _wmm(g.astype(gate.dtype), mp["w_out"], gate.dtype, mesh=mesh,
+                    wspec="row")
+
+
 class _ShortConv:
     """What a gated short convolution supplies: ``[B | C | X] = W_in h``,
     the conv over ``B * X`` without bias or activation, ``W_out (C * v)``.
@@ -1299,6 +1588,7 @@ class _ShortConv:
     in_scope, conv_scope, scope, out_scope = (
         "conv_in_proj", "short_conv", "short_conv", "conv_out_proj")
     activation = None
+    positions = False
     group = 1           # a forward holds one prompt chunk beside its riders
     #                     more often than four: a pass a chunk
 
@@ -1347,10 +1637,10 @@ def state_mixer(cfg: GPTConfig):
     kinds = {cfg.layer_kind(i) for i in cfg.state_layers}
     if len(kinds) > 1:
         raise NotImplementedError(
-            "scan (mamba) AND conv layers in one model: the state pool is "
-            "built for one kind of state layer")
-    return {"mamba": _Mamba2, "conv": _ShortConv}[kinds.pop()] if kinds \
-        else None
+            "more than one of scan (mamba), lightning and conv layers in "
+            "one model: the state pool is built for one kind of state layer")
+    return {"mamba": _Mamba2, "lightning": _Lightning,
+            "conv": _ShortConv}[kinds.pop()] if kinds else None
 
 
 class _ScanPlan(NamedTuple):
@@ -1395,19 +1685,29 @@ def _scan_rows_one(mp, u, aux, scan, si, active, fresh, mixer,
     channels]`` -> (y [S, width], scan')."""
     from deepspeed_tpu import ops
     ssm, conv = scan
-    cw, cb = mixer.conv_params(mp)
+    out = tail = None
     with jax.named_scope("attn_kernel"):
-        with jax.named_scope(mixer.conv_scope):
-            out, tail = ops.causal_conv1d(
-                u[:, None], cw, cb,
-                _conv_tail(conv, si, None, fresh, mixer, cfg),
-                active.astype(jnp.int32), activation=mixer.activation)
+        if mixer.conv_scope is not None:   # (a lightning layer has no conv)
+            cw, cb = mixer.conv_params(mp)
+            with jax.named_scope(mixer.conv_scope):
+                out, tail = ops.causal_conv1d(
+                    u[:, None], cw, cb,
+                    _conv_tail(conv, si, None, fresh, mixer, cfg),
+                    active.astype(jnp.int32), activation=mixer.activation)
         with jax.named_scope(mixer.scope):
-            y, ssm = mixer.step(mp, out[:, 0], aux, ssm, si, active, fresh,
-                                cfg)
-    with jax.named_scope("kv_write"), jax.named_scope(mixer.scope):
-        conv = conv.at[si].set(tail.reshape(tail.shape[0], -1))
+            y, ssm = mixer.step(mp, u if out is None else out[:, 0], aux,
+                                ssm, si, active, fresh, cfg)
+    if tail is not None:
+        with jax.named_scope("kv_write"), jax.named_scope(mixer.scope):
+            conv = conv.at[si].set(tail.reshape(tail.shape[0], -1))
     return y, (ssm, conv)
+
+
+def _row_major(a):
+    """``a`` constrained to the row-major layout it was created in."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+    return with_layout_constraint(
+        a, Layout(major_to_minor=tuple(range(a.ndim))))
 
 
 def _scan_rows_many(mp, u, aux, scan, si, plan: _ScanPlan,
@@ -1422,7 +1722,9 @@ def _scan_rows_many(mp, u, aux, scan, si, plan: _ScanPlan,
     on rows of no prompt chunk, scan')."""
     from deepspeed_tpu import ops
     from deepspeed_tpu.ops.ssm_scan import segment_rows
-    cw, cb = mixer.conv_params(mp)
+    convolves = mixer.conv_scope is not None   # (a lightning layer: no conv)
+    if convolves:
+        cw, cb = mixer.conv_params(mp)
     S = rows.first_row.shape[0]
     N = u.shape[0]
     G = mixer.group
@@ -1430,18 +1732,26 @@ def _scan_rows_many(mp, u, aux, scan, si, plan: _ScanPlan,
 
     def one_pass(carry):
         i, y, ssm, conv = carry
+        if not convolves:
+            # (the pool stays row-major through the loop: left free, the
+            # compiler carries it in the layout the scan's products like
+            # best and copies all of it in and out of the loop,
+            # tests/test_chip_compile.py)
+            ssm = _row_major(ssm)
         with jax.named_scope("attn_kernel"):
-            with jax.named_scope(mixer.conv_scope):
+            with jax.named_scope(mixer.conv_scope or mixer.scope):
                 slots = jax.lax.dynamic_slice_in_dim(plan.order, i * G, G)
                 live = i * G + lanes < plan.n_many
                 count = jnp.where(live, rows.q_counts[slots], 0)
                 read, write, _ = segment_rows(
                     (rows.first_row[slots], count), Q, N)
                 fresh = plan.fresh[slots]
-                out, tail = ops.causal_conv1d(
-                    u[read], cw, cb,
-                    _conv_tail(conv, si, slots, fresh, mixer, cfg), count,
-                    activation=mixer.activation)
+                out, tail = u[read], None
+                if convolves:
+                    out, tail = ops.causal_conv1d(
+                        out, cw, cb,
+                        _conv_tail(conv, si, slots, fresh, mixer, cfg),
+                        count, activation=mixer.activation)
             with jax.named_scope(mixer.scope):
                 y_pass, state = mixer.chunk(
                     mp, out, None if aux is None else aux[read], ssm, si,
@@ -1451,7 +1761,9 @@ def _scan_rows_many(mp, u, aux, scan, si, plan: _ScanPlan,
             dst = jnp.where(live, slots, S)
             if state is not None:
                 ssm = ssm.at[si, dst].set(state, mode="drop")
-            conv = conv.at[si, dst].set(tail.reshape(G, -1), mode="drop")
+            if tail is not None:
+                conv = conv.at[si, dst].set(tail.reshape(G, -1),
+                                            mode="drop")
         return i + 1, y, ssm, conv
 
     with jax.named_scope("attn_kernel"), jax.named_scope(mixer.scope):
@@ -1463,14 +1775,21 @@ def _scan_rows_many(mp, u, aux, scan, si, plan: _ScanPlan,
     return y, (ssm, conv)
 
 
-def _scan_mixed(mp, h, scan, si, plan: _ScanPlan, rows: "_MixedRows", *,
-                mixer, cfg: GPTConfig, Q: int, mesh=None):
+def _project(mixer, mp, h, cfg, mesh, pos):
+    """``mixer.project``, with the rows' positions for a mixer that takes
+    them (``mixer.positions``: RoPE inside a lightning layer)."""
+    return mixer.project(mp, h, cfg, mesh=mesh,
+                         **({} if pos is None else {"pos": pos}))
+
+
+def _scan_mixed(mp, h, scan, si, plan: _ScanPlan, rows: "_MixedRows",
+                pos=None, *, mixer, cfg: GPTConfig, Q: int, mesh=None):
     """A state layer's mixer on a mixed step's token-major rows ``h [N,
     H]``: a slot with one row (a decode row riding the step, a prompt's
     one-token tail) takes the one-row route, a slot with more the chunked
     one.  -> (the mixer's output [N, H], scan')."""
     with jax.named_scope("attn_qkv"), jax.named_scope(mixer.in_scope):
-        keep, u, aux = mixer.project(mp, h, cfg, mesh=mesh)
+        keep, u, aux = _project(mixer, mp, h, cfg, mesh, pos)
     first = rows.first_row
     y_one, scan = _scan_rows_one(
         mp, u[first], None if aux is None else aux[first], scan, si,
@@ -1488,7 +1807,8 @@ def _scan_decode(mp, h, scan, si, active, token_pos, *, mixer,
     """A state layer's mixer in a decode step: one row ``h [S, H]`` a
     slot."""
     with jax.named_scope("attn_qkv"), jax.named_scope(mixer.in_scope):
-        keep, u, aux = mixer.project(mp, h, cfg, mesh=mesh)
+        keep, u, aux = _project(mixer, mp, h, cfg, mesh,
+                                token_pos if mixer.positions else None)
     y, scan = _scan_rows_one(mp, u, aux, scan, si, active,
                              active & (token_pos == 0), mixer, cfg)
     with jax.named_scope("attn_out"), jax.named_scope(mixer.out_scope):
@@ -1565,6 +1885,8 @@ class _Step(NamedTuple):
     lora: Any = None        # (the adapter pool's tables, the rows' ids)
     hc: Any = None          # the program's ``_HyperMix`` where the residual
     #                         is several streams (``cfg.hc``)
+    pooled: Any = None      # a selection by blocks: (lc, k pages, pooled
+    #                         keys, base) -> the pooled keys after the step
 
 
 class _HyperMix(NamedTuple):
@@ -1699,8 +2021,11 @@ def _layer(bb, li: int, x, pool: _KVPool, step: _Step, cfg: GPTConfig,
             qi, wi, ki = _index_rows(ap, h, cq, step.pos, lc)
             pool = pool._replace(ki=_kv_write_local(
                 (pool.ki,), ki, None, step.plans[grp], base, km=False)[0])
-    pages = _LayerPages(k=pk, v=pv, ki=None, table=step.tables[grp],
-                        k_scale=pks, v_scale=pvs,
+    if lc.block_topk:
+        with jax.named_scope("attn_kernel"), jax.named_scope("attn_index"):
+            pool = pool._replace(ki=step.pooled(lc, pk, pool.ki, base))
+    pages = _LayerPages(k=pk, v=pv, ki=pool.ki if lc.block_topk else None,
+                        table=step.tables[grp], k_scale=pks, v_scale=pvs,
                         sink=ap["sink"] if lc.attn_sink else None)
     with _group_scope(kv_layout, grp):
         if lc.index_topk and step.selected is not None:
@@ -1767,6 +2092,10 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
         _mixed_attention, cfg=kind[0], Q=Q, window=kind[1], mesh=mesh))
         for kind in {(cfg.for_layer(i), cfg.window_for_layer(i))
                      for i in cfg.attention_layers}}
+    if cfg.block_topk:      # (a selection by blocks: its own, as above)
+        attend = {kind: (lambda q, rows, pages, fn=jax.jit(named_partial(
+            _block_mixed_attention, cfg=kind[0], Q=Q, mesh=mesh)):
+            fn(q, rows, pages, token_pos)) for kind in attend}
     plans = tuple(_write_plan(t, scat_slot, token_pos, block_size, Q, km)
                   for t in tables)
     pool = _KVPool.open(cache, cfg)
@@ -1807,8 +2136,13 @@ def ragged_forward(params, cache: PagedKVCache, batch, cfg: GPTConfig, *,
             q, qi, wi, pages.at(base), scat_slot, token_pos, rows=rows))
         if selected else None,
         mixer=(lambda blk, h, scan, si: scan_mixer(
-            blk[mixer.key], h, scan, si, plan, rows)) if mixer else None,
-        lora=lora, ffn=dict(ffn, live=valid), hc=_HyperMix.of(cfg))
+            blk[mixer.key], h, scan, si, plan, rows,
+            *((token_pos,) if mixer.positions else ())))
+        if mixer else None,
+        lora=lora, ffn=dict(ffn, live=valid), hc=_HyperMix.of(cfg),
+        pooled=(lambda lc, pk, ki, base: _pooled_upkeep(
+            pk, ki, tables[0] + base, scat_slot, token_pos,
+            lc.block_geometry)) if cfg.block_topk else None)
     for li in range(cfg.num_layers):
         x, pool = _layer(bb, li, x, pool, step, cfg, kv_layout, mesh)
 
@@ -1867,6 +2201,9 @@ def _decode_core(params, pool: _KVPool, tokens, active, token_pos, tables,
         with jax.named_scope("attn_kernel"):
             qg = q.reshape(S, nkv, lc.num_heads // nkv, hd)
             slopes, win = _alibi(lc), cfg.window_for_layer(li)
+            if lc.block_topk:       # (a selection by blocks: its own)
+                return _block_one_row(qg, pages.at(base), token_pos, kv_len,
+                                      lc, mesh).reshape(S, lc.num_heads, vd)
             with _window_latent_scope(cfg, win):
                 o = ops.paged_attention(
                     qg, pages.k, pages.v, pages.table + base, kv_len,
@@ -1887,7 +2224,10 @@ def _decode_core(params, pool: _KVPool, tokens, active, token_pos, tables,
         lora=lora, ffn=dict(
             live=active, stats=stats, routes=routes,
             experts=_experts_fn(cfg, moe_stats) if cfg.num_experts else None),
-        hc=_HyperMix.of(cfg))
+        hc=_HyperMix.of(cfg),
+        pooled=(lambda lc, pk, ki, base: _pooled_upkeep(
+            pk, ki, tables[0] + base, row_slot, token_pos,
+            lc.block_geometry)) if cfg.block_topk else None)
     for li in range(cfg.num_layers):
         x, pool = _layer(bb, li, x, pool, step, cfg, kv_layout, mesh)
 

@@ -164,7 +164,9 @@ class GPTConfig:
     attn_gate: bool = False             # o * sigmoid(Wg n1(x)) before Wo
     qk_norm: bool = False               # RMSNorm on each query and key head
     rope_layers: str = "all"            # "window": RoPE on layers with a
-    #                                     sliding window only (NoPE global)
+    #                                     sliding window only (NoPE global);
+    #                                     "state": inside the lightning
+    #                                     layers only (attention NoPE)
     sandwich_norm: bool = False         # norms after attention and FFN too:
     #                                     x + n2(attn(n1 x)); h + n4(f(n3 h))
     # latent attention (MLA; checkpoint/hf.py maps model_type "deepseek_v3"):
@@ -192,6 +194,20 @@ class GPTConfig:
     index_topk: int = 0
     index_n_heads: int = 0
     index_head_dim: int = 0
+    # a selection of keys by BLOCKS from pooled keys (InfLLM-V2, model_type
+    # "minicpm_sala"; ops/block_select.py states the rule) on every attention
+    # layer: past ``block_dense_len`` tokens of context a row's KV head keeps
+    # ``block_topk`` blocks of ``block_size`` keys: the leading
+    # ``block_init``, the ``block_window`` keys' worth that end at its own
+    # block and the best-scoring others, scored from keys pooled
+    # ``block_kernel`` at a time every ``block_stride``.  0 = none
+    block_topk: int = 0
+    block_size: int = 64
+    block_kernel: int = 32
+    block_stride: int = 16
+    block_window: int = 2048
+    block_init: int = 1
+    block_dense_len: int = 8192
     # attention geometry of the layers WITH a window where it differs from
     # the other layers': ((field, value), ...) over num_heads, num_kv_heads,
     # head_dim, v_head_dim, kv_lora_rank, q_lora_rank, qk_rope_head_dim,
@@ -211,8 +227,13 @@ class GPTConfig:
     attn_value_scale: Optional[float] = None
     attn_sink: Optional[str] = None
     # layers that are no attention (checkpoint/hf.py maps model_types
-    # "granitemoehybrid" and "lfm2_moe"): ``layer_types[i]`` is "attention",
-    # "mamba" or "conv".  "mamba": a Mamba-2 scan layer (``Mamba2Mixer``:
+    # "granitemoehybrid", "lfm2_moe" and "minicpm_sala"): ``layer_types[i]``
+    # is "attention", "mamba", "lightning" or "conv".  "lightning": lightning
+    # attention (``LightningMixer``: the same recurrence at ``dt = 1`` under a
+    # FIXED decay a head, a query and a key a head (``ssm_groups ==
+    # ssm_heads``), q/k norms and RoPE inside, no conv; a scan layer to
+    # everything that counts one).  "mamba": a Mamba-2 scan layer
+    # (``Mamba2Mixer``:
     # ``ssm_heads`` heads of ``ssm_head_dim`` over a state ``ssm_state`` wide,
     # ``B``/``C`` shared by the heads of each of ``ssm_groups`` groups, a
     # depthwise causal conv ``ssm_conv`` taps long over x, B and C, computed
@@ -285,8 +306,8 @@ class GPTConfig:
         the layers without a window only.  The same object where the layers
         are all alike."""
         if self.is_state_layer(i):
-            what = {"mamba": "scan", "conv": "short-convolution"}[
-                self.layer_kind(i)]
+            what = {"mamba": "scan", "lightning": "lightning-attention",
+                    "conv": "short-convolution"}[self.layer_kind(i)]
             raise ValueError(
                 f"layer {i} is a {what} layer ({self.layer_types[i]}): it "
                 f"has no attention geometry; ask is_state_layer(i) first")
@@ -300,7 +321,8 @@ class GPTConfig:
 
     def layer_kind(self, i: int) -> str:
         """What mixes layer ``i``'s sequence: "attention", "mamba" (a
-        Mamba-2 scan) or "conv" (a gated short convolution)."""
+        Mamba-2 scan), "lightning" (lightning attention: a matrix state a
+        head under a fixed decay) or "conv" (a gated short convolution)."""
         if not self.layer_types:
             return "attention"
         if len(self.layer_types) != self.num_layers:
@@ -308,15 +330,16 @@ class GPTConfig:
                 f"layer_types names {len(self.layer_types)} layers, the "
                 f"model has {self.num_layers}")
         kind = self.layer_types[i]
-        if kind not in ("attention", "mamba", "conv"):
+        if kind not in ("attention", "mamba", "lightning", "conv"):
             raise ValueError(f"layer_types[{i}] must be "
-                             f"attention|mamba|conv, got {kind!r}")
+                             f"attention|mamba|lightning|conv, got {kind!r}")
         return kind
 
     def is_scan_layer(self, i: int) -> bool:
-        """Whether layer ``i`` mixes its sequence by a state-space scan
-        (Mamba-2): a float32 recurrent state and a conv tail a sequence."""
-        return self.layer_kind(i) == "mamba"
+        """Whether layer ``i`` mixes its sequence by a scan over a float32
+        recurrent state a sequence: Mamba-2 (with a conv tail beside it) or
+        lightning attention (without)."""
+        return self.layer_kind(i) in ("mamba", "lightning")
 
     def is_conv_layer(self, i: int) -> bool:
         """Whether layer ``i`` is a gated short convolution: a conv tail a
@@ -382,10 +405,24 @@ class GPTConfig:
             return True
         if self.rope_layers == "none":     # use_rope, and no layer rotates:
             return False                   # no positions at all (NoPE)
+        if self.rope_layers == "state":    # inside the lightning layers only
+            return self.layer_kind(i) == "lightning"
         if self.rope_layers != "window":
-            raise ValueError(f"rope_layers must be all|window|none, got "
-                             f"{self.rope_layers!r}")
+            raise ValueError(f"rope_layers must be all|window|state|none, "
+                             f"got {self.rope_layers!r}")
         return self.window_for_layer(i) is not None
+
+    @property
+    def block_geometry(self):
+        """The sizes of the selection by blocks
+        (``ops.block_select.BlockGeometry``, checked), None without one."""
+        if not self.block_topk:
+            return None
+        from deepspeed_tpu.ops.block_select import BlockGeometry
+        return BlockGeometry(
+            self.block_kernel, self.block_stride, self.block_size,
+            self.block_topk, self.block_window, self.block_init,
+            self.block_dense_len).check()
 
     @property
     def local_experts(self) -> int:
@@ -890,6 +927,11 @@ class Attention(nn.Module):
             return (s[:, None, None]
                     * key_pos[..., None, None, :].astype(jnp.float32))
 
+        if use_cache and c.block_topk:
+            raise NotImplementedError(
+                "a selection by blocks (block_topk) through the dense "
+                "KV-cache path: its pooled keys live in the v2 engine's pool "
+                "(inference/v2)")
         if use_cache:
             # static KV cache in a flax "cache" collection (reference:
             # inference_context.h KV workspace; flax decode-cache idiom).
@@ -932,6 +974,21 @@ class Attention(nn.Module):
 
         sp_active = (c.sequence_parallel and self.mesh is not None
                      and self.mesh.shape["sp"] > 1)
+        if c.block_topk:
+            # a selection by blocks (ops/block_select.py), in its dense
+            # form: every key scored, the kept blocks as a mask a KV head
+            if plain or sp_active or c.use_alibi or window is not None:
+                raise NotImplementedError(
+                    "a selection by blocks (block_topk) beside a sink, a "
+                    "value width of its own, alibi, a sliding window or "
+                    "sequence parallelism is not built")
+            from deepspeed_tpu.ops import block_select
+            scale = hd ** -0.5 if c.attn_scale is None else c.attn_scale
+            qg = q.reshape(B, T, nkv, nh // nkv, hd)
+            keep = block_select.dense_key_mask(
+                qg, k, positions, geo=c.block_geometry, scale=scale)
+            out = block_select.masked_attention(qg, k, v, keep, scale)
+            return out_proj(out.reshape(B, T, nh, hd))
         if c.use_alibi and sp_active:
             raise ValueError("alibi + sequence parallelism is not wired "
                              "(the a2a/ring paths carry no logit bias)")
@@ -1431,6 +1488,97 @@ class Mamba2Mixer(nn.Module):
         return y.astype(x.dtype) @ w_out.astype(x.dtype)
 
 
+def lightning_decay(heads: int):
+    """``log lambda_h`` of lightning attention's fixed decay a head, the
+    ALiBi-slope rule of Lightning Attention-2 (arXiv:2401.04658): ``lambda_h
+    = exp(-2^(-8 (h + 1) / heads))``, float32 ``[heads]``."""
+    import numpy as np
+    return -np.exp2(-8.0 * np.arange(1, heads + 1, dtype=np.float64)
+                    / heads).astype(np.float32)
+
+
+def lightning_split(h, c: GPTConfig):
+    """A lightning layer's projected rows ``[..., 4 inner]`` = ``[q | k | v |
+    gate]`` -> (q, k, v ``[..., heads, p]``, the gate's logits ``[...,
+    inner]``)."""
+    inner = c.ssm_inner
+    lead = h.shape[:-1]
+    q, k, v = (h[..., i * inner:(i + 1) * inner].reshape(
+        lead + (c.ssm_heads, c.ssm_head_dim)) for i in range(3))
+    return q, k, v, h[..., 3 * inner:]
+
+
+def lightning_qk(q, k, q_norm, k_norm, positions, c: GPTConfig, rotate=True):
+    """RMSNorm on each query and key head, then RoPE over the whole head
+    (where the layer rotates), and the query times ``1 / sqrt(p)``: what the
+    recurrence takes as ``C`` and ``B``.  ``q``/``k [B, T, heads, p]``,
+    ``positions [B, T]``."""
+    if c.qk_norm:
+        q, k = head_norm(q, q_norm, c), head_norm(k, k_norm, c)
+    if rotate:
+        q, k = rope(q, k, positions, c.ssm_head_dim, base=c.rope_theta)
+    return q * jnp.asarray(c.ssm_state ** -0.5, q.dtype), k
+
+
+def lightning_gate_norm(y, gate, scale, eps):
+    """A lightning layer's output: RMSNorm over each head of ``y [..., heads,
+    p]`` with one learned gain ``[p]``, THEN the sigmoid gate ``[..., heads
+    * p]``; float32 inside."""
+    yf = y.astype(jnp.float32)
+    yf = yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + eps)
+    yf = yf * scale.astype(jnp.float32)
+    return yf.reshape(gate.shape) * jax.nn.sigmoid(gate.astype(jnp.float32))
+
+
+class LightningMixer(nn.Module):
+    """Lightning attention (MiniCPM-SALA's ``lightning-attn`` layers) on whole
+    sequences ``x [B, T, H]``, from a zero state, a head ``h`` of width ``p``
+    over a float32 state ``[p, p]``:
+
+        [q | k | v | g] = W_in x;   q, k = rope(norm(q), norm(k))
+        S_t = lambda_h S_{t-1} + k_t^T v_t;   o_t = q_t S_t / sqrt(p)
+        out = W_out (norm_head(o) * sigmoid(g))
+
+    which is ``ops/ssm_scan.py``'s recurrence with ``x = v``, ``B = k``, ``C =
+    q / sqrt(p)``, ``dt = 1``, ``A_h = log lambda_h`` (``lightning_decay``),
+    ``D = 0`` and a group a head.  The serving engine computes the same from
+    these parameters with a carried state (inference/v2/model.py)."""
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, x, positions, use_rope: bool = True):
+        from deepspeed_tpu import ops
+        from deepspeed_tpu.ops.norms import RMS_EPS
+        c = self.cfg
+        H, inner = c.hidden_size, c.ssm_inner
+        h, p, n = c.ssm_heads, c.ssm_head_dim, c.ssm_state
+        if c.ssm_groups != h or n != p:
+            raise ValueError(
+                f"a lightning layer keeps a query and a key a head over a "
+                f"square state: ssm_groups {c.ssm_groups} must be ssm_heads "
+                f"{h} and ssm_state {n} ssm_head_dim {p}")
+        w_in = self.param("w_in", _part(_kernel_init(), ("embed", "mlp")),
+                          (H, 4 * inner), c.param_dtype)
+        q_norm = self.param("q_norm", _part(nn.initializers.ones, (None,)),
+                            (p,), c.param_dtype)
+        k_norm = self.param("k_norm", _part(nn.initializers.ones, (None,)),
+                            (p,), c.param_dtype)
+        norm = self.param("norm", _part(nn.initializers.ones, (None,)),
+                          (p,), c.param_dtype)
+        w_out = self.param("w_out", _part(_kernel_init(), ("mlp", "embed")),
+                           (inner, H), c.param_dtype)
+        Bt, T = x.shape[:2]
+        q, k, v, gate = lightning_split(x @ w_in.astype(x.dtype), c)
+        q, k = lightning_qk(q, k, q_norm, k_norm, positions, c, use_rope)
+        y, _ = ops.ssm_chunk_scan(
+            v, jnp.ones((Bt, T, h), jnp.float32),
+            jnp.asarray(lightning_decay(h)), k, q,
+            jnp.zeros((h,), jnp.float32),
+            jnp.zeros((Bt, h, p, n), jnp.float32), chunk=c.ssm_chunk)
+        y = lightning_gate_norm(y, gate, norm, c.norm_eps or RMS_EPS)
+        return y.astype(x.dtype) @ w_out.astype(x.dtype)
+
+
 def short_conv_gates(bcx):
     """A short-conv layer's projected rows ``[..., 3 H]`` = ``[B | C | X]``
     -> (the conv's input ``B * X``, the output gate ``C``)."""
@@ -1517,7 +1665,8 @@ class Block(nn.Module):
     attn_cfg: Optional[GPTConfig] = None   # this layer's attention view
     #                                        (GPTConfig.for_layer); None: cfg
     mixer: str = "attention"               # GPTConfig.layer_kind: "mamba"
-    #                                        (Mamba2Mixer) | "conv"
+    #                                        (Mamba2Mixer) | "lightning"
+    #                                        (LightningMixer) | "conv"
     #                                        (ShortConvMixer) mix instead
 
     @nn.compact
@@ -1594,8 +1743,14 @@ class Block(nn.Module):
                     "a scan or conv layer through the dense KV-cache path: "
                     "its state lives in the v2 engine's pool (inference/v2); "
                     "the v1 cache holds keys and values only")
-            mix = Mamba2Mixer if self.mixer == "mamba" else ShortConvMixer
-            a = mix(c)(Norm(c)(xin))
+            if self.mixer == "lightning":
+                a = LightningMixer(c)(Norm(c)(xin), positions,
+                                      c.use_rope if use_rope is None
+                                      else use_rope)
+            else:
+                mix = (Mamba2Mixer if self.mixer == "mamba"
+                       else ShortConvMixer)
+                a = mix(c)(Norm(c)(xin))
         else:
             attn = (MLAttention(self.attn_cfg or c, mesh=self.mesh,
                                 name="Attention_0") if c.mla
@@ -1927,7 +2082,11 @@ def count_params(cfg: GPTConfig) -> int:
                 + (c.num_heads if c.attn_sink else 0))
     attn = plain_attn(cfg)
     per_norms = H * norms * (1 if cfg.use_rmsnorm else 2)
-    n_scan, n_conv = len(cfg.scan_layers), len(cfg.conv_layers)
+    n_light = sum(cfg.layer_kind(i) == "lightning"
+                  for i in range(cfg.num_layers))
+    n_scan, n_conv = len(cfg.scan_layers) - n_light, len(cfg.conv_layers)
+    # a lightning layer's mixer: w_in [H, 4 inner], w_out, three gains
+    light = 5 * H * cfg.ssm_inner + 3 * cfg.ssm_head_dim
     # a scan layer's mixer: w_in, w_out, the conv, dt_bias/A_log/D, the norm
     scan = (H * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads)
             + cfg.ssm_inner * H
@@ -1935,8 +2094,10 @@ def count_params(cfg: GPTConfig) -> int:
             + 3 * cfg.ssm_heads + cfg.ssm_inner)
     # a short-conv layer's mixer: w_in [H, 3H], w_out [H, H], the conv
     conv = H * 3 * H + H * H + H * cfg.conv_taps
-    attn = ((attn + per_norms) * (cfg.num_layers - n_scan - n_conv)
-            + (scan + per_norms) * n_scan + (conv + per_norms) * n_conv)
+    attn = ((attn + per_norms)
+            * (cfg.num_layers - n_scan - n_conv - n_light)
+            + (scan + per_norms) * n_scan + (conv + per_norms) * n_conv
+            + (light + per_norms) * n_light)
     if cfg.mla or cfg.window_attn or cfg.attn_sink == "window":
         # a layer's own geometry (GPTConfig.for_layer)
         attn = sum((_mla_params if cfg.mla else plain_attn)(cfg.for_layer(i))

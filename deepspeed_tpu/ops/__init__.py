@@ -178,6 +178,11 @@ register_op("threshold_mask", xla=_index.xla_threshold_mask,
             pallas=_index.pallas_threshold_mask,
             supported=_index.threshold_mask_supported)
 
+from deepspeed_tpu.ops import block_select as _blocks  # noqa: E402
+from deepspeed_tpu.ops.block_select import block_scores  # noqa: E402
+
+register_op("block_scores", xla=_blocks.xla_block_scores)
+
 from deepspeed_tpu.ops import grouped_gemm as _grouped  # noqa: E402
 
 register_op("grouped_gemm", xla=_grouped.xla_grouped_gemm,
@@ -209,11 +214,15 @@ register_op("causal_conv1d", xla=_ssm.xla_causal_conv1d)
 
 
 def ssm_chunk_scan(x, dt, A, B, C, D, state0, segments=None, *, chunk: int,
-                   max_len=None, impl: Optional[str] = None):
+                   max_len=None, swapped: bool = False,
+                   impl: Optional[str] = None):
     """A scan layer's chunked (SSD) scan of every segment from its own
-    initial state -> (y float32, final states) (ops/ssm_scan.py)."""
+    initial state -> (y float32, final states) (ops/ssm_scan.py).
+    ``swapped``: the states are ``[G, h, n, p]``, as the packed pool holds
+    a head the lanes' width."""
     return dispatch("ssm_chunk_scan", x, dt, A, B, C, D, state0, segments,
-                    chunk=chunk, max_len=max_len, impl=impl)
+                    chunk=chunk, max_len=max_len, impl=impl,
+                    **({"swapped": True} if swapped else {}))
 
 
 def ssm_state_update(x, dt, A, B, C, D, pool, layer=0, active=None,
@@ -306,6 +315,7 @@ __all__ = ["causal_attention", "flash_attention", "configure_flash_blocks",
            "evoformer_attention",
            "index_scores", "index_select", "selected_attention",
            "selection_mask", "threshold_mask",
+           "block_scores",
            "all_gather_matmul", "matmul_reduce_scatter",
            "row_parallel_matmul", "collective_matmul",
            "lm_cross_entropy", "masked_nll_sum", "rms_norm", "layer_norm",

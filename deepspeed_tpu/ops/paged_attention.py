@@ -726,10 +726,15 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
         & live[:, :, None]                                     # [S, Q, K]
     if window is not None:
         mask = mask & (kvpos[None, None, :] > qpos[:, :, None] - window)
+    kept = None
     if sel_mask is not None:
         row = jnp.minimum(flat, N - 1)
-        kept = jnp.right_shift(sel_mask[row // 32], (row % 32)[..., None]) & 1
-        mask = mask & (kept != 0)
+        heads = sel_mask.ndim == 3          # a selection a kv head
+        kept = jnp.right_shift(
+            sel_mask[:, row // 32] if heads else sel_mask[row // 32],
+            (row % 32)[..., None]) & 1
+        if not heads:
+            mask = mask & (kept != 0)
     s_log = jnp.einsum("sqngd,sknd->snqgk", q, k_seq,
                        preferred_element_type=jnp.float32) * scale
     if alibi_slopes is not None:
@@ -738,6 +743,8 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
                          * kvpos[None, None, None, None, :].astype(
                              jnp.float32))
     m = mask[:, None, :, None, :]                              # [S,1,Q,1,K]
+    if kept is not None and kept.ndim == 4:         # [nkv, S, Q, K]
+        m = m & (jnp.moveaxis(kept, 0, 1)[:, :, :, None, :] != 0)
     s_log = jnp.where(m, s_log, jnp.finfo(jnp.float32).min)
     probs = sink_softmax(s_log, None if sink is None
                          else jnp.reshape(sink, (1, nkv, 1, g, 1)))
@@ -749,7 +756,7 @@ def xla_ragged_prefill(q, k_pages, v_pages, block_table, kv_lens, q_starts,
 
 def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
                     kv_major, quant=False, v_dim=None, has_mask=False,
-                    vd=None, has_sink=False):
+                    vd=None, has_sink=False, mask_groups=0):
     """One grid step = one work item (``cq`` rows of one slot) for one kv
     head; see the section comment.  The chunk buffers hold ``g`` heads of
     ``hd`` (``vd``) values in their leading rows and columns: the arrays in
@@ -757,7 +764,9 @@ def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
     block (``_prefill_block_pages``).  ``v_dim``: latent pages, one pool and
     one buffer: the page is the key and its leading ``v_dim`` columns the
     value.  ``has_mask``: the masked form, one more array in HBM and one
-    more buffer (section comment).  ``vd``: the value head's width (the
+    more buffer (section comment); ``mask_groups``: a selection a KV HEAD,
+    each head's words ``mask_groups`` groups behind the one before (0: one
+    selection for all heads).  ``vd``: the value head's width (the
     latent's, or the value pool's own).  ``has_sink``: ``[nkv, g]`` float32 logits
     behind the scalars, the softmax's starting state (module docstring)."""
     it = iter(refs)
@@ -836,6 +845,9 @@ def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
                     mask_buf.at[half], mask_sem.at[half]))
 
         group0 = lax.shift_right_logical(flat0, i32(5)) if has_mask else None
+        first_group = group0        # of the item's rows, in any head's words
+        if mask_groups:
+            group0 = group0 + h * i32(mask_groups)
         block_copies(p_start, 0, lambda c: c.start())
         # a row sees the keys in (lo, hi]: its position bounds them above
         # (and the context's length, and nothing at all if the row is past
@@ -848,7 +860,7 @@ def _prefill_kernel(*refs, P, bs, cq, g, hd, scale, window, has_alibi,
         if has_mask:
             # row r's bit, and which of the copied groups holds it
             flat = flat0 + rown
-            group = lax.shift_right_logical(flat, i32(5)) - group0
+            group = lax.shift_right_logical(flat, i32(5)) - first_group
             bit = lax.shift_left(lax.full((R, 1), 1, i32),
                                  lax.bitwise_and(flat, i32(31)))
         if has_alibi:
@@ -1115,12 +1127,18 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
     if quant:
         pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     P = _prefill_block_pages(pools, cq * g, bs, vd)
+    # (an item's ``cq`` rows lie in at most ``span`` groups of 32; a
+    # selection a kv head, ``[nkv, groups, C]``: each head's words behind
+    # the one before's)
+    span = (cq + 30) // 32 + 1
+    groups = (N - 1) // 32 + span
+    mask_heads = has_mask and sel_mask.ndim == 3
     kernel = functools.partial(
         _prefill_kernel, P=P, bs=bs, cq=cq, g=g, hd=hd, scale=float(scale),
         window=int(window) if window is not None else None,
         has_alibi=has_alibi, kv_major=kv_major, quant=quant,
         v_dim=vd if latent else None, has_mask=has_mask, vd=vd,
-        has_sink=has_sink)
+        has_sink=has_sink, **({"mask_groups": groups} if mask_heads else {}))
     prefetch = [block_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
                 q_starts.astype(jnp.int32), q_counts,
                 row_starts.astype(jnp.int32), item_slot, item_chunk,
@@ -1139,12 +1157,12 @@ def _pallas_ragged_prefill_local(q, k_pages, v_pages, block_table, kv_lens,
         # an item's ``cq`` rows lie in at most ``span`` groups of 32 and a
         # context's last block reaches ``P - 1`` pages past the table: so
         # many groups and columns more, zeros, for the copies to stay inside
-        span = (cq + 30) // 32 + 1
-        groups = (N - 1) // 32 + span
+        heads = ((0, 0, 0),) if mask_heads else ()
         inputs.append(lax.pad(
             sel_mask, jnp.int32(0),
-            ((0, groups - sel_mask.shape[0], 0), (0, (P - 1) * bs, 0))
-        ).reshape(groups, 1, (MB + P - 1) * bs))
+            heads + ((0, groups - sel_mask.shape[-2], 0),
+                     (0, (P - 1) * bs, 0))
+        ).reshape(-1, 1, (MB + P - 1) * bs))
     # the chunk's rows in and out, both halves of the page pipeline (P pages
     # of one kv head each), and the softmax state of the item's rows
     scratch = [pltpu.VMEM((cq, gp, hp), q.dtype),
@@ -1233,8 +1251,9 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_table, kv_lens,
     at most ``max_q`` of them.  ``v_pages=None`` with ``v_dim``: latent pages
     (module docstring).  ``sel_mask``: ``[ceil(N / 32), MB * bs]`` int32,
     the positions of its sequence each row keeps beside what is causal
-    (``ops.threshold_mask``; the section comment has the layout).  ``sink
-    [heads]``: a sink logit a query head (module docstring)."""
+    (``ops.threshold_mask``; the section comment has the layout), or ``[nkv,
+    ceil(N / 32), MB * bs]``: a selection a KV head (``ops/block_select``).
+    ``sink [heads]``: a sink logit a query head (module docstring)."""
     from deepspeed_tpu.ops.registry import dispatch
     return dispatch("ragged_prefill_attention", q, k_pages, v_pages,
                     block_table, kv_lens, q_starts, q_counts, row_starts,

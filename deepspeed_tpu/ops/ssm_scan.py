@@ -50,11 +50,15 @@ def _heads_of_groups(a, heads):
     return a if g == heads else jnp.repeat(a, heads // g, axis=-2)
 
 
-def _chunk(x, dt, A, B, C, D, state):
+def _chunk(x, dt, A, B, C, D, state, swapped=False):
     """One chunk of every sequence: ``x [G, c, h, p]``, ``dt [G, c, h]``,
     ``B``/``C [G, c, g, n]``, ``state [G, h, p, n]`` -> (y, state').  The
     heads are taken group by group (``[g, r]``, ``r`` heads a group), so
-    that ``C B^T`` is computed once a group and not once a head."""
+    that ``C B^T`` is computed once a group and not once a head.
+    ``swapped``: the state is ``[G, h, n, p]`` in and out, as the packed
+    pool holds a head the lanes' width: the two products that touch it name
+    their axes in that order, and no transpose of a state exists for the
+    compiler to turn into a layout of the whole pool."""
     G, c, h, p = x.shape
     g = B.shape[2]
     r = h // g
@@ -73,31 +77,34 @@ def _chunk(x, dt, A, B, C, D, state):
     # what the state brought into the chunk gives row i, decayed through i
     sg = state.reshape((G, g, r) + state.shape[2:])
     y = y.reshape(x.shape) + jnp.einsum(
-        "gikn,gkrpn->gikrp", C, sg, precision=_HI).reshape(x.shape) \
-        * jnp.exp(cum)[..., None]
+        "gikn,gkrnp->gikrp" if swapped else "gikn,gkrpn->gikrp", C, sg,
+        precision=_HI).reshape(x.shape) * jnp.exp(cum)[..., None]
     # ... and the state the chunk leaves: the old one decayed through the
     # chunk, each row's outer product decayed from that row to the end
     last = cum[:, -1:, :]
     wx = (x * (dt * jnp.exp(last - cum))[..., None]).reshape(xg.shape)
-    state = (state * jnp.exp(last[:, 0])[..., None, None]
-             + jnp.einsum("gjkrp,gjkn->gkrpn", wx, B,
-                          precision=_HI).reshape(state.shape))
+    kept = state * jnp.exp(last[:, 0])[..., None, None]
+    grown = (jnp.einsum("gjkn,gjkrp->gkrnp", B, wx, precision=_HI)
+             if swapped else
+             jnp.einsum("gjkrp,gjkn->gkrpn", wx, B, precision=_HI))
+    state = kept + grown.reshape(state.shape)
     return y + D[:, None] * x, state
 
 
-def _ssd_dense(x, dt, A, B, C, D, state0, chunk):
+def _ssd_dense(x, dt, A, B, C, D, state0, chunk, swapped=False):
     """(y [G, L, h, p] float32, state1) for ``L`` a whole number of
     chunks."""
     G, L = x.shape[:2]
     n = L // chunk
+    chunk_fn = functools.partial(_chunk, swapped=True) if swapped else _chunk
     if n == 1:
-        return _chunk(x, dt, A, B, C, D, state0)
+        return chunk_fn(x, dt, A, B, C, D, state0)
 
     def split(a):                    # [G, L, ...] -> [n, G, chunk, ...]
         return jnp.moveaxis(a.reshape((G, n, chunk) + a.shape[2:]), 1, 0)
 
     def step(state, rows):
-        y, state = _chunk(*rows[:2], A, *rows[2:], D, state)
+        y, state = chunk_fn(*rows[:2], A, *rows[2:], D, state)
         return state, y
     state1, y = jax.lax.scan(step, state0, tuple(map(split, (x, dt, B, C))))
     return jnp.moveaxis(y, 0, 1).reshape(x.shape), state1
@@ -115,12 +122,13 @@ def segment_rows(segments, max_len, N):
 
 
 def xla_ssm_chunk_scan(x, dt, A, B, C, D, state0, segments=None, *,
-                       chunk: int, max_len=None):
+                       chunk: int, max_len=None, swapped: bool = False):
     """``(y, state1)``: the scan of every segment from its ``state0 [G, h,
     p, n]`` (module docstring for the layouts).  ``y`` is float32, shaped
     like ``x``; ``dt`` is taken as given (after the bias and the softplus).
     Dense, ``segments`` may still be ``(None, count [G])``: rows behind a
-    sequence's count are padding."""
+    sequence's count are padding.  ``swapped``: ``state0`` and ``state1``
+    are ``[G, h, n, p]`` (``_chunk``)."""
     A, D = A.astype(F32), D.astype(F32)
     count = None if segments is None else segments[1]
     ragged = segments is not None and segments[0] is not None
@@ -139,7 +147,8 @@ def xla_ssm_chunk_scan(x, dt, A, B, C, D, state0, segments=None, *,
     if pad:                                   # dt = 0: the state stands
         x, dt, B, C = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
                        for a in (x, dt, B, C))
-    y, state1 = _ssd_dense(x, dt, A, B, C, D, state0.astype(F32), chunk)
+    y, state1 = _ssd_dense(x, dt, A, B, C, D, state0.astype(F32), chunk,
+                           **({"swapped": True} if swapped else {}))
     y = y[:, :L]
     if ragged:
         y = jnp.zeros((N,) + y.shape[2:], F32).at[write].set(y, mode="drop")
@@ -240,14 +249,18 @@ def xla_ssm_state_update(x, dt, A, B, C, D, pool, layer=0, active=None,
     return y + D.astype(F32)[:, None] * x, pool.at[layer].set(new)
 
 
-def _update_kernel(flags, _, xdt, decay, b, c, st, y, out, *, rows):
+def _update_kernel(flags, _, xdt, decay, b, c, st, y, out, *, rows,
+                   per_head=False):
     import jax.experimental.pallas as pl
     f = flags[pl.program_id(0)]
     active, fresh = (f & 1) == 1, (f & 2) == 2
     for j in range(rows):                   # one head group's [n, lanes]
         old = jnp.where(fresh, 0.0, st[j])
-        new = old * decay[0, j][None, :] + b[0] * xdt[0, j][None, :]
-        y[0, j] = jnp.sum(new * c[0], axis=0)
+        # (a group a head: ``b``/``c`` are the block's heads' columns side
+        # by side, ``[n, rows]``, each broadcast along its own head's lanes)
+        bj, cj = (b[:, j:j + 1], c[:, j:j + 1]) if per_head else (b[0], c[0])
+        new = old * decay[0, j][None, :] + bj * xdt[0, j][None, :]
+        y[0, j] = jnp.sum(new * cj, axis=0)
         out[j] = jnp.where(active, new, old)
 
 
@@ -268,13 +281,25 @@ def pallas_ssm_state_update(x, dt, A, B, C, D, pool, layer=0, active=None,
     rows = 8 if hk % 8 == 0 else hk
     flags = active.astype(jnp.int32) + 2 * fresh.astype(jnp.int32)
     which = jnp.asarray(layer, jnp.int32).reshape(1)   # static or traced
-    cols = [jnp.broadcast_to(a[:, 0, :, None], (S, n, lanes)) for a in (B, C)]
     row = pl.BlockSpec((1, rows, lanes), lambda s, j, *_: (s, j, 0))
-    col = pl.BlockSpec((1, n, lanes), lambda s, j, *_: (s, 0, 0))
+    per_head = B.shape[1] > 1
+    if per_head:
+        # a group a head (and a head the lanes' width): a block's heads'
+        # columns side by side, [S, blocks, n, rows]; the block's minor dim
+        # is the array's own, so a copy takes it whole
+        cols = [jnp.moveaxis(a.reshape(S, hk // rows, rows, n), -1, -2)
+                for a in (B, C)]
+        col = pl.BlockSpec((None, None, n, rows),
+                           lambda s, j, *_: (s, j, 0, 0))
+    else:
+        cols = [jnp.broadcast_to(a[:, 0, :, None], (S, n, lanes))
+                for a in (B, C)]
+        col = pl.BlockSpec((1, n, lanes), lambda s, j, *_: (s, 0, 0))
     slab = pl.BlockSpec((None, None, rows, n, lanes),
                         lambda s, j, _, which: (which[0], s, j, 0, 0))
     y, pool = pl.pallas_call(
-        functools.partial(_update_kernel, rows=rows),
+        functools.partial(_update_kernel, rows=rows,
+                          **({"per_head": True} if per_head else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(S, hk // rows),
             in_specs=[row, row, col, col, slab], out_specs=[row, slab]),
@@ -290,10 +315,13 @@ def pallas_ssm_state_update(x, dt, A, B, C, D, pool, layer=0, active=None,
 
 def state_update_supported(x, dt, A, B, C, D, pool, layer=0, active=None,
                            fresh=None):
-    """The kernel's shapes: one group (``B``/``C`` are one column a slot), a
-    float32 pool whose lanes are full and whose ``n`` fills whole
-    sublanes."""
-    return (B.shape[1] == 1 and pool.dtype == jnp.float32
+    """The kernel's shapes: one group (``B``/``C`` are one column a slot),
+    or a group a head whose width is the lanes' (lightning attention: 32
+    heads of 128, a column a head); a float32 pool whose lanes are full and
+    whose ``n`` fills whole sublanes."""
+    groups_ok = B.shape[1] == 1 or (B.shape[1] == x.shape[1]
+                                    and x.shape[2] == LANES)
+    return (groups_ok and pool.dtype == jnp.float32
             and pool.shape[-1] == LANES and pool.shape[-2] % 8 == 0)
 
 

@@ -286,6 +286,11 @@ class ServingTelemetry:
             "prefill kernel and not by the row gather "
             "(ops.sparse_index.masked_prefill of the step's reach)")
 
+        # ---- a selection by blocks (block_topk): made by
+        # set_block_selection, so another model's registry has no such name
+        self.block_layers = 0
+        self.c_blk_rows = self.c_blk_kept = None
+
         # ---- state layers (layer_types): the rows they mix by path, and
         # the resident that does not grow, one state slot a sequence; under
         # the names of the kind the model has ("ssm": Mamba-2 scan layers,
@@ -483,6 +488,30 @@ class ServingTelemetry:
             for k, v in self.kv_bytes_groups.items():
                 self.g_kv_bytes.set(v, part=k, **self.labels)
 
+    def set_block_selection(self, layers: int) -> None:
+        """A model whose attention layers select their keys by blocks
+        (``GPTConfig.block_topk``), once at start-up: how many layers
+        select."""
+        self.block_layers = int(layers)
+        if self.enabled:
+            self.c_blk_rows = self.registry.counter(
+                "serving_block_rows_total", "rows through the layers that "
+                "select by blocks, rows times selecting layers, per path "
+                "(dense = context within block_dense_len, every key read / "
+                "sparse = past it, block_topk blocks kept a KV head)")
+            self.c_blk_kept = self.registry.counter(
+                "serving_block_kept_blocks_total", "blocks the sparse rows "
+                "kept, a KV head (block_topk a row), summed over the "
+                "selecting layers")
+
+    def block_rows(self, dense: int, sparse: int, kept_blocks: int) -> None:
+        """One dispatch's rows through the selecting layers by path, and
+        the blocks its sparse rows kept."""
+        if self.enabled and self.block_layers:
+            self.c_blk_rows.inc(dense, path="dense", **self.labels)
+            self.c_blk_rows.inc(sparse, path="sparse", **self.labels)
+            self.c_blk_kept.inc(kept_blocks, **self.labels)
+
     def set_scan_state(self, layers: int, bytes_per_slot: int,
                        kind: str = "ssm") -> None:
         """A model with state layers, once at start-up: how many it has,
@@ -582,6 +611,13 @@ class ServingTelemetry:
                 sel_masked_steps=int(
                     self.c_sel_masked.value(**self.labels)),
                 global_pairs=int(pairs))
+        if self.block_layers:
+            note.update(
+                blk_dense_rows=int(self.c_blk_rows.value(
+                    path="dense", **self.labels)),
+                blk_sparse_rows=int(self.c_blk_rows.value(
+                    path="sparse", **self.labels)),
+                blk_kept_blocks=int(self.c_blk_kept.value(**self.labels)))
         if self.scan_layers:
             slots = state.scan_slots_in_use
             self.g_ssm_slots.set(slots, **self.labels)

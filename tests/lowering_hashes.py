@@ -1,8 +1,8 @@
-"""The lowered serving step programs of three accepted configurations at
-their ``rehearsal`` sizes, hashed: what ``tests/test_mimo_v2_engine.py`` holds
-against the hashes recorded on the commit before per-head sinks, unequal K/V
-widths and a pool a page group came in (a model with none of them must take
-none of the new branches: its programs lower to the same text).
+"""The lowered serving step programs of the accepted serving configurations
+(all eight since PR 57; three until then) at their ``rehearsal`` sizes,
+hashed: what ``tests/test_step_lowering_hashes.py`` holds against the hashes
+recorded on the parent commit (a model with none of a PR's new mechanisms must
+take none of its new branches: its programs lower to the same text).
 
     python3 tests/lowering_hashes.py [--root <checkout>]
 
@@ -18,7 +18,9 @@ import os
 import sys
 
 CONFIGS = ("mistral-7b-v0.3-16l", "trinity-large-preview-5l-ep8",
-           "granite-4.0-h-micro")
+           "granite-4.0-h-micro", "moonlight-16b-a3b-7l",
+           "dots3-note-prev-5l-ep8", "lfm2-24b-a2b-10l",
+           "xing4.0-29b-a4b-7l", "mimo-v2-flash-7l-ep16")
 
 
 def record_programs(eng):
@@ -41,7 +43,7 @@ def record_programs(eng):
     return seen
 
 
-def hashes(root):
+def hashes(root, configs=CONFIGS):
     sys.path[:0] = [root, os.path.join(root, "benchmark"),
                     os.path.join(root, "benchmark", "reference")]
     import importlib.util
@@ -53,7 +55,7 @@ def hashes(root):
     from deepspeed_tpu.models import GPTConfig
 
     out = {}
-    for name in CONFIGS:
+    for name in configs:
         with open(os.path.join(root, "benchmark", "configs",
                                name + ".json")) as f:
             cfg = json.load(f)

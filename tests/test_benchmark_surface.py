@@ -23,6 +23,10 @@ lost name fails the case that carries it and no other.
 (g) ``SELECTING_READS``: how often a selecting model's prompt chunks take
     the masked prefill kernel, as a counter and in the dispatch spans (PR
     43; no manifest entry reads them yet, a ``benchmark`` issue may).
+(h) ``BLOCK_READS``: what ``benchmark/readers/sala.py``, ``span_counters.py``
+    and the serving runner take of a model that selects its keys by blocks
+    (PR 57): the rows by path, the kept blocks and pairs, each dispatch's
+    own needs.
 The other spans and their arguments are held by ``tests/test_one_clock.py``."""
 
 import dataclasses
@@ -871,3 +875,88 @@ SELECTING_READS = {
 @pytest.mark.parametrize("name", list(SELECTING_READS))
 def test_selecting_reads(selecting_notes, name):
     SELECTING_READS[name](selecting_notes)
+
+
+# ---------- (h) a selection by blocks: rows by path, kept pairs, the needs
+
+@pytest.fixture(scope="module")
+def block_notes():
+    """``_ctx_note`` of a model that selects by blocks (MiniCPM-SALA's
+    sizes: 64 blocks of 64 past 8,192, three selecting layers), asked as
+    ``_step_sampled`` asks it for a mixed step (a chunk of 1,024 rows that
+    crosses ``dense_len`` beside a rider past it and one within it) and as
+    ``_build_burst`` does for a burst of 8: (telemetry, the two notes with
+    the running totals a dispatch span carries)."""
+    import types
+
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    from deepspeed_tpu.ops.block_select import BlockGeometry
+    from deepspeed_tpu.telemetry.serving import ServingTelemetry
+    tel = ServingTelemetry(pid=0)
+    tel.set_block_selection(3)
+    eng = types.SimpleNamespace(
+        telemetry=tel, _block_size=128, model_config=types.SimpleNamespace(
+            index_topk=0, max_seq_len=66048, num_layers=12, sliding_window=0,
+            attention_layers=(0, 7, 8),
+            block_geometry=BlockGeometry(32, 16, 64, 64, 2048, 1, 8192)))
+    eng._block_note = lambda *a: InferenceEngineV2._block_note(eng, *a)
+    notes = [InferenceEngineV2._ctx_note(eng, [7680, 20000, 5000],
+                                         [1024, 1, 1])]
+    notes[-1].update(tel.counter_note(None))
+    notes.append(InferenceEngineV2._ctx_note(eng, [20001, 5001], steps=8))
+    notes[-1].update(tel.counter_note(None))
+    return tel, notes
+
+
+def _block_rows(o):
+    # the chunk's rows at positions 7,680-8,191 and the rider at 5,000 are
+    # dense; 8 steps of the second slot too; on three layers
+    assert [n["blk_dense_rows"] for n in o[1]] == [3 * 513, 3 * (513 + 8)]
+    assert [n["blk_sparse_rows"] for n in o[1]] == [3 * 513, 3 * (513 + 8)]
+    assert o[1][-1]["blk_kept_blocks"] == 64 * 3 * (513 + 8)
+    tel = o[0]
+    assert tel.registry._metrics["serving_block_rows_total"].value(
+        path="sparse", **tel.labels) == 3 * 521
+    assert tel.registry._metrics["serving_block_kept_blocks_total"].value(
+        **tel.labels) == 64 * 3 * 521
+
+
+def _block_needs(o):
+    mixed, burst = o[1]
+    chunk = sum(63 * 64 + t % 64 + 1 for t in range(8192, 8704))
+    rider = 63 * 64 + 20000 % 64 + 1
+    assert mixed["blk_pairs_step"] == chunk + rider
+    assert mixed["blk_pairs_one_row"] == rider
+    assert mixed["blk_ctx_chunk"] == 8704
+    assert mixed["blk_pooled_pairs"] == sum((t - 31) // 16 + 1
+                                            for t in range(8192, 8704))
+    assert mixed["blk_pooled_chunk"] == (8704 - 31) // 16 + 1
+    # a burst: a row a slot a step, every one its slot's own
+    assert burst["blk_pairs_one_row"] == burst["blk_pairs_step"] == sum(
+        63 * 64 + t % 64 + 1 for t in range(20001, 20009))
+    assert burst["blk_ctx_chunk"] == burst["blk_pooled_pairs"] == 0
+
+
+def _block_pairs(o):
+    # kept < causal once rows select; what the runner's need counters and
+    # ``index_selected_share.sparse`` read
+    last = o[1][-1]
+    assert 0 < last["sel_pairs"] < last["global_pairs"]
+    assert last["index_pairs"] > 0
+    tel = o[0]
+    assert tel.c_sel_pairs.value() == last["sel_pairs"]
+    assert tel.c_index_pairs.value() == last["index_pairs"]
+
+
+BLOCK_READS = {
+    "ds.*_dispatch.blk_dense_rows / blk_sparse_rows / blk_kept_blocks":
+        _block_rows,
+    "ds.*_dispatch.blk_pairs_step / blk_pairs_one_row / blk_ctx_chunk / "
+    "blk_pooled_pairs / blk_pooled_chunk": _block_needs,
+    "ds.*_dispatch.sel_pairs / global_pairs / index_pairs": _block_pairs,
+}
+
+
+@pytest.mark.parametrize("name", list(BLOCK_READS))
+def test_block_selection_reads(block_notes, name):
+    BLOCK_READS[name](block_notes)

@@ -464,6 +464,69 @@ def test_scan_state_pool_stays_in_place(topo, monkeypatch):
         assert compiled.memory_analysis().alias_size_in_bytes >= pools, name
 
 
+# one attention layer (it selects by blocks) and one lightning layer of
+# MiniCPM-SALA at its published widths
+SALA_2L = GPTConfig(
+    vocab_size=4096, num_layers=2, num_heads=32, num_kv_heads=2, head_dim=128,
+    hidden_size=4096, mlp_dim_override=16384, max_seq_len=66048,
+    use_rope=True, rope_layers="state", use_rmsnorm=True, gated_mlp=True,
+    norm_eps=1e-6, qk_norm=True, attn_gate=True, tie_embeddings=False,
+    layer_types=("attention", "lightning"), ssm_heads=32, ssm_head_dim=128,
+    ssm_state=128, ssm_groups=32, ssm_chunk=128, embed_scale=12.0,
+    residual_scale=0.2475, logits_divisor=16.0, block_topk=64)
+
+
+def test_block_selection_and_lightning_state_leave_their_pools_in_place(
+        topo, monkeypatch):
+    """The step programs of MiniCPM-SALA at published widths (a table of 516
+    pages for 66,048 positions, a forward of 1,024 rows, 32 slots) compile
+    for the chip, and none of them copies, slices or RE-LAYS a pool: the key
+    and value pages (every gather of blocks and of a pooled key's 32 keys
+    takes rows of a free two-dimensional view: asked for any other way the
+    compiler transposes the pool around the gather; a ``reshape`` that
+    splits the head width is a copy under the chip's tiling too), the pooled
+    keys, and the float32 lightning state, which the recurrence kernel
+    updates in place with a column a head and the mixed step's loop keeps
+    row-major (``_row_major``).  The one-row slots past ``dense_len`` read
+    their kept blocks through the paged decode kernel (a view of the pool
+    whose pages are one block of one kv head)."""
+    from conftest import lower_serving_steps
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(SALA_2L, dtype=BF16, param_dtype=BF16,
+                              attn_impl="pallas")
+    S, pages = 32, 2304        # (so that no row array is as large as a pool)
+    _, cache, lowered = lower_serving_steps(
+        cfg, BF16, slots=S, tokens=1024, max_q=1024, table_width=516,
+        block_size=128, num_pages=pages, steps=8,
+        sharding=SingleDeviceSharding(topo.devices[0]))
+    assert cache.ssm.shape == (1, S, 32, 128, 128) and cache.conv is None
+    assert cache.k.shape == (1, pages, 2, 128, 128)
+    assert cache.ki.shape == (1, pages, 8, 2, 128)
+    pools = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                for a in (cache.ssm, cache.k, cache.v, cache.ki))
+    sizes = {(dt, int(np.prod(a.shape))) for dt, a in (
+        ("f32", cache.ssm), ("bf16", cache.k), ("bf16", cache.ki))}
+    for name, low in lowered.items():
+        compiled = low.compile()
+        text = compiled.as_text()
+        assert "ssm_state_update" in text, name     # the kernel, by its name
+        assert "paged_decode" in text, name
+        assert ("ragged_prefill" in text) == ("forward" in name), name
+        moved = []
+        for result, op in _HLO_OP.findall(text):
+            if op not in POOL_MOVERS + ("transpose", "reshape"):
+                continue
+            for dt, dims in _HLO_ARRAY.findall(result):
+                n = int(np.prod([int(d) for d in dims.split(",") if d]
+                                or [1]))
+                if (dt, n) in sizes:                    # a whole pool
+                    moved.append(f"{op} -> {dt}[{dims}]")
+        assert not moved, (name, moved)
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= pools, name
+        assert mem.temp_size_in_bytes < 256 << 20, name
+
+
 # the three kinds of LFM2-24B-A2B's layers at its published widths: a dense
 # conv layer, an attention layer and a conv layer with all 64 experts
 LFM2_3L = GPTConfig(

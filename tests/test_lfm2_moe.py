@@ -118,7 +118,8 @@ def test_layer_kinds(cfg):
     assert [cfg.is_moe_layer(i) for i in range(6)] == [False] * 2 + [True] * 4
     with pytest.raises(ValueError, match="short-convolution layer"):
         cfg.for_layer(0)
-    with pytest.raises(ValueError, match="attention\\|mamba\\|conv"):
+    with pytest.raises(ValueError,
+                       match="attention\\|mamba\\|lightning\\|conv"):
         dataclasses.replace(cfg, layer_types=("conv",) * 5 + ("lstm",)
                             ).layer_kind(5)
     from deepspeed_tpu.inference.v2.model import state_mixer

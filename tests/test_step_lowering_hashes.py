@@ -9,11 +9,20 @@ hashes and says so; one that only adds a path beside them must leave them.
 PR 56 re-recorded Trinity's two: its expert layers permute by counts and
 combine by a gather (``moe/layer.py:_expert_ffn_ragged``), which is what
 those programs are; Mistral's and granite's, which have no expert layer, are
-the parent's still."""
+the parent's still.
+
+PR 57 (MiniCPM-SALA: lightning layers through the state layers' one path, a
+selection by blocks, a selection a KV head in the prefill kernel's masked
+form, the recurrence kernel with a column a head) holds ALL EIGHT accepted
+serving configurations, a case each, to the hashes of its parent (2820e62,
+``tests/lowering_hashes.py --root <its checkout>``): the six above are as
+they were, and Moonlight's, dots3's, LFM2's, Xing4.0's and MiMo's are
+recorded for the first time."""
 
 import os
 
-from lowering_hashes import hashes
+import pytest
+from lowering_hashes import CONFIGS, hashes
 
 PARENT = {
     "mistral-7b-v0.3-16l/mixed": "3c1f760476dc84ef",
@@ -22,9 +31,21 @@ PARENT = {
     "trinity-large-preview-5l-ep8/decode": "6f71dcd8f7faf3aa",
     "granite-4.0-h-micro/mixed": "722644b634f466bf",
     "granite-4.0-h-micro/decode": "dcb5c7f153d3fd4d",
+    "moonlight-16b-a3b-7l/mixed": "eff6a6cff871d3f8",
+    "moonlight-16b-a3b-7l/decode": "c3ac911e409692c5",
+    "dots3-note-prev-5l-ep8/mixed": "6b8b2f2310b1a76e",
+    "dots3-note-prev-5l-ep8/decode": "b24ccb3814d6c331",
+    "lfm2-24b-a2b-10l/mixed": "175efd30c848ee5b",
+    "lfm2-24b-a2b-10l/decode": "71305237b56f37db",
+    "xing4.0-29b-a4b-7l/mixed": "66f4cccb861aa1e0",
+    "xing4.0-29b-a4b-7l/decode": "ab0919732662d05b",
+    "mimo-v2-flash-7l-ep16/mixed": "d7e7b10e65812786",
+    "mimo-v2-flash-7l-ep16/decode": "9049e59634f77f99",
 }
 
 
-def test_the_accepted_step_programs_lower_as_on_the_parent():
+@pytest.mark.parametrize("config", CONFIGS)
+def test_the_accepted_step_programs_lower_as_on_the_parent(config):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    assert hashes(root) == PARENT
+    assert hashes(root, (config,)) == {
+        k: v for k, v in PARENT.items() if k.startswith(config + "/")}
