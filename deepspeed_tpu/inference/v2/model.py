@@ -1405,15 +1405,17 @@ def _block_mixed_attention(q, rows: _MixedRows, pages: _LayerPages, row_pos,
 # its conv's tail).  Scopes nest inside the attention scopes, so that a
 # reader that knows only those still files every operation.  A scan layer:
 # the input projection under attn_qkv/ssm_in_proj, the conv and the scan
-# under attn_kernel/ssm_conv and attn_kernel/ssm_scan, the write-back of a
-# slot's state and conv tail under kv_write/ssm_scan, the gated norm and the
-# output projection under attn_out/ssm_gate_norm.  A short-conv layer:
+# under attn_kernel/ssm_conv and attn_kernel/ssm_scan (both routes update a
+# slot's state where it lies in the pool), the write-back of a slot's conv
+# tail under kv_write/ssm_scan, the gated norm and the output projection
+# under attn_out/ssm_gate_norm.  A short-conv layer:
 # attn_qkv/conv_in_proj, attn_kernel/short_conv, kv_write/short_conv,
 # attn_out/conv_out_proj.
 
-SCAN_CHUNK = 128    # rows a chunk of the mixed step's scan, at most: the
-#                     decay matrix [slots, heads, chunk, chunk] float32 is
-#                     written and read once a chunk, and grows with the chunk
+SCAN_CHUNK = 128    # rows a chunk of the mixed step's scan, at most: a head's
+#                     decay matrix [chunk, chunk] float32 is 16 vector
+#                     registers in the kernel (ops.ssm_pool_chunk_scan), and
+#                     the work within a chunk grows with its square
 
 
 class _Mamba2:
@@ -1483,22 +1485,15 @@ class _Mamba2:
         return y.reshape(y.shape[0], -1), ssm
 
     @classmethod
-    def chunk(cls, mp, out, dt, ssm, si, slots, fresh, count, cfg):
+    def chunk(cls, mp, out, dt, ssm, si, slots, fresh, count, live, cfg):
         """A pass's conv'd prompt chunks ``out [G, Q, channels]`` through
-        the chunked scan from their slots' states -> (y [G, Q, inner]
-        float32, the states to write back)."""
+        the chunked scan from their slots' states, in place in layer ``si``
+        of the state pool -> (y [G, Q, inner] float32, ssm')."""
         from deepspeed_tpu import ops
-        from deepspeed_tpu.ops.ssm_scan import pack_state, unpack_state
         A, D = cls._scan_params(mp)
-        state = jnp.where(
-            fresh[:, None, None, None], 0.0,
-            unpack_state(ssm[si, slots], cfg.ssm_head_dim))
-        x, B, C = cls._xbc(out, cfg)
-        y, state = ops.ssm_chunk_scan(
-            x, dt, A, B, C, D, state, (None, count),
+        return ops.ssm_pool_chunk_scan(
+            out, dt, A, D, ssm, si, slots, count, fresh, live,
             chunk=min(cfg.ssm_chunk, SCAN_CHUNK))
-        G, Q = out.shape[:2]
-        return y.reshape(G, Q, -1), pack_state(state)
 
     @staticmethod
     def output(mp, y, z, cfg, mesh=None):
@@ -1546,27 +1541,6 @@ class _Lightning(_Mamba2):
         heads = mp["w_out"].shape[0] // mp["norm"].shape[0]
         return (jnp.asarray(lightning_decay(heads)),
                 jnp.zeros((heads,), jnp.float32))
-
-    @classmethod
-    def chunk(cls, mp, out, dt, ssm, si, slots, fresh, count, cfg):
-        """``_Mamba2.chunk`` over the states AS THE POOL HOLDS THEM: a head
-        is the lanes' width, so a packed state is ``[h, n, p]`` and the scan
-        takes it so (``swapped``); unpacked it would be a transpose that the
-        compiler turns into a layout of the whole pool, copied in and out
-        of every mixed step (tests/test_chip_compile.py)."""
-        from deepspeed_tpu import ops
-        from deepspeed_tpu.ops.ssm_scan import lane_heads
-        if lane_heads(cfg.ssm_heads, cfg.ssm_head_dim) != 1:
-            return super().chunk(mp, out, dt, ssm, si, slots, fresh, count,
-                                 cfg)
-        A, D = cls._scan_params(mp)
-        state = jnp.where(fresh[:, None, None, None], 0.0, ssm[si, slots])
-        x, B, C = cls._xbc(out, cfg)
-        y, state = ops.ssm_chunk_scan(
-            x, dt, A, B, C, D, state, (None, count),
-            chunk=min(cfg.ssm_chunk, SCAN_CHUNK), swapped=True)
-        G, Q = out.shape[:2]
-        return y.reshape(G, Q, -1), state
 
     @staticmethod
     def output(mp, y, gate, cfg, mesh=None):
@@ -1622,8 +1596,8 @@ class _ShortConv:
         return out, ssm
 
     @staticmethod
-    def chunk(mp, out, aux, ssm, si, slots, fresh, count, cfg):
-        return out, None
+    def chunk(mp, out, aux, ssm, si, slots, fresh, count, live, cfg):
+        return out, ssm
 
     @staticmethod
     def output(mp, v, gate, cfg, mesh=None):
@@ -1717,8 +1691,9 @@ def _scan_rows_many(mp, u, aux, scan, si, plan: _ScanPlan,
     more than one row take (a loop whose trip count the device reads).  A
     pass gathers its slots' rows out of the token-major ``u [N, channels]``
     (and ``aux``) ONCE into ``[group, Q, ...]``, convolves them from the
-    slots' tails, takes the mixer's update from the slots' states, scatters
-    ``y`` back and writes tails and states in place.  -> (y [N, width], zero
+    slots' tails, takes the mixer's update of the slots' states in place in
+    the pool (``ops.ssm_pool_chunk_scan``), scatters ``y`` back and writes
+    the tails in place.  -> (y [N, width], zero
     on rows of no prompt chunk, scan')."""
     from deepspeed_tpu import ops
     from deepspeed_tpu.ops.ssm_scan import segment_rows
@@ -1753,17 +1728,14 @@ def _scan_rows_many(mp, u, aux, scan, si, plan: _ScanPlan,
                         _conv_tail(conv, si, slots, fresh, mixer, cfg),
                         count, activation=mixer.activation)
             with jax.named_scope(mixer.scope):
-                y_pass, state = mixer.chunk(
+                y_pass, ssm = mixer.chunk(
                     mp, out, None if aux is None else aux[read], ssm, si,
-                    slots, fresh, count, cfg)
+                    slots, fresh, count, live, cfg)
                 y = y.at[write].set(y_pass, mode="drop")
-        with jax.named_scope("kv_write"), jax.named_scope(mixer.scope):
-            dst = jnp.where(live, slots, S)
-            if state is not None:
-                ssm = ssm.at[si, dst].set(state, mode="drop")
-            if tail is not None:
-                conv = conv.at[si, dst].set(tail.reshape(G, -1),
-                                            mode="drop")
+        if tail is not None:
+            with jax.named_scope("kv_write"), jax.named_scope(mixer.scope):
+                conv = conv.at[si, jnp.where(live, slots, S)].set(
+                    tail.reshape(G, -1), mode="drop")
         return i + 1, y, ssm, conv
 
     with jax.named_scope("attn_kernel"), jax.named_scope(mixer.scope):

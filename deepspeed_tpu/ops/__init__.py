@@ -208,6 +208,9 @@ register_op("ssm_chunk_scan", xla=_ssm.xla_ssm_chunk_scan)
 register_op("ssm_state_update", xla=_ssm.xla_ssm_state_update,
             pallas=_ssm.pallas_ssm_state_update,
             supported=_ssm.state_update_supported)
+register_op("ssm_pool_chunk_scan", xla=_ssm.xla_ssm_pool_chunk_scan,
+            pallas=_ssm.pallas_ssm_pool_chunk_scan,
+            supported=_ssm.pool_chunk_scan_supported)
 # (SiLU after the conv by default, a Mamba-2 layer's; activation=None: none,
 # a short-conv layer's)
 register_op("causal_conv1d", xla=_ssm.xla_causal_conv1d)
@@ -232,6 +235,17 @@ def ssm_state_update(x, dt, A, B, C, D, pool, layer=0, active=None,
     (ops/ssm_scan.py)."""
     return dispatch("ssm_state_update", x, dt, A, B, C, D, pool, layer,
                     active, fresh, impl=impl)
+
+
+def ssm_pool_chunk_scan(xBC, dt, A, D, pool, layer, slots, count, fresh, live,
+                        *, chunk: int, impl: Optional[str] = None):
+    """A mixed step's pass of prompt chunks (the conv's rows ``[x | B | C]``)
+    through a scan layer, each from its slot's state in layer ``layer`` of a
+    packed state pool and the state written back in place -> (y float32,
+    pool') (ops/ssm_scan.py).  Forward only: what is differentiated takes
+    ``ssm_chunk_scan``."""
+    return dispatch("ssm_pool_chunk_scan", xBC, dt, A, D, pool, layer, slots,
+                    count, fresh, live, chunk=chunk, impl=impl)
 
 
 def causal_conv1d(xBC, w, b, tail, count=None, *, activation="silu",
@@ -321,4 +335,5 @@ __all__ = ["causal_attention", "flash_attention", "configure_flash_blocks",
            "lm_cross_entropy", "masked_nll_sum", "rms_norm", "layer_norm",
            "op_report", "register_op", "dispatch", "list_ops", "registry",
            "grouped_gemm", "ssm_chunk_scan", "ssm_state_update",
+           "ssm_pool_chunk_scan",
            "causal_conv1d", "paged_kv_append"]

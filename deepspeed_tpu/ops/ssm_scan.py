@@ -28,9 +28,14 @@ with ``segments = (start [G], count [G])``, segment ``g`` being rows ``start[g]
 back as it went in); rows are gathered into the dense layout (``max_len``
 wide, static), scanned, and scattered back, rows of no segment reading 0.
 
-These are the XLA forms, registered in ``ops/__init__.py``; they are what
-the CPU tests run and the reference's recurrence
-(``benchmark/reference/_granite_hybrid.py``) is compared with.
+The XLA forms are what the CPU tests run and the reference's recurrence
+(``benchmark/reference/_granite_hybrid.py``) is compared with; all are
+registered in ``ops/__init__.py``.  The serving engine's two operations on
+its packed state pool have a Pallas kernel each, which the registry takes on
+the chip where the shapes allow: ``ssm_state_update`` (one row a slot) and
+``ssm_pool_chunk_scan`` (a mixed step's pass of prompt chunks: the chunked
+scan above, reading and writing each chunk's slot in the pool as it lies).
+``ssm_chunk_scan`` itself, which training differentiates, is XLA only.
 """
 
 from __future__ import annotations
@@ -165,8 +170,11 @@ def xla_ssm_chunk_scan(x, dt, A, B, C, D, state0, segments=None, *,
 # the v5e, PERF.md section 6, PR 44); packed, ``dt x`` and the decay are lane
 # vectors a head group, broadcast down the sublanes, ``B`` and ``C`` are
 # columns, and ``y`` is a sublane reduction: every operand lies as the vector
-# unit wants it.  The chunked scan keeps ``[h, p, n]``, which its matmuls
-# want, and packs the few states it touches (``pack_state``).
+# unit wants it.  The chunked scan's products take the packed layout as it
+# is (``C [c, n] @ S [n, k p]`` is ``y`` on the lanes, ``B^T [n, c] @ wx [c,
+# k p]`` the state's growth), which is what the in-pool kernel does; only
+# the XLA form of ``ssm_pool_chunk_scan`` unpacks the few states it touches
+# to ``[h, p, n]`` and packs them again (``pack_state``).
 
 LANES = 128
 
@@ -278,7 +286,7 @@ def pallas_ssm_state_update(x, dt, A, B, C, D, pool, layer=0, active=None,
     x, xdt, decay, B, C, active, fresh = _update_operands(
         x, dt, A, B, C, active, fresh)
     hk, n, lanes = pool.shape[2:]
-    rows = 8 if hk % 8 == 0 else hk
+    rows = _groups_a_block(hk)
     flags = active.astype(jnp.int32) + 2 * fresh.astype(jnp.int32)
     which = jnp.asarray(layer, jnp.int32).reshape(1)   # static or traced
     row = pl.BlockSpec((1, rows, lanes), lambda s, j, *_: (s, j, 0))
@@ -323,6 +331,277 @@ def state_update_supported(x, dt, A, B, C, D, pool, layer=0, active=None,
                                     and x.shape[2] == LANES)
     return (groups_ok and pool.dtype == jnp.float32
             and pool.shape[-1] == LANES and pool.shape[-2] % 8 == 0)
+
+
+# ------------------------------------------- prompt chunks, in the pool
+# A mixed step's pass: ``G`` prompt chunks of up to ``Q`` rows, each scanned
+# from its slot's state in layer ``layer`` of the packed pool, the state
+# written back where it lay.  Forward only and in the pool, so an op of its
+# own beside ``ssm_chunk_scan`` (which the training mixers differentiate).
+
+def _groups_a_block(hk: int) -> int:
+    """Head groups a block of either kernel over the pool (512 KB of state
+    at n = 128)."""
+    return 8 if hk % 8 == 0 else hk
+
+
+def _pool_sizes(xBC, dt, pool):
+    """(h, p, g, n, k) of a pass whose conv'd rows are ``xBC [..., h p + 2 g
+    n]`` (x, then B, then C) over a pool ``[layers, S, h / k, n, k p]``."""
+    h = dt.shape[-1]
+    hk, n, lanes = pool.shape[2:]
+    k = h // hk
+    p = lanes // k
+    return h, p, (xBC.shape[-1] - h * p) // (2 * n), n, k
+
+
+def xla_ssm_pool_chunk_scan(xBC, dt, A, D, pool, layer, slots, count, fresh,
+                            live, *, chunk: int):
+    """``xBC [G, Q, h p + 2 g n]`` (the rows as the conv leaves them: x, then
+    B, then C), ``dt [G, Q, h]``, ``pool [layers, S, h / k, n, k * p]``
+    float32 -> (y [G, Q, h p] float32, pool').  Lane ``i`` scans the first
+    ``count[i]`` rows of its chunk from the state of slot ``slots[i]`` (from
+    zero where ``fresh[i]``) and leaves the state behind them there; a lane
+    that is not ``live`` leaves its slot as it is, and ``y`` behind a lane's
+    count is the caller's to drop.  The XLA form gathers the pass's states,
+    scans them (``xla_ssm_chunk_scan``; a head the lanes' width is scanned
+    as the pool holds it, any other unpacked and packed again) and scatters
+    them back."""
+    G, Q = xBC.shape[:2]
+    S = pool.shape[1]
+    h, p, g, n, k = _pool_sizes(xBC, dt, pool)
+    x, B, C = (a.reshape(G, Q, -1, w) for a, w in zip(
+        jnp.split(xBC, (h * p, h * p + g * n), axis=-1), (p, n, n)))
+    held = pool[layer, slots]
+    as_held = k == 1                           # [h, n, p]: ``swapped``
+    state = jnp.where(fresh[:, None, None, None], 0.0,
+                      held if as_held else unpack_state(held, p))
+    y, state = xla_ssm_chunk_scan(
+        x, dt, A, B, C, D, state, (None, count), chunk=chunk,
+        swapped=as_held)
+    return y.reshape(G, Q, h * p), pool.at[
+        layer, jnp.where(live, slots, S)].set(
+            state if as_held else pack_state(state), mode="drop")
+
+
+def _pool_chunk_kernel(slot, flags, count, _, x, b, c, cols, rows, d, held,
+                       y, st, *, groups, k, per_head, lo):
+    """One grid step: ``groups`` head groups of one lane's slot through one
+    chunk of ``x.shape[0]`` rows.  ``cols`` (rows down the sublanes, a head a
+    lane: the sum of ``dt A`` through a row, its exp, and ``dt`` times the
+    decay from the row to the chunk's end) and ``rows`` (a head a sublane,
+    rows along the lanes: the same sum, and ``dt``) are what XLA made of
+    ``dt``.  ``st`` is the slot's block of the pool, resident from the
+    lane's first chunk to its last."""
+    import jax.experimental.pallas as pl
+    g, ci = pl.program_id(0), pl.program_id(2)
+    f = flags[g]
+    live, fresh = (f & 1) == 1, (f & 2) == 2
+    rows_n, lanes = x.shape[0], LANES
+    n = st.shape[1]
+
+    @pl.when(live & (ci == 0))
+    def _():
+        st[...] = jnp.where(fresh, 0.0, held[...])
+
+    @pl.when(jnp.logical_not(live) & ((flags[0] & 1) == 0))
+    def _():                  # no lane is live: the block goes back as it came
+        st[...] = held[...]
+
+    busy = live & (ci * rows_n < count[g])
+
+    @pl.when(jnp.logical_not(busy))
+    def _():
+        y[...] = jnp.zeros(y.shape, y.dtype)
+
+    @pl.when(busy)
+    def _():
+        i = jax.lax.broadcasted_iota(jnp.int32, (rows_n, rows_n), 0)
+        j = jax.lax.broadcasted_iota(jnp.int32, (rows_n, rows_n), 1)
+        low = i >= j
+        head = jax.lax.broadcasted_iota(
+            jnp.int32, (rows_n, lanes), 1) // (lanes // k)
+
+        def mm(a, bb, dims, precision=None):
+            return jax.lax.dot_general(a, bb, (dims, ((), ())),
+                                       precision=precision,
+                                       preferred_element_type=F32)
+
+        def mm_state(col, full, dims):
+            """``col`` (B or C) times a float32 operand at ``HIGHEST``.
+            That is six bfloat16 passes over both operands' three parts;
+            where ``col`` IS bfloat16 its lower parts are zero and the three
+            passes over ``full``'s parts are all of them."""
+            if col.dtype != jnp.bfloat16:
+                return mm(col.astype(F32), full, dims, _HI)
+            parts = []
+            for _ in range(3):
+                parts.append(full.astype(jnp.bfloat16))
+                full = full - parts[-1].astype(F32)
+            return sum(mm(col, part, dims) for part in reversed(parts))
+        if not per_head:                  # one group: C B^T once a chunk
+            bg, cg = b[...], c[...]
+            cb = mm(lo(cg), lo(bg), ((1,), (1,)))
+        for r in range(groups):           # static: lane slices of a block
+            at = slice(r * lanes, (r + 1) * lanes)
+            xr, x_mxu = x[:, at].astype(F32), lo(x[:, at])
+            if per_head:                  # a group a head: its own C B^T
+                bg, cg = b[:, r * n:(r + 1) * n], c[:, r * n:(r + 1) * n]
+                cb = mm(lo(cg), lo(bg), ((1,), (1,)))
+            y_in = e_in = w_out = through = None
+            for m in range(k):            # the heads side by side on the lanes
+                hh = r * k + m
+                cum = cols[0, :, hh:hh + 1]
+                decay = jnp.exp(jnp.where(
+                    low, cum - rows[0, hh:hh + 1, :], -jnp.inf))
+                w = cb * decay * rows[1, hh:hh + 1, :]
+                got = (mm(lo(w), x_mxu, ((1,), (0,))),
+                       cols[1, :, hh:hh + 1], cols[2, :, hh:hh + 1],
+                       # through the chunk: the last row's (picked by a
+                       # masked sum: a slice at sublane 7 does not broadcast)
+                       jnp.exp(jnp.sum(jnp.where(i[:, :1] == rows_n - 1, cum,
+                                                 0.0), axis=0, keepdims=True)))
+                if m == 0:
+                    y_in, e_in, w_out, through = (
+                        jnp.broadcast_to(a, (a.shape[0], lanes)) for a in got)
+                else:
+                    y_in, e_in, w_out, through = (
+                        jnp.where(head[:a.shape[0]] == m, a, was) for a, was
+                        in zip(got, (y_in, e_in, w_out, through)))
+            old = st[r]
+            y[:, at] = (y_in + mm_state(cg, old, ((1,), (0,))) * e_in
+                        + d[r][None, :] * xr)
+            st[r] = old * through + mm_state(bg, xr * w_out, ((0,), (0,)))
+
+
+def pallas_ssm_pool_chunk_scan(xBC, dt, A, D, pool, layer, slots, count,
+                               fresh, live, *, chunk: int, interpret=None):
+    """``xla_ssm_pool_chunk_scan`` as one kernel over the pool as it lies:
+    the grid walks (lane, block of head groups, chunk), the chunk innermost
+    and in order; a block ``[8 head groups, n, 128 lanes]`` of the lane's
+    slot is read once, carried through the lane's chunks in VMEM and written
+    back once, the pool aliased to the output, and the decay matrix of a
+    chunk never leaves VMEM; x, B and C are blocks of ``xBC``'s columns,
+    so no slice of it is made.  The products that touch a state are float32
+    at ``HIGHEST`` (``mm_state``: where B and C come in bfloat16, as a
+    serving model's conv leaves them, the three passes that is); ``C B^T``
+    and the chunk's own ``(L o C B^T) x`` are at the default precision as in
+    ``_chunk``: on the chip one bfloat16 pass, which a kernel has to ask for
+    by the operands' type.  The live lanes come first, as a pass has them; a
+    lane behind them takes the block the last live lane ended on and does
+    nothing, so no block of its own slot is fetched or written."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _pool_chunk_scan_call(
+        xBC, dt, A, D, pool, jnp.asarray(layer, jnp.int32), slots, count,
+        fresh, live, chunk=chunk, interpret=interpret)
+
+
+# (behind a jit of its own, inlined: every step program traces the mixer
+# again, and the kernel's body, unrolled over a block's heads, is what costs
+# the host; the shapes of a pass are the same in all of them)
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"),
+                   inline=True)
+def _pool_chunk_scan_call(xBC, dt, A, D, pool, layer, slots, count, fresh,
+                          live, *, chunk, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    G, Q = xBC.shape[:2]
+    h, p, g, n, k = _pool_sizes(xBC, dt, pool)
+    hk, lanes = pool.shape[2], pool.shape[4]
+    rows_n = min(chunk, Q)
+    if Q % rows_n:
+        raise ValueError(f"a prompt chunk of {Q} rows is no whole number of "
+                         f"scan chunks of {rows_n}")
+    nc = Q // rows_n
+    groups = _groups_a_block(hk)
+    blocks = hk // groups
+    per_head = g > 1
+    if per_head and (g != h or k != 1):
+        raise ValueError(f"{g} groups over {h} heads of {p}: the kernel "
+                         f"takes one group, or a group a head the lanes' "
+                         f"width")
+    # what XLA makes of dt: [G, Q, h] arrays, a few small ops.  (The sums
+    # within a chunk are a product with a triangle of ones at HIGHEST: a
+    # cumsum is a reduce-window, which read 2.4 ms a mixed step with the
+    # heads of a block minor and 0.35 with all 64, PERF.md section 6, PR 58.)
+    dt = jnp.where(jnp.arange(Q)[None, :, None] < count[:, None, None],
+                   dt.astype(F32), 0.0).reshape(G, nc, rows_n, h)
+    cum = jnp.matmul(jnp.tril(jnp.ones((rows_n, rows_n), F32)),
+                     dt * A.astype(F32), precision=_HI)
+
+    def by_block(*parts):      # [G, chunks, rows, h] each -> a block's heads
+        a = jnp.stack(parts).reshape(len(parts), G, Q, blocks, groups * k)
+        return jnp.transpose(a, (1, 3, 0, 2, 4))
+    cols = by_block(cum, jnp.exp(cum), dt * jnp.exp(cum[:, :, -1:] - cum))
+    rows = jnp.swapaxes(by_block(cum, dt), -1, -2)
+    d = _packed_rows(D.astype(F32)[None], h, p)[0]
+    flags = live.astype(jnp.int32) + 2 * fresh.astype(jnp.int32)
+
+    def block(shape, index):
+        return pl.BlockSpec(shape, lambda gi, bi, ci, *_: index(gi, bi, ci))
+    wide = block((None, rows_n, groups * lanes),
+                 lambda gi, bi, ci: (gi, ci, bi))
+
+    def col(first):        # B's or C's columns of xBC, from column ``first``
+        if per_head:       # (a block's heads' own)
+            return block((None, rows_n, groups * n), lambda gi, bi, ci: (
+                gi, ci, first // (groups * n) + bi))
+        return block((None, rows_n, n),
+                     lambda gi, bi, ci: (gi, ci, first // n))
+
+    def slab_at(gi, bi, ci, slot, flags, count, which):
+        # a dead lane: the last live lane's last block (lane 0's where none
+        # is live), found on the scalar core
+        alive = (flags[gi] & 1) == 1
+        last = jnp.maximum(sum(flags[m] & 1 for m in range(G)) - 1, 0)
+        return (which[0], slot[jnp.where(alive, gi, last)],
+                jnp.where(alive, bi, blocks - 1), 0, 0)
+    slab = pl.BlockSpec((None, None, groups, n, lanes), slab_at)
+    lo = ((lambda a: a.astype(F32)) if interpret
+          else (lambda a: a.astype(jnp.bfloat16)))
+    y, pool = pl.pallas_call(
+        functools.partial(_pool_chunk_kernel, groups=groups, k=k,
+                          per_head=per_head, lo=lo),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(G, blocks, nc),
+            in_specs=[
+                wide, col(h * p), col(h * p + g * n),
+                block((None, None, 3, rows_n, groups * k),
+                      lambda gi, bi, ci: (gi, bi, 0, ci, 0)),
+                block((None, None, 2, groups * k, rows_n),
+                      lambda gi, bi, ci: (gi, bi, 0, 0, ci)),
+                block((groups, lanes), lambda gi, bi, ci: (bi, 0)),
+                slab],
+            out_specs=[wide, slab]),
+        out_shape=[jax.ShapeDtypeStruct((G, Q, h * p), F32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={10: 1}, interpret=interpret,
+        name="ssm_pool_chunk_scan",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+    )(slots.astype(jnp.int32), flags, count.astype(jnp.int32),
+      layer.reshape(1), xBC, xBC, xBC, cols, rows, d, pool)
+    return y, pool
+
+
+def pool_chunk_scan_supported(xBC, dt, A, D, pool, layer, slots, count,
+                              fresh, live, *, chunk: int):
+    """The kernel's shapes: the recurrence kernel's (``state_update_
+    supported``: one group, or a group a head the lanes' width, over a float32
+    pool with full lanes) with a state whose ``n`` fills whole lanes too (a
+    matmul's minor dimension, and the width of B's and C's blocks of
+    ``xBC``), and a prompt chunk that is whole scan chunks of whole lanes (a
+    row is a lane of the decay matrix)."""
+    Q = xBC.shape[1]
+    h, p, g, n, k = _pool_sizes(xBC, dt, pool)
+    rows_n = min(chunk, Q)
+    width = n if g == 1 else _groups_a_block(pool.shape[2]) * n
+    return ((g == 1 or (g == h and k == 1)) and pool.dtype == jnp.float32
+            and pool.shape[-1] == LANES and n % LANES == 0
+            and xBC.shape[-1] == h * p + 2 * g * n
+            and (h * p) % width == 0 and (g * n) % width == 0
+            and rows_n % LANES == 0 and Q % rows_n == 0)
 
 
 def xla_causal_conv1d(xBC, w, b, tail, count=None, activation="silu"):

@@ -3,13 +3,15 @@
 ``benchmark/run.py --trace 1`` run left under ``benchmark_out/trace/<cell>``
 (PR 56, step 0: what the ``moe_experts`` scope of a mixed step is made of
 in-program: the sort, the row gather, the grouped GEMM kernels, the mask
-pass and the combine).
+pass and the combine; PR 58, step 0: what one pass of a scan layer's loop
+over prompt chunks is made of, ``--scope ssm_scan``).
 
     python3 scripts/step0_moe_scope_ops.py benchmark_out/trace/<cell> --tag <name>
         [--program ragged_forward] [--scope moe_experts]
 
 An op belongs to a scope exactly as ``benchmark/readers/moe_scope_time.py``
-decides it (``group_of``); ops are pooled by (HLO opcode, the tail of the
+decides it (``group_of``; a scan layer's four scopes as
+``ssm_scope_time.py`` does); ops are pooled by (HLO opcode, the tail of the
 ``tf_op`` path, result shape) and given as milliseconds a run of the
 program, most first.  Writes ``chiprun_out/pr56/ops_<tag>.json`` and
 ``ops_<tag>.md``; the summary goes to stdout.
@@ -27,6 +29,7 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 sys.path.insert(0, os.path.join(ROOT, "benchmark", "readers"))
 
 import moe_scope_time  # noqa: E402
+import ssm_scope_time  # noqa: E402
 import xmeta  # noqa: E402
 import xtrace  # noqa: E402
 
@@ -40,6 +43,8 @@ def op_key(meta):
 
 
 def scope_ops(devices, prefix, scope):
+    group_of = (ssm_scope_time if scope in ssm_scope_time.SSM
+                else moe_scope_time).group_of
     ns = collections.Counter()
     count = collections.Counter()
     runs = 0
@@ -55,7 +60,7 @@ def scope_ops(devices, prefix, scope):
                                  bisect.bisect_left(starts, b)]:
                 m = meta.get(mid)
                 if (e > b or m is None or m["opcode"] in xtrace.CONTAINERS
-                        or moe_scope_time.group_of(m) != scope):
+                        or group_of(m) != scope):
                     continue
                 ns[op_key(m)] += e - s
                 count[op_key(m)] += 1
@@ -69,6 +74,8 @@ def main():
     ap.add_argument("--program", default="ragged_forward")
     ap.add_argument("--scope", default="moe_experts")
     ap.add_argument("--out", default="chiprun_out/pr56")
+    ap.add_argument("--top", type=int, default=25,
+                    help="rows of the summary on stdout")
     args = ap.parse_args()
     path = args.trace
     if os.path.isdir(path):
@@ -95,7 +102,7 @@ def main():
                     f"{r['opcode']} | {r['tf_op']} | {r['shape']} |\n")
     print(json.dumps({k: out[k] for k in ("program", "scope", "runs",
                                           "ms_per_run")}))
-    for r in rows[:25]:
+    for r in rows[:args.top]:
         print(f"{r['ms_per_run']:9.4f} ms  x{r['events_per_run']:<6.1f} "
               f"{r['opcode']:<14} {r['tf_op']}  {r['shape']}")
 

@@ -180,12 +180,15 @@ class TestEnginePrefetch:
         batches = _data(5, bs=plain.train_batch_size)
         l_plain = [float(plain.train_batch(b).loss) for b in batches]
         pref = _engine(telemetry=True)
+        # (the registry is the process's: count from where an earlier test
+        # of this worker left the counter)
+        handed = pref.telemetry.registry.counter("prefetch_batches_total")
+        before = handed.value(loader="train")
         with pref.prefetch_loader(iter(batches)) as pf:
             l_pref = [float(pref.train_batch(pb).loss) for pb in pf]
             assert pf.batches == len(batches)
         assert l_pref == l_plain                 # bitwise: same math, same order
-        assert pref.telemetry.registry.counter(
-            "prefetch_batches_total").value(loader="train") == len(batches)
+        assert handed.value(loader="train") - before == len(batches)
 
     def test_prepared_batch_carries_tokens_and_step(self):
         eng = _engine()

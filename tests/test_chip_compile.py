@@ -415,7 +415,9 @@ def test_step_programs_leave_the_pool_in_place(step_programs, monkeypatch,
 
 # a scan layer beside an attention layer at granite-4.0-h-micro's widths (64
 # heads of 64 over a state of 128: 2 MiB a slot a layer, packed [32, 128,
-# 128]); 16 slots, so a layer's states are 32 MiB and the pool is 32 MiB too
+# 128]); 64 slots as the cell has, so a layer's states are 128 MiB and the
+# pool is 128 MiB too (at 16 slots the compiler moves the whole 32 MiB pool
+# into VMEM around the mixed step's loop, which a real pool never fits)
 GRANITE_2L = GPTConfig(
     vocab_size=4096, num_layers=2, num_heads=32, num_kv_heads=8, head_dim=64,
     hidden_size=2048, mlp_dim_override=8192, max_seq_len=2560, use_rope=True,
@@ -430,15 +432,16 @@ def test_scan_state_pool_stays_in_place(topo, monkeypatch):
     a layer's states out of the float32 state pool (4.9 GB at 64 slots of
     the whole model): the decode step and the burst update them in place
     through the ``ssm_state_update`` kernel, whose output is the pool, and
-    the mixed step's loop over prompt chunks gathers and scatters the few
-    slots it scans (``_Mamba2.group`` = 4 states a pass, which it may
-    transpose: a quarter of a layer here).  The pool and the conv-tail pool
-    are donated and aliased to the outputs."""
+    the mixed step's loop over prompt chunks through the
+    ``ssm_pool_chunk_scan`` kernel, whose output is the pool too (Mosaic
+    takes it at the published shape: one group, two heads of 64 on the
+    lanes, a prompt chunk of 256 rows).  The pool and the conv-tail pool are
+    donated and aliased to the outputs."""
     from conftest import lower_serving_steps
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = dataclasses.replace(GRANITE_2L, dtype=BF16, param_dtype=BF16,
                               attn_impl="pallas")
-    S = 16
+    S = 64
     _, cache, lowered = lower_serving_steps(
         cfg, BF16, slots=S, tokens=512, max_q=256, table_width=32,
         block_size=128, num_pages=S * 20, steps=8,
@@ -451,6 +454,7 @@ def test_scan_state_pool_stays_in_place(topo, monkeypatch):
         compiled = low.compile()
         text = compiled.as_text()
         assert "ssm_state_update" in text, name     # the kernel, by its name
+        assert ("ssm_pool_chunk_scan" in text) == ("forward" in name), name
         moved = []
         for result, op in _HLO_OP.findall(text):
             if op not in POOL_MOVERS:
@@ -486,8 +490,10 @@ def test_block_selection_and_lightning_state_leave_their_pools_in_place(
     compiler transposes the pool around the gather; a ``reshape`` that
     splits the head width is a copy under the chip's tiling too), the pooled
     keys, and the float32 lightning state, which the recurrence kernel
-    updates in place with a column a head and the mixed step's loop keeps
-    row-major (``_row_major``).  The one-row slots past ``dense_len`` read
+    updates in place with a column a head and the mixed step's loop scans in
+    place through the ``ssm_pool_chunk_scan`` kernel (a group a head, a head
+    the lanes' width, a prompt chunk of 1,024 rows) and keeps row-major
+    (``_row_major``).  The one-row slots past ``dense_len`` read
     their kept blocks through the paged decode kernel (a view of the pool
     whose pages are one block of one kv head)."""
     from conftest import lower_serving_steps
@@ -510,6 +516,7 @@ def test_block_selection_and_lightning_state_leave_their_pools_in_place(
         compiled = low.compile()
         text = compiled.as_text()
         assert "ssm_state_update" in text, name     # the kernel, by its name
+        assert ("ssm_pool_chunk_scan" in text) == ("forward" in name), name
         assert "paged_decode" in text, name
         assert ("ragged_prefill" in text) == ("forward" in name), name
         moved = []
