@@ -3,10 +3,9 @@ tiny preset (``--rehearse``: two attention layers among scan layers, the
 Pallas paged kernels interpreted, the comparison with the plain
 granitemoehybrid reference across a ``put_chunked`` boundary), a planted
 fault through the harness, its metrics' entries, files and readers, the
-configuration against the catalog's row, ``costs_ssm``'s need against the
-arithmetic written out, the new readers on spans as the program writes
-them, and that the cell came by new files, new entries and its name at the
-end of the lists it joined."""
+configuration against the catalog's row, ``costs_ssm``'s and the layers'
+need against the arithmetic written out, the new readers on spans as the
+program writes them, and that the cell reads what it was accepted with."""
 
 import json
 import os
@@ -14,10 +13,12 @@ import subprocess
 import sys
 import types
 
+import costs_serve
 import costs_ssm
 import ssm_spans
-from test_cells import ENV, MANIFEST, readings, run_cell
-from test_manifest import LISTS
+from test_cells import (assert_reads_what_it_was_accepted_with, ENV, MANIFEST,
+                        name_since_pr59, no_longer_read, readings, run_cell)
+from test_serve_mfu import model_cfg as program_cfg
 
 CELL = "serve-granite4h-shortchat-batch"
 CONFIG = "granite-4.0-h-micro"
@@ -83,8 +84,11 @@ def test_a_planted_fault_reads_not_correct_through_the_harness():
 def test_its_metrics_are_entries_with_files_and_readers():
     mine = readings(CELL)                  # what a traced run reads
     names = [p["name"] for p in mine]
-    assert len(mine) == 34 and set(NEW) <= set(names)
-    assert "serve_step_mfu" not in names          # attention on every layer
+    assert {name_since_pr59(n) for n in NEW} <= set(names)
+    assert not no_longer_read(CELL)
+    # the whole step's share under the one name since PR 59: the need asks
+    # each layer its kind (``costs_serve`` once reckoned attention on all)
+    assert "serve_step_mfu" in names
     assert "paged_decode_roofline" not in names   # ... times num_layers
     # the decoding sequences' contexts from the mixed spans' riders, the one
     # source that is there when the host dispatched the bursts ahead of the
@@ -148,7 +152,7 @@ def model_cfg():
 def test_need_functions_against_a_hand_count():
     cfg = model_cfg()
     assert costs_ssm.layers(cfg) == (36, 4)
-    w = costs_ssm.row_weights(cfg)
+    w = costs_serve.row_weights(program_cfg(CONFIG))
     # a scan layer: in 2,048 x (4,096 + 4,352 + 64), out 4,096 x 2,048
     assert w["scan_proj"] == 36 * (2048 * 8512 + 4096 * 2048) == 929562624
     assert w["attention"] == 4 * (2 * 2048 * 2048 + 2 * 2048 * 512)
@@ -161,16 +165,18 @@ def test_need_functions_against_a_hand_count():
     assert flops == 64 * 4 * 4096 * 128
     assert byts == 64 * 2 * 2 ** 21 + 64 * ((3 * 4096 + 256) * 2 + 256)
     # a window: 1,000 rows, 100 tokens produced, 50,000 pairs a layer
-    need = costs_ssm.window_need(cfg, {"rows": 1000, "sampled": 100,
-                                       "pairs_global": 50000})
+    need = costs_serve.window_need(
+        program_cfg(CONFIG), {"rows": 1000, "sampled": 100,
+                              "pairs_global": 50000})
     t = need["terms"]
     assert t["weights_scan_proj"] == 2 * 929562624 * 1000
     assert t["recurrence"] == 36 * 1000 * 4 * 4096 * 128
     assert t["attention"] == 4 * 2 * 2 * 32 * 64 * 50000     # FOUR layers
     assert t["weights_head"] == 2 * 2048 * 100352 * 100
     assert need["flops"] == sum(t.values()) and not need["left_out"]
-    lost = costs_ssm.window_need(cfg, {"rows": 1000, "sampled": 100,
-                                       "pairs_global": None})
+    lost = costs_serve.window_need(
+        program_cfg(CONFIG), {"rows": 1000, "sampled": 100,
+                              "pairs_global": None})
     assert "attention" not in lost["terms"] and lost["left_out"]
 
 
@@ -192,13 +198,8 @@ def test_span_readers_on_spans_and_on_a_program_without_them():
         span("ds.mixed_dispatch", 10, tokens=5)]}, "trace_window": (0, 100)}
     for what in ("state_bytes_per_slot", "step_rows_share"):
         assert ssm_spans.read(bare, {"what": what}) is None   # the parent's
-    import serve_mfu_scan
     import ssm_rooflines
     import ssm_scope_time
-    dense = types.SimpleNamespace(layer_types=())
-    assert serve_mfu_scan.read({"serve_window": {"counts": {}}, "peaks": {},
-                                "model_cfg": dense, "window_s": 1.0},
-                               {"name": "x"}) is None
     spec = {"program": "ragged_decode", "path": "step", "groups": ["ssm_scan"]}
     assert ssm_rooflines.read({**bare, "peaks": {}, "model_cfg": model_cfg()},
                               spec) is None                   # no device ops
@@ -212,24 +213,7 @@ def test_span_readers_on_spans_and_on_a_program_without_them():
         == "attn_qkv"
 
 
-def test_the_cell_came_by_files_alone():
-    """This PR brought the cell by new files, new entries and its name at
-    the END of the lists it joined: against the lists PR 38 left
-    (``data/manifest_lists.json``), every accepted entry is where it was
-    under its name and its ``workloads`` list has grown at its end or not
-    at all; the nine new entries are the manifest's last."""
-    for group, entries in LISTS["accepted_at_pr38"].items():
-        now = MANIFEST[group][:len(entries)]
-        assert [e["name"] for e in now] == [n for n, _ in entries], group
-        for e, (name, cells) in zip(now, entries):
-            if cells is None:
-                assert "workloads" not in e, name
-            else:
-                assert e["workloads"][:len(cells)] == cells, name
-    assert [p["name"] for p in MANIFEST["per_layer"][-9:]] == NEW
-    assert all(p["workloads"] == [CELL] for p in MANIFEST["per_layer"][-9:])
-    assert MANIFEST["workloads"][-1]["name"] == CELL
-    assert MANIFEST["configs"][-1]["name"] == CONFIG
-    for e in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
-        if CELL in e.get("workloads", ()):
-            assert e["workloads"][-1] == CELL, e["name"]
+def test_the_cell_reads_what_it_was_accepted_with():
+    """Held by names through ``run.metric_applies``, not by a count or a
+    place in the manifest, which the next cell's entries move."""
+    assert_reads_what_it_was_accepted_with(CELL, NEW)

@@ -14,12 +14,16 @@ import subprocess
 import sys
 import types
 
+import attn_rooflines
 import conv_rooflines
 import conv_scope_time
 import conv_spans
 import costs_conv
-import serve_mfu_conv
-from test_cells import ENV, MANIFEST, readings, run_cell
+import costs_serve
+import serve_mfu
+from test_cells import (assert_reads_what_it_was_accepted_with, ENV, MANIFEST,
+                        name_since_pr59, no_longer_read, readings, run_cell)
+from test_serve_mfu import model_cfg as program_cfg
 
 CELL = "serve-lfm2-ragdoc-batch"
 CONFIG = "lfm2-24b-a2b-10l"
@@ -30,9 +34,6 @@ NEW = ["decode_conv_ms", "mixed_conv_ms", "conv_state_bytes_per_slot",
        "kv_pool_bytes_per_token", "short_conv_roofline",
        "paged_decode_roofline.conv", "ragged_prefill_roofline.conv",
        "serve_step_mfu.conv"]
-with open(os.path.join(ROOT, "benchmark", "tests", "data",
-                       "manifest_lists_pr45.json")) as _f:
-    ACCEPTED = json.load(_f)["accepted_at_pr45"]
 
 
 def config():
@@ -90,17 +91,17 @@ def test_a_planted_fault_reads_not_correct_through_the_harness():
 def test_its_metrics_are_entries_with_files_and_readers():
     mine = readings(CELL)                  # what a traced run reads
     names = [p["name"] for p in mine]
-    assert len(mine) == 39 and set(NEW) <= set(names)
-    # the accepted readers that would misreckon are not joined: attention
-    # on every layer (for_layer on a conv layer), a call a layer times
-    # num_layers, Mamba-2's mixers
-    for name in ("serve_step_mfu", "serve_step_mfu.scan",
-                 "paged_decode_roofline", "paged_decode_roofline.mixedlen",
-                 "ragged_prefill_roofline.mixedlen", "ssm_decode_roofline",
+    assert {name_since_pr59(n) for n in NEW} <= set(names)
+    assert not no_longer_read(CELL)
+    # the accepted readers that would misreckon are not joined: a call a
+    # layer times num_layers, Mamba-2's mixers (the whole step's share and
+    # the two paged kernels' ask each layer its kind since PR 59)
+    for name in ("paged_decode_roofline", "ssm_decode_roofline",
                  "mixed_moe_shared_ms", "decode_moe_shared_ms",
                  "moe_local_share_of_assignments"):
         assert name not in names, name
-    for name in ("expert_gemm_roofline", "decode_moe_experts_ms",
+    for name in ("serve_step_mfu", "paged_decode_roofline.by_layer",
+                 "expert_gemm_roofline.joined", "decode_moe_experts_ms",
                  "mixed_moe_experts_ms", "moe_rows_per_touched_expert",
                  "decode_step_device_ms.batch", "peak_hbm_gib.batch"):
         assert name in names, name
@@ -114,7 +115,6 @@ def test_its_metrics_are_entries_with_files_and_readers():
         assert {k: spec[k] for k in ("unit", "better", "source", "layer",
                                      "moves")} == {
             k: p[k] for k in ("unit", "better", "source", "layer", "moves")}
-    assert len(MANIFEST["per_layer"]) == 102 <= 128
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
     assert (cell["config"], cell["traffic"]) == (CONFIG, "ragdoc-batch")
@@ -184,7 +184,7 @@ def model_cfg(layers=10):
 def test_need_functions_against_a_hand_count():
     cfg = model_cfg()
     assert costs_conv.layers(cfg) == (8, 2)
-    w = costs_conv.row_weights(cfg)
+    w = costs_serve.row_weights(program_cfg(CONFIG))
     # a conv layer: in 2,048 x 6,144, out 2,048 x 2,048
     assert w["conv_proj"] == 8 * (2048 * 6144 + 2048 * 2048) == 134217728
     assert w["attention"] == 2 * (2 * 2048 * 2048 + 2 * 2048 * 512)
@@ -197,7 +197,7 @@ def test_need_functions_against_a_hand_count():
     assert costs_conv.state_bytes_per_slot(cfg) == 8 * 2 * 2048 * 2 == 65536
     # what a decode step streams: the 8 expert layers' 9.66 GB, and 0.87 GB
     # of every other matmul weight (the head among them)
-    stream = costs_conv.decode_stream_bytes(cfg)
+    stream = costs_conv.decode_stream_bytes(program_cfg(CONFIG))
     assert stream["experts"] == 8 * 64 * 3 * 2048 * 1536 * 2 == 9663676416
     assert round(stream["other"] / 1e9, 2) == 0.87
     # a decode step of 64 slots through one conv layer: a row in and out
@@ -206,15 +206,17 @@ def test_need_functions_against_a_hand_count():
     assert flops == 64 * 2 * 3 * 2048
     assert byts == 64 * 2 * 2048 * 2 + 64 * 2 * (2 * 2048 * 2)
     # the paged kernels on the TWO attention layers
-    f, b = costs_conv.paged_decode_cost(cfg, 100000, 64)
+    real = program_cfg(CONFIG)
+    two = attn_rooflines.calling_layers(real)
+    f, b = attn_rooflines.step_need(real, two, (100000, 0), (100000, 0), 64)
     assert f == 2 * 2 * 2 * 32 * 64 * 100000
     assert b == 2 * (2 * 8 * 64 * 100000 + 2 * 64 * 32 * 64) * 2
-    f, b = costs_conv.ragged_prefill_cost(cfg, 5e6, 3000, 1024)
+    f, b = attn_rooflines.step_need(real, two, (5e6, 0), (3000, 0), 1024)
     assert f == 2 * 2 * 2 * 32 * 64 * 5e6
     assert b == 2 * (2 * 8 * 64 * 3000 + 2 * 1024 * 32 * 64) * 2
     # a window: 1,000 rows, 100 tokens produced, 50,000 pairs a layer,
     # 4 experts a row on 8 layers
-    need = costs_conv.window_need(cfg, {
+    need = costs_serve.window_need(real, {
         "rows": 1000, "sampled": 100, "pairs_global": 50000,
         "moe_local": 32000})
     t = need["terms"]
@@ -224,9 +226,9 @@ def test_need_functions_against_a_hand_count():
     assert t["weights_experts"] == 2 * 3 * 2048 * 1536 * 32000
     assert t["weights_head"] == 2 * 2048 * 65536 * 100
     assert need["flops"] == sum(t.values()) and not need["left_out"]
-    lost = costs_conv.window_need(cfg, {"rows": 1000, "sampled": 100,
-                                        "pairs_global": None,
-                                        "moe_local": None})
+    lost = costs_serve.window_need(real, {"rows": 1000, "sampled": 100,
+                                          "pairs_global": None,
+                                          "moe_local": None})
     assert "attention" not in lost["terms"] and len(lost["left_out"]) == 2
 
 
@@ -249,10 +251,14 @@ def test_span_readers_on_spans_and_on_a_program_without_them():
         "trace_window": (0, 100)}
     assert conv_spans.read(ctx, {"what": "state_bytes_per_slot"}) == 65536
     assert conv_spans.read(ctx, {"what": "kv_bytes_per_token"}) == 4096
-    got = conv_rooflines._decode_steps(ctx["_xmeta"]["annotations"])
-    assert got == (8 * 200000 + 64 * 36, 8 * 64, 8, "decode spans")
-    riders = conv_rooflines._decode_steps(ctx["_xmeta"]["annotations"][:1])
-    assert riders == (120039, 39, 1, "one-row slots of mixed spans")
+    keys, slots, seen = attn_rooflines.decode_step(
+        ctx["_xmeta"]["annotations"], False)
+    assert (keys[0], slots, seen["span_steps"], seen["from"]) == (
+        (8 * 200000 + 64 * 36) / 8, 64, 8, "decode spans")
+    keys, slots, seen = attn_rooflines.decode_step(
+        ctx["_xmeta"]["annotations"][:1], False)
+    assert (keys[0], slots, seen["span_steps"], seen["from"]) == (
+        120039, 39, 1, "one-row slots of mixed spans")
     bare = {"_xmeta": {"devices": {}, "annotations": [
         span("ds.mixed_dispatch", 10, tokens=5, kv_bytes_per_token=131072)]},
         "trace_window": (0, 100)}
@@ -261,16 +267,16 @@ def test_span_readers_on_spans_and_on_a_program_without_them():
     dense = types.SimpleNamespace(layer_types=())
     scan = types.SimpleNamespace(layer_types=("mamba", "attention"))
     for other in (dense, scan):
-        assert serve_mfu_conv.read(
-            {"serve_window": {"counts": {}}, "peaks": {}, "model_cfg": other,
-             "window_s": 1.0}, {"name": "x"}) is None
         assert conv_rooflines.read(
             {**ctx, "peaks": {"x": 1}, "model_cfg": other},
             {"kernel": "short_conv"}) is None
-    for kernel in ("short_conv", "paged_decode", "ragged_prefill"):
-        assert conv_rooflines.read(
-            {**ctx, "peaks": {"x": 1}, "model_cfg": model_cfg()},
-            {"kernel": kernel, "program": "ragged_"}) is None  # no device ops
+    assert conv_rooflines.read(
+        {**ctx, "peaks": {"x": 1}, "model_cfg": model_cfg()},
+        {"kernel": "short_conv"}) is None                     # no device ops
+    for kernel in ("paged_decode", "ragged_prefill"):
+        assert attn_rooflines.read(
+            {**ctx, "peaks": {"x": 1}, "model_cfg": program_cfg(CONFIG)},
+            {"kernel": kernel, "program": "ragged_"}) is None
     spec = {"program": "ragged_decode", "groups": ["short_conv"]}
     assert conv_scope_time.read(bare, spec) is None
     assert conv_scope_time.group_of(
@@ -289,33 +295,15 @@ def test_mfu_reader_on_a_window():
     peaks = {"bf16_flops_per_s": 197e12}
     counts = {"rows": 900000, "sampled": 50000, "pairs_global": 2.0e9,
               "moe_local": 900000 * 32}
-    got = serve_mfu_conv.read(
+    real = program_cfg(CONFIG)
+    got = serve_mfu.read(
         {"serve_window": {"counts": counts}, "peaks": peaks,
-         "model_cfg": model_cfg(), "window_s": 33.0}, {"name": "x"})
-    need = costs_conv.window_need(model_cfg(), counts)["flops"]
+         "model_cfg": real, "window_s": 33.0}, {"name": "x"})
+    need = costs_serve.window_need(real, counts)["flops"]
     assert got == 100.0 * need / (33.0 * 197e12) and 0 < got < 100
 
 
-def test_the_cell_came_by_files_alone():
-    """This PR brought the cell by new files, new entries and its name at
-    the END of the lists it joined: against the lists PR 45 left
-    (``data/manifest_lists_pr45.json``), every accepted entry is where it
-    was under its name and its ``workloads`` list has grown at its end, by
-    this cell, or not at all; the new entries follow the accepted ones."""
-    for group, entries in ACCEPTED.items():
-        now = MANIFEST[group][:len(entries)]
-        assert [e["name"] for e in now] == [n for n, _ in entries], group
-        if group in ("configs", "workloads"):
-            continue
-        for e, (name, cells) in zip(now, entries):
-            if cells is None:
-                assert "workloads" not in e, name
-            else:
-                assert e["workloads"][:len(cells)] == cells, name
-                assert e["workloads"][len(cells):] in ([], [CELL]), name
-    n = len(ACCEPTED["per_layer"])
-    assert [p["name"] for p in MANIFEST["per_layer"][n:n + 8]] == NEW
-    assert all(p["workloads"] == [CELL]
-               for p in MANIFEST["per_layer"][n:n + 8])
-    assert MANIFEST["workloads"][len(ACCEPTED["workloads"])]["name"] == CELL
-    assert MANIFEST["configs"][len(ACCEPTED["configs"])]["name"] == CONFIG
+def test_the_cell_reads_what_it_was_accepted_with():
+    """Held by names through ``run.metric_applies``, not by a count or a
+    place in the manifest, which the next cell's entries move."""
+    assert_reads_what_it_was_accepted_with(CELL, NEW)

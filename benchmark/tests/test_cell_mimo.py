@@ -9,20 +9,21 @@ row, ``costs_swa``'s need against the arithmetic written out, the new readers
 on spans as the program writes them, and that the cell came by new files, new
 entries and its name at the end of the lists it joined."""
 
-import hashlib
 import json
 import os
 import subprocess
 import sys
 import types
 
+import attn_rooflines
 import costs
 import costs_serve
 import costs_swa
-import swa_rooflines
 import swa_scope_time
 import swa_spans
-from test_cells import ENV, MANIFEST, readings, run_cell
+from layer_costs import attention
+from test_cells import (assert_reads_what_it_was_accepted_with, ENV, MANIFEST,
+                        name_since_pr59, no_longer_read, readings, run_cell)
 
 CELL = "serve-mimo-reasoning-batch"
 CONFIG = "mimo-v2-flash-7l-ep16"
@@ -35,10 +36,6 @@ NEW = ["paged_decode_roofline.reasoning", "ragged_prefill_roofline.reasoning",
        "kv_window_released_in_decode_share",
        "kv_pool_bytes_per_token.global", "kv_pool_bytes_per_token.window",
        "serve_step_mfu.swa"]
-with open(os.path.join(ROOT, "benchmark", "tests", "data",
-                       "manifest_lists_pr52.json")) as _f:
-    _DATA = json.load(_f)
-ACCEPTED, FILES = _DATA["accepted_at_pr52"], _DATA["files_at_pr52"]
 
 
 def config():
@@ -101,14 +98,19 @@ def test_a_planted_fault_reads_not_correct_through_the_harness():
 def test_its_metrics_are_entries_with_files_and_readers():
     mine = readings(CELL)                  # what a traced run reads
     names = [p["name"] for p in mine]
-    assert set(NEW) <= set(names) and len(mine) == len(set(names))
-    # everything Trinity's cell reads but what a shared expert, its own
-    # rooflines and the one-geometry MFU need; and the ten new ones
+    new = {name_since_pr59(n) for n in NEW}
+    assert new <= set(names) and len(mine) == len(set(names))
+    assert not no_longer_read(CELL)
+    # everything Trinity's cell reads but what a shared expert needs, and
+    # the ten new ones, three of which are Trinity's too since PR 59 (the
+    # need of the step and of the two paged kernels asks each layer its own
+    # heads and widths)
     trinity = {p["name"] for p in readings("serve-trinity-mixedlen-batch")}
-    assert trinity - set(names) == {
-        "mixed_moe_shared_ms", "decode_moe_shared_ms", "serve_step_mfu",
-        "paged_decode_roofline.mixedlen", "ragged_prefill_roofline.mixedlen"}
-    assert set(names) - trinity == set(NEW)
+    assert trinity - set(names) == {"mixed_moe_shared_ms",
+                                    "decode_moe_shared_ms"}
+    assert set(names) - trinity == new - {
+        "serve_step_mfu", "paged_decode_roofline.by_layer",
+        "ragged_prefill_roofline.by_layer"}
     assert {p["moves"] for p in mine} == {"serve_tokens_per_s", "setup_s"}
     for p in mine:
         with open(os.path.join(ROOT, "benchmark", "metrics",
@@ -119,8 +121,6 @@ def test_its_metrics_are_entries_with_files_and_readers():
         assert {k: spec[k] for k in ("unit", "better", "source", "layer",
                                      "moves")} == {
             k: p[k] for k in ("unit", "better", "source", "layer", "moves")}
-    assert len(MANIFEST["per_layer"]) == 118 <= 128
-    assert len(MANIFEST["workloads"]) == 11
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 1
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
@@ -201,29 +201,31 @@ def test_need_functions_against_a_hand_count():
     full, win = cfg.for_layer(0), cfg.for_layer(1)
     # ISSUE 53's arithmetic: a full layer's attention 89.13 M, a window
     # layer's 94.37 M weights
-    assert costs_swa.attention_weights(full, 4096) == 4096 * (
+    assert attention.weights(full, 4096) == 4096 * (
         64 * 192 + 4 * 192 + 4 * 128 + 64 * 128) == 89_128_960
-    assert costs_swa.attention_weights(win, 4096) == 94_371_840
-    w = costs_swa.row_weights(cfg)
+    assert attention.weights(win, 4096) == 94_371_840
+    w = costs_serve.row_weights(cfg)
     assert w["attention"] == 2 * 89_128_960 + 5 * 94_371_840
     assert w["mlp"] == 3 * 4096 * 16384 and w["router"] == 6 * 4096 * 256
-    # costs_serve counts a value as wide as its key: 106.95 M a full layer
-    assert costs_serve.attention_weights(4096, 64, 4, 192) == 106_954_752
+    # a value counted as wide as its key would be 106.95 M a full layer
+    assert attention.weights(types.SimpleNamespace(
+        num_heads=64, kv_heads=4, head_dim=192), 4096) == 106_954_752
     # a cached token: 2 full layers x 4 heads x 320 x 2 B; 5 window x 8
     assert costs_swa.kv_bytes_per_token(cfg) == (5120, 25600)
-    assert costs_swa.pair_flops(full) == 2 * 64 * 320
+    assert attention.pair_flops(full) == 2 * 64 * 320
     # a decode step at 96 slots and 2,500 of context: 96 x 2,500 keys on
     # each full layer, 96 x 128 on each window layer
     keys_g, keys_w = 96 * 2500, 96 * 128
-    flops, byts = costs_swa.attention_cost(cfg, keys_g, keys_w, keys_g,
-                                           keys_w, 96)
+    layers = attn_rooflines.calling_layers(cfg)
+    flops, byts = attn_rooflines.step_need(
+        cfg, layers, (keys_g, keys_w), (keys_g, keys_w), 96)
     assert flops == 2 * 64 * 320 * (2 * keys_g + 5 * keys_w)
     assert byts == (2560 * 2 * keys_g + 5120 * 5 * keys_w
                     + 7 * 96 * 64 * 320 * 2)
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     share, bound = costs.roofline_share(flops, byts, 0.002, peaks)
     assert bound == "memory" and 90 < share < 100       # 1.9 ms at the roof
-    need = costs_swa.window_need(cfg, {
+    need = costs_serve.window_need(cfg, {
         "rows": 1000, "sampled": 10, "moe_local": 3000,
         "pairs_global": 5e6, "pairs_window": 1e5})
     assert need["left_out"] == []
@@ -261,17 +263,19 @@ def test_span_readers_on_spans_and_on_a_program_without_them():
         "num": "kvw_released_decode", "den": "kvw_released", "scale": 100.0,
         "over": "run"}) == 70.0
     # one decode step of the burst: the mean over its 64 steps
-    flops, byts, seen = swa_rooflines.decode_need(cfg, spans)
-    assert seen["seqs"] == 96 and seen["span_steps"] == 64
+    layers = attn_rooflines.calling_layers(cfg)
+    keys, slots, seen = attn_rooflines.decode_step(spans, True)
+    assert seen["seqs"] == slots == 96 and seen["span_steps"] == 64
     assert seen["ctx_tokens"] == 240000 + 96 * 65 / 2
+    flops, byts = attn_rooflines.step_need(cfg, layers, keys, keys, slots)
     assert flops == 2 * 64 * 320 * (2 * seen["ctx_tokens"] + 5 * 12288)
     # the mixed step's prefill kernel: its 59 one-row slots taken off
-    flops, byts, seen = swa_rooflines.prefill_need(cfg, spans)
-    assert seen["rows"] == 512 - 59 and seen["spans"] == 1
+    _, _, rows, seen = attn_rooflines.prefill_step(spans, True)
+    assert seen["rows"] == rows == 512 - 59 and seen["spans"] == 1
     assert seen["qk_pairs"] == 900000 - 140000 - 59
     assert seen["qk_pairs_window"] == 70000 - 7552
-    assert swa_rooflines.read(ctx, {"kernel": "paged_decode",
-                                    "program": "ragged_decode"}) is None
+    assert attn_rooflines.read(ctx, {"kernel": "paged_decode",
+                                     "program": "ragged_decode"}) is None
     bare = {"_xmeta": {"devices": {}, "annotations": [
         span("ds.mixed_dispatch", 10, tokens=5, kv_bytes_per_token=8960)]},
         "trace_window": (0, 100), "model_cfg": cfg}
@@ -287,38 +291,9 @@ def test_span_readers_on_spans_and_on_a_program_without_them():
     assert swa_scope_time.group_of(
         {"tf_op": "jit(f)/attn_kernel/paged_decode/pallas_call"}) is None
     assert swa_scope_time.group_of({"tf_op": "jit(f)/mlp/dot"}) is None
-    plain = types.SimpleNamespace(head_dim=128, v_head_dim=None,
-                                  kv_lora_rank=0)
-    import serve_mfu_swa
-    assert serve_mfu_swa.read(
-        {"serve_window": {"counts": {}, "fenced_s": 0.0}, "peaks": ctx[
-            "peaks"], "model_cfg": plain, "window_s": 1.0}, {}) is None
 
 
-def test_the_cell_came_by_files_alone():
-    """This PR brought the cell by new files, new entries and its name at
-    the END of the lists it joined: against the lists and the files PR 52
-    left (``data/manifest_lists_pr52.json``), every accepted entry is where
-    it was under its name and its ``workloads`` list has grown at its end,
-    by this cell, or not at all; the new entries follow the accepted ones;
-    and no accepted file under ``benchmark/`` reads otherwise than it
-    did."""
-    for group, entries in ACCEPTED.items():
-        now = MANIFEST[group][:len(entries)]
-        assert [e["name"] for e in now] == [n for n, _ in entries], group
-        if group in ("configs", "workloads"):
-            continue
-        for e, (name, cells) in zip(now, entries):
-            if cells is None:
-                assert "workloads" not in e, name
-            else:
-                assert e["workloads"][:len(cells)] == cells, name
-                assert e["workloads"][len(cells):] in ([], [CELL]), name
-    n = len(ACCEPTED["per_layer"])
-    assert [p["name"] for p in MANIFEST["per_layer"][n:]] == NEW
-    assert all(p["workloads"] == [CELL] for p in MANIFEST["per_layer"][n:])
-    assert MANIFEST["workloads"][len(ACCEPTED["workloads"])]["name"] == CELL
-    assert MANIFEST["configs"][len(ACCEPTED["configs"])]["name"] == CONFIG
-    for path, digest in FILES.items():
-        with open(os.path.join(ROOT, path), "rb") as f:
-            assert hashlib.sha256(f.read()).hexdigest()[:16] == digest, path
+def test_the_cell_reads_what_it_was_accepted_with():
+    """Held by names through ``run.metric_applies``, not by a count or a
+    place in the manifest, which the next cell's entries move."""
+    assert_reads_what_it_was_accepted_with(CELL, NEW)

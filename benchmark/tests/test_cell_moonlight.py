@@ -12,8 +12,8 @@ import subprocess
 
 import costs_mla
 import latent
-from test_cells import ENV, MANIFEST, readings, run_cell
-from test_manifest import LISTS
+from test_cells import (ENV, MANIFEST, no_longer_read, readings,
+                        run_cell)
 
 CELL = "serve-moonlight-longctx-batch"
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -64,9 +64,9 @@ def test_a_planted_fault_reads_not_correct_through_the_harness():
 def test_its_metrics_are_entries_with_files_and_readers():
     mine = readings(CELL)                  # what a traced run reads
     names = {p["name"] for p in mine}
-    assert len(mine) == 39
+    assert not no_longer_read(CELL)       # held by name, not by a count
     assert {"latent_decode_roofline", "latent_prefill_roofline",
-            "expert_gemm_roofline", "latent_pool_bytes_per_token",
+            "expert_gemm_roofline.joined", "latent_pool_bytes_per_token",
             "decode_mla_absorb_ms", "mixed_mla_absorb_ms",
             "decode_live_context_tokens.latent"} <= names
     assert {p["moves"] for p in mine} == {"serve_tokens_per_s", "setup_s"}
@@ -110,23 +110,20 @@ def test_the_configuration_keeps_every_published_number():
     assert cfg["run"]["max_seq_len"] == 60 * sm["kv_block_size"] == 7680
 
 
-def test_the_cell_came_by_files_alone():
+def test_the_cell_reads_what_it_was_accepted_with():
     """PR 33 brought this cell by new files, new entries and its name at
-    the end of ``serve_tokens_per_s``'s cells, and that is how a cell comes:
-    against the lists PR 38 left (``data/manifest_lists.json``; a
-    ``benchmark`` PR alone may change an accepted entry, and renews them),
-    every accepted entry is where it was under its name, and its
-    ``workloads`` list has grown at its end or not at all.  The files
-    themselves are the driver's to hold: a spec file no longer names cells,
-    so joining a metric edits none."""
-    for group, entries in LISTS["accepted_at_pr38"].items():
-        now = MANIFEST[group][:len(entries)]
-        assert [e["name"] for e in now] == [n for n, _ in entries], group
-        for e, (name, cells) in zip(now, entries):
-            if cells is None:
-                assert "workloads" not in e, name
-            else:
-                assert e["workloads"][:len(cells)] == cells, name
+    the end of ``serve_tokens_per_s``'s cells, and that is how a cell comes.
+    What it reads is held by names through ``run.metric_applies``, not by a
+    count or a place in the manifest (the next cell's entries move both):
+    what it read when PR 59 started (``data/manifest_lists_pr58.json``) it
+    reads today under today's names.  The files themselves are the driver's
+    to hold: a spec file no longer names cells, so joining a metric edits
+    none."""
+    assert not no_longer_read(CELL)
+    assert not no_longer_read(CELL, [
+        "latent_decode_roofline", "latent_prefill_roofline",
+        "latent_pool_bytes_per_token", "decode_live_context_tokens.latent",
+        "expert_gemm_roofline", "serve_step_mfu"])
 
 
 def span(name, t, **args):

@@ -6,10 +6,9 @@ reference across ``put_chunked`` boundaries and ``dense_len``), a planted
 fault through the harness, its metrics' entries, files and readers, the
 configuration against the catalog's row, ``costs_sala``'s need against the
 arithmetic written out at two shapes, the new readers on a hand-made trace
-and spans, and that the cell came by new files, new entries and its name at
-the end of the lists it joined."""
+and spans, and that the cell reads what it was accepted with."""
 
-import hashlib
+import dataclasses
 import json
 import os
 import subprocess
@@ -17,9 +16,12 @@ import sys
 import types
 
 import costs_sala
+import costs_serve
 import sala
-import serve_mfu_sala
-from test_cells import ENV, MANIFEST, readings, run_cell
+import serve_mfu
+from test_cells import (assert_reads_what_it_was_accepted_with, ENV, MANIFEST,
+                        name_since_pr59, no_longer_read, readings, run_cell)
+from test_serve_mfu import model_cfg as program_cfg
 
 CELL = "serve-sala-longdoc-batch"
 CONFIG = "minicpm-sala-12l"
@@ -46,10 +48,6 @@ JOINED = [
     "decode_index_ms.sparse", "mixed_index_ms.sparse",
     "index_selected_share.sparse", "index_pool_bytes_per_token",
     "decode_live_context_tokens.latent"]
-with open(os.path.join(ROOT, "benchmark", "tests", "data",
-                       "manifest_lists_pr56.json")) as _f:
-    _DATA = json.load(_f)
-ACCEPTED, FILES = _DATA["accepted_at_pr56"], _DATA["files_at_pr56"]
 
 
 def config():
@@ -108,11 +106,13 @@ def test_its_metrics_are_entries_with_files_and_readers():
     mine = readings(CELL)                  # what a traced run reads
     names = [p["name"] for p in mine]
     assert len(mine) == len(set(names))
-    assert set(names) == set(JOINED) | set(NEW) | {"compile_cache_misses",
-                                                   "compiles_in_window"}
-    # they count every causal pair, or dots3's and granite's costs
-    for other in ("serve_step_mfu", "serve_step_mfu.scan",
-                  "sparse_decode_roofline", "sparse_prefill_roofline",
+    assert {name_since_pr59(n) for n in JOINED + NEW} | {
+        "compile_cache_misses", "compiles_in_window"} <= set(names)
+    assert not no_longer_read(CELL)
+    # they count every causal pair, or dots3's costs (the whole step's share
+    # is under the one name since PR 59: the need asks each layer its kind)
+    assert "serve_step_mfu" in names
+    for other in ("sparse_decode_roofline", "sparse_prefill_roofline",
                   "index_score_roofline", "kv_pool_bytes_per_token"):
         assert other not in names
     assert {p["moves"] for p in mine} == {"serve_tokens_per_s", "setup_s"}
@@ -212,7 +212,8 @@ def model_cfg(layers=12):
 def test_need_functions_against_a_hand_count():
     cfg = model_cfg()
     assert costs_sala.layers(cfg) == (9, 3)
-    w = costs_sala.row_weights(cfg)
+    real = program_cfg(CONFIG)
+    w = costs_serve.row_weights(real)
     assert w["lightning_proj"] == 9 * 5 * 4096 * 4096
     assert w["attention"] == 3 * (3 * 4096 * 4096 + 2 * 4096 * 256)
     assert w["mlp"] == 12 * 3 * 4096 * 16384
@@ -234,9 +235,9 @@ def test_need_functions_against_a_hand_count():
     assert byts == 3 * 1087 * 2 * 128 * 2
     # a window: 1,000 rows, 100 tokens produced, 50,000 kept pairs and
     # 9,000 pooled pairs over the selecting layers
-    need = costs_sala.window_need(cfg, {"rows": 1000, "sampled": 100,
-                                        "selected_pairs": 50000,
-                                        "index_pairs": 9000})
+    need = costs_serve.window_need(real, {"rows": 1000, "sampled": 100,
+                                          "selected_pairs": 50000,
+                                          "index_pairs": 9000})
     t = need["terms"]
     assert t["weights_lightning_proj"] == 2 * 9 * 5 * 4096 * 4096 * 1000
     assert t["recurrence"] == 9 * 1000 * 4 * 32 * 128 * 128
@@ -244,16 +245,18 @@ def test_need_functions_against_a_hand_count():
     assert t["block_scores"] == 9000 * 32 * 2 * 128
     assert t["weights_head"] == 2 * 4096 * 73448 * 100
     assert need["flops"] == sum(t.values()) and len(need["left_out"]) == 1
-    lost = costs_sala.window_need(cfg, {"rows": 1000, "sampled": 100,
-                                        "selected_pairs": None,
-                                        "index_pairs": None})
+    lost = costs_serve.window_need(real, {"rows": 1000, "sampled": 100,
+                                          "selected_pairs": None,
+                                          "index_pairs": None})
     assert "attention_kept" not in lost["terms"] and len(lost["left_out"]) == 3
     # a second shape: two layers (one of each kind), half the rows
     small = model_cfg(2)
     assert costs_sala.layers(small) == (1, 1)
-    half = costs_sala.window_need(small, {"rows": 500, "sampled": 0,
-                                          "selected_pairs": 10,
-                                          "index_pairs": 10})["terms"]
+    kinds = tuple(small.layer_types)
+    half = costs_serve.window_need(
+        dataclasses.replace(real, num_layers=2, layer_types=kinds),
+        {"rows": 500, "sampled": 0, "selected_pairs": 10,
+         "index_pairs": 10})["terms"]
     assert half["weights_mlp"] == 2 * 2 * 3 * 4096 * 16384 * 500
     assert half["recurrence"] == 500 * 4 * 32 * 128 * 128
     assert half["weights_head"] == 0
@@ -342,41 +345,13 @@ def test_the_readers_read_nothing_of_a_program_without_the_mechanism():
                              spec_of(name)) is None, name
     assert sala.read({"model_cfg": model_cfg()},
                      spec_of("block_dense_rows_share")) is None
-    assert serve_mfu_sala.read(
+    assert serve_mfu.read(
         {"serve_window": {"counts": {"rows": 1}}, "peaks": {},
          "model_cfg": dense, "window_s": 1.0}, {"name": "x"}) is None
-    assert serve_mfu_sala.read({}, {"name": "x"}) is None
+    assert serve_mfu.read({}, {"name": "x"}) is None
 
 
-def test_the_cell_came_by_files_alone():
-    """This PR brought the cell by new files, new entries and its name at
-    the END of the lists it joined: against the lists and the files PR 56
-    left (``data/manifest_lists_pr56.json``), every accepted entry is where
-    it was under its name and its ``workloads`` list begins as it did and
-    grew by this cell directly behind, or not at all; the five new entries
-    follow the accepted ones; and no accepted file under ``benchmark/`` reads
-    otherwise than it did."""
-    for group, entries in ACCEPTED.items():
-        now = MANIFEST[group][:len(entries)]
-        assert [e["name"] for e in now] == [n for n, _ in entries], group
-        if group in ("configs", "workloads"):
-            continue
-        for e, (name, cells) in zip(now, entries):
-            if cells is None:
-                assert "workloads" not in e, name
-                continue
-            assert e["workloads"][:len(cells)] == cells, name
-            grown = e["workloads"][len(cells):len(cells) + 1]
-            assert grown == ([CELL] if name in JOINED
-                             or name == "serve_tokens_per_s" else []) \
-                or (grown and grown[0] != CELL and name not in JOINED), name
-    n = len(ACCEPTED["per_layer"])
-    assert n == 123
-    assert [p["name"] for p in MANIFEST["per_layer"][n:n + 5]] == NEW
-    assert all(p["workloads"][0] == CELL
-               for p in MANIFEST["per_layer"][n:n + 5])
-    assert MANIFEST["workloads"][len(ACCEPTED["workloads"])]["name"] == CELL
-    assert MANIFEST["configs"][len(ACCEPTED["configs"])]["name"] == CONFIG
-    for path, digest in FILES.items():
-        with open(os.path.join(ROOT, path), "rb") as f:
-            assert hashlib.sha256(f.read()).hexdigest()[:16] == digest, path
+def test_the_cell_reads_what_it_was_accepted_with():
+    """Held by names through ``run.metric_applies``, not by a count or a
+    place in the manifest, which the next cell's entries move."""
+    assert_reads_what_it_was_accepted_with(CELL, NEW + JOINED)

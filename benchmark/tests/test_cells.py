@@ -26,6 +26,46 @@ def readings(cell):
     return [p for p in MANIFEST["per_layer"] if metric_applies(p, cell)]
 
 
+# ---- what a cell read when PR 59 started, under today's names ------------
+with open(os.path.join(ROOT, "benchmark", "tests", "data",
+                       "manifest_lists_pr58.json")) as _f:
+    AT_PR58 = json.load(_f)
+# PR 59's fold: a family's copy -> the one entry that reads it since (the
+# need asked layer by layer); ``expert_gemm_roofline`` gave way to the
+# reading PR 55 brought to correct it
+SINCE_PR59 = {
+    **{f"serve_step_mfu.{x}": "serve_step_mfu"
+       for x in ("scan", "conv", "swa", "sala")},
+    **{f"{k}_roofline.{x}": f"{k}_roofline.by_layer"
+       for k in ("paged_decode", "ragged_prefill")
+       for x in ("mixedlen", "conv", "reasoning")},
+    "expert_gemm_roofline": "expert_gemm_roofline.joined"}
+
+
+def name_since_pr59(old):
+    """The entry that reads what ``old`` read at PR 58."""
+    return SINCE_PR59.get(old, old)
+
+
+def no_longer_read(cell, names=None):
+    """Which of ``names`` (the cell's readings at PR 58 by default) today's
+    manifest does not give the cell, by ``run.metric_applies`` under
+    today's names: a cell test holds this empty, and holds no count and no
+    place in the manifest, which the next cell's entries move."""
+    if names is None:
+        names = AT_PR58["readings_at_pr58"][cell]
+    now = {p["name"] for p in readings(cell)}
+    return sorted(n for n in names if name_since_pr59(n) not in now)
+
+
+def assert_reads_what_it_was_accepted_with(cell, came_with=()):
+    """A cell test's hold on its cell's readings: what the cell read when
+    PR 59 started (``data/manifest_lists_pr58.json``), the entries it came
+    with among them, it reads today under today's names."""
+    assert set(came_with) <= set(AT_PR58["readings_at_pr58"][cell])
+    assert not no_longer_read(cell)
+
+
 def run_cell(name, trace, root=ROOT, extra=()):
     cmd = [RUN[0], os.path.join(root, "benchmark", "run.py"),
            "--workload", name, "--seed", "2147483659", "--seconds", "2",

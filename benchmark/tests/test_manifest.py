@@ -1,6 +1,6 @@
 """BENCHMARK.json against the contract's letter and the benchmark's files;
-and, since PR 38 folded the per-cell suffix families, that a metric is one
-entry and that the fold dropped no reading."""
+and, since PR 38 folded the per-cell suffix families and PR 59 the per-family
+copies, that a metric is one entry and that neither fold dropped a reading."""
 
 import json
 import os
@@ -8,6 +8,7 @@ import re
 
 import pytest
 from run import metric_applies
+from test_cells import AT_PR58, SINCE_PR59, name_since_pr59
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
@@ -193,4 +194,57 @@ def test_the_fold_dropped_no_reading(cell):
     # and a traced run of today's manifest still reads them all
     now = {p["name"] for p in manifest()["per_layer"]
            if metric_applies(p, cell)}
-    assert accepted <= now
+    assert {name_since_pr59(n) for n in accepted} <= now
+
+
+# ---- the fold of PR 59: nothing dropped --------------------------------
+@pytest.mark.parametrize("cell", sorted(AT_PR58["readings_at_pr58"]))
+def test_the_second_fold_dropped_no_reading(cell):
+    """The (cell, reading) pairs of the manifest PR 59 started from map one
+    to one onto today's, but ``expert_gemm_roofline``'s six, which map onto
+    ``.joined``'s (each of the six cells read both)."""
+    old = AT_PR58["readings_at_pr58"][cell]
+    kept = [n for n in old if n != "expert_gemm_roofline"]
+    mapped = [name_since_pr59(n) for n in kept]
+    assert len(set(mapped)) == len(kept)             # one to one
+    if len(kept) < len(old):
+        assert "expert_gemm_roofline.joined" in kept
+    now = {p["name"] for p in manifest()["per_layer"]
+           if metric_applies(p, cell)}
+    assert set(mapped) <= now
+    # what today's manifest reads beyond them came with a later cell
+    at_pr59 = {name_since_pr59(n) for n, _ in
+               AT_PR58["accepted_at_pr58"]["per_layer"]}
+    assert not (now - set(mapped)) & at_pr59
+
+
+def test_the_second_fold_left_119_entries_and_no_folded_name():
+    """128 entries, the contract's cap, were 119 metrics; the nine freed are
+    the next configuration's.  A family's suffix is gone from the names and
+    from ``metrics/``: a new kind of layer brings a file under
+    ``layer_costs/`` and joins ``serve_step_mfu`` and the ``.by_layer``
+    rooflines by a line."""
+    at_pr58 = [n for n, _ in AT_PR58["accepted_at_pr58"]["per_layer"]]
+    assert len(at_pr58) == 128
+    assert len({name_since_pr59(n) for n in at_pr58}) == 119
+    names = [p["name"] for p in manifest()["per_layer"]]
+    assert not set(names) & set(SINCE_PR59)
+    # the accepted entries in the order they were: a copy taken out, the
+    # oldest of its group (Trinity's) renamed where it stood
+    assert names[:119] == [name_since_pr59(n) for n in at_pr58
+                           if n not in SINCE_PR59 or n.endswith(".mixedlen")]
+    mfu = next(p for p in manifest()["per_layer"]
+               if p["name"] == "serve_step_mfu")
+    assert mfu["workloads"][-4:] == [
+        "serve-granite4h-shortchat-batch", "serve-lfm2-ragdoc-batch",
+        "serve-mimo-reasoning-batch", "serve-sala-longdoc-batch"]
+    for p in manifest()["per_layer"]:
+        if p["name"].endswith(".by_layer"):
+            assert p["workloads"][:3] == [
+                "serve-trinity-mixedlen-batch", "serve-lfm2-ragdoc-batch",
+                "serve-mimo-reasoning-batch"]
+    # every serving cell still reads the whole step's share under ``mfu``
+    for w in manifest()["workloads"]:
+        if w["name"].startswith("serve-"):
+            assert sum("mfu" in p["name"] for p in manifest()["per_layer"]
+                       if metric_applies(p, w["name"])) == 1, w["name"]

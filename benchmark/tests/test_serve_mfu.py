@@ -1,8 +1,10 @@
 """What bounds a serving claim (PR 42): ``costs_serve`` against a hand count
 on each serving configuration's ``rehearsal`` preset (dense, window +
-experts, latent, selecting); ``serve_mfu`` without a profiler trace; a closed
-list's traced stretch placed by the list's progress; the expert GEMM's time
-taken by scope."""
+experts, latent, selecting); since PR 59 the ONE need, asked layer by layer
+of the files under ``layer_costs/``, against what the parent's five family
+functions gave for counts recorded on the chip; ``serve_mfu`` without a
+profiler trace; a closed list's traced stretch placed by the list's
+progress."""
 
 import json
 import os
@@ -13,24 +15,32 @@ import pytest
 
 import costs_serve
 import run as bench_run
+import layer_costs
 import serve_mfu
-import window_rooflines
+from layer_costs import latent, selecting
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
-def rehearsal_cfg(name):
-    """The model configuration ``run.py --rehearse`` builds for ``name``."""
+def model_cfg(name, rehearsal=False):
+    """The model configuration ``run.py`` builds for the configuration
+    ``name``, at its published widths or at its ``rehearsal`` preset."""
     from deepspeed_tpu.models import GPTConfig
     with open(os.path.join(BENCH, "configs", name + ".json")) as f:
         sizes = json.load(f)
-    sizes = {**sizes, **sizes["rehearsal"]}
+    if rehearsal:
+        sizes = {**sizes, **sizes["rehearsal"]}
     ref = bench_run.load_module(
         os.path.join(BENCH, "reference", name + ".py"),
         "ref_" + "".join(c if c.isalnum() else "_" for c in name))
-    return GPTConfig(**ref.program_config(sizes), max_seq_len=512)
+    return GPTConfig(**ref.program_config(sizes),
+                     **({"max_seq_len": 512} if rehearsal else {}))
+
+
+def rehearsal_cfg(name):
+    return model_cfg(name, rehearsal=True)
 
 
 # hidden 64 everywhere; weights a row passes outside the routed experts,
@@ -112,7 +122,8 @@ HAND = {
 @pytest.mark.parametrize("name", sorted(HAND))
 def test_the_need_against_a_hand_count_on_the_rehearsal_preset(name):
     cfg, hand = rehearsal_cfg(name), HAND[name]
-    assert costs_serve.row_weights(cfg) == hand["weights"]
+    assert {k: n for k, n in costs_serve.row_weights(cfg).items() if n} \
+        == {k: n for k, n in hand["weights"].items() if n}
     need = costs_serve.window_need(cfg, hand["counts"])
     assert need["terms"] == {k: float(v) for k, v in hand["terms"].items()}
     assert need["flops"] == sum(hand["terms"].values())
@@ -122,19 +133,126 @@ def test_the_need_against_a_hand_count_on_the_rehearsal_preset(name):
 def test_the_published_widths_give_the_configurations_own_parameter_count():
     """dots3's file states its attention's parameters a kind of layer
     (``published.parameters``): the weights a row passes are those."""
-    full = costs_serve.attention_weights(
-        5120, 128, 128, 192, v_head_dim=128, kv_lora_rank=512,
-        qk_rope_head_dim=64, q_lora_rank=1024, gate="headwise",
-        index_heads=64, index_dim=128)
+    full = types.SimpleNamespace(
+        num_heads=128, kv_heads=128, head_dim=192, v_head_dim=128,
+        kv_lora_rank=512, qk_rope_head_dim=64, q_lora_rank=1024)
+    cfg = types.SimpleNamespace(
+        hidden_size=5120, for_layer=lambda i: full, attn_gate=True,
+        attn_gate_headwise=True, index_n_heads=64, index_head_dim=128)
     # wq_a 5.24 + wq_b 25.17 + wkv_a 2.95 + wkv_b 16.78 + wo 83.89 + gate
     # 0.66 + indexer 9.37 M, as that file lists them (its 144.1 M has the
     # norms' vectors too)
-    assert full == (5242880 + 25165824 + 2949120 + 16777216 + 83886080
-                    + 655360 + 9371648)
+    assert selecting.row_weights(cfg, 0) == {"attention": (
+        5242880 + 25165824 + 2949120 + 16777216 + 83886080 + 655360
+        + 9371648)}
     # Moonlight: 13.76 M a layer
-    assert round(costs_serve.attention_weights(
-        2048, 16, 16, 192, v_head_dim=128, kv_lora_rank=512,
-        qk_rope_head_dim=64) / 1e6, 2) == 13.76
+    moon = types.SimpleNamespace(
+        num_heads=16, kv_heads=16, head_dim=192, v_head_dim=128,
+        kv_lora_rank=512, qk_rope_head_dim=64, q_lora_rank=0)
+    assert round(latent.weights(moon, 2048) / 1e6, 2) == 13.76
+
+
+# ---- PR 59: one need, asked layer by layer -----------------------------
+with open(os.path.join(HERE, "data", "mfu_lines.json")) as _f:
+    RECORDED = json.load(_f)
+with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as _f:
+    _M = json.load(_f)
+CONFIG_OF = {w["name"]: w["config"] for w in _M["workloads"]}
+
+
+@pytest.mark.parametrize("cell,k", [(c, k) for c in sorted(RECORDED)
+                                    for k in range(len(RECORDED[c]))])
+def test_the_need_is_the_parents_on_counts_recorded_on_the_chip(cell, k):
+    """``data/mfu_lines.json``: ``counts`` of ``{"phase": "mfu"}`` lines
+    under ``chiprun_out/`` (and, last of a cell, the first line's with the
+    counters withheld), each with what the PARENT's family function gave for
+    them at the cell's published widths (``costs_serve`` / ``costs_ssm`` /
+    ``costs_conv`` / ``costs_swa`` / ``costs_sala`` ``.window_need`` of
+    commit 556662a, picked as its five readers picked it)."""
+    line = RECORDED[cell][k]
+    need = costs_serve.window_need(model_cfg(CONFIG_OF[cell]),
+                                   line["counts"])
+    want = line["parent"]
+    assert set(need["terms"]) == set(want["terms"])
+    for name, flops in want["terms"].items():
+        assert need["terms"][name] == pytest.approx(flops, rel=1e-9), name
+    assert need["flops"] == pytest.approx(want["flops"], rel=1e-9)
+    assert sorted(need["left_out"]) == sorted(want["left_out"])
+    if line["recorded_needed_flops"]:    # and what the chip run printed
+        assert need["flops"] == pytest.approx(
+            line["recorded_needed_flops"], rel=1e-9)
+
+
+def test_every_kind_of_layer_the_cells_have_is_a_file():
+    kinds = set()
+    for cell in RECORDED:
+        cfg = model_cfg(CONFIG_OF[cell])
+        for i in range(cfg.num_layers):
+            kinds |= set(layer_costs.kinds(cfg, i))
+    assert kinds == {"attention", "latent", "selecting", "block_selecting",
+                     "mamba", "lightning", "conv", "mlp", "experts"}
+    for kind in kinds:
+        cost = layer_costs.find(kind)
+        assert callable(cost.row_weights) and callable(cost.window_terms)
+    assert hasattr(layer_costs.find("attention"), "paged_attention_cost")
+
+
+def test_a_kind_of_layer_without_a_cost_file_reads_nothing_and_is_named(
+        capsys):
+    """A configuration that brings a new kind of layer and not its file:
+    no silent zero for its layers, no share at all, and a line that says
+    which file is missing."""
+    cfg = rehearsal_cfg("mistral-7b-v0.3-16l")
+    odd = types.SimpleNamespace(**{
+        k: getattr(cfg, k) for k in (
+            "num_layers", "hidden_size", "vocab_size", "for_layer",
+            "window_for_layer", "is_moe_layer", "block_topk", "index_topk")},
+        layer_kind=lambda i: "attention" if i else "gated_delta")
+    assert layer_costs.find("gated_delta") is None
+    with pytest.raises(costs_serve.NoCostFile, match="gated_delta"):
+        costs_serve.window_need(odd, {"rows": 1})
+    ctx = {"peaks": PEAKS, "model_cfg": odd, "window_s": 2.0,
+           "serve_window": {"counts": {"rows": 1}, "fenced_s": 0.5}}
+    assert serve_mfu.read(ctx, {"name": "serve_step_mfu"}) is None
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["no_cost_file"] == "gated_delta" and line["value"] is None
+
+
+def test_a_new_kind_of_layer_joins_by_a_file(tmp_path):
+    """What a later ``model_config`` PR does: in a scratch copy of the
+    benchmark, ONE new file under ``layer_costs/`` named as the program
+    names the kind, and nothing that is there edited; the same need then
+    counts the new layers by it."""
+    import shutil
+    import subprocess
+    import sys
+    bench = tmp_path / "benchmark"
+    shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
+        "__pycache__", "tests", "reference", "tools"))
+    (bench / "layer_costs" / "gated_delta.py").write_text(
+        "def row_weights(cfg, i):\n"
+        "    return {'delta_proj': 7 * cfg.hidden_size}\n\n\n"
+        "def window_terms(cfg, i, counts, alike):\n"
+        "    return {'delta_rule': 6.0 * counts['rows'] / alike}, []\n")
+    code = (
+        "import sys, types, json\n"
+        f"sys.path.insert(0, {str(bench)!r})\n"
+        "import costs_serve\n"
+        "cfg = types.SimpleNamespace(num_layers=3, hidden_size=64, "
+        "vocab_size=512, gated_mlp=True, mlp_dim=128, "
+        "layer_kind=lambda i: 'gated_delta', "
+        "is_moe_layer=lambda i: False)\n"
+        "print(json.dumps(costs_serve.window_need(cfg, {'rows': 10, "
+        "'sampled': 1})))\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    need = json.loads(out.stdout.strip().splitlines()[-1])
+    assert need["terms"] == {
+        "weights_delta_proj": 2.0 * 3 * 7 * 64 * 10,
+        "weights_mlp": 2.0 * 3 * 3 * 64 * 128 * 10,
+        "weights_head": 2.0 * 64 * 512, "delta_rule": 6.0 * 10}
+    assert need["left_out"] == []
 
 
 def test_a_count_the_program_did_not_give_is_left_out_and_named():
@@ -263,56 +381,3 @@ def test_a_list_that_ends_first_still_closes_cleanly(reaches):
     assert tr.state == "done" and not tr._thread.is_alive()
     assert ("trace_stop_s" in tr.placed) == reaches
     assert (tr.started_at is not None) == reaches
-
-
-def test_the_expert_gemm_is_taken_by_scope_whatever_its_name():
-    """A hand-made trace whose expert product is a custom call of another
-    name under ``.../mlp/moe_experts/...`` reads the same
-    ``expert_gemm_roofline`` as one named ``ragged-dot-none`` (which keeps no
-    scope path), and what the scope costs around the product counts."""
-    with open(os.path.join(BENCH, "metrics",
-                           "expert_gemm_roofline.json")) as f:
-        spec = json.load(f)
-    ms = 1_000_000
-    pre = "jit(ragged_forward_sampled)/while/body/mlp/"
-
-    def ctx_with(product):
-        meta = {1: product,
-                2: {"name": "fusion.7", "opcode": "fusion",
-                    "tf_op": pre + "moe_experts/mul"},        # the activation
-                3: {"name": "fusion.9", "opcode": "fusion",
-                    "tf_op": pre + "moe_route/top_k"},
-                4: {"name": "while.1", "opcode": "while",
-                    "tf_op": pre + "moe_experts/while"}}      # a container
-        ops = [(3, 0, 1 * ms), (4, 1 * ms, 9 * ms), (1, 1 * ms, 7 * ms),
-               (2, 7 * ms, 9 * ms)]
-        dev = {"meta": meta, "ops": ops,
-               "modules": [("ragged_forward_sampled", 0, 10 * ms)]}
-        spans = [{"name": "ds.mixed_dispatch", "thread": "t",
-                  "start_ns": t, "end_ns": t + 5,
-                  "args": {"moe_local": str(a), "moe_touched": str(b)}}
-                 for t, a, b in ((10, 1000, 40), (20, 9000, 200))]
-        cfg = types.SimpleNamespace(
-            num_layers=1, hidden_size=3072, expert_dim=3072,
-            window_for_layer=lambda i: None)
-        return {"_xmeta": {"devices": {0: dev}, "annotations": spans},
-                "trace_window": (0, 100 * ms), "model_cfg": cfg,
-                "peaks": PEAKS}
-
-    named = window_rooflines.read(ctx_with(
-        {"name": "ragged-dot-none", "opcode": "custom-call", "tf_op": ""}),
-        spec)
-    other = window_rooflines.read(ctx_with(
-        {"name": "expert_mlp_kernel", "opcode": "custom-call",
-         "tf_op": pre + "moe_experts/pallas_call"}), spec)
-    assert named == other
-    # 8,000 rows over 160 touched experts in 8 ms of scope time (6 of the
-    # product, 2 of the activation; the container is not work)
-    flops = 8000 * 3 * 2 * 3072 * 3072
-    byts = (160 * 3 * 3072 * 3072 + 8000 * 3 * 6144) * 2
-    assert abs(named - 100 * max(flops / 197e12, byts / 819e9) / 0.008) < 1e-9
-    # outside the scope and under another name it is not the expert GEMM
-    assert window_rooflines.read(ctx_with(
-        {"name": "expert_mlp_kernel", "opcode": "custom-call",
-         "tf_op": pre + "moe_route/pallas_call"}), spec) == pytest.approx(
-             100 * max(flops / 197e12, byts / 819e9) / 0.002)
